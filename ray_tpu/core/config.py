@@ -51,11 +51,6 @@ def _parse_bool(s: str) -> bool:
 # The table. Keep alphabetized within each section.
 _FLAGS: List[Flag] = [
     # ---- core runtime ----------------------------------------------------
-    Flag("assume_tpu", bool, False,
-         "Treat this host as having a TPU even when libtpu detection "
-         "fails (CI containers, forced-TPU test paths). Read at call "
-         "time directly from RTPU_ASSUME_TPU in resources.detect(), not "
-         "via config resolution, so late env changes take effect."),
     Flag("fault_dump_after_s", float, 0.0,
          "If > 0, every worker dumps all thread stacks to "
          "/tmp/rtpu_worker_dump_<pid>.txt after this many seconds "
@@ -86,8 +81,8 @@ _FLAGS: List[Flag] = [
          "backpressure (reference: "
          "_generator_backpressure_num_objects, _raylet.pyx)."),
     Flag("tpu_topology", str, "",
-         "Override the detected TPU topology string (e.g. '2x2x1'), "
-         "for scheduling tests on hosts without the real topology. "
+         "Replace TPU detection with <generation>-<chips> (e.g. "
+         "'v5e-8'), for scheduling tests on hosts without chips. "
          "Read at call time from RTPU_TPU_TOPOLOGY in "
          "resources.detect(), not via config resolution."),
     Flag("worker_register_timeout_s", float, 30.0,

@@ -106,6 +106,73 @@ _GENERATION_CHIPS_PER_HOST = {
     "v2": 4, "v3": 4, "v4": 4, "v5e": 4, "v5litepod": 4, "v5p": 4, "v6e": 4,
 }
 
+# PCI identity of a TPU chip: Google's vendor id and the device ids jax
+# itself keys on (jax/_src/hardware_utils.py). Only ids that name exactly
+# one generation are listed; anything else from this vendor is an error.
+_GOOGLE_PCI_VENDOR = "0x1ae0"
+_TPU_PCI_GENERATION = {
+    "0x005e": "v4", "0x0062": "v5p", "0x0063": "v5e", "0x006f": "v6e",
+}
+
+
+class TpuDetectionError(RuntimeError):
+    """A chip device node is present but cannot be identified."""
+
+
+def _read(path: str) -> Optional[str]:
+    try:
+        with open(path) as f:
+            return f.read().strip()
+    except OSError:
+        return None
+
+
+def scan_tpu_chips(dev_root: str = "/dev", sys_root: str = "/sys"
+                   ) -> Tuple[List[Tuple[str, str]], str]:
+    """The chips THIS process could open, without touching jax/libtpu.
+
+    A chip is a device node — ``/dev/accel<N>`` (v4 and older drivers) or
+    a numeric ``/dev/vfio/<group>`` (v5e and newer) — whose PCI function
+    carries Google's vendor id. The device node, not the PCI listing,
+    decides the count: a sandbox handed one chip of a four-chip host
+    still lists four PCI functions but exposes one node.
+
+    Returns ``([(node, pci_device_id), ...], seen)`` where ``seen`` is a
+    one-line account of what was looked at, for error messages.
+    """
+    import glob
+
+    # iommu group -> (vendor, device) of the PCI functions in sysfs
+    groups: Dict[str, Tuple[str, str]] = {}
+    google_pci: List[str] = []
+    for vp in sorted(glob.glob(
+            os.path.join(sys_root, "bus/pci/devices/*/vendor"))):
+        d = os.path.dirname(vp)
+        vendor, device = _read(vp), _read(os.path.join(d, "device"))
+        if vendor == _GOOGLE_PCI_VENDOR:
+            google_pci.append(f"{os.path.basename(d)}={device}")
+        link = os.path.join(d, "iommu_group")
+        if os.path.exists(link):
+            groups[os.path.basename(os.path.realpath(link))] = (
+                vendor or "", device or "")
+
+    nodes: List[Tuple[str, str, str]] = []     # (node, vendor, device)
+    for node in sorted(glob.glob(os.path.join(dev_root, "accel*"))):
+        d = os.path.join(sys_root, "class/accel", os.path.basename(node),
+                         "device")
+        nodes.append((node, _read(os.path.join(d, "vendor")) or "",
+                      _read(os.path.join(d, "device")) or ""))
+    for node in sorted(glob.glob(os.path.join(dev_root, "vfio/*"))):
+        if os.path.basename(node).isdigit():   # skip the vfio control node
+            nodes.append((node, *groups.get(os.path.basename(node),
+                                            ("", ""))))
+    chips = [(n, dev) for n, vendor, dev in nodes
+             if vendor == _GOOGLE_PCI_VENDOR]
+    seen = (f"device nodes under {dev_root} (accel*, vfio/<n>): "
+            f"{[n for n, _, _ in nodes] or 'none'}; Google PCI functions "
+            f"under {sys_root}/bus/pci/devices: {google_pci or 'none'}")
+    return chips, seen
+
 
 def _grid_for(num_chips: int) -> Tuple[int, int]:
     """Most-square 2D grid for n chips (ICI mesh model)."""
@@ -138,7 +205,7 @@ class TpuSliceTopology:
     scheduler structure instead of opaque custom resources.
     """
 
-    def __init__(self, generation: str = "v5e", num_chips: int = 1,
+    def __init__(self, generation: str, num_chips: int = 1,
                  chips_per_host: Optional[int] = None):
         self.generation = generation
         self.num_chips = num_chips
@@ -158,37 +225,34 @@ class TpuSliceTopology:
     # -- detection ----------------------------------------------------------
 
     @classmethod
-    def detect(cls) -> Optional["TpuSliceTopology"]:
-        """Detect local TPU chips.
+    def detect(cls, dev_root: str = "/dev", sys_root: str = "/sys"
+               ) -> Optional["TpuSliceTopology"]:
+        """The TPU chips of this machine, or None when it has none.
 
-        Order: explicit env override (RTPU_TPU_TOPOLOGY=v5e-8), TPU chip
-        device files (/dev/accel* or /dev/vfio — same signals the reference
-        scans, accelerators/tpu.py:49), else a jax probe is skipped (too
-        slow for init); no TPU → None.
+        RTPU_TPU_TOPOLOGY=<generation>-<chips> (e.g. v5e-8) replaces
+        detection for scheduling tests on hosts without chips. Otherwise
+        the chips are the device nodes ``scan_tpu_chips`` finds, and the
+        generation is read from their PCI device id — a Google device
+        this table does not know is an error, never a guess. jax is not
+        imported: the driver must stay off the chip.
         """
         override = os.environ.get("RTPU_TPU_TOPOLOGY")
         if override:
             gen, _, n = override.rpartition("-")
-            return cls(generation=gen or "v5e", num_chips=int(n))
-        try:
-            import glob
-
-            accel = glob.glob("/dev/accel*")
-            if not accel:
-                # vfio-backed TPU VMs: group nodes are numeric; skip the
-                # /dev/vfio/vfio control node (and non-TPU vfio hosts are
-                # excluded by requiring the TPU env marker).
-                groups = [p for p in glob.glob("/dev/vfio/*")
-                          if os.path.basename(p).isdigit()]
-                if groups and os.environ.get("TPU_SKIP_MDS_QUERY") is not None:
-                    accel = groups
-            if accel:
-                return cls(generation="v5e", num_chips=len(accel))
-        except OSError:
-            pass
-        if os.environ.get("RTPU_ASSUME_TPU"):
-            return cls(generation="v5e", num_chips=1)
-        return None
+            if not gen or not n.isdigit():
+                raise ValueError(
+                    f"RTPU_TPU_TOPOLOGY={override!r}: expected "
+                    f"<generation>-<chips>, e.g. v5e-8")
+            return cls(generation=gen, num_chips=int(n))
+        chips, seen = scan_tpu_chips(dev_root, sys_root)
+        if not chips:
+            return None
+        gens = {_TPU_PCI_GENERATION.get(dev) for _, dev in chips}
+        if None in gens or len(gens) != 1:
+            raise TpuDetectionError(
+                f"cannot name the TPU generation of {chips}: known PCI "
+                f"device ids are {_TPU_PCI_GENERATION}; {seen}")
+        return cls(generation=gens.pop(), num_chips=len(chips))
 
     # -- allocation ---------------------------------------------------------
 

@@ -43,7 +43,7 @@ from ray_tpu.core.placement_group import (
 )
 from ray_tpu.core.protocol import _TopLevelDep
 from ray_tpu.core.resources import (
-    ResourceSet, TpuSliceTopology, node_resources,
+    ResourceSet, TpuSliceTopology, node_resources, scan_tpu_chips,
 )
 from ray_tpu.util.debug_lock import check_fire_outside, make_condition, \
     make_lock
@@ -283,6 +283,17 @@ class _ForkedProc:
         os.close(self._pidfd)
         self._pidfd = None
         return self.returncode
+
+
+def _pidfd_supported() -> bool:
+    """Whether this kernel has pidfd_open (Linux >= 5.3). Sandboxed
+    kernels (gVisor, as on the v5e hosts) do not; there a zygote-forked
+    worker could not be watched, so the runtime cold-spawns instead."""
+    try:
+        os.close(os.pidfd_open(os.getpid()))
+        return True
+    except OSError:
+        return False
 
 
 class _Worker:
@@ -534,7 +545,7 @@ class Runtime:
         # (reference: prestarted workers, raylet/worker_pool.h:344)
         self._zygote: Optional[subprocess.Popen] = None
         self._zygote_lock = make_lock("Runtime._zygote_lock")
-        if config.worker_zygote:
+        if config.worker_zygote and _pidfd_supported():
             try:
                 with self._zygote_lock:
                     self._start_zygote_locked()
@@ -569,11 +580,16 @@ class Runtime:
         )
         if extra_env:
             env.update(extra_env)
-        if not tpu:
-            # Plain pool workers skip TPU/PJRT plugin registration
-            # (~2s jax import per process); workers that land TPU actors
-            # (num_tpus>0) are spawned with the env intact. Shared with
-            # the zygote fork path — see worker_env.py.
+        if tpu:
+            # the process that owns chips compiles for them: hand it the
+            # persistent compile cache through the env jax reads at import
+            from ray_tpu.core.compile_cache import ensure_compile_cache
+
+            ensure_compile_cache(env)
+        else:
+            # Plain pool workers never open the chip; workers that land
+            # TPU actors (num_tpus>0) keep the ambient platform. Shared
+            # with the zygote fork path — see worker_env.py.
             from ray_tpu.core.worker_env import sanitize_cpu_worker_env
 
             sanitize_cpu_worker_env(env)
@@ -653,8 +669,8 @@ class Runtime:
             warm = self._zygote is not None
         if not tpu and python_exe is None and warm:
             # fast path: fork from the warm template. TPU workers need a
-            # fresh interpreter (PJRT plugin registration is env-driven
-            # at startup), so they always cold-spawn.
+            # fresh interpreter (libtpu reads its chip env at startup),
+            # so they always cold-spawn.
             pid = self._fork_from_zygote(worker_id, extra_env,
                                          out_path, err_path)
             if pid is not None:
@@ -2003,8 +2019,8 @@ class Runtime:
             if not is_actor:
                 raise ValueError(
                     "num_tpus is actor-scoped in this release: TPU chips are "
-                    "bound to dedicated worker processes at spawn time (PJRT "
-                    "plugin registration happens at interpreter startup). "
+                    "bound to dedicated worker processes at spawn time "
+                    "(libtpu reads its chip env at interpreter startup). "
                     "Wrap TPU work in an actor with num_tpus=N."
                 )
             req["TPU"] = float(num_tpus)
@@ -2026,6 +2042,16 @@ class Runtime:
             # through explicit accounting.
             return None, None
         return ResourceSet(req), pg_wire
+
+    def _check_tpu_feasible(self, n_tpus: float, what: str) -> None:
+        """Refuse at once a TPU request this node can never grant — left
+        pending it would only surface as a caller's timeout, with no word
+        about the chips that were (not) found."""
+        have = self._total.get("TPU")
+        if n_tpus > have:
+            raise ValueError(
+                f"{what} asks for {n_tpus:g} TPU chip(s) but this node has "
+                f"{have:g}. Chip detection saw: {scan_tpu_chips()[1]}")
 
     def _nested_unready_locked(self, spec) -> bool:
         """True if any ObjectID nested inside the task's args is not yet
@@ -2577,6 +2603,7 @@ class Runtime:
             self._pin_args(args_payload[1])
         state = _ActorState(actor_id, cls_fn_id, args_payload, deps, opts)
         state.request, state.pg_wire = self._prepare_request(opts, is_actor=True)
+        self._check_tpu_feasible(state.request.get("TPU"), "actor")
         if self._spec_pg_removed(state):
             with self._lock:
                 self._actors[actor_id] = state
@@ -2672,11 +2699,12 @@ class Runtime:
         if w is None:
             extra_env = {}
             if state.chips:
-                chips_str = ",".join(str(c) for c in state.chips)
                 # Same env contract the reference sets for TPU workers
                 # (accelerators/tpu.py:158 set_current_process_visible_accelerator_ids)
-                extra_env["TPU_VISIBLE_CHIPS"] = chips_str
-                extra_env["RTPU_TPU_CHIPS"] = chips_str
+                from ray_tpu.core.worker_env import tpu_worker_env
+
+                extra_env = tpu_worker_env(state.chips,
+                                           self.topology.num_chips)
             w = self._spawn_worker(tpu=needs_tpu, extra_env=extra_env)
         else:
             # replace task-pool capacity lazily (see _pool_deficit): the
@@ -3211,6 +3239,8 @@ class Runtime:
         pg_id = PlacementGroupID.from_random()
         state = PlacementGroupState(pg_id, bundles, strategy, name)
         for b in state.bundles:
+            self._check_tpu_feasible(b.reserved.get("TPU"),
+                                     f"placement group bundle {b.spec}")
             if not b.reserved.is_subset_of(self._total):
                 raise ValueError(
                     f"bundle {b.spec} can never fit this node's resources "

@@ -63,8 +63,9 @@ class LlamaConfig:
     # of recomputing (HBM for FLOPs; 0 = classic full per-layer remat).
     # Caveats: the head/tail split slices the stacked layer params, which
     # XLA may materialize as a duplicate of the stack — budget for it;
-    # measured neutral-to-NEGATIVE on v5e-lite at 1B (BENCH_NOTES.md),
-    # aimed at HBM-rich parts; sequential forward only (pp raises).
+    # measured neutral-to-NEGATIVE on v5e-lite at 1B (round 4, old
+    # machine), aimed at HBM-rich parts; sequential forward only (pp
+    # raises).
     remat_store_layers: int = 0
     # remat selectivity: "full" recomputes the whole layer on backward;
     # "save_qkv" keeps the post-rope q/k/v projections (HBM cost
@@ -76,7 +77,8 @@ class LlamaConfig:
     # carries the stacked weight GRADIENTS through its backward as
     # dynamic-update-slice'd buffers, which XLA partially re-copies per
     # iteration; unrolling removes that and measured +3% step throughput
-    # at 1B on v5e (855→806 ms with the bf16-MLP fix, BENCH_NOTES r5).
+    # at 1B on v5e (855→806 ms with the bf16-MLP fix; round 5, old
+    # machine).
     # Cost: compile time grows with depth (~30 s at 16 layers) — the
     # right trade for long training runs, wrong for tests/CI, so scan
     # stays the default.
@@ -192,7 +194,24 @@ def _attend(cfg: LlamaConfig, q, k, v, mesh=None, seq_axis=None):
     if impl == "auto":
         impl = "flash" if jax.default_backend() == "tpu" else "reference"
     if impl == "flash":
-        return flash_attention(q, k, v, causal=True)
+        if mesh is None:
+            return flash_attention(q, k, v, causal=True)
+        # A pallas_call is opaque to GSPMD: left bare under a sharded jit,
+        # XLA gathers the whole batch onto every chip and runs the kernel
+        # on all of it. shard_map hands each chip its own batch rows (and
+        # its heads, when tp divides the KV heads); the sequence stays
+        # whole — splitting it is ring/ulysses' job.
+        from jax.sharding import PartitionSpec as P
+
+        from ray_tpu.parallel.sharding import resolve_axis
+
+        tp = dict(mesh.shape).get("tp", 1)
+        heads = "tp" if tp > 1 and cfg.num_kv_heads % tp == 0 else None
+        spec = P(resolve_axis("batch", mesh), None, heads, None)
+        return jax.shard_map(
+            lambda q_, k_, v_: flash_attention(q_, k_, v_, causal=True),
+            mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
+            check_vma=False)(q, k, v)
     if impl in ("ring", "ulysses"):
         if seq_axis is not None:
             # already INSIDE a shard_map that includes the sp axis (the
@@ -382,11 +401,6 @@ def loss_fn_pp(cfg: LlamaConfig, params, batch: Dict[str, jax.Array],
             "drop them rather than read tuning signal from a no-op")
     from jax.sharding import PartitionSpec as P
 
-    try:
-        shard_map = jax.shard_map
-    except AttributeError:  # jax < 0.5: public alias not exported yet
-        from jax.experimental.shard_map import shard_map
-
     # pp x sequence-parallel composition: pp OUTER (this shard_map), sp
     # INNER (ring_attention_local's KV blocks rotate on the sp sub-axis,
     # or ulysses_attention_local's all-to-alls run over it). Sequences
@@ -460,7 +474,7 @@ def loss_fn_pp(cfg: LlamaConfig, params, batch: Dict[str, jax.Array],
     mb_spec = P(None, data_axes if data_axes else None,
                 "sp" if seq_par else None)
     rope_spec = P("sp" if seq_par else None)
-    outs = shard_map(
+    outs = jax.shard_map(
         sharded_pipeline, mesh=mesh,
         in_specs=(layer_spec, mb_spec, rope_spec, rope_spec),
         out_specs=mb_spec,

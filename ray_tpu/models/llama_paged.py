@@ -317,4 +317,13 @@ def make_paged_engine_fns(cfg: LlamaConfig, params, mesh=None,
                        block_table, num_steps, rng, temperature, top_k,
                        sample, use_kernel, False)
 
+    # same signatures, lowered instead of run (see llama_decode)
+    pre.lower = lambda cache, tokens, block_table, ctx0, n_valid: \
+        prefill_j.lower(cfg, params, cache, tokens, block_table, ctx0,
+                        n_valid)
+    dec_chunk.lower = lambda cache, tokens, positions, active, \
+        block_table, num_steps, rng, temperature, top_k, sample: \
+        chunk_j.lower(cfg, params, cache, tokens, positions, active,
+                      block_table, num_steps, rng, temperature, top_k,
+                      sample, use_kernel, False)
     return pre, dec_chunk

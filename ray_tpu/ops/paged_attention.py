@@ -198,8 +198,7 @@ def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
             jax.ShapeDtypeStruct((S, KVH, G, 1), jnp.float32),
         ],
         interpret=interpret,
-        compiler_params=getattr(pltpu, "CompilerParams",
-                                getattr(pltpu, "TPUCompilerParams", None))(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
     )(block_table, ctx_len, q, k_pages, v_pages)
     return out, m[..., 0], l[..., 0]

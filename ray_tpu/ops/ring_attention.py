@@ -71,14 +71,14 @@ def ring_attention_local(q: jax.Array, k: jax.Array, v: jax.Array,
         v_nxt = jax.lax.ppermute(v_cur, axis_name, perm)
         return acc_new, m_new, l_new, k_nxt, v_nxt
 
-    # pvary marks the fresh accumulators as varying over the ring axis so the
-    # fori_loop carry types match (outputs depend on axis_index); jax < 0.6
-    # has no varying-axes typing, so the identity is the correct no-op there.
-    pvary = getattr(jax.lax, "pvary", lambda x, _: x)
-    acc0 = pvary(jnp.zeros((b, h, chunk, d), jnp.float32), axis_name)
-    m0 = pvary(
-        jnp.full((b, h, chunk, 1), _NEG_INF, jnp.float32), axis_name)
-    l0 = pvary(jnp.zeros((b, h, chunk, 1), jnp.float32), axis_name)
+    # mark the fresh accumulators as varying over the ring axis so the
+    # fori_loop carry types match (outputs depend on axis_index)
+    def varying(x):
+        return jax.lax.pcast(x, axis_name, to="varying")
+
+    acc0 = varying(jnp.zeros((b, h, chunk, d), jnp.float32))
+    m0 = varying(jnp.full((b, h, chunk, 1), _NEG_INF, jnp.float32))
+    l0 = varying(jnp.zeros((b, h, chunk, 1), jnp.float32))
     acc, m, l, _, _ = jax.lax.fori_loop(0, n, step, (acc0, m0, l0, k, v))
     out = acc / jnp.maximum(l, 1e-30)
     return out.transpose(0, 2, 1, 3).astype(q.dtype)
@@ -89,10 +89,7 @@ def ring_attention(q: jax.Array, k: jax.Array, v: jax.Array, mesh,
                    sm_scale: Optional[float] = None) -> jax.Array:
     """Global-array entry: q/k/v [batch, seq, heads, head_dim] with seq
     sharded over ``axis_name``; returns the same layout."""
-    try:
-        from jax import shard_map
-    except ImportError:  # jax < 0.5: public alias not exported yet
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     spec = P(None, axis_name, None, None)

@@ -90,10 +90,7 @@ def ulysses_attention(q: jax.Array, k: jax.Array, v: jax.Array, mesh,
                       attn_fn: Optional[Callable] = None) -> jax.Array:
     """Global-array entry: q/k/v [batch, seq, heads, head_dim] with seq
     sharded over ``axis_name``; returns the same layout."""
-    try:
-        from jax import shard_map
-    except ImportError:  # jax < 0.5: public alias not exported yet
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     spec = P(None, axis_name, None, None)

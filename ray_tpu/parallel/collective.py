@@ -297,10 +297,7 @@ def _reduce2(a: np.ndarray, b: np.ndarray, op: str) -> np.ndarray:
 def _xla_allreduce(mesh, tensor, op: str):
     import jax
     import jax.numpy as jnp
-    try:
-        from jax import shard_map
-    except ImportError:  # jax < 0.5: public alias not exported yet
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     fns = {"sum": jax.lax.psum, "max": jax.lax.pmax, "min": jax.lax.pmin}

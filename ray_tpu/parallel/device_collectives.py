@@ -74,10 +74,7 @@ def axis_index(axis: AxisName):
 def axis_size(axis: str):
     import jax
 
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis)
-    # jax < 0.5: psum of a literal folds to the static axis size
-    return jax.lax.psum(1, axis)
+    return jax.lax.axis_size(axis)
 
 
 def ring_permute(x, axis: str, shift: int = 1):

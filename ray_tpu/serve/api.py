@@ -307,8 +307,11 @@ def run(target: Deployment, name: Optional[str] = None,
         ok = ray_tpu.get(
             controller.wait_healthy.remote(dep_name, timeout), timeout=timeout + 10)
         if not ok:
+            why = status().get(dep_name, {}).get("last_replica_error")
             raise TimeoutError(
-                f"deployment {dep_name!r} did not become healthy")
+                f"deployment {dep_name!r} did not become healthy within "
+                f"{timeout:g}s" + (f"; last replica failure: {why}"
+                                   if why else ""))
     return DeploymentHandle(dep_name)
 
 

@@ -77,6 +77,12 @@ class _DeploymentInfo:
         # last report (the replica recovered: restore, don't replace)
         self.gray: Dict[str, tuple] = {}
         self.last_gray_replace = 0.0
+        # why replicas are not coming up: a resource request the cluster
+        # can never grant (stops the start loop; wait_healthy raises it)
+        # and the newest replica constructor/ping failure (reported by
+        # serve.run when the wait times out)
+        self.start_error: Optional[str] = None
+        self.last_replica_error: Optional[str] = None
 
     @staticmethod
     def _initial_target(cfg: dict) -> int:
@@ -116,6 +122,7 @@ class ServeController:
                 info.target = _DeploymentInfo._initial_target(config)
                 info.version += 1
                 info.deleting = False
+                info.start_error = info.last_replica_error = None
                 for r in list(info.replicas.values()):
                     self._stop_replica(info, r)
                 self._bump_locked(info)
@@ -276,6 +283,7 @@ class ServeController:
                     "ttft_p50_ms": percentile(info.ttft_ms, 50),
                     "ttft_p99_ms": percentile(info.ttft_ms, 99),
                     "cached_prefix_chains": self._cached_chains(info),
+                    "last_replica_error": info.last_replica_error,
                 }
                 for name, info in self._deployments.items()
             }
@@ -310,6 +318,10 @@ class ServeController:
         while time.monotonic() < deadline:
             with self._lock:
                 info = self._deployments.get(name)
+                if info is not None and info.start_error:
+                    raise RuntimeError(
+                        f"deployment {name!r} cannot start a replica: "
+                        f"{info.start_error}")
                 if info is not None:
                     running = sum(1 for r in info.replicas.values()
                                   if r.state == "RUNNING")
@@ -428,7 +440,7 @@ class ServeController:
             self._autoscale(info)
             with self._lock:
                 n = len(info.replicas)
-                deficit = info.target - n
+                deficit = 0 if info.start_error else info.target - n
                 surplus = n - info.target
             for _ in range(max(0, deficit)):
                 self._start_replica(info)
@@ -462,6 +474,11 @@ class ServeController:
                 info.pickled_def,
                 info.config.get("init_args") or (),
                 info.config.get("init_kwargs") or {})
+        except ValueError as e:
+            # the runtime refused the request outright (e.g. num_tpus on
+            # a node with no chip): no later tick can succeed
+            info.start_error = str(e)
+            return
         except Exception:  # noqa: BLE001 — no capacity yet; retry next tick
             return
         rinfo = _ReplicaInfo(replica_id, handle)
@@ -474,9 +491,10 @@ class ServeController:
                 ray_tpu.get(handle.ping.remote(), timeout=120)
                 rinfo.state = "RUNNING"
                 rinfo.last_healthy = time.monotonic()
-            except Exception:  # noqa: BLE001
+            except Exception as e:  # noqa: BLE001
                 with self._lock:
                     info.replicas.pop(replica_id, None)
+                    info.last_replica_error = repr(e)
                 try:
                     ray_tpu.kill(handle)
                 except Exception:  # noqa: BLE001

@@ -213,6 +213,23 @@ class PagedLLMEngine(LLMEngine):
         # that would keep overtaking it at the back of ``_in``
         self._retry: "collections.deque[tuple]" = collections.deque()
 
+    def _lowered_programs(self):
+        import jax
+
+        jnp = self._jnp
+        sds = jax.ShapeDtypeStruct
+        S = self._num_slots
+        cache = self._abstract_cache()
+        out = {f"prefill[{b}]": self._prefill_chunk.lower(
+            cache, sds((1, b), jnp.int32), sds((self._maxp,), jnp.int32),
+            sds((), jnp.int32), sds((), jnp.int32))
+            for b in self._buckets}
+        out["decode"] = self._decode_chunk.lower(
+            cache, sds((S,), jnp.int32), sds((S,), jnp.int32),
+            sds((S,), bool), sds((S, self._maxp), jnp.int32), 1,
+            self._zero_key, sds((S,), jnp.float32), 0, False)
+        return out
+
     def _reset_device_state(self):
         from ray_tpu.models import llama_paged
 
@@ -308,6 +325,7 @@ class PagedLLMEngine(LLMEngine):
                 firsts = self._run_prefill(np, jnp, slot, toks,
                                            matched, temp)
             except Exception as e:  # noqa: BLE001
+                self._note_error("prefill", e)
                 # this slot's fresh pages hold no valid K/V — they must
                 # NOT be published as cached prefixes
                 self._slot_hashes[slot] = []

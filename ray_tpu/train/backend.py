@@ -74,6 +74,9 @@ class JaxConfig(BackendConfig):
 
 
 def _setup_jax_platform(platform: Optional[str], n_cpu_devices: int):
+    from ray_tpu.core.compile_cache import ensure_compile_cache
+
+    ensure_compile_cache()
     if platform == "cpu":
         import re
 
@@ -90,7 +93,14 @@ def _setup_jax_platform(platform: Optional[str], n_cpu_devices: int):
 
         jax.config.update("jax_platforms", "cpu")
     elif platform == "tpu":
-        os.environ.setdefault("JAX_PLATFORMS", "tpu")
+        # asked for chips: open them now, in the worker the runtime gave
+        # them to, and fail here — not on some other backend mid-loop —
+        # if libtpu cannot
+        os.environ["JAX_PLATFORMS"] = "tpu"
+        import jax
+
+        jax.config.update("jax_platforms", "tpu")
+        jax.devices()
 
 
 def _pick_coordinator(port: int) -> str:
