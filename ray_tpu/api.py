@@ -15,6 +15,7 @@ from ray_tpu.core import runtime_context
 from ray_tpu.core.actor import ActorClass, ActorHandle, get_actor  # noqa: F401
 from ray_tpu.core.object_ref import ObjectRef
 from ray_tpu.core.remote_function import RemoteFunction
+from ray_tpu.util import tracing
 
 _runtime = None
 
@@ -37,6 +38,15 @@ def init(num_workers: Optional[int] = None,
         if ignore_reinit_error:
             return runtime_context.get_runtime_context()
         raise RuntimeError("ray_tpu.init() called twice")
+    with tracing.span("rtpu.init", keep=True):
+        _runtime = _start_core(num_workers, object_store_memory, address,
+                               log_to_driver)
+    runtime_context.set_core(_runtime)
+    atexit.register(shutdown)
+    return runtime_context.get_runtime_context()
+
+
+def _start_core(num_workers, object_store_memory, address, log_to_driver):
     if address is None:
         # submitted jobs inherit the cluster address from the job agent
         address = os.environ.get("RTPU_ADDRESS")
@@ -46,21 +56,17 @@ def init(num_workers: Optional[int] = None,
         from ray_tpu.client import ProxyCore
 
         host, _, port = address[len("ray://"):].rpartition(":")
-        _runtime = ProxyCore((host, int(port)))
-    elif address:
+        return ProxyCore((host, int(port)))
+    if address:
         from ray_tpu.core.cluster.cluster_core import ClusterCore
 
         host, _, port = address.rpartition(":")
-        _runtime = ClusterCore((host, int(port)))
-    else:
-        from ray_tpu.core.runtime import Runtime
+        return ClusterCore((host, int(port)))
+    from ray_tpu.core.runtime import Runtime
 
-        _runtime = Runtime(num_workers=num_workers,
-                           object_store_memory=object_store_memory,
-                           log_to_driver=log_to_driver)
-    runtime_context.set_core(_runtime)
-    atexit.register(shutdown)
-    return runtime_context.get_runtime_context()
+    return Runtime(num_workers=num_workers,
+                   object_store_memory=object_store_memory,
+                   log_to_driver=log_to_driver)
 
 
 def is_initialized() -> bool:
@@ -70,7 +76,8 @@ def is_initialized() -> bool:
 def shutdown():
     global _runtime
     if _runtime is not None:
-        _runtime.shutdown()
+        with tracing.span("rtpu.runtime.shutdown", keep=True):
+            _runtime.shutdown()
         _runtime = None
     if runtime_context.get_core_or_none() is not None:
         runtime_context.set_core(None)
@@ -189,14 +196,19 @@ def free(refs, *, local_only: bool = False) -> int:
 
 
 def timeline(filename: Optional[str] = None):
-    """Export recorded task events as a chrome://tracing trace (reference:
+    """Export recorded task events, merged with the spans this process
+    (and, on a cluster, every node's runtime) kept through
+    ``ray_tpu.util.tracing``, as one chrome://tracing trace (reference:
     ray.timeline, python/ray/_private/worker.py). Requires the
     RTPU_TASK_EVENTS_ENABLED=1 flag; returns the event list when no
-    filename is given."""
+    filename is given. Spans of a worker process are fetched by running
+    ``tracing.chrome_events`` there (``JaxTrainer.fit`` does, and writes
+    the gang's to ``trace_spans.json``)."""
     import json
 
     core = runtime_context.get_core()
     events = getattr(core, "_events", None)
+    spans = tracing.chrome_events()
     if events is None and hasattr(core, "_cluster_view"):
         # cluster driver: aggregate every node's flag-gated event log
         # (reference: ray.timeline merges per-raylet task events)
@@ -220,6 +232,10 @@ def timeline(filename: Optional[str] = None):
             nid = n["node_id"].hex()[:6] if hasattr(
                 n["node_id"], "hex") else str(n["node_id"])[:6]
             for e in node_events:
+                if "ph" in e:       # a span of that node's runtime
+                    spans.append({**e, "tid": f"{nid}:{e['tid']}",
+                                  "pid": idx * (1 << 23) + e["pid"]})
+                    continue
                 # composite pid: same OS pid on different hosts must not
                 # merge into one chrome-trace process row
                 events.append({**e, "worker": f"{nid}:{e['worker']}",
@@ -243,6 +259,7 @@ def timeline(filename: Optional[str] = None):
                                                    e["dispatched"]))
                  ) * 1e3, 3)},
     } for e in events]
+    trace = sorted(trace + spans, key=lambda e: e["ts"])
     if filename is None:
         return trace
     with open(filename, "w") as f:

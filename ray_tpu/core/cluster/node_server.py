@@ -1212,9 +1212,13 @@ class NodeServer:
     def _op_task_events(self):
         """Flag-gated task timeline events recorded by this node's
         runtime (driver aggregates across nodes for ray_tpu.timeline).
-        None = recording disabled on this node."""
+        None = recording disabled on this node. The spans this node's
+        runtime kept (util/tracing.py) ride along, already in
+        chrome-trace form."""
+        from ray_tpu.util import tracing
+
         ev = self.runtime._events
-        return None if ev is None else list(ev)
+        return None if ev is None else list(ev) + tracing.chrome_events()
 
     def _op_list_logs(self):
         from ray_tpu.core.log_monitor import list_log_files

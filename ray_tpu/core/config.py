@@ -123,12 +123,6 @@ _FLAGS: List[Flag] = [
          "for ray_tpu.timeline() chrome-trace export (reference: "
          "RAY_task_events_* flags + ray.timeline, "
          "python/ray/_private/state.py chrome_tracing_dump)."),
-    Flag("usage_stats_enabled", bool, False,
-         "Opt IN to the local usage-stats stub (reference: "
-         "RAY_usage_stats_enabled, usage_stats_head.py — but inverted "
-         "to opt-in, and nothing ever leaves the machine). Read at call "
-         "time from RTPU_USAGE_STATS_ENABLED in usage_stats.enabled(), "
-         "not via config resolution, so tests can flip it per-call."),
     # ---- fault tolerance -------------------------------------------------
     Flag("actor_restart_buffer_max", int, 1000,
          "How many calls may queue on a RESTARTING actor before new "

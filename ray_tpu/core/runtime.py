@@ -45,6 +45,7 @@ from ray_tpu.core.protocol import _TopLevelDep
 from ray_tpu.core.resources import (
     ResourceSet, TpuSliceTopology, node_resources, scan_tpu_chips,
 )
+from ray_tpu.util import tracing
 from ray_tpu.util.debug_lock import check_fire_outside, make_condition, \
     make_lock
 from ray_tpu.exceptions import (
@@ -652,6 +653,14 @@ class Runtime:
                       extra_env: Optional[Dict[str, str]] = None,
                       python_exe: Optional[str] = None,
                       env_key: Optional[str] = None) -> _Worker:
+        with tracing.span("rtpu.worker.spawn", keep=True, tpu=tpu) as sp:
+            w = self._spawn_worker_process(tpu, extra_env, python_exe,
+                                           env_key)
+            sp.attrs["cold"] = not isinstance(w.proc, _ForkedProc)
+        return w
+
+    def _spawn_worker_process(self, tpu, extra_env, python_exe,
+                              env_key) -> _Worker:
         worker_id = WorkerID.from_random()
         if env_key is not None:
             # the worker knows its own env so per-task application can
@@ -724,11 +733,14 @@ class Runtime:
 
     def _watch_until_ready(self, w: _Worker):
         deadline = time.monotonic() + config.worker_ready_timeout_s
-        while (not self._shutdown and w.alive and not w.ready
-               and time.monotonic() < deadline):
-            if w.proc is not None and w.proc.poll() is not None:
-                break
-            time.sleep(0.05)
+        # from the process's creation to its hello (50 ms resolution)
+        with tracing.span("rtpu.worker.ready", keep=True,
+                          cold=not isinstance(w.proc, _ForkedProc)):
+            while (not self._shutdown and w.alive and not w.ready
+                   and time.monotonic() < deadline):
+                if w.proc is not None and w.proc.poll() is not None:
+                    break
+                time.sleep(0.05)
         if not self._shutdown and w.alive and not w.ready:
             if w.proc is not None:
                 try:
@@ -1929,7 +1941,10 @@ class Runtime:
         # pre-ready death (broken env, bogus provider exe) is observed by
         # the shared _watch_until_ready watcher every spawn starts — it
         # feeds _on_worker_death, which drives this env's crash-loop
-        # bound / respawn via _dispatch_env
+        # bound / respawn via _dispatch_env. A worker that died before the
+        # flag above was cleared had its _dispatch_env turned away by it:
+        # look again now, or the env's queue waits forever.
+        self._dispatch_env(key)
 
     def _dispatch(self):
         self._route_env_specs()

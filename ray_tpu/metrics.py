@@ -4,6 +4,11 @@ Reference: python/ray/util/metrics.py (Counter/Gauge/Histogram backed by
 opencensus + the dashboard's /metrics endpoint). Here a process-local
 registry renders the Prometheus text format, served by a stdlib HTTP
 endpoint (start_metrics_server) — scrapeable by any Prometheus.
+
+The program's own counters are not copied into this registry: the object
+that owns them (the runtime, a serving engine, a train session)
+registers its ``stats()`` as a *source*, read at scrape time, and the
+span accumulators of ``ray_tpu.util.tracing`` are one more source.
 """
 
 from __future__ import annotations
@@ -11,21 +16,52 @@ from __future__ import annotations
 import bisect
 import threading
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Callable, Dict, List, Optional, Sequence, Tuple
+
+
+def _span_totals() -> Dict[str, float]:
+    from ray_tpu.util import tracing
+
+    out: Dict[str, float] = {}
+    for name, acc in tracing.totals().items():
+        key = name.replace(".", "_").replace("-", "_")
+        out[key + "_count"] = acc["count"]
+        out[key + "_seconds_total"] = acc["total_ns"] / 1e9
+    return out
 
 
 class _Registry:
     def __init__(self):
         self._metrics: List["Metric"] = []
+        self._sources: Dict[str, Callable[[], Dict]] = {
+            "rtpu_span": _span_totals}
         self._lock = threading.Lock()
 
     def register(self, m: "Metric"):
         with self._lock:
             self._metrics.append(m)
 
+    def register_source(self, prefix: str, read: Callable[[], Dict]):
+        """``read()`` returns a ``stats()``-like dict; its numeric items
+        are served as gauges ``<prefix>_<key>``, read at scrape time. A
+        second source under one prefix replaces the first."""
+        with self._lock:
+            self._sources[prefix] = read
+
     def render(self) -> str:
         with self._lock:
-            return "".join(m.render() for m in self._metrics)
+            metrics, sources = list(self._metrics), dict(self._sources)
+        lines = []
+        for prefix, read in sources.items():
+            try:
+                items = read().items()
+            except Exception:  # noqa: BLE001 — its owner is shutting down
+                continue
+            for k, v in items:
+                if isinstance(v, (int, float)) and not isinstance(v, bool):
+                    lines.append(f"# TYPE {prefix}_{k} gauge\n"
+                                 f"{prefix}_{k} {v}\n")
+        return "".join(m.render() for m in metrics) + "".join(lines)
 
 
 REGISTRY = _Registry()

@@ -111,6 +111,13 @@ def prefill_chunk(cfg: LlamaConfig, params, cache: Dict[str, jax.Array],
 
     def layer(x, inp):
         p, kp, vp = inp                                    # pages [P,KVH,pg,hd]
+        with jax.named_scope("attn"):
+            x, k, v = attend(x, p, kp, vp)
+        with jax.named_scope("mlp"):
+            x = x + _mlp(cfg, p, x)
+        return x, (k[0], v[0])                             # [C, KVH, hd]
+
+    def attend(x, p, kp, vp):
         q, k, v, _ = _project_qkv(cfg, p, x)               # [1,C,H,hd]
         q = apply_rope(q, cos, sin, positions=pos_c[None])
         k = apply_rope(k, cos, sin, positions=pos_c[None])
@@ -134,8 +141,7 @@ def prefill_chunk(cfg: LlamaConfig, params, cache: Dict[str, jax.Array],
         attn = attn.reshape(1, C, cfg.num_heads * hd)
         x = x + jnp.dot(attn, _w(p, "wo", cfg.dtype),
                         preferred_element_type=jnp.float32).astype(cfg.dtype)
-        x = x + _mlp(cfg, p, x)
-        return x, (k[0], v[0])                             # [C, KVH, hd]
+        return x, k, v
 
     x, (new_k, new_v) = jax.lax.scan(
         layer, x, (params["layers"], cache["k"], cache["v"]))
@@ -144,21 +150,23 @@ def prefill_chunk(cfg: LlamaConfig, params, cache: Dict[str, jax.Array],
     # rows (i >= n_valid) redirect out of bounds and drop. Non-adjacent
     # advanced indices (dims 1 and 3) put the index dim FIRST in the
     # update: [C, L, KVH, hd].
-    pidx = block_table[jnp.clip(pos_c // page, 0, MAXP - 1)]
-    pidx = jnp.where(ci < n_valid, pidx, num_pages)
-    poff = pos_c % page
-    upd_k = jnp.moveaxis(new_k, 1, 0)                      # [C, L, KVH, hd]
-    upd_v = jnp.moveaxis(new_v, 1, 0)
-    ck = cache["k"].at[:, pidx, :, poff].set(upd_k, mode="drop",
-                                             unique_indices=True)
-    cv = cache["v"].at[:, pidx, :, poff].set(upd_v, mode="drop",
-                                             unique_indices=True)
-    x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
-    x_last = x[0, jnp.maximum(n_valid - 1, 0)]             # [h]
-    head = (params["embed"].astype(cfg.dtype).T if cfg.tie_embeddings
-            else _w(params, "lm_head", cfg.dtype))
-    logits = jnp.dot(x_last[None], head,
-                     preferred_element_type=jnp.float32)   # [1, vocab]
+    with jax.named_scope("pool_copy"):
+        pidx = block_table[jnp.clip(pos_c // page, 0, MAXP - 1)]
+        pidx = jnp.where(ci < n_valid, pidx, num_pages)
+        poff = pos_c % page
+        upd_k = jnp.moveaxis(new_k, 1, 0)                  # [C, L, KVH, hd]
+        upd_v = jnp.moveaxis(new_v, 1, 0)
+        ck = cache["k"].at[:, pidx, :, poff].set(upd_k, mode="drop",
+                                                 unique_indices=True)
+        cv = cache["v"].at[:, pidx, :, poff].set(upd_v, mode="drop",
+                                                 unique_indices=True)
+    with jax.named_scope("sample"):
+        x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
+        x_last = x[0, jnp.maximum(n_valid - 1, 0)]         # [h]
+        head = (params["embed"].astype(cfg.dtype).T if cfg.tie_embeddings
+                else _w(params, "lm_head", cfg.dtype))
+        logits = jnp.dot(x_last[None], head,
+                         preferred_element_type=jnp.float32)  # [1, vocab]
     return {"k": ck, "v": cv}, logits
 
 
@@ -196,6 +204,13 @@ def paged_decode_step(cfg: LlamaConfig, params, cache: Dict[str, jax.Array],
     def layer(carry, inp):
         x = carry
         p, kp, vp = inp
+        with jax.named_scope("attn"):
+            x, k1, v1 = attend(x, p, kp, vp)
+        with jax.named_scope("mlp"):
+            x = x + _mlp(cfg, p, x)
+        return x, (k1, v1)
+
+    def attend(x, p, kp, vp):
         q, k, v, _ = _project_qkv(cfg, p, x)
         q = apply_rope(q, cos, sin, positions=pos2)
         k = apply_rope(k, cos, sin, positions=pos2)
@@ -221,25 +236,28 @@ def paged_decode_step(cfg: LlamaConfig, params, cache: Dict[str, jax.Array],
         attn = attn.reshape(S, 1, cfg.num_heads * hd)
         x = x + jnp.dot(attn, _w(p, "wo", cfg.dtype),
                         preferred_element_type=jnp.float32).astype(cfg.dtype)
-        x = x + _mlp(cfg, p, x)
-        return x, (k1, v1)
+        return x, k1, v1
 
     x, (new_k, new_v) = jax.lax.scan(
         layer, x, (params["layers"], cache["k"], cache["v"]))
-    pidx = jnp.take_along_axis(
-        block_table, jnp.clip(positions // page, 0, MAXP - 1)[:, None],
-        axis=1)[:, 0]
-    pidx = jnp.where(active, pidx, num_pages)              # drop inactive
-    poff = positions % page
-    # non-adjacent advanced indices (dims 1, 3): update is [S, L, KVH, hd]
-    ck = cache["k"].at[:, pidx, :, poff].set(
-        jnp.moveaxis(new_k, 1, 0), mode="drop")
-    cv = cache["v"].at[:, pidx, :, poff].set(
-        jnp.moveaxis(new_v, 1, 0), mode="drop")
-    x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
-    head = (params["embed"].astype(cfg.dtype).T if cfg.tie_embeddings
-            else _w(params, "lm_head", cfg.dtype))
-    logits = jnp.dot(x[:, 0], head, preferred_element_type=jnp.float32)
+    with jax.named_scope("pool_copy"):
+        pidx = jnp.take_along_axis(
+            block_table, jnp.clip(positions // page, 0, MAXP - 1)[:, None],
+            axis=1)[:, 0]
+        pidx = jnp.where(active, pidx, num_pages)          # drop inactive
+        poff = positions % page
+        # non-adjacent advanced indices (dims 1, 3): update is
+        # [S, L, KVH, hd]
+        ck = cache["k"].at[:, pidx, :, poff].set(
+            jnp.moveaxis(new_k, 1, 0), mode="drop")
+        cv = cache["v"].at[:, pidx, :, poff].set(
+            jnp.moveaxis(new_v, 1, 0), mode="drop")
+    with jax.named_scope("sample"):
+        x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
+        head = (params["embed"].astype(cfg.dtype).T if cfg.tie_embeddings
+                else _w(params, "lm_head", cfg.dtype))
+        logits = jnp.dot(x[:, 0], head,
+                         preferred_element_type=jnp.float32)
     return {"k": ck, "v": cv}, logits
 
 
@@ -269,12 +287,13 @@ def paged_decode_chunk(cfg: LlamaConfig, params,
         cache, logits = paged_decode_step(
             cfg, params, cache, toks, pos, active, block_table,
             use_kernel=use_kernel, interpret=interpret)
-        if sample:
-            key, sub = jax.random.split(key)
-            nxt = sample_tokens(logits, sub, temperature, top_k)
-        else:
-            nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        nxt = jnp.where(active, nxt, toks)
+        with jax.named_scope("sample"):
+            if sample:
+                key, sub = jax.random.split(key)
+                nxt = sample_tokens(logits, sub, temperature, top_k)
+            else:
+                nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            nxt = jnp.where(active, nxt, toks)
         return (cache, nxt, pos + active.astype(jnp.int32), key), nxt
 
     (cache, nxt, pos, _), out = jax.lax.scan(

@@ -177,6 +177,7 @@ def _flash_forward(q, k, v, causal, sm_scale, block_q, block_k, interpret):
     )
     out, lse = pl.pallas_call(
         kernel,
+        name="flash_fwd",
         out_shape=[
             jax.ShapeDtypeStruct((b * h, sq, d), q.dtype),
             jax.ShapeDtypeStruct((b * h, sq, 1), jnp.float32),
@@ -348,6 +349,7 @@ def _flash_backward(q, k, v, out, lse, g, causal, sm_scale, block_q, block_k,
             _flash_bwd_dq_kernel, block_k=block_k, causal=causal,
             sm_scale=sm_scale, seq_k=sk, block_q=block_q,
             causal_offset=causal_offset),
+        name="flash_bwd_dq",
         out_shape=jax.ShapeDtypeStruct((b * h, sq, d), q.dtype),
         grid=(b * h, sq // block_q),
         in_specs=[
@@ -370,6 +372,7 @@ def _flash_backward(q, k, v, out, lse, g, causal, sm_scale, block_q, block_k,
             _flash_bwd_dkv_kernel, block_q=block_q, causal=causal,
             sm_scale=sm_scale, seq_q=sq, block_k=block_k,
             causal_offset=causal_offset),
+        name="flash_bwd_dkv",
         out_shape=[
             jax.ShapeDtypeStruct((b * h, sk, d), k.dtype),
             jax.ShapeDtypeStruct((b * h, sk, d), v.dtype),

@@ -171,6 +171,7 @@ def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
                                kvh=KVH, sm_scale=sm_scale)
     out, m, l = pl.pallas_call(
         kernel,
+        name="paged_attn",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(S, MAXP),

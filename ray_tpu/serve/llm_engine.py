@@ -36,6 +36,8 @@ import time
 import traceback
 from typing import Any, Dict, List, Optional
 
+from ray_tpu.util import tracing
+
 
 def _bucket(n: int, buckets: List[int]) -> int:
     for b in buckets:
@@ -178,18 +180,24 @@ class LLMEngine:
         # jitted helpers: splice admitted slots into the chain state and
         # pick the prompt's first token on device (no host round-trip in
         # the admission path either)
-        def _merge(toks, pos, firsts, slots, valid, new_pos):
+        def merge_admitted(toks, pos, firsts, slots, valid, new_pos):
             idx = jnp.where(valid, slots, toks.shape[0])
             return (toks.at[idx].set(firsts, mode="drop"),
                     pos.at[idx].set(new_pos, mode="drop"))
 
-        self._merge_j = jax.jit(_merge)
-        self._argmax_j = jax.jit(
-            lambda lg: jnp.argmax(lg, axis=-1).astype(jnp.int32))
+        # named functions, not lambdas: a program's name in a profiler
+        # trace is its function's (``jit_first_argmax``)
+        def first_argmax(lg):
+            return jnp.argmax(lg, axis=-1).astype(jnp.int32)
+
         tk = self._top_k
-        self._sample_j = jax.jit(
-            lambda lg, key, temps: llama_decode.sample_tokens(
-                lg, key, temps, tk))
+
+        def first_sample(lg, key, temps):
+            return llama_decode.sample_tokens(lg, key, temps, tk)
+
+        self._merge_j = jax.jit(merge_admitted)
+        self._argmax_j = jax.jit(first_argmax)
+        self._sample_j = jax.jit(first_sample)
 
         # slot bookkeeping (host side)
         self._free = list(range(num_slots))
@@ -211,6 +219,22 @@ class LLMEngine:
         self._done_lock = threading.Lock()
         self._steps = 0
         self._completed = 0
+        # what the tick does with its time and its queue, monotonic,
+        # read through stats(): a tick's phases in ns, the in-flight
+        # queue's length summed once a tick, and slot-ticks (one per
+        # occupied slot per tick; "drained" = every token of the slot's
+        # budget is dispatched and the slot waits for its last reap)
+        self._ticks = 0
+        self._inflight_depth_sum = 0
+        self._steps_dispatched = 0
+        self._admit_ns = 0
+        self._dispatch_ns = 0
+        self._reap_wait_ns = 0
+        self._sleep_ns = 0
+        self._slot_ticks_occupied = 0
+        self._slot_ticks_drained = 0
+        self._trace: Dict[str, Any] = {"state": "off", "dir": None,
+                                       "error": None}
         self._key_ctr = 0
         self._stop = False
         # start-up outcome, read by report(): how long construction +
@@ -220,6 +244,15 @@ class LLMEngine:
         self._first_error: Optional[str] = None
         self._mosaic_calls: Optional[Dict[str, int]] = None
         self._mosaic_thread: Optional[threading.Thread] = None
+        import weakref
+
+        from ray_tpu import metrics
+
+        # weakly: the registry must not keep a shut-down engine's weights
+        # and cache alive (a dead source raises and is left out)
+        me = weakref.ref(self)
+        metrics.REGISTRY.register_source("rtpu_engine",
+                                         lambda: me().stats())
         self._thread = threading.Thread(target=self._run, daemon=True,
                                         name="llm-engine")
         self._thread.start()
@@ -290,6 +323,7 @@ class LLMEngine:
             "setup_s": self._setup_s,
             "first_error": self._first_error,
             "mosaic_calls": self._mosaic_calls,
+            "trace": dict(self._trace),
             "stats": self.stats(),
         }
         if self._setup_s is not None and self._mosaic_thread is None:
@@ -308,6 +342,42 @@ class LLMEngine:
                 for name, lowered in self._lowered_programs().items()}
         except Exception as e:  # noqa: BLE001 — reported, not raised
             self._note_error("report", e)
+
+    def start_trace(self, trace_dir: str) -> None:
+        """Start jax's profiler in this process, writing under
+        ``trace_dir``; returns at once. Starting (and stopping) takes
+        seconds, longer than a replica's health ping allows a call to
+        last, so it runs on a thread of its own: ``report()["trace"]``
+        says ``starting``, then ``on`` (or ``off`` with an ``error``).
+        While it is on, every span of this process is kept."""
+        self._trace_job("starting", "on", tracing.start_profile, trace_dir)
+
+    def stop_trace(self) -> None:
+        """Stop the profiler; ``report()["trace"]["state"]`` goes from
+        ``stopping`` to ``off`` once the trace is written."""
+        self._trace_job("stopping", "off", tracing.stop_profile)
+
+    def _trace_job(self, during: str, after: str, fn, *args) -> None:
+        if self._trace["state"] in ("starting", "stopping"):
+            raise RuntimeError(f"the profiler is {self._trace['state']}")
+        self._trace.update(state=during, error=None,
+                           dir=args[0] if args else self._trace["dir"])
+
+        def work() -> None:
+            try:
+                fn(*args)
+                self._trace["state"] = after
+            except Exception as e:  # noqa: BLE001 — reported, not raised
+                self._trace.update(state="off", error=repr(e))
+
+        threading.Thread(target=work, daemon=True,
+                         name=f"llm-engine-trace-{during}").start()
+
+    def timeline(self) -> List[dict]:
+        """The spans this process kept (chrome-trace form): everything
+        of a profiler window, or since start-up under the
+        ``task_events_enabled`` flag."""
+        return tracing.chrome_events()
 
     def _note_error(self, where: str, exc: BaseException) -> None:
         """Keep the FIRST device-program failure (later ones are usually
@@ -336,18 +406,21 @@ class LLMEngine:
         at-least-once delivery still runs the generation exactly once —
         the original's result lands in the mailbox under the same id."""
         now = time.monotonic()
-        with self._done_lock:
-            if len(self._seen_ids) > 2048:
-                cutoff = now - 600.0
-                self._seen_ids = {r: t for r, t in self._seen_ids.items()
-                                  if t > cutoff}
-            if req_id in self._seen_ids:
-                return
-            self._seen_ids[req_id] = now
-        self._in.put((req_id, list(prompt_tokens),
-                      max_new_tokens or self._max_new, now,
-                      float(temperature),
-                      frozenset(int(t) for t in (stop_ids or ()))))
+        with tracing.span("rtpu.engine.submit", id=req_id,
+                          prompt_tokens=len(prompt_tokens)):
+            with self._done_lock:
+                if len(self._seen_ids) > 2048:
+                    cutoff = now - 600.0
+                    self._seen_ids = {
+                        r: t for r, t in self._seen_ids.items()
+                        if t > cutoff}
+                if req_id in self._seen_ids:
+                    return
+                self._seen_ids[req_id] = now
+            self._in.put((req_id, list(prompt_tokens),
+                          max_new_tokens or self._max_new, now,
+                          float(temperature),
+                          frozenset(int(t) for t in (stop_ids or ()))))
 
     def collect(self, req_ids: Optional[List[str]] = None) -> Dict[str, Any]:
         """Drain finished requests. With ``req_ids``, only those are
@@ -412,7 +485,16 @@ class LLMEngine:
                 "queued": self._in.qsize(), "steps": self._steps,
                 "completed": self._completed,
                 "slots": self._num_slots,
-                "inflight_chunks": len(self._inflight)}
+                "inflight_chunks": len(self._inflight),
+                "ticks": self._ticks,
+                "inflight_depth_sum": self._inflight_depth_sum,
+                "steps_dispatched": self._steps_dispatched,
+                "admit_ns": self._admit_ns,
+                "dispatch_ns": self._dispatch_ns,
+                "reap_wait_ns": self._reap_wait_ns,
+                "sleep_ns": self._sleep_ns,
+                "slot_ticks_occupied": self._slot_ticks_occupied,
+                "slot_ticks_drained": self._slot_ticks_drained}
 
     def shutdown(self):
         self._stop = True
@@ -502,8 +584,10 @@ class LLMEngine:
                     slots[i], valid[i] = slot, True
                     temps[i] = temp
                     plens[i] = len(toks)
-                logits, kv = self._prefill_batch(jnp.asarray(rows),
-                                                 jnp.asarray(last))
+                with tracing.span("rtpu.engine.prefill",
+                                  tokens=int(plens.sum()), requests=len(batch)):
+                    logits, kv = self._prefill_batch(jnp.asarray(rows),
+                                                     jnp.asarray(last))
                 slots_d = jnp.asarray(slots)
                 valid_d = jnp.asarray(valid)
                 self._cache = self._insert_many(
@@ -565,6 +649,7 @@ class LLMEngine:
                         "latency_s": (time.monotonic()
                                       - self._slot_start[slot]),
                     }
+            tracing.mark("rtpu.engine.finish", id=req_id, tokens=len(toks))
             self._drop_slot(slot)
             return True
         return False
@@ -731,6 +816,7 @@ class LLMEngine:
             pass
         self._inflight.append(("chunk", {
             "out": out, "slots": {s: self._slot_req[s] for s in ready}}))
+        self._steps_dispatched += k
         for s in ready:
             self._slot_pos[s] += k
             self._sched[s] += k
@@ -742,18 +828,21 @@ class LLMEngine:
         The slot→request match drops tokens for slots recycled since the
         record was dispatched."""
         kind, rec = self._inflight.popleft()
+        t0 = time.monotonic_ns()
+        arr = np.asarray(rec["firsts" if kind == "admit" else "out"])
+        self._reap_wait_ns += time.monotonic_ns() - t0
         if kind == "admit":
-            firsts = np.asarray(rec["firsts"])
             now = time.monotonic()
             for i, (req_id, slot) in enumerate(rec["batch"]):
                 if self._slot_req.get(slot) != req_id:
                     continue
                 self._slot_ttft[slot] = now - self._slot_start[slot]
-                tok = int(firsts[i])
+                tracing.mark("rtpu.engine.first_token", id=req_id)
+                tok = int(arr[i])
                 self._slot_tokens[slot].append(tok)
                 self._maybe_finish(slot, tok)
             return
-        out = np.asarray(rec["out"])  # [k, S]
+        out = arr  # [k, S]
         self._steps += out.shape[0]
         for slot, req_id in rec["slots"].items():
             if self._slot_req.get(slot) != req_id:
@@ -784,13 +873,26 @@ class LLMEngine:
                 for rid, t in list(self._cancelled.items()):
                     if t < cutoff:
                         del self._cancelled[rid]
-        self._admit()
-        dispatched = self._dispatch(np, jnp)
+        with tracing.span("rtpu.engine.admit") as sp:
+            self._admit()
+        self._admit_ns += sp.dur_ns
+        with tracing.span("rtpu.engine.dispatch") as sp:
+            dispatched = self._dispatch(np, jnp)
+        self._dispatch_ns += sp.dur_ns
+        self._ticks += 1
+        self._inflight_depth_sum += len(self._inflight)
+        self._slot_ticks_occupied += len(self._slot_req)
+        self._slot_ticks_drained += sum(
+            1 for s in self._slot_req
+            if self._sched[s] >= self._slot_budget[s])
         # keep at most `depth` records in flight; when nothing was
         # dispatched, drain the pipeline so finished slots free up
         if self._inflight and (len(self._inflight) > self._depth
                                or not dispatched):
-            self._reap(np)
+            with tracing.span("rtpu.engine.reap"):
+                self._reap(np)
         if not dispatched and not self._inflight:
             if self._in.empty():
-                time.sleep(0.002)
+                with tracing.span("rtpu.engine.sleep") as sp:
+                    time.sleep(0.002)
+                self._sleep_ns += sp.dur_ns

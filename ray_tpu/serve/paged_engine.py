@@ -37,6 +37,7 @@ import queue as _q
 from typing import Dict, List, Optional, Tuple
 
 from ray_tpu.serve.llm_engine import LLMEngine, _bucket
+from ray_tpu.util import tracing
 
 
 class _PageAllocator:
@@ -190,10 +191,14 @@ class PagedLLMEngine(LLMEngine):
         import jax
 
         donate = (0,) if jax.default_backend() != "cpu" else ()
-        self._gather_j = jax.jit(lambda pool, idx: pool[:, idx])
-        self._scatter_j = jax.jit(
-            lambda pool, idx, pages: pool.at[:, idx].set(pages),
-            donate_argnums=donate)
+        def gather_pages(pool, idx):
+            return pool[:, idx]
+
+        def scatter_pages(pool, idx, pages):
+            return pool.at[:, idx].set(pages)
+
+        self._gather_j = jax.jit(gather_pages)
+        self._scatter_j = jax.jit(scatter_pages, donate_argnums=donate)
         # chunked prefill replaces the dense engine's max_len-1
         # overflow bucket: long prompts run as a sequence of
         # bucket-sized chunks, so only the explicit buckets compile
@@ -322,8 +327,10 @@ class PagedLLMEngine(LLMEngine):
             self._prefix_hit_tokens += matched
             self._set_bt_row(slot, pages)
             try:
-                firsts = self._run_prefill(np, jnp, slot, toks,
-                                           matched, temp)
+                with tracing.span("rtpu.engine.admit_request", id=req_id,
+                                  prompt_tokens=plen, hit_tokens=matched):
+                    firsts = self._run_prefill(np, jnp, slot, toks,
+                                               matched, temp)
             except Exception as e:  # noqa: BLE001
                 self._note_error("prefill", e)
                 # this slot's fresh pages hold no valid K/V — they must
@@ -374,9 +381,11 @@ class PagedLLMEngine(LLMEngine):
             C = _bucket(n, self._buckets)
             row = np.zeros((1, C), np.int32)
             row[0, :n] = toks[ctx0:ctx0 + n]
-            self._cache, logits = self._prefill_chunk(
-                self._cache, jnp.asarray(row), bt_row,
-                jnp.asarray(ctx0, jnp.int32), jnp.asarray(n, jnp.int32))
+            with tracing.span("rtpu.engine.prefill", tokens=n):
+                self._cache, logits = self._prefill_chunk(
+                    self._cache, jnp.asarray(row), bt_row,
+                    jnp.asarray(ctx0, jnp.int32),
+                    jnp.asarray(n, jnp.int32))
             self._prefill_tokens_computed += n
             ctx0 += n
         if temp > 0:

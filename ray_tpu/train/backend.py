@@ -14,6 +14,7 @@ from dataclasses import dataclass
 from typing import Optional
 
 from ray_tpu.train.worker_group import WorkerGroup
+from ray_tpu.util import tracing
 
 
 @dataclass
@@ -100,7 +101,8 @@ def _setup_jax_platform(platform: Optional[str], n_cpu_devices: int):
         import jax
 
         jax.config.update("jax_platforms", "tpu")
-        jax.devices()
+        with tracing.span("rtpu.backend.devices", keep=True):
+            jax.devices()
 
 
 def _pick_coordinator(port: int) -> str:

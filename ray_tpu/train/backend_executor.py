@@ -35,6 +35,7 @@ from ray_tpu.train.backend import Backend, BackendConfig
 from ray_tpu.train.config import ScalingConfig
 from ray_tpu.train.session import PreemptedError, TrainingResult
 from ray_tpu.train.worker_group import WorkerGroup
+from ray_tpu.util import tracing
 
 logger = logging.getLogger(__name__)
 
@@ -96,14 +97,29 @@ class BackendExecutor:
 
     def start(self):
         self.worker_group = WorkerGroup(self.scaling)
-        self.worker_group.start()
-        # rank/world-size env before any user code or jax import
-        for rank, w in enumerate(self.worker_group.workers):
-            w.set_env.remote({
+        with tracing.span("rtpu.train.place", keep=True):
+            self.worker_group.start()
+            # rank/world-size env before any user code or jax import;
+            # waited for, so that a worker process that is slow to come
+            # up is counted here and not in the backend's first call
+            ray_tpu.get([w.set_env.remote({
                 "RAY_TPU_RANK": str(rank),
                 "RAY_TPU_WORLD_SIZE": str(self.scaling.num_workers),
-            })
-        self.backend.on_start(self.worker_group, self.backend_config)
+            }) for rank, w in enumerate(self.worker_group.workers)])
+        with tracing.span("rtpu.backend.on_start", keep=True):
+            self.backend.on_start(self.worker_group, self.backend_config)
+
+    def collect_spans(self) -> List[dict]:
+        """Every worker's kept spans (chrome-trace form), asked of a gang
+        whose train function has returned."""
+        try:
+            per_worker = ray_tpu.get(self.worker_group.execute_async(
+                tracing.chrome_events), timeout=30)
+        # rtpu-lint: disable=L4 — a worker that died after its last
+        # result: its spans are lost with it, the run's result stands
+        except Exception:
+            return []
+        return [e for events in per_worker for e in events]
 
     def start_training(self, train_fn: Callable, config_dict: Dict[str, Any],
                        context_kwargs: Dict[str, Any],

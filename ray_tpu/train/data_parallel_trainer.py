@@ -13,7 +13,9 @@ persisted checkpoint (reference semantics).
 
 from __future__ import annotations
 
+import json
 import logging
+import os
 from typing import Any, Callable, Dict, Optional
 
 from ray_tpu.train.backend import BackendConfig, JaxConfig
@@ -24,6 +26,7 @@ from ray_tpu.train.config import RunConfig, ScalingConfig
 from ray_tpu.train.result import Result
 from ray_tpu.train.session import PreemptedError
 from ray_tpu.train.storage import StorageContext
+from ray_tpu.util import tracing
 
 logger = logging.getLogger(__name__)
 
@@ -61,37 +64,43 @@ class DataParallelTrainer:
         latest_metrics: Dict[str, Any] = {}
         history: list = []
         elastic_stats: list = []
+        worker_spans: list = []
         last_error: Optional[BaseException] = None
 
         while True:
             executor = BackendExecutor(self.backend_config, self.scaling_config)
             try:
-                executor.start()
-                # resume from the newest CONSISTENT checkpoint: torn/
-                # partial dirs (worker died mid-persist) are dropped with
-                # a warning instead of crashing the restart
-                resume = ckpt_mgr.latest_consistent() \
-                    or self.resume_from_checkpoint
-                executor.start_training(
-                    self.train_loop_per_worker,
-                    self.train_loop_config,
-                    context_kwargs={
-                        "trial_name": storage.trial_name,
-                        "experiment_name": storage.experiment_name,
-                        "trial_dir": storage.trial_path,
-                        "metadata": self.metadata,
-                    },
-                    checkpoint_path=resume.path if resume else None,
-                    dataset_shards=self._shard_datasets(
-                        self.scaling_config.num_workers),
-                    storage_info={
-                        "storage_path": self.run_config.resolved_storage_path(),
-                        "experiment_name": storage.experiment_name,
-                        "trial_name": storage.trial_name,
-                        "checkpoint_index_start": ckpt_mgr.next_index,
-                    },
-                    shard_fn=self._shard_datasets,
-                )
+                # until the train function has been entered on every
+                # worker: what a run pays before its first step
+                with tracing.span("rtpu.train.start", keep=True,
+                                  id=storage.trial_name):
+                    executor.start()
+                    # resume from the newest CONSISTENT checkpoint: torn/
+                    # partial dirs (worker died mid-persist) are dropped
+                    # with a warning instead of crashing the restart
+                    resume = ckpt_mgr.latest_consistent() \
+                        or self.resume_from_checkpoint
+                    executor.start_training(
+                        self.train_loop_per_worker,
+                        self.train_loop_config,
+                        context_kwargs={
+                            "trial_name": storage.trial_name,
+                            "experiment_name": storage.experiment_name,
+                            "trial_dir": storage.trial_path,
+                            "metadata": self.metadata,
+                        },
+                        checkpoint_path=resume.path if resume else None,
+                        dataset_shards=self._shard_datasets(
+                            self.scaling_config.num_workers),
+                        storage_info={
+                            "storage_path":
+                                self.run_config.resolved_storage_path(),
+                            "experiment_name": storage.experiment_name,
+                            "trial_name": storage.trial_name,
+                            "checkpoint_index_start": ckpt_mgr.next_index,
+                        },
+                        shard_fn=self._shard_datasets,
+                    )
                 while True:
                     results = executor.get_next_results()
                     if results is None:
@@ -104,6 +113,9 @@ class DataParallelTrainer:
                                  if r.checkpoint_dir]
                     if ckpt_dirs:
                         ckpt_mgr.register_persisted(ckpt_dirs[0], latest_metrics)
+                # the gang that finished the run; one that failed is not
+                # asked (its workers are dead or wedged in a collective)
+                worker_spans.extend(executor.collect_spans())
                 last_error = None
                 break
             # rtpu-lint: disable=L4 — this handler IS the restart
@@ -131,8 +143,16 @@ class DataParallelTrainer:
                         break
             finally:
                 elastic_stats.extend(executor.elastic_stats)
-                executor.shutdown()
+                with tracing.span("rtpu.train.shutdown", keep=True,
+                                  id=storage.trial_name):
+                    executor.shutdown()
 
+        # the run's spans, the driver's and every worker's, on one wall
+        # clock: kept in memory while it ran, written once it has ended
+        with storage.fs.open(os.path.join(storage.experiment_path,
+                                          "trace_spans.json"), "w") as f:
+            json.dump(sorted(tracing.chrome_events() + worker_spans,
+                             key=lambda e: e["ts"]), f)
         return Result(metrics=latest_metrics,
                       checkpoint=ckpt_mgr.best,
                       error=last_error,

@@ -17,6 +17,7 @@ from dataclasses import dataclass, field
 from typing import Any, Dict, Optional
 
 from ray_tpu.train.checkpoint import Checkpoint
+from ray_tpu.util import tracing
 
 _session: Optional["_TrainSession"] = None
 _session_lock = threading.Lock()
@@ -104,6 +105,15 @@ class _TrainSession:
         self._consumed = threading.Semaphore(0)
         self._finished = False
         self._interrupted: Optional[str] = None
+        self._reports = 0
+        import weakref
+
+        from ray_tpu import metrics
+
+        me = weakref.ref(self)     # the registry keeps no session alive
+        metrics.REGISTRY.register_source("rtpu_train", lambda: {
+            "reports": me()._reports, "checkpoints": me()._ckpt_index,
+            "world_rank": me().context.world_rank})
 
         def runner():
             try:
@@ -154,6 +164,7 @@ class _TrainSession:
                     checkpoint_dir, self._ckpt_index)
                 persisted = ckpt.path
             self._ckpt_index += 1
+        self._reports += 1
         self._result_q.put(TrainingResult(metrics=dict(metrics),
                                           checkpoint_dir=persisted))
         # Lockstep: wait until the driver consumed this result before the
@@ -264,7 +275,10 @@ def report(metrics: Dict[str, Any], checkpoint: Optional[Checkpoint] = None,
     s = _get_session()
     if checkpoint is not None and checkpoint_dir is None:
         checkpoint_dir = checkpoint.path
-    s.report(metrics, checkpoint_dir=checkpoint_dir)
+    # persisting the checkpoint and waiting for the driver to take the
+    # result: the time a train loop stands still in a report
+    with tracing.span("rtpu.train.report", id=s.context.trial_name):
+        s.report(metrics, checkpoint_dir=checkpoint_dir)
 
 
 def get_checkpoint() -> Optional[Checkpoint]:

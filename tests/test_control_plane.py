@@ -73,7 +73,7 @@ def test_state_api_embedded():
         ray_tpu.get(parent.remote())
         trace = ray_tpu.timeline()
         parents = {ev["args"]["task_id"]: ev["args"]["parent_task_id"]
-                   for ev in trace}
+                   for ev in trace if ev["cat"] != "span"}
         linked = [p for p in parents.values() if p is not None]
         assert linked and all(p in parents for p in linked), (
             "nested task missing parent span link")
@@ -174,7 +174,9 @@ def test_cluster_timeline_aggregates_nodes():
         trace = ray_tpu.timeline()
         assert len(trace) >= 6
         # events from BOTH nodes, tid carrying the node prefix
-        prefixes = {ev["tid"].split(":")[0] for ev in trace}
+        # (the driver's own spans, cat "span", carry no node prefix)
+        prefixes = {ev["tid"].split(":")[0] for ev in trace
+                    if ev["cat"] != "span"}
         assert len(prefixes) == 2, prefixes
     finally:
         os.environ.pop("RTPU_TASK_EVENTS_ENABLED", None)
@@ -553,19 +555,6 @@ def test_dashboard_lite(rt):
         assert "setInterval(tick" in page
     finally:
         stop_dashboard()
-
-
-def test_usage_stats_opt_in(tmp_path, monkeypatch):
-    from ray_tpu import usage_stats
-
-    monkeypatch.setattr(usage_stats, "USAGE_FILE",
-                        str(tmp_path / "usage.json"))
-    usage_stats.record("init", workers=2)  # disabled: no file
-    assert not os.path.exists(usage_stats.USAGE_FILE)
-    monkeypatch.setenv("RTPU_USAGE_STATS_ENABLED", "1")
-    usage_stats.record("init", workers=2)
-    line = json.loads(open(usage_stats.USAGE_FILE).read())
-    assert line["event"] == "init" and line["workers"] == 2
 
 
 # ---------------------------------------------------------------------------

@@ -1,0 +1,432 @@
+"""ray_tpu.util.tracing and what is wired through it: spans (nesting,
+ids, self time, the two tiers), the profiler's host plane, the runtime's
+and the trainer's set-up spans, ``timeline()``, ``trace_spans.json``, the
+engine's tick counters and trace hook, the metrics registry's sources,
+and the named scopes of the train step (metadata only)."""
+
+import contextlib
+import glob
+import json
+import os
+import re
+import subprocess
+import sys
+import time
+
+import pytest
+
+from ray_tpu.core.config import config
+from ray_tpu.util import tracing
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+@pytest.fixture
+def events_on():
+    os.environ["RTPU_TASK_EVENTS_ENABLED"] = "1"
+    config.reload()
+    try:
+        yield
+    finally:
+        os.environ.pop("RTPU_TASK_EVENTS_ENABLED", None)
+        config.reload()
+
+
+def _mine(prefix):
+    return [e for e in tracing.chrome_events()
+            if e["name"].startswith(prefix)]
+
+
+def test_span_nesting_ids_and_self_time(events_on):
+    with tracing.span("t1.outer", id="req-7", tokens=3) as outer:
+        time.sleep(0.01)
+        with tracing.span("t1.inner"):
+            time.sleep(0.02)
+        with tracing.span("t1.inner", id="other"):
+            pass
+    ev = {(e["name"], e["args"]["id"]): e for e in _mine("t1.")}
+    o, i = ev[("t1.outer", "req-7")], ev[("t1.inner", "req-7")]
+    assert i["args"]["parent"] == "t1.outer" and o["args"]["parent"] is None
+    assert ("t1.inner", "other") in ev          # an explicit id wins
+    assert o["args"]["tokens"] == 3
+    assert o["dur"] >= 30e3 and i["dur"] >= 20e3
+    # self time leaves the children out
+    assert 10e3 <= o["args"]["self_us"] <= o["dur"] - i["dur"] + 1
+    assert o["ts"] <= i["ts"] and i["ts"] + i["dur"] <= o["ts"] + o["dur"]
+    assert abs(o["ts"] / 1e6 - time.time()) < 60     # the wall clock
+    assert outer.dur_ns == pytest.approx(o["dur"] * 1e3)
+    tot = tracing.totals()
+    assert tot["t1.inner"]["count"] == 2
+    assert tot["t1.outer"]["total_ns"] == outer.dur_ns
+
+
+def test_events_are_kept_only_for_setup_spans_or_under_the_flag():
+    assert not config.task_events_enabled
+    with tracing.span("t2.hot"):
+        pass
+    with tracing.span("t2.setup", keep=True):
+        pass
+    tracing.mark("t2.mark", id="r")
+    assert [e["name"] for e in _mine("t2.")] == ["t2.setup"]
+    # the accumulators count either way
+    assert tracing.totals()["t2.hot"]["count"] == 1
+    assert tracing.totals()["t2.mark"]["count"] == 1
+
+
+def test_span_module_never_imports_jax():
+    code = ("import sys\n"
+            "from ray_tpu.util import tracing\n"
+            "with tracing.span('a', keep=True):\n"
+            "    pass\n"
+            "assert tracing.chrome_events()[0]['name'] == 'a'\n"
+            "assert 'jax' not in sys.modules, 'jax imported'\n")
+    p = subprocess.run([sys.executable, "-c", code], cwd=REPO,
+                       capture_output=True, text=True, timeout=120)
+    assert p.returncode == 0, p.stderr[-2000:]
+
+
+def test_span_lies_on_the_profilers_host_plane(tmp_path):
+    import jax
+    import jax.numpy as jnp
+    from jax.profiler import ProfileData
+
+    f = jax.jit(lambda x: (x @ x).sum())
+    f(jnp.ones((64, 64))).block_until_ready()
+    tracing.start_profile(str(tmp_path))
+    try:
+        with tracing.span("rtpu.t4.step", id="s1"):
+            f(jnp.ones((64, 64))).block_until_ready()
+    finally:
+        tracing.stop_profile()
+    # while the profiler ran the event was kept, with no flag
+    assert [e["args"]["id"] for e in _mine("rtpu.t4.")] == ["s1"]
+    (pb,) = glob.glob(str(tmp_path / "plugins/profile/*/*.xplane.pb"))
+    host = [p for p in ProfileData.from_file(pb).planes
+            if p.name == "/host:CPU"]
+    names = {ev.name for p in host for ln in p.lines for ev in ln.events}
+    assert "rtpu.t4.step" in names
+
+
+def test_timeline_merges_spans_with_task_events(events_on):
+    import ray_tpu
+    from ray_tpu.core import runtime_context
+
+    prev = runtime_context.get_core_or_none()
+    runtime_context.set_core(None)
+    try:
+        ray_tpu.init(num_workers=1)
+
+        @ray_tpu.remote
+        def t(x):
+            return x
+
+        with tracing.span("rtpu.t5.batch", id="b1"):
+            ray_tpu.get([t.remote(i) for i in range(3)])
+        trace = ray_tpu.timeline()
+        cats = {e["cat"] for e in trace}
+        assert {"task", "span"} <= cats
+        names = [e["name"] for e in trace if e["cat"] == "span"]
+        assert {"rtpu.init", "rtpu.worker.spawn", "rtpu.t5.batch"} \
+            <= set(names)
+        assert trace == sorted(trace, key=lambda e: e["ts"])
+        batch = next(e for e in trace if e["name"] == "rtpu.t5.batch")
+        tasks = [e for e in trace if e["cat"] == "task"]
+        # one clock: the tasks ran inside the span that waited for them
+        assert all(batch["ts"] - 1e5 <= e["ts"] <= batch["ts"]
+                   + batch["dur"] + 1e5 for e in tasks[-3:])
+    finally:
+        core = runtime_context.get_core_or_none()
+        if core is not None:
+            ray_tpu.shutdown()
+        runtime_context.set_core(prev)
+    assert "rtpu.runtime.shutdown" in [
+        e["name"] for e in tracing.chrome_events()]
+
+
+def test_fit_writes_the_gangs_spans(tmp_path):
+    """``trace_spans.json`` of a CPU ``JaxTrainer.fit``: the driver's
+    set-up spans in order and the workers' own, on one wall clock. (A fresh process: the
+    runtime wants one.)"""
+    code = f"""
+import json, os, sys
+os.environ["JAX_PLATFORMS"] = "cpu"
+import ray_tpu
+from ray_tpu import train
+from ray_tpu.train import JaxConfig, JaxTrainer, RunConfig, ScalingConfig
+
+def loop(config):
+    from ray_tpu.util import tracing
+    with tracing.span("t6.in_worker", keep=True):
+        train.report({{"x": 1.0}})
+
+ray_tpu.init(num_workers=2)
+r = JaxTrainer(loop, train_loop_config={{}},
+               scaling_config=ScalingConfig(num_workers=2),
+               jax_config=JaxConfig(platform="cpu"),
+               run_config=RunConfig(name="run7", storage_path={str(tmp_path)!r})).fit()
+assert r.error is None, r.error
+ray_tpu.shutdown()
+assert "jax" not in sys.modules
+"""
+    p = subprocess.run([sys.executable, "-c", code], cwd=REPO,
+                       capture_output=True, text=True, timeout=300)
+    assert p.returncode == 0, p.stderr[-3000:]
+    with open(tmp_path / "run7" / "trace_spans.json") as f:
+        ev = json.load(f)
+    by = {}
+    for e in ev:
+        by.setdefault(e["name"], []).append(e)
+    for name in ("rtpu.init", "rtpu.worker.spawn", "rtpu.train.start",
+                 "rtpu.train.place", "rtpu.backend.on_start",
+                 "rtpu.train.shutdown"):
+        assert name in by, (name, sorted(by))
+    start, place, on_start = (by[n][0] for n in (
+        "rtpu.train.start", "rtpu.train.place", "rtpu.backend.on_start"))
+    assert place["args"]["parent"] == on_start["args"]["parent"] \
+        == "rtpu.train.start"
+    assert start["args"]["id"].startswith("trial_")
+    assert place["args"]["id"] == start["args"]["id"]
+    end = lambda e: e["ts"] + e["dur"]  # noqa: E731
+    assert start["ts"] <= place["ts"] and end(place) <= on_start["ts"] \
+        and end(on_start) <= end(start) <= by["rtpu.train.shutdown"][0]["ts"]
+    assert end(by["rtpu.init"][0]) <= start["ts"]
+    assert ev == sorted(ev, key=lambda e: e["ts"])
+    # every worker's ring came along, under its own pid, on the same clock
+    workers = by["t6.in_worker"]
+    assert len({e["pid"] for e in workers}) == 2
+    assert start["pid"] not in {e["pid"] for e in workers}
+    assert all(end(start) <= e["ts"] + 1e5 and end(e) <= by[
+        "rtpu.train.shutdown"][0]["ts"] + 1e5 for e in workers)
+
+
+TINY = dict(model_config={"preset": "tiny"}, num_slots=4, max_len=96,
+            prefill_buckets=[16], max_new_tokens=8, chunk_steps=4)
+
+
+@pytest.fixture(scope="module")
+def paged_engine():
+    from ray_tpu.serve.paged_engine import PagedLLMEngine
+
+    eng = PagedLLMEngine(page_size=8, **TINY)
+    deadline = time.time() + 300
+    while not eng.report()["ready"] and time.time() < deadline:
+        time.sleep(0.05)
+    try:
+        yield eng
+    finally:
+        eng.shutdown()
+
+
+def _generate(eng, n, tag):
+    import numpy as np
+
+    rng = np.random.default_rng(list(tag.encode()))   # prompts of its own
+    ids = [f"{tag}{i}" for i in range(n)]
+    for rid in ids:
+        eng.submit(rid, [int(t) for t in rng.integers(1, 250, 20)])
+    out = {}
+    deadline = time.time() + 120
+    while len(out) < n and time.time() < deadline:
+        out.update(eng.collect())
+        time.sleep(0.01)
+    assert len(out) == n
+    return ids
+
+
+COUNTERS = ("ticks", "inflight_depth_sum", "steps_dispatched", "steps",
+            "admit_ns", "dispatch_ns", "reap_wait_ns", "sleep_ns",
+            "slot_ticks_occupied", "slot_ticks_drained")
+
+
+def test_engine_tick_counters_are_monotonic(paged_engine):
+    eng = paged_engine
+    s0 = eng.stats()
+    _generate(eng, 6, "a")
+    s1 = eng.stats()
+    _generate(eng, 3, "b")
+    time.sleep(0.05)
+    s2 = eng.stats()
+    for k in COUNTERS:
+        assert s0[k] <= s1[k] <= s2[k], k
+    for k in ("ticks", "steps", "steps_dispatched", "admit_ns",
+              "dispatch_ns", "inflight_depth_sum", "slot_ticks_occupied"):
+        assert s1[k] > s0[k], k
+    assert s2["sleep_ns"] > s1["sleep_ns"] or s2["ticks"] > s1["ticks"]
+    # dispatched counts at dispatch, steps at reap
+    for s in (s1, s2):
+        assert s["steps_dispatched"] >= s["steps"]
+        assert s["slot_ticks_drained"] <= s["slot_ticks_occupied"]
+    # idle again: every chunk dispatched has been reaped
+    assert s2["steps_dispatched"] == s2["steps"]
+    assert s2["inflight_chunks"] == 0
+    # the tick's phases are spans, counted whether or not kept
+    tot = tracing.totals()
+    for name in ("rtpu.engine.admit", "rtpu.engine.dispatch",
+                 "rtpu.engine.reap", "rtpu.engine.sleep",
+                 "rtpu.engine.prefill", "rtpu.engine.submit",
+                 "rtpu.engine.first_token", "rtpu.engine.finish"):
+        assert tot[name]["count"] > 0, name
+    assert tot["rtpu.engine.prefill"]["count"] >= 9 * 2   # 20 tokens / 16
+    assert tot["rtpu.engine.finish"]["count"] >= 9
+
+
+def test_engine_trace_hook_and_request_ids(paged_engine, tmp_path):
+    eng = paged_engine
+    assert eng.report()["trace"]["state"] == "off"
+    eng.start_trace(str(tmp_path))
+
+    def wait_for(state):
+        deadline = time.time() + 120
+        while time.time() < deadline:
+            tr = eng.report()["trace"]
+            assert tr["error"] is None, tr
+            if tr["state"] == state:
+                return tr
+            time.sleep(0.05)
+        raise AssertionError(f"trace never became {state}")
+
+    assert wait_for("on")["dir"] == str(tmp_path)
+    (rid,) = _generate(eng, 1, "traced")
+    eng.stop_trace()
+    wait_for("off")
+    assert glob.glob(str(tmp_path / "plugins/profile/*/*.xplane.pb"))
+    # inside the profiler's window every span was kept; one request's
+    # carry its id from submit to finish
+    mine = [e["name"] for e in eng.timeline() if e["args"]["id"] == rid]
+    assert mine[0] == "rtpu.engine.submit" and mine[-1] == \
+        "rtpu.engine.finish"
+    assert "rtpu.engine.first_token" in mine
+    assert mine.count("rtpu.engine.prefill") == 2
+    before = len(eng.timeline())
+    _generate(eng, 1, "untraced")
+    assert len(eng.timeline()) == before        # the ring is off again
+
+
+def test_metrics_registry_serves_the_owners_counters(paged_engine):
+    from ray_tpu import metrics
+
+    text = metrics.REGISTRY.render()
+    ticks = int(re.search(r"^rtpu_engine_ticks (\d+)$", text, re.M).group(1))
+    assert 0 < ticks <= paged_engine.stats()["ticks"]
+    assert "# TYPE rtpu_engine_inflight_depth_sum gauge" in text
+    assert re.search(r"^rtpu_span_rtpu_engine_admit_count \d+$", text, re.M)
+    assert re.search(r"^rtpu_span_rtpu_engine_admit_seconds_total [\d.e-]+$",
+                     text, re.M)
+    # a source is read at scrape time, and one that raises is left out
+    box = {"n": 1}
+    metrics.REGISTRY.register_source("rtpu_t9", lambda: dict(box, s="x"))
+    assert "rtpu_t9_n 1\n" in metrics.REGISTRY.render()
+    box["n"] = 5
+    text = metrics.REGISTRY.render()
+    assert "rtpu_t9_n 5\n" in text and "rtpu_t9_s" not in text
+    metrics.REGISTRY.register_source("rtpu_t9", lambda: 1 / 0)
+    assert "rtpu_t9" not in metrics.REGISTRY.render()
+
+
+def _train_step_text(scoped: bool) -> str:
+    import jax
+    import jax.numpy as jnp
+    import optax
+
+    from ray_tpu.models import llama
+
+    cfg = llama.LlamaConfig.tiny(vocab_size=128, attn_impl="reference")
+    params = jax.eval_shape(lambda k: llama.init_params(cfg, k),
+                            jax.random.PRNGKey(0))
+    tx = optax.adamw(1e-3)
+    opt = jax.eval_shape(tx.init, params)
+    batch = {"tokens": jax.ShapeDtypeStruct((2, 33), jnp.int32)}
+
+    def step(params, opt, batch):
+        loss, grads = jax.value_and_grad(
+            lambda p: llama.loss_fn(cfg, p, batch))(params)
+        updates, opt = tx.update(grads, opt, params)
+        return optax.apply_updates(params, updates), opt, loss
+
+    plain = contextlib.nullcontext()
+    saved = jax.named_scope
+    if not scoped:
+        jax.named_scope = lambda name: plain
+    try:
+        return jax.jit(step, donate_argnums=(0, 1)).lower(
+            params, opt, batch).compile().as_text()
+    finally:
+        jax.named_scope = saved
+
+
+def test_named_scopes_change_no_instruction_of_the_train_step():
+    with_scopes, without = _train_step_text(True), _train_step_text(False)
+    for scope in ("embed", "attn_qkv", "flash", "attn_out", "mlp",
+                  "head_loss"):
+        assert re.search(rf'op_name="[^"]*[/(]{scope}[/)]', with_scopes), scope
+    assert not re.search(r'op_name="[^"]*/mlp/', without)
+
+
+    def strip(text):
+        # an instruction's metadata, and the module's tables of source
+        # files and stack frames that the metadata points into
+        text = re.sub(r", metadata=\{[^}]*\}", "", text)
+        return re.sub(r"\nFileNames\n.*?\nStackFrames\n.*?\n\n", "\n",
+                      text, flags=re.S)
+
+    assert strip(with_scopes) == strip(without)
+    assert len(re.findall(r"^\s+(?:ROOT )?%?[\w.\-]+ = ", strip(with_scopes),
+                          re.M)) > 200          # instructions are left
+    assert with_scopes != without
+
+
+def test_every_kernel_and_serving_program_has_a_name():
+    """A profiler trace names a Pallas call by its ``name`` and a jitted
+    program by its function's: none may be anonymous."""
+    src = {}
+    for rel in ("ops/attention.py", "ops/paged_attention.py",
+                "serve/llm_engine.py", "serve/paged_engine.py",
+                "models/llama_paged.py", "models/llama_decode.py"):
+        with open(os.path.join(REPO, "ray_tpu", rel)) as f:
+            src[rel] = f.read()
+    names = []
+    for rel in ("ops/attention.py", "ops/paged_attention.py"):
+        calls = [m.start() for m in re.finditer(r"pl\.pallas_call\(",
+                                                src[rel])]
+        assert calls
+        for at in calls:
+            m = re.search(r'\bname="(\w+)"', src[rel][at:at + 1500])
+            assert m, (rel, src[rel][at:at + 80])
+            names.append(m.group(1))
+    assert sorted(names) == ["flash_bwd_dkv", "flash_bwd_dq", "flash_fwd",
+                             "paged_attn"]
+    for rel, text in src.items():
+        assert not re.search(r"jax\.jit\(\s*lambda", text), rel
+
+
+def test_compile_cache_key_sees_a_programs_metadata():
+    """jax leaves metadata out of the persistent cache's key by default,
+    and a named scope is metadata: a program cached before its scopes
+    were added would come back without them. ``ensure_compile_cache``
+    turns the option on, for a later ``import jax`` (the environment) and
+    for a jax already imported (its config)."""
+    import jax
+
+    from ray_tpu.core import compile_cache
+
+    env = {}
+    compile_cache.ensure_compile_cache(env)
+    assert env["JAX_COMPILATION_CACHE_INCLUDE_METADATA_IN_KEY"] == "1"
+    assert "jax_compilation_cache_include_metadata_in_key" in \
+        jax.config.values
+    saved = {k: os.environ.get(k) for k in (
+        compile_cache.ENV_VAR,
+        "JAX_COMPILATION_CACHE_INCLUDE_METADATA_IN_KEY")}
+    saved_dir = jax.config.jax_compilation_cache_dir
+    try:
+        os.environ[compile_cache.ENV_VAR] = saved_dir or "/nonexistent-x"
+        jax.config.update("jax_compilation_cache_include_metadata_in_key",
+                          False)
+        compile_cache.ensure_compile_cache()
+        assert jax.config.jax_compilation_cache_include_metadata_in_key
+    finally:
+        jax.config.update("jax_compilation_cache_include_metadata_in_key",
+                          False)
+        for k, v in saved.items():
+            os.environ.pop(k, None) if v is None else \
+                os.environ.__setitem__(k, v)

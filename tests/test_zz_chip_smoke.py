@@ -274,7 +274,10 @@ def test_tpu_worker_env_confines_a_partial_host():
 def test_compile_cache_set_is_left_alone():
     env = {compile_cache.ENV_VAR: "/x"}
     assert compile_cache.ensure_compile_cache(env) == "/x"
-    assert env == {compile_cache.ENV_VAR: "/x"}
+    # no other directory; the one thing added tells jax to key a cached
+    # program on its metadata too (tests/test_tracing.py says why)
+    assert env == {compile_cache.ENV_VAR: "/x",
+                   "JAX_COMPILATION_CACHE_INCLUDE_METADATA_IN_KEY": "1"}
 
 
 def test_compile_cache_unset_is_one_fixed_dir_in_the_checkout():
