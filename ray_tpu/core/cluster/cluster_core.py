@@ -1479,6 +1479,8 @@ class ClusterCore:
         return True  # nodes bring their own pools up
 
     def shutdown(self):
+        if self._monitor_stop:
+            return  # a second call finds everything closed
         self._monitor_stop = True
         # clean exit: no death event, nodes keep objects until eviction
         self.gcs.try_call(("unregister_driver", self._driver_id))

@@ -86,6 +86,7 @@ class Cluster:
         # netem rules armed via partition()/gray(): (src endpoint,
         # src selector, dst selector, kind); heal() clears exactly these
         self._partitions: List[Tuple[object, str, str, str]] = []
+        self._cores: list = []  # every driver connect() made
 
         self._gcs_port = pick_port()
         self._start_gcs()
@@ -315,6 +316,7 @@ class Cluster:
         from ray_tpu.core.cluster.cluster_core import ClusterCore
 
         core = ClusterCore(self.gcs_address, authkey=self.authkey)
+        self._cores.append(core)
         runtime_context.set_core(core)
         return core
 
@@ -325,6 +327,12 @@ class Cluster:
         if core is not None:
             core.shutdown()
         runtime_context.set_core(None)
+        # and every driver connect() made, whichever core is current
+        # now: a caller that put its previous core back first must not
+        # leave ours heartbeating a cluster that is gone
+        for core in self._cores:
+            core.shutdown()
+        self._cores.clear()
 
     def shutdown(self):
         self.disconnect()

@@ -1401,6 +1401,11 @@ class GcsServer:
                 self._wal.close()
                 self._wal = None
         self._server.close()
+        # reap the health loop (one sleep of at most 0.1 s away): left to
+        # end by itself it outlives close() and runs on into whatever the
+        # process does next
+        if threading.current_thread() is not self._monitor:
+            self._monitor.join(timeout=2.0)
 
 
 def main(argv=None):

@@ -24,6 +24,7 @@ class BCLearner:
         import optax
 
         self.module = module
+        self.seed = seed
         self.params = module.init_params(seed)
         self.tx = optax.adam(lr)
         self.opt_state = self.tx.init(self.params)
@@ -72,6 +73,7 @@ class CQLLearner:
         import optax
 
         self.module = module
+        self.seed = seed
         self.params = module.init_params(seed)
         import jax.numpy as jnp
 
@@ -145,6 +147,7 @@ class MARWILLearner:
         import optax
 
         self.module = module
+        self.seed = seed
         self.params = module.init_params(seed)
         self.tx = optax.adam(lr)
         self.opt_state = self.tx.init(self.params)
@@ -201,11 +204,13 @@ def train_offline(learner, dataset, *, num_epochs: int = 1,
     """Drive a BC/CQL learner over a Dataset; returns the last loss.
 
     With ``shuffle``, each epoch re-executes the pipeline with a full
-    ``random_shuffle`` (new permutation per epoch).
+    ``random_shuffle`` (new permutation per epoch, drawn from the
+    learner's seed: the same seed trains the same way twice).
     """
     loss = float("nan")
-    for _ in range(num_epochs):
-        ds = dataset.random_shuffle() if shuffle else dataset
+    for epoch in range(num_epochs):
+        ds = (dataset.random_shuffle(seed=learner.seed + epoch)
+              if shuffle else dataset)
         for batch in ds.iter_batches(batch_size=batch_size):
             if len(next(iter(batch.values()))) < 2:
                 continue

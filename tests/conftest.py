@@ -17,7 +17,98 @@ if "xla_force_host_platform_device_count" not in _flags:
         _flags + " --xla_force_host_platform_device_count=8"
     ).strip()
 
+# jax's persistent compile cache, one directory a run (xdist's workers
+# and every process they start inherit it): what a run compiles twice,
+# a rebuilt gang's step, it loads the second time, and nothing comes
+# back from an earlier run. XLA:CPU programs cached by an earlier run
+# can hang in the virtual devices' collectives when loaded (the worker
+# then dies at the rendezvous timeout, in a different test each run).
+os.environ.setdefault("JAX_COMPILATION_CACHE_DIR", os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+    ".jax_compile_cache", f"run-{os.getpid()}"))
+
+# transformers imports TensorFlow when it finds it, ten seconds in every
+# process that loads an HF checkpoint; nothing here uses it
+os.environ.setdefault("USE_TF", "0")
+
+import contextlib
+import faulthandler
+import signal
+import sys
+import threading
+
 import pytest
+
+from tests.engines import (_dense_engine, _paged_engine,  # noqa: F401
+                           dense_engine, paged_engine)
+
+# One limit for every test, for its setup, its body and its teardown
+# each: the heaviest test takes under 25 s on the 8-core sandbox and the
+# driver's machine is about 4.4 times slower. A test that runs into it
+# fails alone, with the line it stood in, and the run goes on.
+TEST_LIMIT_S = 240
+
+
+class TestLimitExceeded(Exception):
+    pass
+
+
+def _limited(item):
+    """Wraps one phase of one test. SIGALRM reaches Python code and
+    every interruptible wait of the main thread (locks, joins, sleeps,
+    sockets); it repeats, so a handler that swallows it is asked again."""
+    def on_alarm(signum, frame):
+        raise TestLimitExceeded(
+            f"{item.nodeid} ran over the per-test limit of "
+            f"{TEST_LIMIT_S} s in {frame.f_code.co_name} "
+            f"({frame.f_code.co_filename}:{frame.f_lineno})")
+
+    if threading.current_thread() is not threading.main_thread():
+        return (yield)
+    # for the record: every thread's stack, just before the alarm
+    # unwinds the main one
+    faulthandler.dump_traceback_later(TEST_LIMIT_S * 0.99, exit=False)
+    previous = signal.signal(signal.SIGALRM, on_alarm)
+    signal.setitimer(signal.ITIMER_REAL, TEST_LIMIT_S, TEST_LIMIT_S)
+    try:
+        return (yield)
+    finally:
+        signal.setitimer(signal.ITIMER_REAL, 0)
+        signal.signal(signal.SIGALRM, previous)
+        faulthandler.cancel_dump_traceback_later()
+
+
+pytest_runtest_setup = pytest.hookimpl(wrapper=True)(_limited)
+pytest_runtest_call = pytest.hookimpl(wrapper=True)(_limited)
+pytest_runtest_teardown = pytest.hookimpl(wrapper=True)(_limited)
+
+
+# xdist's --dist loadfile hands whole files to the workers in collection
+# order, each worker holding the file it runs and the next. In name
+# order the last worker ends a minute after the first; with the files
+# over ten test-seconds sorted heaviest first (CHANGES.md, PR 25) all
+# six end within ten seconds of each other.
+_HEAVY_FIRST = (
+    "test_cluster", "test_fault_tolerance", "test_rllib", "test_serve",
+    "test_models", "test_train_multiproc", "test_zz_chip_smoke", "test_ops",
+    "test_control_plane", "test_serve_replay", "test_core_api", "test_data",
+    "test_parallel", "test_tune", "test_train", "test_ha", "test_tracing",
+    "test_netem", "test_drain", "test_regressions")
+
+
+def pytest_collection_modifyitems(items):
+    rank = {name: i for i, name in enumerate(_HEAVY_FIRST)}
+    items.sort(key=lambda item: rank.get(  # stable: a file stays whole
+        item.module.__name__.rpartition(".")[2], len(rank)))
+
+
+def pytest_configure(config):
+    # xdist would sort the files again, by how many tests each holds
+    config.option.loadscopereorder = False
+    config.addinivalue_line(
+        "markers", "slow: too long for tier-1 even at its smallest; the "
+        "driver's -m 'not slow' leaves it out")
+
 
 # Modules that exercise the concurrency surface hardest run with the
 # lock-order sanitizer armed: every runtime lock built inside them is a
@@ -89,3 +180,44 @@ def rt():
     ray_tpu.init(num_workers=4, object_store_memory=256 << 20)
     yield ray_tpu
     ray_tpu.shutdown()
+
+
+@contextlib.contextmanager
+def own_runtime(num_workers=4):
+    """A runtime of a module's own, whatever core an earlier module of
+    the process left installed; serve, if the module used it, goes down
+    with it. For a module-scoped fixture: ``with own_runtime(): yield``."""
+    import ray_tpu
+    from ray_tpu.core import runtime_context
+
+    prev = runtime_context.get_core_or_none()
+    runtime_context.set_core(None)
+    ray_tpu.init(num_workers=num_workers, object_store_memory=256 << 20)
+    try:
+        yield
+    finally:
+        if "ray_tpu.serve.api" in sys.modules:
+            sys.modules["ray_tpu.serve.api"].shutdown()
+        core = runtime_context.get_core_or_none()
+        if core is not None:
+            core.shutdown()
+        runtime_context.set_core(prev)
+
+
+@contextlib.contextmanager
+def own_cluster(num_nodes, **kw):
+    """A cluster of node processes of a test's own, up and connected as
+    the process's core; whatever core was installed before comes back."""
+    from ray_tpu.core import runtime_context
+    from ray_tpu.core.cluster.fixture import Cluster
+
+    prev = runtime_context.get_core_or_none()
+    runtime_context.set_core(None)
+    c = Cluster(num_nodes=num_nodes, **kw)
+    try:
+        assert c.wait_for_nodes(num_nodes, timeout=120)
+        c.connect()
+        yield c
+    finally:
+        c.shutdown()
+        runtime_context.set_core(prev)

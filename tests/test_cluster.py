@@ -8,6 +8,7 @@ cluster_utils.Cluster-based suites.
 from __future__ import annotations
 
 import os
+import threading
 import time
 
 import pytest
@@ -18,6 +19,7 @@ from ray_tpu.core.cluster.fixture import Cluster
 from ray_tpu.core.cluster.gcs import GcsServer
 from ray_tpu.core.cluster.rpc import RpcClient
 from ray_tpu.exceptions import ObjectLostError
+from tests.conftest import own_cluster
 
 
 # --------------------------------------------------------------------- GCS
@@ -223,16 +225,8 @@ def test_detached_actor_survives_driver_and_node_death():
     (reference: gcs_actor_manager.h:278), so the actor (a) outlives the
     creating driver, and (b) is restarted on a surviving node after its
     host dies — with no driver involved."""
-    from ray_tpu.core.cluster.fixture import Cluster
-
-    prev = runtime_context.get_core_or_none()
-    runtime_context.set_core(None)
-    c = Cluster(num_nodes=2, num_workers_per_node=2,
-                node_resources=[{"stay": 4}, {"doomed": 4}])
-    try:
-        c.wait_for_nodes(2)
-        c.connect()
-
+    with own_cluster(2, num_workers_per_node=2,
+                     node_resources=[{"stay": 4}, {"doomed": 4}]) as c:
         @ray_tpu.remote
         class Svc:
             def __init__(self):
@@ -269,9 +263,6 @@ def test_detached_actor_survives_driver_and_node_death():
                 last = e
                 time.sleep(0.5)
         assert last == 1, f"restarted actor should answer fresh: {last!r}"
-    finally:
-        c.shutdown()
-        runtime_context.set_core(prev)
 
 
 def test_cluster_placement_group_spread(cluster):
@@ -317,16 +308,8 @@ def test_many_nodes_scale_stress():
     fleet, and placement groups — exposes O(N) control-plane paths before
     they matter (reference envelope: release/benchmarks/README.md, 64
     nodes; 16 here is bounded by this 1-core CI box, not the design)."""
-    from ray_tpu.core.cluster.fixture import Cluster
-
-    prev = runtime_context.get_core_or_none()
-    runtime_context.set_core(None)
-    c = Cluster(num_nodes=16, num_workers_per_node=1,
-                object_store_memory=64 << 20)
-    try:
-        assert c.wait_for_nodes(16, timeout=120)
-        c.connect()
-
+    with own_cluster(16, num_workers_per_node=1,
+                     object_store_memory=64 << 20) as c:
         @ray_tpu.remote
         def f(x):
             return x + 1
@@ -353,9 +336,6 @@ def test_many_nodes_scale_stress():
             assert pg.wait(timeout_seconds=60)
         for pg in pgs:
             remove_placement_group(pg)
-    finally:
-        c.shutdown()
-        runtime_context.set_core(prev)
 
 
 def test_cluster_kv(cluster):
@@ -371,7 +351,7 @@ def test_cluster_remove_node_survival():
     prev_core = runtime_context.get_core_or_none()
     runtime_context.set_core(None)
     c = Cluster(num_nodes=3, num_workers_per_node=2,
-                node_resources=[{"ra": 4}, {"rb": 4}, {"rc": 4}])
+                node_resources=[{"ra": 4}, {"rb": 4}, {"rc": 4, "rd": 4}])
     try:
         c.wait_for_nodes(3)
         core = c.connect()
@@ -395,7 +375,7 @@ def test_cluster_remove_node_survival():
         a = Sticky.options(resources={"CPU": 0.01}, max_restarts=2,
                            scheduling_strategy=None).remote()
         # pin actor to doomed node via resource
-        b = Sticky.options(resources={"rc": 0.1}, max_restarts=2).remote()
+        b = Sticky.options(resources={"rd": 0.1}, max_restarts=2).remote()
         assert ray_tpu.get(b.ping.remote(), timeout=60) == "alive"
 
         victim = c.nodes[2]
@@ -409,16 +389,26 @@ def test_cluster_remove_node_survival():
                             timeout=60)}
         assert pids == surviving
 
-        # the dead node's object is lost (no lineage yet -> ObjectLostError;
-        # GetTimeoutError is accepted when the GCS hasn't timed the node out
-        # yet at get() time)
+        # the dead node's object is lost: ObjectLostError, or
+        # GetTimeoutError while its creating task, resubmitted through
+        # lineage, waits for a node with "rc" — which never comes back.
+        # Read beside the actor's restart: either way the driver first
+        # dials the dead address for the RPC client's 10 s, and the two
+        # dials need not stand in line.
         from ray_tpu.exceptions import GetTimeoutError
-        with pytest.raises((ObjectLostError, GetTimeoutError)):
-            ray_tpu.get(doomed_ref, timeout=10)
+        lost = []
 
-        # a replacement node with the same resource joins; the restartable
-        # actor's pending restart lands on it
-        c.add_node(resources={"rc": 4})
+        def read_lost():
+            with pytest.raises((ObjectLostError, GetTimeoutError)) as e:
+                ray_tpu.get(doomed_ref, timeout=2)
+            lost.append(e.type)
+
+        reader = threading.Thread(target=read_lost)
+        reader.start()
+
+        # a replacement node with the actor's resource joins; the
+        # restartable actor's pending restart lands on it
+        c.add_node(resources={"rd": 4})
         c.wait_for_nodes(3)
         deadline = time.monotonic() + 90
         ok = False
@@ -431,6 +421,8 @@ def test_cluster_remove_node_survival():
                 time.sleep(0.5)
         assert ok, "actor did not restart on the replacement node"
         assert ray_tpu.get(a.ping.remote(), timeout=60) == "alive"
+        reader.join(timeout=60)
+        assert lost, "the read of the dead node's object never ended"
     finally:
         c.shutdown()
         runtime_context.set_core(prev_core)
@@ -464,21 +456,12 @@ def test_chunked_parallel_object_transfer(tmp_path):
 
     import numpy as np
 
-    from ray_tpu.core import runtime_context
-    from ray_tpu.core.cluster.fixture import Cluster
-
-    prev_core = runtime_context.get_core_or_none()
-    runtime_context.set_core(None)
-    c = Cluster(num_nodes=2, num_workers_per_node=2,
-                object_store_memory=256 << 20,
-                node_resources=[{"pin0": 4}, {"pin1": 4}],
-                env={"RTPU_FETCH_PARALLEL_THRESHOLD_BYTES": str(1 << 20),
-                     "RTPU_FETCH_CHUNK_BYTES": str(1 << 20),
-                     "RTPU_FETCH_PARALLELISM": "3"})
-    try:
-        c.wait_for_nodes(2)
-        c.connect()
-
+    with own_cluster(2, num_workers_per_node=2,
+                     object_store_memory=256 << 20,
+                     node_resources=[{"pin0": 4}, {"pin1": 4}],
+                     env={"RTPU_FETCH_PARALLEL_THRESHOLD_BYTES": str(1 << 20),
+                          "RTPU_FETCH_CHUNK_BYTES": str(1 << 20),
+                          "RTPU_FETCH_PARALLELISM": "3"}) as c:
         @ray_tpu.remote(resources={"pin0": 1})
         def make_big():
             rng = np.random.default_rng(0)
@@ -495,26 +478,15 @@ def test_chunked_parallel_object_transfer(tmp_path):
         # consumer runs on the OTHER node: the 8 MiB payload crosses the
         # node boundary through fetch_size + parallel fetch_range calls
         assert ray_tpu.get(digest.remote(ref), timeout=120) == expected
-    finally:
-        c.shutdown()
-        runtime_context.set_core(prev_core)
 
 
 def test_runtime_env_nested_submission_spills_across_nodes(tmp_path):
     """A nested runtime_env submission from a worker publishes its
     package to the GCS KV, so the nested task survives spilling to a
     node whose table never saw the upload."""
-    from ray_tpu.core import runtime_context
-    from ray_tpu.core.cluster.fixture import Cluster
-
-    prev_core = runtime_context.get_core_or_none()
-    runtime_context.set_core(None)
-    c = Cluster(num_nodes=2, num_workers_per_node=2,
-                object_store_memory=128 << 20,
-                node_resources=[{"pinA": 4}, {"pinB": 4}])
-    try:
-        c.wait_for_nodes(2)
-        c.connect()
+    with own_cluster(2, num_workers_per_node=2,
+                     object_store_memory=128 << 20,
+                     node_resources=[{"pinA": 4}, {"pinB": 4}]) as c:
         proj = tmp_path / "nestproj"
         proj.mkdir()
         (proj / "x.txt").write_text("cross-node-nested")
@@ -532,9 +504,6 @@ def test_runtime_env_nested_submission_spills_across_nodes(tmp_path):
 
         assert ray_tpu.get(outer.remote(str(proj)),
                            timeout=120) == "cross-node-nested"
-    finally:
-        c.shutdown()
-        runtime_context.set_core(prev_core)
 
 
 
@@ -825,14 +794,8 @@ def test_cluster_actor_restart_transparent_calls():
     new calls ride out the RESTARTING window (the GCS actor_state channel
     tells the driver a restart is underway) and land on the restarted
     incarnation on the replacement node — the death never surfaces."""
-    prev_core = runtime_context.get_core_or_none()
-    runtime_context.set_core(None)
-    c = Cluster(num_nodes=2, num_workers_per_node=2,
-                node_resources=[{"ra": 4}, {"rb": 4}])
-    try:
-        c.wait_for_nodes(2)
-        c.connect()
-
+    with own_cluster(2, num_workers_per_node=2,
+                     node_resources=[{"ra": 4}, {"rb": 4}]) as c:
         @ray_tpu.remote
         class Echo:
             def __init__(self):
@@ -865,6 +828,3 @@ def test_cluster_actor_restart_transparent_calls():
         assert got == 42, "actor calls never recovered after node death"
         # steady state: calls work repeatedly against the new incarnation
         assert ray_tpu.get(e.hit.remote(5), timeout=60) == 15
-    finally:
-        c.shutdown()
-        runtime_context.set_core(prev_core)

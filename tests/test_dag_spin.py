@@ -19,18 +19,13 @@ from ray_tpu.core import runtime_context
 from ray_tpu.core.config import config
 from ray_tpu.dag import InputNode, bind, compile_dag, compile_pipeline
 from ray_tpu.dag.channel import Channel, DeviceChannel
+from tests.conftest import own_runtime
 
 
 @pytest.fixture(scope="module")
 def dag_ray():
-    prev = runtime_context.get_core_or_none()
-    runtime_context.set_core(None)
-    ray_tpu.init(num_workers=4, object_store_memory=256 << 20)
-    yield
-    core = runtime_context.get_core_or_none()
-    if core is not None:
-        core.shutdown()
-    runtime_context.set_core(prev)
+    with own_runtime(4):
+        yield
 
 
 def test_spin_fanout_fanin_parity_with_block(dag_ray):

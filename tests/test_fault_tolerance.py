@@ -17,6 +17,7 @@ import pytest
 import ray_tpu
 from ray_tpu.core import runtime_context
 from ray_tpu.exceptions import WorkerCrashedError
+from tests.conftest import own_cluster
 
 
 @pytest.fixture
@@ -130,17 +131,9 @@ def test_gcs_restart_rehydrates_cluster_state(tmp_path):
     actors survive, and new tasks + calls on the pre-crash actor work
     (reference role: redis_store_client.h:33 GCS table persistence +
     gcs_redis_failure_detector)."""
-    from ray_tpu.core.cluster.fixture import Cluster
-
-    prev = runtime_context.get_core_or_none()
-    runtime_context.set_core(None)
-    c = Cluster(num_nodes=2, num_workers_per_node=2,
-                node_resources=[{"a": 4}, {"b": 4}],
-                gcs_persist_dir=str(tmp_path / "gcs"))
-    try:
-        c.wait_for_nodes(2)
-        c.connect()
-
+    with own_cluster(2, num_workers_per_node=2,
+                     node_resources=[{"a": 4}, {"b": 4}],
+                     gcs_persist_dir=str(tmp_path / "gcs")) as c:
         @ray_tpu.remote
         class Counter:
             def __init__(self):
@@ -178,22 +171,11 @@ def test_gcs_restart_rehydrates_cluster_state(tmp_path):
         assert ray_tpu.get(pre, timeout=120) == [i * 2 for i in range(10)]
         assert ray_tpu.get([work.remote(i) for i in range(10)],
                            timeout=120) == [i * 2 for i in range(10)]
-    finally:
-        c.shutdown()
-        runtime_context.set_core(prev)
 
 
 def test_cluster_reconstruction_after_node_death():
-    from ray_tpu.core.cluster.fixture import Cluster
-
-    prev = runtime_context.get_core_or_none()
-    runtime_context.set_core(None)
-    c = Cluster(num_nodes=2, num_workers_per_node=2,
-                node_resources=[{"left": 4}, {"right": 4}])
-    try:
-        c.wait_for_nodes(2)
-        c.connect()
-
+    with own_cluster(2, num_workers_per_node=2,
+                     node_resources=[{"left": 4}, {"right": 4}]) as c:
         @ray_tpu.remote
         def produce(tag):
             import numpy as np
@@ -212,9 +194,6 @@ def test_cluster_reconstruction_after_node_death():
         # replacement node and the get succeeds transparently
         out = ray_tpu.get(ref, timeout=120)
         assert out.shape == (300_000,) and out[0] == 42.0
-    finally:
-        c.shutdown()
-        runtime_context.set_core(prev)
 
 
 def test_driver_death_reclaims_owned_state():
@@ -310,8 +289,8 @@ time.sleep(600)  # parked until killed
         assert dead, "non-detached actor outlived its dead driver"
         node.close()
     finally:
-        runtime_context.set_core(prev)
         c.shutdown()
+        runtime_context.set_core(prev)
 
 
 def test_owner_cleanup_op_reclaims_immediately():
@@ -343,8 +322,8 @@ def test_owner_cleanup_op_reclaims_immediately():
         assert ray_tpu.get(inner, timeout=30) == b"worker-owned"
         node.close()
     finally:
-        rc.set_core(prev)
         c.shutdown()
+        rc.set_core(prev)
 
 
 def test_memory_monitor_oom_kill_retry_and_typed_error(local_ray, tmp_path):

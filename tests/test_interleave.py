@@ -56,6 +56,12 @@ def _schedule_for(seed):
 
 
 def test_same_seed_same_schedule():
+    # a thread that an earlier test of this process started while the
+    # tracer was armed keeps its trace function, and joins this schedule
+    # if it still runs: a ClusterCore's death watch, a GcsServer's health
+    # loop. Their owners reap them on shutdown()/close().
+    assert not [t.name for t in threading.enumerate()
+                if t.name in ("driver-deaths", "gcs-health")]
     first = _schedule_for(7)
     second = _schedule_for(7)
     assert first == second

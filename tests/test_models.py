@@ -34,8 +34,8 @@ def test_llama_initial_loss_near_uniform(llama_setup):
 
 def test_llama_grads_finite_and_nonzero(llama_setup):
     cfg, params, tokens = llama_setup
-    grads = jax.grad(lambda p: llama.loss_fn(cfg, p, {"tokens": tokens}))(
-        params)
+    grads = jax.jit(jax.grad(
+        lambda p: llama.loss_fn(cfg, p, {"tokens": tokens})))(params)
     leaves = jax.tree_util.tree_leaves(grads)
     assert all(bool(jnp.isfinite(g).all()) for g in leaves)
     assert any(float(jnp.abs(g).max()) > 0 for g in leaves)
@@ -440,8 +440,8 @@ def test_partial_remat_matches_full_remat():
     params = llama.init_params(cfg_full, jax.random.PRNGKey(0))
 
     def lg(cfg):
-        return jax.value_and_grad(
-            lambda p: llama.loss_fn(cfg, p, {"tokens": tokens}))(params)
+        return jax.jit(jax.value_and_grad(
+            lambda p: llama.loss_fn(cfg, p, {"tokens": tokens})))(params)
 
     l_full, g_full = lg(cfg_full)
     l_part, g_part = lg(cfg_part)
@@ -469,8 +469,8 @@ def test_unrolled_and_save_qkv_match_scan_full_remat():
     params = llama.init_params(cfg_base, jax.random.PRNGKey(0))
 
     def lg(cfg):
-        return jax.value_and_grad(
-            lambda p: llama.loss_fn(cfg, p, {"tokens": tokens}))(params)
+        return jax.jit(jax.value_and_grad(
+            lambda p: llama.loss_fn(cfg, p, {"tokens": tokens})))(params)
 
     l_base, g_base = lg(cfg_base)
     l_fast, g_fast = lg(cfg_fast)
@@ -582,19 +582,12 @@ def test_int8_quantized_decode_matches_dequantized():
 def test_llm_engine_quantized_generates():
     """model_config quantize='int8' serves end-to-end."""
     from ray_tpu.serve.llm_engine import LLMEngine
+    from tests.engines import SMALLEST, drain, private_engine
 
-    eng = LLMEngine(model_config={"preset": "tiny", "quantize": "int8"},
-                    num_slots=2, max_len=48, prefill_buckets=[16],
-                    max_new_tokens=8, chunk_steps=4)
-    eng.submit("r1", [1, 2, 3, 4], 8)
-    import time as _t
-
-    out = {}
-    deadline = _t.monotonic() + 120
-    while "r1" not in out and _t.monotonic() < deadline:
-        out.update(eng.collect())
-        _t.sleep(0.01)
-    eng.shutdown()
+    # private: its weights are quantized at construction
+    with private_engine(LLMEngine, **dict(SMALLEST, model_config={
+            "preset": "tiny", "quantize": "int8"})) as eng:
+        out = drain(eng, [("r1", [1, 2, 3, 4], {})])
     assert "r1" in out and len(out["r1"]["tokens"]) == 8
 
 

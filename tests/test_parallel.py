@@ -249,15 +249,13 @@ def test_pipeline_parallel_matches_sequential():
     mesh = build_mesh(MeshSpec({"pp": 4}),
                       devices=jax.devices()[:4])
 
-    ref = float(llama.loss_fn(cfg, params, {"tokens": tokens}))
-    pp_loss = jax.jit(lambda p, t: llama.loss_fn_pp(
-        cfg, p, {"tokens": t}, mesh, num_microbatches=4))
-    assert abs(ref - float(pp_loss(params, tokens))) < 1e-4
-
-    g_ref = jax.grad(lambda p: llama.loss_fn(cfg, p,
-                                             {"tokens": tokens}))(params)
-    g_pp = jax.jit(jax.grad(lambda p: llama.loss_fn_pp(
+    # one program a side, loss and grads together (the sequential side
+    # jitted too: op by op it is hundreds of small compiles)
+    ref, g_ref = jax.jit(jax.value_and_grad(lambda p: llama.loss_fn(
+        cfg, p, {"tokens": tokens})))(params)
+    pp, g_pp = jax.jit(jax.value_and_grad(lambda p: llama.loss_fn_pp(
         cfg, p, {"tokens": tokens}, mesh, num_microbatches=4)))(params)
+    assert abs(float(ref) - float(pp)) < 1e-4
     errs = jax.tree.map(lambda a, b: float(jnp.max(jnp.abs(a - b))),
                         g_ref, g_pp)
     assert max(jax.tree.leaves(errs)) < 1e-3
@@ -340,18 +338,13 @@ def test_pipeline_x_ring_attention_matches_sequential():
     mesh = build_mesh(MeshSpec({"pp": 2, "sp": 2, "dp": 2}),
                       devices=jax.devices()[:8])
 
-    ref = float(llama.loss_fn(cfg, params, {"tokens": tokens}))
     ring_cfg = replace(cfg, attn_impl="ring")
-    pp_loss = jax.jit(lambda p, t: llama.loss_fn_pp(
-        ring_cfg, p, {"tokens": t}, mesh, num_microbatches=4))
-    got = float(pp_loss(params, tokens))
-    assert abs(ref - got) < 1e-4, (ref, got)
-
-    g_ref = jax.grad(lambda p: llama.loss_fn(cfg, p,
-                                             {"tokens": tokens}))(params)
-    g_pp = jax.jit(jax.grad(lambda p: llama.loss_fn_pp(
+    ref, g_ref = jax.jit(jax.value_and_grad(lambda p: llama.loss_fn(
+        cfg, p, {"tokens": tokens})))(params)
+    got, g_pp = jax.jit(jax.value_and_grad(lambda p: llama.loss_fn_pp(
         ring_cfg, p, {"tokens": tokens}, mesh,
         num_microbatches=4)))(params)
+    assert abs(float(ref) - float(got)) < 1e-4, (ref, got)
     errs = jax.tree.map(lambda a, b: float(jnp.max(jnp.abs(a - b))),
                         g_ref, g_pp)
     assert max(jax.tree.leaves(errs)) < 1e-3, errs

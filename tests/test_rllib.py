@@ -11,23 +11,16 @@ import time
 import numpy as np
 import pytest
 
-import ray_tpu
-from ray_tpu.core import runtime_context
 from ray_tpu.rllib.envs import CartPoleVec
 from ray_tpu.rllib.learner import PPOLearner
 from ray_tpu.rllib.rl_module import MLPModule, to_numpy
+from tests.conftest import own_runtime
 
 
 @pytest.fixture(scope="module")
 def rl_ray():
-    prev = runtime_context.get_core_or_none()
-    runtime_context.set_core(None)
-    ray_tpu.init(num_workers=3, object_store_memory=256 << 20)
-    yield
-    core = runtime_context.get_core_or_none()
-    if core is not None:
-        core.shutdown()
-    runtime_context.set_core(prev)
+    with own_runtime(3):
+        yield
 
 
 def test_cartpole_dynamics():
@@ -409,7 +402,7 @@ def test_sac_pendulum_learns(rl_ray):
         best = -1e9
         for i in range(150):
             r = algo.train()
-            if i % 20 == 19:
+            if i % 5 == 4:   # an evaluation costs a fiftieth of the five
                 best = max(best, algo.evaluate(8))
                 if best >= -300:
                     break
@@ -904,6 +897,11 @@ def test_offline_parquet_sample_batches_roundtrip(rl_ray, tmp_path):
     assert acc > 0.9, acc
 
 
+# slow: 52 s alone on the 8-core sandbox and 100 s beside five other
+# workers, whatever the networks' size: 18 s go to building the learner
+# and compiling its update before the first step, and the bar needs some
+# 1,100 updates of 23 ms after that (see CHANGES.md, PR 25).
+@pytest.mark.slow
 def test_dreamerv3_cartpole_learns(rl_ray):
     """DreamerV3 (compact): the RSSM world model + imagination
     actor-critic cracks CartPole — eval return well above random
@@ -923,7 +921,7 @@ def test_dreamerv3_cartpole_learns(rl_ray):
         best = 0.0
         for i in range(40):
             r = algo.train()
-            if i % 5 == 4 and r["episode_return_mean"] > 60:
+            if r["episode_return_mean"] > 60:
                 best = max(best, algo.evaluate(6))
                 if best >= 150:
                     break

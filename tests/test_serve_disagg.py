@@ -10,31 +10,22 @@ Parity anchor: PagedLLMEngine is pinned token-exact to the dense engine
 
 from __future__ import annotations
 
+import contextlib
 import time
 
 import numpy as np
 import pytest
 
-import ray_tpu
 from ray_tpu.core import fault_injection, runtime_context
 from ray_tpu.core.config import config
-
-TINY = dict(model_config={"preset": "tiny"}, num_slots=4, max_len=96,
-            prefill_buckets=[16], max_new_tokens=8, chunk_steps=4)
-
-
-def _drain(engine, reqs, timeout_s=120):
-    for rid, prompt, kw in reqs:
-        engine.submit(rid, prompt, **kw)
-    out = {}
-    deadline = time.time() + timeout_s
-    while len(out) < len(reqs) and time.time() < deadline:
-        out.update(engine.collect())
-        time.sleep(0.01)
-    return out
-
+from tests.conftest import own_runtime
+from tests.engines import PAGE, TINY, drain as _drain, private_engine
 
 def _assert_no_leaked_pages(eng):
+    # after shutdown: a result is in the mailbox before its slot's pages
+    # are back, and the engine's last tick is what returns them
+    eng._thread.join(timeout=30)
+    assert not eng._thread.is_alive()
     alloc = eng._alloc
     assert len(alloc.free) + len(alloc.lru) == alloc.num_pages
 
@@ -44,28 +35,29 @@ def _prompts(seed=7, lens=(3, 23, 9, 40, 70)):
     return [[int(t) for t in rng.integers(1, 250, n)] for n in lens]
 
 
-def test_disagg_matches_plain_paged():
+@contextlib.contextmanager
+def _disagg(**kw):
+    """Private by kind: a disaggregated engine with one prefill worker;
+    whatever faults the test injects are cleared with it."""
+    from ray_tpu.serve.disagg import DisaggPagedEngine
+
+    with private_engine(DisaggPagedEngine, page_size=PAGE,
+                        prefill_workers=1, **kw, **TINY) as eng:
+        try:
+            yield eng
+        finally:
+            fault_injection.clear()
+
+
+def test_disagg_matches_plain_paged(paged_engine):
     """Greedy generations are token-identical to the plain paged engine
     for a mixed batch; long prompts actually take the diverted path
     (prefill worker → handoff → decode-side adoption)."""
-    from ray_tpu.serve.disagg import DisaggPagedEngine
-    from ray_tpu.serve.paged_engine import PagedLLMEngine
-
-    prompts = _prompts()
-    reqs = [(f"r{i}", p, {}) for i, p in enumerate(prompts)]
-
-    plain = PagedLLMEngine(page_size=8, **TINY)
-    try:
-        want = _drain(plain, reqs)
-    finally:
-        plain.shutdown()
-
-    dis = DisaggPagedEngine(page_size=8, prefill_workers=1, **TINY)
-    try:
+    reqs = [(f"r{i}", p, {}) for i, p in enumerate(_prompts())]
+    want = _drain(paged_engine, reqs)
+    with _disagg() as dis:
         got = _drain(dis, reqs)
         st = dis.stats()
-    finally:
-        dis.shutdown()
 
     assert set(got) == set(want)
     for rid in want:
@@ -83,27 +75,16 @@ def test_disagg_dropped_handoff_recovers():
     """prefill_handoff 'drop' loses the KV handoff mid-stream; the lease
     sweep resubmits the victim for local prefill. Zero lost requests,
     token output unchanged, zero leaked pages."""
-    from ray_tpu.serve.disagg import DisaggPagedEngine
-
     prompts = _prompts(seed=11, lens=(40, 40))
     reqs = [("victim", prompts[0], {}), ("bystander", prompts[1], {})]
 
-    clean = DisaggPagedEngine(page_size=8, prefill_workers=1, **TINY)
-    try:
+    with _disagg() as clean:
         want = _drain(clean, reqs)
-    finally:
-        clean.shutdown()
-
-    eng = DisaggPagedEngine(page_size=8, prefill_workers=1,
-                            handoff_timeout_s=0.5, **TINY)
-    try:
+    with _disagg(handoff_timeout_s=0.5) as eng:
         fault_injection.inject("prefill_handoff", "drop", "victim",
                                times=1)
         got = _drain(eng, reqs)
         st = eng.stats()
-    finally:
-        fault_injection.clear()
-        eng.shutdown()
 
     assert got["victim"]["tokens"] == want["victim"]["tokens"]
     assert got["bystander"]["tokens"] == want["bystander"]["tokens"]
@@ -116,27 +97,17 @@ def test_disagg_worker_kill_respawns_and_recovers():
     """prefill_handoff 'kill_worker' kills the worker thread mid-request
     (no cleanup, no handoff): the victim recovers through its lease and
     the health check respawns the worker, which serves later requests."""
-    from ray_tpu.serve.disagg import DisaggPagedEngine
-
     prompts = _prompts(seed=13, lens=(40, 40))
-    first = [("victim", prompts[0], {})]
-    second = [("after", prompts[1], {})]
-
-    eng = DisaggPagedEngine(page_size=8, prefill_workers=1,
-                            handoff_timeout_s=0.5, **TINY)
-    try:
+    with _disagg(handoff_timeout_s=0.5) as eng:
         fault_injection.inject("prefill_handoff", "kill_worker",
                                "victim", times=1)
-        got = _drain(eng, first)
+        got = _drain(eng, [("victim", prompts[0], {})])
         assert "victim" in got and got["victim"]["tokens"]
         assert eng.stats()["disagg_recovered"] >= 1
         # the respawned worker handles subsequent diversions normally
-        got2 = _drain(eng, second)
+        got2 = _drain(eng, [("after", prompts[1], {})])
         assert "after" in got2 and got2["after"]["tokens"]
         st = eng.stats()
-    finally:
-        fault_injection.clear()
-        eng.shutdown()
 
     assert st["prefill_workers"] == 1  # dead thread was replaced
     assert st["disagg_handoffs"] >= 1
@@ -149,25 +120,18 @@ def test_disagg_handoff_chaos_seed_sweep(seed):
     random subset of diverted requests loses its handoff. Every request
     still completes and the page pool balances — chaos on this edge
     costs latency only."""
-    from ray_tpu.serve.disagg import DisaggPagedEngine
-
     rng = np.random.default_rng(seed)
     prompts = [[int(t) for t in rng.integers(1, 250, 40)]
                for _ in range(4)]
     reqs = [(f"s{seed}-r{i}", p, {}) for i, p in enumerate(prompts)]
     victims = [reqs[i][0] for i in rng.choice(4, size=2, replace=False)]
 
-    eng = DisaggPagedEngine(page_size=8, prefill_workers=1,
-                            handoff_timeout_s=0.3, **TINY)
-    try:
+    with _disagg(handoff_timeout_s=0.3) as eng:
         for rid in victims:
             fault_injection.inject("prefill_handoff", "drop", rid,
                                    times=1)
         got = _drain(eng, reqs)
         st = eng.stats()
-    finally:
-        fault_injection.clear()
-        eng.shutdown()
 
     assert set(got) == {rid for rid, _, _ in reqs}  # zero lost requests
     assert all(got[rid]["tokens"] for rid, _, _ in reqs)
@@ -197,14 +161,8 @@ def test_engine_class_resolves_serve_disagg_flag():
 
 @pytest.fixture(scope="module")
 def dag_ray():
-    prev = runtime_context.get_core_or_none()
-    runtime_context.set_core(None)
-    ray_tpu.init(num_workers=2, object_store_memory=256 << 20)
-    yield
-    core = runtime_context.get_core_or_none()
-    if core is not None:
-        core.shutdown()
-    runtime_context.set_core(prev)
+    with own_runtime(2):
+        yield
 
 
 def test_device_channel_tuple_payload_roundtrip(dag_ray):
@@ -240,25 +198,16 @@ def test_device_channel_tuple_payload_roundtrip(dag_ray):
     assert not any(kk[0] == ch._key for kk in _DEVICE_HANDOFF)
 
 
-def test_disagg_uses_device_channel_when_store_present(dag_ray):
+def test_disagg_uses_device_channel_when_store_present(dag_ray,
+                                                       paged_engine):
     """Constructed in a process with an object store, the engine's
     prefill workers hand KV pages over DeviceChannels (on-device, by
     reference) — and the output is still token-identical to the plain
     engine."""
-    from ray_tpu.serve.disagg import DisaggPagedEngine
-    from ray_tpu.serve.paged_engine import PagedLLMEngine
-
-    prompts = _prompts(seed=17, lens=(40, 70))
-    reqs = [(f"r{i}", p, {}) for i, p in enumerate(prompts)]
-
-    plain = PagedLLMEngine(page_size=8, **TINY)
-    try:
-        want = _drain(plain, reqs)
-    finally:
-        plain.shutdown()
-
-    eng = DisaggPagedEngine(page_size=8, prefill_workers=1, **TINY)
-    try:
+    reqs = [(f"dc{i}", p, {})
+            for i, p in enumerate(_prompts(seed=17, lens=(40, 70)))]
+    want = _drain(paged_engine, reqs)
+    with _disagg() as eng:
         # the worker state really bound a channel (store present) —
         # state is built inside the worker thread, so poll briefly
         deadline = time.time() + 10
@@ -270,8 +219,6 @@ def test_disagg_uses_device_channel_when_store_present(dag_ray):
                    for ws in eng._wstates.values())
         got = _drain(eng, reqs)
         st = eng.stats()
-    finally:
-        eng.shutdown()
 
     for rid in want:
         assert got[rid]["tokens"] == want[rid]["tokens"], rid

@@ -30,19 +30,13 @@ from ray_tpu.core import fault_injection, runtime_context
 from ray_tpu.core.config import config
 from ray_tpu.exceptions import BackpressureError, ReplicaUnavailableError
 from ray_tpu.serve import qos
+from tests.conftest import own_runtime
 
 
 @pytest.fixture(scope="module")
 def serve_ray():
-    prev = runtime_context.get_core_or_none()
-    runtime_context.set_core(None)
-    ray_tpu.init(num_workers=4, object_store_memory=256 << 20)
-    yield
-    serve.shutdown()
-    core = runtime_context.get_core_or_none()
-    if core is not None:
-        core.shutdown()
-    runtime_context.set_core(prev)
+    with own_runtime(4):
+        yield
 
 
 # ------------------------------------------------------------ typed errors

@@ -13,160 +13,107 @@ import numpy as np
 import pytest
 
 
-def _drain(engine, reqs, timeout_s=120):
-    """submit/poll helper; reqs: list of (req_id, prompt, kwargs)."""
-    for rid, prompt, kw in reqs:
-        engine.submit(rid, prompt, **kw)
-    out = {}
-    deadline = time.time() + timeout_s
-    while len(out) < len(reqs) and time.time() < deadline:
-        out.update(engine.collect())
-        time.sleep(0.01)
-    return out
+from tests.engines import PAGE, TINY, drain, private_engine, tokens
 
 
-TINY = dict(model_config={"preset": "tiny"}, num_slots=4, max_len=96,
-            prefill_buckets=[16], max_new_tokens=8, chunk_steps=4)
+def _prompts(seed, lens):
+    rng = np.random.default_rng(seed)
+    return [[int(t) for t in rng.integers(1, 250, n)] for n in lens]
 
 
-def test_paged_matches_dense_greedy():
+def test_paged_matches_dense_greedy(dense_engine, paged_engine):
     """Greedy generations are token-identical to the dense engine for a
     mixed batch, including a prompt long enough to take multiple prefill
     chunks (23 tokens over 16-token chunks)."""
-    from ray_tpu.serve.llm_engine import LLMEngine
-    from ray_tpu.serve.paged_engine import PagedLLMEngine
-
-    rng = np.random.default_rng(7)
-    prompts = [
-        [int(t) for t in rng.integers(1, 250, n)] for n in (3, 23, 9, 40)
-    ]
-    reqs = [(f"r{i}", p, {}) for i, p in enumerate(prompts)]
-
-    dense = LLMEngine(**TINY)
-    try:
-        want = {k: v["tokens"] for k, v in _drain(dense, reqs).items()}
-    finally:
-        dense.shutdown()
+    reqs = [(f"match{i}", p, {})
+            for i, p in enumerate(_prompts(7, (3, 23, 9, 40)))]
+    want = tokens(drain(dense_engine, reqs))
     assert len(want) == len(reqs)
-
-    paged = PagedLLMEngine(page_size=8, **TINY)
-    try:
-        got = {k: v["tokens"] for k, v in _drain(paged, reqs).items()}
-    finally:
-        paged.shutdown()
-    assert got == want
+    assert tokens(drain(paged_engine, reqs)) == want
 
 
-def test_prefix_cache_reuses_pages():
+def test_prefix_cache_reuses_pages(dense_engine, paged_engine):
     """A repeated prompt prefix skips prefill for its full cached pages:
     the second request computes only the tail, and its output is
     unchanged."""
-    from ray_tpu.serve.paged_engine import PagedLLMEngine
-
-    rng = np.random.default_rng(3)
-    shared = [int(t) for t in rng.integers(1, 250, 32)]  # 4 full pages
+    (shared,) = _prompts(3, (32,))  # 4 full pages
     p1 = shared + [11, 12, 13]
     p2 = shared + [99, 98]
 
-    eng = PagedLLMEngine(page_size=8, **TINY)
-    try:
-        out1 = _drain(eng, [("a", p1, {})])
-        computed_after_first = eng._prefill_tokens_computed
-        assert eng._prefix_hit_tokens == 0
-        out2 = _drain(eng, [("b", p2, {})])
-        tail_cost = eng._prefill_tokens_computed - computed_after_first
-        # 32 shared tokens = 4 pages cached by request a; b prefills only
-        # its 2-token tail (padded to one 16-token chunk)
-        assert eng._prefix_hit_tokens == 32
-        assert tail_cost <= 16
-        assert len(out1["a"]["tokens"]) == 8
-        assert len(out2["b"]["tokens"]) == 8
-    finally:
-        eng.shutdown()
+    eng = paged_engine
+    before = eng.stats()
+    out1 = drain(eng, [("prefix-a", p1, {})])
+    first = eng.stats()
+    assert first["prefix_hit_tokens"] == before["prefix_hit_tokens"]
+    out2 = drain(eng, [("prefix-b", p2, {})])
+    second = eng.stats()
+    # 32 shared tokens = 4 pages cached by request a; b prefills only
+    # its 2-token tail (padded to one 16-token chunk)
+    assert second["prefix_hit_tokens"] - first["prefix_hit_tokens"] == 32
+    assert (second["prefill_tokens_computed"]
+            - first["prefill_tokens_computed"]) <= 16
+    assert len(out1["prefix-a"]["tokens"]) == 8
+    assert len(out2["prefix-b"]["tokens"]) == 8
 
-    # same prompts on a cold engine give identical tokens — sharing
-    # changed the work, not the math
-    eng2 = PagedLLMEngine(page_size=8, **TINY)
-    try:
-        cold = _drain(eng2, [("a", p1, {}), ("b", p2, {})])
-    finally:
-        eng2.shutdown()
-    assert cold["a"]["tokens"] == out1["a"]["tokens"]
-    assert cold["b"]["tokens"] == out2["b"]["tokens"]
+    # the same prompts where nothing is shared (the dense engine has no
+    # page cache) give identical tokens — sharing changed the work, not
+    # the math
+    cold = tokens(drain(dense_engine, [("prefix-a", p1, {}),
+                                       ("prefix-b", p2, {})]))
+    assert cold == tokens({**out1, **out2})
 
 
-def test_long_prompt_chunked_prefill():
+def test_long_prompt_chunked_prefill(dense_engine, paged_engine):
     """A prompt far longer than the prefill bucket (and longer than the
     dense engine could admit per its slot reservation economics) runs
     through chunked prefill and still matches the dense engine given the
     same max_len window."""
-    from ray_tpu.serve.llm_engine import LLMEngine
+    req = [("long", _prompts(5, (70,))[0], {})]
+    want = tokens(drain(dense_engine, req))
+    computed = paged_engine.stats()["prefill_tokens_computed"]
+    got = tokens(drain(paged_engine, req))
+    # 70 tokens / 16-token chunks = 5 chunks
+    assert paged_engine.stats()["prefill_tokens_computed"] - computed == 70
+    assert got == want and len(got["long"]) == 8
+
+
+@pytest.fixture(scope="module")
+def small_pool_engine():
+    """Pool exhaustion is the point: 8 pages where the shared engine
+    has slots x max_len worth."""
     from ray_tpu.serve.paged_engine import PagedLLMEngine
 
-    rng = np.random.default_rng(5)
-    prompt = [int(t) for t in rng.integers(1, 250, 70)]
-
-    kw = dict(TINY, max_len=96)
-    dense = LLMEngine(**kw)
-    try:
-        want = _drain(dense, [("x", prompt, {})])["x"]["tokens"]
-    finally:
-        dense.shutdown()
-
-    paged = PagedLLMEngine(page_size=8, **kw)
-    try:
-        got = _drain(paged, [("x", prompt, {})])["x"]["tokens"]
-        # 70 tokens / 16-token chunks = 5 chunks
-        assert paged._prefill_tokens_computed == 70
-    finally:
-        paged.shutdown()
-    assert got == want
+    with private_engine(PagedLLMEngine, page_size=PAGE, num_pages=8,
+                        **TINY) as eng:
+        yield eng
 
 
-def test_small_pool_requeues_until_pages_free():
+def test_small_pool_requeues_until_pages_free(small_pool_engine):
     """With a pool far smaller than slots × max_len, admission defers
     when pages run out and every request still completes."""
-    from ray_tpu.serve.paged_engine import PagedLLMEngine
-
-    rng = np.random.default_rng(9)
     # each request needs ceil(17/8)+1 ≈ 4 pages; pool of 8 forces
     # serialized admission across the 6 requests
-    reqs = [(f"q{i}", [int(t) for t in rng.integers(1, 250, 17)], {})
-            for i in range(6)]
-    eng = PagedLLMEngine(page_size=8, num_pages=8, **TINY)
-    try:
-        out = _drain(eng, reqs, timeout_s=180)
-        assert sorted(out) == sorted(r[0] for r in reqs)
-        assert all(len(v["tokens"]) == 8 for v in out.values())
-    finally:
-        eng.shutdown()
+    reqs = [(f"q{i}", p, {}) for i, p in enumerate(_prompts(9, (17,) * 6))]
+    out = drain(small_pool_engine, reqs, timeout_s=180)
+    assert sorted(out) == sorted(r[0] for r in reqs)
+    assert all(len(v["tokens"]) == 8 for v in out.values())
 
 
-def test_paged_sampling_and_stop_ids():
+def test_paged_sampling_and_stop_ids(paged_engine):
     """Sampled slots diverge while greedy slots in the same batch stay
     deterministic; per-request stop tokens end generation early."""
-    from ray_tpu.serve.paged_engine import PagedLLMEngine
-
     prompt = [5, 3, 7]
-    eng = PagedLLMEngine(page_size=8, top_k=20, **TINY)
-    try:
-        out = _drain(eng, [("g", prompt, {}),
-                           ("s1", prompt, {"temperature": 1.0}),
-                           ("s2", prompt, {"temperature": 1.0})])
-        toks = {k: v["tokens"] for k, v in out.items()}
-        assert all(len(t) == 8 for t in toks.values())
-        assert toks["s1"] != toks["g"] or toks["s2"] != toks["g"]
-        full = toks["g"]
-    finally:
-        eng.shutdown()
+    toks = tokens(drain(paged_engine, [
+        ("samp-g", prompt, {}),
+        ("samp-s1", prompt, {"temperature": 1.0}),
+        ("samp-s2", prompt, {"temperature": 1.0})]))
+    assert all(len(t) == 8 for t in toks.values())
+    full = toks["samp-g"]
+    assert toks["samp-s1"] != full or toks["samp-s2"] != full
 
-    eng2 = PagedLLMEngine(page_size=8, top_k=20, **TINY)
-    try:
-        stop_tok = full[3]
-        out = _drain(eng2, [("b", prompt, {"stop_ids": [stop_tok]})])
-        assert out["b"]["tokens"] == full[:full.index(stop_tok) + 1]
-    finally:
-        eng2.shutdown()
+    stop_tok = full[3]
+    out = drain(paged_engine, [("samp-b", prompt, {"stop_ids": [stop_tok]})])
+    assert out["samp-b"]["tokens"] == full[:full.index(stop_tok) + 1]
 
 
 def test_paged_attention_kernel_interpret():
@@ -198,87 +145,69 @@ def test_paged_attention_kernel_interpret():
     assert float(jnp.max(l[0])) == 0.0
 
 
-def test_paged_engine_cancel_releases_pages():
+def test_paged_engine_cancel_releases_pages(paged_engine):
     """Cancelling a generating request frees its slot AND its pages."""
-    from ray_tpu.serve.paged_engine import PagedLLMEngine
+    eng = paged_engine
 
-    eng = PagedLLMEngine(page_size=8,
-                         **dict(TINY, max_new_tokens=3000, max_len=64,
-                                chunk_steps=2))
-    try:
-        free0 = len(eng._alloc.free)
-        eng.submit("victim", [1, 2, 3, 4, 5])
-        deadline = time.time() + 60
-        while not eng._slot_req and time.time() < deadline:
-            time.sleep(0.01)
-        assert eng._slot_req, "request never admitted"
-        eng.cancel("victim")
-        deadline = time.time() + 60
-        while eng._slot_req and time.time() < deadline:
-            time.sleep(0.01)
-        assert not eng._slot_req, "slot not freed after cancel"
-        # pages return to free/cached; no result is delivered
-        deadline = time.time() + 30
-        while time.time() < deadline and (
-                len(eng._alloc.free) + len(eng._alloc.lru) < free0):
-            time.sleep(0.01)
-        assert len(eng._alloc.free) + len(eng._alloc.lru) == free0
-        assert eng.collect() == {}
-    finally:
-        eng.shutdown()
+    def pages_at_rest():  # free or cached: not held by a slot
+        st = eng.stats()
+        return st["free_pages"] + st["cached_prefix_pages"]
+
+    def wait_for(cond, what):
+        deadline = time.monotonic() + 60
+        while not cond():
+            assert time.monotonic() < deadline, what
+            time.sleep(0.005)
+
+    free0 = pages_at_rest()
+    # a budget the max_len window cuts: ~90 tokens of decode to cancel in
+    eng.submit("victim", [1, 2, 3, 4, 5], max_new_tokens=3000)
+    wait_for(lambda: "victim" in eng.peek(), "request never admitted")
+    eng.cancel("victim")
+    wait_for(lambda: eng.stats()["active"] == 0,
+             "slot not freed after cancel")
+    # pages return to free/cached; no result is delivered
+    wait_for(lambda: pages_at_rest() == free0, "pages not released")
+    assert eng.collect(["victim"]) == {}
 
 
-def test_oversized_prompt_rejected_not_livelocked():
+def test_oversized_prompt_rejected_not_livelocked(small_pool_engine):
     """A prompt needing more pages than the POOL HAS can never admit;
     it must fail fast with RuntimeError instead of requeueing forever —
     and must not wedge admission for satisfiable requests behind it."""
-    from ray_tpu.serve.paged_engine import PagedLLMEngine
-
-    rng = np.random.default_rng(13)
-    eng = PagedLLMEngine(page_size=8, num_pages=4, **TINY)
-    try:
-        # 40 tokens -> 5 pages > the 4-page pool
-        eng.submit("huge", [int(t) for t in rng.integers(1, 250, 40)])
-        eng.submit("ok", [int(t) for t in rng.integers(1, 250, 9)])
-        out = {}
-        deadline = time.time() + 120
-        while len(out) < 2 and time.time() < deadline:
-            out.update(eng.collect())
-            time.sleep(0.01)
-        assert isinstance(out.get("huge"), RuntimeError)
-        assert "pages" in str(out["huge"])
-        assert len(out["ok"]["tokens"]) == 8
-    finally:
-        eng.shutdown()
+    # 70 tokens -> 9 pages > the 8-page pool
+    huge, ok = _prompts(13, (70, 9))
+    out = drain(small_pool_engine, [("huge", huge, {}), ("ok", ok, {})])
+    assert isinstance(out.get("huge"), RuntimeError)
+    assert "pages" in str(out["huge"])
+    assert len(out["ok"]["tokens"]) == 8
 
 
-def test_pool_exhausted_retry_is_head_of_line():
+def test_pool_exhausted_retry_is_head_of_line(small_pool_engine):
     """A pool-exhausted request parks and retries BEFORE newer arrivals:
     the big request admits as soon as pages free, instead of being
     overtaken indefinitely by a stream of small admits."""
-    from ray_tpu.serve.paged_engine import PagedLLMEngine
-
-    rng = np.random.default_rng(17)
-    eng = PagedLLMEngine(page_size=8, num_pages=8, **TINY)
-    try:
-        eng.submit("s0", [int(t) for t in rng.integers(1, 250, 9)])
-        time.sleep(0.3)  # let s0 admit and hold its pages
-        # 49 tokens -> 7 pages: satisfiable alone, parked while s0 runs
-        eng.submit("big", [int(t) for t in rng.integers(1, 250, 49)])
-        for i in range(1, 4):
-            eng.submit(f"s{i}", [int(t) for t in rng.integers(1, 250, 9)])
-        order = []
-        deadline = time.time() + 180
-        while len(order) < 5 and time.time() < deadline:
-            for rid in eng.collect():
-                order.append(rid)
-            time.sleep(0.01)
-        assert sorted(order) == ["big", "s0", "s1", "s2", "s3"]
-        # head-of-line: big admitted at s0's page release, ahead of the
-        # smalls submitted after it
-        assert order.index("big") < order.index("s1")
-    finally:
-        eng.shutdown()
+    eng = small_pool_engine
+    s0, big, *smalls = _prompts(17, (9, 49, 9, 9, 9))
+    # 49 tokens in all: s0 decodes, holding its pages, while big arrives
+    eng.submit("s0", s0, max_new_tokens=40)
+    deadline = time.monotonic() + 60
+    while "s0" not in eng.peek():   # admitted: s0 holds its pages
+        assert time.monotonic() < deadline
+        time.sleep(0.002)
+    # 49 tokens -> 7 pages: satisfiable alone, parked while s0 runs
+    eng.submit("big", big)
+    for i, p in enumerate(smalls, 1):
+        eng.submit(f"s{i}", p)
+    order = []
+    deadline = time.monotonic() + 180
+    while len(order) < 5 and time.monotonic() < deadline:
+        order.extend(eng.collect())
+        time.sleep(0.01)
+    assert sorted(order) == ["big", "s0", "s1", "s2", "s3"]
+    # head-of-line: big admitted at s0's page release, ahead of the
+    # smalls submitted after it
+    assert order.index("big") < order.index("s1")
 
 
 def test_chain_hash_stable_across_processes():

@@ -18,11 +18,11 @@ import time
 
 import pytest
 
-import ray_tpu
 from ray_tpu import serve
-from ray_tpu.core import fault_injection, netem, runtime_context
+from ray_tpu.core import fault_injection, netem
 from ray_tpu.core.config import config
 from ray_tpu.exceptions import ActorDiedError, ReplicaUnavailableError
+from tests.conftest import own_runtime
 
 # ------------------------------------------------------------ unit layer
 
@@ -128,15 +128,8 @@ def test_resume_call_rebuilds_prompt_and_budget():
 
 @pytest.fixture(scope="module")
 def replay_ray():
-    prev = runtime_context.get_core_or_none()
-    runtime_context.set_core(None)
-    ray_tpu.init(num_workers=4, object_store_memory=256 << 20)
-    yield
-    serve.shutdown()
-    core = runtime_context.get_core_or_none()
-    if core is not None:
-        core.shutdown()
-    runtime_context.set_core(prev)
+    with own_runtime(4):
+        yield
 
 
 @pytest.fixture
@@ -322,8 +315,8 @@ def test_stream_resume_exact_splice(replay_ray, replay_on,
     name = f"llmres{int(affinity_toggle)}"
     dep = serve.deployment(name=name, engine=True, num_cpus=0.1)(
         LLMEngine).bind(
-        model_config={"preset": "tiny"}, num_slots=4, max_len=64,
-        prefill_buckets=[16], max_new_tokens=12, chunk_steps=1)
+        model_config={"preset": "tiny"}, num_slots=2, max_len=32,
+        prefill_buckets=[31], max_new_tokens=12, chunk_steps=1)
     handle = serve.run(dep, timeout=300)
 
     prompt = [5, 11, 2]
@@ -353,8 +346,8 @@ def test_engine_poll_replica_death_redispatches(replay_ray):
 
     dep = serve.deployment(name="llmkill", engine=True, num_cpus=0.1,
                            num_replicas=2)(KillableEngine).bind(
-        model_config={"preset": "tiny"}, num_slots=4, max_len=64,
-        prefill_buckets=[16], max_new_tokens=8)
+        model_config={"preset": "tiny"}, num_slots=4, max_len=16,
+        prefill_buckets=[15], max_new_tokens=8, chunk_steps=1)
     handle = serve.run(dep, timeout=300)
 
     pids = set()

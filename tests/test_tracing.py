@@ -17,6 +17,7 @@ import pytest
 
 from ray_tpu.core.config import config
 from ray_tpu.util import tracing
+from tests.engines import SMALLEST, drain, private_engine
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -199,38 +200,14 @@ assert "jax" not in sys.modules
         "rtpu.train.shutdown"][0]["ts"] + 1e5 for e in workers)
 
 
-TINY = dict(model_config={"preset": "tiny"}, num_slots=4, max_len=96,
-            prefill_buckets=[16], max_new_tokens=8, chunk_steps=4)
-
-
-@pytest.fixture(scope="module")
-def paged_engine():
-    from ray_tpu.serve.paged_engine import PagedLLMEngine
-
-    eng = PagedLLMEngine(page_size=8, **TINY)
-    deadline = time.time() + 300
-    while not eng.report()["ready"] and time.time() < deadline:
-        time.sleep(0.05)
-    try:
-        yield eng
-    finally:
-        eng.shutdown()
-
-
 def _generate(eng, n, tag):
     import numpy as np
 
     rng = np.random.default_rng(list(tag.encode()))   # prompts of its own
-    ids = [f"{tag}{i}" for i in range(n)]
-    for rid in ids:
-        eng.submit(rid, [int(t) for t in rng.integers(1, 250, 20)])
-    out = {}
-    deadline = time.time() + 120
-    while len(out) < n and time.time() < deadline:
-        out.update(eng.collect())
-        time.sleep(0.01)
-    assert len(out) == n
-    return ids
+    reqs = [(f"{tag}{i}", [int(t) for t in rng.integers(1, 250, 20)], {})
+            for i in range(n)]
+    assert len(drain(eng, reqs)) == n
+    return [rid for rid, _, _ in reqs]
 
 
 COUNTERS = ("ticks", "inflight_depth_sum", "steps_dispatched", "steps",
@@ -302,12 +279,18 @@ def test_engine_trace_hook_and_request_ids(paged_engine, tmp_path):
     assert len(eng.timeline()) == before        # the ring is off again
 
 
-def test_metrics_registry_serves_the_owners_counters(paged_engine):
+def test_metrics_registry_serves_the_owners_counters():
     from ray_tpu import metrics
+    from ray_tpu.serve.llm_engine import LLMEngine
 
-    text = metrics.REGISTRY.render()
-    ticks = int(re.search(r"^rtpu_engine_ticks (\d+)$", text, re.M).group(1))
-    assert 0 < ticks <= paged_engine.stats()["ticks"]
+    # private: an engine registers at construction, and the registry
+    # serves the one constructed last
+    with private_engine(LLMEngine, **SMALLEST) as eng:
+        _generate(eng, 1, "scraped")
+        text = metrics.REGISTRY.render()
+        ticks = int(re.search(r"^rtpu_engine_ticks (\d+)$", text,
+                              re.M).group(1))
+        assert 0 < ticks <= eng.stats()["ticks"]
     assert "# TYPE rtpu_engine_inflight_depth_sum gauge" in text
     assert re.search(r"^rtpu_span_rtpu_engine_admit_count \d+$", text, re.M)
     assert re.search(r"^rtpu_span_rtpu_engine_admit_seconds_total [\d.e-]+$",

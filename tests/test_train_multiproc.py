@@ -24,6 +24,7 @@ from ray_tpu.train import (
     RunConfig,
     ScalingConfig,
 )
+from tests.conftest import own_cluster
 
 N_PROCS = 2
 DEVS_PER_PROC = 4
@@ -283,16 +284,8 @@ def test_multiproc_gang_through_cluster_plane(run_cfg):
     processes of a real (local) cluster — scheduling, actor creation, and
     result plumbing all cross the RPC plane, and the JAX mesh crosses the
     node boundary."""
-    from ray_tpu.core import runtime_context
-    from ray_tpu.core.cluster.fixture import Cluster
-
-    prev = runtime_context.get_core_or_none()
-    runtime_context.set_core(None)
-    c = Cluster(num_nodes=2, num_workers_per_node=1,
-                node_resources=[{"CPU": 2}, {"CPU": 2}])
-    try:
-        c.wait_for_nodes(2)
-        c.connect()
+    with own_cluster(2, num_workers_per_node=1,
+                     node_resources=[{"CPU": 2}, {"CPU": 2}]) as c:
         trainer = JaxTrainer(
             _fsdp_gang_loop,
             train_loop_config={"steps": 4},
@@ -305,9 +298,6 @@ def test_multiproc_gang_through_cluster_plane(run_cfg):
         hist = result.metrics_history
         assert hist[0]["global_devices"] == N_PROCS * DEVS_PER_PROC
         assert hist[-1]["loss"] < hist[0]["loss"]
-    finally:
-        c.shutdown()
-        runtime_context.set_core(prev)
 
 
 def _preemptible_gang_loop(config):

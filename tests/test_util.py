@@ -342,3 +342,49 @@ def test_tqdm_multiplexes_concurrent_task_bars(rt):
         assert re.fullmatch(
             r"shard-\d: \|[#-]+\| \d+/30 \[\s*\d+%\] [\d.]+it/s( done)?",
             line.strip()), repr(line)
+
+
+# ------------------------------------------- the suite's own rules (conftest)
+
+
+def _pytest_on(tmp_path, body, *args):
+    """Run ``body`` as a test file of its own under tests/conftest.py
+    (loaded as a plugin: the file lives outside the checkout)."""
+    import os
+    import subprocess
+    import sys
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    (tmp_path / "test_probe.py").write_text(body)
+    env = dict(os.environ, PYTHONPATH=os.pathsep.join([str(tmp_path), repo]))
+    return subprocess.run(
+        [sys.executable, "-m", "pytest", "-q", "-p", "no:cacheprovider",
+         "-p", "no:xdist", "-p", "tests.conftest", "--strict-markers",
+         *args, "test_probe.py"],
+        cwd=tmp_path, env=env, capture_output=True, text=True, timeout=120)
+
+
+def test_a_test_over_the_limit_fails_alone_and_the_run_goes_on(tmp_path):
+    # the constant, patched by a plugin of one line
+    (tmp_path / "one_second.py").write_text(
+        "import tests.conftest\ntests.conftest.TEST_LIMIT_S = 1\n")
+    p = _pytest_on(tmp_path, "import time\n"
+                   "def test_sleeps():\n    time.sleep(60)\n"
+                   "def test_after_it():\n    pass\n", "-p", "one_second")
+    assert p.returncode == 1, p.stdout + p.stderr
+    assert "1 failed, 1 passed" in p.stdout
+    # the message names the test, the limit and where the test stood
+    assert "test_probe.py::test_sleeps ran over the per-test limit of 1 s " \
+           "in test_sleeps" in p.stdout
+    assert "test_probe.py:3" in p.stdout
+    # and every thread's stack went to the test's stderr just before
+    assert "most recent call first" in p.stdout
+
+
+def test_slow_is_a_registered_mark_that_the_drivers_command_deselects(
+        tmp_path):
+    p = _pytest_on(tmp_path, "import pytest\n"
+                   "@pytest.mark.slow\ndef test_long():\n    pass\n"
+                   "def test_short():\n    pass\n", "-m", "not slow")
+    assert p.returncode == 0, p.stdout + p.stderr   # --strict-markers
+    assert "1 passed, 1 deselected" in p.stdout

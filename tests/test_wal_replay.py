@@ -161,6 +161,7 @@ def test_torn_wal_tail_replays_clean_prefix(tmp_path):
         live._handle(("kv", "put", "b", 2), {})
     finally:
         live._server.close()  # skip close(): leave the raw WAL behind
+        live._stop = True     # but not the health loop, for the process's life
         if live._wal is not None:
             live._wal.close()
             live._wal = None
