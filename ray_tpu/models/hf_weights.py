@@ -210,21 +210,60 @@ def llama_from_hf(source, dtype=None) -> Tuple[Any, Dict[str, Any]]:
     return cfg, llama_params_from_hf(source.state_dict(), cfg, dtype=dtype)
 
 
-def mixtral_from_hf(source, dtype=None, capacity_factor=None
-                    ) -> Tuple[Any, Dict[str, Any]]:
-    """(cfg, params) from a transformers MixtralForCausalLM (or a
-    checkpoint path/model id). Experts map w1->e_gate, w3->e_up,
-    w2->e_down (Mixtral's naming), stacked [L, E, ...].
+def _moe_config_kwargs(hf_cfg, num_experts: int, dtype) -> Dict[str, Any]:
+    """What the Mixtral and OLMoE configs share with each other."""
+    return dict(
+        **({} if dtype is None else {"param_dtype": dtype}),
+        vocab_size=hf_cfg.vocab_size,
+        hidden_size=hf_cfg.hidden_size,
+        intermediate_size=hf_cfg.intermediate_size,
+        num_layers=hf_cfg.num_hidden_layers,
+        num_heads=hf_cfg.num_attention_heads,
+        num_kv_heads=hf_cfg.num_key_value_heads,
+        head_dim=getattr(hf_cfg, "head_dim", None),
+        max_seq_len=hf_cfg.max_position_embeddings,
+        rope_theta=float(hf_cfg.rope_theta),
+        rms_norm_eps=float(hf_cfg.rms_norm_eps),
+        tie_embeddings=bool(getattr(hf_cfg, "tie_word_embeddings", False)),
+        num_experts=num_experts,
+        top_k=hf_cfg.num_experts_per_tok,
+        rope_scaling=_parse_rope_scaling(hf_cfg),
+    )
 
-    NOTE on parity: this repo's MoE uses GShard-style STATIC-capacity
-    dispatch (overflow drops); HF computes exact token-wise outputs.
-    Pass ``capacity_factor >= num_experts/top_k`` for drop-free exact
-    parity (the test does); production configs trade capacity for speed.
-    """
+
+def _moe_params_from_hf(source, cfg, router: str, expert: str,
+                        names: Tuple[str, str, str], extra=()):
+    """Stacked [L, E, ...] expert tensors from per-expert linears.
+    ``router`` and ``expert`` are the key templates under a layer
+    (``{e}`` the expert), ``names`` the gate, up and down linears,
+    ``extra`` further per-layer vectors as (ours, theirs)."""
     import numpy as np
 
-    import jax.numpy as jnp
+    sd = source.state_dict()
+    t, lin = _fetcher(sd)
+    _refuse_proj_bias(sd)
+    keys = ("e_gate", "e_up", "e_down")
+    stacked: Dict[str, list] = {k: [] for k in (
+        "attn_norm", "wq", "wk", "wv", "wo", "mlp_norm", "router") + keys
+        + tuple(o for o, _ in extra)}
+    for i in range(cfg.num_layers):
+        p = f"model.layers.{i}."
+        _stack_attn(stacked, t, lin, p)
+        stacked["router"].append(lin(p + router))
+        for ours, theirs in zip(keys, names):
+            stacked[ours].append(np.stack(
+                [lin(p + expert.format(e=e) + theirs + ".weight")
+                 for e in range(cfg.num_experts)]))
+        for ours, theirs in extra:
+            stacked[ours].append(t(p + theirs))
+    return _assemble(cfg, stacked, t, lin, cfg.param_dtype)
 
+
+def mixtral_from_hf(source, dtype=None) -> Tuple[Any, Dict[str, Any]]:
+    """(cfg, params) from a transformers MixtralForCausalLM (or a
+    checkpoint path/model id). Experts map w1->e_gate, w3->e_up,
+    w2->e_down (Mixtral's naming), stacked [L, E, ...]. The routed layer
+    is dropless, as transformers' is, so the logits agree exactly."""
     from ray_tpu.models.mixtral import MixtralConfig
 
     if isinstance(source, str):
@@ -239,47 +278,35 @@ def mixtral_from_hf(source, dtype=None, capacity_factor=None
             f"implements full causal attention only; sequences past the "
             f"window would silently diverge from HF)")
     cfg = MixtralConfig(
-        vocab_size=hf_cfg.vocab_size,
-        hidden_size=hf_cfg.hidden_size,
-        intermediate_size=hf_cfg.intermediate_size,
-        num_layers=hf_cfg.num_hidden_layers,
-        num_heads=hf_cfg.num_attention_heads,
-        num_kv_heads=hf_cfg.num_key_value_heads,
-        head_dim=getattr(hf_cfg, "head_dim", None),
-        max_seq_len=hf_cfg.max_position_embeddings,
-        rope_theta=float(hf_cfg.rope_theta),
-        rms_norm_eps=float(hf_cfg.rms_norm_eps),
-        tie_embeddings=bool(getattr(hf_cfg, "tie_word_embeddings", False)),
-        num_experts=hf_cfg.num_local_experts,
-        top_k=hf_cfg.num_experts_per_tok,
-        rope_scaling=_parse_rope_scaling(hf_cfg),
-    )
-    from dataclasses import replace
+        **_moe_config_kwargs(hf_cfg, hf_cfg.num_local_experts, dtype))
+    return cfg, _moe_params_from_hf(
+        source, cfg, "block_sparse_moe.gate.weight",
+        "block_sparse_moe.experts.{e}.", ("w1", "w3", "w2"))
 
-    if dtype is not None:
-        cfg = replace(cfg, param_dtype=dtype)
-    if capacity_factor is not None:
-        cfg = replace(cfg, capacity_factor=float(capacity_factor))
-    sd = source.state_dict()
-    t, lin = _fetcher(sd)
-    _refuse_proj_bias(sd)
-    pd = cfg.param_dtype  # replace() above already applied dtype
-    L, E = cfg.num_layers, cfg.num_experts
-    stacked: Dict[str, list] = {k: [] for k in (
-        "attn_norm", "wq", "wk", "wv", "wo", "mlp_norm", "router",
-        "e_gate", "e_up", "e_down")}
-    for i in range(L):
-        p = f"model.layers.{i}."
-        _stack_attn(stacked, t, lin, p)
-        moe = p + "block_sparse_moe."
-        stacked["router"].append(lin(moe + "gate.weight"))
-        stacked["e_gate"].append(np.stack(
-            [lin(f"{moe}experts.{e}.w1.weight") for e in range(E)]))
-        stacked["e_up"].append(np.stack(
-            [lin(f"{moe}experts.{e}.w3.weight") for e in range(E)]))
-        stacked["e_down"].append(np.stack(
-            [lin(f"{moe}experts.{e}.w2.weight") for e in range(E)]))
-    return cfg, _assemble(cfg, stacked, t, lin, pd)
+
+def olmoe_from_hf(source, dtype=None) -> Tuple[Any, Dict[str, Any]]:
+    """(cfg, params) from a transformers OlmoeForCausalLM (or a
+    checkpoint path/model id): Mixtral's expert layout under OLMoE's
+    names, plus the q and k norms."""
+    from ray_tpu.models.olmoe import OlmoeConfig
+
+    if isinstance(source, str):
+        from transformers import OlmoeForCausalLM
+
+        source = OlmoeForCausalLM.from_pretrained(source)
+    hf_cfg = source.config
+    if getattr(hf_cfg, "clip_qkv", None) is not None:
+        raise ValueError("unsupported HF config: clip_qkv is set (this "
+                         "model does not clamp q, k and v)")
+    cfg = OlmoeConfig(
+        **_moe_config_kwargs(hf_cfg, hf_cfg.num_experts, dtype),
+        norm_topk_prob=bool(hf_cfg.norm_topk_prob),
+        router_aux_coef=float(hf_cfg.router_aux_loss_coef))
+    return cfg, _moe_params_from_hf(
+        source, cfg, "mlp.gate.weight", "mlp.experts.{e}.",
+        ("gate_proj", "up_proj", "down_proj"),
+        extra=(("q_norm", "self_attn.q_norm.weight"),
+               ("k_norm", "self_attn.k_norm.weight")))
 
 
 def qwen2_from_hf(source, dtype=None) -> Tuple[Any, Dict[str, Any]]:
@@ -407,7 +434,7 @@ def hf_model_type(source) -> str:
 
 
 def from_hf(source, dtype=None) -> Tuple[Any, Dict[str, Any]]:
-    """Architecture-dispatching loader: llama / qwen2 / mixtral / gpt2
+    """Architecture-dispatching loader: llama / qwen2 / mixtral / olmoe / gpt2
     by the checkpoint's ``model_type`` (reference role: engines resolve
     HF ids via AutoConfig). Accepts a model instance or a path/id."""
     if isinstance(source, str):
@@ -418,10 +445,11 @@ def from_hf(source, dtype=None) -> Tuple[Any, Dict[str, Any]]:
         model_type = source.config.model_type
     loader = {"llama": llama_from_hf, "qwen2": qwen2_from_hf,
               "gemma": gemma_from_hf,
-              "mixtral": mixtral_from_hf, "gpt2": gpt2_from_hf}.get(
+              "mixtral": mixtral_from_hf, "olmoe": olmoe_from_hf,
+              "gpt2": gpt2_from_hf}.get(
         model_type)
     if loader is None:
         raise ValueError(
             f"unsupported HF model_type {model_type!r} "
-            f"(implemented: llama, qwen2, mixtral, gpt2)")
+            f"(implemented: llama, qwen2, gemma, mixtral, olmoe, gpt2)")
     return loader(source, dtype=dtype)

@@ -237,7 +237,8 @@ def _attend(cfg: LlamaConfig, q, k, v, mesh=None, seq_axis=None):
 def attention_block(cfg: LlamaConfig, x, p, cos, sin, mesh=None,
                     seq_axis=None):
     """Pre-norm attention sub-block with residual: x + wo(attend(qkv)).
-    Shared by every model in the family (llama dense, mixtral MoE)."""
+    Shared by every model in the family (llama dense, mixtral and olmoe
+    MoE)."""
     # The named scopes here and below (embed, attn_qkv, flash, attn_out,
     # mlp, head_loss) are metadata only: they name the device time of a
     # step in a profiler trace and change no instruction.
@@ -255,6 +256,9 @@ def attention_block(cfg: LlamaConfig, x, p, cos, sin, mesh=None,
             q = q + p["bq"].astype(cfg.dtype)
             k = k + p["bk"].astype(cfg.dtype)
             v = v + p["bv"].astype(cfg.dtype)
+        if "q_norm" in p:  # OLMoE: RMSNorm over the whole q and k vectors
+            q = rms_norm(q, p["q_norm"], cfg.rms_norm_eps)
+            k = rms_norm(k, p["k_norm"], cfg.rms_norm_eps)
         q = q.reshape(b, s, cfg.num_heads, hd)
         k = k.reshape(b, s, cfg.num_kv_heads, hd)
         v = v.reshape(b, s, cfg.num_kv_heads, hd)

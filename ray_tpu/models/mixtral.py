@@ -5,17 +5,14 @@ reference delegates the model to torch; this is the JAX-native design:
 
 - Llama backbone (same attention stack, rms_norm/rope/GQA) with the dense
   MLP replaced by a top-k routed mixture of SwiGLU experts.
-- GShard/Switch-style STATIC-capacity dispatch: routing builds dense
-  dispatch/combine tensors and experts run as one grouped einsum over
-  ``[experts, capacity, hidden]`` — every shape static, so the whole MoE
-  layer is two einsums + the expert FFN on the MXU, and sharding the
-  expert dim over the mesh's ``ep`` axis makes XLA insert the
-  all-to-alls (tokens -> expert shards -> back) over ICI. No scatter,
-  no sort, no dynamic shapes.
+- Sorted, dropless dispatch (``ops/moe.routed_experts``): the (token,
+  choice) pairs are sorted by expert and each expert multiplies its own
+  ragged group of rows. Every shape is static, no row is ever dropped,
+  as in the published model.
 - Switch load-balancing auxiliary loss keeps routing uniform.
 
-Parity oracle: with num_experts=1, top_k=1 and enough capacity the MoE
-layer reduces exactly to the dense SwiGLU MLP (tested).
+Parity oracle: with num_experts=1, top_k=1 the MoE layer reduces exactly
+to the dense SwiGLU MLP (tested).
 """
 
 from __future__ import annotations
@@ -29,13 +26,13 @@ import jax.numpy as jnp
 
 from ray_tpu.models import llama
 from ray_tpu.ops.layers import rms_norm, rope_frequencies
+from ray_tpu.ops.moe import routed_experts
 
 
 @dataclass(frozen=True)
 class MixtralConfig(llama.LlamaConfig):
     num_experts: int = 8
     top_k: int = 2
-    capacity_factor: float = 1.25
     router_aux_coef: float = 0.01
 
     @classmethod
@@ -78,10 +75,18 @@ def logical_axes(cfg: MixtralConfig) -> Dict[str, Any]:
     return base
 
 
-def logical_axes_without_layer(cfg: MixtralConfig):
+def without_layer_axis(axes):
     return jax.tree_util.tree_map(
         lambda t: tuple(None if a == "layer" else a for a in t),
-        logical_axes(cfg), is_leaf=lambda x: isinstance(x, tuple))
+        axes, is_leaf=lambda x: isinstance(x, tuple))
+
+
+def refuse_dense_knobs(cfg: MixtralConfig) -> None:
+    if cfg.remat_policy != "full" or not cfg.scan_layers:
+        raise ValueError(
+            "remat_policy/scan_layers are dense-Llama knobs; the MoE "
+            "forward always scans under full remat — drop them rather "
+            "than read tuning signal from a no-op")
 
 
 def init_params(cfg: MixtralConfig, key: jax.Array) -> Dict[str, Any]:
@@ -105,64 +110,22 @@ def init_params(cfg: MixtralConfig, key: jax.Array) -> Dict[str, Any]:
     return params
 
 
-def _capacity(cfg: MixtralConfig, num_tokens: int) -> int:
-    cap = int(math.ceil(cfg.capacity_factor * num_tokens * cfg.top_k
-                        / cfg.num_experts))
-    return max(8, ((cap + 7) // 8) * 8)  # MXU-friendly multiple of 8
-
-
 def moe_layer(cfg: MixtralConfig, p, x: jax.Array
               ) -> Tuple[jax.Array, jax.Array]:
     """Routed expert MLP. x: [b, s, h] -> (out [b, s, h], aux_loss)."""
     b, s, h = x.shape
     n = b * s
-    E, K = cfg.num_experts, cfg.top_k
-    C = _capacity(cfg, n)
-    xt = x.reshape(n, h)
-
-    logits = jnp.dot(xt, p["router"].astype(cfg.dtype),
-                     preferred_element_type=jnp.float32)   # [n, E] fp32
-    probs = jax.nn.softmax(logits, axis=-1)
-
-    # top-k selection; renormalized gate weights (Mixtral convention)
-    top_w, top_e = jax.lax.top_k(probs, K)                 # [n, K]
-    top_w = top_w / jnp.maximum(top_w.sum(-1, keepdims=True), 1e-9)
-
+    E = cfg.num_experts
+    # renormalized gate weights (Mixtral convention); no row is dropped
+    out, logits, _ = routed_experts(
+        x.reshape(n, h), p["router"], p["e_gate"], p["e_up"], p["e_down"],
+        cfg.top_k, renormalize=True)
     # Switch aux loss: fraction of tokens routed * mean router prob per
     # expert (computed on the top-1 assignment)
-    me = probs.mean(axis=0)                                # [n->E] mean prob
-    ce = jnp.zeros((E,), jnp.float32).at[top_e[:, 0]].add(1.0) / n
+    probs = jax.nn.softmax(logits, axis=-1)
+    me = probs.mean(axis=0)
+    ce = jnp.zeros((E,), jnp.float32).at[jnp.argmax(probs, -1)].add(1.0) / n
     aux = cfg.router_aux_coef * E * jnp.sum(me * ce)
-
-    # static-capacity position assignment: for expert e, tokens keep their
-    # routing in arrival order until capacity; overflow drops (standard)
-    onehot = jax.nn.one_hot(top_e, E, dtype=jnp.int32)     # [n, K, E]
-    flat = onehot.reshape(n * K, E)
-    pos = jnp.cumsum(flat, axis=0) - 1                     # [n*K, E]
-    pos = (pos * flat).sum(-1).reshape(n, K)               # slot per (tok,k)
-    expert_of = top_e                                      # [n, K]
-    keep = (pos < C)
-
-    # dispatch one-hots: [n, K, C] scatter into each expert's buffer
-    pos_oh = jax.nn.one_hot(jnp.where(keep, pos, C), C + 1,
-                            dtype=cfg.dtype)[..., :C]      # drops overflow
-    disp = jnp.einsum("nke,nkc->nec", onehot.astype(cfg.dtype), pos_oh)
-    comb = jnp.einsum("nke,nkc,nk->nec", onehot.astype(jnp.float32),
-                      pos_oh.astype(jnp.float32), top_w).astype(cfg.dtype)
-
-    # tokens -> expert buffers [E, C, h]; with "expert" sharded over ep
-    # this einsum is the all-to-all
-    ex_in = jnp.einsum("nec,nh->ech", disp, xt)
-    # grouped expert SwiGLU
-    g = jnp.einsum("ech,ehf->ecf", ex_in, p["e_gate"].astype(cfg.dtype),
-                   preferred_element_type=jnp.float32)
-    u = jnp.einsum("ech,ehf->ecf", ex_in, p["e_up"].astype(cfg.dtype),
-                   preferred_element_type=jnp.float32)
-    act = (jax.nn.silu(g) * u).astype(cfg.dtype)
-    ex_out = jnp.einsum("ecf,efh->ech", act, p["e_down"].astype(cfg.dtype),
-                        preferred_element_type=jnp.float32).astype(cfg.dtype)
-    # back to tokens, weighted by gates (the reverse all-to-all)
-    out = jnp.einsum("nec,ech->nh", comb, ex_out)
     return out.reshape(b, s, h), aux
 
 
@@ -182,11 +145,7 @@ def forward(cfg: MixtralConfig, params, tokens: jax.Array, mesh=None
                                 cfg.rope_theta, dtype=cfg.dtype,
                                 scaling=cfg.rope_scaling_dict)
 
-    if cfg.remat_policy != "full" or not cfg.scan_layers:
-        raise ValueError(
-            "remat_policy/scan_layers are dense-Llama knobs; the MoE "
-            "forward always scans under full remat — drop them rather "
-            "than read tuning signal from a no-op")
+    refuse_dense_knobs(cfg)
     layer_fn = lambda x_, p_: _layer(cfg, x_, p_, cos, sin, mesh=mesh)
     if cfg.remat:
         layer_fn = jax.checkpoint(layer_fn)
@@ -216,4 +175,4 @@ def loss_fn(cfg: MixtralConfig, params, batch: Dict[str, jax.Array],
 def param_shardings(cfg: MixtralConfig, mesh):
     from ray_tpu.parallel.sharding import shard_pytree_like
 
-    return shard_pytree_like(logical_axes_without_layer(cfg), mesh)
+    return shard_pytree_like(without_layer_axis(logical_axes(cfg)), mesh)

@@ -71,6 +71,11 @@ class TrainContext:
         return self.trial_dir
 
 
+# what a routed-experts train loop may put into ``train.report``: the
+# session serves the last value of each as ``rtpu_train_<key>``
+MOE_COUNTERS = ("moe_rows_routed", "moe_expert_load_max_over_mean")
+
+
 class SessionInterruptedError(BaseException):
     """Raised inside the user train loop when the driver interrupts the
     session (gang resize: a peer died or the gang is growing back). A
@@ -106,6 +111,7 @@ class _TrainSession:
         self._finished = False
         self._interrupted: Optional[str] = None
         self._reports = 0
+        self._moe: Dict[str, float] = {}   # the last reported MOE_COUNTERS
         import weakref
 
         from ray_tpu import metrics
@@ -113,7 +119,7 @@ class _TrainSession:
         me = weakref.ref(self)     # the registry keeps no session alive
         metrics.REGISTRY.register_source("rtpu_train", lambda: {
             "reports": me()._reports, "checkpoints": me()._ckpt_index,
-            "world_rank": me().context.world_rank})
+            "world_rank": me().context.world_rank, **me()._moe})
 
         def runner():
             try:
@@ -165,6 +171,8 @@ class _TrainSession:
                 persisted = ckpt.path
             self._ckpt_index += 1
         self._reports += 1
+        self._moe.update({k: metrics[k] for k in MOE_COUNTERS
+                          if isinstance(metrics.get(k), (int, float))})
         self._result_q.put(TrainingResult(metrics=dict(metrics),
                                           checkpoint_dir=persisted))
         # Lockstep: wait until the driver consumed this result before the

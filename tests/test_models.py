@@ -196,8 +196,8 @@ def test_moe_forward_and_aux():
 
 
 def test_moe_single_expert_equals_dense_mlp():
-    """With E=1, k=1 and ample capacity the routed layer must reduce to a
-    plain SwiGLU MLP — the numerics oracle for dispatch/combine."""
+    """With E=1, k=1 the routed layer must reduce to a plain SwiGLU MLP
+    — the numerics oracle for dispatch/combine."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -205,8 +205,7 @@ def test_moe_single_expert_equals_dense_mlp():
     from ray_tpu.models import mixtral
     from ray_tpu.ops.layers import swiglu
 
-    cfg = mixtral.MixtralConfig.tiny(num_experts=1, top_k=1,
-                                     capacity_factor=2.0)
+    cfg = mixtral.MixtralConfig.tiny(num_experts=1, top_k=1)
     params = mixtral.init_params(cfg, jax.random.PRNGKey(0))
     p0 = jax.tree_util.tree_map(lambda a: a[0], params["layers"])
     x = jax.random.normal(jax.random.PRNGKey(2), (2, 8, cfg.hidden_size),
@@ -251,6 +250,162 @@ def test_moe_expert_parallel_train_step():
     # expert weights are actually partitioned over ep
     sh = p2["layers"]["e_gate"].sharding.spec
     assert "ep" in str(sh)
+
+
+# -------------------------------------------------------------------- olmoe
+
+
+@pytest.fixture(scope="module")
+def olmoe_setup():
+    import os
+    import sys
+
+    sys.path.insert(0, os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))          # benchmark/ lies beside tests/
+    from benchmark.references import olmoe_ref
+    from ray_tpu.models import olmoe
+
+    cfg = olmoe.OlmoeConfig.tiny(attn_impl="reference")
+    params = olmoe.init_params(cfg, jax.random.PRNGKey(0))
+    # the norms start at 1: move them, or a dropped q/k norm goes unseen
+    for i, name in enumerate(("q_norm", "k_norm", "attn_norm", "mlp_norm")):
+        params["layers"][name] = 1.0 + 0.3 * jax.random.normal(
+            jax.random.PRNGKey(10 + i), params["layers"][name].shape)
+    tokens = np.random.default_rng(1).integers(0, cfg.vocab_size, (2, 17))
+    return olmoe, olmoe_ref, cfg, params, tokens
+
+
+def test_olmoe_forward_and_loss_terms_match_the_reference(olmoe_setup):
+    """Logits, router logits, expert counts and all three terms of the
+    loss against the plain float32 reference on seeded weights."""
+    olmoe, olmoe_ref, cfg, params, tokens = olmoe_setup
+    with jax.default_matmul_precision("highest"):
+        logits, router = jax.jit(lambda p, t: olmoe.forward(
+            cfg, p, t, keep_router_logits=True))(params, tokens[:, :-1])
+        loss, terms = jax.jit(lambda p, t: olmoe.loss_terms(
+            cfg, p, {"tokens": t}))(params, tokens)
+    ref = olmoe_ref.token_nll(cfg, params, tokens)
+    np.testing.assert_allclose(
+        np.asarray(logits), np.asarray(olmoe_ref.logits(
+            cfg, params, tokens[:, :-1])), rtol=2e-5, atol=2e-5)
+    np.testing.assert_allclose(np.asarray(router["logits"]),
+                               ref["router_logits"], rtol=2e-5, atol=2e-5)
+    E = cfg.num_experts
+    want_counts = np.stack([np.bincount(c.ravel(), minlength=E)
+                            for c in ref["chosen"]])
+    assert (np.asarray(terms["expert_counts"]) == want_counts).all()
+    assert int(want_counts.sum()) == cfg.num_layers * 32 * cfg.top_k
+    for name in ("cross_entropy", "load_balance", "router_z"):
+        assert abs(float(terms[name]) - ref["terms"][name]) < 2e-5, name
+    assert abs(float(loss) - ref["terms"]["loss"]) < 2e-5
+    assert ref["terms"]["load_balance"] > 1.0 and ref["terms"]["router_z"] > 0
+
+
+def test_olmoe_gradients_match_the_reference(olmoe_setup):
+    olmoe, olmoe_ref, cfg, params, tokens = olmoe_setup
+    with jax.default_matmul_precision("highest"):
+        got = jax.jit(jax.grad(lambda p: olmoe.loss_fn(
+            cfg, p, {"tokens": tokens})))(params)
+    want = jax.jit(jax.grad(
+        lambda p: olmoe_ref.loss(cfg, p, tokens)))(params)
+    flat, _ = jax.tree_util.tree_flatten_with_path(got)
+    assert len(flat) == 15
+    for (path, g), w in zip(flat, jax.tree_util.tree_leaves(want)):
+        assert float(jnp.abs(w).max()) > 1e-4, path     # it is reached
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=1e-4,
+                                   atol=1e-6, err_msg=str(path))
+
+
+def test_olmoe_reference_forced_to_other_choices(olmoe_setup):
+    """``forced_topk`` replaces the reference's choice of experts: its
+    own choice gives its own result back, another choice another."""
+    _, olmoe_ref, cfg, params, tokens = olmoe_setup
+    own = olmoe_ref.token_nll(cfg, params, tokens)
+    same = olmoe_ref.token_nll(cfg, params, tokens,
+                               forced_topk=own["chosen"][:, :, ::-1])
+    np.testing.assert_allclose(same["nll"], own["nll"], atol=1e-6)
+    other = olmoe_ref.token_nll(cfg, params, tokens, forced_topk=(
+        own["chosen"] + 1) % cfg.num_experts)
+    assert np.abs(other["nll"] - own["nll"]).max() > 1e-3
+
+
+def test_olmoe_hf_checkpoint_parity():
+    """transformers' OlmoeForCausalLM at a tiny random config, through
+    ``olmoe_from_hf``: the program's and the reference's logits, and the
+    load-balancing term against transformers' own function. This is
+    what ties the reference to the published model."""
+    from dataclasses import replace
+
+    import torch
+    from transformers import OlmoeConfig as HFConfig, OlmoeForCausalLM
+    from transformers.models.olmoe.modeling_olmoe import (
+        load_balancing_loss_func)
+
+    from benchmark.references import olmoe_ref
+    from ray_tpu.models import olmoe
+    from ray_tpu.models.hf_weights import from_hf
+
+    torch.manual_seed(0)
+    hf = OlmoeForCausalLM(HFConfig(
+        vocab_size=128, hidden_size=32, intermediate_size=16,
+        num_hidden_layers=2, num_attention_heads=4, num_key_value_heads=4,
+        num_experts=8, num_experts_per_tok=2, norm_topk_prob=False,
+        max_position_embeddings=64, rope_theta=10000.0, rms_norm_eps=1e-5,
+        router_aux_loss_coef=0.01)).eval()
+    with torch.no_grad():       # the norms start at 1: move them
+        for name, w in hf.named_parameters():
+            if "norm" in name:
+                w.add_(0.3 * torch.randn_like(w))
+    cfg, params = from_hf(hf, dtype=jnp.float32)
+    assert isinstance(cfg, olmoe.OlmoeConfig) and cfg.num_experts == 8
+    assert not cfg.norm_topk_prob and cfg.router_aux_coef == 0.01
+    cfg = replace(cfg, dtype=jnp.float32, attn_impl="reference", remat=False)
+    tokens = np.random.default_rng(3).integers(0, 128, (2, 16))
+    with torch.no_grad():
+        out = hf(torch.tensor(tokens), output_router_logits=True)
+    want = out.logits.numpy()
+    with jax.default_matmul_precision("highest"):
+        logits, router = olmoe.forward(cfg, params, jnp.asarray(tokens))
+        balance, _ = olmoe.router_losses(cfg, router)
+    assert np.abs(np.asarray(logits) - want).max() < 5e-5
+    assert np.abs(np.asarray(olmoe_ref.logits(cfg, params, tokens))
+                  - want).max() < 5e-5
+    hf_balance = float(load_balancing_loss_func(
+        out.router_logits, 8, 2))
+    assert abs(float(balance) - hf_balance) < 1e-5
+    full = np.concatenate([tokens, tokens[:, :1]], axis=1)
+    ref = olmoe_ref.token_nll(cfg, params, full)
+    assert abs(ref["terms"]["load_balance"] - hf_balance) < 1e-5
+
+
+def test_olmoe_fsdp_train_step_matches_unsharded(olmoe_setup):
+    """A train step with parameters sharded over fsdp=8 (every chip
+    routes its own rows to all experts): the loss and the expert counts
+    of the unsharded step."""
+    import optax
+
+    olmoe, _, cfg, params, _ = olmoe_setup
+    tokens = jax.random.randint(jax.random.PRNGKey(5), (8, 17), 0,
+                                cfg.vocab_size)
+    base, base_terms = jax.jit(lambda p, t: olmoe.loss_terms(
+        cfg, p, {"tokens": t}))(params, tokens)
+    mesh = build_mesh(MeshSpec({"fsdp": 8}))
+    p_sh = jax.device_put(params, olmoe.param_shardings(cfg, mesh))
+    t_sh = jax.device_put(tokens, named_sharding(mesh, "batch", None))
+    tx = optax.adamw(1e-3)
+
+    def step(p, o, t):
+        (loss, terms), grads = jax.value_and_grad(
+            lambda q: olmoe.loss_terms(cfg, q, {"tokens": t}, mesh=mesh),
+            has_aux=True)(p)
+        upd, o = tx.update(grads, o, p)
+        return optax.apply_updates(p, upd), o, loss, terms["expert_counts"]
+
+    p2, _, loss, counts = jax.jit(step)(p_sh, tx.init(p_sh), t_sh)
+    assert abs(float(loss) - float(base)) < 1e-4
+    assert (np.asarray(counts)
+            == np.asarray(base_terms["expert_counts"])).all()
+    assert "fsdp" in str(p2["layers"]["e_gate"].sharding.spec)
 
 
 def test_llama_hf_checkpoint_parity():
@@ -312,9 +467,8 @@ def test_gpt2_hf_checkpoint_parity():
 
 def test_mixtral_hf_checkpoint_parity():
     """HF Mixtral weights (per-expert w1/w3/w2 linears) load into our
-    stacked [L, E, ...] expert tensors, and with drop-free capacity the
-    STATIC-capacity grouped-einsum MoE reproduces transformers' exact
-    token-wise computation (measured ~9e-8)."""
+    stacked [L, E, ...] expert tensors, and the sorted dropless MoE
+    reproduces transformers' exact token-wise computation."""
     from dataclasses import replace
 
     import numpy as np
@@ -332,8 +486,7 @@ def test_mixtral_hf_checkpoint_parity():
         max_position_embeddings=64, rope_theta=10000.0,
         rms_norm_eps=1e-5)).eval()
 
-    cfg, params = mixtral_from_hf(hf, dtype=jnp.float32,
-                                  capacity_factor=(4 / 2) * 1.2)
+    cfg, params = mixtral_from_hf(hf, dtype=jnp.float32)
     cfg = replace(cfg, dtype=jnp.float32, attn_impl="reference",
                   remat=False)
     tokens = np.random.default_rng(3).integers(0, 128, (2, 15))

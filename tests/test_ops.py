@@ -235,3 +235,127 @@ def test_ulysses_rejects_indivisible_heads():
     q = jnp.zeros((1, 64, 6, 16))
     with pytest.raises(ValueError, match="divisible"):
         ulysses_attention(q, q, q, mesh)
+
+
+# ------------------------------------------------------- routed experts
+
+
+def _experts_by_loop(x, router_w, e_gate, e_up, e_down, top_k,
+                     renormalize=False):
+    """Every expert over every token, a mask keeping the chosen ones."""
+    probs = jax.nn.softmax(x @ router_w, axis=-1)
+    top_w, top_e = jax.lax.top_k(probs, top_k)
+    if renormalize:
+        top_w = top_w / top_w.sum(-1, keepdims=True)
+    out = jnp.zeros_like(x)
+    for e in range(router_w.shape[1]):
+        gate = jnp.where(top_e == e, top_w, 0.0).sum(-1)
+        out = out + gate[:, None] * swiglu(x, e_gate[e], e_up[e], e_down[e])
+    return out
+
+
+def _routed_inputs(skewed: bool):
+    n, h, f, E = 96, 32, 48, 16
+    ks = jax.random.split(jax.random.PRNGKey(0), 6)
+    x = jax.random.normal(ks[0], (n, h))
+    router_w = jax.random.normal(ks[1], (h, E))
+    if skewed:      # a constant feature the router sends to experts 8..15
+        x = x.at[:, 0].set(5.0)
+        router_w = (router_w * 0.01).at[0, 8:].add(10.0)
+    return (x, router_w, jax.random.normal(ks[2], (E, h, f)) / 6,
+            jax.random.normal(ks[3], (E, h, f)) / 6,
+            jax.random.normal(ks[4], (E, f, h)) / 7,
+            jax.random.normal(ks[5], (n, h)))
+
+
+@pytest.mark.parametrize("skewed", [False, True])
+@pytest.mark.parametrize("renormalize", [False, True])
+def test_routed_experts_match_the_expert_loop(skewed, renormalize):
+    """Forward and gradients (inputs, router, every expert matrix)
+    against the plain loop, at balanced routing and with a router that
+    sends every token to the same 8 of 16 experts: nothing is dropped,
+    and the 8 empty groups are handled."""
+    from ray_tpu.ops.moe import routed_experts
+
+    *args, cot = _routed_inputs(skewed)
+    with jax.default_matmul_precision("highest"):
+        out, logits, counts = jax.jit(
+            lambda *a: routed_experts(*a, 8, renormalize))(*args)
+        want = _experts_by_loop(*args, 8, renormalize)
+        got_g = jax.jit(jax.grad(
+            lambda *a: (routed_experts(*a, 8, renormalize)[0] * cot).sum(),
+            argnums=(0, 1, 2, 3, 4)))(*args)
+        want_g = jax.jit(jax.grad(
+            lambda *a: (_experts_by_loop(*a, 8, renormalize) * cot).sum(),
+            argnums=(0, 1, 2, 3, 4)))(*args)
+    assert int(counts.sum()) == 96 * 8          # no row dropped
+    if skewed:
+        assert counts.tolist() == [0] * 8 + [96] * 8
+    else:
+        assert int(counts.min()) > 0
+    np.testing.assert_allclose(np.asarray(logits), np.asarray(
+        args[0] @ args[1]), rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want),
+                               rtol=1e-5, atol=1e-5)
+    for got, ref in zip(got_g, want_g):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
+                                   rtol=1e-4, atol=1e-4)
+
+
+def test_routed_experts_single_expert_is_the_dense_swiglu():
+    from ray_tpu.ops.moe import routed_experts
+
+    x, router_w, e_gate, e_up, e_down, _ = _routed_inputs(False)
+    out, _, counts = routed_experts(x, router_w[:, :1], e_gate[:1], e_up[:1],
+                                    e_down[:1], top_k=1)
+    assert counts.tolist() == [96]
+    np.testing.assert_allclose(
+        np.asarray(out), np.asarray(swiglu(x, e_gate[0], e_up[0], e_down[0])),
+        rtol=1e-5, atol=1e-5)
+
+
+def test_routed_experts_names_its_scopes_forward_and_backward():
+    """The four scopes ``benchmark/lib/moe_scopes.py`` reads, on the
+    operations of the forward and of the hand-written transposes."""
+    from ray_tpu.ops.moe import routed_experts
+
+    *args, _ = _routed_inputs(False)
+    text = jax.jit(jax.grad(
+        lambda *a: routed_experts(*a, 8)[0].sum(), argnums=(0, 2))).lower(
+        *args).as_text(debug_info=True)
+    for scope in ("moe_route", "moe_dispatch", "moe_experts", "moe_combine"):
+        assert f"jvp({scope})" in text, scope
+        assert f"transpose(jvp({scope}))" in text, scope
+
+
+def test_routed_experts_tpu_path_in_interpret_mode(monkeypatch):
+    """What a TPU runs: the megablox kernels behind ``grouped_matmul``'s
+    own transposes, here through the Pallas interpreter (768 rows, three
+    tiles of 256, groups that end inside a tile, eight empty groups)."""
+    from functools import partial
+
+    from ray_tpu.ops import moe
+
+    mb = moe._megablox()
+
+    class Interpreted:
+        gmm = staticmethod(partial(mb.gmm, interpret=True))
+        tgmm = staticmethod(partial(mb.tgmm, interpret=True))
+
+    monkeypatch.setattr(moe, "_megablox", lambda: Interpreted)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    for skewed in (False, True):
+        *args, cot = _routed_inputs(skewed)
+        with jax.default_matmul_precision("highest"):
+            fn = lambda *a: (moe.routed_experts(*a, 8)[0] * cot).sum()
+            text = jax.jit(fn).lower(*args).as_text()
+            assert "ragged_dot" not in text
+            got = jax.jit(jax.value_and_grad(fn, argnums=(0, 1, 2, 3, 4)))(
+                *args)
+            want = jax.jit(jax.value_and_grad(
+                lambda *a: (_experts_by_loop(*a, 8) * cot).sum(),
+                argnums=(0, 1, 2, 3, 4)))(*args)
+        for g, w in zip(jax.tree_util.tree_leaves(got),
+                        jax.tree_util.tree_leaves(want)):
+            np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                       rtol=1e-4, atol=1e-4)

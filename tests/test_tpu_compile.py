@@ -1,0 +1,75 @@
+"""Kernels of the main path compiled for a v5e chip that is described
+and not attached, at published widths: what Mosaic refuses (a tile that
+does not fit VMEM, a slice off the tiling) it refuses here, at no chip
+time. Nothing runs, so nothing here is a result or a time.
+
+The topology is described inside a fixture and never at import: only one
+process may load libtpu, and only the xdist worker that is handed this
+file does. Keep such tests in this one file.
+"""
+
+import os
+import re
+
+import pytest
+
+jax = pytest.importorskip("jax")
+import jax.numpy as jnp  # noqa: E402
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def no_compile_cache():
+    """A compile for a described device is written to the persistent
+    cache and cannot be read back without a chip."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", before)
+    compilation_cache.reset_cache()
+
+
+def test_routed_experts_compile_at_olmoe_widths(one_chip, no_compile_cache,
+                                                monkeypatch):
+    """Forward and backward of the routed layer at OLMoE-1B-7B's widths
+    and the benchmark cell's 8,192 tokens: the grouped matmuls are the
+    megablox kernels (three ``gmm`` forward, three transposed, three
+    ``tgmm``), and they carry the scope ``moe_experts``."""
+    from ray_tpu.ops.moe import routed_experts
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    n, h, f, E, K = 8192, 2048, 1024, 64, 8
+    bf16 = jnp.bfloat16
+
+    def S(*shape):
+        return jax.ShapeDtypeStruct(shape, bf16, sharding=one_chip)
+
+    def loss(*a):
+        return routed_experts(*a, K)[0].astype(jnp.float32).sum()
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+        S(n, h), S(h, E), S(E, h, f), S(E, h, f), S(E, f, h)).compile()
+    calls = [line for line in compiled.as_text().splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    names = [re.match(r"\s*(?:ROOT )?%([A-Za-z_]+)", c).group(1)
+             for c in calls]
+    assert sorted(names) == ["gmm"] * 6 + ["tgmm"] * 3, names
+    assert all("moe_experts" in c for c in calls)
+    # rows, gate, up, activation, their cotangents: a few n*K-row arrays
+    assert compiled.memory_analysis().temp_size_in_bytes < 3 << 30

@@ -306,6 +306,42 @@ def test_metrics_registry_serves_the_owners_counters():
     assert "rtpu_t9" not in metrics.REGISTRY.render()
 
 
+def test_train_session_serves_the_last_reported_moe_counters():
+    """``rtpu_train_moe_*``: the last ``moe_rows_routed`` and
+    ``moe_expert_load_max_over_mean`` a loop put into ``train.report``;
+    a loop that reports neither serves neither."""
+    from ray_tpu import metrics
+    from ray_tpu.train.session import TrainContext, _TrainSession
+
+    def loop():
+        from ray_tpu import train
+        train.report({"loss": 1.0})
+        train.report({"loss": 0.9, "moe_rows_routed": 196608,
+                      "moe_expert_load_max_over_mean": 4.5})
+        train.report({"loss": 0.8, "moe_rows_routed": 196608,
+                      "moe_expert_load_max_over_mean": 4.25,
+                      "moe_other": 1})
+
+    s = _TrainSession(loop, {}, TrainContext())
+    from ray_tpu.train import session as session_mod
+    saved, session_mod._session = session_mod._session, s
+    try:
+        s.start()
+        assert s.next_result(timeout=10).metrics == {"loss": 1.0}
+        s.next_result(timeout=10)       # the loop is in its second report
+        assert "rtpu_train_moe" not in metrics.REGISTRY.render().split(
+            "rtpu_train_reports")[0]
+        s.next_result(timeout=10)
+        assert s.next_result(timeout=10).done
+        text = metrics.REGISTRY.render()
+    finally:
+        session_mod._session = saved
+    assert "rtpu_train_moe_rows_routed 196608\n" in text
+    assert "rtpu_train_moe_expert_load_max_over_mean 4.25\n" in text
+    assert "rtpu_train_moe_other" not in text and "rtpu_train_loss" not in text
+    assert "rtpu_train_reports 3\n" in text
+
+
 def _train_step_text(scoped: bool) -> str:
     import jax
     import jax.numpy as jnp
