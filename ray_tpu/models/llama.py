@@ -24,10 +24,37 @@ from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from ray_tpu.ops.attention import attention_reference, flash_attention
 from ray_tpu.ops.layers import apply_rope, rms_norm, rope_frequencies, swiglu
 from ray_tpu.ops.ring_attention import ring_attention
+from ray_tpu.util import tracing
+
+# What a layer's jax.checkpoint keeps besides the layer's input, rung by
+# rung in order of step time saved per byte kept (PERF.md 6, PR 27):
+# checkpoint names given where the values are born (attention_block,
+# ops/attention._flash_fwd, ops/layers.swiglu). "level<n>" keeps the names
+# of the first n rungs. The norms and act(gate) * up are recomputed at
+# every level: elementwise and cheap, and as large again as all four rungs.
+REMAT_LADDER = (
+    ("flash_out", "flash_lse"),         # the backward's second flash_fwd
+    ("q_rope", "k_rope", "v_proj"),     # the q/k/v matmuls and rope
+    ("mlp_gate", "mlp_up"),             # the gate and up matmuls
+    ("attn_resid",),                    # the wo matmul
+)
+REMAT_POLICIES = ("auto", "full", "save_qkv") + tuple(
+    f"level{n}" for n in range(1, len(REMAT_LADDER) + 1))
+# remat_plan's two constants, calibrated against the TPU compiler
+# (PERF.md 6, PR 27: 33 step programs compiled for a v5e, two to sixteen
+# layers, 4k to 16k tokens a device, with and without fsdp). XLA's heap
+# for a step under the layer scan comes out about half again what is live
+# in it at its fullest (the compiler's own report: 35-47% fragmentation);
+# the stacks a level keeps cost their own bytes on top. Reckoned so, no
+# program read more than 3% over its estimate. The reserve covers that
+# and what lives beside the program (the next batch, the step's outputs).
+REMAT_HEAP_FACTOR = 1.5
+REMAT_RESERVE = 0.05
 
 
 @dataclass(frozen=True)
@@ -63,22 +90,25 @@ class LlamaConfig:
     # of recomputing (HBM for FLOPs; 0 = classic full per-layer remat).
     # Caveats: the head/tail split slices the stacked layer params, which
     # XLA may materialize as a duplicate of the stack — budget for it;
-    # measured neutral-to-NEGATIVE on v5e-lite at 1B (round 4, old
-    # machine), aimed at HBM-rich parts; sequential forward only (pp
-    # raises).
+    # measured neutral-to-NEGATIVE at 1B on the old machine (round 4; not
+    # measured on this repo's v5e), aimed at HBM-rich parts; sequential
+    # forward only (pp raises).
     remat_store_layers: int = 0
-    # remat selectivity: "full" recomputes the whole layer on backward;
-    # "save_qkv" keeps the post-rope q/k/v projections (HBM cost
-    # b*s*(H+2*KVH)*hd*2 per layer ≈ 2.1 GB at the 1B bench shape) so
-    # the backward skips their recompute — measured 806→782 ms at 1B on
-    # v5e with bf16 adam momentum funding the HBM.
-    remat_policy: str = "full"  # full | save_qkv
+    # What a layer's jax.checkpoint keeps for its backward. "full": the
+    # layer's input alone, the whole forward runs again. "level1" ..
+    # "level4": the names of REMAT_LADDER's first n rungs besides.
+    # "auto" (the default): the richest of those that remat_plan reckons
+    # to fit the device's memory, "full" where the device reports none
+    # (the CPU). "save_qkv": the post-rope q/k/v projections alone (rung 2
+    # without rung 1; b*s*(H+2*KVH)*hd*2 bytes a layer), kept for whoever
+    # set it.
+    remat_policy: str = "auto"
     # False = python-unrolled layer loop instead of lax.scan. The scan
     # carries the stacked weight GRADIENTS through its backward as
     # dynamic-update-slice'd buffers, which XLA partially re-copies per
     # iteration; unrolling removes that and measured +3% step throughput
-    # at 1B on v5e (855→806 ms with the bf16-MLP fix; round 5, old
-    # machine).
+    # at 1B on the old machine (855→806 ms; round 5; not measured on this
+    # repo's v5e: ROADMAP S8).
     # Cost: compile time grows with depth (~30 s at 16 layers) — the
     # right trade for long training runs, wrong for tests/CI, so scan
     # stays the default.
@@ -92,10 +122,10 @@ class LlamaConfig:
         # validate eagerly (not just when remat kicks in) so a typo'd
         # policy on a remat=False config cannot sit unnoticed until a
         # later remat=True run crashes at trace time
-        if self.remat_policy not in ("full", "save_qkv"):
+        if self.remat_policy not in REMAT_POLICIES:
             raise ValueError(
                 f"unknown remat_policy {self.remat_policy!r} "
-                "(full | save_qkv)")
+                f"({' | '.join(REMAT_POLICIES)})")
 
     @property
     def rope_scaling_dict(self):
@@ -189,6 +219,113 @@ def init_params(cfg: LlamaConfig, key: jax.Array) -> Dict[str, Any]:
     return params
 
 
+def remat_names(policy: str) -> Tuple[str, ...]:
+    """The checkpoint names a resolved ``remat_policy`` keeps."""
+    if policy == "save_qkv":
+        return REMAT_LADDER[1]
+    level = 0 if policy == "full" else int(policy[len("level"):])
+    return tuple(n for rung in REMAT_LADDER[:level] for n in rung)
+
+
+def remat_plan(cfg: LlamaConfig, tokens_per_device: int,
+               param_bytes_per_device: int, capacity_bytes: Optional[int],
+               params_sharded: bool) -> Dict[str, Any]:
+    """Which rung of REMAT_LADDER a train step of ``cfg`` gets: a pure
+    function of shapes and bytes, so the same inputs always give the same
+    program. ``remat_policy="auto"`` resolves to the richest level whose
+    reckoned need fits ``capacity_bytes * (1 - REMAT_RESERVE)``, and to
+    "full" where nothing richer fits or there is no capacity to read; any
+    other policy is returned as set, with its need reckoned beside it.
+
+    The need, per device: parameters and two moments of their dtype,
+    resident; in the step's heap, times REMAT_HEAP_FACTOR, the gradients,
+    under a parameter-sharding mesh the gathered weights in flight (two
+    layers, embedding and head, and their gradients before the
+    reduction), every layer's input, and the larger of one layer's
+    internals while its backward runs and the float32 logits with their
+    gradient; and for every layer what the level keeps."""
+    T, h, ffn = tokens_per_device, cfg.hidden_size, cfg.intermediate_size
+    qd = cfg.num_heads * cfg.head_dim_
+    kvd = cfg.num_kv_heads * cfg.head_dim_
+    act = jnp.dtype(cfg.dtype).itemsize
+    par = jnp.dtype(cfg.param_dtype).itemsize
+    rungs = (T * (qd * act + cfg.num_heads * 4),     # flash_lse is float32
+             T * (qd + 2 * kvd) * act,
+             2 * T * ffn * act,
+             T * h * act)
+    gathered = 0
+    if params_sharded:
+        layer_params = h * (qd + 2 * kvd) + qd * h + 3 * h * ffn
+        gathered = 2 * (2 * layer_params + 2 * cfg.vocab_size * h) * par
+    # a layer's backward holds its recomputed forward (norms, projections,
+    # attention, the three [T, ffn] arrays of the MLP) and the gradients
+    # of the widest of them
+    layer = T * act * (4 * h + 2 * qd + 2 * kvd + 5 * ffn)
+    logits = 2 * T * cfg.vocab_size * 4
+    heap = (gathered + cfg.num_layers * T * h * act
+            + max(param_bytes_per_device + layer, logits))
+    fixed = 3 * param_bytes_per_device + int(REMAT_HEAP_FACTOR * heap)
+
+    def saved(policy: str) -> int:
+        names = remat_names(policy)
+        return sum(b for rung, b in zip(REMAT_LADDER, rungs)
+                   if set(rung) <= set(names))
+
+    def need(policy: str) -> int:
+        return fixed + cfg.num_layers * saved(policy)
+
+    policy = cfg.remat_policy
+    if policy == "auto":
+        policy = "full"
+        if capacity_bytes:
+            budget = capacity_bytes * (1 - REMAT_RESERVE)
+            for n in range(len(REMAT_LADDER), 0, -1):
+                if need(f"level{n}") <= budget:
+                    policy = f"level{n}"
+                    break
+    return {"level": policy, "saved_bytes_per_layer": saved(policy),
+            "need_bytes": need(policy), "capacity_bytes": capacity_bytes,
+            "layers": cfg.num_layers}
+
+
+def _device_capacity(mesh) -> Optional[int]:
+    """The memory limit of one of this process's devices the program will
+    run on, where the backend reports one: the CPU reports none, and a
+    device that is described and not attached (a compile ahead of time)
+    refuses the question."""
+    dev = jax.devices()[0] if mesh is None else mesh.local_devices[0]
+    try:
+        return (dev.memory_stats() or {}).get("bytes_limit")
+    except jax.errors.JaxRuntimeError:
+        return None
+
+
+def _resolve_remat(cfg: LlamaConfig, params, tokens, mesh) -> str:
+    """The remat level of the program being traced, from its shapes: no
+    device work and no trial compile. The plan is one kept span, so an
+    operator reads in ``trace_spans.json`` and ``timeline()`` which level
+    a job got and why."""
+    total = sum(a.size * a.dtype.itemsize
+                for a in jax.tree_util.tree_leaves(params))
+    per_device, data_shards = total, 1
+    if mesh is not None:
+        from ray_tpu.parallel.sharding import resolve_axis
+
+        per_device = sum(jax.tree_util.tree_leaves(jax.tree_util.tree_map(
+            lambda a, sh: math.prod(sh.shard_shape(a.shape))
+            * a.dtype.itemsize, params, param_shardings(cfg, mesh))))
+        sizes = dict(mesh.shape)
+        for logical in ("batch", "seq"):
+            axes = resolve_axis(logical, mesh) or ()
+            for axis in (axes,) if isinstance(axes, str) else axes:
+                data_shards *= sizes[axis]
+    plan = remat_plan(cfg, -(-tokens.size // data_shards), per_device,
+                      _device_capacity(mesh), per_device < total)
+    with tracing.span("rtpu.train.remat_plan", keep=True, **plan):
+        pass
+    return plan["level"]
+
+
 def _attend(cfg: LlamaConfig, q, k, v, mesh=None, seq_axis=None):
     impl = cfg.attn_impl
     if impl == "auto":
@@ -264,12 +401,9 @@ def attention_block(cfg: LlamaConfig, x, p, cos, sin, mesh=None,
         v = v.reshape(b, s, cfg.num_kv_heads, hd)
         q = apply_rope(q, cos, sin)
         k = apply_rope(k, cos, sin)
-        # named for remat_policy="save_qkv" (no-ops otherwise): saving
-        # the post-rope projections lets the backward skip the qkv
-        # matmul+rope recompute — measured +4% step throughput at 1B for
-        # ~2.1 GB HBM
-        from jax.ad_checkpoint import checkpoint_name
-
+        # named for a remat policy that keeps them (REMAT_LADDER, and
+        # "save_qkv"; no-ops otherwise): the backward then skips the q/k/v
+        # matmuls and rope
         q = checkpoint_name(q, "q_rope")
         k = checkpoint_name(k, "k_rope")
         v = checkpoint_name(v, "v_proj")
@@ -280,7 +414,7 @@ def attention_block(cfg: LlamaConfig, x, p, cos, sin, mesh=None,
         attn_out = jnp.dot(
             attn, p["wo"].astype(cfg.dtype),
             preferred_element_type=jnp.float32).astype(cfg.dtype)
-        return x + attn_out
+        return checkpoint_name(x + attn_out, "attn_resid")
 
 
 def _layer(cfg: LlamaConfig, x, layer_params, cos, sin, mesh=None,
@@ -309,16 +443,20 @@ def forward(cfg: LlamaConfig, params: Dict[str, Any], tokens: jax.Array,
                                     scaling=cfg.rope_scaling_dict)
 
     layer_fn = lambda x_, p_: _layer(cfg, x_, p_, cos, sin, mesh=mesh)
+    ckpt_fn = layer_fn
     if cfg.remat:
-        # policy values are validated in __post_init__
-        if cfg.remat_policy == "save_qkv":
-            pol = jax.checkpoint_policies.save_only_these_names(
-                "q_rope", "k_rope", "v_proj")
-            ckpt_fn = jax.checkpoint(layer_fn, policy=pol)
-        else:
-            ckpt_fn = jax.checkpoint(layer_fn)
-    else:
-        ckpt_fn = layer_fn
+        level = _resolve_remat(cfg, params, tokens, mesh)
+        names = remat_names(level)
+        # Inside the scan the forward and the backward are two loops and
+        # XLA cannot merge a recomputation back into the forward, so a
+        # ladder level drops jax.checkpoint's barrier against that, as
+        # jax advises under scan: at 7B widths it cost a gigabyte of
+        # XLA's heap and 5% of the step (PERF.md 6, PR 27). "full" and
+        # "save_qkv" keep the programs they always had.
+        ckpt_fn = jax.checkpoint(
+            layer_fn, policy=jax.checkpoint_policies.save_only_these_names(
+                *names) if names else None,
+            prevent_cse=not (cfg.scan_layers and level.startswith("level")))
 
     def scan_ckpt(x_, p_):
         return ckpt_fn(x_, p_), None
@@ -411,11 +549,12 @@ def loss_fn_pp(cfg: LlamaConfig, params, batch: Dict[str, jax.Array],
             "remat_store_layers applies to the sequential forward only; "
             "under pipeline parallelism every stage is fully "
             "rematerialized (a silent no-op here would mislead tuning)")
-    if cfg.remat_policy != "full" or not cfg.scan_layers:
+    if cfg.remat_policy not in ("auto", "full") or not cfg.scan_layers:
         raise ValueError(
             "remat_policy/scan_layers are sequential-forward knobs; the "
-            "pipeline schedule always scans stages under full remat — "
-            "drop them rather than read tuning signal from a no-op")
+            "pipeline schedule always scans stages under full remat "
+            "(\"auto\" is \"full\" here) — drop them rather than read "
+            "tuning signal from a no-op")
     from jax.sharding import PartitionSpec as P
 
     # pp x sequence-parallel composition: pp OUTER (this shard_map), sp

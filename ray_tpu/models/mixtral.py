@@ -82,11 +82,12 @@ def without_layer_axis(axes):
 
 
 def refuse_dense_knobs(cfg: MixtralConfig) -> None:
-    if cfg.remat_policy != "full" or not cfg.scan_layers:
+    if cfg.remat_policy not in ("auto", "full") or not cfg.scan_layers:
         raise ValueError(
             "remat_policy/scan_layers are dense-Llama knobs; the MoE "
-            "forward always scans under full remat — drop them rather "
-            "than read tuning signal from a no-op")
+            "forward always scans under full remat (\"auto\" is \"full\" "
+            "here) — drop them rather than read tuning signal from a "
+            "no-op")
 
 
 def init_params(cfg: MixtralConfig, key: jax.Array) -> Dict[str, Any]:

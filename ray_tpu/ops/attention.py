@@ -22,6 +22,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from ray_tpu.ops.layers import repeat_kv
 
@@ -412,13 +413,22 @@ def _flash(q, k, v, causal, sm_scale, block_q, block_k, interpret):
 def _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret):
     out, lse = _flash_forward(q, k, v, causal, sm_scale, block_q, block_k,
                               interpret)
+    # Named where they are born, for a layer's remat policy
+    # (models/llama.py REMAT_LADDER): a policy that keeps both drops the
+    # backward's second run of the forward kernel; a name given outside
+    # this rule would miss the residual. Inert under a plain
+    # jax.checkpoint and outside one. The logsumexp is kept without its
+    # singleton lane dim: as a saved [.., sq, 1] array XLA may tile it to
+    # 128 lanes, 128 times its bytes.
+    out = checkpoint_name(out, "flash_out")
+    lse = checkpoint_name(lse[..., 0], "flash_lse")
     return out, (q, k, v, out, lse)
 
 
 def _flash_bwd(causal, sm_scale, block_q, block_k, interpret, res, g):
     q, k, v, out, lse = res
-    return _flash_backward(q, k, v, out, lse, g, causal, sm_scale, block_q,
-                           block_k, interpret)
+    return _flash_backward(q, k, v, out, lse[..., None], g, causal,
+                           sm_scale, block_q, block_k, interpret)
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)

@@ -16,6 +16,7 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 
 def rms_norm(x: jax.Array, weight: jax.Array, eps: float = 1e-6) -> jax.Array:
@@ -177,10 +178,17 @@ def swiglu(x: jax.Array, w_gate: jax.Array, w_up: jax.Array,
     # 1B train step on v5e (profile: three f32[8,2048,5504] fusions per
     # layer). The activation itself is bounded, so bf16 is safe — and
     # XLA folds the convert into the matmul epilogue.
-    gate = jnp.dot(x, w_gate,
-                   preferred_element_type=jnp.float32).astype(x.dtype)
-    up = jnp.dot(x, w_up,
-                 preferred_element_type=jnp.float32).astype(x.dtype)
+    # The two products are named for a layer's remat policy
+    # (models/llama.py REMAT_LADDER; inert elsewhere): kept, the backward
+    # recomputes act(gate) * up from them and neither matmul.
+    gate = checkpoint_name(
+        jnp.dot(x, w_gate,
+                preferred_element_type=jnp.float32).astype(x.dtype),
+        "mlp_gate")
+    up = checkpoint_name(
+        jnp.dot(x, w_up,
+                preferred_element_type=jnp.float32).astype(x.dtype),
+        "mlp_up")
     h = act_fn(gate) * up
     return jnp.dot(h, w_down, preferred_element_type=jnp.float32).astype(x.dtype)
 

@@ -605,39 +605,192 @@ def test_partial_remat_matches_full_remat():
                for a, b in zip(flat_f, flat_p))
 
 
-def test_unrolled_and_save_qkv_match_scan_full_remat():
-    """The round-5 MFU knobs (scan_layers=False unrolled layer loop,
-    remat_policy="save_qkv" keeping post-rope projections) change the
-    schedule, not the math: loss AND grads match the scan + full-remat
-    baseline."""
-    import jax
-    import jax.numpy as jnp
+def _count_primitives(jaxpr, counts=None):
+    """Primitive name -> occurrences, through every sub-jaxpr but a Pallas
+    kernel's body (``pallas_call`` counts as one, under its own name)."""
+    from jax.extend import core as jex_core
 
-    from ray_tpu.models import llama
+    counts = {} if counts is None else counts
+    for eqn in jaxpr.eqns:
+        name = eqn.primitive.name
+        if name == "pallas_call":
+            name = "pallas_call:" + eqn.params["name"]
+            counts[name] = counts.get(name, 0) + 1
+            continue
+        counts[name] = counts.get(name, 0) + 1
+        for v in eqn.params.values():
+            for sub in v if isinstance(v, (list, tuple)) else (v,):
+                if isinstance(sub, jex_core.ClosedJaxpr):
+                    sub = sub.jaxpr
+                if isinstance(sub, jex_core.Jaxpr):
+                    _count_primitives(sub, counts)
+    return counts
+
+
+@pytest.fixture(scope="module")
+def remat_setup():
+    """Tiny llama and its batch; ``grads(attn, **cfg)`` gives loss and
+    gradients jitted, ``loss_of`` the loss to trace. ``attn="flash"``
+    runs the flash kernel through the Pallas interpreter, so that
+    ``flash_out`` / ``flash_lse`` exist to be kept."""
+    import functools
+
+    from ray_tpu.ops.attention import flash_attention
 
     tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 33), 0, 256)
-    cfg_base = llama.LlamaConfig.tiny(remat=True)
-    cfg_fast = llama.LlamaConfig.tiny(remat=True, scan_layers=False,
-                                      remat_policy="save_qkv")
-    params = llama.init_params(cfg_base, jax.random.PRNGKey(0))
+    params = llama.init_params(llama.LlamaConfig.tiny(),
+                               jax.random.PRNGKey(0))
+    interpreted = functools.partial(flash_attention, use_pallas=True,
+                                    interpret=True, block_q=32, block_k=32)
 
-    def lg(cfg):
-        return jax.jit(jax.value_and_grad(
-            lambda p: llama.loss_fn(cfg, p, {"tokens": tokens})))(params)
+    def loss_of(attn, **kw):
+        cfg = llama.LlamaConfig.tiny(attn_impl=attn, **kw)
+        return lambda p: llama.loss_fn(cfg, p, {"tokens": tokens})
 
-    l_base, g_base = lg(cfg_base)
-    l_fast, g_fast = lg(cfg_fast)
-    assert jnp.allclose(l_base, l_fast, atol=1e-6)
+    def traced(fn):
+        with pytest.MonkeyPatch.context() as mp:
+            mp.setattr(llama, "flash_attention", interpreted)
+            return fn(params)
+
+    want = {attn: traced(jax.jit(jax.value_and_grad(
+        loss_of(attn, remat=False)))) for attn in ("reference", "flash")}
+    return loss_of, traced, want
+
+
+@pytest.mark.parametrize("attn", ["reference", "flash"])
+@pytest.mark.parametrize("scan_layers", [True, False])
+@pytest.mark.parametrize("policy", ["full", "save_qkv", "level1", "level2",
+                                    "level3", "level4"])
+def test_every_remat_level_matches_no_remat(remat_setup, policy,
+                                            scan_layers, attn):
+    """What a layer's checkpoint keeps (``remat_policy``) and how the
+    layers are looped (``scan_layers``) change the schedule, not the
+    math: loss AND gradients equal ``remat=False``."""
+    loss_of, traced, want = remat_setup
+    l_got, g_got = traced(jax.jit(jax.value_and_grad(loss_of(
+        attn, remat=True, remat_policy=policy, scan_layers=scan_layers))))
+    l_want, g_want = want[attn]
+    assert jnp.allclose(l_want, l_got, atol=1e-6)
     assert all(jnp.allclose(a, b, atol=1e-5)
-               for a, b in zip(jax.tree_util.tree_leaves(g_base),
-                               jax.tree_util.tree_leaves(g_fast)))
-    # bad policy name raises rather than silently training differently
-    import pytest
+               for a, b in zip(jax.tree_util.tree_leaves(g_want),
+                               jax.tree_util.tree_leaves(g_got)))
 
-    with pytest.raises(ValueError):
-        llama.loss_fn(
-            llama.LlamaConfig.tiny(remat=True, remat_policy="nope"),
-            params, {"tokens": tokens})
+
+def test_richest_remat_level_recomputes_no_matmul_and_no_flash(remat_setup):
+    """The gradient's jaxpr, counted: under "full" every layer's backward
+    runs the six projections (q, k, v, wo, gate, up) and the flash forward
+    a second time; "level4" runs none of them again, "level1" only drops
+    the kernel, "save_qkv" drops three matmuls and still runs the kernel
+    twice (nothing it keeps is the kernel's residual)."""
+    loss_of, traced, _ = remat_setup
+    layers = llama.LlamaConfig.tiny().num_layers
+
+    def counts(policy):
+        c = _count_primitives(traced(jax.make_jaxpr(jax.grad(loss_of(
+            "flash", remat=True, remat_policy=policy,
+            scan_layers=False)))).jaxpr)
+        return c["dot_general"], c["pallas_call:flash_fwd"]
+
+    dots_full, fwd_full = counts("full")
+    assert fwd_full == 2 * layers
+    assert counts("level1") == (dots_full, layers)
+    assert counts("save_qkv") == (dots_full - 3 * layers, 2 * layers)
+    assert counts("level2") == (dots_full - 3 * layers, layers)
+    assert counts("level3") == (dots_full - 5 * layers, layers)
+    assert counts("level4") == (dots_full - 6 * layers, layers)
+
+
+# Mistral-7B-v0.3's widths as the benchmark's dense cells train them
+# (bf16 parameters and moments, 2 x 4,096 tokens a device), and a v5e
+# chip's ``bytes_limit``
+_MISTRAL = dict(vocab_size=32768, hidden_size=4096, intermediate_size=14336,
+                num_heads=32, num_kv_heads=8, head_dim=128,
+                param_dtype=jnp.bfloat16)
+_V5E_LIMIT = int(15.75 * 2 ** 30)
+
+
+def _param_bytes(cfg):
+    return sum(a.size * a.dtype.itemsize for a in
+               jax.tree_util.tree_leaves(llama.init_shapes(cfg)))
+
+
+def test_remat_plan_is_a_pure_function_of_bytes():
+    c1 = llama.LlamaConfig(num_layers=4, **_MISTRAL)
+    c4 = llama.LlamaConfig(num_layers=16, **_MISTRAL)
+    b1, b4 = _param_bytes(c1), _param_bytes(c4) // 4
+    levels = ["full"] + [f"level{n}" for n in range(1, 5)]
+    rank = levels.index
+
+    def level(cfg, tokens=8192, par=b1, cap=_V5E_LIMIT, sharded=False):
+        return llama.remat_plan(cfg, tokens, par, cap, sharded)["level"]
+
+    # what PERF.md says the cells get: train-1chip, train-fsdp4
+    plan = llama.remat_plan(c1, 8192, b1, _V5E_LIMIT, False)
+    assert plan["level"] == "level4" and plan["layers"] == 4
+    assert plan["saved_bytes_per_layer"] == 8192 * (
+        2 * (4096 + 4096 + 2 * 1024 + 2 * 14336 + 4096) + 32 * 4)
+    assert plan["need_bytes"] <= 0.95 * _V5E_LIMIT == \
+        (1 - llama.REMAT_RESERVE) * plan["capacity_bytes"]
+    assert level(c4, par=b4, sharded=True) == "full"
+    # no capacity to read (the CPU): nothing changes
+    assert level(c1, cap=None) == "full"
+    # more room never gives a poorer level; every level is reached
+    caps = [int(g * 1e9) for g in np.arange(11.0, 18.0, 0.125)]
+    got = [rank(level(c1, cap=c)) for c in caps]
+    assert got == sorted(got) and set(got) == set(range(5))
+    # more layers, tokens or resident bytes never give a richer one
+    for grow in (
+            [dict(cfg=llama.LlamaConfig(num_layers=n, **_MISTRAL),
+                  par=_param_bytes(llama.LlamaConfig(num_layers=n,
+                                                     **_MISTRAL)))
+             for n in (2, 3, 4, 5, 6)],
+            [dict(cfg=c1, tokens=t) for t in (2048, 4096, 8192, 12288,
+                                              16384)],
+            [dict(cfg=c1, sharded=s) for s in (False, True)]):
+        got = [rank(level(**kw)) for kw in grow]
+        assert got == sorted(got, reverse=True), got
+    # a policy somebody set is never overridden, whatever the room
+    for policy in ("full", "save_qkv", "level2"):
+        for cap in (None, 10 ** 9, 10 ** 12):
+            cfg = llama.LlamaConfig(num_layers=4, remat_policy=policy,
+                                    **_MISTRAL)
+            assert level(cfg, cap=cap) == policy
+    assert llama.remat_names("save_qkv") == ("q_rope", "k_rope", "v_proj")
+    assert llama.remat_names("full") == ()
+    assert llama.remat_names("level4")[:2] == ("flash_out", "flash_lse")
+    # bad policy name raises rather than silently training differently
+    with pytest.raises(ValueError, match="nope"):
+        llama.LlamaConfig.tiny(remat=True, remat_policy="nope")
+
+
+@pytest.mark.parametrize("layers,fsdp,want", [(4, None, "level4"),
+                                              (16, 4, "full")])
+def test_forward_resolves_the_plan_from_the_shapes_it_traces(
+        monkeypatch, layers, fsdp, want):
+    """``forward`` at the dense cells' real shapes, traced and never run:
+    tokens and parameter bytes per device come from the traced shapes and
+    ``param_shardings``, and the plan is one kept span."""
+    from ray_tpu.util import tracing
+
+    monkeypatch.setattr(llama, "_device_capacity", lambda mesh: _V5E_LIMIT)
+    cfg = llama.LlamaConfig(num_layers=layers, attn_impl="reference",
+                            **_MISTRAL)
+    mesh = build_mesh(MeshSpec({"fsdp": fsdp}),
+                      devices=jax.devices()[:fsdp]) if fsdp else None
+    tokens = jax.ShapeDtypeStruct((2 * (fsdp or 1), 4096), jnp.int32)
+    n0 = len(tracing.chrome_events())
+    out = jax.eval_shape(lambda p, t: llama.forward(cfg, p, t, mesh=mesh),
+                         llama.init_shapes(cfg), tokens)
+    assert out.shape == tokens.shape + (cfg.vocab_size,)
+    spans = [e for e in tracing.chrome_events()[n0:]
+             if e["name"] == "rtpu.train.remat_plan"]
+    assert len(spans) == 1
+    # fsdp shards every parameter, the norms' vectors too
+    per_device = _param_bytes(cfg) // (fsdp or 1)
+    assert spans[0]["args"] == {
+        "id": None, "parent": None, "self_us": spans[0]["args"]["self_us"],
+        **llama.remat_plan(cfg, 8192, per_device, _V5E_LIMIT, bool(fsdp))}
+    assert spans[0]["args"]["level"] == want
 
 
 def test_qwen2_hf_checkpoint_parity():

@@ -73,3 +73,56 @@ def test_routed_experts_compile_at_olmoe_widths(one_chip, no_compile_cache,
     assert all("moe_experts" in c for c in calls)
     # rows, gate, up, activation, their cotangents: a few n*K-row arrays
     assert compiled.memory_analysis().temp_size_in_bytes < 3 << 30
+
+
+def test_remat_plan_holds_against_the_compiler_at_7b_widths(
+        one_chip, no_compile_cache, monkeypatch):
+    """The adamw train step the benchmark's one-chip dense cell runs
+    (Mistral-7B widths, 4 layers, 2 x 4,096 tokens, bf16 state), compiled
+    with the level ``remat_policy="auto"`` resolves for a v5e's memory:
+    what the plan reckons is not under what the compiler allots and
+    within a tenth of it, and the program leaves the plan's reserve free.
+    The richest level keeps the flash kernel's residuals, so the forward
+    kernel is called once (three Mosaic calls, not four)."""
+    import optax
+
+    from ray_tpu.models import llama
+    from ray_tpu.util import tracing
+
+    limit = int(15.75 * 2 ** 30)          # a v5e chip's bytes_limit
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(llama, "_device_capacity", lambda mesh: limit)
+    cfg = llama.LlamaConfig(
+        vocab_size=32768, hidden_size=4096, intermediate_size=14336,
+        num_layers=4, num_heads=32, num_kv_heads=8, head_dim=128,
+        rope_theta=1e6, param_dtype=jnp.bfloat16)
+
+    def placed(tree):
+        return jax.tree_util.tree_map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    tx = optax.adamw(1e-4)
+    params = llama.init_shapes(cfg)
+    opt = jax.eval_shape(tx.init, params)
+    batch = {"tokens": jax.ShapeDtypeStruct((2, 4097), jnp.int32,
+                                            sharding=one_chip)}
+
+    def step(params, opt, batch):
+        loss, grads = jax.value_and_grad(
+            lambda p: llama.loss_fn(cfg, p, batch))(params)
+        updates, opt = tx.update(grads, opt, params)
+        return optax.apply_updates(params, updates), opt, loss
+
+    n0 = len(tracing.chrome_events())
+    compiled = jax.jit(step, donate_argnums=(0, 1)).lower(
+        placed(params), placed(opt), batch).compile()
+    (plan,) = [e["args"] for e in tracing.chrome_events()[n0:]
+               if e["name"] == "rtpu.train.remat_plan"]
+    assert plan["level"] == "level4"
+    ma = compiled.memory_analysis()
+    allotted = ma.argument_size_in_bytes + ma.temp_size_in_bytes \
+        + ma.output_size_in_bytes - ma.alias_size_in_bytes
+    assert allotted <= plan["need_bytes"] <= 1.1 * allotted, (
+        allotted, plan["need_bytes"])
+    assert allotted <= (1 - llama.REMAT_RESERVE) * limit
+    assert compiled.as_text().count("tpu_custom_call") == 3

@@ -342,15 +342,23 @@ def test_train_session_serves_the_last_reported_moe_counters():
     assert "rtpu_train_reports 3\n" in text
 
 
-def _train_step_text(scoped: bool) -> str:
+def _train_step_text(scoped: bool = True, model: str = "llama",
+                     **cfg_kw) -> str:
+    """The optimized HLO of a tiny adamw train step, compiled for shapes
+    alone."""
     import jax
     import jax.numpy as jnp
     import optax
 
-    from ray_tpu.models import llama
+    from ray_tpu.models import llama, olmoe
 
-    cfg = llama.LlamaConfig.tiny(vocab_size=128, attn_impl="reference")
-    params = jax.eval_shape(lambda k: llama.init_params(cfg, k),
+    if model == "olmoe":
+        mod, cfg = olmoe, olmoe.OlmoeConfig.tiny(
+            vocab_size=128, attn_impl="reference", **cfg_kw)
+    else:
+        mod, cfg = llama, llama.LlamaConfig.tiny(
+            vocab_size=128, attn_impl="reference", **cfg_kw)
+    params = jax.eval_shape(lambda k: mod.init_params(cfg, k),
                             jax.random.PRNGKey(0))
     tx = optax.adamw(1e-3)
     opt = jax.eval_shape(tx.init, params)
@@ -358,7 +366,7 @@ def _train_step_text(scoped: bool) -> str:
 
     def step(params, opt, batch):
         loss, grads = jax.value_and_grad(
-            lambda p: llama.loss_fn(cfg, p, batch))(params)
+            lambda p: mod.loss_fn(cfg, p, batch))(params)
         updates, opt = tx.update(grads, opt, params)
         return optax.apply_updates(params, updates), opt, loss
 
@@ -366,11 +374,30 @@ def _train_step_text(scoped: bool) -> str:
     saved = jax.named_scope
     if not scoped:
         jax.named_scope = lambda name: plain
+    # as ensure_compile_cache sets it: without it the run's persistent
+    # cache hands the unscoped step the scoped one's program, whenever
+    # the compile was slow enough to be cached
+    key = "jax_compilation_cache_include_metadata_in_key"
+    saved_key = getattr(jax.config, key)
+    jax.config.update(key, True)
     try:
         return jax.jit(step, donate_argnums=(0, 1)).lower(
             params, opt, batch).compile().as_text()
     finally:
         jax.named_scope = saved
+        jax.config.update(key, saved_key)
+
+
+def _strip(text):
+    # an instruction's metadata, and the module's tables of source
+    # files and stack frames that the metadata points into
+    text = re.sub(r", metadata=\{[^}]*\}", "", text)
+    return re.sub(r"\nFileNames\n.*?\nStackFrames\n.*?\n\n", "\n",
+                  text, flags=re.S)
+
+
+def _instructions(text):
+    return len(re.findall(r"^\s+(?:ROOT )?%?[\w.\-]+ = ", text, re.M))
 
 
 def test_named_scopes_change_no_instruction_of_the_train_step():
@@ -379,19 +406,93 @@ def test_named_scopes_change_no_instruction_of_the_train_step():
                   "head_loss"):
         assert re.search(rf'op_name="[^"]*[/(]{scope}[/)]', with_scopes), scope
     assert not re.search(r'op_name="[^"]*/mlp/', without)
-
-
-    def strip(text):
-        # an instruction's metadata, and the module's tables of source
-        # files and stack frames that the metadata points into
-        text = re.sub(r", metadata=\{[^}]*\}", "", text)
-        return re.sub(r"\nFileNames\n.*?\nStackFrames\n.*?\n\n", "\n",
-                      text, flags=re.S)
-
-    assert strip(with_scopes) == strip(without)
-    assert len(re.findall(r"^\s+(?:ROOT )?%?[\w.\-]+ = ", strip(with_scopes),
-                          re.M)) > 200          # instructions are left
+    assert _strip(with_scopes) == _strip(without)
+    assert _instructions(_strip(with_scopes)) > 200   # instructions are left
     assert with_scopes != without
+
+
+@pytest.mark.parametrize("model", ["llama", "olmoe"])
+def test_a_plan_of_full_is_the_program_of_explicit_full(model):
+    """``remat_policy="auto"`` where the device reports no memory limit
+    (here), and in a forward that has no ladder (OLMoE's), is "full": the
+    checkpoint names are inert and the step compiles to the text of the
+    explicit policy. A kept level is another program."""
+    auto = _strip(_train_step_text(model=model, remat=True))
+    assert auto == _strip(_train_step_text(model=model, remat=True,
+                                           remat_policy="full"))
+    assert _instructions(auto) > 200
+    if model == "llama":
+        assert auto != _strip(_train_step_text(remat=True,
+                                               remat_policy="level4"))
+
+
+def test_remat_auto_passes_where_a_set_policy_is_refused():
+    """The MoE forwards and the pipeline schedule run full remat and
+    refuse a dense-forward knob somebody set; the new default is not
+    one."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.models import llama, mixtral, olmoe
+    from ray_tpu.parallel import MeshSpec, build_mesh
+
+    for cfg in (mixtral.MixtralConfig.tiny(), olmoe.OlmoeConfig.tiny(),
+                olmoe.OlmoeConfig.olmoe_1b_7b(),
+                mixtral.MixtralConfig.tiny(remat_policy="full")):
+        assert cfg.remat_policy in ("auto", "full")
+        mixtral.refuse_dense_knobs(cfg)
+    for policy in ("save_qkv", "level1", "level4"):
+        with pytest.raises(ValueError, match="dense-Llama knobs"):
+            mixtral.refuse_dense_knobs(
+                olmoe.OlmoeConfig.tiny(remat_policy=policy))
+    cfg = llama.LlamaConfig.tiny(attn_impl="reference", remat=True)
+    assert cfg.remat_policy == "auto"
+    mesh = build_mesh(MeshSpec({"pp": 2}), devices=jax.devices()[:2])
+    batch = {"tokens": jax.ShapeDtypeStruct((4, 33), jnp.int32)}
+    n0 = len(_mine("rtpu.train.remat_plan"))
+    loss = jax.eval_shape(
+        lambda p, b: llama.loss_fn_pp(cfg, p, b, mesh, 2),
+        llama.init_shapes(cfg), batch)
+    assert loss.shape == ()
+    assert len(_mine("rtpu.train.remat_plan")) == n0   # no plan to report
+    for policy in ("save_qkv", "level3"):
+        with pytest.raises(ValueError, match="sequential-forward knobs"):
+            llama.loss_fn_pp(llama.LlamaConfig.tiny(remat_policy=policy),
+                             None, batch, mesh, 2)
+
+
+def test_remat_plan_is_one_kept_span_of_a_traced_program():
+    """Tracing a dense train step writes its remat plan once, as a kept
+    span (no flag, no profiler window), with what it chose and why."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.models import llama
+
+    assert not config.task_events_enabled
+    batch = {"tokens": jax.ShapeDtypeStruct((2, 33), jnp.int32)}
+
+    def trace(cfg):
+        n0 = len(_mine("rtpu.train.remat_plan"))
+        jax.eval_shape(jax.grad(lambda p, b: llama.loss_fn(cfg, p, b)),
+                       llama.init_shapes(cfg), batch)
+        return _mine("rtpu.train.remat_plan")[n0:]
+
+    assert trace(llama.LlamaConfig.tiny(attn_impl="reference")) == []
+    (ev,) = trace(llama.LlamaConfig.tiny(attn_impl="reference", remat=True))
+    args = dict(ev["args"])
+    need = args.pop("need_bytes")
+    assert args.pop("self_us") >= 0
+    assert args == {"id": None, "parent": None, "level": "full",
+                    "saved_bytes_per_layer": 0, "capacity_bytes": None,
+                    "layers": 2}
+    # at least the four copies of the parameters the train state holds
+    assert need > 4 * 4 * llama.num_params(llama.init_shapes(
+        llama.LlamaConfig.tiny()))
+    (ev,) = trace(llama.LlamaConfig.tiny(attn_impl="reference", remat=True,
+                                         remat_policy="level3"))
+    assert ev["args"]["level"] == "level3"
+    assert ev["args"]["saved_bytes_per_layer"] > 0
 
 
 def test_every_kernel_and_serving_program_has_a_name():
