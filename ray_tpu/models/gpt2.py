@@ -1,8 +1,8 @@
 """GPT-2 family (decoder-only, learned positions, LayerNorm, GELU MLP).
 
-Covers the reference north-star config "GPT-2-125M on wikitext-2"
-(BASELINE.json configs[0]). Same TPU-first structure as llama.py: stacked
-layers + lax.scan, logical axis names, bf16/fp32 mix, optional remat.
+Covers the reference north-star config "GPT-2-125M on wikitext-2". Same
+TPU-first structure as llama.py: stacked layers walked by its
+``run_layers``, logical axis names, bf16/fp32 mix, optional remat.
 """
 
 from __future__ import annotations
@@ -14,6 +14,7 @@ from typing import Any, Dict, Optional
 import jax
 import jax.numpy as jnp
 
+from ray_tpu.models.llama import cross_entropy_loss, run_layers
 from ray_tpu.ops.attention import attention_reference, flash_attention
 
 
@@ -150,20 +151,15 @@ def forward(cfg: GPT2Config, params, tokens: jax.Array) -> jax.Array:
     x = (params["wte"].astype(cfg.dtype)[tokens]
          + params["wpe"].astype(cfg.dtype)[:s][None])
 
-    layer_fn = lambda x_, p_: _layer(cfg, x_, p_)
-    if cfg.remat:
-        layer_fn = jax.checkpoint(layer_fn)
-
-    x, _ = jax.lax.scan(lambda x_, p_: (layer_fn(x_, p_), None),
-                        x, params["layers"])
+    x, _ = run_layers(lambda x_, p_: (_layer(cfg, x_, p_), None), x,
+                      params["layers"], level="full" if cfg.remat else None,
+                      scan=True)
     x = _layer_norm(x, params["lnf_g"], params["lnf_b"], cfg.ln_eps)
     return jnp.dot(x, params["wte"].T.astype(cfg.dtype),
                    preferred_element_type=jnp.float32)
 
 
 def loss_fn(cfg: GPT2Config, params, batch) -> jax.Array:
-    from ray_tpu.models.llama import cross_entropy_loss
-
     tokens = batch["tokens"]
     logits = forward(cfg, params, tokens[:, :-1])
     mask = batch.get("mask")

@@ -3,14 +3,18 @@
 Design (none of this exists in the reference — it delegates models to
 torch; this is the flagship model the north-star configs name):
 
-- plain-jax pytree params with *stacked* layers and a ``lax.scan`` over the
-  stack: one layer traced/compiled once regardless of depth.
+- plain-jax pytree params with *stacked* layers, walked by ``run_layers``
+  (the one loop of the family: llama, mixtral, olmoe and the pipeline
+  stage): a ``lax.scan`` over the stack, one layer traced/compiled once
+  regardless of depth, or unrolled where ``scan_layers=False``.
 - every parameter carries logical axis names (parallel/sharding.py) so the
   same model runs dp/fsdp/tp/sp by choosing a mesh; no model code changes.
 - bf16 params/activations with fp32 accumulations (preferred_element_type)
   — MXU-native.
-- ``jax.checkpoint`` around each layer (rematerialization: HBM traded for
-  FLOPs on the backward pass).
+- rematerialization: ``run_layers`` puts one ``jax.checkpoint`` around
+  each layer, keeping what its level names (``REMAT_LADDER``). The dense
+  forward's level comes from bytes (``remat_plan``: the richest rung that
+  fits the device's memory); a forward without a plan runs "full".
 - attention backend switch: "flash" (Pallas), "reference" (XLA), "ring"
   (sequence-parallel over the sp axis, KV blocks rotating on the ICI
   ring), "ulysses" (sequence-parallel via all-to-all head re-sharding).
@@ -43,7 +47,7 @@ REMAT_LADDER = (
     ("mlp_gate", "mlp_up"),             # the gate and up matmuls
     ("attn_resid",),                    # the wo matmul
 )
-REMAT_POLICIES = ("auto", "full", "save_qkv") + tuple(
+REMAT_POLICIES = ("auto", "full") + tuple(
     f"level{n}" for n in range(1, len(REMAT_LADDER) + 1))
 # remat_plan's two constants, calibrated against the TPU compiler
 # (PERF.md 6, PR 27: 33 step programs compiled for a v5e, two to sixteen
@@ -86,24 +90,16 @@ class LlamaConfig:
     # cannot be auto-partitioned like plain XLA ops.
     prefill_flash: Optional[bool] = None
     remat: bool = True
-    # partial remat: this many TRAILING layers store activations instead
-    # of recomputing (HBM for FLOPs; 0 = classic full per-layer remat).
-    # Caveats: the head/tail split slices the stacked layer params, which
-    # XLA may materialize as a duplicate of the stack — budget for it;
-    # measured neutral-to-NEGATIVE at 1B on the old machine (round 4; not
-    # measured on this repo's v5e), aimed at HBM-rich parts; sequential
-    # forward only (pp raises).
-    remat_store_layers: int = 0
     # What a layer's jax.checkpoint keeps for its backward. "full": the
     # layer's input alone, the whole forward runs again. "level1" ..
     # "level4": the names of REMAT_LADDER's first n rungs besides.
     # "auto" (the default): the richest of those that remat_plan reckons
     # to fit the device's memory, "full" where the device reports none
-    # (the CPU). "save_qkv": the post-rope q/k/v projections alone (rung 2
-    # without rung 1; b*s*(H+2*KVH)*hd*2 bytes a layer), kept for whoever
-    # set it.
+    # (the CPU) and in a forward that has no plan (Mixtral, OLMoE, the
+    # pipeline schedule: remat_level_without_plan).
     remat_policy: str = "auto"
-    # False = python-unrolled layer loop instead of lax.scan. The scan
+    # False = python-unrolled layer loop instead of lax.scan, in every
+    # forward of the family (run_layers honours it). The scan
     # carries the stacked weight GRADIENTS through its backward as
     # dynamic-update-slice'd buffers, which XLA partially re-copies per
     # iteration; unrolling removes that and measured +3% step throughput
@@ -221,8 +217,6 @@ def init_params(cfg: LlamaConfig, key: jax.Array) -> Dict[str, Any]:
 
 def remat_names(policy: str) -> Tuple[str, ...]:
     """The checkpoint names a resolved ``remat_policy`` keeps."""
-    if policy == "save_qkv":
-        return REMAT_LADDER[1]
     level = 0 if policy == "full" else int(policy[len("level"):])
     return tuple(n for rung in REMAT_LADDER[:level] for n in rung)
 
@@ -401,9 +395,8 @@ def attention_block(cfg: LlamaConfig, x, p, cos, sin, mesh=None,
         v = v.reshape(b, s, cfg.num_kv_heads, hd)
         q = apply_rope(q, cos, sin)
         k = apply_rope(k, cos, sin)
-        # named for a remat policy that keeps them (REMAT_LADDER, and
-        # "save_qkv"; no-ops otherwise): the backward then skips the q/k/v
-        # matmuls and rope
+        # named for a remat level that keeps them (REMAT_LADDER; no-ops
+        # otherwise): the backward then skips the q/k/v matmuls and rope
         q = checkpoint_name(q, "q_rope")
         k = checkpoint_name(k, "k_rope")
         v = checkpoint_name(v, "v_proj")
@@ -431,6 +424,47 @@ def _layer(cfg: LlamaConfig, x, layer_params, cos, sin, mesh=None,
         return x + mlp
 
 
+def remat_level_without_plan(cfg: LlamaConfig) -> Optional[str]:
+    """The level ``run_layers`` gets from a forward that has no plan for
+    its memory (Mixtral, OLMoE, the pipeline stage): "full" under
+    ``cfg.remat``. A ladder level somebody set would be a silent no-op
+    there, so it is refused."""
+    if cfg.remat_policy not in ("auto", "full"):
+        raise ValueError(
+            f"remat_policy={cfg.remat_policy!r} is a level of the dense "
+            "forward's ladder; this forward has no plan and runs full remat "
+            "(\"auto\" is \"full\" here) - drop it rather than read "
+            "tuning signal from a no-op")
+    return "full" if cfg.remat else None
+
+
+def run_layers(layer_fn, x, layers, *, level: Optional[str], scan: bool):
+    """The family's one loop over the stacked ``layers`` and its one
+    ``jax.checkpoint``. ``layer_fn(x, p) -> (x, y)`` is one block (``y``
+    may be None); returns the last ``x`` and the ``y``s stacked, as
+    ``lax.scan`` does. ``level``: None (no remat), "full" or a level of
+    REMAT_LADDER. ``scan``: ``cfg.scan_layers``."""
+    if level is not None:
+        names = remat_names(level)
+        # Inside the scan the forward and the backward are two loops and
+        # XLA cannot merge a recomputation back into the forward, so a
+        # ladder level drops jax.checkpoint's barrier against that, as
+        # jax advises under scan: at 7B widths it cost a gigabyte of
+        # XLA's heap and 5% of the step (PERF.md 6, PR 27). "full" keeps
+        # the program it always had.
+        layer_fn = jax.checkpoint(
+            layer_fn, policy=jax.checkpoint_policies.save_only_these_names(
+                *names) if names else None,
+            prevent_cse=not (scan and level.startswith("level")))
+    if scan:
+        return jax.lax.scan(layer_fn, x, layers)
+    ys = []
+    for l in range(jax.tree_util.tree_leaves(layers)[0].shape[0]):
+        x, y = layer_fn(x, jax.tree_util.tree_map(lambda a: a[l], layers))
+        ys.append(y)
+    return x, jax.tree_util.tree_map(lambda *a: jnp.stack(a), *ys)
+
+
 def forward(cfg: LlamaConfig, params: Dict[str, Any], tokens: jax.Array,
             mesh=None) -> jax.Array:
     """tokens [b, s] int32 → logits [b, s, vocab] float32."""
@@ -441,55 +475,10 @@ def forward(cfg: LlamaConfig, params: Dict[str, Any], tokens: jax.Array,
         cos, sin = rope_frequencies(cfg.head_dim_, tokens.shape[1],
                                     cfg.rope_theta, dtype=cfg.dtype,
                                     scaling=cfg.rope_scaling_dict)
-
-    layer_fn = lambda x_, p_: _layer(cfg, x_, p_, cos, sin, mesh=mesh)
-    ckpt_fn = layer_fn
-    if cfg.remat:
-        level = _resolve_remat(cfg, params, tokens, mesh)
-        names = remat_names(level)
-        # Inside the scan the forward and the backward are two loops and
-        # XLA cannot merge a recomputation back into the forward, so a
-        # ladder level drops jax.checkpoint's barrier against that, as
-        # jax advises under scan: at 7B widths it cost a gigabyte of
-        # XLA's heap and 5% of the step (PERF.md 6, PR 27). "full" and
-        # "save_qkv" keep the programs they always had.
-        ckpt_fn = jax.checkpoint(
-            layer_fn, policy=jax.checkpoint_policies.save_only_these_names(
-                *names) if names else None,
-            prevent_cse=not (cfg.scan_layers and level.startswith("level")))
-
-    def scan_ckpt(x_, p_):
-        return ckpt_fn(x_, p_), None
-
-    n_store = min(cfg.remat_store_layers, cfg.num_layers) \
-        if cfg.remat else 0
-    if not cfg.scan_layers:
-        if n_store > 0:
-            raise ValueError(
-                "scan_layers=False and remat_store_layers>0 conflict: "
-                "partial remat is a scan-path knob (a silent fallback "
-                "to scan would reintroduce the stacked-gradient "
-                "re-copies unrolling opts out of)")
-        # unrolled layer loop (see scan_layers in LlamaConfig)
-        for l in range(cfg.num_layers):
-            pl = jax.tree_util.tree_map(lambda a: a[l], params["layers"])
-            x = ckpt_fn(x, pl)
-    elif n_store <= 0:
-        x, _ = jax.lax.scan(scan_ckpt, x, params["layers"])
-    else:
-        # Partial remat: the LAST n_store layers keep their internal
-        # activations (no recompute in their backward) — recompute cost
-        # drops by n_store/num_layers of a forward pass, paid in HBM.
-        # Late layers are the right ones to store: their recompute would
-        # otherwise sit on the critical path at the START of backward.
-        split = cfg.num_layers - n_store
-        head = jax.tree_util.tree_map(lambda a: a[:split],
-                                      params["layers"])
-        tail = jax.tree_util.tree_map(lambda a: a[split:],
-                                      params["layers"])
-        x, _ = jax.lax.scan(scan_ckpt, x, head)
-        x, _ = jax.lax.scan(lambda x_, p_: (layer_fn(x_, p_), None),
-                            x, tail)
+    level = _resolve_remat(cfg, params, tokens, mesh) if cfg.remat else None
+    x, _ = run_layers(
+        lambda x_, p_: (_layer(cfg, x_, p_, cos, sin, mesh=mesh), None),
+        x, params["layers"], level=level, scan=cfg.scan_layers)
     return _final_head(cfg, params, x)
 
 
@@ -544,17 +533,7 @@ def loss_fn_pp(cfg: LlamaConfig, params, batch: Dict[str, jax.Array],
     usual rules); only the decoder blocks pipeline. num_microbatches must
     divide the batch and should be >> pp to amortize the bubble.
     """
-    if cfg.remat_store_layers:
-        raise ValueError(
-            "remat_store_layers applies to the sequential forward only; "
-            "under pipeline parallelism every stage is fully "
-            "rematerialized (a silent no-op here would mislead tuning)")
-    if cfg.remat_policy not in ("auto", "full") or not cfg.scan_layers:
-        raise ValueError(
-            "remat_policy/scan_layers are sequential-forward knobs; the "
-            "pipeline schedule always scans stages under full remat "
-            "(\"auto\" is \"full\" here) — drop them rather than read "
-            "tuning signal from a no-op")
+    level = remat_level_without_plan(cfg)
     from jax.sharding import PartitionSpec as P
 
     # pp x sequence-parallel composition: pp OUTER (this shard_map), sp
@@ -593,19 +572,13 @@ def loss_fn_pp(cfg: LlamaConfig, params, batch: Dict[str, jax.Array],
             f"sequence length {s} must be divisible by the mesh's "
             f"sp={sp}")
 
-    def layer_fn(x_, p_, cos_, sin_):
-        return _layer(cfg, x_, p_, cos_, sin_, seq_axis=seq_axis)
-    if cfg.remat:
-        layer_fn = jax.checkpoint(layer_fn)
-
     def stage_fn_with_rope(cos_, sin_):
         def stage_fn(stage_layers, xmb):
-            # this stage's L/P layers, leading axis scanned
-            def body(x_, p_):
-                return layer_fn(x_, p_, cos_, sin_), None
-
-            out, _ = jax.lax.scan(body, xmb, stage_layers)
-            return out
+            # this stage's L/P layers
+            return run_layers(
+                lambda x_, p_: (_layer(cfg, x_, p_, cos_, sin_,
+                                       seq_axis=seq_axis), None),
+                xmb, stage_layers, level=level, scan=cfg.scan_layers)[0]
         return stage_fn
 
     def sharded_pipeline(stage_layers, mbs_rep, cos_, sin_):

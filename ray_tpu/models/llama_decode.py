@@ -423,7 +423,7 @@ def make_engine_fns(cfg: LlamaConfig, params, num_slots: int, max_len: int,
     emits one all-reduce after attention and one after the MLP per layer,
     riding ICI on a real v5e-N slice. The KV cache shards over the KV-head
     axis (cache_shardings), so per-chip HBM holds 1/tp of the cache: the
-    reason BASELINE config #5 serves on v5e-4 instead of one chip.
+    reason to serve an 8B model on a v5e-4 host instead of one chip.
     Reference analogue (role, not design): torch_tensor_nccl_channel.py:191
     moving activations between TP shards; here the mesh IS the engine."""
     if mesh is not None:

@@ -1,7 +1,8 @@
 """Mixtral-style sparse MoE transformer, TPU-first.
 
-BASELINE.json config #3 names "Mixtral 8x7B MoE, expert-parallel" — the
-reference delegates the model to torch; this is the JAX-native design:
+"Mixtral 8x7B MoE, expert-parallel" is one of the north-star
+configurations; the reference delegates the model to torch, and this is
+the JAX-native design:
 
 - Llama backbone (same attention stack, rms_norm/rope/GQA) with the dense
   MLP replaced by a top-k routed mixture of SwiGLU experts.
@@ -43,14 +44,6 @@ class MixtralConfig(llama.LlamaConfig):
         return replace(cfg, **kw)
 
     @classmethod
-    def moe_proxy(cls, **kw) -> "MixtralConfig":
-        """~MoE analogue of the 1b llama proxy (for single-chip benches)."""
-        cfg = cls(hidden_size=1024, intermediate_size=2816, num_layers=8,
-                  num_heads=8, num_kv_heads=4, vocab_size=32000,
-                  num_experts=8, top_k=2)
-        return replace(cfg, **kw)
-
-    @classmethod
     def tiny(cls, **kw) -> "MixtralConfig":
         cfg = cls(vocab_size=256, hidden_size=64, intermediate_size=128,
                   num_layers=2, num_heads=4, num_kv_heads=2,
@@ -79,15 +72,6 @@ def without_layer_axis(axes):
     return jax.tree_util.tree_map(
         lambda t: tuple(None if a == "layer" else a for a in t),
         axes, is_leaf=lambda x: isinstance(x, tuple))
-
-
-def refuse_dense_knobs(cfg: MixtralConfig) -> None:
-    if cfg.remat_policy not in ("auto", "full") or not cfg.scan_layers:
-        raise ValueError(
-            "remat_policy/scan_layers are dense-Llama knobs; the MoE "
-            "forward always scans under full remat (\"auto\" is \"full\" "
-            "here) — drop them rather than read tuning signal from a "
-            "no-op")
 
 
 def init_params(cfg: MixtralConfig, key: jax.Array) -> Dict[str, Any]:
@@ -141,26 +125,16 @@ def _layer(cfg: MixtralConfig, x, p, cos, sin, mesh=None):
 def forward(cfg: MixtralConfig, params, tokens: jax.Array, mesh=None
             ) -> Tuple[jax.Array, jax.Array]:
     """tokens [b, s] -> (logits [b, s, vocab] fp32, aux_loss scalar)."""
-    x = params["embed"].astype(cfg.dtype)[tokens]
-    cos, sin = rope_frequencies(cfg.head_dim_, tokens.shape[1],
-                                cfg.rope_theta, dtype=cfg.dtype,
-                                scaling=cfg.rope_scaling_dict)
-
-    refuse_dense_knobs(cfg)
-    layer_fn = lambda x_, p_: _layer(cfg, x_, p_, cos, sin, mesh=mesh)
-    if cfg.remat:
-        layer_fn = jax.checkpoint(layer_fn)
-
-    def scan_body(x_, p_):
-        x2, aux = layer_fn(x_, p_)
-        return x2, aux
-
-    x, auxes = jax.lax.scan(scan_body, x, params["layers"])
-    x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
-    head = (params["embed"].T if cfg.tie_embeddings else params["lm_head"])
-    logits = jnp.dot(x, head.astype(cfg.dtype),
-                     preferred_element_type=jnp.float32)
-    return logits, auxes.sum()
+    with jax.named_scope("embed"):
+        x = params["embed"].astype(cfg.dtype)[tokens]
+        cos, sin = rope_frequencies(cfg.head_dim_, tokens.shape[1],
+                                    cfg.rope_theta, dtype=cfg.dtype,
+                                    scaling=cfg.rope_scaling_dict)
+    x, auxes = llama.run_layers(
+        lambda x_, p_: _layer(cfg, x_, p_, cos, sin, mesh=mesh),
+        x, params["layers"], level=llama.remat_level_without_plan(cfg),
+        scan=cfg.scan_layers)
+    return llama._final_head(cfg, params, x), auxes.sum()
 
 
 def loss_fn(cfg: MixtralConfig, params, batch: Dict[str, jax.Array],

@@ -137,12 +137,11 @@ def forward(cfg: OlmoeConfig, params, tokens: jax.Array, mesh=None,
         cos, sin = rope_frequencies(cfg.head_dim_, tokens.shape[1],
                                     cfg.rope_theta, dtype=cfg.dtype,
                                     scaling=cfg.rope_scaling_dict)
-    mixtral.refuse_dense_knobs(cfg)
-    layer_fn = lambda x_, p_: _layer(cfg, x_, p_, cos, sin, mesh=mesh,
-                                     keep_router_logits=keep_router_logits)
-    if cfg.remat:
-        layer_fn = jax.checkpoint(layer_fn)
-    x, router = jax.lax.scan(layer_fn, x, params["layers"])
+    x, router = llama.run_layers(
+        lambda x_, p_: _layer(cfg, x_, p_, cos, sin, mesh=mesh,
+                              keep_router_logits=keep_router_logits),
+        x, params["layers"], level=llama.remat_level_without_plan(cfg),
+        scan=cfg.scan_layers)
     return llama._final_head(cfg, params, x), router
 
 

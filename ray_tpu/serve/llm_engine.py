@@ -94,7 +94,7 @@ class LLMEngine:
         else:
             cfg = getattr(llama.LlamaConfig, preset)(**cfg_kw)
         self._cfg = cfg
-        # tensor-parallel serving (BASELINE config #5 is v5e-4): weights
+        # tensor-parallel serving (a v5e-4 host serving one model): weights
         # and KV cache shard over a tp mesh; XLA emits the per-layer
         # all-reduces over ICI. tp=1 keeps the single-chip path unchanged.
         if mesh is None and tp > 1:

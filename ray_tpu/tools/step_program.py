@@ -1,0 +1,206 @@
+"""The compiled train step of each benchmark cell, as text: what a PR that
+moves model code without meaning to change a program compares, parent
+against change, before any chip does.
+
+For every training cell of ``BENCHMARK.json`` the cell's adamw step
+(``benchmark/cells/train.py`` and ``train_moe.py``: the cell's
+configuration, batch, mesh and donation) is compiled for a v5e host that
+is described and not attached, with ``jax.default_backend`` answering
+"tpu" and ``llama._device_capacity`` a v5e chip's limit, as
+``tests/test_tpu_compile.py`` does. Nothing runs: no time and no result
+comes from here. Three 7B-width compiles take minutes, so this is a
+script and not a tier-1 test.
+
+    python ray_tpu/tools/step_program.py --tree <checkout> --out <dir>
+    python ray_tpu/tools/step_program.py --compare <dir-a> <dir-b>
+
+Run as a file and not with ``-m``: ``--tree`` (default: this checkout) is
+the checkout whose ``ray_tpu`` and ``benchmark/`` are read, so the same
+script judges a parent commit unpacked elsewhere. ``--out`` gets
+``<cell>.hlo.txt`` (``as_text()`` without metadata) and ``summary.json``
+(its sha256, ``memory_analysis()``, the resolved remat level, the count
+of Mosaic calls).
+"""
+
+from __future__ import annotations
+
+import argparse
+import difflib
+import hashlib
+import json
+import os
+import re
+import sys
+
+V5E_BYTES_LIMIT = int(15.75 * 2 ** 30)
+MEMORY_FIELDS = ("argument_size_in_bytes", "output_size_in_bytes",
+                 "alias_size_in_bytes", "temp_size_in_bytes",
+                 "generated_code_size_in_bytes")
+
+
+def strip_metadata(text: str) -> str:
+    """``compiled.as_text()`` without what names source and scopes: each
+    instruction's metadata, and the module's tables of source files and
+    stack frames that the metadata points into."""
+    text = re.sub(r", metadata=\{[^}]*\}", "", text)
+    return re.sub(r"\nFileNames\n.*?\nStackFrames\n.*?\n\n", "\n",
+                  text, flags=re.S)
+
+
+def _compile_cell(tree: str, cell: dict, topo) -> dict:
+    import jax
+    import jax.numpy as jnp
+    import optax
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from jax.sharding import SingleDeviceSharding
+
+    from ray_tpu.models import llama, olmoe
+    from ray_tpu.parallel import MeshSpec, build_mesh
+    from ray_tpu.parallel.sharding import batch_sharding
+    from ray_tpu.util import tracing
+
+    def load(kind, name):
+        with open(os.path.join(tree, "benchmark", kind, name + ".json")) as f:
+            return json.load(f)
+
+    tr = load("traffic", cell["traffic"])
+    kw = dict(load("configs", cell["config"])["model_config"])
+    preset = kw.pop("preset")
+    for key in ("dtype", "param_dtype"):
+        kw[key] = getattr(jnp, kw[key])
+    moe = tr["family"] == "train_moe"
+    mod = olmoe if moe else llama
+    cfg_cls = olmoe.OlmoeConfig if moe else llama.LlamaConfig
+    cfg = getattr(cfg_cls, preset)(**kw, attn_impl="auto")
+    devs = topo.devices[:cell["chips"]]
+    mesh = None
+    rep = psh = bsh = SingleDeviceSharding(devs[0])
+    if tr["mesh_axes"]:
+        mesh = build_mesh(MeshSpec(tr["mesh_axes"]), devices=devs)
+        psh = mod.param_shardings(cfg, mesh)
+        bsh, rep = batch_sharding(mesh), NamedSharding(mesh, P())
+
+    def placed(tree_, sh):
+        if not isinstance(sh, (dict, tuple, list)):
+            sh = jax.tree_util.tree_map(lambda _: sh, tree_)
+        return jax.tree_util.tree_map(lambda a, s: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=s), tree_, sh)
+
+    tx = optax.adamw(tr["lr"])
+    shapes = jax.eval_shape(lambda k: mod.init_params(cfg, k),
+                            jax.random.PRNGKey(0))
+    params = placed(shapes, psh)
+    # the moments lie where the parameters do; the step count is one scalar
+    opt = jax.eval_shape(tx.init, shapes)
+    opt = (opt[0]._replace(count=placed(opt[0].count, rep), mu=params,
+                           nu=params),) + tuple(opt[1:])
+    batch = {"tokens": jax.ShapeDtypeStruct(
+        (tr["batch"], tr["seq"] + 1), jnp.int32, sharding=bsh)}
+
+    if moe:
+        def step(params, opt, batch):
+            (loss, aux), grads = jax.value_and_grad(
+                lambda p: olmoe.loss_terms(cfg, p, batch, mesh=mesh),
+                has_aux=True)(params)
+            updates, opt = tx.update(grads, opt, params)
+            return (optax.apply_updates(params, updates), opt, loss,
+                    aux["expert_counts"])
+    else:
+        def step(params, opt, batch):
+            loss, grads = jax.value_and_grad(
+                lambda p: llama.loss_fn(cfg, p, batch, mesh=mesh))(params)
+            updates, opt = tx.update(grads, opt, params)
+            return optax.apply_updates(params, updates), opt, loss
+
+    n0 = len(tracing.chrome_events())
+    compiled = jax.jit(step, donate_argnums=(0, 1)).lower(
+        params, opt, batch).compile()
+    plans = [e["args"]["level"] for e in tracing.chrome_events()[n0:]
+             if e["name"] == "rtpu.train.remat_plan"]
+    text = strip_metadata(compiled.as_text())
+    ma = compiled.memory_analysis()
+    return {"text": text, "summary": {
+        "sha256": hashlib.sha256(text.encode()).hexdigest(),
+        "lines": text.count("\n"),
+        "mosaic_calls": text.count("tpu_custom_call"),
+        "remat_plan": plans[0] if plans else None,
+        "memory_analysis": {f: getattr(ma, f) for f in MEMORY_FIELDS}}}
+
+
+def compile_cells(tree: str, out: str) -> None:
+    sys.path.insert(0, tree)
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    import jax
+    from jax.experimental import topologies
+
+    from ray_tpu.models import llama
+
+    # a compile for a described device cannot be read back from the cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    # A Mosaic kernel's serialized body (the custom call's backend_config)
+    # carries the location of every Python frame that led to it, and
+    # strip_metadata cannot reach inside: with the callers' frames in,
+    # moving a line of llama.py changes three lines of the text (PR 28).
+    # Keep the innermost frame alone, the kernel's own source.
+    jax.config.update("jax_traceback_in_locations_limit", 0)
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
+    jax.default_backend = lambda: "tpu"
+    llama._device_capacity = lambda mesh: V5E_BYTES_LIMIT
+    with open(os.path.join(tree, "BENCHMARK.json")) as f:
+        cells = [c for c in json.load(f)["workloads"]
+                 if c["traffic"].startswith("train")]
+    os.makedirs(out, exist_ok=True)
+    summary = {}
+    for cell in cells:
+        got = _compile_cell(tree, cell, topo)
+        with open(os.path.join(out, cell["name"] + ".hlo.txt"), "w") as f:
+            f.write(got["text"])
+        summary[cell["name"]] = got["summary"]
+        print(cell["name"], json.dumps(got["summary"]), flush=True)
+    with open(os.path.join(out, "summary.json"), "w") as f:
+        json.dump(summary, f, indent=1, sort_keys=True)
+
+
+def compare(a: str, b: str) -> int:
+    def summary(d):
+        with open(os.path.join(d, "summary.json")) as f:
+            return json.load(f)
+
+    sa, sb = summary(a), summary(b)
+    differing = 0
+    for name in sorted(set(sa) | set(sb)):
+        same = sa.get(name) == sb.get(name)
+        differing += not same
+        print(f"{name}: {'equal' if same else 'DIFFERENT'} "
+              f"{json.dumps(sb.get(name))}")
+        if not same and name in sa and name in sb:
+            print(f"  was: {json.dumps(sa[name])}")
+            texts = []
+            for d in (a, b):
+                with open(os.path.join(d, name + ".hlo.txt")) as f:
+                    texts.append(f.read().splitlines())
+            for line in list(difflib.unified_diff(*texts, lineterm="",
+                                                  n=0))[:40]:
+                print("  " + line[:240])
+    return 1 if differing else 0
+
+
+def main() -> int:
+    here = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--tree", default=here)
+    ap.add_argument("--out")
+    ap.add_argument("--compare", nargs=2, metavar="DIR")
+    args = ap.parse_args()
+    if args.compare:
+        return compare(*args.compare)
+    if not args.out:
+        ap.error("--out or --compare")
+    compile_cells(os.path.abspath(args.tree), args.out)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

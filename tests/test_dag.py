@@ -284,7 +284,7 @@ def test_cross_node_dag():
 def test_socket_channel_rejects_unauthenticated_peer():
     """A stray/hostile connection must neither hijack the edge nor wedge
     it: the reader keeps accepting until an authkey'd peer completes the
-    HMAC handshake (ADVICE r3: unauthenticated SocketChannel)."""
+    HMAC handshake (an unauthenticated SocketChannel accepted anybody)."""
     import socket as _socket
 
     from ray_tpu.dag.channel import SocketChannel
@@ -334,7 +334,7 @@ def test_socket_channel_rejects_unauthenticated_peer():
 
 
 def test_rpc_retry_whitelist():
-    """Lost-reply retries are restricted to idempotent ops (ADVICE r3:
+    """Lost-reply retries are restricted to idempotent ops (the
     at-least-once hazard on submit/kv-merge/publish)."""
     from ray_tpu.core.cluster.rpc import _retry_safe_after_apply
 
@@ -357,7 +357,7 @@ def test_rpc_retry_whitelist():
 def test_node_server_dedups_retried_submissions():
     """A re-delivered submit/actor_call (lost-reply retry) must not run
     side effects twice, while a FAILED apply must be re-runnable and an
-    in-progress apply must latch duplicates (ADVICE r3 + review r4)."""
+    in-progress apply must latch duplicates."""
     from collections import OrderedDict
 
     from ray_tpu.core.cluster.node_server import NodeServer

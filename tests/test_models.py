@@ -316,6 +316,45 @@ def test_olmoe_gradients_match_the_reference(olmoe_setup):
                                    atol=1e-6, err_msg=str(path))
 
 
+@pytest.mark.parametrize("remat", [False, True], ids=["remat-off", "remat-on"])
+@pytest.mark.parametrize("model", ["mixtral", "olmoe"])
+def test_moe_unrolled_matches_scan(model, remat):
+    """``scan_layers=False`` is ``llama.run_layers``' branch for every
+    forward of the family: the routed models' loss, gradients and
+    per-layer router outputs (stacked as the scan stacks them) equal the
+    scan's, with and without a checkpoint around each layer."""
+    from dataclasses import replace
+
+    from ray_tpu.models import mixtral, olmoe
+
+    mod, cls = {"mixtral": (mixtral, mixtral.MixtralConfig),
+                "olmoe": (olmoe, olmoe.OlmoeConfig)}[model]
+    scanned = cls.tiny(attn_impl="reference", remat=remat)
+    params = mod.init_params(scanned, jax.random.PRNGKey(0))
+    tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 17), 0,
+                                scanned.vocab_size)
+
+    def run(cfg):
+        loss, grads = jax.jit(jax.value_and_grad(
+            lambda p: mod.loss_fn(cfg, p, {"tokens": tokens})))(params)
+        _, router = jax.jit(lambda p: mod.forward(
+            cfg, p, tokens[:, :-1]))(params)
+        return loss, grads, router
+
+    want = run(scanned)
+    got = run(replace(scanned, scan_layers=False))
+    assert jax.tree_util.tree_structure(got) == \
+        jax.tree_util.tree_structure(want)
+    for a, b in zip(jax.tree_util.tree_leaves(want),
+                    jax.tree_util.tree_leaves(got)):
+        assert a.shape == b.shape and a.dtype == b.dtype
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-5, atol=1e-5)
+    if model == "olmoe":
+        assert got[2]["counts"].shape == (scanned.num_layers,
+                                          scanned.num_experts)
+
+
 def test_olmoe_reference_forced_to_other_choices(olmoe_setup):
     """``forced_topk`` replaces the reference's choice of experts: its
     own choice gives its own result back, another choice another."""
@@ -579,32 +618,6 @@ def test_linear_and_yarn_rope_scaling_parity(scaling):
     assert np.abs(ours - ref).max() < 5e-6
 
 
-def test_partial_remat_matches_full_remat():
-    """remat_store_layers trades HBM for recompute without changing the
-    math: loss AND grads match classic full per-layer remat."""
-    import jax
-    import jax.numpy as jnp
-
-    from ray_tpu.models import llama
-
-    tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 33), 0, 256)
-    cfg_full = llama.LlamaConfig.tiny(remat=True)
-    cfg_part = llama.LlamaConfig.tiny(remat=True, remat_store_layers=1)
-    params = llama.init_params(cfg_full, jax.random.PRNGKey(0))
-
-    def lg(cfg):
-        return jax.jit(jax.value_and_grad(
-            lambda p: llama.loss_fn(cfg, p, {"tokens": tokens})))(params)
-
-    l_full, g_full = lg(cfg_full)
-    l_part, g_part = lg(cfg_part)
-    assert jnp.allclose(l_full, l_part, atol=1e-6)
-    flat_f = jax.tree_util.tree_leaves(g_full)
-    flat_p = jax.tree_util.tree_leaves(g_part)
-    assert all(jnp.allclose(a, b, atol=1e-5)
-               for a, b in zip(flat_f, flat_p))
-
-
 def _count_primitives(jaxpr, counts=None):
     """Primitive name -> occurrences, through every sub-jaxpr but a Pallas
     kernel's body (``pallas_call`` counts as one, under its own name)."""
@@ -659,8 +672,8 @@ def remat_setup():
 
 @pytest.mark.parametrize("attn", ["reference", "flash"])
 @pytest.mark.parametrize("scan_layers", [True, False])
-@pytest.mark.parametrize("policy", ["full", "save_qkv", "level1", "level2",
-                                    "level3", "level4"])
+@pytest.mark.parametrize("policy", ["full", "level1", "level2", "level3",
+                                    "level4"])
 def test_every_remat_level_matches_no_remat(remat_setup, policy,
                                             scan_layers, attn):
     """What a layer's checkpoint keeps (``remat_policy``) and how the
@@ -680,8 +693,7 @@ def test_richest_remat_level_recomputes_no_matmul_and_no_flash(remat_setup):
     """The gradient's jaxpr, counted: under "full" every layer's backward
     runs the six projections (q, k, v, wo, gate, up) and the flash forward
     a second time; "level4" runs none of them again, "level1" only drops
-    the kernel, "save_qkv" drops three matmuls and still runs the kernel
-    twice (nothing it keeps is the kernel's residual)."""
+    the kernel."""
     loss_of, traced, _ = remat_setup
     layers = llama.LlamaConfig.tiny().num_layers
 
@@ -694,10 +706,26 @@ def test_richest_remat_level_recomputes_no_matmul_and_no_flash(remat_setup):
     dots_full, fwd_full = counts("full")
     assert fwd_full == 2 * layers
     assert counts("level1") == (dots_full, layers)
-    assert counts("save_qkv") == (dots_full - 3 * layers, 2 * layers)
     assert counts("level2") == (dots_full - 3 * layers, layers)
     assert counts("level3") == (dots_full - 5 * layers, layers)
     assert counts("level4") == (dots_full - 6 * layers, layers)
+
+
+def test_one_walker_owns_the_layer_loop():
+    """``llama.run_layers`` is the family's one ``jax.checkpoint`` and its
+    one loop over the stacked layers: a forward that grows its own would
+    miss the next change to the remat decision, as three did before
+    PR 28."""
+    import inspect
+
+    from ray_tpu.models import mixtral, olmoe
+
+    walker = inspect.getsource(llama.run_layers)
+    for needle in ("jax.checkpoint(", "lax.scan("):
+        assert walker.count(needle) == 1
+        for mod in (llama, mixtral, olmoe, gpt2):
+            outside = inspect.getsource(mod).replace(walker, "")
+            assert needle not in outside, (mod.__name__, needle)
 
 
 # Mistral-7B-v0.3's widths as the benchmark's dense cells train them
@@ -750,17 +778,21 @@ def test_remat_plan_is_a_pure_function_of_bytes():
         got = [rank(level(**kw)) for kw in grow]
         assert got == sorted(got, reverse=True), got
     # a policy somebody set is never overridden, whatever the room
-    for policy in ("full", "save_qkv", "level2"):
+    for policy in ("full", "level2"):
         for cap in (None, 10 ** 9, 10 ** 12):
             cfg = llama.LlamaConfig(num_layers=4, remat_policy=policy,
                                     **_MISTRAL)
             assert level(cfg, cap=cap) == policy
-    assert llama.remat_names("save_qkv") == ("q_rope", "k_rope", "v_proj")
     assert llama.remat_names("full") == ()
+    assert llama.remat_names("level2")[2:] == ("q_rope", "k_rope", "v_proj")
     assert llama.remat_names("level4")[:2] == ("flash_out", "flash_lse")
-    # bad policy name raises rather than silently training differently
-    with pytest.raises(ValueError, match="nope"):
-        llama.LlamaConfig.tiny(remat=True, remat_policy="nope")
+    # bad policy name raises rather than silently training differently,
+    # and so do the knobs PR 28 took away
+    for gone in ("nope", "save_qkv"):
+        with pytest.raises(ValueError, match=gone):
+            llama.LlamaConfig.tiny(remat=True, remat_policy=gone)
+    with pytest.raises(TypeError, match="remat_store_layers"):
+        llama.LlamaConfig(remat_store_layers=1)
 
 
 @pytest.mark.parametrize("layers,fsdp,want", [(4, None, "level4"),

@@ -232,22 +232,28 @@ def test_host_ring_allreduce_matches_star(rt):
         assert abs(total - expect.sum()) < 1e-6
 
 
-def test_pipeline_parallel_matches_sequential():
+@pytest.mark.parametrize("scan_layers,pp,remat", [(True, 4, False),
+                                                  (False, 2, True)])
+def test_pipeline_parallel_matches_sequential(scan_layers, pp, remat):
     """GPipe over the pp mesh axis (parallel/pipeline.py): sharded layer
     stack + ppermute rotation in ONE scanned program must reproduce the
-    sequential model's loss AND grads (jax.grad reverses the schedule)."""
+    sequential model's loss AND grads (jax.grad reverses the schedule).
+    A stage walks its layers as the sequential forward does
+    (``llama.run_layers``): scanned, or unrolled (two layers a stage,
+    each under its checkpoint)."""
     import jax
     import jax.numpy as jnp
 
     from ray_tpu.models import llama
     from ray_tpu.parallel import MeshSpec, build_mesh
 
-    cfg = llama.LlamaConfig.tiny(num_layers=4, remat=False)
+    cfg = llama.LlamaConfig.tiny(num_layers=4, remat=remat,
+                                 scan_layers=scan_layers)
     params = llama.init_params(cfg, jax.random.PRNGKey(0))
     tokens = jax.random.randint(jax.random.PRNGKey(1), (8, 17), 0,
                                 cfg.vocab_size)
-    mesh = build_mesh(MeshSpec({"pp": 4}),
-                      devices=jax.devices()[:4])
+    mesh = build_mesh(MeshSpec({"pp": pp}),
+                      devices=jax.devices()[:pp])
 
     # one program a side, loss and grads together (the sequential side
     # jitted too: op by op it is hundreds of small compiles)

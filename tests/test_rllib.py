@@ -106,7 +106,7 @@ def test_cnn_module_mesh_shardable():
 
 
 def test_ppo_cnn_learns_pixel_catch(rl_ray):
-    """CNN RLModule + pixel env (BASELINE config #4's Atari path, sans
+    """CNN RLModule + pixel env (the north-star PPO-on-Atari path, sans
     ALE): PPO with the conv encoder must go from random (~-0.3) to
     catching (>0.6) in CI minutes. Reference:
     rllib/core/models/torch/encoder.py:107 + ppo Atari configs."""

@@ -490,9 +490,9 @@ def test_llm_engine_sampling(dense_engine):
 def test_llm_engine_tensor_parallel_matches_single(dense_engine):
     """Tensor-parallel decode (weights + KV cache sharded over a tp mesh,
     per-layer all-reduces emitted by XLA) must generate exactly the greedy
-    tokens the single-device engine generates. BASELINE config #5 (v5e-4
-    serving) runs this path on a real slice; here tp=4 spans 4 of the
-    virtual CPU devices."""
+    tokens the single-device engine generates. Serving on a v5e-4 host
+    runs this path on a real slice; here tp=4 spans 4 of the virtual CPU
+    devices."""
     from ray_tpu.serve.llm_engine import LLMEngine
 
     reqs = [(f"tp{i}", p, {"max_new_tokens": 6}) for i, p in enumerate(

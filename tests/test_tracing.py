@@ -16,6 +16,7 @@ import time
 import pytest
 
 from ray_tpu.core.config import config
+from ray_tpu.tools.step_program import strip_metadata as _strip
 from ray_tpu.util import tracing
 from tests.engines import SMALLEST, drain, private_engine
 
@@ -350,14 +351,12 @@ def _train_step_text(scoped: bool = True, model: str = "llama",
     import jax.numpy as jnp
     import optax
 
-    from ray_tpu.models import llama, olmoe
+    from ray_tpu.models import llama, mixtral, olmoe
 
-    if model == "olmoe":
-        mod, cfg = olmoe, olmoe.OlmoeConfig.tiny(
-            vocab_size=128, attn_impl="reference", **cfg_kw)
-    else:
-        mod, cfg = llama, llama.LlamaConfig.tiny(
-            vocab_size=128, attn_impl="reference", **cfg_kw)
+    mod, cls = {"llama": (llama, llama.LlamaConfig),
+                "mixtral": (mixtral, mixtral.MixtralConfig),
+                "olmoe": (olmoe, olmoe.OlmoeConfig)}[model]
+    cfg = cls.tiny(vocab_size=128, attn_impl="reference", **cfg_kw)
     params = jax.eval_shape(lambda k: mod.init_params(cfg, k),
                             jax.random.PRNGKey(0))
     tx = optax.adamw(1e-3)
@@ -388,12 +387,6 @@ def _train_step_text(scoped: bool = True, model: str = "llama",
         jax.config.update(key, saved_key)
 
 
-def _strip(text):
-    # an instruction's metadata, and the module's tables of source
-    # files and stack frames that the metadata points into
-    text = re.sub(r", metadata=\{[^}]*\}", "", text)
-    return re.sub(r"\nFileNames\n.*?\nStackFrames\n.*?\n\n", "\n",
-                  text, flags=re.S)
 
 
 def _instructions(text):
@@ -411,10 +404,11 @@ def test_named_scopes_change_no_instruction_of_the_train_step():
     assert with_scopes != without
 
 
-@pytest.mark.parametrize("model", ["llama", "olmoe"])
+@pytest.mark.parametrize("model", ["llama", "olmoe", "mixtral"])
 def test_a_plan_of_full_is_the_program_of_explicit_full(model):
     """``remat_policy="auto"`` where the device reports no memory limit
-    (here), and in a forward that has no ladder (OLMoE's), is "full": the
+    (here), and in a forward that has no plan (OLMoE's, Mixtral's), is
+    "full": the
     checkpoint names are inert and the step compiles to the text of the
     explicit policy. A kept level is another program."""
     auto = _strip(_train_step_text(model=model, remat=True))
@@ -427,9 +421,10 @@ def test_a_plan_of_full_is_the_program_of_explicit_full(model):
 
 
 def test_remat_auto_passes_where_a_set_policy_is_refused():
-    """The MoE forwards and the pipeline schedule run full remat and
-    refuse a dense-forward knob somebody set; the new default is not
-    one."""
+    """The MoE forwards and the pipeline schedule have no plan: they run
+    full remat and refuse a ladder level somebody set, through one helper
+    with one message; the default is not refused, and neither is
+    ``scan_layers=False`` (the walker honours it for every caller)."""
     import jax
     import jax.numpy as jnp
 
@@ -438,13 +433,11 @@ def test_remat_auto_passes_where_a_set_policy_is_refused():
 
     for cfg in (mixtral.MixtralConfig.tiny(), olmoe.OlmoeConfig.tiny(),
                 olmoe.OlmoeConfig.olmoe_1b_7b(),
-                mixtral.MixtralConfig.tiny(remat_policy="full")):
+                mixtral.MixtralConfig.tiny(remat_policy="full"),
+                olmoe.OlmoeConfig.tiny(scan_layers=False)):
         assert cfg.remat_policy in ("auto", "full")
-        mixtral.refuse_dense_knobs(cfg)
-    for policy in ("save_qkv", "level1", "level4"):
-        with pytest.raises(ValueError, match="dense-Llama knobs"):
-            mixtral.refuse_dense_knobs(
-                olmoe.OlmoeConfig.tiny(remat_policy=policy))
+        assert llama.remat_level_without_plan(cfg) == (
+            "full" if cfg.remat else None)
     cfg = llama.LlamaConfig.tiny(attn_impl="reference", remat=True)
     assert cfg.remat_policy == "auto"
     mesh = build_mesh(MeshSpec({"pp": 2}), devices=jax.devices()[:2])
@@ -455,8 +448,20 @@ def test_remat_auto_passes_where_a_set_policy_is_refused():
         llama.init_shapes(cfg), batch)
     assert loss.shape == ()
     assert len(_mine("rtpu.train.remat_plan")) == n0   # no plan to report
-    for policy in ("save_qkv", "level3"):
-        with pytest.raises(ValueError, match="sequential-forward knobs"):
+    tokens = jax.ShapeDtypeStruct((2, 32), jnp.int32)
+
+    def refused():
+        return pytest.raises(ValueError, match="this forward has no plan")
+
+    for policy in ("level1", "level4"):
+        for mod, cls in ((mixtral, mixtral.MixtralConfig),
+                         (olmoe, olmoe.OlmoeConfig)):
+            moe = cls.tiny(remat_policy=policy)
+            with refused():
+                jax.eval_shape(lambda p, t: mod.forward(moe, p, t),
+                               mod.init_params(moe, jax.random.PRNGKey(0)),
+                               tokens)
+        with refused():
             llama.loss_fn_pp(llama.LlamaConfig.tiny(remat_policy=policy),
                              None, batch, mesh, 2)
 

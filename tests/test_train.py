@@ -251,8 +251,9 @@ def test_dataset_ingest_batches_to_jax(rt, run_cfg):
 
 
 def test_gpt2_language_model_training_e2e(rt, run_cfg):
-    """BASELINE config #1 analogue: GPT-2 (tiny) language-model training on
-    a Data-ingested synthetic corpus, 1 worker — loss must drop."""
+    """The north-star "GPT-2-125M on wikitext-2" at tiny size: GPT-2
+    language-model training on a Data-ingested synthetic corpus, 1 worker
+    — loss must drop."""
     import ray_tpu.data as rd
 
     def loop(config):
