@@ -5,16 +5,22 @@ Moonlight, DeepSeek-V2-Lite, Trinity-Mini on the roadmap). A token picks
 ``top_k`` of ``E`` experts; the ``n * top_k`` (token, choice) pairs are
 sorted by expert, the token rows gathered into that order, and each
 expert multiplies its own contiguous group of rows: three grouped
-matmuls with SwiGLU between. The rows come back to their tokens weighted
-by the gate and summed. There is no capacity and no
-dropped row: how many rows an expert gets is data (``group_sizes``), the
-number of rows in all is the static ``n * top_k``, so no routing, however
-uneven, compiles anything.
+matmuls with SwiGLU between. The rows come back to their tokens and
+are summed. There is no capacity and no dropped row: how many rows an
+expert gets is data (``group_sizes``), the number of rows in all is the
+static ``n * top_k``, so no routing, however uneven, compiles anything.
 
-The two permutations are written as gathers in both directions (a
-``custom_vjp`` each): the transpose of "gather the rows into expert
-order" is "gather them back and sum over the choices", not a
-scatter-add of 65,536 rows.
+The gate weight multiplies the SwiGLU activation, not the down
+projection's output (linear: ``sum_k w * (a @ W)`` = ``sum_k (w * a) @
+W``): in float32, inside the activation's elementwise pass, rounded once;
+``d top_w`` is a row sum in that pass's backward. No float32 ``[n * top_k,
+h]`` array exists and the backward never reads the down projection's
+output, so a layer's ``jax.checkpoint`` does not recompute it (8 grouped
+matmuls a layer, not 9; PERF.md 6, PR 29).
+
+The two permutations are gathers in both directions, not a scatter-add
+of 65,536 rows: ``_dispatch`` (rows into expert order) and ``_combine``
+(back, summed over the choices) are each other's transpose.
 
 The grouped matmul is jax's megablox Mosaic kernels on a TPU (``gmm``,
 ``tgmm`` in a trace) and ``jax.lax.ragged_dot`` elsewhere. On the chip
@@ -27,7 +33,7 @@ Named scopes (metadata only, nested under the caller's ``mlp``; a
 backward operation carries the scope of the call it transposes):
 ``moe_route`` (router matmul, softmax, top-k, sort), ``moe_dispatch``
 (gather into expert order), ``moe_experts`` (grouped matmuls and the
-activation), ``moe_combine`` (gate weighting, gather back, sum).
+activation, the gate weighting in it), ``moe_combine`` (gather back, sum).
 """
 
 from __future__ import annotations
@@ -45,45 +51,36 @@ def _dispatch(x, token_of, inv, top_k):
     return x[token_of]
 
 
-def _dispatch_fwd(x, token_of, inv, top_k):
-    return x[token_of], inv
+@partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _combine(rows, token_of, inv, top_k):
+    """rows [n * top_k, h] in expert order -> [n, h]: a token's
+    ``top_k`` rows gathered back and summed in float32."""
+    back = rows[inv].reshape(-1, top_k, rows.shape[-1])
+    return back.astype(jnp.float32).sum(1).astype(rows.dtype)
 
 
-def _dispatch_bwd(top_k, inv, d_rows):
-    n = inv.shape[0] // top_k
-    dx = d_rows[inv].reshape(n, top_k, -1).astype(jnp.float32).sum(1)
-    return dx.astype(d_rows.dtype), None, None
+def _gather_rules(permute, transpose):
+    """A permutation's ``custom_vjp`` rules: the indices are the residuals."""
+    return (lambda x, token_of, inv, top_k: (
+                permute(x, token_of, inv, top_k), (token_of, inv)),
+            lambda top_k, res, g: (transpose(g, *res, top_k), None, None))
 
 
-_dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
+_dispatch.defvjp(*_gather_rules(_dispatch, _combine))
+_combine.defvjp(*_gather_rules(_combine, _dispatch))
 
 
 @jax.custom_vjp
-def _combine(rows, top_w, inv, order):
-    """rows [n * K, h] in expert order, top_w [n, K] float32 in token
-    order -> [n, h]: sum_k top_w[t, k] * rows[inv[t * K + k]], weighted
-    and summed in float32."""
-    n, k = top_w.shape
-    back = rows[inv].reshape(n, k, -1).astype(jnp.float32)
-    return (back * top_w[:, :, None]).sum(1).astype(rows.dtype)
+def _place(values, to):
+    """values [m] -> out [m], out[to[i]] = values[i], ``to`` a permutation:
+    a sort by ``to``, 0.06 ms for 65,536 scalars on a v5e where the gather
+    by its inverse is 0.56 (PR 29). The transpose stays the gather ``g[to]``:
+    a sort there splits the activation's backward fusion in two."""
+    return jax.lax.sort((to, values), num_keys=1)[1]
 
 
-def _combine_fwd(rows, top_w, inv, order):
-    return _combine(rows, top_w, inv, order), (rows, top_w, inv, order)
-
-
-def _combine_bwd(res, d_out):
-    rows, top_w, inv, order = res
-    n, k = top_w.shape
-    g = d_out.astype(jnp.float32)
-    back = rows[inv].reshape(n, k, -1).astype(jnp.float32)
-    d_w = (back * g[:, None, :]).sum(-1)
-    w_rows = top_w.reshape(-1)[order]
-    d_rows = (g[order // k] * w_rows[:, None]).astype(rows.dtype)
-    return d_rows, d_w, None, None
-
-
-_combine.defvjp(_combine_fwd, _combine_bwd)
+_place.defvjp(lambda values, to: (_place(values, to), to),
+              lambda to, g: (g[to], None))
 
 # megablox tiles (rows, contraction, columns), from a sweep on v5e at
 # 65,536 rows x 2048 x 1024 and x 1024 x 2048, 64 groups, balanced and
@@ -161,26 +158,28 @@ def routed_experts(x: jax.Array, router_w: jax.Array, e_gate: jax.Array,
                    ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """x [n, h], router_w [h, E], e_gate / e_up [E, h, f], e_down
     [E, f, h] -> (out [n, h], router_logits [n, E] float32, counts [E]
-    int32: rows each expert multiplied, n * top_k in all)."""
-    n, _ = x.shape
+    int32: rows each expert multiplied, n * top_k in all). A row's gate
+    weight goes into its activation, before the down projection (above)."""
     num_experts = router_w.shape[-1]
     dt = x.dtype
     with jax.named_scope("moe_route"):
         logits, top_w, top_e = route(x, router_w, top_k, renormalize)
-        flat_e = top_e.reshape(n * top_k)
+        flat_e = top_e.reshape(-1)
         # stable: an expert's rows stay in token order
         order = jnp.argsort(flat_e, stable=True).astype(jnp.int32)
-        inv = jnp.zeros_like(order).at[order].set(
-            jnp.arange(n * top_k, dtype=jnp.int32))
+        inv = jnp.argsort(order)
+        token_of = order // top_k
         counts = (flat_e[:, None] == jnp.arange(num_experts)[None, :]
                   ).sum(0, dtype=jnp.int32)
     with jax.named_scope("moe_dispatch"):
-        rows = _dispatch(x, order // top_k, inv, top_k)
+        rows = _dispatch(x, token_of, inv, top_k)
+        w_rows = _place(top_w.reshape(-1), inv)
     with jax.named_scope("moe_experts"):
         gate = grouped_matmul(rows, e_gate.astype(dt), counts)
         up = grouped_matmul(rows, e_up.astype(dt), counts)
-        rows = grouped_matmul(jax.nn.silu(gate) * up, e_down.astype(dt),
-                              counts)
+        act = (jax.nn.silu(gate.astype(jnp.float32))
+               * up.astype(jnp.float32) * w_rows[:, None])
+        rows = grouped_matmul(act.astype(dt), e_down.astype(dt), counts)
     with jax.named_scope("moe_combine"):
-        out = _combine(rows, top_w, inv, order)
+        out = _combine(rows, token_of, inv, top_k)
     return out, logits, counts

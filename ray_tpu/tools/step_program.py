@@ -35,7 +35,7 @@ import sys
 V5E_BYTES_LIMIT = int(15.75 * 2 ** 30)
 MEMORY_FIELDS = ("argument_size_in_bytes", "output_size_in_bytes",
                  "alias_size_in_bytes", "temp_size_in_bytes",
-                 "generated_code_size_in_bytes")
+                 "generated_code_size_in_bytes", "peak_memory_in_bytes")
 
 
 def strip_metadata(text: str) -> str:

@@ -302,6 +302,35 @@ def test_routed_experts_match_the_expert_loop(skewed, renormalize):
                                    rtol=1e-4, atol=1e-4)
 
 
+@pytest.mark.parametrize("skewed", [False, True])
+@pytest.mark.parametrize("renormalize", [False, True])
+def test_checkpointed_routed_experts_never_recompute_the_down_projection(
+        skewed, renormalize):
+    """The gate weight sits in front of the down projection, so nothing
+    in the backward reads that projection's output and a layer's
+    ``jax.checkpoint`` recomputes two grouped matmuls, not three: 11 in
+    the gradient (3 forward, 2 recomputed, 6 transposed). ``d top_w``
+    comes out of the activation's backward: the router's gradient still
+    matches the plain loop."""
+    from ray_tpu.ops.moe import routed_experts
+    from tests.test_models import _count_primitives
+
+    *args, cot = _routed_inputs(skewed)
+    layer = jax.checkpoint(
+        lambda *a: routed_experts(*a, 8, renormalize)[0])
+    grad = jax.grad(lambda *a: (layer(*a) * cot).sum(),
+                    argnums=(0, 1, 2, 3, 4))
+    assert _count_primitives(jax.make_jaxpr(grad)(*args).jaxpr)[
+        "ragged_dot_general"] == 11
+    with jax.default_matmul_precision("highest"):
+        got = jax.jit(grad)(*args)[1]
+        want = jax.jit(jax.grad(
+            lambda *a: (_experts_by_loop(*a, 8, renormalize) * cot).sum(),
+            argnums=1))(*args)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-4, atol=1e-4)
+
+
 def test_routed_experts_single_expert_is_the_dense_swiglu():
     from ray_tpu.ops.moe import routed_experts
 

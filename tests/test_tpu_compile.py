@@ -45,34 +45,79 @@ def no_compile_cache():
     compilation_cache.reset_cache()
 
 
-def test_routed_experts_compile_at_olmoe_widths(one_chip, no_compile_cache,
+# OLMoE-1B-7B's routed layer at the benchmark cell's tokens: n, h, f, E, K
+OLMOE_ROWS = (8192, 2048, 1024, 64, 8)
+
+
+@pytest.fixture
+def S(one_chip):
+    """A bf16 array of a shape on the described chip, for ``lower``."""
+    return lambda *shape: jax.ShapeDtypeStruct(shape, jnp.bfloat16,
+                                               sharding=one_chip)
+
+
+def _mosaic_calls(text: str):
+    """(kernel name, its line) of every Mosaic call in ``text``."""
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    return [(re.match(r"\s*(?:ROOT )?%([A-Za-z_]+)", c).group(1), c)
+            for c in calls]
+
+
+def test_routed_experts_compile_at_olmoe_widths(S, no_compile_cache,
                                                 monkeypatch):
     """Forward and backward of the routed layer at OLMoE-1B-7B's widths
     and the benchmark cell's 8,192 tokens: the grouped matmuls are the
     megablox kernels (three ``gmm`` forward, three transposed, three
-    ``tgmm``), and they carry the scope ``moe_experts``."""
+    ``tgmm``), and they carry the scope ``moe_experts``. The value is
+    asked for with the gradient: the gradient alone does not need the
+    forward's down projection (PR 29) and compiles to five ``gmm``."""
     from ray_tpu.ops.moe import routed_experts
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    n, h, f, E, K = 8192, 2048, 1024, 64, 8
-    bf16 = jnp.bfloat16
-
-    def S(*shape):
-        return jax.ShapeDtypeStruct(shape, bf16, sharding=one_chip)
+    n, h, f, E, K = OLMOE_ROWS
 
     def loss(*a):
         return routed_experts(*a, K)[0].astype(jnp.float32).sum()
 
-    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
-        S(n, h), S(h, E), S(E, h, f), S(E, h, f), S(E, f, h)).compile()
-    calls = [line for line in compiled.as_text().splitlines()
-             if 'custom_call_target="tpu_custom_call"' in line]
-    names = [re.match(r"\s*(?:ROOT )?%([A-Za-z_]+)", c).group(1)
-             for c in calls]
-    assert sorted(names) == ["gmm"] * 6 + ["tgmm"] * 3, names
-    assert all("moe_experts" in c for c in calls)
+    compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4))
+                       ).lower(S(n, h), S(h, E), S(E, h, f), S(E, h, f),
+                               S(E, f, h)).compile()
+    calls = _mosaic_calls(compiled.as_text())
+    assert sorted(name for name, _ in calls) == ["gmm"] * 6 + ["tgmm"] * 3
+    assert all("moe_experts" in line for _, line in calls)
     # rows, gate, up, activation, their cotangents: a few n*K-row arrays
-    assert compiled.memory_analysis().temp_size_in_bytes < 3 << 30
+    # in bf16 and none in float32. 675,798,528 compiled (PR 29; 1,078 MB
+    # with the weight behind the down projection) and a tenth
+    assert compiled.memory_analysis().temp_size_in_bytes < 709 << 20
+
+
+def test_rematted_routed_layer_recomputes_two_grouped_matmuls(
+        S, no_compile_cache, monkeypatch):
+    """The same layer as the train step runs it: stacked three deep
+    under ``llama.run_layers``' scan and its "full" ``jax.checkpoint``.
+    The backward loop's body holds five ``gmm`` (gate and up recomputed,
+    three transposed) and three ``tgmm``: nothing there asks for the
+    down projection's output, so its recomputation is gone."""
+    from ray_tpu.models import llama
+    from ray_tpu.ops.moe import routed_experts
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    n, h, f, E, K = OLMOE_ROWS
+
+    def loss(x, layers):
+        x, _ = llama.run_layers(
+            lambda x_, p: (x_ + routed_experts(x_, *p, K)[0], None),
+            x, layers, level="full", scan=True)
+        return x.astype(jnp.float32).sum()
+
+    layers = (S(3, h, E), S(3, E, h, f), S(3, E, h, f), S(3, E, f, h))
+    text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        S(n, h), layers).compile().as_text()
+    bodies = [sorted(name for name, _ in _mosaic_calls(body))
+              for body in re.split(r"\n(?=(?:ENTRY )?%[\w.\-]+ \()", text)]
+    assert sorted(b for b in bodies if b) == [
+        ["gmm"] * 3, ["gmm"] * 5 + ["tgmm"] * 3]
 
 
 def test_remat_plan_holds_against_the_compiler_at_7b_widths(
