@@ -22,6 +22,7 @@ torch; this is the flagship model the north-star configs name):
 
 from __future__ import annotations
 
+import itertools
 import math
 from dataclasses import dataclass, field, replace
 from typing import Any, Dict, Optional, Tuple
@@ -320,13 +321,17 @@ def _resolve_remat(cfg: LlamaConfig, params, tokens, mesh) -> str:
     return plan["level"]
 
 
-def _attend(cfg: LlamaConfig, q, k, v, mesh=None, seq_axis=None):
+def _attend(cfg: LlamaConfig, q, k, v, mesh=None, seq_axis=None,
+            window=None):
     impl = cfg.attn_impl
     if impl == "auto":
         impl = "flash" if jax.default_backend() == "tpu" else "reference"
+    if window is not None and impl in ("ring", "ulysses"):
+        raise ValueError(f"attn_impl={impl!r} knows no window: a window "
+                         "layer runs \"flash\" or \"reference\"")
     if impl == "flash":
         if mesh is None:
-            return flash_attention(q, k, v, causal=True)
+            return flash_attention(q, k, v, causal=True, window=window)
         # A pallas_call is opaque to GSPMD: left bare under a sharded jit,
         # XLA gathers the whole batch onto every chip and runs the kernel
         # on all of it. shard_map hands each chip its own batch rows (and
@@ -340,7 +345,8 @@ def _attend(cfg: LlamaConfig, q, k, v, mesh=None, seq_axis=None):
         heads = "tp" if tp > 1 and cfg.num_kv_heads % tp == 0 else None
         spec = P(resolve_axis("batch", mesh), None, heads, None)
         return jax.shard_map(
-            lambda q_, k_, v_: flash_attention(q_, k_, v_, causal=True),
+            lambda q_, k_, v_: flash_attention(q_, k_, v_, causal=True,
+                                               window=window),
             mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
             check_vma=False)(q, k, v)
     if impl in ("ring", "ulysses"):
@@ -362,14 +368,18 @@ def _attend(cfg: LlamaConfig, q, k, v, mesh=None, seq_axis=None):
         from ray_tpu.ops.ulysses import ulysses_attention
 
         return ulysses_attention(q, k, v, mesh, axis_name="sp", causal=True)
-    return attention_reference(q, k, v, causal=True)
+    return attention_reference(q, k, v, causal=True, window=window)
 
 
 def attention_block(cfg: LlamaConfig, x, p, cos, sin, mesh=None,
-                    seq_axis=None):
+                    seq_axis=None, window=None):
     """Pre-norm attention sub-block with residual: x + wo(attend(qkv)).
-    Shared by every model in the family (llama dense, mixtral and olmoe
-    MoE)."""
+    Shared by every model in the family (llama dense, mixtral, olmoe and
+    laguna MoE). The number of query heads is the layer's own, read from
+    its ``wq`` (Laguna's window layers have more than its full ones);
+    ``window``: the layer sees that many keys back (``flash_attention``);
+    a ``wg`` in ``p`` is a per-head output gate, ``sigmoid(norm(x) @ wg)``
+    on each head's output before ``wo`` (arXiv:2505.06708, headwise)."""
     # The named scopes here and below (embed, attn_qkv, flash, attn_out,
     # mlp, head_loss) are metadata only: they name the device time of a
     # step in a profiler trace and change no instruction.
@@ -390,7 +400,8 @@ def attention_block(cfg: LlamaConfig, x, p, cos, sin, mesh=None,
         if "q_norm" in p:  # OLMoE: RMSNorm over the whole q and k vectors
             q = rms_norm(q, p["q_norm"], cfg.rms_norm_eps)
             k = rms_norm(k, p["k_norm"], cfg.rms_norm_eps)
-        q = q.reshape(b, s, cfg.num_heads, hd)
+        heads = p["wq"].shape[-1] // hd
+        q = q.reshape(b, s, heads, hd)
         k = k.reshape(b, s, cfg.num_kv_heads, hd)
         v = v.reshape(b, s, cfg.num_kv_heads, hd)
         q = apply_rope(q, cos, sin)
@@ -400,10 +411,26 @@ def attention_block(cfg: LlamaConfig, x, p, cos, sin, mesh=None,
         q = checkpoint_name(q, "q_rope")
         k = checkpoint_name(k, "k_rope")
         v = checkpoint_name(v, "v_proj")
+        if "wg" in p:
+            with jax.named_scope("attn_gate"):
+                gate = jax.nn.sigmoid(jnp.dot(
+                    h1, p["wg"].astype(cfg.dtype),
+                    preferred_element_type=jnp.float32))
+    # a window layer's kernel calls are ``flash_win`` inside ``flash``: a
+    # reader that knows ``flash`` alone still finds them there
     with jax.named_scope("flash"):
-        attn = _attend(cfg, q, k, v, mesh=mesh, seq_axis=seq_axis)
+        if window is None:
+            attn = _attend(cfg, q, k, v, mesh=mesh, seq_axis=seq_axis)
+        else:
+            with jax.named_scope("flash_win"):
+                attn = _attend(cfg, q, k, v, mesh=mesh, seq_axis=seq_axis,
+                               window=window)
     with jax.named_scope("attn_out"):
-        attn = attn.reshape(b, s, cfg.num_heads * hd)
+        if "wg" in p:
+            with jax.named_scope("attn_gate"):
+                attn = (attn.astype(jnp.float32) * gate[..., None]
+                        ).astype(cfg.dtype)
+        attn = attn.reshape(b, s, heads * hd)
         attn_out = jnp.dot(
             attn, p["wo"].astype(cfg.dtype),
             preferred_element_type=jnp.float32).astype(cfg.dtype)
@@ -438,12 +465,26 @@ def remat_level_without_plan(cfg: LlamaConfig) -> Optional[str]:
     return "full" if cfg.remat else None
 
 
-def run_layers(layer_fn, x, layers, *, level: Optional[str], scan: bool):
+def run_layers(layer_fn, x, layers, *, level: Optional[str], scan: bool,
+               pattern: Optional[Tuple[str, ...]] = None):
     """The family's one loop over the stacked ``layers`` and its one
     ``jax.checkpoint``. ``layer_fn(x, p) -> (x, y)`` is one block (``y``
     may be None); returns the last ``x`` and the ``y``s stacked, as
     ``lax.scan`` does. ``level``: None (no remat), "full" or a level of
-    REMAT_LADDER. ``scan``: ``cfg.scan_layers``."""
+    REMAT_LADDER. ``scan``: ``cfg.scan_layers``.
+
+    ``pattern``: for a stack of unequal layers, the kind of each layer in
+    order, e.g. ``("dense", "win", "win", "win", "full")``; ``layer_fn``
+    and ``layers`` are then dicts by kind, a kind's layers stacked in
+    their order, and the ``y``s come back so too. A run of layers of one
+    kind is one ``lax.scan``; a layer between two of other kinds is
+    walked alone. (A period of several kinds that repeats is not scanned
+    as a period: no cell holds more than one.)"""
+    one_kind = pattern is None
+    if one_kind:
+        depth = jax.tree_util.tree_leaves(layers)[0].shape[0]
+        pattern, layer_fn, layers = (("layer",) * depth, {"layer": layer_fn},
+                                     {"layer": layers})
     if level is not None:
         names = remat_names(level)
         # Inside the scan the forward and the backward are two loops and
@@ -452,17 +493,30 @@ def run_layers(layer_fn, x, layers, *, level: Optional[str], scan: bool):
         # jax advises under scan: at 7B widths it cost a gigabyte of
         # XLA's heap and 5% of the step (PERF.md 6, PR 27). "full" keeps
         # the program it always had.
-        layer_fn = jax.checkpoint(
-            layer_fn, policy=jax.checkpoint_policies.save_only_these_names(
+        layer_fn = {kind: jax.checkpoint(
+            fn, policy=jax.checkpoint_policies.save_only_these_names(
                 *names) if names else None,
             prevent_cse=not (scan and level.startswith("level")))
-    if scan:
-        return jax.lax.scan(layer_fn, x, layers)
-    ys = []
-    for l in range(jax.tree_util.tree_leaves(layers)[0].shape[0]):
-        x, y = layer_fn(x, jax.tree_util.tree_map(lambda a: a[l], layers))
-        ys.append(y)
-    return x, jax.tree_util.tree_map(lambda *a: jnp.stack(a), *ys)
+            for kind, fn in layer_fn.items()}
+    tree_map = jax.tree_util.tree_map
+    taken = dict.fromkeys(layers, 0)      # layers of each kind walked so far
+    ys = {kind: [] for kind in layers}
+    for kind, run in itertools.groupby(pattern):
+        lo, n = taken[kind], len(list(run))
+        taken[kind] += n
+        if scan and n > 1:
+            whole = n == pattern.count(kind)
+            x, y = jax.lax.scan(layer_fn[kind], x, tree_map(
+                lambda a: a if whole else a[lo:lo + n], layers[kind]))
+            ys[kind].append(y)
+            continue
+        for at in range(lo, lo + n):
+            x, y = layer_fn[kind](x, tree_map(lambda a: a[at], layers[kind]))
+            ys[kind].append(tree_map(lambda a: a[None], y))
+    ys = {kind: y[0] if len(y) == 1
+          else tree_map(lambda *a: jnp.concatenate(a), *y)
+          for kind, y in ys.items() if y}
+    return x, ys["layer"] if one_kind else ys
 
 
 def forward(cfg: LlamaConfig, params: Dict[str, Any], tokens: jax.Array,

@@ -109,17 +109,24 @@ def _experts(cfg: OlmoeConfig, p, x: jax.Array, mesh=None):
         out_specs=(P(rows), P(rows), P()), check_vma=False)(x, *weights)
 
 
+def router_stats(logits: jax.Array, counts: jax.Array
+                 ) -> Dict[str, jax.Array]:
+    """What ``router_losses`` reads of one layer: the rows routed to each
+    expert, the mean router probability [E] and the mean squared
+    logsumexp of the router logits [n, E]."""
+    with jax.named_scope("moe_route"):
+        return {"counts": counts,
+                "prob": jax.nn.softmax(logits, axis=-1).mean(0),
+                "z": jnp.square(jax.nn.logsumexp(logits, axis=-1)).mean()}
+
+
 def _layer(cfg: OlmoeConfig, x, p, cos, sin, mesh=None,
            keep_router_logits: bool = False):
     x = llama.attention_block(cfg, x, p, cos, sin, mesh=mesh)
     with jax.named_scope("mlp"):
         h2 = rms_norm(x, p["mlp_norm"], cfg.rms_norm_eps)
         out, logits, counts = _experts(cfg, p, h2, mesh=mesh)
-        with jax.named_scope("moe_route"):
-            router = {
-                "counts": counts,
-                "prob": jax.nn.softmax(logits, axis=-1).mean(0),
-                "z": jnp.square(jax.nn.logsumexp(logits, axis=-1)).mean()}
+        router = router_stats(logits, counts)
         if keep_router_logits:
             router["logits"] = logits
         return x + out, router

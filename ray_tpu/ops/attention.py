@@ -45,12 +45,16 @@ def _auto_block(seq: int, target: int) -> int:
 
 def attention_reference(q: jax.Array, k: jax.Array, v: jax.Array,
                         causal: bool = True,
-                        sm_scale: Optional[float] = None) -> jax.Array:
+                        sm_scale: Optional[float] = None,
+                        window: Optional[int] = None) -> jax.Array:
     """Plain softmax attention (fp32 softmax), GQA-aware.
 
     q: [batch, seq_q, heads, head_dim]
     k, v: [batch, seq_k, kv_heads, head_dim]
+    ``window`` (with ``causal``): a query sees the ``window`` keys that
+    end at its own position, ``i - window < j <= i``.
     """
+    _check_window(causal, window)
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(q.shape[-1])
     n_rep = q.shape[2] // k.shape[2]
@@ -64,7 +68,10 @@ def attention_reference(q: jax.Array, k: jax.Array, v: jax.Array,
         sq, sk = scores.shape[-2], scores.shape[-1]
         qi = jax.lax.broadcasted_iota(jnp.int32, (sq, sk), 0)
         ki = jax.lax.broadcasted_iota(jnp.int32, (sq, sk), 1)
-        scores = jnp.where(qi + (sk - sq) >= ki, scores, _NEG_INF)
+        seen = qi + (sk - sq) >= ki
+        if window is not None:
+            seen &= qi + (sk - sq) - ki < window
+        scores = jnp.where(seen, scores, _NEG_INF)
     probs = jax.nn.softmax(scores.astype(jnp.float32), axis=-1)
     out = jnp.einsum(
         "bhqk,bkhd->bqhd", probs.astype(v.dtype), v,
@@ -73,12 +80,43 @@ def attention_reference(q: jax.Array, k: jax.Array, v: jax.Array,
     return out.astype(q.dtype)
 
 
+def _check_window(causal: bool, window: Optional[int]) -> None:
+    if window is not None and (not causal or window < 1):
+        raise ValueError(f"window={window!r} needs causal=True and at least "
+                         "one key (the query's own position)")
+
+
 # ---------------------------------------------------------------- pallas fwd
+#
+# With a ``window`` a kernel skips the key blocks behind the band as the
+# causal path skips those ahead of the diagonal: its loop starts at the
+# first key block that holds a key inside the window of the block's first
+# query (``_first_key_block``). A later query of the block may see nothing
+# of that block: its row is all ``_NEG_INF`` there, the running maximum
+# stays ``_NEG_INF`` and what it sums is wiped by ``alpha = 0`` at the next
+# block, which holds the query's own position.
+
+
+def _seen(q_pos, k_pos, window: Optional[int]):
+    """The causal mask, and the window's where there is one."""
+    mask = q_pos >= k_pos
+    if window is not None:
+        mask &= q_pos - k_pos < window
+    return mask
+
+
+def _first_key_block(qb, block_q: int, block_k: int, causal_offset: int,
+                     window: Optional[int]):
+    """The first key block the loop of query block ``qb`` visits."""
+    if window is None:
+        return 0
+    first_q = causal_offset + qb * block_q
+    return jnp.maximum(0, jax.lax.div(first_q - (window - 1), block_k))
 
 
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k: int,
                   causal: bool, sm_scale: float, seq_k: int, block_q: int,
-                  causal_offset: int = 0):
+                  causal_offset: int = 0, window: Optional[int] = None):
     # q_ref: [1, block_q, d]; k_ref/v_ref: [1, seq_k, d]; o_ref: [1, block_q, d]
     # lse_ref: [1, block_q] per-row logsumexp of the scaled scores (the only
     # extra forward state the FA-2 backward needs).
@@ -107,7 +145,8 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k: int,
         if causal:
             qi = jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
             ki = jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
-            mask = (causal_offset + qb * block_q + qi) >= (kb * block_k + ki)
+            mask = _seen(causal_offset + qb * block_q + qi,
+                         kb * block_k + ki, window)
             s = jnp.where(mask, s, _NEG_INF)
         m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
         p = jnp.exp(s - m_new)
@@ -128,7 +167,8 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k: int,
         upper = jax.lax.div(last_q, block_k) + 1
     else:
         upper = num_kv_blocks
-    acc, m, l = jax.lax.fori_loop(0, upper, body, init)
+    lower = _first_key_block(qb, block_q, block_k, causal_offset, window)
+    acc, m, l = jax.lax.fori_loop(lower, upper, body, init)
     l_safe = jnp.maximum(l, 1e-30)
     o_ref[0] = (acc / l_safe).astype(o_ref.dtype)
     lse_ref[0] = m + jnp.log(l_safe)  # [block_q, 1]
@@ -145,7 +185,8 @@ def _check_blocks(sq, sk, block_q, block_k):
     return block_q, block_k
 
 
-def _flash_forward(q, k, v, causal, sm_scale, block_q, block_k, interpret):
+def _flash_forward(q, k, v, causal, sm_scale, block_q, block_k, interpret,
+                   window=None):
     """q: [b, sq, h, d]; k/v: [b, sk, kvh, d] → ([b, sq, h, d], lse[b*h, sq, 1]).
 
     The logsumexp rides in a trailing singleton lane dim — TPU block shapes
@@ -174,11 +215,13 @@ def _flash_forward(q, k, v, causal, sm_scale, block_q, block_k, interpret):
 
     kernel = functools.partial(
         _flash_kernel, block_k=block_k, causal=causal, sm_scale=sm_scale,
-        seq_k=sk, block_q=block_q, causal_offset=sk - sq,
+        seq_k=sk, block_q=block_q, causal_offset=sk - sq, window=window,
     )
     out, lse = pl.pallas_call(
         kernel,
-        name="flash_fwd",
+        # the window calls carry names of their own, here and below, so a
+        # trace tells them from the causal calls of the same step
+        name="flash_fwd" if window is None else "flash_win_fwd",
         out_shape=[
             jax.ShapeDtypeStruct((b * h, sq, d), q.dtype),
             jax.ShapeDtypeStruct((b * h, sq, 1), jnp.float32),
@@ -209,7 +252,7 @@ def _flash_forward(q, k, v, causal, sm_scale, block_q, block_k, interpret):
 def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                          dq_ref, *, block_k: int, causal: bool,
                          sm_scale: float, seq_k: int, block_q: int,
-                         causal_offset: int):
+                         causal_offset: int, window: Optional[int] = None):
     import jax.experimental.pallas as pl
 
     qb = pl.program_id(1)
@@ -233,7 +276,8 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         if causal:
             qi = jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
             ki = jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
-            mask = (causal_offset + qb * block_q + qi) >= (kb * block_k + ki)
+            mask = _seen(causal_offset + qb * block_q + qi,
+                         kb * block_k + ki, window)
             s = jnp.where(mask, s, _NEG_INF)
         p = jnp.exp(s - lse)                             # [block_q, block_k]
         dp = jax.lax.dot_general(
@@ -247,15 +291,16 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         )
 
     upper = jax.lax.div(last_q, block_k) + 1 if causal else num_kv_blocks
+    lower = _first_key_block(qb, block_q, block_k, causal_offset, window)
     dq = jax.lax.fori_loop(
-        0, upper, body, jnp.zeros((block_q, d), jnp.float32))
+        lower, upper, body, jnp.zeros((block_q, d), jnp.float32))
     dq_ref[0] = (dq * sm_scale).astype(dq_ref.dtype)
 
 
 def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                           dk_ref, dv_ref, *, block_q: int, causal: bool,
                           sm_scale: float, seq_q: int, block_k: int,
-                          causal_offset: int):
+                          causal_offset: int, window: Optional[int] = None):
     import jax.experimental.pallas as pl
 
     kb = pl.program_id(1)
@@ -278,7 +323,8 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         if causal:
             qi = jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
             ki = jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
-            mask = (causal_offset + qb * block_q + qi) >= (kb * block_k + ki)
+            mask = _seen(causal_offset + qb * block_q + qi,
+                         kb * block_k + ki, window)
             s = jnp.where(mask, s, _NEG_INF)
         p = jnp.exp(s - lse)                             # [block_q, block_k]
         dv_new = dv + jax.lax.dot_general(
@@ -302,16 +348,40 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             0, jax.lax.div(kb * block_k - causal_offset, block_q))
     else:
         lower = 0
+    upper = num_q_blocks
+    if window is not None:
+        # the last q row that sees the block's last key: its position
+        # less than that key's plus the window
+        last_q = (kb + 1) * block_k - 1 + window - 1 - causal_offset
+        upper = jnp.minimum(upper, jax.lax.div(last_q, block_q) + 1)
     dk, dv = jax.lax.fori_loop(
-        lower, num_q_blocks, body,
+        lower, upper, body,
         (jnp.zeros((block_k, d), jnp.float32),
          jnp.zeros((block_k, d), jnp.float32)))
     dk_ref[0] = (dk * sm_scale).astype(dk_ref.dtype)
     dv_ref[0] = dv.astype(dv_ref.dtype)
 
 
+_SCOPED_VMEM = 16 << 20        # Mosaic's default limit for one kernel
+
+
+def _dkv_vmem(sq: int, d: int, dtype) -> dict:
+    """The dK/dV kernel holds a head's whole q and dO and the two [sq, 1]
+    float32 columns (128 lanes wide in VMEM), each twice for the pipeline:
+    12 MB at 4,096 queries, 24.5 at 8,192, over Mosaic's default limit of
+    16 (v5e has 128 MiB). Past the default the call asks for what it
+    needs; under it the call is the one it always was."""
+    need = 2 * (2 * sq * d * jnp.dtype(dtype).itemsize + 2 * sq * 128 * 4)
+    if need + (2 << 20) <= _SCOPED_VMEM:
+        return {}
+    from jax.experimental.pallas import tpu as pltpu
+
+    return {"compiler_params": pltpu.CompilerParams(
+        vmem_limit_bytes=need + (8 << 20))}
+
+
 def _flash_backward(q, k, v, out, lse, g, causal, sm_scale, block_q, block_k,
-                    interpret):
+                    interpret, window=None):
     import jax.experimental.pallas as pl
 
     b, sq, h, d = q.shape
@@ -349,8 +419,8 @@ def _flash_backward(q, k, v, out, lse, g, causal, sm_scale, block_q, block_k,
         functools.partial(
             _flash_bwd_dq_kernel, block_k=block_k, causal=causal,
             sm_scale=sm_scale, seq_k=sk, block_q=block_q,
-            causal_offset=causal_offset),
-        name="flash_bwd_dq",
+            causal_offset=causal_offset, window=window),
+        name="flash_bwd_dq" if window is None else "flash_win_bwd_dq",
         out_shape=jax.ShapeDtypeStruct((b * h, sq, d), q.dtype),
         grid=(b * h, sq // block_q),
         in_specs=[
@@ -372,8 +442,8 @@ def _flash_backward(q, k, v, out, lse, g, causal, sm_scale, block_q, block_k,
         functools.partial(
             _flash_bwd_dkv_kernel, block_q=block_q, causal=causal,
             sm_scale=sm_scale, seq_q=sq, block_k=block_k,
-            causal_offset=causal_offset),
-        name="flash_bwd_dkv",
+            causal_offset=causal_offset, window=window),
+        name="flash_bwd_dkv" if window is None else "flash_win_bwd_dkv",
         out_shape=[
             jax.ShapeDtypeStruct((b * h, sk, d), k.dtype),
             jax.ShapeDtypeStruct((b * h, sk, d), v.dtype),
@@ -392,6 +462,7 @@ def _flash_backward(q, k, v, out, lse, g, causal, sm_scale, block_q, block_k,
             pl.BlockSpec((1, block_k, d), lambda i, kb: (i, kb, 0)),
         ],
         interpret=interpret,
+        **_dkv_vmem(sq, d, q.dtype),
     )(qt, kt, vt, dot, lse, delta)
 
     dq = dq.reshape(b, h, sq, d).transpose(0, 2, 1, 3)
@@ -403,16 +474,17 @@ def _flash_backward(q, k, v, out, lse, g, causal, sm_scale, block_q, block_k,
     return dq.astype(q.dtype), dk, dv
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
-def _flash(q, k, v, causal, sm_scale, block_q, block_k, interpret):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+def _flash(q, k, v, causal, sm_scale, block_q, block_k, interpret, window):
     out, _ = _flash_forward(q, k, v, causal, sm_scale, block_q, block_k,
-                            interpret)
+                            interpret, window)
     return out
 
 
-def _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret):
+def _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret,
+               window):
     out, lse = _flash_forward(q, k, v, causal, sm_scale, block_q, block_k,
-                              interpret)
+                              interpret, window)
     # Named where they are born, for a layer's remat policy
     # (models/llama.py REMAT_LADDER): a policy that keeps both drops the
     # backward's second run of the forward kernel; a name given outside
@@ -425,10 +497,11 @@ def _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret):
     return out, (q, k, v, out, lse)
 
 
-def _flash_bwd(causal, sm_scale, block_q, block_k, interpret, res, g):
+def _flash_bwd(causal, sm_scale, block_q, block_k, interpret, window, res,
+               g):
     q, k, v, out, lse = res
     return _flash_backward(q, k, v, out, lse[..., None], g, causal,
-                           sm_scale, block_q, block_k, interpret)
+                           sm_scale, block_q, block_k, interpret, window)
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
@@ -439,22 +512,29 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                     block_q: Optional[int] = None,
                     block_k: Optional[int] = None,
                     use_pallas: Optional[bool] = None,
-                    interpret: bool = False) -> jax.Array:
+                    interpret: bool = False,
+                    window: Optional[int] = None) -> jax.Array:
     """Flash attention. Layout: q [b, sq, heads, d]; k/v [b, sk, kv_heads, d].
+
+    ``window`` (with ``causal``): a query sees the ``window`` keys that
+    end at its own position; the kernels visit the band's key blocks
+    alone, so their time grows with the window and not with the sequence.
 
     ``use_pallas=None`` auto-selects: the Pallas kernel on TPU backends, the
     reference path elsewhere (tests force the kernel with interpret=True).
     ``block_q``/``block_k`` default to the largest power-of-two divisor of
     the sequence length up to 512.
     """
+    _check_window(causal, window)
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(q.shape[-1])
     if use_pallas is None:
         use_pallas = jax.default_backend() not in ("cpu",)
     if not use_pallas:
-        return attention_reference(q, k, v, causal, sm_scale)
+        return attention_reference(q, k, v, causal, sm_scale, window)
     if block_q is None:
         block_q = _auto_block(q.shape[1], DEFAULT_BLOCK_Q)
     if block_k is None:
         block_k = _auto_block(k.shape[1], DEFAULT_BLOCK_K)
-    return _flash(q, k, v, causal, sm_scale, block_q, block_k, interpret)
+    return _flash(q, k, v, causal, sm_scale, block_q, block_k, interpret,
+                  window)

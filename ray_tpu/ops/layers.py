@@ -134,9 +134,17 @@ def apply_rope(x: jax.Array, cos: jax.Array, sin: jax.Array,
                positions: Optional[jax.Array] = None) -> jax.Array:
     """Apply rotary embeddings.
 
-    x: [..., seq, heads, head_dim]; cos/sin: [max_seq, head_dim//2];
+    x: [..., seq, heads, head_dim]; cos/sin: [max_seq, rotated // 2];
     positions: [..., seq] absolute positions (defaults to arange).
+    The first ``2 * cos.shape[-1]`` dims of a head are rotated (as two
+    halves) and the rest pass: a partial rotary factor is the width the
+    tables were made for.
     """
+    rotated = 2 * cos.shape[-1]
+    if rotated < x.shape[-1]:
+        return jnp.concatenate(
+            [apply_rope(x[..., :rotated], cos, sin, positions),
+             x[..., rotated:]], axis=-1)
     seq = x.shape[-3]
     if positions is None:
         c = cos[:seq][:, None, :]

@@ -1,7 +1,7 @@
 """Routed mixture of SwiGLU experts: sorted, dropless, every shape static.
 
-The layer the many-small-expert models share (OLMoE, Mixtral here;
-Moonlight, DeepSeek-V2-Lite, Trinity-Mini on the roadmap). A token picks
+The layer the many-small-expert models share (OLMoE, Mixtral, Laguna).
+A token picks
 ``top_k`` of ``E`` experts; the ``n * top_k`` (token, choice) pairs are
 sorted by expert, the token rows gathered into that order, and each
 expert multiplies its own contiguous group of rows: three grouped
@@ -29,6 +29,23 @@ TFLOP/s at OLMoE's shapes against megablox's 128-133 (gmm) and 111-114
 (tgmm) at the tiles below, and which drops the scope names from its calls
 (v5e, PR 26, PERF.md).
 
+``held=(first, count)`` is the layer expert parallelism needs, run
+without its exchange: the weights of experts ``first .. first + count``
+of ``E`` live here, the router, the softmax, the top-k and ``counts`` are
+over all ``E``, and the result is the part those experts give. The pairs
+are sorted with the held experts' first; what follows them is never
+gathered or multiplied. How many rows that is, is data, and a buffer for
+the worst case (every row routed here) would be ``n * top_k`` rows wide,
+a gigabyte at Laguna's cell: the held rows are taken in passes of a
+static ``chunk`` of rows (twice the balanced share), as many passes as
+the rows need, each a gather, three grouped matmuls over the pass's
+groups and a scatter-add into the tokens' sums. One pass at a balanced
+routing, none where no row is held; no routing, however uneven, drops a
+row or compiles anything, and the grouped matmuls touch the row tiles
+the pass's groups fill and no other: the work follows the rows held.
+The loop's trip count is data, so its gradient is written out
+(``_held_experts``): the same passes, each the transpose of its forward.
+
 Named scopes (metadata only, nested under the caller's ``mlp``; a
 backward operation carries the scope of the call it transposes):
 ``moe_route`` (router matmul, softmax, top-k, sort), ``moe_dispatch``
@@ -39,7 +56,7 @@ activation, the gate weighting in it), ``moe_combine`` (gather back, sum).
 from __future__ import annotations
 
 from functools import partial
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -87,12 +104,24 @@ _place.defvjp(lambda values, to: (_place(values, to), to),
 # skewed alike: 256 rows a tile (a group's last tile is part empty: the
 # smaller, the less is wasted), the whole contraction, and as many
 # columns as keep a weight tile at 2M elements (VMEM). tgmm wants 1024s.
+# A tile that does not divide its dimension leaves the last one part
+# empty too: at 3072 a tile of 2048 cost 12-19% over one of 1536 (v5e,
+# 16 groups of 640 and 1,280 rows, PR 30), so both are divisors.
 _ROW_TILE = 256
 
 
+def _divisor_tile(size: int, limit: int) -> int:
+    """The largest multiple of 128 that divides ``size`` and is at most
+    ``limit``; ``size`` itself where it fits or has no such divisor."""
+    if size <= limit:
+        return size
+    return next((t for t in range(limit - limit % 128, 0, -128)
+                 if size % t == 0), min(size, limit))
+
+
 def _gmm_tiles(k: int, n: int) -> Tuple[int, int, int]:
-    tk = min(k, 2048)
-    return _ROW_TILE, tk, min(n, (2 << 20) // tk)
+    tk = _divisor_tile(k, 2048)
+    return _ROW_TILE, tk, _divisor_tile(n, (2 << 20) // tk)
 
 
 def _megablox():
@@ -137,34 +166,159 @@ def grouped_matmul(rows: jax.Array, w: jax.Array, sizes: jax.Array
 
 
 def route(x: jax.Array, router_w: jax.Array, top_k: int,
-          renormalize: bool = False
+          renormalize: bool = False, scale: float = 1.0
           ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """(router_logits [n, E] float32, top_w [n, K] float32, top_e [n, K]
     int32): logits accumulate in float32, softmax in float32 over all E,
     then the K largest; ``renormalize`` divides the K weights by their
-    sum (Mixtral does, OLMoE does not)."""
+    sum (Mixtral does, OLMoE does not); ``scale`` multiplies them after
+    that (Laguna's routed scaling factor)."""
     logits = jnp.dot(x, router_w.astype(x.dtype),
                      preferred_element_type=jnp.float32)
     probs = jax.nn.softmax(logits, axis=-1)
     top_w, top_e = jax.lax.top_k(probs, top_k)
     if renormalize:
         top_w = top_w / top_w.sum(-1, keepdims=True)
+    if scale != 1.0:
+        top_w = top_w * scale
     return logits, top_w, top_e
+
+
+def _swiglu_rows(rows, w_rows, sizes, e_gate, e_up, e_down):
+    """rows [m, h] in groups of ``sizes``, their gate weights w_rows [m]
+    float32 -> [m, h]: the experts' three grouped matmuls, the gate
+    weight inside the activation's elementwise pass (the module's
+    docstring)."""
+    dt = rows.dtype
+    gate = grouped_matmul(rows, e_gate.astype(dt), sizes)
+    up = grouped_matmul(rows, e_up.astype(dt), sizes)
+    act = (jax.nn.silu(gate.astype(jnp.float32))
+           * up.astype(jnp.float32) * w_rows[:, None])
+    return grouped_matmul(act.astype(dt), e_down.astype(dt), sizes)
+
+
+def _held_chunk(num_pairs: int, count: int, num_experts: int) -> int:
+    """Rows a pass of the held experts takes: twice their balanced share
+    of the ``num_pairs`` (token, choice) pairs, in whole row tiles."""
+    share = -(-2 * num_pairs * count // num_experts)
+    return min(-(-share // _ROW_TILE), -(-num_pairs // _ROW_TILE)) * _ROW_TILE
+
+
+def _held_passes(sizes, chunk: int):
+    """Passes the held rows need: none where no row is held."""
+    return -(-sizes.sum() // chunk)
+
+
+def _held_pass(i, x, w_pairs, order, sizes, top_k: int, chunk: int):
+    """Pass ``i`` over the held rows: (pair ids [chunk], which of them are
+    held rows [chunk], their tokens, their rows of ``x``, their gate
+    weights, the pass's group sizes [count])."""
+    lo = i * chunk
+    with jax.named_scope("moe_dispatch"):
+        ends = jnp.cumsum(sizes)
+        pairs = jax.lax.dynamic_slice(order, (lo,), (chunk,))
+        valid = lo + jnp.arange(chunk) < ends[-1]
+        tokens = pairs // top_k
+        in_pass = (jnp.clip(ends, lo, lo + chunk)
+                   - jnp.clip(ends - sizes, lo, lo + chunk))
+        return pairs, valid, tokens, x[tokens], w_pairs[pairs], in_pass
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(7, 8))
+def _held_experts(x, w_pairs, e_gate, e_up, e_down, order, sizes, top_k,
+                  chunk):
+    """x [n, h], w_pairs [n * top_k] float32 (pair = token * top_k +
+    choice), the held experts' weights [count, ...], order [n * top_k +
+    chunk]: pair ids, the held experts' first and in expert order, then
+    padding; sizes [count]: rows of each held expert -> [n, h], the sum
+    over a token's held choices, accumulated in float32."""
+
+    def one_pass(i, out):
+        _, valid, tokens, rows, w_rows, in_pass = _held_pass(
+            i, x, w_pairs, order, sizes, top_k, chunk)
+        with jax.named_scope("moe_experts"):
+            y = _swiglu_rows(rows, w_rows, in_pass, e_gate, e_up, e_down)
+        with jax.named_scope("moe_combine"):
+            # a row past the pass's groups is written by no kernel
+            y = jnp.where(valid[:, None], y.astype(jnp.float32), 0.0)
+            return out.at[tokens].add(y)
+
+    out = jax.lax.fori_loop(0, _held_passes(sizes, chunk), one_pass,
+                            jnp.zeros(x.shape, jnp.float32))
+    return out.astype(x.dtype)
+
+
+def _held_experts_fwd(x, w_pairs, e_gate, e_up, e_down, order, sizes, top_k,
+                      chunk):
+    return (_held_experts(x, w_pairs, e_gate, e_up, e_down, order, sizes,
+                          top_k, chunk),
+            (x, w_pairs, e_gate, e_up, e_down, order, sizes))
+
+
+def _held_experts_bwd(top_k, chunk, res, d_out):
+    x, w_pairs, e_gate, e_up, e_down, order, sizes = res
+    weights = (e_gate, e_up, e_down)
+
+    def one_pass(i, carry):
+        d_x, d_w, d_weights = carry
+        pairs, valid, tokens, rows, w_rows, in_pass = _held_pass(
+            i, x, w_pairs, order, sizes, top_k, chunk)
+        with jax.named_scope("moe_combine"):
+            d_y = jnp.where(valid[:, None], d_out[tokens], 0)
+        with jax.named_scope("moe_experts"):
+            _, transpose = jax.vjp(
+                lambda r, w, *ws: _swiglu_rows(r, w, in_pass, *ws),
+                rows, w_rows, *weights)
+            d_rows, d_w_rows, *d_ws = transpose(d_y)
+        with jax.named_scope("moe_dispatch"):
+            d_rows = jnp.where(valid[:, None], d_rows.astype(jnp.float32), 0.0)
+            d_w_rows = jnp.where(valid, d_w_rows, 0.0)
+            return (d_x.at[tokens].add(d_rows), d_w.at[pairs].add(d_w_rows),
+                    tuple(a + b for a, b in zip(d_weights, d_ws)))
+
+    d_x, d_w, d_weights = jax.lax.fori_loop(
+        0, _held_passes(sizes, chunk), one_pass,
+        (jnp.zeros(x.shape, jnp.float32), jnp.zeros_like(w_pairs),
+         tuple(jnp.zeros_like(w) for w in weights)))
+    return (d_x.astype(x.dtype), d_w) + d_weights + (None, None)
+
+
+_held_experts.defvjp(_held_experts_fwd, _held_experts_bwd)
 
 
 def routed_experts(x: jax.Array, router_w: jax.Array, e_gate: jax.Array,
                    e_up: jax.Array, e_down: jax.Array, top_k: int,
-                   renormalize: bool = False
+                   renormalize: bool = False,
+                   held: Optional[Tuple[int, int]] = None,
+                   scale: float = 1.0
                    ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """x [n, h], router_w [h, E], e_gate / e_up [E, h, f], e_down
     [E, f, h] -> (out [n, h], router_logits [n, E] float32, counts [E]
-    int32: rows each expert multiplied, n * top_k in all). A row's gate
-    weight goes into its activation, before the down projection (above)."""
+    int32: rows routed to each expert, n * top_k in all). A row's gate
+    weight goes into its activation, before the down projection (above).
+    ``held=(first, count)``: the expert weights are those of experts
+    ``first .. first + count`` alone, ``[count, ...]``, and ``out`` is
+    their part of the result (the module's docstring); ``None``: all
+    ``E`` are here."""
     num_experts = router_w.shape[-1]
-    dt = x.dtype
     with jax.named_scope("moe_route"):
-        logits, top_w, top_e = route(x, router_w, top_k, renormalize)
+        logits, top_w, top_e = route(x, router_w, top_k, renormalize, scale)
         flat_e = top_e.reshape(-1)
+        if held is not None:
+            first, count = held
+            counts = (flat_e[:, None] == jnp.arange(num_experts)[None, :]
+                      ).sum(0, dtype=jnp.int32)
+            # the held experts' pairs first, an expert's in token order
+            local = flat_e - first
+            key = jnp.where((local >= 0) & (local < count), local, count)
+            chunk = _held_chunk(flat_e.size, count, num_experts)
+            order = jnp.pad(jnp.argsort(key, stable=True).astype(jnp.int32),
+                            (0, chunk))
+            out = _held_experts(
+                x, top_w.reshape(-1), e_gate, e_up, e_down, order,
+                jax.lax.dynamic_slice(counts, (first,), (count,)), top_k,
+                chunk)
+            return out, logits, counts
         # stable: an expert's rows stay in token order
         order = jnp.argsort(flat_e, stable=True).astype(jnp.int32)
         inv = jnp.argsort(order)
@@ -175,11 +329,7 @@ def routed_experts(x: jax.Array, router_w: jax.Array, e_gate: jax.Array,
         rows = _dispatch(x, token_of, inv, top_k)
         w_rows = _place(top_w.reshape(-1), inv)
     with jax.named_scope("moe_experts"):
-        gate = grouped_matmul(rows, e_gate.astype(dt), counts)
-        up = grouped_matmul(rows, e_up.astype(dt), counts)
-        act = (jax.nn.silu(gate.astype(jnp.float32))
-               * up.astype(jnp.float32) * w_rows[:, None])
-        rows = grouped_matmul(act.astype(dt), e_down.astype(dt), counts)
+        rows = _swiglu_rows(rows, w_rows, counts, e_gate, e_up, e_down)
     with jax.named_scope("moe_combine"):
         out = _combine(rows, token_of, inv, top_k)
     return out, logits, counts

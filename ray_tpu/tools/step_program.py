@@ -3,7 +3,7 @@ moves model code without meaning to change a program compares, parent
 against change, before any chip does.
 
 For every training cell of ``BENCHMARK.json`` the cell's adamw step
-(``benchmark/cells/train.py`` and ``train_moe.py``: the cell's
+(``benchmark/cells/train.py``, ``train_moe.py`` and ``train_mixed.py``: the cell's
 configuration, batch, mesh and donation) is compiled for a v5e host that
 is described and not attached, with ``jax.default_backend`` answering
 "tpu" and ``llama._device_capacity`` a v5e chip's limit, as
@@ -11,7 +11,7 @@ is described and not attached, with ``jax.default_backend`` answering
 comes from here. Three 7B-width compiles take minutes, so this is a
 script and not a tier-1 test.
 
-    python ray_tpu/tools/step_program.py --tree <checkout> --out <dir>
+    python ray_tpu/tools/step_program.py --tree <checkout> --out <dir> [--cell <name>]
     python ray_tpu/tools/step_program.py --compare <dir-a> <dir-b>
 
 Run as a file and not with ``-m``: ``--tree`` (default: this checkout) is
@@ -54,7 +54,7 @@ def _compile_cell(tree: str, cell: dict, topo) -> dict:
     from jax.sharding import NamedSharding, PartitionSpec as P
     from jax.sharding import SingleDeviceSharding
 
-    from ray_tpu.models import llama, olmoe
+    from importlib import import_module
     from ray_tpu.parallel import MeshSpec, build_mesh
     from ray_tpu.parallel.sharding import batch_sharding
     from ray_tpu.util import tracing
@@ -64,14 +64,16 @@ def _compile_cell(tree: str, cell: dict, topo) -> dict:
             return json.load(f)
 
     tr = load("traffic", cell["traffic"])
-    kw = dict(load("configs", cell["config"])["model_config"])
+    kw = {k: tuple(v) if isinstance(v, list) else v for k, v in
+          load("configs", cell["config"])["model_config"].items()}
     preset = kw.pop("preset")
     for key in ("dtype", "param_dtype"):
         kw[key] = getattr(jnp, kw[key])
-    moe = tr["family"] == "train_moe"
-    mod = olmoe if moe else llama
-    cfg_cls = olmoe.OlmoeConfig if moe else llama.LlamaConfig
-    cfg = getattr(cfg_cls, preset)(**kw, attn_impl="auto")
+    moe = tr["family"] != "train"      # the step returns its expert counts
+    name = kw.pop("module", "olmoe" if moe else "llama")
+    mod = import_module("ray_tpu.models." + name)
+    cfg = getattr(getattr(mod, name.capitalize() + "Config"), preset)(
+        **kw, attn_impl="auto")
     devs = topo.devices[:cell["chips"]]
     mesh = None
     rep = psh = bsh = SingleDeviceSharding(devs[0])
@@ -86,21 +88,24 @@ def _compile_cell(tree: str, cell: dict, topo) -> dict:
         return jax.tree_util.tree_map(lambda a, s: jax.ShapeDtypeStruct(
             a.shape, a.dtype, sharding=s), tree_, sh)
 
-    tx = optax.adamw(tr["lr"])
+    lr = tr["lr"]
+    if tr.get("lr_warmup_steps"):     # as the cell's runner builds it
+        lr = optax.linear_schedule(0.0, lr, tr["lr_warmup_steps"])
+    tx = optax.adamw(lr)
     shapes = jax.eval_shape(lambda k: mod.init_params(cfg, k),
                             jax.random.PRNGKey(0))
     params = placed(shapes, psh)
     # the moments lie where the parameters do; the step count is one scalar
     opt = jax.eval_shape(tx.init, shapes)
     opt = (opt[0]._replace(count=placed(opt[0].count, rep), mu=params,
-                           nu=params),) + tuple(opt[1:])
+                           nu=params),) + placed(tuple(opt[1:]), rep)
     batch = {"tokens": jax.ShapeDtypeStruct(
         (tr["batch"], tr["seq"] + 1), jnp.int32, sharding=bsh)}
 
     if moe:
         def step(params, opt, batch):
             (loss, aux), grads = jax.value_and_grad(
-                lambda p: olmoe.loss_terms(cfg, p, batch, mesh=mesh),
+                lambda p: mod.loss_terms(cfg, p, batch, mesh=mesh),
                 has_aux=True)(params)
             updates, opt = tx.update(grads, opt, params)
             return (optax.apply_updates(params, updates), opt, loss,
@@ -108,7 +113,7 @@ def _compile_cell(tree: str, cell: dict, topo) -> dict:
     else:
         def step(params, opt, batch):
             loss, grads = jax.value_and_grad(
-                lambda p: llama.loss_fn(cfg, p, batch, mesh=mesh))(params)
+                lambda p: mod.loss_fn(cfg, p, batch, mesh=mesh))(params)
             updates, opt = tx.update(grads, opt, params)
             return optax.apply_updates(params, updates), opt, loss
 
@@ -127,7 +132,7 @@ def _compile_cell(tree: str, cell: dict, topo) -> dict:
         "memory_analysis": {f: getattr(ma, f) for f in MEMORY_FIELDS}}}
 
 
-def compile_cells(tree: str, out: str) -> None:
+def compile_cells(tree: str, out: str, only=()) -> None:
     sys.path.insert(0, tree)
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     import jax
@@ -149,7 +154,8 @@ def compile_cells(tree: str, out: str) -> None:
     llama._device_capacity = lambda mesh: V5E_BYTES_LIMIT
     with open(os.path.join(tree, "BENCHMARK.json")) as f:
         cells = [c for c in json.load(f)["workloads"]
-                 if c["traffic"].startswith("train")]
+                 if c["traffic"].startswith("train")
+                 and (not only or c["name"] in only)]
     os.makedirs(out, exist_ok=True)
     summary = {}
     for cell in cells:
@@ -192,13 +198,15 @@ def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--tree", default=here)
     ap.add_argument("--out")
+    ap.add_argument("--cell", action="append", default=[],
+                    help="compile this cell alone (may be given again)")
     ap.add_argument("--compare", nargs=2, metavar="DIR")
     args = ap.parse_args()
     if args.compare:
         return compare(*args.compare)
     if not args.out:
         ap.error("--out or --compare")
-    compile_cells(os.path.abspath(args.tree), args.out)
+    compile_cells(os.path.abspath(args.tree), args.out, args.cell)
     return 0
 
 

@@ -73,7 +73,10 @@ class TrainContext:
 
 # what a routed-experts train loop may put into ``train.report``: the
 # session serves the last value of each as ``rtpu_train_<key>``
-MOE_COUNTERS = ("moe_rows_routed", "moe_expert_load_max_over_mean")
+# (``moe_rows_held``: of the routed rows, those the experts held here
+# multiplied, where a layer holds a share of its experts)
+MOE_COUNTERS = ("moe_rows_routed", "moe_rows_held",
+                "moe_expert_load_max_over_mean")
 
 
 class SessionInterruptedError(BaseException):

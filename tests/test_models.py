@@ -317,7 +317,7 @@ def test_olmoe_gradients_match_the_reference(olmoe_setup):
 
 
 @pytest.mark.parametrize("remat", [False, True], ids=["remat-off", "remat-on"])
-@pytest.mark.parametrize("model", ["mixtral", "olmoe"])
+@pytest.mark.parametrize("model", ["mixtral", "olmoe", "laguna"])
 def test_moe_unrolled_matches_scan(model, remat):
     """``scan_layers=False`` is ``llama.run_layers``' branch for every
     forward of the family: the routed models' loss, gradients and
@@ -325,10 +325,13 @@ def test_moe_unrolled_matches_scan(model, remat):
     scan's, with and without a checkpoint around each layer."""
     from dataclasses import replace
 
-    from ray_tpu.models import mixtral, olmoe
+    from ray_tpu.models import laguna, mixtral, olmoe
 
+    # laguna: three kinds of layer, walked by its pattern (a scan over
+    # the three sliding layers between two single ones)
     mod, cls = {"mixtral": (mixtral, mixtral.MixtralConfig),
-                "olmoe": (olmoe, olmoe.OlmoeConfig)}[model]
+                "olmoe": (olmoe, olmoe.OlmoeConfig),
+                "laguna": (laguna, laguna.LagunaConfig)}[model]
     scanned = cls.tiny(attn_impl="reference", remat=remat)
     params = mod.init_params(scanned, jax.random.PRNGKey(0))
     tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 17), 0,
@@ -353,6 +356,186 @@ def test_moe_unrolled_matches_scan(model, remat):
     if model == "olmoe":
         assert got[2]["counts"].shape == (scanned.num_layers,
                                           scanned.num_experts)
+
+
+# ------------------------------------------------------------------- laguna
+
+
+@pytest.fixture(scope="module", params=[None, (4, 8)],
+                ids=["all-experts", "held-4..11"])
+def laguna_setup(request):
+    """``LagunaConfig.tiny()``: five layers (full + dense MLP, three
+    sliding, full), 4 and 6 query heads on 2 kv heads, window 8 at 32
+    positions, 16 experts top-4 and a shared one, float32; all experts
+    here, or experts 4..11 of each routed layer as a chip's share."""
+    import os
+    import sys
+
+    sys.path.insert(0, os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))          # benchmark/ lies beside tests/
+    from benchmark.references import laguna_ref
+    from ray_tpu.models import laguna
+
+    cfg = laguna.LagunaConfig.tiny(attn_impl="reference",
+                                   experts_held=request.param)
+    params = laguna.init_params(cfg, jax.random.PRNGKey(0))
+    # the norms start at 1: move them, or a norm applied twice goes unseen
+    for n, kind in enumerate(params["layers"]):
+        for i, name in enumerate(("attn_norm", "mlp_norm")):
+            w = params["layers"][kind][name]
+            params["layers"][kind][name] = 1.0 + 0.3 * jax.random.normal(
+                jax.random.PRNGKey(10 * n + i), w.shape)
+    tokens = np.random.default_rng(1).integers(0, cfg.vocab_size, (2, 33))
+    return laguna, laguna_ref, cfg, params, tokens
+
+
+def test_laguna_forward_and_loss_terms_match_the_reference(laguna_setup):
+    """Logits, the four routed layers' router logits and expert counts
+    (over all 16 experts, held or not) and both terms of the loss against
+    the plain float32 reference on seeded weights."""
+    laguna, laguna_ref, cfg, params, tokens = laguna_setup
+    assert cfg.pattern == ("full_dense", "sliding_moe", "sliding_moe",
+                           "sliding_moe", "full_moe")
+    assert params["layers"]["sliding_moe"]["wq"].shape == (3, 64, 6 * 16)
+    assert params["layers"]["full_moe"]["wq"].shape == (1, 64, 4 * 16)
+    assert params["layers"]["full_moe"]["e_gate"].shape[1] == (
+        8 if cfg.experts_held else 16)
+    with jax.default_matmul_precision("highest"):
+        logits, router = jax.jit(lambda p, t: laguna.forward(
+            cfg, p, t, keep_router_logits=True))(params, tokens[:, :-1])
+        loss, terms = jax.jit(lambda p, t: laguna.loss_terms(
+            cfg, p, {"tokens": t}))(params, tokens)
+    ref = laguna_ref.token_nll(cfg, params, tokens)
+    np.testing.assert_allclose(
+        np.asarray(logits), np.asarray(laguna_ref.logits(
+            cfg, params, tokens[:, :-1])), rtol=1e-5, atol=1e-5)
+    assert router["logits"].shape == (4, 64, 16)
+    np.testing.assert_allclose(np.asarray(router["logits"]),
+                               ref["router_logits"], rtol=1e-5, atol=1e-5)
+    want_counts = np.stack([np.bincount(c.ravel(), minlength=16)
+                            for c in ref["chosen"]])
+    assert (np.asarray(terms["expert_counts"]) == want_counts).all()
+    assert int(want_counts.sum()) == 4 * 64 * cfg.top_k
+    first, count = cfg.experts_held or (0, 16)
+    assert int(laguna.rows_held(cfg, terms["expert_counts"])) == int(
+        want_counts[:, first:first + count].sum())
+    for name in ("cross_entropy", "load_balance"):
+        assert abs(float(terms[name]) - ref["terms"][name]) < 1e-5, name
+    assert abs(float(loss) - ref["terms"]["loss"]) < 1e-5
+    # the share changes the result: what the absent experts add is left out
+    assert ref["terms"]["load_balance"] > 1.0
+
+
+def test_laguna_gradients_match_the_reference(laguna_setup):
+    laguna, laguna_ref, cfg, params, tokens = laguna_setup
+    with jax.default_matmul_precision("highest"):
+        got = jax.jit(jax.grad(lambda p: laguna.loss_fn(
+            cfg, p, {"tokens": tokens})))(params)
+    want = jax.jit(jax.grad(
+        lambda p: laguna_ref.loss(cfg, p, tokens)))(params)
+    flat, _ = jax.tree_util.tree_flatten_with_path(got)
+    assert len(flat) == 3 + 10 + 2 * 14
+    for (path, g), w in zip(flat, jax.tree_util.tree_leaves(want)):
+        scale = float(jnp.abs(w).max())
+        assert scale > 1e-5, path                       # it is reached
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=1e-4,
+                                   atol=1e-5 * max(scale, 1e-2),
+                                   err_msg=str(path))
+
+
+def test_laguna_reference_gradient_of_a_weighted_loss(laguna_setup):
+    """What the chip check compares: ``token_nll(grad_weights=...)`` gives
+    the gradient of ``sum(weights * per-position loss)`` for the first
+    layer of each kind, the embedding, the last norm and the head, row by
+    row; the program's own gradient of that scalar agrees, and the rest
+    of ``token_nll``'s result is what it is without the gradient."""
+    laguna, laguna_ref, cfg, params, tokens = laguna_setup
+    weights = np.random.default_rng(3).uniform(
+        0.5, 1.5, (2, 32)).astype(np.float32) / 64
+
+    def weighted(p):
+        lg, _ = laguna.forward(cfg, p, tokens[:, :-1])
+        nll = jax.nn.logsumexp(lg, -1) - jnp.take_along_axis(
+            lg, tokens[:, 1:, None], -1)[..., 0]
+        return (weights * nll).sum()
+
+    with jax.default_matmul_precision("highest"):
+        got = laguna_ref.first_layers(jax.jit(jax.grad(weighted))(params))
+    plain = laguna_ref.token_nll(cfg, params, tokens)
+    ref = laguna_ref.token_nll(cfg, params, tokens, grad_weights=weights)
+    np.testing.assert_allclose(ref["nll"], plain["nll"], atol=1e-6)
+    assert (ref["chosen"] == plain["chosen"]).all()
+    assert got["layers"]["sliding_moe"]["wq"].shape == (64, 6 * 16)
+    flat, _ = jax.tree_util.tree_flatten_with_path(got)
+    assert len(flat) == 3 + 10 + 2 * 14
+    for (path, g), w in zip(flat, jax.tree_util.tree_leaves(ref["grads"])):
+        scale = float(jnp.abs(w).max())
+        assert scale > 1e-6, path
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=1e-4,
+                                   atol=1e-5 * max(scale, 1e-2),
+                                   err_msg=str(path))
+
+
+def test_laguna_window_and_gate_are_in_the_result():
+    """Leaving out the window mask, the per-head gate or the routed
+    scale changes the logits: none of them is a no-op at these sizes."""
+    from dataclasses import replace
+
+    from ray_tpu.models import laguna
+
+    cfg = laguna.LagunaConfig.tiny(attn_impl="reference")
+    params = laguna.init_params(cfg, jax.random.PRNGKey(0))
+    tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 32), 0, 256)
+    base = laguna.forward(cfg, params, tokens)[0]
+    for other in (replace(cfg, sliding_window=None),
+                  replace(cfg, sliding_window=4),
+                  replace(cfg, routed_scale=1.0),
+                  replace(cfg, partial_rotary_factor=1.0)):
+        assert float(jnp.abs(laguna.forward(other, params, tokens)[0]
+                             - base).max()) > 1e-3, other
+    # a window of the whole sequence is causal attention
+    np.testing.assert_allclose(
+        np.asarray(laguna.forward(replace(cfg, sliding_window=32), params,
+                                  tokens)[0]),
+        np.asarray(laguna.forward(replace(cfg, sliding_window=None), params,
+                                  tokens)[0]), rtol=1e-5, atol=1e-5)
+
+
+def test_layer_patterns_are_walked_by_runs_of_one_kind():
+    """``run_layers`` walks a pattern of kinds: a run of one kind is one
+    scan, a layer alone between others is walked; Laguna-S-2.1's 48
+    layers are 117.6 B parameters."""
+    from ray_tpu.models import laguna
+
+    cfg = laguna.LagunaConfig.laguna_s_2_1()
+    assert cfg.pattern[:5] == ("full_dense", "sliding_moe", "sliding_moe",
+                               "sliding_moe", "full_moe")
+    assert len(cfg.pattern) == 48
+    shapes = jax.eval_shape(lambda k: laguna.init_params(cfg, k),
+                            jax.random.PRNGKey(0))
+    assert round(llama.num_params(shapes) / 1e9, 1) == 117.6
+
+    # runs of one, two and three layers of two kinds: stacks by kind,
+    # outputs back in each kind's order
+    def fn(scale):
+        return lambda x, p: (x * scale + p["w"], x.sum())
+    layers = {"a": {"w": jnp.arange(4.0)}, "b": {"w": 10 + jnp.arange(8.0)}}
+    pattern = ("b", "a", "b", "b", "a", "a", "a", "b", "b", "b", "b", "b")
+    x0 = jnp.ones(())
+    for scan in (True, False):
+        x, ys = llama.run_layers({"a": fn(2.0), "b": fn(0.5)}, x0, layers,
+                                 level="full", scan=scan, pattern=pattern)
+        want, seen = x0, {"a": [], "b": []}
+        at = {"a": 0, "b": 0}
+        for kind in pattern:
+            seen[kind].append(want)
+            want = want * {"a": 2.0, "b": 0.5}[kind] + layers[kind]["w"][
+                at[kind]]
+            at[kind] += 1
+        np.testing.assert_allclose(float(x), float(want), rtol=1e-6)
+        for kind in "ab":
+            np.testing.assert_allclose(np.asarray(ys[kind]),
+                                       np.asarray(seen[kind]), rtol=1e-6)
 
 
 def test_olmoe_reference_forced_to_other_choices(olmoe_setup):
@@ -718,12 +901,12 @@ def test_one_walker_owns_the_layer_loop():
     PR 28."""
     import inspect
 
-    from ray_tpu.models import mixtral, olmoe
+    from ray_tpu.models import laguna, mixtral, olmoe
 
     walker = inspect.getsource(llama.run_layers)
     for needle in ("jax.checkpoint(", "lax.scan("):
         assert walker.count(needle) == 1
-        for mod in (llama, mixtral, olmoe, gpt2):
+        for mod in (llama, mixtral, olmoe, laguna, gpt2):
             outside = inspect.getsource(mod).replace(walker, "")
             assert needle not in outside, (mod.__name__, needle)
 

@@ -57,6 +57,27 @@ def test_rope_relative_property():
     np.testing.assert_allclose(s[5, 2], s[20, 17], rtol=1e-4)
 
 
+def test_partial_rope_rotates_the_first_dims_and_passes_the_rest():
+    """Tables made for 8 of a head's 16 dims: dims 0..3 and 4..7 are the
+    two halves of the rotation, written out here, and 8..15 pass."""
+    x = jax.random.normal(jax.random.PRNGKey(3), (2, 12, 3, 16))
+    cos, sin = rope_frequencies(8, 12, theta=100.0)
+    assert cos.shape == (12, 4)
+    got = np.asarray(apply_rope(x, cos, sin))
+    inv = 1.0 / 100.0 ** (np.arange(0, 8, 2) / 8)
+    ang = np.arange(12)[:, None] * inv[None]                    # [12, 4]
+    c, s_ = np.cos(ang)[None, :, None], np.sin(ang)[None, :, None]
+    xn = np.asarray(x)
+    x1, x2 = xn[..., :4], xn[..., 4:8]
+    want = np.concatenate([x1 * c - x2 * s_, x2 * c + x1 * s_, xn[..., 8:]],
+                          -1)
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+    np.testing.assert_array_equal(got[..., 8:], xn[..., 8:])
+    # tables of the whole width rotate the whole head, as before
+    full = apply_rope(x, *rope_frequencies(16, 12, theta=100.0))
+    assert float(jnp.abs(full[:, 1:, :, 8:] - x[:, 1:, :, 8:]).max()) > 0.1
+
+
 def test_swiglu_shapes_and_values():
     x = jax.random.normal(jax.random.PRNGKey(5), (4, 16))
     wg = jax.random.normal(jax.random.PRNGKey(6), (16, 32)) * 0.1
@@ -115,6 +136,54 @@ def test_flash_attention_grads_match_gqa():
         # arange-weighted cotangent makes grads O(100); compare relatively
         np.testing.assert_allclose(np.asarray(a), np.asarray(b_),
                                    rtol=2e-4, atol=1e-4)
+
+
+@pytest.mark.parametrize("window", [8, 16, 40],
+                         ids=["under-a-block", "a-block", "over-a-block"])
+@pytest.mark.parametrize("group", [6, 9])
+def test_flash_attention_window_matches_reference(group, window):
+    """A sliding window (a query sees the ``window`` keys that end at its
+    own position) in all three kernels, in interpret mode with blocks of
+    16: forward and every gradient against the masked softmax, at
+    Laguna's two GQA ratios (48 and 72 query heads on 8 kv heads). The
+    loops skip the key blocks behind the band, so a query row can meet a
+    block it sees nothing of."""
+    b, s, kvh, d = 2, 64, 2, 16
+    ks = jax.random.split(jax.random.PRNGKey(window), 4)
+    q = jax.random.normal(ks[0], (b, s, kvh * group, d))
+    k = jax.random.normal(ks[1], (b, s, kvh, d))
+    v = jax.random.normal(ks[2], (b, s, kvh, d))
+    cot = jax.random.normal(ks[3], q.shape)
+
+    def flash(*a):
+        return flash_attention(*a, causal=True, window=window,
+                               use_pallas=True, interpret=True, block_q=16,
+                               block_k=16)
+
+    def plain(*a):
+        return attention_reference(*a, causal=True, window=window)
+
+    want = plain(q, k, v)
+    # the mask is the band: position 40 sees 40 - window + 1 .. 40 alone
+    far = k.at[:, :max(0, 41 - window)].set(9.0)
+    np.testing.assert_array_equal(np.asarray(plain(q, far, v)[:, 40]),
+                                  np.asarray(want[:, 40]))
+    np.testing.assert_allclose(np.asarray(flash(q, k, v)), np.asarray(want),
+                               atol=2e-5)
+    got_g = jax.grad(lambda *a: (flash(*a) * cot).sum(),
+                     argnums=(0, 1, 2))(q, k, v)
+    want_g = jax.grad(lambda *a: (plain(*a) * cot).sum(),
+                      argnums=(0, 1, 2))(q, k, v)
+    for got, ref in zip(got_g, want_g):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
+                                   atol=5e-5)
+
+
+def test_flash_attention_window_needs_causal():
+    q = jnp.zeros((1, 16, 2, 8))
+    for fn in (flash_attention, attention_reference):
+        with pytest.raises(ValueError, match="causal"):
+            fn(q, q, q, causal=False, window=4)
 
 
 def test_flash_attention_grads_cross_seq():
@@ -241,17 +310,31 @@ def test_ulysses_rejects_indivisible_heads():
 
 
 def _experts_by_loop(x, router_w, e_gate, e_up, e_down, top_k,
-                     renormalize=False):
-    """Every expert over every token, a mask keeping the chosen ones."""
+                     renormalize=False, held=None, scale=1.0):
+    """Every expert (``held=(first, count)``: those alone) over every
+    token, a mask keeping the chosen ones."""
     probs = jax.nn.softmax(x @ router_w, axis=-1)
     top_w, top_e = jax.lax.top_k(probs, top_k)
     if renormalize:
         top_w = top_w / top_w.sum(-1, keepdims=True)
+    top_w = top_w * scale
     out = jnp.zeros_like(x)
-    for e in range(router_w.shape[1]):
+    first, count = held or (0, router_w.shape[1])
+    for e in range(first, first + count):
         gate = jnp.where(top_e == e, top_w, 0.0).sum(-1)
         out = out + gate[:, None] * swiglu(x, e_gate[e], e_up[e], e_down[e])
     return out
+
+
+def _held_share(held, x, router_w, e_gate, e_up, e_down, top_k, **kw):
+    """``routed_experts`` handed the held experts' weights alone."""
+    from ray_tpu.ops.moe import routed_experts
+
+    if held is not None:
+        e_gate, e_up, e_down = (jax.lax.dynamic_slice_in_dim(w, *held)
+                                for w in (e_gate, e_up, e_down))
+    return routed_experts(x, router_w, e_gate, e_up, e_down, top_k,
+                          held=held, **kw)
 
 
 def _routed_inputs(skewed: bool):
@@ -343,6 +426,134 @@ def test_routed_experts_single_expert_is_the_dense_swiglu():
         rtol=1e-5, atol=1e-5)
 
 
+@pytest.mark.parametrize("skewed", [False, True])
+def test_held_experts_match_the_expert_loop(skewed):
+    """``held=(4, 8)``: the part experts 4..11 of 16 give, forward and
+    every gradient (the router's over all 16 outputs, the expert
+    matrices' for the eight held) against the loop over those experts,
+    renormalised and scaled as Laguna routes; the skewed router sends
+    every token to experts 8..15, half of them held, in two passes."""
+    from ray_tpu.ops import moe
+
+    *args, cot = _routed_inputs(skewed)
+    kw = dict(renormalize=True, scale=2.5)
+    with jax.default_matmul_precision("highest"):
+        out, logits, counts = jax.jit(
+            lambda *a: _held_share((4, 8), *a, 8, **kw))(*args)
+        want = _experts_by_loop(*args, 8, held=(4, 8), **kw)
+        got_g = jax.jit(jax.grad(
+            lambda *a: (_held_share((4, 8), *a, 8, **kw)[0] * cot).sum(),
+            argnums=(0, 1, 2, 3, 4)))(*args)
+        want_g = jax.jit(jax.grad(
+            lambda *a: (_experts_by_loop(*a, 8, held=(4, 8), **kw)
+                        * cot).sum(), argnums=(0, 1, 2, 3, 4)))(*args)
+    assert int(counts.sum()) == 96 * 8 and counts.shape == (16,)
+    if skewed:       # 384 held rows, a pass takes 768
+        assert counts.tolist() == [0] * 8 + [96] * 8
+    assert moe._held_chunk(96 * 8, 8, 16) == 768
+    np.testing.assert_allclose(np.asarray(logits), np.asarray(
+        args[0] @ args[1]), rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want),
+                               rtol=1e-5, atol=1e-5)
+    for got, ref in zip(got_g, want_g):
+        assert got.shape == ref.shape
+        np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
+                                   rtol=1e-4, atol=1e-4)
+
+
+def _laguna_routed_layer():
+    """One routed layer at tiny widths with Laguna's router: 256 experts,
+    10 a token, renormalised, scaled by 2.5, a shared expert beside."""
+    import os
+    import sys
+
+    sys.path.insert(0, os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))          # benchmark/ lies beside tests/
+    from benchmark.references import laguna_ref
+    from ray_tpu.models import laguna
+
+    cfg = laguna.LagunaConfig.tiny(num_experts=256, top_k=10)
+    n, h, f, E = 64, cfg.hidden_size, cfg.moe_intermediate_size, 256
+    ks = jax.random.split(jax.random.PRNGKey(4), 8)
+    p = {"router": jax.random.normal(ks[0], (h, E)) * 0.3,
+         "e_gate": jax.random.normal(ks[1], (E, h, f)) / 8,
+         "e_up": jax.random.normal(ks[2], (E, h, f)) / 8,
+         "e_down": jax.random.normal(ks[3], (E, f, h)) / 6,
+         "s_gate": jax.random.normal(ks[4], (h, f)) / 8,
+         "s_up": jax.random.normal(ks[5], (h, f)) / 8,
+         "s_down": jax.random.normal(ks[6], (f, h)) / 6}
+    return cfg, laguna_ref, p, jax.random.normal(ks[7], (n, h))
+
+
+def test_sixteen_shares_add_up_to_the_uncut_layer():
+    """The share a chip holds is tied to the model: the parts that the 16
+    shares ``held=(16 i, 16)`` of one routed layer give, with the shared
+    expert (which every chip computes alike) counted once, add up to the
+    uncut reference layer, which holds all 256 experts."""
+    from ray_tpu.ops.moe import routed_experts
+
+    cfg, laguna_ref, p, u = _laguna_routed_layer()
+
+    with jax.default_matmul_precision("highest"):
+        total = swiglu(u, p["s_gate"], p["s_up"], p["s_down"])
+        held_rows = 0
+        for i in range(16):
+            out, _, counts = routed_experts(
+                u, p["router"], *(p[k][16 * i:16 * i + 16]
+                                  for k in ("e_gate", "e_up", "e_down")),
+                cfg.top_k, renormalize=True, held=(16 * i, 16),
+                scale=cfg.routed_scale)
+            total = total + out
+            held_rows += int(counts[16 * i:16 * i + 16].sum())
+        want = laguna_ref.routed_layer(cfg, p, u)
+    assert held_rows == int(counts.sum()) == 64 * 10
+    np.testing.assert_allclose(np.asarray(total), np.asarray(want),
+                               rtol=1e-5, atol=1e-5)
+
+
+def test_held_experts_drop_no_row_and_compile_nothing_whatever_the_routing():
+    """A router that sends every row to the held experts (four passes of
+    the loop where a balanced one takes one) and one that sends none:
+    the first gives the whole layer, the second nothing, a gradient flows
+    in both, and it is all one compiled program."""
+    from ray_tpu.ops import moe
+
+    cfg, laguna_ref, p, u = _laguna_routed_layer()
+    held = (32, 16)
+    weights = [p[k][32:48] for k in ("e_gate", "e_up", "e_down")]
+    assert moe._held_chunk(64 * 10, 16, 256) == 256     # 640 rows: 3 passes
+
+    @jax.jit
+    def layer(u, router):
+        def loss(u, router, *w):
+            out, _, counts = moe.routed_experts(
+                u, router, *w, cfg.top_k, renormalize=True, held=held,
+                scale=cfg.routed_scale)
+            return out.sum(), (out, counts)
+        (_, (out, counts)), grads = jax.value_and_grad(
+            loss, argnums=(0, 2), has_aux=True)(u, router, *weights)
+        return out, counts, grads
+
+    u = jnp.abs(u)          # a positive feature steers the router
+    to_held = (p["router"] * 0.01).at[:, 32:48].add(1.0)
+    to_others = (p["router"] * 0.01).at[:, 100:116].add(1.0)
+    with jax.default_matmul_precision("highest"):
+        out, counts, (d_u, d_gate) = layer(u, to_held)
+        assert int(counts[32:48].sum()) == 640      # every row is held
+        whole = dict(p, router=to_held)
+        want = laguna_ref.routed_layer(cfg, whole, u) - swiglu(
+            u, p["s_gate"], p["s_up"], p["s_down"])
+        np.testing.assert_allclose(np.asarray(out), np.asarray(want),
+                                   rtol=1e-5, atol=1e-5)
+        assert float(jnp.abs(d_u).min(-1).max()) > 0    # rows reached
+        assert float(jnp.abs(d_gate).sum((1, 2)).min()) > 0
+        out, counts, (d_u, d_gate) = layer(u, to_others)
+    assert int(counts[32:48].sum()) == 0 and int(counts.sum()) == 640
+    assert float(jnp.abs(out).max()) == 0.0
+    assert float(jnp.abs(d_u).max()) == 0.0 == float(jnp.abs(d_gate).max())
+    assert layer._cache_size() == 1
+
+
 def test_routed_experts_names_its_scopes_forward_and_backward():
     """The four scopes ``benchmark/lib/moe_scopes.py`` reads, on the
     operations of the forward and of the hand-written transposes."""
@@ -357,10 +568,13 @@ def test_routed_experts_names_its_scopes_forward_and_backward():
         assert f"transpose(jvp({scope}))" in text, scope
 
 
-def test_routed_experts_tpu_path_in_interpret_mode(monkeypatch):
+@pytest.mark.parametrize("held", [None, (4, 8)], ids=["all", "held-4..11"])
+def test_routed_experts_tpu_path_in_interpret_mode(monkeypatch, held):
     """What a TPU runs: the megablox kernels behind ``grouped_matmul``'s
     own transposes, here through the Pallas interpreter (768 rows, three
-    tiles of 256, groups that end inside a tile, eight empty groups)."""
+    tiles of 256, groups that end inside a tile, eight empty groups); and
+    with half the experts held, the passes over the held rows (one of 768
+    rows: the kernels write no row past the pass's groups)."""
     from functools import partial
 
     from ray_tpu.ops import moe
@@ -376,13 +590,13 @@ def test_routed_experts_tpu_path_in_interpret_mode(monkeypatch):
     for skewed in (False, True):
         *args, cot = _routed_inputs(skewed)
         with jax.default_matmul_precision("highest"):
-            fn = lambda *a: (moe.routed_experts(*a, 8)[0] * cot).sum()
+            fn = lambda *a: (_held_share(held, *a, 8)[0] * cot).sum()
             text = jax.jit(fn).lower(*args).as_text()
             assert "ragged_dot" not in text
             got = jax.jit(jax.value_and_grad(fn, argnums=(0, 1, 2, 3, 4)))(
                 *args)
             want = jax.jit(jax.value_and_grad(
-                lambda *a: (_experts_by_loop(*a, 8) * cot).sum(),
+                lambda *a: (_experts_by_loop(*a, 8, held=held) * cot).sum(),
                 argnums=(0, 1, 2, 3, 4)))(*args)
         for g, w in zip(jax.tree_util.tree_leaves(got),
                         jax.tree_util.tree_leaves(want)):

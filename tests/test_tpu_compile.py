@@ -171,3 +171,119 @@ def test_remat_plan_holds_against_the_compiler_at_7b_widths(
         allotted, plan["need_bytes"])
     assert allotted <= (1 - llama.REMAT_RESERVE) * limit
     assert compiled.as_text().count("tpu_custom_call") == 3
+
+
+def _placed(tree, sharding):
+    return jax.tree_util.tree_map(lambda a: jax.ShapeDtypeStruct(
+        a.shape, a.dtype, sharding=sharding), tree)
+
+
+def test_laguna_cell_step_compiles_within_a_v5e_chip(one_chip,
+                                                     no_compile_cache,
+                                                     monkeypatch):
+    """The adamw step of the benchmark's ``train-laguna-1chip`` at its
+    published widths (2 x 8,192 tokens, five layers of three kinds, 16 of
+    256 experts held, bf16 state: ``benchmark/configs/
+    laguna-s-2.1-c1.json``): Mosaic takes the window kernels and the
+    8,192-position dK/dV call (which asks for more than the default
+    scoped VMEM), the passes over the held rows compile to loops whose
+    trip count is data, and the program fits 15.75 GiB."""
+    import json
+
+    import optax
+
+    from ray_tpu.models import laguna
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "laguna-s-2.1-c1.json")) as f:
+        kw = {k: tuple(v) if isinstance(v, list) else v
+              for k, v in json.load(f)["model_config"].items()}
+    assert (kw.pop("module"), kw.pop("preset")) == ("laguna", "laguna_s_2_1")
+    for key in ("dtype", "param_dtype"):
+        kw[key] = getattr(jnp, kw[key])
+    cfg = laguna.LagunaConfig.laguna_s_2_1(**kw)
+    assert cfg.pattern == ("full_dense", "sliding_moe", "sliding_moe",
+                           "sliding_moe", "full_moe")
+    tx = optax.adamw(1e-4)
+    params = jax.eval_shape(lambda k: laguna.init_params(cfg, k),
+                            jax.random.PRNGKey(0))
+    assert sum(a.size for a in jax.tree_util.tree_leaves(params)) \
+        == 1_113_007_104
+    opt = jax.eval_shape(tx.init, params)
+    batch = {"tokens": jax.ShapeDtypeStruct((2, 8193), jnp.int32,
+                                            sharding=one_chip)}
+
+    def step(params, opt, batch):
+        (loss, aux), grads = jax.value_and_grad(
+            lambda p: laguna.loss_terms(cfg, p, batch), has_aux=True)(params)
+        updates, opt = tx.update(grads, opt, params)
+        return (optax.apply_updates(params, updates), opt, loss,
+                aux["expert_counts"])
+
+    compiled = jax.jit(step, donate_argnums=(0, 1)).lower(
+        _placed(params, one_chip), _placed(opt, one_chip), batch).compile()
+    ma = compiled.memory_analysis()
+    assert ma.argument_size_in_bytes + ma.temp_size_in_bytes \
+        + ma.output_size_in_bytes - ma.alias_size_in_bytes \
+        <= int(15.75 * 2 ** 30)
+    # (inside the held rows' backward pass jax names megablox's calls
+    # after the transformation it traced them under)
+    names = {name for name, _ in _mosaic_calls(compiled.as_text())}
+    assert names == {"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv",
+                     "flash_win_fwd", "flash_win_bwd_dq",
+                     "flash_win_bwd_dkv", "gmm", "jvp_jit_gmm__",
+                     "jvp_jit_tgmm__"}
+
+
+@pytest.mark.parametrize("model", ["llama", "olmoe"])
+def test_one_kind_of_layer_compiles_the_scan_it_always_did(
+        model, one_chip, no_compile_cache, monkeypatch):
+    """``window``, ``held`` and ``pattern`` at their defaults: a dense
+    and an OLMoE gradient step (head size 128, the flash and megablox
+    kernels in) through ``llama.run_layers`` compile, metadata aside, to
+    the text they compile to through the walker written out as it was
+    before layers had kinds, one ``lax.scan`` of one checkpointed layer,
+    and no window call is in it. (``step_program.py --compare`` holds the
+    cells' whole steps to the parent's text.)"""
+    from ray_tpu.models import llama, olmoe
+    from ray_tpu.tools.step_program import strip_metadata
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    sizes = dict(vocab_size=512, hidden_size=256, num_layers=2, num_heads=2,
+                 num_kv_heads=1, head_dim=128, max_seq_len=512,
+                 dtype=jnp.bfloat16, param_dtype=jnp.bfloat16,
+                 remat_policy="full")
+    if model == "llama":
+        mod, cfg = llama, llama.LlamaConfig(intermediate_size=512, **sizes)
+    else:
+        mod, cfg = olmoe, olmoe.OlmoeConfig(
+            intermediate_size=128, num_experts=8, top_k=2, **sizes)
+    params = _placed(jax.eval_shape(lambda k: mod.init_params(cfg, k),
+                                    jax.random.PRNGKey(0)), one_chip)
+    batch = {"tokens": jax.ShapeDtypeStruct((1, 513), jnp.int32,
+                                            sharding=one_chip)}
+
+    def text():
+        jax.config.update("jax_traceback_in_locations_limit", 0)
+        return strip_metadata(jax.jit(jax.grad(
+            lambda p, b: mod.loss_fn(cfg, p, b))).lower(
+            params, batch).compile().as_text())
+
+    def walker_before(layer_fn, x, layers, *, level, scan, pattern=None):
+        assert level == "full" and scan and pattern is None
+        return jax.lax.scan(jax.checkpoint(layer_fn), x, layers)
+
+    limit = jax.config.jax_traceback_in_locations_limit
+    try:
+        now = text()
+        monkeypatch.setattr(llama, "run_layers", walker_before)
+        before = text()
+    finally:
+        jax.config.update("jax_traceback_in_locations_limit", limit)
+    assert now == before
+    names = {name for name, _ in _mosaic_calls(now)}
+    assert names == ({"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"}
+                     | ({"gmm", "tgmm"} if model == "olmoe" else set()))
+    assert "flash_win" not in now

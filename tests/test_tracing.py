@@ -308,7 +308,8 @@ def test_metrics_registry_serves_the_owners_counters():
 
 
 def test_train_session_serves_the_last_reported_moe_counters():
-    """``rtpu_train_moe_*``: the last ``moe_rows_routed`` and
+    """``rtpu_train_moe_*``: the last ``moe_rows_routed``,
+    ``moe_rows_held`` (where a layer holds a share of its experts) and
     ``moe_expert_load_max_over_mean`` a loop put into ``train.report``;
     a loop that reports neither serves neither."""
     from ray_tpu import metrics
@@ -320,6 +321,7 @@ def test_train_session_serves_the_last_reported_moe_counters():
         train.report({"loss": 0.9, "moe_rows_routed": 196608,
                       "moe_expert_load_max_over_mean": 4.5})
         train.report({"loss": 0.8, "moe_rows_routed": 196608,
+                      "moe_rows_held": 12288,
                       "moe_expert_load_max_over_mean": 4.25,
                       "moe_other": 1})
 
@@ -338,6 +340,7 @@ def test_train_session_serves_the_last_reported_moe_counters():
     finally:
         session_mod._session = saved
     assert "rtpu_train_moe_rows_routed 196608\n" in text
+    assert "rtpu_train_moe_rows_held 12288\n" in text
     assert "rtpu_train_moe_expert_load_max_over_mean 4.25\n" in text
     assert "rtpu_train_moe_other" not in text and "rtpu_train_loss" not in text
     assert "rtpu_train_reports 3\n" in text
@@ -520,6 +523,10 @@ def test_every_kernel_and_serving_program_has_a_name():
             names.append(m.group(1))
     assert sorted(names) == ["flash_bwd_dkv", "flash_bwd_dq", "flash_fwd",
                              "paged_attn"]
+    # a window layer's calls are named apart from the causal ones
+    for name in names[:3]:
+        assert f'else "{name.replace("flash_", "flash_win_")}"' in \
+            src["ops/attention.py"]
     for rel, text in src.items():
         assert not re.search(r"jax\.jit\(\s*lambda", text), rel
 
