@@ -25,20 +25,27 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
 from ray_tpu.ops.layers import repeat_kv
+from ray_tpu.util import tracing
 
-DEFAULT_BLOCK_Q = 512
-DEFAULT_BLOCK_K = 512
+DEFAULT_BLOCK = 512
 _NEG_INF = -1e30
 
 
-def _auto_block(seq: int, target: int) -> int:
-    """Largest power-of-two block <= target that divides seq (measured on
-    v5e: 512 blocks are ~2-3x faster than 128 at long seq — MXU stays fed
-    and the online-softmax VPU work amortizes)."""
-    c = target
-    while c > 128:
-        if seq % c == 0:
-            return c
+def _auto_block(seq: int) -> int:
+    """The block a call gets where its caller names none: the largest
+    power of two that divides ``seq``, from 512 down to 128, whatever the
+    ``window``. Measured on v5e (PR 31, ``benchmark/tests/window_scaling.py``
+    under explicit blocks: forward + dQ + dK/dV, the kernels' own time at
+    2 x 8,192 positions, 72 query heads on 8, head size 128): a 512-key
+    band takes 21.2 ms in blocks of 512, 26.7 in 256 and 49.0 in 128,
+    though 256 visits a quarter fewer score elements and 128 three eighths
+    (``tile_plan``); causal 78.4, 132.7 and 303.6. A smaller tile loses
+    more a score element (the MXU streams fewer rows a weight load, the
+    accumulators' rescale weighs more beside the scores) than the band
+    saves in elements; 4 x 4,096 positions read the same order. Wider
+    tiles (1,024 either way) read 5-28% slower in the forward."""
+    c = DEFAULT_BLOCK
+    while c > 128 and seq % c:
         c //= 2
     return c
 
@@ -88,38 +95,161 @@ def _check_window(causal: bool, window: Optional[int]) -> None:
 
 # ---------------------------------------------------------------- pallas fwd
 #
-# With a ``window`` a kernel skips the key blocks behind the band as the
-# causal path skips those ahead of the diagonal: its loop starts at the
-# first key block that holds a key inside the window of the block's first
-# query (``_first_key_block``). A later query of the block may see nothing
-# of that block: its row is all ``_NEG_INF`` there, the running maximum
+# A kernel's loop walks the tiles of one query block (forward, dQ) or of
+# one key block (dK/dV) that hold a key some query of the tile sees, and
+# skips the others: those ahead of the diagonal and, with a ``window``,
+# those behind the band. ``_key_bounds`` and ``_query_bounds`` give the
+# loop's bounds from positions (so ``causal_offset`` and unequal blocks
+# need no case of their own), to the kernels as traced scalars and to
+# ``tile_plan`` as numpy arrays over all blocks: what is counted is what
+# runs. They also give the *interior* tiles among the visited, where every
+# query sees every key. The kernels mask every tile they visit all the
+# same: on the chip the mask is not what a tile waits for (its iotas and
+# compares do not depend on the scores and run beside the matmul).
+# Measured on v5e, PR 31, 2 x 8,192 positions: with the interior tiles run
+# bare, as a second and third loop over the same body, the causal calls
+# read 14.95 / 15.73 / 25.51 ms (forward / dQ / dK/dV, 48 heads, 120 of
+# 136 tiles bare) for 14.83 / 15.82 / 25.42 masked throughout, and a
+# 512-key band in blocks of 512 (no tile bare, three short loops a grid
+# step) 7.65 / 6.18 / 12.11 for 6.74 / 6.32 / 11.56.
+#
+# With a ``window`` a later query of a tile the band's far edge cuts may
+# see nothing of it: its row is all ``_NEG_INF`` there, the running maximum
 # stays ``_NEG_INF`` and what it sums is wiped by ``alpha = 0`` at the next
-# block, which holds the query's own position.
+# tile, which holds a key it sees.
 
 
-def _seen(q_pos, k_pos, window: Optional[int]):
-    """The causal mask, and the window's where there is one."""
-    mask = q_pos >= k_pos
-    if window is not None:
-        mask &= q_pos - k_pos < window
-    return mask
+def _div(x, block: int, xp):
+    """``x // block`` for ``x >= 0``: in a kernel the truncating division,
+    which is the floor there and half the scalar work of ``//`` (a grid
+    step of a window call is a few microseconds long)."""
+    return x // block if xp is not jnp else jax.lax.div(x, block)
 
 
-def _first_key_block(qb, block_q: int, block_k: int, causal_offset: int,
-                     window: Optional[int]):
-    """The first key block the loop of query block ``qb`` visits."""
-    if window is None:
-        return 0
+def _key_bounds(qb, block_q: int, block_k: int, seq_k: int,
+                causal_offset: int, causal: bool, window: Optional[int],
+                xp=jnp):
+    """The key blocks of query block ``qb``: ``(first, bare_first, bare_end,
+    end)``. The loop visits ``[first, end)``; of those ``[bare_first,
+    bare_end)`` are interior. With ``d = q_pos - k_pos`` a tile is visited
+    where some ``0 <= d < window`` and interior where all are."""
+    nk = seq_k // block_k
+    if not causal:
+        return 0, 0, nk, nk
     first_q = causal_offset + qb * block_q
-    return jnp.maximum(0, jax.lax.div(first_q - (window - 1), block_k))
+    last_q = first_q + block_q - 1
+    end = xp.minimum(
+        nk, _div(xp.maximum(last_q + 1, 0) + block_k - 1, block_k, xp))
+    # interior: the tile's last key at or before the first query ...
+    bare_end = xp.minimum(
+        _div(xp.maximum(first_q + 1, 0), block_k, xp), end)
+    if window is None:
+        return 0, 0, bare_end, end
+    # ... and its first key inside the window of the last query
+    first = _div(xp.maximum(first_q - window + 1, 0), block_k, xp)
+    bare_first = xp.clip(
+        _div(xp.maximum(last_q - window + 1, 0) + block_k - 1, block_k, xp),
+        first, end)
+    return first, bare_first, xp.maximum(bare_end, bare_first), end
+
+
+def _query_bounds(kb, block_q: int, block_k: int, seq_q: int,
+                  causal_offset: int, causal: bool, window: Optional[int],
+                  xp=jnp):
+    """The query blocks of key block ``kb`` (the dK/dV kernel's loop), as
+    ``_key_bounds`` has a row's: the same tiles, walked down a column."""
+    nq = seq_q // block_q
+    if not causal:
+        return 0, 0, nq, nq
+    # as query rows: row i sits at position causal_offset + i
+    first_k = kb * block_k - causal_offset
+    last_k = first_k + block_k - 1
+    first = xp.minimum(nq, _div(xp.maximum(first_k, 0), block_q, xp))
+    # interior: the tile's first query at or after the last key ...
+    bare_first = xp.minimum(
+        nq, _div(xp.maximum(last_k, 0) + block_q - 1, block_q, xp))
+    if window is None:
+        return first, bare_first, nq, nq
+    # ... and its last query inside the window of the first key
+    end = xp.clip(
+        _div(xp.maximum(last_k + window, 0) + block_q - 1, block_q, xp),
+        first, nq)
+    bare_first = xp.minimum(bare_first, end)
+    bare_end = xp.clip(_div(xp.maximum(first_k + window, 0), block_q, xp),
+                       bare_first, end)
+    return first, bare_first, bare_end, end
+
+
+def tile_plan(seq_q: int, seq_k: int, block_q: int, block_k: int,
+              window: Optional[int] = None, causal: bool = True) -> dict:
+    """What the kernels' loops do for one head, from shapes alone: the
+    tiles they visit, the edge tiles among them (those the diagonal or the
+    band's far edge cuts: the others are interior) and the share of the
+    visited score elements that the mask keeps. Counted from
+    ``_key_bounds``, the bounds the forward and dQ loops run;
+    ``_query_bounds`` walks the same tiles by column."""
+    import numpy as np
+
+    first, bare_first, bare_end, end = (
+        np.broadcast_to(b, (seq_q // block_q,)) for b in _key_bounds(
+            np.arange(seq_q // block_q), block_q, block_k, seq_k,
+            seq_k - seq_q, causal, window, xp=np))
+    visited = int(np.sum(end - first))
+    kept = seq_q * seq_k
+    if causal:
+        pos = seq_k - seq_q + np.arange(seq_q)      # a query sees (lo, pos]
+        lo = -1 if window is None else pos - window
+        kept = int(np.sum(np.maximum(pos - np.maximum(lo, -1), 0)))
+    return {"tiles_visited": visited,
+            "tiles_edge": visited - int(np.sum(bare_end - bare_first)),
+            "kept_share": kept / max(1, visited * block_q * block_k)}
+
+
+def _mask(s, first_q, first_k, window: Optional[int], q_axis: int = 0):
+    """A tile's scores with what its queries do not see at ``_NEG_INF``.
+    The tile's first query sits at position ``first_q`` and its first key
+    at ``first_k`` (scalars); queries lie along ``q_axis`` of ``s``."""
+    d = (jax.lax.broadcasted_iota(jnp.int32, s.shape, q_axis)
+         - jax.lax.broadcasted_iota(jnp.int32, s.shape, 1 - q_axis))
+    shift = first_q - first_k           # q_pos - k_pos = d + shift
+    seen = d >= -shift
+    if window is not None:
+        seen &= d < window - shift
+    return jnp.where(seen, s, _NEG_INF)
+
+
+# A query block's softmax statistics (logsumexp; the backward's delta) lie
+# in HBM as rows, [b*h, seq_q // block_q, block_q]: a [seq_q, 1] column is
+# tiled to 128 lanes there, 128 times its bytes, and XLA spends a pass over
+# all of them to squeeze it to the row it saves or to make it from one
+# (measured on v5e, PR 31: 14 of the 22 ms a step that
+# ``train-laguna-1chip`` spent beside its kernels). The forward and dQ
+# kernels want them beside their [block_q, block_k] tiles as columns and
+# turn them themselves, one small transpose a grid step (a plain
+# ``reshape`` of the row costs four times as much).
+
+
+def _row(col):
+    """A [block_q, 1] column as a [1, block_q] row."""
+    return jnp.broadcast_to(col, (col.shape[0], 128)).T[:1]
+
+
+def _cols(*rows):
+    """[1, block_q] rows as [block_q, 1] columns: stacked to the eight
+    sublanes of one tile and turned together."""
+    pad = jnp.zeros((8 - len(rows), rows[0].shape[1]), rows[0].dtype)
+    turned = jnp.concatenate(rows + (pad,), axis=0).T       # [block_q, 8]
+    return [turned[:, i:i + 1] for i in range(len(rows))]
 
 
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k: int,
                   causal: bool, sm_scale: float, seq_k: int, block_q: int,
                   causal_offset: int = 0, window: Optional[int] = None):
     # q_ref: [1, block_q, d]; k_ref/v_ref: [1, seq_k, d]; o_ref: [1, block_q, d]
-    # lse_ref: [1, block_q] per-row logsumexp of the scaled scores (the only
-    # extra forward state the FA-2 backward needs).
+    # lse_ref: [1, seq_q // block_q, block_q], the head's per-row logsumexp
+    # of the scaled scores (the only extra forward state the FA-2 backward
+    # needs); the block stays while the head's query blocks write their
+    # rows.
     # causal_offset = seq_k - seq_q: query row i sits at absolute key
     # position offset + i (decode/chunked-prefill alignment, matching
     # attention_reference).
@@ -128,11 +258,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k: int,
     qb = pl.program_id(1)
     q = q_ref[0].astype(jnp.float32) * sm_scale          # [block_q, d]
     d = q.shape[-1]
-
-    num_kv_blocks = seq_k // block_k
-    if causal:
-        # only blocks whose start is <= the last query's absolute position
-        last_q = causal_offset + (qb + 1) * block_q - 1
+    first_q = causal_offset + qb * block_q
 
     def body(kb, carry):
         acc, m, l = carry
@@ -143,11 +269,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k: int,
             preferred_element_type=jnp.float32,
         )  # [block_q, block_k]
         if causal:
-            qi = jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
-            ki = jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
-            mask = _seen(causal_offset + qb * block_q + qi,
-                         kb * block_k + ki, window)
-            s = jnp.where(mask, s, _NEG_INF)
+            s = _mask(s, first_q, kb * block_k, window)
         m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
         p = jnp.exp(s - m_new)
         alpha = jnp.exp(m - m_new)
@@ -163,15 +285,12 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k: int,
         jnp.full((block_q, 1), _NEG_INF, jnp.float32),
         jnp.zeros((block_q, 1), jnp.float32),
     )
-    if causal:
-        upper = jax.lax.div(last_q, block_k) + 1
-    else:
-        upper = num_kv_blocks
-    lower = _first_key_block(qb, block_q, block_k, causal_offset, window)
-    acc, m, l = jax.lax.fori_loop(lower, upper, body, init)
+    first, _, _, end = _key_bounds(qb, block_q, block_k, seq_k,
+                                   causal_offset, causal, window)
+    acc, m, l = jax.lax.fori_loop(first, end, body, init)
     l_safe = jnp.maximum(l, 1e-30)
     o_ref[0] = (acc / l_safe).astype(o_ref.dtype)
-    lse_ref[0] = m + jnp.log(l_safe)  # [block_q, 1]
+    lse_ref[0, pl.ds(qb, 1), :] = _row(m + jnp.log(l_safe))
 
 
 def _check_blocks(sq, sk, block_q, block_k):
@@ -187,18 +306,13 @@ def _check_blocks(sq, sk, block_q, block_k):
 
 def _flash_forward(q, k, v, causal, sm_scale, block_q, block_k, interpret,
                    window=None):
-    """q: [b, sq, h, d]; k/v: [b, sk, kvh, d] → ([b, sq, h, d], lse[b*h, sq, 1]).
-
-    The logsumexp rides in a trailing singleton lane dim — TPU block shapes
-    need the last dim divisible by 128 *or* equal to the array dim, and a
-    1-lane column costs 128x less HBM than broadcasting to MIN_BLOCK_SIZE
-    lanes the way jax's in-tree kernel stores l/m."""
+    """q: [b, sq, h, d]; k/v: [b, sk, kvh, d] → ([b, sq, h, d], lse[b*h, sq]),
+    the logsumexp written as one row a query block."""
     import jax.experimental.pallas as pl
 
     b, sq, h, d = q.shape
     _, sk, kvh, _ = k.shape
     group = h // kvh
-    block_q, block_k = _check_blocks(sq, sk, block_q, block_k)
 
     # [b*h, s, d] layout for the kernel
     qt = q.transpose(0, 2, 1, 3).reshape(b * h, sq, d)
@@ -224,7 +338,8 @@ def _flash_forward(q, k, v, causal, sm_scale, block_q, block_k, interpret,
         name="flash_fwd" if window is None else "flash_win_fwd",
         out_shape=[
             jax.ShapeDtypeStruct((b * h, sq, d), q.dtype),
-            jax.ShapeDtypeStruct((b * h, sq, 1), jnp.float32),
+            jax.ShapeDtypeStruct((b * h, sq // block_q, block_q),
+                                 jnp.float32),
         ],
         grid=(b * h, sq // block_q),
         in_specs=[
@@ -234,11 +349,13 @@ def _flash_forward(q, k, v, causal, sm_scale, block_q, block_k, interpret,
         ],
         out_specs=[
             pl.BlockSpec((1, block_q, d), q_map),
-            pl.BlockSpec((1, block_q, 1), q_map),
+            pl.BlockSpec((1, sq // block_q, block_q),
+                         lambda i, qb: (i, 0, 0)),
         ],
         interpret=interpret,
     )(qt, kt, vt)
-    return out.reshape(b, h, sq, d).transpose(0, 2, 1, 3), lse
+    return (out.reshape(b, h, sq, d).transpose(0, 2, 1, 3),
+            lse.reshape(b * h, sq))
 
 
 # ---------------------------------------------------------------- pallas bwd
@@ -258,13 +375,10 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     qb = pl.program_id(1)
     q = q_ref[0].astype(jnp.float32)                     # [block_q, d]
     do = do_ref[0].astype(jnp.float32)                   # [block_q, d]
-    lse = lse_ref[0]                                     # [block_q, 1]
-    delta = delta_ref[0]                                 # [block_q, 1]
+    lse, delta = _cols(lse_ref[0, pl.ds(qb, 1), :],      # [block_q, 1]
+                       delta_ref[0, pl.ds(qb, 1), :])
     d = q.shape[-1]
-
-    num_kv_blocks = seq_k // block_k
-    if causal:
-        last_q = causal_offset + (qb + 1) * block_q - 1
+    first_q = causal_offset + qb * block_q
 
     def body(kb, dq):
         k_blk = k_ref[0, pl.ds(kb * block_k, block_k), :].astype(jnp.float32)
@@ -274,11 +388,7 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             preferred_element_type=jnp.float32,
         ) * sm_scale                                     # [block_q, block_k]
         if causal:
-            qi = jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
-            ki = jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
-            mask = _seen(causal_offset + qb * block_q + qi,
-                         kb * block_k + ki, window)
-            s = jnp.where(mask, s, _NEG_INF)
+            s = _mask(s, first_q, kb * block_k, window)
         p = jnp.exp(s - lse)                             # [block_q, block_k]
         dp = jax.lax.dot_general(
             do, v_blk, (((1,), (1,)), ((), ())),
@@ -290,10 +400,10 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             preferred_element_type=jnp.float32,
         )
 
-    upper = jax.lax.div(last_q, block_k) + 1 if causal else num_kv_blocks
-    lower = _first_key_block(qb, block_q, block_k, causal_offset, window)
+    first, _, _, end = _key_bounds(qb, block_q, block_k, seq_k,
+                                   causal_offset, causal, window)
     dq = jax.lax.fori_loop(
-        lower, upper, body, jnp.zeros((block_q, d), jnp.float32))
+        first, end, body, jnp.zeros((block_q, d), jnp.float32))
     dq_ref[0] = (dq * sm_scale).astype(dq_ref.dtype)
 
 
@@ -301,6 +411,14 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                           dk_ref, dv_ref, *, block_q: int, causal: bool,
                           sm_scale: float, seq_q: int, block_k: int,
                           causal_offset: int, window: Optional[int] = None):
+    # The tiles are the transposes of the other kernels', keys down and
+    # queries across: S^T = K Q^T, dV += P^T dO and dK += dS^T Q are then
+    # plain row-by-column products. On [block_q, block_k] tiles the last
+    # two contract the tile's rows, and Mosaic transposes P and dS (two
+    # 512 x 512 float32 transposes a tile) before it can: measured on v5e,
+    # 25.4 -> 22.1 ms at 2 x 8,192 x 48 heads and 11.6 -> 9.3 ms for the
+    # 512-key band at 72. A query block's statistics are a row of lse_ref
+    # and delta_ref, which a [block_k, block_q] tile takes as it lies.
     import jax.experimental.pallas as pl
 
     kb = pl.program_id(1)
@@ -308,54 +426,39 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     v_blk = v_ref[0].astype(jnp.float32)                 # [block_k, d]
     d = k_blk.shape[-1]
 
-    num_q_blocks = seq_q // block_q
-
     def body(qb, carry):
         dk, dv = carry
         q = q_ref[0, pl.ds(qb * block_q, block_q), :].astype(jnp.float32)
         do = do_ref[0, pl.ds(qb * block_q, block_q), :].astype(jnp.float32)
-        lse = lse_ref[0, pl.ds(qb * block_q, block_q), :]   # [block_q, 1]
-        delta = delta_ref[0, pl.ds(qb * block_q, block_q), :]
+        lse = lse_ref[0, pl.ds(qb, 1), :]                # [1, block_q]
+        delta = delta_ref[0, pl.ds(qb, 1), :]            # [1, block_q]
         s = jax.lax.dot_general(
-            q, k_blk, (((1,), (1,)), ((), ())),
+            k_blk, q, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
-        ) * sm_scale                                     # [block_q, block_k]
+        ) * sm_scale                                     # [block_k, block_q]
         if causal:
-            qi = jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
-            ki = jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
-            mask = _seen(causal_offset + qb * block_q + qi,
-                         kb * block_k + ki, window)
-            s = jnp.where(mask, s, _NEG_INF)
-        p = jnp.exp(s - lse)                             # [block_q, block_k]
+            s = _mask(s, causal_offset + qb * block_q, kb * block_k, window,
+                      q_axis=1)
+        p = jnp.exp(s - lse)                             # [block_k, block_q]
         dv_new = dv + jax.lax.dot_general(
-            p, do, (((0,), (0,)), ((), ())),
+            p, do, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )                                                # [block_k, d]
         dp = jax.lax.dot_general(
-            do, v_blk, (((1,), (1,)), ((), ())),
+            v_blk, do, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
-        )                                                # [block_q, block_k]
+        )                                                # [block_k, block_q]
         ds = p * (dp - delta)
         dk_new = dk + jax.lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())),
+            ds, q, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )                                                # [block_k, d]
         return dk_new, dv_new
 
-    if causal:
-        # first q row that can see this key block: qrow >= k_start - offset
-        lower = jnp.maximum(
-            0, jax.lax.div(kb * block_k - causal_offset, block_q))
-    else:
-        lower = 0
-    upper = num_q_blocks
-    if window is not None:
-        # the last q row that sees the block's last key: its position
-        # less than that key's plus the window
-        last_q = (kb + 1) * block_k - 1 + window - 1 - causal_offset
-        upper = jnp.minimum(upper, jax.lax.div(last_q, block_q) + 1)
+    first, _, _, end = _query_bounds(kb, block_q, block_k, seq_q,
+                                     causal_offset, causal, window)
     dk, dv = jax.lax.fori_loop(
-        lower, upper, body,
+        first, end, body,
         (jnp.zeros((block_k, d), jnp.float32),
          jnp.zeros((block_k, d), jnp.float32)))
     dk_ref[0] = (dk * sm_scale).astype(dk_ref.dtype)
@@ -366,18 +469,18 @@ _SCOPED_VMEM = 16 << 20        # Mosaic's default limit for one kernel
 
 
 def _dkv_vmem(sq: int, d: int, dtype) -> dict:
-    """The dK/dV kernel holds a head's whole q and dO and the two [sq, 1]
-    float32 columns (128 lanes wide in VMEM), each twice for the pipeline:
-    12 MB at 4,096 queries, 24.5 at 8,192, over Mosaic's default limit of
-    16 (v5e has 128 MiB). Past the default the call asks for what it
-    needs; under it the call is the one it always was."""
-    need = 2 * (2 * sq * d * jnp.dtype(dtype).itemsize + 2 * sq * 128 * 4)
-    if need + (2 << 20) <= _SCOPED_VMEM:
+    """The dK/dV kernel holds a head's whole q and dO, each twice for the
+    pipeline (8.4 MB at 8,192 queries; the two statistics are rows of a
+    few KB), beside some 7 MB of a tile's float32 temporaries: past
+    Mosaic's default limit of 16 MB (v5e has 128 MiB) the call asks for
+    what it needs; under it the call is the one it always was."""
+    need = 2 * 2 * sq * d * jnp.dtype(dtype).itemsize
+    if need + (8 << 20) <= _SCOPED_VMEM:
         return {}
     from jax.experimental.pallas import tpu as pltpu
 
     return {"compiler_params": pltpu.CompilerParams(
-        vmem_limit_bytes=need + (8 << 20))}
+        vmem_limit_bytes=need + (12 << 20))}
 
 
 def _flash_backward(q, k, v, out, lse, g, causal, sm_scale, block_q, block_k,
@@ -387,7 +490,6 @@ def _flash_backward(q, k, v, out, lse, g, causal, sm_scale, block_q, block_k,
     b, sq, h, d = q.shape
     _, sk, kvh, _ = k.shape
     group = h // kvh
-    block_q, block_k = _check_blocks(sq, sk, block_q, block_k)
 
     qt = q.transpose(0, 2, 1, 3).reshape(b * h, sq, d)
     kt = k.transpose(0, 2, 1, 3).reshape(b * kvh, sk, d)
@@ -396,7 +498,9 @@ def _flash_backward(q, k, v, out, lse, g, causal, sm_scale, block_q, block_k,
     ot = out.transpose(0, 2, 1, 3).reshape(b * h, sq, d)
     # delta_i = dO_i · O_i  (rowwise), the softmax-jacobian correction term.
     delta = jnp.sum(dot.astype(jnp.float32) * ot.astype(jnp.float32),
-                    axis=-1, keepdims=True)              # [b*h, sq, 1]
+                    axis=-1)                             # [b*h, sq]
+    stat_rows = (b * h, sq // block_q, block_q)      # a query block a row
+    lse, delta = lse.reshape(stat_rows), delta.reshape(stat_rows)
 
     def q_map(i, qb):
         return (i, qb, 0)
@@ -408,6 +512,8 @@ def _flash_backward(q, k, v, out, lse, g, causal, sm_scale, block_q, block_k,
 
     def full_q_map(i, kb):
         return (i, 0, 0)
+
+    stat_spec = pl.BlockSpec((1,) + stat_rows[1:], full_q_map)
 
     def k_map(i, kb):
         batch = i // h
@@ -428,8 +534,8 @@ def _flash_backward(q, k, v, out, lse, g, causal, sm_scale, block_q, block_k,
             pl.BlockSpec((1, sk, d), kv_map),
             pl.BlockSpec((1, sk, d), kv_map),
             pl.BlockSpec((1, block_q, d), q_map),
-            pl.BlockSpec((1, block_q, 1), q_map),
-            pl.BlockSpec((1, block_q, 1), q_map),
+            stat_spec,
+            stat_spec,
         ],
         out_specs=pl.BlockSpec((1, block_q, d), q_map),
         interpret=interpret,
@@ -454,8 +560,8 @@ def _flash_backward(q, k, v, out, lse, g, causal, sm_scale, block_q, block_k,
             pl.BlockSpec((1, block_k, d), k_map),
             pl.BlockSpec((1, block_k, d), k_map),
             pl.BlockSpec((1, sq, d), full_q_map),
-            pl.BlockSpec((1, sq, 1), full_q_map),
-            pl.BlockSpec((1, sq, 1), full_q_map),
+            stat_spec,
+            stat_spec,
         ],
         out_specs=[
             pl.BlockSpec((1, block_k, d), lambda i, kb: (i, kb, 0)),
@@ -489,19 +595,17 @@ def _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret,
     # (models/llama.py REMAT_LADDER): a policy that keeps both drops the
     # backward's second run of the forward kernel; a name given outside
     # this rule would miss the residual. Inert under a plain
-    # jax.checkpoint and outside one. The logsumexp is kept without its
-    # singleton lane dim: as a saved [.., sq, 1] array XLA may tile it to
-    # 128 lanes, 128 times its bytes.
+    # jax.checkpoint and outside one.
     out = checkpoint_name(out, "flash_out")
-    lse = checkpoint_name(lse[..., 0], "flash_lse")
+    lse = checkpoint_name(lse, "flash_lse")
     return out, (q, k, v, out, lse)
 
 
 def _flash_bwd(causal, sm_scale, block_q, block_k, interpret, window, res,
                g):
     q, k, v, out, lse = res
-    return _flash_backward(q, k, v, out, lse[..., None], g, causal,
-                           sm_scale, block_q, block_k, interpret, window)
+    return _flash_backward(q, k, v, out, lse, g, causal, sm_scale, block_q,
+                           block_k, interpret, window)
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
@@ -523,7 +627,9 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     ``use_pallas=None`` auto-selects: the Pallas kernel on TPU backends, the
     reference path elsewhere (tests force the kernel with interpret=True).
     ``block_q``/``block_k`` default to the largest power-of-two divisor of
-    the sequence length up to 512.
+    the sequence length up to 512 (``_auto_block``). A call on the kernel
+    path writes one kept span as it is traced, ``rtpu.flash.tiles``: its
+    blocks and ``tile_plan``'s count of what its loops will visit.
     """
     _check_window(causal, window)
     if sm_scale is None:
@@ -533,8 +639,15 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     if not use_pallas:
         return attention_reference(q, k, v, causal, sm_scale, window)
     if block_q is None:
-        block_q = _auto_block(q.shape[1], DEFAULT_BLOCK_Q)
+        block_q = _auto_block(q.shape[1])
     if block_k is None:
-        block_k = _auto_block(k.shape[1], DEFAULT_BLOCK_K)
+        block_k = _auto_block(k.shape[1])
+    block_q, block_k = _check_blocks(q.shape[1], k.shape[1], block_q, block_k)
+    with tracing.span("rtpu.flash.tiles", keep=True, seq_q=q.shape[1],
+                      seq_k=k.shape[1], block_q=block_q, block_k=block_k,
+                      window=window,
+                      **tile_plan(q.shape[1], k.shape[1], block_q, block_k,
+                                  window, causal)):
+        pass
     return _flash(q, k, v, causal, sm_scale, block_q, block_k, interpret,
                   window)

@@ -19,7 +19,10 @@ the checkout whose ``ray_tpu`` and ``benchmark/`` are read, so the same
 script judges a parent commit unpacked elsewhere. ``--out`` gets
 ``<cell>.hlo.txt`` (``as_text()`` without metadata) and ``summary.json``
 (its sha256, ``memory_analysis()``, the resolved remat level, the count
-of Mosaic calls).
+of Mosaic calls, and ``flash_tiles``: what each distinct flash kernel call
+of the step visits, from its ``rtpu.flash.tiles`` span). ``--compare``
+says of two differing programs how many lines changed and how many of
+those are calls of the flash kernels.
 """
 
 from __future__ import annotations
@@ -36,6 +39,9 @@ V5E_BYTES_LIMIT = int(15.75 * 2 ** 30)
 MEMORY_FIELDS = ("argument_size_in_bytes", "output_size_in_bytes",
                  "alias_size_in_bytes", "temp_size_in_bytes",
                  "generated_code_size_in_bytes", "peak_memory_in_bytes")
+SERIAL = re.compile(r"\b([A-Za-z_][\w-]*)\.\d+\b")
+FLASH_CALL = re.compile(
+    r'%flash_\w+ = .*custom_call_target="tpu_custom_call"')
 
 
 def strip_metadata(text: str) -> str:
@@ -120,8 +126,13 @@ def _compile_cell(tree: str, cell: dict, topo) -> dict:
     n0 = len(tracing.chrome_events())
     compiled = jax.jit(step, donate_argnums=(0, 1)).lower(
         params, opt, batch).compile()
-    plans = [e["args"]["level"] for e in tracing.chrome_events()[n0:]
+    events = tracing.chrome_events()[n0:]
+    plans = [e["args"]["level"] for e in events
              if e["name"] == "rtpu.train.remat_plan"]
+    # one line a distinct kernel call of the step: what its loops visit
+    tiles = sorted({json.dumps({k: v for k, v in e["args"].items()
+                                if k not in ("id", "parent", "self_us")})
+                    for e in events if e["name"] == "rtpu.flash.tiles"})
     text = strip_metadata(compiled.as_text())
     ma = compiled.memory_analysis()
     return {"text": text, "summary": {
@@ -129,6 +140,7 @@ def _compile_cell(tree: str, cell: dict, topo) -> dict:
         "lines": text.count("\n"),
         "mosaic_calls": text.count("tpu_custom_call"),
         "remat_plan": plans[0] if plans else None,
+        "flash_tiles": [json.loads(t) for t in tiles],
         "memory_analysis": {f: getattr(ma, f) for f in MEMORY_FIELDS}}}
 
 
@@ -185,9 +197,18 @@ def compare(a: str, b: str) -> int:
             texts = []
             for d in (a, b):
                 with open(os.path.join(d, name + ".hlo.txt")) as f:
-                    texts.append(f.read().splitlines())
-            for line in list(difflib.unified_diff(*texts, lineterm="",
-                                                  n=0))[:40]:
+                    # without the instructions' serial numbers: one more
+                    # instruction renumbers every later one of its kind
+                    texts.append(SERIAL.sub(r"\1", f.read()).splitlines())
+            diff = list(difflib.unified_diff(*texts, lineterm="", n=0))
+            changed = [line[1:].strip() for line in diff
+                       if line[:1] in "+-" and line[:3] not in ("+++", "---")]
+            # a kernel's body is inside its call's line: a PR that edits
+            # the flash kernels alone changes these lines and no other
+            flash = sum(bool(FLASH_CALL.match(line)) for line in changed)
+            print(f"  {len(changed)} changed lines, {flash} of them calls "
+                  "of the flash kernels")
+            for line in diff[:40]:
                 print("  " + line[:240])
     return 1 if differing else 0
 

@@ -503,6 +503,49 @@ def test_remat_plan_is_one_kept_span_of_a_traced_program():
     assert ev["args"]["saved_bytes_per_layer"] > 0
 
 
+def test_flash_tiles_is_one_kept_span_of_a_traced_call():
+    """A traced kernel call writes what its loops will do once, as a kept
+    span (no flag, no profiler window): the blocks it chose, the tiles a
+    head visits, those among them that apply the mask, and the share of
+    the visited scores the mask keeps. The reference path has no tiles."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.ops.attention import flash_attention, tile_plan
+
+    assert not config.task_events_enabled
+
+    def trace(seq_q, seq_k, **kw):
+        q = jax.ShapeDtypeStruct((1, seq_q, 4, 128), jnp.bfloat16)
+        k = jax.ShapeDtypeStruct((1, seq_k, 2, 128), jnp.bfloat16)
+        n0 = len(_mine("rtpu.flash.tiles"))
+        # forward and all three backward kernels of one call
+        jax.eval_shape(jax.grad(lambda q, k, v: flash_attention(
+            q, k, v, interpret=True, **kw).astype(jnp.float32).sum(),
+            argnums=(0, 1, 2)), q, k, k)
+        return [{k_: v for k_, v in e["args"].items()
+                 if k_ not in ("id", "parent", "self_us")}
+                for e in _mine("rtpu.flash.tiles")[n0:]]
+
+    assert trace(1024, 1024, use_pallas=False) == []
+    (causal,) = trace(4096, 4096, use_pallas=True)
+    assert causal == {"seq_q": 4096, "seq_k": 4096, "block_q": 512,
+                      "block_k": 512, "window": None, "tiles_visited": 36,
+                      "tiles_edge": 8,
+                      "kept_share": tile_plan(4096, 4096, 512, 512)[
+                          "kept_share"]}
+    # with blocks as wide as the band no tile of it is whole
+    (band,) = trace(8192, 8192, use_pallas=True, window=512)
+    assert (band["block_q"], band["block_k"], band["window"]) == (
+        512, 512, 512)
+    assert (band["tiles_visited"], band["tiles_edge"]) == (31, 31)
+    assert 0.50 < band["kept_share"] < 0.51
+    # explicit blocks stay the caller's; keys ahead of the queries
+    (chunk,) = trace(512, 2048, use_pallas=True, block_q=256, block_k=512)
+    assert (chunk["block_q"], chunk["block_k"]) == (256, 512)
+    assert (chunk["tiles_visited"], chunk["tiles_edge"]) == (8, 2)
+
+
 def test_every_kernel_and_serving_program_has_a_name():
     """A profiler trace names a Pallas call by its ``name`` and a jitted
     program by its function's: none may be anonymous."""
