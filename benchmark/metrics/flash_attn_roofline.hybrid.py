@@ -1,0 +1,21 @@
+"""``flash_attn_roofline`` for a stack of convolution and attention
+layers: the causal FLOPs (forward and backward) of the attention layers
+at their head size (64 in LFM2) over the peak, divided by the device time
+per step of the calls named ``flash_fwd``, ``flash_bwd_dq`` and
+``flash_bwd_dkv``; the recomputed forward's call is in the time. Bound:
+compute.
+source: device_trace (lib/scopes.py's ``kernel_s``)."""
+from benchmark.lib import hybrid_flops, mixed_flops
+
+KERNELS = ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")
+
+
+def read(obs):
+    t = obs.get("train")
+    if (not t or not t["traced_steps"]
+            or "conv_L_cache" not in obs.get("model", ())):
+        return None
+    tf = obs["traffic"]
+    return mixed_flops.percent_of_peak_in_kernels(
+        obs, hybrid_flops.flash_flops_per_step(
+            obs["model"], tf["batch"] / t["chips"], tf["seq"]), KERNELS)

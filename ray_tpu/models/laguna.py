@@ -40,7 +40,7 @@ import jax.numpy as jnp
 
 from ray_tpu.models import llama, mixtral, olmoe
 from ray_tpu.ops.layers import rms_norm, rope_frequencies, swiglu
-from ray_tpu.ops.moe import routed_experts
+from ray_tpu.ops.moe import routed_experts_on
 
 # kind -> (sliding window attention, routed MLP)
 LAYER_KINDS = {"full_dense": (False, False), "sliding_dense": (True, False),
@@ -190,36 +190,6 @@ def init_params(cfg: LagunaConfig, key: jax.Array) -> Dict[str, Any]:
             "lm_head": draw(jax.random.fold_in(key, 99), (h, v), h)}
 
 
-def _routed(cfg: LagunaConfig, p, x: jax.Array, mesh=None):
-    """x [b, s, h] -> (the held experts' part [b, s, h], router_logits
-    [b * s, E] float32, counts [E]); on a mesh as ``olmoe._experts``:
-    every chip routes its own rows, the weights gathered whole."""
-    b, s, h = x.shape
-
-    def local(x_, router, e_gate, e_up, e_down):
-        out, logits, counts = routed_experts(
-            x_.reshape(-1, h), router, e_gate, e_up, e_down, cfg.top_k,
-            renormalize=True, held=cfg.experts_held, scale=cfg.routed_scale)
-        return out.reshape(x_.shape), logits, counts
-
-    weights = (p["router"], p["e_gate"], p["e_up"], p["e_down"])
-    if mesh is None:
-        return local(x, *weights)
-    from jax.sharding import PartitionSpec as P
-
-    from ray_tpu.parallel.sharding import resolve_axis
-
-    rows = resolve_axis("batch", mesh)
-
-    def sharded(x_, *w):
-        out, logits, counts = local(x_, *w)
-        return out, logits, (jax.lax.psum(counts, rows) if rows else counts)
-
-    return jax.shard_map(
-        sharded, mesh=mesh, in_specs=(P(rows),) + (P(),) * 4,
-        out_specs=(P(rows), P(rows), P()), check_vma=False)(x, *weights)
-
-
 def _layer(cfg: LagunaConfig, kind: str, x, p, cos, sin, mesh=None,
            keep_router_logits: bool = False):
     sliding, routed = LAYER_KINDS[kind]
@@ -235,7 +205,10 @@ def _layer(cfg: LagunaConfig, kind: str, x, p, cos, sin, mesh=None,
         with jax.named_scope("moe_shared"):
             shared = swiglu(h2, p["s_gate"].astype(dt), p["s_up"].astype(dt),
                             p["s_down"].astype(dt))
-        out, logits, counts = _routed(cfg, p, h2, mesh=mesh)
+        out, logits, counts = routed_experts_on(
+            mesh, h2, p["router"], p["e_gate"], p["e_up"], p["e_down"],
+            cfg.top_k, renormalize=True, held=cfg.experts_held,
+            scale=cfg.routed_scale)
         router = olmoe.router_stats(logits, counts)
         if keep_router_logits:
             router["logits"] = logits

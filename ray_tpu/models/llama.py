@@ -379,7 +379,9 @@ def attention_block(cfg: LlamaConfig, x, p, cos, sin, mesh=None,
     its ``wq`` (Laguna's window layers have more than its full ones);
     ``window``: the layer sees that many keys back (``flash_attention``);
     a ``wg`` in ``p`` is a per-head output gate, ``sigmoid(norm(x) @ wg)``
-    on each head's output before ``wo`` (arXiv:2505.06708, headwise)."""
+    on each head's output before ``wo`` (arXiv:2505.06708, headwise);
+    ``q_norm`` and ``k_norm`` are an RMSNorm of q and k before rope, over
+    the whole vector or, with a weight of a head's size, over each head."""
     # The named scopes here and below (embed, attn_qkv, flash, attn_out,
     # mlp, head_loss) are metadata only: they name the device time of a
     # step in a profiler trace and change no instruction.
@@ -397,13 +399,19 @@ def attention_block(cfg: LlamaConfig, x, p, cos, sin, mesh=None,
             q = q + p["bq"].astype(cfg.dtype)
             k = k + p["bk"].astype(cfg.dtype)
             v = v + p["bv"].astype(cfg.dtype)
-        if "q_norm" in p:  # OLMoE: RMSNorm over the whole q and k vectors
+        # a q/k norm's weight says what it is over: [hd] each head's dims
+        # (LFM2), else the whole q and k vectors (OLMoE)
+        per_head = "q_norm" in p and p["q_norm"].shape[-1] == hd
+        if "q_norm" in p and not per_head:
             q = rms_norm(q, p["q_norm"], cfg.rms_norm_eps)
             k = rms_norm(k, p["k_norm"], cfg.rms_norm_eps)
         heads = p["wq"].shape[-1] // hd
         q = q.reshape(b, s, heads, hd)
         k = k.reshape(b, s, cfg.num_kv_heads, hd)
         v = v.reshape(b, s, cfg.num_kv_heads, hd)
+        if per_head:
+            q = rms_norm(q, p["q_norm"], cfg.rms_norm_eps)
+            k = rms_norm(k, p["k_norm"], cfg.rms_norm_eps)
         q = apply_rope(q, cos, sin)
         k = apply_rope(k, cos, sin)
         # named for a remat level that keeps them (REMAT_LADDER; no-ops

@@ -28,7 +28,7 @@ import jax.numpy as jnp
 
 from ray_tpu.models import llama, mixtral
 from ray_tpu.ops.layers import rms_norm, rope_frequencies
-from ray_tpu.ops.moe import routed_experts
+from ray_tpu.ops.moe import routed_experts_on
 
 
 @dataclass(frozen=True)
@@ -77,38 +77,6 @@ def init_params(cfg: OlmoeConfig, key: jax.Array) -> Dict[str, Any]:
     return params
 
 
-def _experts(cfg: OlmoeConfig, p, x: jax.Array, mesh=None):
-    """x [b, s, h] -> (out [b, s, h], router_logits [b * s, E] float32,
-    counts [E]). On a mesh every chip routes its own rows of the batch
-    to all experts (their weights gathered whole, as fsdp gathers any
-    weight): the sort and the grouped matmuls stay local, which a Mosaic
-    call under a sharded jit needs anyway."""
-    b, s, h = x.shape
-
-    def local(x_, router, e_gate, e_up, e_down):
-        out, logits, counts = routed_experts(
-            x_.reshape(-1, h), router, e_gate, e_up, e_down, cfg.top_k,
-            cfg.norm_topk_prob)
-        return out.reshape(x_.shape), logits, counts
-
-    weights = (p["router"], p["e_gate"], p["e_up"], p["e_down"])
-    if mesh is None:
-        return local(x, *weights)
-    from jax.sharding import PartitionSpec as P
-
-    from ray_tpu.parallel.sharding import resolve_axis
-
-    rows = resolve_axis("batch", mesh)
-
-    def sharded(x_, *w):
-        out, logits, counts = local(x_, *w)
-        return out, logits, (jax.lax.psum(counts, rows) if rows else counts)
-
-    return jax.shard_map(
-        sharded, mesh=mesh, in_specs=(P(rows),) + (P(),) * 4,
-        out_specs=(P(rows), P(rows), P()), check_vma=False)(x, *weights)
-
-
 def router_stats(logits: jax.Array, counts: jax.Array
                  ) -> Dict[str, jax.Array]:
     """What ``router_losses`` reads of one layer: the rows routed to each
@@ -125,7 +93,9 @@ def _layer(cfg: OlmoeConfig, x, p, cos, sin, mesh=None,
     x = llama.attention_block(cfg, x, p, cos, sin, mesh=mesh)
     with jax.named_scope("mlp"):
         h2 = rms_norm(x, p["mlp_norm"], cfg.rms_norm_eps)
-        out, logits, counts = _experts(cfg, p, h2, mesh=mesh)
+        out, logits, counts = routed_experts_on(
+            mesh, h2, p["router"], p["e_gate"], p["e_up"], p["e_down"],
+            cfg.top_k, renormalize=cfg.norm_topk_prob)
         router = router_stats(logits, counts)
         if keep_router_logits:
             router["logits"] = logits

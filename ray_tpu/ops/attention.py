@@ -629,7 +629,8 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     ``block_q``/``block_k`` default to the largest power-of-two divisor of
     the sequence length up to 512 (``_auto_block``). A call on the kernel
     path writes one kept span as it is traced, ``rtpu.flash.tiles``: its
-    blocks and ``tile_plan``'s count of what its loops will visit.
+    blocks, the head size and ``tile_plan``'s count of what its loops will
+    visit.
     """
     _check_window(causal, window)
     if sm_scale is None:
@@ -645,7 +646,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     block_q, block_k = _check_blocks(q.shape[1], k.shape[1], block_q, block_k)
     with tracing.span("rtpu.flash.tiles", keep=True, seq_q=q.shape[1],
                       seq_k=k.shape[1], block_q=block_q, block_k=block_k,
-                      window=window,
+                      window=window, head_dim=q.shape[-1],
                       **tile_plan(q.shape[1], k.shape[1], block_q, block_k,
                                   window, causal)):
         pass

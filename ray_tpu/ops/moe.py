@@ -1,7 +1,7 @@
 """Routed mixture of SwiGLU experts: sorted, dropless, every shape static.
 
-The layer the many-small-expert models share (OLMoE, Mixtral, Laguna).
-A token picks
+The layer the many-small-expert models share (OLMoE, Mixtral, Laguna,
+LFM2). A token picks
 ``top_k`` of ``E`` experts; the ``n * top_k`` (token, choice) pairs are
 sorted by expert, the token rows gathered into that order, and each
 expert multiplies its own contiguous group of rows: three grouped
@@ -48,7 +48,7 @@ The loop's trip count is data, so its gradient is written out
 
 Named scopes (metadata only, nested under the caller's ``mlp``; a
 backward operation carries the scope of the call it transposes):
-``moe_route`` (router matmul, softmax, top-k, sort), ``moe_dispatch``
+``moe_route`` (router matmul, scores, top-k, sort), ``moe_dispatch``
 (gather into expert order), ``moe_experts`` (grouped matmuls and the
 activation, the gate weighting in it), ``moe_combine`` (gather back, sum).
 """
@@ -166,19 +166,35 @@ def grouped_matmul(rows: jax.Array, w: jax.Array, sizes: jax.Array
 
 
 def route(x: jax.Array, router_w: jax.Array, top_k: int,
-          renormalize: bool = False, scale: float = 1.0
+          renormalize: bool = False, scale: float = 1.0,
+          score: str = "softmax", select_bias: Optional[jax.Array] = None,
+          renorm_eps: float = 0.0
           ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """(router_logits [n, E] float32, top_w [n, K] float32, top_e [n, K]
-    int32): logits accumulate in float32, softmax in float32 over all E,
+    int32): logits accumulate in float32, the scores are their softmax
+    over all E or (``score="sigmoid"``) each logit's sigmoid, in float32,
     then the K largest; ``renormalize`` divides the K weights by their
-    sum (Mixtral does, OLMoE does not); ``scale`` multiplies them after
-    that (Laguna's routed scaling factor)."""
+    sum plus ``renorm_eps`` (Mixtral does, OLMoE does not); ``scale``
+    multiplies them after that (Laguna's routed scaling factor).
+    ``select_bias [E]`` float32 is added to the scores for the choice of
+    experts alone (loss-free balancing, arXiv:2408.15664): the gate
+    weights are the scores without it, and no gradient reaches it."""
     logits = jnp.dot(x, router_w.astype(x.dtype),
                      preferred_element_type=jnp.float32)
-    probs = jax.nn.softmax(logits, axis=-1)
-    top_w, top_e = jax.lax.top_k(probs, top_k)
+    if score not in ("softmax", "sigmoid"):
+        raise ValueError(f"unknown router score {score!r} (softmax | sigmoid)")
+    scores = (jax.nn.softmax(logits, axis=-1) if score == "softmax"
+              else jax.nn.sigmoid(logits))
+    if select_bias is None:
+        top_w, top_e = jax.lax.top_k(scores, top_k)
+    else:
+        _, top_e = jax.lax.top_k(
+            jax.lax.stop_gradient(scores + select_bias.astype(jnp.float32)),
+            top_k)
+        top_w = jnp.take_along_axis(scores, top_e, axis=-1)
     if renormalize:
-        top_w = top_w / top_w.sum(-1, keepdims=True)
+        norm = top_w.sum(-1, keepdims=True)
+        top_w = top_w / (norm + renorm_eps if renorm_eps else norm)
     if scale != 1.0:
         top_w = top_w * scale
     return logits, top_w, top_e
@@ -290,8 +306,10 @@ def routed_experts(x: jax.Array, router_w: jax.Array, e_gate: jax.Array,
                    e_up: jax.Array, e_down: jax.Array, top_k: int,
                    renormalize: bool = False,
                    held: Optional[Tuple[int, int]] = None,
-                   scale: float = 1.0
-                   ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+                   scale: float = 1.0, score: str = "softmax",
+                   select_bias: Optional[jax.Array] = None,
+                   renorm_eps: float = 0.0, keep_choices: bool = False
+                   ) -> Tuple[jax.Array, ...]:
     """x [n, h], router_w [h, E], e_gate / e_up [E, h, f], e_down
     [E, f, h] -> (out [n, h], router_logits [n, E] float32, counts [E]
     int32: rows routed to each expert, n * top_k in all). A row's gate
@@ -299,10 +317,15 @@ def routed_experts(x: jax.Array, router_w: jax.Array, e_gate: jax.Array,
     ``held=(first, count)``: the expert weights are those of experts
     ``first .. first + count`` alone, ``[count, ...]``, and ``out`` is
     their part of the result (the module's docstring); ``None``: all
-    ``E`` are here."""
+    ``E`` are here. ``score``, ``select_bias`` and ``renorm_eps`` are
+    ``route``'s; ``keep_choices`` appends ``route``'s own ``top_e [n, K]``
+    to the result, for a check of what was chosen."""
     num_experts = router_w.shape[-1]
     with jax.named_scope("moe_route"):
-        logits, top_w, top_e = route(x, router_w, top_k, renormalize, scale)
+        logits, top_w, top_e = route(
+            x, router_w, top_k, renormalize, scale, score=score,
+            select_bias=select_bias, renorm_eps=renorm_eps)
+        choices = (top_e,) if keep_choices else ()
         flat_e = top_e.reshape(-1)
         if held is not None:
             first, count = held
@@ -318,7 +341,7 @@ def routed_experts(x: jax.Array, router_w: jax.Array, e_gate: jax.Array,
                 x, top_w.reshape(-1), e_gate, e_up, e_down, order,
                 jax.lax.dynamic_slice(counts, (first,), (count,)), top_k,
                 chunk)
-            return out, logits, counts
+            return (out, logits, counts) + choices
         # stable: an expert's rows stay in token order
         order = jnp.argsort(flat_e, stable=True).astype(jnp.int32)
         inv = jnp.argsort(order)
@@ -332,4 +355,46 @@ def routed_experts(x: jax.Array, router_w: jax.Array, e_gate: jax.Array,
         rows = _swiglu_rows(rows, w_rows, counts, e_gate, e_up, e_down)
     with jax.named_scope("moe_combine"):
         out = _combine(rows, token_of, inv, top_k)
-    return out, logits, counts
+    return (out, logits, counts) + choices
+
+
+def routed_experts_on(mesh, x: jax.Array, router_w: jax.Array,
+                      e_gate: jax.Array, e_up: jax.Array, e_down: jax.Array,
+                      top_k: int, select_bias: Optional[jax.Array] = None,
+                      **how) -> Tuple[jax.Array, ...]:
+    """``routed_experts`` for x [b, s, h] -> (out [b, s, h], router_logits
+    [b * s, E] float32, counts [E] and, under ``keep_choices``, the
+    choices [b * s, K]); ``how`` is its keywords. On a mesh
+    every chip routes its own rows of the batch to all the experts here
+    (their weights gathered whole, as fsdp gathers any weight): the sort
+    and the grouped matmuls stay local, which a Mosaic call under a
+    sharded jit needs anyway, and ``counts`` are summed over the batch
+    axes."""
+    h = x.shape[-1]
+    weights = (router_w, e_gate, e_up, e_down) + (
+        () if select_bias is None else (select_bias,))
+
+    def local(x_, router, e_gate, e_up, e_down, bias=None):
+        out, *stats = routed_experts(
+            x_.reshape(-1, h), router, e_gate, e_up, e_down, top_k,
+            select_bias=bias, **how)
+        return (out.reshape(x_.shape), *stats)
+
+    if mesh is None:
+        return local(x, *weights)
+    from jax.sharding import PartitionSpec as P
+
+    from ray_tpu.parallel.sharding import resolve_axis
+
+    rows = resolve_axis("batch", mesh)
+
+    def sharded(x_, *w):
+        out, logits, counts, *chosen = local(x_, *w)
+        return (out, logits, jax.lax.psum(counts, rows) if rows else counts,
+                *chosen)
+
+    by_row = (P(rows),) * bool(how.get("keep_choices"))
+    return jax.shard_map(
+        sharded, mesh=mesh, in_specs=(P(rows),) + (P(),) * len(weights),
+        out_specs=(P(rows), P(rows), P()) + by_row,
+        check_vma=False)(x, *weights)

@@ -3,7 +3,8 @@ moves model code without meaning to change a program compares, parent
 against change, before any chip does.
 
 For every training cell of ``BENCHMARK.json`` the cell's adamw step
-(``benchmark/cells/train.py``, ``train_moe.py`` and ``train_mixed.py``: the cell's
+(``benchmark/cells/train.py``, ``train_moe.py``, ``train_mixed.py`` and
+``train_hybrid.py``, whose own ``make_step`` is compiled: the cell's
 configuration, batch, mesh and donation) is compiled for a v5e host that
 is described and not attached, with ``jax.default_backend`` answering
 "tpu" and ``llama._device_capacity`` a v5e chip's limit, as
@@ -19,10 +20,13 @@ the checkout whose ``ray_tpu`` and ``benchmark/`` are read, so the same
 script judges a parent commit unpacked elsewhere. ``--out`` gets
 ``<cell>.hlo.txt`` (``as_text()`` without metadata) and ``summary.json``
 (its sha256, ``memory_analysis()``, the resolved remat level, the count
-of Mosaic calls, and ``flash_tiles``: what each distinct flash kernel call
-of the step visits, from its ``rtpu.flash.tiles`` span). ``--compare``
-says of two differing programs how many lines changed and how many of
-those are calls of the flash kernels.
+of Mosaic calls and their names; and, about the program and not of it,
+``flash_tiles``: what each distinct flash kernel call of the step visits,
+from its ``rtpu.flash.tiles`` span, and ``scopes``: how many instructions
+carry each ``jax.named_scope`` name as the innermost). ``--compare``
+judges the program (``PROGRAM_FIELDS``) and says of two differing
+programs how many lines changed and how many of those are calls of the
+flash kernels.
 """
 
 from __future__ import annotations
@@ -39,6 +43,15 @@ V5E_BYTES_LIMIT = int(15.75 * 2 ** 30)
 MEMORY_FIELDS = ("argument_size_in_bytes", "output_size_in_bytes",
                  "alias_size_in_bytes", "temp_size_in_bytes",
                  "generated_code_size_in_bytes", "peak_memory_in_bytes")
+# what --compare holds two programs equal by; the rest of a summary
+# describes the program and may gain keys
+PROGRAM_FIELDS = ("sha256", "lines", "mosaic_calls", "remat_plan",
+                  "memory_analysis")
+OP_NAME = re.compile(r'op_name="([^"]*)"')
+SCOPE = re.compile(r"(?<![A-Za-z0-9_.])(?<!jit\()([a-z][a-z0-9]*(?:_[a-z0-9]+)+|embed|"
+                   r"flash|mlp)(?=[/)])")
+JAX_OWN = ("closed_call", "rematted_computation", "custom_vjp_call",
+           "custom_jvp_call", "pallas_call", "shard_map")
 SERIAL = re.compile(r"\b([A-Za-z_][\w-]*)\.\d+\b")
 FLASH_CALL = re.compile(
     r'%flash_\w+ = .*custom_call_target="tpu_custom_call"')
@@ -101,14 +114,19 @@ def _compile_cell(tree: str, cell: dict, topo) -> dict:
     shapes = jax.eval_shape(lambda k: mod.init_params(cfg, k),
                             jax.random.PRNGKey(0))
     params = placed(shapes, psh)
+    # the optimizer's leaves (all, but where the module says otherwise)
+    owned = getattr(mod, "trainable", lambda p: p)
     # the moments lie where the parameters do; the step count is one scalar
-    opt = jax.eval_shape(tx.init, shapes)
-    opt = (opt[0]._replace(count=placed(opt[0].count, rep), mu=params,
-                           nu=params),) + placed(tuple(opt[1:]), rep)
+    opt = jax.eval_shape(tx.init, owned(shapes))
+    opt = (opt[0]._replace(count=placed(opt[0].count, rep), mu=owned(params),
+                           nu=owned(params)),) + placed(tuple(opt[1:]), rep)
     batch = {"tokens": jax.ShapeDtypeStruct(
         (tr["batch"], tr["seq"] + 1), jnp.int32, sharding=bsh)}
 
-    if moe:
+    if tr["family"] == "train_hybrid":
+        step = import_module("benchmark.cells.train_hybrid").make_step(
+            mod, cfg, tx, mesh)
+    elif moe:
         def step(params, opt, batch):
             (loss, aux), grads = jax.value_and_grad(
                 lambda p: mod.loss_terms(cfg, p, batch, mesh=mesh),
@@ -133,14 +151,25 @@ def _compile_cell(tree: str, cell: dict, topo) -> dict:
     tiles = sorted({json.dumps({k: v for k, v in e["args"].items()
                                 if k not in ("id", "parent", "self_us")})
                     for e in events if e["name"] == "rtpu.flash.tiles"})
-    text = strip_metadata(compiled.as_text())
+    full = compiled.as_text()
+    scopes = {}
+    for path in OP_NAME.findall(full):
+        found = [n for n in SCOPE.findall(path) if n not in JAX_OWN]
+        if found:
+            scopes[found[-1]] = scopes.get(found[-1], 0) + 1
+    kernels = sorted(set(re.findall(
+        r'%([\w.-]+?)(?:\.\d+)? = [^\n]*custom_call_target="tpu_custom_call"',
+        full)))
+    text = strip_metadata(full)
     ma = compiled.memory_analysis()
     return {"text": text, "summary": {
         "sha256": hashlib.sha256(text.encode()).hexdigest(),
         "lines": text.count("\n"),
         "mosaic_calls": text.count("tpu_custom_call"),
         "remat_plan": plans[0] if plans else None,
+        "mosaic_kernels": kernels,
         "flash_tiles": [json.loads(t) for t in tiles],
+        "scopes": dict(sorted(scopes.items())),
         "memory_analysis": {f: getattr(ma, f) for f in MEMORY_FIELDS}}}
 
 
@@ -188,11 +217,16 @@ def compare(a: str, b: str) -> int:
     sa, sb = summary(a), summary(b)
     differing = 0
     for name in sorted(set(sa) | set(sb)):
-        same = sa.get(name) == sb.get(name)
+        if name not in sa or name not in sb:    # a cell one tree cannot run
+            print(f"{name}: only in {b if name in sb else a} "
+                  f"{json.dumps((sb if name in sb else sa)[name])}")
+            continue
+        same = all(sa[name].get(f) == sb[name].get(f)
+                   for f in PROGRAM_FIELDS)
         differing += not same
         print(f"{name}: {'equal' if same else 'DIFFERENT'} "
               f"{json.dumps(sb.get(name))}")
-        if not same and name in sa and name in sb:
+        if not same:
             print(f"  was: {json.dumps(sa[name])}")
             texts = []
             for d in (a, b):

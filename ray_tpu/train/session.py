@@ -75,8 +75,10 @@ class TrainContext:
 # session serves the last value of each as ``rtpu_train_<key>``
 # (``moe_rows_held``: of the routed rows, those the experts held here
 # multiplied, where a layer holds a share of its experts)
+# (``moe_router_bias_abs_max``: the largest selection bias of a router
+# that balances its load by one, ``models/lfm2.update_router_bias``)
 MOE_COUNTERS = ("moe_rows_routed", "moe_rows_held",
-                "moe_expert_load_max_over_mean")
+                "moe_expert_load_max_over_mean", "moe_router_bias_abs_max")
 
 
 class SessionInterruptedError(BaseException):

@@ -501,6 +501,340 @@ def test_laguna_window_and_gate_are_in_the_result():
                                   tokens)[0]), rtol=1e-5, atol=1e-5)
 
 
+# --------------------------------------------------------------------- lfm2
+
+
+@pytest.fixture(scope="module", params=[None, (0, 4)],
+                ids=["all-experts", "held-0..3"])
+def lfm2_setup(request):
+    """``Lfm2Config.tiny()``: five layers (conv + dense MLP, attention,
+    three conv), 4 query heads on 2 kv heads of 16, 8 experts top-2 on
+    sigmoid scores plus a bias, float32; all experts here, or experts
+    0..3 of each routed layer as a chip's share. Norms and biases are
+    moved off their initial 1 and 0."""
+    import os
+    import sys
+
+    sys.path.insert(0, os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))          # benchmark/ lies beside tests/
+    from benchmark.references import lfm2_ref
+    from ray_tpu.models import lfm2
+
+    cfg = lfm2.Lfm2Config.tiny(attn_impl="reference",
+                               experts_held=request.param)
+    params = lfm2.init_params(cfg, jax.random.PRNGKey(0))
+    for n, kind in enumerate(params["layers"]):
+        for i, name in enumerate(("attn_norm", "op_norm", "mlp_norm",
+                                  "q_norm", "k_norm", "router_bias")):
+            if name in params["layers"][kind]:
+                w = params["layers"][kind][name]
+                params["layers"][kind][name] = w + (
+                    0.1 if name == "router_bias" else 0.3
+                ) * jax.random.normal(jax.random.PRNGKey(10 * n + i), w.shape)
+    tokens = np.random.default_rng(1).integers(0, cfg.vocab_size, (2, 33))
+    return lfm2, lfm2_ref, cfg, params, tokens
+
+
+def test_lfm2_forward_and_loss_match_the_reference(lfm2_setup):
+    """Logits, the four routed layers' router logits, choices and expert
+    counts (over all 8 experts, held or not) and the loss against the
+    plain float32 reference on seeded weights."""
+    lfm2, lfm2_ref, cfg, params, tokens = lfm2_setup
+    assert cfg.pattern == ("conv_dense", "attn_moe", "conv_moe", "conv_moe",
+                           "conv_moe")
+    assert params["layers"]["conv_moe"]["w_in"].shape == (3, 64, 192)
+    assert params["layers"]["attn_moe"]["q_norm"].shape == (1, 16)
+    assert params["layers"]["conv_moe"]["e_gate"].shape[1] == (
+        4 if cfg.experts_held else 8)
+    assert "lm_head" not in params                      # tied
+    with jax.default_matmul_precision("highest"):
+        logits, router = jax.jit(lambda p, t: lfm2.forward(
+            cfg, p, t, keep_router_logits=True))(params, tokens[:, :-1])
+        loss, terms = jax.jit(lambda p, t: lfm2.loss_terms(
+            cfg, p, {"tokens": t}))(params, tokens)
+    ref = lfm2_ref.token_nll(cfg, params, tokens)
+    np.testing.assert_allclose(
+        np.asarray(logits), np.asarray(lfm2_ref.logits(
+            cfg, params, tokens[:, :-1])), rtol=1e-5, atol=1e-5)
+    assert router["logits"].shape == (4, 64, 8)
+    np.testing.assert_allclose(np.asarray(router["logits"]),
+                               ref["router_logits"], rtol=1e-5, atol=1e-5)
+    chosen = np.asarray(router["chosen"])              # route's own
+    assert chosen.shape == (4, 64, cfg.top_k)
+    assert (np.sort(chosen, -1) == np.sort(ref["chosen"], -1)).all()
+    # the bias moved some choice away from the largest scores
+    plain = np.argsort(-ref["router_logits"], -1)[..., :cfg.top_k]
+    assert (np.sort(plain, -1) != np.sort(chosen, -1)).any()
+    want_counts = np.stack([np.bincount(c.ravel(), minlength=8)
+                            for c in ref["chosen"]])
+    assert (np.asarray(terms["expert_counts"]) == want_counts).all()
+    first, count = cfg.experts_held or (0, 8)
+    assert int(lfm2.rows_held(cfg, terms["expert_counts"])) == int(
+        want_counts[:, first:first + count].sum())
+    assert ref["terms"]["load_balance"] == 0.0
+    assert abs(float(loss) - ref["terms"]["loss"]) < 1e-5
+    assert float(loss) == float(terms["cross_entropy"])
+
+
+def test_lfm2_gradients_match_the_reference(lfm2_setup):
+    """Every trained leaf's gradient against the reference's; the bias
+    has none."""
+    lfm2, lfm2_ref, cfg, params, tokens = lfm2_setup
+    with jax.default_matmul_precision("highest"):
+        got = jax.jit(jax.grad(lambda t: lfm2.loss_fn(
+            cfg, lfm2.with_trainable(params, t), {"tokens": tokens})))(
+            lfm2.trainable(params))
+        whole = jax.jit(jax.grad(lambda p: lfm2.loss_fn(
+            cfg, p, {"tokens": tokens})))(params)
+    want = jax.jit(jax.grad(lambda t: lfm2_ref.loss(
+        cfg, lfm2.with_trainable(params, t), tokens)))(lfm2.trainable(params))
+    flat, _ = jax.tree_util.tree_flatten_with_path(got)
+    assert len(flat) == 2 + 8 + 12 + 9
+    for (path, g), w in zip(flat, jax.tree_util.tree_leaves(want)):
+        scale = float(jnp.abs(w).max())
+        assert scale > 1e-5, path                       # it is reached
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=1e-4,
+                                   atol=1e-5 * max(scale, 1e-2),
+                                   err_msg=str(path))
+    for kind in ("attn_moe", "conv_moe"):
+        assert float(jnp.abs(
+            whole["layers"][kind]["router_bias"]).max()) == 0.0
+
+
+def test_lfm2_reference_gradient_of_a_weighted_loss(lfm2_setup):
+    """What the chip check compares: ``token_nll(grad_weights=...)`` gives
+    the gradient of ``sum(weights * per-position loss)`` for the first
+    layer of each kind, the embedding and the last norm, on forced
+    choices; the program's own gradient of that scalar agrees."""
+    lfm2, lfm2_ref, cfg, params, tokens = lfm2_setup
+    weights = np.random.default_rng(2).uniform(
+        0.5, 1.5, (2, 32)).astype(np.float32) / 64
+
+    def weighted(t):
+        lg, router = lfm2.forward(cfg, lfm2.with_trainable(params, t),
+                                  tokens[:, :-1], keep_router_logits=True)
+        nll = jax.nn.logsumexp(lg, -1) - jnp.take_along_axis(
+            lg, jnp.asarray(tokens)[:, 1:, None], -1)[..., 0]
+        return (weights * nll).sum(), router["chosen"]
+
+    with jax.default_matmul_precision("highest"):
+        (_, chosen), got = jax.jit(jax.value_and_grad(
+            weighted, has_aux=True))(lfm2.trainable(params))
+    chosen = np.asarray(chosen)
+    ref = lfm2_ref.token_nll(cfg, params, tokens, forced_topk=chosen,
+                             grad_weights=weights)
+    got = lfm2_ref.first_layers(got)
+    assert set(ref["grads"]) == {"embed", "final_norm", "layers"}
+    for kind, leaves in ref["grads"]["layers"].items():
+        assert "router_bias" not in leaves
+        for name, w in leaves.items():
+            np.testing.assert_allclose(
+                np.asarray(got["layers"][kind][name]), np.asarray(w),
+                rtol=1e-4, atol=1e-6, err_msg=f"{kind}/{name}")
+    for name in ("embed", "final_norm"):
+        np.testing.assert_allclose(np.asarray(got[name]),
+                                   np.asarray(ref["grads"][name]),
+                                   rtol=1e-4, atol=1e-6)
+
+
+def test_per_head_qk_norm_is_not_the_whole_vector_norm():
+    """``attention_block`` tells LFM2's norm (a weight of a head's size:
+    over each head's dims) from OLMoE's (over the whole q and k vectors)
+    by the weight's shape; both against their equations."""
+    from ray_tpu.ops.layers import apply_rope, rope_frequencies
+    from ray_tpu.ops.attention import attention_reference
+
+    cfg = llama.LlamaConfig.tiny(attn_impl="reference")
+    h, hd, H, KV = cfg.hidden_size, cfg.head_dim_, cfg.num_heads, 2
+    ks = jax.random.split(jax.random.PRNGKey(0), 8)
+    p = {"attn_norm": jnp.ones((h,)), "wq": jax.random.normal(ks[0], (h, h)) / 8,
+         "wk": jax.random.normal(ks[1], (h, KV * hd)) / 8,
+         "wv": jax.random.normal(ks[2], (h, KV * hd)) / 8,
+         "wo": jax.random.normal(ks[3], (h, h)) / 8}
+    x = jax.random.normal(ks[4], (2, 16, h))
+    cos, sin = rope_frequencies(hd, 16, cfg.rope_theta)
+
+    def by_hand(q_w, k_w, per_head):
+        def norm(v, w):
+            return v / jnp.sqrt(jnp.mean(v * v, -1, keepdims=True)
+                                + cfg.rms_norm_eps) * w
+        u = norm(x, 1.0)
+        q, k, v = u @ p["wq"], u @ p["wk"], u @ p["wv"]
+        if not per_head:
+            q, k = norm(q, q_w), norm(k, k_w)
+        q, k, v = (a.reshape(2, 16, -1, hd) for a in (q, k, v))
+        if per_head:
+            q, k = norm(q, q_w), norm(k, k_w)
+        attn = attention_reference(apply_rope(q, cos, sin),
+                                   apply_rope(k, cos, sin), v)
+        return x + attn.reshape(2, 16, h) @ p["wo"]
+
+    outs = {}
+    with jax.default_matmul_precision("highest"):
+        for per_head in (True, False):
+            q_w = 1 + 0.3 * jax.random.normal(
+                ks[5], (hd if per_head else H * hd,))
+            k_w = 1 + 0.3 * jax.random.normal(
+                ks[6], (hd if per_head else KV * hd,))
+            got = llama.attention_block(
+                cfg, x, {**p, "q_norm": q_w, "k_norm": k_w}, cos, sin)
+            np.testing.assert_allclose(
+                np.asarray(got), np.asarray(by_hand(q_w, k_w, per_head)),
+                rtol=1e-5, atol=1e-5)
+            outs[per_head] = got
+        # with all weights 1 the two norms still differ
+        ones = {True: (jnp.ones((hd,)),) * 2,
+                False: (jnp.ones((H * hd,)), jnp.ones((KV * hd,)))}
+        a, b = (llama.attention_block(
+            cfg, x, {**p, "q_norm": ones[k][0], "k_norm": ones[k][1]},
+            cos, sin) for k in (True, False))
+    assert float(jnp.abs(a - b).max()) > 1e-3
+
+
+def test_update_router_bias_is_the_references_rule(lfm2_setup):
+    lfm2, lfm2_ref, cfg, params, tokens = lfm2_setup
+    counts = np.random.default_rng(3).integers(0, 40, (4, 8))
+    counts[2] = 16                                  # a balanced layer: no move
+    before = lfm2_ref.router_biases(cfg, params)
+    after = lfm2.update_router_bias(cfg, params, jnp.asarray(counts))
+    want = lfm2_ref.updated_bias(cfg, before, counts)
+    np.testing.assert_array_equal(lfm2_ref.router_biases(cfg, after), want)
+    assert (want[2] == before[2]).all() and (want[0] != before[0]).any()
+    # routed layers 0 is the attention layer's, 1..3 the conv layers'
+    np.testing.assert_array_equal(
+        np.asarray(after["layers"]["attn_moe"]["router_bias"][0]), want[0])
+    np.testing.assert_array_equal(
+        np.asarray(after["layers"]["conv_moe"]["router_bias"]), want[1:])
+    assert float(lfm2.router_bias_abs_max(after)) == float(
+        np.abs(want).max())
+    # nothing else moved
+    for (path, a), b in zip(
+            jax.tree_util.tree_flatten_with_path(lfm2.trainable(after))[0],
+            jax.tree_util.tree_leaves(lfm2.trainable(params))):
+        assert a is b, path
+
+
+def test_router_bias_balances_a_skewed_router():
+    """200 steps of the bias update alone on a router that sends most
+    rows to two experts: ``expert_load_max_over_mean`` falls."""
+    from ray_tpu.ops.moe import route
+
+    E, K, n = 8, 2, 512
+    x = jax.random.normal(jax.random.PRNGKey(0), (n, 16))
+    w = (jax.random.normal(jax.random.PRNGKey(1), (16, E)) * 0.2)
+    x = x.at[:, 0].set(3.0)
+    w = w.at[0, :2].add(0.5)             # experts 0 and 1 favoured
+    from ray_tpu.models import lfm2
+
+    cfg = lfm2.Lfm2Config.tiny(num_layers=2, num_dense_layers=1,
+                               attention_layers=(False, False),
+                               bias_update_rate=0.01)
+    params = {"layers": {"conv_moe": {"router_bias": jnp.zeros((1, E))}}}
+
+    @jax.jit
+    def step(params):
+        top_e = route(x, w, K, True, score="sigmoid",
+                      select_bias=params["layers"]["conv_moe"][
+                          "router_bias"][0], renorm_eps=1e-6)[2]
+        counts = (top_e.reshape(-1, 1) == jnp.arange(E)).sum(0)[None]
+        return lfm2.update_router_bias(cfg, params, counts), counts[0]
+
+    loads = []
+    for _ in range(200):
+        params, counts = step(params)
+        loads.append(float(counts.max() / counts.mean()))
+    assert loads[0] > 2.0
+    assert loads[-1] < 1.3
+    assert float(lfm2.router_bias_abs_max(params)) <= 200 * 0.01 + 1e-6
+
+
+def test_trainable_leaves_the_bias_out_of_adamws_state(lfm2_setup):
+    import optax
+
+    lfm2, _, cfg, params, tokens = lfm2_setup
+    owned = lfm2.trainable(params)
+    assert all("router_bias" not in leaves
+               for leaves in owned["layers"].values())
+    n_all = len(jax.tree_util.tree_leaves(params))
+    assert len(jax.tree_util.tree_leaves(owned)) == n_all - 2
+    tx = optax.adamw(1e-3)
+    opt = tx.init(owned)
+    assert len(jax.tree_util.tree_leaves(opt[0].mu)) == n_all - 2
+    grads = jax.grad(lambda t: lfm2.loss_fn(
+        cfg, lfm2.with_trainable(params, t), {"tokens": tokens}))(owned)
+    updates, _ = tx.update(grads, opt, owned)
+    stepped = lfm2.with_trainable(params, optax.apply_updates(owned, updates))
+    assert jax.tree_util.tree_structure(stepped) == \
+        jax.tree_util.tree_structure(params)
+    for kind in ("attn_moe", "conv_moe"):       # adamw's decay never saw b
+        assert stepped["layers"][kind]["router_bias"] is \
+            params["layers"][kind]["router_bias"]
+    assert float(jnp.abs(stepped["embed"] - params["embed"]).max()) > 0
+
+
+def test_lfm2_8b_a1b_preset_counts_what_the_model_card_says():
+    from ray_tpu.models import lfm2
+
+    cfg = lfm2.Lfm2Config.lfm2_8b_a1b()
+    assert cfg.pattern.count("conv_moe") == 16
+    assert cfg.pattern.count("attn_moe") == 6
+    assert cfg.pattern[:2] == ("conv_dense", "conv_dense")
+    assert cfg.head_dim_ == 64
+    shapes = jax.eval_shape(lambda k: lfm2.init_params(cfg, k),
+                            jax.random.PRNGKey(0))
+    n = sum(a.size for a in jax.tree_util.tree_leaves(shapes))
+    assert abs(n / 8.34e9 - 1) < 0.001
+    # the cell's cut: layer 0 and the first period, 16 of 32, half the rows
+    cut = lfm2.Lfm2Config.lfm2_8b_a1b(
+        num_layers=5, vocab_size=32768, num_dense_layers=1,
+        attention_layers=(False, True, False, False, False),
+        experts_held=(0, 16))
+    shapes = jax.eval_shape(lambda k: lfm2.init_params(cut, k),
+                            jax.random.PRNGKey(0))
+    assert abs(sum(a.size for a in jax.tree_util.tree_leaves(shapes))
+               / 893.7e6 - 1) < 0.001
+    with pytest.raises(ValueError, match="attention_layers names"):
+        lfm2.Lfm2Config.lfm2_8b_a1b(num_layers=5)
+
+
+@pytest.mark.parametrize("how, says", [
+    ({"tie_embeddings": False}, "the head is the embedding"),
+    ({"attention_layers": (True, True, False, False, False)},
+     "an attention layer with a dense MLP")])
+def test_lfm2_refuses_what_it_has_no_parameters_for(how, says):
+    from ray_tpu.models import lfm2
+
+    with pytest.raises(ValueError, match=says):
+        lfm2.Lfm2Config.tiny(**how)
+
+
+def test_the_cells_check_sees_a_route_that_leaves_the_bias_out(
+        lfm2_setup, monkeypatch):
+    """(f) of ``benchmark/cells/train_hybrid.py``: ``route``'s own choices
+    held to the selection scores recomputed from the program's logits and
+    the biases. With the bias dropped inside ``ops/moe.route`` the reading
+    is of the biases' size (0.1 here); the honest program reads a
+    rounding."""
+    from benchmark.cells import train_hybrid
+    from ray_tpu.ops import moe
+
+    lfm2, lfm2_ref, cfg, params, tokens = lfm2_setup
+    tokens = jnp.asarray(tokens, jnp.int32)
+
+    def reading():
+        train_hybrid._program.cache_clear()
+        return train_hybrid.choices_under_bias(lfm2, lfm2_ref, cfg, params,
+                                               tokens)
+
+    assert reading() < 1e-6
+    honest = moe.route
+    monkeypatch.setattr(
+        moe, "route", lambda *a, select_bias=None, **kw: honest(*a, **kw))
+    assert reading() > 0.01
+    monkeypatch.undo()
+    train_hybrid._program.cache_clear()
+
+
 def test_layer_patterns_are_walked_by_runs_of_one_kind():
     """``run_layers`` walks a pattern of kinds: a run of one kind is one
     scan, a layer alone between others is walked; Laguna-S-2.1's 48

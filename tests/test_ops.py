@@ -650,6 +650,47 @@ def test_sixteen_shares_add_up_to_the_uncut_layer():
                                rtol=1e-5, atol=1e-5)
 
 
+def test_two_shares_add_up_to_the_uncut_sigmoid_layer():
+    """LFM2's cut, tied to the model: the parts that the two shares
+    ``held=(0, 16)`` and ``held=(16, 16)`` of one routed layer give (a
+    sigmoid router with a bias over all 32 experts, no shared expert) add
+    up to the uncut reference layer, which holds all 32."""
+    import os
+    import sys
+
+    sys.path.insert(0, os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))          # benchmark/ lies beside tests/
+    from benchmark.references import lfm2_ref
+    from ray_tpu.models import lfm2
+    from ray_tpu.ops.moe import routed_experts
+
+    cfg = lfm2.Lfm2Config.tiny(num_experts=32, top_k=4)
+    n, h, f, E = 64, cfg.hidden_size, cfg.moe_intermediate_size, 32
+    ks = jax.random.split(jax.random.PRNGKey(5), 6)
+    p = {"router": jax.random.normal(ks[0], (h, E)) * 0.3,
+         "router_bias": jax.random.normal(ks[1], (E,)) * 0.1,
+         "e_gate": jax.random.normal(ks[2], (E, h, f)) / 8,
+         "e_up": jax.random.normal(ks[3], (E, h, f)) / 8,
+         "e_down": jax.random.normal(ks[4], (E, f, h)) / 6}
+    u = jax.random.normal(ks[5], (n, h))
+    with jax.default_matmul_precision("highest"):
+        total, held_rows = jnp.zeros_like(u), 0
+        for first in (0, 16):
+            out, _, counts = routed_experts(
+                u, p["router"], *(p[k][first:first + 16]
+                                  for k in ("e_gate", "e_up", "e_down")),
+                cfg.top_k, renormalize=True, held=(first, 16),
+                score="sigmoid", select_bias=p["router_bias"],
+                renorm_eps=cfg.renorm_eps)
+            total = total + out
+            held_rows += int(counts[first:first + 16].sum())
+        want = lfm2_ref.routed_layer(cfg, p, u)
+    assert held_rows == int(counts.sum()) == 64 * 4
+    assert float(jnp.abs(want).max()) > 0.1
+    np.testing.assert_allclose(np.asarray(total), np.asarray(want),
+                               rtol=1e-5, atol=1e-5)
+
+
 def test_held_experts_drop_no_row_and_compile_nothing_whatever_the_routing():
     """A router that sends every row to the held experts (four passes of
     the loop where a balanced one takes one) and one that sends none:
@@ -741,3 +782,252 @@ def test_routed_experts_tpu_path_in_interpret_mode(monkeypatch, held):
                         jax.tree_util.tree_leaves(want)):
             np.testing.assert_allclose(np.asarray(g), np.asarray(w),
                                        rtol=1e-4, atol=1e-4)
+
+
+# ------------------------------------------- gated short convolution (LFM2)
+
+
+def _conv_by_loop(h, w_in, w_conv, w_out):
+    """The operator as its equations read, one sequence and one position
+    at a time: ``v_t = sum_j w_j u_{t - (L - 1) + j}``, zeros before 0."""
+    h, w_in, w_conv, w_out = (np.asarray(a, np.float64)
+                              for a in (h, w_in, w_conv, w_out))
+    taps = w_conv.shape[1]
+    out = np.zeros(h.shape[:2] + (w_out.shape[1],))
+    for n in range(h.shape[0]):
+        b, c, x = np.split(h[n] @ w_in, 3, axis=-1)
+        u = b * x
+        for t in range(h.shape[1]):
+            v = sum(w_conv[:, j] * u[t - (taps - 1) + j]
+                    for j in range(taps) if t - (taps - 1) + j >= 0)
+            out[n, t] = (c[t] * v) @ w_out
+    return out
+
+
+def _conv_inputs(seq, batch=2, hidden=8):
+    ks = jax.random.split(jax.random.PRNGKey(seq), 4)
+    return (jax.random.normal(ks[0], (batch, seq, hidden)),
+            jax.random.normal(ks[1], (hidden, 3 * hidden)) / 3,
+            jax.random.normal(ks[2], (hidden, 3)),
+            jax.random.normal(ks[3], (hidden, hidden)) / 3)
+
+
+@pytest.mark.parametrize("seq", [1, 2, 3, 64])
+def test_gated_short_conv_matches_a_loop_over_taps(seq):
+    """Outputs and all three weight gradients (and the input's) against
+    the loop, float32 at 1e-5, at lengths shorter than the taps too."""
+    from ray_tpu.ops.conv import gated_short_conv
+
+    args = _conv_inputs(seq)
+    with jax.default_matmul_precision("highest"):
+        got = gated_short_conv(*args)
+        np.testing.assert_allclose(np.asarray(got), _conv_by_loop(*args),
+                                   rtol=1e-5, atol=1e-5)
+        cot = jax.random.normal(jax.random.PRNGKey(9), got.shape)
+        grads = jax.grad(lambda *a: (gated_short_conv(*a) * cot).sum(),
+                         argnums=(0, 1, 2, 3))(*args)
+    # the loop's gradient by central differences in float64, a few entries
+    # of each argument
+    rng = np.random.default_rng(seq)
+    for which, g in enumerate(grads):
+        base = [np.asarray(a, np.float64) for a in args]
+        for _ in range(4):
+            at = tuple(rng.integers(0, n) for n in base[which].shape)
+            up, down = (list(base), list(base))
+            for side, sign in ((up, 1e-4), (down, -1e-4)):
+                side[which] = base[which].copy()
+                side[which][at] += sign
+            want = ((_conv_by_loop(*up) - _conv_by_loop(*down))
+                    * np.asarray(cot, np.float64)).sum() / 2e-4
+            assert abs(float(g[at]) - want) < 1e-5 * max(1.0, abs(want)), (
+                which, at)
+
+
+def test_gated_short_conv_keeps_the_sequences_of_a_batch_apart():
+    """Two sequences in a batch: the second's first positions see zeros,
+    not the first's last, in the output and in the gradient."""
+    from ray_tpu.ops.conv import gated_short_conv
+
+    h, *w = _conv_inputs(5)
+    both = gated_short_conv(h, *w)
+    for n in range(2):
+        alone = gated_short_conv(h[n:n + 1], *w)
+        np.testing.assert_array_equal(np.asarray(both[n]),
+                                      np.asarray(alone[0]))
+    # the second sequence's output does not depend on the first's input
+    g = jax.grad(lambda h_: gated_short_conv(h_, *w)[1].sum())(h)
+    assert float(jnp.abs(g[0]).max()) == 0.0 < float(jnp.abs(g[1]).max())
+
+
+def test_gated_short_conv_is_float32_inside_and_bf16_outside():
+    from ray_tpu.ops.conv import conv_mix
+
+    bcx = jax.random.normal(jax.random.PRNGKey(0), (1, 16, 24)
+                            ).astype(jnp.bfloat16)
+    w = jax.random.normal(jax.random.PRNGKey(1), (8, 3)).astype(jnp.bfloat16)
+    got = conv_mix(bcx, w)
+    assert got.dtype == jnp.bfloat16
+    want = conv_mix(bcx.astype(jnp.float32), w.astype(jnp.float32))
+    # rounded once, at the end
+    np.testing.assert_array_equal(np.asarray(got.astype(jnp.float32)),
+                                  np.asarray(want.astype(jnp.bfloat16)
+                                             .astype(jnp.float32)))
+
+
+def test_short_conv_names_its_scopes_forward_and_backward():
+    """``short_conv`` and the three scopes inside it, which
+    ``benchmark/lib/hybrid_flops.py`` reads, on the operations of the
+    forward and of its transpose."""
+    from ray_tpu.ops.conv import gated_short_conv
+
+    args = _conv_inputs(8)
+    text = jax.jit(jax.grad(lambda *a: (gated_short_conv(*a) ** 2).sum(),
+                            argnums=(0, 1, 2, 3))).lower(*args).as_text(
+        debug_info=True)
+    for scope in ("conv_in", "conv_mix", "conv_out"):
+        assert f"jvp(short_conv)/{scope}" in text, scope
+        assert f"transpose(jvp(short_conv))/{scope}" in text, scope
+
+
+# ----------------------------------- a sigmoid router with a selection bias
+
+
+@pytest.mark.parametrize("with_bias", [False, True])
+def test_route_sigmoid_selects_on_scores_plus_bias_and_weighs_by_scores(
+        with_bias):
+    """Selection on ``s + b``, weights from ``s`` alone over their sum
+    plus 1e-6, times the scale; no gradient into ``b``."""
+    from ray_tpu.ops.moe import route
+
+    n, h, E, K = 64, 16, 8, 3
+    ks = jax.random.split(jax.random.PRNGKey(3), 3)
+    x = jax.random.normal(ks[0], (n, h))
+    w = jax.random.normal(ks[1], (h, E))
+    b = (jax.random.normal(ks[2], (E,)) if with_bias
+         else jnp.zeros((E,)))
+    logits, top_w, top_e = route(x, w, K, renormalize=True, scale=1.5,
+                                 score="sigmoid", select_bias=b,
+                                 renorm_eps=1e-6)
+    s = np.asarray(jax.nn.sigmoid(x @ w), np.float64)
+    want_e = np.argsort(-(s + np.asarray(b, np.float64)), axis=-1)[:, :K]
+    assert (np.sort(np.asarray(top_e), -1) == np.sort(want_e, -1)).all()
+    chosen = np.take_along_axis(s, np.asarray(top_e), -1)
+    np.testing.assert_allclose(
+        np.asarray(top_w), 1.5 * chosen / (chosen.sum(-1, keepdims=True)
+                                           + 1e-6), rtol=1e-5, atol=1e-6)
+    np.testing.assert_allclose(np.asarray(logits), np.asarray(x @ w),
+                               rtol=1e-5, atol=1e-5)
+    if with_bias:     # the bias moved some choice, and gets no gradient
+        assert (np.sort(np.argsort(-s, -1)[:, :K], -1)
+                != np.sort(want_e, -1)).any()
+    g_b, g_w = jax.grad(
+        lambda b_, w_: (route(x, w_, K, True, 1.5, "sigmoid", b_, 1e-6)[1]
+                        * jnp.arange(K)).sum(), argnums=(0, 1))(b, w)
+    assert float(jnp.abs(g_b).max()) == 0.0 < float(jnp.abs(g_w).max())
+
+
+def test_route_renorm_eps_is_in_the_denominator():
+    from ray_tpu.ops.moe import route
+
+    x = jnp.ones((1, 2))
+    w = jnp.full((2, 4), -20.0)          # sigmoid scores of 4e-18
+    tiny = route(x, w, 2, True, score="sigmoid", renorm_eps=1e-6)[1]
+    assert float(tiny.sum()) < 1e-6      # s / (2 s + 1e-6), not 1/2 each
+    plain = route(x, w, 2, True, score="sigmoid")[1]
+    np.testing.assert_allclose(np.asarray(plain), 0.5, rtol=1e-6)
+
+
+@pytest.mark.parametrize("renormalize,scale", [(False, 1.0), (True, 1.0),
+                                               (True, 2.5)])
+def test_route_softmax_callers_trace_what_they_did(renormalize, scale):
+    """The three old callers' arguments give the jaxpr they gave before
+    ``score``, ``select_bias`` and ``renorm_eps``: bit-equal results and
+    the same equations."""
+    from ray_tpu.ops.moe import route
+
+    def before(x, router_w, top_k, renormalize=False, scale=1.0):
+        logits = jnp.dot(x, router_w.astype(x.dtype),
+                         preferred_element_type=jnp.float32)
+        probs = jax.nn.softmax(logits, axis=-1)
+        top_w, top_e = jax.lax.top_k(probs, top_k)
+        if renormalize:
+            top_w = top_w / top_w.sum(-1, keepdims=True)
+        if scale != 1.0:
+            top_w = top_w * scale
+        return logits, top_w, top_e
+
+    x = jax.random.normal(jax.random.PRNGKey(0), (32, 16))
+    w = jax.random.normal(jax.random.PRNGKey(1), (16, 8))
+    for got, want in zip(route(x, w, 3, renormalize, scale),
+                         before(x, w, 3, renormalize, scale)):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    assert str(jax.make_jaxpr(lambda a, b: route(a, b, 3, renormalize,
+                                                 scale))(x, w)) == \
+        str(jax.make_jaxpr(lambda a, b: before(a, b, 3, renormalize,
+                                               scale))(x, w))
+    with pytest.raises(ValueError, match="softmax | sigmoid"):
+        route(x, w, 3, score="tanh")
+
+
+@pytest.mark.parametrize("held", [None, (4, 4)], ids=["all", "held-4..7"])
+def test_routed_experts_sigmoid_with_bias_match_the_expert_loop(held):
+    """``routed_experts(score="sigmoid", select_bias=...)``, all experts
+    and a share, against every expert over every token."""
+    n, h, f, E, K = 96, 32, 48, 8, 2
+    ks = jax.random.split(jax.random.PRNGKey(4), 6)
+    x = jax.random.normal(ks[0], (n, h))
+    router_w = jax.random.normal(ks[1], (h, E)) / 4
+    bias = jax.random.normal(ks[5], (E,)) / 4
+    e_gate, e_up, e_down = (jax.random.normal(ks[2], (E, h, f)) / 6,
+                            jax.random.normal(ks[3], (E, h, f)) / 6,
+                            jax.random.normal(ks[4], (E, f, h)) / 7)
+    with jax.default_matmul_precision("highest"):
+        got, _, counts = _held_share(
+            held, x, router_w, e_gate, e_up, e_down, K, renormalize=True,
+            score="sigmoid", select_bias=bias, renorm_eps=1e-6)
+        s = jax.nn.sigmoid(x @ router_w)
+        top_e = jax.lax.top_k(s + bias, K)[1]
+        top_w = jnp.take_along_axis(s, top_e, -1)
+        top_w = top_w / (top_w.sum(-1, keepdims=True) + 1e-6)
+        want = jnp.zeros_like(x)
+        first, count = held or (0, E)
+        for e in range(first, first + count):
+            gate = jnp.where(top_e == e, top_w, 0.0).sum(-1)
+            want = want + gate[:, None] * swiglu(x, e_gate[e], e_up[e],
+                                                 e_down[e])
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-5, atol=1e-5)
+    assert (np.asarray(counts) == np.bincount(
+        np.asarray(top_e).ravel(), minlength=E)).all()
+
+
+# ------------------------------------------------- flash at a head of 64
+
+
+@pytest.mark.parametrize("heads,kv_heads", [(4, 1), (4, 4)],
+                         ids=["gqa-4", "mha"])
+def test_flash_attention_head_64_forward_and_gradients(heads, kv_heads):
+    """LFM2's head size, half a lane tile: forward and the three
+    gradients of the causal kernels (interpret mode) at both ratios of
+    query to kv heads against the reference."""
+    b, s, d = 1, 128, 64
+    q = jax.random.normal(jax.random.PRNGKey(0), (b, s, heads, d))
+    k = jax.random.normal(jax.random.PRNGKey(1), (b, s, kv_heads, d))
+    v = jax.random.normal(jax.random.PRNGKey(2), (b, s, kv_heads, d))
+    cot = jax.random.normal(jax.random.PRNGKey(3), (b, s, heads, d))
+
+    def loss(fn):
+        return lambda *a: (fn(*a) * cot).sum()
+
+    flash = lambda *a: flash_attention(        # noqa: E731
+        *a, causal=True, use_pallas=True, interpret=True, block_q=64,
+        block_k=64)
+    np.testing.assert_allclose(
+        np.asarray(flash(q, k, v)),
+        np.asarray(attention_reference(q, k, v, causal=True)), atol=2e-5)
+    gf = jax.grad(loss(flash), argnums=(0, 1, 2))(q, k, v)
+    gr = jax.grad(loss(lambda *a: attention_reference(*a, causal=True)),
+                  argnums=(0, 1, 2))(q, k, v)
+    for a, b_ in zip(gf, gr):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b_),
+                                   rtol=2e-4, atol=1e-4)

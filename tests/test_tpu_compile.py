@@ -237,6 +237,100 @@ def test_laguna_cell_step_compiles_within_a_v5e_chip(one_chip,
                      "jvp_jit_tgmm__"}
 
 
+@pytest.mark.parametrize("kv_heads", [8, 32], ids=["gqa-32-on-8", "mha"])
+def test_flash_kernels_compile_at_a_head_of_64(S, no_compile_cache,
+                                               kv_heads):
+    """LFM2's heads are 64 wide, half a lane tile: Mosaic takes the causal
+    forward and both backward kernels at 2 x 8,192 positions as they are
+    (no padding inside the call), at the model's 32 query heads on 8 and
+    with as many kv heads as query heads."""
+    from ray_tpu.ops.attention import flash_attention
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v, use_pallas=True).astype(
+            jnp.float32).sum()
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        S(2, 8192, 32, 64), S(2, 8192, kv_heads, 64),
+        S(2, 8192, kv_heads, 64)).compile().as_text()
+    # (outside a model jax names a call after the transformations it
+    # traced it under: ``transpose_jvp_flash_bwd_dq__``)
+    names = {name for name, _ in _mosaic_calls(text)}
+    assert len(names) == 3
+    for kernel in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"):
+        assert any(kernel in name for name in names), (kernel, names)
+
+
+def test_conv_mix_pass_compiles_to_fusions_without_a_kernel(
+        S, no_compile_cache):
+    """The pass between a convolution's two projections at LFM2's width
+    and the cell's tokens, forward and backward: plain XLA fusions (no
+    Mosaic call, no convolution instruction) within a gigabyte of
+    temporaries."""
+    from ray_tpu.ops.conv import conv_mix
+
+    def loss(bcx, w):
+        return conv_mix(bcx, w).astype(jnp.float32).sum()
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        S(2, 8192, 6144), S(2048, 3)).compile()
+    text = compiled.as_text()
+    assert not _mosaic_calls(text) and " convolution(" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 30
+
+
+def test_lfm2_cell_step_compiles_within_a_v5e_chip(one_chip,
+                                                   no_compile_cache,
+                                                   monkeypatch):
+    """The step of the benchmark's ``train-lfm2-1chip`` at its published
+    widths (2 x 8,192 tokens, five layers of three kinds, 16 of 32 experts
+    held, bf16 state and float32 biases: ``benchmark/configs/
+    lfm2-8b-a1b-c1.json``), built by the cell's own ``make_step``: adamw
+    on ``trainable(params)``, then the bias update. Mosaic takes the flash
+    kernels at a head of 64, the held rows' passes compile, the biases are
+    no part of adamw's state and the program fits 15.75 GiB."""
+    import json
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    sys.path.insert(0, root)                  # benchmark/ lies beside tests/
+    from benchmark.cells import train_hybrid
+    from ray_tpu.models import lfm2
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with open(os.path.join(root, "benchmark", "configs",
+                           "lfm2-8b-a1b-c1.json")) as f:
+        model, _, cfg = train_hybrid.load_model(json.load(f)["model_config"])
+    assert model is lfm2
+    assert cfg.pattern == ("conv_dense", "attn_moe", "conv_moe", "conv_moe",
+                           "conv_moe")
+    with open(os.path.join(root, "benchmark", "traffic",
+                           "train-lfm2-1chip.json")) as f:
+        tx = train_hybrid.optimizer(json.load(f))
+    params = jax.eval_shape(lambda k: lfm2.init_params(cfg, k),
+                            jax.random.PRNGKey(0))
+    assert sum(a.size for a in jax.tree_util.tree_leaves(params)) \
+        == 893_696_256
+    opt = jax.eval_shape(tx.init, lfm2.trainable(params))
+    assert sum(a.size for a in jax.tree_util.tree_leaves(opt)) \
+        == 2 * (893_696_256 - 4 * 32) + 2          # two moments, two counts
+    batch = {"tokens": jax.ShapeDtypeStruct((2, 8193), jnp.int32,
+                                            sharding=one_chip)}
+    compiled = jax.jit(train_hybrid.make_step(lfm2, cfg, tx),
+                       donate_argnums=(0, 1)).lower(
+        _placed(params, one_chip), _placed(opt, one_chip), batch).compile()
+    ma = compiled.memory_analysis()
+    assert ma.argument_size_in_bytes + ma.temp_size_in_bytes \
+        + ma.output_size_in_bytes - ma.alias_size_in_bytes \
+        <= int(15.75 * 2 ** 30)
+    text = compiled.as_text()
+    assert {name for name, _ in _mosaic_calls(text)} == {
+        "flash_fwd", "flash_bwd_dq", "flash_bwd_dkv", "gmm",
+        "jvp_jit_gmm__", "jvp_jit_tgmm__"}
+    assert re.search(r'op_name="[^"]*/moe_route/moe_bias_update/', text)
+    assert re.search(r'op_name="[^"]*short_conv[^"]*/conv_mix/', text)
+
+
 @pytest.mark.parametrize("model", ["llama", "olmoe"])
 def test_one_kind_of_layer_compiles_the_scan_it_always_did(
         model, one_chip, no_compile_cache, monkeypatch):

@@ -309,9 +309,10 @@ def test_metrics_registry_serves_the_owners_counters():
 
 def test_train_session_serves_the_last_reported_moe_counters():
     """``rtpu_train_moe_*``: the last ``moe_rows_routed``,
-    ``moe_rows_held`` (where a layer holds a share of its experts) and
-    ``moe_expert_load_max_over_mean`` a loop put into ``train.report``;
-    a loop that reports neither serves neither."""
+    ``moe_rows_held`` (where a layer holds a share of its experts),
+    ``moe_expert_load_max_over_mean`` and ``moe_router_bias_abs_max`` (a
+    router balanced by a bias) a loop put into ``train.report``; a loop
+    that reports neither serves neither."""
     from ray_tpu import metrics
     from ray_tpu.train.session import TrainContext, _TrainSession
 
@@ -323,6 +324,7 @@ def test_train_session_serves_the_last_reported_moe_counters():
         train.report({"loss": 0.8, "moe_rows_routed": 196608,
                       "moe_rows_held": 12288,
                       "moe_expert_load_max_over_mean": 4.25,
+                      "moe_router_bias_abs_max": 0.057,
                       "moe_other": 1})
 
     s = _TrainSession(loop, {}, TrainContext())
@@ -342,6 +344,7 @@ def test_train_session_serves_the_last_reported_moe_counters():
     assert "rtpu_train_moe_rows_routed 196608\n" in text
     assert "rtpu_train_moe_rows_held 12288\n" in text
     assert "rtpu_train_moe_expert_load_max_over_mean 4.25\n" in text
+    assert "rtpu_train_moe_router_bias_abs_max 0.057\n" in text
     assert "rtpu_train_moe_other" not in text and "rtpu_train_loss" not in text
     assert "rtpu_train_reports 3\n" in text
 
@@ -405,6 +408,73 @@ def test_named_scopes_change_no_instruction_of_the_train_step():
     assert _strip(with_scopes) == _strip(without)
     assert _instructions(_strip(with_scopes)) > 200   # instructions are left
     assert with_scopes != without
+
+
+def test_lfm2_train_step_names_its_scopes_and_the_bias_update():
+    """The scopes ``benchmark/lib/hybrid_flops.py`` reads, in the compiled
+    step of a model with convolution layers and a bias-balanced router:
+    ``short_conv`` over ``conv_in`` / ``conv_mix`` / ``conv_out`` forward
+    and backward, ``moe_bias_update`` inside ``moe_route`` after the
+    optimizer, and the family's own; and they change no instruction."""
+    import jax
+    import jax.numpy as jnp
+    import optax
+
+    from ray_tpu.models import lfm2
+
+    cfg = lfm2.Lfm2Config.tiny(vocab_size=128, attn_impl="reference",
+                               experts_held=(0, 4))
+    params = jax.eval_shape(lambda k: lfm2.init_params(cfg, k),
+                            jax.random.PRNGKey(0))
+    tx = optax.adamw(1e-3)
+    opt = jax.eval_shape(tx.init, lfm2.trainable(params))
+    batch = {"tokens": jax.ShapeDtypeStruct((2, 33), jnp.int32)}
+
+    def text(scoped):
+        # a step of its own: jit would hand the second trace the first's
+        def step(params, opt, batch):
+            owned = lfm2.trainable(params)
+            (loss, aux), grads = jax.value_and_grad(
+                lambda t: lfm2.loss_terms(cfg, lfm2.with_trainable(params, t),
+                                          batch), has_aux=True)(owned)
+            updates, opt = tx.update(grads, opt, owned)
+            params = lfm2.with_trainable(params,
+                                         optax.apply_updates(owned, updates))
+            params = lfm2.update_router_bias(cfg, params, aux["expert_counts"])
+            return params, opt, loss, lfm2.router_bias_abs_max(params)
+
+        plain = contextlib.nullcontext()
+        saved = jax.named_scope
+        if not scoped:
+            jax.named_scope = lambda name: plain
+        # as _train_step_text: the cache's key must hold the metadata
+        key = "jax_compilation_cache_include_metadata_in_key"
+        saved_key = getattr(jax.config, key)
+        jax.config.update(key, True)
+        try:
+            return jax.jit(step, donate_argnums=(0, 1)).lower(
+                params, opt, batch).compile().as_text()
+        finally:
+            jax.named_scope = saved
+            jax.config.update(key, saved_key)
+
+    with_scopes, without = text(True), text(False)
+    for scope in ("embed", "attn_qkv", "flash", "attn_out", "mlp",
+                  "head_loss", "moe_route", "moe_dispatch", "moe_experts",
+                  "moe_combine"):
+        assert re.search(rf'op_name="[^"]*[/(]{scope}[/)]', with_scopes), scope
+    for inner in ("conv_in", "conv_mix", "conv_out"):
+        assert re.search(rf'op_name="[^"]*jvp\(short_conv\)/{inner}/',
+                         with_scopes), inner
+        assert re.search(
+            rf'op_name="[^"]*transpose\(jvp\(short_conv\)\)/{inner}/',
+            with_scopes), inner
+    assert re.search(r'op_name="[^"]*/moe_route/moe_bias_update/',
+                     with_scopes)
+    assert not re.search(r'op_name="[^"]*(short_conv|moe_bias_update)',
+                         without)
+    assert _strip(with_scopes) == _strip(without)
+    assert _instructions(_strip(with_scopes)) > 200
 
 
 @pytest.mark.parametrize("model", ["llama", "olmoe", "mixtral"])
@@ -530,7 +600,8 @@ def test_flash_tiles_is_one_kept_span_of_a_traced_call():
     assert trace(1024, 1024, use_pallas=False) == []
     (causal,) = trace(4096, 4096, use_pallas=True)
     assert causal == {"seq_q": 4096, "seq_k": 4096, "block_q": 512,
-                      "block_k": 512, "window": None, "tiles_visited": 36,
+                      "block_k": 512, "window": None, "head_dim": 128,
+                      "tiles_visited": 36,
                       "tiles_edge": 8,
                       "kept_share": tile_plan(4096, 4096, 512, 512)[
                           "kept_share"]}
@@ -540,6 +611,13 @@ def test_flash_tiles_is_one_kept_span_of_a_traced_call():
         512, 512, 512)
     assert (band["tiles_visited"], band["tiles_edge"]) == (31, 31)
     assert 0.50 < band["kept_share"] < 0.51
+    # the span carries the head size: LFM2's 64 beside the others' 128
+    q64 = jax.ShapeDtypeStruct((1, 1024, 4, 64), jnp.bfloat16)
+    n0 = len(_mine("rtpu.flash.tiles"))
+    jax.eval_shape(lambda q: flash_attention(q, q, q, interpret=True,
+                                             use_pallas=True), q64)
+    (small,) = _mine("rtpu.flash.tiles")[n0:]
+    assert small["args"]["head_dim"] == 64
     # explicit blocks stay the caller's; keys ahead of the queries
     (chunk,) = trace(512, 2048, use_pallas=True, block_q=256, block_k=512)
     assert (chunk["block_q"], chunk["block_k"]) == (256, 512)
