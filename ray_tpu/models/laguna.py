@@ -239,10 +239,12 @@ def forward(cfg: LagunaConfig, params, tokens: jax.Array, mesh=None,
         return lambda x_, p_: _layer(cfg, kind, x_, p_, cos, sin, mesh=mesh,
                                      keep_router_logits=keep_router_logits)
 
+    level = llama.resolve_remat(
+        cfg, params, tokens, mesh, param_shardings, pattern=pattern,
+        top_k=cfg.top_k, held=cfg.experts_held) if cfg.remat else None
     x, ys = llama.run_layers(
         {kind: layer_of(kind) for kind in params["layers"]}, x,
-        params["layers"], level=llama.remat_level_without_plan(cfg),
-        scan=cfg.scan_layers, pattern=pattern)
+        params["layers"], level=level, scan=cfg.scan_layers, pattern=pattern)
     # the routed layers' stats, from stacks by kind into layer order
     taken, rows = dict.fromkeys(ys, 0), []
     for kind in pattern:

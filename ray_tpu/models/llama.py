@@ -12,9 +12,10 @@ torch; this is the flagship model the north-star configs name):
 - bf16 params/activations with fp32 accumulations (preferred_element_type)
   — MXU-native.
 - rematerialization: ``run_layers`` puts one ``jax.checkpoint`` around
-  each layer, keeping what its level names (``REMAT_LADDER``). The dense
-  forward's level comes from bytes (``remat_plan``: the richest rung that
-  fits the device's memory); a forward without a plan runs "full".
+  each layer, keeping what its level names (``REMAT_LADDER``). The level
+  comes from bytes (``remat_plan``: the richest rungs that fit the
+  device's memory, by kind of layer in a stack of kinds); a forward
+  without a plan (Mixtral's, the pipeline stage) runs "full".
 - attention backend switch: "flash" (Pallas), "reference" (XLA), "ring"
   (sequence-parallel over the sp axis, KV blocks rotating on the ICI
   ring), "ulysses" (sequence-parallel via all-to-all head re-sharding).
@@ -39,13 +40,14 @@ from ray_tpu.util import tracing
 # What a layer's jax.checkpoint keeps besides the layer's input, rung by
 # rung in order of step time saved per byte kept (PERF.md 6, PR 27):
 # checkpoint names given where the values are born (attention_block,
-# ops/attention._flash_fwd, ops/layers.swiglu). "level<n>" keeps the names
-# of the first n rungs. The norms and act(gate) * up are recomputed at
-# every level: elementwise and cheap, and as large again as all four rungs.
+# ops/attention._flash_fwd, ops/layers.swiglu, ops/moe._swiglu_rows).
+# "level<n>" keeps the names of the first n rungs. The norms and
+# act(gate) * up are recomputed at every level: elementwise and cheap, and
+# as large again as all four rungs.
 REMAT_LADDER = (
     ("flash_out", "flash_lse"),         # the backward's second flash_fwd
     ("q_rope", "k_rope", "v_proj"),     # the q/k/v matmuls and rope
-    ("mlp_gate", "mlp_up"),             # the gate and up matmuls
+    ("mlp_gate", "mlp_up"),             # the gate and up matmuls, grouped too
     ("attn_resid",),                    # the wo matmul
 )
 REMAT_POLICIES = ("auto", "full") + tuple(
@@ -95,9 +97,10 @@ class LlamaConfig:
     # layer's input alone, the whole forward runs again. "level1" ..
     # "level4": the names of REMAT_LADDER's first n rungs besides.
     # "auto" (the default): the richest of those that remat_plan reckons
-    # to fit the device's memory, "full" where the device reports none
-    # (the CPU) and in a forward that has no plan (Mixtral, OLMoE, the
-    # pipeline schedule: remat_level_without_plan).
+    # to fit the device's memory, one for each kind of layer of a stack of
+    # kinds (OLMoE, Laguna and LFM2 share the plan), "full" where the
+    # device reports none (the CPU) and in a forward that has no plan
+    # (Mixtral, the pipeline schedule: remat_level_without_plan).
     remat_policy: str = "auto"
     # False = python-unrolled layer loop instead of lax.scan, in every
     # forward of the family (run_layers honours it). The scan
@@ -222,65 +225,176 @@ def remat_names(policy: str) -> Tuple[str, ...]:
     return tuple(n for rung in REMAT_LADDER[:level] for n in rung)
 
 
-def remat_plan(cfg: LlamaConfig, tokens_per_device: int,
-               param_bytes_per_device: int, capacity_bytes: Optional[int],
-               params_sharded: bool) -> Dict[str, Any]:
-    """Which rung of REMAT_LADDER a train step of ``cfg`` gets: a pure
-    function of shapes and bytes, so the same inputs always give the same
-    program. ``remat_policy="auto"`` resolves to the richest level whose
-    reckoned need fits ``capacity_bytes * (1 - REMAT_RESERVE)``, and to
-    "full" where nothing richer fits or there is no capacity to read; any
-    other policy is returned as set, with its need reckoned beside it.
+def _runs(pattern: Tuple[str, ...]) -> Tuple[Tuple[str, int], ...]:
+    """A stack's runs in its order: (kind, layers of it in a row)."""
+    return tuple((kind, len(list(run)))
+                 for kind, run in itertools.groupby(pattern))
+
+
+def describe_stack(cfg: LlamaConfig, layers, tokens_per_device: int,
+                   pattern: Optional[Tuple[str, ...]] = None, top_k: int = 0,
+                   held: Optional[Tuple[int, int]] = None) -> Dict[str, Any]:
+    """What ``remat_plan`` knows of a stack: its ``runs`` (``_runs``; one
+    run of "layer" without a ``pattern``) and for each of its ``kinds`` the
+    bytes each rung of REMAT_LADDER keeps in one layer, the bytes a layer
+    holds while its backward runs, and the parameters of its matrices. All
+    from the shapes of the kind's stacked parameters
+    ``layers[kind][name]``, as the layer's own code reads them: ``wq``'s
+    width gives the heads (``attention_block``), a ``w_gate`` or
+    ``s_gate`` is a SwiGLU of that width (dense, shared), an ``e_gate`` a
+    routed mixture of ``top_k`` choices a token
+    (``ops/moe.routed_experts``; ``held``: its ``held=``), a ``w_in`` a
+    gated short convolution (``ops/conv.py``)."""
+    from ray_tpu.ops.moe import _held_chunk
+
+    T, h = tokens_per_device, cfg.hidden_size
+    act = jnp.dtype(cfg.dtype).itemsize
+    depth = jax.tree_util.tree_leaves(layers)[0].shape[0]
+    runs = _runs(pattern or ("layer",) * depth)
+    kinds = {}
+    for kind in dict(runs):
+        leaves = layers[kind] if pattern else layers
+        shape = {name: a.shape[1:] for name, a in leaves.items()}
+        flash = qkv = mlp = resid = rows = 0
+        # elements a token that a layer's backward holds: its recomputed
+        # forward (norms, projections, attention, the three [T, ffn]
+        # arrays of a SwiGLU) and the gradients of the widest of them
+        width = 4 * h
+        if "wq" in shape:
+            qd, kvd = shape["wq"][-1], shape["wk"][-1]
+            flash = T * (qd * act + qd // cfg.head_dim_ * 4)   # lse: float32
+            qkv = T * (qd + 2 * kvd) * act
+            resid = T * h * act
+            width += 2 * qd + 2 * kvd
+        if "w_in" in shape:
+            # the in-projection's thirds, the pass's output and their
+            # gradients
+            width += 2 * shape["w_in"][-1]
+        for gate in ("w_gate", "s_gate"):
+            if gate in shape:
+                mlp += 2 * T * shape[gate][-1] * act
+                width += 5 * shape[gate][-1]
+        if "e_gate" in shape:
+            f, pairs = shape["e_gate"][-1], T * top_k
+            if held is None:
+                # the two products carry the MLP rung's names
+                # (ops/moe._swiglu_rows), a row a (token, choice) pair
+                mlp += 2 * pairs * f * act
+            else:
+                # a pass's rows alone are gathered and multiplied, and the
+                # passes add into two float32 [T, h] sums; nothing of a
+                # pass is kept (ops/moe._held_experts: its residuals are
+                # its inputs)
+                pairs = _held_chunk(pairs, held[1], shape["router"][-1])
+                rows = 2 * T * h * 4
+            # the rows and their gradient, the three [pairs, f] arrays of
+            # the experts' SwiGLU and theirs
+            rows += pairs * (2 * h + 6 * f) * act
+        kinds[kind] = {
+            "rungs": (flash, qkv, mlp, resid),
+            "working_bytes": T * act * width + rows,
+            "params": sum(math.prod(s) for s in shape.values()
+                          if len(s) > 1)}
+    return {"runs": runs, "kinds": kinds}
+
+
+def remat_plan(cfg: LlamaConfig, stack: Dict[str, Any],
+               tokens_per_device: int, param_bytes_per_device: int,
+               capacity_bytes: Optional[int], params_sharded: bool
+               ) -> Dict[str, Any]:
+    """Which rungs of REMAT_LADDER each kind of layer of a train step
+    keeps (``stack``: ``describe_stack``): a pure function of shapes and
+    bytes, so the same inputs always give the same program.
+    ``remat_policy="auto"`` climbs the ladder rung by rung, its order of
+    time saved per byte: of the kinds that took every rung before, those
+    take a rung that together keep the most with the need reckoned within
+    ``capacity_bytes * (1 - REMAT_RESERVE)``, and a kind that does not
+    stops there. A kind's level is the last rung it took that keeps
+    anything in it, "full" where none does or there is no capacity to
+    read; any other policy is every kind's level as set, with the need
+    reckoned beside it. ``level``, ``saved_bytes_per_layer`` and
+    ``layers`` are dicts by kind, and the one kind's own for a stack of
+    one ("layer").
 
     The need, per device: parameters and two moments of their dtype,
-    resident; in the step's heap, times REMAT_HEAP_FACTOR, the gradients,
-    under a parameter-sharding mesh the gathered weights in flight (two
-    layers, embedding and head, and their gradients before the
-    reduction), every layer's input, and the larger of one layer's
-    internals while its backward runs and the float32 logits with their
-    gradient; and for every layer what the level keeps."""
-    T, h, ffn = tokens_per_device, cfg.hidden_size, cfg.intermediate_size
-    qd = cfg.num_heads * cfg.head_dim_
-    kvd = cfg.num_kv_heads * cfg.head_dim_
+    resident, and the step's heap at its fullest moment. The backward
+    walks the runs from the last to the first. While a run's layers are
+    in it the heap holds, times REMAT_HEAP_FACTOR: under a
+    parameter-sharding mesh the gathered weights in flight (two layers,
+    embedding and head, and their gradients before the reduction), the
+    input of every layer up to the run's last, the gradients of the run
+    and of all above it, and one layer's internals; before the first, in
+    place of the last two, the float32 logits with their gradient. On top
+    comes what the levels keep in the layers up to the run's last (what
+    the layers above kept is freed by then): its own bytes, and times the
+    factor where a scanned run of a stack of several keeps it (read
+    back from the stack slice by slice: PERF.md 6, PR 33). In a stack of
+    one run that is PR 27's reckoning, unchanged."""
+    T, h = tokens_per_device, cfg.hidden_size
     act = jnp.dtype(cfg.dtype).itemsize
     par = jnp.dtype(cfg.param_dtype).itemsize
-    rungs = (T * (qd * act + cfg.num_heads * 4),     # flash_lse is float32
-             T * (qd + 2 * kvd) * act,
-             2 * T * ffn * act,
-             T * h * act)
+    runs, kinds = stack["runs"], stack["kinds"]
+    depth = {k: sum(n for kind, n in runs if kind == k) for k in kinds}
     gathered = 0
     if params_sharded:
-        layer_params = h * (qd + 2 * kvd) + qd * h + 3 * h * ffn
-        gathered = 2 * (2 * layer_params + 2 * cfg.vocab_size * h) * par
-    # a layer's backward holds its recomputed forward (norms, projections,
-    # attention, the three [T, ffn] arrays of the MLP) and the gradients
-    # of the widest of them
-    layer = T * act * (4 * h + 2 * qd + 2 * kvd + 5 * ffn)
+        top = (1 if cfg.tie_embeddings else 2) * cfg.vocab_size * h
+        gathered = 2 * (2 * max(k["params"] for k in kinds.values())
+                        + top) * par
     logits = 2 * T * cfg.vocab_size * 4
-    heap = (gathered + cfg.num_layers * T * h * act
-            + max(param_bytes_per_device + layer, logits))
-    fixed = 3 * param_bytes_per_device + int(REMAT_HEAP_FACTOR * heap)
 
-    def saved(policy: str) -> int:
-        names = remat_names(policy)
-        return sum(b for rung, b in zip(REMAT_LADDER, rungs)
-                   if set(rung) <= set(names))
+    def saved(kind: str, level: str) -> int:
+        return sum(b for rung, b in zip(REMAT_LADDER, kinds[kind]["rungs"])
+                   if set(rung) <= set(remat_names(level)))
 
-    def need(policy: str) -> int:
-        return fixed + cfg.num_layers * saved(policy)
+    def need(level: Dict[str, str]) -> int:
+        below = grads_below = stacked = freed = fullest = 0
+        for kind, n in runs:
+            below += n
+            kept = n * saved(kind, level[kind])
+            if len(runs) > 1 and cfg.scan_layers and n > 1:
+                stacked += kept
+            else:
+                freed += kept
+            live = (gathered + below * T * h * act
+                    + kinds[kind]["working_bytes"]
+                    + param_bytes_per_device - grads_below)
+            fullest = max(fullest,
+                          REMAT_HEAP_FACTOR * (live + stacked) + freed)
+            grads_below += n * kinds[kind]["params"] * par
+        live = gathered + below * T * h * act + logits
+        fullest = max(fullest, REMAT_HEAP_FACTOR * (live + stacked) + freed)
+        return 3 * param_bytes_per_device + int(fullest)
 
-    policy = cfg.remat_policy
-    if policy == "auto":
-        policy = "full"
-        if capacity_bytes:
-            budget = capacity_bytes * (1 - REMAT_RESERVE)
-            for n in range(len(REMAT_LADDER), 0, -1):
-                if need(f"level{n}") <= budget:
-                    policy = f"level{n}"
-                    break
-    return {"level": policy, "saved_bytes_per_layer": saved(policy),
-            "need_bytes": need(policy), "capacity_bytes": capacity_bytes,
-            "layers": cfg.num_layers}
+    def labels(taken: Dict[str, int]) -> Dict[str, str]:
+        # a rung that keeps nothing in a kind does not name its level: a
+        # convolution layer is "full" whatever its neighbours keep
+        last = {k: max((n + 1 for n in range(taken[k])
+                        if kinds[k]["rungs"][n]), default=0) for k in kinds}
+        return {k: f"level{n}" if n else "full" for k, n in last.items()}
+
+    level = dict.fromkeys(kinds, cfg.remat_policy)
+    if cfg.remat_policy == "auto":
+        taken = dict.fromkeys(kinds, 0)       # rungs each kind keeps
+        budget = (capacity_bytes or 0) * (1 - REMAT_RESERVE)
+        climbing = tuple(kinds)
+        for rung in range(len(REMAT_LADDER)):
+            # most kinds first and in the stack's order: ties fall one way
+            climbing = max(
+                (c for n in range(len(climbing), 0, -1)
+                 for c in itertools.combinations(climbing, n)
+                 if need(labels({**taken, **dict.fromkeys(c, rung + 1)}))
+                 <= budget),
+                key=lambda c: sum(depth[k] * kinds[k]["rungs"][rung]
+                                  for k in c), default=())
+            taken.update(dict.fromkeys(climbing, rung + 1))
+        level = labels(taken)
+    by_kind = {"level": level, "layers": depth,
+               "saved_bytes_per_layer": {k: saved(k, level[k])
+                                         for k in kinds}}
+    if tuple(kinds) == ("layer",):
+        by_kind = {name: of["layer"] for name, of in by_kind.items()}
+    return {**by_kind, "need_bytes": need(level),
+            "capacity_bytes": capacity_bytes}
 
 
 def _device_capacity(mesh) -> Optional[int]:
@@ -295,11 +409,14 @@ def _device_capacity(mesh) -> Optional[int]:
         return None
 
 
-def _resolve_remat(cfg: LlamaConfig, params, tokens, mesh) -> str:
-    """The remat level of the program being traced, from its shapes: no
-    device work and no trial compile. The plan is one kept span, so an
-    operator reads in ``trace_spans.json`` and ``timeline()`` which level
-    a job got and why."""
+def resolve_remat(cfg: LlamaConfig, params, tokens, mesh, shardings=None,
+                  **stack) -> Any:
+    """The remat level of the program being traced (by kind for a stack
+    with a ``pattern``), from its shapes: no device work and no trial
+    compile. ``shardings``: the forward's own ``param_shardings``;
+    ``stack``: ``describe_stack``'s keywords. The plan is one kept span,
+    so an operator reads in ``trace_spans.json`` and ``timeline()`` which
+    level a job got and why."""
     total = sum(a.size * a.dtype.itemsize
                 for a in jax.tree_util.tree_leaves(params))
     per_device, data_shards = total, 1
@@ -308,14 +425,18 @@ def _resolve_remat(cfg: LlamaConfig, params, tokens, mesh) -> str:
 
         per_device = sum(jax.tree_util.tree_leaves(jax.tree_util.tree_map(
             lambda a, sh: math.prod(sh.shard_shape(a.shape))
-            * a.dtype.itemsize, params, param_shardings(cfg, mesh))))
+            * a.dtype.itemsize, params,
+            (shardings or param_shardings)(cfg, mesh))))
         sizes = dict(mesh.shape)
         for logical in ("batch", "seq"):
             axes = resolve_axis(logical, mesh) or ()
             for axis in (axes,) if isinstance(axes, str) else axes:
                 data_shards *= sizes[axis]
-    plan = remat_plan(cfg, -(-tokens.size // data_shards), per_device,
-                      _device_capacity(mesh), per_device < total)
+    per_shard = -(-tokens.size // data_shards)
+    plan = remat_plan(cfg, describe_stack(cfg, params["layers"], per_shard,
+                                          **stack),
+                      per_shard, per_device, _device_capacity(mesh),
+                      per_device < total)
     with tracing.span("rtpu.train.remat_plan", keep=True, **plan):
         pass
     return plan["level"]
@@ -461,13 +582,13 @@ def _layer(cfg: LlamaConfig, x, layer_params, cos, sin, mesh=None,
 
 def remat_level_without_plan(cfg: LlamaConfig) -> Optional[str]:
     """The level ``run_layers`` gets from a forward that has no plan for
-    its memory (Mixtral, OLMoE, the pipeline stage): "full" under
-    ``cfg.remat``. A ladder level somebody set would be a silent no-op
-    there, so it is refused."""
+    its memory (Mixtral, the pipeline stage): "full" under ``cfg.remat``.
+    A ladder level somebody set would be a silent no-op there, so it is
+    refused."""
     if cfg.remat_policy not in ("auto", "full"):
         raise ValueError(
-            f"remat_policy={cfg.remat_policy!r} is a level of the dense "
-            "forward's ladder; this forward has no plan and runs full remat "
+            f"remat_policy={cfg.remat_policy!r} is a level of the planned "
+            "forwards' ladder; this forward has no plan and runs full remat "
             "(\"auto\" is \"full\" here) - drop it rather than read "
             "tuning signal from a no-op")
     return "full" if cfg.remat else None
@@ -479,7 +600,8 @@ def run_layers(layer_fn, x, layers, *, level: Optional[str], scan: bool,
     ``jax.checkpoint``. ``layer_fn(x, p) -> (x, y)`` is one block (``y``
     may be None); returns the last ``x`` and the ``y``s stacked, as
     ``lax.scan`` does. ``level``: None (no remat), "full" or a level of
-    REMAT_LADDER. ``scan``: ``cfg.scan_layers``.
+    REMAT_LADDER, or with a ``pattern`` a dict of those by kind.
+    ``scan``: ``cfg.scan_layers``.
 
     ``pattern``: for a stack of unequal layers, the kind of each layer in
     order, e.g. ``("dense", "win", "win", "win", "full")``; ``layer_fn``
@@ -493,24 +615,29 @@ def run_layers(layer_fn, x, layers, *, level: Optional[str], scan: bool,
         depth = jax.tree_util.tree_leaves(layers)[0].shape[0]
         pattern, layer_fn, layers = (("layer",) * depth, {"layer": layer_fn},
                                      {"layer": layers})
+    runs = _runs(pattern)
     if level is not None:
-        names = remat_names(level)
+        if not isinstance(level, dict):
+            level = dict.fromkeys(layer_fn, level)
         # Inside the scan the forward and the backward are two loops and
         # XLA cannot merge a recomputation back into the forward, so a
         # ladder level drops jax.checkpoint's barrier against that, as
         # jax advises under scan: at 7B widths it cost a gigabyte of
         # XLA's heap and 5% of the step (PERF.md 6, PR 27). "full" keeps
-        # the program it always had.
+        # the program it always had, and so does a kind with a layer that
+        # is walked alone: there the barrier is what keeps the remat.
+        walked = {kind for kind, n in runs if not (scan and n > 1)}
+        names = {kind: remat_names(level[kind]) for kind in layer_fn}
         layer_fn = {kind: jax.checkpoint(
             fn, policy=jax.checkpoint_policies.save_only_these_names(
-                *names) if names else None,
-            prevent_cse=not (scan and level.startswith("level")))
+                *names[kind]) if names[kind] else None,
+            prevent_cse=kind in walked or not names[kind])
             for kind, fn in layer_fn.items()}
     tree_map = jax.tree_util.tree_map
     taken = dict.fromkeys(layers, 0)      # layers of each kind walked so far
     ys = {kind: [] for kind in layers}
-    for kind, run in itertools.groupby(pattern):
-        lo, n = taken[kind], len(list(run))
+    for kind, n in runs:
+        lo = taken[kind]
         taken[kind] += n
         if scan and n > 1:
             whole = n == pattern.count(kind)
@@ -537,7 +664,7 @@ def forward(cfg: LlamaConfig, params: Dict[str, Any], tokens: jax.Array,
         cos, sin = rope_frequencies(cfg.head_dim_, tokens.shape[1],
                                     cfg.rope_theta, dtype=cfg.dtype,
                                     scaling=cfg.rope_scaling_dict)
-    level = _resolve_remat(cfg, params, tokens, mesh) if cfg.remat else None
+    level = resolve_remat(cfg, params, tokens, mesh) if cfg.remat else None
     x, _ = run_layers(
         lambda x_, p_: (_layer(cfg, x_, p_, cos, sin, mesh=mesh), None),
         x, params["layers"], level=level, scan=cfg.scan_layers)

@@ -114,11 +114,12 @@ def forward(cfg: OlmoeConfig, params, tokens: jax.Array, mesh=None,
         cos, sin = rope_frequencies(cfg.head_dim_, tokens.shape[1],
                                     cfg.rope_theta, dtype=cfg.dtype,
                                     scaling=cfg.rope_scaling_dict)
+    level = llama.resolve_remat(cfg, params, tokens, mesh, param_shardings,
+                                top_k=cfg.top_k) if cfg.remat else None
     x, router = llama.run_layers(
         lambda x_, p_: _layer(cfg, x_, p_, cos, sin, mesh=mesh,
                               keep_router_logits=keep_router_logits),
-        x, params["layers"], level=llama.remat_level_without_plan(cfg),
-        scan=cfg.scan_layers)
+        x, params["layers"], level=level, scan=cfg.scan_layers)
     return llama._final_head(cfg, params, x), router
 
 

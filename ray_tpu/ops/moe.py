@@ -60,6 +60,7 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 
 @partial(jax.custom_vjp, nondiff_argnums=(3,))
@@ -206,8 +207,14 @@ def _swiglu_rows(rows, w_rows, sizes, e_gate, e_up, e_down):
     weight inside the activation's elementwise pass (the module's
     docstring)."""
     dt = rows.dtype
-    gate = grouped_matmul(rows, e_gate.astype(dt), sizes)
-    up = grouped_matmul(rows, e_up.astype(dt), sizes)
+    # named as ops/layers.swiglu names its two products, for the MLP rung
+    # of a layer's remat level (models/llama.py REMAT_LADDER): kept, the
+    # backward runs neither grouped matmul again. Inert without a level,
+    # and inside the held experts' passes, whose residuals are their inputs.
+    gate = checkpoint_name(grouped_matmul(rows, e_gate.astype(dt), sizes),
+                           "mlp_gate")
+    up = checkpoint_name(grouped_matmul(rows, e_up.astype(dt), sizes),
+                         "mlp_up")
     act = (jax.nn.silu(gate.astype(jnp.float32))
            * up.astype(jnp.float32) * w_rows[:, None])
     return grouped_matmul(act.astype(dt), e_down.astype(dt), sizes)

@@ -19,8 +19,11 @@ Run as a file and not with ``-m``: ``--tree`` (default: this checkout) is
 the checkout whose ``ray_tpu`` and ``benchmark/`` are read, so the same
 script judges a parent commit unpacked elsewhere. ``--out`` gets
 ``<cell>.hlo.txt`` (``as_text()`` without metadata) and ``summary.json``
-(its sha256, ``memory_analysis()``, the resolved remat level, the count
-of Mosaic calls and their names; and, about the program and not of it,
+(its sha256, ``memory_analysis()``, the resolved remat level, by kind for
+a stack of kinds, the count of Mosaic calls and their names; and, about
+the program and not of it, ``remat``: the whole ``rtpu.train.remat_plan``
+span beside what the compiler allotted, arguments + temporaries + outputs
+- aliased, which is what the plan's ``need_bytes`` is held against;
 ``flash_tiles``: what each distinct flash kernel call of the step visits,
 from its ``rtpu.flash.tiles`` span, and ``scopes``: how many instructions
 carry each ``jax.named_scope`` name as the innermost). ``--compare``
@@ -145,7 +148,8 @@ def _compile_cell(tree: str, cell: dict, topo) -> dict:
     compiled = jax.jit(step, donate_argnums=(0, 1)).lower(
         params, opt, batch).compile()
     events = tracing.chrome_events()[n0:]
-    plans = [e["args"]["level"] for e in events
+    plans = [{k: v for k, v in e["args"].items()
+              if k not in ("id", "parent", "self_us")} for e in events
              if e["name"] == "rtpu.train.remat_plan"]
     # one line a distinct kernel call of the step: what its loops visit
     tiles = sorted({json.dumps({k: v for k, v in e["args"].items()
@@ -162,11 +166,18 @@ def _compile_cell(tree: str, cell: dict, topo) -> dict:
         full)))
     text = strip_metadata(full)
     ma = compiled.memory_analysis()
+    # what the plan's need is held against (tests/test_tpu_compile.py)
+    allotted = (ma.argument_size_in_bytes + ma.temp_size_in_bytes
+                + ma.output_size_in_bytes - ma.alias_size_in_bytes)
     return {"text": text, "summary": {
         "sha256": hashlib.sha256(text.encode()).hexdigest(),
         "lines": text.count("\n"),
         "mosaic_calls": text.count("tpu_custom_call"),
-        "remat_plan": plans[0] if plans else None,
+        "remat_plan": plans[0]["level"] if plans else None,
+        "remat": {**plans[0], "allotted_bytes": allotted,
+                  "allotted_over_need": round(
+                      allotted / plans[0]["need_bytes"], 4)}
+        if plans else None,
         "mosaic_kernels": kernels,
         "flash_tiles": [json.loads(t) for t in tiles],
         "scopes": dict(sorted(scopes.items())),
@@ -207,6 +218,14 @@ def compile_cells(tree: str, out: str, only=()) -> None:
         print(cell["name"], json.dumps(got["summary"]), flush=True)
     with open(os.path.join(out, "summary.json"), "w") as f:
         json.dump(summary, f, indent=1, sort_keys=True)
+    print("remat plan against the compiler, bytes a device:")
+    for name, got in summary.items():
+        plan = got["remat"]
+        if plan:
+            print(f"  {name}: {json.dumps(plan['level'])} need "
+                  f"{plan['need_bytes']:,} allotted "
+                  f"{plan['allotted_bytes']:,} "
+                  f"({plan['allotted_over_need']:.4f} of the need)")
 
 
 def compare(a: str, b: str) -> int:

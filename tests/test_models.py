@@ -1157,48 +1157,125 @@ def _count_primitives(jaxpr, counts=None):
     return counts
 
 
+def _tiny_moe(model):
+    from ray_tpu.models import laguna, lfm2, olmoe
+
+    return {"olmoe": (olmoe, olmoe.OlmoeConfig),
+            "laguna": (laguna, laguna.LagunaConfig),
+            "lfm2": (lfm2, lfm2.Lfm2Config)}[model]
+
+
+def _stack_of(mod, cfg, layers, tokens):
+    """``llama.describe_stack`` as ``mod.forward`` asks for it."""
+    how = {"top_k": cfg.top_k} if mod is not llama else {}
+    if hasattr(cfg, "pattern"):
+        how.update(pattern=cfg.pattern, held=cfg.experts_held)
+    return llama.describe_stack(cfg, layers, tokens, **how)
+
+
+def _capacity_with_room(mod, cfg, params, tokens, room):
+    """The ``bytes_limit`` that leaves ``remat_plan`` ``room`` bytes over
+    what ``cfg``'s step needs at "full"."""
+    from dataclasses import replace
+
+    par = sum(a.size * a.dtype.itemsize
+              for a in jax.tree_util.tree_leaves(params))
+    full = llama.remat_plan(
+        replace(cfg, remat_policy="full"),
+        _stack_of(mod, cfg, params["layers"], tokens), tokens, par, None,
+        False)["need_bytes"]
+    return int((full + room) / (1 - llama.REMAT_RESERVE)) + 1
+
+
+# What ``remat_policy="auto"`` resolves to with 200 kB over "full", by
+# (model, scan_layers). Tiny Laguna: the walked dense layer keeps two
+# rungs, three where the sliding layers' kept values are not a scan's
+# stacks; the sliding layers one; the last full layer, whose backward is
+# not the step's fullest moment, all four. Tiny OLMoE's one kind reaches
+# the MLP rung: the experts' two products are kept.
+_AUTO_LEVELS = {
+    ("laguna", True): {"full_dense": "level2", "sliding_moe": "level1",
+                       "full_moe": "level4"},
+    ("laguna", False): {"full_dense": "level3", "sliding_moe": "level1",
+                        "full_moe": "level4"},
+    ("olmoe", True): "level3", ("olmoe", False): "level3"}
+
+
+def _last_plan_level():
+    from ray_tpu.util import tracing
+
+    return [e["args"]["level"] for e in tracing.chrome_events()
+            if e["name"] == "rtpu.train.remat_plan"][-1]
+
+
 @pytest.fixture(scope="module")
 def remat_setup():
-    """Tiny llama and its batch; ``grads(attn, **cfg)`` gives loss and
-    gradients jitted, ``loss_of`` the loss to trace. ``attn="flash"``
-    runs the flash kernel through the Pallas interpreter, so that
-    ``flash_out`` / ``flash_lse`` exist to be kept."""
+    """``setup(model)`` -> (``loss_of``, ``traced``, ``want``) for the tiny
+    llama, OLMoE or Laguna and its batch: ``loss_of(attn, **cfg)`` is the
+    loss to trace, ``traced(fn, room=None)`` calls it on the parameters
+    (``room``: bytes the device has over what "full" needs, for
+    ``remat_policy="auto"``), ``want[attn]`` loss and gradients without
+    remat. ``attn="flash"`` runs the flash kernels through the Pallas
+    interpreter, so that ``flash_out`` / ``flash_lse`` exist to be
+    kept."""
     import functools
 
     from ray_tpu.ops.attention import flash_attention
 
     tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 33), 0, 256)
-    params = llama.init_params(llama.LlamaConfig.tiny(),
-                               jax.random.PRNGKey(0))
     interpreted = functools.partial(flash_attention, use_pallas=True,
                                     interpret=True, block_q=32, block_k=32)
 
-    def loss_of(attn, **kw):
-        cfg = llama.LlamaConfig.tiny(attn_impl=attn, **kw)
-        return lambda p: llama.loss_fn(cfg, p, {"tokens": tokens})
+    @functools.lru_cache(maxsize=None)
+    def setup(model="llama"):
+        mod, cls = (llama, llama.LlamaConfig) if model == "llama" \
+            else _tiny_moe(model)
+        params = mod.init_params(cls.tiny(), jax.random.PRNGKey(0))
 
-    def traced(fn):
-        with pytest.MonkeyPatch.context() as mp:
-            mp.setattr(llama, "flash_attention", interpreted)
-            return fn(params)
+        def loss_of(attn, **kw):
+            cfg = cls.tiny(attn_impl=attn, **kw)
+            return lambda p: mod.loss_fn(cfg, p, {"tokens": tokens})
 
-    want = {attn: traced(jax.jit(jax.value_and_grad(
-        loss_of(attn, remat=False)))) for attn in ("reference", "flash")}
-    return loss_of, traced, want
+        def traced(fn, room=None):
+            with pytest.MonkeyPatch.context() as mp:
+                mp.setattr(llama, "flash_attention", interpreted)
+                if room is not None:
+                    limit = _capacity_with_room(
+                        mod, cls.tiny(remat=True), params, 64, room)
+                    mp.setattr(llama, "_device_capacity", lambda mesh: limit)
+                return fn(params)
+
+        want = {attn: traced(jax.jit(jax.value_and_grad(
+            loss_of(attn, remat=False)))) for attn in ("reference", "flash")}
+        return loss_of, traced, want
+
+    return setup
 
 
-@pytest.mark.parametrize("attn", ["reference", "flash"])
-@pytest.mark.parametrize("scan_layers", [True, False])
-@pytest.mark.parametrize("policy", ["full", "level1", "level2", "level3",
-                                    "level4"])
-def test_every_remat_level_matches_no_remat(remat_setup, policy,
-                                            scan_layers, attn):
-    """What a layer's checkpoint keeps (``remat_policy``) and how the
-    layers are looped (``scan_layers``) change the schedule, not the
-    math: loss AND gradients equal ``remat=False``."""
-    loss_of, traced, want = remat_setup
+@pytest.mark.parametrize("model,attn,scan_layers,policy,room", [
+    ("llama", attn, scan, policy, None)
+    for attn in ("reference", "flash") for scan in (True, False)
+    for policy in ("full", "level1", "level2", "level3", "level4")
+] + [
+    # the routed stacks: a level somebody set in every kind, and the plan's
+    # own (_AUTO_LEVELS), scanned and walked
+    (model, "flash", scan, policy, room)
+    for model, policy, room in (("olmoe", "level2", None),
+                                ("olmoe", "auto", 200_000),
+                                ("laguna", "level4", None),
+                                ("laguna", "auto", 200_000))
+    for scan in (True, False)])
+def test_every_remat_level_matches_no_remat(remat_setup, model, attn,
+                                            scan_layers, policy, room):
+    """What a layer's checkpoint keeps (``remat_policy``, by kind where
+    the plan chose) and how the layers are looped (``scan_layers``) change
+    the schedule, not the math: loss AND gradients equal ``remat=False``."""
+    loss_of, traced, want = remat_setup(model)
     l_got, g_got = traced(jax.jit(jax.value_and_grad(loss_of(
-        attn, remat=True, remat_policy=policy, scan_layers=scan_layers))))
+        attn, remat=True, remat_policy=policy, scan_layers=scan_layers))),
+        room)
+    if policy == "auto":
+        assert _last_plan_level() == _AUTO_LEVELS[model, scan_layers]
     l_want, g_want = want[attn]
     assert jnp.allclose(l_want, l_got, atol=1e-6)
     assert all(jnp.allclose(a, b, atol=1e-5)
@@ -1206,26 +1283,42 @@ def test_every_remat_level_matches_no_remat(remat_setup, policy,
                                jax.tree_util.tree_leaves(g_got)))
 
 
-def test_richest_remat_level_recomputes_no_matmul_and_no_flash(remat_setup):
+@pytest.mark.parametrize("model", ["llama", "laguna"])
+def test_richest_remat_level_recomputes_no_matmul_and_no_flash(remat_setup,
+                                                               model):
     """The gradient's jaxpr, counted: under "full" every layer's backward
     runs the six projections (q, k, v, wo, gate, up) and the flash forward
     a second time; "level4" runs none of them again, "level1" only drops
-    the kernel."""
-    loss_of, traced, _ = remat_setup
-    layers = llama.LlamaConfig.tiny().num_layers
+    the kernel. Laguna's stack, a level by kind: each kind's kernel (the
+    sliding layers' is ``flash_win_fwd``) and matmuls follow its own."""
+    loss_of, traced, _ = remat_setup(model)
 
-    def counts(policy):
+    def counts(policy, room=None):
         c = _count_primitives(traced(jax.make_jaxpr(jax.grad(loss_of(
             "flash", remat=True, remat_policy=policy,
-            scan_layers=False)))).jaxpr)
-        return c["dot_general"], c["pallas_call:flash_fwd"]
+            scan_layers=False))), room).jaxpr)
+        return (c["dot_general"], c["pallas_call:flash_fwd"],
+                c.get("pallas_call:flash_win_fwd", 0))
 
-    dots_full, fwd_full = counts("full")
-    assert fwd_full == 2 * layers
-    assert counts("level1") == (dots_full, layers)
-    assert counts("level2") == (dots_full - 3 * layers, layers)
-    assert counts("level3") == (dots_full - 5 * layers, layers)
-    assert counts("level4") == (dots_full - 6 * layers, layers)
+    dots_full, fwd_full, win_full = counts("full")
+    if model == "llama":
+        layers = llama.LlamaConfig.tiny().num_layers
+        assert (fwd_full, win_full) == (2 * layers, 0)
+        assert counts("level1") == (dots_full, layers, 0)
+        assert counts("level2") == (dots_full - 3 * layers, layers, 0)
+        assert counts("level3") == (dots_full - 5 * layers, layers, 0)
+        assert counts("level4") == (dots_full - 6 * layers, layers, 0)
+        return
+    # two full layers, three sliding ones; every routed layer has a shared
+    # expert's gate and up beside the experts' two (ragged_dot is its own
+    # primitive: the dots here are the dense ones)
+    assert (fwd_full, win_full) == (2 * 2, 2 * 3)
+    assert counts("level1") == (dots_full, 2, 3)
+    assert counts("level4") == (dots_full - 6 * 5, 2, 3)
+    # by kind: q, k, v, gate and up of the dense layer; nothing but the
+    # kernel in the sliding ones; all six of the last layer
+    assert counts("auto", 200_000) == (dots_full - 5 - 6, 2, 3)
+    assert _last_plan_level() == _AUTO_LEVELS["laguna", False]
 
 
 def test_one_walker_owns_the_layer_loop():
@@ -1259,7 +1352,99 @@ def _param_bytes(cfg):
                jax.tree_util.tree_leaves(llama.init_shapes(cfg)))
 
 
-def test_remat_plan_is_a_pure_function_of_bytes():
+def _dense_plan(cfg, tokens, par, cap, sharded):
+    return llama.remat_plan(
+        cfg, llama.describe_stack(cfg, llama.init_shapes(cfg)["layers"],
+                                  tokens), tokens, par, cap, sharded)
+
+
+def _cell_config(name):
+    """(module, config) of ``benchmark/configs/<name>.json``, as the cell's
+    runner and ``step_program.py`` build it."""
+    import json
+    import os
+    from importlib import import_module
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs", name + ".json")) as f:
+        kw = {k: tuple(v) if isinstance(v, list) else v
+              for k, v in json.load(f)["model_config"].items()}
+    preset = kw.pop("preset")
+    for key in ("dtype", "param_dtype"):
+        kw[key] = getattr(jnp, kw[key])
+    module = kw.pop("module", "olmoe")
+    mod = import_module("ray_tpu.models." + module)
+    return mod, getattr(getattr(mod, module.capitalize() + "Config"),
+                        preset)(**kw)
+
+
+# what each planned cell of the benchmark gets on a v5e chip (PERF.md 6,
+# PR 33: held against the compiler by ``step_program.py``): its tokens a
+# device, the levels, the bytes a layer of each kind keeps
+_SHIPPED_PLANS = {
+    "olmoe-1b-7b-c1": (8192, "level1", 34078720),
+    "laguna-s-2.1-c1": (16384,
+                        {"full_dense": "level3", "sliding_moe": "level1",
+                         "full_moe": "level4"},
+                        {"full_dense": 1278214144, "sliding_moe": 306708480,
+                         "full_moe": 640679936}),
+    "lfm2-8b-a1b-c1": (16384,
+                       {"conv_dense": "level3", "attn_moe": "level4",
+                        "conv_moe": "full"},
+                       {"conv_dense": 469762048, "attn_moe": 236978176,
+                        "conv_moe": 0})}
+
+
+@pytest.mark.parametrize("stack", ["dense", *_SHIPPED_PLANS])
+def test_remat_plan_is_a_pure_function_of_bytes(stack):
+    if stack != "dense":
+        # a routed or mixed stack at its published widths: the levels by
+        # kind that this repo's cells run, from shapes alone
+        from dataclasses import replace
+
+        mod, cfg = _cell_config(stack)
+        tokens, levels, saved = _SHIPPED_PLANS[stack]
+        shapes = jax.eval_shape(lambda k: mod.init_params(cfg, k),
+                                jax.random.PRNGKey(0))
+        par = sum(a.size * a.dtype.itemsize
+                  for a in jax.tree_util.tree_leaves(shapes))
+        described = _stack_of(mod, cfg, shapes["layers"], tokens)
+
+        def plan(cap=_V5E_LIMIT, **kw):
+            return llama.remat_plan(replace(cfg, **kw), described, tokens,
+                                    par, cap, False)
+
+        got = plan()
+        assert (got["level"], got["saved_bytes_per_layer"]) == (levels, saved)
+        assert got == plan()
+        assert got["need_bytes"] <= (1 - llama.REMAT_RESERVE) * _V5E_LIMIT
+        kinds = list(described["kinds"])
+        assert plan(cap=None)["level"] == (
+            "full" if kinds == ["layer"] else dict.fromkeys(kinds, "full"))
+        # a level somebody set is every kind's, whatever the room, and
+        # needs no less than what the plan chose under it
+        for cap in (None, 10 ** 9):
+            top = plan(cap=cap, remat_policy="level4")
+            assert set(top["level"].values() if isinstance(top["level"], dict)
+                       else [top["level"]]) == {"level4"}
+            assert top["need_bytes"] >= got["need_bytes"]
+        # more room never keeps less at the first rung where two plans
+        # differ (a kind may give a later rung back for a larger one of
+        # another kind), up to every rung of every kind
+        rank = (["full"] + [f"level{n}" for n in range(1, 5)]).index
+        depth = dict(described["runs"]) if len(kinds) > 1 else {
+            "layer": got["layers"]}
+        kept = []
+        for cap in np.arange(10.0, 24.0, 0.25):
+            level = plan(cap=int(cap * 1e9))["level"]
+            level = level if len(kinds) > 1 else {"layer": level}
+            kept.append([sum(depth[k] * described["kinds"][k]["rungs"][rung]
+                             for k in kinds if rank(level[k]) > rung)
+                         for rung in range(4)])
+        assert kept == sorted(kept) and not any(kept[0])
+        assert kept[-1] == [sum(depth[k] * described["kinds"][k]["rungs"][r]
+                                for k in kinds) for r in range(4)]
+        return
     c1 = llama.LlamaConfig(num_layers=4, **_MISTRAL)
     c4 = llama.LlamaConfig(num_layers=16, **_MISTRAL)
     b1, b4 = _param_bytes(c1), _param_bytes(c4) // 4
@@ -1267,16 +1452,20 @@ def test_remat_plan_is_a_pure_function_of_bytes():
     rank = levels.index
 
     def level(cfg, tokens=8192, par=b1, cap=_V5E_LIMIT, sharded=False):
-        return llama.remat_plan(cfg, tokens, par, cap, sharded)["level"]
+        return _dense_plan(cfg, tokens, par, cap, sharded)["level"]
 
-    # what PERF.md says the cells get: train-1chip, train-fsdp4
-    plan = llama.remat_plan(c1, 8192, b1, _V5E_LIMIT, False)
+    # what PERF.md says the cells get: train-1chip, train-fsdp4, and the
+    # numbers the plan gave them before it knew other kinds (PR 27)
+    plan = _dense_plan(c1, 8192, b1, _V5E_LIMIT, False)
     assert plan["level"] == "level4" and plan["layers"] == 4
     assert plan["saved_bytes_per_layer"] == 8192 * (
         2 * (4096 + 4096 + 2 * 1024 + 2 * 14336 + 4096) + 32 * 4)
+    assert plan["need_bytes"] == 15_909_326_848
     assert plan["need_bytes"] <= 0.95 * _V5E_LIMIT == \
         (1 - llama.REMAT_RESERVE) * plan["capacity_bytes"]
     assert level(c4, par=b4, sharded=True) == "full"
+    assert _dense_plan(c4, 8192, b4, _V5E_LIMIT, True)["need_bytes"] == \
+        16_710_411_264
     # no capacity to read (the CPU): nothing changes
     assert level(c1, cap=None) == "full"
     # more room never gives a poorer level; every level is reached
@@ -1338,7 +1527,7 @@ def test_forward_resolves_the_plan_from_the_shapes_it_traces(
     per_device = _param_bytes(cfg) // (fsdp or 1)
     assert spans[0]["args"] == {
         "id": None, "parent": None, "self_us": spans[0]["args"]["self_us"],
-        **llama.remat_plan(cfg, 8192, per_device, _V5E_LIMIT, bool(fsdp))}
+        **_dense_plan(cfg, 8192, per_device, _V5E_LIMIT, bool(fsdp))}
     assert spans[0]["args"]["level"] == want
 
 

@@ -178,6 +178,30 @@ def _placed(tree, sharding):
         a.shape, a.dtype, sharding=sharding), tree)
 
 
+V5E_LIMIT = int(15.75 * 2 ** 30)          # a v5e chip's bytes_limit
+
+
+def _planned(lower):
+    """``lower()`` compiled, and the one remat plan its trace wrote, held
+    against the compiler: the program is not more than 3% over what the
+    plan reckoned, the plan not more than a tenth over the program, and
+    the program leaves the plan's reserve free."""
+    from ray_tpu.models import llama
+    from ray_tpu.util import tracing
+
+    n0 = len(tracing.chrome_events())
+    compiled = lower().compile()
+    (plan,) = [e["args"] for e in tracing.chrome_events()[n0:]
+               if e["name"] == "rtpu.train.remat_plan"]
+    ma = compiled.memory_analysis()
+    allotted = ma.argument_size_in_bytes + ma.temp_size_in_bytes \
+        + ma.output_size_in_bytes - ma.alias_size_in_bytes
+    assert allotted <= 1.03 * plan["need_bytes"] <= 1.03 * 1.1 * allotted, (
+        allotted, plan["need_bytes"])
+    assert allotted <= (1 - llama.REMAT_RESERVE) * V5E_LIMIT
+    return compiled, plan
+
+
 def test_laguna_cell_step_compiles_within_a_v5e_chip(one_chip,
                                                      no_compile_cache,
                                                      monkeypatch):
@@ -187,14 +211,16 @@ def test_laguna_cell_step_compiles_within_a_v5e_chip(one_chip,
     laguna-s-2.1-c1.json``): Mosaic takes the window kernels and the
     8,192-position dK/dV call (which asks for more than the default
     scoped VMEM), the passes over the held rows compile to loops whose
-    trip count is data, and the program fits 15.75 GiB."""
+    trip count is data; the remat plan gives each kind its level for a
+    v5e's memory, and the program fits what it reckoned."""
     import json
 
     import optax
 
-    from ray_tpu.models import laguna
+    from ray_tpu.models import laguna, llama
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(llama, "_device_capacity", lambda mesh: V5E_LIMIT)
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     with open(os.path.join(root, "benchmark", "configs",
                            "laguna-s-2.1-c1.json")) as f:
@@ -222,12 +248,19 @@ def test_laguna_cell_step_compiles_within_a_v5e_chip(one_chip,
         return (optax.apply_updates(params, updates), opt, loss,
                 aux["expert_counts"])
 
-    compiled = jax.jit(step, donate_argnums=(0, 1)).lower(
-        _placed(params, one_chip), _placed(opt, one_chip), batch).compile()
-    ma = compiled.memory_analysis()
-    assert ma.argument_size_in_bytes + ma.temp_size_in_bytes \
-        + ma.output_size_in_bytes - ma.alias_size_in_bytes \
-        <= int(15.75 * 2 ** 30)
+    compiled, plan = _planned(lambda: jax.jit(
+        step, donate_argnums=(0, 1)).lower(
+            _placed(params, one_chip), _placed(opt, one_chip), batch))
+    # by kind: the walked dense layer keeps its flash outputs, q/k/v and
+    # the two products of its 12,288-wide SwiGLU, the scanned sliding
+    # layers their flash outputs, the last layer all four rungs
+    assert plan["level"] == {"full_dense": "level3", "sliding_moe": "level1",
+                             "full_moe": "level4"}
+    # five flash forwards, not ten; five dQ and five dK/dV calls
+    calls = [name for name, _ in _mosaic_calls(compiled.as_text())]
+    assert [sum(n == name for n in calls) for name in (
+        "flash_fwd", "flash_win_fwd", "flash_bwd_dq", "flash_win_bwd_dq")
+            ] == [2, 1, 2, 1]      # the three sliding layers are one scan
     # (inside the held rows' backward pass jax names megablox's calls
     # after the transformation it traced them under)
     names = {name for name, _ in _mosaic_calls(compiled.as_text())}
@@ -288,16 +321,18 @@ def test_lfm2_cell_step_compiles_within_a_v5e_chip(one_chip,
     lfm2-8b-a1b-c1.json``), built by the cell's own ``make_step``: adamw
     on ``trainable(params)``, then the bias update. Mosaic takes the flash
     kernels at a head of 64, the held rows' passes compile, the biases are
-    no part of adamw's state and the program fits 15.75 GiB."""
+    no part of adamw's state, and the program fits what the remat plan
+    reckoned for a v5e's memory."""
     import json
     import sys
 
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     sys.path.insert(0, root)                  # benchmark/ lies beside tests/
     from benchmark.cells import train_hybrid
-    from ray_tpu.models import lfm2
+    from ray_tpu.models import lfm2, llama
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(llama, "_device_capacity", lambda mesh: V5E_LIMIT)
     with open(os.path.join(root, "benchmark", "configs",
                            "lfm2-8b-a1b-c1.json")) as f:
         model, _, cfg = train_hybrid.load_model(json.load(f)["model_config"])
@@ -316,14 +351,15 @@ def test_lfm2_cell_step_compiles_within_a_v5e_chip(one_chip,
         == 2 * (893_696_256 - 4 * 32) + 2          # two moments, two counts
     batch = {"tokens": jax.ShapeDtypeStruct((2, 8193), jnp.int32,
                                             sharding=one_chip)}
-    compiled = jax.jit(train_hybrid.make_step(lfm2, cfg, tx),
-                       donate_argnums=(0, 1)).lower(
-        _placed(params, one_chip), _placed(opt, one_chip), batch).compile()
-    ma = compiled.memory_analysis()
-    assert ma.argument_size_in_bytes + ma.temp_size_in_bytes \
-        + ma.output_size_in_bytes - ma.alias_size_in_bytes \
-        <= int(15.75 * 2 ** 30)
+    compiled, plan = _planned(lambda: jax.jit(
+        train_hybrid.make_step(lfm2, cfg, tx), donate_argnums=(0, 1)).lower(
+            _placed(params, one_chip), _placed(opt, one_chip), batch))
+    # a convolution keeps nothing of its operator: the dense layer the
+    # two products of its SwiGLU, the routed ones nothing (held experts)
+    assert plan["level"] == {"conv_dense": "level3", "attn_moe": "level4",
+                             "conv_moe": "full"}
     text = compiled.as_text()
+    assert sum(name == "flash_fwd" for name, _ in _mosaic_calls(text)) == 1
     assert {name for name, _ in _mosaic_calls(text)} == {
         "flash_fwd", "flash_bwd_dq", "flash_bwd_dkv", "gmm",
         "jvp_jit_gmm__", "jvp_jit_tgmm__"}

@@ -480,34 +480,35 @@ def test_lfm2_train_step_names_its_scopes_and_the_bias_update():
 @pytest.mark.parametrize("model", ["llama", "olmoe", "mixtral"])
 def test_a_plan_of_full_is_the_program_of_explicit_full(model):
     """``remat_policy="auto"`` where the device reports no memory limit
-    (here), and in a forward that has no plan (OLMoE's, Mixtral's), is
-    "full": the
-    checkpoint names are inert and the step compiles to the text of the
+    (here: llama's plan and OLMoE's), and in a forward that has no plan
+    (Mixtral's), is "full": the checkpoint names are inert, the routed
+    layer's two among them, and the step compiles to the text of the
     explicit policy. A kept level is another program."""
     auto = _strip(_train_step_text(model=model, remat=True))
     assert auto == _strip(_train_step_text(model=model, remat=True,
                                            remat_policy="full"))
     assert _instructions(auto) > 200
-    if model == "llama":
-        assert auto != _strip(_train_step_text(remat=True,
+    if model != "mixtral":
+        assert auto != _strip(_train_step_text(model=model, remat=True,
                                                remat_policy="level4"))
 
 
 def test_remat_auto_passes_where_a_set_policy_is_refused():
-    """The MoE forwards and the pipeline schedule have no plan: they run
+    """Mixtral's forward and the pipeline schedule have no plan: they run
     full remat and refuse a ladder level somebody set, through one helper
     with one message; the default is not refused, and neither is
-    ``scan_layers=False`` (the walker honours it for every caller)."""
+    ``scan_layers=False`` (the walker honours it for every caller). The
+    forwards that have a plan (OLMoE's since PR 33) take a set level."""
     import jax
     import jax.numpy as jnp
 
     from ray_tpu.models import llama, mixtral, olmoe
     from ray_tpu.parallel import MeshSpec, build_mesh
 
-    for cfg in (mixtral.MixtralConfig.tiny(), olmoe.OlmoeConfig.tiny(),
-                olmoe.OlmoeConfig.olmoe_1b_7b(),
+    for cfg in (mixtral.MixtralConfig.tiny(),
+                mixtral.MixtralConfig.tiny(remat=True),
                 mixtral.MixtralConfig.tiny(remat_policy="full"),
-                olmoe.OlmoeConfig.tiny(scan_layers=False)):
+                mixtral.MixtralConfig.tiny(scan_layers=False)):
         assert cfg.remat_policy in ("auto", "full")
         assert llama.remat_level_without_plan(cfg) == (
             "full" if cfg.remat else None)
@@ -527,50 +528,71 @@ def test_remat_auto_passes_where_a_set_policy_is_refused():
         return pytest.raises(ValueError, match="this forward has no plan")
 
     for policy in ("level1", "level4"):
-        for mod, cls in ((mixtral, mixtral.MixtralConfig),
-                         (olmoe, olmoe.OlmoeConfig)):
-            moe = cls.tiny(remat_policy=policy)
-            with refused():
-                jax.eval_shape(lambda p, t: mod.forward(moe, p, t),
-                               mod.init_params(moe, jax.random.PRNGKey(0)),
-                               tokens)
+        moe = mixtral.MixtralConfig.tiny(remat_policy=policy)
+        with refused():
+            jax.eval_shape(lambda p, t: mixtral.forward(moe, p, t),
+                           mixtral.init_params(moe, jax.random.PRNGKey(0)),
+                           tokens)
         with refused():
             llama.loss_fn_pp(llama.LlamaConfig.tiny(remat_policy=policy),
                              None, batch, mesh, 2)
+        planned = olmoe.OlmoeConfig.tiny(remat=True, remat_policy=policy)
+        n0 = len(_mine("rtpu.train.remat_plan"))
+        jax.eval_shape(lambda p, t: olmoe.forward(planned, p, t),
+                       olmoe.init_params(planned, jax.random.PRNGKey(0)),
+                       tokens)
+        (ev,) = _mine("rtpu.train.remat_plan")[n0:]
+        assert ev["args"]["level"] == policy
 
 
-def test_remat_plan_is_one_kept_span_of_a_traced_program():
-    """Tracing a dense train step writes its remat plan once, as a kept
-    span (no flag, no profiler window), with what it chose and why."""
+@pytest.mark.parametrize("model", ["llama", "olmoe", "laguna", "lfm2"])
+def test_remat_plan_is_one_kept_span_of_a_traced_program(model):
+    """Tracing a train step of a planned forward writes its remat plan
+    once, as a kept span (no flag, no profiler window), with what it chose
+    and why: a level and the bytes a layer keeps, by kind where the stack
+    has kinds."""
     import jax
     import jax.numpy as jnp
 
-    from ray_tpu.models import llama
+    from ray_tpu.models import laguna, lfm2, llama, olmoe
 
+    mod, cls = {"llama": (llama, llama.LlamaConfig),
+                "olmoe": (olmoe, olmoe.OlmoeConfig),
+                "laguna": (laguna, laguna.LagunaConfig),
+                "lfm2": (lfm2, lfm2.Lfm2Config)}[model]
     assert not config.task_events_enabled
     batch = {"tokens": jax.ShapeDtypeStruct((2, 33), jnp.int32)}
 
-    def trace(cfg):
+    def trace(**kw):
+        cfg = cls.tiny(attn_impl="reference", **kw)
+        shapes = jax.eval_shape(lambda k: mod.init_params(cfg, k),
+                                jax.random.PRNGKey(0))
         n0 = len(_mine("rtpu.train.remat_plan"))
-        jax.eval_shape(jax.grad(lambda p, b: llama.loss_fn(cfg, p, b)),
-                       llama.init_shapes(cfg), batch)
-        return _mine("rtpu.train.remat_plan")[n0:]
+        jax.eval_shape(jax.grad(lambda p, b: mod.loss_fn(cfg, p, b)),
+                       shapes, batch)
+        return shapes, _mine("rtpu.train.remat_plan")[n0:]
 
-    assert trace(llama.LlamaConfig.tiny(attn_impl="reference")) == []
-    (ev,) = trace(llama.LlamaConfig.tiny(attn_impl="reference", remat=True))
+    assert trace()[1] == []
+    shapes, (ev,) = trace(remat=True)
     args = dict(ev["args"])
     need = args.pop("need_bytes")
     assert args.pop("self_us") >= 0
-    assert args == {"id": None, "parent": None, "level": "full",
-                    "saved_bytes_per_layer": 0, "capacity_bytes": None,
-                    "layers": 2}
+    pattern = getattr(cls.tiny(), "pattern", None)
+    kinds = dict.fromkeys(pattern) if pattern else None
+
+    def by_kind(one, of=lambda kind: None):
+        return {k: of(k) or one for k in kinds} if kinds else one
+
+    assert args == {"id": None, "parent": None, "level": by_kind("full"),
+                    "saved_bytes_per_layer": by_kind(0),
+                    "capacity_bytes": None,
+                    "layers": by_kind(2, pattern.count if pattern else None)}
     # at least the four copies of the parameters the train state holds
-    assert need > 4 * 4 * llama.num_params(llama.init_shapes(
-        llama.LlamaConfig.tiny()))
-    (ev,) = trace(llama.LlamaConfig.tiny(attn_impl="reference", remat=True,
-                                         remat_policy="level3"))
-    assert ev["args"]["level"] == "level3"
-    assert ev["args"]["saved_bytes_per_layer"] > 0
+    assert need > 4 * 4 * llama.num_params(shapes)
+    _, (ev,) = trace(remat=True, remat_policy="level3")
+    assert ev["args"]["level"] == by_kind("level3")
+    saved = ev["args"]["saved_bytes_per_layer"]
+    assert all(v > 0 for v in (saved.values() if kinds else [saved]))
 
 
 def test_flash_tiles_is_one_kept_span_of_a_traced_call():
