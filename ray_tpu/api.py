@@ -203,7 +203,9 @@ def timeline(filename: Optional[str] = None):
     RTPU_TASK_EVENTS_ENABLED=1 flag; returns the event list when no
     filename is given. Spans of a worker process are fetched by running
     ``tracing.chrome_events`` there (``JaxTrainer.fit`` does, and writes
-    the gang's to ``trace_spans.json``)."""
+    the gang's to ``trace_spans.json``): among them each program's
+    trace, lowering and compile (``rtpu.jax.*``, with the function's
+    name and whether the persistent cache had it)."""
     import json
 
     core = runtime_context.get_core()

@@ -30,6 +30,7 @@ from ray_tpu.core.object_ref import ObjectRef
 from ray_tpu.core import runtime_context
 from ray_tpu.core.object_store.store import ShmObjectStore
 from ray_tpu.exceptions import ObjectStoreFullError, TaskError
+from ray_tpu.util import tracing
 from ray_tpu.util.debug_lock import make_lock
 
 
@@ -776,7 +777,13 @@ class WorkerCore:
                 _signal.signal(
                     _signal.SIGTERM,
                     lambda signum, frame: self.preempted.set())
+            # a worker never imports jax itself; where the actor's class
+            # brought it in (its module's imports have run by now) or its
+            # constructor does, the programs this process compiles from
+            # here on are spans (a no-op without jax, and the second time)
+            tracing.watch_jax()
             instance = cls(*args, **kwargs)
+            tracing.watch_jax()
             self._actors[actor_id_b] = instance
             mc = int(opts.get("max_concurrency") or 1)
             if mc > 1:

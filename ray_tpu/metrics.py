@@ -25,6 +25,9 @@ def _span_totals() -> Dict[str, float]:
     out: Dict[str, float] = {}
     for name, acc in tracing.totals().items():
         key = name.replace(".", "_").replace("-", "_")
+        if isinstance(acc, int):        # jax_cache_hits, jax_cache_misses
+            out[key] = acc
+            continue
         out[key + "_count"] = acc["count"]
         out[key + "_seconds_total"] = acc["total_ns"] / 1e9
     return out

@@ -67,6 +67,10 @@ class LLMEngine:
 
         from ray_tpu.models import llama, llama_decode
 
+        # _precompile's programs, and any a request compiles later, are
+        # spans of timeline()
+        tracing.watch_jax()
+
         cfg_kw = dict(model_config or {})
         hf_model = cfg_kw.pop("hf_model", None)
         preset = cfg_kw.pop("preset", "tiny")
@@ -376,7 +380,9 @@ class LLMEngine:
     def timeline(self) -> List[dict]:
         """The spans this process kept (chrome-trace form): everything
         of a profiler window, or since start-up under the
-        ``task_events_enabled`` flag."""
+        ``task_events_enabled`` flag; always, each program's trace,
+        lowering and compile (``rtpu.jax.*``: ``_precompile``'s, and
+        any a request made later)."""
         return tracing.chrome_events()
 
     def _note_error(self, where: str, exc: BaseException) -> None:

@@ -103,6 +103,8 @@ def _setup_jax_platform(platform: Optional[str], n_cpu_devices: int):
         jax.config.update("jax_platforms", "tpu")
         with tracing.span("rtpu.backend.devices", keep=True):
             jax.devices()
+    # every program the train function compiles from here on is a span
+    tracing.watch_jax()
 
 
 def _pick_coordinator(port: int) -> str:

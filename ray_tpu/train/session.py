@@ -289,8 +289,10 @@ def report(metrics: Dict[str, Any], checkpoint: Optional[Checkpoint] = None,
     if checkpoint is not None and checkpoint_dir is None:
         checkpoint_dir = checkpoint.path
     # persisting the checkpoint and waiting for the driver to take the
-    # result: the time a train loop stands still in a report
-    with tracing.span("rtpu.train.report", id=s.context.trial_name):
+    # result: the time a train loop stands still in a report. A session's
+    # first is kept: an ``rtpu.jax.compile`` after it is a recompile
+    with tracing.span("rtpu.train.report", id=s.context.trial_name,
+                      keep=s._reports == 0):
         s.report(metrics, checkpoint_dir=checkpoint_dir)
 
 

@@ -26,6 +26,15 @@ trace. Durations come from ``time.monotonic_ns``; each process holds one
 (wall, monotonic) anchor so that the spans of several processes line up
 on the wall clock. Counters are not kept here: they are plain integers on
 the object that owns the work, exported by its ``stats()``.
+
+What jax does before a program first runs is recorded too, once a
+process that has imported jax calls ``watch_jax()``: every trace,
+lowering and backend compile (or fetch from the persistent cache) that
+``jax.monitoring`` reports becomes an event ``rtpu.jax.trace``,
+``rtpu.jax.lower`` or ``rtpu.jax.compile`` with the function's name, so
+a timeline shows which program compiled when, and whether the cache had
+it. The listeners run only when jax compiles: a warmed-up step pays
+nothing.
 """
 
 from __future__ import annotations
@@ -47,6 +56,28 @@ _totals: Dict[str, List[int]] = {}          # name -> [count, total ns]
 _ring: "collections.deque[tuple]" = collections.deque(maxlen=RING_EVENTS)
 _kept: "collections.deque[tuple]" = collections.deque(maxlen=4096)
 _profiling = False
+
+# jax's own events (``jax/_src/dispatch.py``, ``compiler.py``) and what
+# they become here; a jax event shorter than JAX_KEEP_S counts in the
+# totals alone (a step's trace holds hundreds of inner jits of a few
+# microseconds each)
+_JAX_SPANS = {
+    "/jax/core/compile/jaxpr_trace_duration": "rtpu.jax.trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "rtpu.jax.lower",
+    "/jax/core/compile/backend_compile_duration": "rtpu.jax.compile",
+}
+_JAX_CACHE_EVENTS = {
+    "/jax/compilation_cache/compile_requests_use_cache": "miss",
+    "/jax/compilation_cache/cache_hits": "hit",
+}
+_JAX_CACHE_DURATIONS = {
+    "/jax/compilation_cache/cache_retrieval_time_sec": "retrieval_s",
+    "/jax/compilation_cache/compile_time_saved_sec": "saved_s",
+}
+JAX_KEEP_S = 1e-3
+_watch_lock = threading.Lock()
+_watching = False
+_jax_cache = {"hit": 0, "miss": 0}           # compiles by the cache's answer
 
 
 def _events_on() -> bool:
@@ -109,10 +140,113 @@ def mark(name: str, *, id: Optional[str] = None, **attrs: Any) -> None:
         pass
 
 
-def totals() -> Dict[str, Dict[str, int]]:
-    """Per-name accumulators since the process started."""
-    return {k: {"count": v[0], "total_ns": v[1]}
-            for k, v in list(_totals.items())}
+def record(name: str, start_wall_s: float, end_wall_s: float, *, keep: bool,
+           id: Optional[str] = None, **attrs: Any) -> None:
+    """An event whose start and end (wall-clock seconds) are known only
+    after the fact, as jax reports its compiles. It counts in the
+    accumulators, is kept by the rule of a ``span`` and leaves through
+    ``chrome_events()`` in the same form; its parent is the span open on
+    this thread, whose child time it adds to. Records that lie inside a
+    later one on the same thread (an inner jit's trace inside the
+    outer's) are that one's children: their time is credited once. No
+    ``TraceAnnotation``: the time has passed."""
+    t0 = _ANCHOR_NS + int((start_wall_s - _ANCHOR_WALL) * 1e9)
+    dur = max(0, int((end_wall_s - start_wall_s) * 1e9))
+    stack = getattr(_tls, "stack", None)
+    parent = stack[-1] if stack else None
+    if id is None and parent is not None:
+        id = parent.id
+    done = getattr(_tls, "recorded", None)   # (start, dur), none nested
+    if done is None:
+        done = _tls.recorded = []
+    inner = 0
+    while done and done[-1][0] >= t0:
+        inner += done.pop()[1]
+    inner = min(inner, dur)
+    done.append((t0, dur))
+    if len(done) > 64:                       # nothing will enclose these
+        del done[:32]
+    acc = _totals.get(name)
+    if acc is None:
+        acc = _totals.setdefault(name, [0, 0])
+    acc[0] += 1
+    acc[1] += dur
+    if parent is not None:
+        parent._child_ns += dur - inner
+    if keep or _events_on():
+        (_kept if keep else _ring).append((
+            name, t0, dur, dur - inner, threading.get_ident(),
+            parent.name if parent is not None else None, id, attrs))
+
+
+def _on_jax_event(event: str, **_kw: Any) -> None:
+    # the cache's events fire inside the backend compile's span, on its
+    # thread, and are folded into it when it closes
+    state = _JAX_CACHE_EVENTS.get(event)
+    if state is not None:
+        _tls.jax_cache = {"cache": state}
+
+
+def _on_jax_duration(event: str, duration_s: float, **_kw: Any) -> None:
+    key = _JAX_CACHE_DURATIONS.get(event)
+    if key is not None:
+        cache = getattr(_tls, "jax_cache", None)
+        if cache is not None:
+            cache[key] = duration_s
+
+
+def _on_jax_span(event: str, start_s: float, end_s: float,
+                 fun_name: str = "", **_kw: Any) -> None:
+    name = _JAX_SPANS.get(event)
+    if name is None:
+        return
+    attrs = {"fun": fun_name}
+    if name == "rtpu.jax.compile":
+        cache = getattr(_tls, "jax_cache", None)
+        if cache is None:
+            attrs["cache"] = "off"
+        else:
+            _tls.jax_cache = None
+            _jax_cache[cache["cache"]] += 1
+            attrs.update(cache)
+    record(name, start_s, end_s, keep=end_s - start_s >= JAX_KEEP_S, **attrs)
+
+
+def watch_jax() -> bool:
+    """Listen to ``jax.monitoring`` in this process from now on; call it
+    once jax is imported (the train backend, the serving engines and the
+    worker that creates an actor do). Idempotent; a process that has not
+    imported jax registers nothing and gets False.
+
+    ``rtpu.jax.compile`` carries ``cache``: ``"hit"`` (with
+    ``retrieval_s``, the fetch and load, and ``saved_s``, the compile
+    time the entry remembers minus the fetch), ``"miss"`` (the
+    persistent cache was asked and had not the program) or ``"off"`` (it
+    was not asked)."""
+    global _watching
+    if "jax" not in sys.modules:
+        return False
+    with _watch_lock:
+        if not _watching:
+            from jax import monitoring
+
+            monitoring.register_event_listener(_on_jax_event)
+            monitoring.register_event_duration_secs_listener(_on_jax_duration)
+            monitoring.register_event_time_span_listener(_on_jax_span)
+            _watching = True
+    return True
+
+
+def totals() -> Dict[str, Any]:
+    """Per-name accumulators since the process started and, where
+    ``watch_jax`` listens, two plain integers: the programs the
+    persistent compile cache had and had not."""
+    out: Dict[str, Any] = {k: {"count": v[0], "total_ns": v[1]}
+                           for k, v in list(_totals.items())}
+    if _watching:
+        out["jax_cache_hits"] = _jax_cache["hit"]
+        out["jax_cache_misses"] = _jax_cache["miss"]
+    return out
 
 
 def chrome_events() -> List[Dict[str, Any]]:

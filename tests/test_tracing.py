@@ -81,10 +81,140 @@ def test_span_module_never_imports_jax():
             "with tracing.span('a', keep=True):\n"
             "    pass\n"
             "assert tracing.chrome_events()[0]['name'] == 'a'\n"
+            # without jax there is nothing to watch, and nothing is served
+            "assert tracing.watch_jax() is False\n"
+            "assert set(tracing.totals()) == {'a'}\n"
             "assert 'jax' not in sys.modules, 'jax imported'\n")
     p = subprocess.run([sys.executable, "-c", code], cwd=REPO,
                        capture_output=True, text=True, timeout=120)
     assert p.returncode == 0, p.stderr[-2000:]
+
+
+def test_record_obeys_the_kept_rule_and_nests_under_the_open_span():
+    assert not config.task_events_enabled
+    now = time.time()
+    with tracing.span("t7.outer", id="run-3", keep=True) as outer:
+        time.sleep(0.05)
+        end = time.time()
+        # as jax reports them: the inner one first, then the one around it
+        tracing.record("t7.inner", end - 0.03, end - 0.01, keep=True, fun="g")
+        tracing.record("t7.around", end - 0.04, end, keep=True, fun="f")
+        tracing.record("t7.short", end - 0.0005, end, keep=False)
+    ev = {e["name"]: e for e in _mine("t7.")}
+    assert set(ev) == {"t7.outer", "t7.inner", "t7.around"}   # not the short
+    inner, around, o = ev["t7.inner"], ev["t7.around"], ev["t7.outer"]
+    assert inner["args"]["parent"] == around["args"]["parent"] == "t7.outer"
+    assert inner["args"]["id"] == "run-3" and inner["args"]["fun"] == "g"
+    assert inner["ph"] == "X" and inner["cat"] == "span"
+    # wall clock in, wall clock out
+    assert abs(around["ts"] - (end - 0.04) * 1e6) < 50
+    assert abs(around["dur"] - 40e3) < 50 and now * 1e6 <= around["ts"]
+    # nested records are credited once: to the one around them, and
+    # through it to the open span
+    assert abs(around["args"]["self_us"] - 20e3) < 50
+    assert abs(o["args"]["self_us"] - (outer.dur_ns / 1e3 - 40e3 - 500)) < 50
+    tot = tracing.totals()
+    assert tot["t7.inner"]["count"] == 1
+    assert abs(tot["t7.inner"]["total_ns"] - 20e6) < 50e3
+    assert tot["t7.short"]["count"] == 1       # the accumulators count it
+
+
+def test_record_goes_to_the_ring_under_the_flag(events_on):
+    end = time.time()
+    tracing.record("t7r.hot", end - 0.001, end, keep=False)
+    assert [e["name"] for e in _mine("t7r.")] == ["t7r.hot"]
+
+
+def _jax_events(fun):
+    return [e for e in tracing.chrome_events()
+            if e["name"].startswith("rtpu.jax.") and fun in e["args"]["fun"]]
+
+
+@pytest.mark.parametrize("how", ["lower_compile", "plain_call"])
+def test_watch_jax_records_each_programs_trace_lowering_and_compile(
+        how, monkeypatch):
+    import jax
+    import jax.numpy as jnp
+    from jax._src import monitoring
+
+    monkeypatch.setattr(tracing, "JAX_KEEP_S", 0.0)   # keep the short ones
+    assert tracing.watch_jax() is True
+    assert tracing.watch_jax() is True                # registers once
+    assert monitoring.get_event_listeners().count(tracing._on_jax_event) == 1
+    assert monitoring.get_event_time_span_listeners().count(
+        tracing._on_jax_span) == 1
+    assert monitoring.get_event_duration_listeners().count(
+        tracing._on_jax_duration) == 1
+
+    def body(x):
+        return (jnp.sin(x) @ x).sum()
+
+    body.__name__ = f"t8_{how}"
+    f, x = jax.jit(body), jnp.ones((16, 16))
+    c0 = tracing.totals()["rtpu.jax.compile"]["count"]
+    with tracing.span("t8.setup", keep=True):
+        if how == "lower_compile":
+            f.lower(x).compile()
+        else:
+            f(x).block_until_ready()
+    got = _jax_events(body.__name__)
+    assert sorted(e["name"] for e in got) == [
+        "rtpu.jax.compile", "rtpu.jax.lower", "rtpu.jax.trace"], got
+    (cache,) = [e["args"]["cache"] for e in got
+                if e["name"] == "rtpu.jax.compile"]
+    assert cache in ("miss", "off")        # conftest gives a fresh cache
+    assert tracing.totals()["rtpu.jax.compile"]["count"] == c0 + 1
+    assert {e["args"]["parent"] for e in got} == {"t8.setup"}
+    # a warmed-up program fires nothing
+    if how == "plain_call":
+        f(x).block_until_ready()
+        assert len(_jax_events(body.__name__)) == 3
+    assert isinstance(tracing.totals()["jax_cache_misses"], int)
+
+
+def test_compile_span_says_whether_the_persistent_cache_had_the_program(
+        tmp_path):
+    code = """
+import json, sys
+import jax, jax.numpy as jnp
+from ray_tpu import metrics
+from ray_tpu.util import tracing
+assert tracing.watch_jax()
+def t9_step(x):
+    return jnp.tanh(x @ x).sum()
+jax.jit(t9_step)(jnp.ones((32, 32))).block_until_ready()
+(ev,) = [e["args"] for e in tracing.chrome_events()
+         if e["name"] == "rtpu.jax.compile" and "t9_step" in e["args"]["fun"]]
+tot = tracing.totals()
+print(json.dumps({"ev": ev, "hits": tot["jax_cache_hits"],
+                  "misses": tot["jax_cache_misses"],
+                  "served": metrics.REGISTRY.render()}))
+"""
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               JAX_COMPILATION_CACHE_DIR=str(tmp_path),
+               JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS="0",
+               JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES="-1")
+    env.pop("JAX_ENABLE_COMPILATION_CACHE", None)
+    runs = []
+    for _ in range(2):
+        p = subprocess.run([sys.executable, "-c", code], cwd=REPO, env=env,
+                           capture_output=True, text=True, timeout=300)
+        assert p.returncode == 0, p.stderr[-3000:]
+        runs.append(json.loads(p.stdout.strip().splitlines()[-1]))
+    first, second = runs
+    assert first["ev"]["cache"] == "miss" and "retrieval_s" not in first["ev"]
+    assert first["hits"] == 0 and first["misses"] >= 1
+    assert second["ev"]["cache"] == "hit"
+    assert second["ev"]["retrieval_s"] > 0 and "saved_s" in second["ev"]
+    assert second["hits"] >= 1 and second["misses"] == 0
+    assert re.search(rf"^rtpu_span_jax_cache_hits {second['hits']}$",
+                     second["served"], re.M)
+    assert re.search(r"^rtpu_span_jax_cache_misses 0$", second["served"],
+                     re.M)
+    assert re.search(r"^rtpu_span_rtpu_jax_compile_count \d+$",
+                     second["served"], re.M)
+    assert re.search(r"^rtpu_span_rtpu_jax_compile_seconds_total [\d.e-]+$",
+                     second["served"], re.M)
 
 
 def test_span_lies_on_the_profilers_host_plane(tmp_path):
@@ -157,8 +287,13 @@ from ray_tpu import train
 from ray_tpu.train import JaxConfig, JaxTrainer, RunConfig, ScalingConfig
 
 def loop(config):
+    import jax, jax.numpy as jnp
     from ray_tpu.util import tracing
+    tracing.JAX_KEEP_S = 0.0        # a tiny program's trace is microseconds
+    def t6_step(x):
+        return (x * 2).sum()
     with tracing.span("t6.in_worker", keep=True):
+        jax.jit(t6_step)(jnp.ones(8)).block_until_ready()
         train.report({{"x": 1.0}})
 
 ray_tpu.init(num_workers=2)
@@ -199,6 +334,22 @@ assert "jax" not in sys.modules
     assert start["pid"] not in {e["pid"] for e in workers}
     assert all(end(start) <= e["ts"] + 1e5 and end(e) <= by[
         "rtpu.train.shutdown"][0]["ts"] + 1e5 for e in workers)
+    # the backend watched jax before the train function ran: each
+    # worker's program is there, traced, lowered and compiled, inside the
+    # span that was open and before the session's first (kept) report
+    for w in workers:
+        mine = [e for e in ev if e["pid"] == w["pid"]
+                and "t6_step" in e["args"].get("fun", "")]
+        assert sorted(e["name"] for e in mine) == [
+            "rtpu.jax.compile", "rtpu.jax.lower", "rtpu.jax.trace"], mine
+        assert all(e["args"]["parent"] == "t6.in_worker" and w["ts"] <=
+                   e["ts"] and end(e) <= end(w) for e in mine)
+        assert mine[-1]["args"]["cache"] in ("hit", "miss", "off")
+        (report,) = [e for e in by["rtpu.train.report"]
+                     if e["pid"] == w["pid"]]
+        assert max(end(e) for e in mine) <= report["ts"]
+    assert start["pid"] not in {e["pid"] for e in ev
+                                if e["name"].startswith("rtpu.jax.")}
 
 
 def _generate(eng, n, tag):
@@ -278,6 +429,12 @@ def test_engine_trace_hook_and_request_ids(paged_engine, tmp_path):
     before = len(eng.timeline())
     _generate(eng, 1, "untraced")
     assert len(eng.timeline()) == before        # the ring is off again
+    # the engine watched jax from its constructor on: the programs it
+    # compiled before any request are on the timeline by name
+    compiled = {e["args"]["fun"] for e in eng.timeline()
+                if e["name"] == "rtpu.jax.compile"}
+    assert any("prefill" in f for f in compiled), compiled
+    assert any("decode" in f for f in compiled), compiled
 
 
 def test_metrics_registry_serves_the_owners_counters():
