@@ -39,8 +39,8 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.models import llama, mixtral, olmoe
+from ray_tpu.ops import moe
 from ray_tpu.ops.layers import rms_norm, rope_frequencies, swiglu
-from ray_tpu.ops.moe import routed_experts_on
 
 # kind -> (sliding window attention, routed MLP)
 LAYER_KINDS = {"full_dense": (False, False), "sliding_dense": (True, False),
@@ -205,7 +205,7 @@ def _layer(cfg: LagunaConfig, kind: str, x, p, cos, sin, mesh=None,
         with jax.named_scope("moe_shared"):
             shared = swiglu(h2, p["s_gate"].astype(dt), p["s_up"].astype(dt),
                             p["s_down"].astype(dt))
-        out, logits, counts = routed_experts_on(
+        out, logits, counts = moe.routed_experts_on(
             mesh, h2, p["router"], p["e_gate"], p["e_up"], p["e_down"],
             cfg.top_k, renormalize=True, held=cfg.experts_held,
             scale=cfg.routed_scale)
@@ -281,6 +281,13 @@ def rows_held(cfg: LagunaConfig, expert_counts) -> Any:
     (the ``moe_rows_held`` counter; all of them where all are held)."""
     first, count = cfg.experts_held or (0, cfg.num_experts)
     return expert_counts[:, first:first + count].sum()
+
+
+def rows_passed(cfg: LagunaConfig, expert_counts) -> int:
+    """Of ``expert_counts [Lr, E]`` on the host, the rows the passes over
+    the held experts' rows took (the ``moe_rows_passed`` counter,
+    ``ops/moe.rows_passed``); ``rows_held`` over it is the passes' fill."""
+    return moe.rows_passed(expert_counts, cfg.experts_held)
 
 
 def param_shardings(cfg: LagunaConfig, mesh):

@@ -40,9 +40,9 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.models import llama, mixtral
+from ray_tpu.ops import moe
 from ray_tpu.ops.conv import gated_short_conv
 from ray_tpu.ops.layers import rms_norm, rope_frequencies, swiglu
-from ray_tpu.ops.moe import routed_experts_on
 
 # kind -> (the operator is attention, the MLP is routed); the published
 # stack has no attention layer among its dense ones
@@ -258,7 +258,7 @@ def _layer(cfg: Lfm2Config, kind: str, x, p, cos, sin, mesh=None,
         if not routed:
             return x + swiglu(h2, p["w_gate"].astype(dt), p["w_up"].astype(dt),
                               p["w_down"].astype(dt)), None
-        out, logits, counts, *chosen = routed_experts_on(
+        out, logits, counts, *chosen = moe.routed_experts_on(
             mesh, h2, p["router"], p["e_gate"], p["e_up"], p["e_down"],
             cfg.top_k, renormalize=True, select_bias=p["router_bias"],
             held=cfg.experts_held, scale=cfg.routed_scale, score="sigmoid",
@@ -325,6 +325,13 @@ def rows_held(cfg: Lfm2Config, expert_counts) -> Any:
     (the ``moe_rows_held`` counter; all of them where all are held)."""
     first, count = cfg.experts_held or (0, cfg.num_experts)
     return expert_counts[:, first:first + count].sum()
+
+
+def rows_passed(cfg: Lfm2Config, expert_counts) -> int:
+    """Of ``expert_counts [Lr, E]`` on the host, the rows the passes over
+    the held experts' rows took (the ``moe_rows_passed`` counter,
+    ``ops/moe.rows_passed``); ``rows_held`` over it is the passes' fill."""
+    return moe.rows_passed(expert_counts, cfg.experts_held)
 
 
 def param_shardings(cfg: Lfm2Config, mesh):

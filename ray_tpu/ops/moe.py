@@ -37,12 +37,18 @@ are sorted with the held experts' first; what follows them is never
 gathered or multiplied. How many rows that is, is data, and a buffer for
 the worst case (every row routed here) would be ``n * top_k`` rows wide,
 a gigabyte at Laguna's cell: the held rows are taken in passes of a
-static ``chunk`` of rows (twice the balanced share), as many passes as
-the rows need, each a gather, three grouped matmuls over the pass's
-groups and a scatter-add into the tokens' sums. One pass at a balanced
-routing, none where no row is held; no routing, however uneven, drops a
-row or compiles anything, and the grouped matmuls touch the row tiles
-the pass's groups fill and no other: the work follows the rows held.
+static ``chunk`` of rows (``_held_chunk``: the balanced share and an
+eighth of it), as many passes as the rows need, each a gather, three
+grouped matmuls over the pass's groups and a scatter-add into the
+tokens' sums. One pass at a balanced routing and up to an eighth over
+it, none where no row is held; no routing, however uneven, drops a row
+or compiles anything. The grouped matmuls touch the row tiles the
+pass's groups fill and no other; the gathers, the activation's pass,
+the masks and the scatter-adds run over all ``chunk`` rows of a pass,
+held or padding, which is why a pass is no wider than that
+(``_HELD_HEADROOM``). The plan of a traced layer is one kept span,
+``rtpu.moe.held_pass`` (pairs, count, num_experts, balanced_share,
+chunk); ``rows_passed`` counts a step's passes from its expert counts.
 The loop's trip count is data, so its gradient is written out
 (``_held_experts``): the same passes, each the transpose of its forward.
 
@@ -60,7 +66,10 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.ad_checkpoint import checkpoint_name
+
+from ray_tpu.util import tracing
 
 
 @partial(jax.custom_vjp, nondiff_argnums=(3,))
@@ -220,11 +229,44 @@ def _swiglu_rows(rows, w_rows, sizes, e_gate, e_up, e_down):
     return grouped_matmul(act.astype(dt), e_down.astype(dt), sizes)
 
 
+# A pass of the held experts' rows takes their balanced share and one part
+# in this many of it. From a sweep of the op alone on v5e (forward and
+# backward, 16,384 tokens, PR 35), the pass's rows a variable: at LFM2's
+# widths (16 of 32 experts of 1792, 4 a token) 4.0 ms + 3.4 ms a pass +
+# 0.33 us a row of the pass + 0.43 us a held row, at Laguna's (16 of 256
+# of 1024, 10 a token) 6.2 + 5.3 + 0.39 + 0.39: a pass costs as much to
+# start as 10,000-13,000 of its rows, so one pass with headroom beats
+# several under the share (a quarter of the share: 42.6 ms for 34.0 at
+# balance), and a padding row costs what a held one does outside the
+# kernels, so twice the share read 42.7 (LFM2) and 23.5 (Laguna). Over held
+# rows of 0.9, 1.0 and 1.1 times the share an eighth was the cheapest of
+# 0, 1/16, 1/8, 3/16, 1/4, 1/2 and 1 at both: 33.9 and 20.2 ms (1/16 pays
+# a second pass at +10%: 38.4; 1/4 reads 35.2).
+_HELD_HEADROOM = 8
+
+
 def _held_chunk(num_pairs: int, count: int, num_experts: int) -> int:
-    """Rows a pass of the held experts takes: twice their balanced share
-    of the ``num_pairs`` (token, choice) pairs, in whole row tiles."""
-    share = -(-2 * num_pairs * count // num_experts)
-    return min(-(-share // _ROW_TILE), -(-num_pairs // _ROW_TILE)) * _ROW_TILE
+    """Rows a pass of the held experts takes: their balanced share of the
+    ``num_pairs`` (token, choice) pairs and an eighth of it
+    (``_HELD_HEADROOM``), in whole row tiles, at most all the pairs."""
+    rows = -(-num_pairs * count * (_HELD_HEADROOM + 1)
+             // (num_experts * _HELD_HEADROOM))
+    return min(-(-rows // _ROW_TILE), -(-num_pairs // _ROW_TILE)) * _ROW_TILE
+
+
+def rows_passed(expert_counts, held: Optional[Tuple[int, int]]) -> int:
+    """Of ``expert_counts [Lr, E]`` on the host, the rows the passes over
+    the ``held`` experts' rows took: each layer's held rows in whole
+    passes of ``_held_chunk`` rows (the ``moe_rows_passed`` counter; the
+    held rows over it is how full the passes were). ``held=None`` has no
+    passes and multiplies every row once."""
+    counts = np.asarray(expert_counts)
+    if held is None:
+        return int(counts.sum())
+    first, count = held
+    chunk = _held_chunk(int(counts[0].sum()), count, counts.shape[-1])
+    rows = counts[:, first:first + count].sum(-1)
+    return int((-(-rows // chunk) * chunk).sum())
 
 
 def _held_passes(sizes, chunk: int):
@@ -342,6 +384,12 @@ def routed_experts(x: jax.Array, router_w: jax.Array, e_gate: jax.Array,
             local = flat_e - first
             key = jnp.where((local >= 0) & (local < count), local, count)
             chunk = _held_chunk(flat_e.size, count, num_experts)
+            with tracing.span("rtpu.moe.held_pass", keep=True,
+                              pairs=flat_e.size, count=count,
+                              num_experts=num_experts,
+                              balanced_share=flat_e.size * count
+                              / num_experts, chunk=chunk):
+                pass
             order = jnp.pad(jnp.argsort(key, stable=True).astype(jnp.int32),
                             (0, chunk))
             out = _held_experts(

@@ -74,10 +74,12 @@ class TrainContext:
 # what a routed-experts train loop may put into ``train.report``: the
 # session serves the last value of each as ``rtpu_train_<key>``
 # (``moe_rows_held``: of the routed rows, those the experts held here
-# multiplied, where a layer holds a share of its experts)
+# multiplied, where a layer holds a share of its experts;
+# ``moe_rows_passed``: the rows the passes over those took, padding and
+# all, ``ops/moe.rows_passed``: held over passed is how full they were)
 # (``moe_router_bias_abs_max``: the largest selection bias of a router
 # that balances its load by one, ``models/lfm2.update_router_bias``)
-MOE_COUNTERS = ("moe_rows_routed", "moe_rows_held",
+MOE_COUNTERS = ("moe_rows_routed", "moe_rows_held", "moe_rows_passed",
                 "moe_expert_load_max_over_mean", "moe_router_bias_abs_max")
 
 

@@ -576,6 +576,36 @@ def test_lfm2_forward_and_loss_match_the_reference(lfm2_setup):
     assert float(loss) == float(terms["cross_entropy"])
 
 
+@pytest.mark.parametrize("model, cell, tokens, chunk", [
+    ("laguna", "laguna-s-2.1-c1", 16384, 11520),
+    ("lfm2", "lfm2-8b-a1b-c1", 16384, 36864)])
+@pytest.mark.parametrize("held_rows, passes", [
+    ("none", 0), ("chunk", 1), ("chunk+1", 2), ("all", None)])
+def test_rows_passed_counts_whole_passes_a_layer(model, cell, tokens, chunk,
+                                                 held_rows, passes):
+    """``rows_passed`` (the ``moe_rows_passed`` counter) from hand-made
+    counts at a cell's shapes, two routed layers, the second balanced
+    (one pass): no held row in the first is no pass, exactly a chunk
+    one, one row more two, every pair as many as hold them; held over
+    passed is how full the passes were. With every expert here nothing
+    is passed: the rows routed."""
+    from dataclasses import replace
+
+    mod, cfg = _cell_config(cell)
+    E, pairs = cfg.num_experts, tokens * cfg.top_k
+    first, count = cfg.experts_held
+    held = {"none": 0, "chunk": chunk, "chunk+1": chunk + 1,
+            "all": pairs}[held_rows]
+    counts = np.zeros((2, E), np.int64)
+    counts[0, first], counts[0, first + count] = held, pairs - held
+    counts[1] = pairs // E
+    want = (-(-pairs // chunk) if passes is None else passes) + 1
+    assert mod.rows_passed(cfg, counts) == want * chunk
+    assert int(mod.rows_held(cfg, counts)) == held + pairs * count // E
+    assert mod.rows_passed(replace(cfg, experts_held=None), counts) == \
+        2 * pairs
+
+
 def test_lfm2_gradients_match_the_reference(lfm2_setup):
     """Every trained leaf's gradient against the reference's; the bias
     has none."""
@@ -1383,16 +1413,54 @@ def _cell_config(name):
 # device, the levels, the bytes a layer of each kind keeps
 _SHIPPED_PLANS = {
     "olmoe-1b-7b-c1": (8192, "level1", 34078720),
+    # since PR 35 a pass of the held rows is 11,520 rows for 20,480, the
+    # routed kinds' working set 0.22 GB less, and layer 0 takes its fourth
+    # rung (``attn_resid``, 0.10 GB; level 3 and 1,278,214,144 before)
     "laguna-s-2.1-c1": (16384,
-                        {"full_dense": "level3", "sliding_moe": "level1",
+                        {"full_dense": "level4", "sliding_moe": "level1",
                          "full_moe": "level4"},
-                        {"full_dense": 1278214144, "sliding_moe": 306708480,
+                        {"full_dense": 1378877440, "sliding_moe": 306708480,
                          "full_moe": 640679936}),
     "lfm2-8b-a1b-c1": (16384,
                        {"conv_dense": "level3", "attn_moe": "level4",
                         "conv_moe": "full"},
                        {"conv_dense": 469762048, "attn_moe": 236978176,
                         "conv_moe": 0})}
+
+
+@pytest.mark.parametrize("stack, chunk, kinds", [
+    ("laguna-s-2.1-c1", 11520, ("sliding_moe", "full_moe")),
+    ("lfm2-8b-a1b-c1", 36864, ("attn_moe", "conv_moe"))])
+def test_a_held_kinds_working_set_is_reckoned_from_a_pass(stack, chunk,
+                                                          kinds):
+    """``describe_stack`` counts for a kind that holds a share of its
+    experts the rows of one pass (``ops/moe._held_chunk``: the balanced
+    share and an eighth, where it was twice the share) and the two
+    float32 ``[T, h]`` sums the passes add into; from shapes alone, at
+    the cells' widths."""
+    from ray_tpu.ops import moe
+
+    mod, cfg = _cell_config(stack)
+    tokens = _SHIPPED_PLANS[stack][0]
+    shapes = jax.eval_shape(lambda k: mod.init_params(cfg, k),
+                            jax.random.PRNGKey(0))
+    described = _stack_of(mod, cfg, shapes["layers"], tokens)["kinds"]
+    h, f = cfg.hidden_size, cfg.moe_intermediate_size
+    pairs, (_, count) = tokens * cfg.top_k, cfg.experts_held
+    assert moe._held_chunk(pairs, count, cfg.num_experts) == chunk
+    a_row = (2 * h + 6 * f) * 2              # bf16: rows, products, theirs
+    for kind in kinds:
+        leaves = {k: jax.ShapeDtypeStruct((1,) + a.shape[1:], a.dtype)
+                  for k, a in shapes["layers"][kind].items()
+                  if not k.startswith("e_") and k != "router"}
+        rest = llama.describe_stack(
+            cfg, {kind: leaves}, tokens, pattern=(kind,))["kinds"][kind]
+        assert described[kind]["working_bytes"] == (
+            rest["working_bytes"] + 2 * tokens * h * 4 + chunk * a_row)
+    # against twice the share: 0.22 GB less in Laguna, 0.85 GB in LFM2
+    twice = min(2 * pairs * count // cfg.num_experts, pairs)
+    assert (twice - chunk) * a_row == {
+        "laguna-s-2.1-c1": 220200960, "lfm2-8b-a1b-c1": 851443712}[stack]
 
 
 @pytest.mark.parametrize("stack", ["dense", *_SHIPPED_PLANS])

@@ -476,14 +476,14 @@ def _held_share(held, x, router_w, e_gate, e_up, e_down, top_k, **kw):
                           held=held, **kw)
 
 
-def _routed_inputs(skewed: bool):
+def _routed_inputs(skewed, toward=(8, 16)):
     n, h, f, E = 96, 32, 48, 16
     ks = jax.random.split(jax.random.PRNGKey(0), 6)
     x = jax.random.normal(ks[0], (n, h))
     router_w = jax.random.normal(ks[1], (h, E))
     if skewed:      # a constant feature the router sends to experts 8..15
         x = x.at[:, 0].set(5.0)
-        router_w = (router_w * 0.01).at[0, 8:].add(10.0)
+        router_w = (router_w * 0.01).at[0, slice(*toward)].add(10.0)
     return (x, router_w, jax.random.normal(ks[2], (E, h, f)) / 6,
             jax.random.normal(ks[3], (E, h, f)) / 6,
             jax.random.normal(ks[4], (E, f, h)) / 7,
@@ -565,16 +565,21 @@ def test_routed_experts_single_expert_is_the_dense_swiglu():
         rtol=1e-5, atol=1e-5)
 
 
-@pytest.mark.parametrize("skewed", [False, True])
-def test_held_experts_match_the_expert_loop(skewed):
+@pytest.mark.parametrize("toward", [None, (8, 16), (4, 12)],
+                         ids=["balanced", "half-held", "all-held"])
+def test_held_experts_match_the_expert_loop(toward):
     """``held=(4, 8)``: the part experts 4..11 of 16 give, forward and
     every gradient (the router's over all 16 outputs, the expert
     matrices' for the eight held) against the loop over those experts,
-    renormalised and scaled as Laguna routes; the skewed router sends
-    every token to experts 8..15, half of them held, in two passes."""
+    renormalised and scaled as Laguna routes. A pass takes 512 of the 768
+    pairs (the share of 384 and an eighth, in row tiles): a balanced
+    router fills a part of one, the skewed one sends every token to
+    experts 8..15, half of them held (384 rows, one pass), and the one
+    skewed to the held experts themselves holds all 768: a pass and a
+    half, which twice the share took in one."""
     from ray_tpu.ops import moe
 
-    *args, cot = _routed_inputs(skewed)
+    *args, cot = _routed_inputs(toward is not None, toward or (8, 16))
     kw = dict(renormalize=True, scale=2.5)
     with jax.default_matmul_precision("highest"):
         out, logits, counts = jax.jit(
@@ -587,9 +592,16 @@ def test_held_experts_match_the_expert_loop(skewed):
             lambda *a: (_experts_by_loop(*a, 8, held=(4, 8), **kw)
                         * cot).sum(), argnums=(0, 1, 2, 3, 4)))(*args)
     assert int(counts.sum()) == 96 * 8 and counts.shape == (16,)
-    if skewed:       # 384 held rows, a pass takes 768
-        assert counts.tolist() == [0] * 8 + [96] * 8
-    assert moe._held_chunk(96 * 8, 8, 16) == 768
+    chunk = moe._held_chunk(96 * 8, 8, 16)
+    assert chunk == 512
+    held_rows = int(counts[4:12].sum())
+    if toward == (8, 16):
+        assert counts.tolist() == [0] * 8 + [96] * 8 and held_rows == 384
+    elif toward == (4, 12):     # between one pass and two
+        assert counts.tolist() == [0] * 4 + [96] * 8 + [0] * 4
+        assert chunk < held_rows == 768 < 2 * chunk
+    else:
+        assert 0 < held_rows < chunk
     np.testing.assert_allclose(np.asarray(logits), np.asarray(
         args[0] @ args[1]), rtol=1e-5, atol=1e-5)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want),
@@ -598,6 +610,59 @@ def test_held_experts_match_the_expert_loop(skewed):
         assert got.shape == ref.shape
         np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                    rtol=1e-4, atol=1e-4)
+
+
+@pytest.mark.parametrize("pairs, count, num_experts, want", [
+    (16384 * 10, 16, 256, 11520),     # train-laguna-1chip: 45 tiles for 80
+    (16384 * 4, 16, 32, 36864),       # train-lfm2-1chip: 144 tiles for 256
+    (96 * 8, 8, 16, 512), (64 * 10, 16, 256, 256),      # the tests above
+    (16384 * 4, 32, 32, 65536),       # all held: every pair and no more
+    (1000, 7, 8, 1024),               # the headroom passes all the pairs
+    (1000, 1, 8, 256), (1000, 3, 16, 256), (100, 1, 64, 256),
+])
+def test_held_chunk_is_the_share_and_a_headroom_in_whole_tiles(
+        pairs, count, num_experts, want):
+    """A pass's static row count from shapes alone: whole row tiles,
+    never under the held experts' balanced share (a balanced routing is
+    one pass), never over all the pairs rounded up to a tile, and well
+    under the twice the share that it was (PERF.md 6, PR 35)."""
+    from ray_tpu.ops import moe
+
+    chunk = moe._held_chunk(pairs, count, num_experts)
+    share = pairs * count / num_experts
+    tile = moe._ROW_TILE
+    assert chunk == want and chunk % tile == 0
+    assert min(share, pairs) <= chunk <= -(-pairs // tile) * tile
+    assert chunk <= max(1.25 * share, share + tile)
+
+
+def test_held_pass_is_one_kept_span_of_a_traced_held_layer():
+    """Tracing a layer that holds a share writes what a pass will take
+    once, as a kept span (no flag, no profiler window): the pairs, the
+    experts held of how many, their balanced share and the chunk. The
+    layer that holds every expert has no passes and writes none."""
+    from ray_tpu.ops import moe
+    from ray_tpu.util import tracing
+
+    def mine():
+        return [e for e in tracing.chrome_events()
+                if e["name"] == "rtpu.moe.held_pass"]
+
+    f32 = jnp.float32
+    shapes = [jax.ShapeDtypeStruct(s, f32) for s in (
+        (96, 32), (32, 16), (8, 32, 48), (8, 32, 48), (8, 48, 32))]
+    n0 = len(mine())
+    jax.eval_shape(jax.grad(lambda *a: moe.routed_experts(
+        *a, 8, held=(4, 8))[0].sum(), argnums=(0, 2)), *shapes)
+    (ev,) = mine()[n0:]
+    assert {k: ev["args"][k] for k in (
+        "pairs", "count", "num_experts", "balanced_share", "chunk")} == {
+        "pairs": 768, "count": 8, "num_experts": 16,
+        "balanced_share": 384.0, "chunk": 512}
+    whole = [jax.ShapeDtypeStruct((16,) + s.shape[1:], f32) if n > 1 else s
+             for n, s in enumerate(shapes)]
+    jax.eval_shape(lambda *a: moe.routed_experts(*a, 8)[0], *whole)
+    assert len(mine()) == n0 + 1
 
 
 def _laguna_routed_layer():
@@ -701,7 +766,8 @@ def test_held_experts_drop_no_row_and_compile_nothing_whatever_the_routing():
     cfg, laguna_ref, p, u = _laguna_routed_layer()
     held = (32, 16)
     weights = [p[k][32:48] for k in ("e_gate", "e_up", "e_down")]
-    assert moe._held_chunk(64 * 10, 16, 256) == 256     # 640 rows: 3 passes
+    # the share of 40 rows and an eighth, one row tile; 640 rows: 3 passes
+    assert moe._held_chunk(64 * 10, 16, 256) == 256
 
     @jax.jit
     def layer(u, router):
@@ -753,8 +819,8 @@ def test_routed_experts_tpu_path_in_interpret_mode(monkeypatch, held):
     """What a TPU runs: the megablox kernels behind ``grouped_matmul``'s
     own transposes, here through the Pallas interpreter (768 rows, three
     tiles of 256, groups that end inside a tile, eight empty groups); and
-    with half the experts held, the passes over the held rows (one of 768
-    rows: the kernels write no row past the pass's groups)."""
+    with half the experts held, the passes over the held rows (one of 512
+    rows, two tiles: the kernels write no row past the pass's groups)."""
     from functools import partial
 
     from ray_tpu.ops import moe

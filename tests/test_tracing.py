@@ -466,7 +466,8 @@ def test_metrics_registry_serves_the_owners_counters():
 
 def test_train_session_serves_the_last_reported_moe_counters():
     """``rtpu_train_moe_*``: the last ``moe_rows_routed``,
-    ``moe_rows_held`` (where a layer holds a share of its experts),
+    ``moe_rows_held`` (where a layer holds a share of its experts) and
+    ``moe_rows_passed`` (the rows its passes took),
     ``moe_expert_load_max_over_mean`` and ``moe_router_bias_abs_max`` (a
     router balanced by a bias) a loop put into ``train.report``; a loop
     that reports neither serves neither."""
@@ -479,7 +480,7 @@ def test_train_session_serves_the_last_reported_moe_counters():
         train.report({"loss": 0.9, "moe_rows_routed": 196608,
                       "moe_expert_load_max_over_mean": 4.5})
         train.report({"loss": 0.8, "moe_rows_routed": 196608,
-                      "moe_rows_held": 12288,
+                      "moe_rows_held": 12288, "moe_rows_passed": 13824,
                       "moe_expert_load_max_over_mean": 4.25,
                       "moe_router_bias_abs_max": 0.057,
                       "moe_other": 1})
@@ -500,6 +501,7 @@ def test_train_session_serves_the_last_reported_moe_counters():
         session_mod._session = saved
     assert "rtpu_train_moe_rows_routed 196608\n" in text
     assert "rtpu_train_moe_rows_held 12288\n" in text
+    assert "rtpu_train_moe_rows_passed 13824\n" in text
     assert "rtpu_train_moe_expert_load_max_over_mean 4.25\n" in text
     assert "rtpu_train_moe_router_bias_abs_max 0.057\n" in text
     assert "rtpu_train_moe_other" not in text and "rtpu_train_loss" not in text
