@@ -33,7 +33,8 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
 from ray_tpu.ops.attention import attention_reference, flash_attention
-from ray_tpu.ops.layers import apply_rope, rms_norm, rope_frequencies, swiglu
+from ray_tpu.ops.layers import (apply_rope, blocked_head_nll, head_block,
+                                rms_norm, rope_frequencies, swiglu)
 from ray_tpu.ops.ring_attention import ring_attention
 from ray_tpu.util import tracing
 
@@ -219,6 +220,18 @@ def init_params(cfg: LlamaConfig, key: jax.Array) -> Dict[str, Any]:
     return params
 
 
+# every leaf of a layer describe_stack reckons with: norms and biases
+# (no bytes of their own), the operators' and the MLPs' matrices
+_STACK_LEAVES = frozenset((
+    "attn_norm", "mlp_norm", "op_norm", "q_norm", "k_norm", "bq", "bk", "bv",
+    "wq", "wk", "wv", "wo", "wg",
+    "w_in", "w_conv", "w_out",
+    "m_in", "m_conv", "m_conv_bias", "dt_bias", "A_log", "D", "m_norm",
+    "m_out",
+    "w_gate", "w_up", "w_down", "s_gate", "s_up", "s_down",
+    "router", "router_bias", "e_gate", "e_up", "e_down"))
+
+
 def remat_names(policy: str) -> Tuple[str, ...]:
     """The checkpoint names a resolved ``remat_policy`` keeps."""
     level = 0 if policy == "full" else int(policy[len("level"):])
@@ -233,7 +246,9 @@ def _runs(pattern: Tuple[str, ...]) -> Tuple[Tuple[str, int], ...]:
 
 def describe_stack(cfg: LlamaConfig, layers, tokens_per_device: int,
                    pattern: Optional[Tuple[str, ...]] = None, top_k: int = 0,
-                   held: Optional[Tuple[int, int]] = None) -> Dict[str, Any]:
+                   held: Optional[Tuple[int, int]] = None,
+                   head_tokens: Optional[int] = None,
+                   scan: Optional[Tuple[int, int]] = None) -> Dict[str, Any]:
     """What ``remat_plan`` knows of a stack: its ``runs`` (``_runs``; one
     run of "layer" without a ``pattern``) and for each of its ``kinds`` the
     bytes each rung of REMAT_LADDER keeps in one layer, the bytes a layer
@@ -244,7 +259,14 @@ def describe_stack(cfg: LlamaConfig, layers, tokens_per_device: int,
     ``s_gate`` is a SwiGLU of that width (dense, shared), an ``e_gate`` a
     routed mixture of ``top_k`` choices a token
     (``ops/moe.routed_experts``; ``held``: its ``held=``), a ``w_in`` a
-    gated short convolution (``ops/conv.py``)."""
+    gated short convolution (``ops/conv.py``), an ``A_log`` a selective
+    scan (``ops/ssm.mamba2_mixer``; ``scan``: its groups and its chunk,
+    which are in no shape). A kind that has none of the three
+    operators, or a leaf whose name is not one of ``_STACK_LEAVES``,
+    raises: a layer the plan does not know is not reckoned as another.
+    ``head_tokens``: the tokens whose logits exist at a time where the
+    head and loss walk blocks (``blocked_token_nll``); all, without."""
+    from ray_tpu.ops import ssm
     from ray_tpu.ops.moe import _held_chunk
 
     T, h = tokens_per_device, cfg.hidden_size
@@ -255,6 +277,13 @@ def describe_stack(cfg: LlamaConfig, layers, tokens_per_device: int,
     for kind in dict(runs):
         leaves = layers[kind] if pattern else layers
         shape = {name: a.shape[1:] for name, a in leaves.items()}
+        ops_ = [name for name in ("wq", "w_in", "A_log") if name in shape]
+        unknown = sorted(set(shape) - _STACK_LEAVES)
+        if len(ops_) != 1 or unknown:
+            raise ValueError(
+                f"describe_stack does not know the layer kind {kind!r}: "
+                + (f"leaves {unknown}" if unknown else
+                   f"its operators are {ops_} (one of wq, w_in, A_log)"))
         flash = qkv = mlp = resid = rows = 0
         # elements a token that a layer's backward holds: its recomputed
         # forward (norms, projections, attention, the three [T, ffn]
@@ -270,6 +299,23 @@ def describe_stack(cfg: LlamaConfig, layers, tokens_per_device: int,
             # the in-projection's thirds, the pass's output and their
             # gradients
             width += 2 * shape["w_in"][-1]
+        if "A_log" in shape:
+            # the in-projection's output (z, x B C, dt) and the taps'
+            # output with their gradients, the gated output; beside them
+            # one step of the walk: its decay matrices, their product with
+            # C B^T in float32 and the activations' dtype and the
+            # gradients of those, and the state before every step. Held
+            # to the compiled step at 16,384, 24,576 and 32,768 tokens of
+            # a 9 : 1 stack at full remat: 2.5, 3.3 and 2.7% over what the
+            # compiler allots, 3.3% under at 8,192 (PERF.md 6, PR 36)
+            heads, d = shape["A_log"][-1], shape["m_out"][0]
+            width += 2 * shape["m_in"][-1] + 2 * shape["m_conv"][0] + d
+            groups, chunk = scan
+            state = (shape["m_conv"][0] - d) // (2 * groups)
+            plan = ssm.scan_plan(1, T, heads, d // heads, state, groups,
+                                 chunk)
+            rows += 4 * plan["decay_bytes_in_hbm"] + (
+                plan["steps"] * heads * (d // heads) * state * 4)
         for gate in ("w_gate", "s_gate"):
             if gate in shape:
                 mlp += 2 * T * shape[gate][-1] * act
@@ -295,7 +341,8 @@ def describe_stack(cfg: LlamaConfig, layers, tokens_per_device: int,
             "working_bytes": T * act * width + rows,
             "params": sum(math.prod(s) for s in shape.values()
                           if len(s) > 1)}
-    return {"runs": runs, "kinds": kinds}
+    return {"runs": runs, "kinds": kinds,
+            **({"head_tokens": head_tokens} if head_tokens else {})}
 
 
 def remat_plan(cfg: LlamaConfig, stack: Dict[str, Any],
@@ -340,7 +387,7 @@ def remat_plan(cfg: LlamaConfig, stack: Dict[str, Any],
         top = (1 if cfg.tie_embeddings else 2) * cfg.vocab_size * h
         gathered = 2 * (2 * max(k["params"] for k in kinds.values())
                         + top) * par
-    logits = 2 * T * cfg.vocab_size * 4
+    logits = 2 * stack.get("head_tokens", T) * cfg.vocab_size * 4
 
     def saved(kind: str, level: str) -> int:
         return sum(b for rung, b in zip(REMAT_LADDER, kinds[kind]["rungs"])
@@ -443,16 +490,21 @@ def resolve_remat(cfg: LlamaConfig, params, tokens, mesh, shardings=None,
 
 
 def _attend(cfg: LlamaConfig, q, k, v, mesh=None, seq_axis=None,
-            window=None):
+            window=None, sm_scale=None):
     impl = cfg.attn_impl
     if impl == "auto":
         impl = "flash" if jax.default_backend() == "tpu" else "reference"
     if window is not None and impl in ("ring", "ulysses"):
         raise ValueError(f"attn_impl={impl!r} knows no window: a window "
                          "layer runs \"flash\" or \"reference\"")
+    if sm_scale is not None and impl in ("ring", "ulysses"):
+        raise ValueError(f"attn_impl={impl!r} scales its scores by the "
+                         "head size alone: a layer with a stated scale "
+                         "runs \"flash\" or \"reference\"")
     if impl == "flash":
         if mesh is None:
-            return flash_attention(q, k, v, causal=True, window=window)
+            return flash_attention(q, k, v, causal=True, window=window,
+                                   sm_scale=sm_scale)
         # A pallas_call is opaque to GSPMD: left bare under a sharded jit,
         # XLA gathers the whole batch onto every chip and runs the kernel
         # on all of it. shard_map hands each chip its own batch rows (and
@@ -467,7 +519,8 @@ def _attend(cfg: LlamaConfig, q, k, v, mesh=None, seq_axis=None,
         spec = P(resolve_axis("batch", mesh), None, heads, None)
         return jax.shard_map(
             lambda q_, k_, v_: flash_attention(q_, k_, v_, causal=True,
-                                               window=window),
+                                               window=window,
+                                               sm_scale=sm_scale),
             mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
             check_vma=False)(q, k, v)
     if impl in ("ring", "ulysses"):
@@ -489,11 +542,13 @@ def _attend(cfg: LlamaConfig, q, k, v, mesh=None, seq_axis=None,
         from ray_tpu.ops.ulysses import ulysses_attention
 
         return ulysses_attention(q, k, v, mesh, axis_name="sp", causal=True)
-    return attention_reference(q, k, v, causal=True, window=window)
+    return attention_reference(q, k, v, causal=True, window=window,
+                               sm_scale=sm_scale)
 
 
 def attention_block(cfg: LlamaConfig, x, p, cos, sin, mesh=None,
-                    seq_axis=None, window=None):
+                    seq_axis=None, window=None, sm_scale=None,
+                    resid_scale=None):
     """Pre-norm attention sub-block with residual: x + wo(attend(qkv)).
     Shared by every model in the family (llama dense, mixtral, olmoe and
     laguna MoE). The number of query heads is the layer's own, read from
@@ -502,7 +557,13 @@ def attention_block(cfg: LlamaConfig, x, p, cos, sin, mesh=None,
     a ``wg`` in ``p`` is a per-head output gate, ``sigmoid(norm(x) @ wg)``
     on each head's output before ``wo`` (arXiv:2505.06708, headwise);
     ``q_norm`` and ``k_norm`` are an RMSNorm of q and k before rope, over
-    the whole vector or, with a weight of a head's size, over each head."""
+    the whole vector or, with a weight of a head's size, over each head.
+    ``cos`` and ``sin`` of None: the layer has no position embedding and
+    q and k are not rotated; ``sm_scale``: the scores' scale where it is
+    not ``head_dim ** -0.5``; ``resid_scale``: the weight of the block's
+    output in the sum with ``x`` where it is not 1 (Granite's
+    ``attention_multiplier`` and ``residual_multiplier``). Left at None
+    the three trace what they always did."""
     # The named scopes here and below (embed, attn_qkv, flash, attn_out,
     # mlp, head_loss) are metadata only: they name the device time of a
     # step in a profiler trace and change no instruction.
@@ -533,8 +594,9 @@ def attention_block(cfg: LlamaConfig, x, p, cos, sin, mesh=None,
         if per_head:
             q = rms_norm(q, p["q_norm"], cfg.rms_norm_eps)
             k = rms_norm(k, p["k_norm"], cfg.rms_norm_eps)
-        q = apply_rope(q, cos, sin)
-        k = apply_rope(k, cos, sin)
+        if cos is not None:
+            q = apply_rope(q, cos, sin)
+            k = apply_rope(k, cos, sin)
         # named for a remat level that keeps them (REMAT_LADDER; no-ops
         # otherwise): the backward then skips the q/k/v matmuls and rope
         q = checkpoint_name(q, "q_rope")
@@ -549,11 +611,12 @@ def attention_block(cfg: LlamaConfig, x, p, cos, sin, mesh=None,
     # reader that knows ``flash`` alone still finds them there
     with jax.named_scope("flash"):
         if window is None:
-            attn = _attend(cfg, q, k, v, mesh=mesh, seq_axis=seq_axis)
+            attn = _attend(cfg, q, k, v, mesh=mesh, seq_axis=seq_axis,
+                           sm_scale=sm_scale)
         else:
             with jax.named_scope("flash_win"):
                 attn = _attend(cfg, q, k, v, mesh=mesh, seq_axis=seq_axis,
-                               window=window)
+                               window=window, sm_scale=sm_scale)
     with jax.named_scope("attn_out"):
         if "wg" in p:
             with jax.named_scope("attn_gate"):
@@ -563,6 +626,8 @@ def attention_block(cfg: LlamaConfig, x, p, cos, sin, mesh=None,
         attn_out = jnp.dot(
             attn, p["wo"].astype(cfg.dtype),
             preferred_element_type=jnp.float32).astype(cfg.dtype)
+        if resid_scale is not None:
+            attn_out = attn_out * jnp.asarray(resid_scale, cfg.dtype)
         return checkpoint_name(x + attn_out, "attn_resid")
 
 
@@ -698,6 +763,25 @@ def cross_entropy_loss(logits: jax.Array, targets: jax.Array,
             nll = nll * mask
             return nll.sum() / jnp.maximum(mask.sum(), 1)
         return nll.mean()
+
+
+def blocked_token_nll(cfg: LlamaConfig, params, x: jax.Array,
+                      targets: jax.Array, block: Optional[int] = None,
+                      logits_divisor: float = 1.0) -> jax.Array:
+    """The model's tail where the logits are too large to exist whole:
+    x [b, s, h] (the last layer's output), targets [b, s] -> the
+    next-token loss of every position [b, s] float32, ``_final_head`` and
+    ``cross_entropy_loss``'s arithmetic over ``block`` tokens at a time
+    (``ops/layers.blocked_head_nll``; ``head_block`` tokens by default).
+    ``logits_divisor``: Granite's ``logits_scaling``."""
+    b, s, h = x.shape
+    with jax.named_scope("head_loss"):
+        x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
+        head = (params["embed"].T if cfg.tie_embeddings
+                else params["lm_head"]).astype(cfg.dtype)
+        return blocked_head_nll(
+            x.reshape(b * s, h), head, targets.reshape(b * s), block=block,
+            logits_divisor=logits_divisor).reshape(b, s)
 
 
 def loss_fn(cfg: LlamaConfig, params, batch: Dict[str, jax.Array],

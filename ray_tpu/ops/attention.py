@@ -353,6 +353,7 @@ def _flash_forward(q, k, v, causal, sm_scale, block_q, block_k, interpret,
                          lambda i, qb: (i, 0, 0)),
         ],
         interpret=interpret,
+        **_dkv_vmem(sk, d, k.dtype, tile=4 << 20),
     )(qt, kt, vt)
     return (out.reshape(b, h, sq, d).transpose(0, 2, 1, 3),
             lse.reshape(b * h, sq))
@@ -468,19 +469,25 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 _SCOPED_VMEM = 16 << 20        # Mosaic's default limit for one kernel
 
 
-def _dkv_vmem(sq: int, d: int, dtype) -> dict:
+def _dkv_vmem(sq: int, d: int, dtype, tile: int = 8 << 20) -> dict:
     """The dK/dV kernel holds a head's whole q and dO, each twice for the
     pipeline (8.4 MB at 8,192 queries; the two statistics are rows of a
     few KB), beside some 7 MB of a tile's float32 temporaries: past
     Mosaic's default limit of 16 MB (v5e has 128 MiB) the call asks for
-    what it needs; under it the call is the one it always was."""
+    what it needs; under it the call is the one it always was. The
+    forward and the dQ kernel hold a head's whole k and v the same way
+    beside less of a tile (``tile``: 4 MB). VMEM pads a row of fewer than
+    128 lanes to 128: a head of 64 holds twice what it counts (33.5 MB at
+    32,768 keys, read off the TPU compiler's refusals, PR 36), and the
+    limit asked for is of what is held."""
     need = 2 * 2 * sq * d * jnp.dtype(dtype).itemsize
-    if need + (8 << 20) <= _SCOPED_VMEM:
+    held = need * max(1, 128 // d)
+    if need + tile <= _SCOPED_VMEM and held + tile // 2 <= _SCOPED_VMEM:
         return {}
     from jax.experimental.pallas import tpu as pltpu
 
     return {"compiler_params": pltpu.CompilerParams(
-        vmem_limit_bytes=need + (12 << 20))}
+        vmem_limit_bytes=held + tile + (4 << 20))}
 
 
 def _flash_backward(q, k, v, out, lse, g, causal, sm_scale, block_q, block_k,
@@ -539,6 +546,7 @@ def _flash_backward(q, k, v, out, lse, g, causal, sm_scale, block_q, block_k,
         ],
         out_specs=pl.BlockSpec((1, block_q, d), q_map),
         interpret=interpret,
+        **_dkv_vmem(sk, d, k.dtype, tile=4 << 20),
     )(qt, kt, vt, dot, lse, delta)
 
     # dK/dV are computed per *query* head (grid over b*h) and reduced over

@@ -26,20 +26,25 @@ import jax
 import jax.numpy as jnp
 
 
+def causal_taps(u: jax.Array, w: jax.Array) -> jax.Array:
+    """u [b, s, c] float32, w [c, taps] -> the causal depthwise convolution
+    ``v_t = sum_j w[:, j] * u_{t - (taps - 1) + j}`` [b, s, c] float32, as
+    ``taps`` shifted multiply-adds (``ops/ssm.py``'s taps are these too)."""
+    s, taps = u.shape[1], w.shape[-1]
+    wf = w.astype(jnp.float32)
+    # u_{t - back}: ``back`` zeros before position 0, the tail cut off
+    return sum(wf[:, taps - 1 - back]
+               * (u if back == 0
+                  else jnp.pad(u, ((0, 0), (back, 0), (0, 0)))[:, :s])
+               for back in range(min(taps, s)))
+
+
 def conv_mix(bcx: jax.Array, w: jax.Array) -> jax.Array:
     """bcx [b, s, 3c] (the in-projection's output: B, C, X thirds), w
     [c, taps] -> C * conv(B * X) [b, s, c] in ``bcx``'s dtype, float32
     inside."""
-    s, taps = bcx.shape[1], w.shape[-1]
     b_, c_, x_ = jnp.split(bcx.astype(jnp.float32), 3, axis=-1)
-    u = b_ * x_
-    wf = w.astype(jnp.float32)
-    # u_{t - back}: ``back`` zeros before position 0, the tail cut off
-    v = sum(wf[:, taps - 1 - back]
-            * (u if back == 0
-               else jnp.pad(u, ((0, 0), (back, 0), (0, 0)))[:, :s])
-            for back in range(min(taps, s)))
-    return (c_ * v).astype(bcx.dtype)
+    return (c_ * causal_taps(b_ * x_, w)).astype(bcx.dtype)
 
 
 def gated_short_conv(h: jax.Array, w_in: jax.Array, w_conv: jax.Array,

@@ -1,4 +1,5 @@
-"""Transformer building blocks: RMSNorm, RoPE, SwiGLU.
+"""Transformer building blocks: RMSNorm, RoPE, SwiGLU, a head and loss over
+blocks of tokens.
 
 Pure-jax implementations — XLA fuses these elementwise chains into the
 surrounding matmuls on TPU (the guide's rule: don't hand-schedule what the
@@ -212,3 +213,44 @@ def repeat_kv(x: jax.Array, n_rep: int) -> jax.Array:
     return jnp.broadcast_to(
         x[:, :, :, None, :], (b, s, h, n_rep, d)
     ).reshape(b, s, h * n_rep, d)
+
+
+# float32 logits one block of ``blocked_head_nll`` may hold
+HEAD_BLOCK_BYTES = 1 << 30
+
+
+def head_block(tokens: int, vocab: int) -> int:
+    """Tokens a block of ``blocked_head_nll``: the largest divisor of
+    ``tokens`` whose float32 logits are within ``HEAD_BLOCK_BYTES``."""
+    most = max(1, HEAD_BLOCK_BYTES // (4 * vocab))
+    return max(n for n in range(1, min(tokens, most) + 1) if tokens % n == 0)
+
+
+def blocked_head_nll(x: jax.Array, head: jax.Array, targets: jax.Array,
+                     block: Optional[int] = None,
+                     logits_divisor: float = 1.0) -> jax.Array:
+    """x [T, h] (normed), head [h, vocab], targets [T] -> the next-token
+    loss of every row [T] float32, a block of rows at a time: a block's
+    logits ``[block, vocab]`` live inside one step of a ``lax.scan`` under
+    ``jax.checkpoint``, so the backward builds them again, block by block,
+    and adds each block's gradient of the head to the sum so far in the
+    head's own dtype. 100,352 rows at 32,768 positions would be 13 GB of
+    float32 whole."""
+    T, h = x.shape
+    block = block or head_block(T, head.shape[-1])
+    if T % block:
+        raise ValueError(f"{T} tokens are not whole blocks of {block}")
+
+    @jax.checkpoint
+    def one_block(_, xt):
+        xb, tb = xt
+        logits = jnp.dot(xb, head, preferred_element_type=jnp.float32)
+        if logits_divisor != 1.0:
+            logits = logits / logits_divisor
+        lse = jax.scipy.special.logsumexp(logits, axis=-1)
+        return None, lse - jnp.take_along_axis(
+            logits, tb[:, None], axis=-1)[:, 0]
+
+    _, nll = jax.lax.scan(one_block, None, (
+        x.reshape(T // block, block, h), targets.reshape(T // block, block)))
+    return nll.reshape(T)

@@ -4,7 +4,8 @@ against change, before any chip does.
 
 For every training cell of ``BENCHMARK.json`` the cell's adamw step
 (``benchmark/cells/train.py``, ``train_moe.py``, ``train_mixed.py`` and
-``train_hybrid.py``, whose own ``make_step`` is compiled: the cell's
+``train_hybrid.py`` and ``train_scan.py``, whose own ``make_step`` is
+compiled: the cell's
 configuration, batch, mesh and donation) is compiled for a v5e host that
 is described and not attached, with ``jax.default_backend`` answering
 "tpu" and ``llama._device_capacity`` a v5e chip's limit, as
@@ -25,7 +26,8 @@ the program and not of it, ``remat``: the whole ``rtpu.train.remat_plan``
 span beside what the compiler allotted, arguments + temporaries + outputs
 - aliased, which is what the plan's ``need_bytes`` is held against;
 ``flash_tiles``: what each distinct flash kernel call of the step visits,
-from its ``rtpu.flash.tiles`` span, and ``scopes``: how many instructions
+from its ``rtpu.flash.tiles`` span, ``scan_plan``: the same of each
+distinct selective scan, from its ``rtpu.ssm.scan_plan`` span, and ``scopes``: how many instructions
 carry each ``jax.named_scope`` name as the innermost). ``--compare``
 judges the program (``PROGRAM_FIELDS``) and says of two differing
 programs how many lines changed and how many of those are calls of the
@@ -126,8 +128,8 @@ def _compile_cell(tree: str, cell: dict, topo) -> dict:
     batch = {"tokens": jax.ShapeDtypeStruct(
         (tr["batch"], tr["seq"] + 1), jnp.int32, sharding=bsh)}
 
-    if tr["family"] == "train_hybrid":
-        step = import_module("benchmark.cells.train_hybrid").make_step(
+    if tr["family"] in ("train_hybrid", "train_scan"):
+        step = import_module("benchmark.cells." + tr["family"]).make_step(
             mod, cfg, tx, mesh)
     elif moe:
         def step(params, opt, batch):
@@ -152,9 +154,15 @@ def _compile_cell(tree: str, cell: dict, topo) -> dict:
               if k not in ("id", "parent", "self_us")} for e in events
              if e["name"] == "rtpu.train.remat_plan"]
     # one line a distinct kernel call of the step: what its loops visit
-    tiles = sorted({json.dumps({k: v for k, v in e["args"].items()
-                                if k not in ("id", "parent", "self_us")})
-                    for e in events if e["name"] == "rtpu.flash.tiles"})
+    def distinct(span):
+        return [json.loads(t) for t in sorted({json.dumps(
+            {k: v for k, v in e["args"].items()
+             if k not in ("id", "parent", "self_us")})
+            for e in events if e["name"] == span})]
+
+    tiles = distinct("rtpu.flash.tiles")
+    # and one a distinct selective scan: its chunks and how it walks them
+    scans = distinct("rtpu.ssm.scan_plan")
     full = compiled.as_text()
     scopes = {}
     for path in OP_NAME.findall(full):
@@ -179,7 +187,8 @@ def _compile_cell(tree: str, cell: dict, topo) -> dict:
                       allotted / plans[0]["need_bytes"], 4)}
         if plans else None,
         "mosaic_kernels": kernels,
-        "flash_tiles": [json.loads(t) for t in tiles],
+        "flash_tiles": tiles,
+        "scan_plan": scans,
         "scopes": dict(sorted(scopes.items())),
         "memory_analysis": {f: getattr(ma, f) for f in MEMORY_FIELDS}}}
 

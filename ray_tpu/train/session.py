@@ -81,6 +81,11 @@ class TrainContext:
 # that balances its load by one, ``models/lfm2.update_router_bias``)
 MOE_COUNTERS = ("moe_rows_routed", "moe_rows_held", "moe_rows_passed",
                 "moe_expert_load_max_over_mean", "moe_router_bias_abs_max")
+# and of a stack with selective-scan layers, served the same way
+# (``ssm_state_abs_max``: the largest ``|S|`` a scan layer's state holds
+# after a sequence's last position, ``ops/ssm.mamba2_mixer``: a state that
+# grows from step to step says the decays have drifted toward 1)
+SSM_COUNTERS = ("ssm_state_abs_max",)
 
 
 class SessionInterruptedError(BaseException):
@@ -118,7 +123,8 @@ class _TrainSession:
         self._finished = False
         self._interrupted: Optional[str] = None
         self._reports = 0
-        self._moe: Dict[str, float] = {}   # the last reported MOE_COUNTERS
+        # the last reported MOE_COUNTERS and SSM_COUNTERS
+        self._moe: Dict[str, float] = {}
         import weakref
 
         from ray_tpu import metrics
@@ -178,7 +184,7 @@ class _TrainSession:
                 persisted = ckpt.path
             self._ckpt_index += 1
         self._reports += 1
-        self._moe.update({k: metrics[k] for k in MOE_COUNTERS
+        self._moe.update({k: metrics[k] for k in MOE_COUNTERS + SSM_COUNTERS
                           if isinstance(metrics.get(k), (int, float))})
         self._result_q.put(TrainingResult(metrics=dict(metrics),
                                           checkpoint_dir=persisted))

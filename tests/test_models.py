@@ -1755,3 +1755,483 @@ def test_gemma_hf_checkpoint_parity(hf_act, our_act):
         cfg, params, cache, toks, jnp.array([8]), jnp.array([True]))
     np.testing.assert_allclose(np.asarray(lg[0]), ref[0, 8],
                                atol=5e-5, rtol=1e-4)
+
+
+# ---- models/granite.py: selective-scan layers, an attention layer without
+# rope, the family's four multipliers, a head and loss over token blocks
+
+
+@pytest.fixture(scope="module")
+def granite_setup():
+    from benchmark.references import granite_ref
+    from ray_tpu.models import granite
+
+    cfg = granite.GraniteConfig.tiny(attn_impl="reference")
+    params = granite.init_params(cfg, jax.random.PRNGKey(0))
+    for n, kind in enumerate(params["layers"]):
+        for i, name in enumerate(("attn_norm", "op_norm", "mlp_norm",
+                                  "m_norm", "D", "m_conv_bias")):
+            if name in params["layers"][kind]:
+                w = params["layers"][kind][name]
+                params["layers"][kind][name] = w + 0.3 * jax.random.normal(
+                    jax.random.PRNGKey(10 * n + i), w.shape)
+    tokens = np.random.default_rng(1).integers(0, cfg.vocab_size, (2, 33))
+    return granite, granite_ref, cfg, params, tokens
+
+
+def test_granite_forward_and_loss_match_the_reference(granite_setup):
+    """Logits, the per-position loss through the blocked head, the loss,
+    the scan layers' last states and the counter against the plain float32
+    reference (the recurrence token by token) on seeded weights, at
+    1e-5."""
+    granite, granite_ref, cfg, params, tokens = granite_setup
+    assert cfg.pattern == ("mamba", "mamba", "attention", "mamba")
+    assert params["layers"]["mamba"]["m_in"].shape == (3, 64, 128 + 160 + 8)
+    assert params["layers"]["mamba"]["m_conv"].shape == (3, 160, 4)
+    assert "lm_head" not in params                      # tied
+    # Mamba-2's published initialisation
+    A = np.exp(np.asarray(params["layers"]["mamba"]["A_log"]))
+    dt = np.log1p(np.exp(np.asarray(params["layers"]["mamba"]["dt_bias"])))
+    assert 1.0 <= A.min() and A.max() <= 16.0
+    assert 0.001 - 1e-6 <= dt.min() and dt.max() <= 0.1 + 1e-6
+    with jax.default_matmul_precision("highest"):
+        logits = jax.jit(lambda p, t: granite.forward(cfg, p, t))(
+            params, tokens[:, :-1])
+        nll, states = jax.jit(lambda p, t: granite.token_nll(
+            cfg, p, t, head_block=16))(params, tokens)
+        loss, terms = jax.jit(lambda p, t: granite.loss_terms(
+            cfg, p, {"tokens": t}))(params, tokens)
+    ref = granite_ref.token_nll(cfg, params, tokens)
+    np.testing.assert_allclose(
+        np.asarray(logits), np.asarray(granite_ref.logits(
+            cfg, params, tokens[:, :-1])), rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(np.asarray(nll), ref["nll"], rtol=1e-5,
+                               atol=1e-5)
+    np.testing.assert_allclose(float(loss), ref["terms"]["loss"], rtol=1e-5)
+    assert states.shape == ref["last_states"].shape == (
+        3, tokens.shape[0], cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state)
+    np.testing.assert_allclose(np.asarray(states), ref["last_states"],
+                               rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(float(terms["ssm_state_abs_max"]),
+                               ref["state_abs_max"], rtol=1e-5)
+    assert ref["state_abs_max"] == np.abs(ref["last_states"]).max() > 0
+
+
+def test_granite_gradients_match_the_reference(granite_setup):
+    """Every leaf's gradient of the loss against the reference's."""
+    granite, granite_ref, cfg, params, tokens = granite_setup
+    with jax.default_matmul_precision("highest"):
+        got = jax.jit(jax.grad(lambda p: granite.loss_fn(
+            cfg, p, {"tokens": tokens})))(params)
+    want = jax.jit(jax.grad(lambda p: granite_ref.loss(cfg, p, tokens)))(
+        params)
+    flat, _ = jax.tree_util.tree_flatten_with_path(got)
+    assert len(flat) == 2 + 9 + 13
+    for (path, g), w in zip(flat, jax.tree_util.tree_leaves(want)):
+        scale = float(jnp.abs(w).max())
+        assert scale > 1e-6, path                       # it is reached
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=1e-4,
+                                   atol=1e-5 * max(scale, 1e-2),
+                                   err_msg=str(path))
+
+
+def test_granite_reference_gradient_of_a_weighted_loss(granite_setup):
+    """What the chip check compares: ``token_nll(grad_weights=...)`` gives
+    the gradient of ``sum(weights * per-position loss)`` for the first
+    layer of each kind, the embedding and the last norm; the program's
+    own gradient of that scalar through the blocked head agrees."""
+    granite, granite_ref, cfg, params, tokens = granite_setup
+    weights = np.random.default_rng(2).uniform(
+        0.5, 1.5, (2, 32)).astype(np.float32) / 64
+    with jax.default_matmul_precision("highest"):
+        got = jax.jit(jax.grad(lambda p: (weights * granite.token_nll(
+            cfg, p, jnp.asarray(tokens), head_block=32)[0]).sum()))(params)
+    ref = granite_ref.token_nll(cfg, params, tokens, grad_weights=weights)
+    got = granite_ref.first_layers(got)
+    assert set(ref["grads"]) == {"embed", "final_norm", "layers"}
+    assert set(ref["grads"]["layers"]) == {"mamba", "attention"}
+    for a, b in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(ref["grads"])):
+        assert a.shape == b.shape
+        np.testing.assert_allclose(
+            np.asarray(a), np.asarray(b), rtol=1e-4,
+            atol=1e-5 * max(float(jnp.abs(b).max()), 1e-4))
+
+
+@pytest.mark.parametrize("how", ["ramp", "constant-rate", "unchanged"])
+def test_granite_first_step_against_the_reference_adamw(granite_setup, how):
+    """What the cell's check holds the update to: the first moment and the
+    parameters its own train step hands on, against optax's adamw in
+    float32 on the reference's gradient of the mean loss. At the foot of
+    a ramp the rate is 0 and the parameters come out bit-equal; at a
+    constant rate they move as the reference's do; a step that hands on
+    what it was given reads 1 on the moment."""
+    import optax
+
+    from benchmark.cells import train_scan
+
+    granite, granite_ref, cfg, params, tokens = granite_setup
+    tokens = np.asarray(tokens, np.int32)
+    tx = optax.adamw(1e-3 if how == "constant-rate"
+                     else optax.linear_schedule(0.0, 1e-4, 2000))
+    with jax.default_matmul_precision("highest"):
+        after, opt, loss, counter = jax.jit(train_scan.make_step(
+            granite, cfg, tx))(params, tx.init(params), {"tokens": tokens})
+        left = train_scan.first_step_left(granite_ref, after, opt)
+        if how == "unchanged":
+            left = {"params": jax.device_get(granite_ref.first_layers(params)),
+                    "mu": jax.tree_util.tree_map(np.zeros_like, left["mu"])}
+        gaps = train_scan.compare(granite, granite_ref, cfg, params,
+                                  jnp.asarray(tokens), tokens,
+                                  first_step=(tx, left))
+    moment = [v for leaves in gaps["first_step"]["moment_gap"].values()
+              for v in leaves.values()]
+    assert len(moment) == 2 + 9 + 13
+    if how == "unchanged":
+        assert all(v == 1.0 for v in moment)
+    else:
+        assert max(moment) < 1e-5
+    if how == "constant-rate":
+        moved = float(jnp.abs(after["embed"] - params["embed"]).max())
+        assert 5e-4 < moved < 2e-3                  # one step at 1e-3
+        assert gaps["first_step"]["param_gap"] < 1e-6
+    else:
+        assert gaps["first_step"]["param_gap"] == 0.0
+    assert gaps["state_head_gap"]["worst"] < 1e-5
+    assert float(counter) == pytest.approx(
+        gaps["state_abs_max"]["reference"], rel=1e-5)
+
+
+@pytest.mark.parametrize("what", ["remat-full", "unrolled", "bf16"])
+def test_granite_variants_agree(granite_setup, what):
+    """Full remat and the unrolled layer loop compute what the scanned
+    stack without remat does; in bf16 the loss stays near float32's."""
+    from dataclasses import replace
+
+    granite, _, cfg, params, tokens = granite_setup
+    base = float(jax.jit(lambda p: granite.loss_fn(
+        cfg, p, {"tokens": tokens}))(params))
+    other = {"remat-full": replace(cfg, remat=True, remat_policy="full"),
+             "unrolled": replace(cfg, scan_layers=False),
+             "bf16": replace(cfg, dtype=jnp.bfloat16)}[what]
+    loss, grads = jax.jit(jax.value_and_grad(lambda p: granite.loss_fn(
+        other, p, {"tokens": tokens})))(params)
+    assert abs(float(loss) - base) < (5e-2 if what == "bf16" else 1e-5)
+    assert all(bool(jnp.isfinite(g).all())
+               for g in jax.tree_util.tree_leaves(grads))
+
+
+def test_granite_micro_preset_counts_what_the_model_card_says():
+    """The published config: 40 layers, 36 of them Mamba-2, 3.19 B
+    parameters with the embedding tied; one period with the whole
+    vocabulary is the cell's 951,991,232."""
+    from ray_tpu.models import granite
+
+    cfg = granite.GraniteConfig.granite_4_0_h_micro(
+        param_dtype=jnp.bfloat16)
+    assert cfg.pattern.count("mamba") == 36 and cfg.pattern[5] == "attention"
+    count = lambda c: sum(int(np.prod(a.shape)) for a in
+                          jax.tree_util.tree_leaves(jax.eval_shape(
+                              lambda k: granite.init_params(c, k),
+                              jax.random.PRNGKey(0))))
+    assert abs(count(cfg) / 3.19e9 - 1) < 0.01
+    period = granite.GraniteConfig.granite_4_0_h_micro(
+        num_layers=10, attention_layers=cfg.attention_layers[:10])
+    assert count(period) == 951_991_232
+    with pytest.raises(ValueError, match="attention_layers names"):
+        granite.GraniteConfig.granite_4_0_h_micro(num_layers=10)
+
+
+def test_granite_fsdp_train_step_matches_unsharded(granite_setup):
+    """``param_shardings`` on an fsdp mesh: the loss and an adamw step's
+    parameters agree with one device's."""
+    import optax
+
+    granite, _, cfg, params, tokens = granite_setup
+    tokens = jnp.asarray(np.concatenate([tokens, tokens]))      # batch 4
+    mesh = build_mesh(MeshSpec({"fsdp": 4}), devices=jax.devices()[:4])
+    tx = optax.adamw(1e-3)
+
+    def step(p, opt, mesh_):
+        loss, grads = jax.value_and_grad(lambda q: granite.loss_fn(
+            cfg, q, {"tokens": tokens}, mesh=mesh_))(p)
+        updates, opt = tx.update(grads, opt, p)
+        return optax.apply_updates(p, updates), loss
+
+    want_p, want = jax.jit(lambda p, o: step(p, o, None))(
+        params, tx.init(params))
+    sharded = jax.device_put(params, granite.param_shardings(cfg, mesh))
+    got_p, got = jax.jit(lambda p, o: step(p, o, mesh))(
+        sharded, tx.init(sharded))
+    assert abs(float(got) - float(want)) < 1e-5
+    for a, b in zip(jax.tree_util.tree_leaves(got_p),
+                    jax.tree_util.tree_leaves(want_p)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-4,
+                                   atol=1e-5)
+
+
+# ---- attention_block: no rope, a stated scale, a residual multiplier
+
+
+def test_attention_block_without_rope_at_a_stated_scale(granite_setup):
+    """``cos=None`` leaves q and k unrotated, ``sm_scale`` replaces the
+    head size's scale and ``resid_scale`` weighs the block's output:
+    against ``granite_ref.attention`` on one layer's weights."""
+    granite, granite_ref, cfg, params, _ = granite_setup
+    p = {k: v[0] for k, v in params["layers"]["attention"].items()}
+    x = jax.random.normal(jax.random.PRNGKey(5), (2, 32, cfg.hidden_size))
+    sz = granite_ref._sizes(cfg)
+    with jax.default_matmul_precision("highest"):
+        got = llama.attention_block(cfg, x, p, None, None,
+                                    sm_scale=cfg.attention_multiplier,
+                                    resid_scale=cfg.residual_multiplier)
+        want = jnp.stack([row + cfg.residual_multiplier
+                          * granite_ref.attention(granite_ref._rms_norm(
+                              row, p["attn_norm"], cfg.rms_norm_eps), p, sz)
+                          for row in x])
+        plain = llama.attention_block(cfg, x, p, None, None)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-5,
+                               atol=1e-5)
+    # the scale is in the result: head_dim ** -0.5 is 1/4 here, not 1/16
+    assert float(jnp.abs(plain - got).max()) > 1e-3
+    with pytest.raises(ValueError, match="stated scale"):
+        from dataclasses import replace
+        llama.attention_block(replace(cfg, attn_impl="ring"), x, p, None,
+                              None, sm_scale=0.1)
+
+
+def _attention_block_before(cfg, x, p, cos, sin, mesh=None,
+                    seq_axis=None, window=None):
+    """Pre-norm attention sub-block with residual: x + wo(attend(qkv)).
+    Shared by every model in the family (llama dense, mixtral, olmoe and
+    laguna MoE). The number of query heads is the layer's own, read from
+    its ``wq`` (Laguna's window layers have more than its full ones);
+    ``window``: the layer sees that many keys back (``flash_attention``);
+    a ``wg`` in ``p`` is a per-head output gate, ``sigmoid(norm(x) @ wg)``
+    on each head's output before ``wo`` (arXiv:2505.06708, headwise);
+    ``q_norm`` and ``k_norm`` are an RMSNorm of q and k before rope, over
+    the whole vector or, with a weight of a head's size, over each head."""
+    # The named scopes here and below (embed, attn_qkv, flash, attn_out,
+    # mlp, head_loss) are metadata only: they name the device time of a
+    # step in a profiler trace and change no instruction.
+    b, s, _ = x.shape
+    hd = cfg.head_dim_
+    with jax.named_scope("attn_qkv"):
+        h1 = llama.rms_norm(x, p["attn_norm"], cfg.rms_norm_eps)
+        q = jnp.dot(h1, p["wq"].astype(cfg.dtype),
+                    preferred_element_type=jnp.float32).astype(cfg.dtype)
+        k = jnp.dot(h1, p["wk"].astype(cfg.dtype),
+                    preferred_element_type=jnp.float32).astype(cfg.dtype)
+        v = jnp.dot(h1, p["wv"].astype(cfg.dtype),
+                    preferred_element_type=jnp.float32).astype(cfg.dtype)
+        if "bq" in p:  # Qwen2-style qkv biases (structure is trace-static)
+            q = q + p["bq"].astype(cfg.dtype)
+            k = k + p["bk"].astype(cfg.dtype)
+            v = v + p["bv"].astype(cfg.dtype)
+        # a q/k norm's weight says what it is over: [hd] each head's dims
+        # (LFM2), else the whole q and k vectors (OLMoE)
+        per_head = "q_norm" in p and p["q_norm"].shape[-1] == hd
+        if "q_norm" in p and not per_head:
+            q = llama.rms_norm(q, p["q_norm"], cfg.rms_norm_eps)
+            k = llama.rms_norm(k, p["k_norm"], cfg.rms_norm_eps)
+        heads = p["wq"].shape[-1] // hd
+        q = q.reshape(b, s, heads, hd)
+        k = k.reshape(b, s, cfg.num_kv_heads, hd)
+        v = v.reshape(b, s, cfg.num_kv_heads, hd)
+        if per_head:
+            q = llama.rms_norm(q, p["q_norm"], cfg.rms_norm_eps)
+            k = llama.rms_norm(k, p["k_norm"], cfg.rms_norm_eps)
+        q = llama.apply_rope(q, cos, sin)
+        k = llama.apply_rope(k, cos, sin)
+        # named for a remat level that keeps them (REMAT_LADDER; no-ops
+        # otherwise): the backward then skips the q/k/v matmuls and rope
+        q = llama.checkpoint_name(q, "q_rope")
+        k = llama.checkpoint_name(k, "k_rope")
+        v = llama.checkpoint_name(v, "v_proj")
+        if "wg" in p:
+            with jax.named_scope("attn_gate"):
+                gate = jax.nn.sigmoid(jnp.dot(
+                    h1, p["wg"].astype(cfg.dtype),
+                    preferred_element_type=jnp.float32))
+    # a window layer's kernel calls are ``flash_win`` inside ``flash``: a
+    # reader that knows ``flash`` alone still finds them there
+    with jax.named_scope("flash"):
+        if window is None:
+            attn = llama._attend(cfg, q, k, v, mesh=mesh, seq_axis=seq_axis)
+        else:
+            with jax.named_scope("flash_win"):
+                attn = llama._attend(cfg, q, k, v, mesh=mesh, seq_axis=seq_axis,
+                               window=window)
+    with jax.named_scope("attn_out"):
+        if "wg" in p:
+            with jax.named_scope("attn_gate"):
+                attn = (attn.astype(jnp.float32) * gate[..., None]
+                        ).astype(cfg.dtype)
+        attn = attn.reshape(b, s, heads * hd)
+        attn_out = jnp.dot(
+            attn, p["wo"].astype(cfg.dtype),
+            preferred_element_type=jnp.float32).astype(cfg.dtype)
+        return llama.checkpoint_name(x + attn_out, "attn_resid")
+
+
+def _attention_caller(caller):
+    """(cfg, one layer's weights, window) as ``caller``'s model hands them
+    to ``attention_block``."""
+    from ray_tpu.models import laguna, lfm2, olmoe
+
+    if caller in ("llama", "qwen2-bias"):
+        cfg = llama.LlamaConfig.tiny(attn_impl="reference",
+                                     attn_qkv_bias=caller == "qwen2-bias")
+        layers = llama.init_params(cfg, jax.random.PRNGKey(0))["layers"]
+        if caller == "qwen2-bias":
+            layers = {k: v + 0.1 if k in ("bq", "bk", "bv") else v
+                      for k, v in layers.items()}
+        return cfg, {k: v[0] for k, v in layers.items()}, None
+    if caller == "olmoe":
+        cfg = olmoe.OlmoeConfig.tiny(attn_impl="reference")
+        layers = olmoe.init_params(cfg, jax.random.PRNGKey(0))["layers"]
+        return cfg, {k: v[0] for k, v in layers.items()}, None
+    if caller == "lfm2":
+        cfg = lfm2.Lfm2Config.tiny(attn_impl="reference")
+        layers = lfm2.init_params(cfg, jax.random.PRNGKey(0))["layers"]
+        return cfg, {k: v[0] for k, v in layers["attn_moe"].items()}, None
+    cfg = laguna.LagunaConfig.tiny(attn_impl="reference")
+    layers = laguna.init_params(cfg, jax.random.PRNGKey(0))["layers"]
+    kind = next(k for k in layers if k.startswith("sliding"))
+    return (cfg, {k: v[0] for k, v in layers[kind].items()},
+            cfg.sliding_window)
+
+
+@pytest.mark.parametrize("caller", ["llama", "qwen2-bias", "olmoe", "lfm2",
+                                    "laguna-window"])
+def test_attention_block_is_bit_equal_for_its_callers(caller):
+    """Every caller from before the rope became optional (plain, with qkv
+    biases, a q/k norm over the whole vector, one over each head, a gated
+    window layer): the block's output and its program are what
+    ``_attention_block_before``, the function as it stood, gives."""
+    cfg, p, window = _attention_caller(caller)
+    x = jax.random.normal(jax.random.PRNGKey(5), (2, 32, cfg.hidden_size))
+    cos, sin = llama.rope_frequencies(cfg.head_dim_, 32, cfg.rope_theta,
+                                      dtype=cfg.dtype)
+    now = jax.jit(lambda x, p: llama.attention_block(
+        cfg, x, p, cos, sin, window=window))
+    before = jax.jit(lambda x, p: _attention_block_before(
+        cfg, x, p, cos, sin, window=window))
+    np.testing.assert_array_equal(np.asarray(now(x, p)),
+                                  np.asarray(before(x, p)))
+    strip = lambda t: __import__("re").sub(r"loc\(.*?\)|#loc.*", "", t)
+    assert strip(now.lower(x, p).as_text()) == strip(
+        before.lower(x, p).as_text())
+
+
+# ---- the head and loss over blocks of tokens
+
+
+@pytest.mark.parametrize("tied", [True, False], ids=["tied", "lm_head"])
+def test_blocked_head_and_loss_match_the_whole_one(tied):
+    """``blocked_token_nll`` against ``_final_head`` + ``cross_entropy_loss``
+    on the same hidden states: each position's loss, the mean, and the
+    gradients with respect to the hidden states, the last norm and the
+    head, at three block sizes (1e-5: the blocks' head gradients are added
+    in another order)."""
+    from dataclasses import replace
+
+    cfg = replace(llama.LlamaConfig.tiny(), tie_embeddings=tied)
+    params = llama.init_params(cfg, jax.random.PRNGKey(0))
+    params["final_norm"] = params["final_norm"] + 0.3 * jax.random.normal(
+        jax.random.PRNGKey(2), params["final_norm"].shape)
+    x = jax.random.normal(jax.random.PRNGKey(1), (2, 24, cfg.hidden_size))
+    targets = jax.random.randint(jax.random.PRNGKey(3), (2, 24), 0,
+                                 cfg.vocab_size)
+    top = {k: params[k] for k in ("final_norm",
+                                  "embed" if tied else "lm_head")}
+
+    def whole(top, x):
+        return llama.cross_entropy_loss(
+            llama._final_head(cfg, {**params, **top}, x) / 8.0, targets)
+
+    def blocked(block):
+        return lambda top, x: llama.blocked_token_nll(
+            cfg, {**params, **top}, x, targets, block=block,
+            logits_divisor=8.0).mean()
+
+    want, want_g = jax.value_and_grad(whole, argnums=(0, 1))(top, x)
+    for block in (48, 16, 1):
+        got, got_g = jax.jit(jax.value_and_grad(
+            blocked(block), argnums=(0, 1)))(top, x)
+        np.testing.assert_allclose(float(got), float(want), rtol=1e-6)
+        for a, b in zip(jax.tree_util.tree_leaves(got_g),
+                        jax.tree_util.tree_leaves(want_g)):
+            np.testing.assert_allclose(
+                np.asarray(a), np.asarray(b), rtol=1e-4,
+                atol=1e-5 * float(jnp.abs(b).max()))
+    with pytest.raises(ValueError, match="not whole blocks"):
+        blocked(5)(top, x)
+    assert llama.head_block(32768, 100352) == 2048
+    assert llama.head_block(30, 256) == 30
+
+
+# ---- describe_stack: the scan kind, and a kind it does not know
+
+
+def test_describe_stack_knows_a_scan_layer_and_a_blocked_head():
+    """A layer with an ``A_log`` is reckoned as a selective scan: the MLP
+    rung alone keeps anything, the working set holds the in-projection's
+    width and one step of the walk; ``head_tokens`` takes the logits' term
+    from all tokens to a block."""
+    from dataclasses import replace
+
+    from ray_tpu.models import granite
+    from ray_tpu.ops import ssm
+
+    cfg = replace(granite.GraniteConfig.granite_4_0_h_micro(
+        num_layers=10, attention_layers=(False,) * 5 + (True,)
+        + (False,) * 4), dtype=jnp.bfloat16, param_dtype=jnp.bfloat16)
+    shapes = jax.eval_shape(lambda k: granite.init_params(cfg, k),
+                            jax.random.PRNGKey(0))
+    T = 32768
+    stack = llama.describe_stack(cfg, shapes["layers"], T,
+                                 pattern=cfg.pattern,
+                                 head_tokens=llama.head_block(
+                                     T, cfg.vocab_size),
+                                 scan=(cfg.ssm_groups, cfg.ssm_chunk))
+    assert stack["runs"] == (("mamba", 5), ("attention", 1), ("mamba", 4))
+    mamba, attn = stack["kinds"]["mamba"], stack["kinds"]["attention"]
+    assert mamba["rungs"] == (0, 0, 2 * T * 8192 * 2, 0)
+    assert attn["rungs"][0] > 0 and attn["rungs"][3] > 0
+    plan = ssm.scan_plan(1, T, 64, 64, 128, 1, 256)
+    assert mamba["working_bytes"] > 4 * plan["decay_bytes_in_hbm"] \
+        + T * 2 * 2 * 8512
+    assert mamba["params"] == 76_182_976 - 2 * 2048 - 4096 - 4352 - 3 * 64
+    par = sum(int(np.prod(a.shape)) * 2
+              for a in jax.tree_util.tree_leaves(shapes))
+    cap = int(15.75 * 2 ** 30)
+    blocked = llama.remat_plan(cfg, stack, T, par, cap, False)
+    whole = llama.remat_plan(cfg, {k: v for k, v in stack.items()
+                                   if k != "head_tokens"}, T, par, cap, False)
+    assert blocked["level"] == {"mamba": "full", "attention": "full"}
+    # 13 GB of float32 logits and as much of their gradient leave the need
+    assert whole["need_bytes"] - blocked["need_bytes"] > 24e9
+    # the compiled step at full remat is allotted 17,708,709,888 bytes
+    # (described v5e, PR 36): the reckoning lies 1 to 4% over it
+    assert 1.01 < blocked["need_bytes"] / 17_708_709_888 < 1.04
+
+
+@pytest.mark.parametrize("how, says", [
+    ("no-operator", "its operators are \\[\\]"),
+    ("two-operators", "its operators are \\['wq', 'A_log'\\]"),
+    ("a-new-leaf", "leaves \\['w_lora'\\]")])
+def test_describe_stack_refuses_a_kind_it_does_not_know(how, says):
+    """A layer without one of the three operators the plan reckons with,
+    with two of them, or with a leaf of a name it has never seen is not
+    planned as another kind: it raises."""
+    cfg = llama.LlamaConfig.tiny()
+    layers = dict(llama.init_shapes(cfg)["layers"])
+    if how == "no-operator":
+        layers = {k: v for k, v in layers.items() if k != "wq"}
+    elif how == "two-operators":
+        layers["A_log"] = jax.ShapeDtypeStruct((2, 8), jnp.float32)
+    else:
+        layers["w_lora"] = jax.ShapeDtypeStruct((2, 64, 8), jnp.float32)
+    with pytest.raises(ValueError, match=says):
+        llama.describe_stack(cfg, layers, 64)

@@ -1097,3 +1097,238 @@ def test_flash_attention_head_64_forward_and_gradients(heads, kv_heads):
     for a, b_ in zip(gf, gr):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b_),
                                    rtol=2e-4, atol=1e-4)
+
+
+# ---- ops/ssm.py: the chunked selective scan and the Mamba-2 mixer
+
+
+def _scan_inputs(b=2, s=32, H=4, P=8, G=2, N=16, seed=0):
+    k = jax.random.split(jax.random.PRNGKey(seed), 5)
+    return (jax.random.normal(k[0], (b, s, H, P)),
+            jax.nn.softplus(jax.random.normal(k[1], (b, s, H)) - 2.0),
+            -jnp.exp(jax.random.normal(k[2], (H,))),
+            jax.random.normal(k[3], (b, s, G, N)),
+            jax.random.normal(k[4], (b, s, G, N)))
+
+
+def _recurrence(x, dt, A, B, C):
+    """``granite_ref.recurrence`` (token by token) a row of the batch at a
+    time."""
+    from benchmark.references import granite_ref
+
+    out = [granite_ref.recurrence(x[i], dt[i], A, B[i], C[i])
+           for i in range(x.shape[0])]
+    return jnp.stack([o[0] for o in out]), jnp.stack([o[1] for o in out])
+
+
+@pytest.mark.parametrize("chunk,walk", [(4, 8), (8, 2), (32, 1)],
+                         ids=["chunk4", "chunk8-walk2", "whole-sequence"])
+def test_ssd_scan_matches_the_recurrence(chunk, walk, monkeypatch):
+    """The chunked scan against the recurrence one position after another
+    (float32, 1e-5): outputs, the last state and every input's gradient,
+    at three chunk sizes, one of them the whole sequence: the result does
+    not depend on the chunk nor on how many a step of the walk takes (its
+    bytes, ``WALK_BYTES``, are the one way to set that)."""
+    from ray_tpu.ops import ssm
+    from ray_tpu.ops.ssm import ssd_scan
+
+    args = _scan_inputs()
+    b, s, H, P = args[0].shape
+    monkeypatch.setattr(ssm, "WALK_BYTES", walk * b * H * chunk * chunk * 4)
+    assert ssm.scan_plan(b, s, H, P, 16, 2, chunk)["walk"] == walk
+
+    def scalar(fn):
+        def f(*a):
+            y, S = fn(*a)
+            return (jnp.sin(y) * y).sum() + (S * S).sum()
+        return f
+
+    with jax.default_matmul_precision("highest"):
+        y, S = jax.jit(lambda *a: ssd_scan(*a, chunk=chunk))(*args)
+        want_y, want_S = _recurrence(*args)
+        got = jax.jit(jax.grad(scalar(lambda *a: ssd_scan(
+            *a, chunk=chunk)), argnums=(0, 1, 2, 3, 4)))(*args)
+        want = jax.jit(jax.grad(scalar(_recurrence),
+                                argnums=(0, 1, 2, 3, 4)))(*args)
+    np.testing.assert_allclose(np.asarray(y), np.asarray(want_y),
+                               rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(np.asarray(S), np.asarray(want_S),
+                               rtol=1e-5, atol=1e-5)
+    for name, g, w in zip(("x", "dt", "A", "B", "C"), got, want):
+        np.testing.assert_allclose(
+            np.asarray(g), np.asarray(w), rtol=1e-4,
+            atol=1e-5 * float(jnp.abs(w).max()), err_msg=name)
+
+
+def test_ssd_scan_pads_a_ragged_sequence_and_keeps_rows_apart():
+    """A sequence that is not whole chunks is padded with ``dt = 0``, which
+    moves neither output nor state; a row of the batch never sees
+    another's state."""
+    from ray_tpu.ops.ssm import ssd_scan
+
+    x, dt, A, B, C = _scan_inputs(s=30)
+    with jax.default_matmul_precision("highest"):
+        y, S = ssd_scan(x, dt, A, B, C, chunk=8)
+        want_y, want_S = _recurrence(x, dt, A, B, C)
+        alone, _ = ssd_scan(x[1:], dt[1:], A, B[1:], C[1:], chunk=8)
+    np.testing.assert_allclose(np.asarray(y), np.asarray(want_y),
+                               rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(np.asarray(S), np.asarray(want_S),
+                               rtol=1e-5, atol=1e-5)
+    np.testing.assert_array_equal(np.asarray(y[1:]), np.asarray(alone))
+
+
+def test_ssd_scan_without_its_carried_state_is_another_function(monkeypatch):
+    """The fault ``benchmark/tests/scan_limits.py`` plants (one chunk a
+    step of the walk, each started from zeros) agrees with the scan inside
+    the first chunk alone; the program has no option for it."""
+    from ray_tpu.ops import ssm
+
+    args = _scan_inputs()
+    y, _ = ssm.ssd_scan(*args, chunk=8)
+    honest = ssm._walk_step
+    monkeypatch.setattr(ssm, "WALK_BYTES", 0)
+    monkeypatch.setattr(ssm, "_walk_step",
+                        lambda S, *a: honest(jnp.zeros_like(S), *a))
+    cut, _ = ssm.ssd_scan(*args, chunk=8)
+    np.testing.assert_allclose(np.asarray(cut[:, :8]), np.asarray(y[:, :8]),
+                               rtol=1e-6, atol=1e-6)
+    assert float(jnp.abs(cut[:, 8:] - y[:, 8:]).max()) > 0.1
+
+
+def test_ssd_scan_with_bfloat16_decays_is_another_function():
+    """The other fault ``scan_limits.py`` plants in ``ops/ssm.py``: running
+    sums, decays and the carried state rounded to bfloat16's eight bits.
+    Output and last state leave the honest scan's by a bfloat16 rounding
+    and more, a hundred times the 1e-5 the honest scan keeps to the
+    recurrence; afterwards the module is what it was."""
+    from benchmark.tests import scan_limits
+    from ray_tpu.ops import ssm
+
+    args = _scan_inputs()
+    y, S = ssm.ssd_scan(*args, chunk=8)
+    honest = ssm._walk_step
+    cut_y, cut_S = scan_limits.with_bfloat16_decays(
+        lambda: ssm.ssd_scan(*args, chunk=8))
+    assert ssm.jnp is jnp and ssm._walk_step is honest
+
+    def rel(a, b):
+        return float(jnp.linalg.norm(a - b) / jnp.linalg.norm(b))
+
+    assert 1e-3 < rel(cut_y, y) < 0.1
+    assert 1e-3 < rel(cut_S, S) < 0.1
+    again, _ = ssm.ssd_scan(*args, chunk=8)
+    np.testing.assert_array_equal(np.asarray(again), np.asarray(y))
+
+
+def test_scan_plan_walks_within_its_bytes():
+    """At the published shapes a step of the walk takes 8 chunks, 128 MB
+    of decay matrices where all 128 chunks at once would be 2.1 GB; a
+    short sequence is one chunk; the walk always divides the chunks."""
+    from ray_tpu.ops import ssm
+
+    plan = ssm.scan_plan(1, 32768, 64, 64, 128, 1, 256)
+    assert (plan["chunks"], plan["walk"], plan["steps"]) == (128, 8, 16)
+    assert plan["decay_bytes_in_hbm"] == 8 * 64 * 256 * 256 * 4 \
+        <= ssm.WALK_BYTES
+    assert plan["decay_bytes_all_chunks"] == 2 ** 31
+    assert plan["form"] == "xla_walk"
+    small = ssm.scan_plan(2, 30, 4, 8, 16, 2, 256)
+    assert (small["chunk"], small["chunks"], small["walk"]) == (30, 1, 1)
+    # one chunk's matrices past the budget: still one chunk a step
+    assert ssm.scan_plan(64, 32768, 64, 64, 128, 1, 256)["walk"] == 1
+    # 12 chunks, room for 8: the largest divisor within it
+    odd = ssm.scan_plan(1, 3072, 64, 64, 128, 1, 256)
+    assert (odd["chunks"], odd["walk"], odd["steps"]) == (12, 6, 2)
+
+
+def test_mamba2_mixer_matches_the_reference():
+    """The mixer (in-projection, taps with bias and silu, scan, skip,
+    gated norm, out-projection) against ``granite_ref.mamba_mixer``:
+    output, the last state and every leaf's gradient, float32 at 1e-5."""
+    from benchmark.references import granite_ref
+    from ray_tpu.models import granite
+    from ray_tpu.ops.ssm import mamba2_mixer
+
+    cfg = granite.GraniteConfig.tiny()
+    p = {k: v[0] for k, v in granite.init_params(
+        cfg, jax.random.PRNGKey(0))["layers"]["mamba"].items()}
+    p["m_conv_bias"] = 0.1 * jax.random.normal(jax.random.PRNGKey(3),
+                                               p["m_conv_bias"].shape)
+    p["D"] = p["D"] + 0.3 * jax.random.normal(jax.random.PRNGKey(4),
+                                              p["D"].shape)
+    u = jax.random.normal(jax.random.PRNGKey(1), (1, 24, cfg.hidden_size))
+    kw = dict(heads=cfg.ssm_heads, head_dim=cfg.ssm_head_dim,
+              state=cfg.ssm_state, groups=cfg.ssm_groups,
+              chunk=cfg.ssm_chunk, eps=cfg.rms_norm_eps)
+    sz = granite_ref._sizes(cfg)
+    with jax.default_matmul_precision("highest"):
+        out, last = jax.jit(lambda u, p: mamba2_mixer(u, p, **kw))(u, p)
+        want, S = granite_ref.mixer(cfg, p, u[0])
+        got_g = jax.jit(jax.grad(lambda p, u: jnp.square(
+            mamba2_mixer(u, p, **kw)[0]).sum(), argnums=(0, 1)))(p, u)
+        want_g = jax.jit(jax.grad(lambda p, u: jnp.square(
+            granite_ref.mamba_mixer(u[0], p, sz)[0]).sum(),
+            argnums=(0, 1)))(p, u)
+    np.testing.assert_allclose(np.asarray(out[0]), np.asarray(want),
+                               rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(np.asarray(last[0]), np.asarray(S),
+                               rtol=1e-5, atol=1e-5)
+    names = set(p) - {"op_norm", "mlp_norm", "w_gate", "w_up", "w_down"}
+    for (path, g), w in zip(
+            jax.tree_util.tree_flatten_with_path(got_g)[0],
+            jax.tree_util.tree_leaves(want_g)):
+        if path[0].idx == 0 and path[1].key not in names:
+            continue                      # the layer's other leaves: zeros
+        scale = float(jnp.abs(w).max())
+        assert scale > 1e-6, path
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=1e-4,
+                                   atol=1e-5 * scale, err_msg=str(path))
+
+
+def test_mamba2_mixer_is_float32_inside_and_names_its_scopes():
+    """bf16 activations in and out, the state float32; the optimized
+    program names the five scopes under ``ssm``, forward and backward."""
+    from ray_tpu.models import granite
+    from ray_tpu.ops.ssm import mamba2_mixer
+
+    cfg = granite.GraniteConfig.tiny()
+    p = {k: v[0].astype(jnp.bfloat16) for k, v in granite.init_params(
+        cfg, jax.random.PRNGKey(0))["layers"]["mamba"].items()}
+    u = jax.random.normal(jax.random.PRNGKey(1), (1, 16, cfg.hidden_size),
+                          jnp.bfloat16)
+    kw = dict(heads=cfg.ssm_heads, head_dim=cfg.ssm_head_dim,
+              state=cfg.ssm_state, chunk=cfg.ssm_chunk)
+    out, last = mamba2_mixer(u, p, **kw)
+    assert out.dtype == jnp.bfloat16 and last.dtype == jnp.float32
+    assert last.shape == (1, cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state)
+    text = jax.jit(jax.grad(lambda p, u: jnp.square(mamba2_mixer(
+        u, p, **kw)[0].astype(jnp.float32)).sum(),
+        argnums=(0, 1))).lower(p, u).as_text(
+        debug_info=True)
+    for scope in ("ssm_in", "ssm_conv", "ssm_scan", "ssm_norm", "ssm_out"):
+        assert f"jvp(ssm)/{scope}" in text, scope
+        assert f"transpose(jvp(ssm))/{scope}" in text, scope
+
+
+@pytest.mark.parametrize("rows,d,tile,asks", [
+    (4096, 128, 8 << 20, None),         # Mistral, OLMoE: dK/dV
+    (8192, 128, 4 << 20, None),         # Laguna: forward and dQ
+    (16384, 128, 8 << 20, (16384 * 128 * 8) + (12 << 20)),  # Laguna: dK/dV
+    (8192, 64, 8 << 20, None),          # LFM2: dK/dV
+    (32768, 64, 4 << 20, (32768 * 128 * 8) + (8 << 20)),   # Granite
+    (32768, 64, 8 << 20, (32768 * 128 * 8) + (12 << 20)),
+], ids=["4k-128", "8k-128-fwd", "16k-128-dkv", "8k-64", "32k-64-fwd",
+        "32k-64-dkv"])
+def test_flash_kernels_ask_for_vmem_past_the_default_alone(rows, d, tile,
+                                                           asks):
+    """The accepted cells' kernel calls carry the compiler parameters they
+    always did (none, or Laguna's dK/dV limit); at 32,768 keys of 64 every
+    kernel asks for what VMEM holds, a row padded to 128 lanes."""
+    from ray_tpu.ops.attention import _dkv_vmem
+
+    got = _dkv_vmem(rows, d, jnp.bfloat16, tile=tile)
+    if asks is None:
+        assert got == {}
+    else:
+        assert got["compiler_params"].vmem_limit_bytes == asks

@@ -296,6 +296,52 @@ def test_flash_kernels_compile_at_a_head_of_64(S, no_compile_cache,
         assert any(kernel in name for name in names), (kernel, names)
 
 
+def test_flash_kernels_compile_at_32k_positions_of_64(S, no_compile_cache):
+    """Granite 4.0-H's attention layer in its cell: one sequence of 32,768
+    positions, 32 query heads on 8 of 64, scores scaled by 1/64. A head's
+    whole k and v (forward, dQ) or q and dO (dK/dV) lie in VMEM, a row of
+    64 padded to 128 lanes: 33.5 MB, past Mosaic's default 16 MB, so every
+    kernel asks for its limit (``_dkv_vmem``) and Mosaic takes all three."""
+    from ray_tpu.ops.attention import flash_attention
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v, use_pallas=True,
+                               sm_scale=0.015625).astype(jnp.float32).sum()
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        S(1, 32768, 32, 64), S(1, 32768, 8, 64),
+        S(1, 32768, 8, 64)).compile().as_text()
+    names = {name for name, _ in _mosaic_calls(text)}
+    assert len(names) == 3
+    for kernel in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"):
+        assert any(kernel in name for name in names), (kernel, names)
+
+
+def test_mamba2_mixer_compiles_without_all_chunks_decay_matrices(
+        S, no_compile_cache):
+    """One Mamba-2 mixer at the published widths over 32,768 positions,
+    forward and backward: XLA's fusions and matmuls (no Mosaic call), the
+    chunks walked 8 at a time, so that the decay matrices of all 128 chunks
+    (2.1 GB in float32, and as much again for the backward) never exist:
+    the whole gradient's temporaries are 3.9 GB, the projections' outputs,
+    the taps' and the gate's float32 passes and their gradients, where
+    the matrices of all chunks alone would be 4.3 GB."""
+    from ray_tpu.ops.ssm import mamba2_mixer
+
+    shapes = {"m_in": (2048, 8512), "m_conv": (4352, 4),
+              "m_conv_bias": (4352,), "dt_bias": (64,), "A_log": (64,),
+              "D": (64,), "m_norm": (4096,), "m_out": (4096, 2048)}
+
+    def loss(h, p):
+        out, last = mamba2_mixer(h, p, heads=64, head_dim=64, state=128)
+        return jnp.square(out.astype(jnp.float32)).sum() + jnp.abs(last).max()
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        S(1, 32768, 2048), {k: S(*v) for k, v in shapes.items()}).compile()
+    assert not _mosaic_calls(compiled.as_text())
+    assert compiled.memory_analysis().temp_size_in_bytes < 4.25 * 2 ** 30
+
+
 def test_conv_mix_pass_compiles_to_fusions_without_a_kernel(
         S, no_compile_cache):
     """The pass between a convolution's two projections at LFM2's width

@@ -704,7 +704,8 @@ def test_remat_auto_passes_where_a_set_policy_is_refused():
         assert ev["args"]["level"] == policy
 
 
-@pytest.mark.parametrize("model", ["llama", "olmoe", "laguna", "lfm2"])
+@pytest.mark.parametrize("model", ["llama", "olmoe", "laguna", "lfm2",
+                                   "granite"])
 def test_remat_plan_is_one_kept_span_of_a_traced_program(model):
     """Tracing a train step of a planned forward writes its remat plan
     once, as a kept span (no flag, no profiler window), with what it chose
@@ -713,12 +714,13 @@ def test_remat_plan_is_one_kept_span_of_a_traced_program(model):
     import jax
     import jax.numpy as jnp
 
-    from ray_tpu.models import laguna, lfm2, llama, olmoe
+    from ray_tpu.models import granite, laguna, lfm2, llama, olmoe
 
     mod, cls = {"llama": (llama, llama.LlamaConfig),
                 "olmoe": (olmoe, olmoe.OlmoeConfig),
                 "laguna": (laguna, laguna.LagunaConfig),
-                "lfm2": (lfm2, lfm2.Lfm2Config)}[model]
+                "lfm2": (lfm2, lfm2.Lfm2Config),
+                "granite": (granite, granite.GraniteConfig)}[model]
     assert not config.task_events_enabled
     batch = {"tokens": jax.ShapeDtypeStruct((2, 33), jnp.int32)}
 
@@ -803,6 +805,113 @@ def test_flash_tiles_is_one_kept_span_of_a_traced_call():
     (chunk,) = trace(512, 2048, use_pallas=True, block_q=256, block_k=512)
     assert (chunk["block_q"], chunk["block_k"]) == (256, 512)
     assert (chunk["tiles_visited"], chunk["tiles_edge"]) == (8, 2)
+
+
+def test_scan_plan_is_one_kept_span_of_a_traced_call():
+    """A traced selective scan writes what it will do once, as a kept span
+    (no flag, no profiler window), as ``rtpu.flash.tiles`` is written:
+    sequence, chunk, chunks, how many a step of the walk takes, heads,
+    head size, state, groups, the form and the bytes of decay matrices the
+    form puts in HBM beside what all chunks at once would."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.ops import ssm
+
+    assert not config.task_events_enabled
+
+    def trace(seq, **kw):
+        x = jax.ShapeDtypeStruct((1, seq, 64, 64), jnp.bfloat16)
+        dt = jax.ShapeDtypeStruct((1, seq, 64), jnp.float32)
+        bc = jax.ShapeDtypeStruct((1, seq, 1, 128), jnp.bfloat16)
+        n0 = len(_mine("rtpu.ssm.scan_plan"))
+        # forward and backward of one call
+        jax.eval_shape(jax.grad(lambda x, dt, B, C: ssm.ssd_scan(
+            x, dt, -jnp.ones((64,)), B, C, **kw)[0].astype(
+                jnp.float32).sum(), argnums=(0, 1, 2, 3)), x, dt, bc, bc)
+        return [{k_: v for k_, v in e["args"].items()
+                 if k_ not in ("id", "parent", "self_us")}
+                for e in _mine("rtpu.ssm.scan_plan")[n0:]]
+
+    (cell,) = trace(32768)
+    assert cell == {"seq": 32768, "chunk": 256, "chunks": 128, "walk": 8,
+                    "steps": 16, "heads": 64, "head_dim": 64, "state": 128,
+                    "groups": 1, "form": "xla_walk",
+                    "decay_bytes_in_hbm": 2 ** 27,
+                    "decay_bytes_all_chunks": 2 ** 31}
+    (short,) = trace(1000, chunk=128)
+    assert (short["chunk"], short["chunks"], short["walk"],
+            short["steps"]) == (128, 8, 8, 1)
+
+
+def test_train_session_serves_the_last_reported_scan_counter():
+    """``rtpu_train_ssm_state_abs_max``: the last value a loop put into
+    ``train.report`` beside the routed layers' counters; a loop that does
+    not report it serves none."""
+    from ray_tpu import metrics
+    from ray_tpu.train.session import (SSM_COUNTERS, TrainContext,
+                                       _TrainSession)
+
+    assert SSM_COUNTERS == ("ssm_state_abs_max",)
+
+    def loop():
+        from ray_tpu import train
+        train.report({"loss": 1.0})
+        train.report({"loss": 0.9, "ssm_state_abs_max": 7.25})
+        train.report({"loss": 0.8, "ssm_state_abs_max": 9.5,
+                      "ssm_other": 1})
+
+    s = _TrainSession(loop, {}, TrainContext())
+    from ray_tpu.train import session as session_mod
+    saved, session_mod._session = session_mod._session, s
+    try:
+        s.start()
+        s.next_result(timeout=10)       # the loop is in its second report
+        assert "rtpu_train_ssm" not in metrics.REGISTRY.render().split(
+            "rtpu_train_reports")[0]
+        s.next_result(timeout=10)
+        s.next_result(timeout=10)
+        assert s.next_result(timeout=10).done
+        text = metrics.REGISTRY.render()
+    finally:
+        session_mod._session = saved
+    assert "rtpu_train_ssm_state_abs_max 9.5\n" in text
+    assert "rtpu_train_ssm_other" not in text
+
+
+def test_granite_train_step_names_its_scopes_and_counts_its_state():
+    """The optimized train step of a stack with scan layers carries the
+    five ``ssm_*`` scopes beside the family's, and hands out the counter
+    ``ssm_state_abs_max`` as one float32 scalar."""
+    import jax
+    import jax.numpy as jnp
+    import optax
+
+    from ray_tpu.models import granite
+
+    cfg = granite.GraniteConfig.tiny(vocab_size=128, attn_impl="reference",
+                                     remat=True)
+    params = jax.eval_shape(lambda k: granite.init_params(cfg, k),
+                            jax.random.PRNGKey(0))
+    tx = optax.adamw(1e-3)
+    opt = jax.eval_shape(tx.init, params)
+    batch = {"tokens": jax.ShapeDtypeStruct((2, 33), jnp.int32)}
+
+    def step(params, opt, batch):
+        (loss, aux), grads = jax.value_and_grad(
+            lambda p: granite.loss_terms(cfg, p, batch), has_aux=True)(params)
+        updates, opt = tx.update(grads, opt, params)
+        return (optax.apply_updates(params, updates), opt, loss,
+                aux["ssm_state_abs_max"])
+
+    lowered = jax.jit(step, donate_argnums=(0, 1)).lower(params, opt, batch)
+    assert lowered.out_info[3].shape == ()
+    assert lowered.out_info[3].dtype == jnp.float32
+    text = lowered.compile().as_text()
+    for scope in ("embed", "ssm_in", "ssm_conv", "ssm_scan", "ssm_norm",
+                  "ssm_out", "attn_qkv", "flash", "attn_out", "mlp",
+                  "head_loss"):
+        assert re.search(rf'op_name="[^"]*[/(]{scope}[/)]', text), scope
 
 
 def test_every_kernel_and_serving_program_has_a_name():
