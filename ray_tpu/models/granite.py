@@ -207,7 +207,7 @@ def _layer(cfg: GraniteConfig, kind: str, x, p, mesh=None):
             rms_norm(x, p["op_norm"], cfg.rms_norm_eps), p,
             heads=cfg.ssm_heads, head_dim=cfg.ssm_head_dim,
             state=cfg.ssm_state, groups=cfg.ssm_groups, chunk=cfg.ssm_chunk,
-            eps=cfg.rms_norm_eps)
+            eps=cfg.rms_norm_eps, mesh=mesh)
         x = x + out * jnp.asarray(r, dt)
     with jax.named_scope("mlp"):
         h2 = rms_norm(x, p["mlp_norm"], cfg.rms_norm_eps)

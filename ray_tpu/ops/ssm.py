@@ -32,19 +32,21 @@ chunks is padded with ``dt = 0``, which moves neither state nor output.
 
 Named scopes (metadata only): ``ssm`` holds ``ssm_in`` (the in-projection;
 the norm before it is the caller's), ``ssm_conv`` (the causal depthwise
-taps, their bias and the silu), ``ssm_scan`` (softplus, the scan, the skip
+taps, their bias and the silu: ``causal_conv_silu``, on a TPU the kernel
+pair ``ops/conv.taps_silu``), ``ssm_scan`` (softplus, the scan, the skip
 ``D x``), ``ssm_norm`` (the gate and the RMSNorm over all channels) and
 ``ssm_out`` (the out-projection).
 """
 
 from __future__ import annotations
 
-from typing import Any, Dict, Tuple
+from typing import Any, Dict, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
-from ray_tpu.ops.conv import causal_taps
+from ray_tpu.ops.conv import causal_taps, taps_plan, taps_silu
 from ray_tpu.util import tracing
 
 FORM = "xla_walk"
@@ -141,23 +143,54 @@ def ssd_scan(x: jax.Array, dt: jax.Array, A: jax.Array, B: jax.Array,
     return y[:, :s], S.reshape(b, H, P, N)
 
 
-def causal_conv_silu(u: jax.Array, w: jax.Array, bias: jax.Array
-                     ) -> jax.Array:
-    """u [b, s, c], w [c, taps], bias [c] -> silu(conv(u) + bias) in
-    ``u``'s dtype, float32 inside: ``ops/conv.causal_taps`` (zeros before
-    position 0; ``w[:, -1]`` weighs the position's own value, as a torch
-    ``Conv1d`` with left padding does), the bias, the silu."""
-    v = causal_taps(u.astype(jnp.float32), w)
-    return jax.nn.silu(v + bias.astype(jnp.float32)).astype(u.dtype)
+def causal_conv_silu(u: jax.Array, w: jax.Array, bias: jax.Array,
+                     first: int = 0, sizes: Optional[Sequence[int]] = None,
+                     mesh=None) -> Tuple[jax.Array, ...]:
+    """u [b, wide, s] (channels before positions, as a torch ``Conv1d``
+    takes them), w [c, taps], bias [c] -> silu(conv(x) + bias) of ``x =
+    u[:, first : first + c]`` in ``u``'s dtype, float32 inside, cut into
+    arrays of ``sizes`` channels (one of all ``c`` by default), each [b,
+    size, s]: ``ops/conv.causal_taps`` (zeros before position 0; ``w[:,
+    -1]`` weighs the position's own value, as a ``Conv1d`` with left
+    padding does), the bias, the silu.
+
+    On a TPU backend it is ``ops/conv.taps_silu``, one pass over HBM
+    forward and one backward that read ``u`` where it lies and write the
+    parts; elsewhere, for channels that are not whole tiles of 16, and
+    under a ``mesh`` (a Mosaic call is whole to the partitioner, which
+    would gather its operands: XLA's form shards as the arrays do) it is
+    XLA's form of the same sums. A traced call writes which as the kept
+    span ``rtpu.ssm.conv_plan``: ``ops/conv.taps_plan``'s blocks and the
+    bytes their copies move beside ``form`` (``pallas``), or ``form``
+    ``xla_taps`` and no blocks."""
+    c, taps = w.shape
+    sizes = tuple(sizes or (c,))
+    plan = taps_plan(u.shape[0], u.shape[2], c, taps, u.dtype.itemsize,
+                     first, sizes)
+    kernel = (mesh is None and jax.default_backend() != "cpu"
+              and plan["block_channels"] % 16 == 0)
+    if not kernel:
+        plan = {k: v if k in ("seq", "channels", "taps") else None
+                for k, v in plan.items()}
+    with tracing.span("rtpu.ssm.conv_plan", keep=True,
+                      form="pallas" if kernel else "xla_taps", **plan):
+        pass
+    if kernel:
+        return taps_silu(u, w, bias, first=first, sizes=sizes)
+    x = jnp.swapaxes(u[:, first:first + c], 1, 2).astype(jnp.float32)
+    y = jax.nn.silu(causal_taps(x, w) + bias.astype(jnp.float32))
+    return tuple(jnp.split(jnp.swapaxes(y.astype(u.dtype), 1, 2),
+                           np.cumsum(sizes)[:-1], axis=1))
 
 
 def mamba2_mixer(h: jax.Array, p: Dict[str, jax.Array], *, heads: int,
                  head_dim: int, state: int, groups: int = 1,
-                 chunk: int = 256, eps: float = 1e-5
+                 chunk: int = 256, eps: float = 1e-5, mesh=None
                  ) -> Tuple[jax.Array, jax.Array]:
     """h [b, s, hidden] (normed) -> (the mixer's output [b, s, hidden],
     the state after the last position [b, H, P, N] float32, which no
-    gradient passes).
+    gradient passes). ``mesh``: the one the caller's arrays are sharded
+    over, if any (``causal_conv_silu`` keeps XLA's form under one).
     ``p``: ``m_in [hidden, 2 d + 2 G N + H]`` (z, then x B C, then dt; ``d =
     H P``), ``m_conv [d + 2 G N, taps]`` and ``m_conv_bias``, ``dt_bias``,
     ``A_log`` and ``D`` ``[H]``, ``m_norm [d]``, ``m_out [d, hidden]``.
@@ -171,11 +204,16 @@ def mamba2_mixer(h: jax.Array, p: Dict[str, jax.Array], *, heads: int,
         with jax.named_scope("ssm_in"):
             zxbcdt = jnp.dot(h, p["m_in"].astype(dt_),
                              preferred_element_type=f32).astype(dt_)
-            z, xbc, dt = jnp.split(zxbcdt, (d, 2 * d + 2 * gn), axis=-1)
+            z, dt = zxbcdt[..., :d], zxbcdt[..., 2 * d + 2 * gn:]
+            # positions last, as the in-projection's output lies on a TPU
+            by_channel = jnp.swapaxes(zxbcdt, 1, 2)
         with jax.named_scope("ssm_conv"):
-            xbc = causal_conv_silu(xbc, p["m_conv"], p["m_conv_bias"])
-            x, B, C = jnp.split(xbc, (d, d + gn), axis=-1)
+            # x B C read where the in-projection left them
+            x, B, C = causal_conv_silu(by_channel, p["m_conv"],
+                                       p["m_conv_bias"], first=d,
+                                       sizes=(d, gn, gn), mesh=mesh)
         with jax.named_scope("ssm_scan"):
+            x, B, C = (jnp.swapaxes(a, 1, 2) for a in (x, B, C))
             x = x.reshape(b, s, heads, head_dim)
             y, S = ssd_scan(
                 x, jax.nn.softplus(dt.astype(f32) + p["dt_bias"].astype(f32)),

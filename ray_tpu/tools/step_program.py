@@ -27,7 +27,9 @@ span beside what the compiler allotted, arguments + temporaries + outputs
 - aliased, which is what the plan's ``need_bytes`` is held against;
 ``flash_tiles``: what each distinct flash kernel call of the step visits,
 from its ``rtpu.flash.tiles`` span, ``scan_plan``: the same of each
-distinct selective scan, from its ``rtpu.ssm.scan_plan`` span, and ``scopes``: how many instructions
+distinct selective scan, from its ``rtpu.ssm.scan_plan`` span,
+``conv_plan``: of the taps before it, from ``rtpu.ssm.conv_plan``, and
+``scopes``: how many instructions
 carry each ``jax.named_scope`` name as the innermost). ``--compare``
 judges the program (``PROGRAM_FIELDS``) and says of two differing
 programs how many lines changed and how many of those are calls of the
@@ -163,6 +165,7 @@ def _compile_cell(tree: str, cell: dict, topo) -> dict:
     tiles = distinct("rtpu.flash.tiles")
     # and one a distinct selective scan: its chunks and how it walks them
     scans = distinct("rtpu.ssm.scan_plan")
+    taps = distinct("rtpu.ssm.conv_plan")
     full = compiled.as_text()
     scopes = {}
     for path in OP_NAME.findall(full):
@@ -189,6 +192,7 @@ def _compile_cell(tree: str, cell: dict, topo) -> dict:
         "mosaic_kernels": kernels,
         "flash_tiles": tiles,
         "scan_plan": scans,
+        "conv_plan": taps,
         "scopes": dict(sorted(scopes.items())),
         "memory_analysis": {f: getattr(ma, f) for f in MEMORY_FIELDS}}}
 

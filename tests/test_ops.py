@@ -1242,14 +1242,103 @@ def test_scan_plan_walks_within_its_bytes():
     assert (odd["chunks"], odd["walk"], odd["steps"]) == (12, 6, 2)
 
 
-def test_mamba2_mixer_matches_the_reference():
+def _taps_silu_reference(u, w, bias, first, sizes):
+    """``causal_taps`` + bias + silu in float32 on ``u [b, wide, s]``'s
+    channels from ``first`` on, cut as ``taps_silu`` cuts them."""
+    from ray_tpu.ops.conv import causal_taps
+
+    x = jnp.swapaxes(u[:, first:first + w.shape[0]], 1, 2)
+    y = jax.nn.silu(causal_taps(x.astype(jnp.float32), w)
+                    + bias.astype(jnp.float32))
+    return tuple(jnp.split(jnp.swapaxes(y, 1, 2), np.cumsum(sizes)[:-1],
+                           axis=1))
+
+
+@pytest.mark.parametrize("taps,dtype,seq,wide,first,sizes,rows,lanes", [
+    (4, jnp.float32, 300, 448, 128, (128, 64, 64), 128, 64),
+    (3, jnp.float32, 256, 96, 0, (96,), 128, 32),
+    (4, jnp.bfloat16, 300, 160, 32, (64, 32, 32), 256, None),
+    (3, jnp.bfloat16, 40, 64, 0, (32, 32), None, 16),
+], ids=["f32-4taps-ragged-3parts", "f32-3taps-whole-blocks",
+        "bf16-4taps-ragged-3parts", "bf16-3taps-short"])
+def test_taps_silu_kernels_match_causal_taps(taps, dtype, seq, wide, first,
+                                             sizes, rows, lanes,
+                                             monkeypatch):
+    """The kernel pair (``interpret=True``) against ``causal_taps`` + bias
+    + silu in float32: every part's output and the gradients of ``u``,
+    ``w`` and ``bias``. The cases hold a sequence that is not whole blocks
+    (300 positions in blocks of 128 or 256: positions on both sides of
+    every block edge are compared, and the tile after the last block is no
+    position), one shorter than a block, channels in several blocks and in
+    two or three parts behind an offset, 3 and 4 taps, float32 and bf16.
+    The first ``taps - 1`` positions of a row see zeros and not the row
+    before: row 1 run alone is bit-equal to row 1 of the pair."""
+    from ray_tpu.ops import conv
+
+    if rows:
+        monkeypatch.setattr(conv, "TAPS_BLOCK_ROWS", rows)
+    if lanes:
+        monkeypatch.setattr(conv, "TAPS_BLOCK_CHANNELS", lanes)
+    c = sum(sizes)
+    k = jax.random.split(jax.random.PRNGKey(taps), 4)
+    u = jax.random.normal(k[0], (2, wide, seq)).astype(dtype)
+    w = (0.5 * jax.random.normal(k[1], (c, taps))).astype(dtype)
+    bias = (0.1 * jax.random.normal(k[2], (c,))).astype(dtype)
+    cts = jnp.split(jax.random.normal(k[3], (2, c, seq)),
+                    np.cumsum(sizes)[:-1], axis=1)
+
+    def kernel(u, w, bias):
+        return conv.taps_silu(u, w, bias, first=first, sizes=sizes,
+                              interpret=True)
+
+    def loss(f):
+        return lambda *a: sum((out.astype(jnp.float32) * ct).sum()
+                              for out, ct in zip(f(*a), cts))
+
+    got = jax.jit(kernel)(u, w, bias)
+    want = _taps_silu_reference(u, w, bias, first, sizes)
+    tol = 1e-5 if dtype == jnp.float32 else 1e-2
+    for g, wv, n in zip(got, want, sizes):
+        assert g.shape == (2, n, seq) and g.dtype == dtype
+        np.testing.assert_allclose(np.asarray(g, np.float32), np.asarray(wv),
+                                   rtol=tol, atol=tol)
+    alone = jax.jit(kernel)(u[1:], w, bias)
+    for a, g in zip(alone, got):
+        assert jnp.array_equal(a[0], g[1])
+    got_g = jax.jit(jax.grad(loss(kernel), argnums=(0, 1, 2)))(u, w, bias)
+    want_g = jax.grad(loss(lambda *a: _taps_silu_reference(
+        *a, first, sizes)), argnums=(0, 1, 2))(
+        *(a.astype(jnp.float32) for a in (u, w, bias)))
+    for name, g, wv in zip(("u", "w", "bias"), got_g, want_g):
+        assert g.dtype == dtype, name
+        scale = float(jnp.abs(wv).max())
+        np.testing.assert_allclose(np.asarray(g, np.float32), np.asarray(wv),
+                                   rtol=tol, atol=tol * scale, err_msg=name)
+    # no gradient to the channels beside the taps'
+    beside = jnp.concatenate([got_g[0][:, :first], got_g[0][:, first + c:]],
+                             axis=1)
+    assert not beside.size or float(jnp.abs(beside).max()) == 0.0
+
+
+@pytest.mark.parametrize("form", ["xla_taps", "pallas"])
+def test_mamba2_mixer_matches_the_reference(form, monkeypatch):
     """The mixer (in-projection, taps with bias and silu, scan, skip,
     gated norm, out-projection) against ``granite_ref.mamba_mixer``:
-    output, the last state and every leaf's gradient, float32 at 1e-5."""
+    output, the last state and every leaf's gradient, float32 at 1e-5;
+    once as the CPU runs it and once through the taps' kernels, as a TPU
+    does (the interpreter in Mosaic's place)."""
+    import functools
+
     from benchmark.references import granite_ref
     from ray_tpu.models import granite
+    from ray_tpu.ops import conv, ssm
     from ray_tpu.ops.ssm import mamba2_mixer
 
+    if form == "pallas":
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        monkeypatch.setattr(ssm, "taps_silu", functools.partial(
+            conv.taps_silu, interpret=True))
+    n0 = len(_conv_plans())
     cfg = granite.GraniteConfig.tiny()
     p = {k: v[0] for k, v in granite.init_params(
         cfg, jax.random.PRNGKey(0))["layers"]["mamba"].items()}
@@ -1284,6 +1373,14 @@ def test_mamba2_mixer_matches_the_reference():
         assert scale > 1e-6, path
         np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=1e-4,
                                    atol=1e-5 * scale, err_msg=str(path))
+    assert {e["args"]["form"] for e in _conv_plans()[n0:]} == {form}
+
+
+def _conv_plans():
+    from ray_tpu.util import tracing
+
+    return [e for e in tracing.chrome_events()
+            if e["name"] == "rtpu.ssm.conv_plan"]
 
 
 def test_mamba2_mixer_is_float32_inside_and_names_its_scopes():

@@ -317,29 +317,56 @@ def test_flash_kernels_compile_at_32k_positions_of_64(S, no_compile_cache):
         assert any(kernel in name for name in names), (kernel, names)
 
 
-def test_mamba2_mixer_compiles_without_all_chunks_decay_matrices(
-        S, no_compile_cache):
+MIXER_SHAPES = {"m_in": (2048, 8512), "m_conv": (4352, 4),
+                "m_conv_bias": (4352,), "dt_bias": (64,), "A_log": (64,),
+                "D": (64,), "m_norm": (4096,), "m_out": (4096, 2048)}
+
+
+@pytest.fixture
+def mixer_gradient(S, no_compile_cache, monkeypatch):
     """One Mamba-2 mixer at the published widths over 32,768 positions,
-    forward and backward: XLA's fusions and matmuls (no Mosaic call), the
-    chunks walked 8 at a time, so that the decay matrices of all 128 chunks
-    (2.1 GB in float32, and as much again for the backward) never exist:
-    the whole gradient's temporaries are 3.9 GB, the projections' outputs,
-    the taps' and the gate's float32 passes and their gradients, where
-    the matrices of all chunks alone would be 4.3 GB."""
+    forward and backward, compiled as a TPU runs it."""
     from ray_tpu.ops.ssm import mamba2_mixer
 
-    shapes = {"m_in": (2048, 8512), "m_conv": (4352, 4),
-              "m_conv_bias": (4352,), "dt_bias": (64,), "A_log": (64,),
-              "D": (64,), "m_norm": (4096,), "m_out": (4096, 2048)}
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
 
     def loss(h, p):
         out, last = mamba2_mixer(h, p, heads=64, head_dim=64, state=128)
         return jnp.square(out.astype(jnp.float32)).sum() + jnp.abs(last).max()
 
-    compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
-        S(1, 32768, 2048), {k: S(*v) for k, v in shapes.items()}).compile()
-    assert not _mosaic_calls(compiled.as_text())
-    assert compiled.memory_analysis().temp_size_in_bytes < 4.25 * 2 ** 30
+    return jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        S(1, 32768, 2048),
+        {k: S(*v) for k, v in MIXER_SHAPES.items()}).compile()
+
+
+def test_mamba2_mixer_compiles_without_all_chunks_decay_matrices(
+        mixer_gradient):
+    """The taps, their bias and the silu are the two Mosaic calls of
+    ``ops/conv.taps_silu``, one forward and one backward, and the scan has
+    none: XLA's fusions and matmuls, the chunks walked 8 at a time, so that
+    the decay matrices of all 128 chunks (2.1 GB in float32, and as much
+    again for the backward) never exist. The whole gradient's temporaries
+    are 2.74 GB (3.89 GB with XLA's taps, PR 36): the projections' outputs,
+    the gate's float32 passes and their gradients, where the matrices of
+    all chunks alone would be 4.3 GB."""
+    names = sorted(name for name, _ in _mosaic_calls(
+        mixer_gradient.as_text()))
+    assert len(names) == 2, names
+    assert "taps_silu_fwd" in names[1] and "taps_silu_bwd" in names[0], names
+    assert (mixer_gradient.memory_analysis().temp_size_in_bytes
+            < 2.75 * 2 ** 30)
+
+
+def test_mamba2_mixer_keeps_no_float32_copy_of_the_taps_channels(
+        mixer_gradient):
+    """Nothing the size of the taps' 4,352 channels at 32,768 positions is
+    float32 in HBM under ``ssm_conv``: XLA's form laid four shifted copies
+    of it out, forward and backward (PERF.md 6, PR 37); the kernels keep
+    what is float32 in VMEM and move bf16."""
+    wide = [line for line in mixer_gradient.as_text().splitlines()
+            if "ssm_conv" in line
+            and re.search(r"f32\[1,(32768,4352|4352,32768)\]", line)]
+    assert not wide, wide[:3]
 
 
 def test_conv_mix_pass_compiles_to_fusions_without_a_kernel(

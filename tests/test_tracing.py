@@ -844,6 +844,61 @@ def test_scan_plan_is_one_kept_span_of_a_traced_call():
             short["steps"]) == (128, 8, 8, 1)
 
 
+def test_ssm_conv_plan_is_written_once_per_traced_call(monkeypatch):
+    """``rtpu.ssm.conv_plan``: one kept span per traced call of
+    ``causal_conv_silu``, forward and backward together. On the CPU it
+    names XLA's form and no blocks; where the backend is a TPU, the blocks
+    of ``ops/conv.taps_plan`` and the bytes their copies move: at the
+    cell's shapes 8 blocks of 4,096 positions by 34 of 128 channels, 0.58
+    GB forward (each block and the tile before it in, a block out) and
+    0.89 GB backward beside the least 0.57 and 0.86."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.ops import ssm
+
+    assert not config.task_events_enabled
+
+    def trace(seq, dtype=jnp.bfloat16):
+        u = jax.ShapeDtypeStruct((1, 8512, seq), dtype)
+        w = jax.ShapeDtypeStruct((4352, 4), dtype)
+        bias = jax.ShapeDtypeStruct((4352,), dtype)
+        n0 = len(_mine("rtpu.ssm.conv_plan"))
+        jax.eval_shape(jax.grad(lambda u, w, bias: sum(
+            a.astype(jnp.float32).sum() for a in ssm.causal_conv_silu(
+                u, w, bias, first=4096, sizes=(4096, 128, 128))),
+            argnums=(0, 1, 2)), u, w, bias)
+        return [{k_: v for k_, v in e["args"].items()
+                 if k_ not in ("id", "parent", "self_us")}
+                for e in _mine("rtpu.ssm.conv_plan")[n0:]]
+
+    (cpu,) = trace(32768)
+    assert cpu == {"seq": 32768, "channels": 4352, "taps": 4,
+                   "form": "xla_taps", "block_rows": None,
+                   "block_channels": None, "blocks": None,
+                   "halo_rows": None, "bytes_moved_fwd": None,
+                   "bytes_moved_bwd": None}
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    (cell,) = trace(32768)
+    assert cell == {"seq": 32768, "channels": 4352, "taps": 4,
+                    "form": "pallas", "block_rows": 4096,
+                    "block_channels": 128, "blocks": 8 * 34,
+                    "halo_rows": 128,
+                    "bytes_moved_fwd": 8 * 34 * (2 * 4096 + 128) * 128 * 2,
+                    "bytes_moved_bwd": 8 * 34 * 3 * (4096 + 128) * 128 * 2
+                    + 5 * 4352 * 128 * 4}
+    assert cell["bytes_moved_fwd"] < 1.02 * 2 * 32768 * 4352 * 2
+    (short,) = trace(1000, jnp.float32)
+    assert (short["block_rows"], short["blocks"]) == (1024, 34)
+    # under a mesh the call keeps XLA's form, whatever the backend
+    n0 = len(_mine("rtpu.ssm.conv_plan"))
+    jax.eval_shape(lambda u, w, bias: ssm.causal_conv_silu(
+        u, w, bias, mesh=object()), jax.ShapeDtypeStruct(
+            (2, 256, 64), jnp.float32), jax.ShapeDtypeStruct(
+            (256, 4), jnp.float32), jax.ShapeDtypeStruct((256,), jnp.float32))
+    assert _mine("rtpu.ssm.conv_plan")[n0]["args"]["form"] == "xla_taps"
+
+
 def test_train_session_serves_the_last_reported_scan_counter():
     """``rtpu_train_ssm_state_abs_max``: the last value a loop put into
     ``train.report`` beside the routed layers' counters; a loop that does
