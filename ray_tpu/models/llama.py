@@ -224,10 +224,12 @@ def init_params(cfg: LlamaConfig, key: jax.Array) -> Dict[str, Any]:
 # (no bytes of their own), the operators' and the MLPs' matrices
 _STACK_LEAVES = frozenset((
     "attn_norm", "mlp_norm", "op_norm", "q_norm", "k_norm", "bq", "bk", "bv",
+    "attn_post_norm", "mlp_post_norm", "op_post_norm",
     "wq", "wk", "wv", "wo", "wg",
     "w_in", "w_conv", "w_out",
     "m_in", "m_conv", "m_conv_bias", "dt_bias", "A_log", "D", "m_norm",
     "m_out",
+    "g_in", "g_conv", "g_dt_bias", "g_A_log", "g_norm", "g_out",
     "w_gate", "w_up", "w_down", "s_gate", "s_up", "s_down",
     "router", "router_bias", "e_gate", "e_up", "e_down"))
 
@@ -248,7 +250,8 @@ def describe_stack(cfg: LlamaConfig, layers, tokens_per_device: int,
                    pattern: Optional[Tuple[str, ...]] = None, top_k: int = 0,
                    held: Optional[Tuple[int, int]] = None,
                    head_tokens: Optional[int] = None,
-                   scan: Optional[Tuple[int, int]] = None) -> Dict[str, Any]:
+                   scan: Optional[Tuple[int, int]] = None,
+                   rule: Optional[Tuple[int, int]] = None) -> Dict[str, Any]:
     """What ``remat_plan`` knows of a stack: its ``runs`` (``_runs``; one
     run of "layer" without a ``pattern``) and for each of its ``kinds`` the
     bytes each rung of REMAT_LADDER keeps in one layer, the bytes a layer
@@ -261,12 +264,14 @@ def describe_stack(cfg: LlamaConfig, layers, tokens_per_device: int,
     (``ops/moe.routed_experts``; ``held``: its ``held=``), a ``w_in`` a
     gated short convolution (``ops/conv.py``), an ``A_log`` a selective
     scan (``ops/ssm.mamba2_mixer``; ``scan``: its groups and its chunk,
-    which are in no shape). A kind that has none of the three
+    which are in no shape), a ``g_in`` a gated delta rule
+    (``ops/delta.gated_delta_mixer``; ``rule``: a head's key size and the
+    rule's chunk). A kind that has none of the four
     operators, or a leaf whose name is not one of ``_STACK_LEAVES``,
     raises: a layer the plan does not know is not reckoned as another.
     ``head_tokens``: the tokens whose logits exist at a time where the
     head and loss walk blocks (``blocked_token_nll``); all, without."""
-    from ray_tpu.ops import ssm
+    from ray_tpu.ops import delta, ssm
     from ray_tpu.ops.moe import _held_chunk
 
     T, h = tokens_per_device, cfg.hidden_size
@@ -277,13 +282,15 @@ def describe_stack(cfg: LlamaConfig, layers, tokens_per_device: int,
     for kind in dict(runs):
         leaves = layers[kind] if pattern else layers
         shape = {name: a.shape[1:] for name, a in leaves.items()}
-        ops_ = [name for name in ("wq", "w_in", "A_log") if name in shape]
+        ops_ = [name for name in ("wq", "w_in", "A_log", "g_in")
+                if name in shape]
         unknown = sorted(set(shape) - _STACK_LEAVES)
         if len(ops_) != 1 or unknown:
             raise ValueError(
                 f"describe_stack does not know the layer kind {kind!r}: "
                 + (f"leaves {unknown}" if unknown else
-                   f"its operators are {ops_} (one of wq, w_in, A_log)"))
+                   f"its operators are {ops_} (one of wq, w_in, A_log, "
+                   "g_in)"))
         flash = qkv = mlp = resid = rows = 0
         # elements a token that a layer's backward holds: its recomputed
         # forward (norms, projections, attention, the three [T, ffn]
@@ -316,6 +323,20 @@ def describe_stack(cfg: LlamaConfig, layers, tokens_per_device: int,
                                  chunk)
             rows += 4 * plan["decay_bytes_in_hbm"] + (
                 plan["steps"] * heads * (d // heads) * state * 4)
+        if "g_in" in shape:
+            # the in-projection's output (z, q k v, a b) and the taps'
+            # output (their gradients lie where the SwiGLU's arrays did);
+            # beside them one step of the walk: its pair matrices and
+            # carried states (``rule_plan``'s float32 bytes) with their
+            # gradients, W, U, V' and the decayed copies of q and k in
+            # both dtypes. Held to the compiled step at 32,768 tokens of a
+            # 3 : 1 stack at full remat: 1.4% over what the compiler allots
+            heads, hv = shape["g_A_log"][-1], shape["g_out"][0]
+            width += shape["g_in"][-1] + shape["g_conv"][0]
+            key_dim, chunk = rule
+            plan = delta.rule_plan(1, T, heads, key_dim, hv // heads, chunk)
+            rows += 4 * plan["float32_bytes_in_hbm"] + (
+                plan["steps"] * hv * key_dim * 4)
         for gate in ("w_gate", "s_gate"):
             if gate in shape:
                 mlp += 2 * T * shape[gate][-1] * act
@@ -549,9 +570,13 @@ def _attend(cfg: LlamaConfig, q, k, v, mesh=None, seq_axis=None,
 def attention_block(cfg: LlamaConfig, x, p, cos, sin, mesh=None,
                     seq_axis=None, window=None, sm_scale=None,
                     resid_scale=None):
-    """Pre-norm attention sub-block with residual: x + wo(attend(qkv)).
+    """Attention sub-block with residual: x + wo(attend(qkv)), the norm
+    where the layer's leaves put it: ``attn_norm`` on the block's input
+    (pre-norm, llama's order), ``attn_post_norm`` on its output before the
+    sum (OLMo 2's order; a layer has one of the two).
     Shared by every model in the family (llama dense, mixtral, olmoe and
-    laguna MoE). The number of query heads is the layer's own, read from
+    laguna MoE, granite, olmo_hybrid). The number of query heads is the
+    layer's own, read from
     its ``wq`` (Laguna's window layers have more than its full ones);
     ``window``: the layer sees that many keys back (``flash_attention``);
     a ``wg`` in ``p`` is a per-head output gate, ``sigmoid(norm(x) @ wg)``
@@ -570,7 +595,8 @@ def attention_block(cfg: LlamaConfig, x, p, cos, sin, mesh=None,
     b, s, _ = x.shape
     hd = cfg.head_dim_
     with jax.named_scope("attn_qkv"):
-        h1 = rms_norm(x, p["attn_norm"], cfg.rms_norm_eps)
+        h1 = (rms_norm(x, p["attn_norm"], cfg.rms_norm_eps)
+              if "attn_norm" in p else x)
         q = jnp.dot(h1, p["wq"].astype(cfg.dtype),
                     preferred_element_type=jnp.float32).astype(cfg.dtype)
         k = jnp.dot(h1, p["wk"].astype(cfg.dtype),
@@ -626,6 +652,9 @@ def attention_block(cfg: LlamaConfig, x, p, cos, sin, mesh=None,
         attn_out = jnp.dot(
             attn, p["wo"].astype(cfg.dtype),
             preferred_element_type=jnp.float32).astype(cfg.dtype)
+        if "attn_post_norm" in p:
+            attn_out = rms_norm(attn_out, p["attn_post_norm"],
+                                cfg.rms_norm_eps)
         if resid_scale is not None:
             attn_out = attn_out * jnp.asarray(resid_scale, cfg.dtype)
         return checkpoint_name(x + attn_out, "attn_resid")

@@ -1,5 +1,5 @@
-"""Transformer building blocks: RMSNorm, RoPE, SwiGLU, a head and loss over
-blocks of tokens.
+"""Transformer building blocks: RMSNorm (plain and gated a head at a time), an
+L2 norm, RoPE, SwiGLU, a head and loss over blocks of tokens.
 
 Pure-jax implementations — XLA fuses these elementwise chains into the
 surrounding matmuls on TPU (the guide's rule: don't hand-schedule what the
@@ -27,6 +27,26 @@ def rms_norm(x: jax.Array, weight: jax.Array, eps: float = 1e-6) -> jax.Array:
     var = jnp.mean(jnp.square(xf), axis=-1, keepdims=True)
     normed = xf * jax.lax.rsqrt(var + eps)
     return (normed * weight.astype(jnp.float32)).astype(dtype)
+
+
+def l2_norm(x: jax.Array, eps: float = 1e-6, scale: float = 1.0) -> jax.Array:
+    """``scale * x / sqrt(sum(x^2) + eps)`` over the last axis (a head's
+    dims), float32 inside, one rounding back to the input dtype: the q and
+    k of a delta-rule layer (``ops/delta.py``)."""
+    xf = x.astype(jnp.float32)
+    norm = jax.lax.rsqrt(jnp.sum(jnp.square(xf), axis=-1, keepdims=True) + eps)
+    return (xf * (norm * scale)).astype(x.dtype)
+
+
+def gated_rms_norm(x: jax.Array, gate: jax.Array, weight: jax.Array,
+                   eps: float = 1e-6) -> jax.Array:
+    """``RMSNorm(x; weight) * silu(gate)`` over the last axis (x and gate
+    [..., heads, dim], weight [dim]: each head normed alone, then gated),
+    float32 inside, cast back to the input dtype."""
+    xf = x.astype(jnp.float32)
+    var = jnp.mean(jnp.square(xf), axis=-1, keepdims=True)
+    normed = xf * jax.lax.rsqrt(var + eps) * weight.astype(jnp.float32)
+    return (normed * jax.nn.silu(gate.astype(jnp.float32))).astype(x.dtype)
 
 
 def rope_frequencies(head_dim: int, max_seq_len: int, theta: float = 10_000.0,

@@ -145,7 +145,8 @@ def ssd_scan(x: jax.Array, dt: jax.Array, A: jax.Array, B: jax.Array,
 
 def causal_conv_silu(u: jax.Array, w: jax.Array, bias: jax.Array,
                      first: int = 0, sizes: Optional[Sequence[int]] = None,
-                     mesh=None) -> Tuple[jax.Array, ...]:
+                     mesh=None, span: str = "rtpu.ssm.conv_plan"
+                     ) -> Tuple[jax.Array, ...]:
     """u [b, wide, s] (channels before positions, as a torch ``Conv1d``
     takes them), w [c, taps], bias [c] -> silu(conv(x) + bias) of ``x =
     u[:, first : first + c]`` in ``u``'s dtype, float32 inside, cut into
@@ -160,9 +161,10 @@ def causal_conv_silu(u: jax.Array, w: jax.Array, bias: jax.Array,
     under a ``mesh`` (a Mosaic call is whole to the partitioner, which
     would gather its operands: XLA's form shards as the arrays do) it is
     XLA's form of the same sums. A traced call writes which as the kept
-    span ``rtpu.ssm.conv_plan``: ``ops/conv.taps_plan``'s blocks and the
-    bytes their copies move beside ``form`` (``pallas``), or ``form``
-    ``xla_taps`` and no blocks."""
+    span ``rtpu.ssm.conv_plan`` (``span``: a delta-rule layer's taps,
+    ``ops/delta.py``, write ``rtpu.gdn.conv_plan``): ``ops/conv.taps_plan``'s
+    blocks and the bytes their copies move beside ``form`` (``pallas``), or
+    ``form`` ``xla_taps`` and no blocks."""
     c, taps = w.shape
     sizes = tuple(sizes or (c,))
     plan = taps_plan(u.shape[0], u.shape[2], c, taps, u.dtype.itemsize,
@@ -172,7 +174,7 @@ def causal_conv_silu(u: jax.Array, w: jax.Array, bias: jax.Array,
     if not kernel:
         plan = {k: v if k in ("seq", "channels", "taps") else None
                 for k, v in plan.items()}
-    with tracing.span("rtpu.ssm.conv_plan", keep=True,
+    with tracing.span(span, keep=True,
                       form="pallas" if kernel else "xla_taps", **plan):
         pass
     if kernel:

@@ -4,8 +4,8 @@ against change, before any chip does.
 
 For every training cell of ``BENCHMARK.json`` the cell's adamw step
 (``benchmark/cells/train.py``, ``train_moe.py``, ``train_mixed.py`` and
-``train_hybrid.py`` and ``train_scan.py``, whose own ``make_step`` is
-compiled: the cell's
+``train_hybrid.py``, ``train_scan.py`` and ``train_delta.py``, whose own
+``make_step`` is compiled: the cell's
 configuration, batch, mesh and donation) is compiled for a v5e host that
 is described and not attached, with ``jax.default_backend`` answering
 "tpu" and ``llama._device_capacity`` a v5e chip's limit, as
@@ -28,8 +28,10 @@ span beside what the compiler allotted, arguments + temporaries + outputs
 ``flash_tiles``: what each distinct flash kernel call of the step visits,
 from its ``rtpu.flash.tiles`` span, ``scan_plan``: the same of each
 distinct selective scan, from its ``rtpu.ssm.scan_plan`` span,
-``conv_plan``: of the taps before it, from ``rtpu.ssm.conv_plan``, and
-``scopes``: how many instructions
+``conv_plan``: of the taps before it, from ``rtpu.ssm.conv_plan``,
+``rule_plan`` and ``gdn_conv_plan``: the same of each distinct gated delta
+rule and of its taps, from ``rtpu.gdn.rule_plan`` and
+``rtpu.gdn.conv_plan``, and ``scopes``: how many instructions
 carry each ``jax.named_scope`` name as the innermost). ``--compare``
 judges the program (``PROGRAM_FIELDS``) and says of two differing
 programs how many lines changed and how many of those are calls of the
@@ -130,9 +132,9 @@ def _compile_cell(tree: str, cell: dict, topo) -> dict:
     batch = {"tokens": jax.ShapeDtypeStruct(
         (tr["batch"], tr["seq"] + 1), jnp.int32, sharding=bsh)}
 
-    if tr["family"] in ("train_hybrid", "train_scan"):
-        step = import_module("benchmark.cells." + tr["family"]).make_step(
-            mod, cfg, tx, mesh)
+    runner = import_module("benchmark.cells." + tr["family"])
+    if hasattr(runner, "make_step"):    # the cell's own step, as it stands
+        step = runner.make_step(mod, cfg, tx, mesh)
     elif moe:
         def step(params, opt, batch):
             (loss, aux), grads = jax.value_and_grad(
@@ -166,6 +168,9 @@ def _compile_cell(tree: str, cell: dict, topo) -> dict:
     # and one a distinct selective scan: its chunks and how it walks them
     scans = distinct("rtpu.ssm.scan_plan")
     taps = distinct("rtpu.ssm.conv_plan")
+    # and one a distinct gated delta rule, and its taps
+    rules = distinct("rtpu.gdn.rule_plan")
+    rule_taps = distinct("rtpu.gdn.conv_plan")
     full = compiled.as_text()
     scopes = {}
     for path in OP_NAME.findall(full):
@@ -193,6 +198,8 @@ def _compile_cell(tree: str, cell: dict, topo) -> dict:
         "flash_tiles": tiles,
         "scan_plan": scans,
         "conv_plan": taps,
+        "rule_plan": rules,
+        "gdn_conv_plan": rule_taps,
         "scopes": dict(sorted(scopes.items())),
         "memory_analysis": {f: getattr(ma, f) for f in MEMORY_FIELDS}}}
 

@@ -86,6 +86,12 @@ MOE_COUNTERS = ("moe_rows_routed", "moe_rows_held", "moe_rows_passed",
 # after a sequence's last position, ``ops/ssm.mamba2_mixer``: a state that
 # grows from step to step says the decays have drifted toward 1)
 SSM_COUNTERS = ("ssm_state_abs_max",)
+# and of a stack with gated delta-rule layers (``gdn_state_abs_max``: the
+# same of a linear layer's ``[value, key]`` state, ``ops/delta.
+# gated_delta_mixer``: with ``beta`` up to 2 a state's eigenvalue along a
+# key may be negative, and a state that grows says the keys have lost
+# their unit length or the decays their float32)
+GDN_COUNTERS = ("gdn_state_abs_max",)
 
 
 class SessionInterruptedError(BaseException):
@@ -123,7 +129,7 @@ class _TrainSession:
         self._finished = False
         self._interrupted: Optional[str] = None
         self._reports = 0
-        # the last reported MOE_COUNTERS and SSM_COUNTERS
+        # the last reported MOE_COUNTERS, SSM_COUNTERS and GDN_COUNTERS
         self._moe: Dict[str, float] = {}
         import weakref
 
@@ -184,7 +190,8 @@ class _TrainSession:
                 persisted = ckpt.path
             self._ckpt_index += 1
         self._reports += 1
-        self._moe.update({k: metrics[k] for k in MOE_COUNTERS + SSM_COUNTERS
+        self._moe.update({k: metrics[k] for k in
+                          MOE_COUNTERS + SSM_COUNTERS + GDN_COUNTERS
                           if isinstance(metrics.get(k), (int, float))})
         self._result_q.put(TrainingResult(metrics=dict(metrics),
                                           checkpoint_dir=persisted))

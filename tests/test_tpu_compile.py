@@ -492,3 +492,89 @@ def test_one_kind_of_layer_compiles_the_scan_it_always_did(
     assert names == ({"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"}
                      | ({"gmm", "tgmm"} if model == "olmoe" else set()))
     assert "flash_win" not in now
+
+
+def test_taps_kernels_compile_over_a_delta_layers_channels(S,
+                                                           no_compile_cache):
+    """The taps of an Olmo-Hybrid linear layer through Granite's kernel
+    pair: q, k and v's 11,520 channels read where the in-projection left
+    them (after the gate's 5,760, before a and b's 60) over 32,768
+    positions, in blocks of 64 channels (what divides 5,760, 2,880 and
+    5,760), a zero bias, three outputs. Mosaic takes both calls."""
+    from ray_tpu.ops.conv import taps_silu
+
+    def loss(u, w):
+        q, k, v = taps_silu(u, w, jnp.zeros((11520,), jnp.float32),
+                            first=5760, sizes=(2880, 2880, 5760))
+        return sum(a.astype(jnp.float32).sum() for a in (q, k, v))
+
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(
+        S(1, 17340, 32768), S(11520, 4)).compile().as_text()
+    names = [name for name, _ in _mosaic_calls(text)]
+    assert len(names) == 2, names
+    for kernel in ("taps_silu_fwd", "taps_silu_bwd"):
+        assert any(kernel in name for name in names), (kernel, names)
+
+
+def test_olmo_hybrid_cell_step_lowers_for_a_v5e_chip(one_chip,
+                                                     no_compile_cache,
+                                                     monkeypatch):
+    """The step of the benchmark's ``train-olmo-hybrid-1chip`` at its
+    published widths (1 x 32,768 tokens, three delta-rule layers and one
+    full layer, 12,544 rows of the vocabulary, bf16 state:
+    ``benchmark/configs/olmo-hybrid-7b-c1.json``), built by the cell's own
+    ``make_step`` and lowered for a v5e (Mosaic's own lowering of every
+    kernel call; the whole program's compile, two minutes, is
+    ``tools/step_program.py``'s): the taps' pair once for the scanned
+    linear layers and the three flash kernels at a head of 128 without
+    rope; the rule walks 64 steps of 8 chunks of 64; the plan reckons
+    more than a v5e's budget at every layer's "full", so no rung is
+    taken."""
+    import json
+
+    import optax
+
+    from benchmark.cells import train_delta
+    from ray_tpu.models import llama, olmo_hybrid
+    from ray_tpu.util import tracing
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(llama, "_device_capacity", lambda mesh: V5E_LIMIT)
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "olmo-hybrid-7b-c1.json")) as f:
+        kw = {k: tuple(v) if isinstance(v, list) else v
+              for k, v in json.load(f)["model_config"].items()}
+    assert (kw.pop("module"), kw.pop("preset")) == ("olmo_hybrid",
+                                                    "olmo_hybrid_7b")
+    for key in ("dtype", "param_dtype"):
+        kw[key] = getattr(jnp, kw[key])
+    cfg = olmo_hybrid.OlmoHybridConfig.olmo_hybrid_7b(**kw)
+    assert cfg.pattern == ("linear", "linear", "linear", "full")
+    tx = optax.adamw(optax.linear_schedule(0.0, 1e-4, 2000))
+    params = jax.eval_shape(lambda k: olmo_hybrid.init_params(cfg, k),
+                            jax.random.PRNGKey(0))
+    assert sum(a.size for a in jax.tree_util.tree_leaves(params)) \
+        == 928_862_196
+    opt = jax.eval_shape(tx.init, params)
+    batch = {"tokens": jax.ShapeDtypeStruct((1, 32769), jnp.int32,
+                                            sharding=one_chip)}
+    n0 = len(tracing.chrome_events())
+    lowered = jax.jit(train_delta.make_step(olmo_hybrid, cfg, tx),
+                      donate_argnums=(0, 1)).lower(
+        _placed(params, one_chip), _placed(opt, one_chip), batch)
+    spans = {}
+    for e in tracing.chrome_events()[n0:]:
+        spans.setdefault(e["name"], []).append(e["args"])
+    (plan,) = spans["rtpu.train.remat_plan"]
+    assert plan["level"] == {"linear": "full", "full": "full"}
+    assert plan["need_bytes"] > (1 - llama.REMAT_RESERVE) * V5E_LIMIT
+    assert {(r["chunks"], r["walk"], r["steps"])
+            for r in spans["rtpu.gdn.rule_plan"]} == {(512, 8, 64)}
+    assert {(c["form"], c["block_channels"])
+            for c in spans["rtpu.gdn.conv_plan"]} == {("pallas", 64)}
+    text = lowered.as_text()
+    for kernel in ("taps_silu_fwd", "taps_silu_bwd", "flash_fwd",
+                   "flash_bwd_dq", "flash_bwd_dkv"):
+        assert kernel in text, kernel
+    assert lowered.out_info[3].shape == ()

@@ -705,7 +705,7 @@ def test_remat_auto_passes_where_a_set_policy_is_refused():
 
 
 @pytest.mark.parametrize("model", ["llama", "olmoe", "laguna", "lfm2",
-                                   "granite"])
+                                   "granite", "olmo_hybrid"])
 def test_remat_plan_is_one_kept_span_of_a_traced_program(model):
     """Tracing a train step of a planned forward writes its remat plan
     once, as a kept span (no flag, no profiler window), with what it chose
@@ -714,9 +714,11 @@ def test_remat_plan_is_one_kept_span_of_a_traced_program(model):
     import jax
     import jax.numpy as jnp
 
-    from ray_tpu.models import granite, laguna, lfm2, llama, olmoe
+    from ray_tpu.models import (granite, laguna, lfm2, llama, olmo_hybrid,
+                                olmoe)
 
     mod, cls = {"llama": (llama, llama.LlamaConfig),
+                "olmo_hybrid": (olmo_hybrid, olmo_hybrid.OlmoHybridConfig),
                 "olmoe": (olmoe, olmoe.OlmoeConfig),
                 "laguna": (laguna, laguna.LagunaConfig),
                 "lfm2": (lfm2, lfm2.Lfm2Config),
@@ -899,22 +901,25 @@ def test_ssm_conv_plan_is_written_once_per_traced_call(monkeypatch):
     assert _mine("rtpu.ssm.conv_plan")[n0]["args"]["form"] == "xla_taps"
 
 
-def test_train_session_serves_the_last_reported_scan_counter():
-    """``rtpu_train_ssm_state_abs_max``: the last value a loop put into
-    ``train.report`` beside the routed layers' counters; a loop that does
-    not report it serves none."""
+@pytest.mark.parametrize("op", ["ssm", "gdn"])
+def test_train_session_serves_the_last_reported_scan_counter(op):
+    """``rtpu_train_ssm_state_abs_max`` (a selective scan's) and
+    ``rtpu_train_gdn_state_abs_max`` (a delta rule's): the last value a
+    loop put into ``train.report`` beside the routed layers' counters; a
+    loop that does not report it serves none."""
     from ray_tpu import metrics
-    from ray_tpu.train.session import (SSM_COUNTERS, TrainContext,
-                                       _TrainSession)
+    from ray_tpu.train.session import (GDN_COUNTERS, SSM_COUNTERS,
+                                       TrainContext, _TrainSession)
 
     assert SSM_COUNTERS == ("ssm_state_abs_max",)
+    assert GDN_COUNTERS == ("gdn_state_abs_max",)
 
     def loop():
         from ray_tpu import train
         train.report({"loss": 1.0})
-        train.report({"loss": 0.9, "ssm_state_abs_max": 7.25})
-        train.report({"loss": 0.8, "ssm_state_abs_max": 9.5,
-                      "ssm_other": 1})
+        train.report({"loss": 0.9, f"{op}_state_abs_max": 7.25})
+        train.report({"loss": 0.8, f"{op}_state_abs_max": 9.5,
+                      f"{op}_other": 1})
 
     s = _TrainSession(loop, {}, TrainContext())
     from ray_tpu.train import session as session_mod
@@ -922,7 +927,7 @@ def test_train_session_serves_the_last_reported_scan_counter():
     try:
         s.start()
         s.next_result(timeout=10)       # the loop is in its second report
-        assert "rtpu_train_ssm" not in metrics.REGISTRY.render().split(
+        assert f"rtpu_train_{op}" not in metrics.REGISTRY.render().split(
             "rtpu_train_reports")[0]
         s.next_result(timeout=10)
         s.next_result(timeout=10)
@@ -930,8 +935,8 @@ def test_train_session_serves_the_last_reported_scan_counter():
         text = metrics.REGISTRY.render()
     finally:
         session_mod._session = saved
-    assert "rtpu_train_ssm_state_abs_max 9.5\n" in text
-    assert "rtpu_train_ssm_other" not in text
+    assert f"rtpu_train_{op}_state_abs_max 9.5\n" in text
+    assert f"rtpu_train_{op}_other" not in text
 
 
 def test_granite_train_step_names_its_scopes_and_counts_its_state():
@@ -965,6 +970,107 @@ def test_granite_train_step_names_its_scopes_and_counts_its_state():
     text = lowered.compile().as_text()
     for scope in ("embed", "ssm_in", "ssm_conv", "ssm_scan", "ssm_norm",
                   "ssm_out", "attn_qkv", "flash", "attn_out", "mlp",
+                  "head_loss"):
+        assert re.search(rf'op_name="[^"]*[/(]{scope}[/)]', text), scope
+
+
+def test_gdn_rule_plan_is_one_kept_span_of_a_traced_call():
+    """A traced ``gated_delta_rule`` writes what it will do once, as a kept
+    span (no flag, no profiler window), as ``rtpu.ssm.scan_plan`` is
+    written: sequence, chunk, chunks, how many a step of the walk takes,
+    heads, a head's key and value sizes, the form and the float32 bytes
+    (pair matrices and carried states) a step puts in HBM beside what all
+    chunks at once would."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.ops import delta
+
+    assert not config.task_events_enabled
+
+    def trace(seq, **kw):
+        qk = jax.ShapeDtypeStruct((1, seq, 30, 96), jnp.bfloat16)
+        v = jax.ShapeDtypeStruct((1, seq, 30, 192), jnp.bfloat16)
+        gb = jax.ShapeDtypeStruct((1, seq, 30), jnp.float32)
+        n0 = len(_mine("rtpu.gdn.rule_plan"))
+        # forward and backward of one call
+        jax.eval_shape(jax.grad(lambda q, k, v, g, beta: delta.
+                                gated_delta_rule(q, k, v, g, beta, **kw)[0]
+                                .astype(jnp.float32).sum(),
+                                argnums=(0, 1, 2, 3, 4)), qk, qk, v, gb, gb)
+        return [{k_: v_ for k_, v_ in e["args"].items()
+                 if k_ not in ("id", "parent", "self_us")}
+                for e in _mine("rtpu.gdn.rule_plan")[n0:]]
+
+    (cell,) = trace(32768)
+    one = 30 * 4 * (4 * 64 * 64 + 192 * 96)
+    assert cell == {"seq": 32768, "chunk": 64, "chunks": 512, "walk": 8,
+                    "steps": 64, "heads": 30, "key_dim": 96,
+                    "value_dim": 192, "form": "xla_walk",
+                    "float32_bytes_in_hbm": 8 * one,
+                    "float32_bytes_all_chunks": 512 * one}
+    (short,) = trace(1000, chunk=128)
+    assert (short["chunk"], short["chunks"], short["walk"],
+            short["steps"]) == (128, 8, 4, 2)
+
+
+def test_gdn_conv_plan_is_written_once_per_traced_mixer(monkeypatch):
+    """``rtpu.gdn.conv_plan``: the taps of a delta-rule layer write
+    ``causal_conv_silu``'s span under their own name, once a traced mixer,
+    and ``rtpu.ssm.conv_plan`` not at all. Where the backend is a TPU, at
+    the cell's shapes: 8 blocks of 4,096 positions by 180 of 64 channels
+    (what divides 5,760, 2,880 and 5,760) over q, k and v's 11,520."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.ops import delta
+
+    assert not config.task_events_enabled
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    h, H, K, V = 3840, 30, 96, 192
+    p = {"g_in": (h, 2 * H * (V + K) + 2 * H), "g_conv": (2 * H * K + H * V, 4),
+         "g_dt_bias": (H,), "g_A_log": (H,), "g_norm": (V,),
+         "g_out": (H * V, h)}
+    p = {k_: jax.ShapeDtypeStruct(s, jnp.bfloat16) for k_, s in p.items()}
+    n0, m0 = len(_mine("rtpu.gdn.conv_plan")), len(_mine("rtpu.ssm.conv_plan"))
+    jax.eval_shape(jax.grad(lambda p, x: delta.gated_delta_mixer(
+        x, p, heads=H, key_dim=K, value_dim=V)[0].astype(jnp.float32).sum()),
+        p, jax.ShapeDtypeStruct((1, 32768, h), jnp.bfloat16))
+    (ev,) = _mine("rtpu.gdn.conv_plan")[n0:]
+    assert len(_mine("rtpu.ssm.conv_plan")) == m0
+    args = ev["args"]
+    assert (args["form"], args["seq"], args["channels"], args["taps"]) == (
+        "pallas", 32768, 11520, 4)
+    assert (args["block_rows"], args["block_channels"], args["blocks"]) == (
+        4096, 64, 8 * 180)
+    assert args["bytes_moved_fwd"] < 1.04 * 2 * 32768 * 11520 * 2
+
+
+def test_olmo_hybrid_train_step_names_its_scopes_and_counts_its_state():
+    """The optimized train step of a stack with delta-rule layers carries
+    the five ``gdn_*`` scopes beside the family's, and hands out the
+    counter ``gdn_state_abs_max`` as one float32 scalar."""
+    import jax
+    import jax.numpy as jnp
+    import optax
+
+    from benchmark.cells import train_delta
+    from ray_tpu.models import olmo_hybrid
+
+    cfg = olmo_hybrid.OlmoHybridConfig.tiny(
+        vocab_size=128, attn_impl="reference", remat=True)
+    params = jax.eval_shape(lambda k: olmo_hybrid.init_params(cfg, k),
+                            jax.random.PRNGKey(0))
+    tx = optax.adamw(1e-3)
+    opt = jax.eval_shape(tx.init, params)
+    batch = {"tokens": jax.ShapeDtypeStruct((2, 33), jnp.int32)}
+    lowered = jax.jit(train_delta.make_step(olmo_hybrid, cfg, tx),
+                      donate_argnums=(0, 1)).lower(params, opt, batch)
+    assert lowered.out_info[3].shape == ()
+    assert lowered.out_info[3].dtype == jnp.float32
+    text = lowered.compile().as_text()
+    for scope in ("embed", "gdn_in", "gdn_conv", "gdn_rule", "gdn_norm",
+                  "gdn_out", "attn_qkv", "flash", "attn_out", "mlp",
                   "head_loss"):
         assert re.search(rf'op_name="[^"]*[/(]{scope}[/)]', text), scope
 
