@@ -265,8 +265,9 @@ def describe_stack(cfg: LlamaConfig, layers, tokens_per_device: int,
     gated short convolution (``ops/conv.py``), an ``A_log`` a selective
     scan (``ops/ssm.mamba2_mixer``; ``scan``: its groups and its chunk,
     which are in no shape), a ``g_in`` a gated delta rule
-    (``ops/delta.gated_delta_mixer``; ``rule``: a head's key size and the
-    rule's chunk). A kind that has none of the four
+    (``ops/delta.gated_delta_mixer``; ``rule``: a head's key size, the
+    rule's chunk and, where the arrays are sharded, the mesh: which form
+    the rule runs follows from it). A kind that has none of the four
     operators, or a leaf whose name is not one of ``_STACK_LEAVES``,
     raises: a layer the plan does not know is not reckoned as another.
     ``head_tokens``: the tokens whose logits exist at a time where the
@@ -326,17 +327,28 @@ def describe_stack(cfg: LlamaConfig, layers, tokens_per_device: int,
         if "g_in" in shape:
             # the in-projection's output (z, q k v, a b) and the taps'
             # output (their gradients lie where the SwiGLU's arrays did);
-            # beside them one step of the walk: its pair matrices and
-            # carried states (``rule_plan``'s float32 bytes) with their
-            # gradients, W, U, V' and the decayed copies of q and k in
-            # both dtypes. Held to the compiled step at 32,768 tokens of a
-            # 3 : 1 stack at full remat: 1.4% over what the compiler allots
+            # beside them what the rule's form puts in HBM
+            # (``rule_plan``). XLA's walk: one step's pair matrices and
+            # carried states with their gradients, W, U, V' and the
+            # decayed copies of q and k in both dtypes, and the state
+            # before every step; held to the compiled step at 32,768
+            # tokens of a 3 : 1 stack at full remat: 1.4% over what the
+            # compiler allots (PR 39). The kernels: the kept states and
+            # the running sums alone, and the taps' output is not held
+            # beside the head-major copies the calls read: 1.8% over at
+            # 32,768 tokens (at 16,384 the full layer takes its rungs and
+            # the need lies 3.8% under the allotment; PERF.md 6, PR 40)
             heads, hv = shape["g_A_log"][-1], shape["g_out"][0]
-            width += shape["g_in"][-1] + shape["g_conv"][0]
-            key_dim, chunk = rule
-            plan = delta.rule_plan(1, T, heads, key_dim, hv // heads, chunk)
-            rows += 4 * plan["float32_bytes_in_hbm"] + (
-                plan["steps"] * hv * key_dim * 4)
+            key_dim, chunk, *mesh = rule
+            plan = delta.rule_plan(1, T, heads, key_dim, hv // heads, chunk,
+                                   *mesh)
+            width += shape["g_in"][-1]
+            if plan["form"] == "pallas":
+                rows += plan["float32_bytes_in_hbm"]
+            else:
+                width += shape["g_conv"][0]
+                rows += 4 * plan["float32_bytes_in_hbm"] + (
+                    plan["steps"] * hv * key_dim * 4)
         for gate in ("w_gate", "s_gate"):
             if gate in shape:
                 mlp += 2 * T * shape[gate][-1] * act

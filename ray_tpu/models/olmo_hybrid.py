@@ -215,7 +215,8 @@ def hidden(cfg: OlmoHybridConfig, params, tokens: jax.Array, mesh=None
     level = llama.resolve_remat(
         cfg, params, tokens, mesh, param_shardings, pattern=pattern,
         head_tokens=llama.head_block(tokens.size, cfg.vocab_size),
-        rule=(cfg.linear_key_dim, cfg.rule_chunk)) if cfg.remat else None
+        rule=(cfg.linear_key_dim, cfg.rule_chunk, mesh)
+    ) if cfg.remat else None
     x, ys = llama.run_layers(
         {kind: layer_of(kind) for kind in params["layers"]}, x,
         params["layers"], level=level, scan=cfg.scan_layers, pattern=pattern)

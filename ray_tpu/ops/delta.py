@@ -30,15 +30,38 @@ T``, exact whatever the keys are, where a sum of powers of ``A`` cancels
 catastrophically on repeated keys), joined two at a time by ``[[T11, 0],
 [-T22 A21 T11, T22]]`` in float32 at the highest matmul precision.
 
-The form is XLA's, not a kernel (``FORM``), walked as ``ops/ssm.ssd_scan``
-is: a ``lax.scan`` whose step takes several chunks at once (as many as put
-``WALK_BYTES`` of float32 pair matrices and carried states in HBM), builds
-``A``, ``T``, ``W``, ``U`` for all of them in one batch, hands the state
-from chunk to chunk in an inner ``lax.scan`` (two small matmuls a chunk),
-and then builds ``O`` for all of them; the step is under
-``jax.checkpoint``, so what the backward keeps of it is the state it
-started from. ``rule_plan`` says what a call will do, and a traced call
-writes it once as the kept span ``rtpu.gdn.rule_plan``.
+Two forms share this arithmetic and no code beyond ``_gates``, ``l2_norm``
+and the padding; which runs is read from the call and never set
+(``_kernel_takes``; ``rule_plan`` says what a call will do, and a traced
+call writes it once as the kept span ``rtpu.gdn.rule_plan``):
+
+- ``xla_walk``, on the CPU, under a mesh (a Mosaic call is whole to the
+  partitioner) and for a chunk that is not whole tiles: walked as
+  ``ops/ssm.ssd_scan`` is, a ``lax.scan`` whose step takes several chunks
+  at once (as many as put ``WALK_BYTES`` of float32 pair matrices and
+  carried states in HBM), builds ``A``, ``T``, ``W``, ``U`` for all of
+  them in one batch, hands the state from chunk to chunk in an inner
+  ``lax.scan`` (two small matmuls a chunk), and then builds ``O`` for all
+  of them; the step is under ``jax.checkpoint``, so what the backward
+  keeps of it is the state it started from. The controls of
+  ``benchmark/tests/delta_limits.py`` plant their faults in
+  ``_walk_step``, ``_unit_lower_inverse`` and this module's ``jnp``.
+- ``pallas``, on a TPU backend without a mesh (``rule_kernels``): two
+  Mosaic calls behind a ``custom_vjp``, ``delta_rule_fwd`` and
+  ``delta_rule_bwd``, on a grid of (batch row, ``KERNEL_HEADS`` heads,
+  ``KERNEL_CHUNKS`` chunks), the sequence the last and sequential axis. A
+  chunk's decays, ``A``, ``T``, ``W``, ``U``, ``V'`` and ``Q K^T`` live in
+  VMEM and nothing ``[chunk, chunk]`` is written to HBM; the float32 state
+  is carried in VMEM from step to step. ``T``'s diagonal tiles of
+  ``KERNEL_BASE`` rows come by substitution in straight-line code, the
+  heads of a block side by side on the lanes, and are joined as above. The
+  forward writes the state before every grid step when a gradient is asked
+  for (``states_kept``); the backward takes the steps last first, builds a
+  step's chunks again from that state in VMEM, and walks them last first
+  carrying the state's cotangent, ``dA = -T^T dT T^T`` under the diagonal.
+  Its three seams for the controls are ``_kernel_state``,
+  ``_kernel_inverse`` and ``_kernel_sums`` / ``_kernel_decays`` (through
+  this module's ``jnp``), looked up while the kernels trace.
 
 Decays, running sums, ``beta``, ``A``, ``T`` and the carried state are
 float32; the MXU's operands (``K``, ``V``, ``Q``, their decayed copies,
@@ -51,13 +74,15 @@ Named scopes (metadata only): ``gdn`` holds ``gdn_in`` (the
 in-projection), ``gdn_conv`` (the causal depthwise taps and the silu over
 q, k and v: ``ops/ssm.causal_conv_silu``, on a TPU the kernel pair
 ``ops/conv.taps_silu`` with a zero bias), ``gdn_rule`` (the L2 norms,
-``g`` and ``beta``, the rule), ``gdn_norm`` (the RMSNorm of each head and
+``g`` and ``beta``, the rule in either form, the kernels' relayouts with
+it), ``gdn_norm`` (the RMSNorm of each head and
 the gate) and ``gdn_out`` (the out-projection).
 """
 
 from __future__ import annotations
 
-from typing import Any, Dict, Tuple
+import functools
+from typing import Any, Dict, List, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -66,8 +91,7 @@ from ray_tpu.ops.layers import gated_rms_norm, l2_norm
 from ray_tpu.ops.ssm import causal_conv_silu
 from ray_tpu.util import tracing
 
-FORM = "xla_walk"
-# float32 a step of the walk may put in HBM: each chunk's pair matrices
+# float32 a step of XLA's walk may put in HBM: each chunk's pair matrices
 # (the decays, A, T and Q K^T: four [chunk, chunk] a head) and the state
 # carried into it. 8 chunks of 64 at 30 heads: the rule alone, forward and
 # gradient, read 25 + 127 ms a layer so and 35 + 147 at four times the
@@ -75,26 +99,71 @@ FORM = "xla_walk"
 WALK_BYTES = 40 << 20
 # rows of T found by forward substitution before blocks are joined
 INVERSE_BASE = 16
+# the kernels: heads a grid step takes (what a chunk's chain waits for
+# overlaps across them; their diagonal tiles lie side by side on the lanes
+# while T's first rows are substituted, 2 heads of 4 tiles of 16 a
+# register's 128), chunks a grid step takes (the backward keeps the state
+# before each step and builds the ones between again), and the rows of T
+# substituted before blocks are joined. Read on the chip at the cell's
+# shapes, forward / gradient ms a layer: 10, 8, 16 13.7 / 40.3; 6, 8, 32
+# 14.9 / 43.2; 2, 8, 16 19.3 / 52.4; 6, 8, 64 22.1 / 57.1 (PERF.md 6, PR 40)
+KERNEL_HEADS = 10
+KERNEL_CHUNKS = 8
+KERNEL_BASE = 16
+
+
+def _kernel_takes(chunk: int, mesh) -> bool:
+    """Whether a call runs as the kernels: on a TPU backend (anything but
+    the CPU), without a ``mesh`` (a Mosaic call is whole to the
+    partitioner, which would gather its operands: XLA's walk shards as the
+    arrays do), and with a chunk (a sequence shorter than one is its own)
+    that is whole tiles of ``KERNEL_BASE`` rows, a power of two of them."""
+    tiles = chunk // KERNEL_BASE
+    return (mesh is None and jax.default_backend() != "cpu"
+            and chunk == tiles * KERNEL_BASE and tiles & (tiles - 1) == 0)
+
+
+def _heads_a_block(heads: int) -> int:
+    """The largest divisor of the heads within ``KERNEL_HEADS``."""
+    return max(h for h in range(1, KERNEL_HEADS + 1) if heads % h == 0)
 
 
 def rule_plan(batch: int, seq: int, heads: int, key_dim: int,
-              value_dim: int, chunk: int) -> Dict[str, Any]:
-    """What ``gated_delta_rule`` does with these shapes: the chunk it uses
-    (no longer than the sequence), the chunks, how many a step of the walk
-    takes (``walk``: the largest divisor of the chunks within
-    ``WALK_BYTES``), the steps, and the float32 bytes a step puts in HBM
-    (pair matrices and carried states) beside what all chunks at once
-    would."""
+              value_dim: int, chunk: int, mesh=None) -> Dict[str, Any]:
+    """What ``gated_delta_rule`` does with these shapes, and in which
+    ``form``. Both forms: the chunk it uses (no longer than the sequence),
+    the chunks, the ``steps`` (of the walk, or of the kernels' grid along
+    the sequence), ``chunks_a_call`` (what one step takes), ``states_kept``
+    (the float32 states a backward starts from, one a step) and the
+    float32 bytes the form puts in HBM beside what all chunks' pair
+    matrices at once would. ``xla_walk``: ``walk`` (= ``chunks_a_call``,
+    the largest divisor of the chunks within ``WALK_BYTES``) and the bytes
+    of one step's pair matrices and carried states. ``pallas``:
+    ``heads_a_block`` (the largest divisor of the heads within
+    ``KERNEL_HEADS``), ``KERNEL_CHUNKS`` chunks a step (all of a shorter
+    sequence), and the bytes of the kept states, the last state and the
+    running sums (in their two layouts) and ``beta``: nothing ``[chunk,
+    chunk]``."""
     chunk = min(chunk, seq)
     chunks = -(-seq // chunk)
     one = batch * heads * 4 * (4 * chunk * chunk + value_dim * key_dim)
+    plan = {"seq": seq, "chunk": chunk, "chunks": chunks, "heads": heads,
+            "key_dim": key_dim, "value_dim": value_dim,
+            "float32_bytes_all_chunks": chunks * one}
+    if _kernel_takes(chunk, mesh):
+        call = min(KERNEL_CHUNKS, chunks)
+        steps = -(-chunks // call)
+        state = batch * heads * value_dim * key_dim * 4
+        return dict(plan, form="pallas", walk=None, steps=steps,
+                    heads_a_block=_heads_a_block(heads),
+                    chunks_a_call=call, states_kept=steps,
+                    float32_bytes_in_hbm=(steps + 1) * state
+                    + 3 * batch * heads * steps * call * chunk * 4)
     walk = max(w for w in range(1, chunks + 1)
                if chunks % w == 0 and (w == 1 or w * one <= WALK_BYTES))
-    return {"seq": seq, "chunk": chunk, "chunks": chunks, "walk": walk,
-            "steps": chunks // walk, "heads": heads, "key_dim": key_dim,
-            "value_dim": value_dim, "form": FORM,
-            "float32_bytes_in_hbm": walk * one,
-            "float32_bytes_all_chunks": chunks * one}
+    return dict(plan, form="xla_walk", walk=walk, steps=chunks // walk,
+                heads_a_block=None, chunks_a_call=walk,
+                states_kept=chunks // walk, float32_bytes_in_hbm=walk * one)
 
 
 def _unit_lower_inverse(A: jax.Array) -> jax.Array:
@@ -179,27 +248,31 @@ def _walk_step(S, xs, dtype):
 
 
 def gated_delta_rule(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
-                     beta: jax.Array, chunk: int = 64
+                     beta: jax.Array, chunk: int = 64, mesh=None
                      ) -> Tuple[jax.Array, jax.Array]:
     """q and k [b, s, H, K] (k of unit length, q scaled as the caller
     wants its outputs), v [b, s, H, V], g [b, s, H] float32 (the log of the
     decay, not positive), beta [b, s, H] float32 -> (o [b, s, H, V] in
     ``v``'s dtype, the state after the last position [b, H, V, K]
-    float32)."""
+    float32). ``mesh``: the one the caller's arrays are sharded over, if
+    any. Which form runs is read from the call (``_kernel_takes``), and
+    the kept span ``rtpu.gdn.rule_plan`` says which."""
     b, s, H, K = q.shape
     V = v.shape[-1]
-    plan = rule_plan(b, s, H, K, V, chunk)
+    plan = rule_plan(b, s, H, K, V, chunk, mesh)
     with tracing.span("rtpu.gdn.rule_plan", keep=True, **plan):
         pass
+    if plan["form"] == "pallas":
+        # looked up at trace time: a test hands it the interpreter
+        return rule_kernels(q, k, v, g, beta, plan)
     C, W, steps = plan["chunk"], plan["walk"], plan["steps"]
     pad = plan["chunks"] * C - s
     dtype = v.dtype
 
     def stepped(a):
         # [b, s, ...] -> [steps, b, W, C, ...]
-        if pad:
-            a = jnp.pad(a, ((0, 0), (0, pad)) + ((0, 0),) * (a.ndim - 2))
-        return jnp.moveaxis(a.reshape((b, steps, W, C) + a.shape[2:]), 1, 0)
+        return jnp.moveaxis(_padded(a, pad).reshape(
+            (b, steps, W, C) + a.shape[2:]), 1, 0)
 
     xs = (stepped(q), stepped(k), stepped(v), stepped(g.astype(jnp.float32)),
           stepped(beta.astype(jnp.float32)))
@@ -209,6 +282,483 @@ def gated_delta_rule(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
     S, o = jax.lax.scan(step, jnp.zeros((b, H, V, K), jnp.float32), xs)
     o = jnp.moveaxis(o, 0, 1).reshape(b, steps * W * C, H, V)
     return o[:, :s], S
+
+
+def _padded(a, pad):
+    """a [b, s, ...] with ``pad`` more positions of zeros: ``g = 0``,
+    ``beta = 0`` and zero rows move neither state nor output."""
+    if not pad:
+        return a
+    return jnp.pad(a, ((0, 0), (0, pad)) + ((0, 0),) * (a.ndim - 2))
+
+
+# ---- the rule as Pallas (Mosaic) kernels. A grid step takes ``hb`` heads
+# and ``n`` chunks of one batch row: q and k [1, hb, n C, K], v and o [1,
+# hb, n C, V], positions on the sublanes; a chunk's running sums and beta
+# come down the sublanes (``cols`` [C, 2 n]: what scales a row), the sums
+# along the lanes too (``rows`` [n, C]: a pair's other end), so that no
+# [C, C] is ever transposed. The state is carried transposed, ``P = S^T
+# [K, V]``, in the block of the last-state output, which stays in VMEM
+# while the grid walks a head's sequence.
+
+
+def _nt(a, b, **kw):
+    """a [m, d], b [n, d] -> a b^T [m, n], float32."""
+    return jax.lax.dot_general(a, b, (((1,), (1,)), ((), ())),
+                               preferred_element_type=jnp.float32, **kw)
+
+
+def _tn(a, b, **kw):
+    """a [d, m], b [d, n] -> a^T b [m, n], float32."""
+    return jax.lax.dot_general(a, b, (((0,), (0,)), ((), ())),
+                               preferred_element_type=jnp.float32, **kw)
+
+
+def _nn(a, b, **kw):
+    return jnp.dot(a, b, preferred_element_type=jnp.float32, **kw)
+
+
+def _at(shape):
+    """(row, column) of every entry of a 2-D ``shape``."""
+    return (jax.lax.broadcasted_iota(jnp.int32, shape, 0),
+            jax.lax.broadcasted_iota(jnp.int32, shape, 1))
+
+
+def _kernel_sums(g):
+    """g [..., C, H] float32 -> its running sums down a chunk (the
+    kernels' ``G``; looked up at trace time, as ``_kernel_decays`` is)."""
+    return jnp.cumsum(g, axis=-2)
+
+
+def _kernel_decays(Gc, Gr):
+    """Every decay a chunk's kernels use, formed here and nowhere else:
+    Gc [C, 1] and Gr [1, C], the chunk's running sums down the sublanes
+    and along the lanes -> ``pair`` [C, C] (``exp(G_i - G_j)`` on and
+    under the diagonal, zero above), ``grown`` (``exp(G)``) and ``to_end``
+    (``exp(G_end - G)``), both [C, 1], and ``whole`` (``exp(G_end)`` [1,
+    1]), float32."""
+    C = Gc.shape[0]
+    row, col = _at((C, C))
+    # (the last sum by a masked sum: Mosaic does not spread a [1, 1] that
+    # it sliced from the last sublane over lanes and sublanes at once)
+    end = jnp.where(row[:, :1] == C - 1, Gc, 0.0).sum(0, keepdims=True)
+    return {"pair": jnp.exp(jnp.where(row >= col, Gc - Gr, -jnp.inf)),
+            "grown": jnp.exp(Gc), "to_end": jnp.exp(end - Gc),
+            "whole": jnp.exp(end)}
+
+
+def _kernel_state(P):
+    """The state a chunk starts from, as the kernels carried it to there
+    (P [K, V] float32; looked up at trace time)."""
+    return P
+
+
+def _kernel_inverse(A: List[jax.Array]) -> List[jax.Array]:
+    """``(I + A)^-1`` of several chunks at once, inside a kernel: each
+    ``A`` [C, C] float32 and zero on and above the diagonal; as many
+    chunks at a time as fill a register's 128 lanes (``_inverse_group``)."""
+    per = max(1, 128 // A[0].shape[0])
+    return [T for first in range(0, len(A), per)
+            for T in _inverse_group(A[first:first + per])]
+
+
+def _inverse_group(A: List[jax.Array]) -> List[jax.Array]:
+    """The diagonal tiles of ``KERNEL_BASE`` rows by substitution, every
+    tile of every chunk side by side on the lanes ([base, chunks * C]: an
+    update is whole registers) and every update straight-line code: with
+    ``t`` a tile's inverse, begun as the identity, step ``j`` takes ``a[i,
+    j] t[j, :]`` from every row ``i`` under ``j`` (row ``j`` is final by
+    then), for all tiles at once: row ``j`` spread down the sublanes,
+    column ``j`` of each tile's ``a`` spread over the tile's lanes by one
+    gather along the lanes. Nothing is summed across lanes, and what a
+    step waits for is a multiply and a subtraction. Then the joins, as the
+    XLA form's, the chunks block-diagonal in one matrix: with ``Td`` the
+    inverses of the diagonal blocks of ``m`` rows and ``M`` the entries of
+    ``A`` that join two of them, ``Td - (Td M) Td`` holds the diagonal
+    blocks of ``2 m``, float32 at the highest precision. Exact whatever
+    the keys are."""
+    C, n = A[0].shape[0], len(A)
+    base, wide = min(KERNEL_BASE, C), len(A) * C
+    f32 = jnp.float32
+    if n > 1:
+        zero = jnp.zeros((C, C), f32)
+        both = jnp.concatenate([jnp.concatenate(
+            [a if i == at else zero for i in range(n)], axis=1)
+            for at, a in enumerate(A)], axis=0)
+    else:
+        both = A[0]
+    row, col = _at((wide, wide))
+    _, lane = _at((base, wide))
+    packed = both[:base]
+    for t in range(1, wide // base):
+        packed = jnp.where(lane // base == t,
+                           both[t * base:(t + 1) * base], packed)
+    lanes = -(-wide // 128) * 128           # a gather takes whole registers
+    if lanes != wide:
+        packed = jnp.concatenate(
+            [packed, jnp.zeros((base, lanes - wide), f32)], axis=1)
+    at_row, at_col = _at((base, lanes))
+    first = at_col - at_col % base          # a tile's first lane
+    P = (at_row == at_col % base).astype(f32)
+    for j in range(base - 1):
+        P = P - (jnp.take_along_axis(packed, first + j, axis=1)
+                 * jnp.broadcast_to(P[j:j + 1], P.shape))
+    T = jnp.where(row // base == col // base,
+                  jnp.concatenate([P[:, :wide]] * (wide // base), axis=0)
+                  if wide > base else P[:, :wide], 0.0)
+    m = base
+    while m < C:
+        join = jnp.where((row // (2 * m) == col // (2 * m))
+                         & (row // m != col // m), both, 0.0)
+        high = jax.lax.Precision.HIGHEST
+        T = T - _nn(_nn(T, join, precision=high), T, precision=high)
+        m *= 2
+    return [T[i * C:(i + 1) * C, i * C:(i + 1) * C] for i in range(n)]
+
+
+def _chunk_local(q, k, v, Gc, Gr, bc, dt):
+    """What a chunk's kernels build from its own rows alone, the state
+    aside: q and k [C, K], v [C, V], the running sums down the sublanes
+    (``Gc`` [C, 1]) and along the lanes (``Gr`` [1, C]) and beta down the
+    sublanes -> those (``q``, ``k``, ``bc``), the decays
+    (``_kernel_decays``), ``kkd`` (``K K^T`` times
+    the pair decays) and ``A`` float32, the rows in float32 (``qf``, ``kf``,
+    ``vf``), the MXU's operands ``Kb`` (``beta exp(G) K``), ``Vb`` (``beta
+    V``), ``Qg`` (``exp(G) Q``), ``Ke`` (K decayed to the chunk's end) in
+    ``dt``, and ``Mf`` (``Q K^T`` times the pair decays, float32). Where
+    two products share their right-hand side their left-hand sides are
+    stacked, here k over q: the MXU holds the right-hand side's tile while
+    the rows stream, and 64 rows leave it waiting for the next tile."""
+    f32 = jnp.float32
+    d = _kernel_decays(Gc, Gr)
+    C = k.shape[0]
+    row, col = _at(d["pair"].shape)
+    with_k = _nt(jnp.concatenate([k, q], axis=0), k)
+    kkd = with_k[:C] * d["pair"]
+    qf, kf, vf = q.astype(f32), k.astype(f32), v.astype(f32)
+    return dict(
+        d, q=q, k=k, bc=bc, kkd=kkd, qf=qf, kf=kf, vf=vf,
+        A=jnp.where(row > col, bc * kkd, 0.0),
+        Kb=(kf * (bc * d["grown"])).astype(dt), Vb=(vf * bc).astype(dt),
+        Qg=(qf * d["grown"]).astype(dt), Ke=(kf * d["to_end"]).astype(dt),
+        Mf=with_k[C:] * d["pair"])
+
+
+def _rows_of(j, C):
+    """Chunk ``j``'s rows of a grid step's block (``j`` a loop's index)."""
+    import jax.experimental.pallas as pl
+
+    return pl.ds(pl.multiple_of(j * C, C), C)
+
+
+def _column(cols, j):
+    """Column ``j`` of ``cols`` [C, 2 n] as [C, 1], ``j`` a loop's index (a
+    lane cannot be sliced at one: the others are masked out of a sum)."""
+    _, lane = _at(cols.shape)
+    return jnp.where(lane == j, cols, 0.0).sum(1, keepdims=True)
+
+
+def _chunk_of(j, h, q_ref, k_ref, v_ref, cols_ref, rows_ref):
+    """``_chunk_local`` of chunk ``j`` of a grid step's head ``h``."""
+    import jax.experimental.pallas as pl
+
+    C, n = rows_ref.shape[-1], rows_ref.shape[-2]
+    at, cols = _rows_of(j, C), cols_ref[0, h, 0]
+    return _chunk_local(
+        q_ref[0, h, at, :], k_ref[0, h, at, :], v_ref[0, h, at, :],
+        _column(cols, j), rows_ref[0, h, 0, pl.ds(j, 1), :],
+        _column(cols, n + j), v_ref.dtype)
+
+
+def _chunks_of(j, q_ref, *refs):
+    """Chunk ``j`` of every head of a grid step, with every chunk's
+    ``T``, ``W`` (in the activations' dtype) and ``U``."""
+    dt = refs[1].dtype
+    local = [_chunk_of(j, h, q_ref, *refs) for h in range(q_ref.shape[1])]
+    # ``_kernel_inverse`` is looked up at trace time, as ``_kernel_state``
+    # and ``_kernel_decays`` are: the controls' three seams
+    for x, T in zip(local, _kernel_inverse([x["A"] for x in local])):
+        Tb = T.astype(dt)
+        x.update(T=T, W=_nn(Tb, x["Kb"]).astype(dt), U=_nn(Tb, x["Vb"]))
+    return local
+
+
+def _handed_on(x, P, dt):
+    """(V' in ``dt``, the state after the chunk, the chunk's output
+    float32): ``x`` the chunk's matrices with ``W`` and ``U``, P [K, V] the
+    state it starts from. W over Qg against the state, M over Ke^T against
+    V'."""
+    C = x["W"].shape[0]
+    on_state = _nn(jnp.concatenate([x["W"], x["Qg"]], axis=0), P.astype(dt))
+    new = (x["U"] - on_state[:C]).astype(dt)
+    on_new = _nn(jnp.concatenate([x["Mf"].astype(dt), x["Ke"].T], axis=0),
+                 new)
+    return new, x["whole"] * P + on_new[C:], on_state[C:] + on_new[:C]
+
+
+def _rule_fwd_kernel(q_ref, k_ref, v_ref, cols_ref, rows_ref, o_ref,
+                     last_ref, *kept_ref, chunks):
+    """A grid step of the forward: its chunks one after another (a loop,
+    its body traced once: what overlaps is a chunk's heads), the state in
+    ``last_ref``'s block from step to step."""
+    import jax.experimental.pallas as pl
+
+    C, dt = cols_ref.shape[3], v_ref.dtype
+
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        last_ref[...] = jnp.zeros_like(last_ref)
+
+    for ref in kept_ref:                    # the state this step starts from
+        ref[0, :, 0] = last_ref[0]
+
+    def chunk(j, carry):
+        for h, x in enumerate(_chunks_of(j, q_ref, k_ref, v_ref, cols_ref,
+                                         rows_ref)):
+            _, last_ref[0, h], o = _handed_on(
+                x, _kernel_state(last_ref[0, h]), dt)
+            o_ref[0, h, _rows_of(j, C), :] = o.astype(o_ref.dtype)
+        return carry
+
+    jax.lax.fori_loop(0, chunks, chunk, 0)
+
+
+def _rule_bwd_kernel(q_ref, k_ref, v_ref, cols_ref, rows_ref, kept_ref,
+                     do_ref, dlast_ref, dq_ref, dk_ref, dv_ref, dcols_ref,
+                     drows_ref, state_ref, T_ref, W_ref, new_ref, dstate_ref,
+                     *, chunks):
+    """A grid step of the backward, the steps taken last first: the
+    chunks' states, ``T``, ``W`` and ``V'`` built again from the state the
+    step started from (``kept_ref``) into scratch, then the chunks last
+    first, ``dstate_ref`` carrying the state's cotangent from step to
+    step."""
+    import jax.experimental.pallas as pl
+
+    n, heads = chunks, q_ref.shape[1]
+    C, dt = cols_ref.shape[3], v_ref.dtype
+    refs = (q_ref, k_ref, v_ref, cols_ref, rows_ref)
+
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        dstate_ref[...] = dlast_ref[0]
+
+    state_ref[0] = kept_ref[0, :, 0]
+    dcols_ref[...] = jnp.zeros_like(dcols_ref)
+
+    def again(j, carry):
+        for h, x in enumerate(_chunks_of(j, *refs)):
+            P = _kernel_state(state_ref[j, h])
+            state_ref[j, h], T_ref[j, h], W_ref[j, h] = P, x["T"], x["W"]
+            new_ref[j, h], state_ref[j + 1, h], _ = _handed_on(x, P, dt)
+        return carry
+
+    jax.lax.fori_loop(0, n, again, 0)
+    row, col = _at((C, C))
+    _, lane = _at((C, 2 * n))
+
+    def back(i, carry):
+        j = n - 1 - i
+        at = _rows_of(j, C)
+        for h in range(heads):
+            x = _chunk_of(j, h, *refs)
+            q, k, bc = x["q"], x["k"], x["bc"]
+            P, T, W, new = (state_ref[j, h], T_ref[j, h], W_ref[j, h],
+                            new_ref[j, h])
+            Pb, Tb = P.astype(dt), T.astype(dt)
+            dO, dP = do_ref[0, h, at, :], dstate_ref[h]
+            dPb = dP.astype(dt)
+            # O = Qg P + M V',  P' = whole P + Ke^T V',  V' = U - W P
+            dnew = _tn(x["Mf"].astype(dt), dO) + _nn(x["Ke"], dPb)
+            dnewb = dnew.astype(dt)
+            dMf = jnp.where(row >= col, _nt(dO, new), 0.0)
+            dQg, dKe = _nt(dO, Pb), _nt(new, dPb)
+            dW = (-_nt(dnewb, Pb)).astype(dt)
+            dwhole = jnp.sum(P * dP, keepdims=True)
+            dstate_ref[h] = (x["whole"] * dP + _tn(x["Qg"], dO)
+                             - _tn(W, dnewb))
+            # W = T Kb, U = T Vb; dA = -T^T dT T^T under the diagonal
+            dT = _nt(dW, x["Kb"]) + _nt(dnewb, x["Vb"])
+            dKb, dVb = _tn(Tb, dW), _tn(Tb, dnewb)
+            dA = jnp.where(row > col, -_tn(
+                Tb, _nt(dT.astype(dt), Tb).astype(dt)), 0.0)
+            # A = beta (K K^T pair), M = Q K^T pair
+            dkk = (dA * bc * x["pair"]).astype(dt)
+            dqk = (dMf * x["pair"]).astype(dt)
+            moved = dA * x["A"] + dMf * x["Mf"]      # d pair * pair
+            along_k = (dKb * x["kf"]).sum(1, keepdims=True)
+            dto_end = (dKe * x["kf"]).sum(1, keepdims=True) * x["to_end"]
+            dq_ref[0, h, at, :] = (dQg * x["grown"] + _nn(dqk, k)
+                                   ).astype(dq_ref.dtype)
+            dk_ref[0, h, at, :] = (
+                _tn(dqk, q) + _nn(dkk, k) + _tn(dkk, k)
+                + dKb * (bc * x["grown"]) + dKe * x["to_end"]
+            ).astype(dk_ref.dtype)
+            dv_ref[0, h, at, :] = (dVb * bc).astype(dv_ref.dtype)
+            dbeta = ((dA * x["kkd"]).sum(1, keepdims=True)
+                     + along_k * x["grown"]
+                     + (dVb * x["vf"]).sum(1, keepdims=True))
+            # G: through exp(G), exp(G_end - G), exp(G_end) and the pairs
+            dGc = (((dQg * x["qf"]).sum(1, keepdims=True) + along_k * bc)
+                   * x["grown"] - dto_end + moved.sum(1, keepdims=True))
+            dGc = dGc + jnp.where(
+                row[:, :1] == C - 1,
+                dto_end.sum(0, keepdims=True) + dwhole * x["whole"], 0.0)
+            dcols_ref[0, h, 0] = jnp.where(lane == j, dGc, jnp.where(
+                lane == n + j, dbeta, dcols_ref[0, h, 0]))
+            drows_ref[0, h, 0, pl.ds(j, 1), :] = -moved.sum(0, keepdims=True)
+        return carry
+
+    jax.lax.fori_loop(0, n, back, 0)
+
+
+def _rule_specs(q, v, cols, steps):
+    """What both calls share: the grid (batch row, block of heads, step
+    along the sequence) and the operands' block shapes."""
+    b, H, _, K = q.shape
+    V, (C, n2) = v.shape[-1], cols.shape[3:]
+    hb = _heads_a_block(H)
+    L = C * n2 // 2
+    return {"grid": (b, H // hb, steps), "hb": hb, "chunks": n2 // 2,
+            "qk": (1, hb, L, K), "v": (1, hb, L, V),
+            "cols": (1, hb, 1, C, n2), "rows": (1, hb, 1, n2 // 2, C),
+            "state": (1, hb, K, V), "kept": (1, hb, 1, K, V)}
+
+
+def _compiler_params():
+    from jax.experimental.pallas import tpu as pltpu
+
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "arbitrary"),
+        vmem_limit_bytes=64 << 20)
+
+
+def _rule_forward(q, k, v, cols, rows, keep, interpret):
+    """q, k [b, H, S, K], v [b, H, S, V], cols [b, H, steps, C, 2 n], rows
+    [b, H, steps, n, C] -> (o [b, H, S, V], the last state transposed [b,
+    H, K, V] float32, and with ``keep`` the state before every step [b, H,
+    steps, K, V])."""
+    import jax.experimental.pallas as pl
+
+    b, H, S, K = q.shape
+    V, steps = v.shape[-1], cols.shape[2]
+    at = _rule_specs(q, v, cols, steps)
+    f32 = jnp.float32
+
+    def seq(i, h, t):
+        return (i, h, t, 0)
+
+    def step(i, h, t):
+        return (i, h, t, 0, 0)
+
+    return pl.pallas_call(
+        functools.partial(_rule_fwd_kernel, chunks=at["chunks"]),
+        name="delta_rule_fwd",
+        out_shape=[jax.ShapeDtypeStruct((b, H, S, V), v.dtype),
+                   jax.ShapeDtypeStruct((b, H, K, V), f32)]
+        + [jax.ShapeDtypeStruct((b, H, steps, K, V), f32)] * keep,
+        grid=at["grid"],
+        in_specs=[pl.BlockSpec(at["qk"], seq), pl.BlockSpec(at["qk"], seq),
+                  pl.BlockSpec(at["v"], seq), pl.BlockSpec(at["cols"], step),
+                  pl.BlockSpec(at["rows"], step)],
+        out_specs=[pl.BlockSpec(at["v"], seq),
+                   pl.BlockSpec(at["state"], lambda i, h, t: (i, h, 0, 0))]
+        + [pl.BlockSpec(at["kept"], step)] * keep,
+        compiler_params=_compiler_params(), interpret=interpret,
+    )(q, k, v, cols, rows)
+
+
+def _rule_backward(q, k, v, cols, rows, kept, do, dlast, interpret):
+    import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    K, V, steps = q.shape[-1], v.shape[-1], cols.shape[2]
+    at = _rule_specs(q, v, cols, steps)
+    n, hb, C = at["chunks"], at["hb"], cols.shape[3]
+    f32 = jnp.float32
+
+    def seq(i, h, t):
+        return (i, h, steps - 1 - t, 0)
+
+    def step(i, h, t):
+        return (i, h, steps - 1 - t, 0, 0)
+
+    def whole(i, h, t):
+        return (i, h, 0, 0)
+
+    return pl.pallas_call(
+        functools.partial(_rule_bwd_kernel, chunks=n),
+        name="delta_rule_bwd",
+        out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
+                   jax.ShapeDtypeStruct(k.shape, k.dtype),
+                   jax.ShapeDtypeStruct(v.shape, v.dtype),
+                   jax.ShapeDtypeStruct(cols.shape, f32),
+                   jax.ShapeDtypeStruct(rows.shape, f32)],
+        grid=at["grid"],
+        in_specs=[pl.BlockSpec(at["qk"], seq), pl.BlockSpec(at["qk"], seq),
+                  pl.BlockSpec(at["v"], seq), pl.BlockSpec(at["cols"], step),
+                  pl.BlockSpec(at["rows"], step),
+                  pl.BlockSpec(at["kept"], step), pl.BlockSpec(at["v"], seq),
+                  pl.BlockSpec(at["state"], whole)],
+        out_specs=[pl.BlockSpec(at["qk"], seq), pl.BlockSpec(at["qk"], seq),
+                   pl.BlockSpec(at["v"], seq), pl.BlockSpec(at["cols"], step),
+                   pl.BlockSpec(at["rows"], step)],
+        scratch_shapes=[pltpu.VMEM((n + 1, hb, K, V), f32),
+                        pltpu.VMEM((n, hb, C, C), f32),
+                        pltpu.VMEM((n, hb, C, K), v.dtype),
+                        pltpu.VMEM((n, hb, C, V), v.dtype),
+                        pltpu.VMEM((hb, K, V), f32)],
+        compiler_params=_compiler_params(), interpret=interpret,
+    )(q, k, v, cols, rows, kept, do, dlast)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def _rule_calls(q, k, v, cols, rows, interpret):
+    return tuple(_rule_forward(q, k, v, cols, rows, 0, interpret))
+
+
+def _rule_calls_fwd(q, k, v, cols, rows, interpret):
+    o, last, kept = _rule_forward(q, k, v, cols, rows, 1, interpret)
+    return (o, last), (q, k, v, cols, rows, kept)
+
+
+def _rule_calls_bwd(interpret, res, cts):
+    return tuple(_rule_backward(*res, *cts, interpret))
+
+
+_rule_calls.defvjp(_rule_calls_fwd, _rule_calls_bwd)
+
+
+def rule_kernels(q, k, v, g, beta, plan, interpret: bool = False):
+    """``gated_delta_rule`` as two Mosaic calls, ``delta_rule_fwd`` and
+    ``delta_rule_bwd`` behind a ``custom_vjp`` (``plan``: ``rule_plan``'s,
+    of the form ``pallas``). Around them, in XLA: the operands head-major
+    ([b, H, S, .], S whole grid steps), the running sums of ``g`` down each
+    chunk, and those and ``beta`` in the two layouts the kernels read
+    (module comment above). The forward carries the state in VMEM and,
+    when a gradient is asked for, writes the state before every grid step
+    (``states_kept``); the backward takes the steps last first, builds a
+    step's chunks again from that state and carries the state's
+    cotangent."""
+    b, s, H, _ = q.shape
+    C, n, steps = plan["chunk"], plan["chunks_a_call"], plan["steps"]
+    pad = steps * n * C - s
+    f32 = jnp.float32
+
+    def by_head(a):
+        return jnp.moveaxis(_padded(a, pad), 2, 1)
+
+    def twice(G, beta_):
+        """[b, steps, n, C, H] each -> (cols [b, H, steps, C, 2 n], rows
+        [b, H, steps, n, C])."""
+        return (jnp.transpose(jnp.concatenate([G, beta_], axis=2),
+                              (0, 4, 1, 3, 2)),
+                jnp.transpose(G, (0, 4, 1, 2, 3)))
+
+    g, beta = (_padded(a.astype(f32), pad).reshape(b, steps, n, C, H)
+               for a in (g, beta))
+    o, last = _rule_calls(by_head(q), by_head(k), by_head(v),
+                          *twice(_kernel_sums(g), beta), interpret)
+    return jnp.moveaxis(o, 1, 2)[:, :s], jnp.swapaxes(last, -1, -2)
 
 
 def _gates(a, b_, p):
@@ -257,7 +807,8 @@ def gated_delta_mixer(h: jax.Array, p: Dict[str, jax.Array], *, heads: int,
             # ``_gates`` and ``l2_norm`` are looked up at trace time too
             g, beta = _gates(a, b_, p)
             o, S = gated_delta_rule(l2_norm(q, scale=key_dim ** -0.5),
-                                    l2_norm(k), v, g, beta, chunk=chunk)
+                                    l2_norm(k), v, g, beta, chunk=chunk,
+                                    mesh=mesh)
             S = jax.lax.stop_gradient(S)
         with jax.named_scope("gdn_norm"):
             y = gated_rms_norm(o, z.reshape(b, s, heads, value_dim),

@@ -1616,7 +1616,9 @@ def test_rule_plan_walks_within_its_bytes():
     assert (plan["chunks"], plan["walk"], plan["steps"]) == (512, 8, 64)
     assert plan["float32_bytes_in_hbm"] == 8 * one <= delta.WALK_BYTES
     assert plan["float32_bytes_all_chunks"] == 512 * one
-    assert plan["form"] == "xla_walk"
+    # the CPU runs XLA's walk, and so does any call under a mesh
+    assert plan["form"] == "xla_walk" and plan["heads_a_block"] is None
+    assert plan["chunks_a_call"] == 8 and plan["states_kept"] == 64
     small = delta.rule_plan(2, 30, 4, 8, 16, 64)
     assert (small["chunk"], small["chunks"], small["walk"]) == (30, 1, 1)
     # one chunk's matrices past the budget: still one chunk a step
@@ -1624,6 +1626,40 @@ def test_rule_plan_walks_within_its_bytes():
     # 12 chunks, room for 9: the largest divisor within it
     odd = delta.rule_plan(1, 768, 30, 96, 192, 64)
     assert (odd["chunks"], odd["walk"], odd["steps"]) == (12, 6, 2)
+
+
+def test_rule_plan_of_the_kernels_keeps_states_and_no_pair_matrix(
+        monkeypatch):
+    """On a TPU backend without a mesh the published shapes run as the
+    kernels: 10 heads a block, 8 chunks a grid step, the state before each
+    of the 64 steps kept for the backward (141 MB of the 149 MB of float32
+    the form puts in HBM, where a step of XLA's walk put 33 MB of pair
+    matrices and all chunks at once 2.1 GB); under a mesh, on the CPU, for
+    a chunk that is not whole tiles or a sequence shorter than a chunk,
+    XLA's walk."""
+    from ray_tpu.ops import delta
+
+    shapes = (1, 32768, 30, 96, 192, 64)
+    assert delta.rule_plan(*shapes)["form"] == "xla_walk"
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    plan = delta.rule_plan(*shapes)
+    assert (plan["form"], plan["heads_a_block"], plan["chunks_a_call"],
+            plan["steps"], plan["states_kept"], plan["walk"]) == (
+        "pallas", 10, 8, 64, 64, None)
+    state = 30 * 192 * 96 * 4
+    assert plan["float32_bytes_in_hbm"] == 65 * state + 3 * 30 * 32768 * 4
+    assert plan["float32_bytes_all_chunks"] == 512 * 30 * 4 * (
+        4 * 64 * 64 + 192 * 96)
+    assert delta.rule_plan(*shapes, mesh=object())["form"] == "xla_walk"
+    # 14 heads: the largest divisor within 10; 3 chunks: all in one step
+    odd = delta.rule_plan(2, 192, 14, 96, 192, 64)
+    assert (odd["form"], odd["heads_a_block"], odd["chunks_a_call"],
+            odd["steps"]) == ("pallas", 7, 3, 1)
+    # 9 chunks: two steps of 8, the second padded
+    assert delta.rule_plan(1, 520, 30, 96, 192, 64)["steps"] == 2
+    for seq, chunk in ((30, 64), (256, 24), (256, 48)):
+        assert delta.rule_plan(1, seq, 30, 96, 192, chunk)["form"] == (
+            "xla_walk"), (seq, chunk)
 
 
 def test_l2_norm_and_gated_rms_norm_match_their_definitions():
@@ -1646,26 +1682,34 @@ def test_l2_norm_and_gated_rms_norm_match_their_definitions():
                           ).dtype == jnp.bfloat16
 
 
-@pytest.mark.parametrize("form", ["xla_taps", "pallas"])
-def test_gated_delta_mixer_matches_the_reference(form, monkeypatch):
+@pytest.mark.parametrize("form,rule", [
+    ("xla_taps", "xla_walk"), ("pallas", "xla_walk"), ("pallas", "pallas")],
+    ids=["xla_taps", "pallas", "pallas-rule"])
+def test_gated_delta_mixer_matches_the_reference(form, rule, monkeypatch):
     """The mixer (in-projection, taps and silu, L2 norms, the rule, the
     gated norm of each head, out-projection) against
     ``olmo_hybrid_ref.delta_mixer``: output, the last state and every
-    leaf's gradient, float32 at 1e-5; once as the CPU runs it and once
-    through the taps' kernels with their zero bias, as a TPU does (the
-    interpreter in Mosaic's place)."""
+    leaf's gradient, float32 at 1e-5; once as the CPU runs it, once
+    through the taps' kernels with their zero bias, and once with the rule
+    through its kernels too, as a TPU does (the interpreter in Mosaic's
+    place; the tiny chunk of 8 is whole tiles of 4 rows there)."""
     import functools
 
     from benchmark.references import olmo_hybrid_ref
     from ray_tpu.models import olmo_hybrid
-    from ray_tpu.ops import conv, ssm
+    from ray_tpu.ops import conv, delta, ssm
     from ray_tpu.ops.delta import gated_delta_mixer
 
     if form == "pallas":
         monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
         monkeypatch.setattr(ssm, "taps_silu", functools.partial(
             conv.taps_silu, interpret=True))
+    if rule == "pallas":
+        monkeypatch.setattr(delta, "KERNEL_BASE", 4)
+        monkeypatch.setattr(delta, "rule_kernels", functools.partial(
+            delta.rule_kernels, interpret=True))
     n0 = len(_conv_plans("rtpu.gdn.conv_plan"))
+    r0 = len(_conv_plans("rtpu.gdn.rule_plan"))
     cfg = olmo_hybrid.OlmoHybridConfig.tiny()
     p = {k: v[0] for k, v in olmo_hybrid.init_params(
         cfg, jax.random.PRNGKey(0))["layers"]["linear"].items()}
@@ -1699,14 +1743,32 @@ def test_gated_delta_mixer_matches_the_reference(form, monkeypatch):
                                    atol=1e-5 * scale, err_msg=str(path))
     assert {e["args"]["form"]
             for e in _conv_plans("rtpu.gdn.conv_plan")[n0:]} == {form}
+    assert {e["args"]["form"]
+            for e in _conv_plans("rtpu.gdn.rule_plan")[r0:]} == {rule}
 
 
-def test_gated_delta_mixer_is_float32_inside_and_names_its_scopes():
-    """bf16 activations in and out, the state float32; the optimized
-    program names the five scopes under ``gdn``, forward and backward."""
+@pytest.mark.parametrize("rule", ["xla_walk", "pallas"])
+def test_gated_delta_mixer_is_float32_inside_and_names_its_scopes(
+        rule, monkeypatch):
+    """bf16 activations in and out, the state float32; in both forms of
+    the rule every running sum and every decay is formed in float32
+    (each ``cumsum`` and ``exp`` of the traced program, the kernels'
+    bodies among them); the optimized program names the five scopes under
+    ``gdn``, forward and backward."""
+    import functools
+    import re
+
     from ray_tpu.models import olmo_hybrid
+    from ray_tpu.ops import conv, delta, ssm
     from ray_tpu.ops.delta import gated_delta_mixer
 
+    if rule == "pallas":
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        monkeypatch.setattr(ssm, "taps_silu", functools.partial(
+            conv.taps_silu, interpret=True))
+        monkeypatch.setattr(delta, "KERNEL_BASE", 4)
+        monkeypatch.setattr(delta, "rule_kernels", functools.partial(
+            delta.rule_kernels, interpret=True))
     cfg = olmo_hybrid.OlmoHybridConfig.tiny()
     p = {k: v[0].astype(jnp.bfloat16) for k, v in olmo_hybrid.init_params(
         cfg, jax.random.PRNGKey(0))["layers"]["linear"].items()}
@@ -1714,13 +1776,23 @@ def test_gated_delta_mixer_is_float32_inside_and_names_its_scopes():
                           jnp.bfloat16)
     kw = dict(heads=cfg.linear_heads, key_dim=cfg.linear_key_dim,
               value_dim=cfg.linear_value_dim, chunk=cfg.rule_chunk)
+    r0 = len(_conv_plans("rtpu.gdn.rule_plan"))
     out, last = gated_delta_mixer(u, p, **kw)
     assert out.dtype == jnp.bfloat16 and last.dtype == jnp.float32
     assert last.shape == (1, cfg.linear_heads, cfg.linear_value_dim,
                           cfg.linear_key_dim)
-    text = jax.jit(jax.grad(lambda p, u: jnp.square(gated_delta_mixer(
-        u, p, **kw)[0].astype(jnp.float32)).sum(),
-        argnums=(0, 1))).lower(p, u).as_text(
+    assert {e["args"]["form"]
+            for e in _conv_plans("rtpu.gdn.rule_plan")[r0:]} == {rule}
+
+    def loss(p, u):
+        return jnp.square(gated_delta_mixer(u, p, **kw)[0].astype(
+            jnp.float32)).sum()
+
+    formed = re.findall(r"(\w+)\[[^\]]*\] = (?:exp|cumsum)\b",
+                        str(jax.make_jaxpr(jax.grad(loss, argnums=(0, 1)))(
+                            p, u)))
+    assert len(formed) >= 5 and set(formed) == {"f32"}, formed
+    text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(p, u).as_text(
         debug_info=True)
     for scope in ("gdn_in", "gdn_conv", "gdn_rule", "gdn_norm", "gdn_out"):
         assert f"jvp(gdn)/{scope}" in text, scope

@@ -974,13 +974,14 @@ def test_granite_train_step_names_its_scopes_and_counts_its_state():
         assert re.search(rf'op_name="[^"]*[/(]{scope}[/)]', text), scope
 
 
-def test_gdn_rule_plan_is_one_kept_span_of_a_traced_call():
+def test_gdn_rule_plan_is_one_kept_span_of_a_traced_call(monkeypatch):
     """A traced ``gated_delta_rule`` writes what it will do once, as a kept
     span (no flag, no profiler window), as ``rtpu.ssm.scan_plan`` is
-    written: sequence, chunk, chunks, how many a step of the walk takes,
-    heads, a head's key and value sizes, the form and the float32 bytes
-    (pair matrices and carried states) a step puts in HBM beside what all
-    chunks at once would."""
+    written: sequence, chunk, chunks, heads, a head's key and value sizes,
+    the form that runs, what a step of it takes, the states a backward
+    keeps and the float32 bytes the form puts in HBM beside what all
+    chunks' pair matrices at once would: XLA's walk on the CPU and under
+    a mesh, the kernels on a TPU backend without one."""
     import jax
     import jax.numpy as jnp
 
@@ -1004,14 +1005,22 @@ def test_gdn_rule_plan_is_one_kept_span_of_a_traced_call():
 
     (cell,) = trace(32768)
     one = 30 * 4 * (4 * 64 * 64 + 192 * 96)
-    assert cell == {"seq": 32768, "chunk": 64, "chunks": 512, "walk": 8,
-                    "steps": 64, "heads": 30, "key_dim": 96,
-                    "value_dim": 192, "form": "xla_walk",
-                    "float32_bytes_in_hbm": 8 * one,
-                    "float32_bytes_all_chunks": 512 * one}
+    walk = {"seq": 32768, "chunk": 64, "chunks": 512, "walk": 8,
+            "steps": 64, "heads": 30, "key_dim": 96, "value_dim": 192,
+            "form": "xla_walk", "heads_a_block": None, "chunks_a_call": 8,
+            "states_kept": 64, "float32_bytes_in_hbm": 8 * one,
+            "float32_bytes_all_chunks": 512 * one}
+    assert cell == walk
     (short,) = trace(1000, chunk=128)
     assert (short["chunk"], short["chunks"], short["walk"],
             short["steps"]) == (128, 8, 4, 2)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    (kernels,) = trace(32768)
+    assert kernels == dict(
+        walk, form="pallas", walk=None, heads_a_block=10,
+        float32_bytes_in_hbm=65 * 30 * 192 * 96 * 4 + 3 * 30 * 32768 * 4)
+    (sharded,) = trace(32768, mesh=object())
+    assert sharded == walk
 
 
 def test_gdn_conv_plan_is_written_once_per_traced_mixer(monkeypatch):
