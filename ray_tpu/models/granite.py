@@ -231,7 +231,7 @@ def hidden(cfg: GraniteConfig, params, tokens: jax.Array, mesh=None
     level = llama.resolve_remat(
         cfg, params, tokens, mesh, param_shardings, pattern=pattern,
         head_tokens=llama.head_block(tokens.size, cfg.vocab_size),
-        scan=(cfg.ssm_groups, cfg.ssm_chunk)) if cfg.remat else None
+        scan=(cfg.ssm_groups, cfg.ssm_chunk, mesh)) if cfg.remat else None
     x, ys = llama.run_layers(
         {kind: layer_of(kind) for kind in params["layers"]}, x,
         params["layers"], level=level, scan=cfg.scan_layers, pattern=pattern)

@@ -250,7 +250,7 @@ def describe_stack(cfg: LlamaConfig, layers, tokens_per_device: int,
                    pattern: Optional[Tuple[str, ...]] = None, top_k: int = 0,
                    held: Optional[Tuple[int, int]] = None,
                    head_tokens: Optional[int] = None,
-                   scan: Optional[Tuple[int, int]] = None,
+                   scan: Optional[Tuple[int, ...]] = None,
                    rule: Optional[Tuple[int, int]] = None) -> Dict[str, Any]:
     """What ``remat_plan`` knows of a stack: its ``runs`` (``_runs``; one
     run of "layer" without a ``pattern``) and for each of its ``kinds`` the
@@ -263,8 +263,9 @@ def describe_stack(cfg: LlamaConfig, layers, tokens_per_device: int,
     routed mixture of ``top_k`` choices a token
     (``ops/moe.routed_experts``; ``held``: its ``held=``), a ``w_in`` a
     gated short convolution (``ops/conv.py``), an ``A_log`` a selective
-    scan (``ops/ssm.mamba2_mixer``; ``scan``: its groups and its chunk,
-    which are in no shape), a ``g_in`` a gated delta rule
+    scan (``ops/ssm.mamba2_mixer``; ``scan``: its groups, its chunk,
+    which are in no shape, and, where the arrays are sharded, the mesh:
+    which form the scan runs follows from it), a ``g_in`` a gated delta rule
     (``ops/delta.gated_delta_mixer``; ``rule``: a head's key size, the
     rule's chunk and, where the arrays are sharded, the mesh: which form
     the rule runs follows from it). A kind that has none of the four
@@ -310,20 +311,31 @@ def describe_stack(cfg: LlamaConfig, layers, tokens_per_device: int,
         if "A_log" in shape:
             # the in-projection's output (z, x B C, dt) and the taps'
             # output with their gradients, the gated output; beside them
-            # one step of the walk: its decay matrices, their product with
-            # C B^T in float32 and the activations' dtype and the
-            # gradients of those, and the state before every step. Held
-            # to the compiled step at 16,384, 24,576 and 32,768 tokens of
-            # a 9 : 1 stack at full remat: 2.5, 3.3 and 2.7% over what the
-            # compiler allots, 3.3% under at 8,192 (PERF.md 6, PR 36)
+            # what the scan's form puts in HBM (``scan_plan``). XLA's
+            # walk: one step's decay matrices, their product with C B^T in
+            # float32 and the activations' dtype and the gradients of
+            # those, and the state before every step. Held to the
+            # compiled step at 16,384, 24,576 and 32,768 tokens of a 9 : 1
+            # stack at full remat: 2.5, 3.3 and 2.7% over what the
+            # compiler allots, 3.3% under at 8,192 (PERF.md 6, PR 36). The
+            # kernels: the kept states and the running sums alone, and
+            # neither the in-projection's output nor the gated output is
+            # held a second time (the walk's float32 copies of x went with
+            # it, and the skip is the kernels'): 5.2% over at 32,768
+            # tokens, and still over a v5e's budget; a closer reckoning
+            # would hand the attention layer a rung (PERF.md 7, PR 41)
             heads, d = shape["A_log"][-1], shape["m_out"][0]
-            width += 2 * shape["m_in"][-1] + 2 * shape["m_conv"][0] + d
-            groups, chunk = scan
+            groups, chunk, *mesh = scan
             state = (shape["m_conv"][0] - d) // (2 * groups)
             plan = ssm.scan_plan(1, T, heads, d // heads, state, groups,
-                                 chunk)
-            rows += 4 * plan["decay_bytes_in_hbm"] + (
-                plan["steps"] * heads * (d // heads) * state * 4)
+                                 chunk, *mesh)
+            if plan["form"] == "pallas":
+                width += shape["m_in"][-1] + 2 * shape["m_conv"][0]
+                rows += plan["float32_bytes_in_hbm"]
+            else:
+                width += 2 * shape["m_in"][-1] + 2 * shape["m_conv"][0] + d
+                rows += 4 * plan["decay_bytes_in_hbm"] + (
+                    plan["steps"] * heads * (d // heads) * state * 4)
         if "g_in" in shape:
             # the in-projection's output (z, q k v, a b) and the taps'
             # output (their gradients lie where the SwiGLU's arrays did);

@@ -1221,20 +1221,54 @@ def test_ssd_scan_with_bfloat16_decays_is_another_function():
     np.testing.assert_array_equal(np.asarray(again), np.asarray(y))
 
 
-def test_scan_plan_walks_within_its_bytes():
-    """At the published shapes a step of the walk takes 8 chunks, 128 MB
+@pytest.mark.parametrize("form", ["xla_walk", "pallas"])
+def test_scan_plan_walks_within_its_bytes(form, monkeypatch):
+    """At the published shapes a step of XLA's walk takes 8 chunks, 128 MB
     of decay matrices where all 128 chunks at once would be 2.1 GB; a
-    short sequence is one chunk; the walk always divides the chunks."""
+    short sequence is one chunk; the walk always divides the chunks. The
+    kernels (a TPU backend, no mesh, a chunk of whole lane tiles) put no
+    decay matrix in HBM: ``KERNEL_CHUNKS`` chunks a grid step, the largest
+    divisor of a group's heads within ``KERNEL_HEADS`` a block, a state
+    kept a step; under a mesh and for a chunk of 30 the plan is the
+    walk's."""
     from ray_tpu.ops import ssm
 
+    if form == "pallas":
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     plan = ssm.scan_plan(1, 32768, 64, 64, 128, 1, 256)
-    assert (plan["chunks"], plan["walk"], plan["steps"]) == (128, 8, 16)
-    assert plan["decay_bytes_in_hbm"] == 8 * 64 * 256 * 256 * 4 \
-        <= ssm.WALK_BYTES
+    assert plan["form"] == form
     assert plan["decay_bytes_all_chunks"] == 2 ** 31
-    assert plan["form"] == "xla_walk"
     small = ssm.scan_plan(2, 30, 4, 8, 16, 2, 256)
-    assert (small["chunk"], small["chunks"], small["walk"]) == (30, 1, 1)
+    assert (small["form"], small["chunk"], small["chunks"],
+            small["walk"]) == ("xla_walk", 30, 1, 1)
+    if form == "pallas":
+        n = ssm.KERNEL_CHUNKS
+        assert (plan["chunks"], plan["walk"], plan["chunks_a_call"],
+                plan["steps"], plan["states_kept"]) == (
+                    128, None, n, 128 // n, 128 // n)
+        assert plan["heads_a_block"] == ssm.KERNEL_HEADS
+        assert plan["decay_bytes_in_hbm"] == 0
+        # the kept and the last states; dt, the sums, their gradients and
+        # the skip's; dB and dC
+        assert plan["float32_bytes_in_hbm"] == (
+            (128 // n + 1) * 64 * 64 * 128 * 4
+            + (5 * 64 + 2 * 128) * 32768 * 4)
+        # two groups of 6 heads: a block lies within a group
+        monkeypatch.setattr(ssm, "KERNEL_HEADS", 4)
+        assert ssm.scan_plan(1, 1024, 12, 64, 128, 2, 256)[
+            "heads_a_block"] == 3
+        # a short sequence is one grid step of all its chunks
+        short = ssm.scan_plan(1, 1000, 64, 64, 128, 1, 128)
+        assert (short["chunks"], short["chunks_a_call"], short["steps"]) == (
+            8, min(8, n), -(-8 // n))
+        sharded = ssm.scan_plan(1, 32768, 64, 64, 128, 1, 256, object())
+        assert (sharded["form"], sharded["walk"]) == ("xla_walk", 8)
+        return
+    assert (plan["chunks"], plan["walk"], plan["steps"]) == (128, 8, 16)
+    assert (plan["chunks_a_call"], plan["states_kept"],
+            plan["heads_a_block"]) == (8, 16, None)
+    assert plan["decay_bytes_in_hbm"] == plan["float32_bytes_in_hbm"] \
+        == 8 * 64 * 256 * 256 * 4 <= ssm.WALK_BYTES
     # one chunk's matrices past the budget: still one chunk a step
     assert ssm.scan_plan(64, 32768, 64, 64, 128, 1, 256)["walk"] == 1
     # 12 chunks, room for 8: the largest divisor within it
@@ -1320,13 +1354,16 @@ def test_taps_silu_kernels_match_causal_taps(taps, dtype, seq, wide, first,
     assert not beside.size or float(jnp.abs(beside).max()) == 0.0
 
 
-@pytest.mark.parametrize("form", ["xla_taps", "pallas"])
-def test_mamba2_mixer_matches_the_reference(form, monkeypatch):
+@pytest.mark.parametrize("form,scan", [
+    ("xla_taps", "xla_walk"), ("pallas", "xla_walk"), ("pallas", "pallas")])
+def test_mamba2_mixer_matches_the_reference(form, scan, monkeypatch):
     """The mixer (in-projection, taps with bias and silu, scan, skip,
     gated norm, out-projection) against ``granite_ref.mamba_mixer``:
     output, the last state and every leaf's gradient, float32 at 1e-5;
-    once as the CPU runs it and once through the taps' kernels, as a TPU
-    does (the interpreter in Mosaic's place)."""
+    once as the CPU runs it, once through the taps' kernels with XLA's
+    walk after them (a TPU with a chunk that is not whole lane tiles) and
+    once through the taps' and the scan's kernels, the skip ``D x`` inside
+    them, as a TPU runs the cell (the interpreter in Mosaic's place)."""
     import functools
 
     from benchmark.references import granite_ref
@@ -1338,7 +1375,12 @@ def test_mamba2_mixer_matches_the_reference(form, monkeypatch):
         monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
         monkeypatch.setattr(ssm, "taps_silu", functools.partial(
             conv.taps_silu, interpret=True))
-    n0 = len(_conv_plans())
+    if scan == "pallas":
+        monkeypatch.setattr(ssm, "scan_kernels", functools.partial(
+            ssm.scan_kernels, interpret=True))
+        monkeypatch.setattr(ssm, "KERNEL_LANES", 8)
+        monkeypatch.setattr(ssm, "KERNEL_CHUNKS", 2)
+    n0, scans0 = len(_conv_plans()), len(_conv_plans("rtpu.ssm.scan_plan"))
     cfg = granite.GraniteConfig.tiny()
     p = {k: v[0] for k, v in granite.init_params(
         cfg, jax.random.PRNGKey(0))["layers"]["mamba"].items()}
@@ -1374,6 +1416,8 @@ def test_mamba2_mixer_matches_the_reference(form, monkeypatch):
         np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=1e-4,
                                    atol=1e-5 * scale, err_msg=str(path))
     assert {e["args"]["form"] for e in _conv_plans()[n0:]} == {form}
+    assert {e["args"]["form"] for e in _conv_plans(
+        "rtpu.ssm.scan_plan")[scans0:]} == {scan}
 
 
 def _conv_plans(span="rtpu.ssm.conv_plan"):

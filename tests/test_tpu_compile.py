@@ -341,20 +341,28 @@ def mixer_gradient(S, no_compile_cache, monkeypatch):
 
 def test_mamba2_mixer_compiles_without_all_chunks_decay_matrices(
         mixer_gradient):
-    """The taps, their bias and the silu are the two Mosaic calls of
-    ``ops/conv.taps_silu``, one forward and one backward, and the scan has
-    none: XLA's fusions and matmuls, the chunks walked 8 at a time, so that
-    the decay matrices of all 128 chunks (2.1 GB in float32, and as much
-    again for the backward) never exist. The whole gradient's temporaries
-    are 2.74 GB (3.89 GB with XLA's taps, PR 36): the projections' outputs,
-    the gate's float32 passes and their gradients, where the matrices of
-    all chunks alone would be 4.3 GB."""
-    names = sorted(name for name, _ in _mosaic_calls(
-        mixer_gradient.as_text()))
-    assert len(names) == 2, names
-    assert "taps_silu_fwd" in names[1] and "taps_silu_bwd" in names[0], names
+    """The mixer's four Mosaic calls: the taps, their bias and the silu are
+    ``ops/conv.taps_silu``'s two, one forward and one backward, and the
+    scan ``ops/ssm.scan_kernels``' two, ``ssd_scan_fwd`` (the forward that
+    keeps its states) and ``ssd_scan_bwd``. A chunk's decay matrices live
+    in VMEM: no float32 array of ``[.., 256, 256]`` lies in HBM under
+    ``ssm_scan`` (XLA's walk put 8 chunks' there, 134 MB a step of 16, and
+    all 128 chunks' at once would be 2.1 GB and as much again for the
+    backward); the skip ``D x`` is the kernels' too. The whole
+    gradient's temporaries are under 2.2 GB (2.74 GB with XLA's walk, PR
+    37; 3.89 GB with XLA's taps too, PR 36): the projections' outputs,
+    the gate's passes and their gradients."""
+    text = mixer_gradient.as_text()
+    names = sorted(name for name, _ in _mosaic_calls(text))
+    assert len(names) == 4, names
+    for kernel in ("ssd_scan_bwd", "ssd_scan_fwd", "taps_silu_bwd",
+                   "taps_silu_fwd"):
+        assert any(kernel in name for name in names), (kernel, names)
+    in_hbm = [line for line in text.splitlines() if "ssm_scan" in line
+              and re.search(r"f32\[[0-9,]*256,256\]", line)]
+    assert not in_hbm, in_hbm[:3]
     assert (mixer_gradient.memory_analysis().temp_size_in_bytes
-            < 2.75 * 2 ** 30)
+            < 2.2 * 2 ** 30)
 
 
 def test_mamba2_mixer_keeps_no_float32_copy_of_the_taps_channels(
@@ -615,3 +623,85 @@ def test_olmo_hybrid_cell_step_lowers_for_a_v5e_chip(one_chip,
                    "flash_bwd_dkv"):
         assert kernel in text, kernel
     assert lowered.out_info[3].shape == ()
+
+
+def test_scan_kernels_compile_and_the_granite_cells_step_names_them(
+        S, one_chip, no_compile_cache, monkeypatch):
+    """The selective scan at ``train-granite-1chip``'s shapes (1 x 32,768
+    positions, 64 heads of 64, a state of 128, one group, chunk 256,
+    bfloat16; ``dt`` and ``A`` float32) on a TPU backend: Mosaic takes the
+    forward call alone, and the forward that keeps its states and the
+    backward call of the gradient; nothing else of the program is a
+    kernel. The cell's own step (``benchmark/configs/
+    granite-4.0-h-micro-c1.json``, ``train_scan.make_step``), lowered for a
+    v5e, names the scan's pair beside the taps' pair and the three flash
+    kernels; the scan runs as its kernels, ``KERNEL_HEADS`` heads and
+    ``KERNEL_CHUNKS`` chunks of 256 a grid step, a state kept a step; the
+    plan reckons more than a v5e's budget at every layer's "full", so no
+    rung is taken, and its need lies within 6% of the 15,429,915,136 bytes
+    the compiler allots that step (``step_program.py``, PR 41: 5.2% over;
+    a reckoning within 3% would lie under the budget and hand the
+    attention layer its first rung, S3c's to do)."""
+    import json
+
+    import optax
+
+    from benchmark.cells import train_scan
+    from ray_tpu.models import granite, llama
+    from ray_tpu.ops import ssm
+    from ray_tpu.util import tracing
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(llama, "_device_capacity", lambda mesh: V5E_LIMIT)
+
+    def f32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+
+    args = (S(1, 32768, 64, 64), f32(1, 32768, 64), f32(64),
+            S(1, 32768, 1, 128), S(1, 32768, 1, 128))
+    assert ssm.scan_plan(1, 32768, 64, 64, 128, 1, 256)["form"] == "pallas"
+
+    def loss(*a):
+        return jnp.square(ssm.ssd_scan(*a)[0].astype(jnp.float32)).sum()
+
+    forward = jax.jit(ssm.ssd_scan).lower(*args).compile().as_text()
+    assert [name for name, _ in _mosaic_calls(forward)] == ["ssd_scan_fwd"]
+    gradient = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+        *args).compile().as_text()
+    names = [name for name, _ in _mosaic_calls(gradient)]
+    assert len(names) == 2, names        # (named after the transformation)
+    for kernel in ("ssd_scan_fwd", "ssd_scan_bwd"):
+        assert any(kernel in name for name in names), (kernel, names)
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "granite-4.0-h-micro-c1.json")) as f:
+        model, _, cfg = train_scan.load_model(json.load(f)["model_config"])
+    assert model is granite and cfg.pattern.count("mamba") == 9
+    tx = optax.adamw(optax.linear_schedule(0.0, 1e-4, 2000))
+    params = jax.eval_shape(lambda k: granite.init_params(cfg, k),
+                            jax.random.PRNGKey(0))
+    opt = jax.eval_shape(tx.init, params)
+    batch = {"tokens": jax.ShapeDtypeStruct((1, 32769), jnp.int32,
+                                            sharding=one_chip)}
+    n0 = len(tracing.chrome_events())
+    lowered = jax.jit(train_scan.make_step(granite, cfg, tx),
+                      donate_argnums=(0, 1)).lower(
+        _placed(params, one_chip), _placed(opt, one_chip), batch)
+    spans = {}
+    for e in tracing.chrome_events()[n0:]:
+        spans.setdefault(e["name"], []).append(e["args"])
+    (plan,) = spans["rtpu.train.remat_plan"]
+    assert plan["level"] == {"mamba": "full", "attention": "full"}
+    assert plan["need_bytes"] > (1 - llama.REMAT_RESERVE) * V5E_LIMIT
+    assert 1.0 < plan["need_bytes"] / 15_429_915_136 < 1.06
+    steps = 128 // ssm.KERNEL_CHUNKS
+    assert {(r["form"], r["chunks"], r["heads_a_block"], r["chunks_a_call"],
+             r["states_kept"], r["decay_bytes_in_hbm"])
+            for r in spans["rtpu.ssm.scan_plan"]} == {
+        ("pallas", 128, ssm.KERNEL_HEADS, ssm.KERNEL_CHUNKS, steps, 0)}
+    text = lowered.as_text()
+    for kernel in ("taps_silu_fwd", "taps_silu_bwd", "ssd_scan_fwd",
+                   "ssd_scan_bwd", "flash_fwd", "flash_bwd_dq",
+                   "flash_bwd_dkv"):
+        assert kernel in text, kernel

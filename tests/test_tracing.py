@@ -809,18 +809,22 @@ def test_flash_tiles_is_one_kept_span_of_a_traced_call():
     assert (chunk["tiles_visited"], chunk["tiles_edge"]) == (8, 2)
 
 
-def test_scan_plan_is_one_kept_span_of_a_traced_call():
+@pytest.mark.parametrize("form", ["xla_walk", "pallas"])
+def test_scan_plan_is_one_kept_span_of_a_traced_call(form, monkeypatch):
     """A traced selective scan writes what it will do once, as a kept span
     (no flag, no profiler window), as ``rtpu.flash.tiles`` is written:
-    sequence, chunk, chunks, how many a step of the walk takes, heads,
-    head size, state, groups, the form and the bytes of decay matrices the
-    form puts in HBM beside what all chunks at once would."""
+    sequence, chunk, chunks, how many a step takes, heads, head size,
+    state, groups, the form that runs (XLA's walk on the CPU, the kernels
+    on a TPU backend) and the float32 bytes it puts in HBM, the decay
+    matrices' beside what all chunks at once would be."""
     import jax
     import jax.numpy as jnp
 
     from ray_tpu.ops import ssm
 
     assert not config.task_events_enabled
+    if form == "pallas":
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
 
     def trace(seq, **kw):
         x = jax.ShapeDtypeStruct((1, seq, 64, 64), jnp.bfloat16)
@@ -836,12 +840,25 @@ def test_scan_plan_is_one_kept_span_of_a_traced_call():
                 for e in _mine("rtpu.ssm.scan_plan")[n0:]]
 
     (cell,) = trace(32768)
-    assert cell == {"seq": 32768, "chunk": 256, "chunks": 128, "walk": 8,
-                    "steps": 16, "heads": 64, "head_dim": 64, "state": 128,
-                    "groups": 1, "form": "xla_walk",
-                    "decay_bytes_in_hbm": 2 ** 27,
-                    "decay_bytes_all_chunks": 2 ** 31}
     (short,) = trace(1000, chunk=128)
+    shared = {"seq": 32768, "chunk": 256, "chunks": 128, "heads": 64,
+              "head_dim": 64, "state": 128, "groups": 1, "form": form,
+              "decay_bytes_all_chunks": 2 ** 31}
+    if form == "pallas":
+        steps = 128 // ssm.KERNEL_CHUNKS
+        assert cell == dict(
+            shared, walk=None, steps=steps, states_kept=steps,
+            chunks_a_call=ssm.KERNEL_CHUNKS,
+            heads_a_block=ssm.KERNEL_HEADS, decay_bytes_in_hbm=0,
+            float32_bytes_in_hbm=(steps + 1) * 2 ** 21 + 5 * 2 ** 23
+            + 2 ** 25)
+        assert (short["form"], short["chunk"], short["chunks"]) == (
+            "pallas", 128, 8)
+        return
+    assert cell == dict(shared, walk=8, steps=16, states_kept=16,
+                        chunks_a_call=8, heads_a_block=None,
+                        decay_bytes_in_hbm=2 ** 27,
+                        float32_bytes_in_hbm=2 ** 27)
     assert (short["chunk"], short["chunks"], short["walk"],
             short["steps"]) == (128, 8, 8, 1)
 
