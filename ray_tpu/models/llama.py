@@ -33,8 +33,9 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
 from ray_tpu.ops.attention import attention_reference, flash_attention
-from ray_tpu.ops.layers import (apply_rope, blocked_head_nll, head_block,
-                                rms_norm, rope_frequencies, swiglu)
+from ray_tpu.ops.layers import (Leaf, Part, apply_rope, blocked_head_nll,
+                                head_block, kept, rms_norm, rope_frequencies,
+                                swiglu, swiglu_part)
 from ray_tpu.ops.ring_attention import ring_attention
 from ray_tpu.util import tracing
 
@@ -220,20 +221,6 @@ def init_params(cfg: LlamaConfig, key: jax.Array) -> Dict[str, Any]:
     return params
 
 
-# every leaf of a layer describe_stack reckons with: norms and biases
-# (no bytes of their own), the operators' and the MLPs' matrices
-_STACK_LEAVES = frozenset((
-    "attn_norm", "mlp_norm", "op_norm", "q_norm", "k_norm", "bq", "bk", "bv",
-    "attn_post_norm", "mlp_post_norm", "op_post_norm",
-    "wq", "wk", "wv", "wo", "wg",
-    "w_in", "w_conv", "w_out",
-    "m_in", "m_conv", "m_conv_bias", "dt_bias", "A_log", "D", "m_norm",
-    "m_out",
-    "g_in", "g_conv", "g_dt_bias", "g_A_log", "g_norm", "g_out",
-    "w_gate", "w_up", "w_down", "s_gate", "s_up", "s_down",
-    "router", "router_bias", "e_gate", "e_up", "e_down"))
-
-
 def remat_names(policy: str) -> Tuple[str, ...]:
     """The checkpoint names a resolved ``remat_policy`` keeps."""
     level = 0 if policy == "full" else int(policy[len("level"):])
@@ -246,147 +233,60 @@ def _runs(pattern: Tuple[str, ...]) -> Tuple[Tuple[str, int], ...]:
                  for kind, run in itertools.groupby(pattern))
 
 
-def describe_stack(cfg: LlamaConfig, layers, tokens_per_device: int,
-                   pattern: Optional[Tuple[str, ...]] = None, top_k: int = 0,
-                   held: Optional[Tuple[int, int]] = None,
-                   head_tokens: Optional[int] = None,
-                   scan: Optional[Tuple[int, ...]] = None,
-                   rule: Optional[Tuple[int, int]] = None) -> Dict[str, Any]:
+def describe_stack(cfg: LlamaConfig, kinds: Dict[str, Tuple[Part, Part]],
+                   layers, tokens_per_device: int,
+                   pattern: Optional[Tuple[str, ...]] = None,
+                   head_tokens: Optional[int] = None, mesh=None
+                   ) -> Dict[str, Any]:
     """What ``remat_plan`` knows of a stack: its ``runs`` (``_runs``; one
     run of "layer" without a ``pattern``) and for each of its ``kinds`` the
     bytes each rung of REMAT_LADDER keeps in one layer, the bytes a layer
-    holds while its backward runs, and the parameters of its matrices. All
-    from the shapes of the kind's stacked parameters
-    ``layers[kind][name]``, as the layer's own code reads them: ``wq``'s
-    width gives the heads (``attention_block``), a ``w_gate`` or
-    ``s_gate`` is a SwiGLU of that width (dense, shared), an ``e_gate`` a
-    routed mixture of ``top_k`` choices a token
-    (``ops/moe.routed_experts``; ``held``: its ``held=``), a ``w_in`` a
-    gated short convolution (``ops/conv.py``), an ``A_log`` a selective
-    scan (``ops/ssm.mamba2_mixer``; ``scan``: its groups, its chunk,
-    which are in no shape, and, where the arrays are sharded, the mesh:
-    which form the scan runs follows from it), a ``g_in`` a gated delta rule
-    (``ops/delta.gated_delta_mixer``; ``rule``: a head's key size, the
-    rule's chunk and, where the arrays are sharded, the mesh: which form
-    the rule runs follows from it). A kind that has none of the four
-    operators, or a leaf whose name is not one of ``_STACK_LEAVES``,
-    raises: a layer the plan does not know is not reckoned as another.
+    holds while its backward runs, and the parameters of its matrices.
+    ``kinds``: the model's table ``kind -> (mixer, mlp)``; each part says
+    what a layer of it keeps (``Part.keeps``, beside the code that runs
+    it) from the shapes of the kind's stacked parameters
+    ``layers[kind][name]``, as the layer's own code reads them, the tokens
+    and the config, and a kind's two are summed here. ``mesh``: the one
+    the arrays are sharded over, if any (which form a scan or a rule runs
+    follows from it). A kind the table has no entry for, a leaf neither
+    of its parts names or a layer without a matrix they name raises: a
+    layer the plan does not know is not reckoned as another.
     ``head_tokens``: the tokens whose logits exist at a time where the
     head and loss walk blocks (``blocked_token_nll``); all, without."""
-    from ray_tpu.ops import delta, ssm
-    from ray_tpu.ops.moe import _held_chunk
-
     T, h = tokens_per_device, cfg.hidden_size
     act = jnp.dtype(cfg.dtype).itemsize
     depth = jax.tree_util.tree_leaves(layers)[0].shape[0]
     runs = _runs(pattern or ("layer",) * depth)
-    kinds = {}
+    described = {}
     for kind in dict(runs):
         leaves = layers[kind] if pattern else layers
         shape = {name: a.shape[1:] for name, a in leaves.items()}
-        ops_ = [name for name in ("wq", "w_in", "A_log", "g_in")
-                if name in shape]
-        unknown = sorted(set(shape) - _STACK_LEAVES)
-        if len(ops_) != 1 or unknown:
+        if kind not in kinds:
             raise ValueError(
                 f"describe_stack does not know the layer kind {kind!r}: "
-                + (f"leaves {unknown}" if unknown else
-                   f"its operators are {ops_} (one of wq, w_in, A_log, "
-                   "g_in)"))
-        flash = qkv = mlp = resid = rows = 0
+                f"the table has {sorted(kinds)}")
+        parts = kinds[kind]
+        named = {n: leaf for part in parts
+                 for n, leaf in part.leaves(cfg).items()}
+        unknown = sorted(set(shape) - set(named))
+        lacking = [n for n, leaf in named.items()
+                   if len(leaf.shape) > 1 and n not in shape]
+        if unknown or lacking:
+            raise ValueError(
+                f"describe_stack does not know the layer kind {kind!r}: "
+                + (f"neither of its parts names the leaves {unknown}"
+                   if unknown else f"it lacks {lacking} of its parts"))
+        keeps = [part.keeps(cfg, shape, T, mesh) for part in parts]
         # elements a token that a layer's backward holds: its recomputed
-        # forward (norms, projections, attention, the three [T, ffn]
-        # arrays of a SwiGLU) and the gradients of the widest of them
-        width = 4 * h
-        if "wq" in shape:
-            qd, kvd = shape["wq"][-1], shape["wk"][-1]
-            flash = T * (qd * act + qd // cfg.head_dim_ * 4)   # lse: float32
-            qkv = T * (qd + 2 * kvd) * act
-            resid = T * h * act
-            width += 2 * qd + 2 * kvd
-        if "w_in" in shape:
-            # the in-projection's thirds, the pass's output and their
-            # gradients
-            width += 2 * shape["w_in"][-1]
-        if "A_log" in shape:
-            # the in-projection's output (z, x B C, dt) and the taps'
-            # output with their gradients, the gated output; beside them
-            # what the scan's form puts in HBM (``scan_plan``). XLA's
-            # walk: one step's decay matrices, their product with C B^T in
-            # float32 and the activations' dtype and the gradients of
-            # those, and the state before every step. Held to the
-            # compiled step at 16,384, 24,576 and 32,768 tokens of a 9 : 1
-            # stack at full remat: 2.5, 3.3 and 2.7% over what the
-            # compiler allots, 3.3% under at 8,192 (PERF.md 6, PR 36). The
-            # kernels: the kept states and the running sums alone, and
-            # neither the in-projection's output nor the gated output is
-            # held a second time (the walk's float32 copies of x went with
-            # it, and the skip is the kernels'): 5.2% over at 32,768
-            # tokens, and still over a v5e's budget; a closer reckoning
-            # would hand the attention layer a rung (PERF.md 7, PR 41)
-            heads, d = shape["A_log"][-1], shape["m_out"][0]
-            groups, chunk, *mesh = scan
-            state = (shape["m_conv"][0] - d) // (2 * groups)
-            plan = ssm.scan_plan(1, T, heads, d // heads, state, groups,
-                                 chunk, *mesh)
-            if plan["form"] == "pallas":
-                width += shape["m_in"][-1] + 2 * shape["m_conv"][0]
-                rows += plan["float32_bytes_in_hbm"]
-            else:
-                width += 2 * shape["m_in"][-1] + 2 * shape["m_conv"][0] + d
-                rows += 4 * plan["decay_bytes_in_hbm"] + (
-                    plan["steps"] * heads * (d // heads) * state * 4)
-        if "g_in" in shape:
-            # the in-projection's output (z, q k v, a b) and the taps'
-            # output (their gradients lie where the SwiGLU's arrays did);
-            # beside them what the rule's form puts in HBM
-            # (``rule_plan``). XLA's walk: one step's pair matrices and
-            # carried states with their gradients, W, U, V' and the
-            # decayed copies of q and k in both dtypes, and the state
-            # before every step; held to the compiled step at 32,768
-            # tokens of a 3 : 1 stack at full remat: 1.4% over what the
-            # compiler allots (PR 39). The kernels: the kept states and
-            # the running sums alone, and the taps' output is not held
-            # beside the head-major copies the calls read: 1.8% over at
-            # 32,768 tokens (at 16,384 the full layer takes its rungs and
-            # the need lies 3.8% under the allotment; PERF.md 6, PR 40)
-            heads, hv = shape["g_A_log"][-1], shape["g_out"][0]
-            key_dim, chunk, *mesh = rule
-            plan = delta.rule_plan(1, T, heads, key_dim, hv // heads, chunk,
-                                   *mesh)
-            width += shape["g_in"][-1]
-            if plan["form"] == "pallas":
-                rows += plan["float32_bytes_in_hbm"]
-            else:
-                width += shape["g_conv"][0]
-                rows += 4 * plan["float32_bytes_in_hbm"] + (
-                    plan["steps"] * hv * key_dim * 4)
-        for gate in ("w_gate", "s_gate"):
-            if gate in shape:
-                mlp += 2 * T * shape[gate][-1] * act
-                width += 5 * shape[gate][-1]
-        if "e_gate" in shape:
-            f, pairs = shape["e_gate"][-1], T * top_k
-            if held is None:
-                # the two products carry the MLP rung's names
-                # (ops/moe._swiglu_rows), a row a (token, choice) pair
-                mlp += 2 * pairs * f * act
-            else:
-                # a pass's rows alone are gathered and multiplied, and the
-                # passes add into two float32 [T, h] sums; nothing of a
-                # pass is kept (ops/moe._held_experts: its residuals are
-                # its inputs)
-                pairs = _held_chunk(pairs, held[1], shape["router"][-1])
-                rows = 2 * T * h * 4
-            # the rows and their gradient, the three [pairs, f] arrays of
-            # the experts' SwiGLU and theirs
-            rows += pairs * (2 * h + 6 * f) * act
-        kinds[kind] = {
-            "rungs": (flash, qkv, mlp, resid),
-            "working_bytes": T * act * width + rows,
+        # forward (norms, projections, the mixer, the MLP's arrays) and
+        # the gradients of the widest of them
+        width = 4 * h + sum(k["width"] for k in keeps)
+        described[kind] = {
+            "rungs": tuple(map(sum, zip(*(k["rungs"] for k in keeps)))),
+            "working_bytes": T * act * width + sum(k["rows"] for k in keeps),
             "params": sum(math.prod(s) for s in shape.values()
                           if len(s) > 1)}
-    return {"runs": runs, "kinds": kinds,
+    return {"runs": runs, "kinds": described,
             **({"head_tokens": head_tokens} if head_tokens else {})}
 
 
@@ -501,14 +401,15 @@ def _device_capacity(mesh) -> Optional[int]:
         return None
 
 
-def resolve_remat(cfg: LlamaConfig, params, tokens, mesh, shardings=None,
-                  **stack) -> Any:
+def resolve_remat(cfg: LlamaConfig, kinds, params, tokens, mesh,
+                  shardings=None, **stack) -> Any:
     """The remat level of the program being traced (by kind for a stack
     with a ``pattern``), from its shapes: no device work and no trial
-    compile. ``shardings``: the forward's own ``param_shardings``;
-    ``stack``: ``describe_stack``'s keywords. The plan is one kept span,
-    so an operator reads in ``trace_spans.json`` and ``timeline()`` which
-    level a job got and why."""
+    compile. ``kinds``: the forward's table of parts; ``shardings``: its
+    own ``param_shardings``; ``stack``: ``describe_stack``'s ``pattern``
+    and ``head_tokens``. The plan is one kept span, so an operator reads
+    in ``trace_spans.json`` and ``timeline()`` which level a job got and
+    why."""
     total = sum(a.size * a.dtype.itemsize
                 for a in jax.tree_util.tree_leaves(params))
     per_device, data_shards = total, 1
@@ -525,8 +426,8 @@ def resolve_remat(cfg: LlamaConfig, params, tokens, mesh, shardings=None,
             for axis in (axes,) if isinstance(axes, str) else axes:
                 data_shards *= sizes[axis]
     per_shard = -(-tokens.size // data_shards)
-    plan = remat_plan(cfg, describe_stack(cfg, params["layers"], per_shard,
-                                          **stack),
+    plan = remat_plan(cfg, describe_stack(cfg, kinds, params["layers"],
+                                          per_shard, mesh=mesh, **stack),
                       per_shard, per_device, _device_capacity(mesh),
                       per_device < total)
     with tracing.span("rtpu.train.remat_plan", keep=True, **plan):
@@ -684,6 +585,79 @@ def attention_block(cfg: LlamaConfig, x, p, cos, sin, mesh=None,
         return checkpoint_name(x + attn_out, "attn_resid")
 
 
+def rope_tables(cfg: LlamaConfig, tokens: jax.Array):
+    """(cos, sin) over the whole head at the config's ``rope_theta`` and
+    ``rope_scaling``: what ``attention_part`` makes once a forward unless
+    its table says otherwise."""
+    return rope_frequencies(cfg.head_dim_, tokens.shape[1], cfg.rope_theta,
+                            dtype=cfg.dtype, scaling=cfg.rope_scaling_dict)
+
+
+def attention_part(heads: str = "num_heads", window: Optional[str] = None,
+                   gate: bool = False, rope=rope_tables,
+                   qk_norm: Optional[str] = None, norm: str = "pre",
+                   scale: Optional[str] = None, resid: Optional[str] = None
+                   ) -> Part:
+    """``attention_block`` as a layer's mixer. ``heads``, ``window``,
+    ``scale`` (the scores') and ``resid`` (the weight of the block's output
+    in the sum) name fields of the config; ``gate``: a per-head output gate
+    ``wg``; ``rope(cfg, tokens) -> (cos, sin)``, made once a forward, or
+    None for a layer without a position embedding; ``qk_norm``: "whole" (an
+    RMSNorm over the q and k vectors: OLMoE, OLMo 2) or "head" (over each
+    head's dims: LFM2); ``norm``: "pre" (``attn_norm`` on the block's
+    input) or "post" (``attn_post_norm`` on its output: OLMo 2).
+    ``cfg.attn_qkv_bias`` adds Qwen2's three biases."""
+    def leaves(cfg):
+        h, hd, n = cfg.hidden_size, cfg.head_dim_, getattr(cfg, heads)
+        qd, kvd = n * hd, cfg.num_kv_heads * hd
+        mat, vec = ("embed", "qkv"), ("qkv",)
+        out = {}
+        if norm == "pre":
+            out["attn_norm"] = Leaf((h,), "ones", ("embed",))
+        out.update(wq=Leaf((h, qd), h, mat), wk=Leaf((h, kvd), h, mat),
+                   wv=Leaf((h, kvd), h, mat))
+        if qk_norm == "whole":
+            out.update(q_norm=Leaf((qd,), "ones", vec),
+                       k_norm=Leaf((kvd,), "ones", vec))
+        out["wo"] = Leaf((qd, h), qd, ("qkv", "embed"))
+        if gate:
+            out["wg"] = Leaf((h, n), h, ("embed", None))
+        if qk_norm == "head":
+            out.update(q_norm=Leaf((hd,), "ones", (None,)),
+                       k_norm=Leaf((hd,), "ones", (None,)))
+        if cfg.attn_qkv_bias:
+            out.update(bq=Leaf((qd,), "zeros", vec),
+                       bk=Leaf((kvd,), "zeros", vec),
+                       bv=Leaf((kvd,), "zeros", vec))
+        if norm == "post":
+            out["attn_post_norm"] = Leaf((h,), "ones", ("embed",))
+        return out
+
+    def body(cfg, x, p, ctx):
+        cos, sin = ctx.once[rope] if rope else (None, None)
+        return attention_block(
+            cfg, x, p, cos, sin, mesh=ctx.mesh,
+            window=getattr(cfg, window) if window else None,
+            sm_scale=getattr(cfg, scale) if scale else None,
+            resid_scale=getattr(cfg, resid) if resid else None), {}
+
+    def keeps(cfg, shape, tokens, mesh):
+        # ``wq``'s width gives the heads, as ``attention_block`` reads them
+        qd, kvd = shape["wq"][-1], shape["wk"][-1]
+        act = jnp.dtype(cfg.dtype).itemsize
+        return kept(
+            flash=tokens * (qd * act + qd // cfg.head_dim_ * 4),  # lse: f32
+            qkv=tokens * (qd + 2 * kvd) * act,
+            resid=tokens * cfg.hidden_size * act,
+            width=2 * qd + 2 * kvd)
+
+    return Part(leaves, body, keeps, once=rope)
+
+
+# the dense stack's one kind, as ``forward`` describes it to the plan
+LAYER_KINDS = {"layer": (attention_part(), swiglu_part())}
+
+
 def _layer(cfg: LlamaConfig, x, layer_params, cos, sin, mesh=None,
            seq_axis=None):
     """One decoder block. x: [b, s, h]."""
@@ -782,7 +756,8 @@ def forward(cfg: LlamaConfig, params: Dict[str, Any], tokens: jax.Array,
         cos, sin = rope_frequencies(cfg.head_dim_, tokens.shape[1],
                                     cfg.rope_theta, dtype=cfg.dtype,
                                     scaling=cfg.rope_scaling_dict)
-    level = resolve_remat(cfg, params, tokens, mesh) if cfg.remat else None
+    level = (resolve_remat(cfg, LAYER_KINDS, params, tokens, mesh)
+             if cfg.remat else None)
     x, _ = run_layers(
         lambda x_, p_: (_layer(cfg, x_, p_, cos, sin, mesh=mesh), None),
         x, params["layers"], level=level, scan=cfg.scan_layers)

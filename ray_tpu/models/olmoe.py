@@ -28,7 +28,8 @@ import jax.numpy as jnp
 
 from ray_tpu.models import llama, mixtral
 from ray_tpu.ops.layers import rms_norm, rope_frequencies
-from ray_tpu.ops.moe import routed_experts_on
+from ray_tpu.ops.moe import (routed_experts_on, routed_part, router_losses,
+                             router_stats)
 
 
 @dataclass(frozen=True)
@@ -77,15 +78,10 @@ def init_params(cfg: OlmoeConfig, key: jax.Array) -> Dict[str, Any]:
     return params
 
 
-def router_stats(logits: jax.Array, counts: jax.Array
-                 ) -> Dict[str, jax.Array]:
-    """What ``router_losses`` reads of one layer: the rows routed to each
-    expert, the mean router probability [E] and the mean squared
-    logsumexp of the router logits [n, E]."""
-    with jax.named_scope("moe_route"):
-        return {"counts": counts,
-                "prob": jax.nn.softmax(logits, axis=-1).mean(0),
-                "z": jnp.square(jax.nn.logsumexp(logits, axis=-1)).mean()}
+# the stack's one kind, as ``forward`` describes it to the plan
+LAYER_KINDS = {"layer": (llama.attention_part(qk_norm="whole"),
+                         routed_part(balance=True,
+                                     width="intermediate_size"))}
 
 
 def _layer(cfg: OlmoeConfig, x, p, cos, sin, mesh=None,
@@ -114,24 +110,13 @@ def forward(cfg: OlmoeConfig, params, tokens: jax.Array, mesh=None,
         cos, sin = rope_frequencies(cfg.head_dim_, tokens.shape[1],
                                     cfg.rope_theta, dtype=cfg.dtype,
                                     scaling=cfg.rope_scaling_dict)
-    level = llama.resolve_remat(cfg, params, tokens, mesh, param_shardings,
-                                top_k=cfg.top_k) if cfg.remat else None
+    level = llama.resolve_remat(cfg, LAYER_KINDS, params, tokens, mesh,
+                                param_shardings) if cfg.remat else None
     x, router = llama.run_layers(
         lambda x_, p_: _layer(cfg, x_, p_, cos, sin, mesh=mesh,
                               keep_router_logits=keep_router_logits),
         x, params["layers"], level=level, scan=cfg.scan_layers)
     return llama._final_head(cfg, params, x), router
-
-
-def router_losses(cfg: OlmoeConfig, router: Dict[str, jax.Array]
-                  ) -> Tuple[jax.Array, jax.Array]:
-    """(load-balancing loss, router z-loss), before their coefficients.
-    Both are over all layers' tokens together, as transformers
-    concatenates the layers' router logits."""
-    counts = router["counts"].astype(jnp.float32)
-    share = counts.sum(0) / (counts.sum() / cfg.top_k)     # f_e, sums to K
-    balance = cfg.num_experts * jnp.sum(share * router["prob"].mean(0))
-    return balance, router["z"].mean()
 
 
 def loss_terms(cfg: OlmoeConfig, params, batch: Dict[str, jax.Array],

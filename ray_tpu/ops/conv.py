@@ -39,6 +39,8 @@ from typing import Any, Dict, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 
+from ray_tpu.ops.layers import Leaf, Part, kept, rms_norm
+
 
 def causal_taps(u: jax.Array, w: jax.Array) -> jax.Array:
     """u [b, s, c] float32, w [c, taps] -> the causal depthwise convolution
@@ -76,6 +78,30 @@ def gated_short_conv(h: jax.Array, w_in: jax.Array, w_conv: jax.Array,
         with jax.named_scope("conv_out"):
             return jnp.dot(y, w_out.astype(dt),
                            preferred_element_type=jnp.float32).astype(dt)
+
+
+def short_conv_part() -> Part:
+    """The gated short convolution as a layer's mixer: ``x +
+    gated_short_conv(RMSNorm(x))`` over ``cfg.hidden_size`` channels with
+    ``cfg.conv_taps`` taps."""
+    def leaves(cfg):
+        h, taps = cfg.hidden_size, cfg.conv_taps
+        return {"op_norm": Leaf((h,), "ones", ("embed",)),
+                "w_in": Leaf((h, 3 * h), h, ("embed", "mlp")),
+                "w_conv": Leaf((h, taps), taps, ("mlp", None)),
+                "w_out": Leaf((h, h), h, ("mlp", "embed"))}
+
+    def body(cfg, x, p, ctx):
+        return x + gated_short_conv(
+            rms_norm(x, p["op_norm"], cfg.rms_norm_eps), p["w_in"],
+            p["w_conv"], p["w_out"]), {}
+
+    def keeps(cfg, shape, tokens, mesh):
+        # the in-projection's thirds, the pass's output and their
+        # gradients; no rung of the ladder names anything in it
+        return kept(width=2 * shape["w_in"][-1])
+
+    return Part(leaves, body, keeps)
 
 
 # ---- taps, bias and silu as one pass forward and one backward ----

@@ -87,7 +87,8 @@ from typing import Any, Dict, List, Tuple
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.ops.layers import gated_rms_norm, l2_norm
+from ray_tpu.ops.layers import (Leaf, Part, gated_rms_norm, kept, l2_norm,
+                                rms_norm)
 from ray_tpu.ops.ssm import causal_conv_silu
 from ray_tpu.util import tracing
 
@@ -817,3 +818,62 @@ def gated_delta_mixer(h: jax.Array, p: Dict[str, jax.Array], *, heads: int,
             out = jnp.dot(y, p["g_out"].astype(dt_),
                           preferred_element_type=f32).astype(dt_)
     return out, S
+
+
+def gated_delta_part(counter: str = "gdn_state_abs_max") -> Part:
+    """The gated delta rule's mixer as a layer's mixer, in OLMo 2's order:
+    ``x + RMSNorm(gated_delta_mixer(x))`` at the config's ``linear_heads``,
+    ``linear_key_dim``, ``linear_value_dim``, ``linear_conv_taps`` and
+    ``rule_chunk``. A layer reports its state after the last position under
+    "gdn_state", and the loss's terms the largest ``|S|`` of any layer
+    under ``counter``. The initialisation is the delta-net's published
+    one: ``A`` uniform in 0-16 as its log, ``dt`` through the inverse
+    softplus, norms 1."""
+    def leaves(cfg):
+        h, H, taps = cfg.hidden_size, cfg.linear_heads, cfg.linear_conv_taps
+        hv = H * cfg.linear_value_dim
+        conv = 2 * H * cfg.linear_key_dim + hv
+        return {"g_in": Leaf((h, hv + conv + 2 * H), h, ("embed", "mlp")),
+                "g_conv": Leaf((conv, taps), taps, ("mlp", None)),
+                "g_dt_bias": Leaf((H,), "dt", (None,)),
+                "g_A_log": Leaf((H,), (0.0, 16.0), (None,)),
+                "g_norm": Leaf((cfg.linear_value_dim,), "ones", (None,)),
+                "g_out": Leaf((hv, h), hv, ("mlp", "embed")),
+                "op_post_norm": Leaf((h,), "ones", ("embed",))}
+
+    def body(cfg, x, p, ctx):
+        out, S = gated_delta_mixer(
+            x, p, heads=cfg.linear_heads, key_dim=cfg.linear_key_dim,
+            value_dim=cfg.linear_value_dim, chunk=cfg.rule_chunk,
+            eps=cfg.rms_norm_eps, mesh=ctx.mesh)
+        return (x + rms_norm(out, p["op_post_norm"], cfg.rms_norm_eps),
+                {"gdn_state": S})
+
+    def keeps(cfg, shape, tokens, mesh):
+        # the in-projection's output (z, q k v, a b) and the taps' output
+        # (their gradients lie where the SwiGLU's arrays did); beside them
+        # what the rule's form puts in HBM (``rule_plan``). XLA's walk: one
+        # step's pair matrices and carried states with their gradients, W,
+        # U, V' and the decayed copies of q and k in both dtypes, and the
+        # state before every step; held to the compiled step at 32,768
+        # tokens of a 3 : 1 stack at full remat: 1.4% over what the
+        # compiler allots (PR 39). The kernels: the kept states and the
+        # running sums alone, and the taps' output is not held beside the
+        # head-major copies the calls read: 1.8% over at 32,768 tokens (at
+        # 16,384 the full layer takes its rungs and the need lies 3.8%
+        # under the allotment; PERF.md 6, PR 40)
+        heads, hv = shape["g_A_log"][-1], shape["g_out"][0]
+        key_dim = cfg.linear_key_dim
+        plan = rule_plan(1, tokens, heads, key_dim, hv // heads,
+                         cfg.rule_chunk, mesh)
+        if plan["form"] == "pallas":
+            return kept(width=shape["g_in"][-1],
+                        rows=plan["float32_bytes_in_hbm"])
+        return kept(width=shape["g_in"][-1] + shape["g_conv"][0],
+                    rows=4 * plan["float32_bytes_in_hbm"]
+                    + plan["steps"] * hv * key_dim * 4)
+
+    def terms(cfg, states):
+        return None, {counter: jnp.abs(states).max()}
+
+    return Part(leaves, body, keeps, reports="gdn_state", terms=terms)

@@ -1,5 +1,6 @@
 """Transformer building blocks: RMSNorm (plain and gated a head at a time), an
-L2 norm, RoPE, SwiGLU, a head and loss over blocks of tokens.
+L2 norm, RoPE, SwiGLU, a head and loss over blocks of tokens; and ``Part``,
+the record a layer's mixer or MLP is to ``models/stack.py``.
 
 Pure-jax implementations — XLA fuses these elementwise chains into the
 surrounding matmuls on TPU (the guide's rule: don't hand-schedule what the
@@ -12,12 +13,71 @@ ops underpin the model zoo (models/llama.py etc.).
 
 from __future__ import annotations
 
+from dataclasses import dataclass
 from functools import partial
-from typing import Optional, Tuple
+from typing import Any, Callable, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
+
+
+class Leaf(NamedTuple):
+    """One parameter of one layer. ``start``: a fan-in (truncated normal
+    over its root), "ones", "zeros", "zeros_float32" (whatever the
+    parameters' dtype), "dt" (log-uniform in 0.001-0.1, stored through the
+    inverse softplus) or ``(lo, hi)`` (uniform in that range, stored as its
+    log); ``models/stack.draw`` reads it. ``axes``: the logical axes
+    ``parallel/sharding.py`` maps onto a mesh."""
+    shape: Tuple[int, ...]
+    start: Any
+    axes: Tuple[Optional[str], ...]
+
+
+class Ctx(NamedTuple):
+    """What a forward hands every part's body beside the layer's own
+    input: the mesh the arrays are sharded over, what each part's ``once``
+    made (keyed by that function) and whether the routers' logits and
+    choices are asked for."""
+    mesh: Any
+    once: Dict[Callable, Any]
+    keep_router_logits: bool = False
+
+
+@dataclass(frozen=True)
+class Part:
+    """A layer's mixer or its MLP, kept beside the code that runs it. A
+    model is a table ``kind -> (mixer, mlp)`` of these and
+    ``models/stack.py`` walks it; what two models that share a part differ
+    in is an argument of the part's maker, which names a field of the
+    config, or the field itself.
+
+    ``leaves(cfg)``: name -> ``Leaf``, in the order the keys of
+    ``init_params`` are dealt. ``body(cfg, x, p, ctx) -> (x, said)``: the
+    sublayer with its norm and its residual; ``said`` is ``{}`` or
+    ``{reports: what this layer reports}``. ``keeps(cfg, shape, tokens,
+    mesh)``: what ``llama.describe_stack`` adds up for ``remat_plan``,
+    from the shapes ``shape[name]`` of one layer's leaves and the tokens a
+    device holds (``kept``). ``once(cfg, tokens)``: what the body wants
+    made once a forward, in the ``embed`` scope (rope tables), found at
+    ``ctx.once[once]``. ``terms(cfg, reports stacked in layer order) ->
+    (what they add to the loss or None, the entries of ``loss_terms``'
+    second result)``."""
+    leaves: Callable[[Any], Dict[str, Leaf]]
+    body: Callable
+    keeps: Callable
+    once: Optional[Callable] = None
+    reports: Optional[str] = None
+    terms: Optional[Callable] = None
+
+
+def kept(flash: int = 0, qkv: int = 0, mlp: int = 0, resid: int = 0,
+         width: int = 0, rows: int = 0) -> Dict[str, Any]:
+    """What a part's ``keeps`` returns: the bytes each rung of
+    ``llama.REMAT_LADDER`` keeps in one layer, the elements a token its
+    backward holds (the recomputed forward and the gradients of the
+    widest of it) and the bytes it holds that are not a token's."""
+    return {"rungs": (flash, qkv, mlp, resid), "width": width, "rows": rows}
 
 
 def rms_norm(x: jax.Array, weight: jax.Array, eps: float = 1e-6) -> jax.Array:
@@ -220,6 +280,48 @@ def swiglu(x: jax.Array, w_gate: jax.Array, w_up: jax.Array,
         "mlp_up")
     h = act_fn(gate) * up
     return jnp.dot(h, w_down, preferred_element_type=jnp.float32).astype(x.dtype)
+
+
+def swiglu_kept(tokens: int, width: int, itemsize: int) -> Dict[str, Any]:
+    """A SwiGLU of ``width``: the MLP rung keeps its two products; the
+    backward holds the three ``[T, width]`` arrays and the gradients of
+    two."""
+    return kept(mlp=2 * tokens * width * itemsize, width=5 * width)
+
+
+def swiglu_part(norm: str = "pre", resid: Optional[str] = None) -> Part:
+    """A dense SwiGLU of ``cfg.intermediate_size`` as a layer's MLP:
+    ``x + r * swiglu(RMSNorm(x))`` (``norm="pre"``, llama's order; ``resid``
+    names the config's field ``r`` where it is not 1: Granite's
+    ``residual_multiplier``) or ``x + RMSNorm(swiglu(x))`` (``"post"``,
+    OLMo 2's)."""
+    def leaves(cfg):
+        h, f = cfg.hidden_size, cfg.intermediate_size
+        mats = {"w_gate": Leaf((h, f), h, ("embed", "mlp")),
+                "w_up": Leaf((h, f), h, ("embed", "mlp")),
+                "w_down": Leaf((f, h), f, ("mlp", "embed"))}
+        if norm == "pre":
+            return {"mlp_norm": Leaf((h,), "ones", ("embed",)), **mats}
+        return {**mats, "mlp_post_norm": Leaf((h,), "ones", ("embed",))}
+
+    def body(cfg, x, p, ctx):
+        dt = cfg.dtype
+        with jax.named_scope("mlp"):
+            h2 = (rms_norm(x, p["mlp_norm"], cfg.rms_norm_eps)
+                  if norm == "pre" else x)
+            mlp = swiglu(h2, p["w_gate"].astype(dt), p["w_up"].astype(dt),
+                         p["w_down"].astype(dt), act=cfg.mlp_act)
+            if norm == "post":
+                mlp = rms_norm(mlp, p["mlp_post_norm"], cfg.rms_norm_eps)
+            if resid is not None:
+                mlp = mlp * jnp.asarray(getattr(cfg, resid), dt)
+            return x + mlp, {}
+
+    def keeps(cfg, shape, tokens, mesh):
+        return swiglu_kept(tokens, shape["w_gate"][-1],
+                           jnp.dtype(cfg.dtype).itemsize)
+
+    return Part(leaves, body, keeps)
 
 
 def repeat_kv(x: jax.Array, n_rep: int) -> jax.Array:

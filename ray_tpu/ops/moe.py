@@ -62,13 +62,15 @@ activation, the gate weighting in it), ``moe_combine`` (gather back, sum).
 from __future__ import annotations
 
 from functools import partial
-from typing import Optional, Tuple
+from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 
+from ray_tpu.ops.layers import (Leaf, Part, kept, rms_norm, swiglu,
+                                swiglu_kept)
 from ray_tpu.util import tracing
 
 
@@ -453,3 +455,121 @@ def routed_experts_on(mesh, x: jax.Array, router_w: jax.Array,
         sharded, mesh=mesh, in_specs=(P(rows),) + (P(),) * len(weights),
         out_specs=(P(rows), P(rows), P()) + by_row,
         check_vma=False)(x, *weights)
+
+
+def router_stats(logits: jax.Array, counts: jax.Array
+                 ) -> Dict[str, jax.Array]:
+    """What ``router_losses`` reads of one layer: the rows routed to each
+    expert, the mean router probability [E] and the mean squared
+    logsumexp of the router logits [n, E]."""
+    with jax.named_scope("moe_route"):
+        return {"counts": counts,
+                "prob": jax.nn.softmax(logits, axis=-1).mean(0),
+                "z": jnp.square(jax.nn.logsumexp(logits, axis=-1)).mean()}
+
+
+def router_losses(cfg, router: Dict[str, jax.Array]
+                  ) -> Tuple[jax.Array, jax.Array]:
+    """(load-balancing loss, router z-loss), before their coefficients,
+    of the layers' ``router_stats`` stacked. Both are over all layers'
+    tokens together, as transformers concatenates the layers' router
+    logits."""
+    counts = router["counts"].astype(jnp.float32)
+    share = counts.sum(0) / (counts.sum() / cfg.top_k)     # f_e, sums to K
+    balance = cfg.num_experts * jnp.sum(share * router["prob"].mean(0))
+    return balance, router["z"].mean()
+
+
+def routed_part(shared: bool = False, score: str = "softmax",
+                bias: bool = False, renorm_eps: Optional[str] = None,
+                balance: bool = False,
+                width: str = "moe_intermediate_size") -> Part:
+    """A routed mixture as a layer's MLP: ``x + [shared(u)] + routed(u)``,
+    ``u = RMSNorm(x)``: ``cfg.num_experts`` experts of ``width`` (the
+    config's field), ``cfg.top_k`` a token, the gate weights renormalised
+    and times ``cfg.routed_scale``, ``cfg.experts_held`` of them here (a
+    config without the field holds them all). ``shared``: a SwiGLU of
+    ``cfg.shared_intermediate_size`` beside them, added ungated (Laguna).
+    ``score="sigmoid"`` and ``bias`` (a ``router_bias`` that takes part in
+    the choice alone, float32, no optimizer's) are LFM2's router,
+    ``renorm_eps`` names its field. ``balance``: a layer reports
+    ``router_stats`` and the loss gains ``cfg.router_aux_coef`` x
+    ``router_losses``' load-balancing term; without, the counts alone. A
+    layer reports under "router"; asked for (``ctx.keep_router_logits``),
+    the router's logits too and, where a bias took part in them,
+    ``route``'s own choices."""
+    def held(cfg):
+        return getattr(cfg, "experts_held", None)
+
+    def leaves(cfg):
+        h, E, f = cfg.hidden_size, cfg.num_experts, getattr(cfg, width)
+        here = held(cfg)[1] if held(cfg) else E
+        experts = ("expert", "embed", "mlp")
+        out = {"mlp_norm": Leaf((h,), "ones", ("embed",)),
+               "router": Leaf((h, E), h, ("embed", None))}
+        if bias:
+            out["router_bias"] = Leaf((E,), "zeros_float32", (None,))
+        out.update(e_gate=Leaf((here, h, f), h, experts),
+                   e_up=Leaf((here, h, f), h, experts),
+                   e_down=Leaf((here, f, h), f, ("expert", "mlp", "embed")))
+        if shared:
+            sf = cfg.shared_intermediate_size
+            out.update(s_gate=Leaf((h, sf), h, ("embed", "mlp")),
+                       s_up=Leaf((h, sf), h, ("embed", "mlp")),
+                       s_down=Leaf((sf, h), sf, ("mlp", "embed")))
+        return out
+
+    def body(cfg, x, p, ctx):
+        dt = cfg.dtype
+        with jax.named_scope("mlp"):
+            h2 = rms_norm(x, p["mlp_norm"], cfg.rms_norm_eps)
+            if shared:
+                with jax.named_scope("moe_shared"):
+                    beside = swiglu(h2, p["s_gate"].astype(dt),
+                                    p["s_up"].astype(dt),
+                                    p["s_down"].astype(dt))
+            out, logits, counts, *chosen = routed_experts_on(
+                ctx.mesh, h2, p["router"], p["e_gate"], p["e_up"],
+                p["e_down"], cfg.top_k, renormalize=True,
+                select_bias=p["router_bias"] if bias else None,
+                held=held(cfg), scale=cfg.routed_scale, score=score,
+                renorm_eps=getattr(cfg, renorm_eps) if renorm_eps else 0.0,
+                keep_choices=bias and ctx.keep_router_logits)
+            router = (router_stats(logits, counts) if balance
+                      else {"counts": counts})
+            if ctx.keep_router_logits:
+                router["logits"] = logits
+                if bias:
+                    router["chosen"] = chosen[0]
+            return (x + beside if shared else x) + out, {"router": router}
+
+    def keeps(cfg, shape, tokens, mesh):
+        h, f = cfg.hidden_size, shape["e_gate"][-1]
+        act = jnp.dtype(cfg.dtype).itemsize
+        pairs, mlp, rows = tokens * cfg.top_k, 0, 0
+        if held(cfg) is None:
+            # the two products carry the MLP rung's names (``_swiglu_rows``),
+            # a row a (token, choice) pair
+            mlp = 2 * pairs * f * act
+        else:
+            # a pass's rows alone are gathered and multiplied, and the
+            # passes add into two float32 [T, h] sums; nothing of a pass is
+            # kept (``_held_experts``: its residuals are its inputs)
+            pairs = _held_chunk(pairs, held(cfg)[1], shape["router"][-1])
+            rows = 2 * tokens * h * 4
+        # the rows and their gradient, the three [pairs, f] arrays of the
+        # experts' SwiGLU and theirs
+        rows += pairs * (2 * h + 6 * f) * act
+        beside = (swiglu_kept(tokens, shape["s_gate"][-1], act)
+                  if shared else kept())
+        return kept(mlp=mlp + beside["rungs"][2], width=beside["width"],
+                    rows=rows)
+
+    def terms(cfg, router):
+        counts = {"expert_counts": router["counts"]}
+        if not balance:
+            return None, counts
+        load, _ = router_losses(cfg, router)
+        return cfg.router_aux_coef * load, {"load_balance": load, **counts}
+
+    return Part(leaves, body, keeps, reports="router", terms=terms)

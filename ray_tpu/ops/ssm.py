@@ -71,6 +71,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ray_tpu.ops.conv import causal_taps, taps_plan, taps_silu
+from ray_tpu.ops.layers import Leaf, Part, kept, rms_norm
 from ray_tpu.util import tracing
 
 # decay matrices one step of XLA's walk may put in HBM (float32, before the
@@ -745,3 +746,72 @@ def mamba2_mixer(h: jax.Array, p: Dict[str, jax.Array], *, heads: int,
             out = jnp.dot(y, p["m_out"].astype(dt_),
                           preferred_element_type=f32).astype(dt_)
     return out, S
+
+
+def mamba2_part(resid: Optional[str] = None,
+                counter: str = "ssm_state_abs_max") -> Part:
+    """Mamba-2's mixer as a layer's mixer: ``x + r * mamba2_mixer(
+    RMSNorm(x))`` at the config's ``ssm_heads``, ``ssm_head_dim``,
+    ``ssm_state``, ``ssm_groups``, ``ssm_conv_taps`` and ``ssm_chunk``
+    (``resid`` names the field ``r`` where it is not 1). A layer reports
+    its state after the last position under "ssm_state", and the loss's
+    terms the largest ``|S|`` of any layer under ``counter``. The
+    initialisation is Mamba-2's published one: ``A`` uniform in 1-16 as its
+    log, ``dt`` through the inverse softplus, ``D`` and the norms 1, the
+    taps' bias 0."""
+    def leaves(cfg):
+        h, H, taps = cfg.hidden_size, cfg.ssm_heads, cfg.ssm_conv_taps
+        d = H * cfg.ssm_head_dim
+        conv = d + 2 * cfg.ssm_groups * cfg.ssm_state
+        return {"op_norm": Leaf((h,), "ones", ("embed",)),
+                "m_in": Leaf((h, d + conv + H), h, ("embed", "mlp")),
+                "m_conv": Leaf((conv, taps), taps, ("mlp", None)),
+                "m_conv_bias": Leaf((conv,), "zeros", ("mlp",)),
+                "dt_bias": Leaf((H,), "dt", (None,)),
+                "A_log": Leaf((H,), (1.0, 16.0), (None,)),
+                "D": Leaf((H,), "ones", (None,)),
+                "m_norm": Leaf((d,), "ones", ("mlp",)),
+                "m_out": Leaf((d, h), d, ("mlp", "embed"))}
+
+    def body(cfg, x, p, ctx):
+        out, S = mamba2_mixer(
+            rms_norm(x, p["op_norm"], cfg.rms_norm_eps), p,
+            heads=cfg.ssm_heads, head_dim=cfg.ssm_head_dim,
+            state=cfg.ssm_state, groups=cfg.ssm_groups, chunk=cfg.ssm_chunk,
+            eps=cfg.rms_norm_eps, mesh=ctx.mesh)
+        if resid is not None:
+            out = out * jnp.asarray(getattr(cfg, resid), cfg.dtype)
+        return x + out, {"ssm_state": S}
+
+    def keeps(cfg, shape, tokens, mesh):
+        # the in-projection's output (z, x B C, dt) and the taps' output
+        # with their gradients, the gated output; beside them what the
+        # scan's form puts in HBM (``scan_plan``). XLA's walk: one step's
+        # decay matrices, their product with C B^T in float32 and the
+        # activations' dtype and the gradients of those, and the state
+        # before every step. Held to the compiled step at 16,384, 24,576
+        # and 32,768 tokens of a 9 : 1 stack at full remat: 2.5, 3.3 and
+        # 2.7% over what the compiler allots, 3.3% under at 8,192 (PERF.md
+        # 6, PR 36). The kernels: the kept states and the running sums
+        # alone, and neither the in-projection's output nor the gated
+        # output is held a second time (the walk's float32 copies of x
+        # went with it, and the skip is the kernels'): 5.2% over at 32,768
+        # tokens, and still over a v5e's budget; a closer reckoning would
+        # hand the attention layer a rung (PERF.md 7, PR 41)
+        heads, d = shape["A_log"][-1], shape["m_out"][0]
+        groups = cfg.ssm_groups
+        state = (shape["m_conv"][0] - d) // (2 * groups)
+        plan = scan_plan(1, tokens, heads, d // heads, state, groups,
+                         cfg.ssm_chunk, mesh)
+        if plan["form"] == "pallas":
+            return kept(width=shape["m_in"][-1] + 2 * shape["m_conv"][0],
+                        rows=plan["float32_bytes_in_hbm"])
+        return kept(
+            width=2 * shape["m_in"][-1] + 2 * shape["m_conv"][0] + d,
+            rows=4 * plan["decay_bytes_in_hbm"]
+            + plan["steps"] * heads * (d // heads) * state * 4)
+
+    def terms(cfg, states):
+        return None, {counter: jnp.abs(states).max()}
+
+    return Part(leaves, body, keeps, reports="ssm_state", terms=terms)

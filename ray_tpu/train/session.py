@@ -71,27 +71,26 @@ class TrainContext:
         return self.trial_dir
 
 
-# what a routed-experts train loop may put into ``train.report``: the
-# session serves the last value of each as ``rtpu_train_<key>``
+# the counters a train loop may put into ``train.report``: the session
+# serves the last value of each as ``rtpu_train_<key>``. Of routed experts
 # (``moe_rows_held``: of the routed rows, those the experts held here
 # multiplied, where a layer holds a share of its experts;
 # ``moe_rows_passed``: the rows the passes over those took, padding and
-# all, ``ops/moe.rows_passed``: held over passed is how full they were)
-# (``moe_router_bias_abs_max``: the largest selection bias of a router
-# that balances its load by one, ``models/lfm2.update_router_bias``)
-MOE_COUNTERS = ("moe_rows_routed", "moe_rows_held", "moe_rows_passed",
-                "moe_expert_load_max_over_mean", "moe_router_bias_abs_max")
-# and of a stack with selective-scan layers, served the same way
-# (``ssm_state_abs_max``: the largest ``|S|`` a scan layer's state holds
-# after a sequence's last position, ``ops/ssm.mamba2_mixer``: a state that
-# grows from step to step says the decays have drifted toward 1)
-SSM_COUNTERS = ("ssm_state_abs_max",)
-# and of a stack with gated delta-rule layers (``gdn_state_abs_max``: the
-# same of a linear layer's ``[value, key]`` state, ``ops/delta.
-# gated_delta_mixer``: with ``beta`` up to 2 a state's eigenvalue along a
-# key may be negative, and a state that grows says the keys have lost
-# their unit length or the decays their float32)
-GDN_COUNTERS = ("gdn_state_abs_max",)
+# all, ``ops/moe.rows_passed``: held over passed is how full they were;
+# ``moe_router_bias_abs_max``: the largest selection bias of a router
+# that balances its load by one, ``models/lfm2.update_router_bias``); of
+# selective-scan layers (``ssm_state_abs_max``: the largest ``|S|`` a scan
+# layer's state holds after a sequence's last position,
+# ``ops/ssm.mamba2_mixer``: a state that grows from step to step says the
+# decays have drifted toward 1); of gated delta-rule layers
+# (``gdn_state_abs_max``: the same of a linear layer's ``[value, key]``
+# state, ``ops/delta.gated_delta_mixer``: with ``beta`` up to 2 a state's
+# eigenvalue along a key may be negative, and a state that grows says the
+# keys have lost their unit length or the decays their float32). A new
+# operator adds its counter's name here.
+STEP_COUNTERS = ("moe_rows_routed", "moe_rows_held", "moe_rows_passed",
+                 "moe_expert_load_max_over_mean", "moe_router_bias_abs_max",
+                 "ssm_state_abs_max", "gdn_state_abs_max")
 
 
 class SessionInterruptedError(BaseException):
@@ -129,8 +128,8 @@ class _TrainSession:
         self._finished = False
         self._interrupted: Optional[str] = None
         self._reports = 0
-        # the last reported MOE_COUNTERS, SSM_COUNTERS and GDN_COUNTERS
-        self._moe: Dict[str, float] = {}
+        # the last reported value of each of STEP_COUNTERS
+        self._counters: Dict[str, float] = {}
         import weakref
 
         from ray_tpu import metrics
@@ -138,7 +137,7 @@ class _TrainSession:
         me = weakref.ref(self)     # the registry keeps no session alive
         metrics.REGISTRY.register_source("rtpu_train", lambda: {
             "reports": me()._reports, "checkpoints": me()._ckpt_index,
-            "world_rank": me().context.world_rank, **me()._moe})
+            "world_rank": me().context.world_rank, **me()._counters})
 
         def runner():
             try:
@@ -190,9 +189,8 @@ class _TrainSession:
                 persisted = ckpt.path
             self._ckpt_index += 1
         self._reports += 1
-        self._moe.update({k: metrics[k] for k in
-                          MOE_COUNTERS + SSM_COUNTERS + GDN_COUNTERS
-                          if isinstance(metrics.get(k), (int, float))})
+        self._counters.update({k: metrics[k] for k in STEP_COUNTERS
+                               if isinstance(metrics.get(k), (int, float))})
         self._result_q.put(TrainingResult(metrics=dict(metrics),
                                           checkpoint_dir=persisted))
         # Lockstep: wait until the driver consumed this result before the

@@ -358,315 +358,6 @@ def test_moe_unrolled_matches_scan(model, remat):
                                           scanned.num_experts)
 
 
-# ------------------------------------------------------------------- laguna
-
-
-@pytest.fixture(scope="module", params=[None, (4, 8)],
-                ids=["all-experts", "held-4..11"])
-def laguna_setup(request):
-    """``LagunaConfig.tiny()``: five layers (full + dense MLP, three
-    sliding, full), 4 and 6 query heads on 2 kv heads, window 8 at 32
-    positions, 16 experts top-4 and a shared one, float32; all experts
-    here, or experts 4..11 of each routed layer as a chip's share."""
-    import os
-    import sys
-
-    sys.path.insert(0, os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))))          # benchmark/ lies beside tests/
-    from benchmark.references import laguna_ref
-    from ray_tpu.models import laguna
-
-    cfg = laguna.LagunaConfig.tiny(attn_impl="reference",
-                                   experts_held=request.param)
-    params = laguna.init_params(cfg, jax.random.PRNGKey(0))
-    # the norms start at 1: move them, or a norm applied twice goes unseen
-    for n, kind in enumerate(params["layers"]):
-        for i, name in enumerate(("attn_norm", "mlp_norm")):
-            w = params["layers"][kind][name]
-            params["layers"][kind][name] = 1.0 + 0.3 * jax.random.normal(
-                jax.random.PRNGKey(10 * n + i), w.shape)
-    tokens = np.random.default_rng(1).integers(0, cfg.vocab_size, (2, 33))
-    return laguna, laguna_ref, cfg, params, tokens
-
-
-def test_laguna_forward_and_loss_terms_match_the_reference(laguna_setup):
-    """Logits, the four routed layers' router logits and expert counts
-    (over all 16 experts, held or not) and both terms of the loss against
-    the plain float32 reference on seeded weights."""
-    laguna, laguna_ref, cfg, params, tokens = laguna_setup
-    assert cfg.pattern == ("full_dense", "sliding_moe", "sliding_moe",
-                           "sliding_moe", "full_moe")
-    assert params["layers"]["sliding_moe"]["wq"].shape == (3, 64, 6 * 16)
-    assert params["layers"]["full_moe"]["wq"].shape == (1, 64, 4 * 16)
-    assert params["layers"]["full_moe"]["e_gate"].shape[1] == (
-        8 if cfg.experts_held else 16)
-    with jax.default_matmul_precision("highest"):
-        logits, router = jax.jit(lambda p, t: laguna.forward(
-            cfg, p, t, keep_router_logits=True))(params, tokens[:, :-1])
-        loss, terms = jax.jit(lambda p, t: laguna.loss_terms(
-            cfg, p, {"tokens": t}))(params, tokens)
-    ref = laguna_ref.token_nll(cfg, params, tokens)
-    np.testing.assert_allclose(
-        np.asarray(logits), np.asarray(laguna_ref.logits(
-            cfg, params, tokens[:, :-1])), rtol=1e-5, atol=1e-5)
-    assert router["logits"].shape == (4, 64, 16)
-    np.testing.assert_allclose(np.asarray(router["logits"]),
-                               ref["router_logits"], rtol=1e-5, atol=1e-5)
-    want_counts = np.stack([np.bincount(c.ravel(), minlength=16)
-                            for c in ref["chosen"]])
-    assert (np.asarray(terms["expert_counts"]) == want_counts).all()
-    assert int(want_counts.sum()) == 4 * 64 * cfg.top_k
-    first, count = cfg.experts_held or (0, 16)
-    assert int(laguna.rows_held(cfg, terms["expert_counts"])) == int(
-        want_counts[:, first:first + count].sum())
-    for name in ("cross_entropy", "load_balance"):
-        assert abs(float(terms[name]) - ref["terms"][name]) < 1e-5, name
-    assert abs(float(loss) - ref["terms"]["loss"]) < 1e-5
-    # the share changes the result: what the absent experts add is left out
-    assert ref["terms"]["load_balance"] > 1.0
-
-
-def test_laguna_gradients_match_the_reference(laguna_setup):
-    laguna, laguna_ref, cfg, params, tokens = laguna_setup
-    with jax.default_matmul_precision("highest"):
-        got = jax.jit(jax.grad(lambda p: laguna.loss_fn(
-            cfg, p, {"tokens": tokens})))(params)
-    want = jax.jit(jax.grad(
-        lambda p: laguna_ref.loss(cfg, p, tokens)))(params)
-    flat, _ = jax.tree_util.tree_flatten_with_path(got)
-    assert len(flat) == 3 + 10 + 2 * 14
-    for (path, g), w in zip(flat, jax.tree_util.tree_leaves(want)):
-        scale = float(jnp.abs(w).max())
-        assert scale > 1e-5, path                       # it is reached
-        np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=1e-4,
-                                   atol=1e-5 * max(scale, 1e-2),
-                                   err_msg=str(path))
-
-
-def test_laguna_reference_gradient_of_a_weighted_loss(laguna_setup):
-    """What the chip check compares: ``token_nll(grad_weights=...)`` gives
-    the gradient of ``sum(weights * per-position loss)`` for the first
-    layer of each kind, the embedding, the last norm and the head, row by
-    row; the program's own gradient of that scalar agrees, and the rest
-    of ``token_nll``'s result is what it is without the gradient."""
-    laguna, laguna_ref, cfg, params, tokens = laguna_setup
-    weights = np.random.default_rng(3).uniform(
-        0.5, 1.5, (2, 32)).astype(np.float32) / 64
-
-    def weighted(p):
-        lg, _ = laguna.forward(cfg, p, tokens[:, :-1])
-        nll = jax.nn.logsumexp(lg, -1) - jnp.take_along_axis(
-            lg, tokens[:, 1:, None], -1)[..., 0]
-        return (weights * nll).sum()
-
-    with jax.default_matmul_precision("highest"):
-        got = laguna_ref.first_layers(jax.jit(jax.grad(weighted))(params))
-    plain = laguna_ref.token_nll(cfg, params, tokens)
-    ref = laguna_ref.token_nll(cfg, params, tokens, grad_weights=weights)
-    np.testing.assert_allclose(ref["nll"], plain["nll"], atol=1e-6)
-    assert (ref["chosen"] == plain["chosen"]).all()
-    assert got["layers"]["sliding_moe"]["wq"].shape == (64, 6 * 16)
-    flat, _ = jax.tree_util.tree_flatten_with_path(got)
-    assert len(flat) == 3 + 10 + 2 * 14
-    for (path, g), w in zip(flat, jax.tree_util.tree_leaves(ref["grads"])):
-        scale = float(jnp.abs(w).max())
-        assert scale > 1e-6, path
-        np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=1e-4,
-                                   atol=1e-5 * max(scale, 1e-2),
-                                   err_msg=str(path))
-
-
-def test_laguna_window_and_gate_are_in_the_result():
-    """Leaving out the window mask, the per-head gate or the routed
-    scale changes the logits: none of them is a no-op at these sizes."""
-    from dataclasses import replace
-
-    from ray_tpu.models import laguna
-
-    cfg = laguna.LagunaConfig.tiny(attn_impl="reference")
-    params = laguna.init_params(cfg, jax.random.PRNGKey(0))
-    tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 32), 0, 256)
-    base = laguna.forward(cfg, params, tokens)[0]
-    for other in (replace(cfg, sliding_window=None),
-                  replace(cfg, sliding_window=4),
-                  replace(cfg, routed_scale=1.0),
-                  replace(cfg, partial_rotary_factor=1.0)):
-        assert float(jnp.abs(laguna.forward(other, params, tokens)[0]
-                             - base).max()) > 1e-3, other
-    # a window of the whole sequence is causal attention
-    np.testing.assert_allclose(
-        np.asarray(laguna.forward(replace(cfg, sliding_window=32), params,
-                                  tokens)[0]),
-        np.asarray(laguna.forward(replace(cfg, sliding_window=None), params,
-                                  tokens)[0]), rtol=1e-5, atol=1e-5)
-
-
-# --------------------------------------------------------------------- lfm2
-
-
-@pytest.fixture(scope="module", params=[None, (0, 4)],
-                ids=["all-experts", "held-0..3"])
-def lfm2_setup(request):
-    """``Lfm2Config.tiny()``: five layers (conv + dense MLP, attention,
-    three conv), 4 query heads on 2 kv heads of 16, 8 experts top-2 on
-    sigmoid scores plus a bias, float32; all experts here, or experts
-    0..3 of each routed layer as a chip's share. Norms and biases are
-    moved off their initial 1 and 0."""
-    import os
-    import sys
-
-    sys.path.insert(0, os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))))          # benchmark/ lies beside tests/
-    from benchmark.references import lfm2_ref
-    from ray_tpu.models import lfm2
-
-    cfg = lfm2.Lfm2Config.tiny(attn_impl="reference",
-                               experts_held=request.param)
-    params = lfm2.init_params(cfg, jax.random.PRNGKey(0))
-    for n, kind in enumerate(params["layers"]):
-        for i, name in enumerate(("attn_norm", "op_norm", "mlp_norm",
-                                  "q_norm", "k_norm", "router_bias")):
-            if name in params["layers"][kind]:
-                w = params["layers"][kind][name]
-                params["layers"][kind][name] = w + (
-                    0.1 if name == "router_bias" else 0.3
-                ) * jax.random.normal(jax.random.PRNGKey(10 * n + i), w.shape)
-    tokens = np.random.default_rng(1).integers(0, cfg.vocab_size, (2, 33))
-    return lfm2, lfm2_ref, cfg, params, tokens
-
-
-def test_lfm2_forward_and_loss_match_the_reference(lfm2_setup):
-    """Logits, the four routed layers' router logits, choices and expert
-    counts (over all 8 experts, held or not) and the loss against the
-    plain float32 reference on seeded weights."""
-    lfm2, lfm2_ref, cfg, params, tokens = lfm2_setup
-    assert cfg.pattern == ("conv_dense", "attn_moe", "conv_moe", "conv_moe",
-                           "conv_moe")
-    assert params["layers"]["conv_moe"]["w_in"].shape == (3, 64, 192)
-    assert params["layers"]["attn_moe"]["q_norm"].shape == (1, 16)
-    assert params["layers"]["conv_moe"]["e_gate"].shape[1] == (
-        4 if cfg.experts_held else 8)
-    assert "lm_head" not in params                      # tied
-    with jax.default_matmul_precision("highest"):
-        logits, router = jax.jit(lambda p, t: lfm2.forward(
-            cfg, p, t, keep_router_logits=True))(params, tokens[:, :-1])
-        loss, terms = jax.jit(lambda p, t: lfm2.loss_terms(
-            cfg, p, {"tokens": t}))(params, tokens)
-    ref = lfm2_ref.token_nll(cfg, params, tokens)
-    np.testing.assert_allclose(
-        np.asarray(logits), np.asarray(lfm2_ref.logits(
-            cfg, params, tokens[:, :-1])), rtol=1e-5, atol=1e-5)
-    assert router["logits"].shape == (4, 64, 8)
-    np.testing.assert_allclose(np.asarray(router["logits"]),
-                               ref["router_logits"], rtol=1e-5, atol=1e-5)
-    chosen = np.asarray(router["chosen"])              # route's own
-    assert chosen.shape == (4, 64, cfg.top_k)
-    assert (np.sort(chosen, -1) == np.sort(ref["chosen"], -1)).all()
-    # the bias moved some choice away from the largest scores
-    plain = np.argsort(-ref["router_logits"], -1)[..., :cfg.top_k]
-    assert (np.sort(plain, -1) != np.sort(chosen, -1)).any()
-    want_counts = np.stack([np.bincount(c.ravel(), minlength=8)
-                            for c in ref["chosen"]])
-    assert (np.asarray(terms["expert_counts"]) == want_counts).all()
-    first, count = cfg.experts_held or (0, 8)
-    assert int(lfm2.rows_held(cfg, terms["expert_counts"])) == int(
-        want_counts[:, first:first + count].sum())
-    assert ref["terms"]["load_balance"] == 0.0
-    assert abs(float(loss) - ref["terms"]["loss"]) < 1e-5
-    assert float(loss) == float(terms["cross_entropy"])
-
-
-@pytest.mark.parametrize("model, cell, tokens, chunk", [
-    ("laguna", "laguna-s-2.1-c1", 16384, 11520),
-    ("lfm2", "lfm2-8b-a1b-c1", 16384, 36864)])
-@pytest.mark.parametrize("held_rows, passes", [
-    ("none", 0), ("chunk", 1), ("chunk+1", 2), ("all", None)])
-def test_rows_passed_counts_whole_passes_a_layer(model, cell, tokens, chunk,
-                                                 held_rows, passes):
-    """``rows_passed`` (the ``moe_rows_passed`` counter) from hand-made
-    counts at a cell's shapes, two routed layers, the second balanced
-    (one pass): no held row in the first is no pass, exactly a chunk
-    one, one row more two, every pair as many as hold them; held over
-    passed is how full the passes were. With every expert here nothing
-    is passed: the rows routed."""
-    from dataclasses import replace
-
-    mod, cfg = _cell_config(cell)
-    E, pairs = cfg.num_experts, tokens * cfg.top_k
-    first, count = cfg.experts_held
-    held = {"none": 0, "chunk": chunk, "chunk+1": chunk + 1,
-            "all": pairs}[held_rows]
-    counts = np.zeros((2, E), np.int64)
-    counts[0, first], counts[0, first + count] = held, pairs - held
-    counts[1] = pairs // E
-    want = (-(-pairs // chunk) if passes is None else passes) + 1
-    assert mod.rows_passed(cfg, counts) == want * chunk
-    assert int(mod.rows_held(cfg, counts)) == held + pairs * count // E
-    assert mod.rows_passed(replace(cfg, experts_held=None), counts) == \
-        2 * pairs
-
-
-def test_lfm2_gradients_match_the_reference(lfm2_setup):
-    """Every trained leaf's gradient against the reference's; the bias
-    has none."""
-    lfm2, lfm2_ref, cfg, params, tokens = lfm2_setup
-    with jax.default_matmul_precision("highest"):
-        got = jax.jit(jax.grad(lambda t: lfm2.loss_fn(
-            cfg, lfm2.with_trainable(params, t), {"tokens": tokens})))(
-            lfm2.trainable(params))
-        whole = jax.jit(jax.grad(lambda p: lfm2.loss_fn(
-            cfg, p, {"tokens": tokens})))(params)
-    want = jax.jit(jax.grad(lambda t: lfm2_ref.loss(
-        cfg, lfm2.with_trainable(params, t), tokens)))(lfm2.trainable(params))
-    flat, _ = jax.tree_util.tree_flatten_with_path(got)
-    assert len(flat) == 2 + 8 + 12 + 9
-    for (path, g), w in zip(flat, jax.tree_util.tree_leaves(want)):
-        scale = float(jnp.abs(w).max())
-        assert scale > 1e-5, path                       # it is reached
-        np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=1e-4,
-                                   atol=1e-5 * max(scale, 1e-2),
-                                   err_msg=str(path))
-    for kind in ("attn_moe", "conv_moe"):
-        assert float(jnp.abs(
-            whole["layers"][kind]["router_bias"]).max()) == 0.0
-
-
-def test_lfm2_reference_gradient_of_a_weighted_loss(lfm2_setup):
-    """What the chip check compares: ``token_nll(grad_weights=...)`` gives
-    the gradient of ``sum(weights * per-position loss)`` for the first
-    layer of each kind, the embedding and the last norm, on forced
-    choices; the program's own gradient of that scalar agrees."""
-    lfm2, lfm2_ref, cfg, params, tokens = lfm2_setup
-    weights = np.random.default_rng(2).uniform(
-        0.5, 1.5, (2, 32)).astype(np.float32) / 64
-
-    def weighted(t):
-        lg, router = lfm2.forward(cfg, lfm2.with_trainable(params, t),
-                                  tokens[:, :-1], keep_router_logits=True)
-        nll = jax.nn.logsumexp(lg, -1) - jnp.take_along_axis(
-            lg, jnp.asarray(tokens)[:, 1:, None], -1)[..., 0]
-        return (weights * nll).sum(), router["chosen"]
-
-    with jax.default_matmul_precision("highest"):
-        (_, chosen), got = jax.jit(jax.value_and_grad(
-            weighted, has_aux=True))(lfm2.trainable(params))
-    chosen = np.asarray(chosen)
-    ref = lfm2_ref.token_nll(cfg, params, tokens, forced_topk=chosen,
-                             grad_weights=weights)
-    got = lfm2_ref.first_layers(got)
-    assert set(ref["grads"]) == {"embed", "final_norm", "layers"}
-    for kind, leaves in ref["grads"]["layers"].items():
-        assert "router_bias" not in leaves
-        for name, w in leaves.items():
-            np.testing.assert_allclose(
-                np.asarray(got["layers"][kind][name]), np.asarray(w),
-                rtol=1e-4, atol=1e-6, err_msg=f"{kind}/{name}")
-    for name in ("embed", "final_norm"):
-        np.testing.assert_allclose(np.asarray(got[name]),
-                                   np.asarray(ref["grads"][name]),
-                                   rtol=1e-4, atol=1e-6)
-
-
 def test_per_head_qk_norm_is_not_the_whole_vector_norm():
     """``attention_block`` tells LFM2's norm (a weight of a head's size:
     over each head's dims) from OLMoE's (over the whole q and k vectors)
@@ -719,150 +410,6 @@ def test_per_head_qk_norm_is_not_the_whole_vector_norm():
             cfg, x, {**p, "q_norm": ones[k][0], "k_norm": ones[k][1]},
             cos, sin) for k in (True, False))
     assert float(jnp.abs(a - b).max()) > 1e-3
-
-
-def test_update_router_bias_is_the_references_rule(lfm2_setup):
-    lfm2, lfm2_ref, cfg, params, tokens = lfm2_setup
-    counts = np.random.default_rng(3).integers(0, 40, (4, 8))
-    counts[2] = 16                                  # a balanced layer: no move
-    before = lfm2_ref.router_biases(cfg, params)
-    after = lfm2.update_router_bias(cfg, params, jnp.asarray(counts))
-    want = lfm2_ref.updated_bias(cfg, before, counts)
-    np.testing.assert_array_equal(lfm2_ref.router_biases(cfg, after), want)
-    assert (want[2] == before[2]).all() and (want[0] != before[0]).any()
-    # routed layers 0 is the attention layer's, 1..3 the conv layers'
-    np.testing.assert_array_equal(
-        np.asarray(after["layers"]["attn_moe"]["router_bias"][0]), want[0])
-    np.testing.assert_array_equal(
-        np.asarray(after["layers"]["conv_moe"]["router_bias"]), want[1:])
-    assert float(lfm2.router_bias_abs_max(after)) == float(
-        np.abs(want).max())
-    # nothing else moved
-    for (path, a), b in zip(
-            jax.tree_util.tree_flatten_with_path(lfm2.trainable(after))[0],
-            jax.tree_util.tree_leaves(lfm2.trainable(params))):
-        assert a is b, path
-
-
-def test_router_bias_balances_a_skewed_router():
-    """200 steps of the bias update alone on a router that sends most
-    rows to two experts: ``expert_load_max_over_mean`` falls."""
-    from ray_tpu.ops.moe import route
-
-    E, K, n = 8, 2, 512
-    x = jax.random.normal(jax.random.PRNGKey(0), (n, 16))
-    w = (jax.random.normal(jax.random.PRNGKey(1), (16, E)) * 0.2)
-    x = x.at[:, 0].set(3.0)
-    w = w.at[0, :2].add(0.5)             # experts 0 and 1 favoured
-    from ray_tpu.models import lfm2
-
-    cfg = lfm2.Lfm2Config.tiny(num_layers=2, num_dense_layers=1,
-                               attention_layers=(False, False),
-                               bias_update_rate=0.01)
-    params = {"layers": {"conv_moe": {"router_bias": jnp.zeros((1, E))}}}
-
-    @jax.jit
-    def step(params):
-        top_e = route(x, w, K, True, score="sigmoid",
-                      select_bias=params["layers"]["conv_moe"][
-                          "router_bias"][0], renorm_eps=1e-6)[2]
-        counts = (top_e.reshape(-1, 1) == jnp.arange(E)).sum(0)[None]
-        return lfm2.update_router_bias(cfg, params, counts), counts[0]
-
-    loads = []
-    for _ in range(200):
-        params, counts = step(params)
-        loads.append(float(counts.max() / counts.mean()))
-    assert loads[0] > 2.0
-    assert loads[-1] < 1.3
-    assert float(lfm2.router_bias_abs_max(params)) <= 200 * 0.01 + 1e-6
-
-
-def test_trainable_leaves_the_bias_out_of_adamws_state(lfm2_setup):
-    import optax
-
-    lfm2, _, cfg, params, tokens = lfm2_setup
-    owned = lfm2.trainable(params)
-    assert all("router_bias" not in leaves
-               for leaves in owned["layers"].values())
-    n_all = len(jax.tree_util.tree_leaves(params))
-    assert len(jax.tree_util.tree_leaves(owned)) == n_all - 2
-    tx = optax.adamw(1e-3)
-    opt = tx.init(owned)
-    assert len(jax.tree_util.tree_leaves(opt[0].mu)) == n_all - 2
-    grads = jax.grad(lambda t: lfm2.loss_fn(
-        cfg, lfm2.with_trainable(params, t), {"tokens": tokens}))(owned)
-    updates, _ = tx.update(grads, opt, owned)
-    stepped = lfm2.with_trainable(params, optax.apply_updates(owned, updates))
-    assert jax.tree_util.tree_structure(stepped) == \
-        jax.tree_util.tree_structure(params)
-    for kind in ("attn_moe", "conv_moe"):       # adamw's decay never saw b
-        assert stepped["layers"][kind]["router_bias"] is \
-            params["layers"][kind]["router_bias"]
-    assert float(jnp.abs(stepped["embed"] - params["embed"]).max()) > 0
-
-
-def test_lfm2_8b_a1b_preset_counts_what_the_model_card_says():
-    from ray_tpu.models import lfm2
-
-    cfg = lfm2.Lfm2Config.lfm2_8b_a1b()
-    assert cfg.pattern.count("conv_moe") == 16
-    assert cfg.pattern.count("attn_moe") == 6
-    assert cfg.pattern[:2] == ("conv_dense", "conv_dense")
-    assert cfg.head_dim_ == 64
-    shapes = jax.eval_shape(lambda k: lfm2.init_params(cfg, k),
-                            jax.random.PRNGKey(0))
-    n = sum(a.size for a in jax.tree_util.tree_leaves(shapes))
-    assert abs(n / 8.34e9 - 1) < 0.001
-    # the cell's cut: layer 0 and the first period, 16 of 32, half the rows
-    cut = lfm2.Lfm2Config.lfm2_8b_a1b(
-        num_layers=5, vocab_size=32768, num_dense_layers=1,
-        attention_layers=(False, True, False, False, False),
-        experts_held=(0, 16))
-    shapes = jax.eval_shape(lambda k: lfm2.init_params(cut, k),
-                            jax.random.PRNGKey(0))
-    assert abs(sum(a.size for a in jax.tree_util.tree_leaves(shapes))
-               / 893.7e6 - 1) < 0.001
-    with pytest.raises(ValueError, match="attention_layers names"):
-        lfm2.Lfm2Config.lfm2_8b_a1b(num_layers=5)
-
-
-@pytest.mark.parametrize("how, says", [
-    ({"tie_embeddings": False}, "the head is the embedding"),
-    ({"attention_layers": (True, True, False, False, False)},
-     "an attention layer with a dense MLP")])
-def test_lfm2_refuses_what_it_has_no_parameters_for(how, says):
-    from ray_tpu.models import lfm2
-
-    with pytest.raises(ValueError, match=says):
-        lfm2.Lfm2Config.tiny(**how)
-
-
-def test_the_cells_check_sees_a_route_that_leaves_the_bias_out(
-        lfm2_setup, monkeypatch):
-    """(f) of ``benchmark/cells/train_hybrid.py``: ``route``'s own choices
-    held to the selection scores recomputed from the program's logits and
-    the biases. With the bias dropped inside ``ops/moe.route`` the reading
-    is of the biases' size (0.1 here); the honest program reads a
-    rounding."""
-    from benchmark.cells import train_hybrid
-    from ray_tpu.ops import moe
-
-    lfm2, lfm2_ref, cfg, params, tokens = lfm2_setup
-    tokens = jnp.asarray(tokens, jnp.int32)
-
-    def reading():
-        train_hybrid._program.cache_clear()
-        return train_hybrid.choices_under_bias(lfm2, lfm2_ref, cfg, params,
-                                               tokens)
-
-    assert reading() < 1e-6
-    honest = moe.route
-    monkeypatch.setattr(
-        moe, "route", lambda *a, select_bias=None, **kw: honest(*a, **kw))
-    assert reading() > 0.01
-    monkeypatch.undo()
-    train_hybrid._program.cache_clear()
 
 
 def test_layer_patterns_are_walked_by_runs_of_one_kind():
@@ -1197,10 +744,8 @@ def _tiny_moe(model):
 
 def _stack_of(mod, cfg, layers, tokens):
     """``llama.describe_stack`` as ``mod.forward`` asks for it."""
-    how = {"top_k": cfg.top_k} if mod is not llama else {}
-    if hasattr(cfg, "pattern"):
-        how.update(pattern=cfg.pattern, held=cfg.experts_held)
-    return llama.describe_stack(cfg, layers, tokens, **how)
+    return llama.describe_stack(cfg, mod.LAYER_KINDS, layers, tokens,
+                                pattern=getattr(cfg, "pattern", None))
 
 
 def _capacity_with_room(mod, cfg, params, tokens, room):
@@ -1358,12 +903,14 @@ def test_one_walker_owns_the_layer_loop():
     PR 28."""
     import inspect
 
-    from ray_tpu.models import laguna, mixtral, olmoe
+    from ray_tpu.models import (granite, laguna, lfm2, mixtral, olmo_hybrid,
+                                olmoe, stack)
 
     walker = inspect.getsource(llama.run_layers)
     for needle in ("jax.checkpoint(", "lax.scan("):
         assert walker.count(needle) == 1
-        for mod in (llama, mixtral, olmoe, laguna, gpt2):
+        for mod in (llama, mixtral, olmoe, gpt2, stack, laguna, lfm2,
+                    granite, olmo_hybrid):
             outside = inspect.getsource(mod).replace(walker, "")
             assert needle not in outside, (mod.__name__, needle)
 
@@ -1384,8 +931,9 @@ def _param_bytes(cfg):
 
 def _dense_plan(cfg, tokens, par, cap, sharded):
     return llama.remat_plan(
-        cfg, llama.describe_stack(cfg, llama.init_shapes(cfg)["layers"],
-                                  tokens), tokens, par, cap, sharded)
+        cfg, llama.describe_stack(cfg, llama.LAYER_KINDS,
+                                  llama.init_shapes(cfg)["layers"], tokens),
+        tokens, par, cap, sharded)
 
 
 def _cell_config(name):
@@ -1450,13 +998,12 @@ def test_a_held_kinds_working_set_is_reckoned_from_a_pass(stack, chunk,
     assert moe._held_chunk(pairs, count, cfg.num_experts) == chunk
     a_row = (2 * h + 6 * f) * 2              # bf16: rows, products, theirs
     for kind in kinds:
-        leaves = {k: jax.ShapeDtypeStruct((1,) + a.shape[1:], a.dtype)
-                  for k, a in shapes["layers"][kind].items()
-                  if not k.startswith("e_") and k != "router"}
-        rest = llama.describe_stack(
-            cfg, {kind: leaves}, tokens, pattern=(kind,))["kinds"][kind]
-        assert described[kind]["working_bytes"] == (
-            rest["working_bytes"] + 2 * tokens * h * 4 + chunk * a_row)
+        shape = {k: a.shape[1:] for k, a in shapes["layers"][kind].items()}
+        mixer, mlp = (part.keeps(cfg, shape, tokens, None)
+                      for part in mod.LAYER_KINDS[kind])
+        assert mlp["rows"] == 2 * tokens * h * 4 + chunk * a_row
+        assert described[kind]["working_bytes"] == mlp["rows"] + (
+            tokens * 2 * (4 * h + mixer["width"] + mlp["width"]))
     # against twice the share: 0.22 GB less in Laguna, 0.85 GB in LFM2
     twice = min(2 * pairs * count // cfg.num_experts, pairs)
     assert (twice - chunk) * a_row == {
@@ -1757,507 +1304,6 @@ def test_gemma_hf_checkpoint_parity(hf_act, our_act):
                                atol=5e-5, rtol=1e-4)
 
 
-# ---- models/granite.py: selective-scan layers, an attention layer without
-# rope, the family's four multipliers, a head and loss over token blocks
-
-
-@pytest.fixture(scope="module")
-def granite_setup():
-    from benchmark.references import granite_ref
-    from ray_tpu.models import granite
-
-    cfg = granite.GraniteConfig.tiny(attn_impl="reference")
-    params = granite.init_params(cfg, jax.random.PRNGKey(0))
-    for n, kind in enumerate(params["layers"]):
-        for i, name in enumerate(("attn_norm", "op_norm", "mlp_norm",
-                                  "m_norm", "D", "m_conv_bias")):
-            if name in params["layers"][kind]:
-                w = params["layers"][kind][name]
-                params["layers"][kind][name] = w + 0.3 * jax.random.normal(
-                    jax.random.PRNGKey(10 * n + i), w.shape)
-    tokens = np.random.default_rng(1).integers(0, cfg.vocab_size, (2, 33))
-    return granite, granite_ref, cfg, params, tokens
-
-
-def test_granite_forward_and_loss_match_the_reference(granite_setup):
-    """Logits, the per-position loss through the blocked head, the loss,
-    the scan layers' last states and the counter against the plain float32
-    reference (the recurrence token by token) on seeded weights, at
-    1e-5."""
-    granite, granite_ref, cfg, params, tokens = granite_setup
-    assert cfg.pattern == ("mamba", "mamba", "attention", "mamba")
-    assert params["layers"]["mamba"]["m_in"].shape == (3, 64, 128 + 160 + 8)
-    assert params["layers"]["mamba"]["m_conv"].shape == (3, 160, 4)
-    assert "lm_head" not in params                      # tied
-    # Mamba-2's published initialisation
-    A = np.exp(np.asarray(params["layers"]["mamba"]["A_log"]))
-    dt = np.log1p(np.exp(np.asarray(params["layers"]["mamba"]["dt_bias"])))
-    assert 1.0 <= A.min() and A.max() <= 16.0
-    assert 0.001 - 1e-6 <= dt.min() and dt.max() <= 0.1 + 1e-6
-    with jax.default_matmul_precision("highest"):
-        logits = jax.jit(lambda p, t: granite.forward(cfg, p, t))(
-            params, tokens[:, :-1])
-        nll, states = jax.jit(lambda p, t: granite.token_nll(
-            cfg, p, t, head_block=16))(params, tokens)
-        loss, terms = jax.jit(lambda p, t: granite.loss_terms(
-            cfg, p, {"tokens": t}))(params, tokens)
-    ref = granite_ref.token_nll(cfg, params, tokens)
-    np.testing.assert_allclose(
-        np.asarray(logits), np.asarray(granite_ref.logits(
-            cfg, params, tokens[:, :-1])), rtol=1e-5, atol=1e-5)
-    np.testing.assert_allclose(np.asarray(nll), ref["nll"], rtol=1e-5,
-                               atol=1e-5)
-    np.testing.assert_allclose(float(loss), ref["terms"]["loss"], rtol=1e-5)
-    assert states.shape == ref["last_states"].shape == (
-        3, tokens.shape[0], cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state)
-    np.testing.assert_allclose(np.asarray(states), ref["last_states"],
-                               rtol=1e-5, atol=1e-5)
-    np.testing.assert_allclose(float(terms["ssm_state_abs_max"]),
-                               ref["state_abs_max"], rtol=1e-5)
-    assert ref["state_abs_max"] == np.abs(ref["last_states"]).max() > 0
-
-
-def test_granite_gradients_match_the_reference(granite_setup):
-    """Every leaf's gradient of the loss against the reference's."""
-    granite, granite_ref, cfg, params, tokens = granite_setup
-    with jax.default_matmul_precision("highest"):
-        got = jax.jit(jax.grad(lambda p: granite.loss_fn(
-            cfg, p, {"tokens": tokens})))(params)
-    want = jax.jit(jax.grad(lambda p: granite_ref.loss(cfg, p, tokens)))(
-        params)
-    flat, _ = jax.tree_util.tree_flatten_with_path(got)
-    assert len(flat) == 2 + 9 + 13
-    for (path, g), w in zip(flat, jax.tree_util.tree_leaves(want)):
-        scale = float(jnp.abs(w).max())
-        assert scale > 1e-6, path                       # it is reached
-        np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=1e-4,
-                                   atol=1e-5 * max(scale, 1e-2),
-                                   err_msg=str(path))
-
-
-def test_granite_reference_gradient_of_a_weighted_loss(granite_setup):
-    """What the chip check compares: ``token_nll(grad_weights=...)`` gives
-    the gradient of ``sum(weights * per-position loss)`` for the first
-    layer of each kind, the embedding and the last norm; the program's
-    own gradient of that scalar through the blocked head agrees."""
-    granite, granite_ref, cfg, params, tokens = granite_setup
-    weights = np.random.default_rng(2).uniform(
-        0.5, 1.5, (2, 32)).astype(np.float32) / 64
-    with jax.default_matmul_precision("highest"):
-        got = jax.jit(jax.grad(lambda p: (weights * granite.token_nll(
-            cfg, p, jnp.asarray(tokens), head_block=32)[0]).sum()))(params)
-    ref = granite_ref.token_nll(cfg, params, tokens, grad_weights=weights)
-    got = granite_ref.first_layers(got)
-    assert set(ref["grads"]) == {"embed", "final_norm", "layers"}
-    assert set(ref["grads"]["layers"]) == {"mamba", "attention"}
-    for a, b in zip(jax.tree_util.tree_leaves(got),
-                    jax.tree_util.tree_leaves(ref["grads"])):
-        assert a.shape == b.shape
-        np.testing.assert_allclose(
-            np.asarray(a), np.asarray(b), rtol=1e-4,
-            atol=1e-5 * max(float(jnp.abs(b).max()), 1e-4))
-
-
-@pytest.mark.parametrize("how", ["ramp", "constant-rate", "unchanged"])
-def test_granite_first_step_against_the_reference_adamw(granite_setup, how):
-    """What the cell's check holds the update to: the first moment and the
-    parameters its own train step hands on, against optax's adamw in
-    float32 on the reference's gradient of the mean loss. At the foot of
-    a ramp the rate is 0 and the parameters come out bit-equal; at a
-    constant rate they move as the reference's do; a step that hands on
-    what it was given reads 1 on the moment."""
-    import optax
-
-    from benchmark.cells import train_scan
-
-    granite, granite_ref, cfg, params, tokens = granite_setup
-    tokens = np.asarray(tokens, np.int32)
-    tx = optax.adamw(1e-3 if how == "constant-rate"
-                     else optax.linear_schedule(0.0, 1e-4, 2000))
-    with jax.default_matmul_precision("highest"):
-        after, opt, loss, counter = jax.jit(train_scan.make_step(
-            granite, cfg, tx))(params, tx.init(params), {"tokens": tokens})
-        left = train_scan.first_step_left(granite_ref, after, opt)
-        if how == "unchanged":
-            left = {"params": jax.device_get(granite_ref.first_layers(params)),
-                    "mu": jax.tree_util.tree_map(np.zeros_like, left["mu"])}
-        gaps = train_scan.compare(granite, granite_ref, cfg, params,
-                                  jnp.asarray(tokens), tokens,
-                                  first_step=(tx, left))
-    moment = [v for leaves in gaps["first_step"]["moment_gap"].values()
-              for v in leaves.values()]
-    assert len(moment) == 2 + 9 + 13
-    if how == "unchanged":
-        assert all(v == 1.0 for v in moment)
-    else:
-        assert max(moment) < 1e-5
-    if how == "constant-rate":
-        moved = float(jnp.abs(after["embed"] - params["embed"]).max())
-        assert 5e-4 < moved < 2e-3                  # one step at 1e-3
-        assert gaps["first_step"]["param_gap"] < 1e-6
-    else:
-        assert gaps["first_step"]["param_gap"] == 0.0
-    assert gaps["state_head_gap"]["worst"] < 1e-5
-    assert float(counter) == pytest.approx(
-        gaps["state_abs_max"]["reference"], rel=1e-5)
-
-
-@pytest.mark.parametrize("what", ["remat-full", "unrolled", "bf16"])
-def test_granite_variants_agree(granite_setup, what):
-    """Full remat and the unrolled layer loop compute what the scanned
-    stack without remat does; in bf16 the loss stays near float32's."""
-    from dataclasses import replace
-
-    granite, _, cfg, params, tokens = granite_setup
-    base = float(jax.jit(lambda p: granite.loss_fn(
-        cfg, p, {"tokens": tokens}))(params))
-    other = {"remat-full": replace(cfg, remat=True, remat_policy="full"),
-             "unrolled": replace(cfg, scan_layers=False),
-             "bf16": replace(cfg, dtype=jnp.bfloat16)}[what]
-    loss, grads = jax.jit(jax.value_and_grad(lambda p: granite.loss_fn(
-        other, p, {"tokens": tokens})))(params)
-    assert abs(float(loss) - base) < (5e-2 if what == "bf16" else 1e-5)
-    assert all(bool(jnp.isfinite(g).all())
-               for g in jax.tree_util.tree_leaves(grads))
-
-
-def test_granite_micro_preset_counts_what_the_model_card_says():
-    """The published config: 40 layers, 36 of them Mamba-2, 3.19 B
-    parameters with the embedding tied; one period with the whole
-    vocabulary is the cell's 951,991,232."""
-    from ray_tpu.models import granite
-
-    cfg = granite.GraniteConfig.granite_4_0_h_micro(
-        param_dtype=jnp.bfloat16)
-    assert cfg.pattern.count("mamba") == 36 and cfg.pattern[5] == "attention"
-    count = lambda c: sum(int(np.prod(a.shape)) for a in
-                          jax.tree_util.tree_leaves(jax.eval_shape(
-                              lambda k: granite.init_params(c, k),
-                              jax.random.PRNGKey(0))))
-    assert abs(count(cfg) / 3.19e9 - 1) < 0.01
-    period = granite.GraniteConfig.granite_4_0_h_micro(
-        num_layers=10, attention_layers=cfg.attention_layers[:10])
-    assert count(period) == 951_991_232
-    with pytest.raises(ValueError, match="attention_layers names"):
-        granite.GraniteConfig.granite_4_0_h_micro(num_layers=10)
-
-
-def test_granite_fsdp_train_step_matches_unsharded(granite_setup):
-    """``param_shardings`` on an fsdp mesh: the loss and an adamw step's
-    parameters agree with one device's."""
-    import optax
-
-    granite, _, cfg, params, tokens = granite_setup
-    tokens = jnp.asarray(np.concatenate([tokens, tokens]))      # batch 4
-    mesh = build_mesh(MeshSpec({"fsdp": 4}), devices=jax.devices()[:4])
-    tx = optax.adamw(1e-3)
-
-    def step(p, opt, mesh_):
-        loss, grads = jax.value_and_grad(lambda q: granite.loss_fn(
-            cfg, q, {"tokens": tokens}, mesh=mesh_))(p)
-        updates, opt = tx.update(grads, opt, p)
-        return optax.apply_updates(p, updates), loss
-
-    want_p, want = jax.jit(lambda p, o: step(p, o, None))(
-        params, tx.init(params))
-    sharded = jax.device_put(params, granite.param_shardings(cfg, mesh))
-    got_p, got = jax.jit(lambda p, o: step(p, o, mesh))(
-        sharded, tx.init(sharded))
-    assert abs(float(got) - float(want)) < 1e-5
-    for a, b in zip(jax.tree_util.tree_leaves(got_p),
-                    jax.tree_util.tree_leaves(want_p)):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-4,
-                                   atol=1e-5)
-
-
-# ---- models/olmo_hybrid.py: gated delta-rule layers, a full-attention
-# layer without rope, OLMo 2's block order, an untied head over token blocks
-
-
-@pytest.fixture(scope="module")
-def olmo_hybrid_setup():
-    from benchmark.references import olmo_hybrid_ref
-    from ray_tpu.models import olmo_hybrid
-
-    cfg = olmo_hybrid.OlmoHybridConfig.tiny(attn_impl="reference")
-    params = olmo_hybrid.init_params(cfg, jax.random.PRNGKey(0))
-    for n, kind in enumerate(params["layers"]):
-        for i, name in enumerate(("attn_post_norm", "op_post_norm",
-                                  "mlp_post_norm", "g_norm", "q_norm",
-                                  "k_norm")):
-            if name in params["layers"][kind]:
-                w = params["layers"][kind][name]
-                params["layers"][kind][name] = w + 0.3 * jax.random.normal(
-                    jax.random.PRNGKey(10 * n + i), w.shape)
-    tokens = np.random.default_rng(1).integers(0, cfg.vocab_size, (2, 33))
-    return olmo_hybrid, olmo_hybrid_ref, cfg, params, tokens
-
-
-def test_olmo_hybrid_forward_and_loss_match_the_reference(olmo_hybrid_setup):
-    """Logits, the per-position loss through the blocked head, the loss,
-    the linear layers' last states and the counter against the plain
-    float32 reference (the recurrence token by token) on seeded weights,
-    at 5e-5: a block that norms every sublayer's output to unit size damps
-    no rounding (the gap to the reference grows threefold a layer, 5e-6
-    after one and 3e-5 after three, and two chunk sizes differ by 1e-5
-    between themselves), where Granite's residual weights of 0.22 do.
-    ``tests/test_ops.py`` holds the rule and the mixer alone to 1e-5."""
-    olmo_hybrid, ref_mod, cfg, params, tokens = olmo_hybrid_setup
-    assert cfg.pattern == ("linear", "linear", "linear", "full")
-    linear = params["layers"]["linear"]
-    assert linear["g_in"].shape == (3, 64, 128 + 256 + 8)
-    assert linear["g_conv"].shape == (3, 256, 4)
-    assert params["lm_head"].shape == (64, 256)          # untied
-    assert not {"attn_norm", "op_norm", "mlp_norm"} & (
-        set(linear) | set(params["layers"]["full"]))     # OLMo 2's order
-    # the delta-net's published initialisation
-    A = np.exp(np.asarray(linear["g_A_log"]))
-    dt = np.log1p(np.exp(np.asarray(linear["g_dt_bias"])))
-    assert 0.0 <= A.min() and A.max() <= 16.0
-    assert 0.001 - 1e-6 <= dt.min() and dt.max() <= 0.1 + 1e-6
-    with jax.default_matmul_precision("highest"):
-        logits = jax.jit(lambda p, t: olmo_hybrid.forward(cfg, p, t))(
-            params, tokens[:, :-1])
-        nll, states = jax.jit(lambda p, t: olmo_hybrid.token_nll(
-            cfg, p, t, head_block=16))(params, tokens)
-        loss, terms = jax.jit(lambda p, t: olmo_hybrid.loss_terms(
-            cfg, p, {"tokens": t}))(params, tokens)
-    ref = ref_mod.token_nll(cfg, params, tokens)
-    np.testing.assert_allclose(
-        np.asarray(logits), np.asarray(ref_mod.logits(
-            cfg, params, tokens[:, :-1])), rtol=1e-5, atol=5e-5)
-    np.testing.assert_allclose(np.asarray(nll), ref["nll"], rtol=1e-5,
-                               atol=5e-5)
-    np.testing.assert_allclose(float(loss), ref["terms"]["loss"], rtol=1e-5)
-    assert states.shape == ref["last_states"].shape == (
-        3, tokens.shape[0], cfg.linear_heads, cfg.linear_value_dim,
-        cfg.linear_key_dim)
-    np.testing.assert_allclose(np.asarray(states), ref["last_states"],
-                               rtol=1e-5, atol=5e-5)
-    np.testing.assert_allclose(float(terms["gdn_state_abs_max"]),
-                               ref["state_abs_max"], rtol=1e-5)
-    assert ref["state_abs_max"] == np.abs(ref["last_states"]).max() > 0
-
-
-def test_olmo_hybrid_gradients_match_the_reference(olmo_hybrid_setup):
-    """Every leaf's gradient of the loss against the reference's."""
-    olmo_hybrid, ref_mod, cfg, params, tokens = olmo_hybrid_setup
-    with jax.default_matmul_precision("highest"):
-        got = jax.jit(jax.grad(lambda p: olmo_hybrid.loss_fn(
-            cfg, p, {"tokens": tokens})))(params)
-    want = jax.jit(jax.grad(lambda p: ref_mod.loss(cfg, p, tokens)))(params)
-    flat, _ = jax.tree_util.tree_flatten_with_path(got)
-    assert len(flat) == 3 + 11 + 11
-    for (path, g), w in zip(flat, jax.tree_util.tree_leaves(want)):
-        scale = float(jnp.abs(w).max())
-        assert scale > 1e-6, path                       # it is reached
-        np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=1e-4,
-                                   atol=5e-5 * max(scale, 1e-2),
-                                   err_msg=str(path))
-
-
-def test_olmo_hybrid_reference_gradient_of_a_weighted_loss(
-        olmo_hybrid_setup):
-    """What the chip check compares: ``token_nll(grad_weights=...)`` gives
-    the gradient of ``sum(weights * per-position loss)`` for the first
-    layer of each kind, the embedding, the last norm and the head; the
-    program's own gradient of that scalar through the blocked head
-    agrees."""
-    olmo_hybrid, ref_mod, cfg, params, tokens = olmo_hybrid_setup
-    weights = np.random.default_rng(2).uniform(
-        0.5, 1.5, (2, 32)).astype(np.float32) / 64
-    with jax.default_matmul_precision("highest"):
-        got = jax.jit(jax.grad(lambda p: (weights * olmo_hybrid.token_nll(
-            cfg, p, jnp.asarray(tokens), head_block=32)[0]).sum()))(params)
-    ref = ref_mod.token_nll(cfg, params, tokens, grad_weights=weights)
-    got = ref_mod.first_layers(got)
-    assert set(ref["grads"]) == {"embed", "final_norm", "lm_head", "layers"}
-    assert set(ref["grads"]["layers"]) == {"linear", "full"}
-    for a, b in zip(jax.tree_util.tree_leaves(got),
-                    jax.tree_util.tree_leaves(ref["grads"])):
-        assert a.shape == b.shape
-        np.testing.assert_allclose(
-            np.asarray(a), np.asarray(b), rtol=1e-4,
-            atol=5e-5 * max(float(jnp.abs(b).max()), 1e-4))
-
-
-@pytest.mark.parametrize("how", ["ramp", "constant-rate", "unchanged"])
-def test_olmo_hybrid_first_step_against_the_reference_adamw(
-        olmo_hybrid_setup, how):
-    """What the cell's check holds the update to: the first moment and the
-    parameters its own train step hands on, against optax's adamw in
-    float32 on the reference's gradient of the mean loss. At the foot of
-    a ramp the rate is 0 and the parameters come out bit-equal; at a
-    constant rate they move as the reference's do; a step that hands on
-    what it was given reads 1 on the moment."""
-    import optax
-
-    from benchmark.cells import train_delta
-
-    olmo_hybrid, ref_mod, cfg, params, tokens = olmo_hybrid_setup
-    tokens = np.asarray(tokens, np.int32)
-    tx = optax.adamw(1e-3 if how == "constant-rate"
-                     else optax.linear_schedule(0.0, 1e-4, 2000))
-    with jax.default_matmul_precision("highest"):
-        after, opt, loss, counter = jax.jit(train_delta.make_step(
-            olmo_hybrid, cfg, tx))(params, tx.init(params),
-                                   {"tokens": tokens})
-        left = train_delta.first_step_left(ref_mod, after, opt)
-        if how == "unchanged":
-            left = {"params": jax.device_get(ref_mod.first_layers(params)),
-                    "mu": jax.tree_util.tree_map(np.zeros_like, left["mu"])}
-        gaps = train_delta.compare(olmo_hybrid, ref_mod, cfg, params,
-                                   jnp.asarray(tokens), tokens,
-                                   first_step=(tx, left))
-    moment = [v for leaves in gaps["first_step"]["moment_gap"].values()
-              for v in leaves.values()]
-    assert len(moment) == 3 + 11 + 11
-    assert set(gaps["gradient_gap"]) == {"linear", "full", "top"}
-    if how == "unchanged":
-        assert all(v == 1.0 for v in moment)
-    else:
-        assert max(moment) < 1e-4
-    if how == "constant-rate":
-        moved = float(jnp.abs(after["embed"] - params["embed"]).max())
-        assert 5e-4 < moved < 2e-3                  # one step at 1e-3
-        assert gaps["first_step"]["param_gap"] < 1e-6
-    else:
-        assert gaps["first_step"]["param_gap"] == 0.0
-    assert gaps["state_head_gap"]["worst"] < 1e-4
-    assert float(counter) == pytest.approx(
-        gaps["state_abs_max"]["reference"], rel=1e-5)
-
-
-@pytest.mark.parametrize("what", ["remat-full", "unrolled", "bf16",
-                                  "chunk-4", "chunk-16"])
-def test_olmo_hybrid_variants_agree(olmo_hybrid_setup, what):
-    """Full remat, the unrolled layer loop and another chunk of the rule
-    compute what the scanned stack without remat does at a chunk of 8; in
-    bf16 the loss stays near float32's."""
-    from dataclasses import replace
-
-    olmo_hybrid, _, cfg, params, tokens = olmo_hybrid_setup
-    base = float(jax.jit(lambda p: olmo_hybrid.loss_fn(
-        cfg, p, {"tokens": tokens}))(params))
-    other = {"remat-full": replace(cfg, remat=True, remat_policy="full"),
-             "unrolled": replace(cfg, scan_layers=False),
-             "bf16": replace(cfg, dtype=jnp.bfloat16),
-             "chunk-4": replace(cfg, rule_chunk=4),
-             "chunk-16": replace(cfg, rule_chunk=16)}[what]
-    loss, grads = jax.jit(jax.value_and_grad(lambda p: olmo_hybrid.loss_fn(
-        other, p, {"tokens": tokens})))(params)
-    assert abs(float(loss) - base) < (5e-2 if what == "bf16" else 1e-5)
-    assert all(bool(jnp.isfinite(g).all())
-               for g in jax.tree_util.tree_leaves(grads))
-
-
-def test_olmo_hybrid_7b_preset_counts_what_the_model_card_says():
-    """The published config: 32 layers, every fourth full attention, 7.43 B
-    parameters with an untied head; one period with an eighth of the
-    vocabulary is the cell's 928,862,196 (928.7 M by the issue's rounded
-    addends)."""
-    from ray_tpu.models import olmo_hybrid
-
-    cfg = olmo_hybrid.OlmoHybridConfig.olmo_hybrid_7b(
-        param_dtype=jnp.bfloat16)
-    assert cfg.pattern.count("linear") == 24
-    assert cfg.pattern[:4] == ("linear", "linear", "linear", "full")
-    assert cfg.head_dim_ == 128 and cfg.linear_conv_dim == 11_520
-    count = lambda c: sum(int(np.prod(a.shape)) for a in
-                          jax.tree_util.tree_leaves(jax.eval_shape(
-                              lambda k: olmo_hybrid.init_params(c, k),
-                              jax.random.PRNGKey(0))))
-    assert count(cfg) == 7_430_870_688
-    period = olmo_hybrid.OlmoHybridConfig.olmo_hybrid_7b(
-        num_layers=4, vocab_size=12_544)
-    assert period.pattern == cfg.pattern[:4]
-    assert count(period) == 928_862_196
-    assert abs(count(period) / 928.7e6 - 1) < 5e-4
-    with pytest.raises(ValueError, match="attention_layers names"):
-        olmo_hybrid.OlmoHybridConfig.olmo_hybrid_7b(
-            num_layers=4, attention_layers=cfg.attention_layers)
-
-
-def test_olmo_hybrid_fsdp_train_step_matches_unsharded(olmo_hybrid_setup):
-    """``param_shardings`` on an fsdp mesh: the loss and an adamw step's
-    parameters agree with one device's."""
-    import optax
-
-    olmo_hybrid, _, cfg, params, tokens = olmo_hybrid_setup
-    tokens = jnp.asarray(np.concatenate([tokens, tokens]))      # batch 4
-    mesh = build_mesh(MeshSpec({"fsdp": 4}), devices=jax.devices()[:4])
-    tx = optax.adamw(1e-3)
-
-    def step(p, opt, mesh_):
-        loss, grads = jax.value_and_grad(lambda q: olmo_hybrid.loss_fn(
-            cfg, q, {"tokens": tokens}, mesh=mesh_))(p)
-        updates, opt = tx.update(grads, opt, p)
-        return optax.apply_updates(p, updates), loss
-
-    want_p, want = jax.jit(lambda p, o: step(p, o, None))(
-        params, tx.init(params))
-    sharded = jax.device_put(params, olmo_hybrid.param_shardings(cfg, mesh))
-    got_p, got = jax.jit(lambda p, o: step(p, o, mesh))(
-        sharded, tx.init(sharded))
-    assert abs(float(got) - float(want)) < 1e-5
-    for a, b in zip(jax.tree_util.tree_leaves(got_p),
-                    jax.tree_util.tree_leaves(want_p)):
-        # adamw's first step is the rate times the gradient's sign, nearly:
-        # an entry whose gradient is within a rounding of zero may move by
-        # a part of 1e-3 more or less (one of 75,264 did, by 1.7e-4)
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-4,
-                                   atol=3e-4)
-
-
-def test_attention_block_in_olmo_order_with_a_whole_vector_qk_norm(
-        olmo_hybrid_setup):
-    """A layer with ``attn_post_norm`` and no ``attn_norm``: the block's
-    input is not normed, its output is, before the sum; q and k are normed
-    over their whole vectors and not rotated: against
-    ``olmo_hybrid_ref.attention`` on one layer's weights."""
-    _, ref_mod, cfg, params, _ = olmo_hybrid_setup
-    p = {k: v[0] for k, v in params["layers"]["full"].items()}
-    x = jax.random.normal(jax.random.PRNGKey(5), (2, 32, cfg.hidden_size))
-    sz = ref_mod._sizes(cfg)
-    with jax.default_matmul_precision("highest"):
-        got = llama.attention_block(cfg, x, p, None, None)
-        want = jnp.stack([row + ref_mod._rms_norm(
-            ref_mod.attention(row, p, sz), p["attn_post_norm"],
-            cfg.rms_norm_eps) for row in x])
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-5,
-                               atol=1e-5)
-
-
-# ---- attention_block: no rope, a stated scale, a residual multiplier
-
-
-def test_attention_block_without_rope_at_a_stated_scale(granite_setup):
-    """``cos=None`` leaves q and k unrotated, ``sm_scale`` replaces the
-    head size's scale and ``resid_scale`` weighs the block's output:
-    against ``granite_ref.attention`` on one layer's weights."""
-    granite, granite_ref, cfg, params, _ = granite_setup
-    p = {k: v[0] for k, v in params["layers"]["attention"].items()}
-    x = jax.random.normal(jax.random.PRNGKey(5), (2, 32, cfg.hidden_size))
-    sz = granite_ref._sizes(cfg)
-    with jax.default_matmul_precision("highest"):
-        got = llama.attention_block(cfg, x, p, None, None,
-                                    sm_scale=cfg.attention_multiplier,
-                                    resid_scale=cfg.residual_multiplier)
-        want = jnp.stack([row + cfg.residual_multiplier
-                          * granite_ref.attention(granite_ref._rms_norm(
-                              row, p["attn_norm"], cfg.rms_norm_eps), p, sz)
-                          for row in x])
-        plain = llama.attention_block(cfg, x, p, None, None)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-5,
-                               atol=1e-5)
-    # the scale is in the result: head_dim ** -0.5 is 1/4 here, not 1/16
-    assert float(jnp.abs(plain - got).max()) > 1e-3
-    with pytest.raises(ValueError, match="stated scale"):
-        from dataclasses import replace
-        llama.attention_block(replace(cfg, attn_impl="ring"), x, p, None,
-                              None, sm_scale=0.1)
-
-
 def _attention_block_before(cfg, x, p, cos, sin, mesh=None,
                     seq_axis=None, window=None):
     """Pre-norm attention sub-block with residual: x + wo(attend(qkv)).
@@ -2435,8 +1481,8 @@ def test_blocked_head_and_loss_match_the_whole_one(tied):
 @pytest.mark.parametrize("form", ["xla_walk", "pallas"])
 def test_describe_stack_knows_a_scan_layer_and_a_blocked_head(form,
                                                               monkeypatch):
-    """A layer with an ``A_log`` is reckoned as a selective scan: the MLP
-    rung alone keeps anything, the working set holds the in-projection's
+    """A kind whose mixer is ``mamba2_part`` is reckoned as a selective
+    scan: the MLP rung alone keeps anything, the working set holds the in-projection's
     width and what the scan's form puts in HBM (``scan_plan``: XLA's walk
     on the CPU and under a mesh, one step of the walk; the kernels on a
     TPU backend, the kept states and the running sums); ``head_tokens``
@@ -2458,8 +1504,8 @@ def test_describe_stack_knows_a_scan_layer_and_a_blocked_head(form,
     T = 32768
     how = dict(pattern=cfg.pattern,
                head_tokens=llama.head_block(T, cfg.vocab_size))
-    stack = llama.describe_stack(cfg, shapes["layers"], T, **how,
-                                 scan=(cfg.ssm_groups, cfg.ssm_chunk))
+    stack = llama.describe_stack(cfg, granite.LAYER_KINDS, shapes["layers"],
+                                 T, **how)
     assert stack["runs"] == (("mamba", 5), ("attention", 1), ("mamba", 4))
     mamba, attn = stack["kinds"]["mamba"], stack["kinds"]["attention"]
     assert mamba["rungs"] == (0, 0, 2 * T * 8192 * 2, 0)
@@ -2467,9 +1513,8 @@ def test_describe_stack_knows_a_scan_layer_and_a_blocked_head(form,
     plan = ssm.scan_plan(1, T, 64, 64, 128, 1, 256)
     assert plan["form"] == form
     # a sharded caller's scan is XLA's walk, and is reckoned so
-    sharded = llama.describe_stack(
-        cfg, shapes["layers"], T, **how,
-        scan=(cfg.ssm_groups, cfg.ssm_chunk, object()))
+    sharded = llama.describe_stack(cfg, granite.LAYER_KINDS,
+                                   shapes["layers"], T, **how, mesh=object())
     walked = sharded["kinds"]["mamba"]["working_bytes"]
     assert walked > 4 * 2 ** 27 + T * 2 * 2 * 8512
     if form == "pallas":
@@ -2499,8 +1544,8 @@ def test_describe_stack_knows_a_scan_layer_and_a_blocked_head(form,
 
 @pytest.mark.parametrize("form", ["xla_walk", "pallas"])
 def test_describe_stack_knows_a_delta_rule_layer(form, monkeypatch):
-    """A layer with a ``g_in`` is reckoned as a gated delta rule: the MLP
-    rung alone keeps anything, the working set holds the in-projection's
+    """A kind whose mixer is ``gated_delta_part`` is reckoned as a gated
+    delta rule: the MLP rung alone keeps anything, the working set holds the in-projection's
     width and what the rule's form puts in HBM (``rule_plan``: XLA's walk
     on the CPU and under a mesh, with the taps' width and one step of the
     walk; the kernels on a TPU backend, with the kept states); the plan of
@@ -2518,9 +1563,8 @@ def test_describe_stack_knows_a_delta_rule_layer(form, monkeypatch):
                             jax.random.PRNGKey(0))
     T = 32768
     stack = llama.describe_stack(
-        cfg, shapes["layers"], T, pattern=cfg.pattern,
-        head_tokens=llama.head_block(T, cfg.vocab_size),
-        rule=(cfg.linear_key_dim, cfg.rule_chunk))
+        cfg, olmo_hybrid.LAYER_KINDS, shapes["layers"], T,
+        pattern=cfg.pattern, head_tokens=llama.head_block(T, cfg.vocab_size))
     assert stack["runs"] == (("linear", 3), ("full", 1))
     linear, full = stack["kinds"]["linear"], stack["kinds"]["full"]
     assert linear["rungs"] == (0, 0, 2 * T * 11008 * 2, 0)
@@ -2532,8 +1576,8 @@ def test_describe_stack_knows_a_delta_rule_layer(form, monkeypatch):
             + T * 2 * 17340
         # a sharded caller's rule is XLA's walk, and is reckoned so
         sharded = llama.describe_stack(
-            cfg, shapes["layers"], T, pattern=cfg.pattern,
-            rule=(cfg.linear_key_dim, cfg.rule_chunk, object()))
+            cfg, olmo_hybrid.LAYER_KINDS, shapes["layers"], T,
+            pattern=cfg.pattern, mesh=object())
         assert sharded["kinds"]["linear"]["working_bytes"] \
             > linear["working_bytes"] + T * 2 * 11520
     else:
@@ -2552,20 +1596,25 @@ def test_describe_stack_knows_a_delta_rule_layer(form, monkeypatch):
 
 
 @pytest.mark.parametrize("how, says", [
-    ("no-operator", "its operators are \\[\\]"),
-    ("two-operators", "its operators are \\['wq', 'A_log'\\]"),
-    ("a-new-leaf", "leaves \\['w_lora'\\]")])
+    ("no-operator", "lacks \\['wq'\\] of its parts"),
+    ("two-operators", "names the leaves \\['A_log'\\]"),
+    ("a-new-leaf", "names the leaves \\['w_lora'\\]"),
+    ("a-new-kind", "the table has \\['layer'\\]")])
 def test_describe_stack_refuses_a_kind_it_does_not_know(how, says):
-    """A layer without one of the four operators the plan reckons with,
-    with two of them, or with a leaf of a name it has never seen is not
-    planned as another kind: it raises."""
+    """A layer without a matrix its parts name, with a second operator's
+    leaf or a leaf of a name neither part has, or of a kind the table has
+    no entry for, is not planned as another kind: it raises, by name."""
     cfg = llama.LlamaConfig.tiny()
     layers = dict(llama.init_shapes(cfg)["layers"])
+    pattern = None
     if how == "no-operator":
         layers = {k: v for k, v in layers.items() if k != "wq"}
     elif how == "two-operators":
         layers["A_log"] = jax.ShapeDtypeStruct((2, 8), jnp.float32)
-    else:
+    elif how == "a-new-leaf":
         layers["w_lora"] = jax.ShapeDtypeStruct((2, 64, 8), jnp.float32)
+    else:
+        layers, pattern = {"hyena": layers}, ("hyena", "hyena")
     with pytest.raises(ValueError, match=says):
-        llama.describe_stack(cfg, layers, 64)
+        llama.describe_stack(cfg, llama.LAYER_KINDS, layers, 64,
+                             pattern=pattern)
