@@ -47,7 +47,11 @@ from ray_tpu.util import tracing
 # act(gate) * up are recomputed at every level: elementwise and cheap, and
 # as large again as all four rungs.
 REMAT_LADDER = (
-    ("flash_out", "flash_lse"),         # the backward's second flash_fwd
+    # the backward's second flash_fwd; of a latent-attention layer
+    # (ops/mla.py) its two latents besides, a quarter of the rung's bytes
+    # there: the backward then reruns the expansions from them and
+    # neither down-projection
+    ("flash_out", "flash_lse", "q_latent", "kv_latent"),
     ("q_rope", "k_rope", "v_proj"),     # the q/k/v matmuls and rope
     ("mlp_gate", "mlp_up"),             # the gate and up matmuls, grouped too
     ("attn_resid",),                    # the wo matmul

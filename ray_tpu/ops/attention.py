@@ -625,7 +625,8 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                     block_k: Optional[int] = None,
                     use_pallas: Optional[bool] = None,
                     interpret: bool = False,
-                    window: Optional[int] = None) -> jax.Array:
+                    window: Optional[int] = None,
+                    k_shared: Optional[jax.Array] = None) -> jax.Array:
     """Flash attention. Layout: q [b, sq, heads, d]; k/v [b, sk, kv_heads, d].
 
     ``window`` (with ``causal``): a query sees the ``window`` keys that
@@ -639,6 +640,13 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     path writes one kept span as it is traced, ``rtpu.flash.tiles``: its
     blocks, the head size and ``tile_plan``'s count of what its loops will
     visit.
+
+    Keys and values may differ in width: q, k ``[.., d_k]``, v ``[..,
+    d_v]`` give ``[b, sq, heads, d_v]`` through kernels of their own
+    (``flash_kv_*``, below), and so does ``k_shared [b, sk, d_s]``, a part
+    of every head's key that all heads share (latent attention's rope
+    dims): k is then ``[.., d_k - d_s]`` and the last ``d_s`` dims of a
+    query meet ``k_shared``. The default scale is ``d_k ** -0.5``.
     """
     _check_window(causal, window)
     if sm_scale is None:
@@ -646,7 +654,12 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     if use_pallas is None:
         use_pallas = jax.default_backend() not in ("cpu",)
     if not use_pallas:
+        if k_shared is not None:
+            k = with_shared_key(k, k_shared)
         return attention_reference(q, k, v, causal, sm_scale, window)
+    if k_shared is not None or q.shape[-1] != v.shape[-1]:
+        return _flash_kv(q, k, v, k_shared, causal, sm_scale, block_q,
+                         block_k, interpret, window)
     if block_q is None:
         block_q = _auto_block(q.shape[1])
     if block_k is None:
@@ -660,3 +673,384 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         pass
     return _flash(q, k, v, causal, sm_scale, block_q, block_k, interpret,
                   window)
+
+
+# ------------------------------------------------ keys wider than values
+#
+# Latent attention (``ops/mla.py``) scores with keys of 128 + 64 dims, the
+# 64 a rotated vector that every head shares, against values of 128. The
+# kernels below are the three above with the widths apart: the scores are
+# the sum of two products, ``q[:, :d_m] k^T`` and ``q[:, d_m:] kx^T`` (kx:
+# ``k_shared``, fetched once a batch row, or absent), so every operand is
+# 128 or 64 lanes wide, no key is broadcast to the heads in HBM and no
+# value is padded; the accumulators and ``dV`` are ``d_v`` wide. They are
+# kernels of their own, named ``flash_kv_*``, and not the ones above made
+# general: the text of a call at equal widths stays what it was, for the
+# programs that were measured with it.
+
+
+def with_shared_key(k: jax.Array, k_shared: jax.Array) -> jax.Array:
+    """k [b, sk, kvh, d_m] and the part all heads share [b, sk, d_s] ->
+    whole keys [b, sk, kvh, d_m + d_s]: what the kernels never build."""
+    return jnp.concatenate(
+        [k, jnp.broadcast_to(k_shared[:, :, None, :],
+                             k.shape[:3] + k_shared.shape[-1:])], axis=-1)
+
+
+def _scores(q, qx, k, kx, transposed: bool = False):
+    """A tile's scores from its one or two products: [block_q, block_k],
+    or ``transposed`` (keys down) [block_k, block_q]."""
+    def dot(a, b):
+        a, b = (b, a) if transposed else (a, b)
+        return jax.lax.dot_general(a, b, (((1,), (1,)), ((), ())),
+                                   preferred_element_type=jnp.float32)
+    return dot(q, k) if qx is None else dot(q, k) + dot(qx, kx)
+
+
+def _dot(a, b, contract=(1, 0)):
+    return jax.lax.dot_general(a, b, ((contract[:1], contract[1:]), ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
+def _flash_kv_kernel(*refs, shared: bool, block_k: int, causal: bool,
+                     sm_scale: float, seq_k: int, block_q: int,
+                     causal_offset: int, window: Optional[int]):
+    # refs: q [1, block_q, d_m], (qx [1, block_q, d_s]), k [1, seq_k, d_m],
+    # (kx [1, seq_k, d_s]), v [1, seq_k, d_v]; o [1, block_q, d_v], lse
+    import jax.experimental.pallas as pl
+
+    if shared:
+        q_ref, qx_ref, k_ref, kx_ref, v_ref, o_ref, lse_ref = refs
+        qx = qx_ref[0].astype(jnp.float32) * sm_scale
+    else:
+        q_ref, k_ref, v_ref, o_ref, lse_ref = refs
+        qx = None
+    qb = pl.program_id(1)
+    q = q_ref[0].astype(jnp.float32) * sm_scale
+    first_q = causal_offset + qb * block_q
+
+    def body(kb, carry):
+        acc, m, l = carry
+        keys = pl.ds(kb * block_k, block_k)
+        s = _scores(q, qx, k_ref[0, keys, :].astype(jnp.float32),
+                    kx_ref[0, keys, :].astype(jnp.float32) if shared
+                    else None)
+        if causal:
+            s = _mask(s, first_q, kb * block_k, window)
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        alpha = jnp.exp(m - m_new)
+        l_new = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        acc_new = acc * alpha + _dot(
+            p, v_ref[0, keys, :].astype(jnp.float32))
+        return acc_new, m_new, l_new
+
+    init = (jnp.zeros((block_q, v_ref.shape[-1]), jnp.float32),
+            jnp.full((block_q, 1), _NEG_INF, jnp.float32),
+            jnp.zeros((block_q, 1), jnp.float32))
+    first, _, _, end = _key_bounds(qb, block_q, block_k, seq_k,
+                                   causal_offset, causal, window)
+    acc, m, l = jax.lax.fori_loop(first, end, body, init)
+    l_safe = jnp.maximum(l, 1e-30)
+    o_ref[0] = (acc / l_safe).astype(o_ref.dtype)
+    lse_ref[0, pl.ds(qb, 1), :] = _row(m + jnp.log(l_safe))
+
+
+def _flash_kv_dq_kernel(*refs, shared: bool, block_k: int, causal: bool,
+                        sm_scale: float, seq_k: int, block_q: int,
+                        causal_offset: int, window: Optional[int]):
+    import jax.experimental.pallas as pl
+
+    if shared:
+        (q_ref, qx_ref, k_ref, kx_ref, v_ref, do_ref, lse_ref, delta_ref,
+         dq_ref, dqx_ref) = refs
+        qx = qx_ref[0].astype(jnp.float32)
+    else:
+        q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref = refs
+        qx = None
+    qb = pl.program_id(1)
+    q = q_ref[0].astype(jnp.float32)
+    do = do_ref[0].astype(jnp.float32)                   # [block_q, d_v]
+    lse, delta = _cols(lse_ref[0, pl.ds(qb, 1), :],
+                       delta_ref[0, pl.ds(qb, 1), :])
+    first_q = causal_offset + qb * block_q
+
+    def body(kb, carry):
+        dq, dqx = carry
+        keys = pl.ds(kb * block_k, block_k)
+        k_blk = k_ref[0, keys, :].astype(jnp.float32)
+        kx_blk = kx_ref[0, keys, :].astype(jnp.float32) if shared else None
+        s = _scores(q, qx, k_blk, kx_blk) * sm_scale
+        if causal:
+            s = _mask(s, first_q, kb * block_k, window)
+        p = jnp.exp(s - lse)
+        dp = _dot(do, v_ref[0, keys, :].astype(jnp.float32), (1, 1))
+        ds = p * (dp - delta)
+        return (dq + _dot(ds, k_blk),
+                dqx + _dot(ds, kx_blk) if shared else dqx)
+
+    first, _, _, end = _key_bounds(qb, block_q, block_k, seq_k,
+                                   causal_offset, causal, window)
+    dq, dqx = jax.lax.fori_loop(
+        first, end, body,
+        (jnp.zeros(q.shape, jnp.float32),
+         jnp.zeros(qx.shape if shared else (1, 1), jnp.float32)))
+    dq_ref[0] = (dq * sm_scale).astype(dq_ref.dtype)
+    if shared:
+        dqx_ref[0] = (dqx * sm_scale).astype(dqx_ref.dtype)
+
+
+def _flash_kv_dkv_kernel(*refs, shared: bool, block_q: int, causal: bool,
+                         sm_scale: float, seq_q: int, block_k: int,
+                         causal_offset: int, window: Optional[int]):
+    # keys down and queries across, as ``_flash_bwd_dkv_kernel``
+    import jax.experimental.pallas as pl
+
+    if shared:
+        (q_ref, qx_ref, k_ref, kx_ref, v_ref, do_ref, lse_ref, delta_ref,
+         dk_ref, dkx_ref, dv_ref) = refs
+        kx_blk = kx_ref[0].astype(jnp.float32)
+    else:
+        (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref,
+         dv_ref) = refs
+        kx_blk = None
+    kb = pl.program_id(1)
+    k_blk = k_ref[0].astype(jnp.float32)                 # [block_k, d_m]
+    v_blk = v_ref[0].astype(jnp.float32)                 # [block_k, d_v]
+
+    def body(qb, carry):
+        dk, dkx, dv = carry
+        rows = pl.ds(qb * block_q, block_q)
+        q = q_ref[0, rows, :].astype(jnp.float32)
+        qx = qx_ref[0, rows, :].astype(jnp.float32) if shared else None
+        do = do_ref[0, rows, :].astype(jnp.float32)
+        lse = lse_ref[0, pl.ds(qb, 1), :]                # [1, block_q]
+        delta = delta_ref[0, pl.ds(qb, 1), :]
+        s = _scores(q, qx, k_blk, kx_blk, transposed=True) * sm_scale
+        if causal:
+            s = _mask(s, causal_offset + qb * block_q, kb * block_k, window,
+                      q_axis=1)
+        p = jnp.exp(s - lse)                             # [block_k, block_q]
+        dv_new = dv + _dot(p, do)
+        ds = p * (_dot(v_blk, do, (1, 1)) - delta)
+        return (dk + _dot(ds, q), dkx + _dot(ds, qx) if shared else dkx,
+                dv_new)
+
+    first, _, _, end = _query_bounds(kb, block_q, block_k, seq_q,
+                                     causal_offset, causal, window)
+    dk, dkx, dv = jax.lax.fori_loop(
+        first, end, body,
+        (jnp.zeros(k_blk.shape, jnp.float32),
+         jnp.zeros(kx_blk.shape if shared else (1, 1), jnp.float32),
+         jnp.zeros(v_blk.shape, jnp.float32)))
+    dk_ref[0] = (dk * sm_scale).astype(dk_ref.dtype)
+    if shared:
+        dkx_ref[0] = (dkx * sm_scale).astype(dkx_ref.dtype)
+    dv_ref[0] = dv.astype(dv_ref.dtype)
+
+
+def _held_vmem(seq: int, widths, dtype, tile: int) -> dict:
+    """``_dkv_vmem`` for a kernel that holds a head's whole rows of
+    several arrays, ``widths`` their last dims (each padded to 128 lanes
+    in VMEM), twice for the pipeline, beside ``tile`` bytes of a tile's
+    temporaries."""
+    held = 2 * seq * jnp.dtype(dtype).itemsize * sum(
+        -(-w // 128) * 128 for w in widths)
+    if held + tile <= _SCOPED_VMEM:
+        return {}
+    from jax.experimental.pallas import tpu as pltpu
+
+    return {"compiler_params": pltpu.CompilerParams(
+        vmem_limit_bytes=held + tile + (4 << 20))}
+
+
+def _heads_first(x):
+    """[b, s, h, d] -> [b * h, s, d], the kernels' layout."""
+    b, s, h, d = x.shape
+    return x.transpose(0, 2, 1, 3).reshape(b * h, s, d)
+
+
+def _kv_specs(pl, h: int, kvh: int, sk: int, widths, block_k=None):
+    """Maps of a grid ``(b * h, block)``: (a key or value head's rows
+    whole or, with ``block_k``, the grid's block of them: one spec a width
+    of ``widths``; the shared key's, one row of the batch)."""
+    def kv_map(i, j):
+        return (i // h * kvh + i % h // (h // kvh), j if block_k else 0, 0)
+
+    def shared_map(i, j):
+        return (i // h, j if block_k else 0, 0)
+
+    rows = block_k or sk
+    return ([pl.BlockSpec((1, rows, w), kv_map) for w in widths],
+            lambda w: pl.BlockSpec((1, rows, w), shared_map))
+
+
+def _flash_kv_forward(q, k, v, kx, causal, sm_scale, block_q, block_k,
+                      interpret, window):
+    """q [b, sq, h, d_m + d_s]; k [b, sk, kvh, d_m]; v [b, sk, kvh, d_v];
+    kx [b, sk, d_s] or None -> (out [b, sq, h, d_v], lse [b * h, sq])."""
+    import jax.experimental.pallas as pl
+
+    b, sq, h, _ = q.shape
+    _, sk, kvh, d_m = k.shape
+    d_v, shared = v.shape[-1], kx is not None
+    d_s = kx.shape[-1] if shared else 0
+    qt, kt, vt = _heads_first(q), _heads_first(k), _heads_first(v)
+
+    def q_spec(w):
+        return pl.BlockSpec((1, block_q, w), lambda i, qb: (i, qb, 0))
+
+    (k_spec, v_spec), kx_spec = _kv_specs(pl, h, kvh, sk, (d_m, d_v))
+    ins = ([qt[..., :d_m], qt[..., d_m:], kt, kx, vt] if shared
+           else [qt, kt, vt])
+    specs = ([q_spec(d_m), q_spec(d_s), k_spec, kx_spec(d_s), v_spec]
+             if shared else [q_spec(d_m), k_spec, v_spec])
+    out, lse = pl.pallas_call(
+        functools.partial(
+            _flash_kv_kernel, shared=shared, block_k=block_k, causal=causal,
+            sm_scale=sm_scale, seq_k=sk, block_q=block_q,
+            causal_offset=sk - sq, window=window),
+        name="flash_kv_fwd",
+        out_shape=[
+            jax.ShapeDtypeStruct((b * h, sq, d_v), q.dtype),
+            jax.ShapeDtypeStruct((b * h, sq // block_q, block_q),
+                                 jnp.float32)],
+        grid=(b * h, sq // block_q),
+        in_specs=specs,
+        out_specs=[q_spec(d_v),
+                   pl.BlockSpec((1, sq // block_q, block_q),
+                                lambda i, qb: (i, 0, 0))],
+        interpret=interpret,
+        **_held_vmem(sk, (d_m, d_s, d_v) if shared else (d_m, d_v), k.dtype,
+                     tile=4 << 20),
+    )(*ins)
+    return (out.reshape(b, h, sq, d_v).transpose(0, 2, 1, 3),
+            lse.reshape(b * h, sq))
+
+
+def _flash_kv_backward(q, k, v, kx, out, lse, g, causal, sm_scale, block_q,
+                       block_k, interpret, window):
+    import jax.experimental.pallas as pl
+
+    b, sq, h, d_q = q.shape
+    _, sk, kvh, d_m = k.shape
+    d_v, shared = v.shape[-1], kx is not None
+    d_s = d_q - d_m if shared else 0
+    group = h // kvh
+    qt, kt, vt = _heads_first(q), _heads_first(k), _heads_first(v)
+    dot, ot = _heads_first(g), _heads_first(out)
+    delta = jnp.sum(dot.astype(jnp.float32) * ot.astype(jnp.float32),
+                    axis=-1)
+    stat_rows = (b * h, sq // block_q, block_q)
+    lse, delta = lse.reshape(stat_rows), delta.reshape(stat_rows)
+    stat_spec = pl.BlockSpec((1,) + stat_rows[1:], lambda i, j: (i, 0, 0))
+
+    def q_spec(w, whole=False):
+        if whole:
+            return pl.BlockSpec((1, sq, w), lambda i, kb: (i, 0, 0))
+        return pl.BlockSpec((1, block_q, w), lambda i, qb: (i, qb, 0))
+
+    def k_out(w):
+        return pl.BlockSpec((1, block_k, w), lambda i, kb: (i, kb, 0))
+
+    static = dict(shared=shared, causal=causal, sm_scale=sm_scale,
+                  causal_offset=sk - sq, window=window, block_q=block_q,
+                  block_k=block_k)
+    qs = [qt[..., :d_m], qt[..., d_m:]] if shared else [qt]
+    ks = [kt, kx] if shared else [kt]
+    q_widths = (d_m, d_s) if shared else (d_m,)
+
+    (k_spec, v_spec), kx_spec = _kv_specs(pl, h, kvh, sk, (d_m, d_v))
+    dq = pl.pallas_call(
+        functools.partial(_flash_kv_dq_kernel, seq_k=sk, **static),
+        name="flash_kv_bwd_dq",
+        out_shape=[jax.ShapeDtypeStruct((b * h, sq, w), q.dtype)
+                   for w in q_widths],
+        grid=(b * h, sq // block_q),
+        in_specs=[q_spec(w) for w in q_widths]
+        + [k_spec] + [kx_spec(d_s)] * shared
+        + [v_spec, q_spec(d_v), stat_spec, stat_spec],
+        out_specs=[q_spec(w) for w in q_widths],
+        interpret=interpret,
+        **_held_vmem(sk, (d_m, d_s, d_v) if shared else (d_m, d_v), k.dtype,
+                     tile=4 << 20),
+    )(*qs, *ks, vt, dot, lse, delta)
+    dq = jnp.concatenate(dq, axis=-1) if shared else dq[0]
+
+    # dK, dV and the shared key's gradient by *query* head, summed over
+    # the heads outside as the kernels above sum a GQA group: the shared
+    # key's sum is over every head, so its parts are float32
+    (k_spec, v_spec), kx_spec = _kv_specs(pl, h, kvh, sk, (d_m, d_v),
+                                          block_k=block_k)
+    per = pl.pallas_call(
+        functools.partial(_flash_kv_dkv_kernel, seq_q=sq, **static),
+        name="flash_kv_bwd_dkv",
+        out_shape=[jax.ShapeDtypeStruct((b * h, sk, d_m), k.dtype)]
+        + [jax.ShapeDtypeStruct((b * h, sk, d_s), jnp.float32)] * shared
+        + [jax.ShapeDtypeStruct((b * h, sk, d_v), v.dtype)],
+        grid=(b * h, sk // block_k),
+        in_specs=[q_spec(w, whole=True) for w in q_widths]
+        + [k_spec] + [kx_spec(d_s)] * shared
+        + [v_spec, q_spec(d_v, whole=True), stat_spec, stat_spec],
+        out_specs=[k_out(d_m)] + [k_out(d_s)] * shared + [k_out(d_v)],
+        interpret=interpret,
+        **_held_vmem(sq, q_widths + (d_v,), q.dtype, tile=8 << 20),
+    )(*qs, *ks, vt, dot, lse, delta)
+
+    def by_kv_head(x, like):
+        x = x.reshape(b, kvh, group, sk, x.shape[-1]).sum(axis=2)
+        return x.transpose(0, 2, 1, 3).astype(like.dtype)
+
+    dq = dq.reshape(b, h, sq, d_q).transpose(0, 2, 1, 3).astype(q.dtype)
+    dkx = (per[1].reshape(b, h, sk, d_s).sum(axis=1).astype(kx.dtype)
+           if shared else None)
+    return dq, by_kv_head(per[0], k), by_kv_head(per[-1], v), dkx
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9))
+def _flash_kv_call(q, k, v, kx, causal, sm_scale, block_q, block_k,
+                   interpret, window):
+    return _flash_kv_forward(q, k, v, kx, causal, sm_scale, block_q,
+                             block_k, interpret, window)[0]
+
+
+def _flash_kv_fwd(q, k, v, kx, causal, sm_scale, block_q, block_k,
+                  interpret, window):
+    out, lse = _flash_kv_forward(q, k, v, kx, causal, sm_scale, block_q,
+                                 block_k, interpret, window)
+    # named as ``_flash_fwd`` names them, for the same rung of the ladder
+    out = checkpoint_name(out, "flash_out")
+    lse = checkpoint_name(lse, "flash_lse")
+    return out, (q, k, v, kx, out, lse)
+
+
+def _flash_kv_bwd(causal, sm_scale, block_q, block_k, interpret, window,
+                  res, g):
+    return _flash_kv_backward(*res, g, causal, sm_scale, block_q, block_k,
+                              interpret, window)
+
+
+_flash_kv_call.defvjp(_flash_kv_fwd, _flash_kv_bwd)
+
+
+def _flash_kv(q, k, v, k_shared, causal, sm_scale, block_q, block_k,
+              interpret, window):
+    """``flash_attention`` on the kernel path where the widths of keys and
+    values differ or a part of the key is shared by the heads."""
+    d_s = 0 if k_shared is None else k_shared.shape[-1]
+    if q.shape[-1] != k.shape[-1] + d_s or q.shape[2] % k.shape[2]:
+        raise ValueError(
+            f"queries {q.shape} do not meet keys {k.shape}"
+            + (f" with a shared part {k_shared.shape}" if d_s else ""))
+    block_q, block_k = _check_blocks(
+        q.shape[1], k.shape[1], block_q or _auto_block(q.shape[1]),
+        block_k or _auto_block(k.shape[1]))
+    with tracing.span("rtpu.flash.tiles", keep=True, seq_q=q.shape[1],
+                      seq_k=k.shape[1], block_q=block_q, block_k=block_k,
+                      window=window, head_dim=q.shape[-1],
+                      value_dim=v.shape[-1], shared_key_dim=d_s,
+                      **tile_plan(q.shape[1], k.shape[1], block_q, block_k,
+                                  window, causal)):
+        pass
+    return _flash_kv_call(q, k, v, k_shared, causal, sm_scale, block_q,
+                          block_k, interpret, window)

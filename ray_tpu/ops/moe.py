@@ -38,11 +38,11 @@ gathered or multiplied. How many rows that is, is data, and a buffer for
 the worst case (every row routed here) would be ``n * top_k`` rows wide,
 a gigabyte at Laguna's cell: the held rows are taken in passes of a
 static ``chunk`` of rows (``_held_chunk``: the balanced share and an
-eighth of it), as many passes as the rows need, each a gather, three
-grouped matmuls over the pass's groups and a scatter-add into the
-tokens' sums. One pass at a balanced routing and up to an eighth over
-it, none where no row is held; no routing, however uneven, drops a row
-or compiles anything. The grouped matmuls touch the row tiles the
+eighth of it, a larger part for fewer than 16 experts), as many passes as
+the rows need, each a gather, three grouped matmuls over the pass's groups
+and a scatter-add into the tokens' sums. One pass at a balanced routing
+and up to that part over it, none where no row is held; no routing,
+however uneven, drops a row or compiles anything. The grouped matmuls touch the row tiles the
 pass's groups fill and no other; the gathers, the activation's pass,
 the masks and the scatter-adds run over all ``chunk`` rows of a pass,
 held or padding, which is why a pass is no wider than that
@@ -180,7 +180,7 @@ def grouped_matmul(rows: jax.Array, w: jax.Array, sizes: jax.Array
 def route(x: jax.Array, router_w: jax.Array, top_k: int,
           renormalize: bool = False, scale: float = 1.0,
           score: str = "softmax", select_bias: Optional[jax.Array] = None,
-          renorm_eps: float = 0.0
+          renorm_eps: float = 0.0, groups: Optional[Tuple[int, int]] = None
           ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """(router_logits [n, E] float32, top_w [n, K] float32, top_e [n, K]
     int32): logits accumulate in float32, the scores are their softmax
@@ -190,14 +190,31 @@ def route(x: jax.Array, router_w: jax.Array, top_k: int,
     multiplies them after that (Laguna's routed scaling factor).
     ``select_bias [E]`` float32 is added to the scores for the choice of
     experts alone (loss-free balancing, arXiv:2408.15664): the gate
-    weights are the scores without it, and no gradient reaches it."""
+    weights are the scores without it, and no gradient reaches it.
+    ``groups=(n_group, topk_group)`` limits the choice (DeepSeek-V2's
+    ``group_limited_greedy``): the E experts are ``n_group`` groups of
+    neighbours, a group's score is the largest of its experts', and the K
+    are the largest scores inside the ``topk_group`` best groups."""
     logits = jnp.dot(x, router_w.astype(x.dtype),
                      preferred_element_type=jnp.float32)
     if score not in ("softmax", "sigmoid"):
         raise ValueError(f"unknown router score {score!r} (softmax | sigmoid)")
     scores = (jax.nn.softmax(logits, axis=-1) if score == "softmax"
               else jax.nn.sigmoid(logits))
-    if select_bias is None:
+    if groups is not None:
+        if select_bias is not None:
+            raise ValueError("a group limit with a selection bias is not "
+                             "implemented")
+        n_group, kept_groups = groups
+        by_group = scores.reshape(scores.shape[0], n_group, -1)
+        _, best = jax.lax.top_k(
+            jax.lax.stop_gradient(by_group.max(-1)), kept_groups)
+        allowed = (best[..., None] == jnp.arange(n_group)).any(-2)
+        # a score is positive, so one outside the kept groups is never
+        # among the K while K experts lie inside them
+        top_w, top_e = jax.lax.top_k(jnp.where(
+            allowed[..., None], by_group, 0.0).reshape(scores.shape), top_k)
+    elif select_bias is None:
         top_w, top_e = jax.lax.top_k(scores, top_k)
     else:
         _, top_e = jax.lax.top_k(
@@ -244,15 +261,25 @@ def _swiglu_rows(rows, w_rows, sizes, e_gate, e_up, e_down):
 # rows of 0.9, 1.0 and 1.1 times the share an eighth was the cheapest of
 # 0, 1/16, 1/8, 3/16, 1/4, 1/2 and 1 at both: 33.9 and 20.2 ms (1/16 pays
 # a second pass at +10%: 38.4; 1/4 reads 35.2).
+# A share of fewer experts wanders more beside its mean: the held rows are
+# the sum of ``count`` experts' loads, whose relative spread falls as
+# ``1 / sqrt(count)``, so the headroom is one part in ``2 sqrt(count)`` (an
+# eighth at the 16 both sweeps held). At 8 of 160 experts, 6 a token, 8,192
+# tokens (v5e, PR 43) a layer's held rows read 2,458 +- ~220 from seed to
+# seed (a seeded router's experts draw 0.6-1.7 times their share); an eighth
+# over the share (2,816 rows) sent a layer of every few steps into a second
+# pass, 35 ms on a step of 704, and a cell's rate apart by 1.2% between two
+# seeds.
 _HELD_HEADROOM = 8
 
 
 def _held_chunk(num_pairs: int, count: int, num_experts: int) -> int:
     """Rows a pass of the held experts takes: their balanced share of the
-    ``num_pairs`` (token, choice) pairs and an eighth of it
-    (``_HELD_HEADROOM``), in whole row tiles, at most all the pairs."""
-    rows = -(-num_pairs * count * (_HELD_HEADROOM + 1)
-             // (num_experts * _HELD_HEADROOM))
+    ``num_pairs`` (token, choice) pairs and one part in ``2 sqrt(count)``
+    of it (an eighth, ``_HELD_HEADROOM``, from 16 experts up), in whole
+    row tiles, at most all the pairs."""
+    part = min(_HELD_HEADROOM, max(2, round(2 * count ** 0.5)))
+    rows = -(-num_pairs * count * (part + 1) // (num_experts * part))
     return min(-(-rows // _ROW_TILE), -(-num_pairs // _ROW_TILE)) * _ROW_TILE
 
 
@@ -359,7 +386,8 @@ def routed_experts(x: jax.Array, router_w: jax.Array, e_gate: jax.Array,
                    held: Optional[Tuple[int, int]] = None,
                    scale: float = 1.0, score: str = "softmax",
                    select_bias: Optional[jax.Array] = None,
-                   renorm_eps: float = 0.0, keep_choices: bool = False
+                   renorm_eps: float = 0.0, keep_choices: bool = False,
+                   groups: Optional[Tuple[int, int]] = None
                    ) -> Tuple[jax.Array, ...]:
     """x [n, h], router_w [h, E], e_gate / e_up [E, h, f], e_down
     [E, f, h] -> (out [n, h], router_logits [n, E] float32, counts [E]
@@ -368,14 +396,15 @@ def routed_experts(x: jax.Array, router_w: jax.Array, e_gate: jax.Array,
     ``held=(first, count)``: the expert weights are those of experts
     ``first .. first + count`` alone, ``[count, ...]``, and ``out`` is
     their part of the result (the module's docstring); ``None``: all
-    ``E`` are here. ``score``, ``select_bias`` and ``renorm_eps`` are
-    ``route``'s; ``keep_choices`` appends ``route``'s own ``top_e [n, K]``
+    ``E`` are here. ``score``, ``select_bias``, ``renorm_eps`` and
+    ``groups`` are ``route``'s; ``keep_choices`` appends ``route``'s own
+    ``top_e [n, K]``
     to the result, for a check of what was chosen."""
     num_experts = router_w.shape[-1]
     with jax.named_scope("moe_route"):
         logits, top_w, top_e = route(
             x, router_w, top_k, renormalize, scale, score=score,
-            select_bias=select_bias, renorm_eps=renorm_eps)
+            select_bias=select_bias, renorm_eps=renorm_eps, groups=groups)
         choices = (top_e,) if keep_choices else ()
         flat_e = top_e.reshape(-1)
         if held is not None:
@@ -480,24 +509,43 @@ def router_losses(cfg, router: Dict[str, jax.Array]
     return balance, router["z"].mean()
 
 
+def sequence_balance(cfg, router: Dict[str, jax.Array]) -> jax.Array:
+    """DeepSeek-V2's ``seq_aux`` balancing loss before its coefficient, of
+    the routed layers' reports stacked (``seq_counts`` and ``seq_prob``
+    [Lr, b, E]): for each layer and each sequence ``sum_e f_e P_e``, ``f_e``
+    the times the sequence's tokens chose expert ``e`` x E / (K x length)
+    and ``P_e`` the sequence's mean score; the mean over the sequences,
+    summed over the layers (each layer adds its own term to the loss)."""
+    counts = router["seq_counts"].astype(jnp.float32)
+    share = counts * (cfg.num_experts / counts.sum(-1, keepdims=True))
+    return (share * router["seq_prob"]).sum(-1).mean(-1).sum()
+
+
 def routed_part(shared: bool = False, score: str = "softmax",
                 bias: bool = False, renorm_eps: Optional[str] = None,
-                balance: bool = False,
-                width: str = "moe_intermediate_size") -> Part:
+                balance=False, width: str = "moe_intermediate_size",
+                renormalize: bool = True, groups: bool = False) -> Part:
     """A routed mixture as a layer's MLP: ``x + [shared(u)] + routed(u)``,
     ``u = RMSNorm(x)``: ``cfg.num_experts`` experts of ``width`` (the
     config's field), ``cfg.top_k`` a token, the gate weights renormalised
-    and times ``cfg.routed_scale``, ``cfg.experts_held`` of them here (a
+    (or, ``renormalize=False``, left the scores they are) and times
+    ``cfg.routed_scale``, ``cfg.experts_held`` of them here (a
     config without the field holds them all). ``shared``: a SwiGLU of
     ``cfg.shared_intermediate_size`` beside them, added ungated (Laguna).
     ``score="sigmoid"`` and ``bias`` (a ``router_bias`` that takes part in
     the choice alone, float32, no optimizer's) are LFM2's router,
     ``renorm_eps`` names its field. ``balance``: a layer reports
     ``router_stats`` and the loss gains ``cfg.router_aux_coef`` x
-    ``router_losses``' load-balancing term; without, the counts alone. A
+    ``router_losses``' load-balancing term; ``balance="sequence"``: a layer
+    reports each sequence's counts and mean scores and the loss gains
+    ``cfg.router_aux_coef`` x ``sequence_balance``; without, the counts
+    alone. ``groups``: the choice is limited to ``cfg.topk_group`` of
+    ``cfg.n_group`` groups of experts (``route``). A
     layer reports under "router"; asked for (``ctx.keep_router_logits``),
-    the router's logits too and, where a bias took part in them,
-    ``route``'s own choices."""
+    the router's logits too and, where a bias or a group limit took part
+    in them, ``route``'s own choices."""
+    by_sequence = balance == "sequence"
+
     def held(cfg):
         return getattr(cfg, "experts_held", None)
 
@@ -530,16 +578,29 @@ def routed_part(shared: bool = False, score: str = "softmax",
                                     p["s_down"].astype(dt))
             out, logits, counts, *chosen = routed_experts_on(
                 ctx.mesh, h2, p["router"], p["e_gate"], p["e_up"],
-                p["e_down"], cfg.top_k, renormalize=True,
+                p["e_down"], cfg.top_k, renormalize=renormalize,
                 select_bias=p["router_bias"] if bias else None,
                 held=held(cfg), scale=cfg.routed_scale, score=score,
                 renorm_eps=getattr(cfg, renorm_eps) if renorm_eps else 0.0,
-                keep_choices=bias and ctx.keep_router_logits)
-            router = (router_stats(logits, counts) if balance
-                      else {"counts": counts})
+                keep_choices=by_sequence or (
+                    (bias or groups) and ctx.keep_router_logits),
+                groups=(cfg.n_group, cfg.topk_group) if groups else None)
+            if by_sequence:
+                with jax.named_scope("moe_route"):
+                    b, E = x.shape[0], counts.shape[0]
+                    router = {
+                        "counts": counts,
+                        "seq_counts": (chosen[0].reshape(b, -1, 1)
+                                       == jnp.arange(E)).sum(
+                                           1, dtype=jnp.int32),
+                        "seq_prob": jax.nn.softmax(logits, -1).reshape(
+                            b, -1, E).mean(1)}
+            else:
+                router = (router_stats(logits, counts) if balance
+                          else {"counts": counts})
             if ctx.keep_router_logits:
                 router["logits"] = logits
-                if bias:
+                if bias or groups:
                     router["chosen"] = chosen[0]
             return (x + beside if shared else x) + out, {"router": router}
 
@@ -569,7 +630,8 @@ def routed_part(shared: bool = False, score: str = "softmax",
         counts = {"expert_counts": router["counts"]}
         if not balance:
             return None, counts
-        load, _ = router_losses(cfg, router)
+        load = (sequence_balance(cfg, router) if by_sequence
+                else router_losses(cfg, router)[0])
         return cfg.router_aux_coef * load, {"load_balance": load, **counts}
 
     return Part(leaves, body, keeps, reports="router", terms=terms)

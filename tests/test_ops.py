@@ -615,6 +615,9 @@ def test_held_experts_match_the_expert_loop(toward):
 @pytest.mark.parametrize("pairs, count, num_experts, want", [
     (16384 * 10, 16, 256, 11520),     # train-laguna-1chip: 45 tiles for 80
     (16384 * 4, 16, 32, 36864),       # train-lfm2-1chip: 144 tiles for 256
+    # train-deepseek-v2-1chip: 8 experts wander more than 16, so a sixth
+    # over the share (12 tiles), where an eighth gave 11
+    (8192 * 6, 8, 160, 3072),
     (96 * 8, 8, 16, 512), (64 * 10, 16, 256, 256),      # the tests above
     (16384 * 4, 32, 32, 65536),       # all held: every pair and no more
     (1000, 7, 8, 1024),               # the headroom passes all the pairs

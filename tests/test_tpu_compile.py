@@ -705,3 +705,35 @@ def test_scan_kernels_compile_and_the_granite_cells_step_names_them(
                    "ssd_scan_bwd", "flash_fwd", "flash_bwd_dq",
                    "flash_bwd_dkv"):
         assert kernel in text, kernel
+
+
+@pytest.mark.parametrize("shared", [True, False], ids=["shared", "one-key"])
+def test_flash_kernels_compile_at_keys_of_192_and_values_of_128(
+        S, no_compile_cache, shared):
+    """Latent attention in its cell: one sequence of 8,192 positions, 32
+    heads, keys of 128 + 64 rope dims, values of 128. Mosaic takes the
+    forward and both backward kernels with the 64 rope dims as one key a
+    position that all heads share (a second product of 64 lanes inside the
+    kernel, what ``ops/mla.py`` runs) and as part of one 192-wide key a
+    head; a head's whole rows lie in VMEM at 128 lanes a row or a multiple
+    (12.6 MB and a tile's temporaries), so each call asks for its limit."""
+    from ray_tpu.ops.attention import flash_attention
+
+    def loss(q, k, v, kx=None):
+        return flash_attention(q, k, v, k_shared=kx, use_pallas=True,
+                               sm_scale=0.114721).astype(jnp.float32).sum()
+
+    args = ((S(1, 8192, 32, 192), S(1, 8192, 32, 128), S(1, 8192, 32, 128),
+             S(1, 8192, 64)) if shared else
+            (S(1, 8192, 32, 192), S(1, 8192, 32, 192), S(1, 8192, 32, 128)))
+    text = jax.jit(jax.grad(loss, argnums=tuple(range(len(args))))).lower(
+        *args).compile().as_text()
+    calls = _mosaic_calls(text)
+    names = {name for name, _ in calls}
+    assert len(names) == 3
+    for kernel in ("flash_kv_fwd", "flash_kv_bwd_dq", "flash_kv_bwd_dkv"):
+        assert any(kernel in name for name in names), (kernel, names)
+    # no value is padded to the keys' width: every operand of 192 lanes is
+    # a query, a key or their gradient
+    for _, line in calls:
+        assert "8192,256]" not in line

@@ -1120,7 +1120,8 @@ def test_every_kernel_and_serving_program_has_a_name():
             assert m, (rel, src[rel][at:at + 80])
             names.append(m.group(1))
     assert sorted(names) == ["flash_bwd_dkv", "flash_bwd_dq", "flash_fwd",
-                             "paged_attn"]
+                             "flash_kv_bwd_dkv", "flash_kv_bwd_dq",
+                             "flash_kv_fwd", "paged_attn"]
     # a window layer's calls are named apart from the causal ones
     for name in names[:3]:
         assert f'else "{name.replace("flash_", "flash_win_")}"' in \
@@ -1160,3 +1161,117 @@ def test_compile_cache_key_sees_a_programs_metadata():
         for k, v in saved.items():
             os.environ.pop(k, None) if v is None else \
                 os.environ.__setitem__(k, v)
+
+
+def test_mla_shapes_is_one_kept_span_of_a_traced_layer():
+    """A traced latent-attention layer writes what it is once, as a kept
+    span (no flag, no profiler window): the heads it holds and of how
+    many, both ranks, the three head widths and the softmax scale with
+    yarn's ``m ** 2`` in it."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.models import deepseek_v2
+
+    assert not config.task_events_enabled
+    cfg = deepseek_v2.DeepseekV2Config.deepseek_v2(
+        num_layers=2, vocab_size=256, num_heads=32, heads_of=128,
+        experts_held=(0, 8), attn_impl="reference", remat=False)
+    params = jax.eval_shape(lambda k: deepseek_v2.init_params(cfg, k),
+                            jax.random.PRNGKey(0))
+    n0 = len(_mine("rtpu.mla.shapes"))
+    jax.eval_shape(lambda p, t: deepseek_v2.forward(cfg, p, t)[0], params,
+                   jax.ShapeDtypeStruct((1, 64), jnp.int32))
+    spans = [{k: v for k, v in e["args"].items()
+              if k not in ("id", "parent", "self_us")}
+             for e in _mine("rtpu.mla.shapes")[n0:]]
+    assert len(spans) == 2                       # one a kind of layer
+    assert spans[0] == spans[1]
+    scale = spans[0].pop("softmax_scale")
+    assert abs(scale - 192 ** -0.5 * (0.1 * 0.707 * 3.6888794541 + 1) ** 2
+               ) < 1e-9 and abs(scale - 0.114721) < 1e-6
+    assert spans[0] == {"heads": 32, "heads_of": 128, "q_lora_rank": 1536,
+                        "kv_lora_rank": 512, "qk_nope_head_dim": 128,
+                        "qk_rope_head_dim": 64, "v_head_dim": 128}
+
+
+@pytest.mark.parametrize("shared", [True, False], ids=["shared", "one-key"])
+def test_flash_tiles_of_unequal_widths_carries_both(shared):
+    """The ``flash_kv_*`` kernels' span says what the equal-width span
+    says and both widths besides: the keys' (a query's) under
+    ``head_dim``, the values' and how many of the key's dims the heads
+    share; an equal-width call's span has neither."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.ops.attention import flash_attention, tile_plan
+
+    def S(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+
+    n0 = len(_mine("rtpu.flash.tiles"))
+    jax.eval_shape(jax.grad(lambda q, k, v, kx: flash_attention(
+        q, k, v, k_shared=kx, use_pallas=True, interpret=True).astype(
+            jnp.float32).sum(), argnums=(0, 1, 2)),
+        S(1, 2048, 4, 192), S(1, 2048, 4, 128 if shared else 192),
+        S(1, 2048, 4, 128), S(1, 2048, 64) if shared else None)
+    (span,) = _mine("rtpu.flash.tiles")[n0:]
+    args = {k: v for k, v in span["args"].items()
+            if k not in ("id", "parent", "self_us")}
+    assert args == {"seq_q": 2048, "seq_k": 2048, "block_q": 512,
+                    "block_k": 512, "window": None, "head_dim": 192,
+                    "value_dim": 128, "shared_key_dim": 64 if shared else 0,
+                    **tile_plan(2048, 2048, 512, 512)}
+    n0 = len(_mine("rtpu.flash.tiles"))
+    jax.eval_shape(lambda q: flash_attention(q, q, q, use_pallas=True,
+                                             interpret=True),
+                   S(1, 2048, 4, 128))
+    (equal,) = _mine("rtpu.flash.tiles")[n0:]
+    assert not {"value_dim", "shared_key_dim"} & set(equal["args"])
+
+
+def test_deepseek_v2_train_step_names_its_scopes_and_counts_its_rows():
+    """The optimized train step of a stack of latent-attention layers
+    carries the four ``mla_*`` scopes and the routed mixture's beside the
+    family's, and hands out the routed layers' expert counts, from which
+    ``moe_rows_held`` and ``moe_rows_passed`` are read as Laguna's are."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    import optax
+
+    from ray_tpu.models import deepseek_v2
+    from ray_tpu.train.session import STEP_COUNTERS
+
+    cfg = deepseek_v2.DeepseekV2Config.tiny(
+        vocab_size=128, attn_impl="reference", remat=True,
+        experts_held=(4, 4))
+    params = jax.eval_shape(lambda k: deepseek_v2.init_params(cfg, k),
+                            jax.random.PRNGKey(0))
+    tx = optax.adamw(1e-3)
+    opt = jax.eval_shape(tx.init, params)
+    batch = {"tokens": jax.ShapeDtypeStruct((2, 33), jnp.int32)}
+
+    def step(params, opt, batch):
+        (loss, aux), grads = jax.value_and_grad(
+            lambda p: deepseek_v2.loss_terms(cfg, p, batch),
+            has_aux=True)(params)
+        updates, opt = tx.update(grads, opt, params)
+        return (optax.apply_updates(params, updates), opt, loss,
+                aux["expert_counts"])
+
+    lowered = jax.jit(step, donate_argnums=(0, 1)).lower(params, opt, batch)
+    assert lowered.out_info[3].shape == (2, 16)
+    text = lowered.compile().as_text()
+    for scope in ("embed", "mla_q", "mla_kv", "mla_rope", "flash", "mla_out",
+                  "mlp", "moe_route", "moe_dispatch", "moe_experts",
+                  "moe_combine", "moe_shared", "head_loss"):
+        assert re.search(rf'op_name="[^"]*[/(]{scope}[/)]', text), scope
+    assert {"moe_rows_routed", "moe_rows_held", "moe_rows_passed"} <= set(
+        STEP_COUNTERS)
+    counts = np.zeros((2, 16), np.int64)
+    counts[:, 4:8] = 30, 10, 0, 8
+    assert int(deepseek_v2.rows_held(cfg, counts)) == 96
+    # two layers' held rows in whole passes of ``_held_chunk`` rows
+    assert deepseek_v2.rows_passed(cfg, np.where(
+        np.arange(16) < 4, 0, counts + 6)) % 256 == 0
