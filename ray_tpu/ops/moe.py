@@ -40,7 +40,13 @@ a gigabyte at Laguna's cell: the held rows are taken in passes of a
 static ``chunk`` of rows (``_held_chunk``: the balanced share and an
 eighth of it, a larger part for fewer than 16 experts), as many passes as
 the rows need, each a gather, three grouped matmuls over the pass's groups
-and a scatter-add into the tokens' sums. One pass at a balanced routing
+and a scatter-add into the tokens' float32 sums. The sums are one ``[n,
+h]`` array up to 4,096 columns and past that blocks of at most 1,280
+columns, carried apart through the passes and joined in the last cast
+(``_sum_columns``): XLA's scatter-add walks the whole table, and what that
+costs a column is a sawtooth in the width, 15.9 ms for a pass's 3,072 rows
+into ``[8192, 5120]`` where four sums of 1,280 take 2.2 (v5e, PR 44; the
+same adds of the same rows, so the same sums). One pass at a balanced routing
 and up to that part over it, none where no row is held; no routing,
 however uneven, drops a row or compiles anything. The grouped matmuls touch the row tiles the
 pass's groups fill and no other; the gathers, the activation's pass,
@@ -48,7 +54,8 @@ the masks and the scatter-adds run over all ``chunk`` rows of a pass,
 held or padding, which is why a pass is no wider than that
 (``_HELD_HEADROOM``). The plan of a traced layer is one kept span,
 ``rtpu.moe.held_pass`` (pairs, count, num_experts, balanced_share,
-chunk); ``rows_passed`` counts a step's passes from its expert counts.
+chunk, sum_blocks); ``rows_passed`` counts a step's passes from its expert
+counts.
 The loop's trip count is data, so its gradient is written out
 (``_held_experts``): the same passes, each the transpose of its forward.
 
@@ -298,6 +305,58 @@ def rows_passed(expert_counts, held: Optional[Tuple[int, int]]) -> int:
     return int((-(-rows // chunk) * chunk).sum())
 
 
+# How wide the tokens' float32 sums are where a pass adds its rows to them.
+# XLA's scatter-add of rows on a v5e is no cliff in the width but a sawtooth
+# (``tools/scatter_sweep.py``, PR 44: 3,072 rows into ``[8192, w]`` float32,
+# the table donated, ms a 1,024 columns without the call's 0.6 ms): 0.24 at
+# 1,024 and 1,280, 0.31 at 2,048, then 1.45 at 2,560 and back to 0.28 at
+# 2,816; 0.34 at 3,072, 1.38 at 3,840, 0.25 at 4,096; 0.57, 0.80, 1.14 and
+# **3.05 at 5,120** (15.8 ms the call, 30.5 into 16,384 rows, 22.6 with
+# 36,864 rows: a cost of the table, not of a row), 0.37 at 5,376; 0.49 at
+# 6,144, 3.02 at 7,680, 0.37 at 8,192. The same 5,120 columns in four sums
+# of 1,280 read 2.2 ms for 15.9, in two of 2,560 8.4; 6,144 in six of 1,024
+# 2.4 for 3.6, 8,192 in eight 2.9 for 3.7. So: one sum up to 4,096 columns,
+# the widest width read fast whole, which keeps the statement that Laguna's
+# 3,072 and LFM2's 2,048 columns compile (2.5 ms for 11,520 rows, 3.7 for
+# 36,864); past it blocks of at most 1,280 columns, under which every width
+# read fast. The layer at DeepSeek-V2's shape (8,192 x 5,120, 8 of 160
+# experts): forward 18.8 -> 5.2 ms, backward 25.0 -> 11.1.
+_SUM_WHOLE = 4096
+_SUM_COLUMNS = 1280
+
+
+def _sum_columns(h: int) -> int:
+    """Columns of a block of the tokens' ``[n, h]`` sums: ``h`` itself up to
+    ``_SUM_WHOLE``; past it the largest divisor of ``h`` in whole 128-lane
+    tiles that is at most ``_SUM_COLUMNS`` (``h`` where it has none)."""
+    if h <= _SUM_WHOLE:
+        return h
+    width = _divisor_tile(h, _SUM_COLUMNS)
+    return h if h % width else width
+
+
+def _zero_sums(n: int, h: int):
+    """The tokens' sums before a pass: float32 ``[n, _sum_columns(h)]``
+    blocks of columns, carried apart."""
+    width = _sum_columns(h)
+    return tuple(jnp.zeros((n, width), jnp.float32)
+                 for _ in range(h // width))
+
+
+def _add_rows(sums, tokens, rows):
+    """``sums`` with ``rows [chunk, h]`` float32 added at ``tokens
+    [chunk]``, block by block of columns (one block: ``sums.at[tokens]
+    .add(rows)``, the slice of all columns traces to nothing)."""
+    width = sums[0].shape[1]
+    return tuple(block.at[tokens].add(rows[:, j * width:(j + 1) * width])
+                 for j, block in enumerate(sums))
+
+
+def _join_sums(sums, dtype):
+    """The blocks side by side, rounded once to ``dtype``."""
+    return jnp.concatenate([block.astype(dtype) for block in sums], axis=1)
+
+
 def _held_passes(sizes, chunk: int):
     """Passes the held rows need: none where no row is held."""
     return -(-sizes.sum() // chunk)
@@ -335,11 +394,11 @@ def _held_experts(x, w_pairs, e_gate, e_up, e_down, order, sizes, top_k,
         with jax.named_scope("moe_combine"):
             # a row past the pass's groups is written by no kernel
             y = jnp.where(valid[:, None], y.astype(jnp.float32), 0.0)
-            return out.at[tokens].add(y)
+            return _add_rows(out, tokens, y)
 
     out = jax.lax.fori_loop(0, _held_passes(sizes, chunk), one_pass,
-                            jnp.zeros(x.shape, jnp.float32))
-    return out.astype(x.dtype)
+                            _zero_sums(*x.shape))
+    return _join_sums(out, x.dtype)
 
 
 def _held_experts_fwd(x, w_pairs, e_gate, e_up, e_down, order, sizes, top_k,
@@ -367,14 +426,15 @@ def _held_experts_bwd(top_k, chunk, res, d_out):
         with jax.named_scope("moe_dispatch"):
             d_rows = jnp.where(valid[:, None], d_rows.astype(jnp.float32), 0.0)
             d_w_rows = jnp.where(valid, d_w_rows, 0.0)
-            return (d_x.at[tokens].add(d_rows), d_w.at[pairs].add(d_w_rows),
+            return (_add_rows(d_x, tokens, d_rows),
+                    d_w.at[pairs].add(d_w_rows),
                     tuple(a + b for a, b in zip(d_weights, d_ws)))
 
     d_x, d_w, d_weights = jax.lax.fori_loop(
         0, _held_passes(sizes, chunk), one_pass,
-        (jnp.zeros(x.shape, jnp.float32), jnp.zeros_like(w_pairs),
+        (_zero_sums(*x.shape), jnp.zeros_like(w_pairs),
          tuple(jnp.zeros_like(w) for w in weights)))
-    return (d_x.astype(x.dtype), d_w) + d_weights + (None, None)
+    return (_join_sums(d_x, x.dtype), d_w) + d_weights + (None, None)
 
 
 _held_experts.defvjp(_held_experts_fwd, _held_experts_bwd)
@@ -419,7 +479,9 @@ def routed_experts(x: jax.Array, router_w: jax.Array, e_gate: jax.Array,
                               pairs=flat_e.size, count=count,
                               num_experts=num_experts,
                               balanced_share=flat_e.size * count
-                              / num_experts, chunk=chunk):
+                              / num_experts, chunk=chunk,
+                              sum_blocks=x.shape[1]
+                              // _sum_columns(x.shape[1])):
                 pass
             order = jnp.pad(jnp.argsort(key, stable=True).astype(jnp.int32),
                             (0, chunk))

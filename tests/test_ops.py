@@ -612,6 +612,141 @@ def test_held_experts_match_the_expert_loop(toward):
                                    rtol=1e-4, atol=1e-4)
 
 
+def _wide_routed_inputs(toward, n=192, top_k=4):
+    """A layer wide enough for column blocks (768 columns, six lane
+    tiles): 16 experts of 48. ``toward=(a, b)``: every token chooses
+    experts a..b-1, ``top_k`` of them."""
+    h, f, E = 768, 48, 16
+    ks = jax.random.split(jax.random.PRNGKey(1), 6)
+    x = jax.random.normal(ks[0], (n, h))
+    router_w = jax.random.normal(ks[1], (h, E)) * h ** -0.5
+    if toward is not None:
+        x = x.at[:, 0].set(5.0)
+        router_w = (router_w * 0.01).at[0, slice(*toward)].add(10.0)
+    return (x, router_w, jax.random.normal(ks[2], (E, h, f)) * h ** -0.5,
+            jax.random.normal(ks[3], (E, h, f)) * h ** -0.5,
+            jax.random.normal(ks[4], (E, f, h)) / 7,
+            jax.random.normal(ks[5], (n, h)))
+
+
+@pytest.mark.parametrize("limit, blocks", [(1024, 1), (256, 3), (512, 2)],
+                         ids=["under", "a-multiple", "not-a-multiple"])
+@pytest.mark.parametrize("toward, passes", [(None, 1), ((4, 8), 3),
+                                            ((8, 12), 0)],
+                         ids=["one-pass", "three-passes", "none-held"])
+def test_held_experts_sum_their_rows_in_column_blocks(
+        monkeypatch, limit, blocks, toward, passes):
+    """Past ``_SUM_WHOLE`` columns a pass adds its rows into the tokens'
+    sums in blocks of at most ``_SUM_COLUMNS``, carried apart and joined
+    after the loop: 768 columns under the first (one block, the statement
+    as it was), in three blocks of 256 and, for a limit of 512 that does
+    not divide them, in two of 384. Forward and every gradient (``d x``, the router's, which
+    carries ``d top_w``, and the held experts' three) against the loop
+    over experts 4..7 of 16, where a balanced router fills one pass, where
+    every token chooses the four held (768 rows, three passes of 256) and
+    where none does."""
+    from ray_tpu.ops import moe
+
+    monkeypatch.setattr(moe, "_SUM_WHOLE", limit)
+    monkeypatch.setattr(moe, "_SUM_COLUMNS", limit)
+    assert moe._sum_columns(768) * blocks == 768
+    *args, cot = _wide_routed_inputs(toward)
+    held, kw = (4, 4), dict(renormalize=True, scale=2.5)
+    with jax.default_matmul_precision("highest"):
+        out, _, counts = jax.jit(
+            lambda *a: _held_share(held, *a, 4, **kw))(*args)
+        want = _experts_by_loop(*args, 4, held=held, **kw)
+        got_g = jax.jit(jax.grad(
+            lambda *a: (_held_share(held, *a, 4, **kw)[0] * cot).sum(),
+            argnums=(0, 1, 2, 3, 4)))(*args)
+        want_g = jax.jit(jax.grad(
+            lambda *a: (_experts_by_loop(*a, 4, held=held, **kw)
+                        * cot).sum(), argnums=(0, 1, 2, 3, 4)))(*args)
+    chunk = moe._held_chunk(192 * 4, 4, 16)
+    assert chunk == 256
+    held_rows = int(counts[4:8].sum())
+    assert -(-held_rows // chunk) == passes
+    assert held_rows == {0: 0, 3: 768}.get(passes, held_rows)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want),
+                               rtol=1e-5, atol=1e-5)
+    for got, ref in zip(got_g, want_g):
+        assert got.shape == ref.shape
+        # the skewed router's constant feature makes gradients of 1e2-1e3
+        np.testing.assert_allclose(
+            np.asarray(got), np.asarray(ref), rtol=1e-4,
+            atol=1e-5 * max(10.0, float(jnp.abs(ref).max())))
+
+
+def test_column_blocks_give_the_one_blocks_bits_where_no_token_repeats(
+        monkeypatch):
+    """The blocks change where a sum's columns live, not what is added to
+    them: with one choice a token (no token twice in a pass, so no sum
+    depends on the order a scatter takes its rows in) the result and every
+    gradient in three blocks are the one block's bit for bit, over three
+    passes."""
+    from ray_tpu.ops import moe
+
+    *args, cot = _wide_routed_inputs((4, 8), n=768, top_k=1)
+
+    def both(limit):
+        monkeypatch.setattr(moe, "_SUM_WHOLE", limit)
+        monkeypatch.setattr(moe, "_SUM_COLUMNS", limit)
+        out, _, counts = jax.jit(
+            lambda *a: _held_share((4, 4), *a, 1, scale=2.5))(*args)
+        grads = jax.jit(jax.grad(
+            lambda *a: (_held_share((4, 4), *a, 1, scale=2.5)[0]
+                        * cot).sum(), argnums=(0, 1, 2, 3, 4)))(*args)
+        assert int(counts[4:8].sum()) == 768 == 3 * moe._held_chunk(
+            768, 4, 16)
+        return (out,) + grads
+
+    one, three = both(1024), both(256)
+    assert moe._sum_columns(768) == 256
+    assert float(jnp.abs(one[0]).max()) > 0
+    for a, b in zip(one, three):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+@pytest.mark.parametrize("h, whole, limit, want", [
+    (5120, None, None, 1280),   # train-deepseek-v2-1chip: four blocks
+    (3072, None, None, 3072), (2048, None, None, 2048),     # Laguna, LFM2
+    (4096, None, None, 4096), (2560, None, None, 2560),
+    (6144, None, None, 1024), (8192, None, None, 1024),
+    (7168, None, None, 1024), (4608, None, None, 1152),
+    (5120, 4096, 4096, 2560), (768, 512, 512, 384), (768, 256, 256, 256),
+    (768, 100, 100, 768),
+    (5000, None, None, 5000),   # no divisor in whole lane tiles: one block
+])
+def test_sum_columns_is_a_divisor_in_whole_lane_tiles(monkeypatch, h, whole,
+                                                      limit, want):
+    """A block of the sums is the whole width up to ``_SUM_WHOLE`` and past
+    it the largest divisor of the width in whole 128-lane tiles that is at
+    most ``_SUM_COLUMNS``; a width without one stays one block. The
+    constants as they stand (``None``) leave 2,048 and 3,072 columns one
+    sum and take 5,120 in four. The kept span of a traced layer carries
+    the count."""
+    from ray_tpu.ops import moe
+    from ray_tpu.util import tracing
+
+    if whole is not None:
+        monkeypatch.setattr(moe, "_SUM_WHOLE", whole)
+        monkeypatch.setattr(moe, "_SUM_COLUMNS", limit)
+    width = moe._sum_columns(h)
+    assert width == want and h % width == 0
+    assert width == h or (width <= moe._SUM_COLUMNS and width % 128 == 0)
+    assert [b.shape for b in moe._zero_sums(8, h)] == [(8, width)] * (
+        h // width)
+    f32 = jnp.float32
+    shapes = [jax.ShapeDtypeStruct(s, f32) for s in (
+        (16, h), (h, 16), (8, h, 8), (8, h, 8), (8, 8, h))]
+    n0 = len(tracing.chrome_events())
+    jax.eval_shape(lambda *a: moe.routed_experts(*a, 8, held=(4, 8))[0],
+                   *shapes)
+    (ev,) = [e for e in tracing.chrome_events()[n0:]
+             if e["name"] == "rtpu.moe.held_pass"]
+    assert ev["args"]["sum_blocks"] == h // want
+
+
 @pytest.mark.parametrize("pairs, count, num_experts, want", [
     (16384 * 10, 16, 256, 11520),     # train-laguna-1chip: 45 tiles for 80
     (16384 * 4, 16, 32, 36864),       # train-lfm2-1chip: 144 tiles for 256
@@ -659,9 +794,10 @@ def test_held_pass_is_one_kept_span_of_a_traced_held_layer():
         *a, 8, held=(4, 8))[0].sum(), argnums=(0, 2)), *shapes)
     (ev,) = mine()[n0:]
     assert {k: ev["args"][k] for k in (
-        "pairs", "count", "num_experts", "balanced_share", "chunk")} == {
+        "pairs", "count", "num_experts", "balanced_share", "chunk",
+        "sum_blocks")} == {
         "pairs": 768, "count": 8, "num_experts": 16,
-        "balanced_share": 384.0, "chunk": 512}
+        "balanced_share": 384.0, "chunk": 512, "sum_blocks": 1}
     whole = [jax.ShapeDtypeStruct((16,) + s.shape[1:], f32) if n > 1 else s
              for n, s in enumerate(shapes)]
     jax.eval_shape(lambda *a: moe.routed_experts(*a, 8)[0], *whole)
