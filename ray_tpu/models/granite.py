@@ -20,9 +20,11 @@ logits_scaling``.
   size.
 
 The head is the embedding, tied. ``loss_terms`` never builds the logits
-whole: ``llama.blocked_token_nll`` walks blocks of tokens (100,352 rows
-at 32,768 positions would be 13 GB of float32). ``forward`` builds them,
-for sizes at which they fit. The model is the table ``LAYER_KINDS``
+whole: ``llama.blocked_cross_entropy`` walks blocks of tokens (100,352 rows
+at 32,768 positions would be 13 GB of float32) and takes a block's
+gradients while its logits stand; ``token_nll`` walks the same blocks for
+every position's loss (``llama.blocked_token_nll``). ``forward`` builds the
+logits, for sizes at which they fit. The model is the table ``LAYER_KINDS``
 (``mamba``, ``attention``) and ``models/stack.py`` walks it; the
 initialisation is Mamba-2's published one (``ops/ssm.mamba2_part``).
 Training only: the serving engines keep no scan state.

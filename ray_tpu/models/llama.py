@@ -33,9 +33,9 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
 from ray_tpu.ops.attention import attention_reference, flash_attention
-from ray_tpu.ops.layers import (Leaf, Part, apply_rope, blocked_head_nll,
-                                head_block, kept, rms_norm, rope_frequencies,
-                                swiglu, swiglu_part)
+from ray_tpu.ops.layers import (Leaf, Part, apply_rope, blocked_head_loss,
+                                blocked_head_nll, head_block, kept, rms_norm,
+                                rope_frequencies, swiglu, swiglu_part)
 from ray_tpu.ops.ring_attention import ring_attention
 from ray_tpu.util import tracing
 
@@ -797,6 +797,17 @@ def cross_entropy_loss(logits: jax.Array, targets: jax.Array,
         return nll.mean()
 
 
+def _blocked_head_inputs(cfg: LlamaConfig, params, x: jax.Array
+                         ) -> Tuple[jax.Array, jax.Array]:
+    """x [b, s, h] (the last layer's output) -> what ``ops/layers``' blocked
+    head walks: the normed rows [b * s, h] and the head [h, vocab] in the
+    compute dtype."""
+    x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
+    head = (params["embed"].T if cfg.tie_embeddings
+            else params["lm_head"]).astype(cfg.dtype)
+    return x.reshape(-1, x.shape[-1]), head
+
+
 def blocked_token_nll(cfg: LlamaConfig, params, x: jax.Array,
                       targets: jax.Array, block: Optional[int] = None,
                       logits_divisor: float = 1.0) -> jax.Array:
@@ -806,14 +817,31 @@ def blocked_token_nll(cfg: LlamaConfig, params, x: jax.Array,
     ``cross_entropy_loss``'s arithmetic over ``block`` tokens at a time
     (``ops/layers.blocked_head_nll``; ``head_block`` tokens by default).
     ``logits_divisor``: Granite's ``logits_scaling``."""
-    b, s, h = x.shape
     with jax.named_scope("head_loss"):
-        x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
-        head = (params["embed"].T if cfg.tie_embeddings
-                else params["lm_head"]).astype(cfg.dtype)
+        rows, head = _blocked_head_inputs(cfg, params, x)
         return blocked_head_nll(
-            x.reshape(b * s, h), head, targets.reshape(b * s), block=block,
-            logits_divisor=logits_divisor).reshape(b, s)
+            rows, head, targets.reshape(-1), block=block,
+            logits_divisor=logits_divisor).reshape(targets.shape)
+
+
+def blocked_cross_entropy(cfg: LlamaConfig, params, x: jax.Array,
+                          targets: jax.Array,
+                          mask: Optional[jax.Array] = None,
+                          block: Optional[int] = None,
+                          logits_divisor: float = 1.0) -> jax.Array:
+    """``cross_entropy_loss`` of ``blocked_token_nll``'s positions, the
+    mean or the mask's weighted mean, as a training step differentiates
+    it: the positions' weights go into ``ops/layers.blocked_head_loss``,
+    whose rule takes a block's gradients while its logits stand."""
+    with jax.named_scope("head_loss"):
+        rows, head = _blocked_head_inputs(cfg, params, x)
+        if mask is None:
+            weights = jnp.full(targets.size, 1.0 / targets.size, jnp.float32)
+        else:
+            mask = mask.astype(jnp.float32)
+            weights = (mask / jnp.maximum(mask.sum(), 1)).reshape(-1)
+        return blocked_head_loss(rows, head, targets.reshape(-1), weights,
+                                 block=block, logits_divisor=logits_divisor)
 
 
 def loss_fn(cfg: LlamaConfig, params, batch: Dict[str, jax.Array],

@@ -18,12 +18,14 @@ RMSNorm(SwiGLU(h))``; ``logits = RMSNorm(h_L) @ lm_head``, untied.
   (OLMo 2, OLMoE) and no position embedding (``rope_theta`` null: q and k
   are not rotated).
 
-``loss_terms`` never builds the logits whole (``llama.blocked_token_nll``);
-``forward`` builds them, for sizes at which they fit. The model is the
-table ``LAYER_KINDS`` (``linear``, ``full``) and ``models/stack.py`` walks
-it; the initialisation is the delta-net's published one
-(``ops/delta.gated_delta_part``). Training only: the serving engines keep
-no rule state.
+``loss_terms`` never builds the logits whole
+(``llama.blocked_cross_entropy``: blocks of tokens, a block's gradients
+taken while its logits stand; ``token_nll`` walks the same blocks for every
+position's loss); ``forward`` builds them, for sizes at which they fit. The
+model is the table ``LAYER_KINDS`` (``linear``, ``full``) and
+``models/stack.py`` walks it; the initialisation is the delta-net's
+published one (``ops/delta.gated_delta_part``). Training only: the serving
+engines keep no rule state.
 """
 
 from __future__ import annotations

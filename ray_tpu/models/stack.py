@@ -9,10 +9,11 @@ beside the code they run (``llama.attention_part``,
 is here, once: the parameters (``logical_axes``, ``init_params``,
 ``param_shardings``), the walk (embed, ``llama.resolve_remat``,
 ``llama.run_layers``, what the layers report put back into layer order),
-the head (whole logits, or ``llama.blocked_token_nll`` where they would not
-fit) and the loss with the terms the parts add to it. ``models/laguna.py``,
-``lfm2.py``, ``granite.py`` and ``olmo_hybrid.py`` are a config, a table and
-the names of one ``Stack``'s methods; a new architecture is one more such
+the head (whole logits, or ``llama.blocked_cross_entropy`` and
+``llama.blocked_token_nll`` where they would not fit) and the loss with the
+terms the parts add to it. ``models/laguna.py``, ``lfm2.py``, ``granite.py``
+and ``olmo_hybrid.py`` are a config, a table and the names of one
+``Stack``'s methods; a new architecture is one more such
 module and, where its operator is new, one part under ``ops/``.
 
 Parameters are stacked by kind: ``params["layers"][kind][name]`` is
@@ -103,12 +104,16 @@ class Stack:
     ``reports``: the name (``Part.reports``) of what ``forward`` and
     ``token_nll`` hand back beside their result: "router", the routed
     layers' statistics, or a mixer's states. ``blocked_head``: the loss
-    never builds the logits whole (``llama.blocked_token_nll``: 100,352
-    rows at 32,768 positions would be 13 GB of float32), and the plan is
-    told so. ``embed_scale`` and ``logits_divisor`` name the config's
-    fields that multiply the embedding and divide the logits (Granite's
-    ``embedding_multiplier`` and ``logits_scaling``). The head is the
-    embedding where ``cfg.tie_embeddings``, an ``lm_head`` of its own
+    never builds the logits whole (100,352 rows at 32,768 positions would
+    be 13 GB of float32), and the plan is told so: ``loss_terms`` sums it
+    block by block with both gradients of a block taken while its logits
+    stand (``llama.blocked_cross_entropy``: three ``[block, vocab]``
+    products a block), ``token_nll`` hands back every position's loss
+    (``llama.blocked_token_nll``: whoever differentiates that pays for a
+    block's logits twice). ``embed_scale`` and ``logits_divisor`` name the
+    config's fields that multiply the embedding and divide the logits
+    (Granite's ``embedding_multiplier`` and ``logits_scaling``). The head is
+    the embedding where ``cfg.tie_embeddings``, an ``lm_head`` of its own
     elsewhere."""
     kinds: Dict[str, Tuple[Part, Part]]
     reports: str
@@ -245,10 +250,9 @@ class Stack:
         if mask is not None:
             mask = mask[:, 1:]
         if self.blocked_head:
-            nll = llama.blocked_token_nll(cfg, params, x, tokens[:, 1:],
-                                          logits_divisor=self._divisor(cfg))
-            ce = (nll.mean() if mask is None
-                  else (nll * mask).sum() / jnp.maximum(mask.sum(), 1))
+            ce = llama.blocked_cross_entropy(
+                cfg, params, x, tokens[:, 1:], mask,
+                logits_divisor=self._divisor(cfg))
         else:
             ce = llama.cross_entropy_loss(self._logits(cfg, params, x),
                                           tokens[:, 1:], mask)

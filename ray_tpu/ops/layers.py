@@ -348,31 +348,120 @@ def head_block(tokens: int, vocab: int) -> int:
     return max(n for n in range(1, min(tokens, most) + 1) if tokens % n == 0)
 
 
+def _block_nll(xb: jax.Array, head: jax.Array, tb: jax.Array,
+               logits_divisor: float
+               ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """One block of the blocked head: xb [block, h], tb [block] -> its
+    float32 logits [block, vocab], their log-sum-exp [block] and the rows'
+    loss [block]. Both walks below run this and nothing else on a block's
+    logits in the forward."""
+    logits = jnp.dot(xb, head, preferred_element_type=jnp.float32)
+    if logits_divisor != 1.0:
+        logits = logits / logits_divisor
+    lse = jax.scipy.special.logsumexp(logits, axis=-1)
+    return logits, lse, lse - jnp.take_along_axis(
+        logits, tb[:, None], axis=-1)[:, 0]
+
+
+def _head_blocks(block: Optional[int], x: jax.Array, head: jax.Array,
+                 *rows: jax.Array) -> Tuple[jax.Array, ...]:
+    """x [T, h] and each of ``rows`` [T] as ``lax.scan`` walks them:
+    [T / block, block, ...]."""
+    T = x.shape[0]
+    block = block or head_block(T, head.shape[-1])
+    if T % block:
+        raise ValueError(f"{T} tokens are not whole blocks of {block}")
+    return tuple(a.reshape(T // block, block, *a.shape[1:])
+                 for a in (x, *rows))
+
+
 def blocked_head_nll(x: jax.Array, head: jax.Array, targets: jax.Array,
                      block: Optional[int] = None,
                      logits_divisor: float = 1.0) -> jax.Array:
     """x [T, h] (normed), head [h, vocab], targets [T] -> the next-token
     loss of every row [T] float32, a block of rows at a time: a block's
     logits ``[block, vocab]`` live inside one step of a ``lax.scan`` under
-    ``jax.checkpoint``, so the backward builds them again, block by block,
-    and adds each block's gradient of the head to the sum so far in the
-    head's own dtype. 100,352 rows at 32,768 positions would be 13 GB of
-    float32 whole."""
-    T, h = x.shape
-    block = block or head_block(T, head.shape[-1])
-    if T % block:
-        raise ValueError(f"{T} tokens are not whole blocks of {block}")
+    ``jax.checkpoint``, so whoever differentiates the rows' losses with
+    weights of their own pays for the logits a second time in the backward,
+    block by block, and has each block's gradient of the head added to the
+    sum so far in the head's own dtype. A training step's loss is
+    ``blocked_head_loss``, which does not. 100,352 rows at 32,768 positions
+    would be 13 GB of float32 whole."""
 
     @jax.checkpoint
     def one_block(_, xt):
-        xb, tb = xt
-        logits = jnp.dot(xb, head, preferred_element_type=jnp.float32)
-        if logits_divisor != 1.0:
-            logits = logits / logits_divisor
-        lse = jax.scipy.special.logsumexp(logits, axis=-1)
-        return None, lse - jnp.take_along_axis(
-            logits, tb[:, None], axis=-1)[:, 0]
+        return None, _block_nll(xt[0], head, xt[1], logits_divisor)[2]
 
-    _, nll = jax.lax.scan(one_block, None, (
-        x.reshape(T // block, block, h), targets.reshape(T // block, block)))
-    return nll.reshape(T)
+    _, nll = jax.lax.scan(one_block, None,
+                          _head_blocks(block, x, head, targets))
+    return nll.reshape(x.shape[0])
+
+
+def blocked_head_loss(x: jax.Array, head: jax.Array, targets: jax.Array,
+                      weights: jax.Array, block: Optional[int] = None,
+                      logits_divisor: float = 1.0) -> jax.Array:
+    """``sum(weights * blocked_head_nll(x, head, targets))``, float32, as a
+    training step differentiates it: x [T, h] (normed), head [h, vocab],
+    targets [T], weights [T] float32 (``1 / T`` for a mean, a mask over its
+    sum). The loss is linear in the rows' losses and the weights are known
+    in the forward, so the rule that ``jax.grad`` runs takes both gradients
+    of a block while its float32 logits stand:
+    ``d_logits = weights * (softmax - onehot) / logits_divisor``,
+    ``dx = d_logits @ head^T`` and the block's share of
+    ``d_head = xb^T @ d_logits``, three ``[block, vocab]`` products a block
+    where a checkpointed block runs four and the log-sum-exp's passes
+    twice; the backward only scales what was kept by the cotangent.
+    ``dx [T, h]`` is kept in float32 and ``d_head [h, vocab]`` in the
+    head's dtype, each block's float32 share added to the sum so far and
+    rounded once (a float32 sum reads and writes 822 MB a block at
+    Granite's widths and cost its product half again its time on a v5e:
+    PERF.md 6, PR 45). Not differentiated, one product a block and no
+    gradient. ``targets`` takes no gradient, ``weights`` the rows'
+    losses."""
+
+    @jax.custom_vjp
+    def loss(head, x_blocks, targets, weights):
+        def one_block(total, xtw):
+            xb, tb, wb = xtw
+            nll = _block_nll(xb, head, tb, logits_divisor)[2]
+            return total + jnp.sum(wb * nll), None
+        return jax.lax.scan(one_block, jnp.zeros((), jnp.float32),
+                            (x_blocks, targets, weights))[0]
+
+    def forward(head, x_blocks, targets, weights):
+        def one_block(carry, xtw):
+            total, d_head = carry
+            # the block's rows as an array of their own before the products
+            # read them: sliced from the stack inside each product, XLA
+            # tiles the logits' and d_head's worse on a v5e (4.4 and 5.5 ms
+            # a block at Granite's widths for 5.1 and 6.3: PERF.md 6, PR 45)
+            xb, tb, wb = jax.lax.optimization_barrier(xtw)
+            logits, lse, nll = _block_nll(xb, head, tb, logits_divisor)
+            soft = jnp.exp(logits - lse[:, None])
+            hit = jax.lax.broadcasted_iota(
+                jnp.int32, soft.shape, 1) == tb[:, None]
+            d_logits = (jnp.where(hit, soft - 1.0, soft)
+                        * (wb / logits_divisor)[:, None])
+            # the float32 operand against the head's dtype, as jax's own
+            # transpose of the forward product has it
+            dx = jax.lax.dot_general(
+                d_logits, head, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            d_head = (d_head + jax.lax.dot_general(
+                xb, d_logits, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)).astype(head.dtype)
+            return (total + jnp.sum(wb * nll), d_head), (dx, nll)
+
+        (total, d_head), (dx, nll) = jax.lax.scan(
+            one_block, (jnp.zeros((), jnp.float32), jnp.zeros_like(head)),
+            (x_blocks, targets, weights))
+        return total, (dx, d_head, nll)
+
+    def backward(kept, g):
+        dx, d_head, nll = kept
+        return ((g * d_head).astype(head.dtype), (g * dx).astype(x.dtype),
+                None, g * nll)
+
+    loss.defvjp(forward, backward)
+    return loss(head, *_head_blocks(block, x, head, targets,
+                                    weights.astype(jnp.float32)))

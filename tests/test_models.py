@@ -1433,13 +1433,10 @@ def test_attention_block_is_bit_equal_for_its_callers(caller):
 # ---- the head and loss over blocks of tokens
 
 
-@pytest.mark.parametrize("tied", [True, False], ids=["tied", "lm_head"])
-def test_blocked_head_and_loss_match_the_whole_one(tied):
-    """``blocked_token_nll`` against ``_final_head`` + ``cross_entropy_loss``
-    on the same hidden states: each position's loss, the mean, and the
-    gradients with respect to the hidden states, the last norm and the
-    head, at three block sizes (1e-5: the blocks' head gradients are added
-    in another order)."""
+def _head_case(tied):
+    """The tiny llama with a last norm that is not all ones, hidden states
+    and targets for the blocked head's tests; ``top`` holds the leaves a
+    head's gradient reaches."""
     from dataclasses import replace
 
     cfg = replace(llama.LlamaConfig.tiny(), tie_embeddings=tied)
@@ -1451,30 +1448,117 @@ def test_blocked_head_and_loss_match_the_whole_one(tied):
                                  cfg.vocab_size)
     top = {k: params[k] for k in ("final_norm",
                                   "embed" if tied else "lm_head")}
+    return cfg, params, top, x, targets
+
+
+def _assert_head_gradients_close(got_g, want_g):
+    for a, b in zip(jax.tree_util.tree_leaves(got_g),
+                    jax.tree_util.tree_leaves(want_g)):
+        np.testing.assert_allclose(
+            np.asarray(a), np.asarray(b), rtol=1e-4,
+            atol=1e-5 * float(jnp.abs(b).max()))
+
+
+@pytest.mark.parametrize("path", ["rows", "sum", "sum-masked"])
+@pytest.mark.parametrize("tied", [True, False], ids=["tied", "lm_head"])
+def test_blocked_head_and_loss_match_the_whole_one(tied, path):
+    """The blocked head against ``_final_head`` + ``cross_entropy_loss``
+    on the same hidden states: the loss and the gradients with respect to
+    the hidden states, the last norm and the head, at three block sizes
+    (1e-5: the blocks' head gradients are added in another order).
+    ``rows``: the mean of ``blocked_token_nll``'s positions, which a
+    checkpointed block's backward rebuilds; ``sum``: the training loss,
+    ``blocked_cross_entropy``, whose rule takes a block's gradients while
+    its logits stand, with a mask that zeroes a third of the positions
+    and without."""
+    cfg, params, top, x, targets = _head_case(tied)
+    mask = ((jnp.arange(48).reshape(2, 24) % 3 != 1).astype(jnp.float32)
+            if path == "sum-masked" else None)
 
     def whole(top, x):
         return llama.cross_entropy_loss(
-            llama._final_head(cfg, {**params, **top}, x) / 8.0, targets)
+            llama._final_head(cfg, {**params, **top}, x) / 8.0, targets,
+            mask)
 
     def blocked(block):
-        return lambda top, x: llama.blocked_token_nll(
-            cfg, {**params, **top}, x, targets, block=block,
-            logits_divisor=8.0).mean()
+        if path == "rows":
+            return lambda top, x: llama.blocked_token_nll(
+                cfg, {**params, **top}, x, targets, block=block,
+                logits_divisor=8.0).mean()
+        return lambda top, x: llama.blocked_cross_entropy(
+            cfg, {**params, **top}, x, targets, mask, block=block,
+            logits_divisor=8.0)
 
     want, want_g = jax.value_and_grad(whole, argnums=(0, 1))(top, x)
     for block in (48, 16, 1):
         got, got_g = jax.jit(jax.value_and_grad(
             blocked(block), argnums=(0, 1)))(top, x)
         np.testing.assert_allclose(float(got), float(want), rtol=1e-6)
-        for a, b in zip(jax.tree_util.tree_leaves(got_g),
-                        jax.tree_util.tree_leaves(want_g)):
-            np.testing.assert_allclose(
-                np.asarray(a), np.asarray(b), rtol=1e-4,
-                atol=1e-5 * float(jnp.abs(b).max()))
+        _assert_head_gradients_close(got_g, want_g)
     with pytest.raises(ValueError, match="not whole blocks"):
         blocked(5)(top, x)
     assert llama.head_block(32768, 100352) == 2048
     assert llama.head_block(30, 256) == 30
+
+
+@pytest.mark.parametrize("tied", [True, False], ids=["tied", "lm_head"])
+def test_blocked_head_loss_under_a_cotangent_other_than_one(tied):
+    """The rule's kept gradients are scaled by what comes back: three
+    times the loss plus another term of the hidden states gives the whole
+    head's gradients of the same function; the weights' gradient is the
+    rows' loss, the targets take none."""
+    from ray_tpu.ops.layers import blocked_head_loss, blocked_head_nll
+
+    cfg, params, top, x, targets = _head_case(tied)
+
+    def whole(top, x):
+        return 3.0 * llama.cross_entropy_loss(
+            llama._final_head(cfg, {**params, **top}, x) / 8.0, targets
+        ) + jnp.sum(jnp.sin(x))
+
+    def blocked(top, x):
+        return 3.0 * llama.blocked_cross_entropy(
+            cfg, {**params, **top}, x, targets, block=16,
+            logits_divisor=8.0) + jnp.sum(jnp.sin(x))
+
+    want, want_g = jax.value_and_grad(whole, argnums=(0, 1))(top, x)
+    got, got_g = jax.jit(jax.value_and_grad(blocked, argnums=(0, 1)))(top, x)
+    np.testing.assert_allclose(float(got), float(want), rtol=1e-6)
+    _assert_head_gradients_close(got_g, want_g)
+
+    rows, head = x.reshape(48, -1), params["embed"].T
+    weights = jax.random.uniform(jax.random.PRNGKey(4), (48,))
+    d_weights = jax.grad(lambda w: 2.0 * blocked_head_loss(
+        rows, head, targets.reshape(48), w, block=16))(weights)
+    np.testing.assert_allclose(
+        np.asarray(d_weights), 2.0 * np.asarray(blocked_head_nll(
+            rows, head, targets.reshape(48), block=16)), rtol=1e-6)
+
+
+@pytest.mark.parametrize("blocks", [1, 3, 48])
+def test_blocked_head_loss_runs_three_products_a_block(blocks):
+    """The static witness of the rule: a block's body holds three
+    ``dot_general`` under ``value_and_grad`` (the logits, ``dx`` and the
+    head's share) where the checkpointed rows hold four, one where nothing
+    is differentiated, and no product outside the blocks' scan."""
+    cfg, params, top, x, targets = _head_case(True)
+
+    def products(fn, scans=1):
+        text = str(jax.make_jaxpr(fn)(top, x))
+        assert text.count(" scan[") == scans
+        return text.count("dot_general")
+
+    def loss(top, x):
+        return llama.blocked_cross_entropy(
+            cfg, {**params, **top}, x, targets, block=48 // blocks)
+
+    def rows(top, x):
+        return llama.blocked_token_nll(
+            cfg, {**params, **top}, x, targets, block=48 // blocks).mean()
+
+    assert products(jax.value_and_grad(loss, argnums=(0, 1))) == 3
+    assert products(loss) == 1
+    assert products(jax.value_and_grad(rows, argnums=(0, 1)), scans=2) == 4
 
 
 # ---- describe_stack: the scan kind, and a kind it does not know

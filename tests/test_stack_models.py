@@ -354,6 +354,42 @@ def test_reference_gradient_of_a_weighted_loss(stack_setup):
     _WEIGHTED[_name(mod)](mod, *rest)
 
 
+@pytest.mark.parametrize("masked", [False, True], ids=["mean", "masked"])
+@pytest.mark.parametrize("stack_setup", _BLOCKED, indirect=True)
+def test_training_loss_is_the_weighted_mean_of_token_nll(stack_setup, masked):
+    """The timed path held to the path the cell's check differentiates:
+    ``loss_terms`` (``blocked_head_loss``, whose rule takes a block's
+    gradients in the forward) and the same weighted mean of
+    ``token_nll``'s positions (the checkpointed rows) give one loss and,
+    leaf by leaf, one gradient, with a mask that zeroes positions and
+    without."""
+    mod, _ref, cfg, params, tokens = stack_setup
+    tokens = jnp.asarray(tokens)
+    mask = (jnp.asarray(np.random.default_rng(5).uniform(size=(2, 33)) < 0.6,
+                        jnp.float32) if masked else None)
+    batch = {"tokens": tokens, **({"mask": mask} if masked else {})}
+
+    def through_rows(p):
+        nll, _ = mod.token_nll(cfg, p, tokens)
+        if not masked:
+            return nll.mean()
+        return (nll * mask[:, 1:]).sum() / jnp.maximum(mask[:, 1:].sum(), 1)
+
+    with jax.default_matmul_precision("highest"):
+        got, got_g = jax.jit(jax.value_and_grad(
+            lambda p: mod.loss_fn(cfg, p, batch)))(params)
+        want, want_g = jax.jit(jax.value_and_grad(through_rows))(params)
+    np.testing.assert_allclose(float(got), float(want), rtol=1e-6)
+    flat, _ = jax.tree_util.tree_flatten_with_path(got_g)
+    assert len(flat) == _GRADIENT[_name(mod)][0]
+    for (path, g), w in zip(flat, jax.tree_util.tree_leaves(want_g)):
+        scale = float(jnp.abs(w).max())
+        assert scale > 1e-6, path
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=1e-5,
+                                   atol=1e-6 * max(scale, 1e-2),
+                                   err_msg=str(path))
+
+
 @pytest.mark.parametrize("how", ["ramp", "constant-rate", "unchanged"])
 @pytest.mark.parametrize("stack_setup", _BLOCKED, indirect=True)
 def test_first_step_against_the_reference_adamw(stack_setup, how):

@@ -556,6 +556,16 @@ def test_delta_rule_kernels_compile_at_the_cells_shapes(S, one_chip,
         assert any(kernel in name for name in names), (kernel, names)
 
 
+def _vocab_products(text: str, block: int, vocab: int) -> int:
+    """The ``dot_general`` of a lowered step that read or write a
+    ``[block, vocab]`` array: the blocked head's products, three a block
+    since its loss takes a block's gradients while the logits stand
+    (``ops/layers.blocked_head_loss``), four when a checkpointed block
+    rebuilt them."""
+    return sum("dot_general" in line and f"{block}x{vocab}x" in line
+               for line in text.splitlines())
+
+
 def test_olmo_hybrid_cell_step_lowers_for_a_v5e_chip(one_chip,
                                                      no_compile_cache,
                                                      monkeypatch):
@@ -622,6 +632,7 @@ def test_olmo_hybrid_cell_step_lowers_for_a_v5e_chip(one_chip,
                    "delta_rule_bwd", "flash_fwd", "flash_bwd_dq",
                    "flash_bwd_dkv"):
         assert kernel in text, kernel
+    assert _vocab_products(text, 16_384, 12_544) == 3
     assert lowered.out_info[3].shape == ()
 
 
@@ -705,6 +716,7 @@ def test_scan_kernels_compile_and_the_granite_cells_step_names_them(
                    "ssd_scan_bwd", "flash_fwd", "flash_bwd_dq",
                    "flash_bwd_dkv"):
         assert kernel in text, kernel
+    assert _vocab_products(text, 2_048, 100_352) == 3
 
 
 @pytest.mark.parametrize("shared", [True, False], ids=["shared", "one-key"])
