@@ -34,9 +34,8 @@ state.
 from __future__ import annotations
 
 from dataclasses import dataclass, replace
-from typing import Any, Dict, Optional, Tuple
+from typing import Optional, Tuple
 
-import jax
 import jax.numpy as jnp
 
 from ray_tpu.models import llama, stack
@@ -125,56 +124,8 @@ forward = STACK.forward
 loss_terms = STACK.loss_terms
 loss_fn = STACK.loss_fn
 rows_held, rows_passed = stack.rows_held, stack.rows_passed
-
-
-def trainable(params: Dict[str, Any]) -> Dict[str, Any]:
-    """The leaves an optimizer owns: every one but the routers' bias."""
-    return {**params, "layers": {
-        kind: {k: v for k, v in leaves.items() if k != "router_bias"}
-        for kind, leaves in params["layers"].items()}}
-
-
-def with_trainable(params: Dict[str, Any], trained: Dict[str, Any]
-                   ) -> Dict[str, Any]:
-    """``params`` with ``trained`` (like ``trainable(params)``) in place
-    of the leaves an optimizer owns."""
-    return {**trained, "layers": {
-        kind: {**params["layers"][kind], **leaves}
-        for kind, leaves in trained["layers"].items()}}
-
-
-def update_router_bias(cfg: Lfm2Config, params: Dict[str, Any],
-                       expert_counts: jax.Array) -> Dict[str, Any]:
-    """``params`` after a step whose routed layers, in their order, sent
-    ``expert_counts [Lr, E]`` rows to each expert: every router's bias
-    moves ``bias_update_rate`` toward the experts that got fewer rows than
-    the mean, away from those that got more. On a mesh the counts are the
-    whole batch's (``forward`` sums them over the batch axes)."""
-    with jax.named_scope("moe_route"), jax.named_scope("moe_bias_update"):
-        c = expert_counts.astype(jnp.float32)
-        move = cfg.bias_update_rate * jnp.sign(
-            c.mean(-1, keepdims=True) - c)                      # [Lr, E]
-        at = _routed_rows(cfg.pattern)
-        return {**params, "layers": {
-            kind: ({**leaves, "router_bias": leaves["router_bias"]
-                    + move[jnp.asarray(at[kind])]}
-                   if kind in at else leaves)
-            for kind, leaves in params["layers"].items()}}
-
-
-def _routed_rows(pattern: Tuple[str, ...]) -> Dict[str, list]:
-    """kind -> of the routed layers in their order, those of that kind."""
-    at: Dict[str, list] = {}
-    routed = [kind for kind in pattern
-              if LAYER_KINDS[kind][1].reports == "router"]
-    for row, kind in enumerate(routed):
-        at.setdefault(kind, []).append(row)
-    return at
-
-
-def router_bias_abs_max(params: Dict[str, Any]) -> jax.Array:
-    """The counter ``moe_router_bias_abs_max``: the largest ``|b|`` of
-    any router."""
-    return jnp.max(jnp.stack([
-        jnp.abs(leaves["router_bias"]).max()
-        for leaves in params["layers"].values() if "router_bias" in leaves]))
+# the bias's: what an optimizer is given and gives back, the move after a
+# step, the counter (``models/stack.py``)
+trainable, with_trainable = stack.trainable, stack.with_trainable
+update_router_bias = STACK.update_router_bias
+router_bias_abs_max = stack.router_bias_abs_max

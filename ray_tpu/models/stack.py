@@ -11,7 +11,9 @@ is here, once: the parameters (``logical_axes``, ``init_params``,
 ``llama.run_layers``, what the layers report put back into layer order),
 the head (whole logits, or ``llama.blocked_cross_entropy`` and
 ``llama.blocked_token_nll`` where they would not fit) and the loss with the
-terms the parts add to it. ``models/laguna.py``, ``lfm2.py``, ``granite.py``
+terms the parts add to it, and for routers balanced by a bias no optimizer
+owns (``routed_part(bias=True)``) what an optimizer is given and the bias's
+move after a step. ``models/laguna.py``, ``lfm2.py``, ``granite.py``
 and ``olmo_hybrid.py`` are a config, a table and the names of one
 ``Stack``'s methods; a new architecture is one more such
 module and, where its operator is new, one part under ``ops/``.
@@ -98,6 +100,34 @@ def rows_passed(cfg, expert_counts) -> int:
     return moe.rows_passed(expert_counts, cfg.experts_held)
 
 
+# A router's bias (``routed_part(bias=True)``: the leaf ``router_bias``)
+# takes part in the choice alone and gets no gradient: it is in the
+# parameter tree and not the optimizer's. ``Stack.update_router_bias``
+# moves it after a step.
+def trainable(params: Dict[str, Any]) -> Dict[str, Any]:
+    """The leaves an optimizer owns: every one but the routers' bias."""
+    return {**params, "layers": {
+        kind: {k: v for k, v in leaves.items() if k != "router_bias"}
+        for kind, leaves in params["layers"].items()}}
+
+
+def with_trainable(params: Dict[str, Any], trained: Dict[str, Any]
+                   ) -> Dict[str, Any]:
+    """``params`` with ``trained`` (like ``trainable(params)``) in place
+    of the leaves an optimizer owns."""
+    return {**trained, "layers": {
+        kind: {**params["layers"][kind], **leaves}
+        for kind, leaves in trained["layers"].items()}}
+
+
+def router_bias_abs_max(params: Dict[str, Any]) -> jax.Array:
+    """The counter ``moe_router_bias_abs_max``: the largest ``|b|`` of
+    any router."""
+    return jnp.max(jnp.stack([
+        jnp.abs(leaves["router_bias"]).max()
+        for leaves in params["layers"].values() if "router_bias" in leaves]))
+
+
 @dataclass(frozen=True, eq=False)
 class Stack:
     """A model: its table of kinds and what the table does not say.
@@ -166,10 +196,13 @@ class Stack:
             mixtral.without_layer_axis(self.logical_axes(cfg)), mesh)
 
     def hidden(self, cfg, params, tokens: jax.Array, mesh=None,
-               keep_router_logits: bool = False
+               keep_router_logits: bool = False,
+               keep_index_choice: bool = False
                ) -> Tuple[jax.Array, Dict[str, Any]]:
         """tokens [b, s] -> (the last layer's output [b, s, hidden], name
-        -> what the layers reported under it, stacked in layer order)."""
+        -> what the layers reported under it, stacked in layer order).
+        ``keep_index_choice``: the index layers report their index's
+        inputs and their packed choice of keys too (``ops/mla.py``)."""
         pattern = cfg.pattern
         with jax.named_scope("embed"):
             x = params["embed"].astype(cfg.dtype)[tokens]
@@ -181,7 +214,7 @@ class Stack:
                 for part in self.kinds[kind]:
                     if part.once and part.once not in once:
                         once[part.once] = part.once(cfg, tokens)
-        ctx = Ctx(mesh, once, keep_router_logits)
+        ctx = Ctx(mesh, once, keep_router_logits, keep_index_choice)
 
         def layer_of(kind):
             mixer, mlp = self.kinds[kind]
@@ -270,3 +303,28 @@ class Stack:
     def loss_fn(self, cfg, params, batch: Dict[str, jax.Array], mesh=None
                 ) -> jax.Array:
         return self.loss_terms(cfg, params, batch, mesh=mesh)[0]
+
+    def update_router_bias(self, cfg, params: Dict[str, Any],
+                           expert_counts: jax.Array) -> Dict[str, Any]:
+        """``params`` after a step whose routed layers, in their order,
+        sent ``expert_counts [Lr, E]`` rows to each expert: every router's
+        bias moves ``cfg.bias_update_rate`` toward the experts that got
+        fewer rows than the mean, away from those that got more (loss-free
+        balancing, arXiv:2408.15664). On a mesh the counts are the whole
+        batch's (``forward`` sums them over the batch axes)."""
+        with jax.named_scope("moe_route"), \
+                jax.named_scope("moe_bias_update"):
+            c = expert_counts.astype(jnp.float32)
+            move = cfg.bias_update_rate * jnp.sign(
+                c.mean(-1, keepdims=True) - c)                  # [Lr, E]
+            # kind -> of the routed layers in their order, that kind's
+            at: Dict[str, list] = {}
+            routed = [kind for kind in cfg.pattern
+                      if self.kinds[kind][1].reports == "router"]
+            for row, kind in enumerate(routed):
+                at.setdefault(kind, []).append(row)
+            return {**params, "layers": {
+                kind: ({**leaves, "router_bias": leaves["router_bias"]
+                        + move[jnp.asarray(at[kind])]}
+                       if kind in at else leaves)
+                for kind, leaves in params["layers"].items()}}

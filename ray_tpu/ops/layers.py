@@ -37,11 +37,13 @@ class Leaf(NamedTuple):
 class Ctx(NamedTuple):
     """What a forward hands every part's body beside the layer's own
     input: the mesh the arrays are sharded over, what each part's ``once``
-    made (keyed by that function) and whether the routers' logits and
-    choices are asked for."""
+    made (keyed by that function), whether the routers' logits and
+    choices are asked for, and whether an index's inputs and its choice
+    of keys are."""
     mesh: Any
     once: Dict[Callable, Any]
     keep_router_logits: bool = False
+    keep_index_choice: bool = False
 
 
 @dataclass(frozen=True)

@@ -31,21 +31,58 @@ without its reduction: ``W_qb`` and ``W_kvb`` hold the held heads' columns,
 down-projections are whole, and the block adds the held heads' part of the
 sum to ``x``. Which heads they are changes no operation.
 
+A model with more than one kind of latent layer names each kind's sizes
+by a ``prefix`` of the config's fields (``latent_attention_part(prefix=
+"swa_")`` reads ``swa_num_heads``, ``swa_q_lora_rank``, ...) and gives each
+its own rope tables (``rope``). Further options, all off in DeepSeek-V2,
+whose traced program they leave as it was:
+
+- ``window``: the config's field with the keys a query sees, its own
+  position among them (the ``flash_kv_*`` kernels under a band);
+- ``gate``: a per-head output gate ``sigmoid(u W_g)`` on each head's
+  output before ``W_o`` (arXiv:2505.06708, headwise; as
+  ``llama.attention_block``'s ``wg``);
+- ``rescale``: ``(hidden / rank) ** 0.5`` on the queries after ``W_qb``
+  and on the normed kv latent before ``W_kvb``, the rope key unscaled
+  (LongCat-Flash's ``mla_scale_q_lora`` / ``mla_scale_kv_lora``). The two
+  expansions then start from a fan-in of ``hidden``, not of the rank: the
+  factor stands for exactly that difference, and drawn from the rank under
+  it the scores' spread at the start is 6 where it should be 1 (measured:
+  the softmax so sharp that bf16 rounding reads 10% at the last layer);
+- ``index``: a learned index chooses ``cfg.index_topk`` keys a query and
+  the layer attends over those (``ops/dsa.py``): ``q_i = c_q W_iq``
+  ``[index_heads, index_head_dim]`` from the normed query latent, ``k_i =
+  LayerNorm(u W_ik)`` one key a position, the first ``d_r`` dims of both
+  rotated with the layer's tables, ``w = (u W_iw) * index_heads ** -0.5 *
+  index_head_dim ** -0.5`` in float32. ``u`` and ``c_q`` reach the index
+  under ``stop_gradient`` and the choice is not differentiated, so the
+  cross entropy gives the index's five leaves nothing; the layer reports
+  (``Part.reports`` "dsa") the sum of its positions' ``KL(p_t ||
+  softmax_{S_t} I)`` and the pairs chosen (asked for,
+  ``ctx.keep_index_choice``, ``q_i``, ``k_i``, ``w`` and the choice packed
+  eight keys a byte too), and ``terms`` adds
+  ``cfg.index_loss_coef`` x the layers' sum of the positions' mean to the
+  loss with the counters ``dsa_index_loss`` and ``dsa_pairs_chosen_share``.
+
 Named scopes: ``mla_q`` (the layer's norm, both query projections and the
 latent's norm), ``mla_kv`` (the two key/value projections and the latent's
-norm), ``mla_rope``, ``flash``, ``mla_out``. One kept span as the part is
-traced, ``rtpu.mla.shapes``. Training only: a latent cache and the absorbed
-decode path are the serving engines' to come.
+norm), ``mla_rope``, ``flash`` (a window layer's kernels ``flash_window``
+inside it), ``mla_out``; ``attn_gate``; ``dsa_proj`` (the index's three
+projections, its LayerNorm and rope) and ``ops/dsa.py``'s. One kept span as
+the part is traced, ``rtpu.mla.shapes``. Training only: a latent cache and
+the absorbed decode path are the serving engines' to come.
 """
 
 from __future__ import annotations
 
 import math
+from typing import Callable, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
+from ray_tpu.ops import dsa
 from ray_tpu.ops.attention import (attention_reference, flash_attention,
                                    with_shared_key)
 from ray_tpu.ops.layers import (Leaf, Part, apply_rope, kept, rms_norm,
@@ -53,11 +90,35 @@ from ray_tpu.ops.layers import (Leaf, Part, apply_rope, kept, rms_norm,
 from ray_tpu.util import tracing
 
 
-def softmax_scale(cfg) -> float:
+class _Sizes(NamedTuple):
+    """One kind of latent layer's sizes, read from the config's fields
+    under the kind's prefix."""
+    heads: int
+    heads_of: int
+    q_rank: int
+    kv_rank: int
+    d_n: int
+    d_r: int
+    d_v: int
+
+
+def sizes(cfg, prefix: str = "") -> _Sizes:
+    def of(name):
+        return getattr(cfg, prefix + name)
+
+    return _Sizes(of("num_heads"),
+                  getattr(cfg, prefix + "heads_of", None) or of("num_heads"),
+                  of("q_lora_rank"), of("kv_lora_rank"),
+                  of("qk_nope_head_dim"), of("qk_rope_head_dim"),
+                  of("v_head_dim"))
+
+
+def softmax_scale(cfg, prefix: str = "") -> float:
     """``(d_n + d_r) ** -0.5``, times ``m ** 2`` where the config's rope
     scaling states an ``mscale_all_dim`` (the published code's
     ``yarn_get_mscale``)."""
-    scale = (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5
+    sz = sizes(cfg, prefix)
+    scale = (sz.d_n + sz.d_r) ** -0.5
     scaling = cfg.rope_scaling_dict or {}
     factor, all_dim = scaling.get("factor", 1.0), scaling.get("mscale_all_dim")
     if all_dim and factor > 1:
@@ -79,58 +140,140 @@ def _rotate(x: jax.Array, cos, sin) -> jax.Array:
                       cos, sin)
 
 
-def latent_attention_part() -> Part:
+def layer_norm(x: jax.Array, weight: jax.Array, bias: jax.Array,
+               eps: float = 1e-5) -> jax.Array:
+    """LayerNorm over the last axis in float32 (the index key's)."""
+    xf = x.astype(jnp.float32)
+    xf = xf - xf.mean(-1, keepdims=True)
+    xf = xf * jax.lax.rsqrt(jnp.square(xf).mean(-1, keepdims=True) + eps)
+    return (xf * weight.astype(jnp.float32)
+            + bias.astype(jnp.float32)).astype(x.dtype)
+
+
+def rescale_factor(cfg, rank: int) -> float:
+    """``(hidden / rank) ** 0.5``: what ``rescale`` multiplies the queries
+    and the normed kv latent by."""
+    return (cfg.hidden_size / rank) ** 0.5
+
+
+def head_gate(cfg, u: jax.Array, wg: jax.Array) -> jax.Array:
+    """``sigmoid(u W_g)`` [b, s, H] float32: a gate a head and position."""
+    return jax.nn.sigmoid(jnp.dot(u, wg.astype(cfg.dtype),
+                                  preferred_element_type=jnp.float32))
+
+
+def index_inputs(cfg, u, c_q, p, cos, sin):
+    """The index's queries [b, s, J, d_i], keys [b, s, d_i] and head
+    weights [b, s, J] float32 from the layer's normed input ``u`` and its
+    normed query latent ``c_q`` (the module's docstring). Neither input
+    receives a gradient from here."""
+    dt = cfg.dtype
+    b, s, _ = u.shape
+    J, di = cfg.index_heads, cfg.index_head_dim
+    u, c_q = jax.lax.stop_gradient(u), jax.lax.stop_gradient(c_q)
+
+    def dot(a, w):
+        return jnp.dot(a, w.astype(dt), preferred_element_type=jnp.float32)
+
+    q_i = dot(c_q, p["wi_q"]).astype(dt).reshape(b, s, J, di)
+    k_i = layer_norm(dot(u, p["wi_k"]).astype(dt), p["wi_k_norm"],
+                     p["wi_k_bias"], cfg.index_norm_eps)
+    dr = 2 * cos.shape[-1]
+    q_i = jnp.concatenate(
+        [_rotate(q_i[..., :dr], cos, sin), q_i[..., dr:]], axis=-1)
+    k_i = jnp.concatenate(
+        [_rotate(k_i[:, :, None, :dr], cos, sin)[:, :, 0], k_i[..., dr:]],
+        axis=-1)
+    return q_i, k_i, dot(u, p["wi_w"]) * (J ** -0.5 * di ** -0.5)
+
+
+def index_terms(cfg, said):
+    """``Part.terms`` of a stack's index layers: ``said``'s ``kl`` and
+    ``pairs`` [Lf, b], ``causal`` and ``positions`` [Lf] -> (the term the
+    loss gains, the step's counters)."""
+    loss = (said["kl"].sum(-1) / said["positions"]).sum()
+    share = said["pairs"].sum() / said["causal"].sum()
+    return cfg.index_loss_coef * loss, {"dsa_index_loss": loss,
+                                        "dsa_pairs_chosen_share": share}
+
+
+def latent_attention_part(prefix: str = "", rope: Callable = rope_tables,
+                          window: Optional[str] = None, gate: bool = False,
+                          rescale: bool = False, index: bool = False
+                          ) -> Part:
     """Latent attention as a layer's mixer, from the config's
     ``num_heads``, ``heads_of``, ``q_lora_rank``, ``kv_lora_rank``,
-    ``qk_nope_head_dim``, ``qk_rope_head_dim`` and ``v_head_dim`` (the
-    module's docstring)."""
+    ``qk_nope_head_dim``, ``qk_rope_head_dim`` and ``v_head_dim`` under
+    ``prefix``, with ``rope(cfg, tokens) -> (cos, sin)`` made once a
+    forward; ``window`` names the config's field of a band's keys;
+    ``gate``, ``rescale`` and ``index`` are the module's docstring's."""
     def leaves(cfg):
-        h, H = cfg.hidden_size, cfg.num_heads
-        rq, rkv = cfg.q_lora_rank, cfg.kv_lora_rank
-        dn, dr, dv = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
-                      cfg.v_head_dim)
-        return {"attn_norm": Leaf((h,), "ones", ("embed",)),
-                "wq_a": Leaf((h, rq), h, ("embed", None)),
-                "q_a_norm": Leaf((rq,), "ones", (None,)),
-                "wq_b": Leaf((rq, H * (dn + dr)), rq, (None, "qkv")),
-                "wkv_a": Leaf((h, rkv + dr), h, ("embed", None)),
-                "kv_a_norm": Leaf((rkv,), "ones", (None,)),
-                "wkv_b": Leaf((rkv, H * (dn + dv)), rkv, (None, "qkv")),
-                "wo": Leaf((H * dv, h), (cfg.heads_of or H) * dv,
-                           ("qkv", "embed"))}
+        h, sz = cfg.hidden_size, sizes(cfg, prefix)
+        H, rq, rkv = sz.heads, sz.q_rank, sz.kv_rank
+        dn, dr, dv = sz.d_n, sz.d_r, sz.d_v
+        out = {"attn_norm": Leaf((h,), "ones", ("embed",)),
+               "wq_a": Leaf((h, rq), h, ("embed", None)),
+               "q_a_norm": Leaf((rq,), "ones", (None,)),
+               "wq_b": Leaf((rq, H * (dn + dr)), h if rescale else rq,
+                            (None, "qkv")),
+               "wkv_a": Leaf((h, rkv + dr), h, ("embed", None)),
+               "kv_a_norm": Leaf((rkv,), "ones", (None,)),
+               "wkv_b": Leaf((rkv, H * (dn + dv)), h if rescale else rkv,
+                             (None, "qkv")),
+               "wo": Leaf((H * dv, h), sz.heads_of * dv, ("qkv", "embed"))}
+        if gate:
+            out["wg"] = Leaf((h, H), h, ("embed", None))
+        if index:
+            J, di = cfg.index_heads, cfg.index_head_dim
+            out.update(wi_q=Leaf((rq, J * di), rq, (None, None)),
+                       wi_k=Leaf((h, di), h, ("embed", None)),
+                       wi_k_norm=Leaf((di,), "ones", (None,)),
+                       wi_k_bias=Leaf((di,), "zeros", (None,)),
+                       wi_w=Leaf((h, J), h, ("embed", None)))
+        return out
 
     def body(cfg, x, p, ctx):
         dt, eps = cfg.dtype, cfg.rms_norm_eps
         b, s, _ = x.shape
-        H, rkv = cfg.num_heads, cfg.kv_lora_rank
-        dn, dr, dv = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
-                      cfg.v_head_dim)
-        scale = softmax_scale(cfg)
+        sz = sizes(cfg, prefix)
+        H, rkv = sz.heads, sz.kv_rank
+        dn, dr, dv = sz.d_n, sz.d_r, sz.d_v
+        scale = softmax_scale(cfg, prefix)
+        seen = getattr(cfg, window) if window else None
+        more = {}
+        if window:
+            more["window"] = seen
+        if gate or rescale or index:
+            more.update(gate=gate, rescale=rescale, index=index)
         with tracing.span("rtpu.mla.shapes", keep=True, heads=H,
-                          heads_of=cfg.heads_of or H,
-                          q_lora_rank=cfg.q_lora_rank,
+                          heads_of=sz.heads_of,
+                          q_lora_rank=sz.q_rank,
                           kv_lora_rank=rkv, qk_nope_head_dim=dn,
                           qk_rope_head_dim=dr, v_head_dim=dv,
-                          softmax_scale=scale):
+                          softmax_scale=scale, **more):
             pass
 
         def dot(a, w):
             return jnp.dot(a, w.astype(dt),
                            preferred_element_type=jnp.float32).astype(dt)
 
-        cos, sin = ctx.once[rope_tables]
+        cos, sin = ctx.once[rope]
         with jax.named_scope("mla_q"):
             u = rms_norm(x, p["attn_norm"], eps)
             # the two latents before their norms are what the ladder's
             # first rung keeps of this layer (models/llama.py REMAT_LADDER):
             # the backward then runs neither down-projection again
             c_q = checkpoint_name(dot(u, p["wq_a"]), "q_latent")
-            q = dot(rms_norm(c_q, p["q_a_norm"], eps),
-                    p["wq_b"]).reshape(b, s, H, dn + dr)
+            c_qn = rms_norm(c_q, p["q_a_norm"], eps)
+            q = dot(c_qn, p["wq_b"]).reshape(b, s, H, dn + dr)
+            if rescale:
+                q = q * jnp.asarray(rescale_factor(cfg, sz.q_rank), dt)
         with jax.named_scope("mla_kv"):
             c_kv = checkpoint_name(dot(u, p["wkv_a"]), "kv_latent")
-            kv = dot(rms_norm(c_kv[..., :rkv], p["kv_a_norm"], eps),
-                     p["wkv_b"]).reshape(b, s, H, dn + dv)
+            c_kvn = rms_norm(c_kv[..., :rkv], p["kv_a_norm"], eps)
+            if rescale:
+                c_kvn = c_kvn * jnp.asarray(rescale_factor(cfg, rkv), dt)
+            kv = dot(c_kvn, p["wkv_b"]).reshape(b, s, H, dn + dv)
         with jax.named_scope("mla_rope"):
             q = jnp.concatenate(
                 [q[..., :dn], _rotate(q[..., dn:], cos, sin)], axis=-1)
@@ -139,44 +282,91 @@ def latent_attention_part() -> Part:
         k_r = checkpoint_name(k_r, "k_rope")
         k_n = checkpoint_name(kv[..., :dn], "k_rope")
         v = checkpoint_name(kv[..., dn:], "v_proj")
-        with jax.named_scope("flash"):
-            attn = _attend(cfg, q, k_n, v, k_r, scale, ctx.mesh)
+        if gate:
+            with jax.named_scope("attn_gate"):
+                g = head_gate(cfg, u, p["wg"])
+        said = {}
+        if index:
+            with jax.named_scope("dsa_proj"):
+                q_i, k_i, w_i = index_inputs(cfg, u, c_qn, p, cos, sin)
+            keep = ctx.keep_index_choice
+            attn, kl, pairs, *choice = dsa.sparse_attention(
+                q, k_n, v, k_r, q_i, k_i, w_i, scale=scale,
+                topk=cfg.index_topk, block=cfg.index_block,
+                tiers=cfg.index_tiers, mesh=ctx.mesh, keep_choice=keep)
+            said = {"dsa": {
+                "kl": kl, "pairs": pairs,
+                "causal": jnp.asarray(b * s * (s + 1) // 2, jnp.float32),
+                "positions": jnp.asarray(b * s, jnp.float32),
+                **({"choice": choice[0], "q_i": q_i, "k_i": k_i,
+                    "w": w_i} if keep else {})}}
+        else:
+            with jax.named_scope("flash"):
+                if window:
+                    with jax.named_scope("flash_window"):
+                        attn = _attend(cfg, q, k_n, v, k_r, scale, ctx.mesh,
+                                       seen)
+                else:
+                    attn = _attend(cfg, q, k_n, v, k_r, scale, ctx.mesh)
         with jax.named_scope("mla_out"):
+            if gate:
+                with jax.named_scope("attn_gate"):
+                    attn = (attn.astype(jnp.float32) * g[..., None]
+                            ).astype(dt)
             out = dot(attn.reshape(b, s, H * dv), p["wo"])
-            return checkpoint_name(x + out, "attn_resid"), {}
+            return checkpoint_name(x + out, "attn_resid"), said
 
     def keeps(cfg, shape, tokens, mesh):
-        H = shape["wo"][0] // cfg.v_head_dim
+        sz = sizes(cfg, prefix)
+        dv, dr = sz.d_v, sz.d_r
+        H = shape["wo"][0] // dv
         act = jnp.dtype(cfg.dtype).itemsize
         latents = shape["wq_a"][-1] + shape["wkv_a"][-1]
         expanded = shape["wq_b"][-1] + shape["wkv_b"][-1]
+        # the gate a head, and the index's queries, key and head weights:
+        # recomputed at every level, held by the layer's backward
+        beside = ((shape["wg"][-1] if gate else 0)
+                  + (shape["wi_q"][-1] + shape["wi_k"][-1]
+                     + 2 * shape["wi_w"][-1] if index else 0))
+        rows = 0
+        if index:
+            # one block of the walk while its backward runs: the index's
+            # products [block, J, s] float32 with their gradient, the
+            # heads' scores and probabilities [H, block, s] float32 twice
+            blk, _ = dsa.walk_plan(tokens, cfg.index_block, cfg.index_tiers)
+            rows = blk * tokens * 4 * (3 * shape["wi_w"][-1] + 4 * H)
         return kept(
-            flash=tokens * (shape["wo"][0] * act + H * 4 + latents * act),
-            qkv=tokens * (expanded + cfg.qk_rope_head_dim) * act,
+            flash=tokens * (shape["wo"][0] * act + H * 4 + latents * act)
+            if not index else tokens * latents * act,
+            qkv=tokens * (expanded + dr) * act,
             resid=tokens * cfg.hidden_size * act,
-            width=2 * (latents + expanded))
+            width=2 * (latents + expanded) + 2 * beside, rows=rows)
 
-    return Part(leaves, body, keeps, once=rope_tables)
+    return Part(leaves, body, keeps, once=rope,
+                **({"reports": "dsa", "terms": index_terms} if index else {}))
 
 
-def _attend(cfg, q, k_n, v, k_r, scale: float, mesh):
+def _attend(cfg, q, k_n, v, k_r, scale: float, mesh,
+            window: Optional[int] = None):
     """Causal attention of q [b, s, H, d_n + d_r] over keys [k_n | k_r]
     (k_n [b, s, H, d_n], k_r [b, s, d_r] shared by the heads) and values v
-    [b, s, H, d_v]: the flash kernels on a TPU, each chip its own rows of
+    [b, s, H, d_v], under a ``window`` the keys that end at the query's
+    position alone: the flash kernels on a TPU, each chip its own rows of
     the batch under a mesh, and the reference elsewhere."""
     impl = cfg.attn_impl
     if impl == "auto":
         impl = "flash" if jax.default_backend() == "tpu" else "reference"
     if impl == "reference":
         return attention_reference(q, with_shared_key(k_n, k_r), v,
-                                   causal=True, sm_scale=scale)
+                                   causal=True, sm_scale=scale,
+                                   window=window)
     if impl != "flash":
         raise ValueError(f"attn_impl={impl!r}: latent attention runs "
                          "\"flash\" or \"reference\"")
 
     def flash(q_, k_, v_, kr_):
         return flash_attention(q_, k_, v_, causal=True, sm_scale=scale,
-                               k_shared=kr_)
+                               k_shared=kr_, window=window)
 
     if mesh is None:
         return flash(q, k_n, v, k_r)

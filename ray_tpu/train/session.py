@@ -78,7 +78,7 @@ class TrainContext:
 # ``moe_rows_passed``: the rows the passes over those took, padding and
 # all, ``ops/moe.rows_passed``: held over passed is how full they were;
 # ``moe_router_bias_abs_max``: the largest selection bias of a router
-# that balances its load by one, ``models/lfm2.update_router_bias``); of
+# that balances its load by one, ``stack.Stack.update_router_bias``); of
 # selective-scan layers (``ssm_state_abs_max``: the largest ``|S|`` a scan
 # layer's state holds after a sequence's last position,
 # ``ops/ssm.mamba2_mixer``: a state that grows from step to step says the
@@ -86,11 +86,16 @@ class TrainContext:
 # (``gdn_state_abs_max``: the same of a linear layer's ``[value, key]``
 # state, ``ops/delta.gated_delta_mixer``: with ``beta`` up to 2 a state's
 # eigenvalue along a key may be negative, and a state that grows says the
-# keys have lost their unit length or the decays their float32). A new
+# keys have lost their unit length or the decays their float32); of layers
+# whose index chooses their keys (``dsa_pairs_chosen_share``: the pairs
+# chosen over the causal pairs; ``dsa_index_loss``: the layers' sum of
+# ``KL(p_t || softmax_{S_t} I)``, ``ops/mla.index_terms``: an index that
+# stops learning to rank as the attention weighs shows here first). A new
 # operator adds its counter's name here.
 STEP_COUNTERS = ("moe_rows_routed", "moe_rows_held", "moe_rows_passed",
                  "moe_expert_load_max_over_mean", "moe_router_bias_abs_max",
-                 "ssm_state_abs_max", "gdn_state_abs_max")
+                 "ssm_state_abs_max", "gdn_state_abs_max",
+                 "dsa_pairs_chosen_share", "dsa_index_loss")
 
 
 class SessionInterruptedError(BaseException):

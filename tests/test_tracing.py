@@ -929,7 +929,7 @@ def test_train_session_serves_the_last_reported_scan_counter(op):
                                        _TrainSession)
 
     assert {"ssm_state_abs_max", "gdn_state_abs_max"} <= set(STEP_COUNTERS)
-    assert len(set(STEP_COUNTERS)) == len(STEP_COUNTERS) == 7
+    assert len(set(STEP_COUNTERS)) == len(STEP_COUNTERS) == 9
 
     def loop():
         from ray_tpu import train
