@@ -20,15 +20,33 @@ L1-normalised, under ``stop_gradient``: the index learns to rank keys as
 the attention it stands in for weighs them, and nothing else receives that
 term's gradient.
 
-``sparse_attention`` walks blocks of ``block`` queries under XLA
-(``lax.map`` over a ``jax.checkpoint``ed block): a block's index scores
-``[block, S]`` float32, its choice (``choose``: an exact radix select of
-the ``topk``-th largest score, 32 compare-and-count passes, no sort) and
-its masked softmax over all heads live for that block alone, so the ``[T,
-T]`` scores never exist whole and neither does a gather of the chosen
-latents. The blocks are walked in ``tiers`` of equal length, a tier's
-blocks against the keys up to the tier's end: four tiers skip three
-eighths of the pairs the causal mask drops. The walk costs the dense
+``sparse_attention`` walks blocks of ``block`` queries (``lax.map`` over a
+``jax.checkpoint``ed block): a block's index scores ``[block, S]`` float32,
+its choice (``choose``: an exact radix select of the ``topk``-th largest
+score, 32 compare-and-count passes, no sort) and its masked softmax over
+all heads live for that block alone, so the ``[T, T]`` scores never exist
+whole and neither does a gather of the chosen latents. The blocks are
+walked in ``tiers`` of equal length, a tier's blocks against the keys up
+to the tier's end: four tiers skip three eighths of the pairs the causal
+mask drops.
+
+The scores have two forms, one equation; which runs is read from the call
+and never set (``scores_plan``, written into ``rtpu.dsa.shapes``):
+
+- ``kernel``, on a TPU backend for whole tiles (``score_kernels``): two
+  Mosaic calls behind a ``custom_vjp``. A grid step takes ``SCORE_TILE``
+  keys; the ``J`` head products of ``SCORE_ROWS`` queries with them are
+  formed on the MXU into VMEM, ``ReLU``, the weights and the sum over the
+  heads run on them there, and ``[block, tile]`` float32 is all that
+  reaches HBM. The backward forms the products again and keeps nothing
+  ``[block, J, S]`` either. The walk tells the calls where a block's
+  diagonal lies, and a tile wholly above it is not scored (zeros, which
+  nothing reads).
+- ``xla`` elsewhere (the CPU, shapes that are not whole tiles):
+  ``plain_scores``, the products ``[block, J, S]`` float32 in HBM and a
+  second pass over them. The tests' yardstick.
+
+Choice, attention over it and the term are XLA's: they cost the dense
 causal attention's FLOPs whatever the choice keeps (the mask zeroes what
 is not chosen); a kernel that visits the chosen keys alone is a later
 change and is read by the same yardstick (needed work = the chosen pairs).
@@ -41,7 +59,8 @@ is traced, ``rtpu.dsa.shapes``. Training only.
 
 from __future__ import annotations
 
-from typing import Optional, Tuple
+import functools
+from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -51,13 +70,290 @@ from ray_tpu.util import tracing
 _NEG = -1e30
 
 
-def index_scores(q_i: jax.Array, k_i: jax.Array, w: jax.Array) -> jax.Array:
-    """q_i [n, J, d], k_i [S, d], w [n, J] float32 -> ``I [n, S]`` float32:
-    ``sum_j w[., j] ReLU(q_i[., j] . k_i)``, the products accumulated in
-    float32. No mask: a caller drops the pairs its queries do not see."""
+# the scores' kernels (``score_kernels``): keys a grid step takes, and
+# queries a chunk of its body takes (all J heads of them at once: J x
+# SCORE_ROWS rows of products are in VMEM at a time). Read on the chip at
+# the cell's shapes, one layer's walk alone, forward / backward ms
+# (``tools/index_sweep.py``; PERF.md 6, PR 47): tiles of 512 by chunks of 8
+# 19.5 / 43.9, 16 17.2 / 41.6, 32 16.1 / 40.6, 64 15.5 / 39.9, 128 15.2 /
+# 39.5 (three times the VMEM and the compile); 256 by 16 20.7 / 47.2, by
+# 128 17.4 / 39.7; 1,024 by 16 16.5 / 41.8, by 64 15.7 / 40.7; 2,048 by 32
+# 16.3 / 42.9; unrolling the chunks moved nothing (15.1 / 39.4); XLA's form
+# 18.9 / 162.0
+SCORE_TILE = 512
+SCORE_ROWS = 64
+# keys a register holds along its lanes: a tile is whole registers of them
+# (tests patch it for small shapes in the interpreter)
+KERNEL_LANES = 128
+
+
+def plain_scores(q_i: jax.Array, k_i: jax.Array, w: jax.Array) -> jax.Array:
+    """``index_scores`` as XLA runs it: the heads' products ``[n, J, S]``
+    float32 whole, then ``ReLU``, the weights and the sum."""
     x = jnp.einsum("njd,sd->njs", q_i, k_i,
                    preferred_element_type=jnp.float32)
     return (jax.nn.relu(x) * w.astype(jnp.float32)[:, :, None]).sum(1)
+
+
+def scores_plan(n: int, keys: int, heads: int, dim: int) -> Dict[str, Any]:
+    """How ``index_scores`` runs ``n`` queries of ``heads`` index heads of
+    ``dim`` against ``keys`` keys: ``scores_form`` "kernel" on a TPU
+    backend (anything but the CPU) where the queries are whole chunks of
+    ``SCORE_ROWS``, ``dim`` whole lanes and the keys whole tiles, with
+    ``scores_tile`` the keys a grid step takes (the largest count of whole
+    ``KERNEL_LANES`` up to ``SCORE_TILE`` that divides the keys); "xla" and
+    no tile elsewhere."""
+    tiles = [t for t in range(KERNEL_LANES, SCORE_TILE + 1, KERNEL_LANES)
+             if keys % t == 0]
+    if (jax.default_backend() == "cpu" or not tiles or n % SCORE_ROWS
+            or dim % KERNEL_LANES):
+        return {"scores_form": "xla", "scores_tile": None}
+    return {"scores_form": "kernel", "scores_tile": tiles[-1]}
+
+
+def _scores(q_i, k_i, w, first):
+    """``index_scores``, told where the queries stand: ``first``, the
+    first one's position (int32), or None. The kernels leave a tile of keys
+    that lies wholly past the last query unscored (zeros), XLA's form
+    scores every pair."""
+    n, J, d = q_i.shape
+    plan = scores_plan(n, k_i.shape[0], J, d)
+    if plan["scores_form"] == "xla":
+        return plain_scores(q_i, k_i, w)
+    # looked up at trace time: a test hands it the interpreter
+    return score_kernels(q_i, k_i, w, first, plan["scores_tile"])
+
+
+def index_scores(q_i: jax.Array, k_i: jax.Array, w: jax.Array) -> jax.Array:
+    """q_i [n, J, d], k_i [S, d], w [n, J] float32 -> ``I [n, S]`` float32:
+    ``sum_j w[., j] ReLU(q_i[., j] . k_i)``, the products accumulated in
+    float32. No mask: a caller drops the pairs its queries do not see.
+    Which form runs is read from the call (``scores_plan``)."""
+    return _scores(q_i, k_i, w, None)
+
+
+_INDEX_SCORES = index_scores
+
+
+# ---- the scores as Pallas (Mosaic) kernels. The queries lie heads first,
+# q [J, n, d], so that a chunk's products ``[J rows, tile]`` float32 (one
+# MXU product of ``J x rows`` rows against the tile's keys) are ``J`` slabs
+# of ``[rows, tile]`` and the sum over the heads adds registers and turns
+# nothing. The head weights are spread along the lanes once a call (``wb
+# [J, n, lanes]``, scratch). The grid walks the tiles of keys; the block of
+# queries stays in VMEM. ``first`` (SMEM) says where the first query stands:
+# a tile past the last query is not scored.
+
+
+def _nt(a, b):
+    """a [m, d], b [n, d] -> a b^T [m, n], float32."""
+    return jax.lax.dot_general(a, b, (((1,), (1,)), ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
+def _spread_weights(w_ref, wb_ref):
+    """w [n, J] -> wb [J, n, lanes], a head's weights down the sublanes
+    and the same along every lane."""
+    for j in range(w_ref.shape[1]):
+        wb_ref[j] = jnp.broadcast_to(w_ref[:, j:j + 1], wb_ref.shape[1:])
+
+
+def _scores_fwd_kernel(first_ref, q_ref, k_ref, w_ref, o_ref, wb_ref, *,
+                       rows):
+    import jax.experimental.pallas as pl
+
+    J, n, d = q_ref.shape
+    tile, lanes = k_ref.shape[0], wb_ref.shape[-1]
+    t = pl.program_id(0)
+    seen = t * tile < first_ref[0] + n
+
+    @pl.when(t == 0)
+    def _():
+        _spread_weights(w_ref, wb_ref)
+
+    @pl.when(seen)
+    def _():
+        k = k_ref[...]
+
+        def chunk(c, carry):
+            at = pl.ds(pl.multiple_of(c * rows, rows), rows)
+            x = jnp.maximum(
+                _nt(q_ref[:, at, :].reshape(J * rows, d), k), 0.0
+            ).reshape(J, rows, tile)
+            wb = wb_ref[:, at, :]
+            o_ref[at, :] = jnp.concatenate(
+                [(x[:, :, i:i + lanes] * wb).sum(0)
+                 for i in range(0, tile, lanes)], axis=-1)
+            return carry
+
+        jax.lax.fori_loop(0, n // rows, chunk, 0)
+
+    @pl.when(jnp.logical_not(seen))
+    def _():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+
+def _scores_bwd_kernel(first_ref, q_ref, k_ref, w_ref, g_ref, dq_ref, dk_ref,
+                       dw_ref, wb_ref, dwb_ref, dk_acc, *, rows):
+    """A tile of keys of the backward: the products again, ``y = g w (x >
+    0)`` to the MXU in the inputs' dtype, ``dq += y k`` (the output's block,
+    float32, in VMEM all through the call), ``dk = y^T q`` summed over the
+    chunks, and ``dw``'s terms ``g ReLU(x)`` added lane by lane (``dwb``),
+    the lanes summed after the last tile."""
+    import jax.experimental.pallas as pl
+
+    J, n, d = q_ref.shape
+    tile, lanes = k_ref.shape[0], wb_ref.shape[-1]
+    t = pl.program_id(0)
+    seen = t * tile < first_ref[0] + n
+
+    @pl.when(t == 0)
+    def _():
+        _spread_weights(w_ref, wb_ref)
+        dq_ref[...] = jnp.zeros_like(dq_ref)
+        dwb_ref[...] = jnp.zeros_like(dwb_ref)
+
+    @pl.when(seen)
+    def _():
+        k = k_ref[...]
+        dk_acc[...] = jnp.zeros_like(dk_acc)
+
+        def chunk(c, carry):
+            at = pl.ds(pl.multiple_of(c * rows, rows), rows)
+            q = q_ref[:, at, :].reshape(J * rows, d)
+            x = _nt(q, k).reshape(J, rows, tile)
+            g, wb = g_ref[at, :], wb_ref[:, at, :]
+            ys, terms = [], 0.0
+            for i in range(0, tile, lanes):
+                xi = x[:, :, i:i + lanes]
+                gi = jnp.where(xi > 0, g[:, i:i + lanes][None], 0.0)
+                ys.append((gi * wb).astype(q.dtype))
+                terms = terms + gi * xi
+            dwb_ref[:, at, :] += terms
+            y = jnp.concatenate(ys, axis=-1).reshape(J * rows, tile)
+            dq_ref[:, at, :] += jnp.dot(
+                y, k, preferred_element_type=jnp.float32
+            ).reshape(J, rows, d)
+            dk_acc[...] += jax.lax.dot_general(
+                y, q, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            return carry
+
+        jax.lax.fori_loop(0, n // rows, chunk, 0)
+        dk_ref[...] = dk_acc[...].astype(dk_ref.dtype)
+
+    @pl.when(jnp.logical_not(seen))
+    def _():
+        dk_ref[...] = jnp.zeros_like(dk_ref)
+
+    @pl.when(t == pl.num_programs(0) - 1)
+    def _():
+        head = jax.lax.broadcasted_iota(jnp.int32, dw_ref.shape, 1)
+        dw = jnp.zeros(dw_ref.shape, jnp.float32)
+        for j in range(J):
+            dw = jnp.where(head == j, dwb_ref[j].sum(-1, keepdims=True), dw)
+        dw_ref[...] = dw
+
+
+def _score_specs(q, k, tile):
+    """What both calls share: the grid (tiles of keys) and the operands'
+    blocks."""
+    import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    J, n, d = q.shape
+    return {
+        "grid": (k.shape[0] // tile,),
+        "first": pl.BlockSpec(memory_space=pltpu.SMEM),
+        "q": pl.BlockSpec((J, n, d), lambda t: (0, 0, 0)),
+        "k": pl.BlockSpec((tile, d), lambda t: (t, 0)),
+        "w": pl.BlockSpec((n, J), lambda t: (0, 0)),
+        "scores": pl.BlockSpec((n, tile), lambda t: (0, t)),
+        "wb": pltpu.VMEM((J, n, KERNEL_LANES), jnp.float32),
+        "params": pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",), vmem_limit_bytes=100 << 20)}
+
+
+def _scores_forward(first, q, k, w, tile, interpret):
+    import jax.experimental.pallas as pl
+
+    at = _score_specs(q, k, tile)
+    return pl.pallas_call(
+        functools.partial(_scores_fwd_kernel, rows=SCORE_ROWS),
+        name="dsa_scores_fwd",
+        out_shape=jax.ShapeDtypeStruct((q.shape[1], k.shape[0]),
+                                       jnp.float32),
+        grid=at["grid"],
+        in_specs=[at["first"], at["q"], at["k"], at["w"]],
+        out_specs=at["scores"], scratch_shapes=[at["wb"]],
+        compiler_params=at["params"], interpret=interpret,
+    )(first, q, k, w)
+
+
+def _scores_backward(first, q, k, w, g, tile, interpret):
+    import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    at = _score_specs(q, k, tile)
+    f32 = jnp.float32
+    return pl.pallas_call(
+        functools.partial(_scores_bwd_kernel, rows=SCORE_ROWS),
+        name="dsa_scores_bwd",
+        out_shape=[jax.ShapeDtypeStruct(q.shape, f32),
+                   jax.ShapeDtypeStruct(k.shape, k.dtype),
+                   jax.ShapeDtypeStruct(w.shape, f32)],
+        grid=at["grid"],
+        in_specs=[at["first"], at["q"], at["k"], at["w"], at["scores"]],
+        out_specs=[at["q"], at["k"], at["w"]],
+        scratch_shapes=[at["wb"], at["wb"],
+                        pltpu.VMEM((tile, k.shape[1]), f32)],
+        compiler_params=at["params"], interpret=interpret,
+    )(first, q, k, w, g)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def _score_calls(first, q, k, w, tile, interpret):
+    return _scores_forward(first, q, k, w, tile, interpret)
+
+
+def _score_calls_fwd(first, q, k, w, tile, interpret):
+    # the inputs alone are kept: the backward forms the products again
+    return (_scores_forward(first, q, k, w, tile, interpret),
+            (first, q, k, w))
+
+
+def _score_calls_bwd(tile, interpret, res, g):
+    # the barrier keeps the call a call: XLA otherwise folds the walk's
+    # update of its stacked ``dw`` into it, and the fusion it makes of both
+    # loses the call's VMEM limit ("scoped allocation ... limit 16.00M")
+    dq, dk, dw = jax.lax.optimization_barrier(
+        _scores_backward(*res, g, tile, interpret))
+    return None, dq.astype(res[1].dtype), dk, dw
+
+
+_score_calls.defvjp(_score_calls_fwd, _score_calls_bwd)
+
+
+def score_kernels(q_i, k_i, w, first, tile: int, interpret: bool = False):
+    """``index_scores`` as two Mosaic calls, ``dsa_scores_fwd`` and
+    ``dsa_scores_bwd`` behind a ``custom_vjp``: the products of a chunk of
+    queries with a tile of keys, all heads', live in VMEM alone, and ``[n,
+    S]`` float32 is written once. The products are the MXU's of the arrays
+    as they are, float32 sums; ``ReLU``, the weights and the sum over the
+    heads are float32 in a fixed order of the heads, so a pair's score does
+    not depend on the block or the tile it was scored in. The backward
+    keeps the three inputs and takes the cotangent ``g [n, S]`` float32:
+    ``y = g w (x > 0)`` goes to the MXU in the inputs' dtype (as XLA's
+    default precision sends the float32 cotangent), ``dq_i = y k_i``,
+    ``dk_i = y^T q_i``, ``dw = sum_s g ReLU(x)`` float32 throughout.
+    ``first``: the first query's position, int32 (None: every tile is
+    scored); a tile that starts past the last query is zeros forward and
+    adds nothing backward, whatever ``g`` holds there."""
+    f32 = jnp.float32
+    first = jnp.asarray(k_i.shape[0] if first is None else first,
+                        jnp.int32).reshape(1)
+    return _score_calls(first, jnp.swapaxes(q_i, 0, 1), k_i, w.astype(f32),
+                        tile, interpret)
 
 
 def choose(scores: jax.Array, first_q, topk: int) -> jax.Array:
@@ -123,7 +419,14 @@ def _walk(q, k_n, v, k_r, q_i, k_i, w, *, scale: float, topk: int,
         q_b, qi_b, w_b, first = args
         kn_t, v_t, kr_t, ki_t = keys
         with jax.named_scope("dsa_scores"):
-            index = index_scores(qi_b, ki_t, w_b)             # [block, S']
+            # [block, S']. ``index_scores`` is looked up as the block is
+            # traced: a control of benchmark/tests/sparse_limits.py replaces
+            # it and is called as it stands; the module's own is told where
+            # the block's diagonal lies (what lies past it is never read:
+            # ``choose`` masks by position, the term reads under ``chosen``)
+            index = (_scores(qi_b, ki_t, w_b, first)
+                     if index_scores is _INDEX_SCORES
+                     else index_scores(qi_b, ki_t, w_b))
         with jax.named_scope("dsa_select"):
             chosen = choose(jax.lax.stop_gradient(index), first, topk)
         with jax.named_scope("flash_sparse"):
@@ -185,6 +488,7 @@ def sparse_attention(q, k_n, v, k_r, q_i, k_i, w, *, scale: float,
     with tracing.span("rtpu.dsa.shapes", keep=True,
                       index_heads=q_i.shape[2], index_head_dim=q_i.shape[3],
                       topk=topk, positions=s, block=blk, tiers=trs,
+                      **scores_plan(blk, s // trs, *q_i.shape[2:]),
                       pairs_scored=b * s * (s + 1) // 2,
                       pairs_chosen=b * sum(min(t + 1, topk)
                                            for t in range(s))):

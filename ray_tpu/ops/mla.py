@@ -330,11 +330,16 @@ def latent_attention_part(prefix: str = "", rope: Callable = rope_tables,
                      + 2 * shape["wi_w"][-1] if index else 0))
         rows = 0
         if index:
-            # one block of the walk while its backward runs: the index's
-            # products [block, J, s] float32 with their gradient, the
-            # heads' scores and probabilities [H, block, s] float32 twice
-            blk, _ = dsa.walk_plan(tokens, cfg.index_block, cfg.index_tiers)
-            rows = blk * tokens * 4 * (3 * shape["wi_w"][-1] + 4 * H)
+            # one block of the walk while its backward runs: the heads'
+            # scores and probabilities [H, block, s] float32 twice, and the
+            # index's scores as their form holds them: XLA's the products
+            # [block, J, s] float32 with their gradient, the kernels' (whose
+            # products stay in VMEM) [block, s] float32 with its gradient
+            blk, trs = dsa.walk_plan(tokens, cfg.index_block, cfg.index_tiers)
+            J, di = shape["wi_w"][-1], shape["wi_k"][-1]
+            form = dsa.scores_plan(blk, tokens // trs, J, di)["scores_form"]
+            rows = blk * tokens * 4 * ((3 * J if form == "xla" else 2)
+                                       + 4 * H)
         return kept(
             flash=tokens * (shape["wo"][0] * act + H * 4 + latents * act)
             if not index else tokens * latents * act,

@@ -482,6 +482,10 @@ def test_the_steps_scopes_span_and_counters():
          a["pairs_scored"], a["pairs_chosen"])
         == (4, 16, 8, 48, 2 * 48 * 49 // 2, 2 * (36 + 40 * 8))
         for a in spans)
+    # on the CPU the scores are XLA's form (tests/test_dsa_kernels.py has
+    # the kernels')
+    assert all((a["block"], a["tiers"], a["scores_form"], a["scores_tile"])
+               == (16, 3, "xla", None) for a in spans)
     assert lowered.out_info[3].shape == (3, 16)
     assert set(lowered.out_info[4]) == {
         "cross_entropy", "dsa_index_loss", "dsa_pairs_chosen_share",

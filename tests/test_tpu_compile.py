@@ -749,3 +749,37 @@ def test_flash_kernels_compile_at_keys_of_192_and_values_of_128(
     # a query, a key or their gradient
     for _, line in calls:
         assert "8192,256]" not in line
+
+
+@pytest.mark.parametrize("block", [128, 256], ids=["the-walks-block",
+                                                   "the-checks-block"])
+def test_index_score_kernels_compile_at_the_cells_shapes(
+        S, one_chip, no_compile_cache, monkeypatch, block):
+    """The index's scores at ``train-dots3-1chip``'s shapes (64 heads of
+    128 against 16,384 keys, bfloat16; the head weights float32) for the
+    walk's blocks of 128 queries and the check's of 256, on a TPU
+    backend: Mosaic takes the forward call, and the backward call of the
+    three gradients; nothing ``[block, 64, keys]`` is left in the
+    program."""
+    from ray_tpu.ops import dsa
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert dsa.scores_plan(block, 16384, 64, 128) == {
+        "scores_form": "kernel", "scores_tile": dsa.SCORE_TILE}
+    args = (S(block, 64, 128), S(16384, 128),
+            jax.ShapeDtypeStruct((block, 64), jnp.float32,
+                                 sharding=one_chip))
+    forward = jax.jit(dsa.index_scores).lower(*args).compile().as_text()
+    assert [name for name, _ in _mosaic_calls(forward)] == ["dsa_scores_fwd"]
+
+    def loss(*a):
+        return jnp.square(dsa.index_scores(*a)).sum()
+
+    gradient = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        *args).compile().as_text()
+    names = [name for name, _ in _mosaic_calls(gradient)]
+    assert len(names) == 2, names        # (named after the transformation)
+    for kernel in ("dsa_scores_fwd", "dsa_scores_bwd"):
+        assert any(kernel in name for name in names), (kernel, names)
+    for text in (forward, gradient):
+        assert f"[{block},64,16384]" not in text
