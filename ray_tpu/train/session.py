@@ -17,6 +17,7 @@ from dataclasses import dataclass, field
 from typing import Any, Dict, Optional
 
 from ray_tpu.train.checkpoint import Checkpoint
+from ray_tpu.train.pulse import LoopPulse
 from ray_tpu.util import tracing
 
 _session: Optional["_TrainSession"] = None
@@ -135,6 +136,9 @@ class _TrainSession:
         self._reports = 0
         # the last reported value of each of STEP_COUNTERS
         self._counters: Dict[str, float] = {}
+        # times every wait of the loop's thread from beside it
+        # (train/pulse.py); its counts are served with the session's
+        self._pulse = pulse = LoopPulse(context.trial_name)
         import weakref
 
         from ray_tpu import metrics
@@ -142,20 +146,36 @@ class _TrainSession:
         me = weakref.ref(self)     # the registry keeps no session alive
         metrics.REGISTRY.register_source("rtpu_train", lambda: {
             "reports": me()._reports, "checkpoints": me()._ckpt_index,
-            "world_rank": me().context.world_rank, **me()._counters})
+            "world_rank": me().context.world_rank, **me()._counters,
+            "loop_waits": pulse.rhythm.main_waits,
+            "loop_stalls": pulse.rhythm.stalls,
+            "loop_stalled_seconds": pulse.rhythm.stalled_ns / 1e9,
+            "proc_paused_seconds": pulse.rhythm.paused_ns / 1e9})
 
         def runner():
+            error = None
+            loop = tracing.span("rtpu.train.loop", id=context.trial_name,
+                                keep=True)
             try:
-                train_fn(config) if _wants_config(train_fn) else train_fn()
-                self._result_q.put(TrainingResult(metrics={}, done=True))
+                pulse.start()
+                with loop:
+                    try:
+                        train_fn(config) if _wants_config(train_fn) \
+                            else train_fn()
+                    finally:
+                        # the pulse has ended and the span is closed
+                        # before the done sentinel is put: a driver that
+                        # collects the gang's spans after it finds both
+                        loop.attrs.update(pulse.stop())
             except BaseException as e:  # surfaced to the driver, not swallowed
                 # Includes SessionInterruptedError: the queue may still
                 # hold the result the interrupt overtook, but the driver
                 # drains every queued result until it sees this done
                 # sentinel, so the blocking put always completes — and
                 # the sentinel is never dropped.
-                self._result_q.put(
-                    TrainingResult(metrics={}, done=True, error=e))
+                error = e
+            self._result_q.put(
+                TrainingResult(metrics={}, done=True, error=error))
 
         self._thread = threading.Thread(target=runner, daemon=True,
                                         name="rtpu-train-loop")

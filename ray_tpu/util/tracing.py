@@ -179,6 +179,13 @@ def record(name: str, start_wall_s: float, end_wall_s: float, *, keep: bool,
             parent.name if parent is not None else None, id, attrs))
 
 
+def wall_s(monotonic_ns: int) -> float:
+    """A ``time.monotonic_ns`` reading on the wall clock of this process's
+    anchor, for ``record``: an event timed by the monotonic clock lands
+    where a ``span`` that read the same clock would."""
+    return _ANCHOR_WALL + (monotonic_ns - _ANCHOR_NS) / 1e9
+
+
 def _on_jax_event(event: str, **_kw: Any) -> None:
     # the cache's events fire inside the backend compile's span, on its
     # thread, and are folded into it when it closes
