@@ -30,37 +30,53 @@ walked in ``tiers`` of equal length, a tier's blocks against the keys up
 to the tier's end: four tiers skip three eighths of the pairs the causal
 mask drops.
 
-The scores have two forms, one equation; which runs is read from the call
-and never set (``scores_plan``, written into ``rtpu.dsa.shapes``):
+The scores and the attention over the choice have two forms each, one
+equation; which runs is read from the call and never set (``scores_plan``,
+``attend_plan``, written into ``rtpu.dsa.shapes``):
 
-- ``kernel``, on a TPU backend for whole tiles (``score_kernels``): two
-  Mosaic calls behind a ``custom_vjp``. A grid step takes ``SCORE_TILE``
-  keys; the ``J`` head products of ``SCORE_ROWS`` queries with them are
-  formed on the MXU into VMEM, ``ReLU``, the weights and the sum over the
-  heads run on them there, and ``[block, tile]`` float32 is all that
-  reaches HBM. The backward forms the products again and keeps nothing
-  ``[block, J, S]`` either. The walk tells the calls where a block's
-  diagonal lies, and a tile wholly above it is not scored (zeros, which
+- ``kernel``, on a TPU backend for whole tiles. The scores
+  (``score_kernels``): two Mosaic calls behind a ``custom_vjp``. A grid
+  step takes ``SCORE_TILE`` keys; the ``J`` head products of
+  ``SCORE_ROWS`` queries with them are formed on the MXU into VMEM,
+  ``ReLU``, the weights and the sum over the heads run on them there, and
+  ``[block, tile]`` float32 is all that reaches HBM. The backward forms the
+  products again and keeps nothing ``[block, J, S]`` either. The attention
+  (``attend_kernels``): two more, on arrays the walk turns heads first once
+  for all its blocks. A grid step takes ``ATTEND_TILE`` keys of every
+  head; a head's scores of them ``[tile, block]`` float32 (keys down: the
+  MXU holds the block's queries and the keys stream past them), the choice
+  as their mask, the softmax and ``p`` live in VMEM alone. The forward
+  walks the tiles twice, once for each head's maximum and sum a query and
+  once for ``out`` and the heads' summed probabilities ``[block, S]``
+  float32, which is what the index's term reads (``kl_target``, with a
+  leading axis of one); the backward forms the products again from
+  ``out`` and the queries' log-sum-exp and sums the rope key's gradient
+  over the heads itself. The walk tells all four calls where a block's
+  diagonal lies, and a tile wholly above it is not visited (zeros, which
   nothing reads).
 - ``xla`` elsewhere (the CPU, shapes that are not whole tiles):
   ``plain_scores``, the products ``[block, J, S]`` float32 in HBM and a
-  second pass over them. The tests' yardstick.
+  second pass over them; ``plain_attend``, the heads' scores and
+  probabilities ``[H, block, S]`` float32 in HBM. The tests' yardstick.
 
-Choice, attention over it and the term are XLA's: they cost the dense
-causal attention's FLOPs whatever the choice keeps (the mask zeroes what
-is not chosen); a kernel that visits the chosen keys alone is a later
-change and is read by the same yardstick (needed work = the chosen pairs).
+The choice (``choose``) and the term are XLA's. The attention costs the
+dense causal attention's FLOPs whatever the choice keeps (the mask zeroes
+what is not chosen: with ``topk`` of at most 16,384 keys chosen by each of
+128 queries no tile is empty for a whole block); it is read by the yardstick
+of the needed work, the chosen pairs.
 
 Named scopes: ``dsa_scores`` (the index's scores), ``dsa_select`` (the
-choice), ``flash_sparse`` (scores, masked softmax and PV of the attention
-over the choice), ``dsa_loss`` (the index's term). One kept span as the op
+choice), ``flash_sparse`` (the attention over the choice: its two calls, a
+block's ``delta`` and the walk's turns of its arrays to the kernels' layout,
+or XLA's scores, masked softmax and PV), ``dsa_loss`` (the index's term). One kept span as the op
 is traced, ``rtpu.dsa.shapes``. Training only.
 """
 
 from __future__ import annotations
 
 import functools
-from typing import Any, Dict, Optional, Tuple
+import math
+from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -82,6 +98,29 @@ _NEG = -1e30
 # 18.9 / 162.0
 SCORE_TILE = 512
 SCORE_ROWS = 64
+# the attention's kernels (``attend_kernels``): keys a grid step takes (all
+# heads' keys and values of them are in VMEM), keys of them a head's
+# products take at a time, and heads a step of the heads' loop lays out as
+# straight-line code. Read on the chip at the cell's shapes (16 heads, keys
+# of 128 | 64, values of 128, blocks of 128 queries in four tiers, bfloat16),
+# one layer's walk alone, forward / forward + the blocks' forward again +
+# backward ms, and what a step spends over two layers (``tools/
+# index_sweep.py --attend``; PERF.md 6, PR 49): XLA's form 86.1 / 207.5, 587
+# a step. Tiles of 512 whole, the loop not unrolled 42.8 / 115.3, 316; two
+# heads a step 39.9 / 110.3, 301; four 35.1 / 99.2, 269; eight 34.5 / 94.3,
+# 258; sixteen 33.5 / 91.7, 250: a head's products of 128 queries are bound
+# by the MXU's latency a product and not by its rate, and straight-line
+# code lets the scheduler overlap one head's products with another's
+# softmax. By chunks of 256 keys 58.6 / 137.5, by 128 93.4 / 183.6 (every
+# chunk pays that latency again); tiles of 256 58.3 / 135.3 (the only
+# setting whose blocks fit Mosaic's default 16 MB), of 1,024 31.2 / 101.6,
+# 266, of 2,048 36.7 / 132.5. Queries down and keys across (the keys held,
+# the queries streamed) 41.4 / 132.0 at chunks of 128 queries, 64.4 / 225.3
+# at 64. Blocks of 256 queries (``index_block``, not this module's) read
+# 22.0 / 65.5, 175 a step, of 512 24.0 / 60.9
+ATTEND_TILE = 512
+ATTEND_ROWS = 512
+ATTEND_UNROLL = 16
 # keys a register holds along its lanes: a tile is whole registers of them
 # (tests patch it for small shapes in the interpreter)
 KERNEL_LANES = 128
@@ -356,6 +395,409 @@ def score_kernels(q_i, k_i, w, first, tile: int, interpret: bool = False):
                         tile, interpret)
 
 
+# ---- attention over the choice as Pallas (Mosaic) kernels. Queries, keys
+# and values lie heads first (q [H, n, d_n + d_r], k_n [H, S, d_n], v [H, S,
+# d_v]; the rope key k_r [S, d_r] is the heads' one), so a head's operands
+# are whole slabs. The grid walks the tiles of keys, all heads of a tile in
+# one step; a head's scores ``[rows, tile]`` float32 live in VMEM alone.
+# ``first`` (a prefetched scalar) says where the first query stands: a tile
+# past the last query is neither fetched nor scored.
+
+
+def plain_attend(q_b, kn_t, v_t, kr_t, chosen, scale: float):
+    """Attention of a block over its choice as XLA runs it: q_b [n, H, d_n
+    + d_r], kn_t [S, H, d_n], v_t [S, H, d_v], kr_t [S, d_r], chosen bool
+    [n, S] -> (out [n, H, d_v], the heads' probabilities [H, n, S] float32
+    whole)."""
+    dn = kn_t.shape[-1]
+    sc = (jnp.einsum("qhd,khd->hqk", q_b[..., :dn], kn_t,
+                     preferred_element_type=jnp.float32)
+          + jnp.einsum("qhd,kd->hqk", q_b[..., dn:], kr_t,
+                       preferred_element_type=jnp.float32)) * scale
+    p = jax.nn.softmax(jnp.where(chosen[None], sc, _NEG), axis=-1)
+    out = jnp.einsum("hqk,khd->qhd", p.astype(v_t.dtype), v_t,
+                     preferred_element_type=jnp.float32).astype(q_b.dtype)
+    return out, p
+
+
+def attend_plan(n: int, keys: int, d_n: int, d_v: int) -> Dict[str, Any]:
+    """How a block of ``n`` queries attends over its choice among ``keys``
+    keys of ``d_n`` lanes beside the rope's and values of ``d_v``:
+    ``attend_form`` "kernel" on a TPU backend (anything but the CPU) where
+    the queries are whole ``KERNEL_LANES`` (the kernels lay them along the
+    lanes), both widths whole lanes and the keys whole tiles, with
+    ``attend_tile`` the keys a grid step takes (the largest count of whole
+    lanes up to ``ATTEND_TILE`` that divides the keys, in whole chunks of
+    ``ATTEND_ROWS`` where it is more than one); "xla" and no tile
+    elsewhere."""
+    tiles = [t for t in range(KERNEL_LANES, ATTEND_TILE + 1, KERNEL_LANES)
+             if keys % t == 0 and t % min(ATTEND_ROWS, t) == 0]
+    if (jax.default_backend() == "cpu" or not tiles or n % KERNEL_LANES
+            or d_n % KERNEL_LANES or d_v % KERNEL_LANES):
+        return {"attend_form": "xla", "attend_tile": None}
+    return {"attend_form": "kernel", "attend_tile": tiles[-1]}
+
+
+def _last_tile(first_ref, n: int, tile: int):
+    """The last tile of keys that holds a pair some query of the block
+    sees (``first_ref``: the prefetched position of the first query)."""
+    return (first_ref[0] + n - 1) // tile
+
+
+def _bias_turned(chosen_ref):
+    """chosen [n, tile] -> [tile, n] float32: 0 on the chosen pairs,
+    ``_NEG`` off them."""
+    c = chosen_ref[...].astype(jnp.int32)
+    return jnp.where(c != 0, 0.0, _NEG).astype(jnp.float32).T
+
+
+def _scores_turned(q, kn, kr, bias, scale, dn):
+    """A head's scores of ``rows`` keys, keys down and queries across
+    [rows, n] float32: the MXU holds the queries and the keys stream."""
+    return (_nt(kn, q[:, :dn]) + _nt(kr, q[:, dn:])) * scale + bias
+
+
+def _over_heads(H: int, body, init, unroll: int):
+    """``body(h, carry)`` over the heads, ``unroll`` of them a step of the
+    loop: traced once, laid out as straight-line code as the call is
+    lowered, which the scheduler may overlap."""
+    u = math.gcd(H, unroll)
+
+    def step(i, carry):
+        return jax.lax.fori_loop(
+            0, u, lambda j, c: body(i * u + j, c), carry, unroll=True)
+
+    return jax.lax.fori_loop(0, H // u, step, init)
+
+
+def _row(ref, h):
+    """Row ``h`` of a [H, n] block of rows' statistics, [1, n]."""
+    import jax.experimental.pallas as pl
+
+    return ref[pl.ds(h, 1), :]
+
+
+def _attend_fwd_kernel(first_ref, q_ref, kn_ref, kr_ref, vt_ref, chosen_ref,
+                       ot_ref, lse_ref, ps_ref, m_ref, l_ref, acc_ref,
+                       bias_ref, *, scale, rows, unroll):
+    """A grid step ``(phase, tile)``, keys down and queries across. Phase
+    0: every head's scores of the tile, the running maximum ``m`` and sum
+    ``l`` of each query [H, n]. Phase 1: the scores again, ``p = exp(s - m)
+    / l``, ``acc += v^T p`` [H, d_v, n] and the heads' sum of ``p``, turned,
+    written to the tile's block of ``ps``."""
+    import jax.experimental.pallas as pl
+
+    H, n, _ = q_ref.shape
+    tile, dn = kn_ref.shape[1:]
+    phase, t = pl.program_id(0), pl.program_id(1)
+    seen = t <= _last_tile(first_ref, n, tile)
+    chunks = [slice(i, i + rows) for i in range(0, tile, rows)]
+
+    def scores(h, at):
+        return _scores_turned(q_ref[h], kn_ref[h, at, :], kr_ref[at, :],
+                              bias_ref[at, :], scale, dn)
+
+    def heads(body, init=0):
+        return _over_heads(H, body, init, unroll)
+
+    @pl.when((phase == 0) & (t == 0))
+    def _():
+        m_ref[...] = jnp.full_like(m_ref, _NEG)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    @pl.when(seen)
+    def _():
+        bias_ref[...] = _bias_turned(chosen_ref)
+
+    @pl.when((phase == 0) & seen)
+    def _():
+        for at in chunks:
+            def head(h, carry, at=at):
+                s = scores(h, at)
+                m = _row(m_ref, h)
+                m_new = jnp.maximum(m, s.max(0, keepdims=True))
+                l_ref[pl.ds(h, 1), :] = (
+                    _row(l_ref, h) * jnp.exp(m - m_new)
+                    + jnp.exp(s - m_new).sum(0, keepdims=True))
+                m_ref[pl.ds(h, 1), :] = m_new
+                return carry
+
+            heads(head)
+
+    @pl.when((phase == 1) & (t == 0))
+    def _():
+        # ``l`` is held inverted through phase 1
+        lse_ref[...] = m_ref[...] + jnp.log(l_ref[...])
+        l_ref[...] = 1.0 / l_ref[...]
+
+    @pl.when((phase == 1) & seen)
+    def _():
+        for at in chunks:
+            def head(h, total, at=at):
+                p = jnp.exp(scores(h, at) - _row(m_ref, h)) * _row(l_ref, h)
+                acc_ref[h] += jnp.dot(
+                    vt_ref[h, :, at], p.astype(vt_ref.dtype),
+                    preferred_element_type=jnp.float32)
+                return total + p
+
+            ps_ref[:, at] = heads(head, jnp.zeros((rows, n), jnp.float32)).T
+
+    @pl.when((phase == 1) & jnp.logical_not(seen))
+    def _():
+        ps_ref[...] = jnp.zeros_like(ps_ref)
+
+    @pl.when((phase == 1) & (t == pl.num_programs(1) - 1))
+    def _():
+        ot_ref[...] = acc_ref[...].astype(ot_ref.dtype)
+
+
+def _attend_bwd_kernel(first_ref, q_ref, kn_ref, kr_ref, v_ref, chosen_ref,
+                       dot_ref, lse_ref, delta_ref, dq_ref, dkn_ref, dkr_ref,
+                       dv_ref, bias_ref, dq_acc, *, scale, rows, unroll):
+    """A tile of keys of the backward, keys down and queries across as the
+    forward: a head's scores of ``rows`` keys again, ``p = exp(s - lse)``,
+    ``dv = p dO``, ``dS = p (v dO^T - delta) scale`` to the MXU in the
+    inputs' dtype, ``dk_n = dS q_n``, ``dk_r`` summed over the heads, ``dq
+    += dS^T [k_n | k_r]`` (float32, in VMEM through the call)."""
+    import jax.experimental.pallas as pl
+
+    H, n, _ = q_ref.shape
+    tile, dn = kn_ref.shape[1:]
+    t = pl.program_id(0)
+    seen = t <= _last_tile(first_ref, n, tile)
+
+    @pl.when(t == 0)
+    def _():
+        dq_acc[...] = jnp.zeros_like(dq_acc)
+
+    @pl.when(seen)
+    def _():
+        bias_ref[...] = _bias_turned(chosen_ref)
+        for at in [slice(i, i + rows) for i in range(0, tile, rows)]:
+            kr = kr_ref[at, :]
+
+            def head(h, dkr, at=at, kr=kr):
+                q, dot = q_ref[h], dot_ref[h]
+                kn, v = kn_ref[h, at, :], v_ref[h, at, :]
+                p = jnp.exp(_scores_turned(q, kn, kr, bias_ref[at, :], scale,
+                                           dn) - _row(lse_ref, h))
+                dv_ref[h, at, :] = _nt(p.astype(dot.dtype), dot
+                                       ).astype(dv_ref.dtype)
+                ds = p * (jnp.dot(v, dot, preferred_element_type=jnp.float32)
+                          - _row(delta_ref, h)) * scale
+                ds_t = ds.T.astype(q.dtype)
+                ds = ds.astype(q.dtype)
+                dkn_ref[h, at, :] = jnp.dot(
+                    ds, q[:, :dn], preferred_element_type=jnp.float32
+                ).astype(dkn_ref.dtype)
+                dq_acc[h, :, :dn] += jnp.dot(
+                    ds_t, kn, preferred_element_type=jnp.float32)
+                dq_acc[h, :, dn:] += jnp.dot(
+                    ds_t, kr, preferred_element_type=jnp.float32)
+                return dkr + jnp.dot(ds, q[:, dn:],
+                                     preferred_element_type=jnp.float32)
+
+            dkr_ref[at, :] = _over_heads(
+                H, head, jnp.zeros((rows, kr.shape[1]), jnp.float32), unroll
+            ).astype(dkr_ref.dtype)
+
+    @pl.when(jnp.logical_not(seen))
+    def _():
+        dkn_ref[...] = jnp.zeros_like(dkn_ref)
+        dkr_ref[...] = jnp.zeros_like(dkr_ref)
+        dv_ref[...] = jnp.zeros_like(dv_ref)
+
+    @pl.when(t == pl.num_programs(0) - 1)
+    def _():
+        dq_ref[...] = dq_acc[...].astype(dq_ref.dtype)
+
+
+def _vmem(blocks, scratch, grid_dims: int):
+    """Compiler parameters of a call with these blocks (twice in VMEM, for
+    the pipeline) and scratch, (shape, dtype) each: Mosaic's default 16 MB
+    where they fit beside a tile's temporaries, what they take and 8 MB
+    else."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    need = (2 * sum(_padded(*b) for b in blocks)
+            + sum(_padded(*b) for b in scratch))
+    return pltpu.CompilerParams(
+        dimension_semantics=("arbitrary",) * grid_dims,
+        **({} if need + (4 << 20) <= 16 << 20
+           else {"vmem_limit_bytes": need + (8 << 20)}))
+
+
+def _padded(shape, dtype) -> int:
+    """Bytes of a block in VMEM: its last dim in whole 128 lanes."""
+    size = jnp.dtype(dtype).itemsize
+    for d in shape[:-1]:
+        size *= d
+    return size * -(-shape[-1] // 128) * 128
+
+
+def _last_seen(n: int, tile: int):
+    """Index maps' clamp: a tile past the last one seen is not fetched
+    (the block index stands)."""
+    return lambda t, first: jnp.minimum(t, _last_tile(first, n, tile))
+
+
+class _How(NamedTuple):
+    """What an attention call is built from beside its arrays (static)."""
+    scale: float
+    tile: int
+    rows: int
+    unroll: int
+    interpret: bool
+
+
+def _attend_forward(first, q, kn, kr, vt, chosen, how: _How):
+    import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    H, n, d = q.shape
+    S, dn, dv, dr = kn.shape[1], kn.shape[2], vt.shape[1], kr.shape[1]
+    f32, tile = jnp.float32, how.tile
+    last = _last_seen(n, tile)
+    blocks = [((H, n, d), q.dtype), ((H, tile, dn), kn.dtype),
+              ((tile, dr), kr.dtype), ((H, dv, tile), vt.dtype),
+              ((n, tile), chosen.dtype), ((H, dv, n), q.dtype),
+              ((H, n), f32), ((n, tile), f32)]
+    scratch = [((H, n), f32), ((H, n), f32), ((H, dv, n), f32),
+               ((tile, n), f32)]
+    return pl.pallas_call(
+        functools.partial(_attend_fwd_kernel, scale=how.scale, rows=how.rows,
+                          unroll=how.unroll),
+        name="dsa_attend_fwd",
+        out_shape=[jax.ShapeDtypeStruct((H, dv, n), q.dtype),
+                   jax.ShapeDtypeStruct((H, n), f32),
+                   jax.ShapeDtypeStruct((n, S), f32)],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(2, S // tile),
+            in_specs=[
+                pl.BlockSpec((H, n, d), lambda p, t, f: (0, 0, 0)),
+                pl.BlockSpec((H, tile, dn), lambda p, t, f: (0, last(t, f), 0)),
+                pl.BlockSpec((tile, dr), lambda p, t, f: (last(t, f), 0)),
+                # the values wait at their first tile through phase 0
+                pl.BlockSpec((H, dv, tile),
+                             lambda p, t, f: (0, 0, last(t, f) * p)),
+                pl.BlockSpec((n, tile), lambda p, t, f: (0, last(t, f)))],
+            out_specs=[
+                pl.BlockSpec((H, dv, n), lambda p, t, f: (0, 0, 0)),
+                pl.BlockSpec((H, n), lambda p, t, f: (0, 0)),
+                pl.BlockSpec((n, tile), lambda p, t, f: (0, t * p))],
+            scratch_shapes=[pltpu.VMEM(shape, dt) for shape, dt in scratch]),
+        compiler_params=_vmem(blocks, scratch, 2), interpret=how.interpret,
+    )(first, q, kn, kr, vt, chosen)
+
+
+def _attend_backward(first, q, kn, kr, v, chosen, dot, lse, delta,
+                     how: _How):
+    import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    H, n, d = q.shape
+    S, dn, dv, dr = kn.shape[1], kn.shape[2], v.shape[2], kr.shape[1]
+    f32, tile = jnp.float32, how.tile
+    last = _last_seen(n, tile)
+    blocks = [((H, n, d), q.dtype), ((H, tile, dn), kn.dtype),
+              ((tile, dr), kr.dtype), ((H, tile, dv), v.dtype),
+              ((n, tile), chosen.dtype), ((H, dv, n), dot.dtype),
+              ((H, n), f32), ((H, n), f32), ((H, n, d), q.dtype),
+              ((H, tile, dn), kn.dtype), ((tile, dr), kr.dtype),
+              ((H, tile, dv), v.dtype)]
+    scratch = [((tile, n), f32), ((H, n, d), f32)]
+    whole3 = pl.BlockSpec((H, n, d), lambda t, f: (0, 0, 0))
+    stat = pl.BlockSpec((H, n), lambda t, f: (0, 0))
+    return pl.pallas_call(
+        functools.partial(_attend_bwd_kernel, scale=how.scale, rows=how.rows,
+                          unroll=how.unroll),
+        name="dsa_attend_bwd",
+        out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
+                   jax.ShapeDtypeStruct(kn.shape, kn.dtype),
+                   jax.ShapeDtypeStruct(kr.shape, kr.dtype),
+                   jax.ShapeDtypeStruct(v.shape, v.dtype)],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(S // tile,),
+            in_specs=[
+                whole3,
+                pl.BlockSpec((H, tile, dn), lambda t, f: (0, last(t, f), 0)),
+                pl.BlockSpec((tile, dr), lambda t, f: (last(t, f), 0)),
+                pl.BlockSpec((H, tile, dv), lambda t, f: (0, last(t, f), 0)),
+                pl.BlockSpec((n, tile), lambda t, f: (0, last(t, f))),
+                pl.BlockSpec((H, dv, n), lambda t, f: (0, 0, 0)),
+                stat, stat],
+            out_specs=[
+                whole3,
+                pl.BlockSpec((H, tile, dn), lambda t, f: (0, t, 0)),
+                pl.BlockSpec((tile, dr), lambda t, f: (t, 0)),
+                pl.BlockSpec((H, tile, dv), lambda t, f: (0, t, 0))],
+            scratch_shapes=[pltpu.VMEM(shape, dt) for shape, dt in scratch]),
+        compiler_params=_vmem(blocks, scratch, 1), interpret=how.interpret,
+    )(first, q, kn, kr, v, chosen, dot, lse, delta)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(7,))
+def _attend_calls(first, q, kn, kr, v, vt, chosen, how):
+    out_t, _, ps = _attend_forward(first, q, kn, kr, vt, chosen, how)
+    return out_t, ps
+
+
+def _attend_calls_fwd(first, q, kn, kr, v, vt, chosen, how):
+    out_t, lse, ps = _attend_forward(first, q, kn, kr, vt, chosen, how)
+    return (out_t, ps), (first, q, kn, kr, v, chosen, out_t, lse)
+
+
+def _attend_calls_bwd(how, res, g):
+    # ``ps`` is a target (``kl_target`` stops its gradient): its cotangent
+    # is dropped. ``vt`` is ``v`` turned: the values' gradient is ``v``'s
+    *ins, out_t, lse = res
+    dot = g[0]
+    delta = (dot.astype(jnp.float32) * out_t.astype(jnp.float32)).sum(1)
+    dq, dkn, dkr, dv = _attend_backward(*ins, dot, lse, delta, how)
+    return None, dq, dkn, dkr, dv, None, None
+
+
+_attend_calls.defvjp(_attend_calls_fwd, _attend_calls_bwd)
+
+
+def attend_kernels(q, kn, v, kr, chosen, first, scale: float, tile: int,
+                   v_t=None, interpret: bool = False):
+    """Attention of a block over its choice as two Mosaic calls,
+    ``dsa_attend_fwd`` and ``dsa_attend_bwd`` behind a ``custom_vjp``, on
+    arrays that lie heads first: q [H, n, d_n + d_r], kn [H, S, d_n], v [H,
+    S, d_v], kr [S, d_r], chosen [n, S] (bool or int8) -> (out turned [H,
+    d_v, n], ``sum_h p`` [n, S] float32). ``v_t``: ``v`` with its last two
+    axes swapped, [H, d_v, S], what the forward reads (a walk turns it once
+    for all its blocks; made here when not given); the values' gradient is
+    ``v``'s whole.
+
+    Both calls hold the scores keys down and queries across, so that the
+    MXU holds a head's queries and the tile's keys stream past them. The
+    forward walks the tiles of keys twice: once for every head's maximum
+    and sum a query, once more for ``p = exp(s - m) / l`` float32, ``out +=
+    p.astype(v.dtype) v`` (float32 sums) and the heads' sum of ``p``: a
+    head's normaliser is not known before its last tile, and the heads' sum
+    does not factor. The products are the MXU's of the arrays as they are,
+    float32 sums; the mask is the choice. The backward keeps the inputs,
+    ``out`` and the queries' log-sum-exp, forms the products again and sends
+    ``dS = p (dP - delta) scale`` to the MXU in the inputs' dtype; ``dk_r``
+    is summed over the heads in the call; the heads' sum receives no
+    gradient. ``first``: the first query's position, int32 (None: every
+    tile is visited); a tile that starts past the last query is not fetched,
+    its block of the heads' sum and of ``dk_n``, ``dk_r``, ``dv`` is
+    zeros."""
+    first = jnp.asarray(kn.shape[1] if first is None else first,
+                        jnp.int32).reshape(1)
+    if v_t is None:
+        v_t = jnp.swapaxes(v, 1, 2)
+    return _attend_calls(
+        first, q, kn, kr, v, jax.lax.stop_gradient(v_t),
+        chosen.astype(jnp.int8),
+        _How(float(scale), tile, min(ATTEND_ROWS, tile), ATTEND_UNROLL,
+             interpret))
+
+
 def choose(scores: jax.Array, first_q, topk: int) -> jax.Array:
     """scores [n, S] float32 of the queries at positions ``first_q + 0 ..
     n - 1`` over the keys at ``0 .. S - 1`` -> bool [n, S]: for each query
@@ -414,10 +856,40 @@ def _walk(q, k_n, v, k_r, q_i, k_i, w, *, scale: float, topk: int,
     dn = k_n.shape[-1]
     block, tiers = walk_plan(s, block, tiers)
     per_tier = s // block // tiers
+    ends = [(g + 1) * per_tier * block for g in range(tiers)]
+    plan = attend_plan(block, s // tiers, dn, v.shape[-1])
+    # where the positions lie in q, k_n and v: the kernels take them heads
+    # first, and the values also turned [H, d_v, s] (the forward call's)
+    at = int(plan["attend_form"] == "kernel")
 
-    def one_block(keys, args):
+    def by_block(x, at=0):
+        x = x.reshape(x.shape[:at] + (tiers, per_tier, block)
+                      + x.shape[at + 1:])
+        return jnp.moveaxis(x, 0, 2) if at else x
+
+    with jax.named_scope("flash_sparse"):
+        # turned once a walk, not once a block
+        if at:
+            q, k_n, v = (jnp.swapaxes(x, 0, 1) for x in (q, k_n, v))
+            v_turned = jnp.swapaxes(v, 1, 2)
+        q_t = by_block(q, at)
+        keys = [(jax.lax.slice_in_dim(k_n, 0, end, axis=at),
+                 jax.lax.slice_in_dim(v, 0, end, axis=at), k_r[:end])
+                + ((v_turned[..., :end],) if at else ()) for end in ends]
+
+    def attend(q_b, chosen, first, kn_t, v_t, kr_t, *turned):
+        """-> (out, p): the heads' probabilities [H, block, S'] (XLA's
+        form), or their sum over the heads [1, block, S'] (the
+        kernels')."""
+        if not at:
+            return plain_attend(q_b, kn_t, v_t, kr_t, chosen, scale)
+        # looked up at trace time: a test hands it the interpreter
+        out, p_sum = attend_kernels(q_b, kn_t, v_t, kr_t, chosen, first,
+                                    scale, plan["attend_tile"], *turned)
+        return out, p_sum[None]
+
+    def one_block(keys, ki_t, args):
         q_b, qi_b, w_b, first = args
-        kn_t, v_t, kr_t, ki_t = keys
         with jax.named_scope("dsa_scores"):
             # [block, S']. ``index_scores`` is looked up as the block is
             # traced: a control of benchmark/tests/sparse_limits.py replaces
@@ -430,14 +902,7 @@ def _walk(q, k_n, v, k_r, q_i, k_i, w, *, scale: float, topk: int,
         with jax.named_scope("dsa_select"):
             chosen = choose(jax.lax.stop_gradient(index), first, topk)
         with jax.named_scope("flash_sparse"):
-            sc = (jnp.einsum("qhd,khd->hqk", q_b[..., :dn], kn_t,
-                             preferred_element_type=jnp.float32)
-                  + jnp.einsum("qhd,kd->hqk", q_b[..., dn:], kr_t,
-                               preferred_element_type=jnp.float32)) * scale
-            p = jax.nn.softmax(jnp.where(chosen[None], sc, _NEG), axis=-1)
-            out = jnp.einsum("hqk,khd->qhd", p.astype(v_t.dtype), v_t,
-                             preferred_element_type=jnp.float32
-                             ).astype(q_b.dtype)
+            out, p = attend(q_b, chosen, first, *keys)
         with jax.named_scope("dsa_loss"):
             target = kl_target(p)
             log_q = jax.nn.log_softmax(jnp.where(chosen, index, _NEG), -1)
@@ -451,21 +916,19 @@ def _walk(q, k_n, v, k_r, q_i, k_i, w, *, scale: float, topk: int,
                 chosen, ((0, 0), (0, s - chosen.shape[1]))), axis=-1),)
         return said
 
-    def by_block(x):
-        return x.reshape((tiers, per_tier, block) + x.shape[1:])
-
-    q_t, qi_t, w_t = by_block(q), by_block(q_i), by_block(w)
+    qi_t, w_t = by_block(q_i), by_block(w)
+    ki = [k_i[:end] for end in ends]
     firsts = (jnp.arange(s // block, dtype=jnp.int32) * block
               ).reshape(tiers, per_tier)
-    parts = []
-    for g in range(tiers):
-        end = (g + 1) * per_tier * block
-        keys = (k_n[:end], v[:end], k_r[:end], k_i[:end])
-        parts.append(jax.lax.map(
-            jax.checkpoint(lambda a, keys=keys: one_block(keys, a)),
-            (q_t[g], qi_t[g], w_t[g], firsts[g])))
+    parts = [jax.lax.map(
+        jax.checkpoint(lambda a, g=g: one_block(keys[g], ki[g], a)),
+        (q_t[g], qi_t[g], w_t[g], firsts[g])) for g in range(tiers)]
     out, kl, pairs, *choice = (
         jnp.concatenate(xs) for xs in zip(*parts))
+    if at:
+        # [blocks, H, d_v, block], as the kernels leave it
+        with jax.named_scope("flash_sparse"):
+            out = jnp.transpose(out, (0, 3, 1, 2))
     return (out.reshape(s, H, -1), kl.sum(), pairs.sum(),
             *(c.reshape(s, -1) for c in choice))
 
@@ -489,6 +952,8 @@ def sparse_attention(q, k_n, v, k_r, q_i, k_i, w, *, scale: float,
                       index_heads=q_i.shape[2], index_head_dim=q_i.shape[3],
                       topk=topk, positions=s, block=blk, tiers=trs,
                       **scores_plan(blk, s // trs, *q_i.shape[2:]),
+                      **attend_plan(blk, s // trs, k_n.shape[-1],
+                                    v.shape[-1]),
                       pairs_scored=b * s * (s + 1) // 2,
                       pairs_chosen=b * sum(min(t + 1, topk)
                                            for t in range(s))):

@@ -330,16 +330,20 @@ def latent_attention_part(prefix: str = "", rope: Callable = rope_tables,
                      + 2 * shape["wi_w"][-1] if index else 0))
         rows = 0
         if index:
-            # one block of the walk while its backward runs: the heads'
-            # scores and probabilities [H, block, s] float32 twice, and the
-            # index's scores as their form holds them: XLA's the products
+            # one block of the walk while its backward runs, as each form
+            # holds it in HBM. The index's scores: XLA's the products
             # [block, J, s] float32 with their gradient, the kernels' (whose
-            # products stay in VMEM) [block, s] float32 with its gradient
+            # products stay in VMEM) [block, s] float32 with its gradient.
+            # The attention: XLA's the heads' scores and probabilities [H,
+            # block, s] float32 twice, the kernels' the heads' summed
+            # probabilities [block, s] float32 and the target made of them
             blk, trs = dsa.walk_plan(tokens, cfg.index_block, cfg.index_tiers)
             J, di = shape["wi_w"][-1], shape["wi_k"][-1]
-            form = dsa.scores_plan(blk, tokens // trs, J, di)["scores_form"]
-            rows = blk * tokens * 4 * ((3 * J if form == "xla" else 2)
-                                       + 4 * H)
+            scores = dsa.scores_plan(blk, tokens // trs, J, di)["scores_form"]
+            attend = dsa.attend_plan(blk, tokens // trs, sz.d_n,
+                                     dv)["attend_form"]
+            rows = blk * tokens * 4 * ((3 * J if scores == "xla" else 2)
+                                       + (4 * H if attend == "xla" else 2))
         return kept(
             flash=tokens * (shape["wo"][0] * act + H * 4 + latents * act)
             if not index else tokens * latents * act,

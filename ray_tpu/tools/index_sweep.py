@@ -1,4 +1,5 @@
-"""The index's scores alone on the chip, one process:
+"""The index's scores, or the attention over its choice (``--attend``,
+below), alone on the chip, one process. The scores:
 ``ops/dsa.index_scores`` forward and backward over one layer's walk at a
 cell's shapes (``train-dots3-1chip``'s by default: 16,384 positions, 64
 index heads of 128, blocks of 128 queries in four tiers of keys, two such
@@ -30,6 +31,14 @@ calls. Prints a line a reading and writes all of them to
 ``chiprun_out/<--out>`` (``index_sweep.json``). Run as a file; a time read
 on the CPU is no device number (the kernels then run in the Pallas
 interpreter: use a short ``--seq``).
+
+    python3 ray_tpu/tools/index_sweep.py --attend 512,512,16 [--attend ..] \
+        [--block 128] [--no-xla] [--no-gaps]
+
+``--attend tile,rows[,unroll]`` sweeps the attention's calls instead
+(``sweep_attend``: ``ATTEND_TILE``, ``ATTEND_ROWS``, ``ATTEND_UNROLL``; 16
+heads of ``--widths`` 128,64,128), XLA's form beside them, into
+``chiprun_out/attend_sweep.json``.
 """
 
 from __future__ import annotations
@@ -47,6 +56,32 @@ ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
 sys.path.insert(0, ROOT)
 
 
+def _ms(fn, xs, calls: int) -> float:
+    """Median wall ms of ``calls`` calls of ``fn(*xs)`` after two warm."""
+    import jax
+
+    times = []
+    for _ in range(calls + 2):
+        t = time.perf_counter()
+        jax.block_until_ready(fn(*xs))
+        times.append((time.perf_counter() - t) * 1e3)
+    return statistics.median(times[2:])
+
+
+def _gap(got, want) -> float:
+    import jax.numpy as jnp
+
+    got, want = got.astype(jnp.float32), want.astype(jnp.float32)
+    return float(jnp.linalg.norm(got - want) / jnp.linalg.norm(want))
+
+
+def _write(out, name: str) -> None:
+    os.makedirs(os.path.join(ROOT, "chiprun_out"), exist_ok=True)
+    with open(os.path.join(ROOT, "chiprun_out", name), "w") as f:
+        json.dump(out, f, indent=1)
+    print(json.dumps(out))
+
+
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--seq", type=int, default=16384)
@@ -58,10 +93,172 @@ def main() -> None:
     ap.add_argument("--layers", type=int, default=2)
     ap.add_argument("--calls", type=int, default=7)
     ap.add_argument("--kernels", action="append", default=[])
+    ap.add_argument("--attend", action="append", default=[],
+                    help="tile,rows of the attention's kernels: sweeps the "
+                         "attention over the choice in the scores' place")
+    ap.add_argument("--attend-heads", type=int, default=16)
+    ap.add_argument("--widths", default="128,64,128",
+                    help="d_n,d_r,d_v of the attention")
     ap.add_argument("--no-gaps", action="store_true")
-    ap.add_argument("--out", default="index_sweep.json")
+    ap.add_argument("--no-xla", action="store_true")
+    ap.add_argument("--out", default=None)
     a = ap.parse_args()
+    if a.attend:
+        sweep_attend(a)
+    else:
+        sweep_scores(a)
 
+
+def sweep_attend(a) -> None:
+    """The attention over the choice alone (``dsa.attend_kernels`` beside
+    ``dsa.plain_attend``): one layer's walk as ``dsa._walk`` makes it, the
+    blocks under ``jax.checkpoint`` in ``lax.map`` a tier, the choice
+    planted (``--topk`` keys a query at random among the causal ones).
+    ``forward_ms``: out and the heads' summed probabilities of every block;
+    ``grad_ms``: the four gradients of the walk (a forward, the blocks'
+    forward again, their backward, and the sums of the blocks' ``dk`` and
+    ``dv`` into the tiers'); ``backward_ms`` = ``grad_ms`` - 2
+    ``forward_ms``; ``step_ms`` = ``--layers`` x (``forward_ms`` +
+    ``grad_ms``), three forwards and a backward a layer."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.ops import dsa
+
+    f32, bf16 = jnp.float32, jnp.bfloat16
+    s, H = a.seq, a.attend_heads
+    dn, dr, dv = (int(x) for x in a.widths.split(","))
+    scale = (dn + dr) ** -0.5
+    block, tiers = dsa.walk_plan(s, a.block, a.tiers)
+    per_tier = s // block // tiers
+    ks = jax.random.split(jax.random.PRNGKey(0), 6)
+    q = jax.random.normal(ks[0], (s, H, dn + dr), f32)
+    kn = jax.random.normal(ks[1], (s, H, dn), f32)
+    v = jax.random.normal(ks[2], (s, H, dv), f32)
+    kr = jax.random.normal(ks[3], (s, dr), f32)
+    do = jax.random.normal(ks[4], (s, H, dv), f32)
+    planted = jax.random.uniform(ks[5], (block, s)) < a.topk / s
+    on_cpu = jax.default_backend() == "cpu"
+
+    def chosen_of(planted, first, end):
+        t = first + jnp.arange(block, dtype=jnp.int32)[:, None]
+        key = jnp.arange(end, dtype=jnp.int32)[None, :]
+        return (planted[:, :end] | (key == t)) & (key <= t)
+
+    def walks(tile):
+        """Jitted: one layer's walk forward and its gradients; ``tile``
+        None is XLA's form. New functions every time (jit traces anew)."""
+        at = int(tile is not None)
+
+        def loss(q, kn, v, kr, do, planted):
+            if at:
+                q, kn, v = (jnp.swapaxes(x, 0, 1) for x in (q, kn, v))
+                vt = jnp.swapaxes(v, 1, 2)
+                do = jnp.transpose(do, (1, 2, 0))       # [H, d_v, s]
+
+            def by_block(x, at=at):
+                x = x.reshape(x.shape[:at] + (tiers, per_tier, block)
+                              + x.shape[at + 1:])
+                return jnp.moveaxis(x, (at, at + 1), (0, 1)) if at else x
+
+            def one_block(keys, end, args):
+                q_b, do_b, first = args
+                chosen = chosen_of(planted, first, end)
+                if at:
+                    out, p = dsa.attend_kernels(q_b, *keys[:3], chosen,
+                                                first, scale, tile, keys[3])
+                else:
+                    out, p = dsa.plain_attend(q_b, *keys, chosen, scale)
+                    p = p.sum(0)
+                p = jax.lax.stop_gradient(p)
+                return ((out.astype(f32) * do_b.astype(f32)).sum()
+                        + (p / p.sum(-1, keepdims=True)).max())
+
+            firsts = (jnp.arange(s // block, dtype=jnp.int32) * block
+                      ).reshape(tiers, per_tier)
+            total = 0.0
+            for g in range(tiers):
+                end = (g + 1) * per_tier * block
+                keys = (jax.lax.slice_in_dim(kn, 0, end, axis=at),
+                        jax.lax.slice_in_dim(v, 0, end, axis=at), kr[:end]
+                        ) + ((vt[..., :end],) if at else ())
+                total = total + jax.lax.map(
+                    jax.checkpoint(lambda x, keys=keys, end=end:
+                                   one_block(keys, end, x)),
+                    (by_block(q)[g], by_block(do, 2 * at)[g], firsts[g])
+                ).sum()
+            return total
+
+        return jax.jit(loss), jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3)))
+
+    # one block against every key, the last block of the sequence
+    rows = slice(s - block, s)
+    chosen = chosen_of(planted, s - block, s)
+
+    def one_block(tile, q_b, kn, v, kr, do_b):
+        def outputs(q_b, kn, v, kr):
+            if tile is None:
+                out, p = dsa.plain_attend(q_b, kn, v, kr, chosen, scale)
+                return out, p.sum(0)
+            out, p = dsa.attend_kernels(
+                *(jnp.swapaxes(x, 0, 1) for x in (q_b, kn, v)), kr, chosen,
+                None, scale, tile)
+            return jnp.transpose(out, (2, 0, 1)), p
+
+        (out, p), vjp = jax.vjp(outputs, q_b, kn, v, kr)
+        return (out, p) + vjp((do_b.astype(out.dtype), jnp.zeros_like(p)))
+
+    want = None
+    if not a.no_gaps:
+        with jax.default_matmul_precision("highest"):
+            want = jax.block_until_ready(jax.jit(functools.partial(
+                one_block, None))(q[rows], kn, v, kr, do[rows]))
+
+    def reading(tile):
+        forward, grad = walks(tile)
+        half = tuple(x.astype(bf16) for x in (q, kn, v, kr, do)) + (planted,)
+        out = {}
+        try:
+            out["forward_ms"] = _ms(forward, half, a.calls)
+            out["grad_ms"] = _ms(grad, half, a.calls)
+            out["backward_ms"] = out["grad_ms"] - 2 * out["forward_ms"]
+            out["step_ms"] = a.layers * (out["forward_ms"] + out["grad_ms"])
+            if want is not None:
+                got = jax.jit(functools.partial(one_block, tile))(
+                    *(x.astype(bf16) for x in (q[rows], kn, v, kr, do[rows])))
+                out["gap_to_float32"] = {
+                    n: _gap(x, y) for n, x, y in zip(
+                        ("out", "p_sum", "dq", "dk_n", "dv", "dk_r"), got,
+                        want)}
+        except Exception as e:  # noqa: BLE001 (a setting Mosaic refuses)
+            out["error"] = f"{type(e).__name__}: {str(e)[:600]}"
+        return out
+
+    out = {"device": jax.devices()[0].device_kind, "seq": s, "heads": H,
+           "widths": [dn, dr, dv], "block": block, "tiers": tiers,
+           "calls": a.calls,
+           "pairs_in_the_tiers": sum(
+               per_tier * block * (t + 1) * per_tier * block
+               for t in range(tiers)),
+           "xla": {} if a.no_xla else reading(None), "kernels": {}}
+    print(json.dumps({"xla": out["xla"]}), flush=True)
+    if on_cpu:
+        dsa.attend_kernels = functools.partial(dsa.attend_kernels,
+                                               interpret=True)
+        jax.default_backend = lambda: "tpu"
+    for text in a.attend:
+        dsa.ATTEND_TILE, dsa.ATTEND_ROWS, *more = (
+            int(x) for x in text.split(","))
+        dsa.ATTEND_UNROLL = more[0] if more else 1
+        plan = dsa.attend_plan(block, per_tier * block, dn, dv)
+        out["kernels"][text] = dict(plan, **(
+            reading(plan["attend_tile"])
+            if plan["attend_form"] == "kernel" else {}))
+        print(json.dumps({text: out["kernels"][text]}), flush=True)
+    _write(out, a.out or "attend_sweep.json")
+
+
+def sweep_scores(a) -> None:
     import jax
     import jax.numpy as jnp
 
@@ -118,16 +315,7 @@ def main() -> None:
         return jax.jit(forward), jax.jit(backward)
 
     def ms(fn, *xs):
-        times = []
-        for _ in range(a.calls + 2):
-            t = time.perf_counter()
-            jax.block_until_ready(fn(*xs))
-            times.append((time.perf_counter() - t) * 1e3)
-        return statistics.median(times[2:])
-
-    def gap(got, want):
-        got, want = got.astype(f32), want.astype(f32)
-        return float(jnp.linalg.norm(got - want) / jnp.linalg.norm(want))
+        return _ms(fn, xs, a.calls)
 
     # one block against every key, the last block of the sequence
     rows = slice(s - block, s)
@@ -163,7 +351,7 @@ def main() -> None:
             if want is not None:
                 got = one_block(scores)(*cell, g)
                 out["gap_to_float32"] = {
-                    n: gap(x, y) for n, x, y in zip(
+                    n: _gap(x, y) for n, x, y in zip(
                         ("scores", "dq_i", "dk_i", "dw"), got, want)}
         except Exception as e:  # noqa: BLE001 (a setting Mosaic refuses)
             out["error"] = f"{type(e).__name__}: {str(e)[:400]}"
@@ -192,10 +380,7 @@ def main() -> None:
             dsa.index_scores(q_b, k_t, w_b))
             if plan["scores_form"] == "kernel" else {}))
         print(json.dumps({key: out["kernels"][key]}), flush=True)
-    os.makedirs(os.path.join(ROOT, "chiprun_out"), exist_ok=True)
-    with open(os.path.join(ROOT, "chiprun_out", a.out), "w") as f:
-        json.dump(out, f, indent=1)
-    print(json.dumps(out))
+    _write(out, a.out or "index_sweep.json")
 
 
 if __name__ == "__main__":

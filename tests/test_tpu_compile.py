@@ -783,3 +783,46 @@ def test_index_score_kernels_compile_at_the_cells_shapes(
         assert any(kernel in name for name in names), (kernel, names)
     for text in (forward, gradient):
         assert f"[{block},64,16384]" not in text
+
+
+@pytest.mark.parametrize("block", [128, 256], ids=["the-walks-block",
+                                                   "a-block-of-256"])
+def test_attend_kernels_compile_at_the_cells_shapes(
+        S, one_chip, no_compile_cache, monkeypatch, block):
+    """Attention over the choice at ``train-dots3-1chip``'s shapes (16
+    heads, keys of 128 beside the rope's 64, values of 128, 16,384 keys,
+    bfloat16, heads first; the choice a bool mask), on a TPU backend:
+    Mosaic takes the forward call, and the backward call of the four
+    gradients; nothing float32 ``[16, block, keys]`` is left in the
+    program (the values turned, bfloat16 ``[16, 128, keys]``, are)."""
+    from ray_tpu.ops import dsa
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    plan = dsa.attend_plan(block, 16384, 128, 128)
+    assert plan == {"attend_form": "kernel", "attend_tile": dsa.ATTEND_TILE}
+
+    def struct(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    args = (S(16, block, 192), S(16, 16384, 128), S(16, 16384, 128),
+            S(16384, 64), struct((block, 16384), jnp.bool_),
+            struct((), jnp.int32))
+
+    def attend(q, kn, v, kr, chosen, first):
+        return dsa.attend_kernels(q, kn, v, kr, chosen, first, 192 ** -0.5,
+                                  plan["attend_tile"])
+
+    forward = jax.jit(attend).lower(*args).compile().as_text()
+    assert [name for name, _ in _mosaic_calls(forward)] == ["dsa_attend_fwd"]
+
+    def loss(*a):
+        return jnp.square(attend(*a)[0].astype(jnp.float32)).sum()
+
+    gradient = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3))).lower(
+        *args).compile().as_text()
+    names = [name for name, _ in _mosaic_calls(gradient)]
+    assert len(names) == 2, names        # (named after the transformation)
+    for kernel in ("dsa_attend_fwd", "dsa_attend_bwd"):
+        assert any(kernel in name for name in names), (kernel, names)
+    for text in (forward, gradient):
+        assert f"f32[16,{block},16384]" not in text
