@@ -26,6 +26,7 @@ from __future__ import annotations
 import itertools
 import math
 from dataclasses import dataclass, field, replace
+from functools import partial
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -34,8 +35,9 @@ from jax.ad_checkpoint import checkpoint_name
 
 from ray_tpu.ops.attention import attention_reference, flash_attention
 from ray_tpu.ops.layers import (Leaf, Part, apply_rope, blocked_head_loss,
-                                blocked_head_nll, head_block, kept, rms_norm,
-                                rope_frequencies, swiglu, swiglu_part)
+                                blocked_head_nll, head_block, kept,
+                                norm_start, rms_norm, rope_frequencies,
+                                swiglu, swiglu_part)
 from ray_tpu.ops.ring_attention import ring_attention
 from ray_tpu.util import tracing
 
@@ -92,6 +94,9 @@ class LlamaConfig:
     # scaled by sqrt(hidden) at lookup. Gemma's (1+w) RMSNorm needs no
     # knob — the +1 folds into the stored norm weights at load time.
     mlp_act: str = "silu"  # silu | gelu_tanh
+    # every RMSNorm of the block, a head's q/k norms and the last norm
+    # scale by 1 + w, w drawn as zeros (Qwen3-Next; ops/layers.rms_norm)
+    zero_centred_norm: bool = False
     embed_scale: float = 1.0
     # serving prefill attention: None = auto (Pallas flash on single-
     # chip TPU, fp32 reference elsewhere). The engine forces False under
@@ -496,9 +501,16 @@ def _attend(cfg: LlamaConfig, q, k, v, mesh=None, seq_axis=None,
                                sm_scale=sm_scale)
 
 
+def _wide_gated(attn, gate):
+    """attn and gate [b, s, heads, head_dim] -> ``attn * sigmoid(gate)``
+    dim by dim, float32 inside."""
+    return (attn.astype(jnp.float32) * jax.nn.sigmoid(
+        gate.astype(jnp.float32))).astype(attn.dtype)
+
+
 def attention_block(cfg: LlamaConfig, x, p, cos, sin, mesh=None,
                     seq_axis=None, window=None, sm_scale=None,
-                    resid_scale=None):
+                    resid_scale=None, gate_in_wq: bool = False):
     """Attention sub-block with residual: x + wo(attend(qkv)), the norm
     where the layer's leaves put it: ``attn_norm`` on the block's input
     (pre-norm, llama's order), ``attn_post_norm`` on its output before the
@@ -517,15 +529,20 @@ def attention_block(cfg: LlamaConfig, x, p, cos, sin, mesh=None,
     not ``head_dim ** -0.5``; ``resid_scale``: the weight of the block's
     output in the sum with ``x`` where it is not 1 (Granite's
     ``attention_multiplier`` and ``residual_multiplier``). Left at None
-    the three trace what they always did."""
+    the three trace what they always did. ``gate_in_wq``: ``wq`` is twice
+    as wide and gives each head its query and then, of the same size, an
+    elementwise gate: ``sigmoid`` of it multiplies that head's output dim
+    by dim before ``wo`` (Qwen3-Next). ``cfg.zero_centred_norm``: every
+    norm here scales by ``1 + w``."""
     # The named scopes here and below (embed, attn_qkv, flash, attn_out,
     # mlp, head_loss) are metadata only: they name the device time of a
     # step in a profiler trace and change no instruction.
     b, s, _ = x.shape
     hd = cfg.head_dim_
+    norm = partial(rms_norm, eps=cfg.rms_norm_eps,
+                   zero_centred=cfg.zero_centred_norm)
     with jax.named_scope("attn_qkv"):
-        h1 = (rms_norm(x, p["attn_norm"], cfg.rms_norm_eps)
-              if "attn_norm" in p else x)
+        h1 = norm(x, p["attn_norm"]) if "attn_norm" in p else x
         q = jnp.dot(h1, p["wq"].astype(cfg.dtype),
                     preferred_element_type=jnp.float32).astype(cfg.dtype)
         k = jnp.dot(h1, p["wk"].astype(cfg.dtype),
@@ -540,15 +557,17 @@ def attention_block(cfg: LlamaConfig, x, p, cos, sin, mesh=None,
         # (LFM2), else the whole q and k vectors (OLMoE)
         per_head = "q_norm" in p and p["q_norm"].shape[-1] == hd
         if "q_norm" in p and not per_head:
-            q = rms_norm(q, p["q_norm"], cfg.rms_norm_eps)
-            k = rms_norm(k, p["k_norm"], cfg.rms_norm_eps)
-        heads = p["wq"].shape[-1] // hd
-        q = q.reshape(b, s, heads, hd)
+            q = norm(q, p["q_norm"])
+            k = norm(k, p["k_norm"])
+        heads = p["wq"].shape[-1] // (2 * hd if gate_in_wq else hd)
+        q = q.reshape(b, s, heads, -1)
+        if gate_in_wq:
+            q, wide_gate = q[..., :hd], q[..., hd:]
         k = k.reshape(b, s, cfg.num_kv_heads, hd)
         v = v.reshape(b, s, cfg.num_kv_heads, hd)
         if per_head:
-            q = rms_norm(q, p["q_norm"], cfg.rms_norm_eps)
-            k = rms_norm(k, p["k_norm"], cfg.rms_norm_eps)
+            q = norm(q, p["q_norm"])
+            k = norm(k, p["k_norm"])
         if cos is not None:
             q = apply_rope(q, cos, sin)
             k = apply_rope(k, cos, sin)
@@ -577,13 +596,16 @@ def attention_block(cfg: LlamaConfig, x, p, cos, sin, mesh=None,
             with jax.named_scope("attn_gate"):
                 attn = (attn.astype(jnp.float32) * gate[..., None]
                         ).astype(cfg.dtype)
+        if gate_in_wq:
+            with jax.named_scope("attn_gate"):
+                # looked up at trace time: delta_moe_limits.py's seam
+                attn = _wide_gated(attn, wide_gate)
         attn = attn.reshape(b, s, heads * hd)
         attn_out = jnp.dot(
             attn, p["wo"].astype(cfg.dtype),
             preferred_element_type=jnp.float32).astype(cfg.dtype)
         if "attn_post_norm" in p:
-            attn_out = rms_norm(attn_out, p["attn_post_norm"],
-                                cfg.rms_norm_eps)
+            attn_out = norm(attn_out, p["attn_post_norm"])
         if resid_scale is not None:
             attn_out = attn_out * jnp.asarray(resid_scale, cfg.dtype)
         return checkpoint_name(x + attn_out, "attn_resid")
@@ -602,39 +624,42 @@ def attention_part(heads: str = "num_heads", window: Optional[str] = None,
                    qk_norm: Optional[str] = None, norm: str = "pre",
                    scale: Optional[str] = None, resid: Optional[str] = None
                    ) -> Part:
-    """``attention_block`` as a layer's mixer. ``heads``, ``window``,
-    ``scale`` (the scores') and ``resid`` (the weight of the block's output
-    in the sum) name fields of the config; ``gate``: a per-head output gate
-    ``wg``; ``rope(cfg, tokens) -> (cos, sin)``, made once a forward, or
-    None for a layer without a position embedding; ``qk_norm``: "whole" (an
-    RMSNorm over the q and k vectors: OLMoE, OLMo 2) or "head" (over each
-    head's dims: LFM2); ``norm``: "pre" (``attn_norm`` on the block's
-    input) or "post" (``attn_post_norm`` on its output: OLMo 2).
-    ``cfg.attn_qkv_bias`` adds Qwen2's three biases."""
+    """``attention_block`` as a layer's mixer. ``heads``, ``window``, ``scale``
+    (the scores') and ``resid`` (the weight of the block's output in the sum)
+    name fields of the config; ``gate``: True, a per-head output gate ``wg``
+    (Laguna), or "elementwise", a gate of a head's size beside each head's
+    query in a ``wq`` twice as wide (Qwen3-Next); ``rope(cfg, tokens) -> (cos,
+    sin)``, made once a forward, or None for a layer without a position
+    embedding; ``qk_norm``: "whole" (an RMSNorm over the q and k vectors:
+    OLMoE, OLMo 2) or "head" (over each head's dims: LFM2); ``norm``: "pre"
+    (``attn_norm`` on the block's input) or "post" (``attn_post_norm`` on its
+    output: OLMo 2). ``cfg.attn_qkv_bias`` adds Qwen2's three biases."""
+    wide = gate == "elementwise"
+
     def leaves(cfg):
         h, hd, n = cfg.hidden_size, cfg.head_dim_, getattr(cfg, heads)
         qd, kvd = n * hd, cfg.num_kv_heads * hd
         mat, vec = ("embed", "qkv"), ("qkv",)
-        out = {}
+        out, ones = {}, norm_start(cfg)
         if norm == "pre":
-            out["attn_norm"] = Leaf((h,), "ones", ("embed",))
-        out.update(wq=Leaf((h, qd), h, mat), wk=Leaf((h, kvd), h, mat),
-                   wv=Leaf((h, kvd), h, mat))
+            out["attn_norm"] = Leaf((h,), ones, ("embed",))
+        out.update(wq=Leaf((h, 2 * qd if wide else qd), h, mat),
+                   wk=Leaf((h, kvd), h, mat), wv=Leaf((h, kvd), h, mat))
         if qk_norm == "whole":
-            out.update(q_norm=Leaf((qd,), "ones", vec),
-                       k_norm=Leaf((kvd,), "ones", vec))
+            out.update(q_norm=Leaf((qd,), ones, vec),
+                       k_norm=Leaf((kvd,), ones, vec))
         out["wo"] = Leaf((qd, h), qd, ("qkv", "embed"))
-        if gate:
+        if gate and not wide:
             out["wg"] = Leaf((h, n), h, ("embed", None))
         if qk_norm == "head":
-            out.update(q_norm=Leaf((hd,), "ones", (None,)),
-                       k_norm=Leaf((hd,), "ones", (None,)))
+            out.update(q_norm=Leaf((hd,), ones, (None,)),
+                       k_norm=Leaf((hd,), ones, (None,)))
         if cfg.attn_qkv_bias:
             out.update(bq=Leaf((qd,), "zeros", vec),
                        bk=Leaf((kvd,), "zeros", vec),
                        bv=Leaf((kvd,), "zeros", vec))
         if norm == "post":
-            out["attn_post_norm"] = Leaf((h,), "ones", ("embed",))
+            out["attn_post_norm"] = Leaf((h,), ones, ("embed",))
         return out
 
     def body(cfg, x, p, ctx):
@@ -643,17 +668,19 @@ def attention_part(heads: str = "num_heads", window: Optional[str] = None,
             cfg, x, p, cos, sin, mesh=ctx.mesh,
             window=getattr(cfg, window) if window else None,
             sm_scale=getattr(cfg, scale) if scale else None,
-            resid_scale=getattr(cfg, resid) if resid else None), {}
+            resid_scale=getattr(cfg, resid) if resid else None,
+            gate_in_wq=wide), {}
 
     def keeps(cfg, shape, tokens, mesh):
         # ``wq``'s width gives the heads, as ``attention_block`` reads them
-        qd, kvd = shape["wq"][-1], shape["wk"][-1]
+        qd, kvd = shape["wq"][-1] // (2 if wide else 1), shape["wk"][-1]
         act = jnp.dtype(cfg.dtype).itemsize
         return kept(
             flash=tokens * (qd * act + qd // cfg.head_dim_ * 4),  # lse: f32
             qkv=tokens * (qd + 2 * kvd) * act,
             resid=tokens * cfg.hidden_size * act,
-            width=2 * qd + 2 * kvd)
+            # (a wide gate: the projection's second half and its gradient)
+            width=2 * qd + 2 * kvd + (2 * qd if wide else 0))
 
     return Part(leaves, body, keeps, once=rope)
 
@@ -771,7 +798,8 @@ def forward(cfg: LlamaConfig, params: Dict[str, Any], tokens: jax.Array,
 def _final_head(cfg: LlamaConfig, params, x: jax.Array) -> jax.Array:
     """Shared model tail: final norm + (tied) LM head in fp32."""
     with jax.named_scope("head_loss"):
-        x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
+        x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps,
+                     cfg.zero_centred_norm)
         head = (params["embed"].T if cfg.tie_embeddings
                 else params["lm_head"])
         return jnp.dot(x, head.astype(cfg.dtype),
@@ -802,7 +830,8 @@ def _blocked_head_inputs(cfg: LlamaConfig, params, x: jax.Array
     """x [b, s, h] (the last layer's output) -> what ``ops/layers``' blocked
     head walks: the normed rows [b * s, h] and the head [h, vocab] in the
     compute dtype."""
-    x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
+    x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps,
+                 cfg.zero_centred_norm)
     head = (params["embed"].T if cfg.tie_embeddings
             else params["lm_head"]).astype(cfg.dtype)
     return x.reshape(-1, x.shape[-1]), head

@@ -27,14 +27,14 @@ from __future__ import annotations
 
 import math
 from dataclasses import dataclass
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, Optional, Tuple, Union
 
 import jax
 import jax.numpy as jnp
 
 from ray_tpu.models import llama, mixtral
 from ray_tpu.ops import moe
-from ray_tpu.ops.layers import Ctx, Part
+from ray_tpu.ops.layers import Ctx, Part, norm_start
 
 
 def draw(cfg, key: jax.Array, shape: Tuple[int, ...], start) -> jax.Array:
@@ -97,7 +97,8 @@ def rows_passed(cfg, expert_counts) -> int:
     """Of ``expert_counts [Lr, E]`` on the host, the rows the passes over
     the held experts' rows took (the ``moe_rows_passed`` counter,
     ``ops/moe.rows_passed``); ``rows_held`` over it is the passes' fill."""
-    return moe.rows_passed(expert_counts, cfg.experts_held)
+    return moe.rows_passed(expert_counts, cfg.experts_held,
+                           getattr(cfg, "held_headroom", None))
 
 
 # A router's bias (``routed_part(bias=True)``: the leaf ``router_bias``)
@@ -133,7 +134,9 @@ class Stack:
     """A model: its table of kinds and what the table does not say.
     ``reports``: the name (``Part.reports``) of what ``forward`` and
     ``token_nll`` hand back beside their result: "router", the routed
-    layers' statistics, or a mixer's states. ``blocked_head``: the loss
+    layers' statistics, or a mixer's states; several names (a tuple: a
+    kind with a linear mixer and a routed MLP) hand back a dict by name.
+    ``blocked_head``: the loss
     never builds the logits whole (100,352 rows at 32,768 positions would
     be 13 GB of float32), and the plan is told so: ``loss_terms`` sums it
     block by block with both gradients of a block taken while its logits
@@ -146,7 +149,7 @@ class Stack:
     the embedding where ``cfg.tie_embeddings``, an ``lm_head`` of its own
     elsewhere."""
     kinds: Dict[str, Tuple[Part, Part]]
-    reports: str
+    reports: Union[str, Tuple[str, ...]]
     blocked_head: bool = False
     embed_scale: Optional[str] = None
     logits_divisor: Optional[str] = None
@@ -183,7 +186,7 @@ class Stack:
                             for k, (name, leaf) in zip(keys, leaves.items())}
         params = {"embed": draw(cfg, jax.random.fold_in(key, 0), (v, h), h),
                   "layers": layers,
-                  "final_norm": jnp.ones((h,), cfg.param_dtype)}
+                  "final_norm": draw(cfg, None, (h,), norm_start(cfg))}
         if not cfg.tie_embeddings:
             params["lm_head"] = draw(cfg, jax.random.fold_in(key, 99),
                                      (h, v), h)
@@ -236,6 +239,13 @@ class Stack:
             pattern=pattern)
         return x, in_layer_order(pattern, ys)
 
+    def _said(self, said: Dict[str, Any]) -> Any:
+        """What ``forward`` and ``token_nll`` hand back of the layers'
+        reports (``reports``)."""
+        if isinstance(self.reports, str):
+            return said[self.reports]
+        return {name: said[name] for name in self.reports}
+
     def _divisor(self, cfg) -> float:
         return (getattr(cfg, self.logits_divisor) if self.logits_divisor
                 else 1.0)
@@ -257,18 +267,20 @@ class Stack:
         after the last position ``[L, b, H, ...]`` float32."""
         x, said = self.hidden(cfg, params, tokens, mesh=mesh,
                               keep_router_logits=keep_router_logits)
-        return self._logits(cfg, params, x), said[self.reports]
+        return self._logits(cfg, params, x), self._said(said)
 
     def token_nll(self, cfg, params, tokens: jax.Array, mesh=None,
-                  head_block: Optional[int] = None
+                  head_block: Optional[int] = None,
+                  keep_router_logits: bool = False
                   ) -> Tuple[jax.Array, Any]:
         """tokens [b, s + 1] -> (the next-token loss of every position
         [b, s] float32 through the blocked head, what ``forward`` hands
         back beside its logits)."""
-        x, said = self.hidden(cfg, params, tokens[:, :-1], mesh=mesh)
+        x, said = self.hidden(cfg, params, tokens[:, :-1], mesh=mesh,
+                              keep_router_logits=keep_router_logits)
         return llama.blocked_token_nll(
             cfg, params, x, tokens[:, 1:], block=head_block,
-            logits_divisor=self._divisor(cfg)), said[self.reports]
+            logits_divisor=self._divisor(cfg)), self._said(said)
 
     def loss_terms(self, cfg, params, batch: Dict[str, jax.Array], mesh=None
                    ) -> Tuple[jax.Array, Dict[str, jax.Array]]:

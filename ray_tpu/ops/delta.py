@@ -82,13 +82,13 @@ the gate) and ``gdn_out`` (the out-projection).
 from __future__ import annotations
 
 import functools
-from typing import Any, Dict, List, Tuple
+from typing import Any, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
 from ray_tpu.ops.layers import (Leaf, Part, gated_rms_norm, kept, l2_norm,
-                                rms_norm)
+                                norm_start, rms_norm)
 from ray_tpu.ops.ssm import causal_conv_silu
 from ray_tpu.util import tracing
 
@@ -130,25 +130,32 @@ def _heads_a_block(heads: int) -> int:
 
 
 def rule_plan(batch: int, seq: int, heads: int, key_dim: int,
-              value_dim: int, chunk: int, mesh=None) -> Dict[str, Any]:
-    """What ``gated_delta_rule`` does with these shapes, and in which
-    ``form``. Both forms: the chunk it uses (no longer than the sequence),
-    the chunks, the ``steps`` (of the walk, or of the kernels' grid along
-    the sequence), ``chunks_a_call`` (what one step takes), ``states_kept``
-    (the float32 states a backward starts from, one a step) and the
-    float32 bytes the form puts in HBM beside what all chunks' pair
-    matrices at once would. ``xla_walk``: ``walk`` (= ``chunks_a_call``,
-    the largest divisor of the chunks within ``WALK_BYTES``) and the bytes
-    of one step's pair matrices and carried states. ``pallas``:
-    ``heads_a_block`` (the largest divisor of the heads within
-    ``KERNEL_HEADS``), ``KERNEL_CHUNKS`` chunks a step (all of a shorter
-    sequence), and the bytes of the kept states, the last state and the
+              value_dim: int, chunk: int, mesh=None,
+              key_heads: Optional[int] = None) -> Dict[str, Any]:
+    """What ``gated_delta_rule`` does with these shapes, and in which ``form``.
+    ``heads`` are the value heads, the rule's own; ``key_heads`` (the mixer's,
+    where fewer heads of q and k serve them) and how they were ``joined``
+    ("repeat": q and k copied to the value heads before the call, which then
+    reads ``heads`` of each; None where they are as many) are said beside them.
+    Both forms: the chunk it uses (no longer than the sequence), the chunks,
+    the ``steps`` (of the walk, or of the kernels' grid along the sequence),
+    ``chunks_a_call`` (what one step takes), ``states_kept`` (the float32
+    states a backward starts from, one a step) and the float32 bytes the form
+    puts in HBM beside what all chunks' pair matrices at once would.
+    ``xla_walk``: ``walk`` (= ``chunks_a_call``, the largest divisor of the
+    chunks within ``WALK_BYTES``) and the bytes of one step's pair matrices and
+    carried states. ``pallas``: ``heads_a_block`` (the largest divisor of the
+    heads within ``KERNEL_HEADS``), ``KERNEL_CHUNKS`` chunks a step (all of a
+    shorter sequence), and the bytes of the kept states, the last state and the
     running sums (in their two layouts) and ``beta``: nothing ``[chunk,
     chunk]``."""
     chunk = min(chunk, seq)
     chunks = -(-seq // chunk)
     one = batch * heads * 4 * (4 * chunk * chunk + value_dim * key_dim)
+    key_heads = key_heads or heads
     plan = {"seq": seq, "chunk": chunk, "chunks": chunks, "heads": heads,
+            "key_heads": key_heads,
+            "joined": "repeat" if key_heads != heads else None,
             "key_dim": key_dim, "value_dim": value_dim,
             "float32_bytes_all_chunks": chunks * one}
     if _kernel_takes(chunk, mesh):
@@ -249,7 +256,8 @@ def _walk_step(S, xs, dtype):
 
 
 def gated_delta_rule(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
-                     beta: jax.Array, chunk: int = 64, mesh=None
+                     beta: jax.Array, chunk: int = 64, mesh=None,
+                     key_heads: Optional[int] = None
                      ) -> Tuple[jax.Array, jax.Array]:
     """q and k [b, s, H, K] (k of unit length, q scaled as the caller
     wants its outputs), v [b, s, H, V], g [b, s, H] float32 (the log of the
@@ -257,10 +265,12 @@ def gated_delta_rule(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
     ``v``'s dtype, the state after the last position [b, H, V, K]
     float32). ``mesh``: the one the caller's arrays are sharded over, if
     any. Which form runs is read from the call (``_kernel_takes``), and
-    the kept span ``rtpu.gdn.rule_plan`` says which."""
+    the kept span ``rtpu.gdn.rule_plan`` says which. ``key_heads``: the
+    heads q and k had before the caller copied them to the value heads
+    (the span's alone)."""
     b, s, H, K = q.shape
     V = v.shape[-1]
-    plan = rule_plan(b, s, H, K, V, chunk, mesh)
+    plan = rule_plan(b, s, H, K, V, chunk, mesh, key_heads)
     with tracing.span("rtpu.gdn.rule_plan", keep=True, **plan):
         pass
     if plan["form"] == "pallas":
@@ -762,19 +772,28 @@ def rule_kernels(q, k, v, g, beta, plan, interpret: bool = False):
     return jnp.moveaxis(o, 1, 2)[:, :s], jnp.swapaxes(last, -1, -2)
 
 
-def _gates(a, b_, p):
+def _gates(a, b_, p, beta_scale: float = 2.0):
     """The in-projection's a and b [b, s, H] -> (g, the log of the decay:
-    ``-exp(A_log) softplus(a + dt_bias)``, and ``beta = 2 sigmoid(b)``, the
-    two of ``linear_allow_neg_eigval``), float32."""
+    ``-exp(A_log) softplus(a + dt_bias)``, and ``beta = beta_scale
+    sigmoid(b)``: 2, the two of ``linear_allow_neg_eigval``, or 1),
+    float32."""
     f32 = jnp.float32
     return (-jnp.exp(p["g_A_log"].astype(f32)) * jax.nn.softplus(
         a.astype(f32) + p["g_dt_bias"].astype(f32)),
-            2.0 * jax.nn.sigmoid(b_.astype(f32)))
+            beta_scale * jax.nn.sigmoid(b_.astype(f32)))
+
+
+def _join_heads(x: jax.Array, heads: int) -> jax.Array:
+    """x [b, s, key heads, K] -> [b, s, heads, K]: value head ``i`` reads
+    key head ``i // (heads / key heads)``, neighbours sharing one."""
+    return jnp.repeat(x, heads // x.shape[2], axis=2)
 
 
 def gated_delta_mixer(h: jax.Array, p: Dict[str, jax.Array], *, heads: int,
                       key_dim: int, value_dim: int, chunk: int = 64,
-                      eps: float = 1e-6, mesh=None
+                      eps: float = 1e-6, mesh=None,
+                      key_heads: Optional[int] = None,
+                      beta_scale: float = 2.0
                       ) -> Tuple[jax.Array, jax.Array]:
     """h [b, s, hidden] -> (the mixer's output [b, s, hidden], the state
     after the last position [b, H, V, K] float32, which no gradient
@@ -783,10 +802,16 @@ def gated_delta_mixer(h: jax.Array, p: Dict[str, jax.Array], *, heads: int,
     v's own taps, no bias), ``g_dt_bias`` and ``g_A_log`` ``[H]``,
     ``g_norm [V]``, ``g_out [H V, hidden]``. No projection has a bias.
     ``mesh``: the one the caller's arrays are sharded over, if any
-    (``causal_conv_silu`` keeps XLA's form under one)."""
+    (``causal_conv_silu`` keeps XLA's form under one). ``key_heads``: q
+    and k have that many heads (a divisor of ``heads``, the value heads';
+    ``H K`` above is then theirs) and value head ``i`` reads key head ``i
+    // (heads / key_heads)``: q and k are normed at their own heads and
+    copied to the value heads before the rule, which reads ``heads`` of
+    each (Qwen3-Next: 16 under 32). ``beta_scale``: ``_gates``'."""
     b, s, _ = h.shape
     dt_ = h.dtype
-    hk, hv = heads * key_dim, heads * value_dim
+    key_heads = key_heads or heads
+    hk, hv = key_heads * key_dim, heads * value_dim
     f32 = jnp.float32
     with jax.named_scope("gdn"):
         with jax.named_scope("gdn_in"):
@@ -803,13 +828,17 @@ def gated_delta_mixer(h: jax.Array, p: Dict[str, jax.Array], *, heads: int,
                 first=hv, sizes=(hk, hk, hv), mesh=mesh,
                 span="rtpu.gdn.conv_plan")
         with jax.named_scope("gdn_rule"):
-            q, k, v = (jnp.swapaxes(x, 1, 2).reshape(b, s, heads, -1)
-                       for x in (q, k, v))
+            q, k, v = (jnp.swapaxes(x, 1, 2).reshape(b, s, n, -1)
+                       for x, n in ((q, key_heads), (k, key_heads),
+                                    (v, heads)))
             # ``_gates`` and ``l2_norm`` are looked up at trace time too
-            g, beta = _gates(a, b_, p)
-            o, S = gated_delta_rule(l2_norm(q, scale=key_dim ** -0.5),
-                                    l2_norm(k), v, g, beta, chunk=chunk,
-                                    mesh=mesh)
+            # (and ``_join_heads``, where fewer heads of q and k serve)
+            g, beta = _gates(a, b_, p, beta_scale)
+            q, k = l2_norm(q, scale=key_dim ** -0.5), l2_norm(k)
+            if key_heads != heads:
+                q, k = (_join_heads(x, heads) for x in (q, k))
+            o, S = gated_delta_rule(q, k, v, g, beta, chunk=chunk,
+                                    mesh=mesh, key_heads=key_heads)
             S = jax.lax.stop_gradient(S)
         with jax.named_scope("gdn_norm"):
             y = gated_rms_norm(o, z.reshape(b, s, heads, value_dim),
@@ -820,34 +849,56 @@ def gated_delta_mixer(h: jax.Array, p: Dict[str, jax.Array], *, heads: int,
     return out, S
 
 
-def gated_delta_part(counter: str = "gdn_state_abs_max") -> Part:
-    """The gated delta rule's mixer as a layer's mixer, in OLMo 2's order:
-    ``x + RMSNorm(gated_delta_mixer(x))`` at the config's ``linear_heads``,
+def gated_delta_part(counter: str = "gdn_state_abs_max", norm: str = "post",
+                     key_heads: Optional[str] = None,
+                     beta_scale: float = 2.0) -> Part:
+    """The gated delta rule's mixer as a layer's mixer, in OLMo 2's order
+    (``norm="post"``): ``x + RMSNorm(gated_delta_mixer(x))``, or llama's
+    (``"pre"``: ``x + gated_delta_mixer(RMSNorm(x))``, the norm as the config's
+    are, ``cfg.zero_centred_norm``), at the config's ``linear_heads``,
     ``linear_key_dim``, ``linear_value_dim``, ``linear_conv_taps`` and
-    ``rule_chunk``. A layer reports its state after the last position under
-    "gdn_state", and the loss's terms the largest ``|S|`` of any layer
-    under ``counter``. The initialisation is the delta-net's published
-    one: ``A`` uniform in 0-16 as its log, ``dt`` through the inverse
-    softplus, norms 1."""
+    ``rule_chunk``. ``key_heads`` names the config's field where q and k have
+    fewer heads than ``linear_heads``, the value heads'
+    (``gated_delta_mixer``); ``beta_scale``: ``beta`` is that times
+    ``sigmoid(b)``. A layer reports its state after the last position under
+    "gdn_state", and the loss's terms the largest ``|S|`` of any layer under
+    ``counter``. The initialisation is the delta-net's published one: ``A``
+    uniform in 0-16 as its log, ``dt`` through the inverse softplus, norms
+    1."""
     def leaves(cfg):
         h, H, taps = cfg.hidden_size, cfg.linear_heads, cfg.linear_conv_taps
         hv = H * cfg.linear_value_dim
-        conv = 2 * H * cfg.linear_key_dim + hv
-        return {"g_in": Leaf((h, hv + conv + 2 * H), h, ("embed", "mlp")),
+        conv = 2 * q_heads(cfg) * cfg.linear_key_dim + hv
+        first = ({"op_norm": Leaf((h,), norm_start(cfg), ("embed",))}
+                 if norm == "pre" else {})
+        last = ({"op_post_norm": Leaf((h,), "ones", ("embed",))}
+                if norm == "post" else {})
+        return {**first,
+                "g_in": Leaf((h, hv + conv + 2 * H), h, ("embed", "mlp")),
                 "g_conv": Leaf((conv, taps), taps, ("mlp", None)),
                 "g_dt_bias": Leaf((H,), "dt", (None,)),
                 "g_A_log": Leaf((H,), (0.0, 16.0), (None,)),
                 "g_norm": Leaf((cfg.linear_value_dim,), "ones", (None,)),
-                "g_out": Leaf((hv, h), hv, ("mlp", "embed")),
-                "op_post_norm": Leaf((h,), "ones", ("embed",))}
+                "g_out": Leaf((hv, h), hv, ("mlp", "embed")), **last}
+
+    def q_heads(cfg):
+        return getattr(cfg, key_heads) if key_heads else cfg.linear_heads
 
     def body(cfg, x, p, ctx):
+        if norm == "pre":
+            with jax.named_scope("gdn_pre_norm"):
+                u = rms_norm(x, p["op_norm"], cfg.rms_norm_eps,
+                             cfg.zero_centred_norm)
+        else:
+            u = x
         out, S = gated_delta_mixer(
-            x, p, heads=cfg.linear_heads, key_dim=cfg.linear_key_dim,
+            u, p, heads=cfg.linear_heads, key_dim=cfg.linear_key_dim,
             value_dim=cfg.linear_value_dim, chunk=cfg.rule_chunk,
-            eps=cfg.rms_norm_eps, mesh=ctx.mesh)
-        return (x + rms_norm(out, p["op_post_norm"], cfg.rms_norm_eps),
-                {"gdn_state": S})
+            eps=cfg.rms_norm_eps, mesh=ctx.mesh, key_heads=q_heads(cfg),
+            beta_scale=beta_scale)
+        if norm == "post":
+            out = rms_norm(out, p["op_post_norm"], cfg.rms_norm_eps)
+        return x + out, {"gdn_state": S}
 
     def keeps(cfg, shape, tokens, mesh):
         # the in-projection's output (z, q k v, a b) and the taps' output
@@ -866,10 +917,13 @@ def gated_delta_part(counter: str = "gdn_state_abs_max") -> Part:
         key_dim = cfg.linear_key_dim
         plan = rule_plan(1, tokens, heads, key_dim, hv // heads,
                          cfg.rule_chunk, mesh)
+        # what q's and k's copies at the value heads add to the width the
+        # in-projection's output has them at
+        joined = 2 * (heads - q_heads(cfg)) * key_dim
         if plan["form"] == "pallas":
-            return kept(width=shape["g_in"][-1],
+            return kept(width=shape["g_in"][-1] + joined,
                         rows=plan["float32_bytes_in_hbm"])
-        return kept(width=shape["g_in"][-1] + shape["g_conv"][0],
+        return kept(width=shape["g_in"][-1] + shape["g_conv"][0] + joined,
                     rows=4 * plan["float32_bytes_in_hbm"]
                     + plan["steps"] * hv * key_dim * 4)
 

@@ -82,13 +82,25 @@ def kept(flash: int = 0, qkv: int = 0, mlp: int = 0, resid: int = 0,
     return {"rungs": (flash, qkv, mlp, resid), "width": width, "rows": rows}
 
 
-def rms_norm(x: jax.Array, weight: jax.Array, eps: float = 1e-6) -> jax.Array:
-    """RMSNorm in fp32 accumulation, cast back to input dtype."""
+def rms_norm(x: jax.Array, weight: jax.Array, eps: float = 1e-6,
+             zero_centred: bool = False) -> jax.Array:
+    """RMSNorm in fp32 accumulation, cast back to input dtype.
+    ``zero_centred``: the scale is ``1 + weight`` (float32), a weight drawn
+    as zeros (Qwen3-Next: a decay then pulls the scale to 1, not to 0)."""
     dtype = x.dtype
     xf = x.astype(jnp.float32)
     var = jnp.mean(jnp.square(xf), axis=-1, keepdims=True)
     normed = xf * jax.lax.rsqrt(var + eps)
-    return (normed * weight.astype(jnp.float32)).astype(dtype)
+    scale = weight.astype(jnp.float32)
+    if zero_centred:
+        scale = 1.0 + scale
+    return (normed * scale).astype(dtype)
+
+
+def norm_start(cfg) -> str:
+    """How a norm's weight starts (``Leaf.start``): "zeros" where the
+    config's norms are zero-centred, "ones" elsewhere."""
+    return "zeros" if cfg.zero_centred_norm else "ones"
 
 
 def l2_norm(x: jax.Array, eps: float = 1e-6, scale: float = 1.0) -> jax.Array:

@@ -38,7 +38,8 @@ gathered or multiplied. How many rows that is, is data, and a buffer for
 the worst case (every row routed here) would be ``n * top_k`` rows wide,
 a gigabyte at Laguna's cell: the held rows are taken in passes of a
 static ``chunk`` of rows (``_held_chunk``: the balanced share and an
-eighth of it, a larger part for fewer than 16 experts), as many passes as
+eighth of it, a larger part for fewer than 16 experts or where the
+configuration's ``held_headroom`` says so), as many passes as
 the rows need, each a gather, three grouped matmuls over the pass's groups
 and a scatter-add into the tokens' float32 sums. The sums are one ``[n,
 h]`` array up to 4,096 columns and past that blocks of at most 1,280
@@ -76,8 +77,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 
-from ray_tpu.ops.layers import (Leaf, Part, kept, rms_norm, swiglu,
-                                swiglu_kept)
+from ray_tpu.ops.layers import (Leaf, Part, kept, norm_start, rms_norm,
+                                swiglu, swiglu_kept)
 from ray_tpu.util import tracing
 
 
@@ -277,20 +278,34 @@ def _swiglu_rows(rows, w_rows, sizes, e_gate, e_up, e_down):
 # over the share (2,816 rows) sent a layer of every few steps into a second
 # pass, 35 ms on a step of 704, and a cell's rate apart by 1.2% between two
 # seeds.
+# The rule reckons with experts that draw 0.6-1.7 times their share. Where a
+# router's loads lie farther out the configuration says so
+# (``held_headroom``, one part in that many): at 64 of 512 experts, 10 a
+# token, 32,768 tokens (v5e, PR 50, ``benchmark/tests/held_share_spread.py``)
+# a seeded router's experts draw up to 4-5 times their share and a layer's
+# held rows read 0.82-1.18 of the share over 160 layers of 40 seeds, 6.5% a
+# standard deviation where the rule expects 3; an eighth sent a layer of 5
+# of the 40 seeds into a second pass (a step of 1,080.6 ms for 1,058.5), a
+# quarter none, for 7 ms of padding rows a step (1,065.5; a third 1,064.5, a
+# half 1,079.7).
 _HELD_HEADROOM = 8
 
 
-def _held_chunk(num_pairs: int, count: int, num_experts: int) -> int:
+def _held_chunk(num_pairs: int, count: int, num_experts: int,
+                headroom: Optional[int] = None) -> int:
     """Rows a pass of the held experts takes: their balanced share of the
     ``num_pairs`` (token, choice) pairs and one part in ``2 sqrt(count)``
-    of it (an eighth, ``_HELD_HEADROOM``, from 16 experts up), in whole
-    row tiles, at most all the pairs."""
-    part = min(_HELD_HEADROOM, max(2, round(2 * count ** 0.5)))
+    of it (an eighth, ``_HELD_HEADROOM``, from 16 experts up) or, where a
+    configuration says how far its routers' loads lie from balance
+    (``held_headroom``), one part in ``headroom``; in whole row tiles, at
+    most all the pairs."""
+    part = headroom or min(_HELD_HEADROOM, max(2, round(2 * count ** 0.5)))
     rows = -(-num_pairs * count * (part + 1) // (num_experts * part))
     return min(-(-rows // _ROW_TILE), -(-num_pairs // _ROW_TILE)) * _ROW_TILE
 
 
-def rows_passed(expert_counts, held: Optional[Tuple[int, int]]) -> int:
+def rows_passed(expert_counts, held: Optional[Tuple[int, int]],
+                headroom: Optional[int] = None) -> int:
     """Of ``expert_counts [Lr, E]`` on the host, the rows the passes over
     the ``held`` experts' rows took: each layer's held rows in whole
     passes of ``_held_chunk`` rows (the ``moe_rows_passed`` counter; the
@@ -300,7 +315,8 @@ def rows_passed(expert_counts, held: Optional[Tuple[int, int]]) -> int:
     if held is None:
         return int(counts.sum())
     first, count = held
-    chunk = _held_chunk(int(counts[0].sum()), count, counts.shape[-1])
+    chunk = _held_chunk(int(counts[0].sum()), count, counts.shape[-1],
+                        headroom)
     rows = counts[:, first:first + count].sum(-1)
     return int((-(-rows // chunk) * chunk).sum())
 
@@ -447,7 +463,8 @@ def routed_experts(x: jax.Array, router_w: jax.Array, e_gate: jax.Array,
                    scale: float = 1.0, score: str = "softmax",
                    select_bias: Optional[jax.Array] = None,
                    renorm_eps: float = 0.0, keep_choices: bool = False,
-                   groups: Optional[Tuple[int, int]] = None
+                   groups: Optional[Tuple[int, int]] = None,
+                   headroom: Optional[int] = None
                    ) -> Tuple[jax.Array, ...]:
     """x [n, h], router_w [h, E], e_gate / e_up [E, h, f], e_down
     [E, f, h] -> (out [n, h], router_logits [n, E] float32, counts [E]
@@ -455,7 +472,8 @@ def routed_experts(x: jax.Array, router_w: jax.Array, e_gate: jax.Array,
     weight goes into its activation, before the down projection (above).
     ``held=(first, count)``: the expert weights are those of experts
     ``first .. first + count`` alone, ``[count, ...]``, and ``out`` is
-    their part of the result (the module's docstring); ``None``: all
+    their part of the result (the module's docstring) in passes of
+    ``_held_chunk(.., headroom)`` rows; ``None``: all
     ``E`` are here. ``score``, ``select_bias``, ``renorm_eps`` and
     ``groups`` are ``route``'s; ``keep_choices`` appends ``route``'s own
     ``top_e [n, K]``
@@ -474,7 +492,7 @@ def routed_experts(x: jax.Array, router_w: jax.Array, e_gate: jax.Array,
             # the held experts' pairs first, an expert's in token order
             local = flat_e - first
             key = jnp.where((local >= 0) & (local < count), local, count)
-            chunk = _held_chunk(flat_e.size, count, num_experts)
+            chunk = _held_chunk(flat_e.size, count, num_experts, headroom)
             with tracing.span("rtpu.moe.held_pass", keep=True,
                               pairs=flat_e.size, count=count,
                               num_experts=num_experts,
@@ -583,7 +601,14 @@ def sequence_balance(cfg, router: Dict[str, jax.Array]) -> jax.Array:
     return (share * router["seq_prob"]).sum(-1).mean(-1).sum()
 
 
-def routed_part(shared: bool = False, score: str = "softmax",
+def _token_gated(out, u, w):
+    """out and u [b, s, h], w [h] -> ``sigmoid(u . w) * out``, one number
+    a token, float32 inside."""
+    gate = jax.nn.sigmoid(jnp.dot(u, w, preferred_element_type=jnp.float32))
+    return (out.astype(jnp.float32) * gate[..., None]).astype(out.dtype)
+
+
+def routed_part(shared=False, score: str = "softmax",
                 bias: bool = False, renorm_eps: Optional[str] = None,
                 balance=False, width: str = "moe_intermediate_size",
                 renormalize: bool = True, groups: bool = False) -> Part:
@@ -592,8 +617,14 @@ def routed_part(shared: bool = False, score: str = "softmax",
     config's field), ``cfg.top_k`` a token, the gate weights renormalised
     (or, ``renormalize=False``, left the scores they are) and times
     ``cfg.routed_scale``, ``cfg.experts_held`` of them here (a
-    config without the field holds them all). ``shared``: a SwiGLU of
-    ``cfg.shared_intermediate_size`` beside them, added ungated (Laguna).
+    config without the field holds them all), their rows in passes with
+    ``cfg.held_headroom`` over the balanced share where the config has the
+    field (``_held_chunk``). ``shared``: a SwiGLU of
+    ``cfg.shared_intermediate_size`` beside them, added ungated (True:
+    Laguna) or, "gated", times ``sigmoid(u . s_sigmoid)``, one number a
+    token from a vector of its own (Qwen3-Next; the scope
+    ``moe_shared_gate`` inside ``moe_shared``). The norm is as the
+    config's are (``cfg.zero_centred_norm``).
     ``score="sigmoid"`` and ``bias`` (a ``router_bias`` that takes part in
     the choice alone, float32, no optimizer's) are LFM2's router,
     ``renorm_eps`` names its field. ``balance``: a layer reports
@@ -611,11 +642,14 @@ def routed_part(shared: bool = False, score: str = "softmax",
     def held(cfg):
         return getattr(cfg, "experts_held", None)
 
+    def headroom(cfg):
+        return getattr(cfg, "held_headroom", None)
+
     def leaves(cfg):
         h, E, f = cfg.hidden_size, cfg.num_experts, getattr(cfg, width)
         here = held(cfg)[1] if held(cfg) else E
         experts = ("expert", "embed", "mlp")
-        out = {"mlp_norm": Leaf((h,), "ones", ("embed",)),
+        out = {"mlp_norm": Leaf((h,), norm_start(cfg), ("embed",)),
                "router": Leaf((h, E), h, ("embed", None))}
         if bias:
             out["router_bias"] = Leaf((E,), "zeros_float32", (None,))
@@ -627,17 +661,25 @@ def routed_part(shared: bool = False, score: str = "softmax",
             out.update(s_gate=Leaf((h, sf), h, ("embed", "mlp")),
                        s_up=Leaf((h, sf), h, ("embed", "mlp")),
                        s_down=Leaf((sf, h), sf, ("mlp", "embed")))
+        if shared == "gated":
+            out["s_sigmoid"] = Leaf((h,), h, ("embed",))
         return out
 
     def body(cfg, x, p, ctx):
         dt = cfg.dtype
         with jax.named_scope("mlp"):
-            h2 = rms_norm(x, p["mlp_norm"], cfg.rms_norm_eps)
+            h2 = rms_norm(x, p["mlp_norm"], cfg.rms_norm_eps,
+                          cfg.zero_centred_norm)
             if shared:
                 with jax.named_scope("moe_shared"):
                     beside = swiglu(h2, p["s_gate"].astype(dt),
                                     p["s_up"].astype(dt),
                                     p["s_down"].astype(dt))
+                    if shared == "gated":
+                        with jax.named_scope("moe_shared_gate"):
+                            # looked up at trace time (delta_moe_limits.py)
+                            beside = _token_gated(beside, h2,
+                                                  p["s_sigmoid"].astype(dt))
             out, logits, counts, *chosen = routed_experts_on(
                 ctx.mesh, h2, p["router"], p["e_gate"], p["e_up"],
                 p["e_down"], cfg.top_k, renormalize=renormalize,
@@ -646,7 +688,8 @@ def routed_part(shared: bool = False, score: str = "softmax",
                 renorm_eps=getattr(cfg, renorm_eps) if renorm_eps else 0.0,
                 keep_choices=by_sequence or (
                     (bias or groups) and ctx.keep_router_logits),
-                groups=(cfg.n_group, cfg.topk_group) if groups else None)
+                groups=(cfg.n_group, cfg.topk_group) if groups else None,
+                headroom=headroom(cfg))
             if by_sequence:
                 with jax.named_scope("moe_route"):
                     b, E = x.shape[0], counts.shape[0]
@@ -678,7 +721,8 @@ def routed_part(shared: bool = False, score: str = "softmax",
             # a pass's rows alone are gathered and multiplied, and the
             # passes add into two float32 [T, h] sums; nothing of a pass is
             # kept (``_held_experts``: its residuals are its inputs)
-            pairs = _held_chunk(pairs, held(cfg)[1], shape["router"][-1])
+            pairs = _held_chunk(pairs, held(cfg)[1], shape["router"][-1],
+                                headroom(cfg))
             rows = 2 * tokens * h * 4
         # the rows and their gradient, the three [pairs, f] arrays of the
         # experts' SwiGLU and theirs

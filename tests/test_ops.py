@@ -774,6 +774,54 @@ def test_held_chunk_is_the_share_and_a_headroom_in_whole_tiles(
     assert chunk <= max(1.25 * share, share + tile)
 
 
+@pytest.mark.parametrize("headroom, want", [
+    (None, 46080), (8, 46080), (4, 51200), (3, 54784), (2, 61440)])
+def test_a_configurations_headroom_sets_the_pass(headroom, want):
+    """train-qwen3-next-1chip's layer (32,768 tokens, 10 of 512 experts a
+    token, 64 held): the op's own part is an eighth over the share of
+    40,960 rows; a configuration that says how far its loads lie from
+    balance (``held_headroom``) gets that part, in whole tiles."""
+    from ray_tpu.ops import moe
+
+    chunk = moe._held_chunk(32768 * 10, 64, 512, headroom)
+    assert chunk == want and chunk % moe._ROW_TILE == 0
+    counts = np.zeros((1, 512), np.int64)
+    counts[0, 0], counts[0, 64] = 46081, 32768 * 10 - 46081
+    assert moe.rows_passed(counts, (0, 64), headroom) == \
+        (2 if want == 46080 else 1) * want
+
+
+def test_a_wider_pass_gives_the_same_sums_in_fewer_passes():
+    """768 held rows (every token chooses experts 4..7 of 16) in three
+    passes of the op's own 256 rows and in two of 512 (the share of 192
+    and as much again, in whole tiles) under a headroom of one part in
+    one: the result and every gradient agree, and
+    ``rows_passed`` counts each."""
+    from ray_tpu.ops import moe
+
+    *args, cot = _wide_routed_inputs((4, 8))
+    assert (moe._held_chunk(768, 4, 16), moe._held_chunk(768, 4, 16, 1)) \
+        == (256, 512)
+
+    def both(headroom):
+        kw = dict(renormalize=True, scale=2.5, headroom=headroom)
+        with jax.default_matmul_precision("highest"):
+            out, _, counts = jax.jit(
+                lambda *a: _held_share((4, 4), *a, 4, **kw))(*args)
+            grads = jax.jit(jax.grad(
+                lambda *a: (_held_share((4, 4), *a, 4, **kw)[0] * cot).sum(),
+                argnums=(0, 1, 2, 3, 4)))(*args)
+        passed = moe.rows_passed(np.asarray(counts)[None], (4, 4), headroom)
+        return passed, (out,) + grads
+
+    (three, narrow), (two, wide) = both(None), both(1)
+    assert (three, two) == (3 * 256, 2 * 512)
+    for a, b in zip(narrow, wide):
+        np.testing.assert_allclose(
+            np.asarray(a), np.asarray(b), rtol=1e-5,
+            atol=1e-6 * max(10.0, float(jnp.abs(a).max())))
+
+
 def test_held_pass_is_one_kept_span_of_a_traced_held_layer():
     """Tracing a layer that holds a share writes what a pass will take
     once, as a kept span (no flag, no profiler window): the pairs, the
@@ -1980,3 +2028,43 @@ def test_gated_delta_mixer_is_float32_inside_and_names_its_scopes(
     for scope in ("gdn_in", "gdn_conv", "gdn_rule", "gdn_norm", "gdn_out"):
         assert f"jvp(gdn)/{scope}" in text, scope
         assert f"transpose(jvp(gdn))/{scope}" in text, scope
+
+
+# ---- the options Qwen3-Next's table asks for (models/qwen3_next.py)
+
+
+def test_rms_norm_zero_centred_scales_by_one_plus_the_weight():
+    from ray_tpu.ops.layers import rms_norm
+
+    x = jax.random.normal(jax.random.PRNGKey(0), (3, 5, 16))
+    w = 0.2 * jax.random.normal(jax.random.PRNGKey(1), (16,))
+    want = x / jnp.sqrt(jnp.mean(x * x, -1, keepdims=True) + 1e-6) * (1 + w)
+    np.testing.assert_allclose(rms_norm(x, w, 1e-6, zero_centred=True), want,
+                               rtol=1e-6, atol=1e-6)
+    # a weight of zeros is the unit scale, and the default is the plain norm
+    np.testing.assert_array_equal(
+        rms_norm(x, jnp.zeros(16), zero_centred=True),
+        rms_norm(x, jnp.ones(16)))
+    np.testing.assert_array_equal(rms_norm(x, w), rms_norm(x, w, 1e-6, False))
+    # in bfloat16 the one is added in float32: a weight of 2^-9 is not lost
+    small = jnp.full((16,), 2.0 ** -9, jnp.bfloat16)
+    xb = jnp.ones((1, 16), jnp.float32)
+    assert float(rms_norm(xb, small, 0.0, True)[0, 0]) == 1 + 2.0 ** -9
+
+
+def test_partial_rope_at_a_quarter_rotates_the_first_dims_alone():
+    from ray_tpu.ops.layers import apply_rope, rope_frequencies
+
+    x = jax.random.normal(jax.random.PRNGKey(2), (1, 12, 2, 16))
+    cos, sin = rope_frequencies(4, 12, 10_000_000.0)
+    got = apply_rope(x, cos, sin)
+    np.testing.assert_array_equal(got[..., 4:], x[..., 4:])
+    ang = jnp.arange(12.0)[:, None] * (1.0 / 10_000_000.0 ** (
+        jnp.arange(0, 4, 2) / 4))[None]
+    c, s = jnp.cos(ang)[None, :, None], jnp.sin(ang)[None, :, None]
+    x1, x2 = x[..., :2], x[..., 2:4]
+    np.testing.assert_allclose(got[..., :2], x1 * c - x2 * s, atol=1e-6)
+    np.testing.assert_allclose(got[..., 2:4], x2 * c + x1 * s, atol=1e-6)
+    # position 0 is not rotated; a later one is
+    np.testing.assert_allclose(got[:, 0], x[:, 0], atol=1e-7)
+    assert float(jnp.abs(got[:, 5, :, :4] - x[:, 5, :, :4]).max()) > 1e-2

@@ -939,3 +939,106 @@ def test_a_table_no_model_ships_trains_and_gets_a_plan(monkeypatch):
     assert jax.tree_util.tree_structure(
         model.param_shardings(cfg, mesh)) == \
         jax.tree_util.tree_structure(params)
+
+
+# ---- the options Qwen3-Next's table asks for, and what they leave alone
+
+
+def test_attention_block_with_an_elementwise_gate_in_wq():
+    """``attention_part(gate="elementwise")``: ``wq`` gives a head its
+    query and then its gate; the block is the ungated block on the query
+    columns with ``sigmoid(gate)`` on each head's output before ``wo``."""
+    from ray_tpu.ops.layers import Ctx
+
+    cfg = llama.LlamaConfig.tiny(attn_impl="reference", head_dim=16)
+    part = llama.attention_part(gate="elementwise", rope=None)
+    plain = llama.attention_part(rope=None)
+    leaves = part.leaves(cfg)
+    assert leaves["wq"].shape == (64, 2 * 64) and "wg" not in leaves
+    keys = jax.random.split(jax.random.PRNGKey(0), len(leaves) + 1)
+    p = {n: (jnp.ones(l.shape) if l.start == "ones" else
+             jax.random.normal(k, l.shape) / 8)
+         for k, (n, l) in zip(keys, leaves.items())}
+    x = jax.random.normal(keys[-1], (2, 12, 64))
+    with jax.default_matmul_precision("highest"):
+        got, _ = part.body(cfg, x, p, Ctx(None, {}))
+        by_head = p["wq"].reshape(64, 4, 2, 16)
+        q_only = {**p, "wq": by_head[:, :, 0].reshape(64, 64)}
+        # wo = identity: the ungated heads' outputs themselves
+        heads, _ = plain.body(cfg, x, {**q_only, "wo": jnp.eye(64)},
+                              Ctx(None, {}))
+        u = llama.rms_norm(x, p["attn_norm"], cfg.rms_norm_eps)
+        gate = jax.nn.sigmoid(u @ by_head[:, :, 1].reshape(64, 64))
+        want = x + ((heads - x) * gate) @ p["wo"]
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+    assert float(jnp.abs(gate - 0.5).max()) > 0.1
+    assert part.keeps(cfg, {n: l.shape for n, l in leaves.items()}, 12,
+                      None)["rungs"][0] == 12 * (64 * 4 + 4 * 4)
+
+
+def test_routed_part_with_a_gated_shared_expert():
+    """``routed_part(shared="gated")`` is ``shared=True`` with the shared
+    expert's output times ``sigmoid(u . s_sigmoid)``, a number a token."""
+    from ray_tpu.models.laguna import LagunaConfig
+    from ray_tpu.ops.layers import Ctx, rms_norm, swiglu
+    from ray_tpu.ops.moe import routed_part
+
+    cfg = LagunaConfig.tiny()
+    gated, plain = routed_part(shared="gated"), routed_part(shared=True)
+    leaves = gated.leaves(cfg)
+    assert list(leaves)[-1] == "s_sigmoid" and leaves["s_sigmoid"].shape == (
+        64,)
+    assert list(leaves)[:-1] == list(plain.leaves(cfg))
+    keys = jax.random.split(jax.random.PRNGKey(1), len(leaves) + 1)
+    p = {n: (jnp.ones(l.shape) if l.start == "ones" else
+             jax.random.normal(k, l.shape) / 8)
+         for k, (n, l) in zip(keys, leaves.items())}
+    x = jax.random.normal(keys[-1], (2, 12, 64))
+    with jax.default_matmul_precision("highest"):
+        got, said = gated.body(cfg, x, p, Ctx(None, {}))
+        base, _ = plain.body(cfg, x, p, Ctx(None, {}))
+        u = rms_norm(x, p["mlp_norm"], cfg.rms_norm_eps)
+        shared = swiglu(u, p["s_gate"], p["s_up"], p["s_down"])
+        gate = jax.nn.sigmoid(u @ p["s_sigmoid"])[..., None]
+    np.testing.assert_allclose(got, base - shared + gate * shared,
+                               rtol=1e-5, atol=1e-5)
+    assert float(jnp.abs(gate - 0.5).max()) > 0.1
+    assert said["router"]["counts"].shape == (16,)
+
+
+# (model, the held share) -> what the parent commit's forward and loss gave
+# on these seeds, float32 on the CPU: the loss, three logits and the sum of
+# all logits' sizes. On the machine this was written on, the logits and
+# every leaf's gradient were bit-equal to the parent's (CHANGES.md, PR 50).
+_PARENTS = {
+    "olmo_hybrid": (6.14553165435791,
+                    (-0.5216737985610962, 0.50602787733078,
+                     -0.5190625190734863), 12633.048828125),
+    "laguna": (5.79429292678833,
+               (-1.1803277730941772, -0.7559819221496582,
+                -1.6450860500335693), 12889.58984375)}
+
+
+@pytest.mark.parametrize("model", sorted(_PARENTS))
+def test_tables_that_take_no_new_option_give_what_they_gave(model):
+    """Olmo-Hybrid's and Laguna's tables name none of the options
+    Qwen3-Next's asks of ``gated_delta_part``, ``attention_part``,
+    ``routed_part`` and ``Stack``: every option defaults to what the code
+    did before."""
+    mod = import_module("ray_tpu.models." + model)
+    how = {"experts_held": (4, 8)} if model == "laguna" else {}
+    cfg = getattr(mod, _CONFIG[model]).tiny(attn_impl="reference", **how)
+    assert not cfg.zero_centred_norm
+    params = mod.init_params(cfg, jax.random.PRNGKey(7))
+    assert float(params["final_norm"].min()) == 1.0
+    tokens = np.random.default_rng(3).integers(0, cfg.vocab_size, (2, 33))
+    logits, _ = jax.jit(lambda p, t: mod.forward(cfg, p, t))(
+        params, tokens[:, :-1])
+    loss = jax.jit(lambda p: mod.loss_fn(cfg, p, {"tokens": jnp.asarray(
+        tokens)}))(params)
+    want_loss, want_logits, want_sum = _PARENTS[model]
+    np.testing.assert_allclose(float(loss), want_loss, rtol=1e-6)
+    np.testing.assert_allclose(np.asarray(logits)[1, [0, 7, 31], 5],
+                               want_logits, rtol=1e-5, atol=1e-6)
+    np.testing.assert_allclose(float(np.abs(np.asarray(logits)).sum()),
+                               want_sum, rtol=1e-6)

@@ -826,3 +826,58 @@ def test_attend_kernels_compile_at_the_cells_shapes(
         assert any(kernel in name for name in names), (kernel, names)
     for text in (forward, gradient):
         assert f"f32[16,{block},16384]" not in text
+
+
+# ---- train-qwen3-next-1chip's kernels at its shapes (PR 50)
+
+
+def test_flash_kernels_compile_at_32k_positions_of_256(S, no_compile_cache):
+    """Qwen3-Next's full layer in its cell: one sequence of 32,768
+    positions, 16 query heads on 2 of 256. A head's whole k and v (forward,
+    dQ) or q and dO (dK/dV) lie in VMEM twice: 67 MB at 256 lanes, the most
+    any cell asks (``_dkv_vmem``), and Mosaic takes all three."""
+    from ray_tpu.ops.attention import flash_attention
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v, use_pallas=True).astype(
+            jnp.float32).sum()
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        S(1, 32768, 16, 256), S(1, 32768, 2, 256),
+        S(1, 32768, 2, 256)).compile().as_text()
+    names = {name for name, _ in _mosaic_calls(text)}
+    assert len(names) == 3
+    for kernel in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"):
+        assert any(kernel in name for name in names), (kernel, names)
+
+
+def test_delta_rule_kernels_compile_at_grouped_heads(S, one_chip,
+                                                    no_compile_cache,
+                                                    monkeypatch):
+    """The rule at ``train-qwen3-next-1chip``'s shapes (1 x 32,768
+    positions, 32 value heads reading 16 key heads' q and k copied to them,
+    keys and values of 128): the plan says how the heads were joined and
+    takes 8 heads a block; Mosaic takes the forward that keeps its states
+    and the backward."""
+    from ray_tpu.ops import delta
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    gates = jax.ShapeDtypeStruct((1, 32768, 32), jnp.float32,
+                                 sharding=one_chip)
+    args = (S(1, 32768, 16, 128), S(1, 32768, 16, 128),
+            S(1, 32768, 32, 128), gates, gates)
+    plan = delta.rule_plan(1, 32768, 32, 128, 128, 64, key_heads=16)
+    assert (plan["form"], plan["heads_a_block"], plan["key_heads"],
+            plan["joined"]) == ("pallas", 8, 16, "repeat")
+
+    def loss(q, k, *a):
+        q, k = (delta._join_heads(x, 32) for x in (q, k))
+        return jnp.square(delta.gated_delta_rule(
+            q, k, *a, chunk=64, key_heads=16)[0].astype(jnp.float32)).sum()
+
+    gradient = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+        *args).compile().as_text()
+    names = [name for name, _ in _mosaic_calls(gradient)]
+    assert len(names) == 2, names
+    for kernel in ("delta_rule_fwd", "delta_rule_bwd"):
+        assert any(kernel in name for name in names), (kernel, names)

@@ -993,12 +993,12 @@ def test_granite_train_step_names_its_scopes_and_counts_its_state():
 
 def test_gdn_rule_plan_is_one_kept_span_of_a_traced_call(monkeypatch):
     """A traced ``gated_delta_rule`` writes what it will do once, as a kept
-    span (no flag, no profiler window), as ``rtpu.ssm.scan_plan`` is
-    written: sequence, chunk, chunks, heads, a head's key and value sizes,
-    the form that runs, what a step of it takes, the states a backward
-    keeps and the float32 bytes the form puts in HBM beside what all
-    chunks' pair matrices at once would: XLA's walk on the CPU and under
-    a mesh, the kernels on a TPU backend without one."""
+    span (no flag, no profiler window), as ``rtpu.ssm.scan_plan`` is written:
+    sequence, chunk, chunks, the value heads, the key heads and how they were
+    joined, a head's key and value sizes, the form that runs, what a step of it
+    takes, the states a backward keeps and the float32 bytes the form puts in
+    HBM beside what all chunks' pair matrices at once would: XLA's walk on the
+    CPU and under a mesh, the kernels on a TPU backend without one."""
     import jax
     import jax.numpy as jnp
 
@@ -1023,8 +1023,9 @@ def test_gdn_rule_plan_is_one_kept_span_of_a_traced_call(monkeypatch):
     (cell,) = trace(32768)
     one = 30 * 4 * (4 * 64 * 64 + 192 * 96)
     walk = {"seq": 32768, "chunk": 64, "chunks": 512, "walk": 8,
-            "steps": 64, "heads": 30, "key_dim": 96, "value_dim": 192,
-            "form": "xla_walk", "heads_a_block": None, "chunks_a_call": 8,
+            "steps": 64, "heads": 30, "key_heads": 30, "joined": None,
+            "key_dim": 96, "value_dim": 192, "form": "xla_walk",
+            "heads_a_block": None, "chunks_a_call": 8,
             "states_kept": 64, "float32_bytes_in_hbm": 8 * one,
             "float32_bytes_all_chunks": 512 * one}
     assert cell == walk
