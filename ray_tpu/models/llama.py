@@ -35,9 +35,9 @@ from jax.ad_checkpoint import checkpoint_name
 
 from ray_tpu.ops.attention import attention_reference, flash_attention
 from ray_tpu.ops.layers import (Leaf, Part, apply_rope, blocked_head_loss,
-                                blocked_head_nll, head_block, kept,
-                                norm_start, rms_norm, rope_frequencies,
-                                swiglu, swiglu_part)
+                                blocked_head_nll, embed_rows, head_block,
+                                kept, norm_start, rms_norm,
+                                rope_frequencies, swiglu, swiglu_part)
 from ray_tpu.ops.ring_attention import ring_attention
 from ray_tpu.util import tracing
 
@@ -781,7 +781,7 @@ def forward(cfg: LlamaConfig, params: Dict[str, Any], tokens: jax.Array,
             mesh=None) -> jax.Array:
     """tokens [b, s] int32 → logits [b, s, vocab] float32."""
     with jax.named_scope("embed"):
-        x = params["embed"].astype(cfg.dtype)[tokens]
+        x = embed_rows(params["embed"], tokens, cfg.dtype, mesh)
         if cfg.embed_scale != 1.0:
             x = x * jnp.asarray(cfg.embed_scale, cfg.dtype)
         cos, sin = rope_frequencies(cfg.head_dim_, tokens.shape[1],
@@ -920,7 +920,7 @@ def loss_fn_pp(cfg: LlamaConfig, params, batch: Dict[str, jax.Array],
     b, s = inputs.shape
     M = num_microbatches
     assert b % M == 0, f"batch {b} must divide into {M} microbatches"
-    x = params["embed"].astype(cfg.dtype)[inputs]
+    x = embed_rows(params["embed"], inputs, cfg.dtype, mesh)
     if cfg.embed_scale != 1.0:
         x = x * jnp.asarray(cfg.embed_scale, cfg.dtype)
     cos, sin = rope_frequencies(cfg.head_dim_, s, cfg.rope_theta,

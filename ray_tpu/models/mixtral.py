@@ -26,7 +26,7 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.models import llama
-from ray_tpu.ops.layers import rms_norm, rope_frequencies
+from ray_tpu.ops.layers import embed_rows, rms_norm, rope_frequencies
 from ray_tpu.ops.moe import routed_experts
 
 
@@ -126,7 +126,7 @@ def forward(cfg: MixtralConfig, params, tokens: jax.Array, mesh=None
             ) -> Tuple[jax.Array, jax.Array]:
     """tokens [b, s] -> (logits [b, s, vocab] fp32, aux_loss scalar)."""
     with jax.named_scope("embed"):
-        x = params["embed"].astype(cfg.dtype)[tokens]
+        x = embed_rows(params["embed"], tokens, cfg.dtype, mesh)
         cos, sin = rope_frequencies(cfg.head_dim_, tokens.shape[1],
                                     cfg.rope_theta, dtype=cfg.dtype,
                                     scaling=cfg.rope_scaling_dict)

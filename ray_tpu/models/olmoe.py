@@ -27,7 +27,7 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.models import llama, mixtral
-from ray_tpu.ops.layers import rms_norm, rope_frequencies
+from ray_tpu.ops.layers import embed_rows, rms_norm, rope_frequencies
 from ray_tpu.ops.moe import (routed_experts_on, routed_part, router_losses,
                              router_stats)
 
@@ -106,7 +106,7 @@ def forward(cfg: OlmoeConfig, params, tokens: jax.Array, mesh=None,
     (mean router probability), ``z [L]`` (mean squared logsumexp of the
     router logits) and, asked for, ``logits [L, b * s, E]``."""
     with jax.named_scope("embed"):
-        x = params["embed"].astype(cfg.dtype)[tokens]
+        x = embed_rows(params["embed"], tokens, cfg.dtype, mesh)
         cos, sin = rope_frequencies(cfg.head_dim_, tokens.shape[1],
                                     cfg.rope_theta, dtype=cfg.dtype,
                                     scaling=cfg.rope_scaling_dict)

@@ -34,7 +34,7 @@ import jax.numpy as jnp
 
 from ray_tpu.models import llama, mixtral
 from ray_tpu.ops import moe
-from ray_tpu.ops.layers import Ctx, Part, norm_start
+from ray_tpu.ops.layers import Ctx, Part, embed_rows, norm_start
 
 
 def draw(cfg, key: jax.Array, shape: Tuple[int, ...], start) -> jax.Array:
@@ -208,7 +208,7 @@ class Stack:
         inputs and their packed choice of keys too (``ops/mla.py``)."""
         pattern = cfg.pattern
         with jax.named_scope("embed"):
-            x = params["embed"].astype(cfg.dtype)[tokens]
+            x = embed_rows(params["embed"], tokens, cfg.dtype, mesh)
             if self.embed_scale:
                 x = x * jnp.asarray(getattr(cfg, self.embed_scale),
                                     cfg.dtype)

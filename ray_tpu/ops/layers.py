@@ -21,6 +21,8 @@ import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
+from ray_tpu.util import tracing
+
 
 class Leaf(NamedTuple):
     """One parameter of one layer. ``start``: a fan-in (truncated normal
@@ -479,3 +481,122 @@ def blocked_head_loss(x: jax.Array, head: jax.Array, targets: jax.Array,
     loss.defvjp(forward, backward)
     return loss(head, *_head_blocks(block, x, head, targets,
                                     weights.astype(jnp.float32)))
+
+
+def _divisor_tile(size: int, limit: int) -> int:
+    """The largest multiple of 128 that divides ``size`` and is at most
+    ``limit``; ``size`` itself where it fits or has no such divisor."""
+    if size <= limit:
+        return size
+    return next((t for t in range(limit - limit % 128, 0, -128)
+                 if size % t == 0), min(size, limit))
+
+
+# How wide the tokens' float32 sums are where a pass adds its rows to them.
+# XLA's scatter-add of rows on a v5e is no cliff in the width but a sawtooth
+# (``tools/scatter_sweep.py``, PR 44: 3,072 rows into ``[8192, w]`` float32,
+# the table donated, ms a 1,024 columns without the call's 0.6 ms): 0.24 at
+# 1,024 and 1,280, 0.31 at 2,048, then 1.45 at 2,560 and back to 0.28 at
+# 2,816; 0.34 at 3,072, 1.38 at 3,840, 0.25 at 4,096; 0.57, 0.80, 1.14 and
+# **3.05 at 5,120** (15.8 ms the call, 30.5 into 16,384 rows, 22.6 with
+# 36,864 rows: a cost of the table, not of a row), 0.37 at 5,376; 0.49 at
+# 6,144, 3.02 at 7,680, 0.37 at 8,192. The same 5,120 columns in four sums
+# of 1,280 read 2.2 ms for 15.9, in two of 2,560 8.4; 6,144 in six of 1,024
+# 2.4 for 3.6, 8,192 in eight 2.9 for 3.7. So: one sum up to 4,096 columns,
+# the widest width read fast whole, which keeps the statement that Laguna's
+# 3,072 and LFM2's 2,048 columns compile (2.5 ms for 11,520 rows, 3.7 for
+# 36,864); past it blocks of at most 1,280 columns, under which every width
+# read fast. The layer at DeepSeek-V2's shape (8,192 x 5,120, 8 of 160
+# experts): forward 18.8 -> 5.2 ms, backward 25.0 -> 11.1.
+_SUM_WHOLE = 4096
+_SUM_COLUMNS = 1280
+
+
+def _sum_columns(h: int) -> int:
+    """Columns of a block of the tokens' ``[n, h]`` sums: ``h`` itself up to
+    ``_SUM_WHOLE``; past it the largest divisor of ``h`` in whole 128-lane
+    tiles that is at most ``_SUM_COLUMNS`` (``h`` where it has none)."""
+    if h <= _SUM_WHOLE:
+        return h
+    width = _divisor_tile(h, _SUM_COLUMNS)
+    return h if h % width else width
+
+
+def _add_rows(sums, tokens, rows):
+    """``sums`` with ``rows [chunk, h]`` float32 added at ``tokens
+    [chunk]``, block by block of columns (one block: ``sums.at[tokens]
+    .add(rows)``, the slice of all columns traces to nothing)."""
+    width = sums[0].shape[1]
+    return tuple(block.at[tokens].add(rows[:, j * width:(j + 1) * width])
+                 for j, block in enumerate(sums))
+
+
+def _join_sums(sums, dtype):
+    """The blocks side by side, rounded once to ``dtype``."""
+    return jnp.concatenate([block.astype(dtype) for block in sums], axis=1)
+
+
+def embed_plan(rows: int, table_rows: int, columns: int,
+               mesh=None) -> Dict[str, Any]:
+    """What ``embed_rows`` does with ``rows`` tokens into a ``[table_rows,
+    columns]`` table, and in which ``form``: "blocked" where the width is
+    past ``_SUM_WHOLE`` with a divisor for ``_sum_columns`` and no mesh is
+    given (the gradient's rows are added into ``blocks`` tables of
+    ``sum_columns`` columns), "whole" elsewhere (jax's own transpose, one
+    scatter-add of all columns)."""
+    width = columns if mesh is not None else _sum_columns(columns)
+    return {"rows": rows, "table_rows": table_rows, "columns": columns,
+            "sum_columns": width, "blocks": columns // width,
+            "form": "whole" if width == columns else "blocked"}
+
+
+# A token gather's gradient is XLA's scatter-add of the cotangent's rows into
+# a table of zeros, on the same sawtooth (``tools/scatter_sweep.py``'s
+# ``embedding`` part, v5e, PR 51: the gradient alone, its join and cast with
+# it, bfloat16 table and sums, ms whole -> in blocks of ``_sum_columns``):
+# 8,192 tokens into ``[12800, 5120]`` **24.1 -> 3.3** and 16,384 into
+# ``[19008, 5120]`` **37.0 -> 5.7** (four blocks of 1,280); 7,680 columns
+# 36.2 -> 4.4 and 54.4 -> 7.7 (six of 1,280), 6,144 5.1 -> 3.4 and 9.0 -> 6.0
+# (six of 1,024); at 4,096 the whole sum is the faster, 2.4 and 3.7 against
+# 2.6 and 4.3 in four of 1,024, so ``_SUM_WHOLE`` stands for this op too. The
+# teeth under it, which the rule leaves whole (no cell's text may move for
+# them here): 3,840 columns 8.5 -> 2.6 and 13.0 -> 4.3 in three of 1,280, 2,560
+# 6.3 -> 1.9 and 9.3 -> 3.0 in two. Float32 sums rounded once at the join read
+# 0.4-1.4 ms slower than bfloat16 ones in every blocked reading (3.8 and 6.5
+# at 5,120), so the sums stay in the cotangent's dtype, as jax's transpose
+# holds its one.
+def embed_rows(table: jax.Array, tokens: jax.Array, dtype,
+               mesh=None) -> jax.Array:
+    """``table.astype(dtype)[tokens]``: table [rows, columns], tokens any
+    shape of ints -> [*tokens.shape, columns]. Where ``embed_plan`` says
+    "whole" (a width within ``_SUM_WHOLE``, one without a divisor, any width
+    under a ``mesh``) it is that expression and nothing around it. Past it
+    the gather has a transpose of its own: the cotangent's rows, every
+    token's, a repeated token's each time, are added in the cotangent's
+    dtype (what jax's transpose holds its one sum in) into ``blocks`` tables
+    of zeros of ``sum_columns`` columns, joined and converted to the table's
+    dtype, under the scope ``embed``. The kept span ``rtpu.embed.plan`` of a
+    traced call says which."""
+    plan = embed_plan(tokens.size, *table.shape, mesh)
+    with tracing.span("rtpu.embed.plan", keep=True, **plan):
+        pass
+
+    def plain(table, tokens):
+        return table.astype(dtype)[tokens]
+
+    if plan["form"] == "whole":
+        return plain(table, tokens)
+
+    def backward(tokens, ct):
+        with jax.named_scope("embed"):
+            sums = tuple(
+                jnp.zeros((plan["table_rows"], plan["sum_columns"]), ct.dtype)
+                for _ in range(plan["blocks"]))
+            sums = _add_rows(sums, tokens.reshape(-1),
+                             ct.reshape(-1, plan["columns"]))
+            return _join_sums(sums, table.dtype), None
+
+    gather = jax.custom_vjp(plain)
+    gather.defvjp(lambda table, tokens: (plain(table, tokens), tokens),
+                  backward)
+    return gather(table, tokens)

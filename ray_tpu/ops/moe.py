@@ -77,8 +77,9 @@ import jax.numpy as jnp
 import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 
-from ray_tpu.ops.layers import (Leaf, Part, kept, norm_start, rms_norm,
-                                swiglu, swiglu_kept)
+from ray_tpu.ops.layers import (Leaf, Part, _add_rows, _divisor_tile,
+                                _join_sums, _sum_columns, kept, norm_start,
+                                rms_norm, swiglu, swiglu_kept)
 from ray_tpu.util import tracing
 
 
@@ -128,15 +129,6 @@ _place.defvjp(lambda values, to: (_place(values, to), to),
 # empty too: at 3072 a tile of 2048 cost 12-19% over one of 1536 (v5e,
 # 16 groups of 640 and 1,280 rows, PR 30), so both are divisors.
 _ROW_TILE = 256
-
-
-def _divisor_tile(size: int, limit: int) -> int:
-    """The largest multiple of 128 that divides ``size`` and is at most
-    ``limit``; ``size`` itself where it fits or has no such divisor."""
-    if size <= limit:
-        return size
-    return next((t for t in range(limit - limit % 128, 0, -128)
-                 if size % t == 0), min(size, limit))
 
 
 def _gmm_tiles(k: int, n: int) -> Tuple[int, int, int]:
@@ -321,56 +313,12 @@ def rows_passed(expert_counts, held: Optional[Tuple[int, int]],
     return int((-(-rows // chunk) * chunk).sum())
 
 
-# How wide the tokens' float32 sums are where a pass adds its rows to them.
-# XLA's scatter-add of rows on a v5e is no cliff in the width but a sawtooth
-# (``tools/scatter_sweep.py``, PR 44: 3,072 rows into ``[8192, w]`` float32,
-# the table donated, ms a 1,024 columns without the call's 0.6 ms): 0.24 at
-# 1,024 and 1,280, 0.31 at 2,048, then 1.45 at 2,560 and back to 0.28 at
-# 2,816; 0.34 at 3,072, 1.38 at 3,840, 0.25 at 4,096; 0.57, 0.80, 1.14 and
-# **3.05 at 5,120** (15.8 ms the call, 30.5 into 16,384 rows, 22.6 with
-# 36,864 rows: a cost of the table, not of a row), 0.37 at 5,376; 0.49 at
-# 6,144, 3.02 at 7,680, 0.37 at 8,192. The same 5,120 columns in four sums
-# of 1,280 read 2.2 ms for 15.9, in two of 2,560 8.4; 6,144 in six of 1,024
-# 2.4 for 3.6, 8,192 in eight 2.9 for 3.7. So: one sum up to 4,096 columns,
-# the widest width read fast whole, which keeps the statement that Laguna's
-# 3,072 and LFM2's 2,048 columns compile (2.5 ms for 11,520 rows, 3.7 for
-# 36,864); past it blocks of at most 1,280 columns, under which every width
-# read fast. The layer at DeepSeek-V2's shape (8,192 x 5,120, 8 of 160
-# experts): forward 18.8 -> 5.2 ms, backward 25.0 -> 11.1.
-_SUM_WHOLE = 4096
-_SUM_COLUMNS = 1280
-
-
-def _sum_columns(h: int) -> int:
-    """Columns of a block of the tokens' ``[n, h]`` sums: ``h`` itself up to
-    ``_SUM_WHOLE``; past it the largest divisor of ``h`` in whole 128-lane
-    tiles that is at most ``_SUM_COLUMNS`` (``h`` where it has none)."""
-    if h <= _SUM_WHOLE:
-        return h
-    width = _divisor_tile(h, _SUM_COLUMNS)
-    return h if h % width else width
-
-
 def _zero_sums(n: int, h: int):
     """The tokens' sums before a pass: float32 ``[n, _sum_columns(h)]``
     blocks of columns, carried apart."""
     width = _sum_columns(h)
     return tuple(jnp.zeros((n, width), jnp.float32)
                  for _ in range(h // width))
-
-
-def _add_rows(sums, tokens, rows):
-    """``sums`` with ``rows [chunk, h]`` float32 added at ``tokens
-    [chunk]``, block by block of columns (one block: ``sums.at[tokens]
-    .add(rows)``, the slice of all columns traces to nothing)."""
-    width = sums[0].shape[1]
-    return tuple(block.at[tokens].add(rows[:, j * width:(j + 1) * width])
-                 for j, block in enumerate(sums))
-
-
-def _join_sums(sums, dtype):
-    """The blocks side by side, rounded once to ``dtype``."""
-    return jnp.concatenate([block.astype(dtype) for block in sums], axis=1)
 
 
 def _held_passes(sizes, chunk: int):

@@ -11,22 +11,29 @@ that way, cost over the width of a block of their sums.
   twice, any order; where the table has the rows for it). The table is
   donated and handed on from call to call, as a loop's carry is.
 - ``blocked``: the same rows added to ``columns / width`` tables of
-  ``width`` columns each, the rows sliced by columns (``moe._add_rows``).
+  ``width`` columns each, the rows sliced by columns (``layers._add_rows``).
 - ``onehot``: the rows added by a product with their 0/1 token matrix
   (``[table_rows, rows] x [rows, columns]``, bfloat16 operands, float32
   sums): the form for a thin share if the width were no cliff.
-- ``embedding``: bfloat16 rows into a bfloat16 table at random tokens, a
-  token gather's gradient (``models/stack.py``'s ``embed``).
+- ``embedding``: a token gather's gradient (``ops/layers.embed_rows``, the
+  models' ``embed``): the cotangent's rows of ``--embed-tokens`` random
+  tokens (repeats among them) added into a bfloat16 ``[--embed-table-rows,
+  --embed-columns]`` table, ``whole`` (jax's transpose: one scatter-add of
+  all columns) beside ``blocked`` (the function's own transpose at the
+  block ``_sum_columns`` would give that width past ``_SUM_WHOLE``, its
+  join and its cast with it), the sums held in bfloat16 and in float32.
 - ``op``: ``routed_experts(held=(0, count))`` forward and gradient at
   ``train-deepseek-v2-1chip``'s shape (8,192 tokens of 5,120, 8 of 160
   experts of 1,536, 6 a token of 3 of 8 groups), milliseconds a layer for
   the forward and for the gradient's program (the router and the backward's
   passes: a pass's residuals are its inputs, so it holds no forward pass) at
-  each ``--sum-columns`` (``moe._SUM_WHOLE`` and ``moe._SUM_COLUMNS`` both
+  each ``--sum-columns`` (``layers._SUM_WHOLE`` and ``._SUM_COLUMNS`` both
   set to it: one sum up to that width, past it the largest divisor under).
 
     python3 ray_tpu/tools/scatter_sweep.py [--columns 2048,5120] \
-        [--rows 3072] [--table-rows 8192] [--sum-columns 8192,2560]
+        [--rows 3072] [--table-rows 8192] [--sum-columns 8192,2560] \
+        [--embed-columns 5120] [--embed-table-rows 12800,19008] \
+        [--embed-tokens 8192,16384] [--skip scatter,blocked,onehot,op]
 
 Every array is an argument of the jitted call (a closed-over one compiles
 into the executable: PERF.md 5, PR 35); a time is the wall clock around
@@ -95,7 +102,10 @@ def main() -> None:
     ap.add_argument("--width", type=int, default=1536)
     ap.add_argument("--top-k", type=int, default=6)
     ap.add_argument("--groups", type=_ints, default=[8, 3])
-    ap.add_argument("--embed-rows", type=int, default=12800)
+    ap.add_argument("--embed-columns", type=_ints,
+                    default=[2560, 3840, 4096, 5120, 6144, 7680])
+    ap.add_argument("--embed-table-rows", type=_ints, default=[12800, 19008])
+    ap.add_argument("--embed-tokens", type=_ints, default=[8192, 16384])
     ap.add_argument("--calls", type=int, default=7)
     ap.add_argument("--skip", default="",
                     help="parts left out: scatter,blocked,onehot,embedding,op")
@@ -107,7 +117,7 @@ def main() -> None:
     import jax.numpy as jnp
     import numpy as np
 
-    from ray_tpu.ops import moe
+    from ray_tpu.ops import layers, moe
 
     f32, bf16 = jnp.float32, jnp.bfloat16
     rng = np.random.default_rng(0)
@@ -158,7 +168,7 @@ def main() -> None:
                             table_rows=table_rows, order=order, ms=t,
                             us_a_row=t * 1e3 / rows)
 
-    blocked = jax.jit(moe._add_rows, donate_argnums=0)
+    blocked = jax.jit(layers._add_rows, donate_argnums=0)
     if "blocked" not in skip:
         for columns in a.blocked_columns:
             for rows in a.rows[:2]:
@@ -191,15 +201,34 @@ def main() -> None:
                     table_rows=a.tokens, ms=t, us_a_row=t * 1e3 / rows)
 
     if "embedding" not in skip:
-        for columns in a.columns:
-            for dtype in (bf16, f32):
-                t = ms(add, jnp.zeros((a.embed_rows, columns), dtype),
-                       jnp.asarray(_indices(rng, "random", a.tokens,
-                                            a.embed_rows)),
-                       updates(a.tokens, columns, dtype))
-                say("embedding", columns=columns, rows=a.tokens,
-                    table_rows=a.embed_rows, dtype=jnp.dtype(dtype).name,
-                    ms=t, us_a_row=t * 1e3 / a.tokens)
+        def gradient(dtype):    # a new function: jit traces it anew
+            def d_table(table, tokens, ct):
+                return jax.vjp(lambda t: layers.embed_rows(t, tokens, dtype),
+                               table)[1](ct)[0]
+            return jax.jit(d_table)
+
+        rule = layers._SUM_WHOLE
+        for columns in a.embed_columns:
+            for table_rows in a.embed_table_rows:
+                table = jnp.zeros((table_rows, columns), bf16)
+                for rows in a.embed_tokens:
+                    tokens = jnp.asarray(_indices(rng, "random", rows,
+                                                  table_rows))
+                    for dtype in (bf16, f32):
+                        ct = updates(rows, columns, dtype)
+                        # no width is past its own, every width is past 0
+                        for whole in (columns, 0):
+                            layers._SUM_WHOLE = whole
+                            plan = layers.embed_plan(rows, table_rows,
+                                                     columns)
+                            t = ms(gradient(dtype), None, table, tokens, ct)
+                            say("embedding", columns=columns, rows=rows,
+                                table_rows=table_rows,
+                                sums=jnp.dtype(dtype).name,
+                                form=plan["form"],
+                                sum_columns=plan["sum_columns"], ms=t,
+                                us_a_row=t * 1e3 / rows)
+        layers._SUM_WHOLE = rule
 
     if "op" not in skip:
         n, h, E, f = a.tokens, a.hidden, a.experts, a.width
@@ -216,7 +245,7 @@ def main() -> None:
         groups = tuple(a.groups) if a.groups else None
 
         for limit in a.sum_columns:
-            moe._SUM_WHOLE = moe._SUM_COLUMNS = limit
+            layers._SUM_WHOLE = layers._SUM_COLUMNS = limit
 
             def layer(*xs):     # a new function: jit traces it anew
                 return moe.routed_experts(*xs, a.top_k, held=(0, a.held),
@@ -228,8 +257,8 @@ def main() -> None:
                              * xs[-1].astype(f32)).sum(),
                 argnums=(0, 1, 2, 3, 4)))
             held_rows = int(forward(*args)[2][:a.held].sum())
-            say("op", sum_columns=limit, block=moe._sum_columns(h),
-                blocks=h // moe._sum_columns(h), held_rows=held_rows,
+            say("op", sum_columns=limit, block=layers._sum_columns(h),
+                blocks=h // layers._sum_columns(h), held_rows=held_rows,
                 chunk=moe._held_chunk(n * a.top_k, a.held, E),
                 forward_ms=ms(forward, None, *args),
                 gradient_ms=ms(gradient, None, *args, cot))

@@ -31,11 +31,12 @@ distinct selective scan, from its ``rtpu.ssm.scan_plan`` span,
 ``conv_plan``: of the taps before it, from ``rtpu.ssm.conv_plan``,
 ``rule_plan`` and ``gdn_conv_plan``: the same of each distinct gated delta
 rule and of its taps, from ``rtpu.gdn.rule_plan`` and
-``rtpu.gdn.conv_plan``, and ``scopes``: how many instructions
-carry each ``jax.named_scope`` name as the innermost). ``--compare``
-judges the program (``PROGRAM_FIELDS``) and says of two differing
-programs how many lines changed and how many of those are calls of the
-flash kernels.
+``rtpu.gdn.conv_plan``, ``embed_plan``: the token gather's and the form
+of its gradient, from ``rtpu.embed.plan``, and ``scopes``: how many
+instructions carry each ``jax.named_scope`` name as the innermost).
+``--compare`` judges the program (``PROGRAM_FIELDS``) and says of two
+differing programs how many lines changed and how many of those are calls
+of the flash kernels.
 """
 
 from __future__ import annotations
@@ -171,6 +172,8 @@ def _compile_cell(tree: str, cell: dict, topo) -> dict:
     # and one a distinct gated delta rule, and its taps
     rules = distinct("rtpu.gdn.rule_plan")
     rule_taps = distinct("rtpu.gdn.conv_plan")
+    # and the token gather: whether its gradient adds in column blocks
+    embeds = distinct("rtpu.embed.plan")
     full = compiled.as_text()
     scopes = {}
     for path in OP_NAME.findall(full):
@@ -200,6 +203,7 @@ def _compile_cell(tree: str, cell: dict, topo) -> dict:
         "conv_plan": taps,
         "rule_plan": rules,
         "gdn_conv_plan": rule_taps,
+        "embed_plan": embeds,
         "scopes": dict(sorted(scopes.items())),
         "memory_analysis": {f: getattr(ma, f) for f in MEMORY_FIELDS}}}
 

@@ -645,10 +645,10 @@ def test_held_experts_sum_their_rows_in_column_blocks(
     over experts 4..7 of 16, where a balanced router fills one pass, where
     every token chooses the four held (768 rows, three passes of 256) and
     where none does."""
-    from ray_tpu.ops import moe
+    from ray_tpu.ops import layers, moe
 
-    monkeypatch.setattr(moe, "_SUM_WHOLE", limit)
-    monkeypatch.setattr(moe, "_SUM_COLUMNS", limit)
+    monkeypatch.setattr(layers, "_SUM_WHOLE", limit)
+    monkeypatch.setattr(layers, "_SUM_COLUMNS", limit)
     assert moe._sum_columns(768) * blocks == 768
     *args, cot = _wide_routed_inputs(toward)
     held, kw = (4, 4), dict(renormalize=True, scale=2.5)
@@ -684,13 +684,13 @@ def test_column_blocks_give_the_one_blocks_bits_where_no_token_repeats(
     depends on the order a scatter takes its rows in) the result and every
     gradient in three blocks are the one block's bit for bit, over three
     passes."""
-    from ray_tpu.ops import moe
+    from ray_tpu.ops import layers, moe
 
     *args, cot = _wide_routed_inputs((4, 8), n=768, top_k=1)
 
     def both(limit):
-        monkeypatch.setattr(moe, "_SUM_WHOLE", limit)
-        monkeypatch.setattr(moe, "_SUM_COLUMNS", limit)
+        monkeypatch.setattr(layers, "_SUM_WHOLE", limit)
+        monkeypatch.setattr(layers, "_SUM_COLUMNS", limit)
         out, _, counts = jax.jit(
             lambda *a: _held_share((4, 4), *a, 1, scale=2.5))(*args)
         grads = jax.jit(jax.grad(
@@ -725,15 +725,15 @@ def test_sum_columns_is_a_divisor_in_whole_lane_tiles(monkeypatch, h, whole,
     constants as they stand (``None``) leave 2,048 and 3,072 columns one
     sum and take 5,120 in four. The kept span of a traced layer carries
     the count."""
-    from ray_tpu.ops import moe
+    from ray_tpu.ops import layers, moe
     from ray_tpu.util import tracing
 
     if whole is not None:
-        monkeypatch.setattr(moe, "_SUM_WHOLE", whole)
-        monkeypatch.setattr(moe, "_SUM_COLUMNS", limit)
+        monkeypatch.setattr(layers, "_SUM_WHOLE", whole)
+        monkeypatch.setattr(layers, "_SUM_COLUMNS", limit)
     width = moe._sum_columns(h)
     assert width == want and h % width == 0
-    assert width == h or (width <= moe._SUM_COLUMNS and width % 128 == 0)
+    assert width == h or (width <= layers._SUM_COLUMNS and width % 128 == 0)
     assert [b.shape for b in moe._zero_sums(8, h)] == [(8, width)] * (
         h // width)
     f32 = jnp.float32
@@ -745,6 +745,112 @@ def test_sum_columns_is_a_divisor_in_whole_lane_tiles(monkeypatch, h, whole,
     (ev,) = [e for e in tracing.chrome_events()[n0:]
              if e["name"] == "rtpu.moe.held_pass"]
     assert ev["args"]["sum_blocks"] == h // want
+
+
+def _one_device_mesh():
+    from jax.sharding import Mesh
+
+    return Mesh(np.array(jax.devices()[:1]), ("dp",))
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("columns, limit, mesh, blocks", [
+    (768, 1024, None, 1), (768, 256, None, 3), (768, 512, None, 2),
+    (700, 256, None, 1), (768, 256, _one_device_mesh, 1),
+], ids=["under", "a-multiple", "not-a-multiple", "no-divisor", "a-mesh"])
+def test_embed_rows_adds_its_gradient_in_column_blocks(
+        monkeypatch, dtype, columns, limit, mesh, blocks):
+    """``embed_rows`` is ``table.astype(dtype)[tokens]`` and, past
+    ``_SUM_WHOLE`` columns with a divisor and no mesh, a ``custom_vjp`` whose
+    backward adds the cotangent's rows into blocks of columns: value and
+    gradient are the plain gather's bit for bit, repeated tokens each time
+    (128 draws of 39 rows) and a row never drawn at zero; within the limit,
+    at a width with no divisor and under a mesh there is nothing around the
+    plain expression; the kept span says which."""
+    from ray_tpu.ops import layers
+    from ray_tpu.util import tracing
+
+    monkeypatch.setattr(layers, "_SUM_WHOLE", limit)
+    monkeypatch.setattr(layers, "_SUM_COLUMNS", limit)
+    mesh = mesh and mesh()
+    rng = np.random.default_rng(0)
+    table = jnp.asarray(rng.standard_normal((40, columns), np.float32))
+    tokens = jnp.asarray(rng.integers(0, 39, (2, 64)))
+
+    def ours(t, at):
+        return layers.embed_rows(t, at, dtype, mesh)
+
+    def plain(t, at):
+        return t.astype(dtype)[at]
+
+    # every array an argument: a closed-over one compiles into the program
+    def both(t, at, cot):
+        return tuple((f(t, at), jax.grad(
+            lambda t_: (f(t_, at).astype(jnp.float32) * cot).sum())(t))
+            for f in (ours, plain))
+
+    args = table, tokens, jnp.asarray(
+        rng.standard_normal((2, 64, columns), np.float32))
+    n0 = len(tracing.chrome_events())
+    text = str(jax.make_jaxpr(both)(*args))
+    (said,) = [e["args"] for e in tracing.chrome_events()[n0:]
+               if e["name"] == "rtpu.embed.plan"][:1]
+    assert ("custom_vjp" in text) == (blocks > 1)
+    assert text.count("scatter-add[") == blocks + 1
+    assert {k: said[k] for k in ("rows", "table_rows", "columns",
+                                 "sum_columns", "blocks", "form")} == {
+        "rows": 128, "table_rows": 40, "columns": columns,
+        "sum_columns": columns // blocks, "blocks": blocks,
+        "form": "blocked" if blocks > 1 else "whole"}
+    got, want = jax.jit(both)(*args)
+    assert got[1].dtype == table.dtype and float(jnp.abs(got[1]).max()) > 0
+    assert not np.asarray(got[1][39]).any()
+    for a, b in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+@pytest.mark.parametrize("model", ["dense", "deepseek-v2"])
+def test_a_models_gradients_are_the_plain_gathers(monkeypatch, model):
+    """A tiny dense stack (``llama.forward``, bfloat16 activations over
+    float32 parameters) and a tiny DeepSeek-V2's first layer
+    (``Stack.hidden``) at 64 columns in two blocks of 32: the loss and every parameter's gradient are
+    what the plain gather's transpose gives, bit for bit."""
+    from ray_tpu.models import deepseek_v2, llama
+    from ray_tpu.ops import layers
+
+    if model == "dense":
+        cfg = llama.LlamaConfig.tiny(attn_impl="reference", num_layers=1,
+                                     dtype=jnp.bfloat16)
+        mod, loss = llama, llama.loss_fn
+    else:
+        cfg = deepseek_v2.DeepseekV2Config.tiny(attn_impl="reference",
+                                                num_layers=1)
+        mod, loss = deepseek_v2, deepseek_v2.loss_fn
+    # the leaves' shapes from ``init_params``, filled here: drawing them
+    # there compiles a program a leaf
+    rng = np.random.default_rng(1)
+    params = jax.tree_util.tree_map(
+        lambda leaf: jnp.asarray(0.1 * rng.standard_normal(leaf.shape),
+                                 leaf.dtype),
+        jax.eval_shape(lambda: mod.init_params(cfg, jax.random.PRNGKey(0))))
+    tokens = rng.integers(0, cfg.vocab_size // 2, (2, 33))
+
+    def grads(p):
+        for limit in (32, 64):      # two blocks, then the plain expression
+            monkeypatch.setattr(layers, "_SUM_WHOLE", limit)
+            monkeypatch.setattr(layers, "_SUM_COLUMNS", limit)
+            assert layers.embed_plan(64, cfg.vocab_size, 64)["blocks"] == (
+                64 // limit)
+            yield jax.value_and_grad(
+                lambda p_: loss(cfg, p_, {"tokens": tokens}))(p)
+
+    blocked, whole = jax.jit(lambda p: tuple(grads(p)))(params)
+    leaves = jax.tree_util.tree_leaves_with_path
+    assert float(jnp.abs(blocked[1]["embed"]).max()) > 0
+    for (path, a), (_, b) in zip(leaves(blocked), leaves(whole)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b),
+                                      err_msg=str(path))
 
 
 @pytest.mark.parametrize("pairs, count, num_experts, want", [
