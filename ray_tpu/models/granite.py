@@ -12,8 +12,9 @@ logits_scaling``.
   in-projection to ``z | x B C | dt``, a causal depthwise convolution of
   ``ssm_conv_taps`` taps with bias and a silu over ``x B C``, the
   selective scan in chunks (state ``[ssm_head_dim, ssm_state]`` a head,
-  float32), the skip ``D x``, a gated RMSNorm over all ``ssm_heads *
-  ssm_head_dim`` channels, an out-projection.
+  float32), the skip ``D x``, a gated RMSNorm over a group's channels
+  (Granite's norm has one group: all ``ssm_heads * ssm_head_dim``
+  channels; ``mamba2_part(norm_groups=)`` for more), an out-projection.
 - ``Mixer`` of an ``attention`` layer is ``llama.attention_block`` with no
   position embedding (``position_embedding_type`` "nope": q and k are not
   rotated), scores scaled by ``attention_multiplier`` and not by the head
@@ -27,7 +28,8 @@ every position's loss (``llama.blocked_token_nll``). ``forward`` builds the
 logits, for sizes at which they fit. The model is the table ``LAYER_KINDS``
 (``mamba``, ``attention``) and ``models/stack.py`` walks it; the
 initialisation is Mamba-2's published one (``ops/ssm.mamba2_part``).
-Training only: the serving engines keep no scan state.
+Training only: the serving engines keep no scan state and no taps
+(``llama_decode.py``, ``llama_paged.py``: ROADMAP R8a).
 """
 
 from __future__ import annotations

@@ -1,7 +1,9 @@
 """A model as a table of layer kinds, and the one module that walks it.
 
 ``Stack(kinds, ...)`` is a model: ``kinds`` maps the names its config's
-``pattern`` uses to ``(mixer, mlp)``, two ``ops/layers.Part`` records kept
+``pattern`` uses to a layer's parts in the order they run, ``(mixer, mlp)``
+or the one part of a layer that is one sum (``nemotron_h``: a scan, an
+attention or a mixture), ``ops/layers.Part`` records kept
 beside the code they run (``llama.attention_part``,
 ``ops/conv.short_conv_part``, ``ops/ssm.mamba2_part``,
 ``ops/delta.gated_delta_part``, ``ops/layers.swiglu_part``,
@@ -13,13 +15,16 @@ the head (whole logits, or ``llama.blocked_cross_entropy`` and
 ``llama.blocked_token_nll`` where they would not fit) and the loss with the
 terms the parts add to it, and for routers balanced by a bias no optimizer
 owns (``routed_part(bias=True)``) what an optimizer is given and the bias's
-move after a step. ``models/laguna.py``, ``lfm2.py``, ``granite.py``
-and ``olmo_hybrid.py`` are a config, a table and the names of one
+move after a step, and a multi-token prediction module beside the head
+(``Stack(mtp=..)``). ``models/laguna.py``, ``lfm2.py``, ``granite.py``,
+``olmo_hybrid.py``, ``deepseek_v2.py``, ``dots3.py``, ``qwen3_next.py`` and
+``nemotron_h.py`` are a config, a table and the names of one
 ``Stack``'s methods; a new architecture is one more such
 module and, where its operator is new, one part under ``ops/``.
 
 Parameters are stacked by kind: ``params["layers"][kind][name]`` is
-``[layers of that kind, ...]``, a kind's layers in their order. Training
+``[layers of that kind, ...]``, a kind's layers in their order; a
+prediction module's are ``params["mtp"]`` (``Stack._mtp_hidden``). Training
 only: the serving engines walk ``llama``'s stack of one kind.
 """
 
@@ -27,6 +32,7 @@ from __future__ import annotations
 
 import math
 from dataclasses import dataclass
+from functools import partial
 from typing import Any, Dict, Optional, Tuple, Union
 
 import jax
@@ -34,7 +40,8 @@ import jax.numpy as jnp
 
 from ray_tpu.models import llama, mixtral
 from ray_tpu.ops import moe
-from ray_tpu.ops.layers import Ctx, Part, embed_rows, norm_start
+from ray_tpu.ops.layers import Ctx, Part, embed_rows, norm_start, rms_norm
+from ray_tpu.util import tracing
 
 
 def draw(cfg, key: jax.Array, shape: Tuple[int, ...], start) -> jax.Array:
@@ -106,19 +113,34 @@ def rows_passed(cfg, expert_counts) -> int:
 # parameter tree and not the optimizer's. ``Stack.update_router_bias``
 # moves it after a step.
 def trainable(params: Dict[str, Any]) -> Dict[str, Any]:
-    """The leaves an optimizer owns: every one but the routers' bias."""
-    return {**params, "layers": {
+    """The leaves an optimizer owns: every one but the routers' bias (a
+    prediction module's layers as the stack's)."""
+    out = {**params, "layers": {
         kind: {k: v for k, v in leaves.items() if k != "router_bias"}
         for kind, leaves in params["layers"].items()}}
+    if "mtp" in params:
+        out["mtp"] = trainable(params["mtp"])
+    return out
 
 
 def with_trainable(params: Dict[str, Any], trained: Dict[str, Any]
                    ) -> Dict[str, Any]:
     """``params`` with ``trained`` (like ``trainable(params)``) in place
     of the leaves an optimizer owns."""
-    return {**trained, "layers": {
+    out = {**trained, "layers": {
         kind: {**params["layers"][kind], **leaves}
         for kind, leaves in trained["layers"].items()}}
+    if "mtp" in trained:
+        out["mtp"] = with_trainable(params["mtp"], trained["mtp"])
+    return out
+
+
+def _layers_of(params: Dict[str, Any]):
+    """The stacked leaves by kind of the stack and, where there is one, of
+    the prediction module after them."""
+    yield from params["layers"].values()
+    if "mtp" in params:
+        yield from params["mtp"]["layers"].values()
 
 
 def router_bias_abs_max(params: Dict[str, Any]) -> jax.Array:
@@ -126,7 +148,12 @@ def router_bias_abs_max(params: Dict[str, Any]) -> jax.Array:
     any router."""
     return jnp.max(jnp.stack([
         jnp.abs(leaves["router_bias"]).max()
-        for leaves in params["layers"].values() if "router_bias" in leaves]))
+        for leaves in _layers_of(params) if "router_bias" in leaves]))
+
+
+# how many tokens ahead a prediction module's target lies (looked up at
+# trace time: ``benchmark/tests/scan_moe_limits.py`` plants 1, the head's own)
+MTP_AHEAD = 2
 
 
 @dataclass(frozen=True, eq=False)
@@ -147,49 +174,89 @@ class Stack:
     config's fields that multiply the embedding and divide the logits
     (Granite's ``embedding_multiplier`` and ``logits_scaling``). The head is
     the embedding where ``cfg.tie_embeddings``, an ``lm_head`` of its own
-    elsewhere."""
-    kinds: Dict[str, Tuple[Part, Part]]
+    elsewhere. ``mtp`` names the config's field that holds the kinds of
+    a multi-token prediction module's layers in their order (none where it
+    is empty): depth 1 of DeepSeek-V3's section 2.2. On positions with both
+    targets, ``h' = [N_e(embed[t_{i+1}]) ; N_h(x_L,i)] W_eh`` (``x_L``
+    before the last norm), the module's own layers of those kinds, then the
+    model's own head behind a norm of the module's, the target ``t_{i+2}``:
+    ``loss_terms`` takes ``seq + 2`` ids a row, runs the head twice and
+    adds ``cfg.mtp_loss_scale`` times the module's cross entropy
+    (``mtp_cross_entropy``); the module's routers report after the
+    stack's, and its layers take the remat level of their kinds. ``forward``
+    and ``token_nll`` stay the main model's."""
+    kinds: Dict[str, Tuple[Part, ...]]
     reports: Union[str, Tuple[str, ...]]
     blocked_head: bool = False
     embed_scale: Optional[str] = None
     logits_divisor: Optional[str] = None
+    mtp: Optional[str] = None
 
-    def _leaves(self, cfg) -> Dict[str, Dict[str, Any]]:
+    def _mtp_pattern(self, cfg) -> Tuple[str, ...]:
+        return tuple(getattr(cfg, self.mtp)) if self.mtp else ()
+
+    def _leaves(self, cfg, pattern=None) -> Dict[str, Dict[str, Any]]:
         """kind -> name -> ``Leaf``, the kinds in the pattern's order, a
         kind's leaves in its parts'."""
-        return {kind: {**self.kinds[kind][0].leaves(cfg),
-                       **self.kinds[kind][1].leaves(cfg)}
-                for kind in dict.fromkeys(cfg.pattern)}
+        return {kind: {name: leaf for part in self.kinds[kind]
+                       for name, leaf in part.leaves(cfg).items()}
+                for kind in dict.fromkeys(
+                    cfg.pattern if pattern is None else pattern)}
 
     def logical_axes(self, cfg) -> Dict[str, Any]:
+        def stacked(pattern):
+            return {kind: {name: ("layer",) + leaf.axes
+                           for name, leaf in leaves.items()}
+                    for kind, leaves in self._leaves(cfg, pattern).items()}
+
+        module = self._mtp_pattern(cfg)
         return {"embed": ("vocab", "embed"),
-                "layers": {kind: {name: ("layer",) + leaf.axes
-                                  for name, leaf in leaves.items()}
-                           for kind, leaves in self._leaves(cfg).items()},
+                "layers": stacked(cfg.pattern),
                 "final_norm": ("embed",),
                 **({} if cfg.tie_embeddings
-                   else {"lm_head": ("embed", "vocab")})}
+                   else {"lm_head": ("embed", "vocab")}),
+                **({"mtp": {"embed_norm": ("embed",),
+                            "hidden_norm": ("embed",),
+                            "join": ("mlp", "embed"),
+                            "layers": stacked(module),
+                            "final_norm": ("embed",)}} if module else {})}
 
     def init_params(self, cfg, key: jax.Array) -> Dict[str, Any]:
         """Every leaf as its part says it starts (``draw``); a kind's
         layers stacked in their order. The keys: ``key`` folded with 0 for
         the embedding, with ``n + 1`` for the pattern's ``n``-th kind and
-        split over its leaves, with 99 for an ``lm_head``."""
+        split over its leaves, with 99 for an ``lm_head``; a prediction
+        module's kinds with ``101 + n``, its joining matrix with 100."""
         h, v = cfg.hidden_size, cfg.vocab_size
-        layers = {}
-        for n, (kind, leaves) in enumerate(self._leaves(cfg).items()):
-            depth = cfg.pattern.count(kind)
-            keys = jax.random.split(jax.random.fold_in(key, n + 1),
-                                    len(leaves))
-            layers[kind] = {name: draw(cfg, k, (depth,) + leaf.shape,
-                                       leaf.start)
-                            for k, (name, leaf) in zip(keys, leaves.items())}
+
+        def stacked(pattern, first):
+            layers = {}
+            for n, (kind, leaves) in enumerate(
+                    self._leaves(cfg, pattern).items()):
+                depth = pattern.count(kind)
+                keys = jax.random.split(jax.random.fold_in(key, first + n),
+                                        len(leaves))
+                layers[kind] = {
+                    name: draw(cfg, k, (depth,) + leaf.shape, leaf.start)
+                    for k, (name, leaf) in zip(keys, leaves.items())}
+            return layers
+
+        ones = norm_start(cfg)
         params = {"embed": draw(cfg, jax.random.fold_in(key, 0), (v, h), h),
-                  "layers": layers,
-                  "final_norm": draw(cfg, None, (h,), norm_start(cfg))}
+                  "layers": stacked(cfg.pattern, 1),
+                  "final_norm": draw(cfg, None, (h,), ones)}
         if not cfg.tie_embeddings:
             params["lm_head"] = draw(cfg, jax.random.fold_in(key, 99),
                                      (h, v), h)
+        module = self._mtp_pattern(cfg)
+        if module:
+            params["mtp"] = {
+                "embed_norm": draw(cfg, None, (h,), ones),
+                "hidden_norm": draw(cfg, None, (h,), ones),
+                "join": draw(cfg, jax.random.fold_in(key, 100), (2 * h, h),
+                             2 * h),
+                "layers": stacked(module, 101),
+                "final_norm": draw(cfg, None, (h,), ones)}
         return params
 
     def param_shardings(self, cfg, mesh):
@@ -206,38 +273,76 @@ class Stack:
         -> what the layers reported under it, stacked in layer order).
         ``keep_index_choice``: the index layers report their index's
         inputs and their packed choice of keys too (``ops/mla.py``)."""
+        return self._walk(cfg, params, tokens, mesh, keep_router_logits,
+                          keep_index_choice)[:2]
+
+    def _embed(self, cfg, params, tokens, mesh) -> jax.Array:
+        x = embed_rows(params["embed"], tokens, cfg.dtype, mesh)
+        if self.embed_scale:
+            x = x * jnp.asarray(getattr(cfg, self.embed_scale), cfg.dtype)
+        return x
+
+    def _layer_of(self, cfg, kind: str, ctx: Ctx):
+        """One layer of ``kind`` for ``llama.run_layers``: its parts in
+        their order."""
+        def layer(x_, p_):
+            said = {}
+            for part in self.kinds[kind]:
+                x_, more = part.body(cfg, x_, p_, ctx)
+                said = {**said, **more}
+            return x_, said
+        return layer
+
+    def _walk(self, cfg, params, tokens, mesh, keep_router_logits=False,
+              keep_index_choice=False, module: Tuple[str, ...] = ()):
+        """``hidden`` and beside its two results what a prediction module
+        run after it shares with it: the parts' context and the remat
+        level. ``module``: the module's kinds, whose layers the plan then
+        reckons as further layers of the stack."""
         pattern = cfg.pattern
         with jax.named_scope("embed"):
-            x = embed_rows(params["embed"], tokens, cfg.dtype, mesh)
-            if self.embed_scale:
-                x = x * jnp.asarray(getattr(cfg, self.embed_scale),
-                                    cfg.dtype)
+            x = self._embed(cfg, params, tokens, mesh)
             once = {}
-            for kind in dict.fromkeys(pattern):
+            for kind in dict.fromkeys(pattern + module):
                 for part in self.kinds[kind]:
                     if part.once and part.once not in once:
                         once[part.once] = part.once(cfg, tokens)
         ctx = Ctx(mesh, once, keep_router_logits, keep_index_choice)
-
-        def layer_of(kind):
-            mixer, mlp = self.kinds[kind]
-
-            def layer(x_, p_):
-                x_, said = mixer.body(cfg, x_, p_, ctx)
-                x_, more = mlp.body(cfg, x_, p_, ctx)
-                return x_, {**said, **more}
-            return layer
-
+        planned = params if not module else {**params, "layers": {
+            **params["mtp"]["layers"], **params["layers"]}}
         level = llama.resolve_remat(
-            cfg, self.kinds, params, tokens, mesh, self.param_shardings,
-            pattern=pattern,
+            cfg, self.kinds, planned, tokens, mesh, self.param_shardings,
+            pattern=pattern + module,
             head_tokens=llama.head_block(tokens.size, cfg.vocab_size)
             if self.blocked_head else None) if cfg.remat else None
         x, ys = llama.run_layers(
-            {kind: layer_of(kind) for kind in params["layers"]}, x,
+            {kind: self._layer_of(cfg, kind, ctx)
+             for kind in params["layers"]}, x,
             params["layers"], level=level, scan=cfg.scan_layers,
             pattern=pattern)
-        return x, in_layer_order(pattern, ys)
+        return x, in_layer_order(pattern, ys), ctx, level
+
+    def _mtp_hidden(self, cfg, params, x: jax.Array, following: jax.Array,
+                    ctx: Ctx, level) -> Tuple[jax.Array, Dict[str, Any]]:
+        """The prediction module before its head: x [b, s, hidden] (the
+        last layer's output, before the last norm) and ``following [b,
+        s]`` (each position's next token) -> (the module's last layer's
+        output, what its layers reported, in their order)."""
+        module, m = self._mtp_pattern(cfg), params["mtp"]
+        norm = partial(rms_norm, eps=cfg.rms_norm_eps,
+                       zero_centred=cfg.zero_centred_norm)
+        with jax.named_scope("mtp_join"):
+            joined = jnp.concatenate(
+                [norm(self._embed(cfg, params, following, ctx.mesh),
+                      m["embed_norm"]), norm(x, m["hidden_norm"])], axis=-1)
+            h = jnp.dot(joined, m["join"].astype(cfg.dtype),
+                        preferred_element_type=jnp.float32
+                        ).astype(cfg.dtype)
+        h, ys = llama.run_layers(
+            {kind: self._layer_of(cfg, kind, ctx) for kind in m["layers"]},
+            h, m["layers"], level=level, scan=cfg.scan_layers,
+            pattern=module)
+        return h, in_layer_order(module, ys)
 
     def _said(self, said: Dict[str, Any]) -> Any:
         """What ``forward`` and ``token_nll`` hand back of the layers'
@@ -282,6 +387,58 @@ class Stack:
             cfg, params, x, tokens[:, 1:], block=head_block,
             logits_divisor=self._divisor(cfg)), self._said(said)
 
+    def _both_heads(self, cfg, params, tokens: jax.Array, mesh, head,
+                    keep_router_logits: bool = False):
+        """The stack, its head and, where the config names one, the
+        prediction module and the head once more. tokens [b, s + 1], or [b,
+        s + 2] with a module, so that each of the ``s`` positions has
+        both its targets; ``head(params, x, lo, hi)`` is given the last
+        layer's output and where its targets lie in ``tokens``, for the
+        module under the scopes ``mtp`` / ``mtp_head`` and with the
+        module's last norm in place of the model's: the embedding and the
+        head are the model's own. -> (the head's result, the module's or
+        None, the layers' reports, the module's after the stack's)."""
+        module = self._mtp_pattern(cfg)
+        ahead, length = (2 if module else 1), tokens.shape[1]
+        x, said, ctx, level = self._walk(
+            cfg, params, tokens[:, :-ahead], mesh,
+            keep_router_logits=keep_router_logits, module=module)
+        main = head(params, x, 1, length - ahead + 1)
+        if not module:
+            return main, None, said
+        with tracing.span("rtpu.train.mtp_plan", keep=True, depth=1,
+                          pattern=list(module),
+                          loss_scale=cfg.mtp_loss_scale, head_shared=True):
+            pass
+        with jax.named_scope("mtp"):
+            h, more = self._mtp_hidden(cfg, params, x, tokens[:, 1:-1], ctx,
+                                       level)
+            with jax.named_scope("mtp_head"):
+                beside = head(
+                    {**params, "final_norm": params["mtp"]["final_norm"]},
+                    h, MTP_AHEAD, length - 2 + MTP_AHEAD)
+        return main, beside, {
+            name: (jax.tree_util.tree_map(
+                lambda a, b: jnp.concatenate([a, b]), said[name], more[name])
+                if name in more else said[name]) for name in said}
+
+    def token_nlls(self, cfg, params, tokens: jax.Array, mesh=None,
+                   head_block: Optional[int] = None,
+                   keep_router_logits: bool = False
+                   ) -> Tuple[jax.Array, Optional[jax.Array], Any]:
+        """``token_nll`` of both heads of a stack with a prediction module:
+        tokens [b, s + 2] -> (the next-token loss of every position [b, s],
+        the module's loss of the token after it [b, s], the layers' reports
+        with the module's after the stack's)."""
+        def nll(params_, x_, lo, hi):
+            return llama.blocked_token_nll(
+                cfg, params_, x_, tokens[:, lo:hi], block=head_block,
+                logits_divisor=self._divisor(cfg))
+
+        main, beside, said = self._both_heads(
+            cfg, params, tokens, mesh, nll, keep_router_logits)
+        return main, beside, self._said(said)
+
     def loss_terms(self, cfg, params, batch: Dict[str, jax.Array], mesh=None
                    ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
         """(loss, its terms and the parts' counters): the cross entropy, a
@@ -291,17 +448,23 @@ class Stack:
         ``|S|`` under its counter's name). Made for
         ``jax.value_and_grad(..., has_aux=True)``."""
         tokens, mask = batch["tokens"], batch.get("mask")
-        x, said = self.hidden(cfg, params, tokens[:, :-1], mesh=mesh)
-        if mask is not None:
-            mask = mask[:, 1:]
-        if self.blocked_head:
-            ce = llama.blocked_cross_entropy(
-                cfg, params, x, tokens[:, 1:], mask,
-                logits_divisor=self._divisor(cfg))
-        else:
-            ce = llama.cross_entropy_loss(self._logits(cfg, params, x),
-                                          tokens[:, 1:], mask)
+
+        def cross_entropy(params_, x_, lo, hi):
+            targets = tokens[:, lo:hi]
+            weights = None if mask is None else mask[:, lo:hi]
+            if self.blocked_head:
+                return llama.blocked_cross_entropy(
+                    cfg, params_, x_, targets, weights,
+                    logits_divisor=self._divisor(cfg))
+            return llama.cross_entropy_loss(
+                self._logits(cfg, params_, x_), targets, weights)
+
+        ce, more_ce, said = self._both_heads(cfg, params, tokens, mesh,
+                                             cross_entropy)
         loss, terms = ce, {"cross_entropy": ce}
+        if more_ce is not None:
+            loss = loss + cfg.mtp_loss_scale * more_ce
+            terms["mtp_cross_entropy"] = more_ce
         reporting = {part.reports: part
                      for kind in dict.fromkeys(cfg.pattern)
                      for part in self.kinds[kind] if part.terms}
@@ -323,20 +486,32 @@ class Stack:
         bias moves ``cfg.bias_update_rate`` toward the experts that got
         fewer rows than the mean, away from those that got more (loss-free
         balancing, arXiv:2408.15664). On a mesh the counts are the whole
-        batch's (``forward`` sums them over the batch axes)."""
+        batch's (``forward`` sums them over the batch axes). A kind may be
+        one part or two: a routed layer is one with a part that reports
+        under "router"."""
         with jax.named_scope("moe_route"), \
                 jax.named_scope("moe_bias_update"):
             c = expert_counts.astype(jnp.float32)
             move = cfg.bias_update_rate * jnp.sign(
                 c.mean(-1, keepdims=True) - c)                  # [Lr, E]
-            # kind -> of the routed layers in their order, that kind's
-            at: Dict[str, list] = {}
-            routed = [kind for kind in cfg.pattern
-                      if self.kinds[kind][1].reports == "router"]
-            for row, kind in enumerate(routed):
-                at.setdefault(kind, []).append(row)
-            return {**params, "layers": {
-                kind: ({**leaves, "router_bias": leaves["router_bias"]
-                        + move[jnp.asarray(at[kind])]}
-                       if kind in at else leaves)
-                for kind, leaves in params["layers"].items()}}
+            def moved(layers, pattern, first):
+                # kind -> of the routed layers in their order, that kind's
+                at: Dict[str, list] = {}
+                routed = [kind for kind in pattern
+                          if any(part.reports == "router"
+                                 for part in self.kinds[kind])]
+                for row, kind in enumerate(routed, first):
+                    at.setdefault(kind, []).append(row)
+                return {kind: ({**leaves,
+                                "router_bias": leaves["router_bias"]
+                                + move[jnp.asarray(at[kind])]}
+                               if kind in at else leaves)
+                        for kind, leaves in layers.items()}, len(routed)
+
+            layers, rows = moved(params["layers"], cfg.pattern, 0)
+            out, module = {**params, "layers": layers}, self._mtp_pattern(cfg)
+            if module:
+                # a prediction module's routers: the rows after the stack's
+                out["mtp"] = {**params["mtp"], "layers": moved(
+                    params["mtp"]["layers"], module, rows)[0]}
+            return out

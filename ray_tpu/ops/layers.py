@@ -1,6 +1,7 @@
 """Transformer building blocks: RMSNorm (plain and gated a head at a time), an
-L2 norm, RoPE, SwiGLU, a head and loss over blocks of tokens; and ``Part``,
-the record a layer's mixer or MLP is to ``models/stack.py``.
+L2 norm, RoPE, SwiGLU, a squared-ReLU MLP, a head and loss over blocks of
+tokens; and ``Part``, the record a layer's mixer or MLP is to
+``models/stack.py``.
 
 Pure-jax implementations — XLA fuses these elementwise chains into the
 surrounding matmuls on TPU (the guide's rule: don't hand-schedule what the
@@ -51,7 +52,8 @@ class Ctx(NamedTuple):
 @dataclass(frozen=True)
 class Part:
     """A layer's mixer or its MLP, kept beside the code that runs it. A
-    model is a table ``kind -> (mixer, mlp)`` of these and
+    model is a table ``kind -> its parts in order`` (``(mixer, mlp)``, or
+    the one part of a layer that has one sum) of these and
     ``models/stack.py`` walks it; what two models that share a part differ
     in is an argument of the part's maker, which names a field of the
     config, or the field itself.
@@ -303,6 +305,25 @@ def swiglu_kept(tokens: int, width: int, itemsize: int) -> Dict[str, Any]:
     backward holds the three ``[T, width]`` arrays and the gradients of
     two."""
     return kept(mlp=2 * tokens * width * itemsize, width=5 * width)
+
+
+def relu2_mlp(x: jax.Array, w_up: jax.Array, w_down: jax.Array
+              ) -> jax.Array:
+    """Squared-ReLU MLP: down( relu(x @ up)^2 ), two matrices and no gate
+    (``nemotron_h``'s ``mlp_hidden_act`` "relu2"). As ``swiglu``: float32
+    accumulation inside the dots, the ``[.., width]`` product stored in the
+    input dtype and named for the MLP rung of a layer's remat level."""
+    up = checkpoint_name(
+        jnp.dot(x, w_up, preferred_element_type=jnp.float32).astype(x.dtype),
+        "mlp_up")
+    return jnp.dot(jnp.square(jax.nn.relu(up)), w_down,
+                   preferred_element_type=jnp.float32).astype(x.dtype)
+
+
+def relu2_kept(tokens: int, width: int, itemsize: int) -> Dict[str, Any]:
+    """A squared-ReLU MLP of ``width``: the MLP rung keeps its one product;
+    the backward holds the product, its square and the gradient of one."""
+    return kept(mlp=tokens * width * itemsize, width=3 * width)
 
 
 def swiglu_part(norm: str = "pre", resid: Optional[str] = None) -> Part:

@@ -1,4 +1,5 @@
-"""Routed mixture of SwiGLU experts: sorted, dropless, every shape static.
+"""Routed mixture of experts (SwiGLU, or two matrices around a squared
+ReLU): sorted, dropless, every shape static.
 
 The layer the many-small-expert models share (OLMoE, Mixtral, Laguna,
 LFM2). A token picks
@@ -65,10 +66,20 @@ backward operation carries the scope of the call it transposes):
 ``moe_route`` (router matmul, scores, top-k, sort), ``moe_dispatch``
 (gather into expert order), ``moe_experts`` (grouped matmuls and the
 activation, the gate weighting in it), ``moe_combine`` (gather back, sum).
+
+Experts in a latent (``routed_part(latent=..)``, ``nemotron_h``'s
+LatentMoE): the router reads the hidden state, the rows that are gathered,
+multiplied and summed are its down-projection to ``cfg.<latent>`` columns,
+and the tokens' sums go up again once (scope ``moe_latent``, both
+projections); a held share's ``r_share W_up`` is its part of the layer's
+sum, ``W_up`` being linear. Two-matrix experts (``act="relu2"``: ``relu(l
+W1)^2 W2``, no ``e_gate`` leaf) run the same passes with one grouped matmul
+fewer each way (``_expert_rows``).
 """
 
 from __future__ import annotations
 
+from fractions import Fraction
 from functools import partial
 from typing import Dict, Optional, Tuple
 
@@ -79,7 +90,8 @@ from jax.ad_checkpoint import checkpoint_name
 
 from ray_tpu.ops.layers import (Leaf, Part, _add_rows, _divisor_tile,
                                 _join_sums, _sum_columns, kept, norm_start,
-                                rms_norm, swiglu, swiglu_kept)
+                                relu2_kept, relu2_mlp, rms_norm, swiglu,
+                                swiglu_kept)
 from ray_tpu.util import tracing
 
 
@@ -248,6 +260,23 @@ def _swiglu_rows(rows, w_rows, sizes, e_gate, e_up, e_down):
     return grouped_matmul(act.astype(dt), e_down.astype(dt), sizes)
 
 
+def _relu2_rows(rows, w_rows, sizes, e_up, e_down):
+    """``_swiglu_rows`` for experts of two matrices around a squared ReLU:
+    rows [m, l] -> [m, l], the gate weight inside the activation's pass."""
+    dt = rows.dtype
+    up = checkpoint_name(grouped_matmul(rows, e_up.astype(dt), sizes),
+                         "mlp_up")
+    act = jnp.square(jax.nn.relu(up.astype(jnp.float32))) * w_rows[:, None]
+    return grouped_matmul(act.astype(dt), e_down.astype(dt), sizes)
+
+
+def _expert_rows(rows, w_rows, sizes, *weights):
+    """The experts' pass over their rows: three matrices are a SwiGLU's
+    (gate, up, down), two a squared-ReLU MLP's (up, down)."""
+    rows_of = _swiglu_rows if len(weights) == 3 else _relu2_rows
+    return rows_of(rows, w_rows, sizes, *weights)
+
+
 # A pass of the held experts' rows takes their balanced share and one part
 # in this many of it. From a sweep of the op alone on v5e (forward and
 # backward, 16,384 tokens, PR 35), the pass's rows a variable: at LFM2's
@@ -284,15 +313,18 @@ _HELD_HEADROOM = 8
 
 
 def _held_chunk(num_pairs: int, count: int, num_experts: int,
-                headroom: Optional[int] = None) -> int:
+                headroom=None) -> int:
     """Rows a pass of the held experts takes: their balanced share of the
     ``num_pairs`` (token, choice) pairs and one part in ``2 sqrt(count)``
     of it (an eighth, ``_HELD_HEADROOM``, from 16 experts up) or, where a
     configuration says how far its routers' loads lie from balance
-    (``held_headroom``), one part in ``headroom``; in whole row tiles, at
-    most all the pairs."""
+    (``held_headroom``), one part in ``headroom`` (a half: twice the share
+    over it, for a share of few experts whose loads lie far apart); in whole
+    row tiles, at most all the pairs."""
     part = headroom or min(_HELD_HEADROOM, max(2, round(2 * count ** 0.5)))
-    rows = -(-num_pairs * count * (part + 1) // (num_experts * part))
+    # (a whole number stays the arithmetic it was: p / 1)
+    p, q = Fraction(part).limit_denominator(64).as_integer_ratio()
+    rows = -(-num_pairs * count * (p + q) // (num_experts * p))
     return min(-(-rows // _ROW_TILE), -(-num_pairs // _ROW_TILE)) * _ROW_TILE
 
 
@@ -341,11 +373,17 @@ def _held_pass(i, x, w_pairs, order, sizes, top_k: int, chunk: int):
         return pairs, valid, tokens, x[tokens], w_pairs[pairs], in_pass
 
 
+def _given(*weights):
+    """The experts' matrices that are there (no ``e_gate``: two)."""
+    return tuple(w for w in weights if w is not None)
+
+
 @partial(jax.custom_vjp, nondiff_argnums=(7, 8))
 def _held_experts(x, w_pairs, e_gate, e_up, e_down, order, sizes, top_k,
                   chunk):
     """x [n, h], w_pairs [n * top_k] float32 (pair = token * top_k +
-    choice), the held experts' weights [count, ...], order [n * top_k +
+    choice), the held experts' weights [count, ...] (``e_gate`` None:
+    experts of two matrices, ``_expert_rows``), order [n * top_k +
     chunk]: pair ids, the held experts' first and in expert order, then
     padding; sizes [count]: rows of each held expert -> [n, h], the sum
     over a token's held choices, accumulated in float32."""
@@ -354,7 +392,8 @@ def _held_experts(x, w_pairs, e_gate, e_up, e_down, order, sizes, top_k,
         _, valid, tokens, rows, w_rows, in_pass = _held_pass(
             i, x, w_pairs, order, sizes, top_k, chunk)
         with jax.named_scope("moe_experts"):
-            y = _swiglu_rows(rows, w_rows, in_pass, e_gate, e_up, e_down)
+            y = _expert_rows(rows, w_rows, in_pass, *_given(e_gate, e_up,
+                                                            e_down))
         with jax.named_scope("moe_combine"):
             # a row past the pass's groups is written by no kernel
             y = jnp.where(valid[:, None], y.astype(jnp.float32), 0.0)
@@ -374,7 +413,7 @@ def _held_experts_fwd(x, w_pairs, e_gate, e_up, e_down, order, sizes, top_k,
 
 def _held_experts_bwd(top_k, chunk, res, d_out):
     x, w_pairs, e_gate, e_up, e_down, order, sizes = res
-    weights = (e_gate, e_up, e_down)
+    weights = _given(e_gate, e_up, e_down)
 
     def one_pass(i, carry):
         d_x, d_w, d_weights = carry
@@ -384,7 +423,7 @@ def _held_experts_bwd(top_k, chunk, res, d_out):
             d_y = jnp.where(valid[:, None], d_out[tokens], 0)
         with jax.named_scope("moe_experts"):
             _, transpose = jax.vjp(
-                lambda r, w, *ws: _swiglu_rows(r, w, in_pass, *ws),
+                lambda r, w, *ws: _expert_rows(r, w, in_pass, *ws),
                 rows, w_rows, *weights)
             d_rows, d_w_rows, *d_ws = transpose(d_y)
         with jax.named_scope("moe_dispatch"):
@@ -398,7 +437,8 @@ def _held_experts_bwd(top_k, chunk, res, d_out):
         0, _held_passes(sizes, chunk), one_pass,
         (_zero_sums(*x.shape), jnp.zeros_like(w_pairs),
          tuple(jnp.zeros_like(w) for w in weights)))
-    return (_join_sums(d_x, x.dtype), d_w) + d_weights + (None, None)
+    return ((_join_sums(d_x, x.dtype), d_w) + (None,) * (3 - len(weights))
+            + d_weights + (None, None))
 
 
 _held_experts.defvjp(_held_experts_fwd, _held_experts_bwd)
@@ -412,7 +452,8 @@ def routed_experts(x: jax.Array, router_w: jax.Array, e_gate: jax.Array,
                    select_bias: Optional[jax.Array] = None,
                    renorm_eps: float = 0.0, keep_choices: bool = False,
                    groups: Optional[Tuple[int, int]] = None,
-                   headroom: Optional[int] = None
+                   headroom: Optional[int] = None,
+                   router_x: Optional[jax.Array] = None
                    ) -> Tuple[jax.Array, ...]:
     """x [n, h], router_w [h, E], e_gate / e_up [E, h, f], e_down
     [E, f, h] -> (out [n, h], router_logits [n, E] float32, counts [E]
@@ -425,11 +466,17 @@ def routed_experts(x: jax.Array, router_w: jax.Array, e_gate: jax.Array,
     ``E`` are here. ``score``, ``select_bias``, ``renorm_eps`` and
     ``groups`` are ``route``'s; ``keep_choices`` appends ``route``'s own
     ``top_e [n, K]``
-    to the result, for a check of what was chosen."""
+    to the result, for a check of what was chosen. ``e_gate=None``: experts
+    of two matrices around a squared ReLU (``_expert_rows``). ``router_x
+    [n, hidden]``: what the router reads where that is not the rows the
+    experts multiply (experts in a latent: ``x`` is then ``[n, latent]``,
+    and so are ``out`` and the experts' outer widths)."""
     num_experts = router_w.shape[-1]
+    weights = _given(e_gate, e_up, e_down)
     with jax.named_scope("moe_route"):
         logits, top_w, top_e = route(
-            x, router_w, top_k, renormalize, scale, score=score,
+            x if router_x is None else router_x, router_w, top_k,
+            renormalize, scale, score=score,
             select_bias=select_bias, renorm_eps=renorm_eps, groups=groups)
         choices = (top_e,) if keep_choices else ()
         flat_e = top_e.reshape(-1)
@@ -466,7 +513,7 @@ def routed_experts(x: jax.Array, router_w: jax.Array, e_gate: jax.Array,
         rows = _dispatch(x, token_of, inv, top_k)
         w_rows = _place(top_w.reshape(-1), inv)
     with jax.named_scope("moe_experts"):
-        rows = _swiglu_rows(rows, w_rows, counts, e_gate, e_up, e_down)
+        rows = _expert_rows(rows, w_rows, counts, *weights)
     with jax.named_scope("moe_combine"):
         out = _combine(rows, token_of, inv, top_k)
     return (out, logits, counts) + choices
@@ -475,10 +522,12 @@ def routed_experts(x: jax.Array, router_w: jax.Array, e_gate: jax.Array,
 def routed_experts_on(mesh, x: jax.Array, router_w: jax.Array,
                       e_gate: jax.Array, e_up: jax.Array, e_down: jax.Array,
                       top_k: int, select_bias: Optional[jax.Array] = None,
+                      router_x: Optional[jax.Array] = None,
                       **how) -> Tuple[jax.Array, ...]:
     """``routed_experts`` for x [b, s, h] -> (out [b, s, h], router_logits
     [b * s, E] float32, counts [E] and, under ``keep_choices``, the
-    choices [b * s, K]); ``how`` is its keywords. On a mesh
+    choices [b * s, K]); ``how`` is its keywords, ``router_x [b, s,
+    hidden]`` what the router reads where ``x`` is a latent. On a mesh
     every chip routes its own rows of the batch to all the experts here
     (their weights gathered whole, as fsdp gathers any weight): the sort
     and the grouped matmuls stay local, which a Mosaic call under a
@@ -488,14 +537,20 @@ def routed_experts_on(mesh, x: jax.Array, router_w: jax.Array,
     weights = (router_w, e_gate, e_up, e_down) + (
         () if select_bias is None else (select_bias,))
 
-    def local(x_, router, e_gate, e_up, e_down, bias=None):
+    def local(x_, router, e_gate, e_up, e_down, bias=None, read=None):
         out, *stats = routed_experts(
             x_.reshape(-1, h), router, e_gate, e_up, e_down, top_k,
-            select_bias=bias, **how)
+            select_bias=bias, **how,
+            **({} if read is None else
+               {"router_x": read.reshape(-1, read.shape[-1])}))
         return (out.reshape(x_.shape), *stats)
 
     if mesh is None:
-        return local(x, *weights)
+        return local(x, *weights, read=router_x)
+    if router_x is not None or e_gate is None:
+        raise NotImplementedError(
+            "experts in a latent and experts of two matrices run without a "
+            "mesh: the latent rows' exchange is not built (parallel/)")
     from jax.sharding import PartitionSpec as P
 
     from ray_tpu.parallel.sharding import resolve_axis
@@ -556,13 +611,23 @@ def _token_gated(out, u, w):
     return (out.astype(jnp.float32) * gate[..., None]).astype(out.dtype)
 
 
+def _to_latent(x, w):
+    """x [.., a] @ w [a, b], float32 inside: a latent layer's down- and
+    up-projection."""
+    return jnp.dot(x, w, preferred_element_type=jnp.float32).astype(x.dtype)
+
+
 def routed_part(shared=False, score: str = "softmax",
                 bias: bool = False, renorm_eps: Optional[str] = None,
                 balance=False, width: str = "moe_intermediate_size",
-                renormalize: bool = True, groups: bool = False) -> Part:
-    """A routed mixture as a layer's MLP: ``x + [shared(u)] + routed(u)``,
+                renormalize: bool = True, groups: bool = False,
+                latent: Optional[str] = None, act: str = "silu") -> Part:
+    """A routed mixture as a layer's MLP (or, in a table of one-part
+    kinds, the whole layer): ``x + [shared(u)] + routed(u)``,
     ``u = RMSNorm(x)``: ``cfg.num_experts`` experts of ``width`` (the
-    config's field), ``cfg.top_k`` a token, the gate weights renormalised
+    config's field), SwiGLUs of three matrices (``act="silu"``) or
+    ``relu(. W1)^2 W2`` of two (``"relu2"``: no ``e_gate`` leaf),
+    ``cfg.top_k`` a token, the gate weights renormalised
     (or, ``renormalize=False``, left the scores they are) and times
     ``cfg.routed_scale``, ``cfg.experts_held`` of them here (a
     config without the field holds them all), their rows in passes with
@@ -571,8 +636,14 @@ def routed_part(shared=False, score: str = "softmax",
     ``cfg.shared_intermediate_size`` beside them, added ungated (True:
     Laguna) or, "gated", times ``sigmoid(u . s_sigmoid)``, one number a
     token from a vector of its own (Qwen3-Next; the scope
-    ``moe_shared_gate`` inside ``moe_shared``). The norm is as the
-    config's are (``cfg.zero_centred_norm``).
+    ``moe_shared_gate`` inside ``moe_shared``) or, "relu2", a squared-ReLU
+    MLP of that width, ungated (``nemotron_h``). ``latent`` names the
+    config's field of a latent's width: the router reads ``u``, the experts
+    multiply ``u l_down`` (``[.., latent]`` rows through the gathers, the
+    held passes and their float32 sums) and the tokens' sums go through
+    ``l_up`` once, both projections under the scope ``moe_latent``; a
+    traced layer writes the kept span ``rtpu.moe.latent_plan`` once. The
+    norm is as the config's are (``cfg.zero_centred_norm``).
     ``score="sigmoid"`` and ``bias`` (a ``router_bias`` that takes part in
     the choice alone, float32, no optimizer's) are LFM2's router,
     ``renorm_eps`` names its field. ``balance``: a layer reports
@@ -586,6 +657,10 @@ def routed_part(shared=False, score: str = "softmax",
     the router's logits too and, where a bias or a group limit took part
     in them, ``route``'s own choices."""
     by_sequence = balance == "sequence"
+    if act not in ("silu", "relu2"):
+        raise ValueError(f"unknown expert activation {act!r} (silu | relu2)")
+    if shared not in (False, True, "gated", "relu2"):
+        raise ValueError(f"unknown shared expert {shared!r}")
 
     def held(cfg):
         return getattr(cfg, "experts_held", None)
@@ -601,13 +676,20 @@ def routed_part(shared=False, score: str = "softmax",
                "router": Leaf((h, E), h, ("embed", None))}
         if bias:
             out["router_bias"] = Leaf((E,), "zeros_float32", (None,))
-        out.update(e_gate=Leaf((here, h, f), h, experts),
-                   e_up=Leaf((here, h, f), h, experts),
-                   e_down=Leaf((here, f, h), f, ("expert", "mlp", "embed")))
+        # the width of an expert's rows: the latent's, or the hidden state's
+        l = getattr(cfg, latent) if latent else h
+        if latent:
+            out.update(l_down=Leaf((h, l), h, ("embed", "mlp")),
+                       l_up=Leaf((l, h), l, ("mlp", "embed")))
+        if act == "silu":
+            out["e_gate"] = Leaf((here, l, f), l, experts)
+        out.update(e_up=Leaf((here, l, f), l, experts),
+                   e_down=Leaf((here, f, l), f, ("expert", "mlp", "embed")))
         if shared:
             sf = cfg.shared_intermediate_size
-            out.update(s_gate=Leaf((h, sf), h, ("embed", "mlp")),
-                       s_up=Leaf((h, sf), h, ("embed", "mlp")),
+            if shared != "relu2":
+                out["s_gate"] = Leaf((h, sf), h, ("embed", "mlp"))
+            out.update(s_up=Leaf((h, sf), h, ("embed", "mlp")),
                        s_down=Leaf((sf, h), sf, ("mlp", "embed")))
         if shared == "gated":
             out["s_sigmoid"] = Leaf((h,), h, ("embed",))
@@ -618,7 +700,11 @@ def routed_part(shared=False, score: str = "softmax",
         with jax.named_scope("mlp"):
             h2 = rms_norm(x, p["mlp_norm"], cfg.rms_norm_eps,
                           cfg.zero_centred_norm)
-            if shared:
+            if shared == "relu2":
+                with jax.named_scope("moe_shared"):
+                    beside = relu2_mlp(h2, p["s_up"].astype(dt),
+                                       p["s_down"].astype(dt))
+            elif shared:
                 with jax.named_scope("moe_shared"):
                     beside = swiglu(h2, p["s_gate"].astype(dt),
                                     p["s_up"].astype(dt),
@@ -628,9 +714,25 @@ def routed_part(shared=False, score: str = "softmax",
                             # looked up at trace time (delta_moe_limits.py)
                             beside = _token_gated(beside, h2,
                                                   p["s_sigmoid"].astype(dt))
+            rows, read = h2, {}
+            if latent:
+                with jax.named_scope("moe_latent"):
+                    # looked up at trace time (scan_moe_limits.py)
+                    rows = _to_latent(h2, p["l_down"].astype(dt))
+                read = {"router_x": h2}
+                with tracing.span(
+                        "rtpu.moe.latent_plan", keep=True,
+                        hidden=x.shape[-1], latent=rows.shape[-1],
+                        experts=p["router"].shape[-1],
+                        held=p["e_up"].shape[0], top_k=cfg.top_k, act=act,
+                        rows_a_pass=_held_chunk(
+                            x.shape[0] * x.shape[1] * cfg.top_k,
+                            held(cfg)[1], p["router"].shape[-1],
+                            headroom(cfg)) if held(cfg) else None):
+                    pass
             out, logits, counts, *chosen = routed_experts_on(
-                ctx.mesh, h2, p["router"], p["e_gate"], p["e_up"],
-                p["e_down"], cfg.top_k, renormalize=renormalize,
+                ctx.mesh, rows, p["router"], p.get("e_gate"), p["e_up"],
+                p["e_down"], cfg.top_k, **read, renormalize=renormalize,
                 select_bias=p["router_bias"] if bias else None,
                 held=held(cfg), scale=cfg.routed_scale, score=score,
                 renorm_eps=getattr(cfg, renorm_eps) if renorm_eps else 0.0,
@@ -655,16 +757,22 @@ def routed_part(shared=False, score: str = "softmax",
                 router["logits"] = logits
                 if bias or groups:
                     router["chosen"] = chosen[0]
+            if latent:
+                with jax.named_scope("moe_latent"):
+                    out = _to_latent(out, p["l_up"].astype(dt))
             return (x + beside if shared else x) + out, {"router": router}
 
     def keeps(cfg, shape, tokens, mesh):
-        h, f = cfg.hidden_size, shape["e_gate"][-1]
+        # h: the width of an expert's rows (a latent's, or the hidden
+        # state's); mats: an expert's [rows, f] arrays (gate, up, act)
+        mats = 3 if "e_gate" in shape else 2
+        h, f = shape["e_gate" if mats == 3 else "e_up"][-2:]
         act = jnp.dtype(cfg.dtype).itemsize
         pairs, mlp, rows = tokens * cfg.top_k, 0, 0
         if held(cfg) is None:
             # the two products carry the MLP rung's names (``_swiglu_rows``),
             # a row a (token, choice) pair
-            mlp = 2 * pairs * f * act
+            mlp = (mats - 1) * pairs * f * act
         else:
             # a pass's rows alone are gathered and multiplied, and the
             # passes add into two float32 [T, h] sums; nothing of a pass is
@@ -674,10 +782,14 @@ def routed_part(shared=False, score: str = "softmax",
             rows = 2 * tokens * h * 4
         # the rows and their gradient, the three [pairs, f] arrays of the
         # experts' SwiGLU and theirs
-        rows += pairs * (2 * h + 6 * f) * act
-        beside = (swiglu_kept(tokens, shape["s_gate"][-1], act)
+        rows += pairs * (2 * h + 2 * mats * f) * act
+        beside = (relu2_kept(tokens, shape["s_up"][-1], act)
+                  if shared == "relu2"
+                  else swiglu_kept(tokens, shape["s_gate"][-1], act)
                   if shared else kept())
-        return kept(mlp=mlp + beside["rungs"][2], width=beside["width"],
+        # a latent: the rows, the sums and the gradients of both
+        return kept(mlp=mlp + beside["rungs"][2],
+                    width=beside["width"] + (4 * h if latent else 0),
                     rows=rows)
 
     def terms(cfg, router):

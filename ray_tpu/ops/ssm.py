@@ -57,8 +57,9 @@ Named scopes (metadata only): ``ssm`` holds ``ssm_in`` (the in-projection;
 the norm before it is the caller's), ``ssm_conv`` (the causal depthwise
 taps, their bias and the silu: ``causal_conv_silu``, on a TPU the kernel
 pair ``ops/conv.taps_silu``), ``ssm_scan`` (softplus, the scan, the skip
-``D x``), ``ssm_norm`` (the gate and the RMSNorm over all channels) and
-``ssm_out`` (the out-projection).
+``D x``), ``ssm_norm`` (the gate and the RMSNorm over a group's channels:
+all of them where the norm has one group) and ``ssm_out`` (the
+out-projection).
 """
 
 from __future__ import annotations
@@ -682,8 +683,8 @@ def causal_conv_silu(u: jax.Array, w: jax.Array, bias: jax.Array,
 
 def mamba2_mixer(h: jax.Array, p: Dict[str, jax.Array], *, heads: int,
                  head_dim: int, state: int, groups: int = 1,
-                 chunk: int = 256, eps: float = 1e-5, mesh=None
-                 ) -> Tuple[jax.Array, jax.Array]:
+                 chunk: int = 256, eps: float = 1e-5, mesh=None,
+                 norm_groups: int = 1) -> Tuple[jax.Array, jax.Array]:
     """h [b, s, hidden] (normed) -> (the mixer's output [b, s, hidden],
     the state after the last position [b, H, P, N] float32, which no
     gradient passes). ``mesh``: the one the caller's arrays are sharded
@@ -692,7 +693,9 @@ def mamba2_mixer(h: jax.Array, p: Dict[str, jax.Array], *, heads: int,
     H P``), ``m_conv [d + 2 G N, taps]`` and ``m_conv_bias``, ``dt_bias``,
     ``A_log`` and ``D`` ``[H]``, ``m_norm [d]``, ``m_out [d, hidden]``.
     No projection has a bias; ``dt`` is not clamped (``time_step_limit``
-    (0, inf))."""
+    (0, inf)). The gated norm is over a group's ``d / norm_groups``
+    channels, each group normed on its own after the gate (``nemotron_h``:
+    as many groups as B and C have); one group (Granite) is all ``d``."""
     b, s, _ = h.shape
     dt_ = h.dtype
     d, gn = heads * head_dim, groups * state
@@ -739,9 +742,11 @@ def mamba2_mixer(h: jax.Array, p: Dict[str, jax.Array], *, heads: int,
             S = jax.lax.stop_gradient(S)
         with jax.named_scope("ssm_norm"):
             y = y * jax.nn.silu(z.astype(f32))
-            y = (y * jax.lax.rsqrt(jnp.mean(jnp.square(y), -1, keepdims=True)
-                                   + eps)
-                 * p["m_norm"].astype(f32)).astype(dt_)
+            if norm_groups > 1:
+                y = y.reshape(b, s, norm_groups, d // norm_groups)
+            y = y * jax.lax.rsqrt(jnp.mean(jnp.square(y), -1, keepdims=True)
+                                  + eps)
+            y = (y.reshape(b, s, d) * p["m_norm"].astype(f32)).astype(dt_)
         with jax.named_scope("ssm_out"):
             out = jnp.dot(y, p["m_out"].astype(dt_),
                           preferred_element_type=f32).astype(dt_)
@@ -749,11 +754,14 @@ def mamba2_mixer(h: jax.Array, p: Dict[str, jax.Array], *, heads: int,
 
 
 def mamba2_part(resid: Optional[str] = None,
-                counter: str = "ssm_state_abs_max") -> Part:
+                counter: str = "ssm_state_abs_max",
+                norm_groups: Optional[str] = None) -> Part:
     """Mamba-2's mixer as a layer's mixer: ``x + r * mamba2_mixer(
     RMSNorm(x))`` at the config's ``ssm_heads``, ``ssm_head_dim``,
     ``ssm_state``, ``ssm_groups``, ``ssm_conv_taps`` and ``ssm_chunk``
-    (``resid`` names the field ``r`` where it is not 1). A layer reports
+    (``resid`` names the field ``r`` where it is not 1; ``norm_groups``
+    the field that says in how many groups of channels the gated norm
+    runs, all channels as one without). A layer reports
     its state after the last position under "ssm_state", and the loss's
     terms the largest ``|S|`` of any layer under ``counter``. The
     initialisation is Mamba-2's published one: ``A`` uniform in 1-16 as its
@@ -778,7 +786,8 @@ def mamba2_part(resid: Optional[str] = None,
             rms_norm(x, p["op_norm"], cfg.rms_norm_eps), p,
             heads=cfg.ssm_heads, head_dim=cfg.ssm_head_dim,
             state=cfg.ssm_state, groups=cfg.ssm_groups, chunk=cfg.ssm_chunk,
-            eps=cfg.rms_norm_eps, mesh=ctx.mesh)
+            eps=cfg.rms_norm_eps, mesh=ctx.mesh,
+            norm_groups=getattr(cfg, norm_groups) if norm_groups else 1)
         if resid is not None:
             out = out * jnp.asarray(getattr(cfg, resid), cfg.dtype)
         return x + out, {"ssm_state": S}

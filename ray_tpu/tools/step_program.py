@@ -32,7 +32,9 @@ distinct selective scan, from its ``rtpu.ssm.scan_plan`` span,
 ``rule_plan`` and ``gdn_conv_plan``: the same of each distinct gated delta
 rule and of its taps, from ``rtpu.gdn.rule_plan`` and
 ``rtpu.gdn.conv_plan``, ``embed_plan``: the token gather's and the form
-of its gradient, from ``rtpu.embed.plan``, and ``scopes``: how many
+of its gradient, from ``rtpu.embed.plan``, ``latent_plan`` and ``mtp_plan``:
+a mixture in a latent's and a prediction module's, from
+``rtpu.moe.latent_plan`` and ``rtpu.train.mtp_plan``, and ``scopes``: how many
 instructions carry each ``jax.named_scope`` name as the innermost).
 ``--compare`` judges the program (``PROGRAM_FIELDS``) and says of two
 differing programs how many lines changed and how many of those are calls
@@ -131,7 +133,10 @@ def _compile_cell(tree: str, cell: dict, topo) -> dict:
     opt = (opt[0]._replace(count=placed(opt[0].count, rep), mu=owned(params),
                            nu=owned(params)),) + placed(tuple(opt[1:]), rep)
     batch = {"tokens": jax.ShapeDtypeStruct(
-        (tr["batch"], tr["seq"] + 1), jnp.int32, sharding=bsh)}
+        # a row's ids past ``seq``: the targets (two with a prediction
+        # module, the traffic's ``ids_ahead``)
+        (tr["batch"], tr["seq"] + tr.get("ids_ahead", 1)), jnp.int32,
+        sharding=bsh)}
 
     runner = import_module("benchmark.cells." + tr["family"])
     if hasattr(runner, "make_step"):    # the cell's own step, as it stands
@@ -174,6 +179,9 @@ def _compile_cell(tree: str, cell: dict, topo) -> dict:
     rule_taps = distinct("rtpu.gdn.conv_plan")
     # and the token gather: whether its gradient adds in column blocks
     embeds = distinct("rtpu.embed.plan")
+    # and a mixture in a latent, and a prediction module beside the head
+    latents = distinct("rtpu.moe.latent_plan")
+    modules = distinct("rtpu.train.mtp_plan")
     full = compiled.as_text()
     scopes = {}
     for path in OP_NAME.findall(full):
@@ -204,6 +212,8 @@ def _compile_cell(tree: str, cell: dict, topo) -> dict:
         "rule_plan": rules,
         "gdn_conv_plan": rule_taps,
         "embed_plan": embeds,
+        "latent_plan": latents,
+        "mtp_plan": modules,
         "scopes": dict(sorted(scopes.items())),
         "memory_analysis": {f: getattr(ma, f) for f in MEMORY_FIELDS}}}
 

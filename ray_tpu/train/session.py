@@ -91,12 +91,16 @@ class TrainContext:
 # whose index chooses their keys (``dsa_pairs_chosen_share``: the pairs
 # chosen over the causal pairs; ``dsa_index_loss``: the layers' sum of
 # ``KL(p_t || softmax_{S_t} I)``, ``ops/mla.index_terms``: an index that
-# stops learning to rank as the attention weighs shows here first). A new
+# stops learning to rank as the attention weighs shows here first); of a
+# stack with a multi-token prediction module (``mtp_cross_entropy``: the
+# module's cross entropy of the token after the next,
+# ``stack.Stack.loss_terms``, beside the loss it is a tenth of). A new
 # operator adds its counter's name here.
 STEP_COUNTERS = ("moe_rows_routed", "moe_rows_held", "moe_rows_passed",
                  "moe_expert_load_max_over_mean", "moe_router_bias_abs_max",
                  "ssm_state_abs_max", "gdn_state_abs_max",
-                 "dsa_pairs_chosen_share", "dsa_index_loss")
+                 "dsa_pairs_chosen_share", "dsa_index_loss",
+                 "mtp_cross_entropy")
 
 
 class SessionInterruptedError(BaseException):

@@ -90,7 +90,7 @@ pytest_runtest_teardown = pytest.hookimpl(wrapper=True)(_limited)
 # six end within ten seconds of each other.
 _HEAVY_FIRST = (
     "test_cluster", "test_fault_tolerance", "test_rllib", "test_serve",
-    "test_models", "test_stack_models", "test_dots3", "test_qwen3_next", "test_train_multiproc", "test_zz_chip_smoke", "test_ops",
+    "test_models", "test_stack_models", "test_dots3", "test_qwen3_next", "test_nemotron_h", "test_train_multiproc", "test_zz_chip_smoke", "test_ops",
     "test_delta_kernels", "test_scan_kernels",
     "test_tpu_compile", "test_control_plane", "test_serve_replay",
     "test_core_api", "test_data", "test_parallel", "test_tune", "test_train",

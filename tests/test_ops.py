@@ -2174,3 +2174,146 @@ def test_partial_rope_at_a_quarter_rotates_the_first_dims_alone():
     # position 0 is not rotated; a later one is
     np.testing.assert_allclose(got[:, 0], x[:, 0], atol=1e-7)
     assert float(jnp.abs(got[:, 5, :, :4] - x[:, 5, :, :4]).max()) > 1e-2
+
+
+# ---- what nemotron_h's table asks of the ops (PR 52)
+
+def test_relu2_mlp_is_two_matrices_around_a_squared_relu():
+    from ray_tpu.ops.layers import relu2_kept, relu2_mlp
+
+    k = jax.random.split(jax.random.PRNGKey(0), 3)
+    x = jax.random.normal(k[0], (2, 8, 16))
+    up, down = (jax.random.normal(k[1], (16, 24)),
+                jax.random.normal(k[2], (24, 16)))
+    want = np.square(np.maximum(np.asarray(x) @ np.asarray(up), 0.0)
+                     ) @ np.asarray(down)
+    np.testing.assert_allclose(relu2_mlp(x, up, down), want, rtol=1e-5,
+                               atol=1e-4)
+    # the MLP rung keeps the one product, named as swiglu names its up
+    jaxpr = str(jax.make_jaxpr(relu2_mlp)(x, up, down))
+    assert jaxpr.count("name=mlp_up") == 1 and "mlp_gate" not in jaxpr
+    assert relu2_kept(64, 24, 2) == {"rungs": (0, 0, 64 * 24 * 2, 0),
+                                     "width": 72, "rows": 0}
+
+
+@pytest.mark.parametrize("held", [None, (2, 4)], ids=["all", "held"])
+def test_routed_experts_in_a_latent_with_two_matrices(held):
+    """``routed_experts(e_gate=None, router_x=)``: the router reads the
+    hidden state, the experts multiply latent rows with ``relu(. W1)^2 W2``,
+    all experts here or a held share in passes, forward and gradient against
+    a loop over the experts."""
+    from ray_tpu.ops import moe
+
+    k = jax.random.split(jax.random.PRNGKey(1), 5)
+    n, h, l, f, E, K = 24, 16, 8, 12, 8, 3
+    u = jax.random.normal(k[0], (n, h))
+    lat = jax.random.normal(k[1], (n, l))
+    router = jax.random.normal(k[2], (h, E))
+    first, count = held or (0, E)
+    e_up = jax.random.normal(k[3], (E, l, f))[first:first + count] / 3
+    e_down = jax.random.normal(k[4], (E, f, l))[first:first + count] / 3
+    how = dict(renormalize=True, scale=2.5, score="sigmoid",
+               renorm_eps=1e-20, held=held)
+
+    def program(lat, e_up, e_down):
+        out, logits, counts = moe.routed_experts(
+            lat, router, None, e_up, e_down, K, router_x=u, **how)
+        return out, (logits, counts)
+
+    def plain(lat, e_up, e_down):
+        s = jax.nn.sigmoid(u @ router)
+        w, chosen = jax.lax.top_k(s, K)
+        w = 2.5 * w / (w.sum(-1, keepdims=True) + 1e-20)
+        out = jnp.zeros_like(lat)
+        for j in range(count):
+            gate = jnp.where(chosen == first + j, w, 0.0).sum(-1)
+            out = out + gate[:, None] * (
+                jnp.square(jax.nn.relu(lat @ e_up[j])) @ e_down[j])
+        return out
+
+    (out, (logits, counts)) = jax.jit(program)(lat, e_up, e_down)
+    assert out.shape == (n, l) and int(counts.sum()) == n * K
+    np.testing.assert_allclose(logits, u @ router, rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(out, plain(lat, e_up, e_down), rtol=1e-4,
+                               atol=1e-4)
+    w = jax.random.normal(jax.random.PRNGKey(9), (n, l))
+    got = jax.jit(jax.grad(lambda *a: (program(*a)[0] * w).sum(),
+                           (0, 1, 2)))(lat, e_up, e_down)
+    want = jax.jit(jax.grad(lambda *a: (plain(*a) * w).sum(), (0, 1, 2)))(
+        lat, e_up, e_down)
+    for g, t in zip(got, want):
+        np.testing.assert_allclose(g, t, rtol=1e-3, atol=1e-4)
+
+
+def test_routed_part_options_for_a_latent_are_off_by_default():
+    """``routed_part(latent=, act="relu2", shared="relu2")`` has no
+    ``e_gate`` and no ``s_gate`` leaf, rows of the latent's width and a
+    plan's reckoning at that width; the default table is what it was."""
+    from dataclasses import dataclass
+
+    from ray_tpu.models import lfm2
+    from ray_tpu.ops import moe
+
+    @dataclass(frozen=True)
+    class Config(lfm2.Lfm2Config):
+        moe_latent_size: int = 16
+        shared_intermediate_size: int = 48
+
+    cfg = Config.tiny()
+    plain = moe.routed_part(score="sigmoid", bias=True,
+                            renorm_eps="renorm_eps")
+    latent = moe.routed_part(score="sigmoid", bias=True,
+                             renorm_eps="renorm_eps", shared="relu2",
+                             latent="moe_latent_size", act="relu2")
+    assert list(plain.leaves(cfg)) == ["mlp_norm", "router", "router_bias",
+                                      "e_gate", "e_up", "e_down"]
+    leaves = latent.leaves(cfg)
+    assert list(leaves) == ["mlp_norm", "router", "router_bias", "l_down",
+                            "l_up", "e_up", "e_down", "s_up", "s_down"]
+    assert leaves["e_up"].shape == (8, 16, 32)
+    assert leaves["e_down"].shape == (8, 32, 16)
+    assert leaves["l_down"].shape == (64, 16)
+    shape = {k: v.shape for k, v in leaves.items()}
+    kept = latent.keeps(cfg, shape, 128, None)
+    pairs, act = 128 * cfg.top_k, 4
+    assert kept["rungs"][2] == pairs * 32 * act + 128 * 48 * act
+    assert kept["rows"] == pairs * (2 * 16 + 4 * 32) * act
+    assert kept["width"] == 3 * 48 + 4 * 16
+    with pytest.raises(ValueError, match="unknown expert activation"):
+        moe.routed_part(act="gelu")
+    with pytest.raises(NotImplementedError, match="without a mesh"):
+        moe.routed_experts_on(object(), jnp.zeros((1, 2, 16)),
+                              jnp.zeros((64, 8)), None, None, None, 2,
+                              router_x=jnp.zeros((1, 2, 64)))
+
+
+def test_mamba2_mixer_norms_a_group_at_a_time():
+    """``norm_groups``: each group's channels divided by the root of their
+    own mean square; one group is the function it was."""
+    from ray_tpu.ops import ssm
+
+    k = jax.random.split(jax.random.PRNGKey(0), 8)
+    H, P, N, G, hid = 4, 8, 16, 2, 32
+    d, conv = H * P, H * P + 2 * G * N
+    p = {"m_in": jax.random.normal(k[0], (hid, d + conv + H)) / 6,
+         "m_conv": jax.random.normal(k[1], (conv, 4)) / 2,
+         "m_conv_bias": jnp.zeros((conv,)),
+         "dt_bias": jnp.zeros((H,)), "A_log": jnp.zeros((H,)),
+         "D": jnp.ones((H,)),
+         "m_norm": 1.0 + 0.1 * jax.random.normal(k[2], (d,)),
+         "m_out": jnp.eye(d, hid)}
+    u = jax.random.normal(k[3], (1, 16, hid))
+    sizes = dict(heads=H, head_dim=P, state=N, groups=G, chunk=8)
+    one, _ = ssm.mamba2_mixer(u, p, **sizes)
+    same, _ = ssm.mamba2_mixer(u, p, norm_groups=1, **sizes)
+    np.testing.assert_array_equal(np.asarray(one), np.asarray(same))
+    two, _ = ssm.mamba2_mixer(u, p, norm_groups=2, **sizes)
+    # undo the weights: each half of the channels has a unit mean square
+    normed = np.asarray(two[0]) / np.asarray(p["m_norm"])[:hid]
+    # (eps 1e-5 beside a mean square that may be small)
+    np.testing.assert_allclose(np.square(normed[:, :16]).mean(-1), 1.0,
+                               rtol=2e-2)
+    np.testing.assert_allclose(np.square(normed[:, 16:]).mean(-1), 1.0,
+                               rtol=2e-2)
+    assert abs(np.square(np.asarray(one[0]) / np.asarray(p["m_norm"])[:hid]
+                         )[:, :16].mean(-1) - 1.0).max() > 5e-2

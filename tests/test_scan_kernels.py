@@ -52,11 +52,12 @@ def _scalar(fn):
         (2, 2, 8, 8, dict(s=32, H=8), 2, 2, 1e-5),
         (8, 8, 8, 256, dict(s=16), 2, 1, 1e-5),
         (2, 2, 128, 128, dict(s=384, b=1, H=2, G=1), 2, 2, 1e-5),
-        (2, 2, 128, 256, dict(s=600, b=1, H=2, G=1), 2, 2, 2e-4)],
+        (2, 2, 128, 256, dict(s=600, b=1, H=2, G=1), 2, 2, 2e-4),
+        (16, 2, 128, 128, dict(s=256, b=1, H=16, G=8), 2, 1, 1e-5)],
     ids=["two-heads-two-chunks", "one-head-one-chunk",
          "whole-sequence-a-call", "ragged-three-heads",
          "two-blocks-a-group", "shorter-than-a-chunk", "chunk-128",
-         "chunk-256-ragged"])
+         "chunk-256-ragged", "chunk-128-eight-groups"])
 def test_scan_kernels_match_the_recurrence(heads, chunks, lanes, chunk, shape,
                                            block, kept, tol, scan_kernels):
     """The kernel path (forward and backward) against the recurrence one
@@ -68,7 +69,8 @@ def test_scan_kernels_match_the_recurrence(heads, chunks, lanes, chunk, shape,
     a batch of two, a sequence that is not whole steps (nor whole chunks),
     one shorter than a chunk, and the published chunk of 256 and one of
     128 at the chip's own lane tiles (running sums of 256 float32 terms:
-    2e-4, where XLA's walk at that chunk reads 8.8e-5 on these inputs)."""
+    2e-4, where XLA's walk at that chunk reads 8.8e-5 on these inputs), the
+    last at ``nemotron_h``'s eight groups of B and C (two heads a group)."""
     from ray_tpu.ops import ssm
 
     scan_kernels(heads, chunks, lanes)
@@ -273,3 +275,32 @@ def test_kernel_takes_refuses_and_the_walk_runs(why, scan_kernels,
                                rtol=1e-5, atol=1e-5)
     np.testing.assert_allclose(np.asarray(S), np.asarray(want_S),
                                rtol=1e-5, atol=1e-5)
+
+
+def test_the_mixer_under_the_kernels_norms_each_of_eight_groups(scan_kernels,
+                                                                monkeypatch):
+    """``mamba2_mixer`` on its kernel path at 16 heads in 8 groups with the
+    gated norm a group at a time (``nemotron_h``'s layer), against the
+    reference's mixer, token by token and a group's norm written out."""
+    from benchmark.references import nemotron_h_ref
+    from ray_tpu.models import nemotron_h
+    from ray_tpu.ops import ssm
+
+    scan_kernels(2, 2)
+    monkeypatch.setattr(ssm, "taps_silu", functools.partial(
+        ssm.taps_silu, interpret=True))
+    cfg = nemotron_h.Nemotron_hConfig.tiny(ssm_heads=16, ssm_head_dim=8,
+                                           ssm_groups=8)
+    p = {name: nemotron_h.stack.draw(cfg, key, leaf.shape, leaf.start)
+         for key, (name, leaf) in zip(
+             jax.random.split(jax.random.PRNGKey(0), 9),
+             nemotron_h.LAYER_KINDS["mamba"][0].leaves(cfg).items())}
+    p["m_norm"] = 1.0 + 0.1 * jax.random.normal(jax.random.PRNGKey(1),
+                                                p["m_norm"].shape)
+    u = jax.random.normal(jax.random.PRNGKey(2), (1, 32, 64))
+    assert ssm.scan_plan(1, 32, 16, 8, 16, 8, 8)["form"] == "pallas"
+    got, S = ssm.mamba2_mixer(u, p, heads=16, head_dim=8, state=16, groups=8,
+                              chunk=8, norm_groups=8)
+    want, want_S = nemotron_h_ref.mixer(cfg, p, u[0])
+    np.testing.assert_allclose(got[0], want, atol=2e-5)
+    np.testing.assert_allclose(S[0], want_S, atol=2e-5)

@@ -1042,3 +1042,123 @@ def test_tables_that_take_no_new_option_give_what_they_gave(model):
                                want_logits, rtol=1e-5, atol=1e-6)
     np.testing.assert_allclose(float(np.abs(np.asarray(logits)).sum()),
                                want_sum, rtol=1e-6)
+
+
+# ---- kinds of one part, and a prediction module beside the head (PR 52)
+
+
+def test_a_table_of_one_part_kinds_trains_and_moves_its_routers_biases():
+    """A layer may be one sum: a table whose kinds are one part each (a
+    scan, a dense SwiGLU, a routed mixture with a bias) beside one of two.
+    It gives leaves and axes by part, a forward, a remat plan by kind, an
+    adamw step that lowers the loss, and ``update_router_bias`` finds the
+    routed layers whichever slot their part stands in."""
+    from dataclasses import dataclass
+
+    import optax
+
+    from ray_tpu.models import granite, stack
+    from ray_tpu.ops.layers import swiglu_part
+    from ray_tpu.ops.moe import routed_part
+    from ray_tpu.ops.ssm import mamba2_part
+
+    @dataclass(frozen=True)
+    class Config(granite.GraniteConfig):
+        layer_kinds: tuple = ("scan", "mix", "scan", "routed", "both")
+        num_experts: int = 8
+        top_k: int = 2
+        routed_scale: float = 1.0
+        moe_intermediate_size: int = 32
+        bias_update_rate: float = 0.01
+
+        @property
+        def pattern(self):
+            return self.layer_kinds
+
+    routed = routed_part(score="sigmoid", bias=True)
+    model = stack.Stack(
+        {"scan": (mamba2_part(),), "mix": (swiglu_part(),),
+         "routed": (routed,), "both": (mamba2_part(), routed)},
+        reports="router")
+    cfg = Config(vocab_size=256, hidden_size=64, intermediate_size=128,
+                 num_layers=5, num_heads=4, num_kv_heads=2, max_seq_len=64,
+                 attention_layers=(False,) * 5, ssm_heads=8, ssm_head_dim=16,
+                 ssm_state=16, ssm_chunk=8, dtype=jnp.float32, remat=False)
+    params = model.init_params(cfg, jax.random.PRNGKey(0))
+    assert list(params["layers"]) == ["scan", "mix", "routed", "both"]
+    assert set(params["layers"]["scan"]) == set(mamba2_part().leaves(cfg))
+    assert set(params["layers"]["mix"]) == set(swiglu_part().leaves(cfg))
+    assert "mtp" not in params
+    assert all(jax.tree_util.tree_leaves(jax.tree_util.tree_map(
+        lambda axes, a: len(axes) == a.ndim, model.logical_axes(cfg), params,
+        is_leaf=lambda x: isinstance(x, tuple))))
+    tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 33), 0, 256)
+    logits, router = jax.jit(lambda p: model.forward(
+        cfg, p, tokens[:, :-1]))(params)
+    assert logits.shape == (2, 32, 256)
+    assert router["counts"].shape == (2, 8)     # "routed", then "both"
+    described = llama.describe_stack(cfg, model.kinds, params["layers"], 64,
+                                     pattern=cfg.pattern)
+    assert set(described["kinds"]) == {"scan", "mix", "routed", "both"}
+    both, scan, part = (described["kinds"][k]["working_bytes"]
+                        for k in ("both", "scan", "routed"))
+    assert both - scan == part - 64 * 4 * 4 * 64    # a kind's parts add up
+    tx = optax.adamw(1e-2)
+
+    @jax.jit
+    def step(p, opt):
+        trained = stack.trainable(p)
+        (loss, terms), grads = jax.value_and_grad(
+            lambda t: model.loss_terms(cfg, stack.with_trainable(p, t),
+                                       {"tokens": tokens}),
+            has_aux=True)(trained)
+        updates, opt = tx.update(grads, opt, trained)
+        p = stack.with_trainable(p, optax.apply_updates(trained, updates))
+        return (model.update_router_bias(cfg, p, terms["expert_counts"]),
+                opt, loss, terms["expert_counts"])
+
+    opt = tx.init(stack.trainable(params))
+    first = None
+    for _ in range(4):
+        before = params
+        params, opt, loss, counts = step(params, opt)
+        first = float(loss) if first is None else first
+    assert float(loss) < first
+    for row, kind in enumerate(("routed", "both")):
+        moved = (params["layers"][kind]["router_bias"][0]
+                 - before["layers"][kind]["router_bias"][0])
+        c = np.asarray(counts[row], np.float32)
+        np.testing.assert_allclose(moved, 0.01 * np.sign(c.mean() - c),
+                                   atol=1e-7)
+
+
+def test_a_prediction_module_is_named_by_the_config_and_reports_last():
+    """``Stack(mtp=<field>)``: the module's kinds come from the config; its
+    leaves lie under ``params["mtp"]``, dealt from keys of their own (the
+    stack's leaves are what they are without a module); a row carries ``seq
+    + 2`` ids and the routed layers' counts gain the module's row."""
+    from ray_tpu.models import nemotron_h as mod
+
+    cfg = mod.Nemotron_hConfig.tiny()
+    bare = mod.Nemotron_hConfig.tiny(mtp_layer_pattern="")
+    params = mod.init_params(cfg, jax.random.PRNGKey(0))
+    without = mod.init_params(bare, jax.random.PRNGKey(0))
+    assert jax.tree_util.tree_all(jax.tree_util.tree_map(
+        lambda a, b: bool((a == b).all()),
+        {k: v for k, v in params.items() if k != "mtp"}, without))
+    assert list(params["mtp"]["layers"]) == ["attention", "moe"]
+    assert params["mtp"]["layers"]["moe"]["router"].shape == (1, 64, 16)
+    assert not bool((params["mtp"]["layers"]["moe"]["router"][0]
+                     == params["layers"]["moe"]["router"][0]).all())
+    tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 34), 0, 256)
+    nll, more, said = jax.jit(lambda p: mod.token_nlls(cfg, p, tokens))(
+        params)
+    assert nll.shape == more.shape == (2, 32)
+    assert said["router"]["counts"].shape == (3, 16)
+    main, _ = jax.jit(lambda p: mod.token_nll(bare, p, tokens[:, :33]))(
+        without)
+    np.testing.assert_allclose(nll, main, atol=1e-6)
+    _, terms = jax.jit(lambda p: mod.loss_terms(
+        cfg, p, {"tokens": tokens, "mask": jnp.ones_like(tokens)}))(params)
+    assert abs(float(terms["cross_entropy"]) - float(nll.mean())) < 1e-5
+    assert abs(float(terms["mtp_cross_entropy"]) - float(more.mean())) < 1e-5

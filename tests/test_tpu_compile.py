@@ -881,3 +881,91 @@ def test_delta_rule_kernels_compile_at_grouped_heads(S, one_chip,
     assert len(names) == 2, names
     for kernel in ("delta_rule_fwd", "delta_rule_bwd"):
         assert any(kernel in name for name in names), (kernel, names)
+
+
+# ---- train-nemotron3-super-1chip's kernels at its shapes (PR 52)
+
+
+def test_scan_kernels_compile_at_eight_groups_and_the_nemotron_step_lowers(
+        S, one_chip, no_compile_cache, monkeypatch):
+    """The selective scan at ``train-nemotron3-super-1chip``'s shapes (1 x
+    8,192 positions, 128 heads of 64 in 8 groups of B and C, a state of 128,
+    a chunk of one lane tile of 128): Mosaic takes the forward that keeps
+    its states and the backward, 16 heads (a group's) and 16 chunks a grid
+    step. The cell's own step (``benchmark/configs/
+    nemotron-3-super-120b-a12b-c1.json``, ``train_scan_moe.make_step``),
+    lowered for a v5e at ``seq + 2`` ids a row, names the scan's and the
+    taps' pairs, the flash kernels and megablox's; its plans are the kept
+    spans (a latent of 1,024 under 4,096 with 8 of 512 experts held, 22 a
+    token, 8,448 rows a pass; a module of depth 1 sharing the head); the
+    remat plan reckons the module's two layers with the stack's eleven and
+    fits a v5e; the head is walked twice."""
+    import json
+
+    import optax
+
+    from benchmark.cells import train_scan_moe
+    from ray_tpu.models import llama, nemotron_h
+    from ray_tpu.ops import ssm
+    from ray_tpu.util import tracing
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(llama, "_device_capacity", lambda mesh: V5E_LIMIT)
+
+    def f32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+
+    args = (S(1, 8192, 128, 64), f32(1, 8192, 128), f32(128),
+            S(1, 8192, 8, 128), S(1, 8192, 8, 128))
+    plan = ssm.scan_plan(1, 8192, 128, 64, 128, 8, 128)
+    assert (plan["form"], plan["heads_a_block"], plan["chunks_a_call"],
+            plan["states_kept"]) == ("pallas", 16, 16, 4)
+
+    def loss(*a):
+        return jnp.square(ssm.ssd_scan(*a, chunk=128)[0].astype(
+            jnp.float32)).sum()
+
+    gradient = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+        *args).compile().as_text()
+    names = [name for name, _ in _mosaic_calls(gradient)]
+    assert len(names) == 2, names        # (named after the transformation)
+    for kernel in ("ssd_scan_fwd", "ssd_scan_bwd"):
+        assert any(kernel in name for name in names), (kernel, names)
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "nemotron-3-super-120b-a12b-c1.json")) as f:
+        model, _, cfg = train_scan_moe.load_model(
+            json.load(f)["model_config"])
+    assert model is nemotron_h and cfg.pattern.count("mamba") == 5
+    tx = optax.adamw(optax.linear_schedule(0.0, 1e-4, 2000))
+    params = jax.eval_shape(lambda k: nemotron_h.init_params(cfg, k),
+                            jax.random.PRNGKey(0))
+    opt = jax.eval_shape(tx.init, nemotron_h.trainable(params))
+    batch = {"tokens": jax.ShapeDtypeStruct((1, 8194), jnp.int32,
+                                            sharding=one_chip)}
+    n0 = len(tracing.chrome_events())
+    lowered = jax.jit(train_scan_moe.make_step(nemotron_h, cfg, tx),
+                      donate_argnums=(0, 1)).lower(
+        _placed(params, one_chip), _placed(opt, one_chip), batch)
+    spans = {}
+    for e in tracing.chrome_events()[n0:]:
+        spans.setdefault(e["name"], []).append(e["args"])
+    (plan,) = spans["rtpu.train.remat_plan"]
+    assert plan["layers"] == {"moe": 6, "mamba": 5, "attention": 2}
+    assert plan["need_bytes"] < (1 - llama.REMAT_RESERVE) * V5E_LIMIT
+    assert {(p["hidden"], p["latent"], p["experts"], p["held"], p["top_k"],
+             p["act"], p["rows_a_pass"])
+            for p in spans["rtpu.moe.latent_plan"]} == {
+        (4096, 1024, 512, 8, 22, "relu2", 8448)}
+    (module,) = spans["rtpu.train.mtp_plan"]
+    assert module["pattern"] == ["attention", "moe"]
+    assert {(r["form"], r["groups"], r["chunk"], r["heads_a_block"])
+            for r in spans["rtpu.ssm.scan_plan"]} == {("pallas", 8, 128, 16)}
+    assert lowered.out_info[3]["expert_counts"].shape == (6, 512)
+    text = lowered.as_text()
+    for kernel in ("taps_silu_fwd", "taps_silu_bwd", "ssd_scan_fwd",
+                   "ssd_scan_bwd", "flash_fwd", "flash_bwd_dq",
+                   "flash_bwd_dkv", "gmm"):
+        assert kernel in text, kernel
+    assert _vocab_products(text, 4_096, 16_384) == 6

@@ -929,7 +929,7 @@ def test_train_session_serves_the_last_reported_scan_counter(op):
                                        _TrainSession)
 
     assert {"ssm_state_abs_max", "gdn_state_abs_max"} <= set(STEP_COUNTERS)
-    assert len(set(STEP_COUNTERS)) == len(STEP_COUNTERS) == 9
+    assert len(set(STEP_COUNTERS)) == len(STEP_COUNTERS) == 10
 
     def loop():
         from ray_tpu import train
@@ -1276,3 +1276,63 @@ def test_deepseek_v2_train_step_names_its_scopes_and_counts_its_rows():
     # two layers' held rows in whole passes of ``_held_chunk`` rows
     assert deepseek_v2.rows_passed(cfg, np.where(
         np.arange(16) < 4, 0, counts + 6)) % 256 == 0
+
+
+def test_latent_and_module_plans_are_kept_spans_and_the_counter_is_served():
+    """A traced step of a stack with mixtures in a latent and a prediction
+    module writes ``rtpu.moe.latent_plan`` (one a traced mixture, all alike)
+    and ``rtpu.train.mtp_plan`` once; its program carries the scopes
+    ``moe_latent``, ``mtp_join`` and ``mtp_head`` beside the scan's and the
+    mixture's; ``mtp_cross_entropy`` is one of the counters a session
+    serves (``rtpu_train_mtp_cross_entropy``)."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu import metrics
+    from ray_tpu.models import nemotron_h
+    from ray_tpu.train.session import (STEP_COUNTERS, TrainContext,
+                                       _TrainSession)
+    from ray_tpu.util import tracing
+
+    cfg = nemotron_h.Nemotron_hConfig.tiny(experts_held=(4, 4),
+                                           attn_impl="reference")
+    params = jax.eval_shape(lambda k: nemotron_h.init_params(cfg, k),
+                            jax.random.PRNGKey(0))
+    batch = {"tokens": jax.ShapeDtypeStruct((2, 34), jnp.int32)}
+    n0 = len(tracing.chrome_events())
+    lowered = jax.jit(lambda p, b: nemotron_h.loss_terms(cfg, p, b)).lower(
+        params, batch)
+    spans = {}
+    for e in tracing.chrome_events()[n0:]:
+        spans.setdefault(e["name"], []).append(e["args"])
+    plans = spans["rtpu.moe.latent_plan"]
+    assert len(plans) == 3                   # two mixtures and the module's
+    assert {(p["hidden"], p["latent"], p["experts"], p["held"], p["top_k"],
+             p["act"], p["rows_a_pass"]) for p in plans} == {
+        (64, 32, 16, 4, 4, "relu2", 256)}
+    (module,) = spans["rtpu.train.mtp_plan"]
+    assert (module["depth"], module["pattern"], module["loss_scale"],
+            module["head_shared"]) == (1, ["attention", "moe"], 0.1, True)
+    text = lowered.compile().as_text()
+    for scope in ("ssm_scan", "ssm_norm", "moe_route", "moe_latent",
+                  "moe_shared", "moe_experts", "mtp_join", "mtp_head",
+                  "head_loss"):
+        assert re.search(rf'op_name="[^"]*[/(]{scope}[/)]', text), scope
+    assert re.search(r'op_name="[^"]*[/(]mtp[/)][^"]*moe_latent', text)
+    assert "mtp_cross_entropy" in STEP_COUNTERS
+
+    def loop():
+        from ray_tpu import train
+        train.report({"loss": 1.0, "mtp_cross_entropy": 5.5})
+
+    s = _TrainSession(loop, {}, TrainContext())
+    from ray_tpu.train import session as session_mod
+    saved, session_mod._session = session_mod._session, s
+    try:
+        s.start()
+        s.next_result(timeout=10)
+        assert s.next_result(timeout=10).done
+        text = metrics.REGISTRY.render()
+    finally:
+        session_mod._session = saved
+    assert "rtpu_train_mtp_cross_entropy 5.5\n" in text
