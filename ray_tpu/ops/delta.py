@@ -1,6 +1,6 @@
 """The gated delta rule (Gated DeltaNet, arXiv:2412.06464) in its chunked
 (WY / UT) form, and the mixer of a ``linear_attention`` layer of
-Olmo-Hybrid.
+Olmo-Hybrid and of Qwen3-Next.
 
 The recurrence, a head at a time (``q_t, k_t [K]`` with ``|k_t| = 1``,
 ``v_t [V]``, ``g_t <= 0`` and ``beta_t`` in (0, 2) scalars, state ``S [V,
@@ -30,10 +30,12 @@ T``, exact whatever the keys are, where a sum of powers of ``A`` cancels
 catastrophically on repeated keys), joined two at a time by ``[[T11, 0],
 [-T22 A21 T11, T22]]`` in float32 at the highest matmul precision.
 
-Two forms share this arithmetic and no code beyond ``_gates``, ``l2_norm``
-and the padding; which runs is read from the call and never set
+Two forms share this arithmetic and no code beyond ``_gates``, the norms'
+``eps`` and the padding; which runs is read from the call and never set
 (``_kernel_takes``; ``rule_plan`` says what a call will do, and a traced
-call writes it once as the kept span ``rtpu.gdn.rule_plan``):
+call writes it once as the kept span ``rtpu.gdn.rule_plan``). Where fewer
+key heads serve the value heads (Qwen3-Next: 16 under 32) value head ``i``
+reads key head ``i // (heads / key heads)`` in both:
 
 - ``xla_walk``, on the CPU, under a mesh (a Mosaic call is whole to the
   partitioner) and for a chunk that is not whole tiles: walked as
@@ -43,40 +45,57 @@ call writes it once as the kept span ``rtpu.gdn.rule_plan``):
   them in one batch, hands the state from chunk to chunk in an inner
   ``lax.scan`` (two small matmuls a chunk), and then builds ``O`` for all
   of them; the step is under ``jax.checkpoint``, so what the backward
-  keeps of it is the state it started from. The controls of
-  ``benchmark/tests/delta_limits.py`` plant their faults in
-  ``_walk_step``, ``_unit_lower_inverse`` and this module's ``jnp``.
+  keeps of it is the state it started from. q and k come [b, s, key heads,
+  K], normed by ``l2_norm``, and are copied to the value heads
+  (``_join_heads``). The controls of ``benchmark/tests/delta_limits.py``
+  plant their faults in ``_walk_step``, ``_unit_lower_inverse`` and this
+  module's ``jnp``, ``delta_moe_limits.py``'s wrong head map in
+  ``_join_heads``.
 - ``pallas``, on a TPU backend without a mesh (``rule_kernels``): two
   Mosaic calls behind a ``custom_vjp``, ``delta_rule_fwd`` and
-  ``delta_rule_bwd``, on a grid of (batch row, ``KERNEL_HEADS`` heads,
-  ``KERNEL_CHUNKS`` chunks), the sequence the last and sequential axis. A
-  chunk's decays, ``A``, ``T``, ``W``, ``U``, ``V'`` and ``Q K^T`` live in
-  VMEM and nothing ``[chunk, chunk]`` is written to HBM; the float32 state
-  is carried in VMEM from step to step. ``T``'s diagonal tiles of
-  ``KERNEL_BASE`` rows come by substitution in straight-line code, the
-  heads of a block side by side on the lanes, and are joined as above. The
-  forward writes the state before every grid step when a gradient is asked
-  for (``states_kept``); the backward takes the steps last first, builds a
-  step's chunks again from that state in VMEM, and walks them last first
-  carrying the state's cotangent, ``dA = -T^T dT T^T`` under the diagonal.
-  Its three seams for the controls are ``_kernel_state``,
-  ``_kernel_inverse`` and ``_kernel_sums`` / ``_kernel_decays`` (through
-  this module's ``jnp``), looked up while the kernels trace.
+  ``delta_rule_bwd``, on a grid of (batch row, a block of key heads with
+  their value heads, ``KERNEL_HEADS`` of those at most, ``KERNEL_CHUNKS``
+  chunks), the sequence the last and sequential axis. The operands are
+  read and written where their producers and consumers hold them,
+  positions last ([b, channels, s], as the taps' kernels leave q, k and
+  v), q and k at the key heads, and XLA relays nothing for the calls: a
+  grid step turns its blocks in VMEM, ``KERNEL_LANES`` positions at a
+  time, takes q's and k's L2 norms there (``_kernel_norm``; the backward
+  its ``jax.vjp``), forms a key head's ``K K^T`` and ``Q K^T`` once for
+  its value heads (``_key_head`` is the map), and the backward adds a key
+  head's ``dq`` and ``dk`` over its value heads in VMEM and writes them
+  once. A chunk's decays, ``A``, ``T``, ``W``, ``U``, ``V'`` and ``Q K^T``
+  live in VMEM and nothing ``[chunk, chunk]`` is written to HBM; the
+  float32 state is carried in VMEM from step to step. ``T``'s diagonal
+  tiles of ``KERNEL_BASE`` rows come by substitution in straight-line
+  code, the heads of a block side by side on the lanes, and are joined as
+  above. The forward writes the state before every grid step when a
+  gradient is asked for (``states_kept``); the backward takes the steps
+  last first, builds a step's chunks again from that state in VMEM, and
+  walks them last first carrying the state's cotangent, ``dA = -T^T dT
+  T^T`` under the diagonal. Its seams for the controls are
+  ``_kernel_state``, ``_kernel_inverse``, ``_kernel_sums`` /
+  ``_kernel_decays`` (through this module's ``jnp``), ``_kernel_norm`` and
+  ``_key_head``, looked up while the kernels trace. Head widths of 96 and
+  192 (Olmo-Hybrid) are whole sublane tiles and not whole registers of
+  lanes: that is why the layout is positions last and not [b, s, heads x
+  dim], and one layout serves both cells.
 
-Decays, running sums, ``beta``, ``A``, ``T`` and the carried state are
-float32; the MXU's operands (``K``, ``V``, ``Q``, their decayed copies,
-``T``, ``W``, ``V'`` and the state where it is multiplied) are the
+Decays, running sums, ``beta``, ``A``, ``T``, the norms and the carried
+state are float32; the MXU's operands (``K``, ``V``, ``Q``, their decayed
+copies, ``T``, ``W``, ``V'`` and the state where it is multiplied) are the
 activations' dtype with float32 accumulation. A sequence that is not whole
-chunks is padded with ``g = 0``, ``beta = 0`` and zero rows, which move
-neither state nor output.
+chunks (the kernels: whole grid steps) is padded with ``g = 0``, ``beta =
+0`` and zero rows, which move neither state nor output.
 
 Named scopes (metadata only): ``gdn`` holds ``gdn_in`` (the
 in-projection), ``gdn_conv`` (the causal depthwise taps and the silu over
 q, k and v: ``ops/ssm.causal_conv_silu``, on a TPU the kernel pair
-``ops/conv.taps_silu`` with a zero bias), ``gdn_rule`` (the L2 norms,
-``g`` and ``beta``, the rule in either form, the kernels' relayouts with
-it), ``gdn_norm`` (the RMSNorm of each head and
-the gate) and ``gdn_out`` (the out-projection).
+``ops/conv.taps_silu`` with a zero bias), ``gdn_rule`` (``rule_part``:
+``g`` and ``beta``, the running sums, the rule in either form; the walk's
+swaps, L2 norms and copies to the value heads with it, which the kernels
+do in VMEM), ``gdn_norm`` (the RMSNorm of each head and the gate) and
+``gdn_out`` (the out-projection).
 """
 
 from __future__ import annotations
@@ -100,43 +119,65 @@ from ray_tpu.util import tracing
 WALK_BYTES = 40 << 20
 # rows of T found by forward substitution before blocks are joined
 INVERSE_BASE = 16
-# the kernels: heads a grid step takes (what a chunk's chain waits for
-# overlaps across them; their diagonal tiles lie side by side on the lanes
-# while T's first rows are substituted, 2 heads of 4 tiles of 16 a
-# register's 128), chunks a grid step takes (the backward keeps the state
-# before each step and builds the ones between again), and the rows of T
-# substituted before blocks are joined. Read on the chip at the cell's
-# shapes, forward / gradient ms a layer: 10, 8, 16 13.7 / 40.3; 6, 8, 32
-# 14.9 / 43.2; 2, 8, 16 19.3 / 52.4; 6, 8, 64 22.1 / 57.1 (PERF.md 6, PR 40)
-KERNEL_HEADS = 10
+# the kernels: value heads a grid step takes at most (what a chunk's chain
+# waits for overlaps across them; their diagonal tiles lie side by side on
+# the lanes while T's first rows are substituted, 2 heads of 4 tiles of 16 a
+# register's 128; a step takes whole key heads: ``_heads_a_block``), chunks a
+# grid step takes (the backward keeps the state before each step and builds
+# the ones between again), and the rows of T substituted before blocks are
+# joined. Read on the chip, the mixer's ``gdn_rule`` part whole, forward /
+# gradient ms a layer (PERF.md 6, PR 53): Olmo-Hybrid's 30 heads of 96 / 192
+# at 15 heads a step 10.6 / 37.7, 10 10.7 / 38.3, 6 11.6 / 40.8; Qwen3-Next's
+# 32 on 16 of 128 / 128 at 16 10.6 / 33.3, 8 11.6 / 35.8, 4 14.2 / 41.9. (PR
+# 40, head-major operands, 30 heads: 10, 8, 16 13.7 / 40.3; 6, 8, 32 14.9 /
+# 43.2; 2, 8, 16 19.3 / 52.4; 6, 8, 64 22.1 / 57.1)
+KERNEL_HEADS = 16
 KERNEL_CHUNKS = 8
 KERNEL_BASE = 16
+# the positions of a grid step's block that the kernels turn at once (the
+# operands lie positions last: a register's lanes), which a block's
+# positions are whole numbers of
+KERNEL_LANES = 128
+# the ``eps`` of q's and k's L2 norms, in XLA (``l2_norm``) and in the kernels
+QK_NORM_EPS = 1e-6
 
 
-def _kernel_takes(chunk: int, mesh) -> bool:
+def _kernel_takes(chunk: int, mesh, key_dim: int, value_dim: int) -> bool:
     """Whether a call runs as the kernels: on a TPU backend (anything but
     the CPU), without a ``mesh`` (a Mosaic call is whole to the
     partitioner, which would gather its operands: XLA's walk shards as the
-    arrays do), and with a chunk (a sequence shorter than one is its own)
-    that is whole tiles of ``KERNEL_BASE`` rows, a power of two of them."""
+    arrays do), with a chunk (a sequence shorter than one is its own)
+    that is whole tiles of ``KERNEL_BASE`` rows, a power of two of them,
+    and with heads of whole sublane tiles (a block's heads lie one under
+    another)."""
     tiles = chunk // KERNEL_BASE
     return (mesh is None and jax.default_backend() != "cpu"
-            and chunk == tiles * KERNEL_BASE and tiles & (tiles - 1) == 0)
+            and chunk == tiles * KERNEL_BASE and tiles & (tiles - 1) == 0
+            and key_dim % 8 == 0 and value_dim % 8 == 0)
 
 
-def _heads_a_block(heads: int) -> int:
-    """The largest divisor of the heads within ``KERNEL_HEADS``."""
-    return max(h for h in range(1, KERNEL_HEADS + 1) if heads % h == 0)
+def _heads_a_block(heads: int, key_heads: Optional[int] = None) -> int:
+    """The value heads a grid step takes: whole key heads with the ``heads
+    / key_heads`` value heads of each, as many key heads as divide theirs
+    and keep the value heads within ``KERNEL_HEADS`` (one key head's at the
+    least)."""
+    key_heads = key_heads or heads
+    ratio = heads // key_heads
+    return ratio * max(k for k in range(1, key_heads + 1) if key_heads % k == 0
+                       and (k == 1 or k * ratio <= KERNEL_HEADS))
 
 
 def rule_plan(batch: int, seq: int, heads: int, key_dim: int,
               value_dim: int, chunk: int, mesh=None,
               key_heads: Optional[int] = None) -> Dict[str, Any]:
-    """What ``gated_delta_rule`` does with these shapes, and in which ``form``.
-    ``heads`` are the value heads, the rule's own; ``key_heads`` (the mixer's,
-    where fewer heads of q and k serve them) and how they were ``joined``
-    ("repeat": q and k copied to the value heads before the call, which then
-    reads ``heads`` of each; None where they are as many) are said beside them.
+    """What the rule does with these shapes, and in which ``form``.
+    ``heads`` are the value heads, the rule's own; ``key_heads`` those of q
+    and k where fewer serve them, and ``joined`` how a value head comes by
+    its key head: "repeat" (XLA's walk: q and k copied to the value heads,
+    ``_join_heads``), "index_map" (the kernels: a grid step takes a block of
+    key heads and the value heads of each, q and k are read and ``dq`` and
+    ``dk`` written once at the key heads, ``_key_head``), None where they
+    are as many.
     Both forms: the chunk it uses (no longer than the sequence), the chunks,
     the ``steps`` (of the walk, or of the kernels' grid along the sequence),
     ``chunks_a_call`` (what one step takes), ``states_kept`` (the float32
@@ -144,34 +185,41 @@ def rule_plan(batch: int, seq: int, heads: int, key_dim: int,
     puts in HBM beside what all chunks' pair matrices at once would.
     ``xla_walk``: ``walk`` (= ``chunks_a_call``, the largest divisor of the
     chunks within ``WALK_BYTES``) and the bytes of one step's pair matrices and
-    carried states. ``pallas``: ``heads_a_block`` (the largest divisor of the
-    heads within ``KERNEL_HEADS``), ``KERNEL_CHUNKS`` chunks a step (all of a
-    shorter sequence), and the bytes of the kept states, the last state and the
+    carried states. ``pallas``: ``heads_a_block`` (``_heads_a_block``),
+    ``KERNEL_CHUNKS`` chunks a step (all of a shorter sequence; whole
+    ``KERNEL_LANES`` positions), ``operands`` ("positions_last": q, k, v, o
+    and their gradients are read and written [b, channels, s], as the taps
+    leave them) and the bytes of the kept states, the last state and the
     running sums (in their two layouts) and ``beta``: nothing ``[chunk,
     chunk]``."""
     chunk = min(chunk, seq)
     chunks = -(-seq // chunk)
     one = batch * heads * 4 * (4 * chunk * chunk + value_dim * key_dim)
     key_heads = key_heads or heads
+    kernels = _kernel_takes(chunk, mesh, key_dim, value_dim)
     plan = {"seq": seq, "chunk": chunk, "chunks": chunks, "heads": heads,
             "key_heads": key_heads,
-            "joined": "repeat" if key_heads != heads else None,
+            "joined": None if key_heads == heads else (
+                "index_map" if kernels else "repeat"),
             "key_dim": key_dim, "value_dim": value_dim,
             "float32_bytes_all_chunks": chunks * one}
-    if _kernel_takes(chunk, mesh):
-        call = min(KERNEL_CHUNKS, chunks)
+    if kernels:
+        whole = max(1, KERNEL_LANES // chunk)
+        call = -(-min(KERNEL_CHUNKS, chunks) // whole) * whole
         steps = -(-chunks // call)
         state = batch * heads * value_dim * key_dim * 4
         return dict(plan, form="pallas", walk=None, steps=steps,
-                    heads_a_block=_heads_a_block(heads),
+                    heads_a_block=_heads_a_block(heads, key_heads),
                     chunks_a_call=call, states_kept=steps,
+                    operands="positions_last",
                     float32_bytes_in_hbm=(steps + 1) * state
                     + 3 * batch * heads * steps * call * chunk * 4)
     walk = max(w for w in range(1, chunks + 1)
                if chunks % w == 0 and (w == 1 or w * one <= WALK_BYTES))
     return dict(plan, form="xla_walk", walk=walk, steps=chunks // walk,
                 heads_a_block=None, chunks_a_call=walk,
-                states_kept=chunks // walk, float32_bytes_in_hbm=walk * one)
+                states_kept=chunks // walk, operands=None,
+                float32_bytes_in_hbm=walk * one)
 
 
 def _unit_lower_inverse(A: jax.Array) -> jax.Array:
@@ -256,26 +304,35 @@ def _walk_step(S, xs, dtype):
 
 
 def gated_delta_rule(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
-                     beta: jax.Array, chunk: int = 64, mesh=None,
-                     key_heads: Optional[int] = None
+                     beta: jax.Array, chunk: int = 64, mesh=None
                      ) -> Tuple[jax.Array, jax.Array]:
-    """q and k [b, s, H, K] (k of unit length, q scaled as the caller
-    wants its outputs), v [b, s, H, V], g [b, s, H] float32 (the log of the
-    decay, not positive), beta [b, s, H] float32 -> (o [b, s, H, V] in
-    ``v``'s dtype, the state after the last position [b, H, V, K]
-    float32). ``mesh``: the one the caller's arrays are sharded over, if
-    any. Which form runs is read from the call (``_kernel_takes``), and
-    the kept span ``rtpu.gdn.rule_plan`` says which. ``key_heads``: the
-    heads q and k had before the caller copied them to the value heads
-    (the span's alone)."""
-    b, s, H, K = q.shape
-    V = v.shape[-1]
+    """q and k [b, s, key heads, K] (k of unit length, q scaled as the
+    caller wants its outputs), v [b, s, H, V] (``H`` a multiple of the key
+    heads: value head ``i`` reads key head ``i // (H / key heads)``), g [b,
+    s, H] float32 (the log of the decay, not positive), beta [b, s, H]
+    float32 -> (o [b, s, H, V] in ``v``'s dtype, the state after the last
+    position [b, H, V, K] float32). ``mesh``: the one the caller's arrays
+    are sharded over, if any. Which form runs is read from the call
+    (``_kernel_takes``), and the kept span ``rtpu.gdn.rule_plan`` says
+    which. The kernels take their operands positions last
+    (``rule_kernels``; the mixer hands them its own so): this order is
+    swapped around them. The walk reads q and k copied to the value heads
+    (``_join_heads``)."""
+    b, s, key_heads, K = q.shape
+    H, V = v.shape[2:]
     plan = rule_plan(b, s, H, K, V, chunk, mesh, key_heads)
     with tracing.span("rtpu.gdn.rule_plan", keep=True, **plan):
         pass
     if plan["form"] == "pallas":
+        def last(a):
+            return jnp.swapaxes(a.reshape(b, s, -1), 1, 2)
+
         # looked up at trace time: a test hands it the interpreter
-        return rule_kernels(q, k, v, g, beta, plan)
+        o, S = rule_kernels(last(q), last(k), last(v), g, beta, plan)
+        return jnp.swapaxes(o, 1, 2).reshape(b, s, H, V), S
+    if key_heads != H:
+        # looked up at trace time too: a test plants a wrong map
+        q, k = (_join_heads(x, H) for x in (q, k))
     C, W, steps = plan["chunk"], plan["walk"], plan["steps"]
     pad = plan["chunks"] * C - s
     dtype = v.dtype
@@ -303,11 +360,17 @@ def _padded(a, pad):
     return jnp.pad(a, ((0, 0), (0, pad)) + ((0, 0),) * (a.ndim - 2))
 
 
-# ---- the rule as Pallas (Mosaic) kernels. A grid step takes ``hb`` heads
-# and ``n`` chunks of one batch row: q and k [1, hb, n C, K], v and o [1,
-# hb, n C, V], positions on the sublanes; a chunk's running sums and beta
-# come down the sublanes (``cols`` [C, 2 n]: what scales a row), the sums
-# along the lanes too (``rows`` [n, C]: a pair's other end), so that no
+# ---- the rule as Pallas (Mosaic) kernels. The operands lie in HBM where
+# their producers left them, positions last: q and k [b, key heads x K, S],
+# v and o [b, heads x V, S]. A grid step takes ``kb`` key heads, the ``hb =
+# kb x ratio`` value heads that read them and ``n`` chunks of one batch row:
+# blocks [1, kb K, n C] and [1, hb V, n C], which the body turns in VMEM,
+# ``KERNEL_LANES`` positions at a time, into scratch [kb, n C, K] and [hb, n
+# C, V], positions on the sublanes (q and k L2-normed on the way, where the
+# caller asks); the chunks' walk reads and writes that scratch, and what it
+# wrote is turned back into the output blocks. A chunk's running sums and
+# beta come down the sublanes (``cols`` [C, 2 n]: what scales a row), the
+# sums along the lanes too (``rows`` [n, C]: a pair's other end), so that no
 # [C, C] is ever transposed. The state is carried transposed, ``P = S^T
 # [K, V]``, in the block of the last-state output, which stays in VMEM
 # while the grid walks a head's sequence.
@@ -427,36 +490,120 @@ def _inverse_group(A: List[jax.Array]) -> List[jax.Array]:
     return [T[i * C:(i + 1) * C, i * C:(i + 1) * C] for i in range(n)]
 
 
-def _chunk_local(q, k, v, Gc, Gr, bc, dt):
-    """What a chunk's kernels build from its own rows alone, the state
-    aside: q and k [C, K], v [C, V], the running sums down the sublanes
-    (``Gc`` [C, 1]) and along the lanes (``Gr`` [1, C]) and beta down the
-    sublanes -> those (``q``, ``k``, ``bc``), the decays
-    (``_kernel_decays``), ``kkd`` (``K K^T`` times
-    the pair decays) and ``A`` float32, the rows in float32 (``qf``, ``kf``,
-    ``vf``), the MXU's operands ``Kb`` (``beta exp(G) K``), ``Vb`` (``beta
-    V``), ``Qg`` (``exp(G) Q``), ``Ke`` (K decayed to the chunk's end) in
-    ``dt``, and ``Mf`` (``Q K^T`` times the pair decays, float32). Where
-    two products share their right-hand side their left-hand sides are
-    stacked, here k over q: the MXU holds the right-hand side's tile while
-    the rows stream, and 64 rows leave it waiting for the next tile."""
+def _key_head(h: int, ratio: int) -> int:
+    """The key head, of a grid step's own, that the step's value head ``h``
+    reads: a step holds whole key heads with the ``ratio`` value heads of
+    each, neighbours sharing one (``_join_heads``' map; the kernels' bodies
+    look it up while they trace)."""
+    return h // ratio
+
+
+def _kernel_norm(x, eps, scale):
+    """``l2_norm``'s arithmetic on a tile in VMEM: x [D, positions] float32,
+    a position a lane -> ``scale x / sqrt(sum(x^2) + eps)`` down each lane
+    (looked up at trace time; the backward takes its ``jax.vjp``)."""
+    return x * (jax.lax.rsqrt(
+        jnp.sum(jnp.square(x), axis=0, keepdims=True) + eps) * scale)
+
+
+def _norms_of(norm):
+    """``rule_kernels``' ``norm`` (``eps``, q's ``scale``) -> (q's, k's)
+    arguments of ``_kernel_norm``, k's scale 1; (None, None) for none."""
+    return (norm, (norm[0], 1.0)) if norm else (None, None)
+
+
+def _lane_groups(block_ref, body):
+    """``body(at)`` for each ``KERNEL_LANES`` positions ``at`` of a block
+    whose last axis is its positions (a loop, its body traced once)."""
+    import jax.experimental.pallas as pl
+
+    w = min(KERNEL_LANES, block_ref.shape[-1])
+
+    def group(i, carry):
+        body(pl.ds(pl.multiple_of(i * w, w), w))
+        return carry
+
+    jax.lax.fori_loop(0, block_ref.shape[-1] // w, group, 0)
+
+
+def _turned_in(block_ref, rows_ref, norm=None):
+    """A block [1, heads D, L], positions last, into scratch [heads, L, D],
+    positions on the sublanes; ``norm`` (``eps``, ``scale``): each head's
+    rows L2-normed on the way (float32, one rounding to the scratch's
+    dtype, as ``l2_norm``)."""
+    heads, _, D = rows_ref.shape
+
+    def group(at):
+        for h in range(heads):
+            x = block_ref[0, h * D:(h + 1) * D, at]
+            if norm is not None:
+                x = _kernel_norm(x.astype(jnp.float32), *norm)
+            rows_ref[h, at, :] = x.astype(rows_ref.dtype).T
+
+    _lane_groups(block_ref, group)
+
+
+def _turned_out(rows_ref, block_ref, normed=None):
+    """Scratch [heads, L, D] into a block [1, heads D, L]. ``normed`` (the
+    block of q or k that ``_turned_in`` normed, and its ``norm``): the rows
+    are the normed rows' cotangent, and what leaves is the block's, through
+    ``_kernel_norm``'s ``jax.vjp`` in float32."""
+    heads, _, D = rows_ref.shape
+    f32 = jnp.float32
+
+    def group(at):
+        for h in range(heads):
+            own = slice(h * D, (h + 1) * D)
+            d = rows_ref[h, at, :].T
+            if normed is not None:
+                x_ref, norm = normed
+                _, back = jax.vjp(lambda x: _kernel_norm(x, *norm),
+                                  x_ref[0, own, at].astype(f32))
+                d, = back(d.astype(f32))
+            block_ref[0, own, at] = d.astype(block_ref.dtype)
+
+    _lane_groups(block_ref, group)
+
+
+def _chunk_keys(q, k):
+    """What the value heads of one key head share of a chunk: its rows q
+    and k [C, K] (``q``, ``k``; float32 ``qf``, ``kf``) and ``K K^T`` and
+    ``Q K^T`` [C, C] float32 (``kk``, ``qk``) from one product, k stacked
+    over q: the MXU holds the right-hand side's tile while the rows stream,
+    and 64 rows leave it waiting for the next tile."""
+    f32 = jnp.float32
+    C = k.shape[0]
+    with_k = _nt(jnp.concatenate([k, q], axis=0), k)
+    return dict(q=q, k=k, qf=q.astype(f32), kf=k.astype(f32),
+                kk=with_k[:C], qk=with_k[C:])
+
+
+def _chunk_local(keys, v, Gc, Gr, bc, dt):
+    """What a chunk's kernels build for one value head from the chunk's own
+    rows alone, the state aside: ``keys`` (``_chunk_keys`` of its key
+    head), v [C, V], the head's running sums down the sublanes (``Gc`` [C,
+    1]) and along the lanes (``Gr`` [1, C]) and its beta down the sublanes
+    -> ``keys``' entries, ``bc``, the decays (``_kernel_decays``), ``kkd``
+    (``K K^T`` times the pair decays) and ``A`` float32, ``vf``, the MXU's
+    operands ``Kb`` (``beta exp(G) K``), ``Vb`` (``beta V``), ``Qg``
+    (``exp(G) Q``), ``Ke`` (K decayed to the chunk's end) in ``dt``, and
+    ``Mf`` (``Q K^T`` times the pair decays, float32)."""
     f32 = jnp.float32
     d = _kernel_decays(Gc, Gr)
-    C = k.shape[0]
     row, col = _at(d["pair"].shape)
-    with_k = _nt(jnp.concatenate([k, q], axis=0), k)
-    kkd = with_k[:C] * d["pair"]
-    qf, kf, vf = q.astype(f32), k.astype(f32), v.astype(f32)
+    kkd = keys["kk"] * d["pair"]
+    qf, kf, vf = keys["qf"], keys["kf"], v.astype(f32)
     return dict(
-        d, q=q, k=k, bc=bc, kkd=kkd, qf=qf, kf=kf, vf=vf,
+        d, **keys, bc=bc, kkd=kkd, vf=vf,
         A=jnp.where(row > col, bc * kkd, 0.0),
         Kb=(kf * (bc * d["grown"])).astype(dt), Vb=(vf * bc).astype(dt),
         Qg=(qf * d["grown"]).astype(dt), Ke=(kf * d["to_end"]).astype(dt),
-        Mf=with_k[C:] * d["pair"])
+        Mf=keys["qk"] * d["pair"])
 
 
 def _rows_of(j, C):
-    """Chunk ``j``'s rows of a grid step's block (``j`` a loop's index)."""
+    """Chunk ``j``'s rows of a grid step's scratch (``j`` a loop's
+    index)."""
     import jax.experimental.pallas as pl
 
     return pl.ds(pl.multiple_of(j * C, C), C)
@@ -469,23 +616,39 @@ def _column(cols, j):
     return jnp.where(lane == j, cols, 0.0).sum(1, keepdims=True)
 
 
-def _chunk_of(j, h, q_ref, k_ref, v_ref, cols_ref, rows_ref):
-    """``_chunk_local`` of chunk ``j`` of a grid step's head ``h``."""
+def _readers(key_heads: int, heads: int):
+    """[(key head, the value heads that read it)] of a grid step of
+    ``key_heads`` key heads and ``heads`` value heads: ``_key_head``'s map,
+    looked up at trace time."""
+    reads = [_key_head(h, heads // key_heads) for h in range(heads)]
+    return [(kh, [h for h in range(heads) if reads[h] == kh])
+            for kh in range(key_heads)]
+
+
+def _chunk_of(j, h, keys, vs_ref, cols_ref, rows_ref):
+    """``_chunk_local`` of chunk ``j`` of a grid step's value head ``h``,
+    ``keys`` its key head's ``_chunk_keys`` of that chunk."""
     import jax.experimental.pallas as pl
 
     C, n = rows_ref.shape[-1], rows_ref.shape[-2]
-    at, cols = _rows_of(j, C), cols_ref[0, h, 0]
+    cols = cols_ref[0, h, 0]
     return _chunk_local(
-        q_ref[0, h, at, :], k_ref[0, h, at, :], v_ref[0, h, at, :],
-        _column(cols, j), rows_ref[0, h, 0, pl.ds(j, 1), :],
-        _column(cols, n + j), v_ref.dtype)
+        keys, vs_ref[h, _rows_of(j, C), :], _column(cols, j),
+        rows_ref[0, h, 0, pl.ds(j, 1), :], _column(cols, n + j),
+        vs_ref.dtype)
 
 
-def _chunks_of(j, q_ref, *refs):
-    """Chunk ``j`` of every head of a grid step, with every chunk's
-    ``T``, ``W`` (in the activations' dtype) and ``U``."""
-    dt = refs[1].dtype
-    local = [_chunk_of(j, h, q_ref, *refs) for h in range(q_ref.shape[1])]
+def _chunks_of(j, qs_ref, ks_ref, vs_ref, cols_ref, rows_ref):
+    """Chunk ``j`` of every value head of a grid step, in the heads' order,
+    with every chunk's ``T``, ``W`` (in the activations' dtype) and ``U``;
+    a key head's rows and products are formed once for its value heads."""
+    dt, at = vs_ref.dtype, _rows_of(j, rows_ref.shape[-1])
+    local = {}
+    for kh, heads in _readers(qs_ref.shape[0], vs_ref.shape[0]):
+        keys = _chunk_keys(qs_ref[kh, at, :], ks_ref[kh, at, :])
+        for h in heads:
+            local[h] = _chunk_of(j, h, keys, vs_ref, cols_ref, rows_ref)
+    local = [local[h] for h in sorted(local)]
     # ``_kernel_inverse`` is looked up at trace time, as ``_kernel_state``
     # and ``_kernel_decays`` are: the controls' three seams
     for x, T in zip(local, _kernel_inverse([x["A"] for x in local])):
@@ -508,13 +671,16 @@ def _handed_on(x, P, dt):
 
 
 def _rule_fwd_kernel(q_ref, k_ref, v_ref, cols_ref, rows_ref, o_ref,
-                     last_ref, *kept_ref, chunks):
-    """A grid step of the forward: its chunks one after another (a loop,
-    its body traced once: what overlaps is a chunk's heads), the state in
-    ``last_ref``'s block from step to step."""
+                     last_ref, *more, norm):
+    """A grid step of the forward: its blocks turned into scratch (``more``:
+    the kept state's block where a gradient is asked for, then the scratch
+    of q, k, v and o), its chunks one after another (a loop, its body
+    traced once: what overlaps is a chunk's heads), the state in
+    ``last_ref``'s block from step to step, and o turned back."""
     import jax.experimental.pallas as pl
 
-    C, dt = cols_ref.shape[3], v_ref.dtype
+    *kept_ref, qs_ref, ks_ref, vs_ref, os_ref = more
+    (n, C), dt = rows_ref.shape[-2:], v_ref.dtype
 
     @pl.when(pl.program_id(2) == 0)
     def _():
@@ -522,32 +688,41 @@ def _rule_fwd_kernel(q_ref, k_ref, v_ref, cols_ref, rows_ref, o_ref,
 
     for ref in kept_ref:                    # the state this step starts from
         ref[0, :, 0] = last_ref[0]
+    q_norm, k_norm = _norms_of(norm)
+    _turned_in(q_ref, qs_ref, q_norm)
+    _turned_in(k_ref, ks_ref, k_norm)
+    _turned_in(v_ref, vs_ref)
 
     def chunk(j, carry):
-        for h, x in enumerate(_chunks_of(j, q_ref, k_ref, v_ref, cols_ref,
+        for h, x in enumerate(_chunks_of(j, qs_ref, ks_ref, vs_ref, cols_ref,
                                          rows_ref)):
             _, last_ref[0, h], o = _handed_on(
                 x, _kernel_state(last_ref[0, h]), dt)
-            o_ref[0, h, _rows_of(j, C), :] = o.astype(o_ref.dtype)
+            os_ref[h, _rows_of(j, C), :] = o.astype(os_ref.dtype)
         return carry
 
-    jax.lax.fori_loop(0, chunks, chunk, 0)
+    jax.lax.fori_loop(0, n, chunk, 0)
+    _turned_out(os_ref, o_ref)
 
 
 def _rule_bwd_kernel(q_ref, k_ref, v_ref, cols_ref, rows_ref, kept_ref,
                      do_ref, dlast_ref, dq_ref, dk_ref, dv_ref, dcols_ref,
                      drows_ref, state_ref, T_ref, W_ref, new_ref, dstate_ref,
-                     *, chunks):
-    """A grid step of the backward, the steps taken last first: the
-    chunks' states, ``T``, ``W`` and ``V'`` built again from the state the
-    step started from (``kept_ref``) into scratch, then the chunks last
-    first, ``dstate_ref`` carrying the state's cotangent from step to
-    step."""
+                     qs_ref, ks_ref, vs_ref, dos_ref, dqs_ref, dks_ref,
+                     dvs_ref, *, norm):
+    """A grid step of the backward, the steps taken last first: its blocks
+    turned into scratch as the forward's; the chunks' states, ``T``, ``W``
+    and ``V'`` built again from the state the step started from
+    (``kept_ref``) into scratch; then the chunks last first, ``dstate_ref``
+    carrying the state's cotangent from step to step, a key head's ``dq``
+    and ``dk`` summed over its value heads in float32 (what multiplies q
+    and k summed before its product) into scratch; and the three gradients
+    turned back, q's and k's through their norms."""
     import jax.experimental.pallas as pl
 
-    n, heads = chunks, q_ref.shape[1]
-    C, dt = cols_ref.shape[3], v_ref.dtype
-    refs = (q_ref, k_ref, v_ref, cols_ref, rows_ref)
+    (n, C), dt = rows_ref.shape[-2:], v_ref.dtype
+    refs = (qs_ref, ks_ref, vs_ref, cols_ref, rows_ref)
+    q_norm, k_norm = _norms_of(norm)
 
     @pl.when(pl.program_id(2) == 0)
     def _():
@@ -555,6 +730,10 @@ def _rule_bwd_kernel(q_ref, k_ref, v_ref, cols_ref, rows_ref, kept_ref,
 
     state_ref[0] = kept_ref[0, :, 0]
     dcols_ref[...] = jnp.zeros_like(dcols_ref)
+    _turned_in(q_ref, qs_ref, q_norm)
+    _turned_in(k_ref, ks_ref, k_norm)
+    _turned_in(v_ref, vs_ref)
+    _turned_in(do_ref, dos_ref)
 
     def again(j, carry):
         for h, x in enumerate(_chunks_of(j, *refs)):
@@ -570,69 +749,104 @@ def _rule_bwd_kernel(q_ref, k_ref, v_ref, cols_ref, rows_ref, kept_ref,
     def back(i, carry):
         j = n - 1 - i
         at = _rows_of(j, C)
-        for h in range(heads):
-            x = _chunk_of(j, h, *refs)
-            q, k, bc = x["q"], x["k"], x["bc"]
-            P, T, W, new = (state_ref[j, h], T_ref[j, h], W_ref[j, h],
-                            new_ref[j, h])
-            Pb, Tb = P.astype(dt), T.astype(dt)
-            dO, dP = do_ref[0, h, at, :], dstate_ref[h]
-            dPb = dP.astype(dt)
-            # O = Qg P + M V',  P' = whole P + Ke^T V',  V' = U - W P
-            dnew = _tn(x["Mf"].astype(dt), dO) + _nn(x["Ke"], dPb)
-            dnewb = dnew.astype(dt)
-            dMf = jnp.where(row >= col, _nt(dO, new), 0.0)
-            dQg, dKe = _nt(dO, Pb), _nt(new, dPb)
-            dW = (-_nt(dnewb, Pb)).astype(dt)
-            dwhole = jnp.sum(P * dP, keepdims=True)
-            dstate_ref[h] = (x["whole"] * dP + _tn(x["Qg"], dO)
-                             - _tn(W, dnewb))
-            # W = T Kb, U = T Vb; dA = -T^T dT T^T under the diagonal
-            dT = _nt(dW, x["Kb"]) + _nt(dnewb, x["Vb"])
-            dKb, dVb = _tn(Tb, dW), _tn(Tb, dnewb)
-            dA = jnp.where(row > col, -_tn(
-                Tb, _nt(dT.astype(dt), Tb).astype(dt)), 0.0)
-            # A = beta (K K^T pair), M = Q K^T pair
-            dkk = (dA * bc * x["pair"]).astype(dt)
-            dqk = (dMf * x["pair"]).astype(dt)
-            moved = dA * x["A"] + dMf * x["Mf"]      # d pair * pair
-            along_k = (dKb * x["kf"]).sum(1, keepdims=True)
-            dto_end = (dKe * x["kf"]).sum(1, keepdims=True) * x["to_end"]
-            dq_ref[0, h, at, :] = (dQg * x["grown"] + _nn(dqk, k)
-                                   ).astype(dq_ref.dtype)
-            dk_ref[0, h, at, :] = (
-                _tn(dqk, q) + _nn(dkk, k) + _tn(dkk, k)
-                + dKb * (bc * x["grown"]) + dKe * x["to_end"]
-            ).astype(dk_ref.dtype)
-            dv_ref[0, h, at, :] = (dVb * bc).astype(dv_ref.dtype)
-            dbeta = ((dA * x["kkd"]).sum(1, keepdims=True)
-                     + along_k * x["grown"]
-                     + (dVb * x["vf"]).sum(1, keepdims=True))
-            # G: through exp(G), exp(G_end - G), exp(G_end) and the pairs
-            dGc = (((dQg * x["qf"]).sum(1, keepdims=True) + along_k * bc)
-                   * x["grown"] - dto_end + moved.sum(1, keepdims=True))
-            dGc = dGc + jnp.where(
-                row[:, :1] == C - 1,
-                dto_end.sum(0, keepdims=True) + dwhole * x["whole"], 0.0)
-            dcols_ref[0, h, 0] = jnp.where(lane == j, dGc, jnp.where(
-                lane == n + j, dbeta, dcols_ref[0, h, 0]))
-            drows_ref[0, h, 0, pl.ds(j, 1), :] = -moved.sum(0, keepdims=True)
+        for kh, heads in _readers(qs_ref.shape[0], vs_ref.shape[0]):
+            keys = _chunk_keys(qs_ref[kh, at, :], ks_ref[kh, at, :])
+            q, k = keys["q"], keys["k"]
+            # what the key head's value heads add to dq and dk: rows, and
+            # what multiplies q and k (summed before the product)
+            dq = dk = jnp.zeros(q.shape, jnp.float32)
+            dqk = dkk = jnp.zeros((C, C), jnp.float32)
+            for h in heads:
+                x = _chunk_of(j, h, keys, *refs[2:])
+                bc = x["bc"]
+                P, T, W, new = (state_ref[j, h], T_ref[j, h], W_ref[j, h],
+                                new_ref[j, h])
+                Pb, Tb = P.astype(dt), T.astype(dt)
+                dO, dP = dos_ref[h, at, :], dstate_ref[h]
+                dPb = dP.astype(dt)
+                # O = Qg P + M V',  P' = whole P + Ke^T V',  V' = U - W P
+                dnew = _tn(x["Mf"].astype(dt), dO) + _nn(x["Ke"], dPb)
+                dnewb = dnew.astype(dt)
+                dMf = jnp.where(row >= col, _nt(dO, new), 0.0)
+                dQg, dKe = _nt(dO, Pb), _nt(new, dPb)
+                dW = (-_nt(dnewb, Pb)).astype(dt)
+                dwhole = jnp.sum(P * dP, keepdims=True)
+                dstate_ref[h] = (x["whole"] * dP + _tn(x["Qg"], dO)
+                                 - _tn(W, dnewb))
+                # W = T Kb, U = T Vb; dA = -T^T dT T^T under the diagonal
+                dT = _nt(dW, x["Kb"]) + _nt(dnewb, x["Vb"])
+                dKb, dVb = _tn(Tb, dW), _tn(Tb, dnewb)
+                dA = jnp.where(row > col, -_tn(
+                    Tb, _nt(dT.astype(dt), Tb).astype(dt)), 0.0)
+                # A = beta (K K^T pair), M = Q K^T pair
+                dkk = dkk + dA * bc * x["pair"]
+                dqk = dqk + dMf * x["pair"]
+                moved = dA * x["A"] + dMf * x["Mf"]      # d pair * pair
+                along_k = (dKb * x["kf"]).sum(1, keepdims=True)
+                dto_end = ((dKe * x["kf"]).sum(1, keepdims=True)
+                           * x["to_end"])
+                dq = dq + dQg * x["grown"]
+                dk = dk + dKb * (bc * x["grown"]) + dKe * x["to_end"]
+                dvs_ref[h, at, :] = (dVb * bc).astype(dvs_ref.dtype)
+                dbeta = ((dA * x["kkd"]).sum(1, keepdims=True)
+                         + along_k * x["grown"]
+                         + (dVb * x["vf"]).sum(1, keepdims=True))
+                # G: through exp(G), exp(G_end - G), exp(G_end), the pairs
+                dGc = (((dQg * x["qf"]).sum(1, keepdims=True) + along_k * bc)
+                       * x["grown"] - dto_end + moved.sum(1, keepdims=True))
+                dGc = dGc + jnp.where(
+                    row[:, :1] == C - 1,
+                    dto_end.sum(0, keepdims=True) + dwhole * x["whole"], 0.0)
+                dcols_ref[0, h, 0] = jnp.where(lane == j, dGc, jnp.where(
+                    lane == n + j, dbeta, dcols_ref[0, h, 0]))
+                drows_ref[0, h, 0, pl.ds(j, 1), :] = -moved.sum(
+                    0, keepdims=True)
+            dqk, dkk = dqk.astype(dt), dkk.astype(dt)
+            dqs_ref[kh, at, :] = dq + _nn(dqk, k)
+            dks_ref[kh, at, :] = (dk + _tn(dqk, q) + _nn(dkk, k)
+                                  + _tn(dkk, k))
         return carry
 
     jax.lax.fori_loop(0, n, back, 0)
+    _turned_out(dqs_ref, dq_ref, norm and (q_ref, q_norm))
+    _turned_out(dks_ref, dk_ref, norm and (k_ref, k_norm))
+    _turned_out(dvs_ref, dv_ref)
 
 
-def _rule_specs(q, v, cols, steps):
-    """What both calls share: the grid (batch row, block of heads, step
-    along the sequence) and the operands' block shapes."""
-    b, H, _, K = q.shape
-    V, (C, n2) = v.shape[-1], cols.shape[3:]
-    hb = _heads_a_block(H)
+def _rule_specs(q, v, cols, dims):
+    """What both calls share: the grid (batch row, block of key heads, step
+    along the sequence), the operands' block shapes (``place(t)``: a step's
+    place along the sequence, which the backward counts from the end) and
+    the scratch a step turns its blocks into. ``dims``: (K, V)."""
+    import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    K, V = dims
+    b, H, steps, C, n2 = cols.shape
+    key_heads = q.shape[1] // K
+    hb = _heads_a_block(H, key_heads)
+    kb = hb * key_heads // H
     L = C * n2 // 2
+
+    def specs(place):
+        def seq(i, h, t):
+            return (i, h, place(t))
+
+        def step(i, h, t):
+            return (i, h, place(t), 0, 0)
+
+        return {"qk": pl.BlockSpec((1, kb * K, L), seq),
+                "v": pl.BlockSpec((1, hb * V, L), seq),
+                "cols": pl.BlockSpec((1, hb, 1, C, n2), step),
+                "rows": pl.BlockSpec((1, hb, 1, n2 // 2, C), step),
+                "state": pl.BlockSpec((1, hb, K, V),
+                                      lambda i, h, t: (i, h, 0, 0)),
+                "kept": pl.BlockSpec((1, hb, 1, K, V), step)}
+
     return {"grid": (b, H // hb, steps), "hb": hb, "chunks": n2 // 2,
-            "qk": (1, hb, L, K), "v": (1, hb, L, V),
-            "cols": (1, hb, 1, C, n2), "rows": (1, hb, 1, n2 // 2, C),
-            "state": (1, hb, K, V), "kept": (1, hb, 1, K, V)}
+            "specs": specs,
+            "qk_rows": lambda dtype: pltpu.VMEM((kb, L, K), dtype),
+            "v_rows": lambda dtype: pltpu.VMEM((hb, L, V), dtype)}
 
 
 def _compiler_params():
@@ -640,64 +854,49 @@ def _compiler_params():
 
     return pltpu.CompilerParams(
         dimension_semantics=("parallel", "parallel", "arbitrary"),
-        vmem_limit_bytes=64 << 20)
+        vmem_limit_bytes=100 << 20)
 
 
-def _rule_forward(q, k, v, cols, rows, keep, interpret):
-    """q, k [b, H, S, K], v [b, H, S, V], cols [b, H, steps, C, 2 n], rows
-    [b, H, steps, n, C] -> (o [b, H, S, V], the last state transposed [b,
-    H, K, V] float32, and with ``keep`` the state before every step [b, H,
-    steps, K, V])."""
+def _rule_forward(q, k, v, cols, rows, dims, norm, keep, interpret):
+    """q, k [b, key heads K, S], v [b, H V, S], cols [b, H, steps, C, 2 n],
+    rows [b, H, steps, n, C] -> (o [b, H V, S], the last state transposed
+    [b, H, K, V] float32, and with ``keep`` the state before every step [b,
+    H, steps, K, V])."""
     import jax.experimental.pallas as pl
 
-    b, H, S, K = q.shape
-    V, steps = v.shape[-1], cols.shape[2]
-    at = _rule_specs(q, v, cols, steps)
+    K, V = dims
+    b, H, steps = cols.shape[:3]
+    at = _rule_specs(q, v, cols, dims)
+    to = at["specs"](lambda t: t)
     f32 = jnp.float32
-
-    def seq(i, h, t):
-        return (i, h, t, 0)
-
-    def step(i, h, t):
-        return (i, h, t, 0, 0)
-
     return pl.pallas_call(
-        functools.partial(_rule_fwd_kernel, chunks=at["chunks"]),
+        functools.partial(_rule_fwd_kernel, norm=norm),
         name="delta_rule_fwd",
-        out_shape=[jax.ShapeDtypeStruct((b, H, S, V), v.dtype),
+        out_shape=[jax.ShapeDtypeStruct(v.shape, v.dtype),
                    jax.ShapeDtypeStruct((b, H, K, V), f32)]
         + [jax.ShapeDtypeStruct((b, H, steps, K, V), f32)] * keep,
         grid=at["grid"],
-        in_specs=[pl.BlockSpec(at["qk"], seq), pl.BlockSpec(at["qk"], seq),
-                  pl.BlockSpec(at["v"], seq), pl.BlockSpec(at["cols"], step),
-                  pl.BlockSpec(at["rows"], step)],
-        out_specs=[pl.BlockSpec(at["v"], seq),
-                   pl.BlockSpec(at["state"], lambda i, h, t: (i, h, 0, 0))]
-        + [pl.BlockSpec(at["kept"], step)] * keep,
+        in_specs=[to["qk"], to["qk"], to["v"], to["cols"], to["rows"]],
+        out_specs=[to["v"], to["state"]] + [to["kept"]] * keep,
+        scratch_shapes=[at["qk_rows"](q.dtype), at["qk_rows"](q.dtype),
+                        at["v_rows"](v.dtype), at["v_rows"](v.dtype)],
         compiler_params=_compiler_params(), interpret=interpret,
     )(q, k, v, cols, rows)
 
 
-def _rule_backward(q, k, v, cols, rows, kept, do, dlast, interpret):
+def _rule_backward(q, k, v, cols, rows, kept, do, dlast, dims, norm,
+                   interpret):
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    K, V, steps = q.shape[-1], v.shape[-1], cols.shape[2]
-    at = _rule_specs(q, v, cols, steps)
-    n, hb, C = at["chunks"], at["hb"], cols.shape[3]
+    K, V = dims
+    steps, C = cols.shape[2:4]
+    at = _rule_specs(q, v, cols, dims)
+    to = at["specs"](lambda t: steps - 1 - t)
+    n, hb = at["chunks"], at["hb"]
     f32 = jnp.float32
-
-    def seq(i, h, t):
-        return (i, h, steps - 1 - t, 0)
-
-    def step(i, h, t):
-        return (i, h, steps - 1 - t, 0, 0)
-
-    def whole(i, h, t):
-        return (i, h, 0, 0)
-
     return pl.pallas_call(
-        functools.partial(_rule_bwd_kernel, chunks=n),
+        functools.partial(_rule_bwd_kernel, norm=norm),
         name="delta_rule_bwd",
         out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
                    jax.ShapeDtypeStruct(k.shape, k.dtype),
@@ -705,71 +904,85 @@ def _rule_backward(q, k, v, cols, rows, kept, do, dlast, interpret):
                    jax.ShapeDtypeStruct(cols.shape, f32),
                    jax.ShapeDtypeStruct(rows.shape, f32)],
         grid=at["grid"],
-        in_specs=[pl.BlockSpec(at["qk"], seq), pl.BlockSpec(at["qk"], seq),
-                  pl.BlockSpec(at["v"], seq), pl.BlockSpec(at["cols"], step),
-                  pl.BlockSpec(at["rows"], step),
-                  pl.BlockSpec(at["kept"], step), pl.BlockSpec(at["v"], seq),
-                  pl.BlockSpec(at["state"], whole)],
-        out_specs=[pl.BlockSpec(at["qk"], seq), pl.BlockSpec(at["qk"], seq),
-                   pl.BlockSpec(at["v"], seq), pl.BlockSpec(at["cols"], step),
-                   pl.BlockSpec(at["rows"], step)],
+        in_specs=[to["qk"], to["qk"], to["v"], to["cols"], to["rows"],
+                  to["kept"], to["v"], to["state"]],
+        out_specs=[to["qk"], to["qk"], to["v"], to["cols"], to["rows"]],
         scratch_shapes=[pltpu.VMEM((n + 1, hb, K, V), f32),
                         pltpu.VMEM((n, hb, C, C), f32),
                         pltpu.VMEM((n, hb, C, K), v.dtype),
                         pltpu.VMEM((n, hb, C, V), v.dtype),
-                        pltpu.VMEM((hb, K, V), f32)],
+                        pltpu.VMEM((hb, K, V), f32),
+                        at["qk_rows"](q.dtype), at["qk_rows"](q.dtype),
+                        at["v_rows"](v.dtype), at["v_rows"](v.dtype),
+                        at["qk_rows"](f32), at["qk_rows"](f32),
+                        at["v_rows"](v.dtype)],
         compiler_params=_compiler_params(), interpret=interpret,
     )(q, k, v, cols, rows, kept, do, dlast)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
-def _rule_calls(q, k, v, cols, rows, interpret):
-    return tuple(_rule_forward(q, k, v, cols, rows, 0, interpret))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
+def _rule_calls(q, k, v, cols, rows, dims, norm, interpret):
+    return tuple(_rule_forward(q, k, v, cols, rows, dims, norm, 0,
+                               interpret))
 
 
-def _rule_calls_fwd(q, k, v, cols, rows, interpret):
-    o, last, kept = _rule_forward(q, k, v, cols, rows, 1, interpret)
+def _rule_calls_fwd(q, k, v, cols, rows, dims, norm, interpret):
+    o, last, kept = _rule_forward(q, k, v, cols, rows, dims, norm, 1,
+                                  interpret)
     return (o, last), (q, k, v, cols, rows, kept)
 
 
-def _rule_calls_bwd(interpret, res, cts):
-    return tuple(_rule_backward(*res, *cts, interpret))
+def _rule_calls_bwd(dims, norm, interpret, res, cts):
+    return tuple(_rule_backward(*res, *cts, dims, norm, interpret))
 
 
 _rule_calls.defvjp(_rule_calls_fwd, _rule_calls_bwd)
 
 
-def rule_kernels(q, k, v, g, beta, plan, interpret: bool = False):
-    """``gated_delta_rule`` as two Mosaic calls, ``delta_rule_fwd`` and
+def rule_kernels(q, k, v, g, beta, plan, norm=None,
+                 interpret: bool = False):
+    """The rule as two Mosaic calls, ``delta_rule_fwd`` and
     ``delta_rule_bwd`` behind a ``custom_vjp`` (``plan``: ``rule_plan``'s,
-    of the form ``pallas``). Around them, in XLA: the operands head-major
-    ([b, H, S, .], S whole grid steps), the running sums of ``g`` down each
-    chunk, and those and ``beta`` in the two layouts the kernels read
-    (module comment above). The forward carries the state in VMEM and,
-    when a gradient is asked for, writes the state before every grid step
-    (``states_kept``); the backward takes the steps last first, builds a
-    step's chunks again from that state and carries the state's
-    cotangent."""
-    b, s, H, _ = q.shape
+    of the form ``pallas``), on operands that lie as the taps' kernels
+    leave them, positions last: q and k [b, key heads x K, s], v [b, H V,
+    s], g and beta [b, s, H] float32 -> (o [b, H V, s] in ``v``'s dtype,
+    the state after the last position [b, H, V, K] float32). ``norm``
+    (``eps``, ``scale``): q and k come as the taps left them and the calls
+    take their L2 norms in VMEM, forward and backward (q's times ``scale``);
+    none: k is of unit length and q scaled already. Around the calls, in
+    XLA: the padding to whole grid steps, the running sums of ``g`` down
+    each chunk, and those and ``beta`` in the two layouts the kernels read
+    (``_sums_laid``); nothing of q, k, v, o or their gradients. The
+    forward carries the state in VMEM and, when a gradient is asked for,
+    writes the state before every grid step (``states_kept``); the backward
+    takes the steps last first, builds a step's chunks again from that
+    state and carries the state's cotangent."""
+    s = q.shape[-1]
+    pad = plan["steps"] * plan["chunks_a_call"] * plan["chunk"] - s
+
+    def padded(a):
+        return jnp.pad(a, ((0, 0), (0, 0), (0, pad))) if pad else a
+
+    o, last = _rule_calls(padded(q), padded(k), padded(v),
+                          *_sums_laid(g, beta, plan),
+                          (plan["key_dim"], plan["value_dim"]), norm,
+                          interpret)
+    return o[..., :s], jnp.swapaxes(last, -1, -2)
+
+
+def _sums_laid(g, beta, plan):
+    """g and beta [b, s, H] -> what the calls read of them, float32 and
+    padded to whole grid steps: ``cols`` [b, H, steps, C, 2 n] (a chunk's
+    running sums of ``g``, then its beta, down the sublanes) and ``rows`` [b,
+    H, steps, n, C] (the sums along the lanes)."""
+    b, s, H = g.shape
     C, n, steps = plan["chunk"], plan["chunks_a_call"], plan["steps"]
-    pad = steps * n * C - s
-    f32 = jnp.float32
-
-    def by_head(a):
-        return jnp.moveaxis(_padded(a, pad), 2, 1)
-
-    def twice(G, beta_):
-        """[b, steps, n, C, H] each -> (cols [b, H, steps, C, 2 n], rows
-        [b, H, steps, n, C])."""
-        return (jnp.transpose(jnp.concatenate([G, beta_], axis=2),
-                              (0, 4, 1, 3, 2)),
-                jnp.transpose(G, (0, 4, 1, 2, 3)))
-
-    g, beta = (_padded(a.astype(f32), pad).reshape(b, steps, n, C, H)
-               for a in (g, beta))
-    o, last = _rule_calls(by_head(q), by_head(k), by_head(v),
-                          *twice(_kernel_sums(g), beta), interpret)
-    return jnp.moveaxis(o, 1, 2)[:, :s], jnp.swapaxes(last, -1, -2)
+    g, beta = (_padded(a.astype(jnp.float32), steps * n * C - s
+                       ).reshape(b, steps, n, C, H) for a in (g, beta))
+    G = _kernel_sums(g)
+    return (jnp.transpose(jnp.concatenate([G, beta], axis=2),
+                          (0, 4, 1, 3, 2)),
+            jnp.transpose(G, (0, 4, 1, 2, 3)))
 
 
 def _gates(a, b_, p, beta_scale: float = 2.0):
@@ -789,6 +1002,36 @@ def _join_heads(x: jax.Array, heads: int) -> jax.Array:
     return jnp.repeat(x, heads // x.shape[2], axis=2)
 
 
+def rule_part(q: jax.Array, k: jax.Array, v: jax.Array, a: jax.Array,
+              b_: jax.Array, p: Dict[str, jax.Array], *, heads: int,
+              key_heads: int, key_dim: int, value_dim: int, chunk: int = 64,
+              mesh=None, beta_scale: float = 2.0
+              ) -> Tuple[jax.Array, jax.Array]:
+    """What the mixer runs under ``gdn_rule``: q and k [b, key heads x K, s]
+    and v [b, H V, s] as the taps leave them, positions last, the
+    in-projection's a and b [b, s, H] -> (o [b, s, H, V], the state after
+    the last position). The kernels take q, k and v where they lie, q and k
+    at their own heads, and norm them in VMEM; XLA's walk gets them swapped,
+    normed (``l2_norm``) and, inside ``gated_delta_rule``, copied to the
+    value heads. ``_gates``, ``rule_kernels`` and ``l2_norm`` are looked up
+    at trace time."""
+    b, _, s = v.shape
+    g, beta = _gates(a, b_, p, beta_scale)
+    plan = rule_plan(b, s, heads, key_dim, value_dim, chunk, mesh, key_heads)
+    scale = key_dim ** -0.5
+    if plan["form"] == "pallas":
+        with tracing.span("rtpu.gdn.rule_plan", keep=True, **plan):
+            pass
+        o, S = rule_kernels(q, k, v, g, beta, plan,
+                            norm=(QK_NORM_EPS, scale))
+        return jnp.swapaxes(o, 1, 2).reshape(b, s, heads, value_dim), S
+    q, k, v = (jnp.swapaxes(x, 1, 2).reshape(b, s, n, -1)
+               for x, n in ((q, key_heads), (k, key_heads), (v, heads)))
+    return gated_delta_rule(
+        l2_norm(q, QK_NORM_EPS, scale), l2_norm(k, QK_NORM_EPS), v, g, beta,
+        chunk=chunk, mesh=mesh)
+
+
 def gated_delta_mixer(h: jax.Array, p: Dict[str, jax.Array], *, heads: int,
                       key_dim: int, value_dim: int, chunk: int = 64,
                       eps: float = 1e-6, mesh=None,
@@ -805,9 +1048,10 @@ def gated_delta_mixer(h: jax.Array, p: Dict[str, jax.Array], *, heads: int,
     (``causal_conv_silu`` keeps XLA's form under one). ``key_heads``: q
     and k have that many heads (a divisor of ``heads``, the value heads';
     ``H K`` above is then theirs) and value head ``i`` reads key head ``i
-    // (heads / key_heads)``: q and k are normed at their own heads and
-    copied to the value heads before the rule, which reads ``heads`` of
-    each (Qwen3-Next: 16 under 32). ``beta_scale``: ``_gates``'."""
+    // (heads / key_heads)`` (Qwen3-Next: 16 under 32); q and k are normed
+    at their own heads, and the rule reads them there as its kernels or
+    copied to the value heads as XLA's walk (``rule_part``).
+    ``beta_scale``: ``_gates``'."""
     b, s, _ = h.shape
     dt_ = h.dtype
     key_heads = key_heads or heads
@@ -828,17 +1072,13 @@ def gated_delta_mixer(h: jax.Array, p: Dict[str, jax.Array], *, heads: int,
                 first=hv, sizes=(hk, hk, hv), mesh=mesh,
                 span="rtpu.gdn.conv_plan")
         with jax.named_scope("gdn_rule"):
-            q, k, v = (jnp.swapaxes(x, 1, 2).reshape(b, s, n, -1)
-                       for x, n in ((q, key_heads), (k, key_heads),
-                                    (v, heads)))
-            # ``_gates`` and ``l2_norm`` are looked up at trace time too
-            # (and ``_join_heads``, where fewer heads of q and k serve)
-            g, beta = _gates(a, b_, p, beta_scale)
-            q, k = l2_norm(q, scale=key_dim ** -0.5), l2_norm(k)
-            if key_heads != heads:
-                q, k = (_join_heads(x, heads) for x in (q, k))
-            o, S = gated_delta_rule(q, k, v, g, beta, chunk=chunk,
-                                    mesh=mesh, key_heads=key_heads)
+            # q k v taken where the taps left them: on the kernel path no
+            # pass of XLA's over them stands between the taps and the
+            # gated norm
+            o, S = rule_part(q, k, v, a, b_, p, heads=heads,
+                             key_heads=key_heads, key_dim=key_dim,
+                             value_dim=value_dim, chunk=chunk, mesh=mesh,
+                             beta_scale=beta_scale)
             S = jax.lax.stop_gradient(S)
         with jax.named_scope("gdn_norm"):
             y = gated_rms_norm(o, z.reshape(b, s, heads, value_dim),
@@ -905,25 +1145,24 @@ def gated_delta_part(counter: str = "gdn_state_abs_max", norm: str = "post",
         # (their gradients lie where the SwiGLU's arrays did); beside them
         # what the rule's form puts in HBM (``rule_plan``). XLA's walk: one
         # step's pair matrices and carried states with their gradients, W,
-        # U, V' and the decayed copies of q and k in both dtypes, and the
-        # state before every step; held to the compiled step at 32,768
-        # tokens of a 3 : 1 stack at full remat: 1.4% over what the
-        # compiler allots (PR 39). The kernels: the kept states and the
-        # running sums alone, and the taps' output is not held beside the
-        # head-major copies the calls read: 1.8% over at 32,768 tokens (at
-        # 16,384 the full layer takes its rungs and the need lies 3.8%
-        # under the allotment; PERF.md 6, PR 40)
+        # U, V' and the decayed copies of q and k in both dtypes, the
+        # state before every step, and q's and k's copies at the value
+        # heads where fewer key heads serve them; held to the compiled step
+        # at 32,768 tokens of a 3 : 1 stack at full remat: 1.4% over what
+        # the compiler allots (PR 39). The kernels: the kept states and the
+        # running sums alone; the calls read the taps' output where it lies,
+        # q and k at their own heads, and nothing of their size is written
+        # beside it: 1.9% over at 32,768 tokens (PERF.md 6, PR 53; PR 40's
+        # head-major copies stood where the taps' output is counted)
         heads, hv = shape["g_A_log"][-1], shape["g_out"][0]
         key_dim = cfg.linear_key_dim
         plan = rule_plan(1, tokens, heads, key_dim, hv // heads,
-                         cfg.rule_chunk, mesh)
-        # what q's and k's copies at the value heads add to the width the
-        # in-projection's output has them at
-        joined = 2 * (heads - q_heads(cfg)) * key_dim
+                         cfg.rule_chunk, mesh, q_heads(cfg))
         if plan["form"] == "pallas":
-            return kept(width=shape["g_in"][-1] + joined,
+            return kept(width=shape["g_in"][-1],
                         rows=plan["float32_bytes_in_hbm"])
-        return kept(width=shape["g_in"][-1] + shape["g_conv"][0] + joined,
+        return kept(width=shape["g_in"][-1] + shape["g_conv"][0]
+                    + 2 * (heads - q_heads(cfg)) * key_dim,
                     rows=4 * plan["float32_bytes_in_hbm"]
                     + plan["steps"] * hv * key_dim * 4)
 

@@ -6,9 +6,17 @@ each form's outputs and gradients lie from a float32 reference.
 
 - ``rule``: ``train-olmo-hybrid-1chip``'s shapes by default (1 x 32,768
   positions, 30 heads, keys of 96, values of 192, chunk 64, bfloat16);
-  ``--kernels heads,chunks,base`` sets ``KERNEL_HEADS``, ``KERNEL_CHUNKS``
-  and ``KERNEL_BASE``; the reference is XLA's walk on float32 operands at
-  the highest matmul precision.
+  ``--key-heads`` gives q and k fewer heads than ``--heads``, the value
+  heads' (``train-qwen3-next-1chip``: ``--heads 32 --key-heads 16 --key-dim
+  128 --value-dim 128``); ``--kernels heads,chunks,base`` sets
+  ``KERNEL_HEADS``, ``KERNEL_CHUNKS`` and ``KERNEL_BASE``; the reference is
+  XLA's walk on float32 operands at the highest matmul precision. Beside
+  ``gated_delta_rule`` (operands [b, s, heads, dim], swapped around the
+  kernels) each setting of the kernels reads ``around``: the two Mosaic
+  calls alone on operands that lie as they take them (``calls``) and what
+  the mixer runs under ``gdn_rule`` whole, from the taps' outputs to the
+  gated norm's input (``part``: ``ops/delta.rule_part``), so that what XLA
+  does around the kernels is a number.
 - ``scan``: ``train-granite-1chip``'s (1 x 32,768 positions, 64 heads of
   64, a state of 128, one group, chunk 256, bfloat16); ``--kernels
   heads,chunks`` sets ``KERNEL_HEADS`` and ``KERNEL_CHUNKS``; the reference
@@ -57,8 +65,9 @@ def _rule(a):
     f32 = jnp.float32
     keys = jax.random.split(jax.random.PRNGKey(0), 6)
     shape = (1, a.seq, a.heads)
-    q, k = (l2_norm(jax.random.normal(key, shape + (a.key_dim,), f32),
-                    scale=scale)
+    key_heads = a.key_heads or a.heads
+    q, k = (l2_norm(jax.random.normal(
+        key, (1, a.seq, key_heads, a.key_dim), f32), scale=scale)
             for key, scale in ((keys[0], a.key_dim ** -0.5), (keys[1], 1.0)))
     v = jax.random.normal(keys[2], shape + (a.value_dim,), f32)
     g = -jax.nn.softplus(jax.random.normal(keys[3], shape) - 2.0)
@@ -70,14 +79,48 @@ def _rule(a):
 
     def plan(mesh):
         return delta.rule_plan(1, a.seq, a.heads, a.key_dim, a.value_dim,
-                               a.chunk, mesh)
+                               a.chunk, mesh, key_heads)
 
+    def around(cell):
+        """{reading: (function, its inputs with the cotangent last)}: the
+        two calls alone, and the mixer's ``gdn_rule`` part whole (the
+        cell's q, k, v stand for the taps' outputs, its g and beta for the
+        in-projection's a and b)."""
+        q, k, v, g, beta, do = cell
+        dims, now = (a.key_dim, a.value_dim), plan(None)
+        whole = now["steps"] * now["chunks_a_call"] * now["chunk"]
+        norm = (delta.QK_NORM_EPS, a.key_dim ** -0.5)
+
+        def last(x, to=a.seq):
+            return jnp.pad(jnp.swapaxes(x.reshape(1, a.seq, -1), 1, 2),
+                           ((0, 0), (0, 0), (0, to - a.seq)))
+
+        p = {"g_A_log": jnp.zeros((a.heads,), f32),
+             "g_dt_bias": jnp.zeros((a.heads,), f32)}
+
+        def calls(*xs):
+            return delta._rule_calls(*xs, dims, norm, on_cpu)
+
+        def part(*xs):
+            return delta.rule_part(
+                *xs, p, heads=a.heads, key_heads=key_heads,
+                key_dim=a.key_dim, value_dim=a.value_dim, chunk=a.chunk)
+
+        return {"calls": (calls, (last(q, whole), last(k, whole),
+                                  last(v, whole),
+                                  *delta._sums_laid(g, beta, now),
+                                  last(do, whole))),
+                "part": (part, (last(q), last(k), last(v),
+                                g.astype(q.dtype), beta.astype(q.dtype),
+                                do))}
+
+    on_cpu = jax.default_backend() == "cpu"
     return dict(
         module=delta, kernels="rule_kernels",
         constants=("KERNEL_HEADS", "KERNEL_CHUNKS", "KERNEL_BASE"),
         inputs=(q, k, v, g, beta, do), half=(0, 1, 2, 5),
         names=("q", "k", "v", "g", "beta"), call=call, plan=plan,
-        laid=lambda xs, form: xs, reference=None,
+        laid=lambda xs, form: xs, reference=None, around=around,
         gap_to="gap_to_float32_walk")
 
 
@@ -153,6 +196,7 @@ def main() -> None:
     ap.add_argument("--seq", type=int, default=32768)
     for name in sorted({n for s in SHAPES.values() for n in s}):
         ap.add_argument("--" + name.replace("_", "-"), type=int)
+    ap.add_argument("--key-heads", type=int)
     ap.add_argument("--calls", type=int, default=10)
     ap.add_argument("--kernels", action="append", default=[])
     ap.add_argument("--no-gaps", action="store_true")
@@ -173,16 +217,16 @@ def main() -> None:
     exact = op["inputs"]
     cell = tuple(x.astype(bf16) if i in op["half"] else x
                  for i, x in enumerate(exact))
-    argnums = tuple(range(len(exact) - 1))
     # any mesh keeps XLA's walk
     walk = jax.sharding.Mesh(jax.devices()[:1], ("x",))
 
-    def programs(fn):
+    def programs(fn, inputs=len(exact) - 1):
         def loss(*xs):
             o, _ = fn(*xs[:-1])
             return (o.astype(f32) * xs[-1]).sum()
 
-        return jax.jit(fn), jax.jit(jax.grad(loss, argnums=argnums))
+        return jax.jit(fn), jax.jit(jax.grad(
+            loss, argnums=tuple(range(inputs))))
 
     def ms(fn, *xs):
         jax.block_until_ready(fn(*xs))
@@ -207,14 +251,21 @@ def main() -> None:
     def reading(mesh):
         fn, grad = programs(functools.partial(call, mesh=mesh))
         plan = op["plan"](mesh)
-        out = {"plan": {n: plan[n] for n in (
-            "form", "steps", "heads_a_block", "chunks_a_call", "states_kept",
-            "float32_bytes_in_hbm")}}
+        out = {"plan": {n: plan.get(n) for n in (
+            "form", "joined", "steps", "heads_a_block", "chunks_a_call",
+            "states_kept", "float32_bytes_in_hbm")}}
         form = plan["form"]
         xs = op["laid"](cell, form)
         try:
             out["forward_ms"] = ms(fn, *xs[:-1])
             out["gradient_ms"] = ms(grad, *xs)
+            if form == "pallas" and "around" in op:
+                out["around"] = {}
+                for name, (part, ys) in op["around"](cell).items():
+                    part_fn, part_grad = programs(part, len(ys) - 1)
+                    out["around"][name] = {
+                        "forward_ms": ms(part_fn, *ys[:-1]),
+                        "gradient_ms": ms(part_grad, *ys)}
             if want is not None:
                 (o, S), grads = fn(*xs[:-1]), grad(*xs)
                 want_o, *want_grads = op["laid"]((want[0][0],) + want[1],
@@ -228,7 +279,7 @@ def main() -> None:
         return out
 
     out = dict({"device": jax.devices()[0].device_kind, "op": a.op,
-                "seq": a.seq},
+                "seq": a.seq, "key_heads": a.key_heads},
                **{n: getattr(a, n) for n in SHAPES[a.op]},
                xla_walk=reading(walk), kernels={})
     print(json.dumps({"xla_walk": out["xla_walk"]}), flush=True)

@@ -30,8 +30,10 @@ from its ``rtpu.flash.tiles`` span, ``scan_plan``: the same of each
 distinct selective scan, from its ``rtpu.ssm.scan_plan`` span,
 ``conv_plan``: of the taps before it, from ``rtpu.ssm.conv_plan``,
 ``rule_plan`` and ``gdn_conv_plan``: the same of each distinct gated delta
-rule and of its taps, from ``rtpu.gdn.rule_plan`` and
-``rtpu.gdn.conv_plan``, ``embed_plan``: the token gather's and the form
+rule (its ``form``; how a value head comes by its key head, ``joined``:
+the kernels' "index_map" or the walk's "repeat"; how the kernels'
+``operands`` lie: "positions_last", as the taps leave them) and of its
+taps, from ``rtpu.gdn.rule_plan`` and ``rtpu.gdn.conv_plan``, ``embed_plan``: the token gather's and the form
 of its gradient, from ``rtpu.embed.plan``, ``latent_plan`` and ``mtp_plan``:
 a mixture in a latent's and a prediction module's, from
 ``rtpu.moe.latent_plan`` and ``rtpu.train.mtp_plan``, and ``scopes``: how many

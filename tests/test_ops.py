@@ -1968,7 +1968,7 @@ def test_rule_plan_walks_within_its_bytes():
 def test_rule_plan_of_the_kernels_keeps_states_and_no_pair_matrix(
         monkeypatch):
     """On a TPU backend without a mesh the published shapes run as the
-    kernels: 10 heads a block, 8 chunks a grid step, the state before each
+    kernels: 15 heads a block, 8 chunks a grid step, the state before each
     of the 64 steps kept for the backward (141 MB of the 149 MB of float32
     the form puts in HBM, where a step of XLA's walk put 33 MB of pair
     matrices and all chunks at once 2.1 GB); under a mesh, on the CPU, for
@@ -1982,16 +1982,31 @@ def test_rule_plan_of_the_kernels_keeps_states_and_no_pair_matrix(
     plan = delta.rule_plan(*shapes)
     assert (plan["form"], plan["heads_a_block"], plan["chunks_a_call"],
             plan["steps"], plan["states_kept"], plan["walk"]) == (
-        "pallas", 10, 8, 64, 64, None)
+        "pallas", 15, 8, 64, 64, None)
     state = 30 * 192 * 96 * 4
     assert plan["float32_bytes_in_hbm"] == 65 * state + 3 * 30 * 32768 * 4
     assert plan["float32_bytes_all_chunks"] == 512 * 30 * 4 * (
         4 * 64 * 64 + 192 * 96)
     assert delta.rule_plan(*shapes, mesh=object())["form"] == "xla_walk"
-    # 14 heads: the largest divisor within 10; 3 chunks: all in one step
-    odd = delta.rule_plan(2, 192, 14, 96, 192, 64)
+    # 22 heads: the largest divisor within 16; 3 chunks: all in one step,
+    # padded to 4 (a step's positions are whole registers of 128 lanes)
+    odd = delta.rule_plan(2, 192, 22, 96, 192, 64)
     assert (odd["form"], odd["heads_a_block"], odd["chunks_a_call"],
-            odd["steps"]) == ("pallas", 7, 3, 1)
+            odd["steps"], odd["operands"]) == (
+        "pallas", 11, 4, 1, "positions_last")
+    # 16 key heads under 32: a step takes whole key heads with the two
+    # value heads of each, 8 and 16 within 16; the kernels read q and k at
+    # the key heads, the walk (under a mesh) reads copies
+    grouped = delta.rule_plan(1, 32768, 32, 128, 128, 64, key_heads=16)
+    assert (grouped["form"], grouped["heads_a_block"], grouped["joined"]
+            ) == ("pallas", 16, "index_map")
+    walked = delta.rule_plan(1, 32768, 32, 128, 128, 64, mesh=object(),
+                             key_heads=16)
+    assert (walked["form"], walked["joined"], walked["operands"]) == (
+        "xla_walk", "repeat", None)
+    assert plan["joined"] is None
+    # a head that is not whole sublane tiles: the walk
+    assert delta.rule_plan(1, 256, 4, 12, 16, 64)["form"] == "xla_walk"
     # 9 chunks: two steps of 8, the second padded
     assert delta.rule_plan(1, 520, 30, 96, 192, 64)["steps"] == 2
     for seq, chunk in ((30, 64), (256, 24), (256, 48)):

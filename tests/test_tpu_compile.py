@@ -524,36 +524,77 @@ def test_taps_kernels_compile_over_a_delta_layers_channels(S,
         assert any(kernel in name for name in names), (kernel, names)
 
 
-def test_delta_rule_kernels_compile_at_the_cells_shapes(S, one_chip,
-                                                       no_compile_cache,
-                                                       monkeypatch):
-    """The gated delta rule at ``train-olmo-hybrid-1chip``'s shapes (1 x
-    32,768 positions, 30 heads, keys of 96 and values of 192, bfloat16;
-    ``g`` and ``beta`` float32) on a TPU backend: Mosaic takes the forward
-    call alone, and the forward that keeps its states and the backward
-    call of the gradient; nothing else of the program is a kernel."""
+def _arrays_under(text: str, scope: str):
+    """(op, dtype, elements) of every instruction of a compiled program's
+    text, fused or not, whose ``op_name`` holds ``scope``."""
+    found = []
+    for line in text.splitlines():
+        name = re.search(r'op_name="([^"]*)"', line)
+        head = line.strip().split(" = ", 1)
+        if not name or scope not in name.group(1) or len(head) < 2:
+            continue
+        shape = re.match(r"\(?(\w+)\[([\d,]*)\]", head[1])
+        op = re.search(r"\}?\)? ([a-z\-]+)\(", head[1])
+        if shape and op:
+            elements = 1
+            for d in shape.group(2).split(","):
+                elements *= int(d or 1)
+            found.append((op.group(1), shape.group(1), elements))
+    return found
+
+
+def _mixer_compiles_with_nothing_around_the_rule(S, hidden, heads, key_heads,
+                                                 key_dim, value_dim):
+    """The gradient of ``gated_delta_mixer`` at 1 x 32,768 positions of a
+    cell's widths, compiled for a v5e: the rule is two Mosaic calls (the
+    forward that keeps its states and the backward), and under
+    ``gdn_rule`` nothing else of the program holds an array of q's, k's,
+    v's or o's size: no swap, no norm pass, no head-major copy, no copy to
+    more heads, no sum over pairs; what XLA still does there are the gates,
+    the running sums and their two layouts, float32 [s, heads] (the
+    largest, ``cols``, twice that)."""
     from ray_tpu.ops import delta
 
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    gates = jax.ShapeDtypeStruct((1, 32768, 30), jnp.float32,
-                                 sharding=one_chip)
-    args = (S(1, 32768, 30, 96), S(1, 32768, 30, 96), S(1, 32768, 30, 192),
-            gates, gates)
-    assert delta.rule_plan(1, 32768, 30, 96, 192, 64)["form"] == "pallas"
+    s = 32768
+    hv, hk = heads * value_dim, key_heads * key_dim
+    p = {"g_in": S(hidden, 2 * hv + 2 * hk + 2 * heads),
+         "g_conv": S(2 * hk + hv, 4), "g_dt_bias": S(heads),
+         "g_A_log": S(heads), "g_norm": S(value_dim), "g_out": S(hv, hidden)}
 
-    def loss(*a):
-        return jnp.square(delta.gated_delta_rule(*a, chunk=64)[0].astype(
-            jnp.float32)).sum()
+    def loss(h, p):
+        return jnp.square(delta.gated_delta_mixer(
+            h, p, heads=heads, key_heads=key_heads, key_dim=key_dim,
+            value_dim=value_dim, chunk=64)[0].astype(jnp.float32)).sum()
 
-    forward = jax.jit(lambda *a: delta.gated_delta_rule(*a, chunk=64)
-                      ).lower(*args).compile().as_text()
-    assert [name for name, _ in _mosaic_calls(forward)] == ["delta_rule_fwd"]
-    gradient = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
-        *args).compile().as_text()
-    names = [name for name, _ in _mosaic_calls(gradient)]
+    text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        S(1, s, hidden), p).compile().as_text()
+    names = [name for name, _ in _mosaic_calls(text) if "delta_rule" in name]
     assert len(names) == 2, names        # (named after the transformation)
     for kernel in ("delta_rule_fwd", "delta_rule_bwd"):
         assert any(kernel in name for name in names), (kernel, names)
+    around = [a for a in _arrays_under(text, "gdn_rule") if a[0] not in (
+        "custom-call", "get-tuple-element", "bitcast")]
+    assert around and all(n <= 2 * s * heads and dtype != "bf16" or n <= s
+                          * heads for _, dtype, n in around), sorted(
+        set(a for a in around if a[2] > s * heads))
+    return text
+
+
+def test_delta_rule_kernels_compile_at_the_cells_shapes(S, no_compile_cache,
+                                                       monkeypatch):
+    """The mixer of ``train-olmo-hybrid-1chip`` (1 x 32,768 positions of
+    3,840, 30 heads, keys of 96 and values of 192, bfloat16) on a TPU
+    backend: Mosaic takes the rule's two calls on operands positions last
+    (keys of 96 and values of 192 are whole sublane tiles there, where they
+    are not whole registers along the lanes), 15 heads a grid step, and
+    XLA relays nothing for them."""
+    from ray_tpu.ops import delta
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    plan = delta.rule_plan(1, 32768, 30, 96, 192, 64)
+    assert (plan["form"], plan["heads_a_block"], plan["joined"],
+            plan["operands"]) == ("pallas", 15, None, "positions_last")
+    _mixer_compiles_with_nothing_around_the_rule(S, 3840, 30, 30, 96, 192)
 
 
 def _vocab_products(text: str, block: int, vocab: int) -> int:
@@ -577,11 +618,11 @@ def test_olmo_hybrid_cell_step_lowers_for_a_v5e_chip(one_chip,
     kernel call; the whole program's compile, a minute and a half, is
     ``tools/step_program.py``'s): the taps' pair and the rule's pair once
     for the scanned linear layers and the three flash kernels at a head of
-    128 without rope; the rule runs as its kernels, 10 heads and 8 chunks
+    128 without rope; the rule runs as its kernels, 15 heads and 8 chunks
     of 64 a grid step, 64 states kept; the plan reckons more than a v5e's
     budget at every layer's "full", so no rung is taken, and its need
-    lies within 3% of the 18,017,885,696 bytes the compiler allots that
-    step (``step_program.py``, PR 40)."""
+    lies within 3% of the 18,010,376,704 bytes the compiler allots that
+    step (``step_program.py``, PR 53: the rule's operands positions last)."""
     import json
 
     import optax
@@ -621,10 +662,11 @@ def test_olmo_hybrid_cell_step_lowers_for_a_v5e_chip(one_chip,
     (plan,) = spans["rtpu.train.remat_plan"]
     assert plan["level"] == {"linear": "full", "full": "full"}
     assert plan["need_bytes"] > (1 - llama.REMAT_RESERVE) * V5E_LIMIT
-    assert 1.0 < plan["need_bytes"] / 18_017_885_696 < 1.03
+    assert 1.0 < plan["need_bytes"] / 18_010_376_704 < 1.03
     assert {(r["form"], r["chunks"], r["heads_a_block"], r["chunks_a_call"],
-             r["states_kept"]) for r in spans["rtpu.gdn.rule_plan"]} == {
-        ("pallas", 512, 10, 8, 64)}
+             r["states_kept"], r["operands"])
+            for r in spans["rtpu.gdn.rule_plan"]} == {
+        ("pallas", 512, 15, 8, 64, "positions_last")}
     assert {(c["form"], c["block_channels"])
             for c in spans["rtpu.gdn.conv_plan"]} == {("pallas", 64)}
     text = lowered.as_text()
@@ -851,36 +893,30 @@ def test_flash_kernels_compile_at_32k_positions_of_256(S, no_compile_cache):
         assert any(kernel in name for name in names), (kernel, names)
 
 
-def test_delta_rule_kernels_compile_at_grouped_heads(S, one_chip,
-                                                    no_compile_cache,
+def test_delta_rule_kernels_compile_at_grouped_heads(S, no_compile_cache,
                                                     monkeypatch):
-    """The rule at ``train-qwen3-next-1chip``'s shapes (1 x 32,768
-    positions, 32 value heads reading 16 key heads' q and k copied to them,
-    keys and values of 128): the plan says how the heads were joined and
-    takes 8 heads a block; Mosaic takes the forward that keeps its states
-    and the backward."""
+    """The mixer of ``train-qwen3-next-1chip`` (1 x 32,768 positions of
+    2,048, 32 value heads reading 16 key heads, keys and values of 128):
+    the plan joins the heads by the kernels' index map and takes 8 key
+    heads with their 16 value heads a grid step; Mosaic takes the forward
+    that keeps its states and the backward, q, k, ``dq`` and ``dk`` [1, 16
+    x 128, 32,768] at the key heads, and neither a copy of q or k to two
+    value heads a key head nor a head-major array is anywhere in the
+    program."""
     from ray_tpu.ops import delta
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    gates = jax.ShapeDtypeStruct((1, 32768, 32), jnp.float32,
-                                 sharding=one_chip)
-    args = (S(1, 32768, 16, 128), S(1, 32768, 16, 128),
-            S(1, 32768, 32, 128), gates, gates)
     plan = delta.rule_plan(1, 32768, 32, 128, 128, 64, key_heads=16)
     assert (plan["form"], plan["heads_a_block"], plan["key_heads"],
-            plan["joined"]) == ("pallas", 8, 16, "repeat")
-
-    def loss(q, k, *a):
-        q, k = (delta._join_heads(x, 32) for x in (q, k))
-        return jnp.square(delta.gated_delta_rule(
-            q, k, *a, chunk=64, key_heads=16)[0].astype(jnp.float32)).sum()
-
-    gradient = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
-        *args).compile().as_text()
-    names = [name for name, _ in _mosaic_calls(gradient)]
-    assert len(names) == 2, names
-    for kernel in ("delta_rule_fwd", "delta_rule_bwd"):
-        assert any(kernel in name for name in names), (kernel, names)
+            plan["joined"]) == ("pallas", 16, 16, "index_map")
+    text = _mixer_compiles_with_nothing_around_the_rule(
+        S, 2048, 32, 16, 128, 128)
+    (bwd,) = [line for name, line in _mosaic_calls(text)
+              if "delta_rule_bwd" in name]
+    assert bwd.count("bf16[1,2048,32768]") >= 4      # q, k in; dq, dk out
+    # the copy to two value heads a key head, and a head-major array
+    for copied in ("32768,16,2,128]", "[1,32,32768,128]"):
+        assert copied not in text, copied
 
 
 # ---- train-nemotron3-super-1chip's kernels at its shapes (PR 52)

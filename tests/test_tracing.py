@@ -1025,7 +1025,7 @@ def test_gdn_rule_plan_is_one_kept_span_of_a_traced_call(monkeypatch):
     walk = {"seq": 32768, "chunk": 64, "chunks": 512, "walk": 8,
             "steps": 64, "heads": 30, "key_heads": 30, "joined": None,
             "key_dim": 96, "value_dim": 192, "form": "xla_walk",
-            "heads_a_block": None, "chunks_a_call": 8,
+            "heads_a_block": None, "chunks_a_call": 8, "operands": None,
             "states_kept": 64, "float32_bytes_in_hbm": 8 * one,
             "float32_bytes_all_chunks": 512 * one}
     assert cell == walk
@@ -1035,7 +1035,8 @@ def test_gdn_rule_plan_is_one_kept_span_of_a_traced_call(monkeypatch):
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     (kernels,) = trace(32768)
     assert kernels == dict(
-        walk, form="pallas", walk=None, heads_a_block=10,
+        walk, form="pallas", walk=None, heads_a_block=15,
+        operands="positions_last",
         float32_bytes_in_hbm=65 * 30 * 192 * 96 * 4 + 3 * 30 * 32768 * 4)
     (sharded,) = trace(32768, mesh=object())
     assert sharded == walk
