@@ -158,10 +158,10 @@ def _compile_cell(tree: str, cell: dict, topo) -> dict:
             updates, opt = tx.update(grads, opt, params)
             return optax.apply_updates(params, updates), opt, loss
 
-    n0 = len(tracing.chrome_events())
+    here = tracing.since()
     compiled = jax.jit(step, donate_argnums=(0, 1)).lower(
         params, opt, batch).compile()
-    events = tracing.chrome_events()[n0:]
+    events = here.events()
     plans = [{k: v for k, v in e["args"].items()
               if k not in ("id", "parent", "self_us")} for e in events
              if e["name"] == "rtpu.train.remat_plan"]

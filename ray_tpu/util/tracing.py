@@ -256,13 +256,11 @@ def totals() -> Dict[str, Any]:
     return out
 
 
-def chrome_events() -> List[Dict[str, Any]]:
-    """This process's kept events as chrome://tracing complete events,
-    ``ts`` in wall-clock microseconds (as ``ray_tpu.timeline()`` has it)."""
+def _chrome(entries) -> List[Dict[str, Any]]:
     pid = os.getpid()
     out = []
     for name, t0, dur, self_ns, tid, parent, sid, attrs in \
-            sorted(list(_kept) + list(_ring), key=lambda e: e[1]):
+            sorted(entries, key=lambda e: e[1]):
         out.append({
             "name": name, "cat": "span", "ph": "X",
             "ts": (_ANCHOR_WALL + (t0 - _ANCHOR_NS) / 1e9) * 1e6,
@@ -270,6 +268,47 @@ def chrome_events() -> List[Dict[str, Any]]:
             "args": {"id": sid, "parent": parent, "self_us": self_ns / 1e3,
                      **attrs}})
     return out
+
+
+def chrome_events() -> List[Dict[str, Any]]:
+    """This process's kept events as chrome://tracing complete events,
+    ``ts`` in wall-clock microseconds (as ``ray_tpu.timeline()`` has it),
+    sorted by start: a position in this list is not an order of arrival,
+    and its length stops growing once a buffer is full. For "what this
+    call wrote" use ``since``."""
+    return _chrome(list(_kept) + list(_ring))
+
+
+class since:
+    """A mark in this process's two buffers and a read from it::
+
+        here = tracing.since()
+        call()
+        here.events()          # what ``call`` wrote, on any thread
+
+    in the form and order of ``chrome_events()``, and nothing that stood
+    there before. The mark is the newest entry of each buffer, by
+    identity (every event is a tuple of its own), so a buffer that was
+    full at the mark, or wrapped past it after, reads right: where the
+    marked entry has left the buffer, all the buffer holds is newer.
+    ``span()`` pays nothing for it."""
+
+    __slots__ = ("_marks",)
+
+    def __init__(self) -> None:
+        self._marks = [(buf, buf[-1] if buf else None)
+                       for buf in (_kept, _ring)]
+
+    def events(self) -> List[Dict[str, Any]]:
+        new: List[tuple] = []
+        for buf, newest_then in self._marks:
+            entries = list(buf)
+            for i in range(len(entries) - 1, -1, -1):
+                if entries[i] is newest_then:
+                    entries = entries[i + 1:]
+                    break
+            new += entries
+        return _chrome(new)
 
 
 def start_profile(trace_dir: str) -> None:

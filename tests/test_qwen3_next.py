@@ -1,9 +1,11 @@
-"""Qwen3-Next's language model (``models/qwen3_next.py``) at ``tiny()`` on
-seeded weights, in float32: the program against its plain reference
-(logits, loss, every leaf's gradient, the linear layers' states), the rule
-at grouped heads against the token-by-token recurrence, the held share (the
-parts four chips give add up to the uncut layer), the remat plan's two kinds
-and what the table reports."""
+"""Qwen3-Next's language model (``models/qwen3_next.py``): its row of the
+conformance suite (``tests/model_suite.py``: the program at ``tiny()``
+against ``benchmark/references/qwen3_next_ref.py`` on the program's own
+choices of experts, every expert here and at experts 4..7; the parts four
+chips give add up to the uncut layer; the remat plan's two kinds), and what
+only Qwen3-Next has: the rule at grouped heads against the token-by-token
+recurrence, the reference's weighted gradient with its router term, and
+the configuration's headroom."""
 
 from dataclasses import replace
 
@@ -13,145 +15,30 @@ import pytest
 jax = pytest.importorskip("jax")
 import jax.numpy as jnp  # noqa: E402
 
+from tests import model_suite  # noqa: E402
 from benchmark.references import qwen3_next_ref as ref  # noqa: E402
-from ray_tpu.models import llama, qwen3_next  # noqa: E402
+from ray_tpu.models import qwen3_next  # noqa: E402
 from ray_tpu.models.qwen3_next import Qwen3NextConfig  # noqa: E402
 from ray_tpu.ops import delta  # noqa: E402
-from ray_tpu.ops.layers import Ctx, rms_norm  # noqa: E402
+from ray_tpu.ops.layers import Ctx  # noqa: E402
 
-_MOVED = ("attn_norm", "op_norm", "mlp_norm", "q_norm", "k_norm", "g_norm")
-_SHARES = [pytest.param(None, id="all-experts"),
-           pytest.param((4, 4), id="held-4..7")]
-
-
-@pytest.fixture(scope="module")
-def setup(request):
-    """(config, parameters, tokens [2, 33]) of ``tiny()`` in float32: three
-    delta-rule layers of 2 key heads under 4 value heads and a full layer
-    of 4 heads of 16 over 2 with 4 rotated dims, 16 experts, 4 a token,
-    beside a gated shared expert in every layer. The norms (drawn as
-    zeros, the rule's own as ones) and the last norm are moved off their
-    starts: a ``1 + w`` applied as ``w`` or twice would go unseen."""
-    cfg = Qwen3NextConfig.tiny(attn_impl="reference",
-                               experts_held=request.param)
-    params = qwen3_next.init_params(cfg, jax.random.PRNGKey(0))
-    for n, kind in enumerate(params["layers"]):
-        for i, name in enumerate(_MOVED):
-            if name in params["layers"][kind]:
-                w = params["layers"][kind][name]
-                params["layers"][kind][name] = w + 0.3 * jax.random.normal(
-                    jax.random.PRNGKey(10 * n + i), w.shape)
-    params["final_norm"] = 0.3 * jax.random.normal(
-        jax.random.PRNGKey(77), params["final_norm"].shape)
-    tokens = np.random.default_rng(1).integers(0, cfg.vocab_size, (2, 33))
-    return cfg, params, tokens
+ROWS = ("qwen3_next",)
+globals().update(model_suite.tests_of(ROWS))
 
 
-@pytest.fixture(scope="module")
-def both(setup):
-    """The program's forward with the routers' logits kept, its own choices
-    of experts, and the reference on those choices."""
-    cfg, params, tokens = setup
-    with jax.default_matmul_precision("highest"):
-        logits, said = jax.jit(lambda p, t: qwen3_next.forward(
-            cfg, p, t, keep_router_logits=True))(params, tokens[:, :-1])
-    chosen = jax.lax.top_k(jax.nn.softmax(said["router"]["logits"], -1),
-                           cfg.top_k)[1]
-    return logits, said, chosen, ref.token_nll(cfg, params, tokens,
-                                               forced_topk=chosen)
-
-
-@pytest.mark.parametrize("setup", _SHARES, indirect=True)
-def test_forward_matches_the_reference(setup, both):
-    cfg, params, tokens = setup
-    assert cfg.pattern == ("linear", "linear", "linear", "full")
-    lin, full = params["layers"]["linear"], params["layers"]["full"]
-    # z 4 x 16 | q and k 2 x 16 each, v 4 x 16 | a and b 4 each
-    assert lin["g_in"].shape == (3, 64, 64 + 128 + 8)
-    assert lin["g_conv"].shape == (3, 128, 4)
-    assert full["wq"].shape == (1, 64, 2 * 4 * 16)
-    assert full["e_gate"].shape == (1, cfg.experts_here, 64, 32)
-    assert full["s_sigmoid"].shape == (1, 64)
-    assert not float(jnp.abs(qwen3_next.init_params(
-        cfg, jax.random.PRNGKey(0))["final_norm"]).max())
-    logits, said, chosen, want = both
-    want_logits = jax.jit(lambda p: ref.logits(
-        cfg, p, tokens[:, :-1], forced_topk=chosen))(params)
-    # (5e-5 as Olmo-Hybrid's: three rule layers hand their rounding on)
-    np.testing.assert_allclose(logits, want_logits, rtol=1e-5, atol=5e-5)
-    # the reference, left to choose, chooses what the program chose
-    np.testing.assert_array_equal(np.sort(chosen, -1),
-                                  np.sort(want["chosen"], -1))
-    np.testing.assert_allclose(said["router"]["logits"],
-                               want["router_logits"], rtol=1e-5, atol=5e-5)
-    assert said["router"]["counts"].shape == (4, 16)
-    assert int(said["router"]["counts"].sum()) == 4 * 2 * 32 * cfg.top_k
-
-
-@pytest.mark.parametrize("setup", _SHARES[1:], indirect=True)
-def test_states_and_loss_match_the_reference(setup, both):
-    cfg, params, tokens = setup
-    _, said, chosen, want = both
-    assert said["gdn_state"].shape == (3, 2, 4, 16, 16)
-    np.testing.assert_allclose(said["gdn_state"], want["last_states"],
-                               rtol=1e-5, atol=1e-5)
-    with jax.default_matmul_precision("highest"):
-        nll, again = qwen3_next.token_nll(cfg, params, jnp.asarray(tokens),
-                                          head_block=16)
-        loss, terms = qwen3_next.loss_terms(
-            cfg, params, {"tokens": jnp.asarray(tokens)})
-    np.testing.assert_allclose(nll, want["nll"], rtol=1e-5, atol=1e-5)
-    assert set(again) == {"gdn_state", "router"}
-    for name in ("cross_entropy", "load_balance"):
-        np.testing.assert_allclose(terms[name], want["terms"][name],
-                                   rtol=1e-5)
-    np.testing.assert_allclose(loss, want["terms"]["loss"], rtol=1e-5)
-    np.testing.assert_allclose(terms["gdn_state_abs_max"],
-                               want["state_abs_max"], rtol=1e-5)
-    np.testing.assert_array_equal(terms["expert_counts"],
-                                  said["router"]["counts"])
-    assert int(qwen3_next.rows_held(cfg, terms["expert_counts"])) == int(
-        terms["expert_counts"][:, 4:8].sum())
-
-
-@pytest.fixture(scope="module")
-def row_gradient(setup, both):
-    """The reference's gradient of the whole loss of the first row, on the
-    program's choices, for every leaf."""
-    cfg, params, tokens = setup
-    return jax.jit(jax.grad(lambda p: ref.loss(
-        cfg, p, tokens[:1], forced_topk=both[2][:, :32])))(params)
-
-
-@pytest.mark.parametrize("setup", _SHARES[1:], indirect=True)
-def test_gradients_match_the_reference(setup, row_gradient):
-    """Every leaf's gradient of the loss, its router term with it, against
-    the reference's at 5e-5 of the leaf's largest entry (Olmo-Hybrid's
-    tolerance, ``tests/test_stack_models.py``)."""
-    cfg, params, tokens = setup
-    with jax.default_matmul_precision("highest"):
-        got = jax.jit(jax.grad(lambda p: qwen3_next.loss_fn(
-            cfg, p, {"tokens": jnp.asarray(tokens[:1])})))(params)
-    flat, _ = jax.tree_util.tree_flatten_with_path(got)
-    assert len(flat) == 3 + 16 + 16
-    for (path, g), w in zip(flat, jax.tree_util.tree_leaves(row_gradient)):
-        scale = float(jnp.abs(w).max())
-        assert scale > 1e-6, path                       # it is reached
-        np.testing.assert_allclose(g, w, rtol=1e-4,
-                                   atol=5e-5 * max(scale, 1e-2),
-                                   err_msg=str(path))
-
-
-@pytest.mark.parametrize("setup", _SHARES[1:], indirect=True)
-def test_the_references_weighted_gradient_is_the_losss(setup, both,
-                                                       row_gradient):
+@pytest.mark.parametrize("case", model_suite.cases(ROWS)[1:], indirect=True)
+def test_the_references_weighted_gradient_is_the_losss(case):
     """``token_nll(grad_weights=1 / n, router_term=True)`` of one row is
-    the gradient of ``loss`` for the leaves it is asked for."""
-    cfg, params, tokens = setup
-    got = ref.token_nll(cfg, params, tokens[:1], forced_topk=both[2][:, :32],
+    the gradient of ``loss`` for the leaves it is asked for (the suite's:
+    the reference's gradient of the first row's loss on the program's
+    choices). Only this reference has a router term in its weighted
+    gradient, and it is a row's."""
+    _, ref, cfg, params, tokens = case
+    chosen = case.forced["forced_topk"][:, :32]
+    got = ref.token_nll(cfg, params, tokens[:1], forced_topk=chosen,
                         grad_weights=np.full((1, 32), 1 / 32, np.float32),
                         router_term=True)["grads"]
-    want = ref.first_layers(row_gradient)
+    want = ref.first_layers(case.gradients[1])
     for g, w in zip(jax.tree_util.tree_leaves(got),
                     jax.tree_util.tree_leaves(want)):
         np.testing.assert_allclose(g, w, rtol=1e-4,
@@ -196,59 +83,6 @@ def test_the_rule_at_grouped_heads_is_the_recurrence(chunk):
         finally:
             delta._join_heads = honest
     assert float(jnp.abs(wrong[0] - want).max()) > 1e-3
-
-
-def test_expert_shares_add_up_to_the_uncut_layer():
-    """Four chips with 4 of 16 experts each: what their layers add (the
-    part's body less its input), with the gated shared expert that every
-    chip computes alike counted once, is the uncut reference's layer; and
-    ``experts_held=None`` is that sum."""
-    cfg = Qwen3NextConfig.tiny()
-    params = qwen3_next.init_params(cfg, jax.random.PRNGKey(2))
-    p = {k: v[0] for k, v in params["layers"]["full"].items()}
-    p["mlp_norm"] = 0.3 * jax.random.normal(jax.random.PRNGKey(3), (64,))
-    x = jax.random.normal(jax.random.PRNGKey(6), (1, 48, 64))
-    u = rms_norm(x, p["mlp_norm"], cfg.rms_norm_eps, True)[0]
-    want = ref.routed_layer(cfg, p, u)
-    shared = want - ref.routed_layer(cfg, p, u, shared=False)
-    assert float(jnp.abs(shared).max()) > 1e-3
-    mlp = qwen3_next.LAYER_KINDS["full"][1]
-    ctx = Ctx(None, {})
-    total = ref_total = shared
-    for first in range(0, 16, 4):
-        mine = {**p, **{n: p[n][first:first + 4]
-                        for n in ("e_gate", "e_up", "e_down")}}
-        held = replace(cfg, experts_held=(first, 4))
-        with jax.default_matmul_precision("highest"):
-            out, said = mlp.body(held, x, mine, ctx)
-        assert int(said["router"]["counts"].sum()) == 48 * cfg.top_k
-        total = total + (out - x)[0] - shared
-        ref_total = ref_total + ref.routed_layer(held, mine, u, shared=False)
-    np.testing.assert_allclose(total, want, rtol=1e-4, atol=1e-5)
-    np.testing.assert_allclose(ref_total, want, rtol=1e-4, atol=1e-5)
-    with jax.default_matmul_precision("highest"):
-        whole, _ = mlp.body(cfg, x, p, ctx)
-    np.testing.assert_allclose((whole - x)[0], want, rtol=1e-4, atol=1e-5)
-
-
-def test_the_plan_knows_both_kinds():
-    cfg = Qwen3NextConfig.tiny(dtype=jnp.bfloat16, param_dtype=jnp.bfloat16,
-                               experts_held=(0, 4))
-    params = jax.eval_shape(lambda k: qwen3_next.init_params(cfg, k),
-                            jax.random.PRNGKey(0))
-    stack = llama.describe_stack(cfg, qwen3_next.LAYER_KINDS,
-                                 params["layers"], 64, pattern=cfg.pattern,
-                                 head_tokens=64)
-    assert stack["runs"] == (("linear", 3), ("full", 1))
-    kinds = stack["kinds"]
-    # a full layer keeps its flash output and log-sum-exp on the first rung
-    # at its own 4 heads of 16, not at ``wq``'s width, which holds the gate
-    assert kinds["full"]["rungs"][0] == 64 * (64 * 2 + 4 * 4)
-    assert kinds["full"]["rungs"][1] == 64 * (64 + 2 * 32) * 2
-    # a linear layer's one rung that keeps anything is the shared SwiGLU's
-    assert kinds["linear"]["rungs"] == (0, 0, 2 * 64 * 32 * 2, 0)
-    plan = llama.remat_plan(cfg, stack, 64, 10 ** 6, 10 ** 9, False)
-    assert set(plan["level"]) == {"linear", "full"}
 
 
 def test_the_configurations_headroom_widens_a_pass_and_changes_no_sum():

@@ -13,7 +13,7 @@ import pytest
 jax = pytest.importorskip("jax")
 import jax.numpy as jnp  # noqa: E402
 
-from tests.test_ops import _recurrence, _scan_inputs  # noqa: E402
+from tests.test_ssm_ops import _recurrence, _scan_inputs  # noqa: E402
 
 
 @pytest.fixture
@@ -117,11 +117,7 @@ def test_scan_kernels_match_the_walk_and_keep_rows_apart(scan_kernels):
         return (jax.jit(scan), jax.jit(jax.grad(
             _scalar(scan), argnums=(0, 1, 2, 3, 4))))
 
-    def spans():
-        return [e["args"]["form"] for e in tracing.chrome_events()
-                if e["name"] == "rtpu.ssm.scan_plan"]
-
-    n0 = len(spans())
+    here = tracing.since()
     with jax.default_matmul_precision("highest"):
         (kernels, kernels_grad), (walk, walk_grad) = forms(None), forms(
             object())                       # any mesh keeps XLA's walk
@@ -129,7 +125,9 @@ def test_scan_kernels_match_the_walk_and_keep_rows_apart(scan_kernels):
         got_g, want_g = kernels_grad(*args), walk_grad(*args)
         x, dt, A, B, C = args
         alone, _ = ssm.ssd_scan(x[1:], dt[1:], A, B[1:], C[1:], chunk=8)
-    assert spans()[n0:n0 + 4] == ["pallas", "xla_walk", "pallas", "xla_walk"]
+    assert [e["args"]["form"] for e in here.events()
+            if e["name"] == "rtpu.ssm.scan_plan"][:4] == [
+        "pallas", "xla_walk", "pallas", "xla_walk"]
     for g, w in zip(got + got_g, want + want_g):
         np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=1e-5,
                                    atol=1e-5 * float(jnp.abs(w).max()))

