@@ -114,7 +114,9 @@ class Dots3Config(llama.LlamaConfig):
     index_norm_eps: float = 1e-5
     index_loss_coef: float = 1.0
     # how ``ops/dsa.sparse_attention`` walks its queries; no equation's
-    index_block: int = 128
+    # (the block is the most a walk takes: ``dsa.walk_plan`` fits it to
+    # the sequence and to what its calls hold in VMEM)
+    index_block: int = 256
     index_tiers: int = 4
     num_experts: int = 256                  # the router's outputs
     experts_held: Optional[Tuple[int, int]] = None

@@ -20,8 +20,11 @@ L1-normalised, under ``stop_gradient``: the index learns to rank keys as
 the attention it stands in for weighs them, and nothing else receives that
 term's gradient.
 
-``sparse_attention`` walks blocks of ``block`` queries (``lax.map`` over a
-``jax.checkpoint``ed block): a block's index scores ``[block, S]`` float32,
+``sparse_attention`` walks blocks of at most ``block`` queries (``lax.map``
+over a ``jax.checkpoint``ed block; ``walk_plan`` fits the block to the
+sequence and, on a TPU backend, to what the four calls below hold in VMEM:
+``walk_needs`` under ``VMEM_CEILING``): a block's index scores ``[block,
+S]`` float32,
 its choice (``choose``: an exact radix select of the ``topk``-th largest
 score, 32 compare-and-count passes, no sort) and its masked softmax over
 all heads live for that block alone, so the ``[T, T]`` scores never exist
@@ -62,14 +65,16 @@ equation; which runs is read from the call and never set (``scores_plan``,
 The choice (``choose``) and the term are XLA's. The attention costs the
 dense causal attention's FLOPs whatever the choice keeps (the mask zeroes
 what is not chosen: with ``topk`` of at most 16,384 keys chosen by each of
-128 queries no tile is empty for a whole block); it is read by the yardstick
-of the needed work, the chosen pairs.
+a block's queries no tile is empty for a whole block); it is read by the
+yardstick of the needed work, the chosen pairs.
 
 Named scopes: ``dsa_scores`` (the index's scores), ``dsa_select`` (the
 choice), ``flash_sparse`` (the attention over the choice: its two calls, a
 block's ``delta`` and the walk's turns of its arrays to the kernels' layout,
-or XLA's scores, masked softmax and PV), ``dsa_loss`` (the index's term). One kept span as the op
-is traced, ``rtpu.dsa.shapes``. Training only.
+or XLA's scores, masked softmax and PV), ``dsa_loss`` (the index's term).
+One kept span as the op is traced, ``rtpu.dsa.shapes`` (the forms, the
+block and tiers that ran, ``block_asked``: the block before the guard,
+``vmem_need_bytes``: the most a call holds). Training only.
 """
 
 from __future__ import annotations
@@ -95,14 +100,20 @@ _NEG = -1e30
 # 39.5 (three times the VMEM and the compile); 256 by 16 20.7 / 47.2, by
 # 128 17.4 / 39.7; 1,024 by 16 16.5 / 41.8, by 64 15.7 / 40.7; 2,048 by 32
 # 16.3 / 42.9; unrolling the chunks moved nothing (15.1 / 39.4); XLA's form
-# 18.9 / 162.0
+# 18.9 / 162.0. Over the queries a block of the walk (``index_sweep.py
+# --block 128 --block 256 --block 512``; PERF.md 6, PR 55), forward /
+# backward ms a layer and ms a step over two layers: 128 queries 15.6 /
+# 40.0, 173.7; 256 15.7 / 41.2, 176.5; 512 15.5 / 41.0, 175.0: the scores do
+# not care. At 256 queries, tiles of 512 by chunks of 32 16.2 / 41.6, of 128
+# 15.4 / 40.8; tiles of 1,024 by 64 15.7 / 41.8, of 256 17.9 / 42.2
 SCORE_TILE = 512
 SCORE_ROWS = 64
 # the attention's kernels (``attend_kernels``): keys a grid step takes (all
 # heads' keys and values of them are in VMEM), keys of them a head's
 # products take at a time, and heads a step of the heads' loop lays out as
 # straight-line code. Read on the chip at the cell's shapes (16 heads, keys
-# of 128 | 64, values of 128, blocks of 128 queries in four tiers, bfloat16),
+# of 128 | 64, values of 128, blocks of 128 queries in four tiers, bfloat16:
+# PR 49's walk),
 # one layer's walk alone, forward / forward + the blocks' forward again +
 # backward ms, and what a step spends over two layers (``tools/
 # index_sweep.py --attend``; PERF.md 6, PR 49): XLA's form 86.1 / 207.5, 587
@@ -116,11 +127,24 @@ SCORE_ROWS = 64
 # setting whose blocks fit Mosaic's default 16 MB), of 1,024 31.2 / 101.6,
 # 266, of 2,048 36.7 / 132.5. Queries down and keys across (the keys held,
 # the queries streamed) 41.4 / 132.0 at chunks of 128 queries, 64.4 / 225.3
-# at 64. Blocks of 256 queries (``index_block``, not this module's) read
-# 22.0 / 65.5, 175 a step, of 512 24.0 / 60.9
+# at 64. Over the queries a block of the walk (``index_sweep.py --block 128
+# --block 256 --block 512``; PERF.md 6, PR 55): 128 queries 33.5 / 91.8,
+# 251 a step; 256 22.0 / 65.7, 176; 512 21.6 / 58.8, 161 (where the choice
+# beside it reads 100 a step for 83 and the scores' backward holds 88 MB of
+# VMEM). At 256 queries: tiles of 512 whole, sixteen heads a step 22.0 /
+# 65.6, 175; eight heads 23.1 / 67.3, 181; by chunks of 256 keys 25.6 /
+# 71.7, 195; tiles of 1,024 whole 22.3 / 69.5, 184, by chunks of 512 22.5 /
+# 69.8, 185; tiles of 256 26.2 / 68.5, 190: the constants stand
 ATTEND_TILE = 512
 ATTEND_ROWS = 512
 ATTEND_UNROLL = 16
+# what a call's blocks and scratch may hold of VMEM (``walk_plan`` takes
+# fewer queries a block while one of the four holds more): half a v5e
+# core's 128 MiB, which leaves the other half to a tile's temporaries (a
+# chunk's products ``[J x SCORE_ROWS, tile]`` float32 and their gradient's,
+# 8 MB each) and keeps the scores' backward, the largest of the four, under
+# the 100 MB its calls ask for
+VMEM_CEILING = 64 << 20
 # keys a register holds along its lanes: a tile is whole registers of them
 # (tests patch it for small shapes in the interpreter)
 KERNEL_LANES = 128
@@ -294,6 +318,28 @@ def _scores_bwd_kernel(first_ref, q_ref, k_ref, w_ref, g_ref, dq_ref, dk_ref,
         dw_ref[...] = dw
 
 
+def _score_blocks(J: int, n: int, d: int, tile: int, dtype):
+    """(shape, dtype) of what the scores' two calls hold in VMEM, by name:
+    the operands' blocks and the scratch."""
+    f32 = jnp.float32
+    return {"q": ((J, n, d), dtype), "k": ((tile, d), dtype),
+            "w": ((n, J), f32), "scores": ((n, tile), f32),
+            "wb": ((J, n, KERNEL_LANES), f32), "dq": ((J, n, d), f32),
+            "dk_acc": ((tile, d), f32)}
+
+
+def _score_needs(J: int, n: int, d: int, tile: int, dtype):
+    """Bytes of VMEM the scores' forward and backward calls hold, their
+    blocks twice for the pipeline and their scratch (the chunks' products
+    beside them: the calls ask a fixed 100 MB)."""
+    at = {name: _padded(*b) for name, b in
+          _score_blocks(J, n, d, tile, dtype).items()}
+    ins = at["q"] + at["k"] + at["w"] + at["scores"]
+    return {"dsa_scores_fwd": 2 * ins + at["wb"],
+            "dsa_scores_bwd": (2 * (ins + at["dq"] + at["k"] + at["w"])
+                               + 2 * at["wb"] + at["dk_acc"])}
+
+
 def _score_specs(q, k, tile):
     """What both calls share: the grid (tiles of keys) and the operands'
     blocks."""
@@ -301,14 +347,16 @@ def _score_specs(q, k, tile):
     from jax.experimental.pallas import tpu as pltpu
 
     J, n, d = q.shape
+    at = _score_blocks(J, n, d, tile, q.dtype)
     return {
         "grid": (k.shape[0] // tile,),
         "first": pl.BlockSpec(memory_space=pltpu.SMEM),
-        "q": pl.BlockSpec((J, n, d), lambda t: (0, 0, 0)),
-        "k": pl.BlockSpec((tile, d), lambda t: (t, 0)),
-        "w": pl.BlockSpec((n, J), lambda t: (0, 0)),
-        "scores": pl.BlockSpec((n, tile), lambda t: (0, t)),
-        "wb": pltpu.VMEM((J, n, KERNEL_LANES), jnp.float32),
+        "q": pl.BlockSpec(at["q"][0], lambda t: (0, 0, 0)),
+        "k": pl.BlockSpec(at["k"][0], lambda t: (t, 0)),
+        "w": pl.BlockSpec(at["w"][0], lambda t: (0, 0)),
+        "scores": pl.BlockSpec(at["scores"][0], lambda t: (0, t)),
+        "wb": pltpu.VMEM(*at["wb"]),
+        "dk_acc": pltpu.VMEM(*at["dk_acc"]),
         "params": pltpu.CompilerParams(
             dimension_semantics=("arbitrary",), vmem_limit_bytes=100 << 20)}
 
@@ -331,7 +379,6 @@ def _scores_forward(first, q, k, w, tile, interpret):
 
 def _scores_backward(first, q, k, w, g, tile, interpret):
     import jax.experimental.pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
 
     at = _score_specs(q, k, tile)
     f32 = jnp.float32
@@ -344,8 +391,7 @@ def _scores_backward(first, q, k, w, g, tile, interpret):
         grid=at["grid"],
         in_specs=[at["first"], at["q"], at["k"], at["w"], at["scores"]],
         out_specs=[at["q"], at["k"], at["w"]],
-        scratch_shapes=[at["wb"], at["wb"],
-                        pltpu.VMEM((tile, k.shape[1]), f32)],
+        scratch_shapes=[at["wb"], at["wb"], at["dk_acc"]],
         compiler_params=at["params"], interpret=interpret,
     )(first, q, k, w, g)
 
@@ -613,15 +659,38 @@ def _attend_bwd_kernel(first_ref, q_ref, kn_ref, kr_ref, v_ref, chosen_ref,
         dq_ref[...] = dq_acc[...].astype(dq_ref.dtype)
 
 
+def _attend_blocks(H: int, n: int, dn: int, dr: int, dv: int, tile: int,
+                   dtype):
+    """(blocks, scratch) of the attention's forward and of its backward
+    call, (shape, dtype) each: what ``_vmem`` reckons their VMEM from."""
+    f32, d = jnp.float32, dn + dr
+    q, kn, kr = ((H, n, d), dtype), ((H, tile, dn), dtype), ((tile, dr), dtype)
+    chosen, stat = ((n, tile), jnp.int8), ((H, n), f32)
+    return {
+        "dsa_attend_fwd": (
+            [q, kn, kr, ((H, dv, tile), dtype), chosen, ((H, dv, n), dtype),
+             stat, ((n, tile), f32)],
+            [stat, stat, ((H, dv, n), f32), ((tile, n), f32)]),
+        "dsa_attend_bwd": (
+            [q, kn, kr, ((H, tile, dv), dtype), chosen, ((H, dv, n), dtype),
+             stat, stat, q, kn, kr, ((H, tile, dv), dtype)],
+            [((tile, n), f32), ((H, n, d), f32)])}
+
+
+def _need(blocks, scratch) -> int:
+    """Bytes of VMEM a call holds: its blocks twice, for the pipeline, and
+    its scratch."""
+    return (2 * sum(_padded(*b) for b in blocks)
+            + sum(_padded(*b) for b in scratch))
+
+
 def _vmem(blocks, scratch, grid_dims: int):
-    """Compiler parameters of a call with these blocks (twice in VMEM, for
-    the pipeline) and scratch, (shape, dtype) each: Mosaic's default 16 MB
-    where they fit beside a tile's temporaries, what they take and 8 MB
-    else."""
+    """Compiler parameters of a call with these blocks and scratch, (shape,
+    dtype) each: Mosaic's default 16 MB where they fit beside a tile's
+    temporaries, what they take and 8 MB else."""
     from jax.experimental.pallas import tpu as pltpu
 
-    need = (2 * sum(_padded(*b) for b in blocks)
-            + sum(_padded(*b) for b in scratch))
+    need = _need(blocks, scratch)
     return pltpu.CompilerParams(
         dimension_semantics=("arbitrary",) * grid_dims,
         **({} if need + (4 << 20) <= 16 << 20
@@ -659,12 +728,8 @@ def _attend_forward(first, q, kn, kr, vt, chosen, how: _How):
     S, dn, dv, dr = kn.shape[1], kn.shape[2], vt.shape[1], kr.shape[1]
     f32, tile = jnp.float32, how.tile
     last = _last_seen(n, tile)
-    blocks = [((H, n, d), q.dtype), ((H, tile, dn), kn.dtype),
-              ((tile, dr), kr.dtype), ((H, dv, tile), vt.dtype),
-              ((n, tile), chosen.dtype), ((H, dv, n), q.dtype),
-              ((H, n), f32), ((n, tile), f32)]
-    scratch = [((H, n), f32), ((H, n), f32), ((H, dv, n), f32),
-               ((tile, n), f32)]
+    blocks, scratch = _attend_blocks(H, n, dn, dr, dv, tile,
+                                     q.dtype)["dsa_attend_fwd"]
     return pl.pallas_call(
         functools.partial(_attend_fwd_kernel, scale=how.scale, rows=how.rows,
                           unroll=how.unroll),
@@ -698,15 +763,10 @@ def _attend_backward(first, q, kn, kr, v, chosen, dot, lse, delta,
 
     H, n, d = q.shape
     S, dn, dv, dr = kn.shape[1], kn.shape[2], v.shape[2], kr.shape[1]
-    f32, tile = jnp.float32, how.tile
+    tile = how.tile
     last = _last_seen(n, tile)
-    blocks = [((H, n, d), q.dtype), ((H, tile, dn), kn.dtype),
-              ((tile, dr), kr.dtype), ((H, tile, dv), v.dtype),
-              ((n, tile), chosen.dtype), ((H, dv, n), dot.dtype),
-              ((H, n), f32), ((H, n), f32), ((H, n, d), q.dtype),
-              ((H, tile, dn), kn.dtype), ((tile, dr), kr.dtype),
-              ((H, tile, dv), v.dtype)]
-    scratch = [((tile, n), f32), ((H, n, d), f32)]
+    blocks, scratch = _attend_blocks(H, n, dn, dr, dv, tile,
+                                     q.dtype)["dsa_attend_bwd"]
     whole3 = pl.BlockSpec((H, n, d), lambda t, f: (0, 0, 0))
     stat = pl.BlockSpec((H, n), lambda t, f: (0, 0))
     return pl.pallas_call(
@@ -836,14 +896,63 @@ def kl_target(p: jax.Array) -> jax.Array:
     return target / target.sum(-1, keepdims=True)
 
 
-def walk_plan(seq: int, block: int, tiers: int) -> Tuple[int, int]:
+class Widths(NamedTuple):
+    """What a position is to the walk's four calls: ``heads`` of keys
+    ``d_n | d_r`` and values ``d_v``, ``index_heads`` of ``index_dim``,
+    all of ``dtype``."""
+    heads: int
+    d_n: int
+    d_r: int
+    d_v: int
+    index_heads: int
+    index_dim: int
+    dtype: Any
+
+    @classmethod
+    def of(cls, q, k_n, v, q_i) -> "Widths":
+        """From the walk's arrays [.., s, heads, width]."""
+        dn = k_n.shape[-1]
+        return cls(q.shape[-2], dn, q.shape[-1] - dn, v.shape[-1],
+                   q_i.shape[-2], q_i.shape[-1], q.dtype)
+
+
+def walk_needs(block: int, keys: int, widths: Widths) -> Dict[str, int]:
+    """Bytes of VMEM each Mosaic call of a block of ``block`` queries
+    against a tier of ``keys`` keys holds, by the call's name: the blocks
+    and scratch the calls are built from (``_score_needs``,
+    ``_attend_blocks``). A form that is XLA's has no call and no entry."""
+    H, dn, dr, dv, J, di, dtype = widths
+    needs = {}
+    tile = scores_plan(block, keys, J, di)["scores_tile"]
+    if tile:
+        needs.update(_score_needs(J, block, di, tile, dtype))
+    tile = attend_plan(block, keys, dn, dv)["attend_tile"]
+    if tile:
+        needs.update({name: _need(*at) for name, at in _attend_blocks(
+            H, block, dn, dr, dv, tile, dtype).items()})
+    return needs
+
+
+def walk_plan(seq: int, block: int, tiers: int,
+              widths: Optional[Widths] = None) -> Tuple[int, int]:
     """(block, tiers) as the walk takes them: the largest divisor of
     ``seq`` up to ``block``, the largest count up to ``tiers`` that divides
-    the blocks."""
-    block = max(b for b in range(1, min(block, seq) + 1) if seq % b == 0)
-    blocks = seq // block
-    return block, max(g for g in range(1, min(tiers, blocks) + 1)
-                      if blocks % g == 0)
+    the blocks. Given the ``widths``, the block steps down to the next
+    divisor of whole ``KERNEL_LANES`` while a call's ``walk_needs`` stands
+    over ``VMEM_CEILING`` (the smallest such block where none fits)."""
+    def tiers_of(b):
+        return max(g for g in range(1, min(tiers, seq // b) + 1)
+                   if seq // b % g == 0)
+
+    fits = [b for b in range(min(block, seq), 0, -1) if seq % b == 0]
+    took = fits[0]
+    if widths is not None:
+        for b in fits[:1] + [b for b in fits[1:] if b % KERNEL_LANES == 0]:
+            took = b
+            if max(walk_needs(b, seq // tiers_of(b), widths).values(),
+                   default=0) <= VMEM_CEILING:
+                break
+    return took, tiers_of(took)
 
 
 def _walk(q, k_n, v, k_r, q_i, k_i, w, *, scale: float, topk: int,
@@ -851,10 +960,10 @@ def _walk(q, k_n, v, k_r, q_i, k_i, w, *, scale: float, topk: int,
     """One sequence: q [s, H, d_n + d_r], k_n [s, H, d_n], v [s, H, d_v],
     k_r [s, d_r], q_i [s, J, d_i], k_i [s, d_i], w [s, J] -> (o [s, H,
     d_v], the sequence's sum of KL terms, pairs chosen, and under
-    ``keep_choice`` the choice packed eight keys a byte [s, s / 8])."""
+    ``keep_choice`` the choice packed eight keys a byte [s, s / 8]).
+    ``block`` and ``tiers`` are ``walk_plan``'s."""
     s, H, _ = q.shape
     dn = k_n.shape[-1]
-    block, tiers = walk_plan(s, block, tiers)
     per_tier = s // block // tiers
     ends = [(g + 1) * per_tier * block for g in range(tiers)]
     plan = attend_plan(block, s // tiers, dn, v.shape[-1])
@@ -934,7 +1043,7 @@ def _walk(q, k_n, v, k_r, q_i, k_i, w, *, scale: float, topk: int,
 
 
 def sparse_attention(q, k_n, v, k_r, q_i, k_i, w, *, scale: float,
-                     topk: int, block: int = 128, tiers: int = 4,
+                     topk: int, block: int = 256, tiers: int = 4,
                      mesh=None, keep_choice: bool = False):
     """Attention of q [b, s, H, d_n + d_r] over the keys the index chooses
     for each position (the module's docstring): keys ``[k_n | k_r]`` (k_n
@@ -947,10 +1056,16 @@ def sparse_attention(q, k_n, v, k_r, q_i, k_i, w, *, scale: float,
     the bit ``7 - j`` of byte ``i``). Under a mesh each chip walks its own
     rows of the batch, as ``mla._attend`` does."""
     b, s, H, _ = q.shape
-    blk, trs = walk_plan(s, block, tiers)
+    widths = Widths.of(q, k_n, v, q_i)
+    blk, trs = walk_plan(s, block, tiers, widths)
     with tracing.span("rtpu.dsa.shapes", keep=True,
                       index_heads=q_i.shape[2], index_head_dim=q_i.shape[3],
                       topk=topk, positions=s, block=blk, tiers=trs,
+                      # the block before the guard, and what it was held to
+                      block_asked=walk_plan(s, block, tiers)[0],
+                      vmem_need_bytes=max(
+                          walk_needs(blk, s // trs, widths).values(),
+                          default=0),
                       **scores_plan(blk, s // trs, *q_i.shape[2:]),
                       **attend_plan(blk, s // trs, k_n.shape[-1],
                                     v.shape[-1]),
@@ -961,7 +1076,7 @@ def sparse_attention(q, k_n, v, k_r, q_i, k_i, w, *, scale: float,
 
     def rows(*a):
         return jax.vmap(lambda *r: _walk(
-            *r, scale=scale, topk=topk, block=block, tiers=tiers,
+            *r, scale=scale, topk=topk, block=blk, tiers=trs,
             keep_choice=keep_choice))(*a)
 
     args = (q, k_n, v, k_r, q_i, k_i, w)

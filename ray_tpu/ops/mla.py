@@ -331,14 +331,14 @@ def latent_attention_part(prefix: str = "", rope: Callable = rope_tables,
         rows = 0
         if index:
             # one block of the walk while its backward runs, as each form
-            # holds it in HBM. The index's scores: XLA's the products
-            # [block, J, s] float32 with their gradient, the kernels' (whose
-            # products stay in VMEM) [block, s] float32 with its gradient.
-            # The attention: XLA's the heads' scores and probabilities [H,
-            # block, s] float32 twice, the kernels' the heads' summed
-            # probabilities [block, s] float32 and the target made of them
-            blk, trs = dsa.walk_plan(tokens, cfg.index_block, cfg.index_tiers)
+            # holds it in HBM, float32. The index's scores: XLA's products
+            # [block, J, s] and their gradient, the kernels' [block, s] and
+            # its. The attention: XLA's heads' scores and probabilities [H,
+            # block, s] twice, the kernels' summed [block, s] and the target
             J, di = shape["wi_w"][-1], shape["wi_k"][-1]
+            blk, trs = dsa.walk_plan(
+                tokens, cfg.index_block, cfg.index_tiers,
+                dsa.Widths(H, sz.d_n, dr, dv, J, di, cfg.dtype))
             scores = dsa.scores_plan(blk, tokens // trs, J, di)["scores_form"]
             attend = dsa.attend_plan(blk, tokens // trs, sz.d_n,
                                      dv)["attend_form"]

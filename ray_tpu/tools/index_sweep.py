@@ -2,13 +2,13 @@
 below), alone on the chip, one process. The scores:
 ``ops/dsa.index_scores`` forward and backward over one layer's walk at a
 cell's shapes (``train-dots3-1chip``'s by default: 16,384 positions, 64
-index heads of 128, blocks of 128 queries in four tiers of keys, two such
+index heads of 128, blocks of 256 queries in four tiers of keys, two such
 layers, bfloat16), milliseconds a layer, for XLA's form and for the
 kernels over their constants, and how far each form's scores and
 gradients lie from XLA's form in float32 at the highest matmul precision.
 
     python3 ray_tpu/tools/index_sweep.py [--kernels 512,64 ...] \
-        [--block 128] [--seq 16384]
+        [--block 256] [--seq 16384]
 
 - ``forward_ms``: every block of the walk scored against its tier's keys,
   the kernels told where the block's diagonal lies (``dsa._scores``), as
@@ -26,19 +26,33 @@ gradients lie from XLA's form in float32 at the highest matmul precision.
 given again; without it the module's constants are read alone. Every array
 is an argument of the jitted call; a time is the wall clock around
 ``block_until_ready`` of one layer's walk (the device is busy all through
-it: 128 calls back to back), the median of ``--calls`` after two warm
+it: a call a block back to back), the median of ``--calls`` after two warm
 calls. Prints a line a reading and writes all of them to
 ``chiprun_out/<--out>`` (``index_sweep.json``). Run as a file; a time read
 on the CPU is no device number (the kernels then run in the Pallas
 interpreter: use a short ``--seq``).
 
     python3 ray_tpu/tools/index_sweep.py --attend 512,512,16 [--attend ..] \
-        [--block 128] [--no-xla] [--no-gaps]
+        [--block 256] [--no-xla] [--no-gaps]
 
 ``--attend tile,rows[,unroll]`` sweeps the attention's calls instead
 (``sweep_attend``: ``ATTEND_TILE``, ``ATTEND_ROWS``, ``ATTEND_UNROLL``; 16
 heads of ``--widths`` 128,64,128), XLA's form beside them, into
 ``chiprun_out/attend_sweep.json``.
+
+    python3 ray_tpu/tools/index_sweep.py --block 128 --block 256 --block 512
+
+``--block`` given more than once sweeps the queries a block of the walk
+(``sweep_blocks``): at each, the scores' calls, the choice (``dsa.choose``,
+forward alone: it has no gradient) and the attention's calls, each in a
+walk of its own at the module's constants (or the one ``--kernels`` and
+``--attend`` given), the kernels alone; then a table of ms a step and what
+``dsa.walk_needs`` says each call holds in VMEM, into
+``chiprun_out/block_sweep.json``; beside them ``walk``, the op whole
+(``dsa.sparse_attention``'s forward and gradient: the three parts, the
+index's term and the sums of the blocks' ``dk`` and ``dv`` into their
+tiers'). The blocks are taken as given: the plan's guard
+(``dsa.VMEM_CEILING``) is lifted for the sweep.
 """
 
 from __future__ import annotations
@@ -87,7 +101,9 @@ def main() -> None:
     ap.add_argument("--seq", type=int, default=16384)
     ap.add_argument("--heads", type=int, default=64)
     ap.add_argument("--dim", type=int, default=128)
-    ap.add_argument("--block", type=int, default=128)
+    ap.add_argument("--block", type=int, action="append", default=[],
+                    help="queries a block of the walk (256); given again: "
+                         "the scores, the choice and the attention at each")
     ap.add_argument("--tiers", type=int, default=4)
     ap.add_argument("--topk", type=int, default=2048)
     ap.add_argument("--layers", type=int, default=2)
@@ -103,13 +119,150 @@ def main() -> None:
     ap.add_argument("--no-xla", action="store_true")
     ap.add_argument("--out", default=None)
     a = ap.parse_args()
-    if a.attend:
-        sweep_attend(a)
+    _interpret_on_the_cpu()
+    blocks = a.block or [256]
+    if len(blocks) > 1:
+        _write(sweep_blocks(a, blocks), a.out or "block_sweep.json")
+    elif a.attend:
+        _write(sweep_attend(a, blocks[0]), a.out or "attend_sweep.json")
     else:
-        sweep_scores(a)
+        _write(sweep_scores(a, blocks[0]), a.out or "index_sweep.json")
 
 
-def sweep_attend(a) -> None:
+def _interpret_on_the_cpu() -> None:
+    """On the CPU the kernels run in the Pallas interpreter and the plans
+    are a TPU backend's (XLA's forms are called by name and stay XLA's)."""
+    import jax
+
+    from ray_tpu.ops import dsa
+
+    if jax.default_backend() == "cpu":
+        dsa.score_kernels = functools.partial(dsa.score_kernels,
+                                              interpret=True)
+        dsa.attend_kernels = functools.partial(dsa.attend_kernels,
+                                               interpret=True)
+        jax.default_backend = lambda: "tpu"
+
+
+def sweep_blocks(a, blocks) -> dict:
+    """The walk's three parts at each of ``blocks`` queries a block, the
+    kernels alone: ms a step over ``--layers`` layers for each, their sum,
+    and the calls' VMEM by ``dsa.walk_needs``."""
+    import jax.numpy as jnp
+
+    from ray_tpu.ops import dsa
+
+    a.no_xla = a.no_gaps = True
+    a.kernels = a.kernels[:1]
+    a.attend = a.attend[:1] or [
+        f"{dsa.ATTEND_TILE},{dsa.ATTEND_ROWS},{dsa.ATTEND_UNROLL}"]
+    dn, dr, dv = (int(x) for x in a.widths.split(","))
+    widths = dsa.Widths(a.attend_heads, dn, dr, dv, a.heads, a.dim,
+                        jnp.bfloat16)
+    out = {"blocks": {}}
+    names = ("dsa_scores", "dsa_select", "flash_sparse", "walk")
+    for block in blocks:
+        (scores,) = sweep_scores(a, block)["kernels"].values()
+        attend = sweep_attend(a, block)
+        (attended,) = attend["kernels"].values()
+        select = sweep_select(a, block)
+        parts = dict(zip(names, (scores, select, attended)))
+        out["blocks"][block] = dict(
+            parts, walk=sweep_walk(a, block, widths),
+            device=attend["device"], tiers=attend["tiers"],
+            vmem_need_bytes=dsa.walk_needs(
+                block, a.seq // attend["tiers"], widths),
+            step_ms=(sum(p["step_ms"] for p in parts.values())
+                     if all("step_ms" in p for p in parts.values())
+                     else None))
+    print("| queries a block | " + " | ".join(
+        f"{name} ms a step" for name in names) + " | the parts together |")
+    print("|---" * (len(names) + 2) + "|")
+    for block, row in out["blocks"].items():
+        print(f"| {block} | " + " | ".join(
+            f"{row[name]['step_ms']:.1f}" if "step_ms" in row[name]
+            else "refused" for name in names)
+            + f" | {row['step_ms'] and round(row['step_ms'], 1)} |")
+    return out
+
+
+def sweep_walk(a, block: int, widths) -> dict:
+    """The op whole, one layer: ``dsa.sparse_attention`` forward and the
+    gradient of ``sum(out * do) + kl`` to its seven inputs at ``block``
+    queries a block (the guard lifted); ``step_ms`` = ``--layers`` x
+    (``forward_ms`` + ``grad_ms``), three forwards and a backward a
+    layer."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.ops import dsa
+
+    dsa.VMEM_CEILING = 1 << 40
+    H, dn, dr, dv, J, di, dtype = widths
+    s, f32 = a.seq, jnp.float32
+    ks = jax.random.split(jax.random.PRNGKey(2), 8)
+    shapes = ((s, H, dn + dr), (s, H, dn), (s, H, dv), (s, dr), (s, J, di),
+              (s, di), (s, H, dv))
+    q, kn, v, kr, q_i, k_i, do = (
+        jax.random.normal(k, (1,) + shape, f32).astype(dtype)
+        for k, shape in zip(ks, shapes))
+    w = jax.random.normal(ks[7], (1, s, J), f32) * (J * di) ** -0.5
+    how = dict(scale=(dn + dr) ** -0.5, topk=a.topk, block=block,
+               tiers=a.tiers)
+
+    def forward(do, *xs):
+        out, kl, _ = dsa.sparse_attention(*xs, **how)
+        return (out.astype(f32) * do.astype(f32)).sum() + kl.sum()
+
+    xs = (do, q, kn, v, kr, q_i, k_i, w)
+    out = {}
+    try:
+        out["forward_ms"] = _ms(jax.jit(forward), xs, a.calls)
+        out["grad_ms"] = _ms(jax.jit(jax.grad(
+            forward, argnums=tuple(range(1, 8)))), xs, a.calls)
+        out["step_ms"] = a.layers * (out["forward_ms"] + out["grad_ms"])
+    except Exception as e:  # noqa: BLE001 (a block Mosaic refuses)
+        out["error"] = f"{type(e).__name__}: {str(e)[:600]}"
+    print(json.dumps({"walk": dict(out, block=block)}), flush=True)
+    return out
+
+
+def sweep_select(a, block: int) -> dict:
+    """The choice alone: ``dsa.choose`` of every block of the walk over its
+    tier's keys from float32 scores ``[block, keys]`` (one array of them,
+    moved by the block's place so that no two blocks' are equal), forward
+    alone; ``step_ms`` = ``--layers`` x 3 ``forward_ms`` (the forward, the
+    layer's remat, the block's own ``jax.checkpoint``)."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.ops import dsa
+
+    s = a.seq
+    block, tiers = dsa.walk_plan(s, block, a.tiers)
+    per_tier = s // block // tiers
+    scores = jax.random.normal(jax.random.PRNGKey(1), (block, s),
+                               jnp.float32)
+    firsts = (jnp.arange(s // block, dtype=jnp.int32) * block
+              ).reshape(tiers, per_tier)
+
+    def forward(scores, firsts):
+        total = 0
+        for t in range(tiers):
+            end = (t + 1) * per_tier * block
+            total = total + jax.lax.map(
+                lambda first, end=end: dsa.choose(
+                    scores[:, :end] + first.astype(jnp.float32) * 1e-6,
+                    first, a.topk).sum(dtype=jnp.int32), firsts[t]).sum()
+        return total
+
+    out = {"forward_ms": _ms(jax.jit(forward), (scores, firsts), a.calls)}
+    out["step_ms"] = a.layers * 3 * out["forward_ms"]
+    print(json.dumps({"select": dict(out, block=block)}), flush=True)
+    return out
+
+
+def sweep_attend(a, block: int) -> dict:
     """The attention over the choice alone (``dsa.attend_kernels`` beside
     ``dsa.plain_attend``): one layer's walk as ``dsa._walk`` makes it, the
     blocks under ``jax.checkpoint`` in ``lax.map`` a tier, the choice
@@ -129,7 +282,7 @@ def sweep_attend(a) -> None:
     s, H = a.seq, a.attend_heads
     dn, dr, dv = (int(x) for x in a.widths.split(","))
     scale = (dn + dr) ** -0.5
-    block, tiers = dsa.walk_plan(s, a.block, a.tiers)
+    block, tiers = dsa.walk_plan(s, block, a.tiers)
     per_tier = s // block // tiers
     ks = jax.random.split(jax.random.PRNGKey(0), 6)
     q = jax.random.normal(ks[0], (s, H, dn + dr), f32)
@@ -138,7 +291,6 @@ def sweep_attend(a) -> None:
     kr = jax.random.normal(ks[3], (s, dr), f32)
     do = jax.random.normal(ks[4], (s, H, dv), f32)
     planted = jax.random.uniform(ks[5], (block, s)) < a.topk / s
-    on_cpu = jax.default_backend() == "cpu"
 
     def chosen_of(planted, first, end):
         t = first + jnp.arange(block, dtype=jnp.int32)[:, None]
@@ -242,10 +394,6 @@ def sweep_attend(a) -> None:
                for t in range(tiers)),
            "xla": {} if a.no_xla else reading(None), "kernels": {}}
     print(json.dumps({"xla": out["xla"]}), flush=True)
-    if on_cpu:
-        dsa.attend_kernels = functools.partial(dsa.attend_kernels,
-                                               interpret=True)
-        jax.default_backend = lambda: "tpu"
     for text in a.attend:
         dsa.ATTEND_TILE, dsa.ATTEND_ROWS, *more = (
             int(x) for x in text.split(","))
@@ -255,10 +403,10 @@ def sweep_attend(a) -> None:
             reading(plan["attend_tile"])
             if plan["attend_form"] == "kernel" else {}))
         print(json.dumps({text: out["kernels"][text]}), flush=True)
-    _write(out, a.out or "attend_sweep.json")
+    return out
 
 
-def sweep_scores(a) -> None:
+def sweep_scores(a, block: int) -> dict:
     import jax
     import jax.numpy as jnp
 
@@ -266,7 +414,7 @@ def sweep_scores(a) -> None:
 
     f32, bf16 = jnp.float32, jnp.bfloat16
     s, J, d = a.seq, a.heads, a.dim
-    block, tiers = dsa.walk_plan(s, a.block, a.tiers)
+    block, tiers = dsa.walk_plan(s, block, a.tiers)
     per_tier = s // block // tiers
     keys = jax.random.split(jax.random.PRNGKey(0), 4)
     q = jax.random.normal(keys[0], (s, J, d), f32)
@@ -275,7 +423,6 @@ def sweep_scores(a) -> None:
     # a cotangent that is zero off a planted choice: ``topk`` keys a query
     g = jnp.where(jax.random.uniform(keys[3], (block, s)) < a.topk / s,
                   jax.random.normal(keys[3], (block, s), f32), 0.0)
-    on_cpu = jax.default_backend() == "cpu"
 
     def small(x):
         """A few numbers of ``x`` that no fusion can reach past."""
@@ -361,12 +508,8 @@ def sweep_scores(a) -> None:
            "dim": d, "block": block, "tiers": tiers, "calls": a.calls,
            "pairs_scored": sum(per_tier * block * (t + 1) * per_tier * block
                                for t in range(tiers)),
-           "xla": reading(xla), "kernels": {}}
+           "xla": {} if a.no_xla else reading(xla), "kernels": {}}
     print(json.dumps({"xla": out["xla"]}), flush=True)
-    if on_cpu:
-        dsa.score_kernels = functools.partial(dsa.score_kernels,
-                                              interpret=True)
-        jax.default_backend = lambda: "tpu"
     names = ("SCORE_TILE", "SCORE_ROWS")
     settings = [tuple(int(x) for x in text.split(",")) for text in a.kernels
                 ] or [tuple(getattr(dsa, n) for n in names)]
@@ -380,7 +523,7 @@ def sweep_scores(a) -> None:
             dsa.index_scores(q_b, k_t, w_b))
             if plan["scores_form"] == "kernel" else {}))
         print(json.dumps({key: out["kernels"][key]}), flush=True)
-    _write(out, a.out or "index_sweep.json")
+    return out
 
 
 if __name__ == "__main__":

@@ -116,6 +116,77 @@ def test_a_mesh_walks_each_chips_own_rows():
         np.testing.assert_allclose(got[name], want[name], rtol=1e-5)
 
 
+# train-dots3-1chip's full layers: 16 heads of 128 | 64 and 128, 64 index
+# heads of 128, bfloat16
+CELL = dsa.Widths(16, 128, 64, 128, 64, 128, jnp.bfloat16)
+
+
+def test_the_plan_takes_256_queries_a_block_at_the_cells_shapes(monkeypatch):
+    """16,384 positions in four tiers on a TPU backend: 256 queries a block
+    as asked, each of the four calls' blocks and scratch reckoned as the
+    calls are built, the scores' backward the largest at 44 MB of the
+    ceiling's 64 MiB; asked for 512, whose scores' backward would hold 88
+    MB, the plan steps down to 256."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert dsa.walk_plan(16384, 256, 4, CELL) == (256, 4)
+    needs = dsa.walk_needs(256, 4096, CELL)
+    assert needs == {"dsa_scores_fwd": 18_350_080,
+                     "dsa_scores_bwd": 44_302_336,
+                     "dsa_attend_fwd": 18_939_904,
+                     "dsa_attend_bwd": 32_833_536}
+    assert max(needs.values()) <= dsa.VMEM_CEILING == 64 << 20
+    assert dsa.walk_needs(512, 4096, CELL)["dsa_scores_bwd"] == 87_818_240
+    assert dsa.walk_plan(16384, 512, 4, CELL) == (256, 4)
+    assert dsa.walk_plan(16384, 128, 4, CELL) == (128, 4)
+
+
+@pytest.mark.parametrize("widths,took,fits", [
+    (CELL._replace(index_heads=128), 128, True),
+    (CELL._replace(heads=32), 256, True),
+    (CELL._replace(heads=40), 128, True),
+    (CELL._replace(heads=64), 128, False),
+    (CELL._replace(dtype=jnp.float32), 256, True)],
+    ids=["128-index-heads", "32-heads-fit", "40-heads", "no-block-fits",
+         "float32"])
+def test_the_plan_steps_the_block_down_at_wider_shapes(widths, took, fits,
+                                                      monkeypatch):
+    """Made-up wider models at the cell's 16,384 positions: where a call of
+    256 queries a block would hold more than the ceiling the walk takes
+    128, the smallest block of whole lanes where even that does not fit (64
+    heads: the compiler's to refuse), and 256 where all four fit."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert dsa.walk_plan(16384, 256, 4, widths) == (took, 4)
+    assert (max(dsa.walk_needs(took, 4096, widths).values())
+            <= dsa.VMEM_CEILING) == fits
+    if took < 256:
+        assert max(dsa.walk_needs(256, 4096, widths).values()
+                   ) > dsa.VMEM_CEILING
+
+
+@pytest.mark.parametrize("backend", ["cpu", "tpu"])
+@pytest.mark.parametrize("seq", [16384, 12288, 16512, 4096, 1000, 384, 49,
+                                 48, 7])
+def test_the_plan_returns_divisors_alone(seq, backend, monkeypatch):
+    """Whatever the sequence, the block asked and the ceiling: the block
+    divides the sequence, the tiers divide the blocks, neither is more than
+    was asked, and without a kernel form (the CPU; a block off the lanes)
+    nothing is reckoned and the largest divisor stands."""
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    for ceiling in (64 << 20, 24 << 20, 1):
+        monkeypatch.setattr(dsa, "VMEM_CEILING", ceiling)
+        for asked in (16, 128, 256, 512, 4096):
+            block, tiers = dsa.walk_plan(seq, asked, 4, CELL)
+            assert seq % block == 0 and seq // block % tiers == 0
+            assert 1 <= block <= asked and 1 <= tiers <= 4
+            plain = dsa.walk_plan(seq, asked, 4)
+            assert block <= plain[0]
+            if backend == "cpu" or not dsa.walk_needs(
+                    plain[0], seq // plain[1], CELL):
+                assert (block, tiers) == plain
+            if block < plain[0]:
+                assert block % dsa.KERNEL_LANES == 0
+
+
 def test_the_steps_scopes_span_and_counters():
     """The compiled train step carries the index's, the gate's and the
     window's scopes; tracing the op writes ``rtpu.dsa.shapes``; the
@@ -149,6 +220,9 @@ def test_the_steps_scopes_span_and_counters():
     # the kernels')
     assert all((a["block"], a["tiers"], a["scores_form"], a["scores_tile"])
                == (16, 3, "xla", None) for a in spans)
+    # XLA's forms hold nothing in VMEM: the guard has nothing to step
+    assert all((a["block_asked"], a["vmem_need_bytes"]) == (16, 0)
+               for a in spans)
     assert lowered.out_info[3].shape == (3, 16)
     assert set(lowered.out_info[4]) == {
         "cross_entropy", "dsa_index_loss", "dsa_pairs_chosen_share",

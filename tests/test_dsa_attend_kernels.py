@@ -252,6 +252,42 @@ def test_the_walk_with_both_kernels_is_the_walk_with_xlas_forms(
         np.testing.assert_allclose(a, b, rtol=1e-3, atol=1e-5, err_msg=name)
 
 
+@pytest.mark.parametrize("block", [64, 128])
+def test_the_walk_does_not_depend_on_its_block(block, kernels):
+    """A row of 256 positions in two tiers through both kernels, blocks
+    of 32 queries against blocks of 64 and of 128 (a tier in one block):
+    the block is in no equation, so the choice is the same bit for bit,
+    ``pairs`` too, and the output, the term and the gradients to all seven
+    inputs are the same to float32's rounding (a key's gradient is summed
+    over fewer, larger blocks)."""
+    kernels(32, 16, attend=(64, 32))
+    args = _wide_inputs(1, 256)
+    g = jax.random.normal(jax.random.PRNGKey(9), args[2].shape)
+
+    def read(block):
+        how = dict(scale=0.2, topk=24, block=block, tiers=2)
+        assert dsa.walk_plan(256, block, 2, dsa.Widths.of(
+            *args[:3], args[4])) == (block, 2)
+
+        def loss(*a):
+            out, kl, _ = dsa.sparse_attention(*a, **how)
+            return (out * g).sum() + kl.sum()
+
+        with jax.default_matmul_precision("highest"):
+            return (jax.jit(functools.partial(
+                dsa.sparse_attention, **how, keep_choice=True))(*args),
+                jax.jit(jax.grad(loss, argnums=tuple(range(7))))(*args))
+
+    (got, got_grads), (want, want_grads) = read(block), read(32)
+    np.testing.assert_array_equal(got[3], want[3])
+    np.testing.assert_array_equal(got[2], want[2])
+    np.testing.assert_allclose(got[0], want[0], rtol=1e-5, atol=1e-6)
+    np.testing.assert_allclose(got[1], want[1], rtol=1e-5, atol=1e-6)
+    for name, a, b in zip(("dq", "dk_n", "dv", "dk_r", "dq_i", "dk_i", "dw"),
+                          got_grads, want_grads):
+        np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-5, err_msg=name)
+
+
 def test_a_replaced_target_and_choice_are_what_the_kernel_form_calls(
         kernels, monkeypatch):
     """``benchmark/tests/sparse_limits.py`` plants faults by replacing
@@ -337,3 +373,35 @@ def test_the_span_says_which_form_attended(kernels):
     assert span() == ("kernel", 32, "xla", None)
     kernels(32, 64, attend=(32, 32))    # nor one of the scores' 64
     assert span() == ("xla", None, "kernel", 32)
+
+
+def test_the_span_says_what_the_guard_held_the_block_to(kernels,
+                                                        monkeypatch):
+    """``rtpu.dsa.shapes`` carries the largest VMEM need of the walk's
+    calls at the block that ran and the block before the guard: equal
+    where the calls fit under ``VMEM_CEILING``, and a smaller ``block``
+    beside the ``block_asked`` where the ceiling stands under them."""
+    from ray_tpu.util import tracing
+
+    kernels(32, 16, attend=(64, 32))
+    args = _wide_inputs(1, 256)
+    widths = dsa.Widths.of(*args[:3], args[4])
+
+    def span():
+        here = tracing.since()
+        jax.eval_shape(functools.partial(
+            dsa.sparse_attention, scale=0.3, topk=8, block=128, tiers=2),
+            *args)
+        (said,) = [e["args"] for e in here.events()
+                   if e["name"] == "rtpu.dsa.shapes"]
+        return tuple(said[k] for k in ("block", "block_asked", "tiers",
+                                       "vmem_need_bytes"))
+
+    at = {b: max(dsa.walk_needs(b, 128, widths).values())
+          for b in (32, 64, 128)}
+    assert at[32] < at[64] < at[128]
+    assert span() == (128, 128, 2, at[128])
+    monkeypatch.setattr(dsa, "VMEM_CEILING", at[64])
+    assert span() == (64, 128, 2, at[64])
+    monkeypatch.setattr(dsa, "VMEM_CEILING", at[64] - 1)
+    assert span() == (32, 128, 2, at[32])

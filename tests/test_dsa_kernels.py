@@ -119,7 +119,7 @@ def test_a_pairs_score_does_not_depend_on_the_block_that_scored_it(
         tile, rows, kernels):
     """256 queries as one block, as two blocks of 128, and under other
     tiles and chunks: bit for bit the same scores (the cell's check scores
-    blocks of 256, the walk blocks of 128)."""
+    blocks of 256, as the walk does)."""
     q_i, k_i, w = _inputs(256, 512, dtype=jnp.bfloat16)
     kernels(128, 64)
     whole = dsa.index_scores(q_i, k_i, w)

@@ -489,13 +489,13 @@ def test_olmo_hybrid_cell_step_lowers_for_a_v5e_chip(one_chip,
     assert lowered.out_info[3].shape == ()
 
 
-@pytest.mark.parametrize("block", [128, 256], ids=["the-walks-block",
-                                                   "the-checks-block"])
+@pytest.mark.parametrize("block", [128, 256], ids=["a-block-of-128",
+                                                   "the-walks-block"])
 def test_index_score_kernels_compile_at_the_cells_shapes(
         S, one_chip, no_compile_cache, monkeypatch, block):
     """The index's scores at ``train-dots3-1chip``'s shapes (64 heads of
-    128 against 16,384 keys, bfloat16; the head weights float32) for the
-    walk's blocks of 128 queries and the check's of 256, on a TPU
+    128 against 16,384 keys, bfloat16; the head weights float32) for
+    blocks of 128 queries and the walk's and the check's of 256, on a TPU
     backend: Mosaic takes the forward call, and the backward call of the
     three gradients; nothing ``[block, 64, keys]`` is left in the
     program."""
@@ -523,8 +523,8 @@ def test_index_score_kernels_compile_at_the_cells_shapes(
         assert f"[{block},64,16384]" not in text
 
 
-@pytest.mark.parametrize("block", [128, 256], ids=["the-walks-block",
-                                                   "a-block-of-256"])
+@pytest.mark.parametrize("block", [128, 256], ids=["a-block-of-128",
+                                                   "the-walks-block"])
 def test_attend_kernels_compile_at_the_cells_shapes(
         S, one_chip, no_compile_cache, monkeypatch, block):
     """Attention over the choice at ``train-dots3-1chip``'s shapes (16
@@ -564,6 +564,47 @@ def test_attend_kernels_compile_at_the_cells_shapes(
         assert any(kernel in name for name in names), (kernel, names)
     for text in (forward, gradient):
         assert f"f32[16,{block},16384]" not in text
+
+
+def test_the_walk_compiles_at_the_cells_shapes(S, one_chip, no_compile_cache,
+                                               monkeypatch):
+    """One full layer's walk of ``train-dots3-1chip`` (16,384 positions of
+    16 heads, 64 index heads, the top 2,048, four tiers) and its gradient
+    to all seven inputs, on a TPU backend: the plan takes 256 queries a
+    block as the model asks and says what the largest call holds, and every
+    Mosaic call stays a call with its own VMEM limit beside the blocks'
+    sums (each tier's four calls: a forward and a backward of both the
+    scores and the attention, and the forward again of both under the
+    block's ``jax.checkpoint``)."""
+    from ray_tpu.models.dots3 import Dots3Config
+    from ray_tpu.ops import dsa
+    from ray_tpu.util import tracing
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    s, f32 = 16384, jnp.float32
+    args = (S(1, s, 16, 192), S(1, s, 16, 128), S(1, s, 16, 128),
+            S(1, s, 64), S(1, s, 64, 128), S(1, s, 128),
+            jax.ShapeDtypeStruct((1, s, 64), f32, sharding=one_chip))
+
+    def loss(*a):
+        out, kl, _ = dsa.sparse_attention(
+            *a, scale=192 ** -0.5, topk=2048,
+            block=Dots3Config.index_block, tiers=Dots3Config.index_tiers)
+        return jnp.square(out.astype(f32)).sum() + kl.sum()
+
+    here = tracing.since()
+    text = jax.jit(jax.grad(loss, argnums=tuple(range(7)))).lower(
+        *args).compile().as_text()
+    (said,) = [e["args"] for e in here.events()
+               if e["name"] == "rtpu.dsa.shapes"]
+    assert (said["block"], said["block_asked"], said["tiers"],
+            said["vmem_need_bytes"]) == (256, 256, 4, 44_302_336)
+    assert (said["scores_form"], said["attend_form"]) == ("kernel", "kernel")
+    names = [name for name, _ in _mosaic_calls(text)]
+    for kernel, calls in (("dsa_scores_fwd", 8), ("dsa_scores_bwd", 4),
+                          ("dsa_attend_fwd", 8), ("dsa_attend_bwd", 4)):
+        assert sum(kernel in name for name in names) == calls, (kernel,
+                                                                 names)
 
 
 def test_delta_rule_kernels_compile_at_grouped_heads(S, no_compile_cache,
