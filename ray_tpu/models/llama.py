@@ -33,10 +33,11 @@ import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
+from ray_tpu.ops import dsa
 from ray_tpu.ops.attention import attention_reference, flash_attention
 from ray_tpu.ops.layers import (Leaf, Part, apply_rope, blocked_head_loss,
                                 blocked_head_nll, embed_rows, head_block,
-                                kept, norm_start, rms_norm,
+                                kept, layer_norm, norm_start, rms_norm,
                                 rope_frequencies, swiglu, swiglu_part)
 from ray_tpu.ops.ring_attention import ring_attention
 from ray_tpu.util import tracing
@@ -510,7 +511,8 @@ def _wide_gated(attn, gate):
 
 def attention_block(cfg: LlamaConfig, x, p, cos, sin, mesh=None,
                     seq_axis=None, window=None, sm_scale=None,
-                    resid_scale=None, gate_in_wq: bool = False):
+                    resid_scale=None, gate_in_wq: bool = False,
+                    attend=None):
     """Attention sub-block with residual: x + wo(attend(qkv)), the norm
     where the layer's leaves put it: ``attn_norm`` on the block's input
     (pre-norm, llama's order), ``attn_post_norm`` on its output before the
@@ -533,7 +535,10 @@ def attention_block(cfg: LlamaConfig, x, p, cos, sin, mesh=None,
     as wide and gives each head its query and then, of the same size, an
     elementwise gate: ``sigmoid`` of it multiplies that head's output dim
     by dim before ``wo`` (Qwen3-Next). ``cfg.zero_centred_norm``: every
-    norm here scales by ``1 + w``."""
+    norm here scales by ``1 + w``. ``attend(u, q, k, v) -> attn``: what
+    stands in for the causal attention under its own scopes, given the
+    block's normed input and its rotated heads (``attention_part(index=
+    True)``: attention over the keys a learned index chooses)."""
     # The named scopes here and below (embed, attn_qkv, flash, attn_out,
     # mlp, head_loss) are metadata only: they name the device time of a
     # step in a profiler trace and change no instruction.
@@ -583,14 +588,16 @@ def attention_block(cfg: LlamaConfig, x, p, cos, sin, mesh=None,
                     preferred_element_type=jnp.float32))
     # a window layer's kernel calls are ``flash_win`` inside ``flash``: a
     # reader that knows ``flash`` alone still finds them there
-    with jax.named_scope("flash"):
-        if window is None:
-            attn = _attend(cfg, q, k, v, mesh=mesh, seq_axis=seq_axis,
-                           sm_scale=sm_scale)
-        else:
+    def causal():
+        with jax.named_scope("flash"):
+            if window is None:
+                return _attend(cfg, q, k, v, mesh=mesh, seq_axis=seq_axis,
+                               sm_scale=sm_scale)
             with jax.named_scope("flash_win"):
-                attn = _attend(cfg, q, k, v, mesh=mesh, seq_axis=seq_axis,
+                return _attend(cfg, q, k, v, mesh=mesh, seq_axis=seq_axis,
                                window=window, sm_scale=sm_scale)
+
+    attn = causal() if attend is None else attend(h1, q, k, v)
     with jax.named_scope("attn_out"):
         if "wg" in p:
             with jax.named_scope("attn_gate"):
@@ -622,8 +629,8 @@ def rope_tables(cfg: LlamaConfig, tokens: jax.Array):
 def attention_part(heads: str = "num_heads", window: Optional[str] = None,
                    gate: bool = False, rope=rope_tables,
                    qk_norm: Optional[str] = None, norm: str = "pre",
-                   scale: Optional[str] = None, resid: Optional[str] = None
-                   ) -> Part:
+                   scale: Optional[str] = None, resid: Optional[str] = None,
+                   index: bool = False) -> Part:
     """``attention_block`` as a layer's mixer. ``heads``, ``window``, ``scale``
     (the scores') and ``resid`` (the weight of the block's output in the sum)
     name fields of the config; ``gate``: True, a per-head output gate ``wg``
@@ -633,8 +640,21 @@ def attention_part(heads: str = "num_heads", window: Optional[str] = None,
     embedding; ``qk_norm``: "whole" (an RMSNorm over the q and k vectors:
     OLMoE, OLMo 2) or "head" (over each head's dims: LFM2); ``norm``: "pre"
     (``attn_norm`` on the block's input) or "post" (``attn_post_norm`` on its
-    output: OLMo 2). ``cfg.attn_qkv_bias`` adds Qwen2's three biases."""
+    output: OLMo 2). ``cfg.attn_qkv_bias`` adds Qwen2's three biases.
+    ``index``: a learned index chooses ``cfg.index_topk`` keys a query and
+    the layer attends over those alone, the grouped keys and values as they
+    are (``ops/dsa.py``; ``ops/mla.py`` has the same option): ``q_i = u
+    W_iq`` ``[index_heads, index_head_dim]`` from the layer's normed input
+    (there is no query latent), ``k_i = LayerNorm(u W_ik)``, both rotated
+    whole with tables of their own width, so ``rope`` then gives ``((cos,
+    sin), (cos_i, sin_i))``, the heads' and the index's; ``u`` reaches the
+    index under ``stop_gradient``; the layer reports under "dsa" and
+    ``terms`` adds the index's loss as ``dsa.index_terms`` says. Pre-norm
+    and no window only."""
     wide = gate == "elementwise"
+    if index and (window or norm != "pre" or rope is None):
+        raise ValueError("an index layer is pre-norm, rotated and sees every "
+                         "key: no window, norm=\"pre\", a rope")
 
     def leaves(cfg):
         h, hd, n = cfg.hidden_size, cfg.head_dim_, getattr(cfg, heads)
@@ -660,21 +680,72 @@ def attention_part(heads: str = "num_heads", window: Optional[str] = None,
                        bv=Leaf((kvd,), "zeros", vec))
         if norm == "post":
             out["attn_post_norm"] = Leaf((h,), ones, ("embed",))
+        if index:
+            out.update(dsa.index_leaves(cfg, h))
         return out
 
+    def chosen_keys(cfg, p, ctx, tables, said):
+        """``attention_block``'s ``attend`` of an index layer: the index's
+        inputs from ``u``, the walk of ``ops/dsa.py`` over the grouped keys
+        and values; what the layer reports goes into ``said``."""
+        def attend(u, q, k, v):
+            b, s = u.shape[:2]
+            with jax.named_scope("dsa_proj"):
+                def rotate(x):
+                    if x.ndim == 3:           # the one key a position
+                        return apply_rope(x[:, :, None], *tables)[:, :, 0]
+                    return apply_rope(x, *tables)
+
+                q_i, k_i, w_i = dsa.index_inputs(cfg, u, u, p, rotate,
+                                                 layer_norm)
+            keep = ctx.keep_index_choice
+            attn, kl, pairs, *choice = dsa.sparse_attention(
+                q, k, v, None, q_i, k_i, w_i,
+                scale=(getattr(cfg, scale) if scale
+                       else cfg.head_dim_ ** -0.5),
+                topk=cfg.index_topk, block=cfg.index_block,
+                tiers=cfg.index_tiers, mesh=ctx.mesh, keep_choice=keep)
+            said.update(dsa.index_report(b, s, kl, pairs, {
+                "choice": choice[0], "q_i": q_i, "k_i": k_i,
+                "w": w_i} if keep else None))
+            # kept on the ladder's first rung, the layer's backward walks
+            # the blocks once more (each block's own checkpoint) and not
+            # twice: the walk is most of the layer
+            return checkpoint_name(attn, "flash_out")
+
+        return attend
+
     def body(cfg, x, p, ctx):
-        cos, sin = ctx.once[rope] if rope else (None, None)
+        tables = ctx.once[rope] if rope else (None, None)
+        said, attend = {}, None
+        if index:
+            tables, of_index = tables
+            attend = chosen_keys(cfg, p, ctx, of_index, said)
         return attention_block(
-            cfg, x, p, cos, sin, mesh=ctx.mesh,
+            cfg, x, p, *tables, mesh=ctx.mesh,
             window=getattr(cfg, window) if window else None,
             sm_scale=getattr(cfg, scale) if scale else None,
             resid_scale=getattr(cfg, resid) if resid else None,
-            gate_in_wq=wide), {}
+            gate_in_wq=wide, attend=attend), said
 
     def keeps(cfg, shape, tokens, mesh):
         # ``wq``'s width gives the heads, as ``attention_block`` reads them
         qd, kvd = shape["wq"][-1] // (2 if wide else 1), shape["wk"][-1]
         act = jnp.dtype(cfg.dtype).itemsize
+        if index:
+            # the walk's output is the first rung's (no log-sum-exp: its
+            # blocks are checkpointed and run again); the index's queries,
+            # key and head weights are recomputed at every level and held
+            # by the layer's backward, beside one block of the walk
+            # (``dsa.walk_rows``)
+            hd, J, di = cfg.head_dim_, shape["wi_w"][-1], shape["wi_k"][-1]
+            return kept(
+                flash=tokens * qd * act,
+                qkv=tokens * (qd + 2 * kvd) * act,
+                resid=tokens * cfg.hidden_size * act,
+                width=2 * qd + 2 * kvd + 2 * (J * di + di + 2 * J),
+                rows=dsa.walk_rows(cfg, tokens, dsa.Widths(
+                    qd // hd, hd, 0, hd, J, di, cfg.dtype, kvd // hd)))
         return kept(
             flash=tokens * (qd * act + qd // cfg.head_dim_ * 4),  # lse: f32
             qkv=tokens * (qd + 2 * kvd) * act,
@@ -682,7 +753,9 @@ def attention_part(heads: str = "num_heads", window: Optional[str] = None,
             # (a wide gate: the projection's second half and its gradient)
             width=2 * qd + 2 * kvd + (2 * qd if wide else 0))
 
-    return Part(leaves, body, keeps, once=rope)
+    return Part(leaves, body, keeps, once=rope,
+                **({"reports": "dsa", "terms": dsa.index_terms}
+                   if index else {}))
 
 
 # the dense stack's one kind, as ``forward`` describes it to the plan

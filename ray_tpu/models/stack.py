@@ -267,14 +267,18 @@ class Stack:
 
     def hidden(self, cfg, params, tokens: jax.Array, mesh=None,
                keep_router_logits: bool = False,
-               keep_index_choice: bool = False
+               keep_index_choice: bool = False, positions=None
                ) -> Tuple[jax.Array, Dict[str, Any]]:
         """tokens [b, s] -> (the last layer's output [b, s, hidden], name
         -> what the layers reported under it, stacked in layer order).
         ``keep_index_choice``: the index layers report their index's
-        inputs and their packed choice of keys too (``ops/mla.py``)."""
+        inputs and their packed choice of keys too (``ops/mla.py``).
+        ``positions`` [streams, b, s]: the batch's own positions, for a
+        model whose rope reads them (``Part.once`` is then called with
+        them: ``ops/layers.mrope_frequencies``); None: positions ``0 .. s -
+        1``, what every ``once`` assumes without."""
         return self._walk(cfg, params, tokens, mesh, keep_router_logits,
-                          keep_index_choice)[:2]
+                          keep_index_choice, positions=positions)[:2]
 
     def _embed(self, cfg, params, tokens, mesh) -> jax.Array:
         x = embed_rows(params["embed"], tokens, cfg.dtype, mesh)
@@ -294,7 +298,8 @@ class Stack:
         return layer
 
     def _walk(self, cfg, params, tokens, mesh, keep_router_logits=False,
-              keep_index_choice=False, module: Tuple[str, ...] = ()):
+              keep_index_choice=False, module: Tuple[str, ...] = (),
+              positions=None):
         """``hidden`` and beside its two results what a prediction module
         run after it shares with it: the parts' context and the remat
         level. ``module``: the module's kinds, whose layers the plan then
@@ -306,7 +311,9 @@ class Stack:
             for kind in dict.fromkeys(pattern + module):
                 for part in self.kinds[kind]:
                     if part.once and part.once not in once:
-                        once[part.once] = part.once(cfg, tokens)
+                        once[part.once] = (
+                            part.once(cfg, tokens) if positions is None
+                            else part.once(cfg, tokens, positions))
         ctx = Ctx(mesh, once, keep_router_logits, keep_index_choice)
         planned = params if not module else {**params, "layers": {
             **params["mtp"]["layers"], **params["layers"]}}
@@ -360,7 +367,7 @@ class Stack:
         return logits / self._divisor(cfg) if self.logits_divisor else logits
 
     def forward(self, cfg, params, tokens: jax.Array, mesh=None,
-                keep_router_logits: bool = False
+                keep_router_logits: bool = False, positions=None
                 ) -> Tuple[jax.Array, Any]:
         """tokens [b, s] -> (logits [b, s, vocab] float32, whole, for sizes
         at which they fit; what the layers reported under
@@ -371,24 +378,27 @@ class Stack:
         choice, ``chosen [Lr, b * s, K]``; of a scan or a rule the states
         after the last position ``[L, b, H, ...]`` float32."""
         x, said = self.hidden(cfg, params, tokens, mesh=mesh,
-                              keep_router_logits=keep_router_logits)
+                              keep_router_logits=keep_router_logits,
+                              positions=positions)
         return self._logits(cfg, params, x), self._said(said)
 
     def token_nll(self, cfg, params, tokens: jax.Array, mesh=None,
                   head_block: Optional[int] = None,
-                  keep_router_logits: bool = False
+                  keep_router_logits: bool = False, positions=None
                   ) -> Tuple[jax.Array, Any]:
         """tokens [b, s + 1] -> (the next-token loss of every position
         [b, s] float32 through the blocked head, what ``forward`` hands
-        back beside its logits)."""
+        back beside its logits). ``positions`` [streams, b, s]:
+        ``hidden``'s."""
         x, said = self.hidden(cfg, params, tokens[:, :-1], mesh=mesh,
-                              keep_router_logits=keep_router_logits)
+                              keep_router_logits=keep_router_logits,
+                              positions=positions)
         return llama.blocked_token_nll(
             cfg, params, x, tokens[:, 1:], block=head_block,
             logits_divisor=self._divisor(cfg)), self._said(said)
 
     def _both_heads(self, cfg, params, tokens: jax.Array, mesh, head,
-                    keep_router_logits: bool = False):
+                    keep_router_logits: bool = False, positions=None):
         """The stack, its head and, where the config names one, the
         prediction module and the head once more. tokens [b, s + 1], or [b,
         s + 2] with a module, so that each of the ``s`` positions has
@@ -402,7 +412,8 @@ class Stack:
         ahead, length = (2 if module else 1), tokens.shape[1]
         x, said, ctx, level = self._walk(
             cfg, params, tokens[:, :-ahead], mesh,
-            keep_router_logits=keep_router_logits, module=module)
+            keep_router_logits=keep_router_logits, module=module,
+            positions=positions)
         main = head(params, x, 1, length - ahead + 1)
         if not module:
             return main, None, said
@@ -442,7 +453,9 @@ class Stack:
     def loss_terms(self, cfg, params, batch: Dict[str, jax.Array], mesh=None
                    ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
         """(loss, its terms and the parts' counters): the cross entropy, a
-        batch's ``mask`` weighting it, plus what each reporting part's
+        batch's ``mask`` weighting it (0 on a target that is not text) and
+        its ``positions`` [streams, b, s] where it brings them reaching the
+        rope (``hidden``), plus what each reporting part's
         ``terms`` adds (a routed mixture its balancing loss and the routed
         layers' ``expert_counts [Lr, E]``, a scan or a rule the largest
         ``|S|`` under its counter's name). Made for
@@ -459,8 +472,9 @@ class Stack:
             return llama.cross_entropy_loss(
                 self._logits(cfg, params_, x_), targets, weights)
 
-        ce, more_ce, said = self._both_heads(cfg, params, tokens, mesh,
-                                             cross_entropy)
+        ce, more_ce, said = self._both_heads(
+            cfg, params, tokens, mesh, cross_entropy,
+            positions=batch.get("positions"))
         loss, terms = ce, {"cross_entropy": ce}
         if more_ce is not None:
             loss = loss + cfg.mtp_loss_scale * more_ce

@@ -68,13 +68,13 @@ what is not chosen: with ``topk`` of at most 16,384 keys chosen by each of
 a block's queries no tile is empty for a whole block); it is read by the
 yardstick of the needed work, the chosen pairs.
 
-Named scopes: ``dsa_scores`` (the index's scores), ``dsa_select`` (the
-choice), ``flash_sparse`` (the attention over the choice: its two calls, a
-block's ``delta`` and the walk's turns of its arrays to the kernels' layout,
-or XLA's scores, masked softmax and PV), ``dsa_loss`` (the index's term).
-One kept span as the op is traced, ``rtpu.dsa.shapes`` (the forms, the
-block and tiers that ran, ``block_asked``: the block before the guard,
-``vmem_need_bytes``: the most a call holds). Training only.
+Keys and values come in two layouts (``attend_layout``): ``per_head`` above,
+or ``grouped``, ``k, v [G, S, d]`` each serving ``H / G`` query heads, with no
+rope key (the section "grouped keys" below). Named scopes: ``dsa_scores``,
+``dsa_select`` (the choice), ``flash_sparse`` (the attention's calls, a
+block's ``delta``, the walk's turns of its arrays), ``dsa_loss``. One kept
+span, ``rtpu.dsa.shapes`` (forms, layout, ``kv_groups``, block and tiers,
+``block_asked``, ``vmem_need_bytes``: the most a call holds). Training only.
 """
 
 from __future__ import annotations
@@ -162,14 +162,14 @@ def scores_plan(n: int, keys: int, heads: int, dim: int) -> Dict[str, Any]:
     """How ``index_scores`` runs ``n`` queries of ``heads`` index heads of
     ``dim`` against ``keys`` keys: ``scores_form`` "kernel" on a TPU
     backend (anything but the CPU) where the queries are whole chunks of
-    ``SCORE_ROWS``, ``dim`` whole lanes and the keys whole tiles, with
+    ``SCORE_ROWS``, ``dim`` whole half lanes and the keys whole tiles, with
     ``scores_tile`` the keys a grid step takes (the largest count of whole
     ``KERNEL_LANES`` up to ``SCORE_TILE`` that divides the keys); "xla" and
     no tile elsewhere."""
     tiles = [t for t in range(KERNEL_LANES, SCORE_TILE + 1, KERNEL_LANES)
              if keys % t == 0]
     if (jax.default_backend() == "cpu" or not tiles or n % SCORE_ROWS
-            or dim % KERNEL_LANES):
+            or dim % (KERNEL_LANES // 2)):
         return {"scores_form": "xla", "scores_tile": None}
     return {"scores_form": "kernel", "scores_tile": tiles[-1]}
 
@@ -858,6 +858,317 @@ def attend_kernels(q, kn, v, kr, chosen, first, scale: float, tile: int,
              interpret))
 
 
+# ---- grouped keys: the same attention where ``G`` key/value heads each
+# serve ``R = H / G`` query heads (grouped-query attention) and the heads
+# share no rope key (``d_r = 0``). k, v [G, S, d] are read once a group, and
+# a group's R heads go through as rows of ONE product: q [G, R x n, d], head
+# ``g R + r`` the rows ``r n .. (r + 1) n - 1`` of group ``g``. The MXU then
+# sees ``R x n`` queries a product where a head alone gives it ``n``, and the
+# latency a product that bound the per-head walk (PERF.md 6, PR 55) is paid
+# once for R heads. ``dk`` and ``dv`` are contractions over those rows, so
+# they come summed over the group out of the call, as ``k_r``'s gradient
+# comes summed over the heads out of the per-head call. A block's choice [n,
+# S] is all heads' alike: its bias is laid R times along the lanes once a
+# tile. ``p_t`` sums over all H heads: over the groups in the loop, over the
+# R slabs of lanes after it. No key or value is repeated in HBM.
+
+
+def plain_attend_grouped(q_b, k_t, v_t, chosen, scale: float):
+    """``plain_attend`` under grouped keys: q_b [n, H, d], k_t [S, G, d],
+    v_t [S, G, d_v], chosen bool [n, S] -> (out [n, H, d_v], the heads'
+    probabilities [H, n, S] float32 whole). A group's heads are an axis of
+    one product: nothing is repeated."""
+    n, H, d = q_b.shape
+    G = k_t.shape[1]
+    sc = jnp.einsum("qgrd,kgd->grqk", q_b.reshape(n, G, H // G, d), k_t,
+                    preferred_element_type=jnp.float32) * scale
+    p = jax.nn.softmax(jnp.where(chosen[None, None], sc, _NEG), axis=-1)
+    out = jnp.einsum("grqk,kgd->qgrd", p.astype(v_t.dtype), v_t,
+                     preferred_element_type=jnp.float32).astype(q_b.dtype)
+    return out.reshape(n, H, -1), p.reshape(H, n, -1)
+
+
+def _bias_grouped(chosen_ref, heads: int):
+    """``_bias_turned`` [tile, n], laid ``heads`` times along the lanes."""
+    bias = _bias_turned(chosen_ref)
+    return jnp.concatenate([bias] * heads, axis=1) if heads > 1 else bias
+
+
+def _grouped_fwd_kernel(first_ref, q_ref, k_ref, vt_ref, chosen_ref, ot_ref,
+                        lse_ref, ps_ref, m_ref, l_ref, acc_ref, bias_ref, *,
+                        scale, rows, unroll):
+    """``_attend_fwd_kernel`` under grouped keys: a grid step ``(phase,
+    tile)``, keys down and a group's ``R x n`` queries across; the
+    statistics ``m``, ``l`` [G, R x n] are a query's of a head as there."""
+    import jax.experimental.pallas as pl
+
+    G, N, _ = q_ref.shape
+    n, tile = chosen_ref.shape
+    phase, t = pl.program_id(0), pl.program_id(1)
+    seen = t <= _last_tile(first_ref, n, tile)
+    chunks = [slice(i, i + rows) for i in range(0, tile, rows)]
+
+    def scores(g, at):
+        return _nt(k_ref[g, at, :], q_ref[g]) * scale + bias_ref[at, :]
+
+    def groups(body, init=0):
+        return _over_heads(G, body, init, unroll)
+
+    @pl.when((phase == 0) & (t == 0))
+    def _():
+        m_ref[...] = jnp.full_like(m_ref, _NEG)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    @pl.when(seen)
+    def _():
+        bias_ref[...] = _bias_grouped(chosen_ref, N // n)
+
+    @pl.when((phase == 0) & seen)
+    def _():
+        for at in chunks:
+            def group(g, carry, at=at):
+                s = scores(g, at)
+                m = _row(m_ref, g)
+                m_new = jnp.maximum(m, s.max(0, keepdims=True))
+                l_ref[pl.ds(g, 1), :] = (
+                    _row(l_ref, g) * jnp.exp(m - m_new)
+                    + jnp.exp(s - m_new).sum(0, keepdims=True))
+                m_ref[pl.ds(g, 1), :] = m_new
+                return carry
+
+            groups(group)
+
+    @pl.when((phase == 1) & (t == 0))
+    def _():
+        # ``l`` is held inverted through phase 1
+        lse_ref[...] = m_ref[...] + jnp.log(l_ref[...])
+        l_ref[...] = 1.0 / l_ref[...]
+
+    @pl.when((phase == 1) & seen)
+    def _():
+        for at in chunks:
+            def group(g, total, at=at):
+                p = jnp.exp(scores(g, at) - _row(m_ref, g)) * _row(l_ref, g)
+                acc_ref[g] += jnp.dot(
+                    vt_ref[g, :, at], p.astype(vt_ref.dtype),
+                    preferred_element_type=jnp.float32)
+                return total + p
+
+            total = groups(group, jnp.zeros((rows, N), jnp.float32))
+            ps_ref[:, at] = sum(total[:, i:i + n] for i in range(0, N, n)).T
+
+    @pl.when((phase == 1) & jnp.logical_not(seen))
+    def _():
+        ps_ref[...] = jnp.zeros_like(ps_ref)
+
+    @pl.when((phase == 1) & (t == pl.num_programs(1) - 1))
+    def _():
+        ot_ref[...] = acc_ref[...].astype(ot_ref.dtype)
+
+
+def _grouped_bwd_kernel(first_ref, q_ref, k_ref, v_ref, chosen_ref, dot_ref,
+                        lse_ref, delta_ref, dq_ref, dk_ref, dv_ref, bias_ref,
+                        dq_acc, *, scale, rows, unroll):
+    """``_attend_bwd_kernel`` under grouped keys: ``dk = dS q`` and ``dv =
+    p dO`` contract a group's ``R x n`` rows, its heads and queries at once,
+    so a group's key and value gradients leave the call summed."""
+    import jax.experimental.pallas as pl
+
+    G, N, _ = q_ref.shape
+    n, tile = chosen_ref.shape
+    t = pl.program_id(0)
+    seen = t <= _last_tile(first_ref, n, tile)
+
+    @pl.when(t == 0)
+    def _():
+        dq_acc[...] = jnp.zeros_like(dq_acc)
+
+    @pl.when(seen)
+    def _():
+        bias_ref[...] = _bias_grouped(chosen_ref, N // n)
+        for at in [slice(i, i + rows) for i in range(0, tile, rows)]:
+            def group(g, carry, at=at):
+                q, dot = q_ref[g], dot_ref[g]
+                k, v = k_ref[g, at, :], v_ref[g, at, :]
+                p = jnp.exp(_nt(k, q) * scale + bias_ref[at, :]
+                            - _row(lse_ref, g))
+                dv_ref[g, at, :] = _nt(p.astype(dot.dtype), dot
+                                       ).astype(dv_ref.dtype)
+                ds = p * (jnp.dot(v, dot, preferred_element_type=jnp.float32)
+                          - _row(delta_ref, g)) * scale
+                ds_t = ds.T.astype(q.dtype)
+                dk_ref[g, at, :] = jnp.dot(
+                    ds.astype(q.dtype), q, preferred_element_type=jnp.float32
+                ).astype(dk_ref.dtype)
+                dq_acc[g] += jnp.dot(ds_t, k,
+                                     preferred_element_type=jnp.float32)
+                return carry
+
+            _over_heads(G, group, 0, unroll)
+
+    @pl.when(jnp.logical_not(seen))
+    def _():
+        dk_ref[...] = jnp.zeros_like(dk_ref)
+        dv_ref[...] = jnp.zeros_like(dv_ref)
+
+    @pl.when(t == pl.num_programs(0) - 1)
+    def _():
+        dq_ref[...] = dq_acc[...].astype(dq_ref.dtype)
+
+
+def _grouped_blocks(G: int, R: int, n: int, d: int, dv: int, tile: int,
+                    dtype):
+    """``_attend_blocks`` of the grouped calls: (blocks, scratch), (shape,
+    dtype) each."""
+    f32, N = jnp.float32, R * n
+    q, k = ((G, N, d), dtype), ((G, tile, d), dtype)
+    chosen, stat = ((n, tile), jnp.int8), ((G, N), f32)
+    return {
+        "dsa_attend_gqa_fwd": (
+            [q, k, ((G, dv, tile), dtype), chosen, ((G, dv, N), dtype), stat,
+             ((n, tile), f32)],
+            [stat, stat, ((G, dv, N), f32), ((tile, N), f32)]),
+        "dsa_attend_gqa_bwd": (
+            [q, k, ((G, tile, dv), dtype), chosen, ((G, dv, N), dtype), stat,
+             stat, q, k, ((G, tile, dv), dtype)],
+            [((tile, N), f32), ((G, N, d), f32)])}
+
+
+def _grouped_need(blocks, scratch, rows: int, N: int) -> int:
+    """Bytes of VMEM a grouped call holds: ``_need`` and six ``[rows, R x
+    n]`` float32 temporaries of a chunk (scores, probabilities, their
+    gradient and its turn: 4 MB each at 512 keys by 8 x 256 queries, past
+    what ``_vmem``'s 8 MB allows for)."""
+    return _need(blocks, scratch) + 6 * rows * N * 4
+
+
+def _grouped_params(blocks, scratch, rows: int, N: int, grid_dims: int):
+    from jax.experimental.pallas import tpu as pltpu
+
+    return pltpu.CompilerParams(
+        dimension_semantics=("arbitrary",) * grid_dims,
+        vmem_limit_bytes=max(_grouped_need(blocks, scratch, rows, N),
+                             16 << 20))
+
+
+def _grouped_forward(first, q, k, vt, chosen, how: _How):
+    import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    G, N, d = q.shape
+    n, S, dv = chosen.shape[0], k.shape[1], vt.shape[1]
+    f32, tile = jnp.float32, how.tile
+    last = _last_seen(n, tile)
+    blocks, scratch = _grouped_blocks(G, N // n, n, d, dv, tile,
+                                      q.dtype)["dsa_attend_gqa_fwd"]
+    return pl.pallas_call(
+        functools.partial(_grouped_fwd_kernel, scale=how.scale,
+                          rows=how.rows, unroll=how.unroll),
+        name="dsa_attend_gqa_fwd",
+        out_shape=[jax.ShapeDtypeStruct((G, dv, N), q.dtype),
+                   jax.ShapeDtypeStruct((G, N), f32),
+                   jax.ShapeDtypeStruct((n, S), f32)],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(2, S // tile),
+            in_specs=[
+                pl.BlockSpec((G, N, d), lambda p, t, f: (0, 0, 0)),
+                pl.BlockSpec((G, tile, d), lambda p, t, f: (0, last(t, f), 0)),
+                # the values wait at their first tile through phase 0
+                pl.BlockSpec((G, dv, tile),
+                             lambda p, t, f: (0, 0, last(t, f) * p)),
+                pl.BlockSpec((n, tile), lambda p, t, f: (0, last(t, f)))],
+            out_specs=[
+                pl.BlockSpec((G, dv, N), lambda p, t, f: (0, 0, 0)),
+                pl.BlockSpec((G, N), lambda p, t, f: (0, 0)),
+                pl.BlockSpec((n, tile), lambda p, t, f: (0, t * p))],
+            scratch_shapes=[pltpu.VMEM(shape, dt) for shape, dt in scratch]),
+        compiler_params=_grouped_params(blocks, scratch, how.rows, N, 2),
+        interpret=how.interpret,
+    )(first, q, k, vt, chosen)
+
+
+def _grouped_backward(first, q, k, v, chosen, dot, lse, delta, how: _How):
+    import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    G, N, d = q.shape
+    n, S, dv = chosen.shape[0], k.shape[1], v.shape[2]
+    tile = how.tile
+    last = _last_seen(n, tile)
+    blocks, scratch = _grouped_blocks(G, N // n, n, d, dv, tile,
+                                      q.dtype)["dsa_attend_gqa_bwd"]
+    whole3 = pl.BlockSpec((G, N, d), lambda t, f: (0, 0, 0))
+    stat = pl.BlockSpec((G, N), lambda t, f: (0, 0))
+    return pl.pallas_call(
+        functools.partial(_grouped_bwd_kernel, scale=how.scale,
+                          rows=how.rows, unroll=how.unroll),
+        name="dsa_attend_gqa_bwd",
+        out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
+                   jax.ShapeDtypeStruct(k.shape, k.dtype),
+                   jax.ShapeDtypeStruct(v.shape, v.dtype)],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(S // tile,),
+            in_specs=[
+                whole3,
+                pl.BlockSpec((G, tile, d), lambda t, f: (0, last(t, f), 0)),
+                pl.BlockSpec((G, tile, dv), lambda t, f: (0, last(t, f), 0)),
+                pl.BlockSpec((n, tile), lambda t, f: (0, last(t, f))),
+                pl.BlockSpec((G, dv, N), lambda t, f: (0, 0, 0)),
+                stat, stat],
+            out_specs=[
+                whole3,
+                pl.BlockSpec((G, tile, d), lambda t, f: (0, t, 0)),
+                pl.BlockSpec((G, tile, dv), lambda t, f: (0, t, 0))],
+            scratch_shapes=[pltpu.VMEM(shape, dt) for shape, dt in scratch]),
+        compiler_params=_grouped_params(blocks, scratch, how.rows, N, 1),
+        interpret=how.interpret,
+    )(first, q, k, v, chosen, dot, lse, delta)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
+def _grouped_calls(first, q, k, v, vt, chosen, how):
+    out_t, _, ps = _grouped_forward(first, q, k, vt, chosen, how)
+    return out_t, ps
+
+
+def _grouped_calls_fwd(first, q, k, v, vt, chosen, how):
+    out_t, lse, ps = _grouped_forward(first, q, k, vt, chosen, how)
+    return (out_t, ps), (first, q, k, v, chosen, out_t, lse)
+
+
+def _grouped_calls_bwd(how, res, g):
+    # as ``_attend_calls_bwd``: ``ps`` is a target, ``vt`` is ``v`` turned
+    *ins, out_t, lse = res
+    dot = g[0]
+    delta = (dot.astype(jnp.float32) * out_t.astype(jnp.float32)).sum(1)
+    dq, dk, dv = _grouped_backward(*ins, dot, lse, delta, how)
+    return None, dq, dk, dv, None, None
+
+
+_grouped_calls.defvjp(_grouped_calls_fwd, _grouped_calls_bwd)
+
+
+def attend_kernels_grouped(q, k, v, chosen, first, scale: float, tile: int,
+                           v_t=None, interpret: bool = False):
+    """``attend_kernels`` under grouped keys, two Mosaic calls
+    ``dsa_attend_gqa_fwd`` and ``dsa_attend_gqa_bwd`` behind a
+    ``custom_vjp``: q [G, R x n, d] (a group's R heads one after another,
+    ``n`` queries each), k [G, S, d], v [G, S, d_v], chosen [n, S] (bool or
+    int8) -> (out turned [G, d_v, R x n], ``sum_h p`` [n, S] float32 over
+    all ``G x R`` heads). ``v_t``, ``first`` and the arithmetic are
+    ``attend_kernels``'; the keys' and values' gradients are the group's,
+    summed over its heads in the call."""
+    first = jnp.asarray(k.shape[1] if first is None else first,
+                        jnp.int32).reshape(1)
+    if v_t is None:
+        v_t = jnp.swapaxes(v, 1, 2)
+    return _grouped_calls(
+        first, q, k, v, jax.lax.stop_gradient(v_t), chosen.astype(jnp.int8),
+        _How(float(scale), tile, min(ATTEND_ROWS, tile), ATTEND_UNROLL,
+             interpret))
+
+
 def choose(scores: jax.Array, first_q, topk: int) -> jax.Array:
     """scores [n, S] float32 of the queries at positions ``first_q + 0 ..
     n - 1`` over the keys at ``0 .. S - 1`` -> bool [n, S]: for each query
@@ -899,7 +1210,8 @@ def kl_target(p: jax.Array) -> jax.Array:
 class Widths(NamedTuple):
     """What a position is to the walk's four calls: ``heads`` of keys
     ``d_n | d_r`` and values ``d_v``, ``index_heads`` of ``index_dim``,
-    all of ``dtype``."""
+    all of ``dtype``; ``groups``: under grouped keys the key/value heads
+    that serve the ``heads`` (``d_r`` is then 0), None per head."""
     heads: int
     d_n: int
     d_r: int
@@ -907,13 +1219,15 @@ class Widths(NamedTuple):
     index_heads: int
     index_dim: int
     dtype: Any
+    groups: Optional[int] = None
 
     @classmethod
-    def of(cls, q, k_n, v, q_i) -> "Widths":
+    def of(cls, q, k_n, v, q_i, grouped: bool = False) -> "Widths":
         """From the walk's arrays [.., s, heads, width]."""
         dn = k_n.shape[-1]
         return cls(q.shape[-2], dn, q.shape[-1] - dn, v.shape[-1],
-                   q_i.shape[-2], q_i.shape[-1], q.dtype)
+                   q_i.shape[-2], q_i.shape[-1], q.dtype,
+                   k_n.shape[-2] if grouped else None)
 
 
 def walk_needs(block: int, keys: int, widths: Widths) -> Dict[str, int]:
@@ -921,13 +1235,18 @@ def walk_needs(block: int, keys: int, widths: Widths) -> Dict[str, int]:
     against a tier of ``keys`` keys holds, by the call's name: the blocks
     and scratch the calls are built from (``_score_needs``,
     ``_attend_blocks``). A form that is XLA's has no call and no entry."""
-    H, dn, dr, dv, J, di, dtype = widths
+    H, dn, dr, dv, J, di, dtype, G = widths
     needs = {}
     tile = scores_plan(block, keys, J, di)["scores_tile"]
     if tile:
         needs.update(_score_needs(J, block, di, tile, dtype))
     tile = attend_plan(block, keys, dn, dv)["attend_tile"]
-    if tile:
+    if tile and G:
+        rows, N = min(ATTEND_ROWS, tile), H // G * block
+        needs.update({name: _grouped_need(*at, rows, N)
+                      for name, at in _grouped_blocks(
+                          G, H // G, block, dn, dv, tile, dtype).items()})
+    elif tile:
         needs.update({name: _need(*at) for name, at in _attend_blocks(
             H, block, dn, dr, dv, tile, dtype).items()})
     return needs
@@ -958,12 +1277,14 @@ def walk_plan(seq: int, block: int, tiers: int,
 def _walk(q, k_n, v, k_r, q_i, k_i, w, *, scale: float, topk: int,
           block: int, tiers: int, keep_choice: bool):
     """One sequence: q [s, H, d_n + d_r], k_n [s, H, d_n], v [s, H, d_v],
-    k_r [s, d_r], q_i [s, J, d_i], k_i [s, d_i], w [s, J] -> (o [s, H,
-    d_v], the sequence's sum of KL terms, pairs chosen, and under
+    k_r [s, d_r], or under grouped keys q [s, H, d], k_n [s, G, d], v [s,
+    G, d_v] and ``k_r`` None; q_i [s, J, d_i], k_i [s, d_i], w [s, J] -> (o
+    [s, H, d_v], the sequence's sum of KL terms, pairs chosen, and under
     ``keep_choice`` the choice packed eight keys a byte [s, s / 8]).
     ``block`` and ``tiers`` are ``walk_plan``'s."""
     s, H, _ = q.shape
-    dn = k_n.shape[-1]
+    dn, G = k_n.shape[-1], k_n.shape[-2]
+    grouped = k_r is None
     per_tier = s // block // tiers
     ends = [(g + 1) * per_tier * block for g in range(tiers)]
     plan = attend_plan(block, s // tiers, dn, v.shape[-1])
@@ -983,18 +1304,27 @@ def _walk(q, k_n, v, k_r, q_i, k_i, w, *, scale: float, topk: int,
             v_turned = jnp.swapaxes(v, 1, 2)
         q_t = by_block(q, at)
         keys = [(jax.lax.slice_in_dim(k_n, 0, end, axis=at),
-                 jax.lax.slice_in_dim(v, 0, end, axis=at), k_r[:end])
+                 jax.lax.slice_in_dim(v, 0, end, axis=at),
+                 None if grouped else k_r[:end])
                 + ((v_turned[..., :end],) if at else ()) for end in ends]
 
     def attend(q_b, chosen, first, kn_t, v_t, kr_t, *turned):
         """-> (out, p): the heads' probabilities [H, block, S'] (XLA's
         form), or their sum over the heads [1, block, S'] (the
         kernels')."""
+        if not at and grouped:
+            return plain_attend_grouped(q_b, kn_t, v_t, chosen, scale)
         if not at:
             return plain_attend(q_b, kn_t, v_t, kr_t, chosen, scale)
-        # looked up at trace time: a test hands it the interpreter
-        out, p_sum = attend_kernels(q_b, kn_t, v_t, kr_t, chosen, first,
-                                    scale, plan["attend_tile"], *turned)
+        # looked up at trace time: a test hands them the interpreter
+        if grouped:
+            # [H, block, d] -> [G, R x block, d]: a group's heads in a row
+            out, p_sum = attend_kernels_grouped(
+                q_b.reshape(G, -1, q_b.shape[-1]), kn_t, v_t, chosen, first,
+                scale, plan["attend_tile"], *turned)
+        else:
+            out, p_sum = attend_kernels(q_b, kn_t, v_t, kr_t, chosen, first,
+                                        scale, plan["attend_tile"], *turned)
         return out, p_sum[None]
 
     def one_block(keys, ki_t, args):
@@ -1035,9 +1365,14 @@ def _walk(q, k_n, v, k_r, q_i, k_i, w, *, scale: float, topk: int,
     out, kl, pairs, *choice = (
         jnp.concatenate(xs) for xs in zip(*parts))
     if at:
-        # [blocks, H, d_v, block], as the kernels leave it
+        # [blocks, H, d_v, block], as the kernels leave it; under grouped
+        # keys [blocks, G, d_v, R x block]
         with jax.named_scope("flash_sparse"):
-            out = jnp.transpose(out, (0, 3, 1, 2))
+            if grouped:
+                out = out.reshape(out.shape[:3] + (H // G, block))
+                out = jnp.transpose(out, (0, 4, 1, 3, 2))
+            else:
+                out = jnp.transpose(out, (0, 3, 1, 2))
     return (out.reshape(s, H, -1), kl.sum(), pairs.sum(),
             *(c.reshape(s, -1) for c in choice))
 
@@ -1048,7 +1383,9 @@ def sparse_attention(q, k_n, v, k_r, q_i, k_i, w, *, scale: float,
     """Attention of q [b, s, H, d_n + d_r] over the keys the index chooses
     for each position (the module's docstring): keys ``[k_n | k_r]`` (k_n
     [b, s, H, d_n], k_r [b, s, d_r] shared by the heads), values v [b, s,
-    H, d_v]; the index's queries q_i [b, s, J, d_i], keys k_i [b, s, d_i]
+    H, d_v]; or, ``k_r`` None, grouped keys k_n [b, s, G, d] and values v
+    [b, s, G, d_v], each serving ``H / G`` query heads of q [b, s, H, d];
+    the index's queries q_i [b, s, J, d_i], keys k_i [b, s, d_i]
     and head weights w [b, s, J] float32. -> (o [b, s, H, d_v]; ``kl [b]``,
     each sequence's sum over its positions of ``KL(p_t || softmax_{S_t}
     I)``; ``pairs [b]`` int32, the pairs chosen; under ``keep_choice`` the
@@ -1056,9 +1393,15 @@ def sparse_attention(q, k_n, v, k_r, q_i, k_i, w, *, scale: float,
     the bit ``7 - j`` of byte ``i``). Under a mesh each chip walks its own
     rows of the batch, as ``mla._attend`` does."""
     b, s, H, _ = q.shape
-    widths = Widths.of(q, k_n, v, q_i)
+    grouped = k_r is None
+    if grouped and H % k_n.shape[2]:
+        raise ValueError(f"{k_n.shape[2]} key/value heads do not divide "
+                         f"{H} query heads")
+    widths = Widths.of(q, k_n, v, q_i, grouped)
     blk, trs = walk_plan(s, block, tiers, widths)
     with tracing.span("rtpu.dsa.shapes", keep=True,
+                      attend_layout="grouped" if grouped else "per_head",
+                      kv_groups=k_n.shape[2],
                       index_heads=q_i.shape[2], index_head_dim=q_i.shape[3],
                       topk=topk, positions=s, block=blk, tiers=trs,
                       # the block before the guard, and what it was held to
@@ -1075,11 +1418,13 @@ def sparse_attention(q, k_n, v, k_r, q_i, k_i, w, *, scale: float,
         pass
 
     def rows(*a):
+        if grouped:
+            a = a[:3] + (None,) + a[3:]
         return jax.vmap(lambda *r: _walk(
             *r, scale=scale, topk=topk, block=blk, tiers=trs,
             keep_choice=keep_choice))(*a)
 
-    args = (q, k_n, v, k_r, q_i, k_i, w)
+    args = tuple(x for x in (q, k_n, v, k_r, q_i, k_i, w) if x is not None)
     if mesh is None:
         return rows(*args)
     from jax.sharding import PartitionSpec as P
@@ -1090,6 +1435,89 @@ def sparse_attention(q, k_n, v, k_r, q_i, k_i, w, *, scale: float,
     return jax.shard_map(
         rows, mesh=mesh, in_specs=(by_row,) * len(args),
         out_specs=(by_row,) * (3 + keep_choice), check_vma=False)(*args)
+
+
+# ---- the index as a part of a layer: what ``ops/mla.
+# latent_attention_part(index=True)`` and ``models/llama.attention_part(
+# index=True)`` share. The query side reads whatever the layer has: the
+# normed query latent ``c_q`` (latent attention) or the layer's normed input
+# ``u`` itself (a layer without a query latent).
+
+INDEX_LEAVES = ("wi_q", "wi_k", "wi_k_norm", "wi_k_bias", "wi_w")
+
+
+def index_leaves(cfg, query_width: int) -> Dict[str, Any]:
+    """The index's five leaves: ``wi_q [query_width, J d_i]`` from the
+    width its queries are read at, ``wi_k [hidden, d_i]``, the key's
+    LayerNorm and ``wi_w [hidden, J]``. Not divided under a mesh."""
+    from ray_tpu.ops.layers import Leaf
+
+    h, J, di = cfg.hidden_size, cfg.index_heads, cfg.index_head_dim
+    return {"wi_q": Leaf((query_width, J * di), query_width, (None, None)),
+            "wi_k": Leaf((h, di), h, ("embed", None)),
+            "wi_k_norm": Leaf((di,), "ones", (None,)),
+            "wi_k_bias": Leaf((di,), "zeros", (None,)),
+            "wi_w": Leaf((h, J), h, ("embed", None))}
+
+
+def index_inputs(cfg, u, q_from, p, rotate, key_norm):
+    """The index's queries [b, s, J, d_i], keys [b, s, d_i] and head
+    weights [b, s, J] float32: ``q_i = q_from W_iq``, ``k_i = key_norm(u
+    W_ik)`` (a LayerNorm with weight and bias at ``cfg.index_norm_eps``),
+    both through ``rotate`` (the layer's own rope over the dims it rotates,
+    of x [b, s, J, d_i] and of the one key a position [b, s, d_i]), ``w =
+    (u W_iw) J ** -0.5 d_i ** -0.5``.
+    Neither ``u`` nor ``q_from`` receives a gradient from here."""
+    dt = cfg.dtype
+    b, s, _ = u.shape
+    J, di = cfg.index_heads, cfg.index_head_dim
+    u, q_from = jax.lax.stop_gradient(u), jax.lax.stop_gradient(q_from)
+
+    def dot(a, w):
+        return jnp.dot(a, w.astype(dt), preferred_element_type=jnp.float32)
+
+    q_i = dot(q_from, p["wi_q"]).astype(dt).reshape(b, s, J, di)
+    k_i = key_norm(dot(u, p["wi_k"]).astype(dt), p["wi_k_norm"],
+                   p["wi_k_bias"], cfg.index_norm_eps)
+    return (rotate(q_i), rotate(k_i),
+            dot(u, p["wi_w"]) * (J ** -0.5 * di ** -0.5))
+
+
+def index_report(b: int, s: int, kl, pairs, kept=None) -> Dict[str, Any]:
+    """What an index layer reports under "dsa": its ``kl`` and ``pairs``
+    [b], the causal pairs and positions they are shares of, and ``kept``
+    (asked for: the choice and the index's inputs)."""
+    return {"dsa": {"kl": kl, "pairs": pairs,
+                    "causal": jnp.asarray(b * s * (s + 1) // 2, jnp.float32),
+                    "positions": jnp.asarray(b * s, jnp.float32),
+                    **(kept or {})}}
+
+
+def index_terms(cfg, said):
+    """``Part.terms`` of a stack's index layers: ``said``'s ``kl`` and
+    ``pairs`` [Lf, b], ``causal`` and ``positions`` [Lf] -> (the term the
+    loss gains, the step's counters)."""
+    loss = (said["kl"].sum(-1) / said["positions"]).sum()
+    share = said["pairs"].sum() / said["causal"].sum()
+    return cfg.index_loss_coef * loss, {"dsa_index_loss": loss,
+                                        "dsa_pairs_chosen_share": share}
+
+
+def walk_rows(cfg, tokens: int, widths: Widths) -> int:
+    """Bytes of one block of the walk while its backward runs, as each
+    form holds it in HBM, float32 (a part's ``keeps``: ``rows``). The
+    index's scores: XLA's products [block, J, s] and their gradient, the
+    kernels' [block, s] and its. The attention: XLA's heads' scores and
+    probabilities [H, block, s] twice, the kernels' summed [block, s] and
+    the target."""
+    blk, trs = walk_plan(tokens, cfg.index_block, cfg.index_tiers, widths)
+    scores = scores_plan(blk, tokens // trs, widths.index_heads,
+                         widths.index_dim)["scores_form"]
+    attend = attend_plan(blk, tokens // trs, widths.d_n,
+                         widths.d_v)["attend_form"]
+    return blk * tokens * 4 * (
+        (3 * widths.index_heads if scores == "xla" else 2)
+        + (4 * widths.heads if attend == "xla" else 2))
 
 
 def unpack_choice(packed, s: Optional[int] = None):

@@ -1,7 +1,7 @@
 """Transformer building blocks: RMSNorm (plain and gated a head at a time), an
-L2 norm, RoPE, SwiGLU, a squared-ReLU MLP, a head and loss over blocks of
-tokens; and ``Part``, the record a layer's mixer or MLP is to
-``models/stack.py``.
+L2 norm, a LayerNorm, RoPE (one position stream or several), SwiGLU, a
+squared-ReLU MLP, a head and loss over blocks of tokens; and ``Part``, the
+record a layer's mixer or MLP is to ``models/stack.py``.
 
 Pure-jax implementations — XLA fuses these elementwise chains into the
 surrounding matmuls on TPU (the guide's rule: don't hand-schedule what the
@@ -105,6 +105,16 @@ def norm_start(cfg) -> str:
     """How a norm's weight starts (``Leaf.start``): "zeros" where the
     config's norms are zero-centred, "ones" elsewhere."""
     return "zeros" if cfg.zero_centred_norm else "ones"
+
+
+def layer_norm(x: jax.Array, weight: jax.Array, bias: jax.Array,
+               eps: float = 1e-5) -> jax.Array:
+    """LayerNorm over the last axis in float32 (an index key's)."""
+    xf = x.astype(jnp.float32)
+    xf = xf - xf.mean(-1, keepdims=True)
+    xf = xf * jax.lax.rsqrt(jnp.square(xf).mean(-1, keepdims=True) + eps)
+    return (xf * weight.astype(jnp.float32)
+            + bias.astype(jnp.float32)).astype(x.dtype)
 
 
 def l2_norm(x: jax.Array, eps: float = 1e-6, scale: float = 1.0) -> jax.Array:
@@ -229,11 +239,48 @@ def rope_frequencies(head_dim: int, max_seq_len: int, theta: float = 10_000.0,
             (jnp.sin(freqs) * attention_factor).astype(dtype))
 
 
+def mrope_frequencies(head_dim: int, positions: jax.Array,
+                      sections: Tuple[int, ...], theta: float = 10_000.0,
+                      dtype=jnp.float32) -> Tuple[jax.Array, jax.Array]:
+    """RoPE tables from the batch's own positions in several streams
+    (Qwen2-VL's multimodal rope, chunked): positions ``[streams, b, s]``
+    (temporal, height, width), ``sections`` the count of the ``head_dim //
+    2`` frequency pairs each stream turns, in order (pair ``i`` of [16, 24,
+    24] turns by stream 0 for ``i < 16``, by stream 1 for ``16 <= i < 40``,
+    by stream 2 beyond). -> (cos, sin) ``[b, s, head_dim // 2]``, which
+    ``apply_rope`` takes as they are. Where the streams are all ``0 .. s -
+    1`` (text) the tables are ``rope_frequencies``' bit for bit. The scope
+    ``mrope``; one kept span as it is traced, ``rtpu.mrope.plan``."""
+    half = head_dim // 2
+    if sum(sections) != half or len(sections) != positions.shape[0]:
+        raise ValueError(
+            f"sections {tuple(sections)} do not split the {half} frequency "
+            f"pairs of a head of {head_dim} over {positions.shape[0]} "
+            "position streams")
+    with tracing.span("rtpu.mrope.plan", keep=True, streams=len(sections),
+                      sections=list(sections), head_dim=head_dim,
+                      theta=theta, table_shape=list(positions.shape[1:])
+                      + [half]):
+        pass
+    with jax.named_scope("mrope"):
+        inv_freq = 1.0 / (
+            theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32)
+                      / head_dim))
+        stream = [n for n, pairs in enumerate(sections) for _ in range(pairs)]
+        # [b, s, half]: pair i's position is its stream's
+        pos = jnp.moveaxis(positions.astype(jnp.float32), 0, -1)[
+            ..., jnp.asarray(stream)]
+        freqs = pos * inv_freq
+        return jnp.cos(freqs).astype(dtype), jnp.sin(freqs).astype(dtype)
+
+
 def apply_rope(x: jax.Array, cos: jax.Array, sin: jax.Array,
                positions: Optional[jax.Array] = None) -> jax.Array:
     """Apply rotary embeddings.
 
-    x: [..., seq, heads, head_dim]; cos/sin: [max_seq, rotated // 2];
+    x: [..., seq, heads, head_dim]; cos/sin: [max_seq, rotated // 2], or
+    ``mrope_frequencies``' [batch, seq, rotated // 2], a table row for each
+    position of the batch already;
     positions: [..., seq] absolute positions (defaults to arange).
     The first ``2 * cos.shape[-1]`` dims of a head are rotated (as two
     halves) and the rest pass: a partial rotary factor is the width the
@@ -245,7 +292,10 @@ def apply_rope(x: jax.Array, cos: jax.Array, sin: jax.Array,
             [apply_rope(x[..., :rotated], cos, sin, positions),
              x[..., rotated:]], axis=-1)
     seq = x.shape[-3]
-    if positions is None:
+    if cos.ndim == 3:
+        c = cos[:, :, None, :]
+        s = sin[:, :, None, :]
+    elif positions is None:
         c = cos[:seq][:, None, :]
         s = sin[:seq][:, None, :]
     else:

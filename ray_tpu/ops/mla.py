@@ -50,8 +50,12 @@ whose traced program they leave as it was:
   it the scores' spread at the start is 6 where it should be 1 (measured:
   the softmax so sharp that bf16 rounding reads 10% at the last layer);
 - ``index``: a learned index chooses ``cfg.index_topk`` keys a query and
-  the layer attends over those (``ops/dsa.py``): ``q_i = c_q W_iq``
-  ``[index_heads, index_head_dim]`` from the normed query latent, ``k_i =
+  the layer attends over those (``ops/dsa.py``, whose ``index_leaves``,
+  ``index_inputs``, ``index_report`` and ``index_terms`` this part shares
+  with ``models/llama.attention_part(index=True)``; here the keys are
+  per head with one shared rope key, there grouped): ``q_i = c_q W_iq``
+  ``[index_heads, index_head_dim]`` from the normed query latent (a layer
+  without one reads its normed input ``u``), ``k_i =
   LayerNorm(u W_ik)`` one key a position, the first ``d_r`` dims of both
   rotated with the layer's tables, ``w = (u W_iw) * index_heads ** -0.5 *
   index_head_dim ** -0.5`` in float32. ``u`` and ``c_q`` reach the index
@@ -85,8 +89,8 @@ from jax.ad_checkpoint import checkpoint_name
 from ray_tpu.ops import dsa
 from ray_tpu.ops.attention import (attention_reference, flash_attention,
                                    with_shared_key)
-from ray_tpu.ops.layers import (Leaf, Part, apply_rope, kept, rms_norm,
-                                rope_frequencies)
+from ray_tpu.ops.layers import (Leaf, Part, apply_rope, kept, layer_norm,
+                                rms_norm, rope_frequencies)
 from ray_tpu.util import tracing
 
 
@@ -140,16 +144,6 @@ def _rotate(x: jax.Array, cos, sin) -> jax.Array:
                       cos, sin)
 
 
-def layer_norm(x: jax.Array, weight: jax.Array, bias: jax.Array,
-               eps: float = 1e-5) -> jax.Array:
-    """LayerNorm over the last axis in float32 (the index key's)."""
-    xf = x.astype(jnp.float32)
-    xf = xf - xf.mean(-1, keepdims=True)
-    xf = xf * jax.lax.rsqrt(jnp.square(xf).mean(-1, keepdims=True) + eps)
-    return (xf * weight.astype(jnp.float32)
-            + bias.astype(jnp.float32)).astype(x.dtype)
-
-
 def rescale_factor(cfg, rank: int) -> float:
     """``(hidden / rank) ** 0.5``: what ``rescale`` multiplies the queries
     and the normed kv latent by."""
@@ -163,38 +157,25 @@ def head_gate(cfg, u: jax.Array, wg: jax.Array) -> jax.Array:
 
 
 def index_inputs(cfg, u, c_q, p, cos, sin):
-    """The index's queries [b, s, J, d_i], keys [b, s, d_i] and head
-    weights [b, s, J] float32 from the layer's normed input ``u`` and its
-    normed query latent ``c_q`` (the module's docstring). Neither input
-    receives a gradient from here."""
-    dt = cfg.dtype
-    b, s, _ = u.shape
-    J, di = cfg.index_heads, cfg.index_head_dim
-    u, c_q = jax.lax.stop_gradient(u), jax.lax.stop_gradient(c_q)
-
-    def dot(a, w):
-        return jnp.dot(a, w.astype(dt), preferred_element_type=jnp.float32)
-
-    q_i = dot(c_q, p["wi_q"]).astype(dt).reshape(b, s, J, di)
-    k_i = layer_norm(dot(u, p["wi_k"]).astype(dt), p["wi_k_norm"],
-                     p["wi_k_bias"], cfg.index_norm_eps)
+    """``dsa.index_inputs`` of a latent layer: the queries from the normed
+    query latent ``c_q``, the first ``d_r`` dims of the queries and of the
+    key de-interleaved and rotated with the layer's tables."""
     dr = 2 * cos.shape[-1]
-    q_i = jnp.concatenate(
-        [_rotate(q_i[..., :dr], cos, sin), q_i[..., dr:]], axis=-1)
-    k_i = jnp.concatenate(
-        [_rotate(k_i[:, :, None, :dr], cos, sin)[:, :, 0], k_i[..., dr:]],
-        axis=-1)
-    return q_i, k_i, dot(u, p["wi_w"]) * (J ** -0.5 * di ** -0.5)
+
+    def rotate(x):
+        if x.ndim == 3:                       # the one key a position
+            return jnp.concatenate(
+                [_rotate(x[:, :, None, :dr], cos, sin)[:, :, 0], x[..., dr:]],
+                axis=-1)
+        return jnp.concatenate(
+            [_rotate(x[..., :dr], cos, sin), x[..., dr:]], axis=-1)
+
+    # ``layer_norm`` is looked up here as the layer is traced: a control of
+    # benchmark/tests/sparse_limits.py replaces it
+    return dsa.index_inputs(cfg, u, c_q, p, rotate, layer_norm)
 
 
-def index_terms(cfg, said):
-    """``Part.terms`` of a stack's index layers: ``said``'s ``kl`` and
-    ``pairs`` [Lf, b], ``causal`` and ``positions`` [Lf] -> (the term the
-    loss gains, the step's counters)."""
-    loss = (said["kl"].sum(-1) / said["positions"]).sum()
-    share = said["pairs"].sum() / said["causal"].sum()
-    return cfg.index_loss_coef * loss, {"dsa_index_loss": loss,
-                                        "dsa_pairs_chosen_share": share}
+index_terms = dsa.index_terms
 
 
 def latent_attention_part(prefix: str = "", rope: Callable = rope_tables,
@@ -224,12 +205,7 @@ def latent_attention_part(prefix: str = "", rope: Callable = rope_tables,
         if gate:
             out["wg"] = Leaf((h, H), h, ("embed", None))
         if index:
-            J, di = cfg.index_heads, cfg.index_head_dim
-            out.update(wi_q=Leaf((rq, J * di), rq, (None, None)),
-                       wi_k=Leaf((h, di), h, ("embed", None)),
-                       wi_k_norm=Leaf((di,), "ones", (None,)),
-                       wi_k_bias=Leaf((di,), "zeros", (None,)),
-                       wi_w=Leaf((h, J), h, ("embed", None)))
+            out.update(dsa.index_leaves(cfg, rq))
         return out
 
     def body(cfg, x, p, ctx):
@@ -294,12 +270,9 @@ def latent_attention_part(prefix: str = "", rope: Callable = rope_tables,
                 q, k_n, v, k_r, q_i, k_i, w_i, scale=scale,
                 topk=cfg.index_topk, block=cfg.index_block,
                 tiers=cfg.index_tiers, mesh=ctx.mesh, keep_choice=keep)
-            said = {"dsa": {
-                "kl": kl, "pairs": pairs,
-                "causal": jnp.asarray(b * s * (s + 1) // 2, jnp.float32),
-                "positions": jnp.asarray(b * s, jnp.float32),
-                **({"choice": choice[0], "q_i": q_i, "k_i": k_i,
-                    "w": w_i} if keep else {})}}
+            said = dsa.index_report(b, s, kl, pairs, {
+                "choice": choice[0], "q_i": q_i, "k_i": k_i,
+                "w": w_i} if keep else None)
         else:
             with jax.named_scope("flash"):
                 if window:
@@ -328,22 +301,9 @@ def latent_attention_part(prefix: str = "", rope: Callable = rope_tables,
         beside = ((shape["wg"][-1] if gate else 0)
                   + (shape["wi_q"][-1] + shape["wi_k"][-1]
                      + 2 * shape["wi_w"][-1] if index else 0))
-        rows = 0
-        if index:
-            # one block of the walk while its backward runs, as each form
-            # holds it in HBM, float32. The index's scores: XLA's products
-            # [block, J, s] and their gradient, the kernels' [block, s] and
-            # its. The attention: XLA's heads' scores and probabilities [H,
-            # block, s] twice, the kernels' summed [block, s] and the target
-            J, di = shape["wi_w"][-1], shape["wi_k"][-1]
-            blk, trs = dsa.walk_plan(
-                tokens, cfg.index_block, cfg.index_tiers,
-                dsa.Widths(H, sz.d_n, dr, dv, J, di, cfg.dtype))
-            scores = dsa.scores_plan(blk, tokens // trs, J, di)["scores_form"]
-            attend = dsa.attend_plan(blk, tokens // trs, sz.d_n,
-                                     dv)["attend_form"]
-            rows = blk * tokens * 4 * ((3 * J if scores == "xla" else 2)
-                                       + (4 * H if attend == "xla" else 2))
+        rows = dsa.walk_rows(cfg, tokens, dsa.Widths(
+            H, sz.d_n, dr, dv, shape["wi_w"][-1], shape["wi_k"][-1],
+            cfg.dtype)) if index else 0
         return kept(
             flash=tokens * (shape["wo"][0] * act + H * 4 + latents * act)
             if not index else tokens * latents * act,

@@ -141,6 +141,10 @@ def _compile_cell(tree: str, cell: dict, topo) -> dict:
         sharding=bsh)}
 
     runner = import_module("benchmark.cells." + tr["family"])
+    if hasattr(runner, "batch_shapes"):   # a batch of more than its ids
+        batch = {name: jax.ShapeDtypeStruct(
+            shape, getattr(jnp, dtype), sharding=bsh)
+            for name, (shape, dtype) in runner.batch_shapes(tr).items()}
     if hasattr(runner, "make_step"):    # the cell's own step, as it stands
         step = runner.make_step(mod, cfg, tx, mesh)
     elif moe:

@@ -42,7 +42,9 @@ class Row:
     tokens: Tuple[int, Tuple[int, int], Any] = (1, (2, 33), np.int64)
     ahead: int = 1                  # ids a row carries past its positions
     also_moved: Optional[Callable] = None      # (params) -> params
-    forward: Optional[Callable] = None         # (mod, cfg, p, t): not forward
+    # (mod, cfg, p, t, **what ``batch`` brings): not ``forward``
+    forward: Optional[Callable] = None
+    batch: Optional[Callable] = None   # (case) -> a batch's further entries
     # what the reference is told to choose, from what the program said,
     # and which of its functions take it: "logits", "nll", "weighted" (a
     # row's own ``want_terms`` and ``gradients`` read ``case.forced``)
@@ -179,6 +181,93 @@ def _nemotron_h_says(cfg, params):
 
 
 # ---- where a reference takes or hands back something of its own
+
+
+def _keye_vl2_says(cfg, params):
+    assert cfg.pattern == ("sparse_moe",) * 3
+    layer = params["layers"]["sparse_moe"]
+    assert layer["wq"].shape == (3, 64, 4 * 16)
+    assert layer["wk"].shape == (3, 64, 2 * 16)           # grouped keys
+    assert layer["q_norm"].shape == (3, 16)               # a head's norm
+    assert layer["wi_q"].shape == (3, 64, 4 * 8)          # from the input
+    assert layer["e_gate"].shape[1] == (4 if cfg.experts_held else 16)
+    assert "s_gate" not in layer and "router_bias" not in layer
+    assert params["lm_head"].shape == (64, 256)           # untied
+
+
+# a document of text runs and image spans at a tiny size, as the cell's
+# generator lays them out
+KEYE_TRAFFIC = {"text_run": [2, 6], "grids": [[2, 2], [2, 3], [3, 3]],
+                "image_share": 0.5}
+
+
+def keye_vl2_batch(case):
+    """``positions [3, 2, 48]`` in three streams and the text ``mask [2,
+    49]`` of two seeded interleaved documents."""
+    from benchmark.generators import train_batches_mrope as gen
+
+    rng = np.random.default_rng(11)
+    docs = [gen.document(rng, case.tokens.shape[1], KEYE_TRAFFIC)
+            for _ in case.tokens]
+    positions = np.stack([d["positions"][:, :-1] for d in docs], 1)
+    assert (positions[0] != positions[2]).any()          # streams differ
+    # (numpy: a case may make its batch while a program is being traced)
+    return {"positions": positions,
+            "mask": np.stack([1.0 - d["image"] for d in docs]
+                             ).astype(np.float32)}
+
+
+def _keye_vl2_forward(mod, cfg, p, t, positions, mask):
+    return mod.forward_reports(cfg, p, t, positions)
+
+
+def _keye_vl2_forced(case):
+    said = case.program[1]
+    return {"positions": case.batch["positions"],
+            "forced_topk": jax.lax.top_k(said["router"]["logits"],
+                                         case.cfg.top_k)[1],
+            "forced_keys": said["dsa"]["choice"]}
+
+
+def _keye_vl2_reports(case):
+    assert case.program[1]["dsa"]["choice"].shape == (3, 2, 48, 6)
+
+
+def _keye_vl2_reference(case):
+    """((cross entropy over the text targets, index loss, balancing term),
+    every leaf's gradient of the whole loss) of the reference on the
+    program's choices, from one compiled function; kept on the case."""
+    if "reference" not in case.__dict__:
+        forced, mask = case.forced, case.batch["mask"]
+
+        def whole(p):
+            ce, l_i, bal = case.ref.loss_terms(case.cfg, p, case.tokens,
+                                               mask=mask, **forced)
+            return (ce + case.cfg.index_loss_coef * l_i
+                    + case.cfg.router_aux_coef * bal, (ce, l_i, bal))
+
+        (loss, terms), grads = jax.jit(
+            jax.value_and_grad(whole, has_aux=True))(case.params)
+        case.reference = (loss,) + terms, grads
+    return case.reference
+
+
+def _keye_vl2_want_terms(case):
+    loss, ce, l_i, bal = _keye_vl2_reference(case)[0]
+    return {"cross_entropy": ce, "dsa_index_loss": l_i, "load_balance": bal,
+            "loss": loss}
+
+
+def _keye_vl2_terms(case, loss, terms):
+    assert float(terms["dsa_index_loss"]) > 0.05
+    # 48 positions, 8 keys each past the first 8: (36 + 40 x 8) / 1176
+    np.testing.assert_allclose(terms["dsa_pairs_chosen_share"],
+                               (36 + 40 * 8) / (48 * 49 / 2), rtol=1e-6)
+    assert terms["expert_counts"].shape == (3, 16)
+
+
+def _keye_vl2_gradients(case):
+    return case._loss_and_gradient[1], _keye_vl2_reference(case)[1]
 
 
 def _dots3_forward(mod, cfg, p, t):
@@ -495,6 +584,67 @@ def _nemotron_h_cell(model):
 # ---- the plan's kinds
 
 
+def _keye_vl2_preset(keye_vl2):
+    """48 layers of one kind, 128 experts of 768 in each: 30.5 B parameters
+    in the language model, 3.3 B of them active a token."""
+    cfg = keye_vl2.KeyeVL2Config.keye_vl2_30b_a3b()
+    assert cfg.pattern == ("sparse_moe",) * 48
+    total = count(keye_vl2, cfg)
+    assert abs(total / 30.6e9 - 1) < 0.01
+    expert = 3 * 2048 * 768
+    assert abs((total - 48 * 120 * expert) / 3.4e9 - 1) < 0.02
+    # the cell's cut: six layers, 32 experts, a quarter of the vocabulary
+    cut = keye_vl2.KeyeVL2Config.keye_vl2_30b_a3b(
+        num_layers=6, experts_held=(0, 32), vocab_size=37_984)
+    assert count(keye_vl2, cut) == 1_189_966_080
+
+
+def _keye_vl2_rungs(kinds):
+    # an index layer keeps the walk's output on the first rung (no
+    # log-sum-exp: the blocks run again) and its q, k, v on the second
+    assert kinds["sparse_moe"]["rungs"][0] == 48 * 4 * 16 * 2
+    assert kinds["sparse_moe"]["rungs"][1] == 48 * (4 + 2 * 2) * 16 * 2
+
+
+def _keye_vl2_hand_counts():
+    """``benchmark/lib/sparse_gqa_flops.py`` on the cell's configuration
+    file, against counts written out by hand."""
+    from benchmark.lib import sparse_gqa_flops as sg
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "keye-vl-2.0-30b-a3b-c1.json")) as f:
+        m = json.load(f)
+    assert sg.layers(m) == 6
+    attn = 2048 * 4096 * 2 + 2048 * 512 * 2
+    index = 2048 * 1024 + 2048 * 64 + 2048 * 16
+    assert sg.attn_proj_params(m) == attn == 18_874_368
+    assert sg.index_proj_params(m) == index == 2_260_992
+    T = 16_384
+    assert sg.proj_flops_per_step(m, T) == T * 6 * (6 * attn + 4 * index)
+    chosen = 2048 * 2049 / 2 + (T - 2048) * 2048
+    assert sg.kept_pairs(T, 2048) == chosen == 31_458_304
+    assert sg.causal_pairs(T) == 134_225_920
+    # the index: 2,048 a causal pair forward, twice that a chosen pair back
+    assert sg.index_flops_per_step(m, 1, T) == 6 * 2 * 16 * 64 * (
+        T * (T + 1) / 2 + 2 * chosen)
+    # attention: chosen pairs x 32 heads x (8 x 128 + 6 x 128), six layers
+    assert sg.sparse_flash_flops_per_step(m, 1, T) == \
+        6 * 32 * (8 * 128 + 6 * 128) * chosen
+    # q and o at 32 heads, k and v once a group of 8: 4 heads' worth
+    assert sg.flash_bytes_per_step(m, T) == 6 * T * 2 * (
+        2 * (32 * 128 + 2 * 4 * 128) + 2 * 32 * 128)
+    assert sg.head_params(m) == 2048 * 37_984
+    assert sg.router_params(m) == 6 * 2048 * 128
+    rows = 6 * T * 8 / 4
+    assert sg.train_flops_per_step(m, 1, T, rows) == (
+        T * 6 * (6 * attn + 4 * index)
+        + 6 * (6 * 2048 * 128 + 2048 * 37_984) * T
+        + 6 * 3 * 2048 * 768 * rows
+        + 6 * 2 * 16 * 64 * (T * (T + 1) / 2 + 2 * chosen)
+        + 3 * 6 * 32 * 4 * 128 * chosen)
+
+
 def _dots3_rungs(kinds):
     # an index layer keeps its two latents alone on the first rung (the
     # walk has no flash output to keep); a window layer its flash output
@@ -771,6 +921,26 @@ ROWS = {row.name: row for row in (
               "runs": (("full_dense", 1), ("full_moe", 1),
                        ("sliding_moe", 2)), "rungs": _dots3_rungs},
         hand_counts=_dots3_hand_counts),
+    Row("keye_vl2", "KeyeVL2Config", tokens=(1, (2, 49), np.int64),
+        shares={"whole": {"experts_held": None},
+                "held-4..7": {"experts_held": (4, 4)}},
+        moved=(("attn_norm", 0.3), ("mlp_norm", 0.3), ("q_norm", 0.3),
+               ("k_norm", 0.3), ("wi_k_norm", 0.3), ("wi_k_bias", 0.3)),
+        says=_keye_vl2_says, groups={"top": 3, "sparse_moe": 17},
+        forward=_keye_vl2_forward, forced=_keye_vl2_forced,
+        forced_in=("logits",), batch=keye_vl2_batch,
+        logits_tol=(2e-4, 2e-4), reports_also=_keye_vl2_reports,
+        want_terms=_keye_vl2_want_terms,
+        term_tol={"": (1e-5, 0.0), "dsa_index_loss": (1e-4, 0.0)},
+        terms_also=_keye_vl2_terms,
+        gradients=_keye_vl2_gradients,
+        grad_tol=(2e-3, 2e-4, 0.0, 0.0),
+        expert_shares={"kind": "sparse_moe", "tiny": {}, "each": 4,
+                       "norm": _norm},
+        presets={"": _keye_vl2_preset},
+        plan={"tiny": {}, "tokens": 48, "runs": (("sparse_moe", 3),),
+              "rungs": _keye_vl2_rungs},
+        hand_counts=_keye_vl2_hand_counts),
     Row("qwen3_next", "Qwen3NextConfig",
         shares={"all-experts": {"experts_held": None},
                 "held-4..7": {"experts_held": (4, 4)}},

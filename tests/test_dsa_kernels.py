@@ -29,6 +29,8 @@ def kernels(monkeypatch):
         dsa.score_kernels, interpret=True))
     monkeypatch.setattr(dsa, "attend_kernels", functools.partial(
         dsa.attend_kernels, interpret=True))
+    monkeypatch.setattr(dsa, "attend_kernels_grouped", functools.partial(
+        dsa.attend_kernels_grouped, interpret=True))
 
     def constants(tile, rows, lanes=32, attend=None):
         monkeypatch.setattr(dsa, "SCORE_TILE", tile)
