@@ -53,8 +53,9 @@ REMAT_LADDER = (
     # the backward's second flash_fwd; of a latent-attention layer
     # (ops/mla.py) its two latents besides, a quarter of the rung's bytes
     # there: the backward then reruns the expansions from them and
-    # neither down-projection
-    ("flash_out", "flash_lse", "q_latent", "kv_latent"),
+    # neither down-projection; of an index layer (ops/dsa.py) its packed
+    # choice besides, and its backward runs no block's forward again
+    ("flash_out", "flash_lse", "q_latent", "kv_latent", "dsa_choice"),
     ("q_rope", "k_rope", "v_proj"),     # the q/k/v matmuls and rope
     ("mlp_gate", "mlp_up"),             # the gate and up matmuls, grouped too
     ("attn_resid",),                    # the wo matmul
@@ -704,14 +705,16 @@ def attention_part(heads: str = "num_heads", window: Optional[str] = None,
                 scale=(getattr(cfg, scale) if scale
                        else cfg.head_dim_ ** -0.5),
                 topk=cfg.index_topk, block=cfg.index_block,
-                tiers=cfg.index_tiers, mesh=ctx.mesh, keep_choice=keep)
+                tiers=cfg.index_tiers, mesh=ctx.mesh, keep_choice=keep,
+                # kept on the ladder's first rung (the walk's output, its
+                # log-sum-exp and its choice, ``dsa.KEPT_NAMES``), the
+                # layer's backward runs no block's forward again: the walk
+                # is most of the layer
+                named=True)
             said.update(dsa.index_report(b, s, kl, pairs, {
                 "choice": choice[0], "q_i": q_i, "k_i": k_i,
                 "w": w_i} if keep else None))
-            # kept on the ladder's first rung, the layer's backward walks
-            # the blocks once more (each block's own checkpoint) and not
-            # twice: the walk is most of the layer
-            return checkpoint_name(attn, "flash_out")
+            return attn
 
         return attend
 
@@ -733,19 +736,26 @@ def attention_part(heads: str = "num_heads", window: Optional[str] = None,
         qd, kvd = shape["wq"][-1] // (2 if wide else 1), shape["wk"][-1]
         act = jnp.dtype(cfg.dtype).itemsize
         if index:
-            # the walk's output is the first rung's (no log-sum-exp: its
-            # blocks are checkpointed and run again); the index's queries,
-            # key and head weights are recomputed at every level and held
-            # by the layer's backward, beside one block of the walk
-            # (``dsa.walk_rows``)
+            # what the walk keeps is the first rung's (its output, the
+            # log-sum-exp and the choice: ``dsa.kept_bytes``); the index's
+            # queries, key and head weights are recomputed at every level
+            # and held by the layer's backward, beside one block of the
+            # walk (``dsa.walk_rows``). The backward's forward forms q, k
+            # and v again and not the walk's output, which is the kept one
+            # (one ``qd`` a token where ``attention_block``'s count has
+            # two: compiled at level4 for a v5e the peak rose 33 MB over
+            # the checkpointed blocks' where the kept set is 138, PR 58)
             hd, J, di = cfg.head_dim_, shape["wi_w"][-1], shape["wi_k"][-1]
+            widths = dsa.Widths(qd // hd, hd, 0, hd, J, di, cfg.dtype,
+                                kvd // hd)
+            blk, trs = dsa.walk_plan(tokens, cfg.index_block,
+                                     cfg.index_tiers, widths)
             return kept(
-                flash=tokens * qd * act,
+                flash=dsa.kept_bytes(tokens, blk, trs, widths, True),
                 qkv=tokens * (qd + 2 * kvd) * act,
                 resid=tokens * cfg.hidden_size * act,
-                width=2 * qd + 2 * kvd + 2 * (J * di + di + 2 * J),
-                rows=dsa.walk_rows(cfg, tokens, dsa.Widths(
-                    qd // hd, hd, 0, hd, J, di, cfg.dtype, kvd // hd)))
+                width=qd + 2 * kvd + 2 * (J * di + di + 2 * J),
+                rows=dsa.walk_rows(cfg, tokens, widths))
         return kept(
             flash=tokens * (qd * act + qd // cfg.head_dim_ * 4),  # lse: f32
             qkv=tokens * (qd + 2 * kvd) * act,

@@ -20,8 +20,9 @@ L1-normalised, under ``stop_gradient``: the index learns to rank keys as
 the attention it stands in for weighs them, and nothing else receives that
 term's gradient.
 
-``sparse_attention`` walks blocks of at most ``block`` queries (``lax.map``
-over a ``jax.checkpoint``ed block; ``walk_plan`` fits the block to the
+``sparse_attention`` walks blocks of at most ``block`` queries (a
+``lax.map`` a tier forward and a scan a tier backward, under a rule of the
+walk's own, ``_walk``; ``walk_plan`` fits the block to the
 sequence and, on a TPU backend, to what the four calls below hold in VMEM:
 ``walk_needs`` under ``VMEM_CEILING``): a block's index scores ``[block,
 S]`` float32,
@@ -32,6 +33,21 @@ whole and neither does a gather of the chosen latents. The blocks are
 walked in ``tiers`` of equal length, a tier's blocks against the keys up
 to the tier's end: four tiers skip three eighths of the pairs the causal
 mask drops.
+
+What a block's forward computed is kept, and the block's backward reads it
+(``KEPT_NAMES``): the choice packed eight keys a byte (``_pack``: 21 MB a
+sequence of 16,384 in four tiers), the heads' log-sum-exp float32 and the
+output. The backward runs no ``choose`` and no attention forward: its
+attention call takes the kept choice, log-sum-exp and ``delta = sum_d dO
+out`` and hands out the heads' summed probabilities once more, which is
+what the index term's gradient reads (through ``jax.vjp`` of the scores and
+the term: the scores' own two calls, their products formed again). The kept
+set carries names for the policy of a layer's ``jax.checkpoint``
+(``sparse_attention(named=True)``: ``models/llama.REMAT_LADDER``'s first
+rung holds them, and that layer's backward then runs none of the walk's
+forward; a layer whose policy holds none of them runs the forward once
+more and the backward after it). XLA's form keeps the choice alone and
+differentiates ``plain_attend`` where it stands.
 
 The scores and the attention over the choice have two forms each, one
 equation; which runs is read from the call and never set (``scores_plan``,
@@ -74,7 +90,9 @@ rope key (the section "grouped keys" below). Named scopes: ``dsa_scores``,
 ``dsa_select`` (the choice), ``flash_sparse`` (the attention's calls, a
 block's ``delta``, the walk's turns of its arrays), ``dsa_loss``. One kept
 span, ``rtpu.dsa.shapes`` (forms, layout, ``kv_groups``, block and tiers,
-``block_asked``, ``vmem_need_bytes``: the most a call holds). Training only.
+``block_asked``, ``vmem_need_bytes``: the most a call holds;
+``block_forwards``: how often a block's attention forward runs a step and
+layer under the walk's rule alone, ``kept_bytes_a_layer``). Training only.
 """
 
 from __future__ import annotations
@@ -85,6 +103,7 @@ from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from ray_tpu.util import tracing
 
@@ -318,14 +337,15 @@ def _scores_bwd_kernel(first_ref, q_ref, k_ref, w_ref, g_ref, dq_ref, dk_ref,
         dw_ref[...] = dw
 
 
-def _score_blocks(J: int, n: int, d: int, tile: int, dtype):
+def _score_blocks(J: int, n: int, d: int, tile: int, dtype,
+                  lanes: Optional[int] = None):
     """(shape, dtype) of what the scores' two calls hold in VMEM, by name:
-    the operands' blocks and the scratch."""
+    the operands' blocks and the scratch (``lanes``: ``KERNEL_LANES``)."""
     f32 = jnp.float32
     return {"q": ((J, n, d), dtype), "k": ((tile, d), dtype),
             "w": ((n, J), f32), "scores": ((n, tile), f32),
-            "wb": ((J, n, KERNEL_LANES), f32), "dq": ((J, n, d), f32),
-            "dk_acc": ((tile, d), f32)}
+            "wb": ((J, n, lanes or KERNEL_LANES), f32),
+            "dq": ((J, n, d), f32), "dk_acc": ((tile, d), f32)}
 
 
 def _score_needs(J: int, n: int, d: int, tile: int, dtype):
@@ -340,14 +360,23 @@ def _score_needs(J: int, n: int, d: int, tile: int, dtype):
                                + 2 * at["wb"] + at["dk_acc"])}
 
 
-def _score_specs(q, k, tile):
+class _ScoreHow(NamedTuple):
+    """What a scores' call is built from beside its arrays (static)."""
+    tile: int
+    rows: int
+    lanes: int
+    interpret: bool
+
+
+def _score_specs(q, k, how: _ScoreHow):
     """What both calls share: the grid (tiles of keys) and the operands'
     blocks."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     J, n, d = q.shape
-    at = _score_blocks(J, n, d, tile, q.dtype)
+    tile = how.tile
+    at = _score_blocks(J, n, d, tile, q.dtype, how.lanes)
     return {
         "grid": (k.shape[0] // tile,),
         "first": pl.BlockSpec(memory_space=pltpu.SMEM),
@@ -361,29 +390,69 @@ def _score_specs(q, k, tile):
             dimension_semantics=("arbitrary",), vmem_limit_bytes=100 << 20)}
 
 
-def _scores_forward(first, q, k, w, tile, interpret):
+def _traced_once(scope: str):
+    """A builder of a Mosaic call as a ``jax.jit`` of its own, static in
+    ``how`` (what the call is built from beside its arrays): a kernel's
+    body is traced once a distinct shape and lowered once a program,
+    whoever calls it (a rule's primal, its ``fwd``, a second layer of the
+    same shapes, a layer's recomputation). What a test or a control plants
+    lies above (``score_kernels``, ``attend_kernels``, ...: looked up as a
+    step traces). The one lowered function carries its ``scope`` itself,
+    whoever's path its first caller's is. The barrier keeps the call a
+    call: XLA otherwise folds a consumer that updates a stack in place
+    (the walk's ``lax.map`` laying a block's output down, its scan summing
+    ``dw``) into one fusion with it, which loses the call's VMEM limit
+    ("scoped allocation ... limit 16.00M"), its name in a device trace
+    (``fusion.N`` for ``dsa_attend_fwd.N``) and its scope (the update's).
+    Entered with the abstract mesh spelled out: jax traces a scan's body
+    and a checkpoint's linearization under the empty mesh and the rest
+    under none, and the two are different keys of jit's cache."""
+    def wrap(builder):
+        @functools.wraps(builder)
+        def scoped(*args, how):
+            with jax.named_scope(scope):
+                return jax.lax.optimization_barrier(builder(*args, how))
+
+        jitted = jax.jit(scoped, static_argnames=("how",))
+
+        @functools.wraps(builder)
+        def call(*args):
+            *arrays, how = args
+            with jax.sharding.use_abstract_mesh(
+                    jax.sharding.get_abstract_mesh()):
+                return jitted(*arrays, how=how)
+
+        call.clear_cache = jitted.clear_cache
+        return call
+
+    return wrap
+
+
+@_traced_once("dsa_scores")
+def _scores_forward(first, q, k, w, how: _ScoreHow):
     import jax.experimental.pallas as pl
 
-    at = _score_specs(q, k, tile)
+    at = _score_specs(q, k, how)
     return pl.pallas_call(
-        functools.partial(_scores_fwd_kernel, rows=SCORE_ROWS),
+        functools.partial(_scores_fwd_kernel, rows=how.rows),
         name="dsa_scores_fwd",
         out_shape=jax.ShapeDtypeStruct((q.shape[1], k.shape[0]),
                                        jnp.float32),
         grid=at["grid"],
         in_specs=[at["first"], at["q"], at["k"], at["w"]],
         out_specs=at["scores"], scratch_shapes=[at["wb"]],
-        compiler_params=at["params"], interpret=interpret,
+        compiler_params=at["params"], interpret=how.interpret,
     )(first, q, k, w)
 
 
-def _scores_backward(first, q, k, w, g, tile, interpret):
+@_traced_once("dsa_scores")
+def _scores_backward(first, q, k, w, g, how: _ScoreHow):
     import jax.experimental.pallas as pl
 
-    at = _score_specs(q, k, tile)
+    at = _score_specs(q, k, how)
     f32 = jnp.float32
     return pl.pallas_call(
-        functools.partial(_scores_bwd_kernel, rows=SCORE_ROWS),
+        functools.partial(_scores_bwd_kernel, rows=how.rows),
         name="dsa_scores_bwd",
         out_shape=[jax.ShapeDtypeStruct(q.shape, f32),
                    jax.ShapeDtypeStruct(k.shape, k.dtype),
@@ -392,27 +461,22 @@ def _scores_backward(first, q, k, w, g, tile, interpret):
         in_specs=[at["first"], at["q"], at["k"], at["w"], at["scores"]],
         out_specs=[at["q"], at["k"], at["w"]],
         scratch_shapes=[at["wb"], at["wb"], at["dk_acc"]],
-        compiler_params=at["params"], interpret=interpret,
+        compiler_params=at["params"], interpret=how.interpret,
     )(first, q, k, w, g)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
-def _score_calls(first, q, k, w, tile, interpret):
-    return _scores_forward(first, q, k, w, tile, interpret)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _score_calls(first, q, k, w, how):
+    return _scores_forward(first, q, k, w, how)
 
 
-def _score_calls_fwd(first, q, k, w, tile, interpret):
+def _score_calls_fwd(first, q, k, w, how):
     # the inputs alone are kept: the backward forms the products again
-    return (_scores_forward(first, q, k, w, tile, interpret),
-            (first, q, k, w))
+    return _scores_forward(first, q, k, w, how), (first, q, k, w)
 
 
-def _score_calls_bwd(tile, interpret, res, g):
-    # the barrier keeps the call a call: XLA otherwise folds the walk's
-    # update of its stacked ``dw`` into it, and the fusion it makes of both
-    # loses the call's VMEM limit ("scoped allocation ... limit 16.00M")
-    dq, dk, dw = jax.lax.optimization_barrier(
-        _scores_backward(*res, g, tile, interpret))
+def _score_calls_bwd(how, res, g):
+    dq, dk, dw = _scores_backward(*res, g, how)
     return None, dq.astype(res[1].dtype), dk, dw
 
 
@@ -438,7 +502,7 @@ def score_kernels(q_i, k_i, w, first, tile: int, interpret: bool = False):
     first = jnp.asarray(k_i.shape[0] if first is None else first,
                         jnp.int32).reshape(1)
     return _score_calls(first, jnp.swapaxes(q_i, 0, 1), k_i, w.astype(f32),
-                        tile, interpret)
+                        _ScoreHow(tile, SCORE_ROWS, KERNEL_LANES, interpret))
 
 
 # ---- attention over the choice as Pallas (Mosaic) kernels. Queries, keys
@@ -600,12 +664,15 @@ def _attend_fwd_kernel(first_ref, q_ref, kn_ref, kr_ref, vt_ref, chosen_ref,
 
 def _attend_bwd_kernel(first_ref, q_ref, kn_ref, kr_ref, v_ref, chosen_ref,
                        dot_ref, lse_ref, delta_ref, dq_ref, dkn_ref, dkr_ref,
-                       dv_ref, bias_ref, dq_acc, *, scale, rows, unroll):
+                       dv_ref, ps_ref, bias_ref, dq_acc, *, scale, rows,
+                       unroll):
     """A tile of keys of the backward, keys down and queries across as the
     forward: a head's scores of ``rows`` keys again, ``p = exp(s - lse)``,
     ``dv = p dO``, ``dS = p (v dO^T - delta) scale`` to the MXU in the
     inputs' dtype, ``dk_n = dS q_n``, ``dk_r`` summed over the heads, ``dq
-    += dS^T [k_n | k_r]`` (float32, in VMEM through the call)."""
+    += dS^T [k_n | k_r]`` (float32, in VMEM through the call), and the
+    heads' sum of ``p``, turned, to the tile's block of ``ps`` as the
+    forward writes it."""
     import jax.experimental.pallas as pl
 
     H, n, _ = q_ref.shape
@@ -623,7 +690,8 @@ def _attend_bwd_kernel(first_ref, q_ref, kn_ref, kr_ref, v_ref, chosen_ref,
         for at in [slice(i, i + rows) for i in range(0, tile, rows)]:
             kr = kr_ref[at, :]
 
-            def head(h, dkr, at=at, kr=kr):
+            def head(h, carry, at=at, kr=kr):
+                dkr, total = carry
                 q, dot = q_ref[h], dot_ref[h]
                 kn, v = kn_ref[h, at, :], v_ref[h, at, :]
                 p = jnp.exp(_scores_turned(q, kn, kr, bias_ref[at, :], scale,
@@ -641,18 +709,22 @@ def _attend_bwd_kernel(first_ref, q_ref, kn_ref, kr_ref, v_ref, chosen_ref,
                     ds_t, kn, preferred_element_type=jnp.float32)
                 dq_acc[h, :, dn:] += jnp.dot(
                     ds_t, kr, preferred_element_type=jnp.float32)
-                return dkr + jnp.dot(ds, q[:, dn:],
-                                     preferred_element_type=jnp.float32)
+                return (dkr + jnp.dot(ds, q[:, dn:],
+                                      preferred_element_type=jnp.float32),
+                        total + p)
 
-            dkr_ref[at, :] = _over_heads(
-                H, head, jnp.zeros((rows, kr.shape[1]), jnp.float32), unroll
-            ).astype(dkr_ref.dtype)
+            dkr, total = _over_heads(
+                H, head, (jnp.zeros((rows, kr.shape[1]), jnp.float32),
+                          jnp.zeros((rows, n), jnp.float32)), unroll)
+            dkr_ref[at, :] = dkr.astype(dkr_ref.dtype)
+            ps_ref[:, at] = total.T
 
     @pl.when(jnp.logical_not(seen))
     def _():
         dkn_ref[...] = jnp.zeros_like(dkn_ref)
         dkr_ref[...] = jnp.zeros_like(dkr_ref)
         dv_ref[...] = jnp.zeros_like(dv_ref)
+        ps_ref[...] = jnp.zeros_like(ps_ref)
 
     @pl.when(t == pl.num_programs(0) - 1)
     def _():
@@ -673,7 +745,8 @@ def _attend_blocks(H: int, n: int, dn: int, dr: int, dv: int, tile: int,
             [stat, stat, ((H, dv, n), f32), ((tile, n), f32)]),
         "dsa_attend_bwd": (
             [q, kn, kr, ((H, tile, dv), dtype), chosen, ((H, dv, n), dtype),
-             stat, stat, q, kn, kr, ((H, tile, dv), dtype)],
+             stat, stat, q, kn, kr, ((H, tile, dv), dtype),
+             ((n, tile), f32)],
             [((tile, n), f32), ((H, n, d), f32)])}
 
 
@@ -720,6 +793,7 @@ class _How(NamedTuple):
     interpret: bool
 
 
+@_traced_once("flash_sparse")
 def _attend_forward(first, q, kn, kr, vt, chosen, how: _How):
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
@@ -756,6 +830,7 @@ def _attend_forward(first, q, kn, kr, vt, chosen, how: _How):
     )(first, q, kn, kr, vt, chosen)
 
 
+@_traced_once("flash_sparse")
 def _attend_backward(first, q, kn, kr, v, chosen, dot, lse, delta,
                      how: _How):
     import jax.experimental.pallas as pl
@@ -763,7 +838,7 @@ def _attend_backward(first, q, kn, kr, v, chosen, dot, lse, delta,
 
     H, n, d = q.shape
     S, dn, dv, dr = kn.shape[1], kn.shape[2], v.shape[2], kr.shape[1]
-    tile = how.tile
+    f32, tile = jnp.float32, how.tile
     last = _last_seen(n, tile)
     blocks, scratch = _attend_blocks(H, n, dn, dr, dv, tile,
                                      q.dtype)["dsa_attend_bwd"]
@@ -776,7 +851,8 @@ def _attend_backward(first, q, kn, kr, v, chosen, dot, lse, delta,
         out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
                    jax.ShapeDtypeStruct(kn.shape, kn.dtype),
                    jax.ShapeDtypeStruct(kr.shape, kr.dtype),
-                   jax.ShapeDtypeStruct(v.shape, v.dtype)],
+                   jax.ShapeDtypeStruct(v.shape, v.dtype),
+                   jax.ShapeDtypeStruct((n, S), f32)],
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1, grid=(S // tile,),
             in_specs=[
@@ -791,30 +867,37 @@ def _attend_backward(first, q, kn, kr, v, chosen, dot, lse, delta,
                 whole3,
                 pl.BlockSpec((H, tile, dn), lambda t, f: (0, t, 0)),
                 pl.BlockSpec((tile, dr), lambda t, f: (t, 0)),
-                pl.BlockSpec((H, tile, dv), lambda t, f: (0, t, 0))],
+                pl.BlockSpec((H, tile, dv), lambda t, f: (0, t, 0)),
+                pl.BlockSpec((n, tile), lambda t, f: (0, t))],
             scratch_shapes=[pltpu.VMEM(shape, dt) for shape, dt in scratch]),
         compiler_params=_vmem(blocks, scratch, 1), interpret=how.interpret,
     )(first, q, kn, kr, v, chosen, dot, lse, delta)
 
 
+def _delta(dot, out_t):
+    """``sum_d dO out`` of a block as the kernels lay both, [.., d_v, n] ->
+    [.., n] float32."""
+    return (dot.astype(jnp.float32) * out_t.astype(jnp.float32)).sum(-2)
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(7,))
 def _attend_calls(first, q, kn, kr, v, vt, chosen, how):
-    out_t, _, ps = _attend_forward(first, q, kn, kr, vt, chosen, how)
-    return out_t, ps
+    return _attend_forward(first, q, kn, kr, vt, chosen, how)
 
 
 def _attend_calls_fwd(first, q, kn, kr, v, vt, chosen, how):
     out_t, lse, ps = _attend_forward(first, q, kn, kr, vt, chosen, how)
-    return (out_t, ps), (first, q, kn, kr, v, chosen, out_t, lse)
+    return (out_t, lse, ps), (first, q, kn, kr, v, chosen, out_t, lse)
 
 
 def _attend_calls_bwd(how, res, g):
-    # ``ps`` is a target (``kl_target`` stops its gradient): its cotangent
-    # is dropped. ``vt`` is ``v`` turned: the values' gradient is ``v``'s
+    # ``lse`` and ``ps`` are targets (``kl_target`` stops the sum's
+    # gradient): their cotangents are dropped, and so is the backward
+    # call's own ``ps``. ``vt`` is ``v`` turned: the values' gradient is
+    # ``v``'s
     *ins, out_t, lse = res
-    dot = g[0]
-    delta = (dot.astype(jnp.float32) * out_t.astype(jnp.float32)).sum(1)
-    dq, dkn, dkr, dv = _attend_backward(*ins, dot, lse, delta, how)
+    dq, dkn, dkr, dv, _ = _attend_backward(
+        *ins, g[0], lse, _delta(g[0], out_t), how)
     return None, dq, dkn, dkr, dv, None, None
 
 
@@ -822,15 +905,19 @@ _attend_calls.defvjp(_attend_calls_fwd, _attend_calls_bwd)
 
 
 def attend_kernels(q, kn, v, kr, chosen, first, scale: float, tile: int,
-                   v_t=None, interpret: bool = False):
+                   v_t=None, interpret: bool = False, back=None):
     """Attention of a block over its choice as two Mosaic calls,
     ``dsa_attend_fwd`` and ``dsa_attend_bwd`` behind a ``custom_vjp``, on
     arrays that lie heads first: q [H, n, d_n + d_r], kn [H, S, d_n], v [H,
     S, d_v], kr [S, d_r], chosen [n, S] (bool or int8) -> (out turned [H,
-    d_v, n], ``sum_h p`` [n, S] float32). ``v_t``: ``v`` with its last two
+    d_v, n], the queries' log-sum-exp a head [H, n] float32, ``sum_h p``
+    [n, S] float32). ``v_t``: ``v`` with its last two
     axes swapped, [H, d_v, S], what the forward reads (a walk turns it once
     for all its blocks; made here when not given); the values' gradient is
-    ``v``'s whole.
+    ``v``'s whole. ``back = (dO turned [H, d_v, n], the log-sum-exp,
+    delta [H, n] = sum_d dO out)``: the backward call itself, for a caller
+    that kept the forward's -> (dq, dk_n, dk_r, dv, ``sum_h p`` once more):
+    what the walk's rule calls, and no forward runs.
 
     Both calls hold the scores keys down and queries across, so that the
     MXU holds a head's queries and the tile's keys stream past them. The
@@ -849,13 +936,15 @@ def attend_kernels(q, kn, v, kr, chosen, first, scale: float, tile: int,
     zeros."""
     first = jnp.asarray(kn.shape[1] if first is None else first,
                         jnp.int32).reshape(1)
+    how = _How(float(scale), tile, min(ATTEND_ROWS, tile), ATTEND_UNROLL,
+               interpret)
+    chosen = chosen.astype(jnp.int8)
+    if back is not None:
+        return _attend_backward(first, q, kn, kr, v, chosen, *back, how)
     if v_t is None:
         v_t = jnp.swapaxes(v, 1, 2)
-    return _attend_calls(
-        first, q, kn, kr, v, jax.lax.stop_gradient(v_t),
-        chosen.astype(jnp.int8),
-        _How(float(scale), tile, min(ATTEND_ROWS, tile), ATTEND_UNROLL,
-             interpret))
+    return _attend_calls(first, q, kn, kr, v, jax.lax.stop_gradient(v_t),
+                         chosen, how)
 
 
 # ---- grouped keys: the same attention where ``G`` key/value heads each
@@ -968,11 +1057,12 @@ def _grouped_fwd_kernel(first_ref, q_ref, k_ref, vt_ref, chosen_ref, ot_ref,
 
 
 def _grouped_bwd_kernel(first_ref, q_ref, k_ref, v_ref, chosen_ref, dot_ref,
-                        lse_ref, delta_ref, dq_ref, dk_ref, dv_ref, bias_ref,
-                        dq_acc, *, scale, rows, unroll):
+                        lse_ref, delta_ref, dq_ref, dk_ref, dv_ref, ps_ref,
+                        bias_ref, dq_acc, *, scale, rows, unroll):
     """``_attend_bwd_kernel`` under grouped keys: ``dk = dS q`` and ``dv =
     p dO`` contract a group's ``R x n`` rows, its heads and queries at once,
-    so a group's key and value gradients leave the call summed."""
+    so a group's key and value gradients leave the call summed; ``ps`` sums
+    a group's heads' ``p`` into the loop's carry [rows, n]."""
     import jax.experimental.pallas as pl
 
     G, N, _ = q_ref.shape
@@ -988,7 +1078,7 @@ def _grouped_bwd_kernel(first_ref, q_ref, k_ref, v_ref, chosen_ref, dot_ref,
     def _():
         bias_ref[...] = _bias_grouped(chosen_ref, N // n)
         for at in [slice(i, i + rows) for i in range(0, tile, rows)]:
-            def group(g, carry, at=at):
+            def group(g, total, at=at):
                 q, dot = q_ref[g], dot_ref[g]
                 k, v = k_ref[g, at, :], v_ref[g, at, :]
                 p = jnp.exp(_nt(k, q) * scale + bias_ref[at, :]
@@ -1003,14 +1093,16 @@ def _grouped_bwd_kernel(first_ref, q_ref, k_ref, v_ref, chosen_ref, dot_ref,
                 ).astype(dk_ref.dtype)
                 dq_acc[g] += jnp.dot(ds_t, k,
                                      preferred_element_type=jnp.float32)
-                return carry
+                return total + sum(p[:, i:i + n] for i in range(0, N, n))
 
-            _over_heads(G, group, 0, unroll)
+            ps_ref[:, at] = _over_heads(
+                G, group, jnp.zeros((rows, n), jnp.float32), unroll).T
 
     @pl.when(jnp.logical_not(seen))
     def _():
         dk_ref[...] = jnp.zeros_like(dk_ref)
         dv_ref[...] = jnp.zeros_like(dv_ref)
+        ps_ref[...] = jnp.zeros_like(ps_ref)
 
     @pl.when(t == pl.num_programs(0) - 1)
     def _():
@@ -1031,27 +1123,33 @@ def _grouped_blocks(G: int, R: int, n: int, d: int, dv: int, tile: int,
             [stat, stat, ((G, dv, N), f32), ((tile, N), f32)]),
         "dsa_attend_gqa_bwd": (
             [q, k, ((G, tile, dv), dtype), chosen, ((G, dv, N), dtype), stat,
-             stat, q, k, ((G, tile, dv), dtype)],
+             stat, q, k, ((G, tile, dv), dtype), ((n, tile), f32)],
             [((tile, N), f32), ((G, N, d), f32)])}
 
 
 def _grouped_need(blocks, scratch, rows: int, N: int) -> int:
-    """Bytes of VMEM a grouped call holds: ``_need`` and six ``[rows, R x
-    n]`` float32 temporaries of a chunk (scores, probabilities, their
+    """Bytes of VMEM a grouped call holds: ``_need`` and six ``[rows,
+    R x n]`` float32 temporaries of a chunk (scores, probabilities, their
     gradient and its turn: 4 MB each at 512 keys by 8 x 256 queries, past
-    what ``_vmem``'s 8 MB allows for)."""
+    what ``_vmem``'s 8 MB allows for); the backward's heads' sum of ``p``
+    [rows, n] is an eighth of one and rides in that allowance."""
     return _need(blocks, scratch) + 6 * rows * N * 4
 
 
-def _grouped_params(blocks, scratch, rows: int, N: int, grid_dims: int):
+def _grouped_params(name: str, G: int, R: int, n: int, d: int, dv: int,
+                    dtype, how: "_How", grid_dims: int):
+    """(blocks, scratch, compiler parameters) of the grouped call
+    ``name``."""
     from jax.experimental.pallas import tpu as pltpu
 
-    return pltpu.CompilerParams(
+    blocks, scratch = _grouped_blocks(G, R, n, d, dv, how.tile, dtype)[name]
+    need = _grouped_need(blocks, scratch, how.rows, R * n)
+    return blocks, scratch, pltpu.CompilerParams(
         dimension_semantics=("arbitrary",) * grid_dims,
-        vmem_limit_bytes=max(_grouped_need(blocks, scratch, rows, N),
-                             16 << 20))
+        vmem_limit_bytes=max(need, 16 << 20))
 
 
+@_traced_once("flash_sparse")
 def _grouped_forward(first, q, k, vt, chosen, how: _How):
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
@@ -1060,8 +1158,8 @@ def _grouped_forward(first, q, k, vt, chosen, how: _How):
     n, S, dv = chosen.shape[0], k.shape[1], vt.shape[1]
     f32, tile = jnp.float32, how.tile
     last = _last_seen(n, tile)
-    blocks, scratch = _grouped_blocks(G, N // n, n, d, dv, tile,
-                                      q.dtype)["dsa_attend_gqa_fwd"]
+    _, scratch, params = _grouped_params(
+        "dsa_attend_gqa_fwd", G, N // n, n, d, dv, q.dtype, how, 2)
     return pl.pallas_call(
         functools.partial(_grouped_fwd_kernel, scale=how.scale,
                           rows=how.rows, unroll=how.unroll),
@@ -1083,21 +1181,21 @@ def _grouped_forward(first, q, k, vt, chosen, how: _How):
                 pl.BlockSpec((G, N), lambda p, t, f: (0, 0)),
                 pl.BlockSpec((n, tile), lambda p, t, f: (0, t * p))],
             scratch_shapes=[pltpu.VMEM(shape, dt) for shape, dt in scratch]),
-        compiler_params=_grouped_params(blocks, scratch, how.rows, N, 2),
-        interpret=how.interpret,
+        compiler_params=params, interpret=how.interpret,
     )(first, q, k, vt, chosen)
 
 
+@_traced_once("flash_sparse")
 def _grouped_backward(first, q, k, v, chosen, dot, lse, delta, how: _How):
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     G, N, d = q.shape
     n, S, dv = chosen.shape[0], k.shape[1], v.shape[2]
-    tile = how.tile
+    f32, tile = jnp.float32, how.tile
     last = _last_seen(n, tile)
-    blocks, scratch = _grouped_blocks(G, N // n, n, d, dv, tile,
-                                      q.dtype)["dsa_attend_gqa_bwd"]
+    _, scratch, params = _grouped_params(
+        "dsa_attend_gqa_bwd", G, N // n, n, d, dv, q.dtype, how, 1)
     whole3 = pl.BlockSpec((G, N, d), lambda t, f: (0, 0, 0))
     stat = pl.BlockSpec((G, N), lambda t, f: (0, 0))
     return pl.pallas_call(
@@ -1106,7 +1204,8 @@ def _grouped_backward(first, q, k, v, chosen, dot, lse, delta, how: _How):
         name="dsa_attend_gqa_bwd",
         out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
                    jax.ShapeDtypeStruct(k.shape, k.dtype),
-                   jax.ShapeDtypeStruct(v.shape, v.dtype)],
+                   jax.ShapeDtypeStruct(v.shape, v.dtype),
+                   jax.ShapeDtypeStruct((n, S), f32)],
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1, grid=(S // tile,),
             in_specs=[
@@ -1119,30 +1218,29 @@ def _grouped_backward(first, q, k, v, chosen, dot, lse, delta, how: _How):
             out_specs=[
                 whole3,
                 pl.BlockSpec((G, tile, d), lambda t, f: (0, t, 0)),
-                pl.BlockSpec((G, tile, dv), lambda t, f: (0, t, 0))],
+                pl.BlockSpec((G, tile, dv), lambda t, f: (0, t, 0)),
+                pl.BlockSpec((n, tile), lambda t, f: (0, t))],
             scratch_shapes=[pltpu.VMEM(shape, dt) for shape, dt in scratch]),
-        compiler_params=_grouped_params(blocks, scratch, how.rows, N, 1),
-        interpret=how.interpret,
+        compiler_params=params, interpret=how.interpret,
     )(first, q, k, v, chosen, dot, lse, delta)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
 def _grouped_calls(first, q, k, v, vt, chosen, how):
-    out_t, _, ps = _grouped_forward(first, q, k, vt, chosen, how)
-    return out_t, ps
+    return _grouped_forward(first, q, k, vt, chosen, how)
 
 
 def _grouped_calls_fwd(first, q, k, v, vt, chosen, how):
     out_t, lse, ps = _grouped_forward(first, q, k, vt, chosen, how)
-    return (out_t, ps), (first, q, k, v, chosen, out_t, lse)
+    return (out_t, lse, ps), (first, q, k, v, chosen, out_t, lse)
 
 
 def _grouped_calls_bwd(how, res, g):
-    # as ``_attend_calls_bwd``: ``ps`` is a target, ``vt`` is ``v`` turned
+    # as ``_attend_calls_bwd``: ``lse`` and ``ps`` are targets, ``vt`` is
+    # ``v`` turned
     *ins, out_t, lse = res
-    dot = g[0]
-    delta = (dot.astype(jnp.float32) * out_t.astype(jnp.float32)).sum(1)
-    dq, dk, dv = _grouped_backward(*ins, dot, lse, delta, how)
+    dq, dk, dv, _ = _grouped_backward(
+        *ins, g[0], lse, _delta(g[0], out_t), how)
     return None, dq, dk, dv, None, None
 
 
@@ -1150,23 +1248,27 @@ _grouped_calls.defvjp(_grouped_calls_fwd, _grouped_calls_bwd)
 
 
 def attend_kernels_grouped(q, k, v, chosen, first, scale: float, tile: int,
-                           v_t=None, interpret: bool = False):
+                           v_t=None, interpret: bool = False, back=None):
     """``attend_kernels`` under grouped keys, two Mosaic calls
     ``dsa_attend_gqa_fwd`` and ``dsa_attend_gqa_bwd`` behind a
     ``custom_vjp``: q [G, R x n, d] (a group's R heads one after another,
     ``n`` queries each), k [G, S, d], v [G, S, d_v], chosen [n, S] (bool or
-    int8) -> (out turned [G, d_v, R x n], ``sum_h p`` [n, S] float32 over
-    all ``G x R`` heads). ``v_t``, ``first`` and the arithmetic are
+    int8) -> (out turned [G, d_v, R x n], the log-sum-exp [G, R x n]
+    float32, ``sum_h p`` [n, S] float32 over all ``G x R`` heads). ``v_t``,
+    ``first``, ``back`` (-> dq, dk, dv, ``sum_h p``) and the arithmetic are
     ``attend_kernels``'; the keys' and values' gradients are the group's,
     summed over its heads in the call."""
     first = jnp.asarray(k.shape[1] if first is None else first,
                         jnp.int32).reshape(1)
+    how = _How(float(scale), tile, min(ATTEND_ROWS, tile), ATTEND_UNROLL,
+               interpret)
+    chosen = chosen.astype(jnp.int8)
+    if back is not None:
+        return _grouped_backward(first, q, k, v, chosen, *back, how)
     if v_t is None:
         v_t = jnp.swapaxes(v, 1, 2)
-    return _grouped_calls(
-        first, q, k, v, jax.lax.stop_gradient(v_t), chosen.astype(jnp.int8),
-        _How(float(scale), tile, min(ATTEND_ROWS, tile), ATTEND_UNROLL,
-             interpret))
+    return _grouped_calls(first, q, k, v, jax.lax.stop_gradient(v_t), chosen,
+                          how)
 
 
 def choose(scores: jax.Array, first_q, topk: int) -> jax.Array:
@@ -1252,6 +1354,21 @@ def walk_needs(block: int, keys: int, widths: Widths) -> Dict[str, int]:
     return needs
 
 
+def kept_bytes(seq: int, block: int, tiers: int, widths: Widths,
+               kernels: bool) -> int:
+    """Bytes a sequence's walk keeps of its forward beside its inputs: a
+    tier's choices packed eight keys a byte over the tier's keys and, where
+    the attention runs as ``kernels``, the heads' log-sum-exp float32 and
+    the output."""
+    per_tier = seq // tiers
+    choice = sum(per_tier * -(-(g + 1) * per_tier // 8)
+                 for g in range(tiers))
+    if not kernels:
+        return choice
+    return choice + seq * widths.heads * (
+        4 + widths.d_v * jnp.dtype(widths.dtype).itemsize)
+
+
 def walk_plan(seq: int, block: int, tiers: int,
               widths: Optional[Widths] = None) -> Tuple[int, int]:
     """(block, tiers) as the walk takes them: the largest divisor of
@@ -1274,112 +1391,335 @@ def walk_plan(seq: int, block: int, tiers: int,
     return took, tiers_of(took)
 
 
-def _walk(q, k_n, v, k_r, q_i, k_i, w, *, scale: float, topk: int,
-          block: int, tiers: int, keep_choice: bool):
-    """One sequence: q [s, H, d_n + d_r], k_n [s, H, d_n], v [s, H, d_v],
-    k_r [s, d_r], or under grouped keys q [s, H, d], k_n [s, G, d], v [s,
-    G, d_v] and ``k_r`` None; q_i [s, J, d_i], k_i [s, d_i], w [s, J] -> (o
-    [s, H, d_v], the sequence's sum of KL terms, pairs chosen, and under
-    ``keep_choice`` the choice packed eight keys a byte [s, s / 8]).
-    ``block`` and ``tiers`` are ``walk_plan``'s."""
-    s, H, _ = q.shape
-    dn, G = k_n.shape[-1], k_n.shape[-2]
-    grouped = k_r is None
-    per_tier = s // block // tiers
-    ends = [(g + 1) * per_tier * block for g in range(tiers)]
-    plan = attend_plan(block, s // tiers, dn, v.shape[-1])
+# What a walk keeps of its forward for its backward beside its inputs, and
+# the names it carries where the caller asks (``sparse_attention(named=)``):
+# the output, the heads' log-sum-exp and the choice packed eight keys a
+# byte. A layer's ``jax.checkpoint`` whose policy holds these names
+# (``models/llama.REMAT_LADDER``'s first rung) runs no call of the walk's
+# forward in its backward; one that does not runs the forward once for them.
+KEPT_NAMES = ("flash_out", "flash_lse", "dsa_choice")
+
+
+def _pack(chosen):
+    """chosen bool [n, S] -> uint8 [n, ceil(S / 8)], what the walk keeps of
+    a block's choice: bit ``j`` of byte ``i`` is key ``j W + i``, ``W`` the
+    bytes a row. Eight planes of neighbouring keys and not
+    ``jnp.packbits``' bytes of eight neighbours: packing and unpacking are
+    shifts of whole slices along the lanes, where a byte of neighbours
+    would turn every register."""
+    n, S = chosen.shape
+    W = -(-S // 8)
+    planes = jnp.pad(chosen, ((0, 0), (0, 8 * W - S))).astype(jnp.uint8)
+    return functools.reduce(jnp.bitwise_or, (
+        planes[:, j * W:(j + 1) * W] << j for j in range(8)))
+
+
+def _unpack(packed, S: int):
+    """``_pack``'s bytes -> bool [n, S]."""
+    return jnp.concatenate([(packed >> j) & 1 for j in range(8)],
+                           axis=1)[:, :S] != 0
+
+
+class _Walk(NamedTuple):
+    """What a walk is built from beside its arrays (static)."""
+    scale: float
+    topk: int
+    block: int
+    tiers: int
+    keep_choice: bool
+    named: bool
+
+
+class _Laid(NamedTuple):
+    """A walk's arrays as its blocks take them: a tier's blocks of queries
+    ``q`` (heads first under the kernels), index queries ``qi`` and head
+    weights ``w`` [tiers, blocks a tier, ..], the first positions
+    ``firsts``, and a tier's ``keys`` (k_n, v, k_r or None, and under the
+    kernels v turned) and index keys ``ki`` up to its end."""
+    q: Any
+    qi: Any
+    w: Any
+    firsts: Any
+    keys: Any
+    ki: Any
+    ends: Any
+    tile: Optional[int]
+
+
+def _by_block(x, how: _Walk, at: int = 0):
+    """x [.., s, ..], the positions at axis ``at`` (0, or 1 behind the
+    heads) -> [tiers, blocks a tier, .., block, ..]."""
+    per_tier = x.shape[at] // how.block // how.tiers
+    x = x.reshape(x.shape[:at] + (how.tiers, per_tier, how.block)
+                  + x.shape[at + 1:])
+    return jnp.moveaxis(x, 0, 2) if at else x
+
+
+def _lay_out(how: _Walk, q, k_n, v, k_r, q_i, k_i, w) -> _Laid:
+    s = q.shape[0]
+    per_tier = s // how.block // how.tiers
+    ends = [(g + 1) * per_tier * how.block for g in range(how.tiers)]
+    tile = attend_plan(how.block, s // how.tiers, k_n.shape[-1],
+                       v.shape[-1])["attend_tile"]
     # where the positions lie in q, k_n and v: the kernels take them heads
     # first, and the values also turned [H, d_v, s] (the forward call's)
-    at = int(plan["attend_form"] == "kernel")
-
-    def by_block(x, at=0):
-        x = x.reshape(x.shape[:at] + (tiers, per_tier, block)
-                      + x.shape[at + 1:])
-        return jnp.moveaxis(x, 0, 2) if at else x
-
+    at = int(tile is not None)
     with jax.named_scope("flash_sparse"):
         # turned once a walk, not once a block
         if at:
             q, k_n, v = (jnp.swapaxes(x, 0, 1) for x in (q, k_n, v))
             v_turned = jnp.swapaxes(v, 1, 2)
-        q_t = by_block(q, at)
         keys = [(jax.lax.slice_in_dim(k_n, 0, end, axis=at),
                  jax.lax.slice_in_dim(v, 0, end, axis=at),
-                 None if grouped else k_r[:end])
+                 None if k_r is None else k_r[:end])
                 + ((v_turned[..., :end],) if at else ()) for end in ends]
+        q = _by_block(q, how, at)
+    firsts = (jnp.arange(s // how.block, dtype=jnp.int32) * how.block
+              ).reshape(how.tiers, per_tier)
+    return _Laid(q, _by_block(q_i, how), _by_block(w, how), firsts, keys,
+                 [k_i[:end] for end in ends], ends, tile)
 
-    def attend(q_b, chosen, first, kn_t, v_t, kr_t, *turned):
-        """-> (out, p): the heads' probabilities [H, block, S'] (XLA's
-        form), or their sum over the heads [1, block, S'] (the
-        kernels')."""
-        if not at and grouped:
-            return plain_attend_grouped(q_b, kn_t, v_t, chosen, scale)
-        if not at:
-            return plain_attend(q_b, kn_t, v_t, kr_t, chosen, scale)
-        # looked up at trace time: a test hands them the interpreter
+
+def _block_scores(qi_b, ki_t, w_b, first):
+    """A block's index scores [block, S']. ``index_scores`` is looked up
+    as the block is traced: a control of benchmark/tests/sparse_limits.py
+    replaces it and is called as it stands; the module's own is told where
+    the block's diagonal lies (what lies past it is never read: ``choose``
+    masks by position, the term reads under ``chosen``)."""
+    with jax.named_scope("dsa_scores"):
+        return (_scores(qi_b, ki_t, w_b, first)
+                if index_scores is _INDEX_SCORES
+                else index_scores(qi_b, ki_t, w_b))
+
+
+def _index_term(index, chosen, target):
+    """A block's sum of ``KL(p_t || softmax_{S_t} I)``."""
+    with jax.named_scope("dsa_loss"):
+        log_q = jax.nn.log_softmax(jnp.where(chosen, index, _NEG), -1)
+        return jnp.where(
+            target > 0,
+            target * (jnp.log(jnp.where(target > 0, target, 1.0)) - log_q),
+            0.0).sum()
+
+
+def _block_attend(how: _Walk, tile, q_b, chosen, first, keys, back=None):
+    """A block's attention over its choice, in the form and layout the
+    walk's arrays have. Forward -> (out, the log-sum-exp or None, p: the
+    heads' probabilities [H, block, S'] (XLA's form) or their sum over the
+    heads [1, block, S'] (the kernels')). ``back``, the block's cotangent
+    and what the forward kept -> ((dq, dk_n, dv, dk_r or None), p): the
+    kernels' backward call alone, which hands out the sum once more; XLA's
+    form through ``jax.vjp``, its products formed again. The four entry
+    points are looked up as the block is traced: a test hands the kernels
+    the interpreter, a control replaces one and is differentiated as it
+    stands."""
+    kn_t, v_t, kr_t, *turned = keys
+    grouped = kr_t is None
+    with jax.named_scope("flash_sparse"):
+        if tile is None:
+            def plain(q_b, kn_t, v_t, kr_t):
+                if grouped:
+                    return plain_attend_grouped(q_b, kn_t, v_t, chosen,
+                                                how.scale)
+                return plain_attend(q_b, kn_t, v_t, kr_t, chosen, how.scale)
+
+            if back is None:
+                out, p = plain(q_b, kn_t, v_t, kr_t)
+                return out, None, p
+            _, pull, p = jax.vjp(plain, q_b, kn_t, v_t, kr_t, has_aux=True)
+            return pull(back[0]), p
         if grouped:
             # [H, block, d] -> [G, R x block, d]: a group's heads in a row
-            out, p_sum = attend_kernels_grouped(
-                q_b.reshape(G, -1, q_b.shape[-1]), kn_t, v_t, chosen, first,
-                scale, plan["attend_tile"], *turned)
-        else:
-            out, p_sum = attend_kernels(q_b, kn_t, v_t, kr_t, chosen, first,
-                                        scale, plan["attend_tile"], *turned)
-        return out, p_sum[None]
+            q_g = q_b.reshape(kn_t.shape[0], -1, q_b.shape[-1])
+            if back is None:
+                out, lse, p_sum = attend_kernels_grouped(
+                    q_g, kn_t, v_t, chosen, first, how.scale, tile, *turned)
+                return out, lse, p_sum[None]
+            dq, dk, dv, p_sum = attend_kernels_grouped(
+                q_g, kn_t, v_t, chosen, first, how.scale, tile, back=back)
+            return (dq.reshape(q_b.shape), dk, dv, None), p_sum[None]
+        if back is None:
+            out, lse, p_sum = attend_kernels(
+                q_b, kn_t, v_t, kr_t, chosen, first, how.scale, tile, *turned)
+            return out, lse, p_sum[None]
+        dq, dkn, dkr, dv, p_sum = attend_kernels(
+            q_b, kn_t, v_t, kr_t, chosen, first, how.scale, tile, back=back)
+        return (dq, dkn, dv, dkr), p_sum[None]
 
-    def one_block(keys, ki_t, args):
+
+def _walk_forward(how: _Walk, q, k_n, v, k_r, q_i, k_i, w):
+    """``_walk``'s forward -> (what it returns, what its backward reads
+    beside the inputs: a tier's choices packed [blocks a tier, block, S' /
+    8] uint8 and, under the kernels, log-sum-exps [blocks a tier, H,
+    block] float32)."""
+    s, H, _ = q.shape
+    G = k_n.shape[-2]
+    at = _lay_out(how, q, k_n, v, k_r, q_i, k_i, w)
+
+    def one_block(g, args):
         q_b, qi_b, w_b, first = args
-        with jax.named_scope("dsa_scores"):
-            # [block, S']. ``index_scores`` is looked up as the block is
-            # traced: a control of benchmark/tests/sparse_limits.py replaces
-            # it and is called as it stands; the module's own is told where
-            # the block's diagonal lies (what lies past it is never read:
-            # ``choose`` masks by position, the term reads under ``chosen``)
-            index = (_scores(qi_b, ki_t, w_b, first)
-                     if index_scores is _INDEX_SCORES
-                     else index_scores(qi_b, ki_t, w_b))
+        index = _block_scores(qi_b, at.ki[g], w_b, first)
         with jax.named_scope("dsa_select"):
-            chosen = choose(jax.lax.stop_gradient(index), first, topk)
-        with jax.named_scope("flash_sparse"):
-            out, p = attend(q_b, chosen, first, *keys)
-        with jax.named_scope("dsa_loss"):
-            target = kl_target(p)
-            log_q = jax.nn.log_softmax(jnp.where(chosen, index, _NEG), -1)
-            kl = jnp.where(
-                target > 0,
-                target * (jnp.log(jnp.where(target > 0, target, 1.0))
-                          - log_q), 0.0).sum()
-        said = (out, kl, chosen.sum(dtype=jnp.int32))
-        if keep_choice:
-            said += (jnp.packbits(jnp.pad(
-                chosen, ((0, 0), (0, s - chosen.shape[1]))), axis=-1),)
+            chosen = choose(index, first, how.topk)
+            packed = _pack(chosen)
+        out, lse, p = _block_attend(how, at.tile, q_b, chosen, first,
+                                    at.keys[g])
+        kl = _index_term(index, chosen, kl_target(p))
+        said = (out, kl, chosen.sum(dtype=jnp.int32), packed, lse)
+        if how.keep_choice:
+            with jax.named_scope("dsa_select"):
+                said += (jnp.packbits(jnp.pad(
+                    chosen, ((0, 0), (0, s - chosen.shape[1]))), axis=-1),)
         return said
 
-    qi_t, w_t = by_block(q_i), by_block(w)
-    ki = [k_i[:end] for end in ends]
-    firsts = (jnp.arange(s // block, dtype=jnp.int32) * block
-              ).reshape(tiers, per_tier)
-    parts = [jax.lax.map(
-        jax.checkpoint(lambda a, g=g: one_block(keys[g], ki[g], a)),
-        (q_t[g], qi_t[g], w_t[g], firsts[g])) for g in range(tiers)]
-    out, kl, pairs, *choice = (
-        jnp.concatenate(xs) for xs in zip(*parts))
-    if at:
+    parts = [jax.lax.map(functools.partial(one_block, g),
+                         (at.q[g], at.qi[g], at.w[g], at.firsts[g]))
+             for g in range(how.tiers)]
+    out, kl, pairs = (jnp.concatenate([part[i] for part in parts])
+                      for i in range(3))
+    if at.tile:
         # [blocks, H, d_v, block], as the kernels leave it; under grouped
         # keys [blocks, G, d_v, R x block]
         with jax.named_scope("flash_sparse"):
-            if grouped:
-                out = out.reshape(out.shape[:3] + (H // G, block))
+            if k_r is None:
+                out = out.reshape(out.shape[:3] + (H // G, how.block))
                 out = jnp.transpose(out, (0, 4, 1, 3, 2))
             else:
                 out = jnp.transpose(out, (0, 3, 1, 2))
-    return (out.reshape(s, H, -1), kl.sum(), pairs.sum(),
-            *(c.reshape(s, -1) for c in choice))
+    said = (out.reshape(s, H, -1), kl.sum(), pairs.sum())
+    if how.keep_choice:
+        said += (jnp.concatenate([part[5] for part in parts]
+                                 ).reshape(s, -1),)
+    return said, ([part[3] for part in parts],
+                  [part[4] for part in parts] if at.tile else None)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _walk_rule(how: _Walk, q, k_n, v, k_r, q_i, k_i, w):
+    return _walk_forward(how, q, k_n, v, k_r, q_i, k_i, w)[0]
+
+
+def _walk_rule_fwd(how: _Walk, q, k_n, v, k_r, q_i, k_i, w):
+    said, (packed, lse) = _walk_forward(how, q, k_n, v, k_r, q_i, k_i, w)
+    o = said[0]
+    if how.named:
+        o, lse, packed = (
+            jax.tree.map(lambda x, name=name: checkpoint_name(x, name), kept)
+            for kept, name in zip((o, lse, packed), KEPT_NAMES))
+    # the output is kept where the kernels' backward reads it (``delta``)
+    return (o,) + said[1:], ((q, k_n, v, k_r, q_i, k_i, w),
+                             o if lse is not None else None, lse, packed)
+
+
+def _walk_rule_bwd(how: _Walk, kept, cotangents):
+    """The walk's backward: a block's attention backward over the kept
+    choice, log-sum-exp and output, the heads' summed probabilities out of
+    that call, and the index term's gradient through the scores from them
+    (``jax.vjp`` of the scores and the term: the scores' forward and
+    backward calls, or whatever stands in ``index_scores``' place). No
+    attention forward and no ``choose`` runs here. The keys' gradients are
+    summed over a tier's blocks in the arrays' own dtype, as a scan's
+    transposition sums them."""
+    inputs, o, lse, packed = kept
+    d_o, d_kl = cotangents[:2]
+    q, k_n, v, k_r, q_i, k_i, w = inputs
+    s, H, _ = q.shape
+    G = k_n.shape[-2]
+    grouped = k_r is None
+    at = _lay_out(how, *inputs)
+    per_tier = s // how.block // how.tiers
+
+    with jax.named_scope("flash_sparse"):
+        # the blocks' cotangents as the forward laid their outputs out
+        d_out = d_o.reshape(-1, how.block, H, d_o.shape[-1])
+        if at.tile:
+            delta = (d_o.astype(jnp.float32) * o.astype(jnp.float32)).sum(-1)
+            delta = delta.reshape(-1, how.block, H)
+            if grouped:
+                d_out = jnp.transpose(d_out.reshape(
+                    d_out.shape[:2] + (G, H // G, -1)), (0, 2, 4, 3, 1))
+                d_out = d_out.reshape(d_out.shape[:3] + (-1,))
+                delta = jnp.transpose(delta.reshape(
+                    delta.shape[:2] + (G, H // G)), (0, 2, 3, 1))
+                delta = delta.reshape(delta.shape[:2] + (-1,))
+            else:
+                d_out = jnp.transpose(d_out, (0, 2, 3, 1))
+                delta = jnp.swapaxes(delta, 1, 2)
+            delta = delta.reshape((how.tiers, per_tier) + delta.shape[1:])
+        d_out = d_out.reshape((how.tiers, per_tier) + d_out.shape[1:])
+
+    def one_block(g, sums, args):
+        q_b, qi_b, w_b, first, packed_b, dout_b, *stat = args
+        keys = at.keys[g]
+        with jax.named_scope("dsa_select"):
+            chosen = _unpack(packed_b, at.ends[g])
+        grads, p = _block_attend(how, at.tile, q_b, chosen, first, keys,
+                                 back=(dout_b, *stat))
+        target = kl_target(p)
+        _, pull = jax.vjp(
+            lambda qi_b, ki_t, w_b: _index_term(
+                _block_scores(qi_b, ki_t, w_b, first), chosen, target),
+            qi_b, at.ki[g], w_b)
+        dqi, dki, dw = pull(d_kl)
+        dq, *dkeys = grads
+        sums = jax.tree.map(lambda a, d: a + d.astype(a.dtype), sums,
+                            (dkeys, dki))
+        return sums, (dq, dqi, dw)
+
+    parts = [jax.lax.scan(
+        functools.partial(one_block, g),
+        jax.tree.map(jnp.zeros_like, (list(at.keys[g][:3]), at.ki[g])),
+        (at.q[g], at.qi[g], at.w[g], at.firsts[g], packed[g], d_out[g])
+        + ((lse[g], delta[g]) if at.tile else ()))
+        for g in range(how.tiers)]
+
+    def whole(xs, axis=0):
+        """The tiers' sums over their keys [.., S', ..] -> one over all
+        keys [.., s, ..]."""
+        return sum(jnp.pad(x, [(0, s - x.shape[a] if a == axis else 0)
+                               for a in range(x.ndim)]) for x in xs)
+
+    with jax.named_scope("flash_sparse"):
+        dq = jnp.concatenate([ys[0] for _, ys in parts])
+        heads_first = int(at.tile is not None)
+        if heads_first:
+            # [blocks, H, block, d] -> [s, H, d]
+            dq = jnp.swapaxes(dq, 1, 2)
+        dq = dq.reshape(q.shape)
+        of_keys, of_index = zip(*(sums for sums, _ in parts))
+        dkn, dv = (whole([tier[i] for tier in of_keys], heads_first)
+                   for i in range(2))
+        dkr = None if grouped else whole([tier[2] for tier in of_keys])
+        if heads_first:
+            dkn, dv = jnp.swapaxes(dkn, 0, 1), jnp.swapaxes(dv, 0, 1)
+    dki = whole(of_index)
+    dqi, dw = (jnp.concatenate([ys[i] for _, ys in parts]).reshape(x.shape)
+               for i, x in ((1, q_i), (2, w)))
+    return dq, dkn, dv, dkr, dqi, dki, dw
+
+
+_walk_rule.defvjp(_walk_rule_fwd, _walk_rule_bwd)
+
+
+def _walk(q, k_n, v, k_r, q_i, k_i, w, *, scale: float, topk: int,
+          block: int, tiers: int, keep_choice: bool, named: bool = False):
+    """One sequence: q [s, H, d_n + d_r], k_n [s, H, d_n], v [s, H, d_v],
+    k_r [s, d_r], or under grouped keys q [s, H, d], k_n [s, G, d], v [s,
+    G, d_v] and ``k_r`` None; q_i [s, J, d_i], k_i [s, d_i], w [s, J] -> (o
+    [s, H, d_v], the sequence's sum of KL terms, pairs chosen, and under
+    ``keep_choice`` the choice packed eight keys a byte [s, s / 8]).
+    ``block`` and ``tiers`` are ``walk_plan``'s. A rule of its own
+    (``custom_vjp``): the forward is a ``lax.map`` a tier over its blocks,
+    the backward a scan a tier over the same blocks that reads what the
+    forward kept (``KEPT_NAMES``) and runs no block's forward again."""
+    return _walk_rule(_Walk(float(scale), topk, block, tiers, keep_choice,
+                            named), q, k_n, v, k_r, q_i, k_i, w)
 
 
 def sparse_attention(q, k_n, v, k_r, q_i, k_i, w, *, scale: float,
                      topk: int, block: int = 256, tiers: int = 4,
-                     mesh=None, keep_choice: bool = False):
+                     mesh=None, keep_choice: bool = False,
+                     named: bool = False):
     """Attention of q [b, s, H, d_n + d_r] over the keys the index chooses
     for each position (the module's docstring): keys ``[k_n | k_r]`` (k_n
     [b, s, H, d_n], k_r [b, s, d_r] shared by the heads), values v [b, s,
@@ -1390,8 +1730,18 @@ def sparse_attention(q, k_n, v, k_r, q_i, k_i, w, *, scale: float,
     each sequence's sum over its positions of ``KL(p_t || softmax_{S_t}
     I)``; ``pairs [b]`` int32, the pairs chosen; under ``keep_choice`` the
     choice packed eight keys a byte, uint8 [b, s, s / 8], key ``8 i + j``
-    the bit ``7 - j`` of byte ``i``). Under a mesh each chip walks its own
-    rows of the batch, as ``mla._attend`` does."""
+    the bit ``7 - j`` of byte ``i``: ``jnp.packbits``' layout, which the
+    benchmark's references read with ``jnp.unpackbits``; the walk's own
+    kept choice lies in ``_pack``'s planes, 0.065 ms a block of 256 x
+    16,384 on the chip for ``packbits``' 0.111, PR 58, and the measured
+    step hands no choice out). ``named``: what the walk keeps for its
+    backward carries ``KEPT_NAMES``, for the policy of a layer's
+    ``jax.checkpoint`` to hold. A caller says so whose ``keeps`` counts
+    ``kept_bytes`` on the ladder's first rung (``llama.attention_part``);
+    ``ops/mla.py``'s latent layers do not yet (ROADMAP S18 (b), the
+    layer's half: their first rung is the two latents, and a name the plan
+    does not count would be held unreckoned). Under a mesh each chip walks
+    its own rows of the batch, as ``mla._attend`` does."""
     b, s, H, _ = q.shape
     grouped = k_r is None
     if grouped and H % k_n.shape[2]:
@@ -1399,6 +1749,7 @@ def sparse_attention(q, k_n, v, k_r, q_i, k_i, w, *, scale: float,
                          f"{H} query heads")
     widths = Widths.of(q, k_n, v, q_i, grouped)
     blk, trs = walk_plan(s, block, tiers, widths)
+    attend = attend_plan(blk, s // trs, k_n.shape[-1], v.shape[-1])
     with tracing.span("rtpu.dsa.shapes", keep=True,
                       attend_layout="grouped" if grouped else "per_head",
                       kv_groups=k_n.shape[2],
@@ -1410,8 +1761,18 @@ def sparse_attention(q, k_n, v, k_r, q_i, k_i, w, *, scale: float,
                           walk_needs(blk, s // trs, widths).values(),
                           default=0),
                       **scores_plan(blk, s // trs, *q_i.shape[2:]),
-                      **attend_plan(blk, s // trs, k_n.shape[-1],
-                                    v.shape[-1]),
+                      **attend,
+                      # how often the walk's own rule runs a block's
+                      # attention forward a step and layer (XLA's form
+                      # forms its products again in the backward), and what
+                      # it keeps for that. The rule's count, not the
+                      # step's: a layer whose ``jax.checkpoint`` holds none
+                      # of ``KEPT_NAMES`` (dots3's) runs the rule's forward
+                      # once more; ``tools/step_program.py`` counts the
+                      # compiled step's calls
+                      block_forwards=1 if attend["attend_tile"] else 2,
+                      kept_bytes_a_layer=b * kept_bytes(
+                          s, blk, trs, widths, bool(attend["attend_tile"])),
                       pairs_scored=b * s * (s + 1) // 2,
                       pairs_chosen=b * sum(min(t + 1, topk)
                                            for t in range(s))):
@@ -1420,9 +1781,17 @@ def sparse_attention(q, k_n, v, k_r, q_i, k_i, w, *, scale: float,
     def rows(*a):
         if grouped:
             a = a[:3] + (None,) + a[3:]
-        return jax.vmap(lambda *r: _walk(
-            *r, scale=scale, topk=topk, block=blk, tiers=trs,
-            keep_choice=keep_choice))(*a)
+
+        # a row a call of the walk, whatever the batch: the same program a
+        # row as a batch of one's (what the cells run and the chip timed;
+        # under ``jax.vmap`` the walk's scans and calls read 3.3 s more of
+        # trace on the bench host, PERF.md 6, PR 58), and a second row's
+        # kernels are the first's (``_traced_once``)
+        said = [_walk(*(x if x is None else x[i] for x in a), scale=scale,
+                      topk=topk, block=blk, tiers=trs,
+                      keep_choice=keep_choice, named=named)
+                for i in range(a[0].shape[0])]
+        return tuple(jnp.stack(xs) for xs in zip(*said))
 
     args = tuple(x for x in (q, k_n, v, k_r, q_i, k_i, w) if x is not None)
     if mesh is None:

@@ -20,7 +20,7 @@ gradients lie from XLA's form in float32 at the highest matmul precision.
   inputs).
 - ``step_ms``: what a train step spends in them over ``--layers`` layers,
   three forwards and a backward a layer (the forward, the layer's remat and
-  the block's own ``jax.checkpoint``).
+  the one the index term's gradient forms in the walk's backward).
 
 ``--kernels tile,rows`` sets ``SCORE_TILE`` and ``SCORE_ROWS`` and may be
 given again; without it the module's constants are read alone. Every array
@@ -190,8 +190,9 @@ def sweep_walk(a, block: int, widths) -> dict:
     """The op whole, one layer: ``dsa.sparse_attention`` forward and the
     gradient of ``sum(out * do) + kl`` to its seven inputs at ``block``
     queries a block (the guard lifted); ``step_ms`` = ``--layers`` x
-    (``forward_ms`` + ``grad_ms``), three forwards and a backward a
-    layer."""
+    (``forward_ms`` + ``grad_ms``): the forward, the layer's remat of it
+    and the walk's backward, which runs no block's forward again (PR
+    58)."""
     import jax
     import jax.numpy as jnp
 
@@ -231,8 +232,8 @@ def sweep_select(a, block: int) -> dict:
     """The choice alone: ``dsa.choose`` of every block of the walk over its
     tier's keys from float32 scores ``[block, keys]`` (one array of them,
     moved by the block's place so that no two blocks' are equal), forward
-    alone; ``step_ms`` = ``--layers`` x 3 ``forward_ms`` (the forward, the
-    layer's remat, the block's own ``jax.checkpoint``)."""
+    alone; ``step_ms`` = ``--layers`` x 2 ``forward_ms`` (the forward and
+    the layer's remat: the walk's backward reads the kept choice)."""
     import jax
     import jax.numpy as jnp
 
@@ -257,22 +258,24 @@ def sweep_select(a, block: int) -> dict:
         return total
 
     out = {"forward_ms": _ms(jax.jit(forward), (scores, firsts), a.calls)}
-    out["step_ms"] = a.layers * 3 * out["forward_ms"]
+    out["step_ms"] = a.layers * 2 * out["forward_ms"]
     print(json.dumps({"select": dict(out, block=block)}), flush=True)
     return out
 
 
 def sweep_attend(a, block: int) -> dict:
     """The attention over the choice alone (``dsa.attend_kernels`` beside
-    ``dsa.plain_attend``): one layer's walk as ``dsa._walk`` makes it, the
-    blocks under ``jax.checkpoint`` in ``lax.map`` a tier, the choice
+    ``dsa.plain_attend``): one layer's walk with the blocks under
+    ``jax.checkpoint`` in ``lax.map`` a tier (as ``dsa._walk`` made it
+    before it kept a block's forward, PR 58), the choice
     planted (``--topk`` keys a query at random among the causal ones).
     ``forward_ms``: out and the heads' summed probabilities of every block;
-    ``grad_ms``: the four gradients of the walk (a forward, the blocks'
+    ``grad_ms``: the four gradients of this walk (a forward, the blocks'
     forward again, their backward, and the sums of the blocks' ``dk`` and
     ``dv`` into the tiers'); ``backward_ms`` = ``grad_ms`` - 2
-    ``forward_ms``; ``step_ms`` = ``--layers`` x (``forward_ms`` +
-    ``grad_ms``), three forwards and a backward a layer."""
+    ``forward_ms``; ``step_ms`` = ``--layers`` x ``grad_ms``: two forwards
+    (the forward, the layer's remat) and a backward a layer, what
+    ``dsa._walk`` runs now."""
     import jax
     import jax.numpy as jnp
 
@@ -317,7 +320,7 @@ def sweep_attend(a, block: int) -> dict:
                 q_b, do_b, first = args
                 chosen = chosen_of(planted, first, end)
                 if at:
-                    out, p = dsa.attend_kernels(q_b, *keys[:3], chosen,
+                    out, _, p = dsa.attend_kernels(q_b, *keys[:3], chosen,
                                                 first, scale, tile, keys[3])
                 else:
                     out, p = dsa.plain_attend(q_b, *keys, chosen, scale)
@@ -352,7 +355,7 @@ def sweep_attend(a, block: int) -> dict:
             if tile is None:
                 out, p = dsa.plain_attend(q_b, kn, v, kr, chosen, scale)
                 return out, p.sum(0)
-            out, p = dsa.attend_kernels(
+            out, _, p = dsa.attend_kernels(
                 *(jnp.swapaxes(x, 0, 1) for x in (q_b, kn, v)), kr, chosen,
                 None, scale, tile)
             return jnp.transpose(out, (2, 0, 1)), p
@@ -374,7 +377,7 @@ def sweep_attend(a, block: int) -> dict:
             out["forward_ms"] = _ms(forward, half, a.calls)
             out["grad_ms"] = _ms(grad, half, a.calls)
             out["backward_ms"] = out["grad_ms"] - 2 * out["forward_ms"]
-            out["step_ms"] = a.layers * (out["forward_ms"] + out["grad_ms"])
+            out["step_ms"] = a.layers * out["grad_ms"]
             if want is not None:
                 got = jax.jit(functools.partial(one_block, tile))(
                     *(x.astype(bf16) for x in (q[rows], kn, v, kr, do[rows])))

@@ -36,8 +36,17 @@ the kernels' "index_map" or the walk's "repeat"; how the kernels'
 taps, from ``rtpu.gdn.rule_plan`` and ``rtpu.gdn.conv_plan``, ``embed_plan``: the token gather's and the form
 of its gradient, from ``rtpu.embed.plan``, ``latent_plan`` and ``mtp_plan``:
 a mixture in a latent's and a prediction module's, from
-``rtpu.moe.latent_plan`` and ``rtpu.train.mtp_plan``, and ``scopes``: how many
-instructions carry each ``jax.named_scope`` name as the innermost).
+``rtpu.moe.latent_plan`` and ``rtpu.train.mtp_plan``, ``scopes``: how many
+instructions carry each ``jax.named_scope`` name as the innermost, and
+``mosaic_fused``: the Mosaic calls XLA folded into a fusion with a
+consumer, by name (a device trace then shows ``fusion.N`` under the
+consumer's scope and no such call: dots3's second ``dsa_attend_fwd`` a
+layer, PR 58), and
+``trace_s``, ``lower_s``, ``compile_s`` and ``mosaic_lowerings``: the
+seconds this machine took to trace the step and to lower it, and how often
+each Mosaic call's body was lowered, by the call's name: what a change
+does to a cell's ``setup_trace_lower_s``, read here before a chip reads
+it; the bench host takes about 2.5 times the seconds, PR 58).
 ``--compare`` judges the program (``PROGRAM_FIELDS``) and says of two
 differing programs how many lines changed and how many of those are calls
 of the flash kernels.
@@ -52,6 +61,7 @@ import json
 import os
 import re
 import sys
+import time
 
 V5E_BYTES_LIMIT = int(15.75 * 2 ** 30)
 MEMORY_FIELDS = ("argument_size_in_bytes", "output_size_in_bytes",
@@ -163,8 +173,26 @@ def _compile_cell(tree: str, cell: dict, topo) -> dict:
             return optax.apply_updates(params, updates), opt, loss
 
     here = tracing.since()
-    compiled = jax.jit(step, donate_argnums=(0, 1)).lower(
-        params, opt, batch).compile()
+    lowerings, clock = {}, [time.perf_counter()]
+
+    def counted(ctx, *nodes, name=None, **params):
+        lowerings[str(name)] = lowerings.get(str(name), 0) + 1
+        return lower_call(ctx, *nodes, name=name, **params)
+
+    from jax._src.pallas.mosaic import pallas_call_registration as mosaic
+    lower_call = mosaic.pallas_call_tpu_lowering_rule
+    mosaic.pallas_call_tpu_lowering_rule = counted
+    try:
+        compiled = jax.jit(step, donate_argnums=(0, 1)).trace(
+            params, opt, batch)
+        for stage in ("lower", "compile"):
+            clock.append(time.perf_counter())
+            compiled = getattr(compiled, stage)()
+        clock.append(time.perf_counter())
+    finally:
+        mosaic.pallas_call_tpu_lowering_rule = lower_call
+    trace_s, lower_s, compile_s = (
+        round(b - a, 2) for a, b in zip(clock, clock[1:]))
     events = here.events()
     plans = [{k: v for k, v in e["args"].items()
               if k not in ("id", "parent", "self_us")} for e in events
@@ -197,6 +225,14 @@ def _compile_cell(tree: str, cell: dict, topo) -> dict:
     kernels = sorted(set(re.findall(
         r'%([\w.-]+?)(?:\.\d+)? = [^\n]*custom_call_target="tpu_custom_call"',
         full)))
+    # the calls XLA folded into a fusion of its own, by the call's name
+    fused, inside = {}, False
+    for line in full.splitlines():
+        if line[:1] not in (" ", "}", ""):
+            inside = line.lstrip("%").startswith("fused_computation")
+        elif inside and 'custom_call_target="tpu_custom_call"' in line:
+            name = re.match(r"\s*(?:ROOT )?%([A-Za-z_]+)", line).group(1)
+            fused[name] = fused.get(name, 0) + 1
     text = strip_metadata(full)
     ma = compiled.memory_analysis()
     # what the plan's need is held against (tests/test_tpu_compile.py)
@@ -212,6 +248,7 @@ def _compile_cell(tree: str, cell: dict, topo) -> dict:
                       allotted / plans[0]["need_bytes"], 4)}
         if plans else None,
         "mosaic_kernels": kernels,
+        "mosaic_fused": dict(sorted(fused.items())),
         "flash_tiles": tiles,
         "scan_plan": scans,
         "conv_plan": taps,
@@ -221,6 +258,8 @@ def _compile_cell(tree: str, cell: dict, topo) -> dict:
         "latent_plan": latents,
         "mtp_plan": modules,
         "scopes": dict(sorted(scopes.items())),
+        "trace_s": trace_s, "lower_s": lower_s, "compile_s": compile_s,
+        "mosaic_lowerings": dict(sorted(lowerings.items())),
         "memory_analysis": {f: getattr(ma, f) for f in MEMORY_FIELDS}}}
 
 

@@ -600,9 +600,12 @@ def _keye_vl2_preset(keye_vl2):
 
 
 def _keye_vl2_rungs(kinds):
-    # an index layer keeps the walk's output on the first rung (no
-    # log-sum-exp: the blocks run again) and its q, k, v on the second
-    assert kinds["sparse_moe"]["rungs"][0] == 48 * 4 * 16 * 2
+    # an index layer keeps what its walk keeps on the first rung (the
+    # output, the heads' log-sum-exp float32 and the choice packed over
+    # each of four tiers' keys: 12 rows of 2, 3, 5 and 6 bytes) and its q,
+    # k, v on the second
+    assert kinds["sparse_moe"]["rungs"][0] == (
+        48 * 4 * (16 * 2 + 4) + 12 * (2 + 3 + 5 + 6))
     assert kinds["sparse_moe"]["rungs"][1] == 48 * (4 + 2 * 2) * 16 * 2
 
 

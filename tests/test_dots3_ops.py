@@ -133,7 +133,7 @@ def test_the_plan_takes_256_queries_a_block_at_the_cells_shapes(monkeypatch):
     assert needs == {"dsa_scores_fwd": 18_350_080,
                      "dsa_scores_bwd": 44_302_336,
                      "dsa_attend_fwd": 18_939_904,
-                     "dsa_attend_bwd": 32_833_536}
+                     "dsa_attend_bwd": 33_882_112}
     assert max(needs.values()) <= dsa.VMEM_CEILING == 64 << 20
     assert dsa.walk_needs(512, 4096, CELL)["dsa_scores_bwd"] == 87_818_240
     assert dsa.walk_plan(16384, 512, 4, CELL) == (256, 4)

@@ -48,7 +48,7 @@ def _kernel(chosen, first, tile, scale=0.2):
     """The same through ``attend_kernels``, which takes and hands out
     arrays that lie heads first."""
     def attend(q, kn, v, kr):
-        out, p = dsa.attend_kernels(
+        out, _, p = dsa.attend_kernels(
             *(jnp.swapaxes(x, 0, 1) for x in (q, kn, v)), kr, chosen, first,
             scale, tile)
         return jnp.transpose(out, (2, 0, 1)), p
@@ -238,7 +238,7 @@ def test_the_walk_with_both_kernels_is_the_walk_with_xlas_forms(
         real = dsa.attend_kernels
         # as the walk passes them: (.., chosen, first, scale, tile, v_t)
         monkeypatch.setattr(dsa, "attend_kernels",
-                            lambda *a: seen.append(a[5:8]) or real(*a))
+                            lambda *a, **more: seen.append(a[5:8]) or real(*a, **more))
         got = walk(*args, keep_choice=True)
         got_grads = jax.grad(loss, argnums=tuple(range(7)))(*args)
     assert seen and all(first is not None and tile == 64
@@ -405,3 +405,134 @@ def test_the_span_says_what_the_guard_held_the_block_to(kernels,
     assert span() == (64, 128, 2, at[64])
     monkeypatch.setattr(dsa, "VMEM_CEILING", at[64] - 1)
     assert span() == (32, 128, 2, at[32])
+
+
+# ---- the walk's rule of its own (PR 58): what a block's forward computed
+# is kept, and the backward reads it
+
+
+def _walk_loss(walk, args, g, **how):
+    """(outputs with the choice, the gradients to every array of ``args``)
+    of ``(o g).sum() + 0.3 kl.sum()`` through ``walk``, jitted."""
+    at = tuple(i for i, x in enumerate(args) if x is not None)
+
+    def loss(*a):
+        full = list(args)
+        for i, x in zip(at, a):
+            full[i] = x
+        o, kl, pairs, choice = walk(*full, **how)
+        return (o * g).sum() + 0.3 * kl.sum(), (o, kl, pairs, choice)
+
+    (_, said), grads = jax.jit(jax.value_and_grad(
+        loss, argnums=tuple(range(len(at))), has_aux=True))(
+            *(args[i] for i in at))
+    return said, grads
+
+
+def check_against_the_checkpointed_walk(args, how, exact):
+    from tests.dsa_reference import checkpointed_walk
+
+    g = jax.random.normal(jax.random.PRNGKey(9), args[2].shape[:2] + (
+        args[0].shape[2], args[2].shape[3]))
+    with jax.default_matmul_precision("highest"):
+        want, want_grads = _walk_loss(checkpointed_walk, args, g, **how)
+        got, got_grads = _walk_loss(functools.partial(
+            dsa.sparse_attention, keep_choice=True), args, g, **how)
+    np.testing.assert_array_equal(got[3], want[3])
+    np.testing.assert_array_equal(got[2], want[2])
+    tol = dict(rtol=1e-6, atol=1e-6) if exact else dict(rtol=1e-3, atol=1e-5)
+    for a, b in zip(got[:2] + got_grads, want[:2] + want_grads):
+        np.testing.assert_allclose(a, b, **tol)
+
+
+@pytest.mark.parametrize("s,block,tiers,topk", [
+    (36, 12, 3, 5), (48, 8, 2, 6), (40, 20, 2, 40)],
+    ids=["a-ragged-byte-a-tier", "three-blocks-a-tier", "every-causal-key"])
+def test_xlas_walk_is_the_checkpointed_walk(s, block, tiers, topk):
+    """XLA's forms under the walk's own rule against the walk that
+    checkpoints each block and lets jax differentiate it: the choice and
+    the pairs bit for bit, the output, the term and all seven gradients to
+    float32's last digits, where a tier's keys are no whole bytes of the
+    packed choice (12 and 20 keys) and where a tier holds three blocks."""
+    check_against_the_checkpointed_walk(
+        _attention_inputs(2, s), dict(scale=0.3, topk=topk, block=block,
+                                      tiers=tiers), exact=True)
+
+
+def test_the_kernels_walk_is_the_checkpointed_walk(kernels):
+    """Both pairs of kernels under the rule, blocks of 32 queries in two
+    tiers of two blocks, tiles of 64 keys: against the checkpointed walk of
+    XLA's forms."""
+    kernels(32, 16, attend=(64, 32))
+    check_against_the_checkpointed_walk(
+        _wide_inputs(2, 128), dict(scale=0.2, topk=24, block=32, tiers=2),
+        exact=False)
+
+
+def _gradient_jaxpr(args, policy=None, **how):
+    """The jaxpr of the gradient of a layer that is the walk (``named``)
+    under ``jax.checkpoint(policy=)``, or bare."""
+    at = tuple(i for i, x in enumerate(args) if x is not None)
+
+    def layer(*a):
+        full = list(args)
+        for i, x in zip(at, a):
+            full[i] = x
+        o, kl, _ = dsa.sparse_attention(*full, **how, named=True)
+        return (o ** 2).sum() + kl.sum()
+
+    if policy is not None:
+        layer = jax.checkpoint(layer, policy=policy)
+    return jax.make_jaxpr(jax.grad(layer, argnums=tuple(range(len(at)))))(
+        *(args[i] for i in at))
+
+
+def test_the_gradient_runs_each_forward_call_once_a_tier(kernels):
+    """The jaxpr of the walk's gradient, two rows of two tiers: the
+    attention's forward call once a tier and row and its backward call once
+    a tier and row; the scores' forward once a tier and row forward and
+    once more where the index term's gradient forms the scores; one span
+    says so (``block_forwards`` 1,
+    ``kept_bytes_a_layer``: the packed choice [32 x 8 + 32 x 16 bytes a
+    tier's rows], the log-sum-exp and the output of two rows)."""
+    from jax.ad_checkpoint import checkpoint_policies as policies
+
+    from ray_tpu.util import tracing
+    from tests.dsa_reference import mosaic_calls
+
+    kernels(32, 16, attend=(64, 32))
+    args = _wide_inputs(2, 128)
+    how = dict(scale=0.2, topk=24, block=32, tiers=2)
+    here = tracing.since()
+    assert mosaic_calls(_gradient_jaxpr(args, **how)) == {
+        "dsa_attend_fwd": 4, "dsa_attend_bwd": 4, "dsa_scores_fwd": 8,
+        "dsa_scores_bwd": 4}
+    (said,) = [e["args"] for e in here.events()
+               if e["name"] == "rtpu.dsa.shapes"]
+    assert said["block_forwards"] == 1
+    assert said["kept_bytes_a_layer"] == 2 * (
+        64 * 8 + 64 * 16 + 128 * 2 * (4 + 32 * 4))
+    # a layer's policy that holds the kept names runs no forward call for
+    # its backward; one that holds nothing runs the forward once more
+    held = mosaic_calls(_gradient_jaxpr(
+        args, policies.save_only_these_names(*dsa.KEPT_NAMES), **how))
+    assert (held["dsa_attend_fwd"], held["dsa_attend_bwd"]) == (4, 4)
+    bare = mosaic_calls(_gradient_jaxpr(
+        args, policies.nothing_saveable, **how))
+    assert (bare["dsa_attend_fwd"], bare["dsa_attend_bwd"]) == (8, 4)
+
+
+def test_xlas_form_says_it_forms_its_products_twice():
+    """On the CPU the attention is XLA's: the rule keeps the choice alone
+    and its backward differentiates ``plain_attend`` where it stands, so
+    ``block_forwards`` reads 2 and the kept bytes are the packed choice."""
+    from ray_tpu.util import tracing
+
+    here = tracing.since()
+    jax.eval_shape(functools.partial(
+        dsa.sparse_attention, scale=0.3, topk=8, block=16, tiers=2),
+        *_attention_inputs(1, 64))
+    (said,) = [e["args"] for e in here.events()
+               if e["name"] == "rtpu.dsa.shapes"]
+    assert said["block_forwards"] == 2
+    assert said["kept_bytes_a_layer"] == 32 * 4 + 32 * 8

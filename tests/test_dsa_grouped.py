@@ -59,7 +59,7 @@ def _kernel(chosen, first, tile, scale=0.2):
     def attend(q, k, v):
         n, H, d = q.shape
         G = k.shape[1]
-        out, p = dsa.attend_kernels_grouped(
+        out, _, p = dsa.attend_kernels_grouped(
             jnp.swapaxes(q, 0, 1).reshape(G, H // G * n, d),
             jnp.swapaxes(k, 0, 1), jnp.swapaxes(v, 0, 1), chosen, first,
             scale, tile)
@@ -205,7 +205,8 @@ def test_the_grouped_walk_with_both_kernels_is_the_walk_with_xlas_forms(
         # as the walk passes them: (q, k, v, chosen, first, scale, tile, v_t)
         monkeypatch.setattr(
             dsa, "attend_kernels_grouped",
-            lambda *a: seen.append((a[0].shape, a[4], a[6])) or real(*a))
+            lambda *a, **more: seen.append((a[0].shape, a[4], a[6]))
+            or real(*a, **more))
         monkeypatch.setattr(dsa, "attend_kernels", None)
         got = walk(*arrays, keep_choice=True)
         got_grads = jax.grad(loss, argnums=tuple(range(6)))(*arrays)
@@ -283,3 +284,57 @@ def test_the_scores_kernels_take_an_index_of_half_a_lanes_width(kernels):
                         argnums=(0, 1, 2))(*args)
     for name, a, b in zip(("dq_i", "dk_i", "dw"), gots, wants):
         np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-5, err_msg=name)
+
+
+# ---- the walk's rule of its own (PR 58) under grouped keys
+
+
+@pytest.mark.parametrize("s,block,tiers,topk", [
+    (36, 12, 3, 5), (48, 8, 2, 6)],
+    ids=["a-ragged-byte-a-tier", "three-blocks-a-tier"])
+def test_xlas_grouped_walk_is_the_checkpointed_walk(s, block, tiers, topk):
+    """``test_xlas_walk_is_the_checkpointed_walk`` under grouped keys."""
+    from tests.test_dsa_attend_kernels import (
+        check_against_the_checkpointed_walk)
+
+    check_against_the_checkpointed_walk(
+        _walk_inputs(2, s), dict(scale=0.3, topk=topk, block=block,
+                                 tiers=tiers), exact=True)
+
+
+def test_the_grouped_kernels_walk_is_the_checkpointed_walk(kernels):
+    """Both pairs of kernels under the rule, grouped keys, blocks of 32
+    queries in two tiers of two blocks, against the checkpointed walk of
+    XLA's forms."""
+    from tests.test_dsa_attend_kernels import (
+        check_against_the_checkpointed_walk)
+
+    kernels(32, 16, attend=(64, 32))
+    check_against_the_checkpointed_walk(
+        _walk_inputs(2, 128), dict(scale=0.2, topk=24, block=32, tiers=2),
+        exact=False)
+
+
+def test_the_grouped_gradient_runs_each_forward_call_once_a_tier(kernels):
+    """The jaxpr of the grouped walk's gradient, two tiers: the grouped
+    forward call once a tier, bare and under a layer's policy that holds
+    ``KEPT_NAMES``; twice under a policy that holds nothing; the per-head
+    calls never."""
+    from jax.ad_checkpoint import checkpoint_policies as policies
+
+    from tests.dsa_reference import mosaic_calls
+    from tests.test_dsa_attend_kernels import _gradient_jaxpr
+
+    kernels(32, 16, attend=(64, 32))
+    args = _walk_inputs(1, 128)
+    how = dict(scale=0.2, topk=24, block=32, tiers=2)
+    assert mosaic_calls(_gradient_jaxpr(args, **how)) == {
+        "dsa_attend_gqa_fwd": 2, "dsa_attend_gqa_bwd": 2,
+        "dsa_scores_fwd": 4, "dsa_scores_bwd": 2}
+    held = mosaic_calls(_gradient_jaxpr(
+        args, policies.save_only_these_names(*dsa.KEPT_NAMES), **how))
+    bare = mosaic_calls(_gradient_jaxpr(
+        args, policies.nothing_saveable, **how))
+    assert held["dsa_attend_gqa_fwd"] == 2 and bare[
+        "dsa_attend_gqa_fwd"] == 4
+    assert held["dsa_attend_gqa_bwd"] == bare["dsa_attend_gqa_bwd"] == 2

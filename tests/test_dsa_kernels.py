@@ -276,3 +276,79 @@ def test_the_span_says_which_form_scored(kernels):
     kernels(32, 64)         # a block is not whole chunks of 64 queries
     (said,) = spans()
     assert (said["scores_form"], said["scores_tile"]) == ("xla", None)
+
+
+# ---- every Mosaic call of the walk is traced once a distinct shape, and
+# what a control plants above the cache still takes effect (PR 58)
+
+
+def test_two_equal_layers_trace_each_kernel_body_once_a_shape(kernels,
+                                                              monkeypatch):
+    """A step of two layers of the same shapes, each the walk under a
+    layer's ``jax.checkpoint`` that holds nothing (so the forward is traced
+    as the rule's primal, as its ``fwd`` and in the layer's recomputation),
+    two tiers: each of the four kernel bodies is traced once a tier, twice
+    in all, whoever calls it."""
+    from tests.dsa_reference import clear_call_caches
+
+    kernels(32, 16, attend=(64, 32))
+    clear_call_caches()
+    traced = {}
+    for name in ("_scores_fwd_kernel", "_scores_bwd_kernel",
+                 "_attend_fwd_kernel", "_attend_bwd_kernel"):
+        def body(*a, _real=getattr(dsa, name), _name=name, **k):
+            traced[_name] = traced.get(_name, 0) + 1
+            return _real(*a, **k)
+        monkeypatch.setattr(dsa, name, body)
+    args = _attention_inputs(1, 128, dn=32, dr=16, dv=32)
+
+    @jax.checkpoint
+    def layer(q, *rest):
+        o, kl, _ = dsa.sparse_attention(q, *rest, scale=0.2, topk=24,
+                                        block=32, tiers=2)
+        return q + jnp.pad(o, ((0, 0),) * 3 + ((0, 16),)), kl.sum()
+
+    def step(q, *rest):
+        q, kl_0 = layer(q, *rest)
+        q, kl_1 = layer(q, *rest)
+        return (q ** 2).sum() + kl_0 + kl_1
+
+    jax.make_jaxpr(jax.grad(step, argnums=tuple(range(7))))(*args)
+    assert traced == {"_scores_fwd_kernel": 2, "_scores_bwd_kernel": 2,
+                      "_attend_fwd_kernel": 2, "_attend_bwd_kernel": 2}
+    clear_call_caches()
+
+
+def test_a_plant_after_an_honest_trace_still_takes_effect(kernels,
+                                                          monkeypatch):
+    """``benchmark/tests/sparse_limits.py`` traces the honest program and
+    then, in the same process, a program with ``dsa.index_scores`` or
+    ``dsa.choose`` replaced: the kernels' traces are cached below those
+    names, so the second program is the planted one, forward and
+    gradient (the term's gradient flows through what was planted)."""
+    kernels(32, 16, attend=(64, 32))
+    args = _attention_inputs(1, 128, dn=32, dr=16, dv=32)
+    how = dict(scale=0.2, topk=8, block=32, tiers=2)
+
+    def read():
+        def loss(*a):
+            o, kl, _ = dsa.sparse_attention(*a, **how)
+            return (o ** 2).sum() + kl.sum()
+
+        return jax.jit(jax.value_and_grad(loss, argnums=(4, 5, 6)))(*args)
+
+    honest, again = read(), read()
+    np.testing.assert_array_equal(honest[0], again[0])
+    real = dsa.choose
+    monkeypatch.setattr(dsa, "choose", lambda scores, first, topk: real(
+        scores, first, scores.shape[-1]))
+    every_key = read()
+    assert not np.allclose(every_key[0], honest[0])
+    monkeypatch.setattr(dsa, "choose", real)
+    monkeypatch.setattr(dsa, "index_scores",
+                        lambda q_i, k_i, w: dsa.plain_scores(q_i, k_i, -w))
+    negated = read()
+    assert not np.allclose(negated[0], honest[0])
+    # the planted scores' own gradient: d/dw of the negated scores
+    assert not np.allclose(negated[1][2], honest[1][2])
+    monkeypatch.undo()

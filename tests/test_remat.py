@@ -411,7 +411,9 @@ def test_remat_plan_is_a_pure_function_of_bytes(stack):
     assert llama.remat_names("level2")[-3:] == ("q_rope", "k_rope", "v_proj")
     assert llama.remat_names("level4")[:2] == ("flash_out", "flash_lse")
     # the first rung names a latent-attention layer's two latents too
-    assert llama.remat_names("level1")[2:] == ("q_latent", "kv_latent")
+    # and an index layer's packed choice (ops/dsa.KEPT_NAMES)
+    assert llama.remat_names("level1")[2:] == ("q_latent", "kv_latent",
+                                               "dsa_choice")
     # bad policy name raises rather than silently training differently,
     # and so do the knobs PR 28 took away
     for gone in ("nope", "save_qkv"):
