@@ -45,7 +45,8 @@ from ray_tpu.util import tracing
 # What a layer's jax.checkpoint keeps besides the layer's input, rung by
 # rung in order of step time saved per byte kept (PERF.md 6, PR 27):
 # checkpoint names given where the values are born (attention_block,
-# ops/attention._flash_fwd, ops/layers.swiglu, ops/moe._swiglu_rows).
+# ops/attention._flash_fwd, ops/layers.swiglu, ops/moe._swiglu_rows,
+# ops/ssm.mamba2_mixer).
 # "level<n>" keeps the names of the first n rungs. The norms and
 # act(gate) * up are recomputed at every level: elementwise and cheap, and
 # as large again as all four rungs.
@@ -54,8 +55,16 @@ REMAT_LADDER = (
     # (ops/mla.py) its two latents besides, a quarter of the rung's bytes
     # there: the backward then reruns the expansions from them and
     # neither down-projection; of an index layer (ops/dsa.py) its packed
-    # choice besides, and its backward runs no block's forward again
-    ("flash_out", "flash_lse", "q_latent", "kv_latent", "dsa_choice"),
+    # choice besides, and its backward runs no block's forward again; of
+    # a scan layer (ops/ssm.py) its in-projection's output, the widest
+    # product of the layer, which the backward then runs three times and
+    # not four: by the ladder's order the scan kind's first, 7.1 ms for
+    # the 304 MB it is at Nemotron's widths and 8,192 tokens, where the
+    # taps' and the scan's second forwards beside it are ~2.7 ms for as
+    # many bytes and stay recomputed, reading the kept array (PERF.md 6,
+    # PR 59)
+    ("flash_out", "flash_lse", "q_latent", "kv_latent", "dsa_choice",
+     "ssm_in"),
     ("q_rope", "k_rope", "v_proj"),     # the q/k/v matmuls and rope
     ("mlp_gate", "mlp_up"),             # the gate and up matmuls, grouped too
     ("attn_resid",),                    # the wo matmul
@@ -751,13 +760,13 @@ def attention_part(heads: str = "num_heads", window: Optional[str] = None,
             blk, trs = dsa.walk_plan(tokens, cfg.index_block,
                                      cfg.index_tiers, widths)
             return kept(
-                flash=dsa.kept_bytes(tokens, blk, trs, widths, True),
+                first=dsa.kept_bytes(tokens, blk, trs, widths, True),
                 qkv=tokens * (qd + 2 * kvd) * act,
                 resid=tokens * cfg.hidden_size * act,
                 width=qd + 2 * kvd + 2 * (J * di + di + 2 * J),
                 rows=dsa.walk_rows(cfg, tokens, widths))
         return kept(
-            flash=tokens * (qd * act + qd // cfg.head_dim_ * 4),  # lse: f32
+            first=tokens * (qd * act + qd // cfg.head_dim_ * 4),  # lse: f32
             qkv=tokens * (qd + 2 * kvd) * act,
             resid=tokens * cfg.hidden_size * act,
             # (a wide gate: the projection's second half and its gradient)

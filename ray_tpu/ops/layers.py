@@ -77,13 +77,16 @@ class Part:
     terms: Optional[Callable] = None
 
 
-def kept(flash: int = 0, qkv: int = 0, mlp: int = 0, resid: int = 0,
+def kept(first: int = 0, qkv: int = 0, mlp: int = 0, resid: int = 0,
          width: int = 0, rows: int = 0) -> Dict[str, Any]:
     """What a part's ``keeps`` returns: the bytes each rung of
-    ``llama.REMAT_LADDER`` keeps in one layer, the elements a token its
+    ``llama.REMAT_LADDER`` keeps in one layer (``first``: what the
+    mixer's kind has on the first rung, an attention layer's kernel output
+    and log-sum-exp, a latent layer's two latents, an index layer's
+    choice, a scan layer's in-projection), the elements a token its
     backward holds (the recomputed forward and the gradients of the
     widest of it) and the bytes it holds that are not a token's."""
-    return {"rungs": (flash, qkv, mlp, resid), "width": width, "rows": rows}
+    return {"rungs": (first, qkv, mlp, resid), "width": width, "rows": rows}
 
 
 def rms_norm(x: jax.Array, weight: jax.Array, eps: float = 1e-6,

@@ -305,7 +305,7 @@ def latent_attention_part(prefix: str = "", rope: Callable = rope_tables,
             H, sz.d_n, dr, dv, shape["wi_w"][-1], shape["wi_k"][-1],
             cfg.dtype)) if index else 0
         return kept(
-            flash=tokens * (shape["wo"][0] * act + H * 4 + latents * act)
+            first=tokens * (shape["wo"][0] * act + H * 4 + latents * act)
             if not index else tokens * latents * act,
             qkv=tokens * (expanded + dr) * act,
             resid=tokens * cfg.hidden_size * act,

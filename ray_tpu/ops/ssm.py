@@ -72,7 +72,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ray_tpu.ops.conv import causal_taps, taps_plan, taps_silu
-from ray_tpu.ops.layers import Leaf, Part, kept, rms_norm
+from ray_tpu.ops.layers import Leaf, Part, checkpoint_name, kept, rms_norm
 from ray_tpu.util import tracing
 
 # decay matrices one step of XLA's walk may put in HBM (float32, before the
@@ -695,15 +695,21 @@ def mamba2_mixer(h: jax.Array, p: Dict[str, jax.Array], *, heads: int,
     No projection has a bias; ``dt`` is not clamped (``time_step_limit``
     (0, inf)). The gated norm is over a group's ``d / norm_groups``
     channels, each group normed on its own after the gate (``nemotron_h``:
-    as many groups as B and C have); one group (Granite) is all ``d``."""
+    as many groups as B and C have); one group (Granite) is all ``d``.
+    The in-projection's output carries the checkpoint name ``ssm_in``,
+    the one value of the mixer a layer's remat level can keep."""
     b, s, _ = h.shape
     dt_ = h.dtype
     d, gn = heads * head_dim, groups * state
     f32 = jnp.float32
     with jax.named_scope("ssm"):
         with jax.named_scope("ssm_in"):
-            zxbcdt = jnp.dot(h, p["m_in"].astype(dt_),
-                             preferred_element_type=f32).astype(dt_)
+            # named for the ladder's first rung (models/llama.py
+            # REMAT_LADDER; inert elsewhere): kept, the backward runs the
+            # product three times a layer (forward, dX, dW) and not four
+            zxbcdt = checkpoint_name(
+                jnp.dot(h, p["m_in"].astype(dt_),
+                        preferred_element_type=f32).astype(dt_), "ssm_in")
             z, dt = zxbcdt[..., :d], zxbcdt[..., 2 * d + 2 * gn:]
             # positions last, as the in-projection's output lies on a TPU
             by_channel = jnp.swapaxes(zxbcdt, 1, 2)
@@ -793,9 +799,15 @@ def mamba2_part(resid: Optional[str] = None,
         return x + out, {"ssm_state": S}
 
     def keeps(cfg, shape, tokens, mesh):
-        # the in-projection's output (z, x B C, dt) and the taps' output
-        # with their gradients, the gated output; beside them what the
-        # scan's form puts in HBM (``scan_plan``). XLA's walk: one step's
+        # The first rung's: the in-projection's output (z, x B C, dt:
+        # ``mamba2_mixer`` names it ``ssm_in``), one array of ``m_in``'s
+        # width a token in the activations' dtype, the same in both of the
+        # scan's forms. Nothing else of a scan layer is named: the taps'
+        # and the scan's second forwards read the kept array.
+        # The working set, which the rung leaves as calibrated: the
+        # in-projection's output and the taps' output with their
+        # gradients, the gated output; beside them what the scan's form
+        # puts in HBM (``scan_plan``). XLA's walk: one step's
         # decay matrices, their product with C B^T in float32 and the
         # activations' dtype and the gradients of those, and the state
         # before every step. Held to the compiled step at 16,384, 24,576
@@ -812,10 +824,13 @@ def mamba2_part(resid: Optional[str] = None,
         state = (shape["m_conv"][0] - d) // (2 * groups)
         plan = scan_plan(1, tokens, heads, d // heads, state, groups,
                          cfg.ssm_chunk, mesh)
+        first = tokens * jnp.dtype(cfg.dtype).itemsize * shape["m_in"][-1]
         if plan["form"] == "pallas":
-            return kept(width=shape["m_in"][-1] + 2 * shape["m_conv"][0],
+            return kept(first=first,
+                        width=shape["m_in"][-1] + 2 * shape["m_conv"][0],
                         rows=plan["float32_bytes_in_hbm"])
         return kept(
+            first=first,
             width=2 * shape["m_in"][-1] + 2 * shape["m_conv"][0] + d,
             rows=4 * plan["decay_bytes_in_hbm"]
             + plan["steps"] * heads * (d // heads) * state * 4)

@@ -290,10 +290,12 @@ def test_scan_kernels_compile_and_the_granite_cells_step_names_them(
     kernels; the scan runs as its kernels, ``KERNEL_HEADS`` heads and
     ``KERNEL_CHUNKS`` chunks of 256 a grid step, a state kept a step; the
     plan reckons more than a v5e's budget at every layer's "full", so no
-    rung is taken, and its need lies within 6% of the 15,429,915,136 bytes
-    the compiler allots that step (``step_program.py``, PR 41: 5.2% over;
-    a reckoning within 3% would lie under the budget and hand the
-    attention layer its first rung, S3c's to do)."""
+    rung is taken (the scans' first, their in-projection's output, 557 MB
+    a layer here, neither), and its need lies within 6% of the
+    15,429,915,136 bytes the compiler allots that step
+    (``step_program.py``, PR 41: 5.2% over; a reckoning within 3% would
+    lie under the budget and hand the attention layer its first rung,
+    S3c's to do)."""
     import json
 
     import optax
@@ -345,6 +347,7 @@ def test_scan_kernels_compile_and_the_granite_cells_step_names_them(
         spans.setdefault(e["name"], []).append(e["args"])
     (plan,) = spans["rtpu.train.remat_plan"]
     assert plan["level"] == {"mamba": "full", "attention": "full"}
+    assert plan["saved_bytes_per_layer"] == {"mamba": 0, "attention": 0}
     assert plan["need_bytes"] > (1 - llama.REMAT_RESERVE) * V5E_LIMIT
     assert 1.0 < plan["need_bytes"] / 15_429_915_136 < 1.06
     steps = 128 // ssm.KERNEL_CHUNKS
@@ -372,8 +375,10 @@ def test_scan_kernels_compile_at_eight_groups_and_the_nemotron_step_lowers(
     taps' pairs, the flash kernels and megablox's; its plans are the kept
     spans (a latent of 1,024 under 4,096 with 8 of 512 experts held, 22 a
     token, 8,448 rows a pass; a module of depth 1 sharing the head); the
-    remat plan reckons the module's two layers with the stack's eleven and
-    fits a v5e; the head is walked twice."""
+    remat plan reckons the module's two layers with the stack's eleven,
+    gives the scans their first rung (the in-projection's output, 304 MB
+    a layer) beside the mixtures' third and the attention layers' fourth,
+    and fits a v5e; the head is walked twice."""
     import json
 
     import optax
@@ -427,6 +432,10 @@ def test_scan_kernels_compile_at_eight_groups_and_the_nemotron_step_lowers(
         spans.setdefault(e["name"], []).append(e["args"])
     (plan,) = spans["rtpu.train.remat_plan"]
     assert plan["layers"] == {"moe": 6, "mamba": 5, "attention": 2}
+    # the scans keep their in-projection's output, 304 MB a layer
+    assert plan["level"] == {"moe": "level3", "mamba": "level1",
+                             "attention": "level4"}
+    assert plan["saved_bytes_per_layer"]["mamba"] == 8192 * 18560 * 2
     assert plan["need_bytes"] < (1 - llama.REMAT_RESERVE) * V5E_LIMIT
     assert {(p["hidden"], p["latent"], p["experts"], p["held"], p["top_k"],
              p["act"], p["rows_a_pass"])

@@ -13,26 +13,41 @@ from ray_tpu.models import gpt2, llama  # noqa: E402
 from ray_tpu.parallel import MeshSpec, build_mesh  # noqa: E402
 
 
-def _count_primitives(jaxpr, counts=None):
-    """Primitive name -> occurrences, through every sub-jaxpr but a Pallas
-    kernel's body (``pallas_call`` counts as one, under its own name)."""
+def _equations(jaxpr):
+    """Every equation of ``jaxpr`` and of its sub-jaxprs but a Pallas
+    kernel's body (a ``pallas_call`` is one equation)."""
     from jax.extend import core as jex_core
 
-    counts = {} if counts is None else counts
     for eqn in jaxpr.eqns:
-        name = eqn.primitive.name
-        if name == "pallas_call":
-            name = "pallas_call:" + eqn.params["name"]
-            counts[name] = counts.get(name, 0) + 1
+        yield eqn
+        if eqn.primitive.name == "pallas_call":
             continue
-        counts[name] = counts.get(name, 0) + 1
         for v in eqn.params.values():
             for sub in v if isinstance(v, (list, tuple)) else (v,):
                 if isinstance(sub, jex_core.ClosedJaxpr):
                     sub = sub.jaxpr
                 if isinstance(sub, jex_core.Jaxpr):
-                    _count_primitives(sub, counts)
+                    yield from _equations(sub)
+
+
+def _count_primitives(jaxpr):
+    """Primitive name -> occurrences (``_equations``; a ``pallas_call``
+    under its own name)."""
+    counts = {}
+    for eqn in _equations(jaxpr):
+        name = eqn.primitive.name
+        if name == "pallas_call":
+            name = "pallas_call:" + eqn.params["name"]
+        counts[name] = counts.get(name, 0) + 1
     return counts
+
+
+def _count_products(jaxpr, width):
+    """The matrix products with a side of ``width``: a projection of that
+    width forward, its dX and its dW."""
+    return sum(eqn.primitive.name == "dot_general" and any(
+        width in v.aval.shape for v in (*eqn.invars, *eqn.outvars))
+        for eqn in _equations(jaxpr))
 
 
 def _tiny_moe(model):
@@ -157,6 +172,69 @@ def test_every_remat_level_matches_no_remat(remat_setup, model, attn,
     assert all(jnp.allclose(a, b, atol=1e-5)
                for a, b in zip(jax.tree_util.tree_leaves(g_want),
                                jax.tree_util.tree_leaves(g_got)))
+
+
+@pytest.mark.parametrize("form", ["xla_walk", "pallas"])
+def test_a_scan_layers_first_rung_matches_no_remat_and_drops_a_product(
+        form, monkeypatch):
+    """Tiny Granite (two scan layers in a scanned run, an attention layer,
+    a scan layer walked alone) at "level1", in both of the scan's forms
+    (the kernels through the Pallas interpreter): loss and every leaf's
+    gradient equal ``remat=False``; the gradient's jaxpr, unrolled, holds
+    three products of ``m_in``'s width a scan layer (forward, dX, dW)
+    where "full" holds four, and as many calls of the taps and the scan
+    as "full": nothing else of the layer is kept."""
+    import functools
+
+    from ray_tpu.models import granite
+    from ray_tpu.ops import ssm
+
+    if form == "pallas":
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        for name in ("scan_kernels", "taps_silu"):
+            monkeypatch.setattr(ssm, name, functools.partial(
+                getattr(ssm, name), interpret=True))
+        for name, n in (("KERNEL_HEADS", 2), ("KERNEL_CHUNKS", 2),
+                        ("KERNEL_LANES", 8)):
+            monkeypatch.setattr(ssm, name, n)
+    tiny = functools.partial(granite.GraniteConfig.tiny,
+                             attn_impl="reference")
+    cfg = tiny()
+    assert ssm.scan_plan(2, 32, cfg.ssm_heads, cfg.ssm_head_dim,
+                         cfg.ssm_state, cfg.ssm_groups,
+                         cfg.ssm_chunk)["form"] == form
+    params = granite.init_params(cfg, jax.random.PRNGKey(0))
+    batch = {"tokens": jax.random.randint(jax.random.PRNGKey(1), (2, 33),
+                                          0, 256)}
+
+    def loss_of(**kw):
+        return lambda p: granite.loss_fn(tiny(**kw), p, batch)
+
+    l_want, g_want = jax.jit(jax.value_and_grad(loss_of()))(params)
+    l_got, g_got = jax.jit(jax.value_and_grad(loss_of(
+        remat=True, remat_policy="level1")))(params)
+    assert jnp.allclose(l_want, l_got, atol=1e-6)
+    assert all(jnp.allclose(a, b, atol=1e-5)
+               for a, b in zip(jax.tree_util.tree_leaves(g_want),
+                               jax.tree_util.tree_leaves(g_got)))
+
+    width = params["layers"]["mamba"]["m_in"].shape[-1]
+    assert width == 2 * 128 + 2 * 16 + 8
+    scans = cfg.pattern.count("mamba")
+
+    def counts(policy):
+        jaxpr = jax.make_jaxpr(jax.grad(loss_of(
+            remat=True, remat_policy=policy, scan_layers=False)))(
+                params).jaxpr
+        kernels = {k: n for k, n in _count_primitives(jaxpr).items()
+                   if k.startswith("pallas_call:")}
+        return _count_products(jaxpr, width), kernels
+
+    products_full, kernels_full = counts("full")
+    products_kept, kernels_kept = counts("level1")
+    assert (products_full, products_kept) == (4 * scans, 3 * scans)
+    assert kernels_kept == kernels_full
+    assert bool(kernels_full) == (form == "pallas")
 
 
 @pytest.mark.parametrize("model", ["llama", "laguna"])
@@ -412,8 +490,9 @@ def test_remat_plan_is_a_pure_function_of_bytes(stack):
     assert llama.remat_names("level4")[:2] == ("flash_out", "flash_lse")
     # the first rung names a latent-attention layer's two latents too
     # and an index layer's packed choice (ops/dsa.KEPT_NAMES)
+    # and a scan layer's in-projection (ops/ssm.mamba2_mixer)
     assert llama.remat_names("level1")[2:] == ("q_latent", "kv_latent",
-                                               "dsa_choice")
+                                               "dsa_choice", "ssm_in")
     # bad policy name raises rather than silently training differently,
     # and so do the knobs PR 28 took away
     for gone in ("nope", "save_qkv"):
@@ -457,7 +536,9 @@ def test_forward_resolves_the_plan_from_the_shapes_it_traces(
 def test_describe_stack_knows_a_scan_layer_and_a_blocked_head(form,
                                                               monkeypatch):
     """A kind whose mixer is ``mamba2_part`` is reckoned as a selective
-    scan: the MLP rung alone keeps anything, the working set holds the in-projection's
+    scan: the first rung keeps the in-projection's output (``m_in``'s
+    width a token, the same in both forms) and the MLP rung the SwiGLU's
+    two products, the working set holds the in-projection's
     width and what the scan's form puts in HBM (``scan_plan``: XLA's walk
     on the CPU and under a mesh, one step of the walk; the kernels on a
     TPU backend, the kept states and the running sums); ``head_tokens``
@@ -483,7 +564,7 @@ def test_describe_stack_knows_a_scan_layer_and_a_blocked_head(form,
                                  T, **how)
     assert stack["runs"] == (("mamba", 5), ("attention", 1), ("mamba", 4))
     mamba, attn = stack["kinds"]["mamba"], stack["kinds"]["attention"]
-    assert mamba["rungs"] == (0, 0, 2 * T * 8192 * 2, 0)
+    assert mamba["rungs"] == (T * 8512 * 2, 0, 2 * T * 8192 * 2, 0)
     assert attn["rungs"][0] > 0 and attn["rungs"][3] > 0
     plan = ssm.scan_plan(1, T, 64, 64, 128, 1, 256)
     assert plan["form"] == form
@@ -515,6 +596,54 @@ def test_describe_stack_knows_a_scan_layer_and_a_blocked_head(form,
     allotted = {"xla_walk": 17_708_709_888, "pallas": 15_429_915_136}[form]
     assert 1.01 < blocked["need_bytes"] / allotted < 1.06
     assert blocked["need_bytes"] > (1 - llama.REMAT_RESERVE) * cap
+
+
+# ``train-nemotron3-super-1chip``'s plan by the device's bytes: the levels
+# of (moe, mamba, attention) and the need reckoned for them. A v5e reports
+# 16.91 GB and the budget is 95% of it. The scans' first rung is the
+# largest of the stack (5 x 304 MB), so with less room the climb lets the
+# attention layers' later rungs go first, then the mixtures' one, and the
+# scans' last
+@pytest.mark.parametrize("cap, levels, need", [
+    (_V5E_LIMIT, ("level3", "level1", "level4"), 14_386_467_712),
+    (_V5E_LIMIT - 1_200_000_000, ("level3", "level1", "level4"),
+     14_386_467_712),
+    (15_100_000_000, ("level3", "level1", "level2"), 14_232_023_936),
+    (14_900_000_000, ("full", "level1", "level4"), 14_143_943_552),
+    (14_800_000_000, ("level3", "full", "level4"), 13_927_936_896),
+    (14_500_000_000, ("full", "full", "full"), 13_839_856_512)],
+    ids=["v5e", "1.2GB-less", "15.1GB", "14.9GB", "14.8GB", "14.5GB"])
+def test_the_nemotron_cells_scans_take_the_first_rung(cap, levels, need,
+                                                      monkeypatch):
+    """The plan at ``train-nemotron3-super-1chip``'s shapes (the config
+    file's ``model_config``, 8,192 tokens, the kernels' form, the
+    prediction module's two layers reckoned as further layers of the
+    stack, as ``Stack._walk`` hands them over): on a v5e the scans keep
+    their in-projection's output, 8,192 x 18,560 bf16 a layer, beside the
+    mixtures' third rung and the attention layers' fourth, under the
+    budget; with less room, what the climb gives, pinned."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    mod, cfg = _cell_config("nemotron-3-super-120b-a12b-c1")
+    shapes = jax.eval_shape(lambda k: mod.init_params(cfg, k),
+                            jax.random.PRNGKey(0))
+    T = 8192
+    stack = llama.describe_stack(
+        cfg, mod.LAYER_KINDS,
+        {**shapes["mtp"]["layers"], **shapes["layers"]}, T,
+        pattern=cfg.pattern + cfg.mtp_pattern,
+        head_tokens=llama.head_block(T, cfg.vocab_size))
+    assert stack["kinds"]["mamba"]["rungs"] == (T * 18560 * 2, 0, 0, 0)
+    par = sum(a.size * a.dtype.itemsize
+              for a in jax.tree_util.tree_leaves(shapes))
+    plan = llama.remat_plan(cfg, stack, T, par, cap, False)
+    assert plan["level"] == dict(zip(("moe", "mamba", "attention"), levels))
+    assert plan["layers"] == {"moe": 6, "mamba": 5, "attention": 2}
+    assert plan["saved_bytes_per_layer"]["mamba"] == (
+        T * 18560 * 2 if levels[1] == "level1" else 0)
+    assert plan["need_bytes"] == need
+    # nothing is kept only where "full" itself is over the budget
+    assert (need <= (1 - llama.REMAT_RESERVE) * cap) == (
+        set(levels) != {"full"})
 
 
 @pytest.mark.parametrize("form", ["xla_walk", "pallas"])

@@ -175,7 +175,8 @@ def test_a_table_no_model_ships_trains_and_gets_a_plan(monkeypatch):
     assert float(terms["scan_abs_max"]) > 0
     # the plan: a level a kind, from what the kind's two parts say they
     # keep (the routed experts' and the SwiGLU's two products: the MLP
-    # rung; no rung names anything in a scan or a convolution)
+    # rung, and a scan's in-projection: the first; no rung names
+    # anything in a convolution)
     from dataclasses import replace
     monkeypatch.setattr(llama, "_device_capacity", lambda mesh: 2 ** 30)
     here = tracing.since()
