@@ -1,27 +1,54 @@
-"""The gated delta rule (Gated DeltaNet, arXiv:2412.06464) in its chunked
-(WY / UT) form, and the mixer of a ``linear_attention`` layer of
-Olmo-Hybrid and of Qwen3-Next.
+"""The gated delta rule (Gated DeltaNet, arXiv:2412.06464) and the rule whose
+decay is a vector over the key's channels (Kimi Delta Attention, KDA,
+arXiv:2510.26692) in their chunked (WY / UT) form, and the mixers of a
+``linear_attention`` layer of Olmo-Hybrid and Qwen3-Next and of a KDA layer.
 
-The recurrence, a head at a time (``q_t, k_t [K]`` with ``|k_t| = 1``,
-``v_t [V]``, ``g_t <= 0`` and ``beta_t`` in (0, 2) scalars, state ``S [V,
-K]`` float32, zero before the sequence):
+Two recurrences, a head at a time (``q_t, k_t [K]`` with ``|k_t| = 1``,
+``v_t [V]``, ``beta_t`` in (0, 2), state ``S [V, K]`` float32, zero before
+the sequence), told apart by the rank of ``g``: a decay a head, ``g_t <= 0``
+a scalar,
 
     S_t = exp(g_t) S_{t-1} (I - beta_t k_t k_t^T) + beta_t v_t k_t^T
+    o_t = S_t q_t
+
+and a decay a key channel, ``g_t [K]``, each entry in (``lower_bound``, 0),
+
+    S_t = S_{t-1} Diag(exp g_t) (I - beta_t k_t k_t^T) + beta_t v_t k_t^T
     o_t = S_t q_t
 
 Every position multiplies the state by a rank-one factor whose eigenvalue
 along ``k_t`` is ``1 - beta_t``, of either sign: a ``beta`` that lost its
 factor two, or a decay that lost its float32, is another model.
 
-``gated_delta_rule`` computes it in chunks of ``chunk`` positions and
+``gated_delta_rule`` computes either in chunks of ``chunk`` positions and
 never token by token. Within a chunk, with ``G`` the running sum of ``g``,
-``K``, ``V``, ``Q`` the chunk's rows and ``S_in`` the state before it:
+``K``, ``V``, ``Q`` the chunk's rows and ``S_in`` the state before it, a
+decay a head:
 
     A = tril(diag(beta) (K K^T * exp(G_i - G_j)), -1);   T = (I + A)^-1
     W = T diag(beta) (K * exp(G));                       U = T diag(beta) V
     V' = U - W S_in^T
     O = (Q * exp(G)) S_in^T + tril(Q K^T * exp(G_i - G_j)) V'
     S_out = exp(G_end) S_in + V'^T (K * exp(G_end - G))
+
+A decay a channel moves inside the two products over ``K``, with ``Gam =
+exp(G)`` ``[C, K]``:
+
+    A = tril(diag(beta) ((K * Gam) (K / Gam)^T), -1);    T = (I + A)^-1
+    W = T diag(beta) (K * Gam);                          U = T diag(beta) V
+    V' = U - W S_in^T
+    O = (Q * Gam) S_in^T + tril((Q * Gam) (K / Gam)^T) V'
+    S_out = S_in Diag(Gam_end) + V'^T (K * Gam_end / Gam)
+
+``1 / Gam`` is never formed over a chunk (at -5 a position 64 rows reach
+``exp(320)``). **The sub-block rule** (``_decayed_pairs``): a chunk is
+sub-blocks of ``INVERSE_BASE`` rows (``_sub_block``) that take their decays
+from the sub-block's first row ``r``: rows ``i`` of it carry ``exp(G_i -
+G_r)`` (at most 1) and every row ``j`` up to the sub-block's end ``exp(G_r
+- G_j)``: at most 1 before the sub-block, at most ``exp(5 x 15)`` inside it
+for a ``g`` bounded by -5, within float32 and bfloat16; later rows are
+masked before the exponential. So a pair in different sub-blocks multiplies
+two factors that are at most 1.
 
 The result does not depend on ``chunk``. ``T`` is the inverse of a unit
 lower-triangular ``[chunk, chunk]`` matrix a head: blocks of
@@ -32,26 +59,32 @@ catastrophically on repeated keys), joined two at a time by ``[[T11, 0],
 
 Two forms share this arithmetic and no code beyond ``_gates``, the norms'
 ``eps`` and the padding; which runs is read from the call and never set
-(``_kernel_takes``; ``rule_plan`` says what a call will do, and a traced
-call writes it once as the kept span ``rtpu.gdn.rule_plan``). Where fewer
-key heads serve the value heads (Qwen3-Next: 16 under 32) value head ``i``
-reads key head ``i // (heads / key heads)`` in both:
+(``_kernel_takes``; ``rule_plan`` says what a call will do, its ``decay``
+with it, and a traced call writes it once as the kept span
+``rtpu.gdn.rule_plan``, a KDA layer's as ``rtpu.kda.rule_plan``). Where
+fewer key heads serve the value heads (Qwen3-Next: 16 under 32) value head
+``i`` reads key head ``i // (heads / key heads)`` in both:
 
 - ``xla_walk``, on the CPU, under a mesh (a Mosaic call is whole to the
-  partitioner) and for a chunk that is not whole tiles: walked as
+  partitioner), for a chunk that is not whole tiles and for a decay a
+  channel (no Mosaic form is built for it yet): walked as
   ``ops/ssm.ssd_scan`` is, a ``lax.scan`` whose step takes several chunks
-  at once (as many as put ``WALK_BYTES`` of float32 pair matrices and
-  carried states in HBM), builds ``A``, ``T``, ``W``, ``U`` for all of
-  them in one batch, hands the state from chunk to chunk in an inner
+  at once (as many as put ``WALK_BYTES`` of float32 pair matrices, decayed
+  rows and carried states in HBM), builds ``A``, ``T``, ``W``, ``U`` for
+  all of them in one batch, hands the state from chunk to chunk in an inner
   ``lax.scan`` (two small matmuls a chunk), and then builds ``O`` for all
   of them; the step is under ``jax.checkpoint``, so what the backward
-  keeps of it is the state it started from. q and k come [b, s, key heads,
-  K], normed by ``l2_norm``, and are copied to the value heads
-  (``_join_heads``). The controls of ``benchmark/tests/delta_limits.py``
-  plant their faults in ``_walk_step``, ``_unit_lower_inverse`` and this
-  module's ``jnp``, ``delta_moe_limits.py``'s wrong head map in
-  ``_join_heads``.
-- ``pallas``, on a TPU backend without a mesh (``rule_kernels``): two
+  keeps of it is the state it started from. One step serves both decays:
+  they differ in ``_decayed_pairs`` and in what a decay broadcasts over.
+  q and k come [b, s, key heads, K], normed by ``l2_norm``, and are copied
+  to the value heads (``_join_heads``). The controls of
+  ``benchmark/tests/delta_limits.py`` plant their faults in ``_walk_step``,
+  ``_unit_lower_inverse`` and this module's ``jnp``,
+  ``delta_moe_limits.py``'s wrong head map in ``_join_heads``,
+  ``kda_moe_limits.py``'s in ``_channel_gates``, ``_head_gated`` and
+  ``l2_norm``.
+- ``pallas``, on a TPU backend without a mesh, a decay a head
+  (``rule_kernels``): two
   Mosaic calls behind a ``custom_vjp``, ``delta_rule_fwd`` and
   ``delta_rule_bwd``, on a grid of (batch row, a block of key heads with
   their value heads, ``KERNEL_HEADS`` of those at most, ``KERNEL_CHUNKS``
@@ -95,7 +128,9 @@ q, k and v: ``ops/ssm.causal_conv_silu``, on a TPU the kernel pair
 ``g`` and ``beta``, the running sums, the rule in either form; the walk's
 swaps, L2 norms and copies to the value heads with it, which the kernels
 do in VMEM), ``gdn_norm`` (the RMSNorm of each head and the gate) and
-``gdn_out`` (the out-projection).
+``gdn_out`` (the out-projection); ``kda`` holds ``kda_in``, ``kda_conv``,
+``kda_gate`` (the bounded gate and ``beta``), ``kda_rule``, ``kda_norm`` and
+``kda_out`` (``kda_mixer``).
 """
 
 from __future__ import annotations
@@ -142,18 +177,19 @@ KERNEL_LANES = 128
 QK_NORM_EPS = 1e-6
 
 
-def _kernel_takes(chunk: int, mesh, key_dim: int, value_dim: int) -> bool:
+def _kernel_takes(chunk: int, mesh, key_dim: int, value_dim: int,
+                  decay: str = "head") -> bool:
     """Whether a call runs as the kernels: on a TPU backend (anything but
     the CPU), without a ``mesh`` (a Mosaic call is whole to the
     partitioner, which would gather its operands: XLA's walk shards as the
-    arrays do), with a chunk (a sequence shorter than one is its own)
-    that is whole tiles of ``KERNEL_BASE`` rows, a power of two of them,
-    and with heads of whole sublane tiles (a block's heads lie one under
-    another)."""
+    arrays do), with one decay a head (a decay a channel is the walk's),
+    with a chunk (a sequence shorter than one is its own) that is whole
+    tiles of ``KERNEL_BASE`` rows, a power of two of them, and with heads
+    of whole sublane tiles (a block's heads lie one under another)."""
     tiles = chunk // KERNEL_BASE
     return (mesh is None and jax.default_backend() != "cpu"
             and chunk == tiles * KERNEL_BASE and tiles & (tiles - 1) == 0
-            and key_dim % 8 == 0 and value_dim % 8 == 0)
+            and key_dim % 8 == 0 and value_dim % 8 == 0 and decay == "head")
 
 
 def _heads_a_block(heads: int, key_heads: Optional[int] = None) -> int:
@@ -167,9 +203,28 @@ def _heads_a_block(heads: int, key_heads: Optional[int] = None) -> int:
                        and (k == 1 or k * ratio <= KERNEL_HEADS))
 
 
+def _sub_block(chunk: int) -> int:
+    """The rows of a chunk whose pair decays share one reference row (the
+    module's docstring's sub-block rule): the largest divisor of the chunk
+    within ``INVERSE_BASE``."""
+    return max(r for r in range(1, min(INVERSE_BASE, chunk) + 1)
+               if chunk % r == 0)
+
+
+def _decayed_rows(decay: str, chunk: int) -> int:
+    """The float32 ``[rows, K]`` a head and chunk that a decay a channel
+    adds to a walk's step (``rule_plan``'s ``one``): the running sums, k's
+    and q's rows decayed from their sub-block's reference row, and the keys
+    decayed to each sub-block's reference row."""
+    if decay != "channel":
+        return 0
+    return (3 + chunk // _sub_block(chunk)) * chunk
+
+
 def rule_plan(batch: int, seq: int, heads: int, key_dim: int,
               value_dim: int, chunk: int, mesh=None,
-              key_heads: Optional[int] = None) -> Dict[str, Any]:
+              key_heads: Optional[int] = None, decay: str = "head"
+              ) -> Dict[str, Any]:
     """What the rule does with these shapes, and in which ``form``.
     ``heads`` are the value heads, the rule's own; ``key_heads`` those of q
     and k where fewer serve them, and ``joined`` how a value head comes by
@@ -177,32 +232,37 @@ def rule_plan(batch: int, seq: int, heads: int, key_dim: int,
     ``_join_heads``), "index_map" (the kernels: a grid step takes a block of
     key heads and the value heads of each, q and k are read and ``dq`` and
     ``dk`` written once at the key heads, ``_key_head``), None where they
-    are as many.
+    are as many. ``decay``: "head" (``g [b, s, H]``) or "channel" (``g [b,
+    s, H, K]``, which adds ``sub_block``, the rows that share a reference
+    row).
     Both forms: the chunk it uses (no longer than the sequence), the chunks,
     the ``steps`` (of the walk, or of the kernels' grid along the sequence),
     ``chunks_a_call`` (what one step takes), ``states_kept`` (the float32
     states a backward starts from, one a step) and the float32 bytes the form
     puts in HBM beside what all chunks' pair matrices at once would.
     ``xla_walk``: ``walk`` (= ``chunks_a_call``, the largest divisor of the
-    chunks within ``WALK_BYTES``) and the bytes of one step's pair matrices and
-    carried states. ``pallas``: ``heads_a_block`` (``_heads_a_block``),
+    chunks within ``WALK_BYTES``) and the bytes of one step's pair matrices,
+    decayed rows and carried states. ``pallas``: ``heads_a_block``
+    (``_heads_a_block``),
     ``KERNEL_CHUNKS`` chunks a step (all of a shorter sequence; whole
     ``KERNEL_LANES`` positions), ``operands`` ("positions_last": q, k, v, o
     and their gradients are read and written [b, channels, s], as the taps
     leave them) and the bytes of the kept states, the last state and the
     running sums (in their two layouts) and ``beta``: nothing ``[chunk,
     chunk]``."""
-    chunk = min(chunk, seq)
+    chunk, key_heads = min(chunk, seq), key_heads or heads
     chunks = -(-seq // chunk)
-    one = batch * heads * 4 * (4 * chunk * chunk + value_dim * key_dim)
-    key_heads = key_heads or heads
-    kernels = _kernel_takes(chunk, mesh, key_dim, value_dim)
+    one = batch * heads * 4 * (4 * chunk * chunk + value_dim * key_dim
+                               + _decayed_rows(decay, chunk) * key_dim)
+    kernels = _kernel_takes(chunk, mesh, key_dim, value_dim, decay)
     plan = {"seq": seq, "chunk": chunk, "chunks": chunks, "heads": heads,
-            "key_heads": key_heads,
+            "key_heads": key_heads, "decay": decay,
             "joined": None if key_heads == heads else (
                 "index_map" if kernels else "repeat"),
             "key_dim": key_dim, "value_dim": value_dim,
             "float32_bytes_all_chunks": chunks * one}
+    if decay == "channel":
+        plan["sub_block"] = _sub_block(chunk)
     if kernels:
         whole = max(1, KERNEL_LANES // chunk)
         call = -(-min(KERNEL_CHUNKS, chunks) // whole) * whole
@@ -253,40 +313,81 @@ def _unit_lower_inverse(A: jax.Array) -> jax.Array:
         1, n, row, jnp.broadcast_to(jnp.eye(n, dtype=A.dtype), A.shape))
 
 
+def _decayed_pairs(q, k, G, beta, dtype):
+    """``walk`` chunks' ``diag(beta) K K^T`` and ``Q K^T`` with their pair
+    decays: q and k [b, W, C, H, K], G the running sums, [b, W, C, H] or a
+    key channel [b, W, C, H, K], and beta [b, W, C, H] float32 -> two [b,
+    W, H, C, C] float32, row ``i`` and column ``j`` the product decayed
+    from ``j`` to ``i``, zero where ``j > i``. A decay a head multiplies
+    the product; a decay a channel lies inside it, by the sub-block rule of
+    the module's docstring."""
+    b, W, C, H, K = q.shape
+    f32 = jnp.float32
+    at = jnp.arange(C)
+    causal = at[:, None] >= at[None, :]
+    by_row = jnp.moveaxis(beta, 2, -1)[..., None]        # [b, W, H, C, 1]
+    if G.ndim == 4:
+        by_head = jnp.moveaxis(G, 2, -1)                 # [b, W, H, C]
+        decay = jnp.exp(jnp.where(                       # [b, W, H, C, C]
+            causal, by_head[..., :, None] - by_head[..., None, :], -jnp.inf))
+        kk, qk = (jnp.einsum("bwihd,bwjhd->bwhij", x, k,
+                             preferred_element_type=f32) for x in (k, q))
+        return by_row * kk * decay, qk * decay
+    R = _sub_block(C)
+    n = C // R
+    kf = k.astype(f32)
+    ref = G.reshape(b, W, n, R, H, K)[:, :, :, 0]        # [b, W, n, H, K]
+    from_ref = jnp.exp(G.reshape(b, W, n, R, H, K) - ref[:, :, :, None])
+    rows = jnp.concatenate(                              # k's rows, then q's
+        [(x.reshape(b, W, n, R, H, K) * from_ref).astype(dtype)
+         for x in (kf, q.astype(f32))], axis=3)          # [b, W, n, 2 R, H, K]
+    upto = at[None, :] < (jnp.arange(n)[:, None] + 1) * R          # [n, C]
+    to_ref = jnp.exp(jnp.where(                          # [b, W, n, C, H, K]
+        upto[None, None, :, :, None, None],
+        ref[:, :, :, None] - G[:, :, None], -jnp.inf))
+    pairs = jnp.einsum("bwnrhd,bwnjhd->bwhnrj", rows,
+                       (kf[:, :, None] * to_ref).astype(dtype),
+                       preferred_element_type=f32)       # [b, W, H, n, 2R, C]
+    kk, qk = (x.reshape(b, W, H, C, C)
+              for x in (pairs[:, :, :, :, :R], pairs[:, :, :, :, R:]))
+    return jnp.where(causal, by_row * kk, 0.0), jnp.where(causal, qk, 0.0)
+
+
 def _walk_step(S, xs, dtype):
     """``walk`` chunks: S [b, H, V, K] float32, xs = (q and k [b, W, C, H,
-    K], v [b, W, C, H, V], g and beta [b, W, C, H] float32) -> (the state
-    after them, o [b, W, C, H, V])."""
+    K], v [b, W, C, H, V], g [b, W, C, H] or, a decay a key channel, [b, W,
+    C, H, K], and beta [b, W, C, H] float32) -> (the state after them, o
+    [b, W, C, H, V])."""
     q, k, v, g, beta = xs
     C = q.shape[2]
     f32 = jnp.float32
-    G = jnp.cumsum(g, axis=2)                            # [b, W, C, H]
-    by_head = jnp.moveaxis(G, 2, -1)                     # [b, W, H, C]
+    G = jnp.cumsum(g, axis=2)                            # [b, W, C, H(, K)]
+    kk, qk = _decayed_pairs(q, k, G, beta, dtype)
     at = jnp.arange(C)
-    decay = jnp.exp(jnp.where(                           # [b, W, H, C, C]
-        at[:, None] >= at[None, :],
-        by_head[..., :, None] - by_head[..., None, :], -jnp.inf))
-    kk = jnp.einsum("bwihd,bwjhd->bwhij", k, k, preferred_element_type=f32)
-    A = jnp.where(at[:, None] > at[None, :],
-                  jnp.moveaxis(beta, 2, -1)[..., None] * kk * decay, 0.0)
+    A = jnp.where(at[:, None] > at[None, :], kk, 0.0)
     T = _unit_lower_inverse(A).astype(dtype)
-    grown = jnp.exp(G)
+
+    def wide(x):
+        # over the key's channels: a decay a head is one number for all
+        return x if g.ndim == 5 else x[..., None]
+
+    grown = wide(jnp.exp(G))
     kf, vf = k.astype(f32), v.astype(f32)
     Wm = jnp.einsum("bwhij,bwjhd->bwihd", T,
-                    (kf * (beta * grown)[..., None]).astype(dtype),
+                    (kf * (grown * beta[..., None])).astype(dtype),
                     preferred_element_type=f32).astype(dtype)
     U = jnp.einsum("bwhij,bwjhd->bwihd", T,
                    (vf * beta[..., None]).astype(dtype),
                    preferred_element_type=f32)
     # K decayed to the chunk's end, and the whole chunk's decay
-    k_end = (kf * jnp.exp(G[:, :, -1:] - G)[..., None]).astype(dtype)
-    whole = jnp.exp(G[:, :, -1])                         # [b, W, H]
+    k_end = (kf * wide(jnp.exp(G[:, :, -1:] - G))).astype(dtype)
+    whole = wide(jnp.exp(G[:, :, -1]))[:, :, :, None]    # [b, W, H, 1, K | 1]
 
     def chunk_step(S, c):
         W_c, U_c, k_c, whole_c = c
         new = U_c - jnp.einsum("bchk,bhvk->bchv", W_c, S.astype(dtype),
                                preferred_element_type=f32)
-        after = whole_c[..., None, None] * S + jnp.einsum(
+        after = whole_c * S + jnp.einsum(
             "bchv,bchk->bhvk", new.astype(dtype), k_c,
             preferred_element_type=f32)
         return after, (S, new.astype(dtype))
@@ -294,34 +395,41 @@ def _walk_step(S, xs, dtype):
     S, (into, new) = jax.lax.scan(chunk_step, S, tuple(
         jnp.moveaxis(a, 1, 0) for a in (Wm, U, k_end, whole)))
     into, new = jnp.moveaxis(into, 0, 1), jnp.moveaxis(new, 0, 1)
-    qk = jnp.einsum("bwihd,bwjhd->bwhij", q, k, preferred_element_type=f32)
     o = jnp.einsum("bwchk,bwhvk->bwchv",
-                   (q.astype(f32) * grown[..., None]).astype(dtype),
+                   (q.astype(f32) * grown).astype(dtype),
                    into.astype(dtype), preferred_element_type=f32)
-    o = o + jnp.einsum("bwhij,bwjhv->bwihv", (qk * decay).astype(dtype), new,
+    o = o + jnp.einsum("bwhij,bwjhv->bwihv", qk.astype(dtype), new,
                        preferred_element_type=f32)
     return S, o.astype(dtype)
 
 
 def gated_delta_rule(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
-                     beta: jax.Array, chunk: int = 64, mesh=None
+                     beta: jax.Array, chunk: int = 64, mesh=None,
+                     span: str = "rtpu.gdn.rule_plan", **said
                      ) -> Tuple[jax.Array, jax.Array]:
     """q and k [b, s, key heads, K] (k of unit length, q scaled as the
     caller wants its outputs), v [b, s, H, V] (``H`` a multiple of the key
-    heads: value head ``i`` reads key head ``i // (H / key heads)``), g [b,
-    s, H] float32 (the log of the decay, not positive), beta [b, s, H]
-    float32 -> (o [b, s, H, V] in ``v``'s dtype, the state after the last
-    position [b, H, V, K] float32). ``mesh``: the one the caller's arrays
-    are sharded over, if any. Which form runs is read from the call
-    (``_kernel_takes``), and the kept span ``rtpu.gdn.rule_plan`` says
-    which. The kernels take their operands positions last
-    (``rule_kernels``; the mixer hands them its own so): this order is
+    heads: value head ``i`` reads key head ``i // (H / key heads)``), g
+    float32, the log of the decay, not positive: [b, s, H], or a decay a key
+    channel [b, s, H, K] (every head then has q and k of its own, and ``g``
+    is bounded below: a sub-block's ``exp(-R g)`` must stay inside float32),
+    beta [b, s, H] float32 -> (o [b, s, H, V] in ``v``'s dtype, the state
+    after the last position [b, H, V, K] float32). ``mesh``: the one the
+    caller's arrays are sharded over, if any. Which form runs is read from
+    the call (``_kernel_takes``), and the kept span ``span`` says which,
+    with what the caller ``said``. The kernels take their operands positions
+    last (``rule_kernels``; the mixer hands them its own so): this order is
     swapped around them. The walk reads q and k copied to the value heads
     (``_join_heads``)."""
     b, s, key_heads, K = q.shape
     H, V = v.shape[2:]
-    plan = rule_plan(b, s, H, K, V, chunk, mesh, key_heads)
-    with tracing.span("rtpu.gdn.rule_plan", keep=True, **plan):
+    decay = "channel" if g.ndim == 4 else "head"
+    if decay == "channel" and not q.shape == k.shape == g.shape[:2] + (H, K):
+        raise ValueError(
+            f"a decay a channel wants q, k and g of one shape and v at their "
+            f"heads: q {q.shape}, k {k.shape}, v {v.shape}, g {g.shape}")
+    plan = rule_plan(b, s, H, K, V, chunk, mesh, key_heads, decay)
+    with tracing.span(span, keep=True, **plan, **said):
         pass
     if plan["form"] == "pallas":
         def last(a):
@@ -1170,3 +1278,141 @@ def gated_delta_part(counter: str = "gdn_state_abs_max", norm: str = "post",
         return None, {counter: jnp.abs(states).max()}
 
     return Part(leaves, body, keeps, reports="gdn_state", terms=terms)
+
+
+# ---- Kimi Delta Attention (arXiv:2510.26692): the mixer of a layer whose
+# rule has a decay a key channel (the module's docstring's second recurrence)
+
+
+def _channel_gates(f, b_, p, lower_bound: float):
+    """The in-projection's f [b, s, H, K] and b [b, s, H] -> (g, the log of
+    the decay a channel: ``lower_bound sigmoid(exp(A_log[h]) (f +
+    dt_bias))``, each entry in (``lower_bound``, 0): the lower-bounded gate
+    that keeps a sub-block's ``exp(-G)`` inside float32; ``beta =
+    sigmoid(b)``), float32. Looked up at trace time: the controls of
+    ``benchmark/tests/kda_moe_limits.py`` plant their gates here."""
+    f32 = jnp.float32
+    H, K = f.shape[-2:]
+    rate = jnp.exp(p["k_A_log"].astype(f32))[:, None]
+    return (lower_bound * jax.nn.sigmoid(rate * (
+        f.astype(f32) + p["k_dt_bias"].astype(f32).reshape(H, K))),
+            jax.nn.sigmoid(b_.astype(f32)))
+
+
+def _head_gated(o, gate, weight, eps):
+    """o [b, s, H, V], gate [b, s, H] -> ``RMSNorm(o; weight) *
+    sigmoid(gate)``, a head normed alone over its V and gated by one
+    number, float32 inside. Looked up at trace time (a control leaves the
+    gate off)."""
+    of = o.astype(jnp.float32)
+    normed = of * jax.lax.rsqrt(
+        jnp.mean(jnp.square(of), axis=-1, keepdims=True) + eps)
+    return (normed * weight.astype(jnp.float32)
+            * jax.nn.sigmoid(gate.astype(jnp.float32))[..., None]
+            ).astype(o.dtype)
+
+
+def kda_mixer(h: jax.Array, p: Dict[str, jax.Array], *, heads: int,
+              key_dim: int, value_dim: int, chunk: int = 64,
+              eps: float = 1e-6, lower_bound: float = -5.0, mesh=None
+              ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+    """Kimi Delta Attention's mixer: h [b, s, hidden] (normed) -> (its
+    output [b, s, hidden], {"state": the state after the last position [b,
+    H, V, K] float32, "log_decay_min": the smallest ``g``}, which no
+    gradient passes). ``p``: ``k_in [hidden, 2 H K + H V + H K + 2 H]``
+    (q k v, then the decay's f, then beta's b and the output gate's, one
+    number a head each), ``k_conv [2 H K + H V, taps]`` (q's, k's and v's
+    own taps, no bias), ``k_A_log [H]``, ``k_dt_bias [H K]``, ``k_norm
+    [V]``, ``k_out [H V, hidden]``. Every head has q, k and v of its own;
+    q and k are L2-normed after the taps' silu, v is not; ``beta =
+    sigmoid(b)``; the output is ``RMSNorm_head(o) sigmoid(gate_h)`` through
+    ``k_out``. Scopes: ``kda`` holds ``kda_in``, ``kda_conv``, ``kda_gate``
+    (the bounded gate and beta), ``kda_rule`` (the swaps, the L2 norms and
+    the walk, its running sums with it), ``kda_norm`` and ``kda_out``."""
+    b, s, _ = h.shape
+    dt_ = h.dtype
+    hk, hv = heads * key_dim, heads * value_dim
+    conv = 2 * hk + hv
+    f32 = jnp.float32
+    with jax.named_scope("kda"):
+        with jax.named_scope("kda_in"):
+            proj = jnp.dot(h, p["k_in"].astype(dt_),
+                           preferred_element_type=f32).astype(dt_)
+            # positions last, as the in-projection's output lies on a TPU
+            by_channel = jnp.swapaxes(proj, 1, 2)
+        with jax.named_scope("kda_conv"):
+            q, k, v = causal_conv_silu(
+                by_channel, p["k_conv"], jnp.zeros((conv,), f32),
+                sizes=(hk, hk, hv), mesh=mesh, span="rtpu.kda.conv_plan")
+        with jax.named_scope("kda_gate"):
+            b_, gate = jnp.split(proj[..., conv + hk:], 2, axis=-1)
+            g, beta = _channel_gates(
+                proj[..., conv:conv + hk].reshape(b, s, heads, key_dim), b_,
+                p, lower_bound)
+        with jax.named_scope("kda_rule"):
+            q, k, v = (jnp.swapaxes(x, 1, 2).reshape(b, s, heads, -1)
+                       for x in (q, k, v))
+            o, S = gated_delta_rule(
+                l2_norm(q, QK_NORM_EPS, key_dim ** -0.5),
+                l2_norm(k, QK_NORM_EPS), v, g, beta, chunk=chunk, mesh=mesh,
+                span="rtpu.kda.rule_plan", lower_bound=lower_bound)
+            said = jax.lax.stop_gradient(
+                {"state": S, "log_decay_min": g.min()})
+        with jax.named_scope("kda_norm"):
+            y = _head_gated(o, gate, p["k_norm"], eps).reshape(b, s, hv)
+        with jax.named_scope("kda_out"):
+            out = jnp.dot(y, p["k_out"].astype(dt_),
+                          preferred_element_type=f32).astype(dt_)
+    return out, said
+
+
+def kda_part() -> Part:
+    """Kimi Delta Attention as a layer's mixer in llama's order, ``x +
+    kda_mixer(RMSNorm(x))``, at the config's ``linear_heads``,
+    ``linear_key_dim``, ``linear_value_dim``, ``linear_conv_taps``,
+    ``rule_chunk`` and ``kda_lower_bound``. A layer reports under "kda" its
+    state after the last position and its smallest ``g``; the loss's terms
+    hold the counters ``kda_state_abs_max`` and ``kda_log_decay_min`` (which
+    must stay above the bound). ``A`` starts uniform in 1-16 as its log,
+    ``dt_bias`` through the inverse softplus, norms 1."""
+    def leaves(cfg):
+        h, H, taps = cfg.hidden_size, cfg.linear_heads, cfg.linear_conv_taps
+        hk, hv = H * cfg.linear_key_dim, H * cfg.linear_value_dim
+        conv = 2 * hk + hv
+        return {"op_norm": Leaf((h,), norm_start(cfg), ("embed",)),
+                "k_in": Leaf((h, conv + hk + 2 * H), h, ("embed", "mlp")),
+                "k_conv": Leaf((conv, taps), taps, ("mlp", None)),
+                "k_dt_bias": Leaf((hk,), "dt", (None,)),
+                "k_A_log": Leaf((H,), (1.0, 16.0), (None,)),
+                "k_norm": Leaf((cfg.linear_value_dim,), "ones", (None,)),
+                "k_out": Leaf((hv, h), hv, ("mlp", "embed"))}
+
+    def body(cfg, x, p, ctx):
+        with jax.named_scope("kda_pre_norm"):
+            u = rms_norm(x, p["op_norm"], cfg.rms_norm_eps,
+                         cfg.zero_centred_norm)
+        out, said = kda_mixer(
+            u, p, heads=cfg.linear_heads, key_dim=cfg.linear_key_dim,
+            value_dim=cfg.linear_value_dim, chunk=cfg.rule_chunk,
+            eps=cfg.rms_norm_eps, lower_bound=cfg.kda_lower_bound,
+            mesh=ctx.mesh)
+        return x + out, {"kda": said}
+
+    def keeps(cfg, shape, tokens, mesh):
+        # as ``gated_delta_part``'s walk: the in-projection's and the taps'
+        # outputs, the float32 gate and its gradient (four activations'
+        # widths a key channel), q and k normed, and what a step of the
+        # walk puts in HBM with the state before every step
+        heads, hv = shape["k_A_log"][-1], shape["k_out"][0]
+        hk = shape["k_dt_bias"][-1]
+        plan = rule_plan(1, tokens, heads, hk // heads, hv // heads,
+                         cfg.rule_chunk, mesh, heads, decay="channel")
+        return kept(width=shape["k_in"][-1] + shape["k_conv"][0] + 6 * hk,
+                    rows=4 * plan["float32_bytes_in_hbm"]
+                    + plan["steps"] * hv * hk // heads * 4)
+
+    def terms(cfg, said):
+        return None, {"kda_state_abs_max": jnp.abs(said["state"]).max(),
+                      "kda_log_decay_min": said["log_decay_min"].min()}
+
+    return Part(leaves, body, keeps, reports="kda", terms=terms)

@@ -2,7 +2,9 @@
 mixer, for training.
 
 Queries and keys/values each pass through a low-rank latent with an
-RMSNorm of its own:
+RMSNorm of its own (``q_lora_rank`` None: the queries come straight from
+the layer's normed input, ``q = u W_q``, one leaf ``wq``, and the first
+rung keeps the kv latent alone):
 
 - ``c_q = RMSNorm(u W_qa)`` ``[q_lora_rank]``; ``q = c_q W_qb`` ``[H, d_n +
   d_r]``, a head's first ``d_n`` dims position-free, its last ``d_r``
@@ -192,11 +194,15 @@ def latent_attention_part(prefix: str = "", rope: Callable = rope_tables,
         h, sz = cfg.hidden_size, sizes(cfg, prefix)
         H, rq, rkv = sz.heads, sz.q_rank, sz.kv_rank
         dn, dr, dv = sz.d_n, sz.d_r, sz.d_v
-        out = {"attn_norm": Leaf((h,), "ones", ("embed",)),
-               "wq_a": Leaf((h, rq), h, ("embed", None)),
-               "q_a_norm": Leaf((rq,), "ones", (None,)),
-               "wq_b": Leaf((rq, H * (dn + dr)), h if rescale else rq,
-                            (None, "qkv")),
+        # no query latent (``q_lora_rank`` None): one ``wq`` from the
+        # layer's normed input
+        queries = {"wq": Leaf((h, H * (dn + dr)), h, ("embed", "qkv"))
+                   } if rq is None else {
+            "wq_a": Leaf((h, rq), h, ("embed", None)),
+            "q_a_norm": Leaf((rq,), "ones", (None,)),
+            "wq_b": Leaf((rq, H * (dn + dr)), h if rescale else rq,
+                         (None, "qkv"))}
+        out = {"attn_norm": Leaf((h,), "ones", ("embed",)), **queries,
                "wkv_a": Leaf((h, rkv + dr), h, ("embed", None)),
                "kv_a_norm": Leaf((rkv,), "ones", (None,)),
                "wkv_b": Leaf((rkv, H * (dn + dv)), h if rescale else rkv,
@@ -205,7 +211,7 @@ def latent_attention_part(prefix: str = "", rope: Callable = rope_tables,
         if gate:
             out["wg"] = Leaf((h, H), h, ("embed", None))
         if index:
-            out.update(dsa.index_leaves(cfg, rq))
+            out.update(dsa.index_leaves(cfg, rq or h))
         return out
 
     def body(cfg, x, p, ctx):
@@ -239,10 +245,13 @@ def latent_attention_part(prefix: str = "", rope: Callable = rope_tables,
             # the two latents before their norms are what the ladder's
             # first rung keeps of this layer (models/llama.py REMAT_LADDER):
             # the backward then runs neither down-projection again
-            c_q = checkpoint_name(dot(u, p["wq_a"]), "q_latent")
-            c_qn = rms_norm(c_q, p["q_a_norm"], eps)
-            q = dot(c_qn, p["wq_b"]).reshape(b, s, H, dn + dr)
-            if rescale:
+            if sz.q_rank is None:
+                c_qn, q = u, dot(u, p["wq"]).reshape(b, s, H, dn + dr)
+            else:
+                c_q = checkpoint_name(dot(u, p["wq_a"]), "q_latent")
+                c_qn = rms_norm(c_q, p["q_a_norm"], eps)
+                q = dot(c_qn, p["wq_b"]).reshape(b, s, H, dn + dr)
+            if rescale and sz.q_rank is not None:
                 q = q * jnp.asarray(rescale_factor(cfg, sz.q_rank), dt)
         with jax.named_scope("mla_kv"):
             c_kv = checkpoint_name(dot(u, p["wkv_a"]), "kv_latent")
@@ -294,8 +303,11 @@ def latent_attention_part(prefix: str = "", rope: Callable = rope_tables,
         dv, dr = sz.d_v, sz.d_r
         H = shape["wo"][0] // dv
         act = jnp.dtype(cfg.dtype).itemsize
-        latents = shape["wq_a"][-1] + shape["wkv_a"][-1]
-        expanded = shape["wq_b"][-1] + shape["wkv_b"][-1]
+        # (without a query latent the queries are an expansion alone)
+        latents = (shape["wq_a"][-1] if "wq_a" in shape else 0
+                   ) + shape["wkv_a"][-1]
+        expanded = shape["wq_b" if "wq_b" in shape else "wq"][-1] \
+            + shape["wkv_b"][-1]
         # the gate a head, and the index's queries, key and head weights:
         # recomputed at every level, held by the layer's backward
         beside = ((shape["wg"][-1] if gate else 0)

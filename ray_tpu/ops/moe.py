@@ -192,7 +192,8 @@ def grouped_matmul(rows: jax.Array, w: jax.Array, sizes: jax.Array
 def route(x: jax.Array, router_w: jax.Array, top_k: int,
           renormalize: bool = False, scale: float = 1.0,
           score: str = "softmax", select_bias: Optional[jax.Array] = None,
-          renorm_eps: float = 0.0, groups: Optional[Tuple[int, int]] = None
+          renorm_eps: float = 0.0, groups: Optional[Tuple[int, int]] = None,
+          group_score: str = "max"
           ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """(router_logits [n, E] float32, top_w [n, K] float32, top_e [n, K]
     int32): logits accumulate in float32, the scores are their softmax
@@ -205,18 +206,28 @@ def route(x: jax.Array, router_w: jax.Array, top_k: int,
     weights are the scores without it, and no gradient reaches it.
     ``groups=(n_group, topk_group)`` limits the choice (DeepSeek-V2's
     ``group_limited_greedy``): the E experts are ``n_group`` groups of
-    neighbours, a group's score is the largest of its experts', and the K
-    are the largest scores inside the ``topk_group`` best groups."""
+    neighbours, a group's score is the largest of its experts'
+    (``group_score="max"``) or the sum of its two largest (``"top2"``:
+    DeepSeek-V3's ``noaux_tc``), and the K are the largest scores inside
+    the ``topk_group`` best groups. With a ``select_bias`` the groups are
+    scored and the K chosen on ``score + bias``, the weights being the
+    scores without it."""
     logits = jnp.dot(x, router_w.astype(x.dtype),
                      preferred_element_type=jnp.float32)
     if score not in ("softmax", "sigmoid"):
         raise ValueError(f"unknown router score {score!r} (softmax | sigmoid)")
     scores = (jax.nn.softmax(logits, axis=-1) if score == "softmax"
               else jax.nn.sigmoid(logits))
-    if groups is not None:
-        if select_bias is not None:
-            raise ValueError("a group limit with a selection bias is not "
-                             "implemented")
+    if group_score not in ("max", "top2"):
+        raise ValueError(f"unknown group score {group_score!r} (max | top2)")
+    if groups is not None and (select_bias is not None
+                               or group_score != "max"):
+        # the choice is made on ``score + bias`` (the scores without one):
+        # the groups' scores and the K inside the kept groups; the weights
+        # are the scores. ``route_choice`` is looked up at trace time
+        top_e = route_choice(scores, select_bias, top_k, groups, group_score)
+        top_w = jnp.take_along_axis(scores, top_e, axis=-1)
+    elif groups is not None:
         n_group, kept_groups = groups
         by_group = scores.reshape(scores.shape[0], n_group, -1)
         _, best = jax.lax.top_k(
@@ -239,6 +250,27 @@ def route(x: jax.Array, router_w: jax.Array, top_k: int,
     if scale != 1.0:
         top_w = top_w * scale
     return logits, top_w, top_e
+
+
+def route_choice(scores: jax.Array, select_bias: Optional[jax.Array],
+                 top_k: int, groups: Tuple[int, int], group_score: str
+                 ) -> jax.Array:
+    """``route``'s choice under a group limit with a selection bias (or a
+    group scored by its two largest): scores [n, E] float32 -> the K
+    experts [n, K] with the largest ``score + bias`` inside the
+    ``topk_group`` groups whose own score (their largest ``score + bias``,
+    or the sum of their two largest) is best. No gradient passes."""
+    n_group, kept_groups = groups
+    on = jax.lax.stop_gradient(scores if select_bias is None else
+                               scores + select_bias.astype(jnp.float32))
+    by_group = on.reshape(on.shape[0], n_group, -1)
+    of_group = (by_group.max(-1) if group_score == "max"
+                else jax.lax.top_k(by_group, 2)[0].sum(-1))
+    _, best = jax.lax.top_k(of_group, kept_groups)
+    allowed = (best[..., None] == jnp.arange(n_group)).any(-2)
+    # ``score + bias`` may be negative: -inf outside the kept groups
+    return jax.lax.top_k(jnp.where(
+        allowed[..., None], by_group, -jnp.inf).reshape(on.shape), top_k)[1]
 
 
 def _swiglu_rows(rows, w_rows, sizes, e_gate, e_up, e_down):
@@ -453,7 +485,8 @@ def routed_experts(x: jax.Array, router_w: jax.Array, e_gate: jax.Array,
                    renorm_eps: float = 0.0, keep_choices: bool = False,
                    groups: Optional[Tuple[int, int]] = None,
                    headroom: Optional[int] = None,
-                   router_x: Optional[jax.Array] = None
+                   router_x: Optional[jax.Array] = None,
+                   group_score: str = "max"
                    ) -> Tuple[jax.Array, ...]:
     """x [n, h], router_w [h, E], e_gate / e_up [E, h, f], e_down
     [E, f, h] -> (out [n, h], router_logits [n, E] float32, counts [E]
@@ -463,11 +496,12 @@ def routed_experts(x: jax.Array, router_w: jax.Array, e_gate: jax.Array,
     ``first .. first + count`` alone, ``[count, ...]``, and ``out`` is
     their part of the result (the module's docstring) in passes of
     ``_held_chunk(.., headroom)`` rows; ``None``: all
-    ``E`` are here. ``score``, ``select_bias``, ``renorm_eps`` and
-    ``groups`` are ``route``'s; ``keep_choices`` appends ``route``'s own
-    ``top_e [n, K]``
-    to the result, for a check of what was chosen. ``e_gate=None``: experts
-    of two matrices around a squared ReLU (``_expert_rows``). ``router_x
+    ``E`` are here. ``score``, ``select_bias``, ``renorm_eps``,
+    ``groups`` and ``group_score`` are ``route``'s; ``keep_choices``
+    appends ``route``'s own ``top_e [n, K]`` and ``top_w [n, K]`` to the
+    result, for a check of what was chosen and how it was weighted.
+    ``e_gate=None``: experts of two matrices around a squared ReLU
+    (``_expert_rows``). ``router_x
     [n, hidden]``: what the router reads where that is not the rows the
     experts multiply (experts in a latent: ``x`` is then ``[n, latent]``,
     and so are ``out`` and the experts' outer widths)."""
@@ -477,8 +511,9 @@ def routed_experts(x: jax.Array, router_w: jax.Array, e_gate: jax.Array,
         logits, top_w, top_e = route(
             x if router_x is None else router_x, router_w, top_k,
             renormalize, scale, score=score,
-            select_bias=select_bias, renorm_eps=renorm_eps, groups=groups)
-        choices = (top_e,) if keep_choices else ()
+            select_bias=select_bias, renorm_eps=renorm_eps, groups=groups,
+            group_score=group_score)
+        choices = (top_e, top_w) if keep_choices else ()
         flat_e = top_e.reshape(-1)
         if held is not None:
             first, count = held
@@ -526,13 +561,13 @@ def routed_experts_on(mesh, x: jax.Array, router_w: jax.Array,
                       **how) -> Tuple[jax.Array, ...]:
     """``routed_experts`` for x [b, s, h] -> (out [b, s, h], router_logits
     [b * s, E] float32, counts [E] and, under ``keep_choices``, the
-    choices [b * s, K]); ``how`` is its keywords, ``router_x [b, s,
-    hidden]`` what the router reads where ``x`` is a latent. On a mesh
-    every chip routes its own rows of the batch to all the experts here
-    (their weights gathered whole, as fsdp gathers any weight): the sort
-    and the grouped matmuls stay local, which a Mosaic call under a
-    sharded jit needs anyway, and ``counts`` are summed over the batch
-    axes."""
+    choices and their weights [b * s, K]); ``how`` is its keywords,
+    ``router_x [b, s, hidden]`` what the router reads where ``x`` is a
+    latent. On a mesh every chip routes its own rows of the batch to all
+    the experts here (their weights gathered whole, as fsdp gathers any
+    weight): the sort and the grouped matmuls stay local, which a Mosaic
+    call under a sharded jit needs anyway, and ``counts`` are summed over
+    the batch axes."""
     h = x.shape[-1]
     weights = (router_w, e_gate, e_up, e_down) + (
         () if select_bias is None else (select_bias,))
@@ -562,7 +597,7 @@ def routed_experts_on(mesh, x: jax.Array, router_w: jax.Array,
         return (out, logits, jax.lax.psum(counts, rows) if rows else counts,
                 *chosen)
 
-    by_row = (P(rows),) * bool(how.get("keep_choices"))
+    by_row = (P(rows),) * 2 * bool(how.get("keep_choices"))
     return jax.shard_map(
         sharded, mesh=mesh, in_specs=(P(rows),) + (P(),) * len(weights),
         out_specs=(P(rows), P(rows), P()) + by_row,
@@ -621,7 +656,8 @@ def routed_part(shared=False, score: str = "softmax",
                 bias: bool = False, renorm_eps: Optional[str] = None,
                 balance=False, width: str = "moe_intermediate_size",
                 renormalize: bool = True, groups: bool = False,
-                latent: Optional[str] = None, act: str = "silu") -> Part:
+                latent: Optional[str] = None, act: str = "silu",
+                group_score: str = "max") -> Part:
     """A routed mixture as a layer's MLP (or, in a table of one-part
     kinds, the whole layer): ``x + [shared(u)] + routed(u)``,
     ``u = RMSNorm(x)``: ``cfg.num_experts`` experts of ``width`` (the
@@ -652,10 +688,11 @@ def routed_part(shared=False, score: str = "softmax",
     reports each sequence's counts and mean scores and the loss gains
     ``cfg.router_aux_coef`` x ``sequence_balance``; without, the counts
     alone. ``groups``: the choice is limited to ``cfg.topk_group`` of
-    ``cfg.n_group`` groups of experts (``route``). A
+    ``cfg.n_group`` groups of experts (``route``; ``group_score`` is its
+    too, and with ``bias`` the groups are scored on ``score + bias``). A
     layer reports under "router"; asked for (``ctx.keep_router_logits``),
     the router's logits too and, where a bias or a group limit took part
-    in them, ``route``'s own choices."""
+    in them, ``route``'s own choices and the weights it gave them."""
     by_sequence = balance == "sequence"
     if act not in ("silu", "relu2"):
         raise ValueError(f"unknown expert activation {act!r} (silu | relu2)")
@@ -739,7 +776,7 @@ def routed_part(shared=False, score: str = "softmax",
                 keep_choices=by_sequence or (
                     (bias or groups) and ctx.keep_router_logits),
                 groups=(cfg.n_group, cfg.topk_group) if groups else None,
-                headroom=headroom(cfg))
+                headroom=headroom(cfg), group_score=group_score)
             if by_sequence:
                 with jax.named_scope("moe_route"):
                     b, E = x.shape[0], counts.shape[0]
@@ -756,7 +793,7 @@ def routed_part(shared=False, score: str = "softmax",
             if ctx.keep_router_logits:
                 router["logits"] = logits
                 if bias or groups:
-                    router["chosen"] = chosen[0]
+                    router["chosen"], router["weights"] = chosen
             if latent:
                 with jax.named_scope("moe_latent"):
                     out = _to_latent(out, p["l_up"].astype(dt))

@@ -47,6 +47,8 @@ seconds this machine took to trace the step and to lower it, and how often
 each Mosaic call's body was lowered, by the call's name: what a change
 does to a cell's ``setup_trace_lower_s``, read here before a chip reads
 it; the bench host takes about 2.5 times the seconds, PR 58).
+A Mosaic call's body is compared without its source lines (a location is
+left unknown), so both directories of a ``--compare`` come from this script.
 ``--compare`` judges the program (``PROGRAM_FIELDS``) and says of two
 differing programs how many lines changed and how many of those are calls
 of the flash kernels.
@@ -279,6 +281,12 @@ def compile_cells(tree: str, out: str, only=()) -> None:
     # moving a line of llama.py changes three lines of the text (PR 28).
     # Keep the innermost frame alone, the kernel's own source.
     jax.config.update("jax_traceback_in_locations_limit", 0)
+    # And of that frame no file or line: lines added above a kernel in its
+    # module renumber the kernel's and change nothing it computes (PR 60:
+    # ``ops/delta.py``'s docstring and walk grew above the rule's kernels).
+    # Without a user frame a location is unknown; the scopes' names stay.
+    from jax._src import source_info_util
+    source_info_util.user_frame = lambda traceback: None
     topo = topologies.get_topology_desc(platform="tpu",
                                         topology_name="v5e:2x2")
     jax.default_backend = lambda: "tpu"

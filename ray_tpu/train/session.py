@@ -94,13 +94,18 @@ class TrainContext:
 # stops learning to rank as the attention weighs shows here first); of a
 # stack with a multi-token prediction module (``mtp_cross_entropy``: the
 # module's cross entropy of the token after the next,
-# ``stack.Stack.loss_terms``, beside the loss it is a tenth of). A new
-# operator adds its counter's name here.
+# ``stack.Stack.loss_terms``, beside the loss it is a tenth of); of Kimi
+# Delta Attention layers (``kda_state_abs_max``: as ``gdn_state_abs_max``;
+# ``kda_log_decay_min``: the smallest log decay a key channel of a step,
+# ``ops/delta.kda_mixer``, which must stay above the gate's lower bound: at
+# it a sub-block's decays leave float32). A new operator adds its
+# counter's name here.
 STEP_COUNTERS = ("moe_rows_routed", "moe_rows_held", "moe_rows_passed",
                  "moe_expert_load_max_over_mean", "moe_router_bias_abs_max",
                  "ssm_state_abs_max", "gdn_state_abs_max",
                  "dsa_pairs_chosen_share", "dsa_index_loss",
-                 "mtp_cross_entropy")
+                 "mtp_cross_entropy", "kda_state_abs_max",
+                 "kda_log_decay_min")
 
 
 class SessionInterruptedError(BaseException):

@@ -239,6 +239,14 @@ def _group(tree, group):
     return tree["mtp"] if group == "mtp" else tree["layers"][group]
 
 
+def _report(said, key):
+    """What a row's ``state`` names of the layers' reports: all of them
+    (None), one name, or a path of names."""
+    for name in () if key is None else (key,) if isinstance(key, str) else key:
+        said = said[name]
+    return said
+
+
 def _flat(tree):
     return {jax.tree_util.keystr(path): leaf for path, leaf in
             jax.tree_util.tree_flatten_with_path(tree)[0]}
@@ -287,7 +295,7 @@ def test_output_matches_the_reference(case):
         _routers_match(case, said.get("router", said))
     if "state" in row.reports:
         key, _, shape = row.state
-        states = said if key is None else said[key]
+        states = _report(said, key)
         want = case.want["last_states"]
         assert states.shape == want.shape == (
             3, case.tokens.shape[0]) + shape(case.cfg)
@@ -327,9 +335,8 @@ def test_each_term_of_the_loss_matches_the_reference(case):
         np.testing.assert_allclose(np.asarray(nll), case.want["nll"],
                                    rtol=1e-5, atol=row.reports["state"])
         said = case.program[1]
-        np.testing.assert_array_equal(
-            np.asarray(said if key is None else said[key]),
-            np.asarray(again if key is None else again[key]))
+        np.testing.assert_array_equal(np.asarray(_report(said, key)),
+                                      np.asarray(_report(again, key)))
         np.testing.assert_allclose(float(terms[counter]),
                                    case.want["state_abs_max"], rtol=1e-5)
         assert case.want["state_abs_max"] == np.abs(

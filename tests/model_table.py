@@ -180,6 +180,22 @@ def _nemotron_h_says(cfg, params):
                                   "layers", "final_norm"}
 
 
+def _ling3_says(cfg, params):
+    assert cfg.pattern == ("kda+dense", "kda+moe", "mla+moe", "kda+moe")
+    kda, mla = params["layers"]["kda+moe"], params["layers"]["mla+moe"]
+    # q k v 3 x 4 x 16 | the decay's f 4 x 16 | b and the gate 4 each
+    assert kda["k_in"].shape == (2, 64, 192 + 64 + 8)
+    assert kda["k_conv"].shape == (2, 192, 4)
+    assert kda["k_dt_bias"].shape == (2, 64)       # a bias a key channel
+    assert kda["k_A_log"].shape == (2, 4)          # a rate a head
+    assert mla["wq"].shape == (1, 64, 4 * 24)      # no query latent
+    assert "wq_a" not in mla and "q_a_norm" not in mla
+    assert mla["wg"].shape == (1, 64, 4)           # a gate a head
+    assert mla["router_bias"].dtype == jnp.float32
+    assert mla["e_gate"].shape == (1, cfg.experts_here, 64, 32)
+    assert "router" not in params["layers"]["kda+dense"]
+
+
 # ---- where a reference takes or hands back something of its own
 
 
@@ -340,6 +356,68 @@ def _qwen3_next_gradients(case):
     want = jax.jit(jax.grad(lambda p: case.ref.loss(
         cfg, p, tokens[:1], forced_topk=chosen)))(params)
     return got, want
+
+
+def _ling3_reports(case):
+    """No token's choices span more than ``topk_group`` groups, and the
+    smallest log decay stays above the bound."""
+    cfg, said = case.cfg, case.program[1]
+    chosen = np.asarray(said["router"]["chosen"])
+    per_group = cfg.num_experts // cfg.n_group
+    assert max(len(set(row // per_group)) for row in chosen.reshape(
+        -1, cfg.top_k)) == cfg.topk_group
+    least = np.asarray(said["kda"]["log_decay_min"])
+    assert least.shape == (3,) and (least > cfg.kda_lower_bound).all()
+    np.testing.assert_allclose(least.min(), case.want["log_decay_min"],
+                               rtol=1e-5)
+
+
+def _ling3_rungs(kinds):
+    # the latent layer keeps its kv latent alone on the first rung (no
+    # query latent) beside the flash output and log-sum-exp
+    assert kinds["mla+moe"]["rungs"][0] == 64 * (4 * 12 * 2 + 4 * 4
+                                                 + (24 + 8) * 2)
+    # a KDA layer's one rung that keeps anything is its MLP's
+    assert kinds["kda+moe"]["rungs"][:2] == (0, 0)
+    assert kinds["kda+dense"]["rungs"][:2] == (0, 0)
+
+
+def _ling3_hand_counts():
+    """``benchmark/lib/kda_moe_flops.py`` at the configuration file's sizes
+    against the issue's arithmetic."""
+    from benchmark.lib import kda_moe_flops as lib
+    from benchmark.lib import spec
+
+    m = spec.model_sizes(json.load(open(os.path.join(
+        spec.ROOT, "benchmark/configs/ling-3.0-flash-vl-c1.json"))))
+    assert (lib.count(m, "kda"), lib.count(m, "mla"), lib.count(m, "dense"),
+            lib.count(m, "moe")) == (6, 1, 1, 6)
+    # a KDA mixer's matrices: W_q, W_k, W_v, W_f, W_b, W_g, W_o
+    assert lib.kda_proj_params(m) == 5 * 2560 * 4096 + 2 * 2560 * 32 \
+        == 52_648_608 - 2560 - 3 * 4096 * 4 - 32 - 4096 - 128
+    assert lib.mla_proj_params(m) == 31_968_256 - 2560 - 512
+    assert lib.dense_params(m) == 47_188_480 - 2560
+    assert lib.router_params(m) + lib.shared_params(m) == 7_211_520 - 2560
+    assert lib.expert_params(m) == 5_898_240
+    assert lib.head_params(m) == 2560 * 19_648
+    assert lib.conv_dim(m) == 12_288
+    # 96 TFLOP of token matmuls; the latent layer's causal attention 11 a
+    # forward, 33 at the MFU's three times, 39.6 as the kernels' seven
+    # products
+    assert 95e12 < 6.0 * lib.token_matmul_params(m) * 32768 < 97e12
+    fwd = 32 * (2 * 192 + 2 * 128) * 32768 * 32769 / 2
+    assert lib.attention_flops_fwd(m, 1, 32768) == fwd
+    assert lib.flash_flops_per_step(m, 1, 32768) == 3.6 * fwd
+    assert lib.experts_train_flops(m, 6 * 4096) == 6.0 * 5_898_240 * 24_576
+    # the rule: lib/delta_flops.py's count at 32 heads of 128 / 128
+    C, H, K, V = 64, 32, 128, 128
+    fwd = 32768 / C * H * (C * (C + 1) / 2 * (6 * K + 4 * V) + C ** 3 / 3
+                           + 6 * C * K * V)
+    assert lib.rule_flops_per_step(m, 1, 32768) == 3 * 6 * fwd
+    # q, k, v, g and beta in, o out; the backward those, do, five gradients
+    ins, out = (3 * 4096 + 4096 + 32) * 2, 4096 * 2
+    assert lib.rule_bytes_per_step(m, 32768) == 6 * 32768 * (
+        (ins + out) + (2 * ins + out))
 
 
 def _lfm2_terms(case, loss, terms):
@@ -805,6 +883,7 @@ def _relu2_share(ref, cfg, p, u):
 
 
 _STATES = {
+    "ling3": (("kda", "state"), "kda_state_abs_max", lambda c: (4, 16, 16)),
     "granite": (None, "ssm_state_abs_max", lambda c: (
         c.ssm_heads, c.ssm_head_dim, c.ssm_state)),
     "olmo_hybrid": (None, "gdn_state_abs_max", lambda c: (
@@ -970,6 +1049,33 @@ ROWS = {row.name: row for row in (
         plan={"tiny": {"experts_held": (0, 4)}, "tokens": 64,
               "runs": (("linear", 3), ("full", 1)),
               "rungs": _qwen3_next_rungs}),
+    Row("ling3", "Ling3Config",
+        shares={"all-experts": {"experts_held": None},
+                "held-4..7": {"experts_held": (4, 4)}},
+        # the norms are drawn as ones, the biases as zeros: a weight left
+        # out, or a bias left out of the choice, would go unseen
+        moved=(("attn_norm", 0.3), ("op_norm", 0.3), ("mlp_norm", 0.3),
+               ("kv_a_norm", 0.3), ("k_norm", 0.3), ("router_bias", 0.05)),
+        says=_ling3_says,
+        groups={"top": 3, "kda+dense": 11, "kda+moe": 15, "mla+moe": 15},
+        forced=lambda case: {"forced_topk": np.asarray(
+            case.program[1]["router"]["chosen"])},
+        forced_in=("weighted",),
+        # (5e-5 as Olmo-Hybrid's: three rule layers hand their rounding on)
+        logits_tol=(1e-5, 5e-5), term_tol={"": (1e-5, 0.0)},
+        reports={**_ROUTE, "router": 5e-5, "state": 1e-5},
+        state=_STATES["ling3"], reports_also=_ling3_reports,
+        gradient_shares=("held-4..7",),
+        # (a KDA layer at tiny()'s decays, many at the bound, sums products
+        # of factors near exp(75) and exp(-75): 2e-4 of a leaf's largest)
+        grad_tol=(1e-3, 2e-4, 1e-2, 1e-6), weighted=2,
+        expert_shares={"kind": "kda+moe", "tiny": {}, "each": 4,
+                       "norm": _norm, "shared": _routed_layers_share,
+                       "bias": 0.05},
+        plan={"tiny": {"experts_held": (0, 4)}, "tokens": 64,
+              "runs": (("kda+dense", 1), ("kda+moe", 1), ("mla+moe", 1),
+                       ("kda+moe", 1)), "rungs": _ling3_rungs},
+        hand_counts=_ling3_hand_counts),
     Row("nemotron_h", "Nemotron_hConfig", tiny={},
         tokens=(0, (2, 34), np.int32), ahead=2,
         shares={"whole": {"experts_held": None},

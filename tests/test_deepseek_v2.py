@@ -161,5 +161,11 @@ def test_route_limits_the_choice_to_the_best_groups():
     # and renormalised where asked
     _, renorm, _ = route(x, jnp.eye(160), 6, renormalize=True, groups=(8, 3))
     np.testing.assert_allclose(np.asarray(renorm).sum(-1), 1.0, rtol=1e-6)
-    with pytest.raises(ValueError, match="group limit"):
-        route(x, jnp.eye(160), 6, groups=(8, 3), select_bias=jnp.zeros(160))
+    # a selection bias under the limit (PR 60; it raised before): zeros
+    # change no choice, and the weights are the scores without it
+    _, biased_w, biased_e = route(x, jnp.eye(160), 6, scale=16.0,
+                                  groups=(8, 3), select_bias=jnp.zeros(160))
+    assert (np.sort(np.asarray(biased_e), -1)
+            == np.sort(np.asarray(top_e), -1)).all()
+    np.testing.assert_allclose(np.sort(np.asarray(biased_w), -1),
+                               np.sort(np.asarray(top_w), -1), rtol=1e-6)

@@ -969,7 +969,8 @@ def test_train_session_serves_the_last_reported_scan_counter(op):
                                        _TrainSession)
 
     assert {"ssm_state_abs_max", "gdn_state_abs_max"} <= set(STEP_COUNTERS)
-    assert len(set(STEP_COUNTERS)) == len(STEP_COUNTERS) == 10
+    assert len(set(STEP_COUNTERS)) == len(STEP_COUNTERS) == 12
+    assert {"kda_state_abs_max", "kda_log_decay_min"} <= set(STEP_COUNTERS)
 
     def loop():
         from ray_tpu import train
@@ -1065,6 +1066,7 @@ def test_gdn_rule_plan_is_one_kept_span_of_a_traced_call(monkeypatch):
     walk = {"seq": 32768, "chunk": 64, "chunks": 512, "walk": 8,
             "steps": 64, "heads": 30, "key_heads": 30, "joined": None,
             "key_dim": 96, "value_dim": 192, "form": "xla_walk",
+            "decay": "head",
             "heads_a_block": None, "chunks_a_call": 8, "operands": None,
             "states_kept": 64, "float32_bytes_in_hbm": 8 * one,
             "float32_bytes_all_chunks": 512 * one}
